@@ -42,9 +42,16 @@ ICI_BW = 4.8e10          # bytes/s per link-direction class estimate
 def _platform(kind: Optional[str], table: Dict[str, float],
               default: float) -> float:
     if kind:
+        low = kind.lower()
         for key, val in table.items():
-            if key in kind.lower():
+            if key in low:
                 return val
+        if "tpu" in low:
+            # a TPU generation the table lacks must not borrow another
+            # chip's peak: every MFU and roofline share would be wrong
+            raise ValueError(
+                f"device_kind {kind!r} is a TPU the cost model's peak "
+                f"tables do not list (known: {sorted(table)}) — add it")
     return default
 
 

@@ -22,7 +22,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 import jax
-from ..utils.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 

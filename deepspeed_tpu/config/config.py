@@ -1050,11 +1050,11 @@ class CompileCacheConfig(ConfigModel):
     where the segment programs can take minutes to compile — with the cache
     they compile ONCE (optionally incrementally, see
     ``ParamOffloadExecutor.compile_step_programs``) and every later run
-    loads them in milliseconds. Default on; dir overridable via env
-    ``DSTPU_COMPILE_CACHE``."""
+    loads them from disk. Default on; the directory is
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else a fixed path in the
+    checkout (``utils/compile_cache.py``) — not a setting here."""
 
     enabled: bool = True
-    dir: str = ""          # "" => $DSTPU_COMPILE_CACHE or ~/.cache/deepspeed_tpu/xla
     min_compile_time_secs: float = 1.0
 
 

@@ -68,7 +68,7 @@ class InferenceConfig:
     #   reference's int8 path also quantizes activations,
     #   pt_binding.cpp quantize_activation). dtype='w8a8' sets this.
     compile_cache: bool = True         # persistent XLA compile cache
-    #   (utils/compile_cache.py); DSTPU_COMPILE_CACHE overrides dir/disables
+    #   (utils/compile_cache.py says where it lives)
     prompt_bucket: int = 64            # prompt-length compile bucket: prompts
     #   pad up to a multiple of this, bounding the number of distinct
     #   compiled prefill programs. The serving layer pins it to its KV

@@ -22,8 +22,13 @@ one ``T_max`` row per sequence, the time axis is carved into fixed-size
 blocks shared by every in-flight request (vLLM's PagedAttention block
 tables, Kwon et al. SOSP '23):
 
-    {"k": (L, NUM_BLOCKS, BLOCK, KV_HEADS, HEAD_DIM),
-     "v": (L, NUM_BLOCKS, BLOCK, KV_HEADS, HEAD_DIM)}
+    {"k": (L, NUM_BLOCKS, BLOCK, KV_HEADS * HEAD_DIM),
+     "v": (L, NUM_BLOCKS, BLOCK, KV_HEADS * HEAD_DIM)}
+
+A token's KV heads share one lane-dense row: the TPU stores and blocks
+arrays in (8, 128) tiles of the last two dims, so a trailing
+``(KV_HEADS, 64)`` would be padded to twice its size and could not be read
+a head at a time (``ops/paged_decode_attention.py``).
 
 Block 0 is a reserved scratch block: writes of inactive decode rows and
 prompt-chunk padding land there, so the jit program needs no write-masking
@@ -104,6 +109,11 @@ def assert_block_divisible(max_seq_len: int, block_size: int) -> int:
     return max_seq_len // block_size
 
 
+def _paged_shape(cfg, num_blocks: int, block_size: int):
+    return (cfg.num_layers, num_blocks, block_size,
+            cfg.num_kv_heads * cfg.head_dim)
+
+
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype
                      ) -> Dict[str, jax.Array]:
     """Allocate the paged arena: ``num_blocks`` INCLUDES the reserved
@@ -111,8 +121,7 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype
     if num_blocks < 2:
         raise ValueError(f"num_blocks={num_blocks}: need the scratch block "
                          "plus at least one allocatable block")
-    L, K, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    shape = (L, num_blocks, block_size, K, D)
+    shape = _paged_shape(cfg, num_blocks, block_size)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -125,7 +134,6 @@ def paged_cache_memory_bytes(cfg, num_blocks: int, block_size: int,
 
 def paged_cache_shape_struct(cfg, num_blocks: int, block_size: int,
                              dtype) -> Dict[str, Any]:
-    L, K, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    shape = (L, num_blocks, block_size, K, D)
+    shape = _paged_shape(cfg, num_blocks, block_size)
     return {"k": jax.ShapeDtypeStruct(shape, dtype),
             "v": jax.ShapeDtypeStruct(shape, dtype)}

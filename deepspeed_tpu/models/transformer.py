@@ -756,7 +756,7 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     attention path throughout.
 
     ``block_table`` switches the cache to PAGED mode (serving layer): the
-    per-layer cache is a shared pool ``{"k","v": (NUM_BLOCKS, BLOCK, K, D)}``
+    per-layer cache is a shared pool ``{"k","v": (NUM_BLOCKS, BLOCK, K*D)}``
     and ``block_table`` (B, MAX_BLOCKS) maps each row's logical blocks to
     physical ids. ``positions`` must then be the (B, S) absolute write
     positions; ``paged_write_mask`` (B, S) routes masked-off tokens (prompt
@@ -882,8 +882,11 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             # chunk padding / inactive decode rows write to scratch block 0
             blk = jnp.where(paged_write_mask, blk, 0)
             off = jnp.where(paged_write_mask, off, 0)
-        ck = cache["k"].at[blk, off].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[blk, off].set(v.astype(cache["v"].dtype))
+        # a pool row is one token's K*D lanes (ops/paged_decode_attention.py)
+        ck = cache["k"].at[blk, off].set(
+            k.reshape(B, S, K * D).astype(cache["k"].dtype))
+        cv = cache["v"].at[blk, off].set(
+            v.reshape(B, S, K * D).astype(cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
         use_dense = (paged_impl == "gather" or cfg.attention_impl is not None
                      or window is not None or cfg.attention_scale is not None)
@@ -1134,7 +1137,7 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     values differ).
 
     ``block_table`` (B, MAX_BLOCKS) switches the cache to the PAGED layout
-    ``{"k","v": (L, NUM_BLOCKS, BLOCK, K, D)}`` (serving layer); ``positions``
+    ``{"k","v": (L, NUM_BLOCKS, BLOCK, K*D)}`` (serving layer); ``positions``
     is then REQUIRED — per-row absolute write positions — and
     ``paged_write_mask`` (B, S) routes padding writes to the scratch block.
     ``paged_impl``/``paged_chunk`` select the paged read path (see

@@ -87,7 +87,7 @@ def build_replica_checksum_probe(mesh, param_specs) -> Callable:
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     other_axes = tuple(a for a in mesh.axis_names
                        if a != DATA_AXIS and mesh.shape[a] > 1)

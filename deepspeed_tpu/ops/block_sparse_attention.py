@@ -198,7 +198,7 @@ def _sparse_fwd(q, k, v, plan: TilePlan, *, causal: bool, scale: float,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, N, S, D), q.dtype),
                    jax.ShapeDtypeStruct((B, N, S, LANES), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -326,7 +326,7 @@ def _sparse_bwd(causal, scale, interpret, plan: TilePlan, residuals, grads):
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, na=A),
         grid_spec=dq_spec,
         out_shape=[jax.ShapeDtypeStruct((B, N, S, D), q.dtype)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -362,7 +362,7 @@ def _sparse_bwd(causal, scale, interpret, plan: TilePlan, residuals, grads):
         grid_spec=dkv_spec,
         out_shape=[jax.ShapeDtypeStruct((B, N, S, D), k.dtype),
                    jax.ShapeDtypeStruct((B, N, S, D), v.dtype)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -41,6 +41,20 @@ LANES = 128
 VMEM_KV_BUDGET = 8 << 20
 
 
+def kv_vmem_bytes_per_token(kv_heads: int, head_dim: int, dtype) -> int:
+    """VMEM bytes one cache token costs as double-buffered k+v blocks of
+    ``(tokens, K, D)``. The block's last two dims are laid out in
+    (sublane, 128-lane) tiles — 8 sublanes of 32-bit words, so 16 rows of
+    bf16, 32 of int8 — and a short dim is padded up to its tile: at
+    head_dim 64 a block takes twice its nominal bytes, which is what put
+    the B>=8 decode of a 32x64 model 100 KB over the scoped limit."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(4 // itemsize, 1)
+    k_pad = -(-kv_heads // sublanes) * sublanes
+    d_pad = -(-head_dim // LANES) * LANES
+    return k_pad * d_pad * itemsize * 4
+
+
 def _kernel(q_ref, k_ref, v_ref, valid_ref, alibi_ref, kpos_ref, o_ref,
             acc, m_scr, l_scr, *, scale: float, bt: int, t_total: int,
             n_heads: int, kv_heads: int, has_alibi: bool):
@@ -125,8 +139,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     T, K = k_cache.shape[1], k_cache.shape[2]
     # the double-buffered k/v blocks must fit scoped VMEM (see
     # VMEM_KV_BUDGET above)
-    itemsize = jnp.dtype(k_cache.dtype).itemsize
-    per_t = K * D * itemsize * 4            # k+v, double-buffered
+    per_t = kv_vmem_bytes_per_token(K, D, k_cache.dtype)
     budget = VMEM_KV_BUDGET
     # bt is a middle block dim so sub-128 values are legal (the last-two-dims
     # tiling rule applies to (K, D), taken whole); grid = ceil(T/bt), the
@@ -136,7 +149,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     if bt is None:
         raise ValueError(
             f"decode_attention KV blocks do not fit VMEM: {K} kv-heads x "
-            f"head_dim {D} x {itemsize}B needs {per_t} B/token — reduce "
+            f"head_dim {D} ({k_cache.dtype}) needs {per_t} B/token — reduce "
             "kv heads per device (tensor parallelism) or cache dtype")
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
@@ -176,7 +189,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((N, LANES), jnp.float32),
             pltpu.VMEM((N, LANES), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k_cache, v_cache, valid3, alibi_arr, kpos3)

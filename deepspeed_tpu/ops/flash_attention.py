@@ -152,7 +152,7 @@ def _fwd(q: jax.Array, k: jax.Array, v: jax.Array, kvm: jax.Array,
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, kvm, slopes)
@@ -292,7 +292,7 @@ def _bwd(causal: bool, scale: float, kv_len: int, has_mask: bool,
         out_specs=[pl.BlockSpec((1, 1, bq, D), lambda b, n, x, y: (b, n, x, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, N, S, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse_pad, delta, kvm, slopes)[0]
@@ -322,7 +322,7 @@ def _bwd(causal: bool, scale: float, kv_len: int, has_mask: bool,
                    jax.ShapeDtypeStruct((B, N, T, D), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse_pad, delta, kvm, slopes)

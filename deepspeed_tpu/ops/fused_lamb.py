@@ -45,8 +45,11 @@ def _lamb_phase1_kernel(p_ref, g_ref, m_ref, v_ref, bc_ref,
     u_out[:] = u
     m_out[:] = m
     v_out[:] = v
-    norms_out[0, 0] = jnp.sum(p * p)
-    norms_out[0, 1] = jnp.sum(u * u)
+    # one full (8, 128) tile per block — the smallest block the TPU lowering
+    # takes; row 0 carries sum(p^2), row 1 sum(u^2)
+    row = jax.lax.broadcasted_iota(jnp.int32, norms_out.shape[1:], 0)
+    norms_out[0] = jnp.where(row == 0, jnp.sum(p * p),
+                             jnp.where(row == 1, jnp.sum(u * u), 0.0))
 
 
 def fused_lamb_flat(params: jax.Array, grads: jax.Array, exp_avg: jax.Array,
@@ -85,18 +88,18 @@ def fused_lamb_flat(params: jax.Array, grads: jax.Array, exp_avg: jax.Array,
         in_specs=[bspec, bspec, bspec, bspec,
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[bspec, bspec, bspec,
-                   pl.BlockSpec((1, 2), lambda i: (i, 0))],
+                   pl.BlockSpec((1, 8, 128), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((total,), jnp.float32),
                    jax.ShapeDtypeStruct((total,), jnp.float32),
                    jax.ShapeDtypeStruct((total,), jnp.float32),
-                   jax.ShapeDtypeStruct((blocks, 2), jnp.float32)],
+                   jax.ShapeDtypeStruct((blocks, 8, 128), jnp.float32)],
         input_output_aliases={2: 1, 3: 2},
         interpret=interpret,
     )(params, grads, exp_avg, exp_avg_sq, bc)
 
     # padded tail contributes 0 to both partial sums (p and g pads are 0, so
     # u there is 0 + wd*0), so the norms are exact
-    sums = jnp.sum(partials, axis=0)
+    sums = jnp.sum(partials[:, :2, 0], axis=0)
     p_norm, u_norm = jnp.sqrt(sums[0]), jnp.sqrt(sums[1])
     ratio = jnp.where((p_norm > 0.0) & (u_norm > 0.0),
                       jnp.clip(p_norm / u_norm, min_coeff, max_coeff), 1.0)

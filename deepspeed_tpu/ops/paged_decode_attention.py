@@ -25,6 +25,14 @@ arena is LEFT-ALIGNED — the token at absolute position ``p`` sits in block
 coordinate IS its position: causality over true positions is the entire
 validity story and the alibi key bias is exact by construction.
 
+A pool is ``(NUM_BLOCKS, BLOCK, K*D)``: a token's KV heads lie side by side
+in the lane dimension. The TPU lowering takes a block whose last two dims
+are multiples of (8, 128) or the whole array dims, and stores arrays in
+such tiles: a ``(BLOCK, K, D)`` page cannot be blocked one head at a time,
+and at head_dim 64 is padded to twice its size. A ``(BLOCK, K*D)`` page is
+lane-dense as stored, and the kernels slice heads out of it by static lane
+offsets.
+
 ``reference_paged_attention`` is the pure-jnp oracle and CPU fallback:
 GQA-native over the gathered view (no head expansion, no (B,S,T) mask
 materialization) — also measurably leaner than the PR-6 gather +
@@ -48,15 +56,30 @@ LANES = 128
 from .decode_attention import VMEM_KV_BUDGET as _VMEM_PAGE_BUDGET
 
 
-def _check_page_fits(block_size: int, kv_heads: int, head_dim: int,
-                     itemsize: int) -> None:
-    per_page = block_size * kv_heads * head_dim * itemsize * 4
+def _check_page_fits(block_size: int, width: int, dtype) -> None:
+    """k + v ``(block_size, width)`` pages, double-buffered, as VMEM holds
+    them: rows pad to the dtype's sublane tile, lanes to 128."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(4 // itemsize, 1)
+    rows = -(-block_size // sublanes) * sublanes
+    lanes = -(-width // LANES) * LANES
+    per_page = rows * lanes * itemsize * 4
     if per_page > _VMEM_PAGE_BUDGET:
         raise ValueError(
             f"paged attention KV pages do not fit VMEM: block_size "
-            f"{block_size} x {kv_heads} kv-heads x head_dim {head_dim} x "
-            f"{itemsize}B needs {per_page} B double-buffered — shrink "
-            "serving.block_size or shard KV heads (tensor parallelism)")
+            f"{block_size} x {width} lanes ({jnp.dtype(dtype).name}) needs "
+            f"{per_page} B double-buffered — shrink serving.block_size or "
+            "shard KV heads (tensor parallelism)")
+
+
+def _kv_heads(pool: jax.Array, n_heads: int, head_dim: int) -> int:
+    width = pool.shape[-1]
+    if pool.ndim != 3 or width % head_dim != 0 \
+            or n_heads % (width // head_dim) != 0:
+        raise ValueError(
+            f"paged pool must be (NUM_BLOCKS, BLOCK, K*D) with K dividing "
+            f"n_heads {n_heads} at head_dim {head_dim}, got {pool.shape}")
+    return width // head_dim
 
 
 # ---------------------------------------------------------------------------
@@ -84,13 +107,14 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
     @pl.when(j * bs < length)
     def _step():
         q = q_ref[0].astype(jnp.float32) * scale      # (N, D)
-        k = k_ref[0].astype(jnp.float32)              # (bs, K, D)
-        v = v_ref[0].astype(jnp.float32)              # (bs, K, D)
+        D = q.shape[-1]
+        k = k_ref[0].astype(jnp.float32)              # (bs, K*D)
+        v = v_ref[0].astype(jnp.float32)              # (bs, K*D)
         parts = []
         for kh in range(kv_heads):
             qg = q[kh * G:(kh + 1) * G]               # (G, D) static slice
             parts.append(jax.lax.dot_general(
-                qg, k[:, kh, :], (((1,), (1,)), ((), ())),
+                qg, k[:, kh * D:(kh + 1) * D], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32))  # (G, bs)
         s = jnp.concatenate(parts, axis=0)            # (N, bs)
         col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
@@ -109,7 +133,7 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
         for kh in range(kv_heads):
             pg = p[kh * G:(kh + 1) * G]
             outs.append(jax.lax.dot_general(
-                pg, v[:, kh, :], (((1,), (0,)), ((), ())),
+                pg, v[:, kh * D:(kh + 1) * D], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))
         acc[:] = acc[:] * corr + jnp.concatenate(outs, axis=0)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -128,16 +152,15 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            scale: Optional[float] = None,
                            interpret: bool = False) -> jax.Array:
     """q (R, N, D) — one new token per row; k/v_pool (NUM_BLOCKS, BLOCK,
-    K, D) — the shared arena; block_table (R, MAXB) int32 physical page ids
+    K*D) — the shared arena; block_table (R, MAXB) int32 physical page ids
     (unfilled entries 0 = scratch); lengths (R,) int32 — valid keys per row
     INCLUDING the just-written token (0 ⇒ inactive row, output zeros).
     Returns (R, N, D). Reads only each row's resident pages."""
     R, N, D = q.shape
-    BS, K = k_pool.shape[1], k_pool.shape[2]
+    K = _kv_heads(k_pool, N, D)
+    BS = k_pool.shape[1]
     MAXB = block_table.shape[1]
-    if N % K != 0:
-        raise ValueError(f"n_heads {N} not a multiple of kv_heads {K}")
-    _check_page_fits(BS, K, D, jnp.dtype(k_pool.dtype).itemsize)
+    _check_page_fits(BS, K * D, k_pool.dtype)
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
     alibi_arr = (alibi.astype(jnp.float32).reshape(1, N) if has_alibi
@@ -148,15 +171,15 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         # re-request the same block index, which the pipeline recognizes
         # and skips the DMA — only resident pages move
         last = jnp.maximum((len_ref[b] + BS - 1) // BS - 1, 0)
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0, 0)
+        return (bt_ref[b, jnp.minimum(j, last)], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(R, MAXB),
         in_specs=[
             pl.BlockSpec((1, N, D), lambda b, j, bt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, BS, K, D), _page),
-            pl.BlockSpec((1, BS, K, D), _page),
+            pl.BlockSpec((1, BS, K * D), _page),
+            pl.BlockSpec((1, BS, K * D), _page),
             pl.BlockSpec((1, N), lambda b, j, bt, ln: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, N, D), lambda b, j, bt, ln: (b, 0, 0)),
@@ -172,7 +195,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, N, D), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
@@ -184,6 +207,16 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _heads_per_step(kv_heads: int, head_dim: int) -> int:
+    """KV heads one prefill grid step reads: the fewest whose lanes make a
+    legal block of the ``(BLOCK, K*D)`` page — a multiple of 128 (two heads
+    at head_dim 64, one at 128), else the whole page."""
+    for hp in range(1, kv_heads):
+        if kv_heads % hp == 0 and (hp * head_dim) % LANES == 0:
+            return hp
+    return kv_heads
+
+
 def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
                     acc, m_scr, l_scr, *, scale: float, bs: int, C: int,
                     has_alibi: bool):
@@ -191,7 +224,7 @@ def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
     j = pl.program_id(2)
     nj = pl.num_programs(2)
     st = start_ref[b]
-    GC = q_ref.shape[2]
+    HP, GC, D = q_ref.shape[1:]
 
     @pl.when(j == 0)
     def _init():
@@ -202,34 +235,35 @@ def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
     # a page is visible iff it holds positions <= the last query (st + C - 1)
     @pl.when(j * bs < st + C)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * scale   # (GC, D), rows (g, c)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)     # (bs, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
         col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (GC, bs), 1)
         # query row r = (g, c): its absolute position is st + (r mod C)
         qpos = st + jax.lax.broadcasted_iota(jnp.int32, (GC, bs), 0) % C
-        if has_alibi:
-            s = s + alibi_ref[0][:, None] * col.astype(jnp.float32)
-        s = jnp.where(col <= qpos, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_scr.shape)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        for h in range(HP):                           # static: HP is 1 or 2
+            q = q_ref[0, h].astype(jnp.float32) * scale   # (GC, D), rows (g, c)
+            k = k_ref[0, :, h * D:(h + 1) * D].astype(jnp.float32)  # (bs, D)
+            v = v_ref[0, :, h * D:(h + 1) * D].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if has_alibi:
+                s = s + alibi_ref[0, h][:, None] * col.astype(jnp.float32)
+            s = jnp.where(col <= qpos, s, NEG_INF)
+            m_prev = m_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = jnp.broadcast_to(
+                corr * l_scr[h, :, :1] + jnp.sum(p, axis=1, keepdims=True),
+                l_scr.shape[1:])
+            acc[h] = acc[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
 
     @pl.when(j == nj - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[:, :, :1]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[:] / safe).astype(o_ref.dtype)
+        o_ref[0] = (acc[:] / safe).astype(o_ref.dtype)
 
 
 def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
@@ -241,17 +275,18 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     """Chunked-prefill attention through the block table: q (B, C, N, D) —
     C contiguous queries per row at absolute positions ``start[b] + s``
     (the serving ``prefill_chunk`` contract; the chunk's own keys must
-    already be scatter-written into the pool). Returns (B, C, N, D).
-    Grid (B, K, MAXB): each KV head flash-accumulates its G*C query rows
-    page by page; pages past ``start + C`` never move."""
+    already be scatter-written into the pool); pools (NUM_BLOCKS, BLOCK,
+    K*D). Returns (B, C, N, D). Grid (B, K/HP, MAXB): each group of HP KV
+    heads (``_heads_per_step``) flash-accumulates its G*C query rows per
+    head, page by page; pages past ``start + C`` never move."""
     B, C, N, D = q.shape
-    BS, K = k_pool.shape[1], k_pool.shape[2]
+    K = _kv_heads(k_pool, N, D)
+    BS = k_pool.shape[1]
     MAXB = block_table.shape[1]
-    if N % K != 0:
-        raise ValueError(f"n_heads {N} not a multiple of kv_heads {K}")
     G = N // K
     GC = G * C
-    _check_page_fits(BS, 1, D, jnp.dtype(k_pool.dtype).itemsize)
+    HP = _heads_per_step(K, D)
+    _check_page_fits(BS, HP * D, k_pool.dtype)
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
     # (B, C, N, D) -> (B, K, G*C, D): head-major rows grouped by KV head so
@@ -264,29 +299,31 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         # index; a (K, G*C) operand is trivially small)
         alibi_arr = jnp.broadcast_to(
             alibi.astype(jnp.float32).reshape(K, G)[:, :, None],
-            (K, G, C)).reshape(K, GC)
+            (K, G, C)).reshape(K // HP, HP, GC)
     else:
-        alibi_arr = jnp.zeros((K, GC), jnp.float32)
+        alibi_arr = jnp.zeros((K // HP, HP, GC), jnp.float32)
 
-    def _page(b, kh, j, bt_ref, start_ref):
+    def _page(b, kb, j, bt_ref, start_ref):
         npages = jnp.maximum((start_ref[b] + C + BS - 1) // BS, 1)
-        return (bt_ref[b, jnp.minimum(j, npages - 1)], 0, kh, 0)
+        return (bt_ref[b, jnp.minimum(j, npages - 1)], 0, kb)
+
+    def _heads(b, kb, j, bt, st):
+        return (b, kb, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, K, MAXB),
+        grid=(B, K // HP, MAXB),
         in_specs=[
-            pl.BlockSpec((1, 1, GC, D), lambda b, kh, j, bt, st: (b, kh, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D), _page),
-            pl.BlockSpec((1, BS, 1, D), _page),
-            pl.BlockSpec((1, GC), lambda b, kh, j, bt, st: (kh, 0)),
+            pl.BlockSpec((1, HP, GC, D), _heads),
+            pl.BlockSpec((1, BS, HP * D), _page),
+            pl.BlockSpec((1, BS, HP * D), _page),
+            pl.BlockSpec((1, HP, GC), lambda b, kb, j, bt, st: (kb, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, GC, D),
-                               lambda b, kh, j, bt, st: (b, kh, 0, 0)),
+        out_specs=pl.BlockSpec((1, HP, GC, D), _heads),
         scratch_shapes=[
-            pltpu.VMEM((GC, D), jnp.float32),
-            pltpu.VMEM((GC, LANES), jnp.float32),
-            pltpu.VMEM((GC, LANES), jnp.float32),
+            pltpu.VMEM((HP, GC, D), jnp.float32),
+            pltpu.VMEM((HP, GC, LANES), jnp.float32),
+            pltpu.VMEM((HP, GC, LANES), jnp.float32),
         ],
     )
     kernel = functools.partial(_prefill_kernel, scale=scale, bs=BS, C=C,
@@ -295,7 +332,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, GC, D), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
@@ -317,10 +354,11 @@ def reference_paged_attention(q: jax.Array, k_pool: jax.Array,
     """GQA-native jnp paged attention — parity oracle for both kernels and
     the CPU serving fallback. q (B, S, N, D); positions (B, S) absolute
     query positions (decode: the row's length-1; negative ⇒ row inactive,
-    output zeros); pools (NUM_BLOCKS, BLOCK, K, D); mask is causality over
+    output zeros); pools (NUM_BLOCKS, BLOCK, K*D); mask is causality over
     true positions (left-aligned layout: gathered column == position)."""
     B, S, N, D = q.shape
-    BS, K = k_pool.shape[1], k_pool.shape[2]
+    K = _kv_heads(k_pool, N, D)
+    BS = k_pool.shape[1]
     MAXB = block_table.shape[1]
     T = MAXB * BS
     G = N // K

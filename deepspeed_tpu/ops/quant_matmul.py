@@ -87,7 +87,7 @@ def int8_matmul(x: jax.Array, q8: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((Mp, bn), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, q8, scale)
@@ -176,7 +176,7 @@ def int8_a8_matmul(x: jax.Array, q8: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((Mp, bn), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xq, sx, q8, scale)
@@ -260,7 +260,7 @@ def int4_a8_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((Mp, bn), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xq, xq, sx, q4, scale)
@@ -403,7 +403,7 @@ def int4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((Mp, bn), lambda n, k: (0, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, x, q4, scale)

@@ -111,7 +111,7 @@ def fused_group_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, HW, C), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, LANES), jnp.float32),
                         pltpu.VMEM((1, LANES), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(x, jnp.asarray(onehot), scale.reshape(1, C), bias.reshape(1, C))

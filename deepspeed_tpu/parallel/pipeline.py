@@ -28,7 +28,7 @@ from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
-from ..utils.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax import lax

@@ -30,7 +30,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from ..utils.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P

@@ -27,7 +27,7 @@ import time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
 import jax
-from ..utils.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -73,8 +73,7 @@ class TrainEngine:
         if config.compile_cache.enabled:
             from ..utils.compile_cache import enable_compile_cache
 
-            enable_compile_cache(config.compile_cache.dir,
-                                 config.compile_cache.min_compile_time_secs)
+            enable_compile_cache(config.compile_cache.min_compile_time_secs)
         # observability session first: model transforms (pipelinize), mesh
         # build and step compiles below all publish through it; the disabled
         # default is a shared no-op so tier-1 cost is zero
@@ -1115,10 +1114,7 @@ class TrainEngine:
         """Route one globalized batch through whichever step executor this
         engine built (offload / NVMe / 1-bit / plain jit) — the body
         ``train_batch`` wraps in its span. Returns (loss, StepStats)."""
-        from ..utils.compat import pipeline_partitioner
-
-        with self._obs.span("train_batch/dispatch"), \
-                pipeline_partitioner(self.model.pipelined):
+        with self._obs.span("train_batch/dispatch"):
             if self._param_offload is not None:
                 # host-driven segmented step: params stream through HBM per
                 # layer block (runtime/param_offload.py)
@@ -1359,11 +1355,8 @@ class TrainEngine:
         self._ensure_eval_step()
         if built:
             self._register_eval_audit(batch)
-        from ..utils.compat import pipeline_partitioner
-
         with mesh_mod.ambient(self.mesh):
-            with self._obs.span("eval", step=self.global_steps), \
-                    pipeline_partitioner(self.model.pipelined):
+            with self._obs.span("eval", step=self.global_steps):
                 return self._eval_step(self.params, batch)
 
     def _ensure_eval_step(self) -> None:
@@ -1691,10 +1684,7 @@ class TrainEngine:
         autotuning cost model. Pure host arithmetic — never a device sync."""
         from ..autotuning.cost_model import peak_flops_for
 
-        try:
-            kind = jax.devices()[0].device_kind
-        except Exception:
-            kind = None
+        peak = peak_flops_for(jax.devices()[0].device_kind)
         gas = self.gradient_accumulation_steps()
         micro = self.train_micro_batch_size_per_gpu()
         cfg = self.model.config
@@ -1718,7 +1708,7 @@ class TrainEngine:
             self._obs.goodput.set_workload(
                 tokens_per_step=tokens_per_step,
                 flops_per_step=flops_per_step,
-                peak_flops=peak_flops_for(kind), source=source)
+                peak_flops=peak, source=source)
         except Exception:  # telemetry must never take the engine down
             logger.warning("goodput workload wiring failed", exc_info=True)
 

@@ -12,7 +12,7 @@ shared-mesh implementation — two jitted programs over the existing paged
 arena abstraction:
 
 * ``serving/kv_export`` gathers the request's blocks out of the source
-  arena into a dense ``(L, MAXB, BLOCK, K, D)`` transfer buffer (source
+  arena into a dense ``(L, MAXB, BLOCK, K*D)`` transfer buffer (source
   arena NOT donated — its other requests keep decoding from it);
 * ``serving/kv_import`` scatters the buffer into freshly allocated blocks
   of the (donated) destination arena.
@@ -214,7 +214,7 @@ def register_handoff_audit_entries(engine, handoff: ArenaHandoff
         def _shapes(eng):
             arena = eng._arena_sds()
             buf = jax.ShapeDtypeStruct(
-                (cfg.num_layers, maxb, bs, cfg.num_kv_heads, cfg.head_dim),
+                (cfg.num_layers, maxb, bs, cfg.num_kv_heads * cfg.head_dim),
                 eng._dtype)
             ids = jax.ShapeDtypeStruct((maxb,), jnp.int32)
             return arena, buf, ids

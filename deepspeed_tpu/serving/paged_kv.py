@@ -560,7 +560,7 @@ def build_score_program(cfg, paged_impl: str = "auto"):
 def build_kv_export_program():
     """Jitted KV-handoff export: gather one request's resident blocks out of
     the (NOT donated — other requests keep reading it) source arena into a
-    dense ``(L, MAXB, BLOCK, K, D)`` transfer buffer, one program for any
+    dense ``(L, MAXB, BLOCK, K*D)`` transfer buffer, one program for any
     block count. ``ids`` is the request's block list padded to MAXB with the
     scratch block 0 — pad lanes carry scratch garbage the import writes
     straight back into the destination's scratch block, so residency is

@@ -6,9 +6,18 @@ TORCH_EXTENSIONS_DIR); here the expensive artifact is the compiled XLA
 executable, and jax's persistent compilation cache plays the same role.
 Applied from both engines at construction so every step program — most
 importantly the >10B param-offload segment programs, whose first compile
-can take minutes — compiles once per (program, shape, flags) and loads in
-milliseconds afterwards (measured on the attached v5e: 2.1 s compile →
-0.02 s cached load across processes).
+can take minutes — compiles once per (program, shape, flags) and loads from
+disk afterwards.
+
+Where the cache lives is decided outside the program or not at all:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads it itself; this module sets
+  no directory.
+* unset — ``CHECKOUT_CACHE_DIR``, one fixed path beside the package (the
+  path is part of the cache key, so a directory that moves never hits).
+
+To switch the cache off use JAX's own ``jax_enable_compilation_cache``
+(``JAX_ENABLE_COMPILATION_CACHE=0``).
 """
 
 from __future__ import annotations
@@ -20,38 +29,25 @@ import jax
 
 from .logging import logger
 
-_APPLIED: Optional[str] = None
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def default_cache_dir() -> str:
-    env = os.environ.get("DSTPU_COMPILE_CACHE")
-    if env:
-        return env
-    # per-backend dirs: a process attached to a remote TPU also AOT-compiles
-    # XLA:CPU host executables against the REMOTE host's CPU features (AMX
-    # etc.) — sharing those entries with local CPU runs risks SIGILL
-    return os.path.join(os.path.expanduser("~"), ".cache", "deepspeed_tpu",
-                        f"xla-{jax.default_backend()}")
-
-
-def enable_compile_cache(cache_dir: str = "",
-                         min_compile_time_secs: float = 1.0) -> Optional[str]:
-    """Point jax at a persistent compilation cache directory (idempotent;
-    first caller wins — the cache dir is process-global in jax). Returns
-    the active dir, or None when disabled via DSTPU_COMPILE_CACHE=0."""
-    global _APPLIED
-    env = os.environ.get("DSTPU_COMPILE_CACHE")
-    if env == "0":
+def enable_compile_cache(min_compile_time_secs: float = 1.0) -> Optional[str]:
+    """Make sure jax has a persistent compilation cache directory
+    (idempotent). Returns the active dir, or None when the cache is
+    switched off (``jax_enable_compilation_cache``)."""
+    if not jax.config.jax_enable_compilation_cache:
         return None
-    # env var wins over the configured dir (documented contract in
-    # inference/engine.py and config.py)
-    path = env or cache_dir or default_cache_dir()
-    if _APPLIED is not None:
-        return _APPLIED
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        path = jax.config.jax_compilation_cache_dir
+    else:
+        path = CHECKOUT_CACHE_DIR
+        if jax.config.jax_compilation_cache_dir != path:
+            os.makedirs(path, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", path)
+            logger.info(f"persistent XLA compile cache: {path}")
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_time_secs))
-    _APPLIED = path
-    logger.info(f"persistent XLA compile cache: {path}")
     return path

@@ -5,7 +5,8 @@ VERDICT r3 weak #5/#7: README's numbers must cite driver-auditable files,
 not builder prose. Writes bench_results/r{N}/<name>.json with the bench's
 own JSON line plus run metadata; validation scripts get their stdout
 captured verbatim. Skips (with a recorded reason) anything that needs a
-real accelerator when only CPU is present.
+real accelerator when only CPU is present. This parent stays off JAX — one
+process holds the chip at a time, and each child here needs it.
 
 Usage: python scripts/run_bench_suite.py r04 [filter-substring]
 """
@@ -76,9 +77,15 @@ def main() -> None:
     outdir = os.path.join(REPO, "bench_results", tag)
     os.makedirs(outdir, exist_ok=True)
 
-    import jax
-
-    on_accel = jax.default_backend() != "cpu"
+    # the parent never initialises a JAX backend: a chip belongs to one
+    # process at a time, and every child below needs it. A throwaway child
+    # asks, and has exited (released the chip) before the first bench starts
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        cwd=REPO, capture_output=True, text=True, timeout=300)
+    if probe.returncode != 0:
+        sys.exit(f"backend probe failed:\n{probe.stderr[-2000:]}")
+    on_accel = probe.stdout.strip().splitlines()[-1] != "cpu"
     for name, cmd, env in SUITE:
         if filt and filt not in name:
             continue

@@ -25,9 +25,10 @@ INTERPRET = True
 
 
 def _pool(nb=9, bs=16, k=2, d=32, seed=0, dtype=jnp.float32):
+    """k/v pools in the arena layout: (NUM_BLOCKS, BLOCK, K*D)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 2)
-    return (jax.random.normal(ks[0], (nb, bs, k, d), dtype),
-            jax.random.normal(ks[1], (nb, bs, k, d), dtype))
+    return (jax.random.normal(ks[0], (nb, bs, k * d), dtype),
+            jax.random.normal(ks[1], (nb, bs, k * d), dtype))
 
 
 def _ragged_tables(bs=16, maxb=4):
@@ -41,10 +42,10 @@ def _ragged_tables(bs=16, maxb=4):
     return jnp.asarray(bt), jnp.asarray(lengths)
 
 
-def _dense_view(pool, bt):
-    nb, bs, k, d = pool.shape
+def _dense_view(pool, bt, d=32):
+    nb, bs, kd = pool.shape
     b, maxb = bt.shape
-    return pool[bt].reshape(b, maxb * bs, k, d)
+    return pool[bt].reshape(b, maxb * bs, kd // d, d)
 
 
 class TestPagedDecodeKernel:

@@ -396,7 +396,7 @@ class TestKVHandoffPrograms:
         dst = ServingEngine(tiny_engine, ServingConfig(**SCFG))
         try:
             rng = np.random.RandomState(0)
-            shape = src._arena["k"].shape        # (L, 1+N, BS, K, D)
+            shape = src._arena["k"].shape        # (L, 1+N, BS, K*D)
             src._arena = {
                 "k": jnp.asarray(rng.randn(*shape).astype(np.float32)),
                 "v": jnp.asarray(rng.randn(*shape).astype(np.float32))}

@@ -433,6 +433,8 @@ class TestGoodput:
         assert peak_flops_for("TPU v5p chip") == PEAK_FLOPS["v5p"]
         assert peak_flops_for(None) == 197e12
         assert peak_flops_for("cpu") == 197e12     # unknown kind => default
+        with pytest.raises(ValueError, match="TPU v9x"):
+            peak_flops_for("TPU v9x")     # an unlisted TPU never defaults
 
     def test_session_routes_compile_and_publish_into_recorder(self, tmp_path):
         sess = configure_observability(ObservabilityConfig(

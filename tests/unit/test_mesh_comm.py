@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from deepspeed_tpu import comm
 from deepspeed_tpu.config.config import ParallelConfig

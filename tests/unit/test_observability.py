@@ -30,7 +30,7 @@ from deepspeed_tpu.observability.metrics import MetricsRegistry
 from deepspeed_tpu.observability.recompile import install as install_watchdog
 from deepspeed_tpu.observability.report import report as render_report
 from deepspeed_tpu.observability.spans import SpanTracer
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 @pytest.fixture(autouse=True)

@@ -4,7 +4,7 @@ compressed-stage convergence) plus primitive-level checks of the
 error-feedback collective."""
 
 import jax
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

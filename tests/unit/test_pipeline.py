@@ -220,11 +220,9 @@ class TestPipelineMemory:
         pmodel = pipelinize_model(model, 4)
         params = pmodel.init(jax.random.PRNGKey(0))
 
-        from deepspeed_tpu.utils.compat import pipeline_partitioner
-
         def temp_bytes(M):
             ids = jnp.zeros((M, 4, 64), jnp.int32)
-            with mesh, pipeline_partitioner():
+            with mesh:
                 lowered = jax.jit(pmodel.grad_fn).lower(
                     params, {"input_ids": ids}, jnp.float32(1.0))
                 return lowered.compile().memory_analysis().temp_size_in_bytes
@@ -259,9 +257,7 @@ class TestPipelineMoE:
         params = pmodel.init(jax.random.PRNGKey(0))
         ids = jax.random.randint(jax.random.PRNGKey(1), (2, 4, 32), 0, 250)
         batch = {"input_ids": ids}
-        from deepspeed_tpu.utils.compat import pipeline_partitioner
-
-        with mesh, pipeline_partitioner():
+        with mesh:
             train_loss, grads = jax.jit(pmodel.grad_fn)(
                 params, batch, jnp.float32(1.0))
             eval_loss = jax.jit(pmodel.loss_fn)(params, batch)

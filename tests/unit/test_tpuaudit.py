@@ -84,7 +84,7 @@ class TestUnexpectedCollective:
     def test_explicit_shard_map_collective_without_compile(self):
         """shard_map collectives appear in the lowered StableHLO, so the
         census works even with compile=False."""
-        from deepspeed_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         mesh = mesh2x4()
         body = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
@@ -251,7 +251,7 @@ class TestCollectiveCensus:
     def test_explicit_collective_not_double_counted(self):
         """An explicit shard_map collective appears in BOTH the lowered and
         the compiled text; the census must report it once, not twice."""
-        from deepspeed_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         mesh = mesh2x4()
         body = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,

@@ -247,10 +247,22 @@ class TestDeprecatedJaxApi:
         src = "import jax.experimental.maps\n"
         assert "deprecated-jax-api" in rules_of(src)
 
+    def test_positive_experimental_shard_map_import(self):
+        src = "from jax.experimental.shard_map import shard_map\n"
+        assert "deprecated-jax-api" in rules_of(src)
+
+    def test_positive_tpu_compiler_params(self):
+        src = ("from jax.experimental.pallas import tpu as pltpu\n"
+               "p = pltpu.TPUCompilerParams(dimension_semantics=())\n")
+        assert "deprecated-jax-api" in rules_of(src)
+
     def test_negative_modern_apis(self):
         src = ("import jax\n"
+               "from jax import shard_map\n"
+               "from jax.experimental.pallas import tpu as pltpu\n"
                "out = jax.tree.map(lambda v: v, {})\n"
-               "out2 = jax.tree_util.tree_map(lambda v: v, {})\n")
+               "out2 = jax.tree_util.tree_map(lambda v: v, {})\n"
+               "p = pltpu.CompilerParams(dimension_semantics=())\n")
         assert rules_of(src) == []
 
 

@@ -142,7 +142,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     # (alias_size_in_bytes=0), which flips peak_hbm_bytes run-to-run for
     # programs near the cache's min-compile-time threshold. Host compiles of
     # the selftest programs are ~1 s each — determinism is worth more here.
-    os.environ["DSTPU_COMPILE_CACHE"] = "0"
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"   # read at jax import
 
     from ..tpuaudit.cli import _setup_platform
 

@@ -293,12 +293,17 @@ class DeprecatedJaxApi(Rule):
     name = "deprecated-jax-api"
     description = "JAX API that is deprecated/removed in current releases"
 
-    _PREFIXES = ("jax.experimental.pjit", "jax.experimental.maps")
+    _PREFIXES = ("jax.experimental.pjit", "jax.experimental.maps",
+                 "jax.experimental.shard_map")
     _EXACT = {
         "jax.tree_map": "use jax.tree.map (or jax.tree_util.tree_map)",
         "jax.tree_multimap": "use jax.tree.map",
         "jax.experimental.pjit": "jit handles shardings; use jax.jit",
         "jax.experimental.maps": "removed; use jax.shard_map / jax.jit",
+        "jax.experimental.shard_map":
+            "use jax.shard_map (check_vma / axis_names)",
+        "jax.experimental.pallas.tpu.TPUCompilerParams":
+            "removed; use pltpu.CompilerParams",
     }
 
     def _advice(self, dotted: str) -> str:

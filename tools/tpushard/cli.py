@@ -118,7 +118,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     # determinism (same contract as tpucost): executables deserialized from
     # the persistent compile cache lose analysis-relevant attributes
-    os.environ["DSTPU_COMPILE_CACHE"] = "0"
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"   # read at jax import
 
     from ..tpuaudit.cli import _setup_platform
 
