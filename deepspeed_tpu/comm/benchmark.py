@@ -22,8 +22,8 @@ import time
 from typing import Any, Dict, List, Optional
 
 import jax
-from jax import shard_map
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel import mesh as mesh_mod

@@ -41,18 +41,17 @@ LANES = 128
 VMEM_KV_BUDGET = 8 << 20
 
 
-def kv_vmem_bytes_per_token(kv_heads: int, head_dim: int, dtype) -> int:
-    """VMEM bytes one cache token costs as double-buffered k+v blocks of
-    ``(tokens, K, D)``. The block's last two dims are laid out in
-    (sublane, 128-lane) tiles — 8 sublanes of 32-bit words, so 16 rows of
-    bf16, 32 of int8 — and a short dim is padded up to its tile: at
-    head_dim 64 a block takes twice its nominal bytes, which is what put
-    the B>=8 decode of a 32x64 model 100 KB over the scoped limit."""
+def tiled_vmem_bytes(rows: int, lanes: int, dtype) -> int:
+    """Bytes a ``(rows, lanes)`` slab — the last two dims of a block — takes
+    in VMEM. It is laid out in (sublane, 128-lane) tiles — 8 sublanes of
+    32-bit words, so 16 rows of bf16, 32 of int8 — and a short dim is padded
+    up to its tile: a ``(K, 64)`` slab takes twice its nominal bytes, which
+    is what put the B>=8 decode of a 32x64 model 100 KB over the scoped
+    limit."""
     itemsize = jnp.dtype(dtype).itemsize
     sublanes = 8 * max(4 // itemsize, 1)
-    k_pad = -(-kv_heads // sublanes) * sublanes
-    d_pad = -(-head_dim // LANES) * LANES
-    return k_pad * d_pad * itemsize * 4
+    return (-(-rows // sublanes) * sublanes * -(-lanes // LANES) * LANES
+            * itemsize)
 
 
 def _kernel(q_ref, k_ref, v_ref, valid_ref, alibi_ref, kpos_ref, o_ref,
@@ -139,7 +138,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     T, K = k_cache.shape[1], k_cache.shape[2]
     # the double-buffered k/v blocks must fit scoped VMEM (see
     # VMEM_KV_BUDGET above)
-    per_t = kv_vmem_bytes_per_token(K, D, k_cache.dtype)
+    per_t = 4 * tiled_vmem_bytes(K, D, k_cache.dtype)   # k+v, 2 buffers
     budget = VMEM_KV_BUDGET
     # bt is a middle block dim so sub-128 values are legal (the last-two-dims
     # tiling rule applies to (K, D), taken whole); grid = ceil(T/bt), the
@@ -191,6 +190,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attention",
         interpret=interpret,
     )(q, k_cache, v_cache, valid3, alibi_arr, kpos3)
     return out

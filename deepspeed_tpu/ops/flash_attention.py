@@ -154,6 +154,7 @@ def _fwd(q: jax.Array, k: jax.Array, v: jax.Array, kvm: jax.Array,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v, kvm, slopes)
     return o, lse[..., 0]
@@ -294,6 +295,7 @@ def _bwd(causal: bool, scale: float, kv_len: int, has_mask: bool,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse_pad, delta, kvm, slopes)[0]
 
@@ -324,6 +326,7 @@ def _bwd(causal: bool, scale: float, kv_len: int, has_mask: bool,
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse_pad, delta, kvm, slopes)
     return dq, dk, dv, jnp.zeros_like(kvm), jnp.zeros_like(slopes)

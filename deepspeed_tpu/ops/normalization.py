@@ -62,6 +62,7 @@ def _ln_forward(x: jax.Array, scale: jax.Array, bias: Optional[jax.Array],
         in_specs=in_specs,
         out_specs=pl.BlockSpec((ROW_BLOCK, H), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, H), x.dtype),
+        name="fused_layer_norm",
         interpret=interpret,
     )(*args)
     if pad:

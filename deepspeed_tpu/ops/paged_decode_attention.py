@@ -54,16 +54,13 @@ LANES = 128
 # k + v pages, double-buffered by the pipeline — ONE budget shared with
 # the dense decode kernel's tile sizing
 from .decode_attention import VMEM_KV_BUDGET as _VMEM_PAGE_BUDGET
+from .decode_attention import tiled_vmem_bytes
 
 
 def _check_page_fits(block_size: int, width: int, dtype) -> None:
     """k + v ``(block_size, width)`` pages, double-buffered, as VMEM holds
-    them: rows pad to the dtype's sublane tile, lanes to 128."""
-    itemsize = jnp.dtype(dtype).itemsize
-    sublanes = 8 * max(4 // itemsize, 1)
-    rows = -(-block_size // sublanes) * sublanes
-    lanes = -(-width // LANES) * LANES
-    per_page = rows * lanes * itemsize * 4
+    them."""
+    per_page = 4 * tiled_vmem_bytes(block_size, width, dtype)
     if per_page > _VMEM_PAGE_BUDGET:
         raise ValueError(
             f"paged attention KV pages do not fit VMEM: block_size "
@@ -197,6 +194,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct((R, N, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="paged_decode_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q, k_pool, v_pool, alibi_arr)
@@ -334,6 +332,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, K, GC, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="paged_prefill_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
       qk, k_pool, v_pool, alibi_arr)

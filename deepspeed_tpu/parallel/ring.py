@@ -30,9 +30,8 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax import shard_map
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from .mesh import SEQ_AXIS, get_mesh

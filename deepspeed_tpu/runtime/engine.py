@@ -27,9 +27,9 @@ import time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
 import jax
-from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..comm.comms_logging import configure_comms_logger
@@ -1024,7 +1024,7 @@ class TrainEngine:
             self._register_step_audit(batch)
 
         # Steady-state path is SYNC-FREE: no host<->device scalar fetches per
-        # step (each one drains the TPU queue — ruinous over remote tunnels).
+        # step (each one drains the TPU queue).
         # Device-side counters accumulate lazily; materialised only at
         # steps_per_print boundaries (reference logs at the same cadence).
         breakdown = self.wall_clock_breakdown()

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Run every bench + chip-validation script and commit raw JSON artifacts.
 
-VERDICT r3 weak #5/#7: README's numbers must cite driver-auditable files,
-not builder prose. Writes bench_results/r{N}/<name>.json with the bench's
+README's numbers must cite driver-auditable files, not builder prose.
+Writes bench_results/r{N}/<name>.json with the bench's
 own JSON line plus run metadata; validation scripts get their stdout
 captured verbatim. Skips (with a recorded reason) anything that needs a
 real accelerator when only CPU is present. This parent stays off JAX — one
@@ -26,14 +26,14 @@ SUITE = [
      {"BENCH_INFER_DTYPE": "int8"}),
     ("bench_infer_int4", ["python", "bench_infer.py"],
      {"BENCH_INFER_DTYPE": "int4"}),
-    # W8A8: s8xs8 MXU decode (VERDICT r4 #3 — the weight-only kernel is
-    # VPU-convert-bound; this removes the convert entirely)
+    # W8A8: s8xs8 MXU decode (the weight-only kernel is VPU-convert-bound;
+    # this removes the convert entirely)
     ("bench_infer_w8a8", ["python", "bench_infer.py"],
      {"BENCH_INFER_DTYPE": "w8a8"}),
     ("bench_infer_w4a8", ["python", "bench_infer.py"],
      {"BENCH_INFER_DTYPE": "w4a8"}),
-    # MoE expert-parallel inference (VERDICT r4 #2) + BLOOM-7B kernel-
-    # injected inference as tracked config #5 names it (VERDICT r4 #6)
+    # MoE expert-parallel inference + BLOOM-7B kernel-injected inference as
+    # tracked config #5 names it
     ("bench_infer_moe8e", ["python", "bench_infer.py"],
      {"BENCH_INFER_MODEL": "moe-gpt-125m-8e"}),
     ("bench_infer_bloom7b", ["python", "bench_infer.py"],
@@ -60,9 +60,9 @@ SUITE = [
      {"BENCH_ZERO_PARAM_OFFLOAD": "cpu", "BENCH_ZERO_MODEL": "llama-13b",
       "BENCH_ZERO_LAYERS": "30", "BENCH_WARMUP": "1", "BENCH_STEPS": "1"}),
     ("bench_rlhf", ["python", "bench_rlhf.py"], {}),
-    ("validate_kernels", ["python", "scripts/validate_kernels_tpu.py"], {}),
+    # (kernel parity on the chip is chip_smoke.py's kernels phase)
     ("validate_offload", ["python", "scripts/validate_offload_tpu.py"], {}),
-    # VERDICT r4 #5: fetch-vs-compute overlap + h2d utilization evidence
+    # fetch-vs-compute overlap + h2d utilization evidence
     ("validate_offload_overlap",
      ["python", "scripts/validate_offload_overlap.py"], {}),
     ("validate_offload_overlap_1.3b",

@@ -102,13 +102,16 @@ class TestPagedDecodeKernel:
 
 
 class TestPagedPrefillKernel:
-    @pytest.mark.parametrize("n,k", [(4, 4), (8, 2)])
-    def test_chunk_matches_reference(self, n, k):
-        kp, vp = _pool(k=k)
+    # heads per grid step: 4 (4x32 lanes), the whole page (2x32), 2 (64-wide
+    # heads pair up), 1 (128-wide heads)
+    @pytest.mark.parametrize("n,k,d", [(4, 4, 32), (8, 2, 32), (4, 4, 64),
+                                       (4, 2, 128)])
+    def test_chunk_matches_reference(self, n, k, d):
+        kp, vp = _pool(k=k, d=d)
         bt = jnp.asarray(np.array([[5, 1, 7, 0], [3, 8, 0, 0]], np.int32))
         start = jnp.asarray(np.array([21, 0], np.int32))
         C = 16
-        q = jax.random.normal(jax.random.PRNGKey(7), (2, C, n, 32))
+        q = jax.random.normal(jax.random.PRNGKey(7), (2, C, n, d))
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         out = paged_prefill_attention(q, kp, vp, bt, start,
                                       interpret=INTERPRET)

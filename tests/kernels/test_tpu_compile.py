@@ -30,6 +30,9 @@ def v5e():
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # libtpu lets one process at a time load it unless told otherwise; with
+    # no chip attached several pytest workers can describe one side by side
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")

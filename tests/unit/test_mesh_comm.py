@@ -5,8 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
 from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu import comm
 from deepspeed_tpu.config.config import ParallelConfig

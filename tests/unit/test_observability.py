@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu
@@ -30,7 +31,6 @@ from deepspeed_tpu.observability.metrics import MetricsRegistry
 from deepspeed_tpu.observability.recompile import install as install_watchdog
 from deepspeed_tpu.observability.report import report as render_report
 from deepspeed_tpu.observability.spans import SpanTracer
-from jax import shard_map
 
 
 @pytest.fixture(autouse=True)

@@ -4,10 +4,10 @@ compressed-stage convergence) plus primitive-level checks of the
 error-feedback collective."""
 
 import jax
-from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import deepspeed_tpu

@@ -457,7 +457,7 @@ class TestBenchIntegration:
         import bench_common
 
         with pytest.raises(SystemExit) as e:
-            bench_common.skip("m", "tok/s", "tunnel", "backend-init",
+            bench_common.skip("m", "tok/s", "outage", "backend-init",
                               predicted_mfu=0.42)
         assert e.value.code == 0
         rec = json.loads(capsys.readouterr().out)

@@ -178,7 +178,8 @@ class HostCallback(Check):
         counts: Counter = Counter()
         for eqn in program.iter_eqns():
             name = eqn.primitive.name
-            if "callback" in name:
+            # jax.debug.print is its own primitive (it was a debug_callback)
+            if "callback" in name or name == "debug_print":
                 counts[name] += 1
         for prim, n in sorted(counts.items()):
             yield self._f(
@@ -254,10 +255,13 @@ class BakedConstant(Check):
         threshold = int(options.get("max_const_bytes",
                                     DEFAULT_MAX_CONST_BYTES))
         for const in program.closed_jaxpr.consts:
-            nbytes = int(getattr(const, "nbytes", 0) or 0)
+            shape = tuple(getattr(const, "shape", ()))
+            dtype = getattr(const, "dtype", None)
+            # closure constants arrive as jax's TypedNdArray, which has a
+            # shape and a dtype but no nbytes
+            nbytes = (int(np.prod(shape)) * np.dtype(dtype).itemsize
+                      if dtype is not None else 0)
             if nbytes > threshold:
-                shape = tuple(getattr(const, "shape", ()))
-                dtype = getattr(const, "dtype", "?")
                 yield self._f(
                     program,
                     f"constant {shape} {dtype} ({_mib(nbytes)}) baked into "

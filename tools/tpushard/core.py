@@ -58,12 +58,19 @@ REPLICATION_WASTE_MIN_BYTES = 1 << 20   # 1 MiB: below this, replication is
 # whitespace leaves exactly the computation + layout — the thing the
 # rule-registry migration must preserve bit-for-bit.
 _METADATA_RE = re.compile(r",?\s*metadata=\{[^}]*\}")
+# the module header's source tables (FileNames / FunctionNames /
+# FileLocations / StackFrames: numbered lines up to the first blank one)
+# carry the same positions the per-op metadata points into
+_SOURCE_TABLES_RE = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(\d+ .*\n)*",
+    re.MULTILINE)
 _WS_RE = re.compile(r"\s+")
 
 
 def canonical_hash(hlo_text: str) -> str:
     """Position-independent hash of a compiled-HLO text (16 hex chars)."""
-    text = _METADATA_RE.sub("", hlo_text)
+    text = _SOURCE_TABLES_RE.sub("", hlo_text)
+    text = _METADATA_RE.sub("", text)
     text = _WS_RE.sub(" ", text).strip()
     return hashlib.sha256(text.encode("utf-8")).hexdigest()[:16]
 
