@@ -1,0 +1,200 @@
+"""BENCHMARK.json and the data files its names point to.
+
+A cell names a configuration and a traffic mix; `configs/<config>.json`,
+`traffic/<traffic>.json` and `layer_metrics/<metric>.json` are found by those
+names and nowhere else, so a later PR adds a cell by adding files and
+entries. `validate` holds the file to the parts of the contract that a run
+on the CPU can check.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+import re
+from typing import Any, Dict, List
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO_ROOT = os.path.dirname(BENCH_DIR)
+
+NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+TRAFFIC_KINDS = ("train", "closed_loop", "open_loop")
+# widths a configuration may never cut (the contract's rule for `reduced`)
+WIDTH_WORDS = ("hidden_size", "intermediate", "ffn", "latent", "state_size",
+               "head_dim", "head_size", "expansion", "experts_per_tok",
+               "proj")
+
+
+class SpecError(ValueError):
+    """BENCHMARK.json or a file it names breaks the contract."""
+
+
+def _load(path: str) -> Any:
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except FileNotFoundError:
+        raise SpecError(f"no file {path}") from None
+    except json.JSONDecodeError as e:
+        raise SpecError(f"{path}: not JSON ({e})") from None
+
+
+@dataclasses.dataclass
+class Cell:
+    """One entry of `workloads` with everything its names resolve to."""
+
+    name: str
+    chips: int
+    config_name: str
+    traffic_name: str
+    config: Dict[str, Any]          # configs/<config>.json
+    traffic: Dict[str, Any]         # traffic/<traffic>.json
+    end_to_end: List[Dict[str, Any]]    # BENCHMARK.json entries reported here
+    per_layer: List[Dict[str, Any]]     # each with its layer_metrics file
+    #   merged in under "reader": {"reducer": ..., "args": {...}}
+
+
+class Spec:
+    """BENCHMARK.json under `root`, with the benchmark's files under the
+    first of its `paths`."""
+
+    def __init__(self, root: str = REPO_ROOT):
+        self.root = root
+        self.doc = _load(os.path.join(root, "BENCHMARK.json"))
+        self.bench_dir = os.path.join(root, self.doc["paths"][0])
+
+    def path(self, *parts: str) -> str:
+        return os.path.join(self.bench_dir, *parts)
+
+    @staticmethod
+    def _in_cell(metric: Dict[str, Any], cell: str) -> bool:
+        return "workloads" not in metric or cell in metric["workloads"]
+
+    def reader(self, metric: str) -> Dict[str, Any]:
+        return _load(self.path("layer_metrics", f"{metric}.json"))
+
+    def cell(self, name: str) -> Cell:
+        for w in self.doc["workloads"]:
+            if w["name"] == name:
+                break
+        else:
+            known = ", ".join(x["name"] for x in self.doc["workloads"])
+            raise SpecError(f"no workload '{name}' (known: {known})")
+        cfg_entry = next((c for c in self.doc["configs"]
+                          if c["name"] == w["config"]), None)
+        if cfg_entry is None:
+            raise SpecError(f"workload {name}: no config '{w['config']}'")
+        per_layer = []
+        for m in self.doc["per_layer"]:
+            if self._in_cell(m, name):
+                per_layer.append(dict(m, reader=self.reader(m["name"])))
+        return Cell(
+            name=name, chips=int(w["chips"]), config_name=w["config"],
+            traffic_name=w["traffic"],
+            config=_load(os.path.join(self.root, cfg_entry["file"])),
+            traffic=_load(self.path("traffic", f"{w['traffic']}.json")),
+            end_to_end=[m for m in self.doc["end_to_end"]
+                        if self._in_cell(m, name)],
+            per_layer=per_layer)
+
+    # -- the contract, as far as it can be checked without a chip ---------
+    def validate(self) -> None:
+        d = self.doc
+        want = {"command", "paths", "run_seconds", "configs", "workloads",
+                "end_to_end", "per_layer"}
+        if set(d) != want:
+            raise SpecError(f"BENCHMARK.json keys {sorted(d)} != {sorted(want)}")
+        if not 1 <= int(d["run_seconds"]) <= 51:
+            raise SpecError("run_seconds outside 1..51")
+
+        def names(entries, what):
+            seen = set()
+            for e in entries:
+                if not NAME_RE.match(e["name"]):
+                    raise SpecError(f"{what} name '{e['name']}' not allowed")
+                if e["name"] in seen:
+                    raise SpecError(f"{what} name '{e['name']}' twice")
+                seen.add(e["name"])
+            return seen
+
+        configs = names(d["configs"], "config")
+        cells = names(d["workloads"], "workload")
+        names(d["end_to_end"] + d["per_layer"], "metric")
+        e2e = {m["name"]: m for m in d["end_to_end"]}
+        if "setup_s" not in e2e:
+            raise SpecError("no setup_s among end_to_end")
+        if "workloads" in e2e["setup_s"]:
+            raise SpecError("setup_s must be reported by every cell")
+        for c in d["configs"]:
+            for key in c["reduced"]:
+                if (any(w in key for w in WIDTH_WORDS)
+                        or key.endswith(("_dim", "_rank"))):
+                    raise SpecError(f"config {c['name']}: reduces a width "
+                                    f"('{key}')")
+            if not c["file"].startswith(tuple(p + "/" for p in d["paths"])):
+                raise SpecError(f"config file {c['file']} outside paths")
+        pairs = set()
+        for w in d["workloads"]:
+            if w["config"] not in configs:
+                raise SpecError(f"workload {w['name']}: unknown config")
+            if w["chips"] not in (1, 4):
+                raise SpecError(f"workload {w['name']}: chips must be 1 or 4")
+            for key in ("config", "traffic"):
+                if not NAME_RE.match(w[key]):
+                    raise SpecError(f"workload {w['name']}: bad {key} name")
+            if (w["config"], w["traffic"]) in pairs:
+                raise SpecError(f"pair of {w['name']} appears twice")
+            pairs.add((w["config"], w["traffic"]))
+            if not 1 <= len(w["why"]) <= 200:
+                raise SpecError(f"workload {w['name']}: why is 1..200 chars")
+        four = sum(w["chips"] == 4 for w in d["workloads"])
+        if four > max(1, len(d["workloads"]) // 4):
+            raise SpecError(f"{four} four-chip cells of {len(cells)}")
+        if {c["name"] for c in d["configs"]} - {w["config"]
+                                               for w in d["workloads"]}:
+            raise SpecError("a config is used by no cell")
+        for m in d["end_to_end"] + d["per_layer"]:
+            if not UNIT_RE.match(m["unit"]):
+                raise SpecError(f"metric {m['name']}: unit '{m['unit']}'")
+            if m["better"] not in ("lower", "higher"):
+                raise SpecError(f"metric {m['name']}: better")
+            if m["source"] not in SOURCES:
+                raise SpecError(f"metric {m['name']}: source")
+            for cell in m.get("workloads", ()):
+                if cell not in cells:
+                    raise SpecError(f"metric {m['name']}: unknown cell {cell}")
+        for m in d["end_to_end"]:
+            if m["source"] not in ("host_clock", "device_trace"):
+                raise SpecError(f"end-to-end {m['name']}: source")
+            if not 0 < m["bound"] <= 0.1:
+                raise SpecError(f"end-to-end {m['name']}: bound")
+        for m in d["per_layer"]:
+            if m["moves"] not in e2e:
+                raise SpecError(f"per-layer {m['name']}: moves "
+                                f"'{m['moves']}' is no end-to-end metric")
+            for cell in cells:
+                if (self._in_cell(m, cell)
+                        and not self._in_cell(e2e[m["moves"]], cell)):
+                    raise SpecError(
+                        f"per-layer {m['name']} is reported in {cell}, "
+                        f"where {m['moves']} is not")
+            if m["name"].endswith("_roofline") and m["unit"] != "%":
+                raise SpecError(f"{m['name']}: a roofline share is in %")
+            r = self.reader(m["name"])
+            for key in ("layer", "unit", "moves", "source"):
+                if r.get(key) != m[key]:
+                    raise SpecError(f"layer_metrics/{m['name']}.json: {key} "
+                                    f"differs from BENCHMARK.json")
+            if not os.path.exists(self.path("reducers",
+                                            f"{r['reducer']}.py")):
+                raise SpecError(f"{m['name']}: no reducer '{r['reducer']}'")
+        for cell in cells:
+            c = self.cell(cell)
+            if c.traffic.get("kind") not in TRAFFIC_KINDS:
+                raise SpecError(f"traffic {c.traffic_name}: kind")
+            if len(c.end_to_end) < 2 or not c.per_layer:
+                raise SpecError(f"cell {cell}: needs setup_s, another "
+                                "end-to-end metric and a per-layer metric")

@@ -1,0 +1,9 @@
+"""Ops and bytes of `flash_attention_fwd` over the traced window: calls x
+per call (flash_attention_cost.py)."""
+
+from benchmarks.reducers.flash_attention_cost import per_call
+
+
+def total(ctx, calls: int):
+    ops, nbytes = per_call(ctx, "fwd")
+    return ops * calls, nbytes * calls

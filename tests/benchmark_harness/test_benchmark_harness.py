@@ -1,0 +1,460 @@
+"""The benchmark's harness, held to the contract on the CPU: the data files
+are found by name and validate, the arithmetic of the metrics is right on
+known inputs, the traffic is the same work under every seed, the trace
+reduction gives known numbers on a small recorded trace, and every cell runs
+end to end at tiny size through a test-only steer — where it reports counts
+and refuses to report a time, a rate or a share."""
+
+import copy
+import json
+import math
+import os
+import statistics
+import sys
+import time
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import bench_tiny  # noqa: E402
+from benchmarks import run as bench_run  # noqa: E402
+from benchmarks.harness import (device, layers, spec as spec_mod, stats,  # noqa: E402
+                                traffic, trace as trace_mod)
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SPEC = spec_mod.Spec()
+DOC = SPEC.doc
+CELLS = [w["name"] for w in DOC["workloads"]]
+# every serving mix in the directory, also one that no cell uses yet
+SERVE_TRAFFIC = sorted(
+    f[:-5] for f in os.listdir(SPEC.path("traffic"))
+    if json.load(open(SPEC.path("traffic", f)))["kind"] != "train")
+
+
+# -- every cell's files are found by name and validate ---------------------
+
+def test_benchmark_json_meets_the_contract():
+    SPEC.validate()
+    assert DOC["command"][-1] == "benchmarks/run.py"
+    assert len(json.dumps(DOC)) < 64 * 1024
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_files_are_found_by_name(cell):
+    c = SPEC.cell(cell)
+    assert c.traffic["kind"] in spec_mod.TRAFFIC_KINDS
+    assert c.traffic["why"] and c.traffic["who"]
+    assert c.config["source"].startswith("https://")
+    assert os.path.exists(SPEC.path("references",
+                                    f"{c.config['reference']}.py"))
+    published, model = c.config["published"], c.config["model"]["overrides"]
+    # no width is cut; depth only where `reduced` says so
+    assert model["hidden_size"] == published["hidden_size"]
+    assert model["ffn_hidden_size"] == published["ffn_dim"]
+    assert model["num_heads"] == published["num_attention_heads"]
+    assert model["vocab_size"] == published["vocab_size"]
+    entry = next(x for x in DOC["configs"] if x["name"] == c.config_name)
+    if model["num_layers"] != published["num_hidden_layers"]:
+        assert entry["reduced"] == ["num_hidden_layers"]
+        assert "num_hidden_layers" in c.config["reduced"]
+    else:
+        assert entry["reduced"] == [] and not c.config["reduced"]
+    if c.traffic["kind"] == "train":
+        assert c.chips == 4 or c.traffic["engine"][
+            "zero_optimization"]["stage"] == 0
+    else:
+        s = c.config["serving"]
+        longest = (c.traffic["prompt_tokens"]["max"]
+                   + c.traffic["output_tokens"]["max"])
+        assert longest <= s["max_model_len"]    # no request can fail to fit
+        assert c.traffic["requests"] >= 64
+
+
+ALL_NAMES = sorted(
+    {x["name"] for k in ("configs", "workloads", "end_to_end", "per_layer")
+     for x in DOC[k]}
+    | {w["traffic"] for w in DOC["workloads"]}
+    | {k for c in DOC["configs"] for k in c["reduced"]})
+
+
+@pytest.mark.parametrize("name", ALL_NAMES)
+def test_names_use_only_allowed_characters(name):
+    assert spec_mod.NAME_RE.match(name)
+    assert all(ch.isascii() for ch in name)
+
+
+@pytest.mark.parametrize("metric", DOC["end_to_end"] + DOC["per_layer"],
+                         ids=lambda m: m["name"])
+def test_units_and_sources(metric):
+    assert spec_mod.UNIT_RE.match(metric["unit"])
+    assert metric["source"] in spec_mod.SOURCES
+    assert metric["better"] in ("lower", "higher")
+    allowed = {"name", "unit", "better", "source", "workloads"} | (
+        {"bound"} if metric in DOC["end_to_end"] else {"layer", "moves"})
+    assert set(metric) <= allowed
+
+
+@pytest.mark.parametrize("metric", DOC["per_layer"], ids=lambda m: m["name"])
+def test_moves_is_reported_wherever_the_metric_is(metric):
+    e2e = {m["name"]: m for m in DOC["end_to_end"]}
+    target = e2e[metric["moves"]]
+    for cell in metric.get("workloads", CELLS):
+        assert "workloads" not in target or cell in target["workloads"]
+    reader = SPEC.reader(metric["name"])
+    assert os.path.exists(SPEC.path("reducers", f"{reader['reducer']}.py"))
+    assert hasattr(layers.reducer(reader["reducer"]), "reduce")
+
+
+def _break(doc, how):
+    doc = copy.deepcopy(doc)
+    if how == "bad-name":
+        doc["workloads"][0]["name"] = "has space"
+    elif how == "unknown-moves":
+        doc["per_layer"][0]["moves"] = "nothing"
+    elif how == "moves-not-reported":
+        doc["per_layer"][0]["moves"] = "ttft_p25_ms"
+    elif how == "width-reduced":
+        doc["configs"][0]["reduced"] = ["hidden_size"]
+    elif how == "two-four-chip-cells":
+        for w in doc["workloads"][:2]:
+            w["chips"] = 4
+    elif how == "no-setup":
+        doc["end_to_end"] = [m for m in doc["end_to_end"]
+                             if m["name"] != "setup_s"]
+    elif how == "loose-bound":
+        doc["end_to_end"][0]["bound"] = 0.5
+    elif how == "extra-key":
+        doc["notes"] = "x"
+    return doc
+
+
+@pytest.mark.parametrize("how", ["bad-name", "unknown-moves",
+                                 "moves-not-reported", "width-reduced",
+                                 "two-four-chip-cells", "no-setup",
+                                 "loose-bound", "extra-key"])
+def test_validate_refuses(how, tmp_path):
+    root = bench_tiny.make_root(str(tmp_path))
+    json.dump(_break(DOC, how), open(os.path.join(root, "BENCHMARK.json"),
+                                     "w"))
+    with pytest.raises(spec_mod.SpecError):
+        spec_mod.Spec(root).validate()
+
+
+# -- the arithmetic --------------------------------------------------------
+
+@pytest.mark.parametrize("stalled", [0, 7, 24])
+def test_window_rate_carries_a_stalled_group_and_the_median_does_not(stalled):
+    groups = [1.6] * 25
+    steady = stats.group_rates(49152, groups)
+    assert steady["window_tok_s"] == pytest.approx(49152 / 1.6)
+    groups[stalled] = 2.4          # one group lost 0.8 s
+    got = stats.group_rates(49152, groups)
+    assert got["median_tok_s"] == steady["median_tok_s"] == 49152 / 1.6
+    assert got["window_tok_s"] == pytest.approx(49152 * 25 / (40 + 0.8))
+    # time between groups is the window's too
+    assert stats.group_rates(49152, groups, window_s=41.0)[
+        "window_tok_s"] == pytest.approx(49152 * 25 / 41.0)
+
+
+@pytest.mark.parametrize("kind", ["train", "closed_loop"])
+def test_end_to_end_rates_are_all_the_work_over_all_the_time(kind):
+    """Where the cells' loops take their rates from: read in the source, so
+    that a median cannot come back as the end-to-end figure unseen."""
+    import inspect
+
+    from benchmarks.harness import serve_cell, train_cell
+
+    src = inspect.getsource(train_cell if kind == "train" else serve_cell)
+    if kind == "train":
+        assert '"train_tok_s": rates["window_tok_s"]' in src
+    else:
+        assert '"serve_tok_s": in_window / seconds' in src
+
+
+@pytest.mark.parametrize("case", ["inside", "straddles-open",
+                                  "straddles-close", "outside", "no-token"])
+def test_prompt_tokens_inside_the_window(case):
+    import numpy as np
+
+    from benchmarks.harness.serve_cell import Record, prompt_tokens_inside
+
+    submit, first = {"inside": (11.0, 13.0), "straddles-open": (9.0, 11.0),
+                     "straddles-close": (19.0, 23.0), "outside": (2.0, 9.5),
+                     "no-token": (12.0, None)}[case]
+    rec = Record(0, 1000, 4, np.zeros(1000, np.int32), submit_time=submit,
+                 token_times=[] if first is None else [first, first + 0.1])
+    want = {"inside": 1000.0, "straddles-open": 500.0,
+            "straddles-close": 250.0, "outside": 0.0, "no-token": 0.0}[case]
+    assert prompt_tokens_inside([rec], 10.0, 20.0) == pytest.approx(want)
+
+
+def test_percentile_and_spread_match_the_contracts_definitions():
+    xs = [5.0, 1.0, 4.0, 2.0, 3.0, 6.0]
+    assert stats.percentile(xs, 50) == 3.5
+    assert stats.percentile(xs, 95) == pytest.approx(5.75)
+    q = statistics.quantiles(xs, n=4)
+    assert stats.spread(xs) == pytest.approx((q[2] - q[0]) / 3.5)
+    assert stats.union_seconds([(0, 2), (1, 3), (5, 6)]) == 4
+    assert stats.gaps([(0, 2), (1, 3), (5, 6)], 0, 7) == [[3, 5], [6, 7]]
+
+
+# -- seed-invariant traffic ------------------------------------------------
+
+@pytest.mark.parametrize("name", SERVE_TRAFFIC)
+def test_two_seeds_offer_the_same_work(name):
+    """The seed permutes the order of a fixed set of lengths and draws the
+    token ids (and the weights): the same work, in another order."""
+    t = json.load(open(SPEC.path("traffic", f"{name}.json")))
+    seeds = (1, 2 ** 31 + 12345)
+    sets = [traffic.request_set(t, s) for s in seeds]
+    assert sets[0] != sets[1]                           # another order
+    assert sorted(sets[0]) == sorted(sets[1])           # of the same set
+    assert sets[0] == traffic.request_set(t, seeds[0])  # the seed's own
+    seqs = traffic.client_sequences(t, seeds[0])
+    assert len(seqs) == t["clients"]
+    flat = [r for seq in seqs for r in seq]
+    assert sorted(flat) == sorted(sets[0]) and len(flat) == t["requests"]
+    lo, hi = t["prompt_tokens"]["min"], t["prompt_tokens"]["max"]
+    assert all(lo <= r.prompt_len <= hi for r in flat)
+    assert len({r.new_tokens for r in flat}) > len(flat) // 2   # a spread
+    # stratified: the set's mean is the distribution's, to a percent or two
+    want = ((hi - lo) / math.log(hi / lo)
+            if t["prompt_tokens"]["dist"] == "log_uniform" else (lo + hi) / 2)
+    assert sum(r.prompt_len for r in flat) / len(flat) == pytest.approx(
+        want, rel=0.02)
+
+    class Serving:      # what Load needs of an engine before it starts
+        pass
+
+    from benchmarks.harness.serve_cell import Load
+    turns = t["requests"] // t["clients"]       # once through the whole set
+    totals = []
+    for seed in seeds:
+        load = Load(Serving(), t, seed, 50272, horizon_s=10.0)
+        assert load.requests == traffic.client_sequences(t, seed)
+        recs = [load._take(c, turn) for turn in range(turns)
+                for c in range(t["clients"])]
+        totals.append((sum(r.prompt_len for r in recs),
+                       sum(r.new_tokens for r in recs),
+                       int(sum(int(r.prompt.sum()) for r in recs))))
+    assert totals[0][:2] == totals[1][:2]       # the same token totals
+    assert totals[0][2] != totals[1][2]         # of other token ids
+    ids = traffic.prompt_ids(2 ** 31 + 5, 3, 40, 50272)
+    assert ids.shape == (40,) and ids.min() >= 0 and ids.max() < 50272
+    assert (ids != traffic.prompt_ids(2 ** 31 + 5, 4, 40, 50272)).any()
+
+
+@pytest.mark.parametrize("process", ["poisson", "bursts"])
+def test_open_loop_arrivals(process):
+    arr = {"process": process, "rate": 10.0, "burst": 5, "seed": 3}
+    times = traffic.arrival_times(arr, 200.0)
+    assert times == sorted(times) and times[-1] < 200.0
+    assert times == traffic.arrival_times(arr, 200.0)    # the mix's own
+    assert len(times) == pytest.approx(2000, rel=0.15)    # the mean rate
+    if process == "bursts":
+        assert len(set(times)) * 5 == len(times)
+
+
+# -- the trace reduction, on a small recorded trace -------------------------
+
+@pytest.fixture(scope="module")
+def small_trace():
+    doc = json.load(open(os.path.join(HERE, "fixtures", "small_trace.json")))
+    return doc, trace_mod.Trace.from_json(doc["trace"])
+
+
+def _ctx(small_trace):
+    doc, tr = small_trace
+    cell = SPEC.cell("opt-1.3b.train-zero3-x4")
+    cfg = type("Cfg", (), dict(doc["model_config"]))()
+    ctx = layers.Context(cell=cell, chips=1, peaks=device.peaks("TPU v5 lite"),
+                         counters=dict(doc["counters"]), model_config=cfg,
+                         trace=tr)
+    return doc, ctx
+
+
+def test_small_trace_known_numbers_exist(small_trace):
+    doc, _ = small_trace
+    assert set(doc["expect"]) >= {"train_idle_pct", "train_step_dev_ms"}
+
+
+@pytest.mark.parametrize("metric", [
+    "train_step_dev_ms", "train_idle_pct", "train_attn_time_pct",
+    "train_collective_exposed_pct", "flash_attention_fwd_roofline",
+    "flash_attention_bwd_dq_roofline", "flash_attention_bwd_dkv_roofline",
+    "train_mfu_pct", "train_group_median_tok_s", "train_compiles_in_window",
+    "serve_host_iter_pct"])
+def test_reduction_gives_known_numbers(metric, small_trace):
+    doc, ctx = _ctx(small_trace)
+    r = SPEC.reader(metric)
+    got = layers.reducer(r["reducer"]).reduce(ctx, **r.get("args", {}))
+    assert got == pytest.approx(doc["expect"][metric], rel=1e-6)
+
+
+@pytest.mark.parametrize("metric,loud", [
+    ("serve_decode_iter_ms", True), ("serve_prefill_iter_ms", True),
+    ("serve_prefill_iter_ms.decode", False)])
+def test_a_program_the_trace_does_not_hold(metric, loud, small_trace):
+    """The fixture is a training trace: a metric of a serving program finds
+    none of its executions there. That is an error, except where the metric
+    says a short trace may miss the program."""
+    _, ctx = _ctx(small_trace)
+    r = SPEC.reader(metric)
+    read = lambda: layers.reducer(r["reducer"]).reduce(ctx, **r["args"])
+    if loud:
+        with pytest.raises(layers.MissingProgram, match="jit_"):
+            read()
+    else:
+        assert read() is None
+
+
+def test_a_program_the_benchmark_has_no_file_for_is_an_error(small_trace):
+    _, ctx = _ctx(small_trace)
+    assert ctx.module_of("train/step") == "jit_train_step"
+    with pytest.raises(spec_mod.SpecError, match="programs/train/renamed.json"):
+        ctx.module_of("train/renamed")
+
+
+def test_breakdown_names_operations_and_gaps(small_trace):
+    doc, tr = small_trace
+    top = tr.top_ops(10)
+    assert [t[0] for t in top][:2] == doc["expect"]["top_ops"]
+    assert all(not n.startswith("while") for n, _ in top)
+    gaps = dict(tr.idle_gaps(10))
+    assert gaps == pytest.approx(doc["expect"]["idle_gaps"])
+    assert tr.busy_s(0) == pytest.approx(doc["expect"]["busy_s"])
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in DOC["per_layer"]
+                                    if m["source"] == "device_trace"])
+def test_reader_with_nothing_to_read_returns_nothing(metric):
+    ctx = layers.Context(cell=SPEC.cell(CELLS[0]), chips=1, peaks={},
+                         counters={}, model_config=None, trace=None)
+    r = SPEC.reader(metric)
+    assert layers.reducer(r["reducer"]).reduce(ctx, **r.get("args", {})) \
+        is None
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_paged_attention_cost_counts_resident_pages_only(kernel):
+    from benchmarks.harness.serve_cell import Record
+    import numpy as np
+
+    cell = SPEC.cell("opt-1.3b.serve-decode")
+    cfg = type("Cfg", (), dict(num_heads=32, num_kv_heads=32, head_dim=64,
+                               num_layers=24))()
+    rec = Record(0, 100, 4, np.zeros(100, np.int32), submit_time=10.0,
+                 token_times=[11.0, 11.1, 11.2, 50.0])
+    ctx = layers.Context(cell=cell, chips=1, peaks={}, counters={},
+                         model_config=cfg, records=[rec], traced=(10.5, 12.0))
+    ops, nbytes = layers.reducer(
+        f"paged_{kernel}_attention_cost").total(ctx, calls=1)
+    page = lambda tokens: 2 * (-(-tokens // 16) * 16) * 32 * 64 * 2
+    if kernel == "decode":      # tokens 1 and 2 fell inside; contexts 101, 102
+        assert ops == 24 * 4 * 2048 * (101 + 102)
+        assert nbytes == 24 * (page(101) + page(102) + 2 * 2 * 2048 * 2)
+    else:       # half of submit -> first token lies inside: half the chunk
+        assert ops == 24 * 0.5 * 4 * 2048 * (100 * 101 // 2)
+        assert nbytes == 24 * 0.5 * (page(100) + 2 * 100 * 2048 * 2)
+
+
+# -- every cell end to end at tiny size, steered to the CPU ----------------
+
+@pytest.fixture(scope="module")
+def tiny_root(tmp_path_factory):
+    return bench_tiny.make_root(str(tmp_path_factory.mktemp("tinybench")))
+
+
+@pytest.fixture
+def steered(monkeypatch, tiny_root):
+    monkeypatch.setitem(device.TARGET, "platform", "cpu")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       os.path.join(tiny_root, ".jax_cache"))
+    return spec_mod.Spec(tiny_root)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_runs_end_to_end_at_tiny_size(cell, trace, steered, capsys):
+    # a serving window long enough for a few requests even when six test
+    # workers share the machine
+    seconds = 0.6 if steered.cell(cell).traffic["kind"] == "train" else 3.0
+    result = bench_run.run_cell(steered, cell, 2 ** 31 + 17, seconds,
+                                bool(trace), time.perf_counter())
+    out = capsys.readouterr().out
+    assert result["correct"], out
+    assert result["failed"] == 0 and result["attempted"] >= 2
+    assert result["device"]["platform"] == "cpu" and result["rehearsal"]
+    assert result["device"]["count"] == steered.cell(cell).chips
+    # counts only: never a time, a rate or a share from a CPU run
+    sources = {m["name"]: m["source"]
+               for m in DOC["end_to_end"] + DOC["per_layer"]}
+    assert all(sources[k] == "program_counter" for k in result["metrics"])
+    assert "busy_s" not in result["device"] and "breakdown" not in result
+    if trace:
+        assert [v["value"] for k, v in result["metrics"].items()
+                if "compiles_in_window" in k] == [0.0]
+    else:
+        assert result["metrics"] == {}
+    kind = steered.cell(cell).traffic["kind"]
+    notes = [json.loads(ln) for ln in out.splitlines() if ln.startswith("{")]
+    if kind == "train":
+        groups = next(n for n in notes if "group_seconds" in n)
+        assert len(groups["group_seconds"]) >= 2      # printed, every run
+    else:
+        counts = next(n for n in notes if "ttft_samples" in n)
+        assert counts["ttft_samples"] >= 2 and counts["gap_samples"] >= 20
+
+
+def test_open_loop_cell_arrives_as_data_only(steered, tiny_root, capsys):
+    """A cell under Open questions is data: a traffic file of kind
+    `open_loop` and an entry in BENCHMARK.json, and it runs."""
+    root = steered.root
+    t = json.load(open(os.path.join(root, "benchmarks", "traffic",
+                                    "serve-decode.json")))
+    t.update(kind="open_loop", clients=6,
+             arrivals={"process": "bursts", "rate": 30.0, "burst": 3})
+    json.dump(t, open(os.path.join(root, "benchmarks", "traffic",
+                                   "serve-chat-burst.json"), "w"))
+    doc = copy.deepcopy(DOC)
+    doc["workloads"].append(dict(doc["workloads"][1],
+                                 name="opt-1.3b.serve-chat-burst",
+                                 traffic="serve-chat-burst"))
+    for m in doc["end_to_end"] + doc["per_layer"]:
+        if "opt-1.3b.serve-decode" in m.get("workloads", ()):
+            m["workloads"].append("opt-1.3b.serve-chat-burst")
+    json.dump(doc, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    try:
+        s = spec_mod.Spec(root)
+        s.validate()
+        result = bench_run.run_cell(s, "opt-1.3b.serve-chat-burst", 5, 3.0,
+                                    False, time.perf_counter())
+    finally:
+        json.dump(DOC, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    assert result["correct"] and result["attempted"] >= 2
+    notes = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    assert next(n for n in notes if "ttft_samples" in n)[
+        "generator_late_p95_ms"] is not None     # lateness is reported
+
+
+# -- no chip, no result ----------------------------------------------------
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_without_a_tpu_the_command_fails_and_prints_no_result(cell, capsys,
+                                                              monkeypatch,
+                                                              tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    rc = bench_run.main(["--workload", cell, "--seed", "1", "--seconds", "1",
+                         "--trace", "0"])
+    captured = capsys.readouterr()
+    assert rc == 1
+    assert "need a tpu device" in captured.err
+    assert not [ln for ln in captured.out.splitlines() if ln.startswith("{")]
+
+
+def test_unknown_device_kind_is_an_error_not_a_default():
+    assert device.peaks("TPU v5 lite")["bf16_flops"] == 197e12
+    with pytest.raises(device.NoChip):
+        device.peaks("TPU v99")
