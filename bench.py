@@ -182,7 +182,6 @@ def main() -> None:
         obs.registry.gauge("bench/mfu").set(mfu)
         obs.dump_metrics(path=metrics_path,
                          metric=METRIC, steps=steps, batch=batch, seq=seq)
-        obs.export_chrome_trace()
         obs.close(export=False)   # already exported to the bench paths
 
     from bench_common import fleet_skew_from_metrics

@@ -201,7 +201,6 @@ def main() -> None:
         obs.dump_metrics(path=os.environ.get("BENCH_METRICS_JSONL",
                                              "BENCH_metrics_infer.jsonl"),
                          metric=f"{model_name}_{dtype_name}_p50_ttft_ms")
-        obs.export_chrome_trace()
         obs.close(export=False)   # already exported to the bench paths
 
     record = {
@@ -846,7 +845,6 @@ def serving_main() -> None:
                                                  "BENCH_metrics_serve"
                                                  ".jsonl"),
                              metric=metric)
-            obs.export_chrome_trace()
             obs.close(export=False)
         rr = fleet_arms["round_robin"]
         record = {
@@ -914,7 +912,6 @@ def serving_main() -> None:
         obs.dump_metrics(path=os.environ.get("BENCH_METRICS_JSONL",
                                              "BENCH_metrics_serve.jsonl"),
                          metric=metric)
-        obs.export_chrome_trace()
         obs.close(export=False)
 
     record = {

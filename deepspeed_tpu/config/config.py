@@ -403,13 +403,13 @@ class ProfilingConfig(ConfigModel):
 class ObservabilityConfig(ConfigModel):
     """Gate for ``deepspeed_tpu.observability`` — span tracer, metrics
     registry file output, recompile watchdog, memory gauges. Off by default:
-    a disabled session records nothing and writes no files (tier-1 cost is
-    zero); the monitor writers still work independently of this switch."""
+    a disabled session writes no files and records nothing but spans while a
+    ``jax.profiler`` capture is open; the monitor writers still work
+    independently of this switch."""
 
     enabled: bool = False
     output_dir: str = ""               # "" => ./dstpu_obs
     trace_file: str = "trace.jsonl"            # append-only span records
-    chrome_trace_file: str = "trace_chrome.json"  # chrome://tracing export
     metrics_file: str = "metrics.jsonl"        # registry snapshot dump
     all_ranks: bool = False            # False => rank-0 only (reference norm)
     max_spans: int = 100_000           # in-memory span cap (JSONL unaffected)

@@ -20,6 +20,7 @@ greedy/temperature/top-k sampling, early-EOS masking.
 from __future__ import annotations
 
 import dataclasses
+import time
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -641,15 +642,16 @@ class InferenceEngine:
                 # stale keys stay masked by `valid` and are overwritten as
                 # prefill/decode proceed
                 cache = {**cache, "index": jnp.zeros_like(cache["index"])}
-            # TTFT through the span tracer: the span brackets prefill +
-            # first-token sampling, and the explicit block_until_ready is
-            # the async-dispatch fence that makes the wall-clock real (the
-            # tpulint wallclock-timing-without-sync contract). A disabled
-            # tracer still measures, so return_ttft works without telemetry.
+            # TTFT brackets prefill + first-token sampling; the explicit
+            # block_until_ready is the async-dispatch fence that makes the
+            # wall-clock real (the tpulint wallclock-timing-without-sync
+            # contract). The two readings are this function's own (the span
+            # is the shared no-op when nothing records), so return_ttft
+            # works without telemetry.
             obs = get_session()
-            prefill_span = obs.span("inference/prefill", sync=False,
-                                    batch=B, prompt_tokens=int(S))
-            with prefill_span:
+            t_prefill = time.perf_counter()
+            with obs.span("inference/prefill", batch=B,
+                          prompt_tokens=int(S)):
                 logits, cache = self._prefill_cache[key_p](
                     self.params, ids_pad, valid, cache)
                 # rewind the write cursor from the padded to the true prompt
@@ -663,7 +665,7 @@ class InferenceEngine:
                 rng, r_first = jax.random.split(jax.random.PRNGKey(seed))
                 first = _sample(last, r_first, temperature, top_k, top_p)
                 first = jax.block_until_ready(first)
-            ttft = prefill_span.duration_s
+            ttft = time.perf_counter() - t_prefill
             if n_rest == 0:
                 out = first[:, None]
             else:

@@ -6,9 +6,10 @@ The reference DeepSpeed ships telemetry as disconnected islands
 profiler); this package unifies them behind two process-local primitives plus
 two TPU-specific watchers:
 
-* :mod:`.spans`   — hierarchical wall-clock span tracer (context manager /
-  decorator, rank-0 aware, sync-honest), exporting Chrome trace-event JSON
-  and append-only JSONL;
+* :mod:`.spans`   — the one span API (context manager / decorator, rank-0
+  aware, sync-honest): spans reach the ``jax.profiler`` capture beside the
+  device lines, an in-memory record (``recorded_spans()``) and an
+  append-only JSONL;
 * :mod:`.metrics` — ``MetricsRegistry`` of labeled counters / gauges /
   histograms; the ``monitor/`` CSV/TB/WandB writers are *exporters* of this
   registry, not a parallel event path;
@@ -42,7 +43,8 @@ two TPU-specific watchers:
   resume loop is CI-testable on a CPU mesh (docs/resilience.md).
 
 Everything is **off by default** (``ObservabilityConfig.enabled``); a
-disabled session records nothing and writes no files, so tier-1 cost is zero.
+disabled session writes no files and records nothing — except spans while a
+``jax.profiler`` capture is open, which is what the capture is for.
 ``python -m deepspeed_tpu.observability report <jsonl...>`` summarizes runs.
 """
 
@@ -69,12 +71,13 @@ from .recompile import uninstall as uninstall_watchdog
 from .reqtrace import ReqTrace, RequestTracer
 from .servegoodput import ServeGoodput
 from .servegoodput import note_compile_current as _sg_note_compile
-from .spans import Span, SpanTracer, noop_tracer, write_chrome_trace
+from .spans import (NOOP_SPAN, Span, SpanTracer, noop_tracer,
+                    write_chrome_trace)
 from .timeseries import TimeSeriesStore
 
 __all__ = [
     "Observability", "configure_observability", "get_session", "reset_session",
-    "SpanTracer", "Span", "noop_tracer",
+    "recorded_spans", "SpanTracer", "Span", "NOOP_SPAN", "noop_tracer",
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "get_registry",
     "RecompileWatchdog", "install_watchdog", "uninstall_watchdog",
     "get_watchdog", "record_memory",
@@ -241,7 +244,7 @@ class Observability:
         self._closed = False
         if self.enabled:
             # nothing in the engine API marks "the run is over", so the final
-            # metrics/chrome exports ride process exit; close() is idempotent,
+            # metrics export rides process exit; close() is idempotent,
             # so sessions torn down earlier (tests, bench) no-op here
             import atexit
 
@@ -323,8 +326,11 @@ class Observability:
 
     # -- thin delegates (the API integration sites use) -------------------
     def span(self, name: str, category: str = "span", sync: bool = False,
-             **attrs: Any) -> Span:
-        return self.tracer.span(name, category=category, sync=sync, **attrs)
+             **attrs: Any):
+        """The one span call, ``obs.span(name, **counts)``: records while
+        this session is enabled or a profiler capture is open, else hands
+        back the shared ``NOOP_SPAN``."""
+        return self.tracer.span(name, category, sync, **attrs)
 
     def heartbeat(self, name: str) -> None:
         """Non-span liveness signal (comm census, pipeline census) for the
@@ -377,11 +383,6 @@ class Observability:
             return None
         return os.path.join(self.output_dir, self.config.metrics_file)
 
-    def chrome_trace_path(self) -> Optional[str]:
-        if not self.enabled:
-            return None
-        return os.path.join(self.output_dir, self.config.chrome_trace_file)
-
     def dump_metrics(self, path: Optional[str] = None, **extra: Any) -> Optional[str]:
         """Write the registry snapshot (+ recompile report) as JSONL. Honors
         the same rank gate as the tracer (``all_ranks=False`` => rank 0
@@ -393,12 +394,6 @@ class Observability:
         if self.watchdog is not None:
             extra.setdefault("recompile_report", self.watchdog.report())
         return self.registry.dump_jsonl(path, extra=extra or None)
-
-    def export_chrome_trace(self, path: Optional[str] = None) -> Optional[str]:
-        path = path or self.chrome_trace_path()
-        if path is None or not self.tracer.enabled:
-            return None
-        return self.tracer.export_chrome_trace(path)
 
     def flush(self) -> None:
         self.tracer.flush()
@@ -428,7 +423,6 @@ class Observability:
                 if self.goodput is not None:
                     self.goodput.publish()   # final bucket snapshot
                 self.dump_metrics()
-                self.export_chrome_trace()
                 if self.reqtrace is not None and self.reqtrace.retained:
                     self.reqtrace.export_chrome_trace(os.path.join(
                         self.output_dir, self.config.reqtrace_chrome_file))
@@ -513,10 +507,21 @@ def get_session() -> Observability:
     return _SESSION if _SESSION is not None else _disabled_session()
 
 
+def recorded_spans() -> list:
+    """The spans this process holds in memory, closed ones in closing order
+    (``spans.Span.to_record`` dicts): the current session's — with no session
+    configured, what the shared disabled one recorded while a profiler
+    capture was open. The benchmark's span reducers read this."""
+    return get_session().tracer.snapshot()
+
+
 def reset_session(close: bool = True) -> None:
-    """Tear down the current session (tests / end of run)."""
+    """Tear down the current session (tests / end of run), and empty what
+    the shared disabled one recorded under profiler captures."""
     global _SESSION
     if _SESSION is not None and close:
         _SESSION.close(export=False)
     _SESSION = None
+    if _DISABLED is not None:
+        _DISABLED.tracer.clear()
     uninstall_watchdog()

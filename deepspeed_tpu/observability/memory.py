@@ -55,6 +55,16 @@ def device_memory_stats() -> Dict[str, Dict[str, int]]:
     return out
 
 
+def hbm_counts() -> Dict[str, int]:
+    """The first reporting device's ``hbm_bytes_in_use`` / ``hbm_peak_bytes``
+    as counts for a span (``serving/iteration``, ``train_batch``): peak HBM
+    by phase, read only while the span records. Empty on stat-less backends."""
+    for stats in device_memory_stats().values():
+        return {"hbm_bytes_in_use": stats.get("bytes_in_use", 0),
+                "hbm_peak_bytes": stats.get("peak_bytes_in_use", 0)}
+    return {}
+
+
 def record_memory(registry: Optional[Any] = None) -> bool:
     """Poll memory into gauges. Returns True if any *device* stats were
     recorded (False on stat-less backends — the CPU no-op contract)."""

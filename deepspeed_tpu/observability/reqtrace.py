@@ -479,6 +479,8 @@ class _ActiveTrace:
         self._prev = None
 
     def __enter__(self):
+        # tpusync: disable=unguarded-shared-write — one thread builds and
+        # uses an _ActiveTrace, inside one with-statement
         self._prev = getattr(_ACTIVE, "trace", None)
         _ACTIVE.trace = self._trace
         return self._trace

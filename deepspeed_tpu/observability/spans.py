@@ -1,47 +1,62 @@
-"""Hierarchical span tracer — the wall-clock half of the observability layer.
+"""The one span API — named intervals of the program on the profiler's clock.
 
 The reference DeepSpeed times things with ad-hoc ``SynchronizedWallClockTimer``
 instances and NVTX ranges; here one process-local tracer owns every timed
-region. A *span* is a named wall-clock interval with attributes; spans nest
-(context manager / decorator / explicit begin-end for non-lexical regions like
-``start_profile``..``stop_profile``) and the tracer records the completed tree.
+region. ``tracer.span(name, **counts)`` (reached as ``obs.span(...)``) is the
+one call; spans nest (context manager / decorator / explicit begin-end for
+non-lexical regions like ``start_profile``..``stop_profile``).
 
-Two export formats, both loadable without this package:
+A span is *recording* when the tracer is enabled by config or while a
+``jax.profiler`` capture is open (``TraceAnnotation.is_enabled()``), so
+"tracing on" needs no switch of its own. A recording span
 
-* **Chrome trace-event JSON** (``export_chrome_trace``) — complete ``"ph": "X"``
-  events; open in ``chrome://tracing`` / Perfetto.
-* **Append-only JSONL** (``jsonl_path``) — one record per closed span, written
-  as it closes, so a killed run keeps its tail. The ``report`` CLI
+* opens a ``jax.profiler.TraceAnnotation(name, **counts)`` while a capture is
+  open: it lies in the ``.xplane.pb`` beside the device lines, on their clock,
+  with its counts as the event's ``stats`` — the NVTX-range analog;
+* is kept in memory when it closes (``snapshot()``; bounded by ``max_spans``)
+  with name, start and end in ``time.perf_counter`` seconds, thread name, its
+  counts, an id and its parent's id — what the benchmark's reducers read;
+* is appended to the JSONL (``jsonl_path``, enabled tracers only) as it
+  closes, so a killed run keeps its tail. The ``report`` CLI
   (``python -m deepspeed_tpu.observability report``) summarizes it.
+
+When not recording the call costs one ``is_enabled()`` check and returns the
+shared ``NOOP_SPAN``: no object is built and no clock is read. A tracer that
+records only because a capture is open empties its record when the next
+capture opens, so two captures in one process never read each other's spans.
 
 TPU honesty rule: a jitted call returns before the device finishes (async
 dispatch), so a naive wall-clock around it times the *enqueue*, not the work.
-Spans therefore carry ``sync=``: a syncing span drains the dispatch queue at
-entry and exit (the ``cudaEventSynchronize`` analog), making its duration a
-true device-inclusive measurement. Non-syncing spans are free and honest about
-what they are — their records carry ``"synced": false``.
+Spans therefore carry ``sync=``: a syncing span of an ENABLED tracer drains
+the dispatch queue at entry and exit (the ``cudaEventSynchronize`` analog),
+making its duration a true device-inclusive measurement. Non-syncing spans are
+free and honest about what they are — their records carry ``"synced": false``.
+A span that records only because a capture is open never syncs: the capture
+is there to see the program as it runs.
 
-Rank-awareness: by default only process 0 records (the reference's rank-0
-logging convention); ``all_ranks=True`` records everywhere, with the process
+Rank-awareness: by default only process 0 is enabled (the reference's rank-0
+logging convention); ``all_ranks=True`` enables everywhere, with the process
 index in every record's ``pid``.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from ..utils.logging import logger
+from jax.profiler import TraceAnnotation
+
+_capture_open = TraceAnnotation.is_enabled
 
 
 def write_chrome_trace(events: List[Dict[str, Any]], path: str) -> str:
-    """Write pre-built Chrome trace events as a loadable trace file — the
-    one exporter behind both the span tracer and the request tracer
-    (``reqtrace.py``), so every timeline this package produces opens in
-    chrome://tracing / Perfetto the same way."""
+    """Write pre-built Chrome trace events as a loadable trace file (the
+    request tracer's timelines, ``reqtrace.py``; spans reach a timeline
+    through the profiler's own capture)."""
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     with open(path, "w") as fh:
         json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, fh)
@@ -62,30 +77,40 @@ def _drain_dispatch_queue() -> None:
 
 
 class Span:
-    """One open (then closed) timed region. Returned by ``SpanTracer.span``;
-    ``duration_s`` is valid after the context exits (or after ``end()``)."""
+    """One open (then closed) recording span. Returned by ``SpanTracer.span``
+    while recording; ``duration_s`` is valid after the context exits (or
+    after ``end()``)."""
 
-    __slots__ = ("name", "category", "attrs", "sync", "depth", "parent_name",
-                 "start_ns", "end_ns", "_tracer")
+    __slots__ = ("name", "category", "attrs", "sync", "depth", "id",
+                 "parent_id", "start_ns", "end_ns", "_tracer", "_annotation")
+    recording = True
 
-    def __init__(self, name: str, category: str, sync: bool, attrs: Dict[str, Any],
-                 tracer: Optional["SpanTracer"]):
+    def __init__(self, name: str, category: str, sync: bool,
+                 attrs: Dict[str, Any], tracer: "SpanTracer", capture: bool):
         self.name = name
         self.category = category
         self.attrs = attrs
         self.sync = sync
         self.depth = 0
-        self.parent_name: Optional[str] = None
+        self.id = next(tracer._ids)
+        self.parent_id: Optional[int] = None
         self.start_ns = 0
         self.end_ns = 0
         self._tracer = tracer
+        # made here, entered in begin(): a capture that closes in between
+        # leaves a harmless annotation that records nothing
+        self._annotation = TraceAnnotation(name, **attrs) if capture else None
 
     @property
     def duration_s(self) -> float:
         return (self.end_ns - self.start_ns) / 1e9
 
     def annotate(self, **attrs: Any) -> "Span":
+        """Counts known only once the span is open (rows found ready, tokens
+        emitted): into the record and, before ``end()``, the trace event."""
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
     # -- lifecycle --------------------------------------------------------
@@ -93,33 +118,49 @@ class Span:
         if self.sync:
             _drain_dispatch_queue()
         t = self._tracer
-        if t is not None:
-            stack = t._stack()
-            self.depth = len(stack)
-            self.parent_name = stack[-1].name if stack else None
-            stack.append(self)
+        stack = t._stack()
+        # tpusync: disable=unguarded-shared-write — here and below: a Span is
+        # built, begun and ended by ONE thread (the open-span stack is
+        # thread-local); only its closed record is shared, under the
+        # tracer's lock
+        self.depth = len(stack)
+        if stack:
+            # tpusync: disable=unguarded-shared-write
+            self.parent_id = stack[-1].id
+        stack.append(self)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        # tpusync: disable=unguarded-shared-write
         self.start_ns = time.perf_counter_ns()
-        if t is not None and t.on_event is not None:
+        if t.on_event is not None:
             t.on_event("begin", self)
         return self
 
     def end(self) -> "Span":
         if self.sync:
             _drain_dispatch_queue()
+        # tpusync: disable=unguarded-shared-write
         self.end_ns = time.perf_counter_ns()
+        self._close_annotation()
         t = self._tracer
-        if t is not None:
-            stack = t._stack()
-            # pop through any unclosed children (non-lexical misuse) so the
-            # stack cannot leak depth
-            while stack and stack[-1] is not self:
-                stack.pop()
-            if stack:
-                stack.pop()
-            t._record(self)
-            if t.on_event is not None:
-                t.on_event("end", self)
+        stack = t._stack()
+        # pop through any unclosed children (non-lexical misuse, or an
+        # exception between a child's begin() and end()) so the stack cannot
+        # leak depth nor the trace an open annotation
+        while stack and stack[-1] is not self:
+            stack.pop()._close_annotation()
+        if stack:
+            stack.pop()
+        t._record(self)
+        if t.on_event is not None:
+            t.on_event("end", self)
         return self
+
+    def _close_annotation(self) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            # tpusync: disable=unguarded-shared-write
+            self._annotation = None
 
     def __enter__(self) -> "Span":
         return self.begin()
@@ -132,16 +173,47 @@ class Span:
             "type": "span",
             "name": self.name,
             "cat": self.category,
-            "ts_us": self.start_ns / 1e3,
+            "id": self.id,
+            "start_s": self.start_ns / 1e9,
+            "end_s": self.end_ns / 1e9,
             "dur_us": (self.end_ns - self.start_ns) / 1e3,
             "depth": self.depth,
             "synced": self.sync,
         }
-        if self.parent_name:
-            rec["parent"] = self.parent_name
+        if self.parent_id is not None:
+            rec["parent_id"] = self.parent_id
         if self.attrs:
             rec["attrs"] = self.attrs
         return rec
+
+
+class _NoopSpan:
+    """What ``span()`` hands back when nothing records: one shared object,
+    every method a no-op, no clock read. ``duration_s`` is 0.0 — a caller
+    that needs a time when tracing is off reads the clock itself."""
+
+    __slots__ = ()
+    recording = False
+    sync = False
+    duration_s = 0.0
+
+    def annotate(self, **attrs: Any) -> "_NoopSpan":
+        return self
+
+    def begin(self) -> "_NoopSpan":
+        return self
+
+    def end(self) -> "_NoopSpan":
+        return self
+
+    def __enter__(self) -> "_NoopSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+NOOP_SPAN = _NoopSpan()
 
 
 class SpanTracer:
@@ -169,6 +241,9 @@ class SpanTracer:
         # check per span boundary
         self.on_event: Optional[Any] = None
         self._spans: List[Dict[str, Any]] = []
+        self._ids = itertools.count(1)
+        self._capture_seen = False      # a profiler capture was open at the
+        #   last span() call (guarded by _lock on change)
         self._lock = threading.Lock()
         self._local = threading.local()
         self._fh = None
@@ -182,12 +257,15 @@ class SpanTracer:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+            self._local.tid = threading.get_ident() & 0xFFFF
+            self._local.thread = threading.current_thread().name
         return stack
 
     def _record(self, span: Span) -> None:
         rec = span.to_record()
         rec["pid"] = self.process_index
-        rec["tid"] = threading.get_ident() & 0xFFFF
+        rec["tid"] = self._local.tid
+        rec["thread"] = self._local.thread
         with self._lock:
             if len(self._spans) < self.max_spans:
                 self._spans.append(rec)
@@ -196,17 +274,33 @@ class SpanTracer:
             if self._fh is not None:
                 self._fh.write(json.dumps(rec) + "\n")
 
+    def _capture_edge(self, capture: bool) -> None:
+        """A capture opened or closed since the last span() call. A tracer
+        that records only under a capture starts each one with an empty
+        record (an enabled tracer keeps its whole run). The edge is seen at a
+        span() call: two captures with no span between them share a record."""
+        with self._lock:
+            if capture is self._capture_seen:
+                return
+            self._capture_seen = capture
+            if capture and not self.enabled:
+                self._spans.clear()
+                self.dropped = 0
+
     # -- public API -------------------------------------------------------
     def span(self, name: str, category: str = "span", sync: bool = False,
-             **attrs: Any) -> Span:
+             **attrs: Any):
         """Open a span as a context manager (``with tracer.span("fwd"): ...``)
-        or drive it manually via ``begin()``/``end()``. A disabled tracer
-        still returns a measuring span (``duration_s`` works — callers that
-        derive metrics from the span, e.g. TTFT, stay correct) but records
-        nothing and never syncs."""
-        if not self.enabled:
-            return Span(name, category, sync=False, attrs=attrs, tracer=None)
-        return Span(name, category, sync=sync, attrs=attrs, tracer=self)
+        or drive it manually via ``begin()``/``end()``. ``attrs`` are the
+        span's counts (numbers or short strings). Returns ``NOOP_SPAN`` when
+        neither the tracer is enabled nor a profiler capture is open."""
+        capture = _capture_open()
+        if capture is not self._capture_seen:
+            self._capture_edge(capture)
+        if self.enabled or capture:
+            return Span(name, category, sync and self.enabled, attrs, self,
+                        capture)
+        return NOOP_SPAN
 
     def trace(self, name: Optional[str] = None, category: str = "span",
               sync: bool = False):
@@ -227,33 +321,26 @@ class SpanTracer:
         return deco
 
     def current_name(self) -> Optional[str]:
-        """Name of the innermost open span on this thread (recompile watchdog
-        attribution hook)."""
-        stack = getattr(self._local, "stack", None)
-        return stack[-1].name if stack else None
+        """Name of the innermost open span on this thread that is not a
+        ``category="phase"`` subdivision of its parent (recompile watchdog
+        attribution hook: a compile under ``serving/decode/dispatch`` is
+        ``serving/decode``'s, the name the program is registered under)."""
+        for span in reversed(getattr(self._local, "stack", ())):
+            if span.category != "phase":
+                return span.name
+        return None
 
     def snapshot(self) -> List[Dict[str, Any]]:
+        """The closed spans kept in memory, in closing order."""
         with self._lock:
             return list(self._spans)
 
-    def export_chrome_trace(self, path: str) -> str:
-        """Write the recorded spans as a Chrome trace-event JSON file."""
+    def clear(self) -> None:
+        """Empty the in-memory record (``reset_session``: tests, end of run)."""
         with self._lock:
-            events = [{
-                "name": rec["name"],
-                "cat": rec.get("cat", "span"),
-                "ph": "X",
-                "ts": rec["ts_us"],
-                "dur": rec["dur_us"],
-                "pid": rec.get("pid", 0),
-                "tid": rec.get("tid", 0),
-                "args": {**rec.get("attrs", {}), "synced": rec.get("synced")},
-            } for rec in self._spans]
-        write_chrome_trace(events, path)
-        if self.dropped:
-            logger.warning(f"span tracer dropped {self.dropped} spans past "
-                           f"max_spans={self.max_spans}")
-        return path
+            self._spans.clear()
+            self.dropped = 0
+            self._capture_seen = False
 
     def flush(self) -> None:
         with self._lock:

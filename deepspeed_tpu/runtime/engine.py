@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..comm.comms_logging import configure_comms_logger
 from ..config.config import Config, load_config
 from ..models.core import Model, cast_floating, param_count
+from ..observability.memory import hbm_counts
 from ..parallel import mesh as mesh_mod
 from ..parallel.zero import (ZeroShardingPlan, as_named, build_sharding_plan,
                              describe_plan, optimizer_state_specs)
@@ -1039,6 +1040,8 @@ class TrainEngine:
                     sum(int(getattr(x, "nbytes", 0))
                         for x in jax.tree.leaves(batch)))
         _batch_span = obs.span("train_batch", step=self.global_steps)
+        if _batch_span.recording:
+            _batch_span.annotate(**hbm_counts())
         _batch_span.begin()
         try:
             with mesh_mod.ambient(self.mesh):

@@ -15,9 +15,11 @@ discipline rationale).
 
 Telemetry flows through the PR-2 observability substrate: ``serving/*``
 metrics in the MetricsRegistry (ttft_ms, tpot_ms, queue_depth,
-kv_blocks_in_use, preemptions, ...), spans ``serving/prefill_chunk`` and
-``serving/decode`` (which also give the recompile watchdog its attribution
-site), and tpuaudit entries of the same names.
+kv_blocks_in_use, preemptions, ...), the spans of ``docs/serving.md``'s table
+(``serving/iteration`` and what it holds, ``serving/submit``,
+``serving/request/*``; they reach the profiler's capture while one is open,
+and ``serving/prefill_chunk`` / ``serving/decode`` also give the recompile
+watchdog its attribution site), and tpuaudit entries of the same names.
 """
 
 from __future__ import annotations
@@ -31,6 +33,7 @@ import numpy as np
 
 from ..config.config import ServingConfig
 from ..observability import get_session
+from ..observability.memory import hbm_counts
 from ..parallel import mesh as mesh_mod
 from ..utils.logging import log_dist, logger
 from . import paged_kv
@@ -241,64 +244,72 @@ class ServingEngine:
         block). Sibling ``i`` samples with ``seed + i``, so each sample is
         bit-identical to a separately submitted request with that seed.
         Returns a list of ``n`` handles instead of one."""
+        # TTFT counts from here: the wait for the engine's lock (the driver
+        # thread holds it through an iteration) is time the caller spends
+        entry_s = self.clock()
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if n < 1:
             raise ValueError(f"submit(n={n}): need n >= 1")
-        with self._lock:
-            # pending (not-yet-forked) siblings hold real queue capacity:
-            # submit_forked bypasses the scheduler's max_queue check, so
-            # the reservation must be enforced here, against scheduler
-            # occupancy PLUS every sibling still waiting for its fork
-            in_flight = self.sched.in_flight() + self._pending_fork_count()
-            if in_flight + n > self.config.max_queue:
-                from .scheduler import QueueFull
+        obs = get_session()
+        with obs.span("serving/submit", n_prompt=int(prompt.size)) as span:
+            lock_wait = obs.span("serving/submit/lock_wait").begin()
+            with self._lock:
+                lock_wait.end()
+                # pending (not-yet-forked) siblings hold real queue capacity:
+                # submit_forked bypasses the scheduler's max_queue check, so
+                # the reservation must be enforced here, against scheduler
+                # occupancy PLUS every sibling still waiting for its fork
+                in_flight = self.sched.in_flight() + self._pending_fork_count()
+                if in_flight + n > self.config.max_queue:
+                    from .scheduler import QueueFull
 
-                raise QueueFull(
-                    f"serving queue cannot take {n} more request(s) "
-                    f"({in_flight} in flight incl. pending forks, "
-                    f"max_queue={self.config.max_queue})")
+                    raise QueueFull(
+                        f"serving queue cannot take {n} more request(s) "
+                        f"({in_flight} in flight incl. pending forks, "
+                        f"max_queue={self.config.max_queue})")
 
-            def make(rid, sd, fork_of=None):
-                return Request(
-                    rid=rid, prompt=prompt.copy(),
-                    max_new_tokens=(max_new_tokens
-                                    if max_new_tokens is not None
-                                    else self.config.default_max_new_tokens),
-                    sampling=SamplingParams(temperature=float(temperature),
-                                            top_k=int(top_k),
-                                            top_p=float(top_p)),
-                    eos_token_id=eos_token_id, tenant=tenant, seed=sd,
-                    fork_of=fork_of,
-                    deadline_s=(self.clock() + deadline_s
-                                if deadline_s is not None else None))
+                if max_new_tokens is None:
+                    max_new_tokens = self.config.default_max_new_tokens
 
-            req = make(self._rid, seed)
-            self.sched.submit(req)   # raises before rid is consumed
-            self._rid += 1
-            self._trace_start(req)
-            handle = RequestHandle(self, req)
-            self._handles[req.rid] = handle
-            obs = get_session()
-            if obs.enabled:
-                obs.registry.counter(
-                    "serving/requests_submitted",
-                    help="requests accepted into the serving queue").inc(
-                        n, tenant=tenant)
-            if n == 1:
-                return handle
-            sibs, handles = [], [handle]
-            for i in range(1, n):
-                sib = make(self._rid, seed + i, fork_of=req.rid)
-                sib.arrival_s = req.arrival_s   # TTFT from the client's
-                #   submit — the wait through the parent's prefill counts
+                def make(rid, sd, fork_of=None):
+                    return Request(
+                        rid=rid, prompt=prompt.copy(),
+                        max_new_tokens=max_new_tokens,
+                        sampling=SamplingParams(
+                            temperature=float(temperature),
+                            top_k=int(top_k), top_p=float(top_p)),
+                        eos_token_id=eos_token_id, tenant=tenant, seed=sd,
+                        fork_of=fork_of, submit_s=entry_s,
+                        deadline_s=(self.clock() + deadline_s
+                                    if deadline_s is not None else None))
+
+                req = make(self._rid, seed)
+                self.sched.submit(req)   # raises before rid is consumed
                 self._rid += 1
-                self._trace_start(sib, parent_trace=req.trace)
-                sibs.append(sib)
-                h = RequestHandle(self, sib)
-                self._handles[sib.rid] = h
-                handles.append(h)
-            self._pending_forks[req.rid] = sibs
-            return handles
+                span.annotate(rid=req.rid)
+                self._trace_start(req)
+                handle = RequestHandle(self, req)
+                self._handles[req.rid] = handle
+                if obs.enabled:
+                    obs.registry.counter(
+                        "serving/requests_submitted",
+                        help="requests accepted into the serving queue").inc(
+                            n, tenant=tenant)
+                if n == 1:
+                    return handle
+                sibs, handles = [], [handle]
+                for i in range(1, n):
+                    sib = make(self._rid, seed + i, fork_of=req.rid)
+                    sib.arrival_s = req.arrival_s   # queued with the parent:
+                    #   the wait through the parent's prefill counts
+                    self._rid += 1
+                    self._trace_start(sib, parent_trace=req.trace)
+                    sibs.append(sib)
+                    h = RequestHandle(self, sib)
+                    self._handles[sib.rid] = h
+                    handles.append(h)
+                self._pending_forks[req.rid] = sibs
+                return handles
 
     def cancel(self, handle: RequestHandle) -> bool:
         cancelled = 0   # every cancellation this call caused — pre-fork
@@ -395,7 +406,9 @@ class ServingEngine:
         if rt is None:
             return
         req.trace = rt.start(
-            tenant=req.tenant, t=self.clock(),
+            tenant=req.tenant,
+            # the queue wait starts where TTFT does: at entry to submit()
+            t=req.submit_s if req.submit_s is not None else self.clock(),
             fork_of=(parent_trace.trace_id if parent_trace is not None
                      else None),
             attrs={"rid": req.rid, "seed": req.seed,
@@ -404,25 +417,40 @@ class ServingEngine:
         if parent_trace is not None:
             rt.link_fork(parent_trace, req.trace)
 
-    def _trace_admitted(self, admitted: List[Request]) -> None:
-        rt = get_session().reqtrace
-        if rt is None:
-            return
+    @staticmethod
+    def _request_span(obs, name: str, req: Request, **counts: Any) -> None:
+        """One boundary of a request's life, as a short span where it
+        happens; all of one request's carry its ``rid`` (and its
+        ``trace_id`` when request tracing is on)."""
+        with obs.span(name, rid=req.rid, **counts) as span:
+            if span.recording and req.trace is not None:
+                span.annotate(trace_id=req.trace.trace_id)
+
+    def _trace_admitted(self, obs, admitted: List[Request]) -> None:
         now = self.clock()
+        rt = obs.reqtrace
         for req in admitted:
-            if req.trace is not None:
+            self._request_span(
+                obs, "serving/request/admitted", req, row=req.row,
+                queue_wait_us=int((now - req.entry_s) * 1e6))
+            if rt is not None and req.trace is not None:
                 rt.admitted(req.trace, now, self.trace_tag, row=req.row)
 
     def _trace_preempt(self, req: Request) -> None:
+        obs = get_session()
+        self._request_span(obs, "serving/request/preempted", req)
         if req.trace is not None:
-            rt = get_session().reqtrace
+            rt = obs.reqtrace
             if rt is not None:
                 rt.preempted(req.trace, self.clock(), self.trace_tag)
 
     def _trace_finish(self, req: Request, state: str, **attrs: Any) -> None:
+        obs = get_session()
+        self._request_span(obs, "serving/request/finished", req,
+                           tokens=len(req.generated), state=state)
         if req.trace is None:
             return
-        rt = get_session().reqtrace
+        rt = obs.reqtrace
         if rt is not None:
             rt.finish(req.trace, state, t=self.clock(), ttft_s=req.ttft_s,
                       tokens=len(req.generated), replica=self.trace_tag,
@@ -660,72 +688,102 @@ class ServingEngine:
     def step(self) -> bool:
         """One continuous-batching iteration; returns True when any request
         made progress (admission, a prefill chunk, a decode token, or a
-        deadline expiry reclaiming its resources)."""
-        with self._lock:
-            acct = self._accountant()
-            if acct is not None:
-                acct.iteration_begin(self.clock())
-            try:
-                # before admit: an already-expired queued request must
-                # never take a decode row first
-                progress = self._expire_deadlines()
-                admitted = self.sched.admit()
-                progress |= bool(admitted)
-                if admitted:
-                    self._trace_admitted(admitted)
-                for _ in range(max(int(self.prefill_chunks_per_iter), 1)):
-                    # tpusync: disable=lock-order-inversion — the SE->FR
-                    # edge (prefill-complete handoff) and the FR->SE edge
-                    # (router submit/step) are both RLock re-entries on the
-                    # one thread that drives a fleet: engines under a
-                    # router are stepped only from FleetRouter.step, which
-                    # already holds FR
-                    ran_chunk = self._step_prefill()
-                    progress |= ran_chunk
-                    if not ran_chunk:
-                        break
-                progress |= (self._step_verify()
-                             if self._drafter is not None
-                             and not self.spec_suspended
-                             else self._step_decode())
-                self._publish_iteration()
-                it = self._iterations
-                self._iterations += 1
-            finally:
+        deadline expiry reclaiming its resources). The ``serving/iteration``
+        span opens before the engine lock is taken, so the wait for callers
+        inside ``submit()`` is part of it (``.../lock_wait``)."""
+        obs = get_session()
+        with obs.span("serving/iteration") as span:
+            lock_wait = obs.span("serving/iteration/lock_wait").begin()
+            with self._lock:
+                lock_wait.end()
+                acct = self._accountant()
                 if acct is not None:
-                    acct.iteration_end(self.clock())
-                    # gauge refresh at a cadence, always AFTER the window
-                    # closed (wall and buckets stay consistent): per-
-                    # iteration publishing would put O(window) breach-deque
-                    # scans on the decode loop's critical path. close()
-                    # publishes the final snapshot.
-                    if acct.iterations % 16 == 1:
-                        acct.publish()
-        # the live tuner's decision tick runs OUTSIDE the engine lock: the
-        # controller is foreign code with its own lock, and its knob writes
-        # are plain scheduling attributes — keeping it out of the critical
-        # section keeps the lock graph acyclic (tools/tpusync)
-        tuner = self._maybe_tuner()
-        if tuner is not None:
-            tuner.on_iteration(it)
-        # deep-profiler tick, same discipline: trigger polling and window
-        # open/close do their own locking and may dispatch (start_trace)
-        prof = get_session().profiler
-        if prof is not None:
-            prof.on_iteration(it)
+                    acct.iteration_begin(self.clock())
+                try:
+                    # tpusync: disable=lock-order-inversion — the SE->FR
+                    # edge (prefill-complete handoff, in _step_prefill) and
+                    # the FR->SE edge (router submit/step) are both RLock
+                    # re-entries on the one thread that drives a fleet:
+                    # engines under a router are stepped only from
+                    # FleetRouter.step, which already holds FR
+                    progress = self._step_locked(obs)
+                    it = self._iterations
+                    self._iterations += 1
+                    if span.recording:
+                        span.annotate(
+                            it=it, queued=self.sched.queue_depth(),
+                            running=len(self.sched.running),
+                            blocks_in_use=self.alloc.blocks_in_use,
+                            blocks_running=sum(
+                                len(r.blocks)
+                                for r in self.sched.running.values()),
+                            blocks_total=self.alloc.capacity,
+                            preemptions=self.sched.preemption_count,
+                            **hbm_counts())
+                finally:
+                    if acct is not None:
+                        acct.iteration_end(self.clock())
+                        # gauge refresh at a cadence, always AFTER the
+                        # window closed (wall and buckets stay consistent):
+                        # per-iteration publishing would put O(window)
+                        # breach-deque scans on the decode loop's critical
+                        # path. close() publishes the final snapshot.
+                        if acct.iterations % 16 == 1:
+                            acct.publish()
+            # the live tuner's decision tick runs OUTSIDE the engine lock:
+            # the controller is foreign code with its own lock, and its knob
+            # writes are plain scheduling attributes — keeping it out of the
+            # critical section keeps the lock graph acyclic (tools/tpusync).
+            # The deep profiler's tick, same discipline: trigger polling and
+            # window open/close do their own locking and may dispatch
+            # (start_trace)
+            tuner = self._maybe_tuner()
+            prof = obs.profiler
+            if tuner is not None or prof is not None:
+                with obs.span("serving/ticks"):
+                    if tuner is not None:
+                        tuner.on_iteration(it)
+                    if prof is not None:
+                        prof.on_iteration(it)
         return progress
 
-    def _expire_deadlines(self) -> bool:
+    def _step_locked(self, obs) -> bool:
+        """The iteration's work, under the engine lock: each stretch of it
+        lies in one span of ``docs/serving.md``'s table."""
+        with obs.span("serving/admit") as span:
+            # before admit: an already-expired queued request must
+            # never take a decode row first
+            expired = self._expire_deadlines()
+            admitted = self.sched.admit()
+            if admitted and (span.recording or obs.reqtrace is not None):
+                self._trace_admitted(obs, admitted)
+            span.annotate(admitted=len(admitted), expired=expired)
+        progress = bool(expired or admitted)
+        for _ in range(max(int(self.prefill_chunks_per_iter), 1)):
+            ran_chunk = self._step_prefill()
+            progress |= ran_chunk
+            if not ran_chunk:
+                break
+        progress |= (self._step_verify()
+                     if self._drafter is not None
+                     and not self.spec_suspended
+                     else self._step_decode())
+        with obs.span("serving/publish"):
+            self._publish_iteration()
+        return progress
+
+    def _expire_deadlines(self) -> int:
         """Deadline enforcement at decode time: a request whose absolute
         deadline passed finishes as ``deadline_exceeded`` NOW — rows and
         blocks free at this iteration boundary instead of decoding to its
         token budget — and its un-forked siblings (who could never fork
         anymore) expire with it. The ledger stays balanced:
-        submitted == completed + cancelled + deadline_exceeded."""
+        submitted == completed + cancelled + deadline_exceeded. Returns how
+        many expired."""
         now = self.clock()
         expired = self.sched.expire_deadlines(now)
         if not expired:
-            return False
+            return 0
         from .scheduler import DEADLINE_EXCEEDED
 
         for req in list(expired):
@@ -753,7 +811,7 @@ class ServingEngine:
             handle = self._handles.pop(req.rid, None)
             if handle is not None:
                 handle._wake()
-        return True
+        return len(expired)
 
     def _table_for(self, reqs: List[Request]) -> np.ndarray:
         """(len(reqs), MAXB) block table; unfilled entries → scratch 0."""
@@ -804,77 +862,98 @@ class ServingEngine:
             self._cow_copies += 1
         return True
 
+    def _run_program(self, obs, name: str, program, *args):
+        """Dispatch one jitted program over the arena (its first output the
+        sampled tokens, its last the arena) and bring the tokens to the host:
+        ``<name>/dispatch`` is the call, which returns at enqueue, and
+        ``<name>/fetch`` the wait for the tokens (device time + D2H: the
+        iteration's host sync). ONE pair of readings of the engine's clock
+        around both feeds the accountants and the request tracer: returns
+        (tokens, t0, t1). The spans stamp themselves, on the profiler's
+        clock, and only while they record."""
+        t0 = self.clock()
+        with obs.span(name + "/dispatch", category="phase"):
+            tok, *_, self._arena = program(self.engine.params, self._arena,
+                                           *args)
+        with obs.span(name + "/fetch", category="phase"):
+            tok = np.asarray(tok)
+        return tok, t0, self.clock()
+
     def _step_prefill(self) -> bool:
         req = self.sched.next_prefill()
         if req is None:
             return False
+        obs = get_session()
         C = self.config.prefill_chunk
         src = req.prompt
         start = req.prefill_pos
         n_valid = min(C, int(src.size) - start)
-        if not self.sched.ensure_blocks(req, start + n_valid):
-            return False    # pool dry, nothing evictable — wait a turn
-        if not self._make_writable(req, start, start + n_valid):
-            return False    # shared block needs a copy the pool can't give
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :n_valid] = src[start:start + n_valid]
-        temps, topks, topps, seeds = self._sampling_arrays([req])
-        obs = get_session()
-        rt = obs.reqtrace
-        acct = self._serve_acct
-        timed = acct is not None or (rt is not None
-                                     and req.trace is not None)
-        t0 = self.clock() if timed else 0.0
-        with self._trace_dispatch(rt, req.trace):
-            with mesh_mod.ambient(self.engine.mesh):
-                with obs.span("serving/prefill_chunk", batch=1,
-                              tokens=int(n_valid)):
-                    tok, _last, self._arena = self._prefill(
-                        self.engine.params, self._arena,
-                        self._table_for([req]), chunk,
-                        np.asarray(start, np.int32),
+        with obs.span("serving/prefill_chunk", rid=req.rid,
+                      chunk_start=int(start)) as span:
+            with obs.span("serving/prefill_chunk/prepare", category="phase"):
+                if not self.sched.ensure_blocks(req, start + n_valid):
+                    return False    # pool dry, nothing evictable — wait
+                if not self._make_writable(req, start, start + n_valid):
+                    return False    # a shared block needs a copy the pool
+                    #   can't give
+                chunk = np.zeros((1, C), np.int32)
+                chunk[0, :n_valid] = src[start:start + n_valid]
+                temps, topks, topps, seeds = self._sampling_arrays([req])
+                table = self._table_for([req])
+            rt = obs.reqtrace
+            with self._trace_dispatch(rt, req.trace):
+                with mesh_mod.ambient(self.engine.mesh):
+                    tok, t0, t1 = self._run_program(
+                        obs, "serving/prefill_chunk", self._prefill, table,
+                        chunk, np.asarray(start, np.int32),
                         np.asarray(n_valid, np.int32),
                         temps, topks, topps, seeds, self._base_rng)
-                    tok = np.asarray(tok)   # the fence: chunk really ran
-        if timed:
-            t1 = self.clock()
-            if acct is not None:
-                acct.note_phase("prefill", t1 - t0)
+            if self._serve_acct is not None:
+                self._serve_acct.note_phase("prefill", t1 - t0)
             if rt is not None and req.trace is not None:
                 rt.interval(req.trace, "prefill", t0, t1,
                             kind="prefill_chunk", tokens=int(n_valid),
                             chunk_start=int(start), replica=self.trace_tag)
-        self.prefill_chunks_run += 1
-        self.prefill_tokens_run += int(n_valid)
-        req.prefill_pos += n_valid
-        req.length = req.prefill_pos
-        # newly completed full prompt blocks become shareable prefix cache
-        self.sched.note_prefill_progress(req, start, req.prefill_pos)
-        self.sched.note_service(req, n_valid)
-        if req.prefill_pos == int(src.size):
-            req.state = DECODE
-            # the COW fork point for submit(n=...): siblings share the
-            # freshly prefilled blocks BEFORE the parent can finish (a
-            # max_new_tokens=1 parent releases its refs in _emit below;
-            # the siblings' increfs keep the blocks alive)
-            self._submit_pending_forks(req)
-            if req.resume:
-                # recompute after preemption: the stored pending token is
-                # authoritative (identical under greedy; under temperature
-                # sampling the resampled one may diverge) and was already
-                # streamed — never re-emit
-                req.resume = False
-            else:
-                self._emit(req, int(tok[0]), first=True)
-            if (self.on_prefill_complete is not None
-                    and req.state == DECODE):
-                # still DECODE: a max_new_tokens=1 request already finished
-                # in _emit above and has nothing left to hand off.
-                # tpusync: disable=callback-under-lock — router-bound seam,
-                # not user code; the handoff must see the request frozen at
-                # prefill completion, so it runs under the engine lock
-                self.on_prefill_complete(req)
+            span.annotate(tokens=int(n_valid))   # the chunk ran: a span
+            #   without the count is a chunk the pool could not place
+            self.prefill_chunks_run += 1
+            self.prefill_tokens_run += int(n_valid)
+            req.prefill_pos += n_valid
+            req.length = req.prefill_pos
+            # newly completed full prompt blocks become shareable prefix
+            # cache
+            self.sched.note_prefill_progress(req, start, req.prefill_pos)
+            self.sched.note_service(req, n_valid)
+            if req.prefill_pos == int(src.size):
+                self._finish_prefill(obs, req, int(tok[0]))
         return True
+
+    def _finish_prefill(self, obs, req: Request, token: int) -> None:
+        """The prompt's last chunk ran: the request decodes from here."""
+        req.state = DECODE
+        # the COW fork point for submit(n=...): siblings share the
+        # freshly prefilled blocks BEFORE the parent can finish (a
+        # max_new_tokens=1 parent releases its refs in _emit below;
+        # the siblings' increfs keep the blocks alive)
+        self._submit_pending_forks(req)
+        if req.resume:
+            # recompute after preemption: the stored pending token is
+            # authoritative (identical under greedy; under temperature
+            # sampling the resampled one may diverge) and was already
+            # streamed — never re-emit
+            req.resume = False
+        else:
+            with obs.span("serving/emit", tokens=1) as span:
+                self._emit(req, token, first=True)
+                span.annotate(finished=int(req.done))
+        if (self.on_prefill_complete is not None
+                and req.state == DECODE):
+            # still DECODE: a max_new_tokens=1 request already finished
+            # in _emit above and has nothing left to hand off.
+            # tpusync: disable=callback-under-lock — router-bound seam,
+            # not user code; the handoff must see the request frozen at
+            # prefill completion, so it runs under the engine lock
+            self.on_prefill_complete(req)
 
     # -- parallel-sampling fork (COW) --------------------------------------
     def _submit_pending_forks(self, req: Request) -> None:
@@ -955,15 +1034,12 @@ class ServingEngine:
                 self._forks += 1
             return out
 
-    def _ready_decode_rows(self) -> List[Request]:
+    def _ready_decode_rows(self, dec: List[Request]) -> List[Request]:
         """The decode-readiness discipline shared by the plain and
-        speculative iterations: guarantee the pending token's block for
-        every decoding row (this may evict), then keep only rows that are
-        still DECODE, have block coverage for the incoming write, and
-        whose write block is exclusively owned."""
-        dec = self.sched.decode_requests()
-        if not dec:
-            return []
+        speculative iterations, over ``sched.decode_requests()``: guarantee
+        the pending token's block for every decoding row (this may evict),
+        then keep only rows that are still DECODE, have block coverage for
+        the incoming write, and whose write block is exclusively owned."""
         for r in dec:
             # re-check state INSIDE the loop: an earlier ensure_blocks may
             # have evicted this very request — growing a now-QUEUED request
@@ -985,10 +1061,8 @@ class ServingEngine:
         # a later row's COW may have preempted an earlier accepted row
         return [r for r in ready if r.state == DECODE]
 
-    def _step_decode(self) -> bool:
-        ready = self._ready_decode_rows()
-        if not ready:
-            return False
+    def _decode_operands(self, ready: List[Request]):
+        """The decode program's host arrays, one row per decode row."""
         R = self.config.max_seqs
         bt = np.zeros((R, self.blocks_per_seq), np.int32)
         lengths = np.zeros((R,), np.int32)
@@ -1010,36 +1084,47 @@ class ServingEngine:
             steps[row] = len(r.generated)   # output-token index: the
             #   sampling stream is (engine seed, request seed, index) —
             #   schedule-independent and preemption-stable
+        return bt, lengths, tokens, temps, topks, topps, seeds, steps
+
+    def _step_decode(self) -> bool:
+        dec = self.sched.decode_requests()
+        if not dec:
+            return False
         obs = get_session()
-        rt = obs.reqtrace
-        acct = self._serve_acct
-        timed = acct is not None or rt is not None
-        t0 = self.clock() if timed else 0.0
-        first_trace = (next((r.trace for r in ready
-                             if r.trace is not None), None)
-                       if rt is not None else None)
-        with self._trace_dispatch(rt, first_trace):
-            with mesh_mod.ambient(self.engine.mesh):
-                with obs.span("serving/decode", batch=len(ready)):
-                    nxt, self._arena = self._decode(
-                        self.engine.params, self._arena, bt, lengths,
-                        tokens, temps, topks, topps, seeds, steps,
+        with obs.span("serving/decode",
+                      max_rows=self.config.max_seqs) as span:
+            with obs.span("serving/decode/prepare", category="phase"):
+                ready = self._ready_decode_rows(dec)
+                operands = self._decode_operands(ready) if ready else ()
+            span.annotate(rows=len(ready))
+            if not ready:
+                return False
+            rt = obs.reqtrace
+            acct = self._serve_acct
+            first_trace = (next((r.trace for r in ready
+                                 if r.trace is not None), None)
+                           if rt is not None else None)
+            with self._trace_dispatch(rt, first_trace):
+                with mesh_mod.ambient(self.engine.mesh):
+                    nxt, t0, t1 = self._run_program(
+                        obs, "serving/decode", self._decode, *operands,
                         self._base_rng)
-                    nxt = np.asarray(nxt)  # the iteration's one host sync
-        t1 = self.clock() if timed else 0.0
-        if acct is not None:
-            acct.note_phase("decode", t1 - t0)
-        if rt is not None:
-            for r in ready:
-                if r.trace is not None:
-                    rt.note_decode(r.trace, t0, t1, batch=len(ready),
-                                   replica=self.trace_tag)
-        for r in ready:
-            r.length += 1
-            self.sched.note_service(r, 1)
-            self._emit(r, int(nxt[r.row]))
-        if acct is not None:
-            acct.note_phase("sample_host", self.clock() - t1)
+            if acct is not None:
+                acct.note_phase("decode", t1 - t0)
+            if rt is not None:
+                for r in ready:
+                    if r.trace is not None:
+                        rt.note_decode(r.trace, t0, t1, batch=len(ready),
+                                       replica=self.trace_tag)
+            with obs.span("serving/emit", tokens=len(ready)) as emit:
+                for r in ready:
+                    r.length += 1
+                    self.sched.note_service(r, 1)
+                    self._emit(r, int(nxt[r.row]))
+                if emit.recording:
+                    emit.annotate(finished=sum(r.done for r in ready))
+            if acct is not None:
+                acct.note_phase("sample_host", self.clock() - t1)
         return True
 
     def _step_verify(self) -> bool:
@@ -1051,9 +1136,20 @@ class ServingEngine:
         tokens advance lengths/blocks on the host; rejected draft KV rolls
         back by position (whole blocks past the accepted length return to
         the pool)."""
+        dec = self.sched.decode_requests()
+        if not dec:
+            return False
+        obs = get_session()
+        with obs.span("serving/verify",
+                      max_rows=self.config.max_seqs) as span:
+            return self._verify_rows(obs, span, dec)
+
+    def _verify_rows(self, obs, span, dec: List[Request]) -> bool:
+        """``_step_verify`` inside its ``serving/verify`` span, whose self
+        time is the drafter and the plan."""
         # the guaranteed (pending-token) block may evict via
         # _ready_decode_rows — speculation itself never does
-        ready = self._ready_decode_rows()
+        ready = self._ready_decode_rows(dec)
         if not ready:
             return False
         spec = self.config.speculative
@@ -1122,23 +1218,18 @@ class ServingEngine:
             steps[row] = len(r.generated)   # first output-token index of
             #   this dispatch — position j samples index steps+j, the
             #   exact key the non-speculative path uses
-        obs = get_session()
+        span.annotate(rows=len(plan), tokens=int(n_valid.sum()))
         rt = obs.reqtrace
         acct = self._serve_acct
         first_trace = (next((r.trace for r, _ in plan
                              if r.trace is not None), None)
                        if rt is not None else None)
-        t0 = self.clock()
         with self._trace_dispatch(rt, first_trace):
             with mesh_mod.ambient(self.engine.mesh):
-                with obs.span("serving/verify", batch=len(plan),
-                              tokens=int(n_valid.sum())):
-                    sampled, self._arena = self._verify(
-                        self.engine.params, self._arena, bt, lengths,
-                        tokens, n_valid, temps, topks, topps, seeds, steps,
-                        self._base_rng)
-                    sampled = np.asarray(sampled)  # the iteration's 1 sync
-        t1 = self.clock()
+                sampled, t0, t1 = self._run_program(
+                    obs, "serving/verify", self._verify, bt, lengths,
+                    tokens, n_valid, temps, topks, topps, seeds, steps,
+                    self._base_rng)
         self._spec_verify_s += t1 - t0
         if acct is not None:
             acct.note_phase("verify", t1 - t0)
@@ -1148,31 +1239,36 @@ class ServingEngine:
                     rt.note_decode(r.trace, t0, t1, kind="verify",
                                    batch=len(plan), replica=self.trace_tag)
         self._spec_dispatches += 1
-        for r, prop in plan:
-            x = sampled[r.row]
-            a = 0   # accepted drafts: x[j] (the sample after draft j) must
-            #   CONFIRM draft j — first mismatch emits x[a] as the
-            #   correction, full acceptance emits x[cap] as the bonus
-            while a < prop.size and int(x[a]) == int(prop[a]):
-                a += 1
-            r.spec_proposed += int(prop.size)
-            r.spec_accepted += a
-            self._spec_proposed += int(prop.size)
-            self._spec_accepted += a
-            for t in x[:a + 1]:
-                r.length += 1
-                self.sched.note_service(r, 1)
-                self._emit(r, int(t))
-                self._spec_emitted += 1
-                if r.done:
-                    break   # EOS/budget mid-verify: later samples are
-                    #   beyond the request's end — never emitted
-            if not r.done:
-                # positional rollback: whole blocks past the accepted
-                # length go back to the pool; the drafter rolls its arena
-                # back the same way
-                self.sched.truncate_blocks(r, r.length)
-                self._drafter.commit(r)
+        emitted = self._spec_emitted
+        with obs.span("serving/emit") as emit:
+            for r, prop in plan:
+                x = sampled[r.row]
+                a = 0   # accepted drafts: x[j] (the sample after draft j)
+                #   must CONFIRM draft j — first mismatch emits x[a] as the
+                #   correction, full acceptance emits x[cap] as the bonus
+                while a < prop.size and int(x[a]) == int(prop[a]):
+                    a += 1
+                r.spec_proposed += int(prop.size)
+                r.spec_accepted += a
+                self._spec_proposed += int(prop.size)
+                self._spec_accepted += a
+                for t in x[:a + 1]:
+                    r.length += 1
+                    self.sched.note_service(r, 1)
+                    self._emit(r, int(t))
+                    self._spec_emitted += 1
+                    if r.done:
+                        break   # EOS/budget mid-verify: later samples are
+                        #   beyond the request's end — never emitted
+                if not r.done:
+                    # positional rollback: whole blocks past the accepted
+                    # length go back to the pool; the drafter rolls its arena
+                    # back the same way
+                    self.sched.truncate_blocks(r, r.length)
+                    self._drafter.commit(r)
+            if emit.recording:
+                emit.annotate(tokens=self._spec_emitted - emitted,
+                              finished=sum(r.done for r, _ in plan))
         if acct is not None:
             acct.note_phase("sample_host", self.clock() - t1)
         return True
@@ -1187,12 +1283,15 @@ class ServingEngine:
         # TPOT cover forked samples too
         if first or req.first_token_s is None:
             req.first_token_s = now
+            self._request_span(obs, "serving/request/first_token", req,
+                               ttft_us=int(req.ttft_s * 1e6))
             if obs.enabled:
-                ttft_ms = (now - req.arrival_s) * 1e3
+                ttft_ms = req.ttft_s * 1e3
                 self._ttft_samples.append(ttft_ms)
                 obs.registry.histogram(
                     "serving/ttft_ms",
-                    help="arrival → first streamed token, wall ms").observe(
+                    help="entry to submit() → first streamed token, wall "
+                         "ms").observe(
                         ttft_ms, tenant=req.tenant)
         req.generated.append(token)
         req.pending_token = token
@@ -1372,10 +1471,15 @@ class ServingEngine:
     def _drive(self) -> None:
         while not self._stop.is_set():
             try:
-                if self.in_flight():
+                # the poll takes the engine lock, behind callers in
+                # submit(): that wait, like the one when nothing is in
+                # flight, is the driver outside an iteration
+                with get_session().span("serving/idle"):
+                    busy = self.in_flight()
+                    if not busy:
+                        self._stop.wait(0.002)
+                if busy:
                     self.step()
-                else:
-                    self._stop.wait(0.002)
             except Exception:
                 logger.exception("serving driver step failed")
                 get_session().crash_dump("serving-step-exception")

@@ -82,7 +82,9 @@ class Request:
     tenant: str = "default"
     deadline_s: Optional[float] = None       # absolute (scheduler clock)
     seed: int = 0
-    arrival_s: float = 0.0
+    arrival_s: float = 0.0             # joined the queue: admission order
+    submit_s: Optional[float] = None   # the caller entered submit(), before
+    #   any wait for the engine's lock: where TTFT counts from
     # -- runtime state (scheduler-owned) --
     state: str = QUEUED
     row: Optional[int] = None                # decode-batch row while running
@@ -123,10 +125,17 @@ class Request:
         return self.state in (FINISHED, CANCELLED, DEADLINE_EXCEEDED)
 
     @property
+    def entry_s(self) -> float:
+        """When the request reached the engine: entry to ``submit()`` where
+        the engine stamped it, else its arrival in the queue (requests built
+        directly, recovered or adopted ones)."""
+        return self.submit_s if self.submit_s is not None else self.arrival_s
+
+    @property
     def ttft_s(self) -> Optional[float]:
         if self.first_token_s is None:
             return None
-        return self.first_token_s - self.arrival_s
+        return self.first_token_s - self.entry_s
 
     @property
     def tpot_s(self) -> Optional[float]:
