@@ -170,22 +170,27 @@ def phase_kernels() -> None:
           ops.reference_layer_norm(x, sc, b))
 
     # serving path: paged decode + chunked prefill over a ragged block table
-    # (pools are (NUM_BLOCKS, BLOCK, K*D); pages out of order on purpose)
-    heads, d, bs = 4, 64, 16
-    kp, vp = rnd(12, bs, heads * d), rnd(12, bs, heads * d)
+    # (arenas are (L, NUM_BLOCKS, BLOCK, K*D) and the kernels read one layer
+    # inside them; pages out of order on purpose). The reference reads the
+    # same layer's pool handed over alone, as a 1-layer arena
+    heads, d, bs, layer = 4, 64, 16, 1
+    ka, va = rnd(3, 12, bs, heads * d), rnd(3, 12, bs, heads * d)
+    kp, vp = ka[layer][None], va[layer][None]
     bt = jnp.asarray([[5, 1, 7, 9], [3, 0, 0, 0], [8, 2, 4, 6]], jnp.int32)
     lengths = jnp.asarray([2 * bs + 5, 9, 4 * bs], jnp.int32)
     qd = rnd(3, heads, d)
     close("paged_decode_attention",
-          ops.paged_decode_attention(qd, kp, vp, bt, lengths, interpret=it),
-          ops.reference_paged_attention(qd[:, None], kp, vp, bt,
+          ops.paged_decode_attention(qd, ka, va, layer, bt, lengths,
+                                     interpret=it),
+          ops.reference_paged_attention(qd[:, None], kp, vp, 0, bt,
                                         lengths[:, None] - 1)[:, 0])
     start = jnp.asarray([21, 0, 40], jnp.int32)
     qc = rnd(3, 16, heads, d)
     pos = start[:, None] + jnp.arange(16, dtype=jnp.int32)[None]
     close("paged_prefill_attention",
-          ops.paged_prefill_attention(qc, kp, vp, bt, start, interpret=it),
-          ops.reference_paged_attention(qc, kp, vp, bt, pos))
+          ops.paged_prefill_attention(qc, ka, va, layer, bt, start,
+                                      interpret=it),
+          ops.reference_paged_attention(qc, kp, vp, 0, bt, pos))
 
     # offline generate(): dense decode with ragged alibi key positions
     qf, kc, vc = (rnd(2, 8, 64, dtype=jnp.float32),

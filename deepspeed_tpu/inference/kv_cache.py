@@ -34,8 +34,10 @@ Block 0 is a reserved scratch block: writes of inactive decode rows and
 prompt-chunk padding land there, so the jit program needs no write-masking
 branch. A host-side free list (``serving/paged_kv.BlockAllocator``) owns
 blocks 1.. and hands each sequence a block table ``(MAX_BLOCKS,)`` of
-physical ids; attention reads gather ``k[block_table]`` — a shape-static
-lookup, so one decode program serves any occupancy.
+physical ids; attention reads walk it inside the arena, layer ``l``'s
+pages at ``k[l, block_table]`` — a shape-static lookup, so one decode
+program serves any occupancy, and a layer's pool is never sliced out of the
+arena (``models/transformer.forward``, paged branch).
 
 ``dtype`` is mandatory throughout: a default here let call sites silently
 allocate a bf16 arena for an fp32 (or fp16) engine — the arena dtype must

@@ -832,7 +832,8 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                    block_table: Optional[jax.Array] = None,
                    paged_write_mask: Optional[jax.Array] = None,
                    paged_impl: str = "auto",
-                   paged_chunk: bool = False
+                   paged_chunk: bool = False,
+                   paged_layer: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """One decoder block. ``layer`` holds this layer's (unstacked) params.
     ``cache`` (decode): dict with k/v of shape (B, T_max, K, D) and scalar
@@ -841,15 +842,20 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     attention_layers models (GPT-Neo), which take the windowed jnp
     attention path throughout.
 
-    ``block_table`` switches the cache to PAGED mode (serving layer): the
-    per-layer cache is a shared pool ``{"k","v": (NUM_BLOCKS, BLOCK, K*D)}``
-    and ``block_table`` (B, MAX_BLOCKS) maps each row's logical blocks to
-    physical ids. ``positions`` must then be the (B, S) absolute write
+    ``block_table`` switches the cache to PAGED mode (serving layer):
+    ``cache`` is then the WHOLE arena ``{"k","v": (L, NUM_BLOCKS, BLOCK,
+    K*D)}``, ``paged_layer`` (int32 scalar, the layer scan's index) says
+    which layer's pool this block writes and reads inside it, and the arena
+    comes back as the new cache; ``block_table`` (B, MAX_BLOCKS) maps each
+    row's logical blocks to physical ids. A pool is never sliced out of the
+    arena: a custom call's operand is a buffer of its own, so a slice is a
+    pool-sized copy in and another out (ops/paged_decode_attention.py).
+    ``positions`` must then be the (B, S) absolute write
     positions; ``paged_write_mask`` (B, S) routes masked-off tokens (prompt
     chunk padding) to the scratch block 0 instead of the row's blocks.
     ``paged_impl`` selects the paged READ path: 'auto' (Pallas paged
     kernels when active, GQA-native jnp paged reference otherwise) or
-    'gather' (the dense ``arena[block_table]`` view — the A/B baseline,
+    'gather' (the dense ``arena[layer, block_table]`` view — the A/B baseline,
     and always the path a custom ``attention_impl`` sees). ``paged_chunk``
     asserts the chunked-prefill contract (``positions[b] == start_b +
     arange(S)``), which is what lets S>1 take the paged flash-prefill
@@ -951,13 +957,13 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         # the whole validity story and keys' alibi column bias is exact by
         # construction. Reads walk the table: the Pallas paged kernels
         # (ops/paged_decode_attention.py) DMA only each row's RESIDENT
-        # pages; 'gather' materializes the dense arena[block_table] view —
-        # the PR-6 path, kept as the A/B baseline
+        # pages; 'gather' materializes the dense arena[layer, block_table]
+        # view — the PR-6 path, kept as the A/B baseline
         # (serving.paged_kernel='off') and as what a custom attention_impl
         # sees (it has no block-table operand). Every path is shape-static:
         # one compiled program covers any arena occupancy (the jit-cache
         # analog of vLLM's PagedAttention block tables).
-        BSz = cache["k"].shape[1]
+        BSz = cache["k"].shape[2]
         T_view = block_table.shape[1] * BSz
         pos = positions if positions.ndim == 2 else jnp.broadcast_to(
             positions[None], (B, S))
@@ -968,17 +974,19 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             # chunk padding / inactive decode rows write to scratch block 0
             blk = jnp.where(paged_write_mask, blk, 0)
             off = jnp.where(paged_write_mask, off, 0)
-        # a pool row is one token's K*D lanes (ops/paged_decode_attention.py)
-        ck = cache["k"].at[blk, off].set(
+        # ONE scatter into the 4-D arena, which the layer scan carries: it
+        # updates the carry in place, only the written rows move. An arena
+        # row is one token's K*D lanes (ops/paged_decode_attention.py)
+        ck = cache["k"].at[paged_layer, blk, off].set(
             k.reshape(B, S, K * D).astype(cache["k"].dtype))
-        cv = cache["v"].at[blk, off].set(
+        cv = cache["v"].at[paged_layer, blk, off].set(
             v.reshape(B, S, K * D).astype(cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
         use_dense = (paged_impl == "gather" or cfg.attention_impl is not None
                      or window is not None or cfg.attention_scale is not None)
         if use_dense:
-            kk = ck[block_table].reshape(B, T_view, K, D)
-            vv = cv[block_table].reshape(B, T_view, K, D)
+            kk = ck[paged_layer, block_table].reshape(B, T_view, K, D)
+            vv = cv[paged_layer, block_table].reshape(B, T_view, K, D)
             col = jnp.arange(T_view, dtype=jnp.int32)
             # zero v beyond each row's max resident position — masked
             # columns carry softmax weight 0, and 0 × NaN = NaN: scratch/
@@ -1000,22 +1008,23 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             # paged decode: walks the block table, DMAs resident pages only
             from ..ops.paged_decode_attention import paged_decode_attention
 
-            attn = paged_decode_attention(q[:, 0], ck, cv, block_table,
-                                          pos[:, 0] + 1,
+            attn = paged_decode_attention(q[:, 0], ck, cv, paged_layer,
+                                          block_table, pos[:, 0] + 1,
                                           alibi=alibi)[:, None]
         elif S > 1 and paged_chunk and _kernels_active():
             # chunked prefill reads prior context through the table too
             from ..ops.paged_decode_attention import paged_prefill_attention
 
-            attn = paged_prefill_attention(q, ck, cv, block_table,
-                                           pos[:, 0], alibi=alibi)
+            attn = paged_prefill_attention(q, ck, cv, paged_layer,
+                                           block_table, pos[:, 0],
+                                           alibi=alibi)
         else:
             # GQA-native jnp paged reference (no head expansion, no dense
             # (B,S,T) mask materialization) — CPU fallback + parity oracle
             from ..ops.paged_decode_attention import reference_paged_attention
 
-            attn = reference_paged_attention(q, ck, cv, block_table, pos,
-                                             alibi=alibi)
+            attn = reference_paged_attention(q, ck, cv, paged_layer,
+                                             block_table, pos, alibi=alibi)
     elif cache is not None:
         idx = cache["index"]
         ck = lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
@@ -1333,8 +1342,7 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             h_new, new_cache, aux = _layer_forward(
                 cfg, h, layer, attention_mask, positions, layer_cache,
                 static_prefill=static_prefill, key_positions=key_positions,
-                window=window, block_table=block_table,
-                paged_write_mask=paged_write_mask)
+                window=window)
         if use_pld:
             h_new, aux = pld_gate(cfg, h, h_new, aux, idx, pld_theta)
         return (h_new, aux_acc + aux), new_cache
@@ -1358,35 +1366,33 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                                      unroll=cfg.scan_unroll)
         new_cache = None
     elif block_table is not None:
-        # PAGED: the arena rides the layer scan as CARRY, not xs/ys — loop
-        # carries update in place, so the shared block pool stops
-        # round-tripping through per-iteration input/output buffers. On the
-        # selftest decode program this cut XLA-counted bytes_accessed 33%
-        # and peak HBM 22% vs the xs/ys form (the pool dominates both).
-        # window/PLD/LTD are training- or dense-cache-only features; the
+        # PAGED: the layer scan's CARRY is the arena itself, and the body
+        # hands it down whole with the layer index. _layer_forward scatters
+        # the new rows into it at (idx, blk, off) — in place on the carry —
+        # and the paged kernels address arena[idx, page] where it lies. The
+        # body must never take a layer's pool out (dynamic_index_in_dim) and
+        # put it back: a Pallas call's operand is a buffer of its own, so
+        # XLA then copies the pool out and in around every kernel and
+        # scatter — four 185 MiB copies a layer at OPT-1.3B's serving size,
+        # 54 ms of a 73 ms decode iteration on the v5e (PERF.md, PR 26).
+        # tests/kernels/test_tpu_compile.py holds the compiled programs to
+        # it. window/PLD/LTD are training- or dense-cache-only features; the
         # serving engine rejects sliding-window models, and the dense-view
-        # fallback inside _layer_forward ignores `window` exactly like the
-        # PR-6 paged branch did.
+        # fallback inside _layer_forward ignores `window`.
         def paged_block(carry, layer_and_idx):
-            h, aux_acc, ark, arv = carry
+            h, aux_acc, arena = carry
             layer, idx = layer_and_idx
-            layer_cache = {
-                "k": lax.dynamic_index_in_dim(ark, idx, keepdims=False),
-                "v": lax.dynamic_index_in_dim(arv, idx, keepdims=False)}
-            h_new, new_c, aux = _layer_forward(
-                cfg, h, layer, attention_mask, positions, layer_cache,
-                static_prefill=static_prefill, key_positions=key_positions,
-                window=None, block_table=block_table,
-                paged_write_mask=paged_write_mask, paged_impl=paged_impl,
-                paged_chunk=paged_chunk)
-            ark = lax.dynamic_update_index_in_dim(ark, new_c["k"], idx, 0)
-            arv = lax.dynamic_update_index_in_dim(arv, new_c["v"], idx, 0)
-            return (h_new, aux_acc + aux, ark, arv), None
+            h_new, arena, aux = _layer_forward(
+                cfg, h, layer, attention_mask, positions, arena,
+                block_table=block_table, paged_write_mask=paged_write_mask,
+                paged_impl=paged_impl, paged_chunk=paged_chunk,
+                paged_layer=idx)
+            return (h_new, aux_acc + aux, arena), None
 
-        (x, aux_total, ck_all, cv_all), _ = lax.scan(
-            paged_block, (x, jnp.float32(0.0), cache["k"], cache["v"]),
+        (x, aux_total, new_cache), _ = lax.scan(
+            paged_block,
+            (x, jnp.float32(0.0), {"k": cache["k"], "v": cache["v"]}),
             (params["layers"], jnp.arange(L, dtype=jnp.int32)))
-        new_cache = {"k": ck_all, "v": cv_all}
     else:
         xs = ((params["layers"], cache) if not use_win else
               ((params["layers"], cache), jnp.arange(L, dtype=jnp.float32)))

@@ -25,16 +25,28 @@ arena is LEFT-ALIGNED — the token at absolute position ``p`` sits in block
 coordinate IS its position: causality over true positions is the entire
 validity story and the alibi key bias is exact by construction.
 
-A pool is ``(NUM_BLOCKS, BLOCK, K*D)``: a token's KV heads lie side by side
-in the lane dimension. The TPU lowering takes a block whose last two dims
-are multiples of (8, 128) or the whole array dims, and stores arrays in
-such tiles: a ``(BLOCK, K, D)`` page cannot be blocked one head at a time,
-and at head_dim 64 is padded to twice its size. A ``(BLOCK, K*D)`` page is
-lane-dense as stored, and the kernels slice heads out of it by static lane
-offsets.
+The kernels take the WHOLE arena ``(L, NUM_BLOCKS, BLOCK, K*D)`` and a
+``layer`` index, never one layer's pool. ``layer`` (a traced int32 scalar:
+the model's layer scan hands down its loop index) rides as a third
+scalar-prefetch operand and the k/v index maps put it in front of the page
+id, so a page's DMA starts at ``arena[layer, table[row, page]]`` where the
+arena lies. A custom call needs each operand as a buffer of its own: handed
+``arena[layer]``, XLA materialises that pool (185 MiB at OPT-1.3B's serving
+size) before the call and copies it back after the write, four copies a
+layer that cost more than the attention itself and grow with the arena, not
+with the tokens in it. A caller with a single pool passes ``pool[None]`` and
+layer 0.
+
+A page is ``(BLOCK, K*D)``: a token's KV heads lie side by side in the lane
+dimension. The TPU lowering takes a block whose last two dims are multiples
+of (8, 128) or the whole array dims, and stores arrays in such tiles: a
+``(BLOCK, K, D)`` page cannot be blocked one head at a time, and at head_dim
+64 is padded to twice its size. A ``(BLOCK, K*D)`` page is lane-dense as
+stored, and the kernels slice heads out of it by static lane offsets.
 
 ``reference_paged_attention`` is the pure-jnp oracle and CPU fallback:
-GQA-native over the gathered view (no head expansion, no (B,S,T) mask
+GQA-native over the view gathered straight from the arena
+(``arena[layer, block_table]``; no head expansion, no (B,S,T) mask
 materialization) — also measurably leaner than the PR-6 gather +
 ``dot_product_attention`` path that it replaces.
 """
@@ -69,14 +81,20 @@ def _check_page_fits(block_size: int, width: int, dtype) -> None:
             "shard KV heads (tensor parallelism)")
 
 
-def _kv_heads(pool: jax.Array, n_heads: int, head_dim: int) -> int:
-    width = pool.shape[-1]
-    if pool.ndim != 3 or width % head_dim != 0 \
+def _kv_heads(arena: jax.Array, n_heads: int, head_dim: int) -> int:
+    width = arena.shape[-1]
+    if arena.ndim != 4 or width % head_dim != 0 \
             or n_heads % (width // head_dim) != 0:
         raise ValueError(
-            f"paged pool must be (NUM_BLOCKS, BLOCK, K*D) with K dividing "
-            f"n_heads {n_heads} at head_dim {head_dim}, got {pool.shape}")
+            f"paged arena must be (L, NUM_BLOCKS, BLOCK, K*D) with K "
+            f"dividing n_heads {n_heads} at head_dim {head_dim}, got "
+            f"{arena.shape} (a single pool goes in as pool[None], layer 0)")
     return width // head_dim
+
+
+def _layer_operand(layer) -> jax.Array:
+    """``layer`` as the (1,) int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 # ---------------------------------------------------------------------------
@@ -84,8 +102,8 @@ def _kv_heads(pool: jax.Array, n_heads: int, head_dim: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
-                   acc, m_scr, l_scr, *, scale: float, bs: int,
+def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, alibi_ref,
+                   o_ref, acc, m_scr, l_scr, *, scale: float, bs: int,
                    n_heads: int, kv_heads: int, has_alibi: bool):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -142,44 +160,46 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
         o_ref[0] = (acc[:] / safe).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, block_table: jax.Array,
-                           lengths: jax.Array,
+def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
+                           v_arena: jax.Array, layer,
+                           block_table: jax.Array, lengths: jax.Array,
                            alibi: Optional[jax.Array] = None,
                            scale: Optional[float] = None,
                            interpret: bool = False) -> jax.Array:
-    """q (R, N, D) — one new token per row; k/v_pool (NUM_BLOCKS, BLOCK,
-    K*D) — the shared arena; block_table (R, MAXB) int32 physical page ids
-    (unfilled entries 0 = scratch); lengths (R,) int32 — valid keys per row
-    INCLUDING the just-written token (0 ⇒ inactive row, output zeros).
-    Returns (R, N, D). Reads only each row's resident pages."""
+    """q (R, N, D) — one new token per row; k/v_arena (L, NUM_BLOCKS, BLOCK,
+    K*D) — the whole shared arena; layer — int32 scalar (may be traced),
+    the layer whose pool is read; block_table (R, MAXB) int32 physical page
+    ids (unfilled entries 0 = scratch); lengths (R,) int32 — valid keys per
+    row INCLUDING the just-written token (0 ⇒ inactive row, output zeros).
+    Returns (R, N, D). Reads only each row's resident pages of that layer."""
     R, N, D = q.shape
-    K = _kv_heads(k_pool, N, D)
-    BS = k_pool.shape[1]
+    K = _kv_heads(k_arena, N, D)
+    BS = k_arena.shape[2]
     MAXB = block_table.shape[1]
-    _check_page_fits(BS, K * D, k_pool.dtype)
+    _check_page_fits(BS, K * D, k_arena.dtype)
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
     alibi_arr = (alibi.astype(jnp.float32).reshape(1, N) if has_alibi
                  else jnp.zeros((1, N), jnp.float32))
 
-    def _page(b, j, bt_ref, len_ref):
+    def _page(b, j, bt_ref, len_ref, layer_ref):
         # clamp to the row's last resident page: trailing grid steps
         # re-request the same block index, which the pipeline recognizes
         # and skips the DMA — only resident pages move
         last = jnp.maximum((len_ref[b] + BS - 1) // BS - 1, 0)
-        return (bt_ref[b, jnp.minimum(j, last)], 0, 0)
+        return (layer_ref[0], bt_ref[b, jnp.minimum(j, last)], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(R, MAXB),
         in_specs=[
-            pl.BlockSpec((1, N, D), lambda b, j, bt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, BS, K * D), _page),
-            pl.BlockSpec((1, BS, K * D), _page),
-            pl.BlockSpec((1, N), lambda b, j, bt, ln: (0, 0)),
+            pl.BlockSpec((1, N, D), lambda b, j, *_: (b, 0, 0)),
+            # the layer dim is squeezed: the kernel sees (1, BS, K*D) pages
+            pl.BlockSpec((None, 1, BS, K * D), _page),
+            pl.BlockSpec((None, 1, BS, K * D), _page),
+            pl.BlockSpec((1, N), lambda b, j, *_: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, N, D), lambda b, j, bt, ln: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, N, D), lambda b, j, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((N, D), jnp.float32),
             pltpu.VMEM((N, LANES), jnp.float32),
@@ -197,7 +217,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         name="paged_decode_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool, alibi_arr)
+      _layer_operand(layer), q, k_arena, v_arena, alibi_arr)
 
 
 # ---------------------------------------------------------------------------
@@ -215,9 +235,9 @@ def _heads_per_step(kv_heads: int, head_dim: int) -> int:
     return kv_heads
 
 
-def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
-                    acc, m_scr, l_scr, *, scale: float, bs: int, C: int,
-                    has_alibi: bool):
+def _prefill_kernel(bt_ref, start_ref, layer_ref, q_ref, k_ref, v_ref,
+                    alibi_ref, o_ref, acc, m_scr, l_scr, *, scale: float,
+                    bs: int, C: int, has_alibi: bool):
     b = pl.program_id(0)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
@@ -264,27 +284,29 @@ def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, alibi_ref, o_ref,
         o_ref[0] = (acc[:] / safe).astype(o_ref.dtype)
 
 
-def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
-                            v_pool: jax.Array, block_table: jax.Array,
-                            start: jax.Array,
+def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
+                            v_arena: jax.Array, layer,
+                            block_table: jax.Array, start: jax.Array,
                             alibi: Optional[jax.Array] = None,
                             scale: Optional[float] = None,
                             interpret: bool = False) -> jax.Array:
     """Chunked-prefill attention through the block table: q (B, C, N, D) —
     C contiguous queries per row at absolute positions ``start[b] + s``
     (the serving ``prefill_chunk`` contract; the chunk's own keys must
-    already be scatter-written into the pool); pools (NUM_BLOCKS, BLOCK,
-    K*D). Returns (B, C, N, D). Grid (B, K/HP, MAXB): each group of HP KV
-    heads (``_heads_per_step``) flash-accumulates its G*C query rows per
-    head, page by page; pages past ``start + C`` never move."""
+    already be scatter-written into the arena); arenas (L, NUM_BLOCKS,
+    BLOCK, K*D) and the int32 scalar ``layer`` to read, as in
+    ``paged_decode_attention``. Returns (B, C, N, D). Grid (B, K/HP, MAXB):
+    each group of HP KV heads (``_heads_per_step``) flash-accumulates its
+    G*C query rows per head, page by page; pages past ``start + C`` never
+    move."""
     B, C, N, D = q.shape
-    K = _kv_heads(k_pool, N, D)
-    BS = k_pool.shape[1]
+    K = _kv_heads(k_arena, N, D)
+    BS = k_arena.shape[2]
     MAXB = block_table.shape[1]
     G = N // K
     GC = G * C
     HP = _heads_per_step(K, D)
-    _check_page_fits(BS, HP * D, k_pool.dtype)
+    _check_page_fits(BS, HP * D, k_arena.dtype)
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
     # (B, C, N, D) -> (B, K, G*C, D): head-major rows grouped by KV head so
@@ -301,21 +323,21 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     else:
         alibi_arr = jnp.zeros((K // HP, HP, GC), jnp.float32)
 
-    def _page(b, kb, j, bt_ref, start_ref):
+    def _page(b, kb, j, bt_ref, start_ref, layer_ref):
         npages = jnp.maximum((start_ref[b] + C + BS - 1) // BS, 1)
-        return (bt_ref[b, jnp.minimum(j, npages - 1)], 0, kb)
+        return (layer_ref[0], bt_ref[b, jnp.minimum(j, npages - 1)], 0, kb)
 
-    def _heads(b, kb, j, bt, st):
+    def _heads(b, kb, j, *_):
         return (b, kb, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, K // HP, MAXB),
         in_specs=[
             pl.BlockSpec((1, HP, GC, D), _heads),
-            pl.BlockSpec((1, BS, HP * D), _page),
-            pl.BlockSpec((1, BS, HP * D), _page),
-            pl.BlockSpec((1, HP, GC), lambda b, kb, j, bt, st: (kb, 0, 0)),
+            pl.BlockSpec((None, 1, BS, HP * D), _page),
+            pl.BlockSpec((None, 1, BS, HP * D), _page),
+            pl.BlockSpec((1, HP, GC), lambda b, kb, j, *_: (kb, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, HP, GC, D), _heads),
         scratch_shapes=[
@@ -335,7 +357,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         name="paged_prefill_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
-      qk, k_pool, v_pool, alibi_arr)
+      _layer_operand(layer), qk, k_arena, v_arena, alibi_arr)
     return out.reshape(B, K, G, C, D).transpose(0, 3, 1, 2, 4).reshape(
         B, C, N, D)
 
@@ -345,25 +367,27 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def reference_paged_attention(q: jax.Array, k_pool: jax.Array,
-                              v_pool: jax.Array, block_table: jax.Array,
-                              positions: jax.Array,
+def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
+                              v_arena: jax.Array, layer,
+                              block_table: jax.Array, positions: jax.Array,
                               alibi: Optional[jax.Array] = None,
                               scale: Optional[float] = None) -> jax.Array:
     """GQA-native jnp paged attention — parity oracle for both kernels and
     the CPU serving fallback. q (B, S, N, D); positions (B, S) absolute
     query positions (decode: the row's length-1; negative ⇒ row inactive,
-    output zeros); pools (NUM_BLOCKS, BLOCK, K*D); mask is causality over
-    true positions (left-aligned layout: gathered column == position)."""
+    output zeros); arenas (L, NUM_BLOCKS, BLOCK, K*D) and the ``layer`` to
+    read, gathered as ``arena[layer, block_table]`` — no pool-sized
+    intermediate; mask is causality over true positions (left-aligned
+    layout: gathered column == position)."""
     B, S, N, D = q.shape
-    K = _kv_heads(k_pool, N, D)
-    BS = k_pool.shape[1]
+    K = _kv_heads(k_arena, N, D)
+    BS = k_arena.shape[2]
     MAXB = block_table.shape[1]
     T = MAXB * BS
     G = N // K
     scale = scale if scale is not None else D ** -0.5
-    kk = k_pool[block_table].reshape(B, T, K, D)
-    vv = v_pool[block_table].reshape(B, T, K, D)
+    kk = k_arena[layer, block_table].reshape(B, T, K, D)
+    vv = v_arena[layer, block_table].reshape(B, T, K, D)
     # zero v beyond each row's max resident position: masked columns get
     # softmax weight 0, but 0 × NaN = NaN — scratch/recycled pages may
     # carry nonfinite residue (e.g. KV written under briefly-poisoned

@@ -15,8 +15,10 @@ pool of fixed-size **blocks** (vLLM's PagedAttention, Kwon et al. SOSP '23):
   (the jit-cache analog of the reference's CUDA-graph discipline). The
   attention read walks the block table: on TPU the Pallas paged kernels
   (``ops/paged_decode_attention.py``) DMA only each row's RESIDENT pages;
-  ``paged_impl='gather'`` keeps the dense ``arena[block_table]`` view as
-  the A/B baseline (``serving.paged_kernel='off'``).
+  ``paged_impl='gather'`` keeps the dense ``arena[layer, block_table]``
+  view as the A/B baseline (``serving.paged_kernel='off'``). Every path
+  addresses a layer's pool inside the ``(L, NUM_BLOCKS, BLOCK, K*D)``
+  arena; none slices it out.
 * ``PrefixCache`` + refcounted ``BlockAllocator`` + ``build_cow_program``
   — prefix sharing: full prompt blocks are content-hash cached, a new
   request whose prompt prefix is cached maps those blocks into its table

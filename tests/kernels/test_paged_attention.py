@@ -1,19 +1,30 @@
 """Paged-attention kernel parity (interpret mode on CPU).
 
 The serving acceptance story rests on three read paths producing the same
-attention: the dense ``arena[block_table]`` gather view (PR-6 baseline,
-``paged_impl='gather'``), the GQA-native jnp paged reference (CPU serving
-fallback), and the Pallas paged kernels (TPU; interpret-mode here). Every
-test pins two of them against each other across ragged occupancy, GQA and
-alibi — the greedy bit-exactness smoke in tests/unit/test_serving.py then
-covers the end-to-end program.
+attention: the dense ``arena[layer, block_table]`` gather view (PR-6
+baseline, ``paged_impl='gather'``), the GQA-native jnp paged reference (CPU
+serving fallback), and the Pallas paged kernels (TPU; interpret-mode here).
+Every test pins two of them against each other across ragged occupancy, GQA
+and alibi — the greedy bit-exactness smoke in tests/unit/test_serving.py
+then covers the end-to-end program.
+
+All three take the whole arena ``(L, NUM_BLOCKS, BLOCK, K*D)`` and a layer
+index. The kernels are held, at every layer of a 3-layer arena, to the
+reference on that layer's pool alone (``arena[layer][None]``, layer 0), and
+the reference to a dense view sliced by hand — so neither side's layer
+addressing is checked against itself.
 """
+
+import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models.presets import transformer_config
 from deepspeed_tpu.models.transformer import (alibi_slopes,
                                               dot_product_attention)
 from deepspeed_tpu.ops import (decode_attention, paged_decode_attention,
@@ -22,13 +33,21 @@ from deepspeed_tpu.ops import (decode_attention, paged_decode_attention,
                                reference_paged_attention)
 
 INTERPRET = True
+LAYERS = (0, 1, 2)      # first, middle, last of the 3-layer arena
 
 
-def _pool(nb=9, bs=16, k=2, d=32, seed=0, dtype=jnp.float32):
-    """k/v pools in the arena layout: (NUM_BLOCKS, BLOCK, K*D)."""
+def _arena(nb=9, bs=16, k=2, d=32, seed=0, dtype=jnp.float32):
+    """k/v arenas (L, NUM_BLOCKS, BLOCK, K*D); every layer's pool differs."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 2)
-    return (jax.random.normal(ks[0], (nb, bs, k * d), dtype),
-            jax.random.normal(ks[1], (nb, bs, k * d), dtype))
+    shape = (len(LAYERS), nb, bs, k * d)
+    return (jax.random.normal(ks[0], shape, dtype),
+            jax.random.normal(ks[1], shape, dtype))
+
+
+def _pool_reference(q, ka, va, layer, *args, **kwargs):
+    """The reference on ``layer``'s pool alone, as a 1-layer arena."""
+    return reference_paged_attention(q, ka[layer][None], va[layer][None], 0,
+                                     *args, **kwargs)
 
 
 def _ragged_tables(bs=16, maxb=4):
@@ -42,58 +61,64 @@ def _ragged_tables(bs=16, maxb=4):
     return jnp.asarray(bt), jnp.asarray(lengths)
 
 
-def _dense_view(pool, bt, d=32):
+def _dense_view(arena, layer, bt, d=32):
+    pool = arena[layer]
     nb, bs, kd = pool.shape
     b, maxb = bt.shape
     return pool[bt].reshape(b, maxb * bs, kd // d, d)
 
 
 class TestPagedDecodeKernel:
+    @pytest.mark.parametrize("layer", LAYERS)
     @pytest.mark.parametrize("n,k", [(4, 4), (4, 2), (8, 2)])
-    def test_matches_reference_ragged_gqa(self, n, k):
-        kp, vp = _pool(k=k)
+    def test_matches_reference_ragged_gqa(self, n, k, layer):
+        ka, va = _arena(k=k)
         bt, lengths = _ragged_tables()
         q = jax.random.normal(jax.random.PRNGKey(3), (3, n, 32))
-        out = paged_decode_attention(q, kp, vp, bt, lengths,
+        out = paged_decode_attention(q, ka, va, layer, bt, lengths,
                                      interpret=INTERPRET)
-        ref = reference_paged_attention(q[:, None], kp, vp, bt,
-                                        lengths[:, None] - 1)[:, 0]
+        ref = _pool_reference(q[:, None], ka, va, layer, bt,
+                              lengths[:, None] - 1)[:, 0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    def test_alibi_uses_true_positions(self):
-        kp, vp = _pool(k=2)
+    @pytest.mark.parametrize("layer", LAYERS)
+    def test_alibi_uses_true_positions(self, layer):
+        ka, va = _arena(k=2)
         bt, lengths = _ragged_tables()
         n = 4
         q = jax.random.normal(jax.random.PRNGKey(4), (3, n, 32))
         al = alibi_slopes(n)
-        out = paged_decode_attention(q, kp, vp, bt, lengths, alibi=al,
+        out = paged_decode_attention(q, ka, va, layer, bt, lengths, alibi=al,
                                      interpret=INTERPRET)
-        ref = reference_paged_attention(q[:, None], kp, vp, bt,
-                                        lengths[:, None] - 1, alibi=al)[:, 0]
+        ref = _pool_reference(q[:, None], ka, va, layer, bt,
+                              lengths[:, None] - 1, alibi=al)[:, 0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
     def test_inactive_row_outputs_zero(self):
-        kp, vp = _pool()
+        ka, va = _arena()
         bt, lengths = _ragged_tables()
         lengths = lengths.at[1].set(0)          # inactive decode row
         q = jax.random.normal(jax.random.PRNGKey(5), (3, 4, 32))
-        out = paged_decode_attention(q, kp, vp, bt, lengths,
+        out = paged_decode_attention(q, ka, va, 1, bt, lengths,
                                      interpret=INTERPRET)
         assert bool(jnp.all(out[1] == 0))
 
-    def test_reference_matches_dense_gather_path(self):
+    @pytest.mark.parametrize("layer", LAYERS)
+    def test_reference_matches_dense_gather_path(self, layer):
         """The jnp paged reference (CPU serving fallback) computes the
         same attention as the PR-6 gather + dot_product_attention path —
-        what 'paged_kernel=off' A/Bs against."""
-        kp, vp = _pool(k=2)
+        what 'paged_kernel=off' A/Bs against — on the layer it is given
+        (here as a traced scalar, as the layer scan gives it)."""
+        ka, va = _arena(k=2)
         bt, lengths = _ragged_tables()
         n = 4
         q1 = jax.random.normal(jax.random.PRNGKey(6), (3, 1, n, 32))
         pos = lengths[:, None] - 1
-        ref = reference_paged_attention(q1, kp, vp, bt, pos)
-        kk, vv = _dense_view(kp, bt), _dense_view(vp, bt)
+        ref = jax.jit(reference_paged_attention)(
+            q1, ka, va, jnp.int32(layer), bt, pos)
+        kk, vv = _dense_view(ka, layer, bt), _dense_view(va, layer, bt)
         col = jnp.arange(kk.shape[1], dtype=jnp.int32)
         full = (col[None, None, :] <= pos[:, :, None]).astype(jnp.int32)
         want = dot_product_attention(q1, kk, vv, full, causal=False)
@@ -104,50 +129,158 @@ class TestPagedDecodeKernel:
 class TestPagedPrefillKernel:
     # heads per grid step: 4 (4x32 lanes), the whole page (2x32), 2 (64-wide
     # heads pair up), 1 (128-wide heads)
+    @pytest.mark.parametrize("layer", LAYERS)
     @pytest.mark.parametrize("n,k,d", [(4, 4, 32), (8, 2, 32), (4, 4, 64),
                                        (4, 2, 128)])
-    def test_chunk_matches_reference(self, n, k, d):
-        kp, vp = _pool(k=k, d=d)
+    def test_chunk_matches_reference(self, n, k, d, layer):
+        ka, va = _arena(k=k, d=d)
         bt = jnp.asarray(np.array([[5, 1, 7, 0], [3, 8, 0, 0]], np.int32))
         start = jnp.asarray(np.array([21, 0], np.int32))
         C = 16
         q = jax.random.normal(jax.random.PRNGKey(7), (2, C, n, d))
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
-        out = paged_prefill_attention(q, kp, vp, bt, start,
+        out = paged_prefill_attention(q, ka, va, layer, bt, start,
                                       interpret=INTERPRET)
-        ref = reference_paged_attention(q, kp, vp, bt, pos)
+        ref = _pool_reference(q, ka, va, layer, bt, pos)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    def test_chunk_alibi(self):
-        kp, vp = _pool(k=2)
+    @pytest.mark.parametrize("layer", LAYERS)
+    def test_chunk_alibi(self, layer):
+        ka, va = _arena(k=2)
         bt = jnp.asarray(np.array([[5, 1, 7, 0]], np.int32))
         start = jnp.asarray(np.array([17], np.int32))
         n, C = 4, 16
         q = jax.random.normal(jax.random.PRNGKey(8), (1, C, n, 32))
         al = alibi_slopes(n)
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
-        out = paged_prefill_attention(q, kp, vp, bt, start, alibi=al,
+        out = paged_prefill_attention(q, ka, va, layer, bt, start, alibi=al,
                                       interpret=INTERPRET)
-        ref = reference_paged_attention(q, kp, vp, bt, pos, alibi=al)
+        ref = _pool_reference(q, ka, va, layer, bt, pos, alibi=al)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    def test_chunk_matches_dense_gather_path(self):
-        kp, vp = _pool(k=2)
+    @pytest.mark.parametrize("layer", LAYERS)
+    def test_chunk_matches_dense_gather_path(self, layer):
+        ka, va = _arena(k=2)
         bt = jnp.asarray(np.array([[5, 1, 7, 0]], np.int32))
         start = jnp.asarray(np.array([21], np.int32))
         n, C = 4, 16
         q = jax.random.normal(jax.random.PRNGKey(9), (1, C, n, 32))
         pos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
-        out = paged_prefill_attention(q, kp, vp, bt, start,
+        out = paged_prefill_attention(q, ka, va, layer, bt, start,
                                       interpret=INTERPRET)
-        kk, vv = _dense_view(kp, bt), _dense_view(vp, bt)
+        kk, vv = _dense_view(ka, layer, bt), _dense_view(va, layer, bt)
         col = jnp.arange(kk.shape[1], dtype=jnp.int32)
         full = (col[None, None, :] <= pos[:, :, None]).astype(jnp.int32)
         want = dot_product_attention(q, kk, vv, full, causal=False)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+
+class TestPagedForwardWritesInPlace:
+    """A paged ``forward`` step over a 3-layer arena full of other
+    sequences' rows: the arena goes in whole and comes back with exactly the
+    rows (layer, blk, off) of this step rewritten, and the three read paths
+    see the same thing there."""
+
+    BS, NB = 4, 10
+
+    @pytest.fixture(scope="class")
+    def model(self):
+        cfg = transformer_config("opt-125m", dtype=jnp.float32,
+                                 hidden_size=64, num_layers=len(LAYERS),
+                                 num_heads=4, vocab_size=96, max_seq_len=32)
+        return cfg, T.init_params(jax.random.PRNGKey(0), cfg)
+
+    @pytest.fixture
+    def interpreted_kernels(self, monkeypatch):
+        """The model's kernel branch, run by the Pallas interpreter."""
+        monkeypatch.setattr(T, "_kernels_active", lambda: True)
+        for module, names in (("paged_decode_attention",
+                               ("paged_decode_attention",
+                                "paged_prefill_attention")),
+                              ("normalization", ("fused_layer_norm",))):
+            mod = importlib.import_module(f"deepspeed_tpu.ops.{module}")
+            for name in names:
+                monkeypatch.setattr(mod, name, functools.partial(
+                    getattr(mod, name), interpret=True))
+
+    def _step(self, step):
+        """(ids, positions, block_table, write_mask, written (blk, off))."""
+        if step == "decode":
+            # rows at lengths 5, 0 (inactive: table of zeros) and 11
+            bt = np.array([[7, 2, 0], [0, 0, 0], [4, 9, 1]], np.int32)
+            lengths = np.array([5, 0, 11], np.int32)
+            ids = np.array([[3], [0], [17]], np.int32)
+            written = {(2, 1), (0, 0), (1, 3)}
+            return ids, lengths[:, None], bt, None, written
+        # one prompt's second chunk: 6 slots from position 4, 5 of them real
+        bt = np.array([[5, 8, 3]], np.int32)
+        ids = np.arange(11, 17, dtype=np.int32)[None]
+        mask = (np.arange(6) < 5)[None]
+        pos = np.where(mask, 4 + np.arange(6)[None], -1).astype(np.int32)
+        written = {(8, 0), (8, 1), (8, 2), (8, 3), (3, 0), (0, 0)}
+        return ids, pos, bt, mask, written
+
+    def _forward(self, model, step, paged_impl):
+        cfg, params = model
+        ids, pos, bt, mask, _ = self._step(step)
+        ks = jax.random.split(jax.random.PRNGKey(1), 2)
+        shape = (len(LAYERS), self.NB, self.BS,
+                 cfg.num_kv_heads * cfg.head_dim)
+        arena = {"k": jax.random.normal(ks[0], shape, jnp.float32),
+                 "v": jax.random.normal(ks[1], shape, jnp.float32)}
+        # under jit, as the serving programs run it: the layer index is
+        # traced and the arena is the scan's carry
+        fwd = jax.jit(functools.partial(
+            T.forward, cfg=cfg, paged_impl=paged_impl,
+            paged_chunk=step == "chunk"))
+        logits, new, _ = fwd(
+            params, jnp.asarray(ids), cache=arena, positions=jnp.asarray(pos),
+            block_table=jnp.asarray(bt),
+            paged_write_mask=None if mask is None else jnp.asarray(mask))
+        return arena, new, np.asarray(logits)
+
+    @pytest.mark.parametrize("step", ["decode", "chunk"])
+    def test_only_the_written_rows_change_in_every_layer(self, model, step):
+        arena, new, _ = self._forward(model, step, "auto")
+        written = self._step(step)[-1]
+        touched = np.zeros((len(LAYERS), self.NB, self.BS), bool)
+        for blk, off in written:
+            touched[:, blk, off] = True
+        for side in ("k", "v"):
+            before, after = np.asarray(arena[side]), np.asarray(new[side])
+            assert after.shape == before.shape
+            np.testing.assert_array_equal(after[~touched], before[~touched])
+            changed = (after != before).any(axis=-1)
+            np.testing.assert_array_equal(changed, touched)
+            # each layer wrote its own keys: no layer's rows equal another's
+            for blk, off in written - {(0, 0)}:
+                assert not np.array_equal(after[0, blk, off],
+                                          after[-1, blk, off])
+
+    @pytest.mark.parametrize("step", ["decode", "chunk"])
+    def test_gather_reference_and_kernels_agree(self, model, step,
+                                                interpreted_kernels,
+                                                monkeypatch):
+        _, new_k, logits_k = self._forward(model, step, "auto")
+        monkeypatch.setattr(T, "_kernels_active", lambda: False)
+        _, new_r, logits_r = self._forward(model, step, "auto")
+        _, new_g, logits_g = self._forward(model, step, "gather")
+        ids, pos, *_ = self._step(step)
+        live = np.asarray(pos) >= 0            # pad logits are never read
+        for other_new, other in ((new_r, logits_r), (new_g, logits_g)):
+            np.testing.assert_allclose(logits_k[live], other[live],
+                                       atol=2e-4, rtol=2e-4)
+            # block 0 is scratch: a pad query's output, and so the next
+            # layer's pad keys, differ by path and are never read
+            for side in ("k", "v"):
+                np.testing.assert_allclose(np.asarray(new_k[side])[:, 1:],
+                                           np.asarray(other_new[side])[:, 1:],
+                                           atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(logits_r[live].argmax(-1),
+                                      logits_k[live].argmax(-1))
 
 
 class TestDecodeAttentionUnalignedCache:

@@ -9,6 +9,7 @@ parity tests' job.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,17 +70,17 @@ def _layer_norm(rows, t, e, grad):
 
 
 def _paged_decode(rows, heads, d, block, maxb):
-    pool = ((1024, block, heads * d), BF16)
+    arena = ((3, 1024, block, heads * d), BF16)    # layer: a traced scalar
     return (paged_decode_attention,
-            [((rows, heads, d), BF16), pool, pool, ((rows, maxb), I32),
-             ((rows,), I32)])
+            [((rows, heads, d), BF16), arena, arena, ((), I32),
+             ((rows, maxb), I32), ((rows,), I32)])
 
 
 def _paged_prefill(chunk, heads, d, block, maxb):
-    pool = ((1024, block, heads * d), BF16)
+    arena = ((3, 1024, block, heads * d), BF16)
     return (paged_prefill_attention,
-            [((1, chunk, heads, d), BF16), pool, pool, ((1, maxb), I32),
-             ((1,), I32)])
+            [((1, chunk, heads, d), BF16), arena, arena, ((), I32),
+             ((1, maxb), I32), ((1,), I32)])
 
 
 def _dense_decode(b, t, heads, d):
@@ -116,3 +117,104 @@ def test_kernel_compiles_for_v5e(v5e, case):
     compiled = jax.jit(fn).lower(*args).compile()
     if "layernorm-bwd" not in case:   # the norm's backward is plain jnp
         assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the serving programs at the benchmark's size: a layer's pool is addressed
+# inside the arena, never copied out of it
+# ---------------------------------------------------------------------------
+
+# opt-1.3b as benchmarks/ serves it, cut to 3 layers: arena
+# bf16[3, 2957, 16, 2048], one layer's pool 185 MiB
+LAYERS, NUM_BLOCKS, BLOCK, ROWS, MAXB, CHUNK = 3, 2957, 16, 16, 128, 256
+POOL_BYTES = NUM_BLOCKS * BLOCK * 2048 * 2
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\) -> .*\{$")
+_RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+
+
+def _serving_program(kind, v5e, monkeypatch, paged_impl="auto"):
+    """``build_decode_program`` / ``build_prefill_program`` lowered for the
+    described chip on its kernel path (``jax.default_backend()`` is the CPU
+    here, so ``_kernels_active`` is steered)."""
+    from deepspeed_tpu.inference.kv_cache import paged_cache_shape_struct
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.presets import transformer_config
+    from deepspeed_tpu.serving import paged_kv
+
+    monkeypatch.setattr(T, "_kernels_active", lambda: True)
+    cfg = transformer_config("opt-1.3b", dtype=BF16, num_layers=LAYERS)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    arena = on_chip(paged_cache_shape_struct(cfg, NUM_BLOCKS, BLOCK, BF16))
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    if kind == "decode":
+        r = ROWS
+        return paged_kv.build_decode_program(cfg, paged_impl).lower(
+            params, arena, arg((r, MAXB), I32), arg((r,), I32),
+            arg((r,), I32), arg((r,), F32), arg((r,), I32), arg((r,), F32),
+            arg((r,), I32), arg((r,), I32), key)
+    return paged_kv.build_prefill_program(cfg, paged_impl).lower(
+        params, arena, arg((1, MAXB), I32), arg((1, CHUNK), I32),
+        arg((), I32), arg((), I32), arg((1,), F32), arg((1,), I32),
+        arg((1,), F32), arg((1,), I32), key)
+
+
+@pytest.mark.parametrize("kind,paged_impl", [
+    ("decode", "auto"), ("prefill", "auto"), ("decode", "gather")])
+def test_serving_program_never_copies_a_pool(v5e, monkeypatch, kind,
+                                             paged_impl):
+    """No CPU test can see a pool copy: the numbers are the same with it.
+    The optimised HLO for the chip shows it, and so does the temporary
+    memory (three pools at the parent of PR 26, a few MiB since). The
+    'gather' read path builds its dense view, 0.7 of a pool a side at this
+    size, but gathers it from the arena: no pool either."""
+    compiled = _serving_program(kind, v5e, monkeypatch, paged_impl).compile()
+    pool = f"bf16[{NUM_BLOCKS},{BLOCK},2048]"
+    arena = f"bf16[{LAYERS},{NUM_BLOCKS},{BLOCK},2048]"
+    kernel = f"paged_{kind}_attention"
+    text = compiled.as_text()
+    # the op at the root of each fused computation
+    roots, current = {}, None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = head.group(1)
+        m = _RESULT.match(line)
+        if m and line.lstrip().startswith("ROOT"):
+            roots[current] = m.group(3)
+    calls, offenders = 0, []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m is None:
+            continue
+        _, result, op = m.groups()
+        if op == "custom-call" and kernel in line:
+            calls += 1
+            # the kernel's k and v operands are the arena, not a pool
+            operands = line.split("operand_layout_constraints=", 1)[1]
+            assert operands.count(arena) == 2 and pool not in operands, line
+        if pool not in result and arena not in result:
+            continue
+        # what may have the arena's shape: the program's and the loop's
+        # operands and results handed along, and the in-place scatter
+        if op in ("parameter", "get-tuple-element", "tuple", "while",
+                  "scatter"):
+            continue
+        if op == "fusion" and roots.get(
+                re.search(r"calls=%([\w.\-]+)", line).group(1)) == "scatter":
+            continue
+        offenders.append(line.strip()[:200])
+    assert not offenders, "\n".join(offenders)
+    if paged_impl == "auto":
+        assert calls >= 1, f"no custom call named {kernel}"
+        assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES
