@@ -22,6 +22,7 @@ traffic, which also compiles the cell's two programs: part of set-up).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import threading
 import time
@@ -30,7 +31,8 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from . import stats, traffic as traffic_mod
-from .program import build_model, program_seed, reference_module
+from .program import (build_model, program_seed, reference_args,
+                      reference_module)
 
 
 # some tens of decode iterations, and as a rule a prefill chunk or two
@@ -299,24 +301,30 @@ def run(cell, seed: int, seconds: float, tracer, devs, counter,
     # one served sequence: log-probabilities through the paged kernels
     # against the plain float32 reference, outside the window
     ref = t["reference"]
-    diff = None
+    seq = got = diff = None
     if finished:
         cap = int(ref.get("max_tokens", 1024))
         fits = [r for r in finished if r.prompt_len + r.new_tokens <= cap]
         r = max(fits or finished[:1], key=lambda r: r.prompt_len + r.new_tokens)
         seq = np.concatenate([r.prompt, np.asarray(r.tokens, np.int32)])
         got = serving.score_logprobs(seq)
+    params = serving.engine.params
+    serving.close()
+    # the arena lives as long as the engine object: let both go, so that the
+    # reference's float32 pass has the chip beside the weights alone
+    del serving, load
+    gc.collect()
+    if seq is not None:
+        reference, ref_args = reference_module(cell), reference_args(cell)
         with jax.default_matmul_precision("highest"):
             want = np.asarray(jax.jit(
-                lambda p, ids: reference_module(cell).next_token_logprobs(
-                    p, ids, num_heads=cfg.num_heads))(
-                        serving.engine.params, seq[None]))[0]
+                lambda p, ids: reference.next_token_logprobs(
+                    p, ids, **ref_args))(params, seq[None]))[0]
         diff = float(np.abs(got - want).max())
         if not np.isfinite(got).all() or not diff <= float(ref["logprob_atol"]):
             problems.append(f"served log-probs differ from the reference by "
                             f"{diff:.4f} over {len(seq)} tokens (tolerance "
                             f"{ref['logprob_atol']})")
-    serving.close()
 
     e2e = {"setup_s": setup_s, "serve_tok_s": in_window / seconds}
     prefill_tok_s = prompt_tokens_inside(records, t_open, t_close) / seconds

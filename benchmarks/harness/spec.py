@@ -3,8 +3,11 @@
 A cell names a configuration and a traffic mix; `configs/<config>.json`,
 `traffic/<traffic>.json` and `layer_metrics/<metric>.json` are found by those
 names and nowhere else, so a later PR adds a cell by adding files and
-entries. `validate` holds the file to the parts of the contract that a run
-on the CPU can check.
+entries. A configuration's file also says what the harness needs to know of
+its family: which of the program's sizes is which of the source's (`widths`),
+what its plain reference is called with (`reference_args`) and the small
+model of the same family that the CPU rehearsal runs (`tiny`). `validate`
+holds the files to the parts of the contract that a run on the CPU can check.
 """
 
 from __future__ import annotations
@@ -26,6 +29,27 @@ TRAFFIC_KINDS = ("train", "closed_loop", "open_loop")
 WIDTH_WORDS = ("hidden_size", "intermediate", "ffn", "latent", "state_size",
                "head_dim", "head_size", "expansion", "experts_per_tok",
                "proj")
+# counts that a deployment may divide among its chips and that a cell here
+# holds whole: a configuration's file accounts for them as for its widths
+COUNT_WORDS = ("heads", "n_head", "vocab_size", "experts")
+# the one kind of size a configuration may cut
+DEPTH_WORDS = ("layer",)
+TINY_KEYS = {"preset", "dtype", "overrides", "reference_args"}
+
+
+def names_a_width(key: str) -> bool:
+    return (any(w in key for w in WIDTH_WORDS)
+            or key.endswith(("_dim", "_rank")))
+
+
+def names_a_size(key: str, value: Any) -> bool:
+    """A number of the source that its file has to map to the program's."""
+    return (isinstance(value, (int, float)) and not isinstance(value, bool)
+            and (names_a_width(key) or any(w in key for w in COUNT_WORDS)))
+
+
+def names_depth(key: str) -> bool:
+    return any(w in key for w in DEPTH_WORDS)
 
 
 class SpecError(ValueError):
@@ -48,6 +72,7 @@ class Cell:
 
     name: str
     chips: int
+    bench_dir: str                  # where references/<family>.py is found
     config_name: str
     traffic_name: str
     config: Dict[str, Any]          # configs/<config>.json
@@ -92,13 +117,79 @@ class Spec:
             if self._in_cell(m, name):
                 per_layer.append(dict(m, reader=self.reader(m["name"])))
         return Cell(
-            name=name, chips=int(w["chips"]), config_name=w["config"],
+            name=name, chips=int(w["chips"]), bench_dir=self.bench_dir,
+            config_name=w["config"],
             traffic_name=w["traffic"],
             config=_load(os.path.join(self.root, cfg_entry["file"])),
             traffic=_load(self.path("traffic", f"{w['traffic']}.json")),
             end_to_end=[m for m in self.doc["end_to_end"]
                         if self._in_cell(m, name)],
             per_layer=per_layer)
+
+    def _validate_config(self, entry: Dict[str, Any]) -> None:
+        """What a configuration's file says of its family holds together:
+        no size is written that the source does not have, and the program
+        runs every size as published but the depth, where `reduced` says."""
+        cfg = _load(os.path.join(self.root, entry["file"]))
+
+        def bad(what):
+            return SpecError(f"config {entry['name']} ({entry['file']}): "
+                             f"{what}")
+
+        for block in ("published", "model", "reference", "widths",
+                      "reference_args", "tiny"):
+            if block not in cfg:
+                raise bad(f"no '{block}'")
+        published, widths = cfg["published"], cfg["widths"]
+        equal = cfg.get("equal_widths", {})
+        overrides = cfg["model"]["overrides"]
+        if not os.path.exists(self.path("references",
+                                        f"{cfg['reference']}.py")):
+            raise bad(f"no references/{cfg['reference']}.py")
+        if set(cfg.get("reduced", {})) != set(entry["reduced"]):
+            raise bad("'reduced' differs from BENCHMARK.json's")
+        for key in entry["reduced"]:
+            if ((key in widths.values() or key in equal)
+                    and not names_depth(key)):
+                raise bad(f"'{key}' is in 'reduced' and does not name the "
+                          "depth: of the sizes in 'widths', only the number "
+                          "of layers may be cut")
+        for key, source in widths.items():
+            if source not in published:
+                raise bad(f"widths: '{source}' is no key of 'published'")
+            if key not in overrides:
+                raise bad(f"model.overrides leaves '{key}' to the preset")
+            if (overrides[key] != published[source]
+                    and source not in entry["reduced"]):
+                raise bad(f"model.overrides.{key} is {overrides[key]}, the "
+                          f"source's {source} is {published[source]}, and "
+                          f"'{source}' is not in 'reduced'")
+        for key, other in equal.items():
+            if (key not in published or other not in widths.values()
+                    or published[key] != published[other]):
+                raise bad(f"equal_widths: '{key}' must be a key of "
+                          f"'published' with the number of '{other}', and "
+                          "'widths' must map that one")
+        for key, value in published.items():
+            if (names_a_size(key, value) and key not in widths.values()
+                    and key not in equal):
+                raise bad(f"'{key}' names a size: map it in 'widths', or "
+                          "name under 'equal_widths' the mapped key whose "
+                          "number it has")
+        for arg, source in cfg["reference_args"].items():
+            if set(source) != {"published"} \
+                    or source["published"] not in published:
+                raise bad(f"reference_args.{arg} must be "
+                          '{"published": <a key of \'published\'>}')
+        tiny = cfg["tiny"]
+        if set(tiny) != TINY_KEYS:
+            raise bad(f"tiny has keys {sorted(tiny)}, not {sorted(TINY_KEYS)}")
+        if set(tiny["reference_args"]) != set(cfg["reference_args"]):
+            raise bad("tiny.reference_args names other arguments than "
+                      "reference_args")
+        left = sorted(set(widths) - set(tiny["overrides"]))
+        if left:
+            raise bad(f"tiny.overrides leaves {left} to the preset")
 
     # -- the contract, as far as it can be checked without a chip ---------
     def validate(self) -> None:
@@ -130,12 +221,12 @@ class Spec:
             raise SpecError("setup_s must be reported by every cell")
         for c in d["configs"]:
             for key in c["reduced"]:
-                if (any(w in key for w in WIDTH_WORDS)
-                        or key.endswith(("_dim", "_rank"))):
+                if names_a_width(key):
                     raise SpecError(f"config {c['name']}: reduces a width "
                                     f"('{key}')")
             if not c["file"].startswith(tuple(p + "/" for p in d["paths"])):
                 raise SpecError(f"config file {c['file']} outside paths")
+            self._validate_config(c)
         pairs = set()
         for w in d["workloads"]:
             if w["config"] not in configs:

@@ -21,7 +21,8 @@ import numpy as np
 
 from . import stats, traffic as traffic_mod
 from .device import TARGET
-from .program import build_model, program_seed, reference_module
+from .program import (build_model, program_seed, reference_args,
+                      reference_module)
 
 
 # one group of steps is traced (the trace stops at the end of the group in
@@ -63,10 +64,11 @@ def run(cell, seed: int, seconds: float, tracer, devs, counter,
     ids0 = jax.device_put(
         batches[0][0],
         NamedSharding(engine.mesh, PartitionSpec(DATA_SHARD, None)))
+    reference, ref_args = reference_module(cell), reference_args(cell)
     with jax.default_matmul_precision("highest"):
         ref_loss = float(jax.jit(
-            lambda p, ids: reference_module(cell).loss(
-                p, ids, num_heads=cfg.num_heads))(engine.params, ids0))
+            lambda p, ids: reference.loss(p, ids, **ref_args))(
+                engine.params, ids0))
 
     setup.mark("reference")
     step_no = 0

@@ -1,7 +1,7 @@
 """A tiny copy of the benchmark for the CPU: the real BENCHMARK.json and data
-files with the sizes cut (preset `tiny-opt`, short sequences, few clients),
-written into a temporary root. Derived from the real files, so a cell that a
-later PR adds is rehearsed too."""
+files with the sizes cut (each configuration's own `tiny` model, short
+sequences, few clients), written into a temporary root. Derived from the real
+files, so a cell that a later PR adds is rehearsed too, whatever its family."""
 
 import copy
 import json
@@ -10,8 +10,6 @@ import shutil
 
 from benchmarks.harness.spec import REPO_ROOT
 
-TINY_MODEL = {"preset": "tiny-opt", "dtype": "float32",
-              "overrides": {"num_layers": 2, "max_seq_len": 128}}
 TINY_SERVING = {"num_blocks": 40, "block_size": 16, "max_seqs": 4,
                 "prefill_chunk": 32, "max_model_len": 128}
 
@@ -37,6 +35,25 @@ def shrink_traffic(t: dict) -> dict:
     return t
 
 
+def tiny_config(cfg: dict) -> dict:
+    """The configuration's rehearsal, written as a configuration: its `tiny`
+    model in place of the real one, and `published` holding that model's
+    sizes. So the file validates under the same rules as the real one, and
+    the reference is called with the small model's arguments."""
+    cfg = copy.deepcopy(cfg)
+    tiny = cfg["tiny"]
+    cfg["model"] = {k: tiny[k] for k in ("preset", "dtype", "overrides")}
+    for key, source in cfg["widths"].items():
+        cfg["published"][source] = tiny["overrides"][key]
+    for key, other in cfg.get("equal_widths", {}).items():
+        cfg["published"][key] = cfg["published"][other]
+    for arg, value in tiny["reference_args"].items():
+        cfg["published"][cfg["reference_args"][arg]["published"]] = value
+    if "serving" in cfg:
+        cfg["serving"] = dict(TINY_SERVING)
+    return cfg
+
+
 def make_root(tmp: str) -> str:
     real = json.load(open(os.path.join(REPO_ROOT, "BENCHMARK.json")))
     bench = os.path.join(REPO_ROOT, real["paths"][0])
@@ -47,12 +64,12 @@ def make_root(tmp: str) -> str:
                     os.path.join(out, "layer_metrics"))
     shutil.copytree(os.path.join(bench, "reducers"),
                     os.path.join(out, "reducers"))
+    shutil.copytree(os.path.join(bench, "references"),
+                    os.path.join(out, "references"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
     for c in real["configs"]:
         cfg = json.load(open(os.path.join(REPO_ROOT, c["file"])))
-        cfg["model"] = copy.deepcopy(TINY_MODEL)
-        if "serving" in cfg:
-            cfg["serving"] = dict(TINY_SERVING)
-        json.dump(cfg, open(os.path.join(tmp, c["file"]), "w"))
+        json.dump(tiny_config(cfg), open(os.path.join(tmp, c["file"]), "w"))
     for w in real["workloads"]:
         t = json.load(open(os.path.join(bench, "traffic",
                                         f"{w['traffic']}.json")))

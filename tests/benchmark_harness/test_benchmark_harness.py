@@ -6,9 +6,11 @@ end to end at tiny size through a test-only steer — where it reports counts
 and refuses to report a time, a rate or a share."""
 
 import copy
+import inspect
 import json
 import math
 import os
+import shutil
 import statistics
 import sys
 import time
@@ -19,8 +21,9 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import bench_tiny  # noqa: E402
 from benchmarks import run as bench_run  # noqa: E402
-from benchmarks.harness import (device, layers, spec as spec_mod, stats,  # noqa: E402
-                                traffic, trace as trace_mod)
+from benchmarks.harness import (device, layers, program,  # noqa: E402
+                                spec as spec_mod, stats, traffic,
+                                trace as trace_mod)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SPEC = spec_mod.Spec()
@@ -49,17 +52,24 @@ def test_cell_files_are_found_by_name(cell):
     assert os.path.exists(SPEC.path("references",
                                     f"{c.config['reference']}.py"))
     published, model = c.config["published"], c.config["model"]["overrides"]
-    # no width is cut; depth only where `reduced` says so
-    assert model["hidden_size"] == published["hidden_size"]
-    assert model["ffn_hidden_size"] == published["ffn_dim"]
-    assert model["num_heads"] == published["num_attention_heads"]
-    assert model["vocab_size"] == published["vocab_size"]
     entry = next(x for x in DOC["configs"] if x["name"] == c.config_name)
-    if model["num_layers"] != published["num_hidden_layers"]:
-        assert entry["reduced"] == ["num_hidden_layers"]
-        assert "num_hidden_layers" in c.config["reduced"]
-    else:
-        assert entry["reduced"] == [] and not c.config["reduced"]
+    # of the sizes the file maps to the source's, the program changes those
+    # that `reduced` names and no other, and each of them is the depth:
+    # widths, heads and vocabulary are the source's
+    widths = c.config["widths"]
+    cut = {source for key, source in widths.items()
+           if model[key] != published[source]}
+    assert cut == set(entry["reduced"]) & set(widths.values())
+    assert set(entry["reduced"]) == set(c.config["reduced"])
+    assert all(spec_mod.names_depth(source) for source in cut)
+    accounted = set(widths.values()) | set(c.config.get("equal_widths", {}))
+    assert all(key in accounted for key, value in published.items()
+               if spec_mod.names_a_size(key, value))
+    # what the file says the reference is called with, the reference takes
+    reference, args = program.reference_module(c), program.reference_args(c)
+    for fn in (reference.loss, reference.next_token_logprobs):
+        inspect.signature(fn).bind("params", "ids", **args)
+    assert c.config["tiny"]["dtype"] == "float32"
     if c.traffic["kind"] == "train":
         assert c.chips == 4 or c.traffic["engine"][
             "zero_optimization"]["stage"] == 0
@@ -129,15 +139,81 @@ def _break(doc, how):
     return doc
 
 
-@pytest.mark.parametrize("how", ["bad-name", "unknown-moves",
-                                 "moves-not-reported", "width-reduced",
-                                 "two-four-chip-cells", "no-setup",
-                                 "loose-bound", "extra-key"])
+def _break_config(cfg, entry, how):
+    """A configuration's file (and, where the break needs it, its entry in
+    BENCHMARK.json), broken in one of the ways `validate` refuses; returns
+    what the refusal has to say."""
+    key, source = next((k, s) for k, s in cfg["widths"].items()
+                       if s not in cfg["reduced"])
+    if how == "config-without-tiny":
+        del cfg["tiny"]
+        return "no 'tiny'"
+    if how == "widths-source-unknown":
+        cfg["widths"][key] = "d_model"
+        return "'d_model' is no key of 'published'"
+    if how == "size-neither-mapped-nor-equal":
+        cfg["published"]["bottleneck_dim"] = 512
+        return "'bottleneck_dim' names a size"
+    if how == "override-differs-unreduced":
+        cfg["model"]["overrides"][key] += 64
+        return f"'{source}' is not in 'reduced'"
+    if how == "reference-arg-source-unknown":
+        arg = next(iter(cfg["reference_args"]))
+        cfg["reference_args"][arg] = {"published": "n_head"}
+        return f"reference_args.{arg} must be"
+    if how == "tiny-leaves-a-size-to-the-preset":
+        del cfg["tiny"]["overrides"][key]
+        return "tiny.overrides leaves"
+    if how in ("heads-reduced", "vocab-reduced"):
+        # a count that is no width by its name: listed in `reduced` on both
+        # sides and halved in the program, it is still not the depth
+        key, source = next((k, s) for k, s in cfg["widths"].items()
+                           if how[:5] in s)
+        assert not spec_mod.names_a_width(source)
+        cfg["model"]["overrides"][key] //= 2
+        cfg["reduced"][source] = "halved"
+        entry["reduced"] = entry["reduced"] + [source]
+        return f"'{source}' is in 'reduced' and does not name the depth"
+    if how == "heads-left-unmapped":
+        key, source = next((k, s) for k, s in cfg["widths"].items()
+                           if "heads" in s)
+        del cfg["widths"][key]
+        cfg["model"]["overrides"][key] //= 2
+        return f"'{source}' names a size"
+    if how == "equal-width-differs":
+        name = next(iter(cfg["equal_widths"]))
+        cfg["published"][name] //= 2
+        return f"equal_widths: '{name}' must be"
+    raise KeyError(how)
+
+
+BROKEN_DOCS = ["bad-name", "unknown-moves", "moves-not-reported",
+               "width-reduced", "two-four-chip-cells", "no-setup",
+               "loose-bound", "extra-key"]
+BROKEN_CONFIGS = ["config-without-tiny", "widths-source-unknown",
+                  "size-neither-mapped-nor-equal",
+                  "override-differs-unreduced",
+                  "reference-arg-source-unknown",
+                  "tiny-leaves-a-size-to-the-preset",
+                  "heads-reduced", "vocab-reduced", "heads-left-unmapped",
+                  "equal-width-differs"]
+
+
+@pytest.mark.parametrize("how", BROKEN_DOCS + BROKEN_CONFIGS)
 def test_validate_refuses(how, tmp_path):
     root = bench_tiny.make_root(str(tmp_path))
-    json.dump(_break(DOC, how), open(os.path.join(root, "BENCHMARK.json"),
-                                     "w"))
-    with pytest.raises(spec_mod.SpecError):
+    spec_mod.Spec(root).validate()      # sound before it is broken
+    says = None
+    if how in BROKEN_DOCS:
+        doc = _break(DOC, how)
+    else:
+        doc = copy.deepcopy(DOC)
+        path = os.path.join(root, doc["configs"][0]["file"])
+        cfg = json.load(open(path))
+        says = _break_config(cfg, doc["configs"][0], how)
+        json.dump(cfg, open(path, "w"))
+    json.dump(doc, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    with pytest.raises(spec_mod.SpecError, match=says):
         spec_mod.Spec(root).validate()
 
 
@@ -437,6 +513,92 @@ def test_open_loop_cell_arrives_as_data_only(steered, tiny_root, capsys):
              if ln.startswith("{")]
     assert next(n for n in notes if "ttft_samples" in n)[
         "generator_late_p95_ms"] is not None     # lateness is reported
+
+
+def _files(top):
+    return {os.path.relpath(os.path.join(d, f), top): os.path.join(d, f)
+            for d, _, fs in os.walk(top) if "__pycache__" not in d
+            for f in fs}
+
+
+@pytest.mark.parametrize("rope_base", ["the-small-models-own",
+                                       "the-sources"])
+def test_new_family_arrives_as_files(rope_base, tmp_path, monkeypatch,
+                                     capsys):
+    """A configuration of a family the benchmark has never run (GPT-NeoX:
+    rotary on a quarter of each head, parallel residual, a head of its own)
+    is a configuration file, a reference file and entries in BENCHMARK.json:
+    it validates and serves `correct` against its own float32 reference
+    through the paged path, and no file that was there has changed.
+
+    float32 on both sides: the served log-probabilities read 9.5e-7 from the
+    reference. Called with the source's rope base (10000) where the small
+    model turns at 500 the reference reads 4.4e-4 away, with half of each
+    head rotated 1.5e-3, with 8 heads 2.7e-3: the limit here is 1e-4, so
+    that an argument which did not come from the file's `tiny` block shows
+    (the second case)."""
+    root = bench_tiny.make_root(str(tmp_path))
+    before = {rel: open(path, "rb").read()
+              for rel, path in _files(root).items()}
+    fixtures = os.path.join(HERE, "fixtures")
+    cfg = json.load(open(os.path.join(fixtures, "configs",
+                                      "gptneox-20b.json")))
+    if rope_base == "the-sources":
+        cfg["tiny"]["reference_args"]["rotary_emb_base"] = cfg["published"][
+            "rotary_emb_base"]
+    shutil.copy(os.path.join(fixtures, "references", "gptneox.py"),
+                os.path.join(root, "benchmarks", "references"))
+    doc = copy.deepcopy(DOC)
+    like = next(w for w in doc["workloads"] if w["traffic"] == "serve-decode")
+    cell = "gptneox-20b.serve-decode"
+    doc["configs"].append({
+        "name": "gptneox-20b", "source": cfg["source"], "reduced": [],
+        "file": "benchmarks/configs/gptneox-20b.json",
+        "why": "rotary on part of each head, parallel residual, untied head"})
+    doc["workloads"].append(dict(like, name=cell, config="gptneox-20b"))
+    for m in doc["end_to_end"] + doc["per_layer"]:
+        if like["name"] in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    json.dump(doc, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    # the file holds together as the fixture has it, and again cut to its
+    # own small model
+    spec = spec_mod.Spec(root)
+    for as_run in (cfg, bench_tiny.tiny_config(cfg)):
+        json.dump(as_run, open(spec.path("configs", "gptneox-20b.json"), "w"))
+        spec.validate()
+    monkeypatch.setitem(device.TARGET, "platform", "cpu")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       os.path.join(root, ".jax_cache"))
+    assert spec.cell(cell).traffic["reference"]["logprob_atol"] == 1e-3
+    result = bench_run.run_cell(spec, cell, 2 ** 31 + 29, 3.0, False,
+                                time.perf_counter())
+    out = capsys.readouterr().out
+    assert result["failed"] == 0 and result["attempted"] >= 2
+    notes = [json.loads(ln) for ln in out.splitlines() if ln.startswith("{")]
+    diff = next(n for n in notes if "ttft_samples" in n)[
+        "reference_logprob_maxdiff"]
+    if rope_base == "the-sources":
+        assert diff > 1e-4
+        return
+    assert result["correct"], out
+    assert diff <= 1e-4
+
+    # only files were added: the harness that ran is the repo's own, and
+    # what was copied beside it is byte for byte what it was
+    after = _files(root)
+    assert all(open(after[rel], "rb").read() == data
+               for rel, data in before.items() if rel != "BENCHMARK.json")
+    added = {rel for rel in set(after) - set(before)
+             if not rel.startswith(".jax_cache")}
+    assert added == {"benchmarks/configs/gptneox-20b.json",
+                     "benchmarks/references/gptneox.py"}
+    repo = _files(SPEC.bench_dir)
+    for sub in ("references", "reducers", "layer_metrics"):
+        for rel, path in _files(os.path.join(root, "benchmarks", sub)).items():
+            if os.path.join(sub, rel) in repo:
+                assert open(path, "rb").read() == open(
+                    repo[os.path.join(sub, rel)], "rb").read()
+    assert bench_run.__file__.startswith(spec_mod.REPO_ROOT)
 
 
 # -- no chip, no result ----------------------------------------------------
