@@ -28,7 +28,7 @@ def _layer0_drop_rate(engine, cfg_m, ids, batch, seq, k) -> float:
 
     from deepspeed_tpu.models.transformer import (_norm,
                                                   dot_product_attention)
-    from deepspeed_tpu.parallel.moe import top1_plan, top2_plan
+    from deepspeed_tpu.parallel.moe import topk_plan
 
     p = engine.params
     l0 = jax.tree.map(lambda x: x[0], p["layers"])
@@ -58,10 +58,8 @@ def _layer0_drop_rate(engine, cfg_m, ids, batch, seq, k) -> float:
                 @ l0["router"].astype(jnp.float32))
 
     logits = pre_mlp_hidden(p, ids)
-    plan = (top2_plan(logits, cfg_m.moe_capacity_factor,
-                      cfg_m.moe_min_capacity) if k == 2 else
-            top1_plan(logits, cfg_m.moe_capacity_factor,
-                      cfg_m.moe_min_capacity))
+    plan = topk_plan(logits, k, cfg_m.moe_capacity_factor,
+                     cfg_m.moe_min_capacity)
     kept = float(plan.valid.sum())
     return 1.0 - kept / (batch * seq * k)
 

@@ -48,6 +48,15 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
                   tie_embeddings=False, norm_eps=1e-6),
     "mistral": dict(norm="rmsnorm", position="rope", activation="swiglu",
                     tie_embeddings=False, norm_eps=1e-6),
+    # OLMoE (Muennighoff et al. 2024, arXiv:2409.02060; HF model_type
+    # "olmoe"): a Llama block with RMSNorm over the whole q and k
+    # projections, and in every layer 8 of 64 SwiGLU experts, the router's
+    # softmax weights used as they are (norm_topk_prob false); no shared or
+    # residual expert, no biases
+    "olmoe": dict(norm="rmsnorm", position="rope", activation="swiglu",
+                  tie_embeddings=False, norm_eps=1e-5, qk_norm=True,
+                  moe_num_experts=64, moe_top_k=8,
+                  moe_norm_topk_prob=False),
 }
 
 # size presets: hidden, layers, heads, kv_heads, vocab, max_seq
@@ -116,6 +125,16 @@ _SIZES: Dict[str, Dict[str, Any]] = {
     "tiny-distilbert": dict(family="distilbert", hidden_size=64,
                             num_layers=2, num_heads=4, vocab_size=256,
                             max_seq_len=128),
+    # allenai/OLMoE-1B-7B-0125-Instruct config.json (1.3B active of 6.9B)
+    "olmoe-1b-7b": dict(family="olmoe", hidden_size=2048, num_layers=16,
+                        num_heads=16, num_kv_heads=16, ffn_hidden_size=1024,
+                        vocab_size=50304, max_seq_len=4096),
+    # 3 experts a token: neither 1 nor 2, so a fall-through to a top-1 or
+    # top-2 plan cannot pass the tests
+    "tiny-olmoe": dict(family="olmoe", hidden_size=64, num_layers=2,
+                       num_heads=4, num_kv_heads=4, ffn_hidden_size=32,
+                       vocab_size=256, max_seq_len=128, moe_num_experts=8,
+                       moe_top_k=3),
     # GShard/Switch-style 8-expert GPT (BASELINE tracked config #4)
     "moe-tiny": dict(family="gpt2", hidden_size=64, num_layers=2, num_heads=4,
                      vocab_size=256, max_seq_len=128, moe_num_experts=8),

@@ -65,6 +65,9 @@ class TransformerConfig:
     #   layer (post-LN encoders norm inside the block)
     lm_head_bias: bool = False              # untied head carries a bias (GPT-J)
     norm_eps: float = 1e-5
+    qk_norm: bool = False                   # RMSNorm over the WHOLE q and the
+    #   whole k projection (N*D / K*D wide), before the split into heads and
+    #   before rope (OLMoE, OLMo-2): part of the architecture, not a knob
     rope_theta: float = 10000.0
     dropout: float = 0.0              # embed/attn-out/mlp-out dropout rate.
     #   Applied only when dropout_enabled (the TrainEngine sets it; eval and
@@ -96,6 +99,10 @@ class TransformerConfig:
     # gated expert bank with top_k routing + load-balancing aux loss
     moe_num_experts: int = 0
     moe_top_k: int = 2
+    moe_norm_topk_prob: bool = True   # the k > 1 chosen router
+    #   probabilities are renormalised to sum to 1 (GShard top-2, Mixtral);
+    #   the published norm_topk_prob, false for OLMoE. A single expert's
+    #   weight is always its raw probability (Switch top-1)
     moe_capacity_factor: float = 1.25
     moe_min_capacity: int = 4
     moe_aux_loss_coef: float = 0.01
@@ -209,6 +216,9 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                 "wo": normal(3, (N * D, H), resid_std),
             },
         }
+        if cfg.qk_norm:
+            layer["attn"]["q_norm"] = jnp.ones((N * D,), cfg.dtype)
+            layer["attn"]["k_norm"] = jnp.ones((K * D,), cfg.dtype)
         if E > 0:
             layer["router"] = normal(4, (H, E))
             if cfg.moe_use_residual:
@@ -263,6 +273,8 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.norm == "layernorm":
         attn.update({"bq": (LAYERS, HEADS), "bk": (LAYERS, KV_HEADS),
                      "bv": (LAYERS, KV_HEADS), "bo": (LAYERS, EMBED)})
+    if cfg.qk_norm:
+        attn.update({"q_norm": (LAYERS, HEADS), "k_norm": (LAYERS, KV_HEADS)})
     from .core import EXPERT
 
     if cfg.moe_num_experts > 0:
@@ -833,7 +845,10 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                    paged_write_mask: Optional[jax.Array] = None,
                    paged_impl: str = "auto",
                    paged_chunk: bool = False,
-                   paged_layer: Optional[jax.Array] = None
+                   paged_layer: Optional[jax.Array] = None,
+                   moe_counts: bool = False,
+                   expert_banks: Optional[Dict[str, jax.Array]] = None,
+                   layer_index: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """One decoder block. ``layer`` holds this layer's (unstacked) params.
     ``cache`` (decode): dict with k/v of shape (B, T_max, K, D) and scalar
@@ -859,7 +874,17 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     and always the path a custom ``attention_impl`` sees). ``paged_chunk``
     asserts the chunked-prefill contract (``positions[b] == start_b +
     arange(S)``), which is what lets S>1 take the paged flash-prefill
-    kernel."""
+    kernel.
+
+    ``expert_banks`` (inference, an MoE model): the model's WHOLE expert
+    stacks ``(L, E, ...)`` in place of ``layer["mlp"]``, with ``layer_index``
+    saying which layer this is - ``forward`` keeps them out of the layer
+    scan's slicing, because the grouped-matmul kernel can read a touched
+    expert where it lies in the stack but not out of a slice that XLA would
+    first have to copy (``ops/moe_grouped_matmul.py``).
+
+    Returns ``(x, new_cache, aux)``; with ``moe_counts`` (an MoE model) a
+    fourth value, this layer's routing counts (``parallel/moe.moe_mlp``)."""
     B, S, H = x.shape
     N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
@@ -882,6 +907,11 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         q = q + layer["attn"]["bq"]
         k = k + layer["attn"]["bk"]
         v = v + layer["attn"]["bv"]
+    if cfg.qk_norm:
+        # over all heads at once (the published OlmoeAttention: q_norm and
+        # k_norm are hidden-wide), before the heads are split and roped
+        q = _norm(q, layer["attn"]["q_norm"], None, "rmsnorm", cfg.norm_eps)
+        k = _norm(k, layer["attn"]["k_norm"], None, "rmsnorm", cfg.norm_eps)
     q = q.reshape(B, S, N, D)
     k = k.reshape(B, S, K, D)
     v = v.reshape(B, S, K, D)
@@ -1152,22 +1182,22 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     if cfg.moe_num_experts > 0:
         from ..parallel.moe import moe_mlp
 
-        # cache mode == inference: exact routing, no capacity drops and no
-        # RTS — dropping a decode token would silently zero its MLP output,
-        # and right-padded prefill junk tokens must not steal capacity from
-        # real ones (the reference's DeepSpeedMoEInference routes without
-        # training-time capacity limits, moe_inference.py:160)
+        # cache mode == inference: moe_mlp then routes exactly (no capacity
+        # drops, no RTS) and keeps padding rows out of the routing
         infer = cache is not None
         rts_rng = (_activation_derived_key(h, 0)
                    if (cfg.moe_use_rts and not infer) else None)
-        mlp_out, aux = moe_mlp(h, layer["router"], layer["mlp"], cfg.activation,
-                               top_k=cfg.moe_top_k,
-                               capacity_factor=cfg.moe_capacity_factor,
-                               min_capacity=cfg.moe_min_capacity,
-                               drop_tokens=cfg.moe_drop_tokens and not infer,
-                               use_rts=cfg.moe_use_rts and not infer,
-                               rng=rts_rng,
-                               dispatch_impl=cfg.moe_dispatch)
+        mlp_out, aux, *counts = moe_mlp(
+            h, layer["router"],
+            layer["mlp"] if expert_banks is None else expert_banks,
+            cfg.activation,
+            expert_layer=None if expert_banks is None else layer_index,
+            top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
+            min_capacity=cfg.moe_min_capacity,
+            drop_tokens=cfg.moe_drop_tokens, use_rts=cfg.moe_use_rts,
+            rng=rts_rng, dispatch_impl=cfg.moe_dispatch,
+            norm_topk_prob=cfg.moe_norm_topk_prob, infer=infer,
+            row_mask=paged_write_mask, with_counts=moe_counts)
         if cfg.moe_use_residual:
             # PR-MoE (reference moe/layer.py:120): dense MLP in parallel,
             # mixed by a learned softmax coefficient over (moe, dense)
@@ -1207,6 +1237,8 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                   layer["ln2"].get("bias"), cfg.norm, cfg.norm_eps)
     else:
         x = x + mlp_out
+    if moe_counts:
+        return x, new_cache, aux, counts[0]
     return x, new_cache, aux
 
 
@@ -1222,7 +1254,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             block_table: Optional[jax.Array] = None,
             paged_write_mask: Optional[jax.Array] = None,
             paged_impl: str = "auto",
-            paged_chunk: bool = False
+            paged_chunk: bool = False,
+            moe_counts: bool = False
             ) -> Tuple[jax.Array, Optional[Dict[str, Any]], jax.Array]:
     """Token ids (B,S) → (logits (B,S,V), new_cache, moe_aux_loss). With
     ``cache``, runs in decode mode (cache is a per-layer stacked pytree; see
@@ -1236,7 +1269,10 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     is then REQUIRED — per-row absolute write positions — and
     ``paged_write_mask`` (B, S) routes padding writes to the scratch block.
     ``paged_impl``/``paged_chunk`` select the paged read path (see
-    ``_layer_forward``)."""
+    ``_layer_forward``). ``moe_counts`` (paged mode, an MoE model) adds a
+    fourth result: int32 ``[assignments, experts with a row, rows of the
+    largest expert]`` summed over the layers, from the rows that
+    ``paged_write_mask`` keeps (``parallel/moe.moe_mlp``)."""
     B, S = input_ids.shape
     if paged_impl not in ("auto", "gather"):
         raise ValueError(f"paged_impl must be 'auto' or 'gather', "
@@ -1261,6 +1297,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                                     or positions.ndim != 2):
         raise ValueError("paged mode (block_table) requires cache= and "
                          "explicit (B, S) positions")
+    if moe_counts and (block_table is None or cfg.moe_num_experts <= 0):
+        raise ValueError("moe_counts needs an MoE model in paged mode")
     static_prefill = (cache is not None and block_table is None
                       and isinstance(start_pos, int) and start_pos == 0)
 
@@ -1297,12 +1335,21 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         ltd_flags = jnp.array([1.0 if i in ltd_layers else 0.0
                                for i in range(L)], jnp.float32)
 
+    # at inference an MoE model's expert stacks stay whole, outside the
+    # layer scan's slicing, and go down with the layer's index (see
+    # _layer_forward's ``expert_banks``)
+    layers, banks = params["layers"], None
+    if cache is not None and cfg.moe_num_experts > 0:
+        banks = layers["mlp"]
+        layers = {k: v for k, v in layers.items() if k != "mlp"}
+    with_idx = use_pld or use_win or banks is not None
+
     def block(carry, layer_and_cache):
         h, aux_acc = carry
         ltd_flag = None
         if use_ltd:
             (layer, layer_cache), idx, ltd_flag = layer_and_cache
-        elif use_pld or use_win:
+        elif with_idx:
             (layer, layer_cache), idx = layer_and_cache
         else:
             layer, layer_cache = layer_and_cache
@@ -1342,7 +1389,9 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             h_new, new_cache, aux = _layer_forward(
                 cfg, h, layer, attention_mask, positions, layer_cache,
                 static_prefill=static_prefill, key_positions=key_positions,
-                window=window)
+                window=window, expert_banks=banks,
+                layer_index=(None if banks is None
+                             else idx.astype(jnp.int32)))
         if use_pld:
             h_new, aux = pld_gate(cfg, h, h_new, aux, idx, pld_theta)
         return (h_new, aux_acc + aux), new_cache
@@ -1380,26 +1429,31 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         # serving engine rejects sliding-window models, and the dense-view
         # fallback inside _layer_forward ignores `window`.
         def paged_block(carry, layer_and_idx):
-            h, aux_acc, arena = carry
+            h, aux_acc, arena, *counts_acc = carry
             layer, idx = layer_and_idx
-            h_new, arena, aux = _layer_forward(
+            h_new, arena, aux, *counts = _layer_forward(
                 cfg, h, layer, attention_mask, positions, arena,
                 block_table=block_table, paged_write_mask=paged_write_mask,
                 paged_impl=paged_impl, paged_chunk=paged_chunk,
-                paged_layer=idx)
-            return (h_new, aux_acc + aux, arena), None
+                paged_layer=idx, moe_counts=moe_counts, expert_banks=banks,
+                layer_index=idx)
+            return (h_new, aux_acc + aux, arena,
+                    *(a + c for a, c in zip(counts_acc, counts))), None
 
-        (x, aux_total, new_cache), _ = lax.scan(
+        (x, aux_total, new_cache, *moe_totals), _ = lax.scan(
             paged_block,
-            (x, jnp.float32(0.0), {"k": cache["k"], "v": cache["v"]}),
-            (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+            (x, jnp.float32(0.0), {"k": cache["k"], "v": cache["v"]},
+             *([jnp.zeros((3,), jnp.int32)] if moe_counts else [])),
+            (layers, jnp.arange(L, dtype=jnp.int32)))
     else:
-        xs = ((params["layers"], cache) if not use_win else
-              ((params["layers"], cache), jnp.arange(L, dtype=jnp.float32)))
+        xs = ((layers, cache) if not with_idx else
+              ((layers, cache), jnp.arange(L, dtype=jnp.float32)))
         (x, aux_total), new_cache = lax.scan(block_fn, (x, jnp.float32(0.0)),
                                              xs)
 
     logits = head_logits(params, x, cfg)
+    if moe_counts:
+        return logits, new_cache, aux_total, moe_totals[0]
     return logits, new_cache, aux_total
 
 

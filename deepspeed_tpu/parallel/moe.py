@@ -64,97 +64,88 @@ def _one_hot(x: jax.Array, n: int) -> jax.Array:
     return jax.nn.one_hot(x, n, dtype=jnp.float32)
 
 
-def top1_plan(logits: jax.Array, capacity_factor: float = 1.0,
-              min_capacity: int = 4, noisy_gate_policy: Optional[str] = None,
-              rng: Optional[jax.Array] = None, drop_tokens: bool = True,
+def route_topk(gates: jax.Array, choice: jax.Array, k: int,
+               normalize: bool) -> Tuple[jax.Array, jax.Array]:
+    """The router's decision for every token: ``lax.top_k`` over ``choice``
+    (the float32 softmax ``gates`` themselves, or noised logits for the
+    RSample policy) -> ``(expert_idx (T, k) int32, weight (T, k) f32)``, most
+    probable first. The weights are the chosen experts' probabilities, and
+    where ``normalize`` and ``k > 1`` they are divided by their sum (GShard
+    top-2, Mixtral; the published ``norm_topk_prob``); a single expert keeps
+    its raw probability (Switch), or the router would get no gradient."""
+    E = gates.shape[-1]
+    if not 1 <= k <= E:
+        raise ValueError(f"moe top_k={k} must be between 1 and the number "
+                         f"of experts ({E})")
+    _, idx = jax.lax.top_k(choice, k)
+    weight = jnp.take_along_axis(gates, idx, axis=-1)
+    if normalize and k > 1:
+        weight = weight / jnp.maximum(weight.sum(-1, keepdims=True), 1e-9)
+    return idx.astype(jnp.int32), weight
+
+
+def topk_plan(logits: jax.Array, k: int, capacity_factor: float = 1.0,
+              min_capacity: int = 4, drop_tokens: bool = True,
+              normalize: bool = True,
+              noisy_gate_policy: Optional[str] = None,
+              rng: Optional[jax.Array] = None,
               use_rts: bool = False) -> GatePlan:
-    """Switch-style top-1 gating (reference sharded_moe.py:179), index form.
+    """Capacity-limited top-``k`` gating in index form: softmax in float32
+    over all experts, the ``k`` most probable, each with a slot in its
+    expert's queue. ``k`` of 1 and 2 are the reference's ``top1gating``
+    (sharded_moe.py:179) and ``top2gating`` (:277): capacity from
+    ``k * capacity_factor``, the load-balancing loss from the FIRST choice
+    (GShard l_aux = E * sum(me * ce)), queues filled choice by choice (every
+    first choice before any second), top-2's weights renormalised.
 
-    ``drop_tokens=False`` — infinite capacity (C=T; the reference computes a
-    dynamic max-count capacity, which jit cannot — C=T is the static-shape
-    equivalent; prefer capacity_factor at scale). ``use_rts`` — Random Token
-    Selection (sharded_moe.py:220): over-capacity tokens are chosen by random
-    priority instead of sequence order (needs ``rng``)."""
+    ``drop_tokens=False`` - infinite capacity (C=T; the reference computes a
+    dynamic max-count capacity, which jit cannot - C=T is the static-shape
+    equivalent; prefer capacity_factor at scale). ``use_rts`` - Random Token
+    Selection (sharded_moe.py:220), top-1 only: over-capacity tokens are
+    chosen by random priority instead of sequence order (needs ``rng``).
+    ``noisy_gate_policy='RSample'`` (top-1 only, needs ``rng``) chooses by
+    Gumbel-noised logits and weighs by the clean probability."""
     T, E = logits.shape
-    C = T if not drop_tokens else _capacity(T, E, capacity_factor, min_capacity)
-    if noisy_gate_policy == "RSample" and rng is not None:
-        logits_for_choice = logits + jax.random.gumbel(rng, logits.shape)
-    else:
-        logits_for_choice = logits
+    if (use_rts or noisy_gate_policy) and k != 1:
+        raise ValueError("use_rts (Random Token Selection) and "
+                         "noisy_gate_policy are top-1 only, as in the "
+                         "reference (sharded_moe.py top1gating)")
+    C = T if not drop_tokens else _capacity(T, E, k * capacity_factor,
+                                            min_capacity)
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)      # (T, E)
-    expert_idx = jnp.argmax(logits_for_choice, axis=-1)              # (T,)
-    mask = _one_hot(expert_idx, E)                                   # (T, E)
+    choice = gates
+    if noisy_gate_policy == "RSample" and rng is not None:
+        choice = logits + jax.random.gumbel(rng, logits.shape)
+    idx, weight = route_topk(gates, choice, k, normalize)            # (T, k)
+    masks = [_one_hot(idx[:, j], E) for j in range(k)]               # (T, E)
 
-    # aux loss: E * mean_e(frac_tokens_e * mean_gate_e)  (GShard eq.) —
-    # computed on the PRE-RTS mask, as in the reference
-    me = gates.mean(axis=0)
-    ce = mask.mean(axis=0)
-    aux = jnp.sum(me * ce) * E
+    # aux loss: E * mean_e(frac_tokens_e * mean_gate_e)  (GShard eq.) -
+    # computed on the first choice's PRE-RTS mask, as in the reference
+    aux = jnp.sum(gates.mean(axis=0) * masks[0].mean(axis=0)) * E
+    counts = sum(masks).sum(axis=0)
 
     if use_rts and drop_tokens and rng is not None and C < T:
         # keep a RANDOM capacity-subset per expert (reference mask1_rand +
         # _top_idx): top-C random priorities, then positions as usual
-        pri = mask * jax.random.uniform(rng, mask.shape, jnp.float32)
+        pri = masks[0] * jax.random.uniform(rng, masks[0].shape, jnp.float32)
         _, top_idx = jax.lax.top_k(pri.T, C)                        # (E, C)
         sel = jnp.zeros((E, T), jnp.float32).at[
             jnp.arange(E)[:, None], top_idx].set(1.0)
-        mask = mask * sel.T
+        masks[0] = masks[0] * sel.T
+        counts = masks[0].sum(axis=0)
 
-    # capacity assignment: position of each token within its expert queue
-    pos_in_expert = jnp.cumsum(mask, axis=0) * mask                  # 1-based
-    keep = (pos_in_expert <= C) & (mask > 0)
-    pos = ((pos_in_expert - 1.0) * mask).sum(axis=-1).astype(jnp.int32)
-    valid = keep.any(axis=-1)                                        # (T,)
-    gate_val = (gates * mask).sum(axis=-1)                           # (T,)
-    weight = jnp.where(valid, gate_val, 0.0)
-    return GatePlan(expert_idx=expert_idx.astype(jnp.int32)[:, None],
-                    slot_pos=pos[:, None], weight=weight[:, None],
-                    valid=valid[:, None], capacity=C, aux_loss=aux,
-                    expert_counts=mask.sum(axis=0))
-
-
-def top2_plan(logits: jax.Array, capacity_factor: float = 1.0,
-              min_capacity: int = 4, drop_tokens: bool = True) -> GatePlan:
-    """GShard top-2 gating (reference sharded_moe.py:277), index form:
-    second expert weighted by renormalised gate, both capacity-limited."""
-    T, E = logits.shape
-    C = T if not drop_tokens else _capacity(T, E, 2 * capacity_factor,
-                                            min_capacity)
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-
-    idx1 = jnp.argmax(gates, axis=-1)
-    mask1 = _one_hot(idx1, E)
-    gates_wo1 = gates * (1.0 - mask1)
-    idx2 = jnp.argmax(gates_wo1, axis=-1)
-    mask2 = _one_hot(idx2, E)
-
-    me = gates.mean(axis=0)
-    ce = mask1.mean(axis=0)
-    aux = jnp.sum(me * ce) * E
-
-    # queue positions: expert-1 tokens first, then expert-2 tokens
-    pos1 = jnp.cumsum(mask1, axis=0) * mask1
-    pos2 = (jnp.cumsum(mask2, axis=0) + mask1.sum(axis=0)[None, :]) * mask2
-    keep1 = (pos1 <= C) & (mask1 > 0)
-    keep2 = (pos2 <= C) & (mask2 > 0)
-
-    g1 = (gates * mask1).sum(axis=-1)
-    g2 = (gates * mask2).sum(axis=-1)
-    denom = jnp.maximum(g1 + g2, 1e-9)
-    g1, g2 = g1 / denom, g2 / denom
-
-    def pos0(pos):
-        return (pos.sum(-1) - 1.0).clip(0).astype(jnp.int32)
-
-    valid1, valid2 = keep1.any(axis=-1), keep2.any(axis=-1)
-    return GatePlan(
-        expert_idx=jnp.stack([idx1, idx2], axis=1).astype(jnp.int32),
-        slot_pos=jnp.stack([pos0(pos1), pos0(pos2)], axis=1),
-        weight=jnp.stack([jnp.where(valid1, g1, 0.0),
-                          jnp.where(valid2, g2, 0.0)], axis=1),
-        valid=jnp.stack([valid1, valid2], axis=1),
-        capacity=C, aux_loss=aux,
-        expert_counts=(mask1 + mask2).sum(axis=0))
+    # queue positions: every first choice, then every second, ...
+    pos, valid = [], []
+    ahead = jnp.zeros((E,), jnp.float32)
+    for mask in masks:
+        in_queue = (jnp.cumsum(mask, axis=0) + ahead[None, :]) * mask  # 1-based
+        valid.append(((in_queue <= C) & (mask > 0)).any(axis=-1))
+        pos.append((in_queue.sum(-1) - 1.0).clip(0).astype(jnp.int32))
+        ahead = ahead + mask.sum(axis=0)
+    valid = jnp.stack(valid, axis=1)
+    return GatePlan(expert_idx=idx, slot_pos=jnp.stack(pos, axis=1),
+                    weight=jnp.where(valid, weight, 0.0), valid=valid,
+                    capacity=C, aux_loss=aux, expert_counts=counts)
 
 
 def _densify(plan: GatePlan, num_experts: int) -> GateOutput:
@@ -164,7 +155,7 @@ def _densify(plan: GatePlan, num_experts: int) -> GateOutput:
     K = plan.expert_idx.shape[1]
     combine = jnp.zeros((), jnp.float32)
     dispatch = None
-    for kk in range(K):   # K<=2; keeps peak at (T,E,C), not (T,K,E,C)
+    for kk in range(K):   # keeps peak at (T,E,C), not (T,K,E,C)
         e_oh = _one_hot(plan.expert_idx[:, kk], E) > 0          # (T, E)
         c_oh = _one_hot(plan.slot_pos[:, kk], C) > 0            # (T, C)
         d = (e_oh[:, :, None] & c_oh[:, None, :]
@@ -180,16 +171,16 @@ def top1gating(logits: jax.Array, capacity_factor: float = 1.0,
                min_capacity: int = 4, noisy_gate_policy: Optional[str] = None,
                rng: Optional[jax.Array] = None, drop_tokens: bool = True,
                use_rts: bool = False) -> GateOutput:
-    """Dense (T, E, C) rendering of :func:`top1_plan` (same semantics)."""
-    return _densify(top1_plan(logits, capacity_factor, min_capacity,
-                              noisy_gate_policy, rng, drop_tokens, use_rts),
-                    logits.shape[1])
+    """Dense (T, E, C) rendering of :func:`topk_plan` at k = 1."""
+    return _densify(topk_plan(logits, 1, capacity_factor, min_capacity,
+                              drop_tokens, noisy_gate_policy=noisy_gate_policy,
+                              rng=rng, use_rts=use_rts), logits.shape[1])
 
 
 def top2gating(logits: jax.Array, capacity_factor: float = 1.0,
                min_capacity: int = 4, drop_tokens: bool = True) -> GateOutput:
-    """Dense (T, E, C) rendering of :func:`top2_plan` (same semantics)."""
-    return _densify(top2_plan(logits, capacity_factor, min_capacity,
+    """Dense (T, E, C) rendering of :func:`topk_plan` at k = 2."""
+    return _densify(topk_plan(logits, 2, capacity_factor, min_capacity,
                               drop_tokens), logits.shape[1])
 
 
@@ -287,50 +278,172 @@ def _combine_gather_bwd(res, dout):
 _combine_gather.defvjp(_combine_gather_fwd, _combine_gather_bwd)
 
 
+def _grouped_experts(xt: jax.Array, gates: jax.Array,
+                     experts: Dict[str, jax.Array], activation: str, k: int,
+                     normalize: bool, real: Optional[jax.Array],
+                     layer: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Dropless expert compute over the ASSIGNED rows only: the ``T x k``
+    assignments are laid out sorted by expert, each expert's group padded to
+    the row tile (``ops/moe_grouped_matmul.group_layout``), and the expert
+    matmuls run as grouped matmuls over those rows - the Pallas kernel
+    ``moe_grouped_matmul`` where kernels are active, its ``jnp`` twin
+    elsewhere. No ``(E, T, H)`` tensor exists; an expert nobody chose does no
+    work and its weights are not read. Rows where ``real`` is false (decode
+    rows with no request, a prompt chunk's padding) are not routed at all and
+    come back zero. With ``layer`` the weights are the model's whole
+    ``(L, E, ...)`` stacks, read in place at that layer (see the kernel). Returns ``(out (T, H), counts)`` with ``counts`` int32
+    ``[assignments, experts with a row, rows of the largest expert]``."""
+    from ..ops.moe_grouped_matmul import (group_layout, moe_grouped_matmul,
+                                          reference_grouped_matmul,
+                                          tile_rows)
+    from ..models.transformer import _kernels_active
+    from .mesh import ambient_mesh
+
+    T, H = xt.shape
+    E = gates.shape[-1]
+    idx, weight = route_topk(gates, gates, k, normalize)          # (T, k)
+    flat = idx.reshape(-1)
+    chosen = flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :]
+    if real is not None:
+        chosen = chosen & jnp.repeat(real, k)[:, None]            # (T*k, E)
+    sizes = chosen.sum(axis=0, dtype=jnp.int32)                   # (E,)
+    tm = tile_rows(T * k, E, xt.dtype)
+    row_start, tile_expert, used = group_layout(sizes, T * k, tm)
+    rows = tile_expert.shape[0] * tm
+    # an assignment's padded row: its expert's first row + its place among
+    # that expert's assignments (a counting sort; order within an expert is
+    # token order). Unrouted assignments go to a dump row that is cut off.
+    rank = (jnp.cumsum(chosen, axis=0, dtype=jnp.int32) - 1)
+    rank = jnp.take_along_axis(rank, flat[:, None], axis=1)[:, 0]
+    routed = chosen.any(axis=1)
+    slot = jnp.where(routed, row_start[flat] + rank, rows)        # (T*k,)
+    token_of_row = jnp.zeros((rows + 1,), jnp.int32).at[slot].set(
+        jnp.repeat(jnp.arange(T, dtype=jnp.int32), k))[:rows]
+    xs = xt[token_of_row]           # padding rows read token 0, unread after
+
+    mesh = ambient_mesh()
+    if _kernels_active() and (mesh is None or mesh.size == 1):
+        mm = moe_grouped_matmul
+    else:       # CPU; or XLA partitions the layer over the mesh itself
+        mm = reference_grouped_matmul
+    f32 = jnp.float32
+    up = mm(xs, experts["w_up"], tile_expert, used, layer).astype(f32)
+    if activation == "swiglu":
+        gate = mm(xs, experts["w_gate"], tile_expert, used, layer).astype(f32)
+        inner = jax.nn.silu(gate) * up
+    else:
+        inner = jax.nn.gelu(up, approximate=True)
+    y = mm(inner.astype(xt.dtype), experts["w_down"], tile_expert, used,
+           layer)
+
+    # rows past the used tiles were never written: select, do not multiply
+    picked = jnp.where(routed[:, None], y[jnp.minimum(slot, rows - 1)], 0)
+    out = (picked.astype(f32).reshape(T, k, H)
+           * weight[..., None]).sum(axis=1).astype(xt.dtype)
+    return out, _routing_counts(sizes)
+
+
+def _routing_counts(sizes: jax.Array) -> jax.Array:
+    """(E,) rows per expert -> int32 ``[assignments, experts with a row,
+    rows of the largest expert]``."""
+    n = sizes.astype(jnp.int32)
+    return jnp.stack([n.sum(), (n > 0).sum(dtype=jnp.int32), n.max()])
+
+
 def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
             activation: str, top_k: int = 2, capacity_factor: float = 1.25,
             min_capacity: int = 4, drop_tokens: bool = True,
             use_rts: bool = False, rng: Optional[jax.Array] = None,
-            dispatch_impl: str = "sparse") -> Tuple[jax.Array, jax.Array]:
+            dispatch_impl: str = "sparse", norm_topk_prob: bool = True,
+            infer: bool = False, row_mask: Optional[jax.Array] = None,
+            with_counts: bool = False,
+            expert_layer: Optional[jax.Array] = None):
     """MoE FFN for one layer. x (B, S, H); router_w (H, E); experts:
-    w_up/w_down (+w_gate for swiglu) with leading expert dim E.
-    Returns (out (B,S,H), aux_loss scalar).
+    w_up/w_down (+w_gate for swiglu) with leading expert dim E - or, with
+    ``expert_layer`` (int32 scalar), the model's whole stacks with a layer
+    dim in front of it, of which that layer is used: the grouped kernel then
+    reads the touched experts where they lie, and no layer's bank is copied
+    out of the stack for it.
+    Returns (out (B,S,H), aux_loss scalar), and with ``with_counts`` a third
+    value: int32 ``[assignments, experts with a row, rows of the largest
+    expert]`` of this layer.
 
+    ``top_k`` is any number of experts a token from 1 to E (softmax in
+    float32 over all experts, then the k most probable); ``norm_topk_prob``
+    says whether their weights are renormalised (``route_topk``).
+
+    Training (``infer`` false) runs the capacity plan (``topk_plan``) under
     ``dispatch_impl``:
-      * ``"sparse"`` (default) — scatter/gather dispatch: a (E·C,) int32
+      * ``"sparse"`` (default) - scatter/gather dispatch: a (E*C,) int32
         token-of-slot map is built by scatter, tokens reach their expert
-        queue by GATHER (O(E·C·H) bytes, no FLOPs) and return by a (T, K)
-        gather + weighted sum (O(T·K·H) FLOPs). Dispatch cost scales with
-        the routed tokens — at E=8/top-2/cap 1.25 the dense formulation
+        queue by GATHER (O(E*C*H) bytes, no FLOPs) and return by a (T, K)
+        gather + weighted sum (O(T*K*H) FLOPs). Dispatch cost scales with
+        the routed tokens - at E=8/top-2/cap 1.25 the dense formulation
         burns ~4x the expert compute in the one-hot contraction alone.
-      * ``"einsum"`` — the GShard (T,E,C) one-hot einsum formulation (what
+      * ``"einsum"`` - the GShard (T,E,C) one-hot einsum formulation (what
         the reference computes, sharded_moe.py:90); equivalence-tested
-        against sparse."""
+        against sparse.
+
+    Inference (``infer``: a KV cache is present) routes exactly - no
+    capacity drops, no RTS: a dropped decode token would silently lose its
+    FFN output (the reference's DeepSpeedMoEInference routes without
+    training-time limits too, moe_inference.py:160). Which compute runs is
+    decided HERE, by what can be observed, and is no option:
+      * experts whole on every chip (``_ep_active(E)`` false): the dropless
+        grouped path (``_grouped_experts``) - work and weight traffic follow
+        the T*k assignments, and ``row_mask`` (B, S) keeps padding rows out
+        of the routing. ``dispatch_impl`` plays no part.
+      * experts sharded over chips: the capacity plan with ``C = T`` and its
+        ``(E, C, H)`` sharding constraint, which XLA lowers to the
+        all-to-all. A dropless exchange over chips needs the group sizes on
+        every chip before the exchange, which nothing here does yet;
+        ``row_mask`` is not applied there (padding rows are routed as real
+        ones, as before) and the counts include them."""
     B, S, H = x.shape
     E = router_w.shape[-1]
     T = B * S
     xt = x.reshape(T, H)
     logits = xt.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    if top_k == 2 and use_rts:
-        raise ValueError("use_rts (Random Token Selection) is top-1 only, "
-                         "as in the reference (sharded_moe.py top1gating)")
     if dispatch_impl not in ("sparse", "einsum"):
         raise ValueError(f"unknown moe dispatch_impl {dispatch_impl!r} "
                          "(expected 'sparse' or 'einsum')")
-    plan = (top2_plan(logits, capacity_factor, min_capacity,
-                      drop_tokens=drop_tokens) if top_k == 2 else
-            top1_plan(logits, capacity_factor, min_capacity,
-                      drop_tokens=drop_tokens, use_rts=use_rts, rng=rng))
+    if infer and not _ep_active(E):
+        out, counts = _grouped_experts(
+            xt, jax.nn.softmax(logits, axis=-1), experts, activation, top_k,
+            norm_topk_prob,
+            None if row_mask is None else row_mask.reshape(T), expert_layer)
+        out, aux = out.reshape(B, S, H), jnp.float32(0.0)
+        return (out, aux, counts) if with_counts else (out, aux)
+    if expert_layer is not None:    # XLA fuses this slice into the einsums
+        experts = jax.tree.map(lambda w: w[expert_layer], experts)
+    plan = topk_plan(logits, top_k, capacity_factor, min_capacity,
+                     drop_tokens=drop_tokens and not infer,
+                     normalize=norm_topk_prob,
+                     use_rts=use_rts and not infer, rng=rng)
+    out = _capacity_experts(xt, plan, experts, activation, dispatch_impl)
+    out = out.reshape(B, S, H)
+    if not with_counts:
+        return out, plan.aux_loss
+    return out, plan.aux_loss, _routing_counts(plan.expert_counts)
+
+
+def _capacity_experts(xt: jax.Array, plan: GatePlan,
+                      experts: Dict[str, jax.Array], activation: str,
+                      dispatch_impl: str) -> jax.Array:
+    """(T, H) tokens through the experts under a capacity plan: dispatch to
+    the (E, C, H) queues, the batched expert MLPs, combine. Returns (T, H)."""
+    T, H = xt.shape
+    E = experts["w_up"].shape[0]
     C = plan.capacity
 
     if dispatch_impl == "einsum":
         gate = _densify(plan, E)
-        dispatch = gate.dispatch.astype(x.dtype)                  # (T, E, C)
+        dispatch = gate.dispatch.astype(xt.dtype)                 # (T, E, C)
         dispatched = jnp.einsum("tec,th->ech", dispatch, xt)      # (E, C, H)
         expert_out = _expert_ffn(dispatched, experts, activation, E)
-        out = jnp.einsum("tec,ech->th", gate.combine.astype(x.dtype),
-                         expert_out)
-        return out.reshape(B, S, H), plan.aux_loss
+        return jnp.einsum("tec,ech->th", gate.combine.astype(xt.dtype),
+                          expert_out)
 
     # ---- sparse dispatch -------------------------------------------------
     # flat slot id per (token, assignment); dropped tokens write to a dump
@@ -357,6 +470,5 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
     expert_out = _expert_ffn(dispatched, experts, activation, E)
 
     y = expert_out.reshape(E * C, H)
-    out = _combine_gather(y, plan.weight.astype(x.dtype), slot, plan.valid,
-                          token_of_slot, wt_of_slot, filled)      # (T, H)
-    return out.reshape(B, S, H), plan.aux_loss
+    return _combine_gather(y, plan.weight.astype(xt.dtype), slot, plan.valid,
+                           token_of_slot, wt_of_slot, filled)     # (T, H)

@@ -111,8 +111,15 @@ class ServingEngine:
         # 'auto' = Pallas paged kernels on TPU, jnp paged reference on CPU
         self._paged_impl = ("gather" if self.config.paged_kernel == "off"
                             else "auto")
-        self._prefill = paged_kv.build_prefill_program(cfg, self._paged_impl)
-        self._decode = paged_kv.build_decode_program(cfg, self._paged_impl)
+        # an MoE model's two programs return their routing counts behind
+        # the tokens (_moe_counts); 0 = a dense model, whose programs and
+        # spans know nothing of it
+        self._moe_experts_total = cfg.moe_num_experts * cfg.num_layers
+        moe = self._moe_experts_total > 0
+        self._prefill = paged_kv.build_prefill_program(
+            cfg, self._paged_impl, moe_counts=moe)
+        self._decode = paged_kv.build_decode_program(
+            cfg, self._paged_impl, moe_counts=moe)
         self._cow = paged_kv.build_cow_program()
         # teacher-forced scoring over the same arena (the RLHF second
         # serving pass — docs/rlhf.md); jit is lazy, so an engine that
@@ -879,6 +886,23 @@ class ServingEngine:
             tok = np.asarray(tok)
         return tok, t0, self.clock()
 
+    def _moe_counts(self, span, fetched: np.ndarray, n: int) -> np.ndarray:
+        """The ``n`` sampled tokens of what an MoE model's program returned
+        (``paged_kv._with_moe_counts``); the routing counts behind them go
+        onto ``span``: over the real rows of this iteration and summed over
+        the layers, the (token, expert) assignments, the experts that had a
+        row (of ``moe_experts_total`` = experts x layers) and the rows of
+        each layer's largest expert."""
+        if not self._moe_experts_total:
+            return fetched
+        if span.recording:
+            assigned, touched, largest = (int(c) for c in fetched[n:n + 3])
+            span.annotate(moe_assignments=assigned,
+                          moe_experts_touched=touched,
+                          moe_experts_total=self._moe_experts_total,
+                          moe_max_expert_rows=largest)
+        return fetched[:n]
+
     def _step_prefill(self) -> bool:
         req = self.sched.next_prefill()
         if req is None:
@@ -908,6 +932,7 @@ class ServingEngine:
                         chunk, np.asarray(start, np.int32),
                         np.asarray(n_valid, np.int32),
                         temps, topks, topps, seeds, self._base_rng)
+            tok = self._moe_counts(span, tok, 1)
             if self._serve_acct is not None:
                 self._serve_acct.note_phase("prefill", t1 - t0)
             if rt is not None and req.trace is not None:
@@ -1109,6 +1134,7 @@ class ServingEngine:
                     nxt, t0, t1 = self._run_program(
                         obs, "serving/decode", self._decode, *operands,
                         self._base_rng)
+            nxt = self._moe_counts(span, nxt, self.config.max_seqs)
             if acct is not None:
                 acct.note_phase("decode", t1 - t0)
             if rt is not None:

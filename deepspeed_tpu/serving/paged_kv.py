@@ -373,7 +373,16 @@ def sample_rows(logits: jax.Array, base_key: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def build_prefill_program(cfg, paged_impl: str = "auto"):
+def _with_moe_counts(tokens: jax.Array, counts: jax.Array) -> jax.Array:
+    """An MoE model's routing counts ride behind the sampled tokens in the
+    ONE int32 array the host fetches: ``[tokens..., assignments, experts
+    with a row, rows of the largest expert]``, the three summed over the
+    layers (``models/transformer.forward(moe_counts=True)``)."""
+    return jnp.concatenate([tokens.astype(jnp.int32), counts])
+
+
+def build_prefill_program(cfg, paged_impl: str = "auto",
+                          moe_counts: bool = False):
     """Jitted prefill-chunk program over the paged arena.
 
     Args (all shapes static per (C, max_blocks) pair):
@@ -389,7 +398,9 @@ def build_prefill_program(cfg, paged_impl: str = "auto"):
     Returns (token (1,), last_logits (1, V) f32, cache): ``token`` samples
     the position-``n_valid-1`` logits at output-token index 0 — the
     request's FIRST generated token when this was the final chunk, ignored
-    otherwise.
+    otherwise. With ``moe_counts`` (an MoE model; the serving engine's own
+    programs) ``token`` is (4,): the token, then the chunk's routing counts
+    over its ``n_valid`` real tokens (``_with_moe_counts``).
     """
     from ..models.transformer import forward as model_forward
 
@@ -403,23 +414,24 @@ def build_prefill_program(cfg, paged_impl: str = "auto"):
         # path's residency window onto scratch/recycled pages, whose
         # nonfinite residue must never touch live rows
         pos = jnp.where(write_mask, (start + offs)[None], -1)
-        logits, cache, _ = model_forward(params, chunk, cfg, cache=cache,
-                                         positions=pos,
-                                         block_table=block_table,
-                                         paged_write_mask=write_mask,
-                                         paged_impl=paged_impl,
-                                         paged_chunk=True)
+        logits, cache, _, *counts = model_forward(
+            params, chunk, cfg, cache=cache, positions=pos,
+            block_table=block_table, paged_write_mask=write_mask,
+            paged_impl=paged_impl, paged_chunk=True, moe_counts=moe_counts)
         last = jnp.take_along_axis(
             logits, jnp.maximum(n_valid - 1, 0)[None, None, None],
             axis=1)[:, 0].astype(jnp.float32)
         tok = sample_rows(last, base_key, temperature, top_k, top_p,
                           seeds, jnp.zeros((1,), jnp.int32))
+        if moe_counts:
+            tok = _with_moe_counts(tok, counts[0])
         return tok, last, cache
 
     return jax.jit(prefill_chunk, donate_argnums=(1,))
 
 
-def build_decode_program(cfg, paged_impl: str = "auto"):
+def build_decode_program(cfg, paged_impl: str = "auto",
+                         moe_counts: bool = False):
     """Jitted one-token decode step over the paged arena for a fixed row
     count R. Inactive rows carry an all-zero block table and length 0 — their
     writes land in the scratch block and their sampled tokens are ignored by
@@ -430,19 +442,29 @@ def build_decode_program(cfg, paged_impl: str = "auto"):
     tokens (R,) int32, temperature/top_k/top_p/seeds (R,), steps (R,) int32
     (each row's output-token index, for the schedule-independent sampling
     stream), base_key.
-    Returns (next_token (R,), cache).
+    Returns (next_token (R,), cache). An MoE model keeps rows of length 0
+    out of the routing; with ``moe_counts`` (the serving engine's own
+    program) ``next_token`` is (R + 3,): the tokens, then the step's routing
+    counts over the rows that hold a request (``_with_moe_counts``).
     """
     from ..models.transformer import forward as model_forward
 
     def decode(params, cache, block_table, lengths, tokens,
                temperature, top_k, top_p, seeds, steps, base_key):
-        logits, cache, _ = model_forward(params, tokens[:, None], cfg,
-                                         cache=cache,
-                                         positions=lengths[:, None],
-                                         block_table=block_table,
-                                         paged_impl=paged_impl)
+        # a row that holds a request has at least its prompt in the cache.
+        # The mask also sends an empty row's write to the scratch block,
+        # which is where its all-zero table sent it anyway. A dense model
+        # routes nothing, and its program stays as it was.
+        live = (lengths > 0)[:, None] if cfg.moe_num_experts > 0 else None
+        logits, cache, _, *counts = model_forward(
+            params, tokens[:, None], cfg, cache=cache,
+            positions=lengths[:, None], block_table=block_table,
+            paged_write_mask=live, paged_impl=paged_impl,
+            moe_counts=moe_counts)
         nxt = sample_rows(logits[:, -1], base_key, temperature, top_k,
                           top_p, seeds, steps)
+        if moe_counts:
+            nxt = _with_moe_counts(nxt, counts[0])
         return nxt, cache
 
     return jax.jit(decode, donate_argnums=(1,))

@@ -16,8 +16,10 @@ import jax.numpy as jnp
 import pytest
 
 from deepspeed_tpu.ops import (decode_attention, flash_attention,
-                               fused_layer_norm, paged_decode_attention,
+                               fused_layer_norm, moe_grouped_matmul,
+                               paged_decode_attention,
                                paged_prefill_attention)
+from deepspeed_tpu.ops.moe_grouped_matmul import max_tiles, tile_rows
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -89,10 +91,32 @@ def _dense_decode(b, t, heads, d):
             [((b, heads, d), BF16), cache, cache, ((b, t), I32)])
 
 
+def _grouped_matmul(tokens, top_k, experts, k, n):
+    """The expert matmul of `tokens` x `top_k` assignments laid out in
+    tiles, as parallel/moe.py calls it."""
+    tm = tile_rows(tokens * top_k, experts, BF16)
+    tiles = max_tiles(tokens * top_k, experts, tm)
+    return (moe_grouped_matmul,
+            [((tiles * tm, k), BF16), ((experts, k, n), BF16),
+             ((tiles,), I32), ((1,), I32)])
+
+
 # gpt2-125m: 12 heads x 64, hidden 768, seq 1024, micro-batch 32.
 # opt-1.3b: 32 heads x 64, hidden 2048, seq 2048; serving block 16,
 # chunk 256, 16 decode rows, 128 blocks per sequence.
+# olmoe-1b-7b: 16 heads x 128, hidden 2048, 8 of 64 experts of width 1024;
+# served like opt-1.3b.
 CASES = {
+    "paged-decode-olmoe-1b-7b": lambda: _paged_decode(16, 16, 128, 16, 128),
+    "paged-prefill-olmoe-1b-7b": lambda: _paged_prefill(256, 16, 128, 16, 128),
+    "moe-up-decode-olmoe-1b-7b":
+        lambda: _grouped_matmul(16, 8, 64, 2048, 1024),
+    "moe-down-decode-olmoe-1b-7b":
+        lambda: _grouped_matmul(16, 8, 64, 1024, 2048),
+    "moe-up-prefill-olmoe-1b-7b":
+        lambda: _grouped_matmul(256, 8, 64, 2048, 1024),
+    "moe-down-prefill-olmoe-1b-7b":
+        lambda: _grouped_matmul(256, 8, 64, 1024, 2048),
     "flash-fwd-gpt2-125m": lambda: _flash(32, 1024, 12, 64, grad=False),
     "flash-bwd-gpt2-125m": lambda: _flash(32, 1024, 12, 64, grad=True),
     "flash-fwd-opt-1.3b": lambda: _flash(4, 2048, 32, 64, grad=False),
@@ -132,7 +156,8 @@ _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\) -> .*\{$")
 _RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
 
 
-def _serving_program(kind, v5e, monkeypatch, paged_impl="auto"):
+def _serving_program(kind, v5e, monkeypatch, paged_impl="auto",
+                     preset="opt-1.3b", **program_options):
     """``build_decode_program`` / ``build_prefill_program`` lowered for the
     described chip on its kernel path (``jax.default_backend()`` is the CPU
     here, so ``_kernels_active`` is steered)."""
@@ -142,7 +167,7 @@ def _serving_program(kind, v5e, monkeypatch, paged_impl="auto"):
     from deepspeed_tpu.serving import paged_kv
 
     monkeypatch.setattr(T, "_kernels_active", lambda: True)
-    cfg = transformer_config("opt-1.3b", dtype=BF16, num_layers=LAYERS)
+    cfg = transformer_config(preset, dtype=BF16, num_layers=LAYERS)
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
@@ -159,11 +184,13 @@ def _serving_program(kind, v5e, monkeypatch, paged_impl="auto"):
     key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     if kind == "decode":
         r = ROWS
-        return paged_kv.build_decode_program(cfg, paged_impl).lower(
+        return paged_kv.build_decode_program(
+            cfg, paged_impl, **program_options).lower(
             params, arena, arg((r, MAXB), I32), arg((r,), I32),
             arg((r,), I32), arg((r,), F32), arg((r,), I32), arg((r,), F32),
             arg((r,), I32), arg((r,), I32), key)
-    return paged_kv.build_prefill_program(cfg, paged_impl).lower(
+    return paged_kv.build_prefill_program(
+        cfg, paged_impl, **program_options).lower(
         params, arena, arg((1, MAXB), I32), arg((1, CHUNK), I32),
         arg((), I32), arg((), I32), arg((1,), F32), arg((1,), I32),
         arg((1,), F32), arg((1,), I32), key)
@@ -218,3 +245,26 @@ def test_serving_program_never_copies_a_pool(v5e, monkeypatch, kind,
     if paged_impl == "auto":
         assert calls >= 1, f"no custom call named {kernel}"
         assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES
+
+
+@pytest.mark.parametrize("kind,rows", [("decode", ROWS), ("prefill", CHUNK)])
+def test_olmoe_serving_program_computes_assigned_rows_only(v5e, monkeypatch,
+                                                           kind, rows):
+    """OLMoE-1B-7B's two serving programs (3 layers) for the chip: the
+    expert compute is the kernel `moe_grouped_matmul` (gate, up, down), no
+    tensor has the (experts, rows, hidden) shape of the capacity path's
+    dispatch with C = T, the paged kernel is there at head size 128 and no
+    pool-sized temporary is."""
+    compiled = _serving_program(kind, v5e, monkeypatch, preset="olmoe-1b-7b",
+                                moe_counts=True).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "custom-call" in ln
+             and "tpu_custom_call" in ln]
+    assert sum("moe_grouped_matmul" in ln for ln in calls) == 3
+    assert sum(f"paged_{kind}_attention" in ln for ln in calls) == 1
+    for width in (2048, 1024):
+        assert f"[64,{rows},{width}]" not in text
+    # nor is a layer's bank of experts copied out of the stack for the kernel
+    assert "bf16[64,2048,1024]" not in text
+    assert "bf16[64,1024,2048]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES

@@ -215,6 +215,25 @@ class TestMoEV2:
         # every token keeps both its experts
         assert float(out.dispatch.sum()) == 128.0
 
+    @pytest.mark.parametrize("k", [1, 2, 3, 8])
+    def test_general_top_k_dispatch_conservation(self, k):
+        """One plan for any k: with no drops every token holds k distinct
+        (expert, slot) pairs, a slot holds one token, and the weights are
+        the chosen probabilities (renormalised for k > 1 unless told not)."""
+        from deepspeed_tpu.parallel.moe import topk_plan
+
+        logits = jax.random.normal(jax.random.PRNGKey(k), (32, 8))
+        plan = topk_plan(logits, k, drop_tokens=False)
+        assert plan.expert_idx.shape == (32, k) and bool(plan.valid.all())
+        slots = np.asarray(plan.expert_idx * plan.capacity + plan.slot_pos)
+        assert len(set(slots.ravel().tolist())) == 32 * k
+        gates = np.asarray(jax.nn.softmax(logits, axis=-1))
+        picked = np.sort(gates, axis=-1)[:, ::-1][:, :k]
+        want = picked / picked.sum(-1, keepdims=True) if k > 1 else picked
+        np.testing.assert_allclose(np.asarray(plan.weight), want, rtol=1e-6)
+        raw = topk_plan(logits, k, drop_tokens=False, normalize=False)
+        np.testing.assert_allclose(np.asarray(raw.weight), picked, rtol=1e-6)
+
     def test_rts_top2_rejected(self):
         from deepspeed_tpu.parallel.moe import moe_mlp
 
@@ -297,6 +316,20 @@ class TestSparseDispatch:
         b, aux_b = moe_mlp(x, router, experts, act,
                            dispatch_impl="einsum", **kw)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("top_k", [1, 2, 3])
+    def test_inference_grouped_path_matches_einsum_without_drops(self, top_k):
+        """At inference the dropless grouped path (assigned rows only) gives
+        what the (E, C, H) einsum path gives with C = T."""
+        from deepspeed_tpu.parallel.moe import moe_mlp
+
+        x, router, experts = self._setup(seed=9)
+        dense, _ = moe_mlp(x, router, experts, "swiglu", top_k=top_k,
+                           drop_tokens=False, dispatch_impl="einsum")
+        grouped, _ = moe_mlp(x, router, experts, "swiglu", top_k=top_k,
+                             infer=True)
+        np.testing.assert_allclose(np.asarray(grouped), np.asarray(dense),
                                    rtol=1e-5, atol=1e-6)
 
     def test_grads_match(self):
