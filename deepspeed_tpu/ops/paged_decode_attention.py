@@ -7,13 +7,24 @@ per step (``arena[block_table]``), so every decode token pays HBM traffic
 proportional to the *pool view*, not the tokens actually resident. These
 kernels walk each row's block table instead and DMA only **resident** pages:
 
-* ``paged_decode_attention`` — single-query decode. Grid ``(R, MAXB)``; the
-  block table and per-row lengths ride as scalar-prefetch operands, so the
-  k/v BlockSpec index maps resolve ``table[row, page]`` *before* the pipeline
-  issues the page's DMA. Non-resident trailing pages re-request the row's
-  last resident page — consecutive identical block indices make the Pallas
-  pipeline skip the copy, so a row with 3 live pages out of 64 costs 3 page
-  DMAs, not 64. GQA-native (KV heads never expanded), alibi in-kernel.
+* ``paged_decode_attention`` — single-query decode. The grid runs over the
+  rows only and the arenas stay in HBM (``pl.ANY``): a row's step walks ITS
+  resident pages itself, ``ceil(length / BLOCK)`` of them read from the
+  scalar-prefetched table, a tile of P pages at a time — one async copy a
+  page, ``arena[layer, table[row, page]]`` into slot p of one of two
+  ``(P, BLOCK, K*D)`` VMEM buffers, the next tile's copies (or, after a
+  row's last tile, the first tile of the row below) started before the
+  current tile is computed. Nothing is issued, and no step taken, for a
+  table slot past a row's last resident page: the work follows the tokens
+  in the cache, not ``max_model_len``. P is derived (``_pages_per_tile``:
+  what k + v, two buffers each, fit the VMEM budget, up to 256 keys a
+  tile). All heads of a tile are scored in ONE product against q laid out
+  block-diagonally over the ``K*D`` lanes, and weigh the values in one
+  more: bf16 keys and values as stored, scores, softmax state and
+  accumulator in float32, ``p`` into the value product as float32 (three
+  bf16 terms stacked into the one product, ``_dot_f32``). GQA-native (KV
+  heads never expanded), alibi in-kernel; a tile's tail past the row's
+  length is masked by true position, in the scores and in v.
 * ``paged_prefill_attention`` — the chunked-prefill mate: C queries at
   absolute positions ``start..start+C-1`` read prior context through the
   same table, flash-accumulating page by page (grid ``(B, K, MAXB)``), so a
@@ -63,10 +74,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
-# k + v pages, double-buffered by the pipeline — ONE budget shared with
-# the dense decode kernel's tile sizing
+# k + v pages (prefill) or tiles of pages (decode), two buffers each — ONE
+# budget shared with the dense decode kernel's tile sizing
 from .decode_attention import VMEM_KV_BUDGET as _VMEM_PAGE_BUDGET
 from .decode_attention import tiled_vmem_bytes
+
+# keys a tile of the decode walk holds at most (and at least 128, where the
+# budget allows): see ``_pages_per_tile``
+_TILE_KEYS = 256
 
 
 def _check_page_fits(block_size: int, width: int, dtype) -> None:
@@ -102,62 +117,157 @@ def _layer_operand(layer) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, alibi_ref,
-                   o_ref, acc, m_scr, l_scr, *, scale: float, bs: int,
-                   n_heads: int, kv_heads: int, has_alibi: bool):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    G = n_heads // kv_heads
-    length = len_ref[b]
+def _pages_per_tile(block_size: int, width: int, dtype) -> int:
+    """Pages one tile of the decode walk holds — derived, not set: the
+    largest power of two whose k + v tiles, two buffers each, fit the VMEM
+    budget as VMEM lays them out, capped at ``_TILE_KEYS`` keys a tile (at
+    least one page: ``_check_page_fits`` guards that one)."""
+    pages = 1
+    while (2 * pages * block_size <= _TILE_KEYS
+           and 4 * tiled_vmem_bytes(2 * pages * block_size, width, dtype)
+           <= _VMEM_PAGE_BUDGET):
+        pages *= 2
+    return pages
 
-    @pl.when(j == 0)
-    def _init():
+
+def _dot_f32(a, b, b_dim: int):
+    """``a`` (M, C) times ``b``, contracted over ``b``'s dim ``b_dim``,
+    summed in float32 with every bit of ``a``, whatever ``b``'s dtype: ``a``
+    goes in as the sum of as many terms of that dtype as hold it exactly (a
+    float32 is three bfloat16), stacked along the rows of ONE product. So
+    few rows stream through the MXU here that more of them cost little, where
+    a float32 product would cost six passes and a cast of ``b``."""
+    M = a.shape[0]
+    terms = pl.cdiv(jnp.finfo(a.dtype).nmant + 1, jnp.finfo(b.dtype).nmant + 1)
+    parts, rest = [], a.astype(jnp.float32)
+    for _ in range(terms - 1):
+        parts.append(rest.astype(b.dtype).astype(jnp.float32))
+        rest = rest - parts[-1]
+    stacked = jnp.concatenate(parts + [rest], axis=0).astype(b.dtype)
+    out = jax.lax.dot_general(stacked, b, (((1,), (b_dim,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    return sum(out[i * M:(i + 1) * M] for i in range(terms))
+
+
+def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
+                   o_ref, kbuf, vbuf, sems, lane, diag, qbd, acc, m_scr,
+                   l_scr, slot_ref, *, scale: float, n_heads: int,
+                   kv_heads: int, has_alibi: bool):
+    r = pl.program_id(0)
+    R = pl.num_programs(0)
+    _, P, BS, W = kbuf.shape
+    TK = P * BS
+    N, D = q_ref.shape[1:]
+    G = n_heads // kv_heads
+    layer = layer_ref[0]
+    length = len_ref[r]
+    n_tiles = pl.cdiv(length, TK)
+
+    def each_copy(row, tile, slot, wait=False):
+        """Start (or wait for) the copy of every RESIDENT page of ``row``'s
+        tile ``tile`` into buffer ``slot``: nothing for a table slot past
+        the row's last resident page."""
+        first = tile * P
+        resident = jnp.minimum(pl.cdiv(len_ref[row], BS) - first, P)
+
+        def page(p, carry):
+            blk = bt_ref[row, first + p]
+            for side, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                copy = pltpu.make_async_copy(hbm.at[layer, blk],
+                                             buf.at[slot, p],
+                                             sems.at[side, slot])
+                copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, resident, page, 0)
+
+    @pl.when(r == 0)
+    def _first_row():
+        slot_ref[0] = 0
+        # all heads of a tile are scored in one product against q laid out
+        # block-diagonally, (N, K*D): row n holds q[n] in its KV head's D
+        # lanes. ``lane`` tiles a (., D) array K times along the lanes (and
+        # takes the blocks back out of the accumulator at the end): each
+        # output is a single term, so exact. ``diag`` keeps a head's own block
+        lane[:] = (jax.lax.broadcasted_iota(jnp.int32, (D, W), 1) % D
+                   == jax.lax.broadcasted_iota(jnp.int32, (D, W), 0)
+                   ).astype(lane.dtype)
+        diag[:] = (jax.lax.broadcasted_iota(jnp.int32, (N, W), 1) // D
+                   == jax.lax.broadcasted_iota(jnp.int32, (N, W), 0) // G
+                   ).astype(diag.dtype)
+
+    @pl.when(n_tiles == 0)
+    def _empty_row():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(n_tiles > 0)
+    def _row():
+        # the buffer this row's first tile is in: the row above started it
+        # beside its own last tile, unless that row was empty (or is none)
+        base = slot_ref[0]
+
+        @pl.when((r == 0) | (len_ref[jnp.maximum(r - 1, 0)] == 0))
+        def _own_first_tile():
+            each_copy(r, 0, base)
+
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
+        qbd[:] = (_dot_f32(q_ref[0], lane[:], 0) * diag[:]).astype(qbd.dtype)
 
-    # page j holds positions [j*bs, (j+1)*bs) — all-future pages are skipped
-    # (their DMA was already elided by the clamped index map)
-    @pl.when(j * bs < length)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale      # (N, D)
-        D = q.shape[-1]
-        k = k_ref[0].astype(jnp.float32)              # (bs, K*D)
-        v = v_ref[0].astype(jnp.float32)              # (bs, K*D)
-        parts = []
-        for kh in range(kv_heads):
-            qg = q[kh * G:(kh + 1) * G]               # (G, D) static slice
-            parts.append(jax.lax.dot_general(
-                qg, k[:, kh * D:(kh + 1) * D], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))  # (G, bs)
-        s = jnp.concatenate(parts, axis=0)            # (N, bs)
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        if has_alibi:
-            # left-aligned layout: the page column IS the key position
-            s = s + alibi_ref[0][:, None] * col.astype(jnp.float32)
-        s = jnp.where(col < length, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_scr.shape)
-        outs = []
-        for kh in range(kv_heads):
-            pg = p[kh * G:(kh + 1) * G]
-            outs.append(jax.lax.dot_general(
-                pg, v[:, kh * D:(kh + 1) * D], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-        acc[:] = acc[:] * corr + jnp.concatenate(outs, axis=0)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        def tile(t, carry):
+            slot = (base + t) % 2
+            # the next tile's copies go out before this one is computed:
+            # this row's, or after its last the first of the row below
+            @pl.when(t + 1 < n_tiles)
+            def _next_tile():
+                each_copy(r, t + 1, 1 - slot)
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        safe = jnp.where(l == 0.0, 1.0, l)            # length-0 rows → 0
-        o_ref[0] = (acc[:] / safe).astype(o_ref.dtype)
+            @pl.when((t + 1 == n_tiles) & (r + 1 < R))
+            def _next_row():
+                each_copy(jnp.minimum(r + 1, R - 1), 0, 1 - slot)
+
+            each_copy(r, t, slot, wait=True)
+
+            # a tile's tail past the row's length holds what was there
+            # before: another row's pages, or whatever the buffer started
+            # with. Scores are masked below; v is zeroed: 0 * NaN is NaN
+            @pl.when((t + 1) * TK > length)
+            def _zero_tail():
+                pos = (t * TK
+                       + jax.lax.broadcasted_iota(jnp.int32, (P, BS, W), 0)
+                       * BS
+                       + jax.lax.broadcasted_iota(jnp.int32, (P, BS, W), 1))
+                vbuf[slot] = jnp.where(pos < length,
+                                       vbuf[slot].astype(jnp.float32),
+                                       0.0).astype(vbuf.dtype)
+
+            k = kbuf[slot].reshape(TK, W).astype(qbd.dtype)
+            s = jax.lax.dot_general(
+                qbd[:], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # (N, TK)
+            col = t * TK + jax.lax.broadcasted_iota(jnp.int32, (1, TK), 1)
+            if has_alibi:
+                # left-aligned layout: the tile's column IS the key position
+                s = s + alibi_ref[0][:, None] * col.astype(jnp.float32)
+            s = jnp.where(col < length, s, NEG_INF)
+            m_prev = m_scr[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[:] = jnp.broadcast_to(
+                corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+                l_scr.shape)
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            # p goes into the value product as float32, not rounded to v's
+            acc[:] = acc[:] * corr + _dot_f32(
+                p, vbuf[slot].reshape(TK, W), 0)               # (N, K*D)
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, tile, 0)
+        slot_ref[0] = (base + n_tiles) % 2
+        out = _dot_f32(acc[:] * diag[:], lane[:], 1)           # (N, D)
+        o_ref[0] = (out / l_scr[:, :1]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
@@ -174,46 +284,48 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
     Returns (R, N, D). Reads only each row's resident pages of that layer."""
     R, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
-    BS = k_arena.shape[2]
-    MAXB = block_table.shape[1]
-    _check_page_fits(BS, K * D, k_arena.dtype)
+    BS, W = k_arena.shape[2:]
+    _check_page_fits(BS, W, k_arena.dtype)
+    pages = _pages_per_tile(BS, W, k_arena.dtype)
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
     alibi_arr = (alibi.astype(jnp.float32).reshape(1, N) if has_alibi
                  else jnp.zeros((1, N), jnp.float32))
-
-    def _page(b, j, bt_ref, len_ref, layer_ref):
-        # clamp to the row's last resident page: trailing grid steps
-        # re-request the same block index, which the pipeline recognizes
-        # and skips the DMA — only resident pages move
-        last = jnp.maximum((len_ref[b] + BS - 1) // BS - 1, 0)
-        return (layer_ref[0], bt_ref[b, jnp.minimum(j, last)], 0, 0)
-
+    # the products take q and the keys in the wider of their two dtypes
+    pd = jnp.promote_types(q.dtype, k_arena.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(R, MAXB),
+        grid=(R,),
         in_specs=[
-            pl.BlockSpec((1, N, D), lambda b, j, *_: (b, 0, 0)),
-            # the layer dim is squeezed: the kernel sees (1, BS, K*D) pages
-            pl.BlockSpec((None, 1, BS, K * D), _page),
-            pl.BlockSpec((None, 1, BS, K * D), _page),
-            pl.BlockSpec((1, N), lambda b, j, *_: (0, 0)),
+            pl.BlockSpec((1, N, D), lambda r, *_: (r, 0, 0)),
+            # the arenas stay where they lie: the kernel copies pages itself
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, N), lambda r, *_: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, N, D), lambda b, j, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, N, D), lambda r, *_: (r, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((N, D), jnp.float32),
+            pltpu.VMEM((2, pages, BS, W), k_arena.dtype),
+            pltpu.VMEM((2, pages, BS, W), v_arena.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),          # (k | v, buffer)
+            pltpu.VMEM((D, W), jnp.bfloat16),         # lane, 0/1: exact
+            pltpu.VMEM((N, W), jnp.float32),          # diag
+            pltpu.VMEM((N, W), pd),                   # block-diagonal q
+            pltpu.VMEM((N, W), jnp.float32),
             pltpu.VMEM((N, LANES), jnp.float32),
             pltpu.VMEM((N, LANES), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
-    kernel = functools.partial(_decode_kernel, scale=scale, bs=BS,
-                               n_heads=N, kv_heads=K, has_alibi=has_alibi)
+    kernel = functools.partial(_decode_kernel, scale=scale, n_heads=N,
+                               kv_heads=K, has_alibi=has_alibi)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, N, D), q.dtype),
+        # rows in order: a row starts the copies of the next one's first tile
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name="paged_decode_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),

@@ -32,6 +32,10 @@ from deepspeed_tpu.ops import (decode_attention, paged_decode_attention,
                                reference_decode_attention,
                                reference_paged_attention)
 
+# the module, which ``deepspeed_tpu.ops`` shadows with the function of its name
+paged_module = importlib.import_module(
+    "deepspeed_tpu.ops.paged_decode_attention")
+
 INTERPRET = True
 LAYERS = (0, 1, 2)      # first, middle, last of the 3-layer arena
 
@@ -59,6 +63,47 @@ def _ragged_tables(bs=16, maxb=4):
     bt[2, :4] = [8, 2, 4, 6]
     lengths = np.array([bs * 2 + 5, 9, bs * 4], np.int32)
     return jnp.asarray(bt), jnp.asarray(lengths)
+
+
+# the decode walk's edges, in keys: a table of 40 pages is two and a half
+# tiles of the 16 pages that the arenas of these tests give a tile
+BS, MAXB = 16, 40
+TILE = 16 * BS
+WALKS = {
+    "empty": (0, 0, 0),
+    "one-token": (1, 1, 1),
+    "one-page": (BS, BS, BS),
+    "one-tile": (TILE, TILE, TILE),
+    "tile-plus-one": (TILE + 1, TILE + 1, TILE + 1),
+    "whole-table": (MAXB * BS, MAXB * BS, MAXB * BS),
+    # very different rows in one call, empty ones between the others: a
+    # row's first tile is started by the row above, unless that one is empty
+    "mixed": (0, 1, TILE + 1, 0, 0, MAXB * BS, BS, TILE, 2 * TILE + 5, 0),
+}
+
+
+def _walk_tables(lengths, nb, maxb=MAXB, seed=0):
+    """A table for rows of these lengths: every row's pages drawn from one
+    shuffle of the pool, so neither contiguous nor ascending; unfilled
+    entries 0, the scratch block."""
+    pages = np.random.default_rng(seed).permutation(np.arange(1, nb))
+    bt, at = np.zeros((len(lengths), maxb), np.int32), 0
+    for r, n in enumerate(lengths):
+        held = -(-n // BS)
+        bt[r, :held] = pages[at:at + held]
+        at += held
+    assert at <= nb - 1
+    return jnp.asarray(bt), jnp.asarray(np.asarray(lengths, np.int32))
+
+
+def _poison_what_no_row_reads(arena, bt, lengths):
+    """NaN in every key slot past a row's length: the tail of its last page,
+    and every page that no row holds (block 0 among them)."""
+    live = np.zeros(arena.shape[1:3], bool)
+    for row, n in zip(np.asarray(bt), np.asarray(lengths)):
+        for j in range(-(-int(n) // BS)):
+            live[row[j], :min(BS, int(n) - j * BS)] = True
+    return jnp.where(jnp.asarray(live)[None, :, :, None], arena, jnp.nan)
 
 
 def _dense_view(arena, layer, bt, d=32):
@@ -95,6 +140,94 @@ class TestPagedDecodeKernel:
                               lengths[:, None] - 1, alibi=al)[:, 0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("n,k,d", [(4, 2, 32), (8, 2, 32), (2, 2, 128)])
+    @pytest.mark.parametrize("walk", sorted(WALKS))
+    def test_walk_matches_reference_at_its_edges(self, walk, n, k, d):
+        """Lengths of 0, 1, a page, exactly a tile, a tile and a token, the
+        whole table, and all of them in one call; what lies past a row's
+        length is NaN, in k and in v, and never reaches the output."""
+        assert paged_module._pages_per_tile(BS, k * d, jnp.float32) * BS \
+            == TILE
+        ka, va = _arena(nb=161, k=k, d=d, seed=1)
+        bt, lengths = _walk_tables(WALKS[walk], 161)
+        q = jax.random.normal(jax.random.PRNGKey(12), (len(lengths), n, d))
+        ref = _pool_reference(q[:, None], ka, va, 1, bt,
+                              lengths[:, None] - 1)[:, 0]
+        out = paged_decode_attention(
+            q, _poison_what_no_row_reads(ka, bt, lengths),
+            _poison_what_no_row_reads(va, bt, lengths), 1, bt, lengths,
+            interpret=INTERPRET)
+        assert bool(jnp.all(jnp.isfinite(out)))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        empty = np.asarray(lengths) == 0
+        assert not np.asarray(out)[empty].any()
+
+    @pytest.mark.parametrize("walk", ["mixed", "whole-table"])
+    def test_walk_alibi_over_several_tiles(self, walk):
+        ka, va = _arena(nb=161, k=2, seed=2)
+        bt, lengths = _walk_tables(WALKS[walk], 161, seed=3)
+        n = 4
+        q = jax.random.normal(jax.random.PRNGKey(13), (len(lengths), n, 32))
+        al = alibi_slopes(n)
+        out = paged_decode_attention(q, ka, va, 2, bt, lengths, alibi=al,
+                                     interpret=INTERPRET)
+        ref = _pool_reference(q[:, None], ka, va, 2, bt,
+                              lengths[:, None] - 1, alibi=al)[:, 0]
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_walk_in_the_served_dtype(self, dtype=jnp.bfloat16, tol=2e-2):
+        """bf16 as served: p goes into the value product as float32 (three
+        bf16 terms), so the kernel lies closer to the reference computed in
+        float32 from the same bf16 arena than bf16's own step."""
+        ka, va = _arena(nb=161, k=2, seed=4, dtype=dtype)
+        bt, lengths = _walk_tables(WALKS["mixed"], 161, seed=5)
+        q = jax.random.normal(jax.random.PRNGKey(14),
+                              (len(lengths), 4, 32), dtype)
+        out = paged_decode_attention(q, ka, va, 0, bt, lengths,
+                                     interpret=INTERPRET)
+        assert out.dtype == dtype
+        f32 = jnp.float32
+        ref = _pool_reference(q.astype(f32)[:, None], ka.astype(f32),
+                              va.astype(f32), 0, bt, lengths[:, None] - 1)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref[:, 0]), atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("maxb", [MAXB, 128, 512])
+    @pytest.mark.parametrize("walk", ["mixed", "one-token", "empty"])
+    def test_copies_follow_resident_pages_not_the_table(self, walk, maxb,
+                                                        monkeypatch):
+        """What the PR is for, without a chip: the kernel starts one copy of
+        k and one of v for each RESIDENT page, ceil(length / BLOCK) a row,
+        and none for a table slot past it, however wide the table is."""
+        started = []
+
+        class Counted:
+            def __init__(self, copy):
+                self.copy = copy
+
+            def start(self):
+                jax.debug.callback(lambda: started.append(1))
+                self.copy.start()
+
+            def wait(self):
+                self.copy.wait()
+
+        real = paged_module.pltpu.make_async_copy
+        monkeypatch.setattr(paged_module.pltpu, "make_async_copy",
+                            lambda *a: Counted(real(*a)))
+        lengths = WALKS[walk]
+        ka, va = _arena(nb=161, k=2, seed=6)
+        bt, lens = _walk_tables(lengths, 161, maxb=maxb)
+        q = jax.random.normal(jax.random.PRNGKey(15), (len(lengths), 4, 32))
+        out = paged_decode_attention(q, ka, va, 1, bt, lens,
+                                     interpret=INTERPRET)
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+        resident = sum(-(-n // BS) for n in lengths)
+        assert len(started) == 2 * resident
 
     def test_inactive_row_outputs_zero(self):
         ka, va = _arena()

@@ -71,8 +71,9 @@ def _layer_norm(rows, t, e, grad):
     return fn, [((rows, t, e), BF16), ((e,), F32), ((e,), F32)]
 
 
-def _paged_decode(rows, heads, d, block, maxb):
-    arena = ((3, 1024, block, heads * d), BF16)    # layer: a traced scalar
+def _paged_decode(rows, heads, d, block, maxb, kv_heads=None):
+    # layer: a traced scalar
+    arena = ((3, 1024, block, (kv_heads or heads) * d), BF16)
     return (paged_decode_attention,
             [((rows, heads, d), BF16), arena, arena, ((), I32),
              ((rows, maxb), I32), ((rows,), I32)])
@@ -129,6 +130,9 @@ CASES = {
     "paged-decode-opt-1.3b": lambda: _paged_decode(16, 32, 64, 16, 128),
     "paged-prefill-gpt2-125m": lambda: _paged_prefill(256, 12, 64, 16, 64),
     "paged-prefill-opt-1.3b": lambda: _paged_prefill(256, 32, 64, 16, 128),
+    # the whole table of 2,048 tokens under GQA: 32 heads over 8 KV heads
+    "paged-decode-gqa-full-table":
+        lambda: _paged_decode(16, 32, 128, 16, 128, kv_heads=8),
     "dense-decode-gpt2-125m": lambda: _dense_decode(8, 1024, 12, 64),
     "dense-decode-opt-1.3b": lambda: _dense_decode(8, 2048, 32, 64),
 }
@@ -196,6 +200,61 @@ def _serving_program(kind, v5e, monkeypatch, paged_impl="auto",
         arg((1,), F32), arg((1,), I32), key)
 
 
+def _fusion_roots(text):
+    """The op at the root of each computation of an optimised HLO module."""
+    roots, current = {}, None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = head.group(1)
+        m = _RESULT.match(line)
+        if m and line.lstrip().startswith("ROOT"):
+            roots[current] = m.group(3)
+    return roots
+
+
+def _writes_in_place(line, op, roots):
+    """An op that hands the arena along or scatters into it where it lies."""
+    if op in ("parameter", "get-tuple-element", "tuple", "while", "scatter"):
+        return True
+    return op == "fusion" and roots.get(
+        re.search(r"calls=%([\w.\-]+)", line).group(1)) == "scatter"
+
+
+# the decode program's temporaries at the parent of PR 29, whose kernel kept
+# one 16-token page a side in VMEM: the walk's tiles are VMEM too, not HBM
+PARENT_DECODE_TEMP_BYTES = {"opt-1.3b": 4_268_032, "olmoe-1b-7b": 4_364_800}
+
+
+@pytest.mark.parametrize("preset", sorted(PARENT_DECODE_TEMP_BYTES))
+def test_decode_walk_reads_the_arena_where_it_lies(v5e, monkeypatch, preset):
+    """`jit_decode` at the cells' widths with the kernel that walks the
+    pages itself: its k and v operands (left in HBM, `pl.ANY`) are the arena
+    as the layer's scatter wrote it, not a copy of it, and the program's
+    temporaries are no larger than the parent's."""
+    options = {"moe_counts": True} if preset.startswith("olmoe") else {}
+    compiled = _serving_program("decode", v5e, monkeypatch, preset=preset,
+                                **options).compile()
+    text = compiled.as_text()
+    roots = _fusion_roots(text)
+    made_by = {}
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m:
+            made_by[m.group(1)] = (line, m.group(3))
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "paged_decode_attention" in ln]
+    assert len(calls) == 1
+    operands = re.findall(r"%([\w.\-]+)",
+                          calls[0].split("custom-call(", 1)[1].split(")")[0])
+    for name in operands[4:6]:                 # after 3 scalars and q
+        line, op = made_by[name]
+        assert f"bf16[{LAYERS},{NUM_BLOCKS},{BLOCK},2048]" in line
+        assert _writes_in_place(line, op, roots), line.strip()[:200]
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= PARENT_DECODE_TEMP_BYTES[preset])
+
+
 @pytest.mark.parametrize("kind,paged_impl", [
     ("decode", "auto"), ("prefill", "auto"), ("decode", "gather")])
 def test_serving_program_never_copies_a_pool(v5e, monkeypatch, kind,
@@ -210,15 +269,7 @@ def test_serving_program_never_copies_a_pool(v5e, monkeypatch, kind,
     arena = f"bf16[{LAYERS},{NUM_BLOCKS},{BLOCK},2048]"
     kernel = f"paged_{kind}_attention"
     text = compiled.as_text()
-    # the op at the root of each fused computation
-    roots, current = {}, None
-    for line in text.splitlines():
-        head = _COMPUTATION.match(line)
-        if head:
-            current = head.group(1)
-        m = _RESULT.match(line)
-        if m and line.lstrip().startswith("ROOT"):
-            roots[current] = m.group(3)
+    roots = _fusion_roots(text)
     calls, offenders = 0, []
     for line in text.splitlines():
         m = _RESULT.match(line)
@@ -234,11 +285,7 @@ def test_serving_program_never_copies_a_pool(v5e, monkeypatch, kind,
             continue
         # what may have the arena's shape: the program's and the loop's
         # operands and results handed along, and the in-place scatter
-        if op in ("parameter", "get-tuple-element", "tuple", "while",
-                  "scatter"):
-            continue
-        if op == "fusion" and roots.get(
-                re.search(r"calls=%([\w.\-]+)", line).group(1)) == "scatter":
+        if _writes_in_place(line, op, roots):
             continue
         offenders.append(line.strip()[:200])
     assert not offenders, "\n".join(offenders)
