@@ -50,7 +50,7 @@ TARGET = {
 # engine's small arena, prefill activations and the 50k-wide logits).
 FULL = {
     "train": dict(model="gpt2-125m", seq=1024, micro_batch=32, steps=8,
-                  unroll=12),          # bench.py: all 12 layers unrolled
+                  unroll=12),          # all 12 layers unrolled
     "serve": dict(model="opt-1.3b", max_model_len=2048, block_size=16,
                   prefill_chunk=256, max_seqs=16, num_blocks=None,
                   requests=8, prompt_min=64, prompt_max=512, new_tokens=32),
@@ -64,8 +64,8 @@ TINY = {
     "zero": dict(model="tiny-opt", seq=64, micro_batch=2, steps=3),
 }
 ARENA_SHARE = 0.55
-# bf16 keeps 8 bits of mantissa: the kernel path (f32 softmax over bf16 KV)
-# and the gather path round differently in every one of 24 layers
+# bf16 keeps 8 bits of mantissa: the paged kernels (f32 softmax over bf16 KV)
+# and the plain forward round differently in every one of 24 layers
 LOGPROB_ATOL = 0.1
 LOSS_RTOL = 2e-2
 
@@ -264,7 +264,7 @@ def registered(name: str):
 
 
 def train_config(micro_batch: int, zero_stage: int, seed: int) -> dict:
-    """The shape of bench.py's config, observability off."""
+    """A plain AdamW bf16 training config, observability off."""
     return {
         "seed": seed,
         "train_micro_batch_size_per_gpu": micro_batch,
@@ -336,7 +336,7 @@ def phase_train(p: dict, seed: int) -> None:
 
 
 # ---------------------------------------------------------------------------
-# serve: opt-1.3b, one chip, Pallas paged kernels against the jnp gather read
+# serve: opt-1.3b, one chip, Pallas paged kernels against the plain forward
 
 
 def phase_serve(p: dict, seed: int) -> None:
@@ -344,7 +344,8 @@ def phase_serve(p: dict, seed: int) -> None:
     from deepspeed_tpu.inference.engine import InferenceConfig
     from deepspeed_tpu.inference.kv_cache import paged_cache_memory_bytes
     from deepspeed_tpu.models import create_model
-    from deepspeed_tpu.serving import ServingConfig, ServingEngine
+    from deepspeed_tpu.models.transformer import gather_target_logprobs
+    from deepspeed_tpu.serving import ServingConfig
 
     model = create_model(p["model"], dtype=jnp.bfloat16)
     cfg = model.config
@@ -369,7 +370,6 @@ def phase_serve(p: dict, seed: int) -> None:
                  f"bf16; arena {num_blocks} blocks x {p['block_size']} tokens "
                  f"= {arena_gb:.2f} GiB, {p['max_seqs']} decode rows, chunk "
                  f"{p['prefill_chunk']}")
-    # the plain engine below registers the same program names: keep these
     programs = [registered(name)
                 for name in ("serving/prefill_chunk", "serving/decode")]
 
@@ -403,31 +403,29 @@ def phase_serve(p: dict, seed: int) -> None:
     prompts, outs = serve_round("first round (compile included)")
     serve_round("second round (compiled, blocks recycled)")
 
-    # same weights, plain path: paged_kernel='off' reads the arena through a
-    # dense jnp gather. Score the longest prompt plus what was generated
+    # same weights, plain path: the forward over the whole sequence, no
+    # cache and no table. Score the longest prompt plus what was generated
     # for it on both (several chunks: later ones read earlier pages through
-    # the table) and serve the shortest prompt again.
-    plain = ServingEngine(serving.engine, ServingConfig(
-        max_seqs=2, num_blocks=0, paged_kernel="off", **shape))
+    # the table) and generate from the shortest prompt again.
     seq = np.concatenate([prompts[-1], outs[-1]])
     lp_kernel = serving.score_logprobs(seq)
-    lp_plain = plain.score_logprobs(seq)
+    lp_plain = np.asarray(gather_target_logprobs(
+        serving.engine.forward(seq[None, :-1]), jnp.asarray(seq[None, 1:])))[0]
     diff = float(np.abs(lp_kernel - lp_plain).max())
     say("serve", f"prefill log-probs over {len(seq)} tokens, paged kernels "
-                 f"vs jnp gather: max|diff| {diff:.3e} (tolerance "
+                 f"vs the plain forward: max|diff| {diff:.3e} (tolerance "
                  f"{LOGPROB_ATOL}, bf16), mean log-prob "
                  f"{float(lp_kernel.mean()):.3f}")
     check(np.isfinite(lp_kernel).all() and np.isfinite(lp_plain).all(),
           "non-finite log-probs")
     check(diff <= LOGPROB_ATOL,
-          f"paged kernels and gather read disagree: max|diff| {diff}")
-    again = plain.submit(prompts[0], max_new_tokens=p["new_tokens"]).result(
-        timeout_s=900.0)
+          f"paged kernels and the plain forward disagree: max|diff| {diff}")
+    again = np.asarray(serving.engine.generate(
+        prompts[0][None], max_new_tokens=p["new_tokens"]))[0]
     same = int((again == outs[0]).sum())
-    say("serve", f"greedy tokens, kernels vs gather, shortest prompt: {same} "
-                 f"of {len(again)} equal (near-ties of random weights may "
-                 f"flip in bf16)")
-    plain.close()
+    say("serve", f"greedy tokens, served vs generate(), shortest prompt: "
+                 f"{same} of {len(again)} equal (near-ties of random weights "
+                 f"may flip in bf16)")
     serving.close()
 
     for ep in programs:

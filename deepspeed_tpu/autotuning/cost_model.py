@@ -10,7 +10,7 @@ of the grid instead of sweeping it.
 
 Inputs come from the config's ``model_info`` section (the reference has the
 same section, ``autotuning.model_info.num_params``) plus the platform
-constants bench.py/bench_infer.py already use.
+constants below.
 """
 
 from __future__ import annotations
@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional
 
-HBM_BW = {  # bytes/s (the one table: bench_infer + tpucost read hbm_bw_for)
+HBM_BW = {  # bytes/s (the one table: tpucost reads hbm_bw_for)
     "v5 lite": 819e9, "v5e": 819e9, "v5litepod": 819e9,
     "v5p": 2765e9, "v4": 1228e9, "v6e": 1640e9, "v6 lite": 1640e9,
 }
@@ -59,15 +59,13 @@ def peak_flops_for(device_kind: Optional[str]) -> float:
     """bf16 peak FLOP/s for a ``device.device_kind`` string (v5e-class
     default for unknown kinds — CPU smoke runs get a real-chip denominator
     so MFU numbers stay comparable, just tiny). The shared lookup behind
-    bench.py's MFU math, the observability goodput/mfu gauge and tpucost's
-    roofline bound."""
+    the observability goodput/mfu gauge and tpucost's roofline bound."""
     return _platform(device_kind, PEAK_FLOPS, 197e12)
 
 
 def hbm_bw_for(device_kind: Optional[str]) -> float:
     """HBM bytes/s for a ``device.device_kind`` string (v5e-class default
-    for unknown kinds) — the other roofline denominator, shared by
-    bench_infer.py's decode roofline and tpucost."""
+    for unknown kinds) — the other roofline denominator (tpucost)."""
     return _platform(device_kind, HBM_BW, 819e9)
 
 

@@ -698,10 +698,6 @@ class ServingConfig(ConfigModel):
     #   EDF within a tenant) | 'fcfs' (arrival order)
     default_max_new_tokens: int = 64
     seed: int = 0                      # sampling stream seed
-    paged_kernel: str = "auto"         # 'auto': Pallas paged-attention
-    #   kernels when the platform supports them (GQA-native jnp paged
-    #   reference otherwise); 'off': the dense arena[block_table] gather
-    #   view — the A/B baseline (bench_infer --serving --paged-kernel)
     prefix_cache: bool = True          # content-hashed prompt-prefix
     #   sharing: cached full blocks join a new request's table by refcount
     #   (copy-on-write on first divergent write) and their prefill chunks
@@ -755,9 +751,6 @@ class ServingConfig(ConfigModel):
                               f"got '{self.fairness}'")
         if self.default_max_new_tokens < 1:
             raise ConfigError("serving.default_max_new_tokens must be >= 1")
-        if self.paged_kernel not in ("auto", "off"):
-            raise ConfigError("serving.paged_kernel must be 'auto' or "
-                              f"'off', got '{self.paged_kernel}'")
         self.speculative.validate()
         if (self.speculative.mode != "off"
                 and self.speculative.num_draft_tokens + 1

@@ -332,16 +332,6 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 import functools as _functools
 
 
-@_functools.lru_cache(maxsize=None)
-def _kernels_active() -> bool:
-    """True when the Pallas kernels are compatible with the current backend
-    (ops/registry platform probe). Evaluated once per process at trace time;
-    CPU/test runs keep the pure-jnp paths."""
-    from ..ops.registry import is_compatible
-
-    return is_compatible("flash_attention")
-
-
 def _tp_world() -> int:
     """Model-axis size of the AMBIENT mesh context at trace time — the
     quantized-GEMM Pallas route is single-shard only (a pallas_call over
@@ -469,14 +459,19 @@ def _flash_attention(q, k, v, mask, causal=True, alibi=None):
 def default_attention_impl() -> Callable:
     """Platform-resolved attention: Pallas flash attention on TPU, plain-jnp
     elsewhere. This is what ``attention_impl=None`` means."""
-    return _flash_attention if _kernels_active() else dot_product_attention
+    from ..ops import registry
+
+    return (_flash_attention if registry.kernels_active()
+            else dot_product_attention)
 
 
 def active_attention_impl(cfg: "TransformerConfig") -> str:
     """Introspection for benches/tests: which attention path will run."""
+    from ..ops import registry
+
     if cfg.attention_impl is not None:
         return "custom"
-    return "flash_attention" if _kernels_active() else "jnp"
+    return "flash_attention" if registry.kernels_active() else "jnp"
 
 
 def _activation_derived_key(h: jax.Array, salt: int) -> jax.Array:
@@ -620,10 +615,12 @@ def _qeinsum(spec: str, x: jax.Array, w: Any, dtype: Any,
     on the output; the optimization barrier stops XLA hoisting the
     loop-invariant dequantized weight stack out of the token/layer loops
     (hoisting materialises full-precision weights — OOM at 7B/16GB)."""
+    from ..ops import registry
+
     if isinstance(w, dict) and "q8" in w:
         q8, s = w["q8"], w["s"]
         B, S = x.shape[0], x.shape[1]
-        if (S * B <= 8 and q8.ndim == 2 and _kernels_active()
+        if (S * B <= 8 and q8.ndim == 2 and registry.kernels_active()
                 and _tp_world() == 1
                 and q8.shape[0] % 128 == 0 and q8.shape[1] % 128 == 0):
             from ..ops.quant_matmul import int8_a8_matmul, int8_matmul
@@ -642,7 +639,7 @@ def _qeinsum(spec: str, x: jax.Array, w: Any, dtype: Any,
         K2, N = q4.shape[-2:]
         G = s.shape[-2]
         gs = 2 * K2 // G
-        if (S * B <= 8 and q4.ndim == 2 and _kernels_active()
+        if (S * B <= 8 and q4.ndim == 2 and registry.kernels_active()
                 and _tp_world() == 1
                 and K2 % 128 == 0 and N % 128 == 0
                 and (G == 1 or gs % 128 == 0)):
@@ -683,7 +680,9 @@ def _fused_norm(x, scale, bias, kind: str, eps: float):
 
 def _norm(x: jax.Array, scale: jax.Array, bias: Optional[jax.Array],
           kind: str, eps: float) -> jax.Array:
-    if _kernels_active():
+    from ..ops import registry
+
+    if registry.kernels_active():
         return _fused_norm(x, scale, bias, kind, eps)
     x32 = x.astype(jnp.float32)
     if kind == "rmsnorm":
@@ -843,8 +842,6 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                    window: Optional[jax.Array] = None,
                    block_table: Optional[jax.Array] = None,
                    paged_write_mask: Optional[jax.Array] = None,
-                   paged_impl: str = "auto",
-                   paged_chunk: bool = False,
                    paged_layer: Optional[jax.Array] = None,
                    moe_counts: bool = False,
                    expert_banks: Optional[Dict[str, jax.Array]] = None,
@@ -868,13 +865,11 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     ``positions`` must then be the (B, S) absolute write
     positions; ``paged_write_mask`` (B, S) routes masked-off tokens (prompt
     chunk padding) to the scratch block 0 instead of the row's blocks.
-    ``paged_impl`` selects the paged READ path: 'auto' (Pallas paged
-    kernels when active, GQA-native jnp paged reference otherwise) or
-    'gather' (the dense ``arena[layer, block_table]`` view — the A/B baseline,
-    and always the path a custom ``attention_impl`` sees). ``paged_chunk``
-    asserts the chunked-prefill contract (``positions[b] == start_b +
-    arange(S)``), which is what lets S>1 take the paged flash-prefill
-    kernel.
+    The read is ``ops.paged_decode_attention.paged_attention``, which picks
+    kernel or reference by platform; S > 1 queries of a row sit at
+    ``positions[b, 0] + arange(S)`` (the chunk, verify and score programs).
+    It has no window, custom-scale or custom-impl operand: ``forward``
+    refuses such a model.
 
     ``expert_banks`` (inference, an MoE model): the model's WHOLE expert
     stacks ``(L, E, ...)`` in place of ``layer["mlp"]``, with ``layer_index``
@@ -985,14 +980,9 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         # at offset p%BS — a scatter write. The layout is left-aligned
         # (column == true position), so causality over true positions is
         # the whole validity story and keys' alibi column bias is exact by
-        # construction. Reads walk the table: the Pallas paged kernels
-        # (ops/paged_decode_attention.py) DMA only each row's RESIDENT
-        # pages; 'gather' materializes the dense arena[layer, block_table]
-        # view — the PR-6 path, kept as the A/B baseline
-        # (serving.paged_kernel='off') and as what a custom attention_impl
-        # sees (it has no block-table operand). Every path is shape-static:
-        # one compiled program covers any arena occupancy (the jit-cache
-        # analog of vLLM's PagedAttention block tables).
+        # construction. The read walks the table and is shape-static: one
+        # compiled program covers any arena occupancy (the jit-cache analog
+        # of vLLM's PagedAttention block tables).
         BSz = cache["k"].shape[2]
         T_view = block_table.shape[1] * BSz
         pos = positions if positions.ndim == 2 else jnp.broadcast_to(
@@ -1012,57 +1002,20 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         cv = cache["v"].at[paged_layer, blk, off].set(
             v.reshape(B, S, K * D).astype(cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
-        use_dense = (paged_impl == "gather" or cfg.attention_impl is not None
-                     or window is not None or cfg.attention_scale is not None)
-        if use_dense:
-            kk = ck[paged_layer, block_table].reshape(B, T_view, K, D)
-            vv = cv[paged_layer, block_table].reshape(B, T_view, K, D)
-            col = jnp.arange(T_view, dtype=jnp.int32)
-            # zero v beyond each row's max resident position — masked
-            # columns carry softmax weight 0, and 0 × NaN = NaN: scratch/
-            # recycled pages may hold nonfinite residue that must not
-            # leak into live rows (same rule as reference_paged_attention
-            # and the Pallas kernels' edge-padded v zeroing)
-            resident = col[None, :] <= jnp.max(pos, axis=1)[:, None]
-            vv = jnp.where(resident[:, :, None, None], vv, 0)
-            full = (col[None, None, :] <= pos[:, :, None]).astype(jnp.int32)
-            dense_fn = cfg.attention_impl or dot_product_attention
-            if cfg.attention_scale is not None and cfg.attention_impl is None:
-                dense_fn = _functools.partial(dot_product_attention,
-                                              scale=cfg.attention_scale)
-            if alibi is None:
-                attn = dense_fn(q, kk, vv, full, causal=False)
-            else:
-                attn = dense_fn(q, kk, vv, full, causal=False, alibi=alibi)
-        elif S == 1 and _kernels_active():
-            # paged decode: walks the block table, DMAs resident pages only
-            from ..ops.paged_decode_attention import paged_decode_attention
+        from ..ops.paged_decode_attention import paged_attention
 
-            attn = paged_decode_attention(q[:, 0], ck, cv, paged_layer,
-                                          block_table, pos[:, 0] + 1,
-                                          alibi=alibi)[:, None]
-        elif S > 1 and paged_chunk and _kernels_active():
-            # chunked prefill reads prior context through the table too
-            from ..ops.paged_decode_attention import paged_prefill_attention
-
-            attn = paged_prefill_attention(q, ck, cv, paged_layer,
-                                           block_table, pos[:, 0],
-                                           alibi=alibi)
-        else:
-            # GQA-native jnp paged reference (no head expansion, no dense
-            # (B,S,T) mask materialization) — CPU fallback + parity oracle
-            from ..ops.paged_decode_attention import reference_paged_attention
-
-            attn = reference_paged_attention(q, ck, cv, paged_layer,
-                                             block_table, pos, alibi=alibi)
+        attn = paged_attention(q, ck, cv, paged_layer, block_table, pos,
+                               alibi=alibi)
     elif cache is not None:
+        from ..ops import registry
+
         idx = cache["index"]
         ck = lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
         cv = lax.dynamic_update_slice(cache["v"], v, (0, idx, 0, 0))
         new_cache = {"k": ck, "v": cv, "index": idx + S}
         T = ck.shape[1]
-        if (S == 1 and cfg.attention_impl is None and _kernels_active()
-                and window is None
+        if (S == 1 and cfg.attention_impl is None
+                and registry.kernels_active() and window is None
                 and cfg.attention_scale is None):
             # single-token decode → Pallas decode kernel (GQA-native, reads
             # the arena without head expansion; alibi in-kernel)
@@ -1078,7 +1031,7 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             attn = decode_attention(q[:, 0], ck, cv, valid, alibi=alibi,
                                     key_positions=key_positions)[:, None]
         elif (static_prefill and S > 1 and cfg.attention_impl is None
-              and _kernels_active() and T % 128 == 0 and window is None
+              and registry.kernels_active() and T % 128 == 0 and window is None
               and cfg.attention_scale is None):
             # prefill from position 0: queries sit at absolute rows 0..S-1, so
             # the flash kernel's 0-based causal col<=row over the arena is
@@ -1253,8 +1206,6 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             key_positions: Optional[jax.Array] = None,
             block_table: Optional[jax.Array] = None,
             paged_write_mask: Optional[jax.Array] = None,
-            paged_impl: str = "auto",
-            paged_chunk: bool = False,
             moe_counts: bool = False
             ) -> Tuple[jax.Array, Optional[Dict[str, Any]], jax.Array]:
     """Token ids (B,S) → (logits (B,S,V), new_cache, moe_aux_loss). With
@@ -1268,15 +1219,21 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     ``{"k","v": (L, NUM_BLOCKS, BLOCK, K*D)}`` (serving layer); ``positions``
     is then REQUIRED — per-row absolute write positions — and
     ``paged_write_mask`` (B, S) routes padding writes to the scratch block.
-    ``paged_impl``/``paged_chunk`` select the paged read path (see
-    ``_layer_forward``). ``moe_counts`` (paged mode, an MoE model) adds a
-    fourth result: int32 ``[assignments, experts with a row, rows of the
-    largest expert]`` summed over the layers, from the rows that
-    ``paged_write_mask`` keeps (``parallel/moe.moe_mlp``)."""
+    The paged read has no window, custom-scale or custom-impl operand: a
+    model with ``attention_layers``, ``attention_scale`` or
+    ``attention_impl`` is refused. ``moe_counts`` (paged mode, an MoE
+    model) adds a fourth result: int32 ``[assignments, experts with a row,
+    rows of the largest expert]`` summed over the layers, from the rows
+    that ``paged_write_mask`` keeps (``parallel/moe.moe_mlp``)."""
     B, S = input_ids.shape
-    if paged_impl not in ("auto", "gather"):
-        raise ValueError(f"paged_impl must be 'auto' or 'gather', "
-                         f"got '{paged_impl}'")
+    if block_table is not None:
+        for operand in ("attention_layers", "attention_scale",
+                        "attention_impl"):
+            if getattr(cfg, operand) not in (None, ()):
+                raise NotImplementedError(
+                    f"paged attention (block_table given) has no operand "
+                    f"for cfg.{operand} — reading the arena without it "
+                    "would silently change the model")
     x = params["embed"]["tokens"][input_ids].astype(cfg.dtype)
     if positions is None:
         positions = jnp.arange(S) + start_pos
@@ -1425,16 +1382,14 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         # scatter — four 185 MiB copies a layer at OPT-1.3B's serving size,
         # 54 ms of a 73 ms decode iteration on the v5e (PERF.md, PR 26).
         # tests/kernels/test_tpu_compile.py holds the compiled programs to
-        # it. window/PLD/LTD are training- or dense-cache-only features; the
-        # serving engine rejects sliding-window models, and the dense-view
-        # fallback inside _layer_forward ignores `window`.
+        # it. window/PLD/LTD are training- or dense-cache-only features
+        # (a sliding-window model was refused above).
         def paged_block(carry, layer_and_idx):
             h, aux_acc, arena, *counts_acc = carry
             layer, idx = layer_and_idx
             h_new, arena, aux, *counts = _layer_forward(
                 cfg, h, layer, attention_mask, positions, arena,
                 block_table=block_table, paged_write_mask=paged_write_mask,
-                paged_impl=paged_impl, paged_chunk=paged_chunk,
                 paged_layer=idx, moe_counts=moe_counts, expert_banks=banks,
                 layer_index=idx)
             return (h_new, aux_acc + aux, arena,

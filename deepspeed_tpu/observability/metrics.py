@@ -231,8 +231,7 @@ class MetricsRegistry:
     def dump_jsonl(self, path: str, extra: Optional[Dict[str, Any]] = None,
                    append: bool = False) -> str:
         """Write one record per series (plus an optional header record) —
-        the bench harness calls this once per run so BENCH_*.json numbers
-        carry their per-phase breakdown alongside. The default truncates:
+        a run's per-phase breakdown beside its result. The default truncates:
         the file is a *snapshot*, and accumulating full-registry snapshots
         across runs would double-count every series for consumers that
         don't replicate the report CLI's latest-record-wins dedup. Pass

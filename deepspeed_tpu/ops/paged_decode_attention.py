@@ -1,11 +1,13 @@
 """Pallas paged attention: block-table-aware decode + chunked-prefill kernels.
 
 The serving layer's arena is a shared pool of fixed-size KV blocks
-(``serving/paged_kv.py``; vLLM's PagedAttention, Kwon et al. SOSP '23). The
-jnp read path materializes a dense ``(R, MAXB*BLOCK, K, D)`` view per layer
-per step (``arena[block_table]``), so every decode token pays HBM traffic
+(``serving/paged_kv.py``; vLLM's PagedAttention, Kwon et al. SOSP '23). A
+read that materializes a dense ``(R, MAXB*BLOCK, K, D)`` view per layer per
+step (``arena[block_table]``) makes every decode token pay HBM traffic
 proportional to the *pool view*, not the tokens actually resident. These
-kernels walk each row's block table instead and DMA only **resident** pages:
+kernels walk each row's block table instead and DMA only **resident** pages
+(``paged_attention``, at the end of the module, is the one entry the model
+calls and the one place kernel or reference is chosen):
 
 * ``paged_decode_attention`` — single-query decode. The grid runs over the
   rows only and the arenas stay in HBM (``pl.ANY``): a row's step walks ITS
@@ -58,8 +60,7 @@ stored, and the kernels slice heads out of it by static lane offsets.
 ``reference_paged_attention`` is the pure-jnp oracle and CPU fallback:
 GQA-native over the view gathered straight from the arena
 (``arena[layer, block_table]``; no head expansion, no (B,S,T) mask
-materialization) — also measurably leaner than the PR-6 gather +
-``dot_product_attention`` path that it replaces.
+materialization).
 """
 
 from __future__ import annotations
@@ -78,6 +79,7 @@ LANES = 128
 # budget shared with the dense decode kernel's tile sizing
 from .decode_attention import VMEM_KV_BUDGET as _VMEM_PAGE_BUDGET
 from .decode_attention import tiled_vmem_bytes
+from . import registry
 
 # keys a tile of the decode walk holds at most (and at least 128, where the
 # budget allows): see ``_pages_per_tile``
@@ -524,3 +526,30 @@ def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
     inactive = (positions < 0)[:, :, None, None]
     o = jnp.where(inactive[:, :, None], 0.0, o.reshape(B, S, K, G, D))
     return o.reshape(B, S, N, D)
+
+
+# ---------------------------------------------------------------------------
+# the one place a paged read is chosen
+# ---------------------------------------------------------------------------
+
+
+def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
+                    layer, block_table: jax.Array, positions: jax.Array,
+                    alibi: Optional[jax.Array] = None) -> jax.Array:
+    """The model's paged read, after its scatter: q (B, S, N, D) at absolute
+    ``positions`` (B, S) against ``arena[layer]`` through ``block_table``;
+    returns (B, S, N, D). Where the Pallas kernels run (``ops/registry``'s
+    platform probe) one query a row takes the decode walk and S > 1 the
+    prefill kernel, which reads ``positions[:, 0]`` as the row's start: the
+    serving programs' contract that S > 1 queries sit at ``start + 0..S-1``
+    (slots past a row's real tokens ride position -1 and are never read).
+    Anywhere else: ``reference_paged_attention``."""
+    if not registry.kernels_active():
+        return reference_paged_attention(q, k_arena, v_arena, layer,
+                                         block_table, positions, alibi=alibi)
+    if q.shape[1] == 1:
+        return paged_decode_attention(q[:, 0], k_arena, v_arena, layer,
+                                      block_table, positions[:, 0] + 1,
+                                      alibi=alibi)[:, None]
+    return paged_prefill_attention(q, k_arena, v_arena, layer, block_table,
+                                   positions[:, 0], alibi=alibi)

@@ -10,6 +10,7 @@ with pure-jnp reference fallbacks selected per platform.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -42,6 +43,16 @@ def is_compatible(name: str) -> bool:
     # a backend that fails to initialise raises here: answering "cpu" would
     # route a TPU job onto the jnp references without a word
     return jax.default_backend() in spec.platforms
+
+
+@functools.lru_cache(maxsize=None)
+def kernels_active() -> bool:
+    """True when the Pallas kernels are compatible with the current backend.
+    THE platform probe of the model code and of every op that chooses between
+    a kernel and its jnp reference: evaluated once per process at trace time;
+    CPU/test runs keep the pure-jnp paths. Callers read it through the module
+    (``registry.kernels_active()``), so a test steers this one name."""
+    return is_compatible("flash_attention")
 
 
 def get_op(name: str, force_reference: bool = False) -> Callable:

@@ -297,7 +297,7 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     from ..ops.moe_grouped_matmul import (group_layout, moe_grouped_matmul,
                                           reference_grouped_matmul,
                                           tile_rows)
-    from ..models.transformer import _kernels_active
+    from ..ops import registry
     from .mesh import ambient_mesh
 
     T, H = xt.shape
@@ -323,7 +323,7 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     xs = xt[token_of_row]           # padding rows read token 0, unread after
 
     mesh = ambient_mesh()
-    if _kernels_active() and (mesh is None or mesh.size == 1):
+    if registry.kernels_active() and (mesh is None or mesh.size == 1):
         mm = moe_grouped_matmul
     else:       # CPU; or XLA partitions the layer over the mesh itself
         mm = reference_grouped_matmul

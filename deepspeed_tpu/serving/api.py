@@ -63,17 +63,13 @@ class ServingEngine:
         self.config = config or ServingConfig()
         self.config.validate()
         cfg = engine.model.config
-        if cfg.attention_layers or cfg.attention_scale is not None:
+        if (cfg.attention_layers or cfg.attention_scale is not None
+                or cfg.attention_impl is not None):
             raise NotImplementedError(
                 "serving does not support sliding-window/custom-scale "
-                "attention models (GPT-Neo family) yet — the paged read "
-                "path has no window operand")
-        if cfg.attention_impl is not None:
-            # custom impls are served through the dense gathered-view path
-            # (the impl has no block-table operand); the Pallas paged
-            # kernels only engage for attention_impl=None
-            log_dist("serving: custom attention_impl set — the paged read "
-                     "uses the dense gather view, not the paged kernels")
+                "attention models (GPT-Neo family) or a custom "
+                "attention_impl yet — the paged read has no window, scale "
+                "or impl operand")
         if cfg.position == "learned" and \
                 self.config.max_model_len > cfg.max_seq_len:
             raise ValueError(
@@ -107,24 +103,18 @@ class ServingEngine:
             self._arena = paged_kv.init_paged_cache(
                 cfg, self.config.pool_blocks() + 1, self.config.block_size,
                 self._dtype)
-        # 'off' pins the dense gather-view read (the A/B baseline);
-        # 'auto' = Pallas paged kernels on TPU, jnp paged reference on CPU
-        self._paged_impl = ("gather" if self.config.paged_kernel == "off"
-                            else "auto")
         # an MoE model's two programs return their routing counts behind
         # the tokens (_moe_counts); 0 = a dense model, whose programs and
         # spans know nothing of it
         self._moe_experts_total = cfg.moe_num_experts * cfg.num_layers
         moe = self._moe_experts_total > 0
-        self._prefill = paged_kv.build_prefill_program(
-            cfg, self._paged_impl, moe_counts=moe)
-        self._decode = paged_kv.build_decode_program(
-            cfg, self._paged_impl, moe_counts=moe)
+        self._prefill = paged_kv.build_prefill_program(cfg, moe_counts=moe)
+        self._decode = paged_kv.build_decode_program(cfg, moe_counts=moe)
         self._cow = paged_kv.build_cow_program()
         # teacher-forced scoring over the same arena (the RLHF second
         # serving pass — docs/rlhf.md); jit is lazy, so an engine that
         # never scores pays nothing
-        self._score = paged_kv.build_score_program(cfg, self._paged_impl)
+        self._score = paged_kv.build_score_program(cfg)
         self._cow_copies = 0
         self._published_cow = 0
         # rollout accounting: prefill dispatches + real tokens they
@@ -159,13 +149,11 @@ class ServingEngine:
         self._tuner_obs = None
         self._drafter = make_drafter(self.config, engine, self.alloc,
                                      self.blocks_per_seq,
-                                     draft_engine=draft_engine,
-                                     paged_impl=self._paged_impl)
+                                     draft_engine=draft_engine)
         self._verify = None
         if self._drafter is not None:
             self._verify = paged_kv.build_verify_program(
-                cfg, self.config.speculative.num_draft_tokens + 1,
-                self._paged_impl)
+                cfg, self.config.speculative.num_draft_tokens + 1)
             # one release point covers finish/cancel/preempt: the drafter
             # must drop its draft-arena blocks whenever the scheduler
             # releases the request's target blocks, or a preempted
@@ -1665,7 +1653,7 @@ class ServingEngine:
                 donate_argnums=(1,), expected_collectives=expected,
                 mesh=self.engine.mesh,
                 tags={"engine": "ServingEngine", "chunk": C,
-                      "max_blocks": MAXB, "paged_impl": self._paged_impl,
+                      "max_blocks": MAXB,
                       # one chunked-prefill run ingests C prompt tokens
                       "tokens_per_step": C, "shard": shard,
                       # lowered module name ("jit_<program>") — the deep
@@ -1676,7 +1664,7 @@ class ServingEngine:
                 "serving/decode", build=build_decode, donate_argnums=(1,),
                 expected_collectives=expected, mesh=self.engine.mesh,
                 tags={"engine": "ServingEngine", "rows": R,
-                      "max_blocks": MAXB, "paged_impl": self._paged_impl,
+                      "max_blocks": MAXB,
                       # one decode iteration emits one token per row
                       "tokens_per_step": R, "shard": shard,
                       "program": "decode"})
@@ -1720,7 +1708,7 @@ class ServingEngine:
                 donate_argnums=(1,), expected_collectives=expected,
                 mesh=self.engine.mesh,
                 tags={"engine": "ServingEngine", "chunk": C,
-                      "max_blocks": MAXB, "paged_impl": self._paged_impl,
+                      "max_blocks": MAXB,
                       # one scoring chunk ingests C sequence tokens
                       "tokens_per_step": C, "shard": shard,
                       "program": "score_chunk"})
@@ -1766,7 +1754,7 @@ class ServingEngine:
             "serving/verify", build=build_verify, donate_argnums=(1,),
             expected_collectives=expected, mesh=self.engine.mesh,
             tags={"engine": "ServingEngine", "rows": R, "spec_tokens": S,
-                  "max_blocks": MAXB, "paged_impl": self._paged_impl,
+                  "max_blocks": MAXB,
                   # conservative floor: one verify dispatch emits AT LEAST
                   # one token per row (acceptance only adds to this)
                   "tokens_per_step": R,

@@ -13,12 +13,11 @@ pool of fixed-size **blocks** (vLLM's PagedAttention, Kwon et al. SOSP '23):
   ``(rows, max_blocks)`` and per-row lengths are data, not shapes, so one
   compiled decode program serves every occupancy the scheduler produces
   (the jit-cache analog of the reference's CUDA-graph discipline). The
-  attention read walks the block table: on TPU the Pallas paged kernels
-  (``ops/paged_decode_attention.py``) DMA only each row's RESIDENT pages;
-  ``paged_impl='gather'`` keeps the dense ``arena[layer, block_table]``
-  view as the A/B baseline (``serving.paged_kernel='off'``). Every path
-  addresses a layer's pool inside the ``(L, NUM_BLOCKS, BLOCK, K*D)``
-  arena; none slices it out.
+  attention read walks the block table
+  (``ops/paged_decode_attention.paged_attention``): on TPU the Pallas paged
+  kernels DMA only each row's RESIDENT pages, elsewhere the jnp paged
+  reference reads the same table. Both address a layer's pool inside the
+  ``(L, NUM_BLOCKS, BLOCK, K*D)`` arena; neither slices it out.
 * ``PrefixCache`` + refcounted ``BlockAllocator`` + ``build_cow_program``
   — prefix sharing: full prompt blocks are content-hash cached, a new
   request whose prompt prefix is cached maps those blocks into its table
@@ -381,8 +380,7 @@ def _with_moe_counts(tokens: jax.Array, counts: jax.Array) -> jax.Array:
     return jnp.concatenate([tokens.astype(jnp.int32), counts])
 
 
-def build_prefill_program(cfg, paged_impl: str = "auto",
-                          moe_counts: bool = False):
+def build_prefill_program(cfg, moe_counts: bool = False):
     """Jitted prefill-chunk program over the paged arena.
 
     Args (all shapes static per (C, max_blocks) pair):
@@ -417,7 +415,7 @@ def build_prefill_program(cfg, paged_impl: str = "auto",
         logits, cache, _, *counts = model_forward(
             params, chunk, cfg, cache=cache, positions=pos,
             block_table=block_table, paged_write_mask=write_mask,
-            paged_impl=paged_impl, paged_chunk=True, moe_counts=moe_counts)
+            moe_counts=moe_counts)
         last = jnp.take_along_axis(
             logits, jnp.maximum(n_valid - 1, 0)[None, None, None],
             axis=1)[:, 0].astype(jnp.float32)
@@ -430,8 +428,7 @@ def build_prefill_program(cfg, paged_impl: str = "auto",
     return jax.jit(prefill_chunk, donate_argnums=(1,))
 
 
-def build_decode_program(cfg, paged_impl: str = "auto",
-                         moe_counts: bool = False):
+def build_decode_program(cfg, moe_counts: bool = False):
     """Jitted one-token decode step over the paged arena for a fixed row
     count R. Inactive rows carry an all-zero block table and length 0 — their
     writes land in the scratch block and their sampled tokens are ignored by
@@ -459,8 +456,7 @@ def build_decode_program(cfg, paged_impl: str = "auto",
         logits, cache, _, *counts = model_forward(
             params, tokens[:, None], cfg, cache=cache,
             positions=lengths[:, None], block_table=block_table,
-            paged_write_mask=live, paged_impl=paged_impl,
-            moe_counts=moe_counts)
+            paged_write_mask=live, moe_counts=moe_counts)
         nxt = sample_rows(logits[:, -1], base_key, temperature, top_k,
                           top_p, seeds, steps)
         if moe_counts:
@@ -470,7 +466,7 @@ def build_decode_program(cfg, paged_impl: str = "auto",
     return jax.jit(decode, donate_argnums=(1,))
 
 
-def build_verify_program(cfg, num_tokens: int, paged_impl: str = "auto"):
+def build_verify_program(cfg, num_tokens: int):
     """Jitted speculative-decoding verify step: the R×1 decode program
     generalized to R×S (S = ``num_tokens`` = K+1 draft slots + the pending
     token). Row r feeds ``tokens[r] = [pending, d_1 .. d_K]`` at absolute
@@ -516,9 +512,7 @@ def build_verify_program(cfg, num_tokens: int, paged_impl: str = "auto"):
         logits, cache, _ = model_forward(params, tokens, cfg, cache=cache,
                                          positions=pos,
                                          block_table=block_table,
-                                         paged_write_mask=write_mask,
-                                         paged_impl=paged_impl,
-                                         paged_chunk=True)
+                                         paged_write_mask=write_mask)
         flat = logits.reshape(R * S, logits.shape[-1]).astype(jnp.float32)
         sampled = sample_rows(flat, base_key,
                               jnp.repeat(temperature, S),
@@ -533,7 +527,7 @@ def build_verify_program(cfg, num_tokens: int, paged_impl: str = "auto"):
     return jax.jit(verify, donate_argnums=(1,))
 
 
-def build_score_program(cfg, paged_impl: str = "auto"):
+def build_score_program(cfg):
     """Jitted teacher-forced scoring chunk over the paged arena — the RLHF
     second serving pass (``docs/rlhf.md``): instead of sampling, it returns
     the log-probability the model assigns to given TARGET tokens. Same
@@ -573,9 +567,7 @@ def build_score_program(cfg, paged_impl: str = "auto"):
         logits, cache, _ = model_forward(params, chunk, cfg, cache=cache,
                                          positions=pos,
                                          block_table=block_table,
-                                         paged_write_mask=write_mask,
-                                         paged_impl=paged_impl,
-                                         paged_chunk=True)
+                                         paged_write_mask=write_mask)
         return gather_target_logprobs(logits, targets), cache
 
     return jax.jit(score_chunk, donate_argnums=(1,))

@@ -175,8 +175,7 @@ class DraftModelDrafter(Drafter):
 
     name = "draft"
 
-    def __init__(self, draft_engine, config, allocator, blocks_per_seq: int,
-                 paged_impl: str = "auto"):
+    def __init__(self, draft_engine, config, allocator, blocks_per_seq: int):
         super().__init__()
         import jax
 
@@ -196,9 +195,8 @@ class DraftModelDrafter(Drafter):
             self._arena = paged_kv.init_paged_cache(
                 cfg, config.pool_blocks() + 1, config.block_size,
                 self._dtype)
-        self._decode = paged_kv.build_decode_program(cfg, paged_impl)
-        self._prefill = paged_kv.build_prefill_program(cfg, paged_impl)
-        self._paged_impl = paged_impl
+        self._decode = paged_kv.build_decode_program(cfg)
+        self._prefill = paged_kv.build_prefill_program(cfg)
         self._state: Dict[int, _DraftState] = {}
         self._key = jax.random.PRNGKey(0)   # greedy drafts never draw
 
@@ -353,8 +351,7 @@ def _obs():
 
 
 def make_drafter(config, target_engine, allocator, blocks_per_seq: int,
-                 draft_engine=None,
-                 paged_impl: str = "auto") -> Optional[Drafter]:
+                 draft_engine=None) -> Optional[Drafter]:
     """Build the drafter ``config.speculative`` asks for (None when off).
     ``draft_engine`` is an ``InferenceEngine`` over the (smaller) draft
     model — required for mode='draft', vocab-checked against the target;
@@ -383,4 +380,4 @@ def make_drafter(config, target_engine, allocator, blocks_per_seq: int,
             draft_engine.config.dtype, target_engine.config.dtype)
     return DraftModelDrafter(
         draft_engine, config, allocator=allocator,
-        blocks_per_seq=blocks_per_seq, paged_impl=paged_impl)
+        blocks_per_seq=blocks_per_seq)

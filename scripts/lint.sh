@@ -8,7 +8,6 @@ cd "$(dirname "$0")/.."
 
 python -m tools.tpulint \
     deepspeed_tpu/ tools/ scripts/ tests/ \
-    bench.py bench_infer.py bench_moe.py bench_rlhf.py bench_zero.py \
     --baseline .tpulint-baseline.json "$@"
 
 # metric-name <-> docs drift gate: every literal registry.counter/gauge/

@@ -1,14 +1,14 @@
 """Paged-attention kernel parity (interpret mode on CPU).
 
-The serving acceptance story rests on three read paths producing the same
-attention: the dense ``arena[layer, block_table]`` gather view (PR-6
-baseline, ``paged_impl='gather'``), the GQA-native jnp paged reference (CPU
-serving fallback), and the Pallas paged kernels (TPU; interpret-mode here).
-Every test pins two of them against each other across ragged occupancy, GQA
-and alibi — the greedy bit-exactness smoke in tests/unit/test_serving.py
-then covers the end-to-end program.
+The serving acceptance story rests on the read paths producing the same
+attention: the GQA-native jnp paged reference (CPU serving fallback) and the
+Pallas paged kernels (TPU; interpret-mode here), each also held to a dense
+``arena[layer, block_table]`` view that the test builds by hand. Every test
+pins two of them against each other across ragged occupancy, GQA and alibi
+— the greedy bit-exactness smoke in tests/unit/test_serving.py then covers
+the end-to-end program.
 
-All three take the whole arena ``(L, NUM_BLOCKS, BLOCK, K*D)`` and a layer
+All take the whole arena ``(L, NUM_BLOCKS, BLOCK, K*D)`` and a layer
 index. The kernels are held, at every layer of a 3-layer arena, to the
 reference on that layer's pool alone (``arena[layer][None]``, layer 0), and
 the reference to a dense view sliced by hand — so neither side's layer
@@ -27,6 +27,7 @@ from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.presets import transformer_config
 from deepspeed_tpu.models.transformer import (alibi_slopes,
                                               dot_product_attention)
+from deepspeed_tpu.ops import registry
 from deepspeed_tpu.ops import (decode_attention, paged_decode_attention,
                                paged_prefill_attention,
                                reference_decode_attention,
@@ -241,9 +242,9 @@ class TestPagedDecodeKernel:
     @pytest.mark.parametrize("layer", LAYERS)
     def test_reference_matches_dense_gather_path(self, layer):
         """The jnp paged reference (CPU serving fallback) computes the
-        same attention as the PR-6 gather + dot_product_attention path —
-        what 'paged_kernel=off' A/Bs against — on the layer it is given
-        (here as a traced scalar, as the layer scan gives it)."""
+        same attention as a dense gather view + dot_product_attention,
+        built here by hand, on the layer it is given (here as a traced
+        scalar, as the layer scan gives it)."""
         ka, va = _arena(k=2)
         bt, lengths = _ragged_tables()
         n = 4
@@ -314,7 +315,7 @@ class TestPagedPrefillKernel:
 class TestPagedForwardWritesInPlace:
     """A paged ``forward`` step over a 3-layer arena full of other
     sequences' rows: the arena goes in whole and comes back with exactly the
-    rows (layer, blk, off) of this step rewritten, and the three read paths
+    rows (layer, blk, off) of this step rewritten, and kernels and reference
     see the same thing there."""
 
     BS, NB = 4, 10
@@ -329,7 +330,7 @@ class TestPagedForwardWritesInPlace:
     @pytest.fixture
     def interpreted_kernels(self, monkeypatch):
         """The model's kernel branch, run by the Pallas interpreter."""
-        monkeypatch.setattr(T, "_kernels_active", lambda: True)
+        monkeypatch.setattr(registry, "kernels_active", lambda: True)
         for module, names in (("paged_decode_attention",
                                ("paged_decode_attention",
                                 "paged_prefill_attention")),
@@ -356,7 +357,7 @@ class TestPagedForwardWritesInPlace:
         written = {(8, 0), (8, 1), (8, 2), (8, 3), (3, 0), (0, 0)}
         return ids, pos, bt, mask, written
 
-    def _forward(self, model, step, paged_impl):
+    def _forward(self, model, step):
         cfg, params = model
         ids, pos, bt, mask, _ = self._step(step)
         ks = jax.random.split(jax.random.PRNGKey(1), 2)
@@ -366,9 +367,7 @@ class TestPagedForwardWritesInPlace:
                  "v": jax.random.normal(ks[1], shape, jnp.float32)}
         # under jit, as the serving programs run it: the layer index is
         # traced and the arena is the scan's carry
-        fwd = jax.jit(functools.partial(
-            T.forward, cfg=cfg, paged_impl=paged_impl,
-            paged_chunk=step == "chunk"))
+        fwd = jax.jit(functools.partial(T.forward, cfg=cfg))
         logits, new, _ = fwd(
             params, jnp.asarray(ids), cache=arena, positions=jnp.asarray(pos),
             block_table=jnp.asarray(bt),
@@ -377,7 +376,7 @@ class TestPagedForwardWritesInPlace:
 
     @pytest.mark.parametrize("step", ["decode", "chunk"])
     def test_only_the_written_rows_change_in_every_layer(self, model, step):
-        arena, new, _ = self._forward(model, step, "auto")
+        arena, new, _ = self._forward(model, step)
         written = self._step(step)[-1]
         touched = np.zeros((len(LAYERS), self.NB, self.BS), bool)
         for blk, off in written:
@@ -394,26 +393,49 @@ class TestPagedForwardWritesInPlace:
                                           after[-1, blk, off])
 
     @pytest.mark.parametrize("step", ["decode", "chunk"])
-    def test_gather_reference_and_kernels_agree(self, model, step,
-                                                interpreted_kernels,
-                                                monkeypatch):
-        _, new_k, logits_k = self._forward(model, step, "auto")
-        monkeypatch.setattr(T, "_kernels_active", lambda: False)
-        _, new_r, logits_r = self._forward(model, step, "auto")
-        _, new_g, logits_g = self._forward(model, step, "gather")
+    def test_kernels_match_reference_through_forward(self, model, step,
+                                                     interpreted_kernels,
+                                                     monkeypatch):
+        """Through ``forward``: the kernels (interpreted) against the
+        reference, whose gather of ``arena[layer, block_table]`` the tests
+        above hold to a dense view built by hand."""
+        _, new_k, logits_k = self._forward(model, step)
+        monkeypatch.setattr(registry, "kernels_active", lambda: False)
+        _, new_r, logits_r = self._forward(model, step)
         ids, pos, *_ = self._step(step)
         live = np.asarray(pos) >= 0            # pad logits are never read
-        for other_new, other in ((new_r, logits_r), (new_g, logits_g)):
-            np.testing.assert_allclose(logits_k[live], other[live],
-                                       atol=2e-4, rtol=2e-4)
-            # block 0 is scratch: a pad query's output, and so the next
-            # layer's pad keys, differ by path and are never read
-            for side in ("k", "v"):
-                np.testing.assert_allclose(np.asarray(new_k[side])[:, 1:],
-                                           np.asarray(other_new[side])[:, 1:],
-                                           atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(logits_k[live], logits_r[live],
+                                   atol=2e-4, rtol=2e-4)
+        # block 0 is scratch: a pad query's output, and so the next
+        # layer's pad keys, differ by path and are never read
+        for side in ("k", "v"):
+            np.testing.assert_allclose(np.asarray(new_k[side])[:, 1:],
+                                       np.asarray(new_r[side])[:, 1:],
+                                       atol=2e-5, rtol=2e-5)
         np.testing.assert_array_equal(logits_r[live].argmax(-1),
                                       logits_k[live].argmax(-1))
+
+    @pytest.mark.parametrize("field,value", [
+        ("attention_layers", ("global", "local", "global")),
+        ("attention_scale", 1.0),
+        ("attention_impl", dot_product_attention)])
+    def test_paged_forward_refuses_what_it_has_no_operand_for(self, model,
+                                                              field, value):
+        """The paged read takes no window, no custom scale and no custom
+        attention: a paged call names the one it was asked for and stops,
+        where the gather view once served it in silence."""
+        import dataclasses
+
+        cfg, params = model
+        ids, pos, bt, *_ = self._step("decode")
+        shape = (len(LAYERS), self.NB, self.BS,
+                 cfg.num_kv_heads * cfg.head_dim)
+        arena = {"k": jnp.zeros(shape), "v": jnp.zeros(shape)}
+        with pytest.raises(NotImplementedError, match=field):
+            T.forward(params, jnp.asarray(ids),
+                      dataclasses.replace(cfg, **{field: value}),
+                      cache=arena, positions=jnp.asarray(pos),
+                      block_table=jnp.asarray(bt))
 
 
 class TestDecodeAttentionUnalignedCache:

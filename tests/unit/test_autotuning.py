@@ -70,7 +70,7 @@ class TestModelBasedTuner:
 
     def _oracle(self):
         """Recorded-sweep stand-in: measured tokens/s by (micro, stage) on
-        the dev chip for gpt2-125m (bench.py family numbers); micro 64 OOMs."""
+        the dev chip for gpt2-125m; micro 64 OOMs."""
         sweep = {(8, 0): 84e3, (8, 1): 82e3, (8, 2): 80e3,
                  (16, 0): 105e3, (16, 1): 103e3, (16, 2): 100e3,
                  (32, 0): 117e3, (32, 1): 115e3, (32, 2): 112e3,

@@ -1,7 +1,6 @@
 """Unit tests for the flight recorder, hang watchdog and goodput accountant
 (`deepspeed_tpu/observability/{flightrecorder,hangdetect,goodput}.py`) plus
-the `report --crash-dump` CLI and the bench-guard satellite
-(`bench_common.py`).
+the `report --crash-dump` CLI.
 
 The acceptance paths live here:
 
@@ -44,8 +43,6 @@ from deepspeed_tpu.observability.metrics import MetricsRegistry
 from deepspeed_tpu.observability.report import crash_report, main as report_main
 from deepspeed_tpu.observability.spans import SpanTracer
 from deepspeed_tpu.profiling import compiled_cost
-
-import bench_common
 
 
 @pytest.fixture(autouse=True)
@@ -538,86 +535,6 @@ class TestEngineGoodputSmoke:
             and obs.goodput is None
         assert obs.tracer.on_event is None
         assert obs.registry.on_publish is None
-
-
-# ---------------------------------------------------------------------------
-# bench guard satellite (bench_common.py)
-
-
-class TestBenchGuard:
-    def test_skip_record_carries_failure_kind(self, capsys):
-        with pytest.raises(SystemExit) as e:
-            bench_common.skip("m", "u", "watchdog expired", "hang")
-        assert e.value.code == 0
-        rec = json.loads(capsys.readouterr().out)
-        assert rec["skipped"] is True and rec["failure_kind"] == "hang"
-        assert rec["value"] is None and "watchdog expired" in rec["reason"]
-
-    def test_crash_bundle_info_finds_newest(self, tmp_path):
-        assert bench_common.crash_bundle_info(None) is None
-        assert bench_common.crash_bundle_info(str(tmp_path)) is None
-        for name, span, age in (("old", "fwd", 100), ("new", "bwd", 0)):
-            d = tmp_path / f"crash-{name}"
-            d.mkdir()
-            (d / "MANIFEST.json").write_text(
-                json.dumps({"stalled_span": span}))
-            t = time.time() - age
-            os.utime(d, (t, t))
-        info = bench_common.crash_bundle_info(str(tmp_path))
-        assert info["bundle"].endswith("crash-new")
-        assert info["stalled_span"] == "bwd"
-        # newer_than rejects bundles left over from a previous round — an
-        # old bundle must never be presented as THIS hang's evidence
-        assert bench_common.crash_bundle_info(
-            str(tmp_path), newer_than=time.time() - 10) is not None
-        assert bench_common.crash_bundle_info(
-            str(tmp_path), newer_than=time.time() + 10) is None
-        # a bundle whose manifest has no open span still reads cleanly
-        (tmp_path / "crash-new" / "MANIFEST.json").write_text(
-            json.dumps({"stalled_span": None}))
-        assert bench_common.crash_bundle_info(
-            str(tmp_path))["stalled_span"] == "<none open>"
-
-    def test_real_bug_exit_forwards_child_stdout(self, tmp_path):
-        """A child that prints a structured partial record (bench_infer's
-        OOM JSON) and exits non-zero with a non-backend error must have that
-        stdout forwarded by the parent, not discarded."""
-        child = tmp_path / "oom.py"
-        child.write_text(
-            "import sys\n"
-            "print('{\"oom\": true}')\n"
-            "sys.stderr.write('RuntimeError: boom\\n')\n"
-            "sys.exit(3)\n")
-        driver = tmp_path / "driver.py"
-        driver.write_text(
-            "import sys\n"
-            "sys.path.insert(0, '/root/repo')\n"
-            "import bench_common\n"
-            f"bench_common.run_watchdogged('m', 'u', {str(child)!r})\n")
-        r = subprocess.run([sys.executable, str(driver)],
-                           capture_output=True, text=True)
-        assert r.returncode == 3
-        assert '{"oom": true}' in r.stdout
-        assert "boom" in r.stderr
-
-    def test_sigusr1_then_kill_collects_dump(self, tmp_path):
-        """run_child on a hung script: SIGUSR1 lets the child write its
-        black box (here: a SIGUSR1 handler writing a file), SIGKILL follows,
-        and the caller sees hung=True."""
-        script = tmp_path / "hang.py"
-        marker = tmp_path / "dumped.txt"
-        script.write_text(
-            "import signal, sys, time\n"
-            f"f = {str(marker)!r}\n"
-            "signal.signal(signal.SIGUSR1,\n"
-            "              lambda s, fr: open(f, 'w').write('dump'))\n"
-            "print('ready', flush=True)\n"
-            "while True:\n"
-            "    time.sleep(0.05)\n")
-        rc, out, err, hung = bench_common.run_child(
-            str(script), timeout_s=1.0, grace_s=2.0)
-        assert hung and rc is None
-        assert marker.exists() and marker.read_text() == "dump"
 
 
 # ---------------------------------------------------------------------------

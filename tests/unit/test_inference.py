@@ -227,6 +227,7 @@ def test_kernel_prefill_decode_branches(preset, monkeypatch):
     import deepspeed_tpu.models.transformer as T
     from deepspeed_tpu.inference import kv_cache
     from deepspeed_tpu.models.transformer import forward
+    from deepspeed_tpu.ops import registry
 
     engine = init_inference(preset, dtype=jnp.float32, max_out_tokens=128)
     cfg = engine.model.config
@@ -238,7 +239,7 @@ def test_kernel_prefill_decode_branches(preset, monkeypatch):
 
     fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
     da = importlib.import_module("deepspeed_tpu.ops.decode_attention")
-    monkeypatch.setattr(T, "_kernels_active", lambda: True)
+    monkeypatch.setattr(registry, "kernels_active", lambda: True)
     monkeypatch.setattr(T, "default_attention_impl",
                         lambda: fa.make_attention_impl(interpret=True))
     monkeypatch.setattr(da, "decode_attention",

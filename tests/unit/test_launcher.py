@@ -519,35 +519,3 @@ class TestAgentRestartHardening:
         agent._terminate_all()
         assert not os.path.exists(req)
         assert agent.evictions == 0   # the stale request was never honoured
-
-
-class TestOneProcessPerChip:
-    def test_bench_suite_parent_stays_off_jax(self, tmp_path, monkeypatch):
-        """A parent that initialises a JAX backend holds the chip and its
-        children hang: ``run_bench_suite`` asks a throwaway child for the
-        backend instead. With ``jax`` unimportable in the parent, a whole
-        pass over the suite still works."""
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "run_bench_suite",
-            os.path.join(REPO, "scripts", "run_bench_suite.py"))
-        suite = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(suite)
-        calls = []
-
-        def fake_run(cmd, **kw):
-            calls.append(cmd)
-            return subprocess.CompletedProcess(cmd, 0, stdout="cpu\n",
-                                               stderr="")
-
-        monkeypatch.setattr(suite, "REPO", str(tmp_path))
-        monkeypatch.setattr(suite.subprocess, "run", fake_run)
-        monkeypatch.setattr(sys, "argv", ["run_bench_suite.py", "rXX"])
-        monkeypatch.setitem(sys.modules, "jax", None)   # import jax raises
-        suite.main()
-        assert len(calls) == 1 and "jax.default_backend()" in calls[0][-1]
-        records = sorted(os.listdir(tmp_path / "bench_results" / "rXX"))
-        assert len(records) == len(suite.SUITE)
-        with open(tmp_path / "bench_results" / "rXX" / records[0]) as f:
-            assert "cpu" in json.load(f)["skipped"]

@@ -774,55 +774,3 @@ class TestRecommendationsApply:
             block_size=16, num_blocks=32, max_seqs=4, max_model_len=128,
             prefill_chunk=16), recommendations="auto", dtype=jnp.float32)
         assert srv.recommendations_applied == []
-
-
-# ---------------------------------------------------------------------------
-# benchdiff learns profile_summary.json (satellite)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchdiffProfileSummary:
-    def _load_benchdiff(self):
-        import importlib.util
-
-        path = os.path.join(os.path.dirname(__file__), "..", "..",
-                            "scripts", "benchdiff.py")
-        spec = importlib.util.spec_from_file_location("benchdiff", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def _summary(self, tmp_path, name, err):
-        doc = {"format": 1, "entries": {
-            "serving/decode": {"measured_step_ms": 2.0,
-                               "predicted_step_ms": 1.0,
-                               "model_error": err, "measured_mfu": 0.1,
-                               "device_s": 0.5, "invocations": 100}}}
-        p = tmp_path / name
-        with open(p, "w") as fh:
-            json.dump(doc, fh)
-        return str(p)
-
-    def test_widening_model_error_flags_regression(self, tmp_path):
-        bd = self._load_benchdiff()
-        old = bd.load(self._summary(tmp_path, "old.json", 1.1))
-        new = bd.load(self._summary(tmp_path, "new.json", 2.2))
-        rows = list(bd.diff(old, new, threshold_pct=5.0))
-        flagged = {path: flag for _, path, _, _, flag in rows}
-        assert flagged["serving/decode.model_error"] == "REGRESSION"
-
-    def test_direction_tokens(self):
-        bd = self._load_benchdiff()
-        assert bd.direction("serving/decode.model_error") == -1
-        assert bd.direction("serving/decode.measured_mfu") == 1
-        assert bd.direction("serving/decode.device_s") == -1
-        # pre-existing classification unharmed by the new tokens
-        assert bd.direction(
-            "serve_goodput/fleet_tokens_per_device_sec") == 1
-
-    def test_non_summary_json_rejected(self, tmp_path):
-        bd = self._load_benchdiff()
-        p = tmp_path / "x.json"
-        p.write_text("{}")
-        with pytest.raises(SystemExit):
-            bd.load(str(p))

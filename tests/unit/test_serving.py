@@ -822,34 +822,42 @@ class TestPrefixSharing:
         assert reg.counter("serving/cow_copies").value() >= 1
 
 
-class TestPagedKernelAB:
-    def test_gather_vs_paged_outputs_identical(self, tiny_engine):
-        """The --paged-kernel A/B: the dense gather view
-        (paged_kernel='off') and the paged read path ('auto': Pallas
-        kernels on TPU, the GQA-native jnp reference here) produce
-        identical greedy outputs — the 16-request acceptance smoke re-run
-        on both paths."""
+class TestPagedReadOracle:
+    def test_concurrent_requests_match_plain_forward(self, tiny_engine):
+        """The 16-request acceptance smoke against the oracle the benchmark
+        uses: every served greedy token is the argmax of the plain forward
+        over the whole sequence (no cache, no table) at the position before
+        it."""
         rng = np.random.RandomState(12)
         prompts = [rng.randint(0, 250, (rng.randint(4, 40),))
                    for _ in range(16)]
-        outs = {}
-        for mode in ("off", "auto"):
-            srv = serving(tiny_engine, paged_kernel=mode, num_blocks=64,
-                          max_seqs=8)
-            handles = []
-            for i, p in enumerate(prompts):
-                handles.append(srv.submit(p, max_new_tokens=8))
-                if i % 4 == 3:
-                    srv.step()
-            srv.run()
-            outs[mode] = [h.result() for h in handles]
+        srv = serving(tiny_engine, num_blocks=64, max_seqs=8)
+        handles = []
         for i, p in enumerate(prompts):
-            want = np.asarray(tiny_engine.generate(p[None],
-                                                   max_new_tokens=8))[0]
-            np.testing.assert_array_equal(outs["off"][i], want,
-                                          err_msg=f"gather {i} diverged")
-            np.testing.assert_array_equal(outs["auto"][i], want,
-                                          err_msg=f"paged {i} diverged")
+            handles.append(srv.submit(p, max_new_tokens=8))
+            if i % 4 == 3:
+                srv.step()
+        srv.run()
+        for i, (p, h) in enumerate(zip(prompts, handles)):
+            got = h.result()
+            # right-padded to one shape: causal, so the pad changes nothing
+            seq = np.zeros((1, 48), np.int32)
+            seq[0, :len(p) + 8] = np.concatenate([p, got])
+            want = np.asarray(tiny_engine.forward(seq))[0].argmax(-1)
+            np.testing.assert_array_equal(
+                got, want[len(p) - 1:len(p) + 7],
+                err_msg=f"request {i} diverged")
+
+    def test_custom_attention_impl_refused(self):
+        """The paged read has no operand for a custom attention: the engine
+        says so when it is built, not by serving another model."""
+        from deepspeed_tpu.models.transformer import dot_product_attention
+
+        engine = init_inference("tiny", dtype=jnp.float32,
+                                max_out_tokens=128,
+                                attention_impl=dot_product_attention)
+        with pytest.raises(NotImplementedError, match="attention_impl"):
+            serving(engine)
 
 
 # ---------------------------------------------------------------------------

@@ -449,41 +449,6 @@ class TestAutotunerShim:
 
 
 # ---------------------------------------------------------------------------
-# bench integration (jax-free parent pieces)
-
-
-class TestBenchIntegration:
-    def test_skip_record_carries_predicted_mfu(self, capsys):
-        import bench_common
-
-        with pytest.raises(SystemExit) as e:
-            bench_common.skip("m", "tok/s", "outage", "backend-init",
-                              predicted_mfu=0.42)
-        assert e.value.code == 0
-        rec = json.loads(capsys.readouterr().out)
-        assert rec["skipped"] and rec["predicted_mfu"] == 0.42
-        assert rec["failure_kind"] == "backend-init"
-
-    def test_cost_vector_record_unregistered_entry(self):
-        import bench_common
-
-        assert bench_common.cost_vector_record("no/entry") is None
-
-    def test_cost_vector_record_shape(self):
-        import bench_common
-
-        register_entry_point(
-            "bench/step", fn=jax.jit(lambda x: (x @ x).sum()),
-            args=(sds((64, 64)),), expected_collectives=None,
-            tags={"tokens_per_step": 64})
-        rec = bench_common.cost_vector_record("bench/step")
-        assert rec["flops"] > 0 and rec["predicted_mfu"] > 0
-        assert rec["bound"] in ("compute", "hbm", "ici")
-        assert len(rec["program_hash"]) == 12
-        assert rec["predicted_tokens_per_sec"] > 0
-
-
-# ---------------------------------------------------------------------------
 # repo-wide gate (tier-1 acceptance)
 
 

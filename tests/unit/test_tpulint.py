@@ -581,6 +581,23 @@ class TestRepoGate:
         assert proc.returncode == 0, \
             f"scripts/lint.sh failed:\n{proc.stdout}\n{proc.stderr}"
 
+    def test_every_scanned_path_exists(self):
+        """Both gates skip a path that is not there without a word, so a
+        deleted or renamed file would leave its scan behind as a no-op:
+        what ``scripts/lint.sh`` hands tpulint and what ``metricsdoc`` scans
+        by default must be in the tree."""
+        import re
+
+        from tools.tpulint.metricsdoc import DEFAULT_PATHS
+
+        script = (REPO / "scripts" / "lint.sh").read_text()
+        call = re.search(r"python -m tools\.tpulint \\\n(.*?)--baseline",
+                         script, re.S).group(1)
+        scanned = call.replace("\\\n", " ").split()
+        assert scanned, "no path parsed out of scripts/lint.sh"
+        for path in [*scanned, *DEFAULT_PATHS]:
+            assert (REPO / path).exists(), path
+
     def test_seeded_violation_detected(self, tmp_path):
         """A seeded .item() inside a jitted fn must be flagged as NEW even
         with the committed baseline in effect."""

@@ -34,9 +34,7 @@ import re
 import sys
 from typing import Dict, List, Tuple
 
-DEFAULT_PATHS = ("deepspeed_tpu", "tools", "bench.py", "bench_infer.py",
-                 "bench_moe.py", "bench_rlhf.py", "bench_zero.py",
-                 "__graft_entry__.py")
+DEFAULT_PATHS = ("deepspeed_tpu", "tools", "__graft_entry__.py")
 DEFAULT_DOC = os.path.join("docs", "observability.md")
 _METRIC_METHODS = ("counter", "gauge", "histogram")
 _BACKTICK = re.compile(r"`([^`]+)`")
