@@ -27,10 +27,25 @@ calls and the one place kernel or reference is chosen):
   bf16 terms stacked into the one product, ``_dot_f32``). GQA-native (KV
   heads never expanded), alibi in-kernel; a tile's tail past the row's
   length is masked by true position, in the scores and in v.
-* ``paged_prefill_attention`` — the chunked-prefill mate: C queries at
-  absolute positions ``start..start+C-1`` read prior context through the
-  same table, flash-accumulating page by page (grid ``(B, K, MAXB)``), so a
-  later chunk never materializes the gathered view either.
+* ``paged_prefill_attention`` — the same walk under a chunk of queries: C
+  queries a row at absolute positions ``start..start+C-1``, of which the
+  row's ``length`` says how many are real (``start + n_valid`` keys). The
+  grid runs over the rows; a row's step copies ITS resident pages up to its
+  last real token, a tile at a time into the same two buffers, the next
+  tile in flight (``_pages_per_tile`` again: 512 keys a tile at the served
+  page, where the budget binds), and flash-accumulates every head against
+  each tile, a group of heads at a time (``_heads_per_group``: a slab of
+  whole 128-lane tiles of q, the page and the accumulator, which a loop
+  can address). No copy and no step for a pad slot's page or a table slot
+  past the real length; a row of length 0 writes zeros. The products take
+  q, k and v as they are stored (bf16 x bf16 as served), summed in
+  float32; scores, running max, sum and accumulator stay float32 and
+  ``scale`` multiplies the scores; ``p`` is ROUNDED to the values' dtype
+  for the value product, as the reference below and the training flash
+  kernels round it (with a chunk of rows in the product the three-term
+  float32 ``p`` of the decode walk would triple it). Only tiles that
+  overlap ``[start, start + C)`` pay for the causal mask. So a later chunk
+  never materializes the gathered view either.
 
 Layout contract (shared with ``models/transformer._layer_forward``): the
 arena is LEFT-ALIGNED — the token at absolute position ``p`` sits in block
@@ -40,8 +55,8 @@ validity story and the alibi key bias is exact by construction.
 
 The kernels take the WHOLE arena ``(L, NUM_BLOCKS, BLOCK, K*D)`` and a
 ``layer`` index, never one layer's pool. ``layer`` (a traced int32 scalar:
-the model's layer scan hands down its loop index) rides as a third
-scalar-prefetch operand and the k/v index maps put it in front of the page
+the model's layer scan hands down its loop index) rides as a
+scalar-prefetch operand and the kernels' copies put it in front of the page
 id, so a page's DMA starts at ``arena[layer, table[row, page]]`` where the
 arena lies. A custom call needs each operand as a buffer of its own: handed
 ``arena[layer]``, XLA materialises that pool (185 MiB at OPT-1.3B's serving
@@ -75,15 +90,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
-# k + v pages (prefill) or tiles of pages (decode), two buffers each — ONE
-# budget shared with the dense decode kernel's tile sizing
+# k + v tiles of pages, two buffers each, in both walks — ONE budget shared
+# with the dense decode kernel's tile sizing
 from .decode_attention import VMEM_KV_BUDGET as _VMEM_PAGE_BUDGET
 from .decode_attention import tiled_vmem_bytes
 from . import registry
 
-# keys a tile of the decode walk holds at most (and at least 128, where the
-# budget allows): see ``_pages_per_tile``
+# keys a tile of a walk holds at most (and at least 128, where the budget
+# allows): see ``_pages_per_tile``. A chunk of queries has the rows to feed
+# a wider tile; at the served page (2,048 lanes of bf16) the budget, not the
+# cap, sets its 512
 _TILE_KEYS = 256
+_CHUNK_TILE_KEYS = 512
 
 
 def _check_page_fits(block_size: int, width: int, dtype) -> None:
@@ -119,13 +137,14 @@ def _layer_operand(layer) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _pages_per_tile(block_size: int, width: int, dtype) -> int:
-    """Pages one tile of the decode walk holds — derived, not set: the
-    largest power of two whose k + v tiles, two buffers each, fit the VMEM
-    budget as VMEM lays them out, capped at ``_TILE_KEYS`` keys a tile (at
-    least one page: ``_check_page_fits`` guards that one)."""
+def _pages_per_tile(block_size: int, width: int, dtype,
+                    max_keys: int = _TILE_KEYS) -> int:
+    """Pages one tile of a walk holds — derived, not set: the largest power
+    of two whose k + v tiles, two buffers each, fit the VMEM budget as VMEM
+    lays them out, capped at ``max_keys`` keys a tile (at least one page:
+    ``_check_page_fits`` guards that one)."""
     pages = 1
-    while (2 * pages * block_size <= _TILE_KEYS
+    while (2 * pages * block_size <= max_keys
            and 4 * tiled_vmem_bytes(2 * pages * block_size, width, dtype)
            <= _VMEM_PAGE_BUDGET):
         pages *= 2
@@ -151,6 +170,75 @@ def _dot_f32(a, b, b_dim: int):
     return sum(out[i * M:(i + 1) * M] for i in range(terms))
 
 
+def _page_copies(bt_ref, len_ref, layer, k_hbm, v_hbm, kbuf, vbuf, sems):
+    """The walk's copies, for both kernels: ``each_copy(row, tile, slot)``
+    starts (or, with ``wait``, waits for) the copy of every RESIDENT page of
+    ``row``'s tile ``tile``, ``arena[layer, table[row, page]]``, into slot p
+    of buffer ``slot`` — nothing for a table slot past the row's last
+    resident page."""
+    P, BS = kbuf.shape[1:3]
+
+    def each_copy(row, tile, slot, wait=False):
+        first = tile * P
+        resident = jnp.minimum(pl.cdiv(len_ref[row], BS) - first, P)
+
+        def page(p, carry):
+            blk = bt_ref[row, first + p]
+            for side, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                copy = pltpu.make_async_copy(hbm.at[layer, blk],
+                                             buf.at[slot, p],
+                                             sems.at[side, slot])
+                copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, resident, page, 0)
+
+    return each_copy
+
+
+def _first_tile(each_copy, len_ref, slot_ref, row):
+    """The buffer ``row``'s first tile is in: the row above started it
+    beside its own last tile, unless that row was empty (or is none), and
+    then the row starts it here."""
+    base = slot_ref[0]
+
+    @pl.when((row == 0) | (len_ref[jnp.maximum(row - 1, 0)] == 0))
+    def _own_first_tile():
+        each_copy(row, 0, base)
+
+    return base
+
+
+def _tile_arrives(each_copy, vbuf, row, rows, t, n_tiles, base, length):
+    """The buffer that holds ``row``'s tile ``t``, copied. The next tile's
+    copies go out first: this row's, or after its last the first of the row
+    below. A tile's tail past the row's length holds what was there before
+    (another row's pages, or whatever the buffer started with): the kernels
+    mask its scores, and its v is zeroed here: 0 * NaN is NaN."""
+    _, P, BS, W = vbuf.shape
+    slot = (base + t) % 2
+
+    @pl.when(t + 1 < n_tiles)
+    def _next_tile():
+        each_copy(row, t + 1, 1 - slot)
+
+    @pl.when((t + 1 == n_tiles) & (row + 1 < rows))
+    def _next_row():
+        each_copy(jnp.minimum(row + 1, rows - 1), 0, 1 - slot)
+
+    each_copy(row, t, slot, wait=True)
+
+    @pl.when((t + 1) * P * BS > length)
+    def _zero_tail():
+        pos = (t * P * BS
+               + jax.lax.broadcasted_iota(jnp.int32, (P, BS, W), 0) * BS
+               + jax.lax.broadcasted_iota(jnp.int32, (P, BS, W), 1))
+        vbuf[slot] = jnp.where(pos < length, vbuf[slot].astype(jnp.float32),
+                               0.0).astype(vbuf.dtype)
+
+    return slot
+
+
 def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
                    o_ref, kbuf, vbuf, sems, lane, diag, qbd, acc, m_scr,
                    l_scr, slot_ref, *, scale: float, n_heads: int,
@@ -165,23 +253,8 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
     length = len_ref[r]
     n_tiles = pl.cdiv(length, TK)
 
-    def each_copy(row, tile, slot, wait=False):
-        """Start (or wait for) the copy of every RESIDENT page of ``row``'s
-        tile ``tile`` into buffer ``slot``: nothing for a table slot past
-        the row's last resident page."""
-        first = tile * P
-        resident = jnp.minimum(pl.cdiv(len_ref[row], BS) - first, P)
-
-        def page(p, carry):
-            blk = bt_ref[row, first + p]
-            for side, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
-                copy = pltpu.make_async_copy(hbm.at[layer, blk],
-                                             buf.at[slot, p],
-                                             sems.at[side, slot])
-                copy.wait() if wait else copy.start()
-            return carry
-
-        jax.lax.fori_loop(0, resident, page, 0)
+    each_copy = _page_copies(bt_ref, len_ref, layer, k_hbm, v_hbm, kbuf, vbuf,
+                             sems)
 
     @pl.when(r == 0)
     def _first_row():
@@ -204,46 +277,15 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
 
     @pl.when(n_tiles > 0)
     def _row():
-        # the buffer this row's first tile is in: the row above started it
-        # beside its own last tile, unless that row was empty (or is none)
-        base = slot_ref[0]
-
-        @pl.when((r == 0) | (len_ref[jnp.maximum(r - 1, 0)] == 0))
-        def _own_first_tile():
-            each_copy(r, 0, base)
-
+        base = _first_tile(each_copy, len_ref, slot_ref, r)
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         qbd[:] = (_dot_f32(q_ref[0], lane[:], 0) * diag[:]).astype(qbd.dtype)
 
         def tile(t, carry):
-            slot = (base + t) % 2
-            # the next tile's copies go out before this one is computed:
-            # this row's, or after its last the first of the row below
-            @pl.when(t + 1 < n_tiles)
-            def _next_tile():
-                each_copy(r, t + 1, 1 - slot)
-
-            @pl.when((t + 1 == n_tiles) & (r + 1 < R))
-            def _next_row():
-                each_copy(jnp.minimum(r + 1, R - 1), 0, 1 - slot)
-
-            each_copy(r, t, slot, wait=True)
-
-            # a tile's tail past the row's length holds what was there
-            # before: another row's pages, or whatever the buffer started
-            # with. Scores are masked below; v is zeroed: 0 * NaN is NaN
-            @pl.when((t + 1) * TK > length)
-            def _zero_tail():
-                pos = (t * TK
-                       + jax.lax.broadcasted_iota(jnp.int32, (P, BS, W), 0)
-                       * BS
-                       + jax.lax.broadcasted_iota(jnp.int32, (P, BS, W), 1))
-                vbuf[slot] = jnp.where(pos < length,
-                                       vbuf[slot].astype(jnp.float32),
-                                       0.0).astype(vbuf.dtype)
-
+            slot = _tile_arrives(each_copy, vbuf, r, R, t, n_tiles, base,
+                                 length)
             k = kbuf[slot].reshape(TK, W).astype(qbd.dtype)
             s = jax.lax.dot_general(
                 qbd[:], k, (((1,), (1,)), ((), ())),
@@ -339,68 +381,142 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _heads_per_step(kv_heads: int, head_dim: int) -> int:
-    """KV heads one prefill grid step reads: the fewest whose lanes make a
-    legal block of the ``(BLOCK, K*D)`` page — a multiple of 128 (two heads
-    at head_dim 64, one at 128), else the whole page."""
+def _heads_per_group(kv_heads: int, head_dim: int) -> int:
+    """KV heads the prefill kernel takes out of a tile at a time: the fewest
+    whose lanes make a slab that a loop can address in the ``(BLOCK, K*D)``
+    page — a multiple of 128 (two heads at head_dim 64, one at 128), else
+    the whole page."""
     for hp in range(1, kv_heads):
         if kv_heads % hp == 0 and (hp * head_dim) % LANES == 0:
             return hp
     return kv_heads
 
 
-def _prefill_kernel(bt_ref, start_ref, layer_ref, q_ref, k_ref, v_ref,
-                    alibi_ref, o_ref, acc, m_scr, l_scr, *, scale: float,
-                    bs: int, C: int, has_alibi: bool):
+def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
+                    v_hbm, alibi_ref, o_ref, kbuf, vbuf, sems, acc, m_scr,
+                    l_scr, slot_ref, *, scale: float, n_heads: int,
+                    kv_heads: int, has_alibi: bool):
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    st = start_ref[b]
-    HP, GC, D = q_ref.shape[1:]
+    B = pl.num_programs(0)
+    _, P, BS, W = kbuf.shape
+    TK = P * BS
+    C = q_ref.shape[1]
+    D = W // kv_heads
+    G = n_heads // kv_heads
+    n_groups = m_scr.shape[0]
+    HP = kv_heads // n_groups           # KV heads a group; G * HP queries'
+    pd = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+    start = start_ref[b]
+    length = len_ref[b]
+    n_tiles = pl.cdiv(length, TK)
+    # tiles wholly at or below ``start`` are visible to every query
+    n_open = jnp.minimum((start + 1) // TK, n_tiles)
+    each_copy = _page_copies(bt_ref, len_ref, layer_ref[0], k_hbm, v_hbm,
+                             kbuf, vbuf, sems)
 
-    @pl.when(j == 0)
-    def _init():
+    def lanes(g, width):
+        """Group ``g``'s slab of ``width`` lanes: where there is more than
+        one group a slab is whole 128-lane tiles, so a loop can address it."""
+        first = g * width
+        return pl.ds(first if n_groups == 1 else pl.multiple_of(first, LANES),
+                     width)
+
+    def each_group(body):
+        if n_groups == 1:
+            return body(0)
+
+        def step(g, carry):
+            body(g)
+            return carry
+
+        jax.lax.fori_loop(0, n_groups, step, 0)
+
+    @pl.when(b == 0)
+    def _first_row():
+        slot_ref[0] = 0
+
+    @pl.when(n_tiles == 0)
+    def _empty_row():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(n_tiles > 0)
+    def _row():
+        base = _first_tile(each_copy, len_ref, slot_ref, b)
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    # a page is visible iff it holds positions <= the last query (st + C - 1)
-    @pl.when(j * bs < st + C)
-    def _step():
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (GC, bs), 1)
-        # query row r = (g, c): its absolute position is st + (r mod C)
-        qpos = st + jax.lax.broadcasted_iota(jnp.int32, (GC, bs), 0) % C
-        for h in range(HP):                           # static: HP is 1 or 2
-            q = q_ref[0, h].astype(jnp.float32) * scale   # (GC, D), rows (g, c)
-            k = k_ref[0, :, h * D:(h + 1) * D].astype(jnp.float32)  # (bs, D)
-            v = v_ref[0, :, h * D:(h + 1) * D].astype(jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if has_alibi:
-                s = s + alibi_ref[0, h][:, None] * col.astype(jnp.float32)
-            s = jnp.where(col <= qpos, s, NEG_INF)
-            m_prev = m_scr[h, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[h] = jnp.broadcast_to(
-                corr * l_scr[h, :, :1] + jnp.sum(p, axis=1, keepdims=True),
-                l_scr.shape[1:])
-            acc[h] = acc[h] * corr + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        def tile(t, carry, *, masked: bool):
+            slot = _tile_arrives(each_copy, vbuf, b, B, t, n_tiles, base,
+                                 length)
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = l_scr[:, :, :1]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc[:] / safe).astype(o_ref.dtype)
+            def group(g):
+                # left-aligned layout: a tile's column IS the key position
+                col = t * TK + jax.lax.broadcasted_iota(jnp.int32, (1, TK), 1)
+                if masked:
+                    # a query sees the keys at or below its position and
+                    # none past the row's real tokens (a pad query past
+                    # them sees them all: finite, and never read)
+                    qpos = jnp.minimum(
+                        start + jax.lax.broadcasted_iota(jnp.int32, (C, 1),
+                                                         0), length - 1)
+                    keep = col <= qpos                          # (C, TK)
+                k = kbuf[slot, :, :, lanes(g, HP * D)].reshape(TK, HP * D)
+                v = vbuf[slot, :, :, lanes(g, HP * D)].reshape(TK, HP * D)
+                q = q_ref[0, :, lanes(g, G * HP * D)]
+                a = acc[:, lanes(g, G * HP * D)]
+                out = []
+                for j in range(G * HP):     # static: a head is a lane slice
+                    kv = slice(j // G * D, (j // G + 1) * D)
+                    s = jax.lax.dot_general(
+                        q[:, j * D:(j + 1) * D].astype(pd),
+                        k[:, kv].astype(pd), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+                    if has_alibi:
+                        s = s + (alibi_ref[0, g * G * HP + j]
+                                 * col.astype(jnp.float32))
+                    if masked:
+                        s = jnp.where(keep, s, NEG_INF)
+                    m_prev = m_scr[g, :, j:j + 1]
+                    m_new = jnp.maximum(m_prev,
+                                        jnp.max(s, axis=1, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    corr = jnp.exp(m_prev - m_new)
+                    l_scr[g, :, j:j + 1] = (
+                        corr * l_scr[g, :, j:j + 1]
+                        + jnp.sum(p, axis=1, keepdims=True))
+                    m_scr[g, :, j:j + 1] = m_new
+                    # p is rounded to the values' dtype, as the reference
+                    # and the training flash kernels round it; its sum is not
+                    out.append(
+                        a[:, j * D:(j + 1) * D] * corr + jax.lax.dot_general(
+                            p.astype(v.dtype), v[:, kv],
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32))
+                acc[:, lanes(g, G * HP * D)] = jnp.concatenate(out, axis=1)
+
+            each_group(group)
+            return carry
+
+        jax.lax.fori_loop(0, n_open, functools.partial(tile, masked=False),
+                          0)
+        jax.lax.fori_loop(n_open, n_tiles,
+                          functools.partial(tile, masked=True), 0)
+        slot_ref[0] = (base + n_tiles) % 2
+
+        def finish(g):
+            a = acc[:, lanes(g, G * HP * D)]
+            o_ref[0, :, lanes(g, G * HP * D)] = jnp.concatenate(
+                [a[:, j * D:(j + 1) * D] / l_scr[g, :, j:j + 1]
+                 for j in range(G * HP)], axis=1).astype(o_ref.dtype)
+
+        each_group(finish)
 
 
 def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
                             v_arena: jax.Array, layer,
                             block_table: jax.Array, start: jax.Array,
+                            lengths: Optional[jax.Array] = None,
                             alibi: Optional[jax.Array] = None,
                             scale: Optional[float] = None,
                             interpret: bool = False) -> jax.Array:
@@ -409,71 +525,78 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
     (the serving ``prefill_chunk`` contract; the chunk's own keys must
     already be scatter-written into the arena); arenas (L, NUM_BLOCKS,
     BLOCK, K*D) and the int32 scalar ``layer`` to read, as in
-    ``paged_decode_attention``. Returns (B, C, N, D). Grid (B, K/HP, MAXB):
-    each group of HP KV heads (``_heads_per_step``) flash-accumulates its
-    G*C query rows per head, page by page; pages past ``start + C`` never
-    move."""
+    ``paged_decode_attention``; lengths (B,) int32 — the keys a row holds
+    up to and including its last REAL query (``start + n_valid``; None: the
+    whole chunk is real; 0 ⇒ inactive row, output zeros). Returns
+    (B, C, N, D). The decode walk under a chunk of queries: the grid runs
+    over the rows, a row's step copies ITS ``ceil(length / BLOCK)`` resident
+    pages from the arenas in HBM, a tile of pages at a time with the next in
+    flight, and flash-accumulates every head against each tile, a group of
+    heads (``_heads_per_group``) at a time. The products take q, k and v as
+    they are stored and sum in float32; ``p`` is rounded to the values'
+    dtype for the value product. Queries past a row's real tokens come out
+    finite and mean nothing."""
     B, C, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
-    BS = k_arena.shape[2]
-    MAXB = block_table.shape[1]
-    G = N // K
-    GC = G * C
-    HP = _heads_per_step(K, D)
-    _check_page_fits(BS, HP * D, k_arena.dtype)
+    BS, W = k_arena.shape[2:]
+    _check_page_fits(BS, W, k_arena.dtype)
+    pages = _pages_per_tile(BS, W, k_arena.dtype, _CHUNK_TILE_KEYS)
+    HP = _heads_per_group(K, D)
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
-    # (B, C, N, D) -> (B, K, G*C, D): head-major rows grouped by KV head so
-    # one grid step's queries share the page it just DMA'd
-    qk = q.reshape(B, C, K, G, D).transpose(0, 2, 3, 1, 4).reshape(
-        B, K, GC, D)
-    if has_alibi:
-        # per-row slopes, expanded host-side to match the (g, c) row order
-        # (in-kernel gather by r // C would need an unsupported dynamic
-        # index; a (K, G*C) operand is trivially small)
-        alibi_arr = jnp.broadcast_to(
-            alibi.astype(jnp.float32).reshape(K, G)[:, :, None],
-            (K, G, C)).reshape(K // HP, HP, GC)
-    else:
-        alibi_arr = jnp.zeros((K // HP, HP, GC), jnp.float32)
-
-    def _page(b, kb, j, bt_ref, start_ref, layer_ref):
-        npages = jnp.maximum((start_ref[b] + C + BS - 1) // BS, 1)
-        return (layer_ref[0], bt_ref[b, jnp.minimum(j, npages - 1)], 0, kb)
-
-    def _heads(b, kb, j, *_):
-        return (b, kb, 0, 0)
-
+    alibi_arr = (alibi.astype(jnp.float32).reshape(1, N) if has_alibi
+                 else jnp.zeros((1, N), jnp.float32))
+    if lengths is None:
+        lengths = start + C
+    # running max and sum: a group's heads in columns 0..G*HP-1
+    stats = (K // HP, C, pl.cdiv(N // K * HP, LANES) * LANES)
+    # what the kernel holds: the k + v tiles, q and the output as the
+    # pipeline double-buffers them, the accumulator and the statistics; and
+    # room for a few (C, tile) float32 blocks of a head's scores (a chunk of
+    # 256 passes the 16 MiB a kernel gets unasked; never ask for less)
+    held = (4 * tiled_vmem_bytes(pages * BS, W, k_arena.dtype)
+            + 4 * tiled_vmem_bytes(C, N * D, q.dtype)
+            + tiled_vmem_bytes(C, N * D, jnp.float32)
+            + 2 * stats[0] * tiled_vmem_bytes(*stats[1:], jnp.float32))
+    scores = tiled_vmem_bytes(C, pages * BS, jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, K // HP, MAXB),
+        num_scalar_prefetch=4,
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, HP, GC, D), _heads),
-            pl.BlockSpec((None, 1, BS, HP * D), _page),
-            pl.BlockSpec((None, 1, BS, HP * D), _page),
-            pl.BlockSpec((1, HP, GC), lambda b, kb, j, *_: (kb, 0, 0)),
+            # a token's heads side by side in the lanes, as the arena has
+            # them: (B, C, N, D) goes in and comes out without a transpose
+            pl.BlockSpec((1, C, N * D), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, HP, GC, D), _heads),
+        out_specs=pl.BlockSpec((1, C, N * D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((HP, GC, D), jnp.float32),
-            pltpu.VMEM((HP, GC, LANES), jnp.float32),
-            pltpu.VMEM((HP, GC, LANES), jnp.float32),
+            pltpu.VMEM((2, pages, BS, W), k_arena.dtype),
+            pltpu.VMEM((2, pages, BS, W), v_arena.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),          # (k | v, buffer)
+            pltpu.VMEM((C, N * D), jnp.float32),
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
-    kernel = functools.partial(_prefill_kernel, scale=scale, bs=BS, C=C,
-                               has_alibi=has_alibi)
+    kernel = functools.partial(_prefill_kernel, scale=scale, n_heads=N,
+                               kv_heads=K, has_alibi=has_alibi)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, GC, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, C, N * D), q.dtype),
+        # rows in order: a row starts the copies of the next one's first tile
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(held + 8 * scores, 16 << 20)),
         name="paged_prefill_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
-      _layer_operand(layer), qk, k_arena, v_arena, alibi_arr)
-    return out.reshape(B, K, G, C, D).transpose(0, 3, 1, 2, 4).reshape(
-        B, C, N, D)
+      lengths.astype(jnp.int32), _layer_operand(layer),
+      q.reshape(B, C, N * D), k_arena, v_arena, alibi_arr)
+    return out.reshape(B, C, N, D)
 
 
 # ---------------------------------------------------------------------------
@@ -540,10 +663,11 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
     ``positions`` (B, S) against ``arena[layer]`` through ``block_table``;
     returns (B, S, N, D). Where the Pallas kernels run (``ops/registry``'s
     platform probe) one query a row takes the decode walk and S > 1 the
-    prefill kernel, which reads ``positions[:, 0]`` as the row's start: the
-    serving programs' contract that S > 1 queries sit at ``start + 0..S-1``
-    (slots past a row's real tokens ride position -1 and are never read).
-    Anywhere else: ``reference_paged_attention``."""
+    prefill kernel, which reads ``positions[:, 0]`` as the row's start and
+    the largest position + 1 as its length: the serving programs' contract
+    that S > 1 queries sit at ``start + 0..S-1`` (slots past a row's real
+    tokens ride position -1 and are never read; a row of them all holds
+    nothing). Anywhere else: ``reference_paged_attention``."""
     if not registry.kernels_active():
         return reference_paged_attention(q, k_arena, v_arena, layer,
                                          block_table, positions, alibi=alibi)
@@ -552,4 +676,6 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
                                       block_table, positions[:, 0] + 1,
                                       alibi=alibi)[:, None]
     return paged_prefill_attention(q, k_arena, v_arena, layer, block_table,
-                                   positions[:, 0], alibi=alibi)
+                                   positions[:, 0],
+                                   jnp.max(positions, axis=1) + 1,
+                                   alibi=alibi)

@@ -107,11 +107,73 @@ def _poison_what_no_row_reads(arena, bt, lengths):
     return jnp.where(jnp.asarray(live)[None, :, :, None], arena, jnp.nan)
 
 
+# the chunk walk's edges, a row as (start, real tokens): the arenas of these
+# tests give the prefill kernel a tile of 32 pages
+CHUNK_TILE = 32 * BS
+CHUNKS = {
+    "start-0": [(0, 16)],
+    "ends-on-a-tile-edge": [(CHUNK_TILE - 16, 16)],
+    "ends-one-past-the-edge": [(CHUNK_TILE - 15, 16)],
+    "straddles-two-tiles": [(CHUNK_TILE - 8, 16)],
+    "several-tiles": [(2 * CHUNK_TILE + 40, 16)],
+    # fewer real tokens than slots: the pad queries' keys were never written
+    "short-chunk": [(37, 5)],
+    "one-token": [(CHUNK_TILE, 1)],
+    # very different rows in one call, empty ones among them: a row's first
+    # tile is started by the row above, unless that one is empty
+    "mixed": [(0, 16), (CHUNK_TILE - 8, 16), (0, 0), (0, 0),
+              (2 * CHUNK_TILE + 40, 9), (17, 1), (0, 0)],
+    # the speculative verify step: 5 slots a row (not a multiple of 8),
+    # 1-5 of them real, one row that holds nothing
+    "verify": [(100, 5), (0, 0), (CHUNK_TILE - 2, 3), (7, 1)],
+    "small-table": [(21, 16), (0, 0), (0, 9)],
+}
+CHUNK_SLOTS = {"verify": 5}     # every other case: 16 slots a row
+
+
+def _chunk_case(case, nb, n, d, maxb=3 * 32 + 8, seed=0, dtype=jnp.float32):
+    """(q, block_table, start, lengths, positions, real) of ``CHUNKS[case]``:
+    positions -1 on the slots past a row's real tokens, as the serving
+    programs send them; ``real`` marks the queries a caller reads."""
+    rows = CHUNKS[case]
+    C = CHUNK_SLOTS.get(case, 16)
+    bt, lengths = _walk_tables([s + v for s, v in rows], nb, maxb=maxb,
+                               seed=seed)
+    start = jnp.asarray(np.array([s for s, _ in rows], np.int32))
+    real = np.arange(C)[None] < np.array([v for _, v in rows])[:, None]
+    pos = jnp.where(jnp.asarray(real),
+                    start[:, None] + jnp.arange(C, dtype=jnp.int32)[None], -1)
+    q = jax.random.normal(jax.random.PRNGKey(16 + seed),
+                          (len(rows), C, n, d), dtype)
+    return q, bt, start, lengths, pos, real
+
+
 def _dense_view(arena, layer, bt, d=32):
     pool = arena[layer]
     nb, bs, kd = pool.shape
     b, maxb = bt.shape
     return pool[bt].reshape(b, maxb * bs, kd // d, d)
+
+
+def _count_started_copies(monkeypatch):
+    """A list that grows by one for every page copy a kernel starts."""
+    started = []
+
+    class Counted:
+        def __init__(self, copy):
+            self.copy = copy
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    real = paged_module.pltpu.make_async_copy
+    monkeypatch.setattr(paged_module.pltpu, "make_async_copy",
+                        lambda *a: Counted(real(*a)))
+    return started
 
 
 class TestPagedDecodeKernel:
@@ -203,22 +265,7 @@ class TestPagedDecodeKernel:
         """What the PR is for, without a chip: the kernel starts one copy of
         k and one of v for each RESIDENT page, ceil(length / BLOCK) a row,
         and none for a table slot past it, however wide the table is."""
-        started = []
-
-        class Counted:
-            def __init__(self, copy):
-                self.copy = copy
-
-            def start(self):
-                jax.debug.callback(lambda: started.append(1))
-                self.copy.start()
-
-            def wait(self):
-                self.copy.wait()
-
-        real = paged_module.pltpu.make_async_copy
-        monkeypatch.setattr(paged_module.pltpu, "make_async_copy",
-                            lambda *a: Counted(real(*a)))
+        started = _count_started_copies(monkeypatch)
         lengths = WALKS[walk]
         ka, va = _arena(nb=161, k=2, seed=6)
         bt, lens = _walk_tables(lengths, 161, maxb=maxb)
@@ -261,8 +308,9 @@ class TestPagedDecodeKernel:
 
 
 class TestPagedPrefillKernel:
-    # heads per grid step: 4 (4x32 lanes), the whole page (2x32), 2 (64-wide
-    # heads pair up), 1 (128-wide heads)
+    # KV heads a group (the slab of lanes the kernel's loop addresses): 4
+    # (4x32 lanes), the whole page (2x32), 2 (64-wide heads pair up), 1
+    # (128-wide heads)
     @pytest.mark.parametrize("layer", LAYERS)
     @pytest.mark.parametrize("n,k,d", [(4, 4, 32), (8, 2, 32), (4, 4, 64),
                                        (4, 2, 128)])
@@ -310,6 +358,94 @@ class TestPagedPrefillKernel:
         want = dot_product_attention(q, kk, vv, full, causal=False)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("n,k,d", [(4, 2, 32), (8, 2, 32), (2, 2, 128)])
+    @pytest.mark.parametrize("case", sorted(set(CHUNKS) - {"small-table"}))
+    def test_walk_matches_reference_at_its_edges(self, case, n, k, d):
+        """A chunk at position 0, ending on a tile's edge and one past it,
+        across two tiles, after several, with fewer real tokens than slots,
+        5 slots a row with an empty row among them, and all of these in one
+        call; GQA 8 over 2 and head size 128. What lies past a row's real
+        tokens is NaN, in k and in v, the scratch page too, and never
+        reaches the output: the queries past them come out finite."""
+        assert paged_module._pages_per_tile(
+            BS, k * d, jnp.float32, paged_module._CHUNK_TILE_KEYS) * BS \
+            == CHUNK_TILE
+        ka, va = _arena(nb=161, k=k, d=d, seed=7)
+        q, bt, start, lengths, pos, real = _chunk_case(case, 161, n, d)
+        ref = _pool_reference(q, ka, va, 1, bt, pos)
+        out = paged_prefill_attention(
+            q, _poison_what_no_row_reads(ka, bt, lengths),
+            _poison_what_no_row_reads(va, bt, lengths), 1, bt, start,
+            lengths, interpret=INTERPRET)
+        assert bool(jnp.all(jnp.isfinite(out)))
+        np.testing.assert_allclose(np.asarray(out)[real],
+                                   np.asarray(ref)[real],
+                                   atol=2e-5, rtol=2e-5)
+        assert not np.asarray(out)[np.asarray(lengths) == 0].any()
+
+    @pytest.mark.parametrize("case", ["several-tiles", "mixed", "verify"])
+    def test_walk_alibi_over_several_tiles(self, case):
+        ka, va = _arena(nb=161, k=2, seed=8)
+        n = 4
+        q, bt, start, lengths, pos, real = _chunk_case(case, 161, n, 32,
+                                                       seed=3)
+        al = alibi_slopes(n)
+        out = paged_prefill_attention(q, ka, va, 2, bt, start, lengths,
+                                      alibi=al, interpret=INTERPRET)
+        ref = _pool_reference(q, ka, va, 2, bt, pos, alibi=al)
+        np.testing.assert_allclose(np.asarray(out)[real],
+                                   np.asarray(ref)[real],
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_walk_in_the_served_dtype(self, dtype=jnp.bfloat16, tol=2e-2):
+        """bf16 as served: q, k and v go into the products as stored, p
+        rounded to bf16 for the value product, everything summed in
+        float32 — within the decode walk's 2e-2 of the reference computed in
+        float32 from the same bf16 arena."""
+        ka, va = _arena(nb=161, k=2, seed=9, dtype=dtype)
+        q, bt, start, lengths, pos, real = _chunk_case(
+            "mixed", 161, 4, 32, seed=5, dtype=dtype)
+        out = paged_prefill_attention(q, ka, va, 0, bt, start, lengths,
+                                      interpret=INTERPRET)
+        assert out.dtype == dtype
+        f32 = jnp.float32
+        ref = _pool_reference(q.astype(f32), ka.astype(f32), va.astype(f32),
+                              0, bt, pos)
+        np.testing.assert_allclose(np.asarray(out, np.float32)[real],
+                                   np.asarray(ref)[real], atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("case,maxb", [
+        ("small-table", 4), ("mixed", 128), ("mixed", 512), ("verify", 128),
+        ("short-chunk", 128), ("several-tiles", 512)])
+    def test_copies_follow_resident_pages_not_the_table(self, case, maxb,
+                                                        monkeypatch):
+        """The decode walk's count, for a chunk: one copy of k and one of v
+        for each page up to a row's last REAL token, ceil(length / BLOCK) a
+        row, and none for a table slot past it (nor for a pad slot's page),
+        however wide the table is."""
+        started = _count_started_copies(monkeypatch)
+        ka, va = _arena(nb=161, k=2, seed=6)
+        q, bt, start, lengths, _, _ = _chunk_case(case, 161, 4, 32,
+                                                  maxb=maxb)
+        out = paged_prefill_attention(q, ka, va, 1, bt, start, lengths,
+                                      interpret=INTERPRET)
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+        resident = sum(-(-(s + v) // BS) for s, v in CHUNKS[case])
+        assert len(started) == 2 * resident
+
+    def test_whole_chunk_is_real_where_no_length_is_given(self):
+        ka, va = _arena(k=2)
+        bt = jnp.asarray(np.array([[5, 1, 7, 0]], np.int32))
+        start = jnp.asarray(np.array([21], np.int32))
+        q = jax.random.normal(jax.random.PRNGKey(10), (1, 16, 4, 32))
+        np.testing.assert_array_equal(
+            np.asarray(paged_prefill_attention(q, ka, va, 1, bt, start,
+                                               interpret=INTERPRET)),
+            np.asarray(paged_prefill_attention(q, ka, va, 1, bt, start,
+                                               start + 16,
+                                               interpret=INTERPRET)))
 
 
 class TestPagedForwardWritesInPlace:

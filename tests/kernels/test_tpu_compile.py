@@ -81,11 +81,12 @@ def _paged_decode(rows, heads, d, block, maxb, kv_heads=None):
              ((rows, maxb), I32), ((rows,), I32)])
 
 
-def _paged_prefill(chunk, heads, d, block, maxb):
-    arena = ((3, 1024, block, heads * d), BF16)
+def _paged_prefill(chunk, heads, d, block, maxb, rows=1, kv_heads=None):
+    # start and the rows' real lengths, as ``paged_attention`` hands them
+    arena = ((3, 1024, block, (kv_heads or heads) * d), BF16)
     return (paged_prefill_attention,
-            [((1, chunk, heads, d), BF16), arena, arena, ((), I32),
-             ((1, maxb), I32), ((1,), I32)])
+            [((rows, chunk, heads, d), BF16), arena, arena, ((), I32),
+             ((rows, maxb), I32), ((rows,), I32), ((rows,), I32)])
 
 
 def _dense_decode(b, t, heads, d):
@@ -135,6 +136,11 @@ CASES = {
     # the whole table of 2,048 tokens under GQA: 32 heads over 8 KV heads
     "paged-decode-gqa-full-table":
         lambda: _paged_decode(16, 32, 128, 16, 128, kv_heads=8),
+    "paged-prefill-gqa": lambda: _paged_prefill(256, 32, 128, 16, 128,
+                                                kv_heads=8),
+    # the speculative verify step: 16 rows of 5 slots
+    "paged-prefill-verify-opt-1.3b":
+        lambda: _paged_prefill(5, 32, 64, 16, 128, rows=16),
     "dense-decode-gpt2-125m": lambda: _dense_decode(8, 1024, 12, 64),
     "dense-decode-opt-1.3b": lambda: _dense_decode(8, 2048, 32, 64),
 }
