@@ -5,9 +5,11 @@ A cell names a configuration and a traffic mix; `configs/<config>.json`,
 names and nowhere else, so a later PR adds a cell by adding files and
 entries. A configuration's file also says what the harness needs to know of
 its family: which of the program's sizes is which of the source's (`widths`),
-what its plain reference is called with (`reference_args`) and the small
-model of the same family that the CPU rehearsal runs (`tiny`). `validate`
-holds the files to the parts of the contract that a run on the CPU can check.
+what its plain reference is called with (`reference_args`), the small
+model of the same family that the CPU rehearsal runs (`tiny`) and, where the
+cell is one chip's share of a deployment that divides each layer over
+several, that deployment (`share`). `validate` holds the files to the parts
+of the contract that a run on the CPU can check.
 """
 
 from __future__ import annotations
@@ -29,12 +31,18 @@ TRAFFIC_KINDS = ("train", "closed_loop", "open_loop")
 WIDTH_WORDS = ("hidden_size", "intermediate", "ffn", "latent", "state_size",
                "head_dim", "head_size", "expansion", "experts_per_tok",
                "proj")
-# counts that a deployment may divide among its chips and that a cell here
-# holds whole: a configuration's file accounts for them as for its widths
+# counts that a deployment may divide among the chips that share a layer: a
+# configuration's file accounts for them as for its widths, and holds them
+# whole unless its `share` block names them
 COUNT_WORDS = ("heads", "n_head", "vocab_size", "experts")
-# the one kind of size a configuration may cut
+# the one kind of size a configuration may cut with no deployment stated
 DEPTH_WORDS = ("layer",)
 TINY_KEYS = {"preset", "dtype", "overrides", "reference_args"}
+SHARE_KEYS = {"chips", "divided", "how"}
+# the floors of the `model-configs` guide, section 4, for a chip's share
+MIN_EXPERTS_HELD = 8
+MAX_CHIPS_OVER_A_VOCABULARY = 8
+MIN_LAYERS_OF_A_SHARE = 4
 
 
 def names_a_width(key: str) -> bool:
@@ -50,6 +58,12 @@ def names_a_size(key: str, value: Any) -> bool:
 
 def names_depth(key: str) -> bool:
     return any(w in key for w in DEPTH_WORDS)
+
+
+def names_a_count(key: str, value: Any) -> bool:
+    """A size that chips sharing a layer may divide among them: how many
+    experts, heads or rows of the vocabulary, never how wide."""
+    return names_a_size(key, value) and not names_a_width(key)
 
 
 class SpecError(ValueError):
@@ -126,10 +140,65 @@ class Spec:
                         if self._in_cell(m, name)],
             per_layer=per_layer)
 
+    @staticmethod
+    def _validate_share(cfg: Dict[str, Any], reduced, bad) -> Dict[str, int]:
+        """The file's optional `share` block: the cell is what ONE of `chips`
+        chips that share each layer would hold. Returns, for each source key
+        it divides, the count held here (exactly `published / chips`); `{}`
+        for a file that states no deployment."""
+        if "share" not in cfg:
+            return {}
+        share, published = cfg["share"], cfg["published"]
+        if not isinstance(share, dict) or set(share) != SHARE_KEYS:
+            has = sorted(share) if isinstance(share, dict) else share
+            raise bad(f"share has keys {has!r}, not {sorted(SHARE_KEYS)}")
+        chips, divided = share["chips"], share["divided"]
+        if isinstance(chips, bool) or not isinstance(chips, int) or chips < 2:
+            raise bad(f"share.chips is {chips!r}: a share is of 2 chips or "
+                      "more that divide each layer among them")
+        if (not isinstance(divided, list) or not divided
+                or len(set(divided)) != len(divided)):
+            raise bad("share.divided lists, once each, the keys of "
+                      "'published' that the chips divide")
+        if not isinstance(share["how"], str) or not share["how"].strip():
+            raise bad("share.how says what is divided over the chips that "
+                      "share a layer and what every chip holds whole")
+        held = {}
+        for key in divided:
+            if key not in published:
+                raise bad(f"share.divided: '{key}' is no key of 'published'")
+            if names_a_width(key):
+                raise bad(f"share.divided: '{key}' is a width, and no width "
+                          "is ever cut: a share divides a count (experts, "
+                          "heads, rows of the vocabulary)")
+            if not names_a_count(key, published[key]):
+                raise bad(f"share.divided: '{key}' names no count that "
+                          "chips can divide (experts, heads, vocabulary)")
+            if key not in reduced:
+                raise bad(f"share.divided names '{key}', which 'reduced' "
+                          "does not list: the count held here is a change "
+                          "from the source")
+            if published[key] % chips:
+                raise bad(f"share: {chips} chips do not divide the source's "
+                          f"{key} of {published[key]} (remainder "
+                          f"{published[key] % chips})")
+            held[key] = published[key] // chips
+            if "experts" in key and held[key] < MIN_EXPERTS_HELD:
+                raise bad(f"share: {held[key]} of {published[key]} {key} "
+                          f"held; the floor is {MIN_EXPERTS_HELD} experts "
+                          "in each layer that has them")
+            if "vocab" in key and chips > MAX_CHIPS_OVER_A_VOCABULARY:
+                raise bad(f"share: {key} over {chips} chips; the floor is "
+                          "an eighth of the vocabulary (at most "
+                          f"{MAX_CHIPS_OVER_A_VOCABULARY} chips)")
+        return held
+
     def _validate_config(self, entry: Dict[str, Any]) -> None:
         """What a configuration's file says of its family holds together:
         no size is written that the source does not have, and the program
-        runs every size as published but the depth, where `reduced` says."""
+        runs every size as published but the depth, where `reduced` says,
+        and the counts that a stated deployment (`share`) divides among the
+        chips that share a layer, each at exactly this chip's part."""
         cfg = _load(os.path.join(self.root, entry["file"]))
 
         def bad(what):
@@ -148,22 +217,45 @@ class Spec:
             raise bad(f"no references/{cfg['reference']}.py")
         if set(cfg.get("reduced", {})) != set(entry["reduced"]):
             raise bad("'reduced' differs from BENCHMARK.json's")
+        held = self._validate_share(cfg, entry["reduced"], bad)
         for key in entry["reduced"]:
             if ((key in widths.values() or key in equal)
-                    and not names_depth(key)):
+                    and not names_depth(key) and key not in held):
                 raise bad(f"'{key}' is in 'reduced' and does not name the "
                           "depth: of the sizes in 'widths', only the number "
-                          "of layers may be cut")
+                          "of layers may be cut, or a count that a 'share' "
+                          "block lists under 'divided'")
         for key, source in widths.items():
             if source not in published:
                 raise bad(f"widths: '{source}' is no key of 'published'")
             if key not in overrides:
                 raise bad(f"model.overrides leaves '{key}' to the preset")
-            if (overrides[key] != published[source]
+            if source in held:
+                # held whole (a router keeps its published width) or exactly
+                # this chip's part: no other number is a share
+                if overrides[key] not in (published[source], held[source]):
+                    raise bad(f"model.overrides.{key} is {overrides[key]}: "
+                              f"of the source's {source} a chip of "
+                              f"{cfg['share']['chips']} holds all "
+                              f"{published[source]} or its share of "
+                              f"{held[source]}, nothing else")
+            elif (overrides[key] != published[source]
                     and source not in entry["reduced"]):
                 raise bad(f"model.overrides.{key} is {overrides[key]}, the "
                           f"source's {source} is {published[source]}, and "
                           f"'{source}' is not in 'reduced'")
+        for source, part in held.items():
+            if part not in [overrides[k] for k, s in widths.items()
+                            if s == source]:
+                raise bad(f"share.divided names '{source}', and no key that "
+                          f"'widths' maps to it holds the share of {part}")
+        if held:
+            depth = [overrides[k] for k, s in widths.items()
+                     if names_depth(s)]
+            if not depth or min(depth) < MIN_LAYERS_OF_A_SHARE:
+                raise bad(f"a share runs {depth} layers; the floor is "
+                          f"{MIN_LAYERS_OF_A_SHARE} (whole periods of the "
+                          "layer pattern: the reviewer's to check)")
         for key, other in equal.items():
             if (key not in published or other not in widths.values()
                     or published[key] != published[other]):

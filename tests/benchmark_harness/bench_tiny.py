@@ -39,12 +39,19 @@ def tiny_config(cfg: dict) -> dict:
     """The configuration's rehearsal, written as a configuration: its `tiny`
     model in place of the real one, and `published` holding that model's
     sizes. So the file validates under the same rules as the real one, and
-    the reference is called with the small model's arguments."""
+    the reference is called with the small model's arguments. Where the file
+    states a `share`, the block stays and `published` holds the WHOLE count
+    of each divided key: the smallest number the small model runs under it
+    (a router may keep the whole) times the chips."""
     cfg = copy.deepcopy(cfg)
     tiny = cfg["tiny"]
     cfg["model"] = {k: tiny[k] for k in ("preset", "dtype", "overrides")}
     for key, source in cfg["widths"].items():
         cfg["published"][source] = tiny["overrides"][key]
+    for source in cfg.get("share", {}).get("divided", ()):
+        cfg["published"][source] = cfg["share"]["chips"] * min(
+            tiny["overrides"][k] for k, s in cfg["widths"].items()
+            if s == source)
     for key, other in cfg.get("equal_widths", {}).items():
         cfg["published"][key] = cfg["published"][other]
     for arg, value in tiny["reference_args"].items():

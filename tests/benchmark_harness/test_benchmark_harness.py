@@ -54,14 +54,24 @@ def test_cell_files_are_found_by_name(cell):
     published, model = c.config["published"], c.config["model"]["overrides"]
     entry = next(x for x in DOC["configs"] if x["name"] == c.config_name)
     # of the sizes the file maps to the source's, the program changes those
-    # that `reduced` names and no other, and each of them is the depth:
-    # widths, heads and vocabulary are the source's
+    # that `reduced` names and no other, and each of them is the depth or a
+    # count that the file's `share` divides, at exactly this chip's part:
+    # widths are the source's, and so are heads, experts and vocabulary
+    # where no deployment is stated
     widths = c.config["widths"]
     cut = {source for key, source in widths.items()
            if model[key] != published[source]}
     assert cut == set(entry["reduced"]) & set(widths.values())
     assert set(entry["reduced"]) == set(c.config["reduced"])
-    assert all(spec_mod.names_depth(source) for source in cut)
+    share = c.config.get("share", {"chips": 1, "divided": []})
+    for source in cut:
+        if spec_mod.names_depth(source):
+            continue
+        assert source in share["divided"]
+        assert spec_mod.names_a_count(source, published[source])
+        assert published[source] % share["chips"] == 0
+        assert {model[k] for k, s in widths.items() if s == source} <= {
+            published[source], published[source] // share["chips"]}
     accounted = set(widths.values()) | set(c.config.get("equal_widths", {}))
     assert all(key in accounted for key, value in published.items()
                if spec_mod.names_a_size(key, value))
@@ -187,6 +197,89 @@ def _break_config(cfg, entry, how):
     raise KeyError(how)
 
 
+SHARED = "olmoe-1b-7b-d12"      # the configuration the share cases start from
+
+
+def _share(cfg, entry):
+    """OLMoE's own file as one of 8 chips that share each layer would run it:
+    the router keeps its 64 outputs, a second program key holds the 8 experts
+    that live here, an eighth of the vocabulary, attention whole. `validate`
+    builds no model, so the second key needs no program behind it. The small
+    model of the rehearsal follows: 64 experts of which 8 are held."""
+    cfg["widths"]["moe_experts_held"] = "num_experts"
+    cfg["model"]["overrides"].update(moe_experts_held=8, vocab_size=6288)
+    cfg["tiny"]["overrides"].update(moe_num_experts=64, moe_experts_held=8,
+                                    num_layers=4)
+    cfg["reduced"].update(num_experts="64 -> 8 held, the router whole",
+                          vocab_size="50304 -> 6288, an eighth")
+    cfg["share"] = {"chips": 8, "divided": ["num_experts", "vocab_size"],
+                    "how": "expert parallel over 8 chips: each holds 8 of a "
+                           "layer's 64 experts and an eighth of the "
+                           "vocabulary; router and attention whole"}
+    entry["reduced"] = entry["reduced"] + ["num_experts", "vocab_size"]
+
+
+def _undivide(cfg, entry, source):
+    """Take one count back out of the share: held whole again."""
+    cfg["share"]["divided"].remove(source)
+    del cfg["reduced"][source]
+    entry["reduced"].remove(source)
+    if source == "num_experts":
+        del cfg["widths"]["moe_experts_held"]
+        del cfg["model"]["overrides"]["moe_experts_held"]
+    else:
+        cfg["model"]["overrides"][source] = cfg["published"][source]
+
+
+def _break_share(cfg, entry, how):
+    """A sound share (`_share`), broken in one of the ways `validate`
+    refuses; returns what the refusal has to say."""
+    overrides, share = cfg["model"]["overrides"], cfg["share"]
+    if how == "share-remainder":
+        share["chips"] = 7
+        return "7 chips do not divide the source's num_experts of 64"
+    if how == "share-vocabulary-over-16-chips":
+        _undivide(cfg, entry, "num_experts")
+        share["chips"], overrides["vocab_size"] = 16, 50304 // 16
+        return "the floor is an eighth of the vocabulary"
+    if how == "share-four-experts-held":
+        _undivide(cfg, entry, "vocab_size")
+        share["chips"], overrides["moe_experts_held"] = 16, 4
+        return "4 of 64 num_experts held; the floor is 8 experts"
+    if how == "share-divides-a-width":
+        share["divided"].append("num_experts_per_tok")
+        return "'num_experts_per_tok' is a width, and no width is ever cut"
+    if how == "share-divides-no-count":
+        share["divided"].append("rope_theta")
+        return "'rope_theta' names no count"
+    if how == "share-divided-not-reduced":
+        del cfg["reduced"]["vocab_size"]
+        entry["reduced"].remove("vocab_size")
+        return "share.divided names 'vocab_size', which 'reduced' does not"
+    if how == "share-override-neither-whole-nor-share":
+        overrides["moe_experts_held"] = 9
+        return "holds all 64 or its share of 8, nothing else"
+    if how == "share-held-by-no-key":
+        overrides["moe_experts_held"] = 64
+        return "no key that 'widths' maps to it holds the share of 8"
+    if how == "share-three-layers":
+        overrides["num_layers"] = 3
+        return "the floor is 4"
+    if how == "share-of-one-chip":
+        share["chips"] = 1
+        return "a share is of 2 chips or more"
+    if how == "share-with-another-key":
+        share["layers_elsewhere"] = 4
+        return "share has keys"
+    raise KeyError(how)
+
+
+BROKEN_SHARES = ["share-remainder", "share-vocabulary-over-16-chips",
+                 "share-four-experts-held", "share-divides-a-width",
+                 "share-divides-no-count", "share-divided-not-reduced",
+                 "share-override-neither-whole-nor-share",
+                 "share-held-by-no-key", "share-three-layers",
+                 "share-of-one-chip", "share-with-another-key"]
 BROKEN_DOCS = ["bad-name", "unknown-moves", "moves-not-reported",
                "width-reduced", "two-four-chip-cells", "no-setup",
                "loose-bound", "extra-key"]
@@ -199,22 +292,173 @@ BROKEN_CONFIGS = ["config-without-tiny", "widths-source-unknown",
                   "equal-width-differs"]
 
 
-@pytest.mark.parametrize("how", BROKEN_DOCS + BROKEN_CONFIGS)
+def _add_config_and_cell(root, name, cfg, like, why):
+    """Entries in the root's BENCHMARK.json for a configuration whose file
+    is `cfg`, and for a cell of it under the traffic and the metrics of the
+    cell `like`; returns the new cell's name."""
+    doc = copy.deepcopy(DOC)
+    like = next(w for w in doc["workloads"] if w["name"] == like)
+    cell = f"{name}.{like['traffic']}"
+    doc["configs"].append({
+        "name": name, "source": cfg["source"],
+        "reduced": sorted(cfg["reduced"]),
+        "file": f"benchmarks/configs/{name}.json", "why": why})
+    doc["workloads"].append(dict(like, name=cell, config=name))
+    for m in doc["end_to_end"] + doc["per_layer"]:
+        if like["name"] in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    json.dump(doc, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    return cell
+
+
+def _shared_root(tmp):
+    """A tiny root in which OLMoE's REAL file states a share (`_share`);
+    returns the root, the document, the file's path and its entry."""
+    root = bench_tiny.make_root(str(tmp))
+    doc = copy.deepcopy(DOC)
+    entry = next(c for c in doc["configs"] if c["name"] == SHARED)
+    cfg = json.load(open(os.path.join(spec_mod.REPO_ROOT, entry["file"])))
+    _share(cfg, entry)
+    path = os.path.join(root, entry["file"])
+    json.dump(cfg, open(path, "w"))
+    json.dump(doc, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    return root, doc, path, entry
+
+
+@pytest.mark.parametrize("how", BROKEN_DOCS + BROKEN_CONFIGS + BROKEN_SHARES)
 def test_validate_refuses(how, tmp_path):
-    root = bench_tiny.make_root(str(tmp_path))
-    spec_mod.Spec(root).validate()      # sound before it is broken
-    says = None
-    if how in BROKEN_DOCS:
-        doc = _break(DOC, how)
+    if how in BROKEN_SHARES:
+        root, doc, path, entry = _shared_root(tmp_path)
     else:
-        doc = copy.deepcopy(DOC)
-        path = os.path.join(root, doc["configs"][0]["file"])
+        root, doc = bench_tiny.make_root(str(tmp_path)), copy.deepcopy(DOC)
+        entry = doc["configs"][0]
+        path = os.path.join(root, entry["file"])
+    spec_mod.Spec(root).validate()      # sound before it is broken
+    if how in BROKEN_DOCS:
+        doc, says = _break(DOC, how), None
+    else:
         cfg = json.load(open(path))
-        says = _break_config(cfg, doc["configs"][0], how)
+        says = (_break_share if how in BROKEN_SHARES
+                else _break_config)(cfg, entry, how)
         json.dump(cfg, open(path, "w"))
     json.dump(doc, open(os.path.join(root, "BENCHMARK.json"), "w"))
     with pytest.raises(spec_mod.SpecError, match=says):
         spec_mod.Spec(root).validate()
+
+
+def test_a_share_of_a_stated_deployment_validates(tmp_path):
+    """The guide's third cut at rule level, on a copy of OLMoE's file: the
+    router's key at the source's 64, a second program key mapped to the same
+    source at 64 / 8, an eighth of the vocabulary. The file's own small
+    model follows the same rule: `published` there holds the WHOLE counts
+    (what is held times the chips) and the `share` block stays."""
+    root, doc, path, entry = _shared_root(tmp_path)
+    spec = spec_mod.Spec(root)
+    spec.validate()
+    real = json.load(open(path))
+    assert [real["model"]["overrides"][k] for k in (
+        "moe_num_experts", "moe_experts_held", "vocab_size")] == [64, 8, 6288]
+    tiny = bench_tiny.tiny_config(real)
+    assert tiny["share"] == real["share"]
+    assert tiny["published"]["num_experts"] == 64       # 8 held x 8 chips
+    assert tiny["published"]["vocab_size"] == 256 * 8   # the slice x 8 chips
+    assert tiny["model"]["overrides"]["vocab_size"] == 256
+    json.dump(tiny, open(path, "w"))
+    spec.validate()
+    # OLMoE's small model as the repo has it (8 experts, 2 layers) is too
+    # small to be a share: the floors hold in the rehearsal too
+    real["tiny"] = json.load(open(os.path.join(
+        spec_mod.REPO_ROOT, entry["file"])))["tiny"]
+    real["tiny"]["overrides"]["moe_experts_held"] = 1
+    json.dump(bench_tiny.tiny_config(real), open(path, "w"))
+    with pytest.raises(spec_mod.SpecError, match="the floor is 8 experts"):
+        spec.validate()
+
+
+# a file shaped as a model whose experts outnumber a chip would arrive: the
+# names are made up, `validate` builds no model and needs no family
+A_SHARE = {
+    "source": "https://example.org/made-up/sparse-320e/config.json",
+    "published": {
+        "hidden_size": 4096, "moe_intermediate_size": 1280,
+        "num_attention_heads": 64, "num_key_value_heads": 8, "head_dim": 128,
+        "n_routed_experts": 320, "num_experts_per_tok": 8,
+        "n_shared_experts": 1, "vocab_size": 196608,
+        "num_hidden_layers": 48, "rope_theta": 10000, "rms_norm_eps": 1e-05,
+        "norm_topk_prob": True},
+    "reduced": {
+        "num_hidden_layers": "48 -> 8: two periods of four",
+        "n_routed_experts": "320 -> 40 held; the router keeps 320 outputs",
+        "vocab_size": "196608 -> 24576, an eighth"},
+    "share": {"chips": 8, "divided": ["n_routed_experts", "vocab_size"],
+              "how": "8 chips share each layer: 40 of its 320 routed "
+                     "experts and 24,576 rows of the vocabulary on each; "
+                     "attention, the shared expert and the router whole"},
+    "model": {"preset": "made-up", "dtype": "bfloat16", "overrides": {
+        "hidden_size": 4096, "ffn_hidden_size": 1280, "num_heads": 64,
+        "num_kv_heads": 8, "head_dim": 128, "moe_num_experts": 320,
+        "moe_experts_held": 40, "moe_top_k": 8, "moe_shared_experts": 1,
+        "vocab_size": 24576, "num_layers": 8}},
+    "widths": {
+        "hidden_size": "hidden_size", "ffn_hidden_size":
+        "moe_intermediate_size", "num_heads": "num_attention_heads",
+        "num_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
+        "moe_num_experts": "n_routed_experts", "moe_experts_held":
+        "n_routed_experts", "moe_top_k": "num_experts_per_tok",
+        "moe_shared_experts": "n_shared_experts",
+        "vocab_size": "vocab_size", "num_layers": "num_hidden_layers"},
+    "reference": "olmoe",
+    "reference_args": {
+        "num_heads": {"published": "num_attention_heads"},
+        "num_experts_per_tok": {"published": "num_experts_per_tok"}},
+    "tiny": {"preset": "made-up-tiny", "dtype": "float32", "overrides": {
+        "hidden_size": 64, "ffn_hidden_size": 32, "num_heads": 4,
+        "num_kv_heads": 2, "head_dim": 16, "moe_num_experts": 64,
+        "moe_experts_held": 8, "moe_top_k": 3, "moe_shared_experts": 1,
+        "vocab_size": 256, "num_layers": 4},
+        "reference_args": {"num_heads": 4, "num_experts_per_tok": 3}},
+}
+
+
+@pytest.mark.parametrize("how", [
+    "as-stated", "as-stated-at-its-tiny-size", "41-experts-held",
+    "16-chips", "experts-per-token-divided", "no-share-block"])
+def test_a_model_whose_experts_outnumber_a_chip_arrives_as_a_share(
+        how, tmp_path):
+    """320 routed experts, 8 a token, a vocabulary of 196,608 and 48 layers
+    as published; as run 40 experts beside a router of 320, 24,576 rows and
+    8 layers, one of 8 chips that share each layer. That validates; another
+    number of experts, a vocabulary in sixteenths, a divided top-k or the
+    same cuts with no deployment stated do not."""
+    root = bench_tiny.make_root(str(tmp_path))
+    cfg = copy.deepcopy(A_SHARE)
+    says = None
+    if how == "as-stated-at-its-tiny-size":
+        cfg = bench_tiny.tiny_config(cfg)
+        assert cfg["published"]["n_routed_experts"] == 64
+    elif how == "41-experts-held":
+        cfg["model"]["overrides"]["moe_experts_held"] = 41
+        says = "holds all 320 or its share of 40, nothing else"
+    elif how == "16-chips":
+        cfg["share"]["chips"] = 16
+        says = "vocab_size over 16 chips; the floor is an eighth"
+    elif how == "experts-per-token-divided":
+        cfg["share"]["divided"].append("num_experts_per_tok")
+        says = "'num_experts_per_tok' is a width"
+    elif how == "no-share-block":
+        del cfg["share"]
+        says = "is in 'reduced' and does not name the depth"
+    _add_config_and_cell(
+        root, "sparse-320e-s8", cfg, f"{SHARED}.serve-decode",
+        "one of 8 chips of an expert-parallel deployment; attention sees "
+        "more than its share of the batch")
+    spec = spec_mod.Spec(root)
+    json.dump(cfg, open(spec.path("configs", "sparse-320e-s8.json"), "w"))
+    if says is None:
+        spec.validate()
+    else:
+        with pytest.raises(spec_mod.SpecError, match=says):
+            spec.validate()
 
 
 # -- the arithmetic --------------------------------------------------------
@@ -521,45 +765,19 @@ def _files(top):
             for f in fs}
 
 
-@pytest.mark.parametrize("rope_base", ["the-small-models-own",
-                                       "the-sources"])
-def test_new_family_arrives_as_files(rope_base, tmp_path, monkeypatch,
-                                     capsys):
-    """A configuration of a family the benchmark has never run (GPT-NeoX:
-    rotary on a quarter of each head, parallel residual, a head of its own)
-    is a configuration file, a reference file and entries in BENCHMARK.json:
-    it validates and serves `correct` against its own float32 reference
-    through the paged path, and no file that was there has changed.
-
-    float32 on both sides: the served log-probabilities read 9.5e-7 from the
-    reference. Called with the source's rope base (10000) where the small
-    model turns at 500 the reference reads 4.4e-4 away, with half of each
-    head rotated 1.5e-3, with 8 heads 2.7e-3: the limit here is 1e-4, so
-    that an argument which did not come from the file's `tiny` block shows
-    (the second case)."""
+def _neox_arrives(cfg, why, tmp_path, monkeypatch, capsys):
+    """Adds the GPT-NeoX fixture (as `cfg` has it) to a tiny root as files
+    and entries, validates it as it is and cut to its own small model, and
+    serves its cell at tiny size on the CPU. Returns the result, the served
+    log-probabilities' distance from the reference, the run's output, the
+    root and its files as they were before."""
     root = bench_tiny.make_root(str(tmp_path))
     before = {rel: open(path, "rb").read()
               for rel, path in _files(root).items()}
-    fixtures = os.path.join(HERE, "fixtures")
-    cfg = json.load(open(os.path.join(fixtures, "configs",
-                                      "gptneox-20b.json")))
-    if rope_base == "the-sources":
-        cfg["tiny"]["reference_args"]["rotary_emb_base"] = cfg["published"][
-            "rotary_emb_base"]
-    shutil.copy(os.path.join(fixtures, "references", "gptneox.py"),
+    shutil.copy(os.path.join(HERE, "fixtures", "references", "gptneox.py"),
                 os.path.join(root, "benchmarks", "references"))
-    doc = copy.deepcopy(DOC)
-    like = next(w for w in doc["workloads"] if w["traffic"] == "serve-decode")
-    cell = "gptneox-20b.serve-decode"
-    doc["configs"].append({
-        "name": "gptneox-20b", "source": cfg["source"], "reduced": [],
-        "file": "benchmarks/configs/gptneox-20b.json",
-        "why": "rotary on part of each head, parallel residual, untied head"})
-    doc["workloads"].append(dict(like, name=cell, config="gptneox-20b"))
-    for m in doc["end_to_end"] + doc["per_layer"]:
-        if like["name"] in m.get("workloads", ()):
-            m["workloads"].append(cell)
-    json.dump(doc, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    cell = _add_config_and_cell(root, "gptneox-20b", cfg,
+                                "opt-1.3b.serve-decode", why)
     # the file holds together as the fixture has it, and again cut to its
     # own small model
     spec = spec_mod.Spec(root)
@@ -577,14 +795,12 @@ def test_new_family_arrives_as_files(rope_base, tmp_path, monkeypatch,
     notes = [json.loads(ln) for ln in out.splitlines() if ln.startswith("{")]
     diff = next(n for n in notes if "ttft_samples" in n)[
         "reference_logprob_maxdiff"]
-    if rope_base == "the-sources":
-        assert diff > 1e-4
-        return
-    assert result["correct"], out
-    assert diff <= 1e-4
+    return result, diff, out, root, before
 
-    # only files were added: the harness that ran is the repo's own, and
-    # what was copied beside it is byte for byte what it was
+
+def _only_files_were_added(root, before):
+    """The harness that ran is the repo's own, and what was copied beside it
+    is byte for byte what it was."""
     after = _files(root)
     assert all(open(after[rel], "rb").read() == data
                for rel, data in before.items() if rel != "BENCHMARK.json")
@@ -599,6 +815,71 @@ def test_new_family_arrives_as_files(rope_base, tmp_path, monkeypatch,
                 assert open(path, "rb").read() == open(
                     repo[os.path.join(sub, rel)], "rb").read()
     assert bench_run.__file__.startswith(spec_mod.REPO_ROOT)
+
+
+def _neox_fixture():
+    return json.load(open(os.path.join(HERE, "fixtures", "configs",
+                                       "gptneox-20b.json")))
+
+
+@pytest.mark.parametrize("rope_base", ["the-small-models-own",
+                                       "the-sources"])
+def test_new_family_arrives_as_files(rope_base, tmp_path, monkeypatch,
+                                     capsys):
+    """A configuration of a family the benchmark has never run (GPT-NeoX:
+    rotary on a quarter of each head, parallel residual, a head of its own)
+    is a configuration file, a reference file and entries in BENCHMARK.json:
+    it validates and serves `correct` against its own float32 reference
+    through the paged path, and no file that was there has changed.
+
+    float32 on both sides: the served log-probabilities read 9.5e-7 from the
+    reference. Called with the source's rope base (10000) where the small
+    model turns at 500 the reference reads 4.4e-4 away, with half of each
+    head rotated 1.5e-3, with 8 heads 2.7e-3: the limit here is 1e-4, so
+    that an argument which did not come from the file's `tiny` block shows
+    (the second case)."""
+    cfg = _neox_fixture()
+    if rope_base == "the-sources":
+        cfg["tiny"]["reference_args"]["rotary_emb_base"] = cfg["published"][
+            "rotary_emb_base"]
+    result, diff, out, root, before = _neox_arrives(
+        cfg, "rotary on part of each head, parallel residual, untied head",
+        tmp_path, monkeypatch, capsys)
+    if rope_base == "the-sources":
+        assert diff > 1e-4
+        return
+    assert result["correct"], out
+    assert diff <= 1e-4
+    _only_files_were_added(root, before)
+
+
+def test_a_sliced_vocabulary_arrives_as_files(tmp_path, monkeypatch, capsys):
+    """The same family as one of 8 chips that divide the vocabulary among
+    them (the guide's third cut, where it needs nothing of the program): the
+    file states the deployment under `share`, runs 50432 / 8 rows as a
+    smaller vocabulary, and lists the key under `reduced`. The traffic draws
+    its ids from the slice, the served tokens are checked against it and the
+    reference, which reads the slice from the parameters' shapes, agrees:
+    files and entries only, as for any family."""
+    cfg = _neox_fixture()
+    cfg["model"]["overrides"]["vocab_size"] = 50432 // 8
+    cfg["reduced"]["vocab_size"] = "50432 -> 6304: an eighth"
+    cfg["share"] = {"chips": 8, "divided": ["vocab_size"],
+                    "how": "8 chips divide the embedding's and the head's "
+                           "rows; every layer whole on each"}
+    cfg["tiny"]["overrides"]["num_layers"] = 4      # a share's floor
+    result, diff, out, root, before = _neox_arrives(
+        cfg, "an eighth of the vocabulary, every layer whole: attention "
+        "sees more than its share of the batch", tmp_path, monkeypatch,
+        capsys)
+    assert result["correct"], out
+    assert diff <= 1e-4
+    spec = spec_mod.Spec(root)
+    as_run = spec.cell("gptneox-20b.serve-decode").config
+    assert as_run["model"]["overrides"]["vocab_size"] == 256
+    assert as_run["published"]["vocab_size"] == 256 * 8
+    assert as_run["share"]["chips"] == 8
+    _only_files_were_added(root, before)
 
 
 # -- no chip, no result ----------------------------------------------------

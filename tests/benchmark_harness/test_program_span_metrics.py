@@ -15,7 +15,26 @@ from benchmarks.reducers import program_spans, span_count, span_ms
 HERE = os.path.dirname(os.path.abspath(__file__))
 SPEC = spec_mod.Spec()
 FIXTURE = json.load(open(os.path.join(HERE, "fixtures", "small_spans.json")))
-METRICS = sorted(FIXTURE["expect"])
+
+
+def span_fixtures(directory):
+    """`small_spans.json` and every `spans/<anything>.json` beside it, by
+    name: a later PR brings the known number of a span metric of its own as
+    one more file there, each with its `spans`, its `traced` seconds and the
+    numbers to `expect`."""
+    found = {"small_spans": json.load(
+        open(os.path.join(directory, "small_spans.json")))}
+    more = os.path.join(directory, "spans")
+    for f in sorted(os.listdir(more)) if os.path.isdir(more) else ():
+        if f.endswith(".json"):
+            found[f"spans/{f[:-5]}"] = json.load(open(os.path.join(more, f)))
+    return found
+
+
+FIXTURES = span_fixtures(os.path.join(HERE, "fixtures"))
+KNOWN = [(name, metric) for name, doc in FIXTURES.items()
+         for metric in sorted(doc["expect"])]
+METRICS = sorted({metric for _, metric in KNOWN})
 
 
 def _ctx(traced=None):
@@ -41,17 +60,51 @@ def program(monkeypatch):
 
 
 def test_every_span_metric_of_the_benchmark_has_a_known_number():
+    """In some fixture: the ten of `small_spans.json` stay as they are, and
+    a metric that a later PR declares needs a file under `fixtures/spans/`,
+    not an edit here."""
     declared = {m["name"] for m in SPEC.doc["per_layer"]
                 if SPEC.reader(m["name"])["reducer"] in ("span_ms",
                                                          "span_count")}
-    assert declared == set(METRICS) and len(METRICS) == 10
+    assert declared <= set(METRICS), sorted(declared - set(METRICS))
+    assert len(FIXTURE["expect"]) == 10 and set(FIXTURE["expect"]) <= declared
+    # and a known number is of a metric that has its file
+    for metric in METRICS:
+        assert os.path.exists(SPEC.path("layer_metrics", f"{metric}.json"))
 
 
-@pytest.mark.parametrize("metric", METRICS)
-def test_known_number_on_the_recorded_spans(metric, program, capfd):
-    program(FIXTURE["spans"])
-    got = _read(metric, _ctx(traced=tuple(FIXTURE["traced"])))
-    assert got == pytest.approx(FIXTURE["expect"][metric], rel=1e-9)
+@pytest.mark.parametrize("fixture", sorted(FIXTURES))
+def test_a_span_fixture_holds_what_a_known_number_needs(fixture):
+    doc = FIXTURES[fixture]
+    assert {"spans", "traced", "expect"} <= set(doc) and doc["expect"]
+    lo, hi = doc["traced"]
+    assert lo < hi
+    assert any(lo <= s["start_s"] and s["end_s"] <= hi for s in doc["spans"])
+    assert len({s["id"] for s in doc["spans"]}) == len(doc["spans"])
+
+
+def test_a_later_fixture_is_found_by_its_place(tmp_path):
+    """What a PR that may only add files does: one more file, no edit."""
+    (tmp_path / "spans").mkdir()
+    for name, doc in (("small_spans.json", FIXTURE),
+                      ("spans/a_new_metric.json",
+                       {"spans": [], "traced": [0.0, 1.0],
+                        "expect": {"a_new_span_metric_ms": 1.0}}),
+                      ("spans/notes.txt", "not a fixture")):
+        (tmp_path / name).write_text(json.dumps(doc))
+    found = span_fixtures(str(tmp_path))
+    assert sorted(found) == ["small_spans", "spans/a_new_metric"]
+    assert found["spans/a_new_metric"]["expect"] == {
+        "a_new_span_metric_ms": 1.0}
+
+
+@pytest.mark.parametrize("fixture,metric", KNOWN,
+                         ids=[f"{f}-{m}" for f, m in KNOWN])
+def test_known_number_on_the_recorded_spans(fixture, metric, program, capfd):
+    doc = FIXTURES[fixture]
+    program(doc["spans"])
+    got = _read(metric, _ctx(traced=tuple(doc["traced"])))
+    assert got == pytest.approx(doc["expect"][metric], rel=1e-9)
     assert " samples" in capfd.readouterr().err     # how many it rests on
 
 
@@ -148,6 +201,9 @@ def test_new_metrics_agree_with_their_files():
         assert {k: r[k] for k in ("layer", "unit", "moves", "source",
                                   "better")} == \
             {k: m[k] for k in ("layer", "unit", "moves", "source", "better")}
-        want = ("program_counter" if name == "serve_preemptions"
-                else "program_span")
-        assert m["source"] == want
+        if name in FIXTURE["expect"]:
+            assert m["source"] == ("program_counter"
+                                   if name == "serve_preemptions"
+                                   else "program_span")
+        else:       # a later fixture's: a span's time, or a count it carries
+            assert m["source"] in ("program_span", "program_counter")
