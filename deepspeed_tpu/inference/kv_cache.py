@@ -39,6 +39,20 @@ pages at ``k[l, block_table]`` — a shape-static lookup, so one decode
 program serves any occupancy, and a layer's pool is never sliced out of the
 arena (``models/transformer.forward``, paged branch).
 
+**Two kinds of state.** Pages are what a softmax layer keeps: ``L`` above
+counts the layers whose mixer is "attn" (``TransformerConfig.layer_pattern``),
+not the model's depth. A recurrent ("kda") layer keeps no pages but, for
+each sequence, a matrix state and a convolution tail of fixed size; they
+live in two pools beside the pages, in the same dict:
+
+    {"state": (KDA_LAYERS, SLOTS, HEADS, D, D) float32,
+     "tail":  (KDA_LAYERS, SLOTS, TAPS - 1, 3 * HEADS * D)}
+
+A slot belongs to a decode row (``serving/api.py``); the last slot is
+scratch. The programs address a row's slot where it lies, as they address a
+page, and a sequence whose chunk starts at position 0 starts from zeros
+whatever the slot held.
+
 ``dtype`` is mandatory throughout: a default here let call sites silently
 allocate a bf16 arena for an fp32 (or fp16) engine — the arena dtype must
 come from ``InferenceConfig.dtype``.
@@ -50,6 +64,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def init_cache(cfg, batch_size: int, max_seq_len: int, dtype
@@ -111,31 +126,72 @@ def assert_block_divisible(max_seq_len: int, block_size: int) -> int:
     return max_seq_len // block_size
 
 
+def _paged_layers(cfg) -> int:
+    """Layers that keep pages: those whose mixer is softmax attention."""
+    from ..models.transformer import layers_of_kind
+
+    return len(layers_of_kind(cfg, "attn"))
+
+
 def _paged_shape(cfg, num_blocks: int, block_size: int):
-    return (cfg.num_layers, num_blocks, block_size,
+    return (_paged_layers(cfg), num_blocks, block_size,
             cfg.num_kv_heads * cfg.head_dim)
 
 
-def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype
-                     ) -> Dict[str, jax.Array]:
+def _state_shapes(cfg, state_slots: int, dtype) -> Dict[str, Any]:
+    """The second kind of per-sequence state, beside pages: for each
+    recurrent ("kda") layer and slot a float32 matrix state a head and the
+    last ``taps - 1`` rows of the convolution's input (q, k and v side by
+    side, in the model's dtype). ``{}`` for a model with no such layer."""
+    from ..models.transformer import KDA_CONV_TAPS, layers_of_kind
+
+    n = len(layers_of_kind(cfg, "kda"))
+    if not n:
+        return {}
+    if state_slots < 1:
+        raise ValueError("a model with recurrent layers needs state_slots: "
+                         "a slot a decode row and one scratch")
+    H, d = cfg.kda_num_heads, cfg.kda_head_dim
+    return {"state": ((n, state_slots, H, d, d), jnp.float32),
+            "tail": ((n, state_slots, KDA_CONV_TAPS - 1, 3 * H * d),
+                     dtype)}
+
+
+def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype,
+                     state_slots: int = 0) -> Dict[str, jax.Array]:
     """Allocate the paged arena: ``num_blocks`` INCLUDES the reserved
-    scratch block 0 (allocatable blocks are 1..num_blocks-1)."""
+    scratch block 0 (allocatable blocks are 1..num_blocks-1). Pages exist
+    for the softmax layers only; a model with recurrent layers gets, in the
+    same dict, the pools ``"state"`` and ``"tail"`` of ``state_slots`` slots
+    (``_state_shapes``), zeroed."""
     if num_blocks < 2:
         raise ValueError(f"num_blocks={num_blocks}: need the scratch block "
                          "plus at least one allocatable block")
     shape = _paged_shape(cfg, num_blocks, block_size)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            **{name: jnp.zeros(sh, dt) for name, (sh, dt)
+               in _state_shapes(cfg, state_slots, dtype).items()}}
 
 
 def paged_cache_memory_bytes(cfg, num_blocks: int, block_size: int,
                              dtype) -> int:
+    """The pages' footprint (what an arena of ``num_blocks`` costs; the
+    state pools are sized by rows, not blocks: ``state_pool_memory_bytes``)."""
     itemsize = jnp.dtype(dtype).itemsize
-    return (2 * cfg.num_layers * num_blocks * block_size
+    return (2 * _paged_layers(cfg) * num_blocks * block_size
             * cfg.num_kv_heads * cfg.head_dim * itemsize)
 
 
+def state_pool_memory_bytes(cfg, state_slots: int, dtype) -> int:
+    """The state pools' footprint; 0 for a model with no recurrent layer."""
+    return sum(int(np.prod(sh)) * jnp.dtype(dt).itemsize for sh, dt
+               in _state_shapes(cfg, state_slots, dtype).values())
+
+
 def paged_cache_shape_struct(cfg, num_blocks: int, block_size: int,
-                             dtype) -> Dict[str, Any]:
+                             dtype, state_slots: int = 0) -> Dict[str, Any]:
     shape = _paged_shape(cfg, num_blocks, block_size)
     return {"k": jax.ShapeDtypeStruct(shape, dtype),
-            "v": jax.ShapeDtypeStruct(shape, dtype)}
+            "v": jax.ShapeDtypeStruct(shape, dtype),
+            **{name: jax.ShapeDtypeStruct(sh, dt) for name, (sh, dt)
+               in _state_shapes(cfg, state_slots, dtype).items()}}

@@ -1,6 +1,11 @@
 """Named model presets — the families the reference targets with injection
 policies (module_inject/containers/{gpt2,opt,bloom,gptj,gptneo,gptneox,llama}
-and the BASELINE configs: GPT-2 125M, OPT-1.3B, Llama-7B, BLOOM-7B)."""
+and the BASELINE configs: GPT-2 125M, OPT-1.3B, Llama-7B, BLOOM-7B), and
+since then OLMoE (``olmoe-1b-7b``, ``tiny-olmoe``) and Solar Open 2
+(``solar-open2-250b``, ``tiny-solar-open2``: layers of two kinds, three
+gated delta-rule linear-attention layers to one gated NoPE GQA layer, a
+shared expert beside sigmoid-routed ones; served through ``init_serving``,
+whole or as one chip's share of its experts via ``moe_experts_held``)."""
 
 from __future__ import annotations
 
@@ -57,6 +62,19 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
                   tie_embeddings=False, norm_eps=1e-5, qk_norm=True,
                   moe_num_experts=64, moe_top_k=8,
                   moe_norm_topk_prob=False),
+    # Solar Open 2 (upstage/Solar-Open2-250B config.json, model_type
+    # "solar_open2"): periods of four layers, a softmax layer FIRST
+    # (gqa_layers 0, 4, 8, ...: GQA, no positional term at all, a sigmoid
+    # output gate) and then three gated delta-rule layers with per-channel
+    # decay (ops/kda.py; negative eigenvalues allowed: beta up to 2); every
+    # layer's FFN is sigmoid-scored top-k experts (a choice-only bias, the
+    # chosen weights renormalised) plus one shared expert; no biases
+    "solar-open2": dict(norm="rmsnorm", position="none", activation="swiglu",
+                        tie_embeddings=False, norm_eps=1e-5, attn_gate=True,
+                        layer_pattern=("attn", "kda", "kda", "kda"),
+                        moe_score_func="sigmoid", moe_router_bias=True,
+                        moe_norm_topk_prob=True,
+                        moe_shared_experts=1),
 }
 
 # size presets: hidden, layers, heads, kv_heads, vocab, max_seq
@@ -135,6 +153,25 @@ _SIZES: Dict[str, Dict[str, Any]] = {
                        num_heads=4, num_kv_heads=4, ffn_hidden_size=32,
                        vocab_size=256, max_seq_len=128, moe_num_experts=8,
                        moe_top_k=3),
+    # upstage/Solar-Open2-250B config.json (250B, 15B active). The low-rank
+    # pairs' rank (= the head size) is the published layer's, not a key of
+    # the config; max_seq_len bounds nothing (no position table)
+    "solar-open2-250b": dict(family="solar-open2", hidden_size=4096,
+                             num_layers=48, num_heads=64, num_kv_heads=8,
+                             head_size=128, ffn_hidden_size=1280,
+                             dense_ffn_hidden_size=10240,
+                             kda_num_heads=64, kda_head_dim=128,
+                             kda_gate_rank=128, moe_num_experts=320,
+                             moe_top_k=8, vocab_size=196608,
+                             max_seq_len=1048576),
+    # heads wider than hidden / heads, as in the real one (4 x 32 on 64)
+    "tiny-solar-open2": dict(family="solar-open2", hidden_size=64,
+                             num_layers=4, num_heads=4, num_kv_heads=2,
+                             head_size=32, ffn_hidden_size=32,
+                             dense_ffn_hidden_size=128,
+                             kda_num_heads=4, kda_head_dim=16,
+                             kda_gate_rank=16, moe_num_experts=16,
+                             moe_top_k=3, vocab_size=256, max_seq_len=128),
     # GShard/Switch-style 8-expert GPT (BASELINE tracked config #4)
     "moe-tiny": dict(family="gpt2", hidden_size=64, num_layers=2, num_heads=4,
                      vocab_size=256, max_seq_len=128, moe_num_experts=8),

@@ -31,6 +31,12 @@ from .core import (EMBED, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ, VOCAB,
                    cast_floating)
 
 
+MIXERS = ("attn", "kda")
+# taps of the "kda" mixer's depthwise convolution over time (the published
+# short_conv_kernel_size of the one family that has the mixer)
+KDA_CONV_TAPS = 4
+
+
 @dataclasses.dataclass
 class TransformerConfig:
     vocab_size: int = 50257
@@ -43,7 +49,8 @@ class TransformerConfig:
     norm: str = "layernorm"                 # layernorm | rmsnorm
     norm_position: str = "pre"              # pre | post (post: BERT-family
     #   encoders — LN applied AFTER each residual add, no final norm)
-    position: str = "learned"               # learned | rope | alibi
+    position: str = "learned"               # learned | rope | alibi | none
+    #   (none: no positional term anywhere; causality alone orders tokens)
     embed_norm: bool = False                # LayerNorm after embedding (BLOOM)
     activation: str = "gelu"                # gelu | relu | swiglu
     tie_embeddings: bool = True
@@ -112,6 +119,37 @@ class TransformerConfig:
     #   'einsum' dense one-hot (the GShard/reference formulation; fallback)
     moe_use_residual: bool = False    # PR-MoE: dense residual MLP + learned
     #   2-way coefficient mix (reference moe/layer.py use_residual)
+    moe_experts_held: int = 0         # >0: this chip's share of an
+    #   expert-parallel deployment. The router keeps moe_num_experts
+    #   outputs and chooses over all of them; the expert stack holds the
+    #   FIRST moe_experts_held, and an assignment to an absent expert is
+    #   dropped before the expert matmuls (parallel/moe._grouped_experts):
+    #   the layer computes its own experts' part of the result. 0 = all
+    moe_shared_experts: int = 0       # a dense SwiGLU of width
+    #   moe_shared_experts * ffn_hidden_size added to every token, unweighted
+    moe_score_func: str = "softmax"   # softmax | sigmoid: the router's scores
+    #   over all experts, in float32
+    moe_router_bias: bool = False     # a per-expert bias added to the scores
+    #   for the CHOICE of experts only, never to their weights
+    dense_ffn_hidden_size: Optional[int] = None   # the width of a family's
+    #   leading dense layers (its first_k_dense_replace). No preset has such
+    #   layers yet, so no layer reads it; a configuration states it
+    # layers of more than one kind. ``layer_pattern`` names the MIXER of each
+    # layer of one period, cycled over the depth (num_layers a multiple of
+    # it); () = every layer "attn". "attn": softmax attention as configured
+    # above; "kda": the gated delta rule with per-channel decay
+    # (ops/kda.py), kda_num_heads heads of kda_head_dim for keys and values
+    # alike, a depthwise causal convolution of KDA_CONV_TAPS taps and SiLU on
+    # q, k and v, decay and output gate through low-rank pairs of
+    # kda_gate_rank, beta = 2 * sigmoid (negative eigenvalues allowed). Each
+    # kind keeps its own stacked parameter tree and its own cache entry
+    # (pages / a matrix state and a convolution tail)
+    layer_pattern: tuple = ()
+    head_size: Optional[int] = None         # None => hidden_size / num_heads
+    attn_gate: bool = False                 # y = (attn * sigmoid(x W_g)) W_o
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_gate_rank: int = 0
     a8_decode: bool = False           # W8A8: decode-shaped int8 weight sites
     #   quantize the activation row too and ride the MXU's s8xs8 path
     #   (set by InferenceEngine from InferenceConfig.quantize_activations;
@@ -125,12 +163,30 @@ class TransformerConfig:
                 self.ffn_hidden_size = int(8 * self.hidden_size / 3 / 64 + 0.999) * 64
             else:
                 self.ffn_hidden_size = 4 * self.hidden_size
-        assert self.hidden_size % self.num_heads == 0
+        assert self.head_size or self.hidden_size % self.num_heads == 0
         assert self.num_heads % self.num_kv_heads == 0
+        self.layer_pattern = tuple(self.layer_pattern)
+        if self.layer_pattern:
+            assert set(self.layer_pattern) <= set(MIXERS), self.layer_pattern
+            assert self.num_layers % len(self.layer_pattern) == 0
+        if self.moe_experts_held:
+            assert 0 < self.moe_experts_held <= self.moe_num_experts
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def moe_dropless_only(self) -> bool:
+        """The router is one that only the dropless path of
+        ``parallel/moe.moe_mlp`` computes (no capacity plan, no aux loss)."""
+        return bool(self.moe_experts_held or self.moe_router_bias
+                    or self.moe_score_func != "softmax")
+
+    @property
+    def experts_held(self) -> int:
+        """Experts in a layer's stack (the router is moe_num_experts wide)."""
+        return self.moe_experts_held or self.moe_num_experts
 
 
 def eval_config(cfg: TransformerConfig) -> TransformerConfig:
@@ -186,12 +242,35 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     return params
 
 
+def layer_kinds(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The mixer kind of each layer of one period of the stack."""
+    return cfg.layer_pattern or ("attn",)
+
+
+def layers_of_kind(cfg: TransformerConfig, kind: str) -> Tuple[int, ...]:
+    """The layers (global indices, ascending) whose mixer is ``kind``."""
+    pattern = layer_kinds(cfg)
+    return tuple(i for i in range(cfg.num_layers)
+                 if pattern[i % len(pattern)] == kind)
+
+
+def layer_stacks(layers: Dict[str, Any], cfg: TransformerConfig
+                 ) -> Dict[str, Any]:
+    """``params["layers"]`` as ``{kind: stacked tree}``. A stack of ONE kind
+    is the tree itself, leaves ``(num_layers, ...)``, as every model had it
+    before layers came in kinds; a stack of several kinds keeps one tree a
+    kind, leaves ``(layers of that kind, ...)`` in layer order."""
+    kinds = set(layer_kinds(cfg))
+    return layers if len(kinds) > 1 else {next(iter(kinds)): layers}
+
+
 def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                       lo: Any, blen: int) -> Dict[str, Any]:
     """Layer-stack params for layers [lo, lo+blen): leaves shaped
     (blen, ...). Draws are per (leaf, layer) — ``fold_in(fold_in(base, tag),
     layer_idx)`` — so ANY range reproduces exactly the same values the full
-    init produces (ZeRO-3 param offload inits one block at a time)."""
+    init produces (ZeRO-3 param offload inits one block at a time). A stack
+    of several kinds (``layer_stacks``) is initialised whole."""
     H, L = cfg.hidden_size, cfg.num_layers
     N, K, D, F = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
                   cfg.ffn_hidden_size)
@@ -199,28 +278,71 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
     # GPT-2-style scaled init on residual-writing projections
     resid_std = std / (2 * L) ** 0.5
     E = cfg.moe_num_experts
+    held = cfg.experts_held
 
-    def one_layer(li):
+    def one_layer(li, kind="attn"):
         def normal(tag, shape, s=std):
             k = jax.random.fold_in(jax.random.fold_in(base_key, tag), li)
             return (jax.random.normal(k, shape, jnp.float32) * s
                     ).astype(cfg.dtype)
 
+        def uniform(tag, shape, lo, hi):
+            k = jax.random.fold_in(jax.random.fold_in(base_key, tag), li)
+            return jax.random.uniform(k, shape, jnp.float32, lo, hi)
+
         layer: Dict[str, Any] = {
             "ln1": {"scale": jnp.ones((H,), cfg.dtype)},
             "ln2": {"scale": jnp.ones((H,), cfg.dtype)},
-            "attn": {
+        }
+        if kind == "attn":
+            layer["attn"] = {
                 "wq": normal(0, (H, N * D)),
                 "wk": normal(1, (H, K * D)),
                 "wv": normal(2, (H, K * D)),
                 "wo": normal(3, (N * D, H), resid_std),
-            },
-        }
-        if cfg.qk_norm:
-            layer["attn"]["q_norm"] = jnp.ones((N * D,), cfg.dtype)
-            layer["attn"]["k_norm"] = jnp.ones((K * D,), cfg.dtype)
+            }
+            if cfg.qk_norm:
+                layer["attn"]["q_norm"] = jnp.ones((N * D,), cfg.dtype)
+                layer["attn"]["k_norm"] = jnp.ones((K * D,), cfg.dtype)
+            if cfg.attn_gate:
+                layer["attn"]["wg"] = normal(11, (H, N * D))
+        else:
+            KH, KD, r = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+            W = KH * KD
+            layer["kda"] = {
+                "wq": normal(20, (H, W)), "wk": normal(21, (H, W)),
+                "wv": normal(22, (H, W)), "wo": normal(23, (W, H), resid_std),
+                # taps of the depthwise convolution over time, oldest first
+                "conv_q": normal(24, (KDA_CONV_TAPS, W), 0.5),
+                "conv_k": normal(25, (KDA_CONV_TAPS, W), 0.5),
+                "conv_v": normal(26, (KDA_CONV_TAPS, W), 0.5),
+                "wf1": normal(27, (H, r)), "wf2": normal(28, (r, W)),
+                "wg1": normal(29, (H, r)), "wg2": normal(30, (r, W)),
+                "wb": normal(31, (H, KH)),
+                # decay g = -exp(A_log) * softplus(. + dt_bias): a rate of
+                # 1 to 16 a head times a step of 0.001 to 0.1 a channel
+                # (softplus^-1 of it), so a token keeps 20% to 99.9%
+                "A_log": jnp.log(uniform(32, (KH,), 1.0, 16.0)),
+                "dt_bias": (lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(
+                    jnp.exp(uniform(33, (W,), jnp.log(1e-3), jnp.log(0.1)))),
+                "o_norm": jnp.ones((KD,), cfg.dtype),
+            }
         if E > 0:
             layer["router"] = normal(4, (H, E))
+            if cfg.moe_router_bias:
+                # nonzero, or the choice-only bias would go untested; small,
+                # as a trained one is: sigmoid scores of the most probable
+                # experts lie within 0.005 of each other, and a bias of 0.1
+                # would choose the same experts for every token
+                layer["router_bias"] = normal(12, (E,), 0.01).astype(
+                    jnp.float32)
+            if cfg.moe_shared_experts:
+                Fs = cfg.moe_shared_experts * F
+                layer["shared"] = {
+                    "w_gate": normal(13, (H, Fs)),
+                    "w_up": normal(14, (H, Fs)),
+                    "w_down": normal(15, (Fs, H), resid_std),
+                }
             if cfg.moe_use_residual:
                 layer["res_mlp"] = {
                     "w_up": normal(5, (H, F)),
@@ -232,14 +354,14 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                                      "b": jnp.zeros((2,), cfg.dtype)}
             if cfg.activation == "swiglu":
                 layer["mlp"] = {
-                    "w_gate": normal(8, (E, H, F)),
-                    "w_up": normal(9, (E, H, F)),
-                    "w_down": normal(10, (E, F, H), resid_std),
+                    "w_gate": normal(8, (held, H, F)),
+                    "w_up": normal(9, (held, H, F)),
+                    "w_down": normal(10, (held, F, H), resid_std),
                 }
             else:
                 layer["mlp"] = {
-                    "w_up": normal(9, (E, H, F)),
-                    "w_down": normal(10, (E, F, H), resid_std),
+                    "w_up": normal(9, (held, H, F)),
+                    "w_down": normal(10, (held, F, H), resid_std),
                 }
         elif cfg.activation == "swiglu":
             layer["mlp"] = {
@@ -257,13 +379,23 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
         if cfg.norm == "layernorm":
             layer["ln1"]["bias"] = jnp.zeros((H,), cfg.dtype)
             layer["ln2"]["bias"] = jnp.zeros((H,), cfg.dtype)
-            layer["attn"]["bq"] = jnp.zeros((N * D,), cfg.dtype)
-            layer["attn"]["bk"] = jnp.zeros((K * D,), cfg.dtype)
-            layer["attn"]["bv"] = jnp.zeros((K * D,), cfg.dtype)
-            layer["attn"]["bo"] = jnp.zeros((H,), cfg.dtype)
+            if kind == "attn":
+                layer["attn"]["bq"] = jnp.zeros((N * D,), cfg.dtype)
+                layer["attn"]["bk"] = jnp.zeros((K * D,), cfg.dtype)
+                layer["attn"]["bv"] = jnp.zeros((K * D,), cfg.dtype)
+                layer["attn"]["bo"] = jnp.zeros((H,), cfg.dtype)
         return layer
 
-    return jax.vmap(one_layer)(lo + jnp.arange(blen))
+    kinds = sorted(set(layer_kinds(cfg)))
+    if len(kinds) == 1:
+        return jax.vmap(partial(one_layer, kind=kinds[0]))(
+            lo + jnp.arange(blen))
+    if not (isinstance(lo, int) and lo == 0 and blen == L):
+        raise NotImplementedError(
+            "a stack of several kinds of layer is initialised whole; a "
+            "range of its layers has no one stacked tree")
+    return {kind: jax.vmap(partial(one_layer, kind=kind))(
+        jnp.asarray(layers_of_kind(cfg, kind))) for kind in kinds}
 
 
 def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
@@ -275,6 +407,16 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                      "bv": (LAYERS, KV_HEADS), "bo": (LAYERS, EMBED)})
     if cfg.qk_norm:
         attn.update({"q_norm": (LAYERS, HEADS), "k_norm": (LAYERS, KV_HEADS)})
+    if cfg.attn_gate:
+        attn["wg"] = (LAYERS, EMBED, HEADS)
+    kda = {**{w: (LAYERS, EMBED, HEADS) for w in ("wq", "wk", "wv")},
+           "wo": (LAYERS, HEADS, EMBED),
+           **{c: (LAYERS, None, HEADS) for c in ("conv_q", "conv_k",
+                                                   "conv_v")},
+           "wf1": (LAYERS, EMBED, None), "wf2": (LAYERS, None, HEADS),
+           "wg1": (LAYERS, EMBED, None), "wg2": (LAYERS, None, HEADS),
+           "wb": (LAYERS, EMBED, None), "A_log": (LAYERS, None),
+           "dt_bias": (LAYERS, HEADS), "o_norm": (LAYERS, None)}
     from .core import EXPERT
 
     if cfg.moe_num_experts > 0:
@@ -294,18 +436,27 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     ln = {"scale": (LAYERS, EMBED)}
     if cfg.norm == "layernorm":
         ln = {"scale": (LAYERS, EMBED), "bias": (LAYERS, EMBED)}
-    layer_axes = {"ln1": dict(ln), "ln2": dict(ln), "attn": attn, "mlp": mlp}
+    layer_axes = {"ln1": dict(ln), "ln2": dict(ln), "mlp": mlp}
     if cfg.moe_num_experts > 0:
         layer_axes["router"] = (LAYERS, EMBED, None)
+        if cfg.moe_router_bias:
+            layer_axes["router_bias"] = (LAYERS, None)
+        if cfg.moe_shared_experts:
+            layer_axes["shared"] = {"w_gate": (LAYERS, EMBED, MLP),
+                                    "w_up": (LAYERS, EMBED, MLP),
+                                    "w_down": (LAYERS, MLP, EMBED)}
         if cfg.moe_use_residual:
             layer_axes["res_mlp"] = {
                 "w_up": (LAYERS, EMBED, MLP), "b_up": (LAYERS, MLP),
                 "w_down": (LAYERS, MLP, EMBED), "b_down": (LAYERS, EMBED)}
             layer_axes["res_coef"] = {"w": (LAYERS, EMBED, None),
                                       "b": (LAYERS, None)}
+    kinds = sorted(set(layer_kinds(cfg)))
+    mixers = {"attn": attn, "kda": kda}
+    by_kind = {kind: {**layer_axes, kind: mixers[kind]} for kind in kinds}
     axes: Dict[str, Any] = {
         "embed": {"tokens": (VOCAB, EMBED)},
-        "layers": layer_axes,
+        "layers": by_kind if len(kinds) > 1 else by_kind[kinds[0]],
     }
     if cfg.final_norm:
         axes["final_norm"] = ({"scale": (EMBED,), "bias": (EMBED,)}
@@ -653,6 +804,16 @@ def _qeinsum(spec: str, x: jax.Array, w: Any, dtype: Any,
     return jnp.einsum(spec, x, w)
 
 
+def _swiglu(cfg: "TransformerConfig", h: jax.Array,
+            w: Dict[str, Any]) -> jax.Array:
+    """``down(silu(gate(h)) * up(h))``: a dense SwiGLU FFN (the whole FFN
+    of a dense layer; the shared expert of an MoE one)."""
+    gate = _qeinsum("bsh,hf->bsf", h, w["w_gate"], cfg.dtype, a8=cfg.a8_decode)
+    up = _qeinsum("bsh,hf->bsf", h, w["w_up"], cfg.dtype, a8=cfg.a8_decode)
+    return _qeinsum("bsf,fh->bsh", jax.nn.silu(gate) * up, w["w_down"],
+                    cfg.dtype, a8=cfg.a8_decode)
+
+
 def _dropout(x: jax.Array, cfg: "TransformerConfig", salt: int) -> jax.Array:
     """Inverted dropout on a residual-path tensor; active only when the
     engine enabled it (training). Key derives from the tensor's content —
@@ -833,68 +994,127 @@ def pld_gate(cfg: TransformerConfig, h: jax.Array, h_new: jax.Array,
     return h_mixed, aux * gate / keep_p
 
 
-def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
-                   mask: Optional[jax.Array],
-                   positions: jax.Array,
-                   cache: Optional[Dict[str, jax.Array]] = None,
-                   static_prefill: bool = False,
-                   key_positions: Optional[jax.Array] = None,
-                   window: Optional[jax.Array] = None,
-                   block_table: Optional[jax.Array] = None,
-                   paged_write_mask: Optional[jax.Array] = None,
-                   paged_layer: Optional[jax.Array] = None,
-                   moe_counts: bool = False,
-                   expert_banks: Optional[Dict[str, jax.Array]] = None,
-                   layer_index: Optional[jax.Array] = None
-                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """One decoder block. ``layer`` holds this layer's (unstacked) params.
-    ``cache`` (decode): dict with k/v of shape (B, T_max, K, D) and scalar
-    ``index`` — returns the updated cache. ``window``: this layer's
-    sliding-window width (traced scalar, <=0 = global) — present only for
-    attention_layers models (GPT-Neo), which take the windowed jnp
-    attention path throughout.
+def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+               cache: Optional[Dict[str, jax.Array]],
+               kind_layer: Optional[jax.Array],
+               state_slots: Optional[jax.Array], positions: jax.Array,
+               real: Optional[jax.Array]
+               ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """The "kda" mixer of ``_layer_forward``: the gated delta rule with
+    per-channel decay (``ops/kda.py`` has the recurrence) over the normed
+    input ``h`` (B, S, H) -> (its contribution to the residual, new cache).
 
-    ``block_table`` switches the cache to PAGED mode (serving layer):
-    ``cache`` is then the WHOLE arena ``{"k","v": (L, NUM_BLOCKS, BLOCK,
-    K*D)}``, ``paged_layer`` (int32 scalar, the layer scan's index) says
-    which layer's pool this block writes and reads inside it, and the arena
-    comes back as the new cache; ``block_table`` (B, MAX_BLOCKS) maps each
-    row's logical blocks to physical ids. A pool is never sliced out of the
-    arena: a custom call's operand is a buffer of its own, so a slice is a
-    pool-sized copy in and another out (ops/paged_decode_attention.py).
-    ``positions`` must then be the (B, S) absolute write
-    positions; ``paged_write_mask`` (B, S) routes masked-off tokens (prompt
-    chunk padding) to the scratch block 0 instead of the row's blocks.
-    The read is ``ops.paged_decode_attention.paged_attention``, which picks
-    kernel or reference by platform; S > 1 queries of a row sit at
-    ``positions[b, 0] + arange(S)`` (the chunk, verify and score programs).
-    It has no window, custom-scale or custom-impl operand: ``forward``
-    refuses such a model.
+    ``q~ = h W_q`` and likewise k, v; on each a depthwise causal convolution
+    over time and SiLU; ``q`` and ``k`` l2-normalised a head (eps 1e-6), ``q``
+    times ``1/sqrt(d)``; decay ``g = -exp(A_log) * softplus((h W_f1) W_f2 +
+    dt_bias)`` a channel, ``beta = 2 * sigmoid(h W_b)`` a head;
+    the recurrence; ``y = (RMSNorm_head(o) * sigmoid((h W_g1) W_g2)) W_o``.
 
-    ``expert_banks`` (inference, an MoE model): the model's WHOLE expert
-    stacks ``(L, E, ...)`` in place of ``layer["mlp"]``, with ``layer_index``
-    saying which layer this is - ``forward`` keeps them out of the layer
-    scan's slicing, because the grouped-matmul kernel can read a touched
-    expert where it lies in the stack but not out of a slice that XLA would
-    first have to copy (``ops/moe_grouped_matmul.py``).
+    With no cache a sequence starts from a zero state and zero convolution
+    history. A cache is the serving layer's ``{"state": (layers of this
+    kind, slots, heads, d, d) float32, "tail": (..., slots, taps - 1, 3 *
+    heads * d)}`` beside the pages; ``kind_layer`` says which of this kind's
+    layers this is and ``state_slots`` (B,) which slot each row owns. A row
+    whose first position is 0 starts from zeros whatever its slot held (a
+    sequence is reset as data, on admission and on re-admission after a
+    preemption alike). ``real`` (B, S) marks the tokens that exist, a prefix
+    of each row: the others (a ragged chunk's padding, a decode row that
+    holds nothing) write nothing - beta 0, decay 1, and the tail is taken
+    from the last REAL rows."""
+    from ..ops import kda as kda_ops
+    from ..ops import registry
+    from ..parallel.mesh import ambient_mesh
 
-    Returns ``(x, new_cache, aux)``; with ``moe_counts`` (an MoE model) a
-    fourth value, this layer's routing counts (``parallel/moe.moe_mlp``)."""
-    B, S, H = x.shape
-    N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-
-    post_ln = cfg.norm_position == "post"
-    if post_ln:
-        h = x      # post-LN (BERT family): raw input feeds attention; the
-        #            norm is applied after each residual add below
+    f32 = jnp.float32
+    B, S, _ = h.shape
+    KH, KD, taps = cfg.kda_num_heads, cfg.kda_head_dim, KDA_CONV_TAPS
+    W = KH * KD
+    qkv = jnp.concatenate(
+        [jnp.einsum("bsh,hd->bsd", h, p[w]) for w in ("wq", "wk", "wv")],
+        axis=-1)                                            # (B, S, 3W)
+    if cache is None:
+        tail = jnp.zeros((B, taps - 1, 3 * W), qkv.dtype)
+        fresh = None
     else:
-        h = _norm(x, layer["ln1"]["scale"], layer["ln1"].get("bias"),
-                  cfg.norm, cfg.norm_eps)
-    if cfg.act_quant_bits and cache is None:
-        # activation QAT (reference QuantAct): quantize the attention input
-        from ..compression.compress import fake_quant_activation
+        fresh = positions[:, 0] == 0
+        tail = jnp.where(fresh[:, None, None], 0,
+                         cache["tail"][kind_layer, state_slots])
+    ext = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
+    conv = jnp.concatenate([p["conv_q"], p["conv_k"], p["conv_v"]],
+                           axis=-1).astype(f32)             # (taps, 3W)
+    mixed = sum(conv[j] * ext[:, j:j + S].astype(f32) for j in range(taps))
+    q, k, v = (a.reshape(B, S, KH, KD)
+               for a in jnp.split(jax.nn.silu(mixed), 3, axis=-1))
 
-        h = fake_quant_activation(h, cfg.act_quant_bits)
+    def l2norm(a):
+        return a * lax.rsqrt((a * a).sum(-1, keepdims=True) + 1e-6)
+
+    def low_rank(down, up):         # (h W_down) W_up, float32 out
+        return jnp.einsum("bsr,rd->bsd",
+                          jnp.einsum("bsh,hr->bsr", h, p[down]),
+                          p[up]).astype(f32)
+
+    q, k = l2norm(q) * KD ** -0.5, l2norm(k)
+    g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        low_rank("wf1", "wf2") + p["dt_bias"].astype(f32)
+    ).reshape(B, S, KH, KD)
+    beta = 2.0 * jax.nn.sigmoid(
+        jnp.einsum("bsh,hn->bsn", h, p["wb"]).astype(f32))
+    if real is not None:
+        g = jnp.where(real[..., None, None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+
+    new_cache = None
+    if cache is None:
+        o, _ = kda_ops.kda_chunk(q, k, v, g, beta,
+                                 jnp.zeros((B, KH, KD, KD), f32))
+    else:
+        if S == 1:
+            mesh = ambient_mesh()
+            step = (kda_ops.kda_decode_step
+                    if registry.kernels_active()
+                    and (mesh is None or mesh.size == 1)
+                    else kda_ops.reference_kda_decode_step)
+            o, states = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                             cache["state"], kind_layer, state_slots)
+            o = o[:, None]
+        else:
+            start = jnp.where(fresh[:, None, None, None], 0.0,
+                              cache["state"][kind_layer, state_slots])
+            o, end = kda_ops.kda_chunk(q, k, v, g, beta, start)
+            states = cache["state"].at[kind_layer, state_slots].set(
+                end.astype(cache["state"].dtype))
+        # the last taps - 1 rows that exist, of the history and this call
+        n_real = (jnp.full((B,), S, jnp.int32) if real is None
+                  else real.sum(axis=1, dtype=jnp.int32))
+        new_tail = jax.vmap(lambda rows, n: lax.dynamic_slice_in_dim(
+            rows, n, taps - 1, axis=0))(ext, n_real)
+        new_cache = {**cache, "state": states,
+                     "tail": cache["tail"].at[kind_layer, state_slots].set(
+                         new_tail.astype(cache["tail"].dtype))}
+
+    o = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.norm_eps) \
+        * p["o_norm"].astype(f32)
+    y = (o.reshape(B, S, W)
+         * jax.nn.sigmoid(low_rank("wg1", "wg2"))).astype(h.dtype)
+    return jnp.einsum("bsd,dh->bsh", y, p["wo"]), new_cache
+
+
+def _softmax_mixer(cfg: TransformerConfig, h: jax.Array,
+                   layer: Dict[str, Any], mask: Optional[jax.Array],
+                   positions: jax.Array,
+                   cache: Optional[Dict[str, jax.Array]],
+                   static_prefill: bool,
+                   key_positions: Optional[jax.Array],
+                   window: Optional[jax.Array],
+                   block_table: Optional[jax.Array],
+                   paged_write_mask: Optional[jax.Array],
+                   paged_layer: Optional[jax.Array]
+                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """The "attn" mixer of ``_layer_forward``: softmax attention over the
+    normed input ``h`` -> (its contribution to the residual, new cache)."""
+    B, S, H = h.shape
+    N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = _qeinsum("bsh,hd->bsd", h, layer["attn"]["wq"], cfg.dtype, a8=cfg.a8_decode)
     k = _qeinsum("bsh,hd->bsd", h, layer["attn"]["wk"], cfg.dtype, a8=cfg.a8_decode)
     v = _qeinsum("bsh,hd->bsd", h, layer["attn"]["wv"], cfg.dtype, a8=cfg.a8_decode)
@@ -1001,7 +1221,7 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             k.reshape(B, S, K * D).astype(cache["k"].dtype))
         cv = cache["v"].at[paged_layer, blk, off].set(
             v.reshape(B, S, K * D).astype(cache["v"].dtype))
-        new_cache = {"k": ck, "v": cv}
+        new_cache = {**cache, "k": ck, "v": cv}
         from ..ops.paged_decode_attention import paged_attention
 
         attn = paged_attention(q, ck, cv, paged_layer, block_table, pos,
@@ -1103,9 +1323,93 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             # Ulysses inverse all-to-all on the 4D tensor (see attn_out_spec)
             attn = constrain(attn, out_spec)
     attn = attn.reshape(B, S, N * D)
+    if "wg" in layer["attn"]:
+        # the output gate: elementwise and full-rank, from the layer's input
+        gate = _qeinsum("bsh,hd->bsd", h, layer["attn"]["wg"], cfg.dtype,
+                        a8=cfg.a8_decode)
+        attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+            attn.dtype)
     attn_out = _qeinsum("bsd,dh->bsh", attn, layer["attn"]["wo"], cfg.dtype, a8=cfg.a8_decode)
     if "bo" in layer["attn"]:
         attn_out = attn_out + layer["attn"]["bo"]
+    return attn_out, new_cache
+
+
+def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
+                   mask: Optional[jax.Array],
+                   positions: jax.Array,
+                   cache: Optional[Dict[str, jax.Array]] = None,
+                   static_prefill: bool = False,
+                   key_positions: Optional[jax.Array] = None,
+                   window: Optional[jax.Array] = None,
+                   block_table: Optional[jax.Array] = None,
+                   paged_write_mask: Optional[jax.Array] = None,
+                   paged_layer: Optional[jax.Array] = None,
+                   moe_counts: bool = False,
+                   expert_banks: Optional[Dict[str, jax.Array]] = None,
+                   layer_index: Optional[jax.Array] = None,
+                   kind: str = "attn",
+                   state_slots: Optional[jax.Array] = None
+                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """One decoder block: ``x + mixer(norm(x))``, then the FFN. ``kind``
+    names the mixer (``TransformerConfig.layer_pattern``); what follows is
+    the "attn" mixer's cache, and ``_kda_mixer`` says what a "kda" layer
+    keeps instead (``paged_layer`` then counts the layers of ITS kind, and
+    ``state_slots`` (B,) names each row's slot in the state pools).
+    ``layer`` holds this layer's (unstacked) params.
+    ``cache`` (decode): dict with k/v of shape (B, T_max, K, D) and scalar
+    ``index`` — returns the updated cache. ``window``: this layer's
+    sliding-window width (traced scalar, <=0 = global) — present only for
+    attention_layers models (GPT-Neo), which take the windowed jnp
+    attention path throughout.
+
+    ``block_table`` switches the cache to PAGED mode (serving layer):
+    ``cache`` is then the WHOLE arena ``{"k","v": (L, NUM_BLOCKS, BLOCK,
+    K*D)}``, ``paged_layer`` (int32 scalar, the layer scan's index) says
+    which layer's pool this block writes and reads inside it, and the arena
+    comes back as the new cache; ``block_table`` (B, MAX_BLOCKS) maps each
+    row's logical blocks to physical ids. A pool is never sliced out of the
+    arena: a custom call's operand is a buffer of its own, so a slice is a
+    pool-sized copy in and another out (ops/paged_decode_attention.py).
+    ``positions`` must then be the (B, S) absolute write
+    positions; ``paged_write_mask`` (B, S) routes masked-off tokens (prompt
+    chunk padding) to the scratch block 0 instead of the row's blocks.
+    The read is ``ops.paged_decode_attention.paged_attention``, which picks
+    kernel or reference by platform; S > 1 queries of a row sit at
+    ``positions[b, 0] + arange(S)`` (the chunk, verify and score programs).
+    It has no window, custom-scale or custom-impl operand: ``forward``
+    refuses such a model.
+
+    ``expert_banks`` (inference, an MoE model): the model's WHOLE expert
+    stacks ``(L, E, ...)`` in place of ``layer["mlp"]``, with ``layer_index``
+    saying which layer this is - ``forward`` keeps them out of the layer
+    scan's slicing, because the grouped-matmul kernel can read a touched
+    expert where it lies in the stack but not out of a slice that XLA would
+    first have to copy (``ops/moe_grouped_matmul.py``).
+
+    Returns ``(x, new_cache, aux)``; with ``moe_counts`` (an MoE model) a
+    fourth value, this layer's routing counts (``parallel/moe.moe_mlp``)."""
+    post_ln = cfg.norm_position == "post"
+    if post_ln:
+        h = x      # post-LN (BERT family): raw input feeds attention; the
+        #            norm is applied after each residual add below
+    else:
+        h = _norm(x, layer["ln1"]["scale"], layer["ln1"].get("bias"),
+                  cfg.norm, cfg.norm_eps)
+    if cfg.act_quant_bits and cache is None:
+        # activation QAT (reference QuantAct): quantize the attention input
+        from ..compression.compress import fake_quant_activation
+
+        h = fake_quant_activation(h, cfg.act_quant_bits)
+    if kind == "kda":
+        attn_out, new_cache = _kda_mixer(
+            cfg, h, layer["kda"], cache, paged_layer, state_slots,
+            positions, paged_write_mask)
+    else:
+        attn_out, new_cache = _softmax_mixer(
+            cfg, h, layer, mask, positions, cache, static_prefill,
+            key_positions, window, block_table, paged_write_mask,
+            paged_layer)
     if cache is None:
         attn_out = _dropout(attn_out, cfg, salt=31)
     if cache is None:
@@ -1137,7 +1441,8 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
 
         # cache mode == inference: moe_mlp then routes exactly (no capacity
         # drops, no RTS) and keeps padding rows out of the routing
-        infer = cache is not None
+        # (a router the capacity plans cannot express routes so always)
+        infer = cache is not None or cfg.moe_dropless_only
         rts_rng = (_activation_derived_key(h, 0)
                    if (cfg.moe_use_rts and not infer) else None)
         mlp_out, aux, *counts = moe_mlp(
@@ -1150,7 +1455,13 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             drop_tokens=cfg.moe_drop_tokens, use_rts=cfg.moe_use_rts,
             rng=rts_rng, dispatch_impl=cfg.moe_dispatch,
             norm_topk_prob=cfg.moe_norm_topk_prob, infer=infer,
-            row_mask=paged_write_mask, with_counts=moe_counts)
+            row_mask=paged_write_mask, with_counts=moe_counts,
+            score_func=cfg.moe_score_func,
+            choice_bias=layer.get("router_bias"))
+        if "shared" in layer:
+            # the shared expert: one dense SwiGLU beside the routed ones,
+            # added to every token unweighted
+            mlp_out = mlp_out + _swiglu(cfg, h, layer["shared"])
         if cfg.moe_use_residual:
             # PR-MoE (reference moe/layer.py:120): dense MLP in parallel,
             # mixed by a learned softmax coefficient over (moe, dense)
@@ -1166,10 +1477,7 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             ).astype(h.dtype)
             mlp_out = mlp_out * coef[..., 0:1] + res_out * coef[..., 1:2]
     elif cfg.activation == "swiglu":
-        gate = _qeinsum("bsh,hf->bsf", h, layer["mlp"]["w_gate"], cfg.dtype, a8=cfg.a8_decode)
-        up = _qeinsum("bsh,hf->bsf", h, layer["mlp"]["w_up"], cfg.dtype, a8=cfg.a8_decode)
-        inner = jax.nn.silu(gate) * up
-        mlp_out = _qeinsum("bsf,fh->bsh", inner, layer["mlp"]["w_down"], cfg.dtype, a8=cfg.a8_decode)
+        mlp_out = _swiglu(cfg, h, layer["mlp"])
     else:
         inner = _qeinsum("bsh,hf->bsf", h, layer["mlp"]["w_up"], cfg.dtype, a8=cfg.a8_decode) + layer["mlp"]["b_up"]
         if cfg.activation == "relu":
@@ -1206,7 +1514,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             key_positions: Optional[jax.Array] = None,
             block_table: Optional[jax.Array] = None,
             paged_write_mask: Optional[jax.Array] = None,
-            moe_counts: bool = False
+            moe_counts: bool = False,
+            state_slots: Optional[jax.Array] = None
             ) -> Tuple[jax.Array, Optional[Dict[str, Any]], jax.Array]:
     """Token ids (B,S) → (logits (B,S,V), new_cache, moe_aux_loss). With
     ``cache``, runs in decode mode (cache is a per-layer stacked pytree; see
@@ -1224,7 +1533,17 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     ``attention_impl`` is refused. ``moe_counts`` (paged mode, an MoE
     model) adds a fourth result: int32 ``[assignments, experts with a row,
     rows of the largest expert]`` summed over the layers, from the rows
-    that ``paged_write_mask`` keeps (``parallel/moe.moe_mlp``)."""
+    that ``paged_write_mask`` keeps (``parallel/moe.moe_mlp``).
+
+    **Layers of several kinds** (``cfg.layer_pattern``): the stack is
+    scanned a PERIOD a step, each kind's stacked tree sliced by the period
+    (``layer_stacks``), the period's layers run in their published order
+    inside the step; a stack of one kind is the period of one, and its scan
+    is the scan there always was. In paged mode the cache holds pages for
+    the "attn" layers alone, ``(layers of that kind, ...)``, and for "kda"
+    layers the pools ``"state"`` and ``"tail"`` (``_kda_mixer``), with
+    ``state_slots`` (B,) naming each row's slot; the dense cache of
+    ``inference/engine.py`` has no such entry and refuses such a model."""
     B, S = input_ids.shape
     if block_table is not None:
         for operand in ("attention_layers", "attention_scale",
@@ -1292,14 +1611,54 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         ltd_flags = jnp.array([1.0 if i in ltd_layers else 0.0
                                for i in range(L)], jnp.float32)
 
+    # a period of the layer pattern is one step of the scan: each kind's
+    # stacked tree goes in sliced by the period (several layers of a kind in
+    # a period: leaves (periods, that many, ...)), and `run_period` walks the
+    # period's layers in order. A stack of one kind is the period of one:
+    # its tree goes in as it is and the walk is one call.
+    pattern = layer_kinds(cfg)
+    P = len(pattern)
+    per = {kind: pattern.count(kind) for kind in pattern}
+    several = len(per) > 1
+    if several and (use_pld or use_ltd or use_win or (
+            cache is not None and block_table is None)):
+        raise NotImplementedError(
+            "a stack of several kinds of layer runs without a cache or over "
+            "the serving layer's paged cache and state pools: the dense "
+            "cache (inference/engine.py) holds no recurrent state, and "
+            "progressive layer drop, random-LTD and per-layer windows index "
+            "a stack of one kind")
+    if cache is not None and "kda" in per and state_slots is None:
+        raise ValueError("a model with recurrent layers needs state_slots "
+                         "beside its cache: each row's slot in the pools")
+    stacks = layer_stacks(params["layers"], cfg)
     # at inference an MoE model's expert stacks stay whole, outside the
     # layer scan's slicing, and go down with the layer's index (see
     # _layer_forward's ``expert_banks``)
-    layers, banks = params["layers"], None
+    banks = None
     if cache is not None and cfg.moe_num_experts > 0:
-        banks = layers["mlp"]
-        layers = {k: v for k, v in layers.items() if k != "mlp"}
-    with_idx = use_pld or use_win or banks is not None
+        banks = {kind: tree["mlp"] for kind, tree in stacks.items()}
+        stacks = {kind: {k: v for k, v in tree.items() if k != "mlp"}
+                  for kind, tree in stacks.items()}
+    periods = {kind: tree if per[kind] == 1 else jax.tree.map(
+        lambda a, n=per[kind]: a.reshape((L // P, n) + a.shape[1:]), tree)
+        for kind, tree in stacks.items()}
+    layers = periods if several else periods[pattern[0]]
+    with_idx = use_pld or use_win or banks is not None or several
+
+    def run_period(layers, pidx, one_layer, h, *acc):
+        """``one_layer(h, kind, layer, layer index among its kind, *acc)
+        -> (h, *acc)`` for each layer of period ``pidx``, in order."""
+        seen = dict.fromkeys(per, 0)
+        for kind in pattern:
+            j, n = seen[kind], per[kind]
+            seen[kind] += 1
+            layer = layers[kind] if several else layers
+            if n > 1:
+                layer = jax.tree.map(lambda a: a[j], layer)
+            kidx = pidx if n == 1 or pidx is None else pidx * n + j
+            h, *acc = one_layer(h, kind, layer, kidx, *acc)
+        return (h, *acc)
 
     def block(carry, layer_and_cache):
         h, aux_acc = carry
@@ -1342,13 +1701,24 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
 
             h_new, aux = lax.cond(ltd_flag > 0, ltd_branch, full_branch, h)
             new_cache = None
+        elif several:
+            def one_layer(h, kind, layer, kidx, aux_sum):
+                h, _, aux = _layer_forward(cfg, h, layer, attention_mask,
+                                           positions, None, kind=kind)
+                return h, aux_sum + aux
+
+            h_new, aux = run_period(layer, None, one_layer, h,
+                                    jnp.float32(0.0))
+            new_cache = None
         else:
             h_new, new_cache, aux = _layer_forward(
                 cfg, h, layer, attention_mask, positions, layer_cache,
                 static_prefill=static_prefill, key_positions=key_positions,
-                window=window, expert_banks=banks,
+                window=window,
+                expert_banks=None if banks is None else banks[pattern[0]],
                 layer_index=(None if banks is None
-                             else idx.astype(jnp.int32)))
+                             else idx.astype(jnp.int32)),
+                kind=pattern[0])
         if use_pld:
             h_new, aux = pld_gate(cfg, h, h_new, aux, idx, pld_theta)
         return (h_new, aux_acc + aux), new_cache
@@ -1362,12 +1732,12 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         # one scan; xs packing varies with the active stochastic features
         # (block unpacks in the same order; None rides the pytree untouched)
         if use_ltd:
-            xs = ((params["layers"], None), jnp.arange(L, dtype=jnp.float32),
+            xs = ((layers, None), jnp.arange(L, dtype=jnp.float32),
                   ltd_flags)
-        elif use_pld or use_win:
-            xs = ((params["layers"], None), jnp.arange(L, dtype=jnp.float32))
+        elif with_idx:
+            xs = ((layers, None), jnp.arange(L // P, dtype=jnp.float32))
         else:
-            xs = (params["layers"], None)
+            xs = (layers, None)
         (x, aux_total), _ = lax.scan(block_fn, (x, jnp.float32(0.0)), xs,
                                      unroll=cfg.scan_unroll)
         new_cache = None
@@ -1382,24 +1752,34 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         # scatter — four 185 MiB copies a layer at OPT-1.3B's serving size,
         # 54 ms of a 73 ms decode iteration on the v5e (PERF.md, PR 26).
         # tests/kernels/test_tpu_compile.py holds the compiled programs to
-        # it. window/PLD/LTD are training- or dense-cache-only features
-        # (a sliding-window model was refused above).
-        def paged_block(carry, layer_and_idx):
+        # it. The same holds for a recurrent layer's state pool, which rides
+        # the carry beside the pages and is addressed (layer of its kind,
+        # slot) where it lies. window/PLD/LTD are training- or
+        # dense-cache-only features (a sliding-window model was refused
+        # above).
+        def paged_block(carry, layers_and_idx):
             h, aux_acc, arena, *counts_acc = carry
-            layer, idx = layer_and_idx
-            h_new, arena, aux, *counts = _layer_forward(
-                cfg, h, layer, attention_mask, positions, arena,
-                block_table=block_table, paged_write_mask=paged_write_mask,
-                paged_layer=idx, moe_counts=moe_counts, expert_banks=banks,
-                layer_index=idx)
-            return (h_new, aux_acc + aux, arena,
-                    *(a + c for a, c in zip(counts_acc, counts))), None
+            layers, pidx = layers_and_idx
+
+            def one_layer(h, kind, layer, kidx, aux_sum, arena, *counts_sum):
+                h, arena, aux, *counts = _layer_forward(
+                    cfg, h, layer, attention_mask, positions, arena,
+                    block_table=block_table,
+                    paged_write_mask=paged_write_mask, paged_layer=kidx,
+                    moe_counts=moe_counts,
+                    expert_banks=None if banks is None else banks[kind],
+                    layer_index=kidx, kind=kind, state_slots=state_slots)
+                return (h, aux_sum + aux, arena,
+                        *(a + c for a, c in zip(counts_sum, counts)))
+
+            return run_period(layers, pidx, one_layer, h, aux_acc, arena,
+                              *counts_acc), None
 
         (x, aux_total, new_cache, *moe_totals), _ = lax.scan(
             paged_block,
-            (x, jnp.float32(0.0), {"k": cache["k"], "v": cache["v"]},
+            (x, jnp.float32(0.0), dict(cache),
              *([jnp.zeros((3,), jnp.int32)] if moe_counts else [])),
-            (layers, jnp.arange(L, dtype=jnp.int32)))
+            (layers, jnp.arange(L // P, dtype=jnp.int32)))
     else:
         xs = ((layers, cache) if not with_idx else
               ((layers, cache), jnp.arange(L, dtype=jnp.float32)))

@@ -10,6 +10,8 @@ from .paged_decode_attention import (paged_decode_attention,
 from .flash_attention import flash_attention, make_attention_impl
 from .moe_grouped_matmul import (moe_grouped_matmul,
                                  reference_grouped_matmul)
+from .kda import (kda_chunk, kda_decode_step, kda_recurrence,
+                  reference_kda_decode_step)
 from .fused_adam import fused_adam_flat, reference_adam_flat
 from .fused_lamb import fused_lamb_flat, reference_lamb_flat
 from .normalization import fused_layer_norm, reference_layer_norm
@@ -61,6 +63,10 @@ register_op("moe_grouped_matmul", moe_grouped_matmul,
             reference=reference_grouped_matmul,
             description="expert matmuls over rows sorted by expert (dropless "
                         "MoE inference; touched experts only)")
+register_op("kda_decode_step", kda_decode_step,
+            reference=reference_kda_decode_step,
+            description="one token of the gated delta rule a decode row, "
+                        "the state pool updated in place")
 register_op("int4_a8_matmul", int4_a8_matmul,
             reference=reference_int4_a8_matmul,
             description="W4A8 GEMM (s8 unpack + s8xs8 MXU)")
@@ -95,6 +101,8 @@ __all__ = [
     "paged_decode_attention", "paged_prefill_attention",
     "reference_paged_attention",
     "moe_grouped_matmul", "reference_grouped_matmul",
+    "kda_chunk", "kda_decode_step", "kda_recurrence",
+    "reference_kda_decode_step",
     "flash_attention", "make_attention_impl", "fused_adam_flat",
     "reference_adam_flat", "fused_lamb_flat", "reference_lamb_flat",
     "fused_layer_norm", "reference_layer_norm",

@@ -281,7 +281,8 @@ _combine_gather.defvjp(_combine_gather_fwd, _combine_gather_bwd)
 def _grouped_experts(xt: jax.Array, gates: jax.Array,
                      experts: Dict[str, jax.Array], activation: str, k: int,
                      normalize: bool, real: Optional[jax.Array],
-                     layer: Optional[jax.Array] = None
+                     layer: Optional[jax.Array] = None,
+                     choice: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """Dropless expert compute over the ASSIGNED rows only: the ``T x k``
     assignments are laid out sorted by expert, each expert's group padded to
@@ -292,7 +293,11 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     work and its weights are not read. Rows where ``real`` is false (decode
     rows with no request, a prompt chunk's padding) are not routed at all and
     come back zero. With ``layer`` the weights are the model's whole
-    ``(L, E, ...)`` stacks, read in place at that layer (see the kernel). Returns ``(out (T, H), counts)`` with ``counts`` int32
+    ``(L, E, ...)`` stacks, read in place at that layer (see the kernel).
+    ``choice`` (T, E): what the k experts are chosen by where that is not
+    ``gates`` (a choice-only bias). ``gates`` may be wider than the stacks
+    hold experts: an assignment to an expert past the held ones is dropped
+    here like a row that is not ``real`` (``moe_mlp``: a chip's share). Returns ``(out (T, H), counts)`` with ``counts`` int32
     ``[assignments, experts with a row, rows of the largest expert]``."""
     from ..ops.moe_grouped_matmul import (group_layout, moe_grouped_matmul,
                                           reference_grouped_matmul,
@@ -301,10 +306,13 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     from .mesh import ambient_mesh
 
     T, H = xt.shape
-    E = gates.shape[-1]
-    idx, weight = route_topk(gates, gates, k, normalize)          # (T, k)
+    E = experts["w_up"].shape[-3]       # held here; the router may be wider
+    idx, weight = route_topk(gates, gates if choice is None else choice, k,
+                             normalize)                           # (T, k)
     flat = idx.reshape(-1)
     chosen = flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :]
+    if E < gates.shape[-1]:
+        flat = jnp.minimum(flat, E - 1)     # an absent expert indexes nothing
     if real is not None:
         chosen = chosen & jnp.repeat(real, k)[:, None]            # (T*k, E)
     sizes = chosen.sum(axis=0, dtype=jnp.int32)                   # (E,)
@@ -358,7 +366,9 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
             dispatch_impl: str = "sparse", norm_topk_prob: bool = True,
             infer: bool = False, row_mask: Optional[jax.Array] = None,
             with_counts: bool = False,
-            expert_layer: Optional[jax.Array] = None):
+            expert_layer: Optional[jax.Array] = None,
+            score_func: str = "softmax",
+            choice_bias: Optional[jax.Array] = None):
     """MoE FFN for one layer. x (B, S, H); router_w (H, E); experts:
     w_up/w_down (+w_gate for swiglu) with leading expert dim E - or, with
     ``expert_layer`` (int32 scalar), the model's whole stacks with a layer
@@ -372,6 +382,21 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
     ``top_k`` is any number of experts a token from 1 to E (softmax in
     float32 over all experts, then the k most probable); ``norm_topk_prob``
     says whether their weights are renormalised (``route_topk``).
+    ``score_func`` "sigmoid" scores each expert on its own instead (float32
+    too); ``choice_bias`` (E,) is added to the scores for the CHOICE of the
+    k experts and never to their weights. Both exist at inference with the
+    experts whole on the chip (the dropless path below); the capacity plans
+    refuse them.
+
+    **A chip's share of the experts.** Where the stacks hold FEWER experts
+    than the router has outputs, they are the router's first ones, held here
+    as one chip of an expert-parallel deployment holds its own: scores,
+    choice and weights are over all E as published, an assignment to an
+    expert that is not held is dropped before the expert matmuls (it costs
+    no row, no tile and no weight traffic), and the result is this chip's
+    experts' part of the layer. Nothing stands in for the absent chips or
+    for the exchange with them. The counts then count what reached a held
+    expert.
 
     Training (``infer`` false) runs the capacity plan (``topk_plan``) under
     ``dispatch_impl``:
@@ -408,13 +433,26 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
     if dispatch_impl not in ("sparse", "einsum"):
         raise ValueError(f"unknown moe dispatch_impl {dispatch_impl!r} "
                          "(expected 'sparse' or 'einsum')")
+    if score_func not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown moe score_func {score_func!r} "
+                         "(expected 'softmax' or 'sigmoid')")
+    held = experts["w_up"].shape[-3]
     if infer and not _ep_active(E):
+        scores = (jax.nn.softmax(logits, axis=-1) if score_func == "softmax"
+                  else jax.nn.sigmoid(logits))
         out, counts = _grouped_experts(
-            xt, jax.nn.softmax(logits, axis=-1), experts, activation, top_k,
-            norm_topk_prob,
-            None if row_mask is None else row_mask.reshape(T), expert_layer)
+            xt, scores, experts, activation, top_k, norm_topk_prob,
+            None if row_mask is None else row_mask.reshape(T), expert_layer,
+            choice=(None if choice_bias is None
+                    else scores + choice_bias.astype(jnp.float32)))
         out, aux = out.reshape(B, S, H), jnp.float32(0.0)
         return (out, aux, counts) if with_counts else (out, aux)
+    if held != E or score_func != "softmax" or choice_bias is not None:
+        raise NotImplementedError(
+            "a share of the experts, sigmoid scores and a choice-only bias "
+            "exist on the dropless inference path only "
+            "(parallel/moe._grouped_experts): the capacity plans of "
+            "training, and of experts sharded over chips, have none of them")
     if expert_layer is not None:    # XLA fuses this slice into the einsums
         experts = jax.tree.map(lambda w: w[expert_layer], experts)
     plan = topk_plan(logits, top_k, capacity_factor, min_capacity,

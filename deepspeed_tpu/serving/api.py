@@ -43,6 +43,10 @@ from .session import RequestHandle
 
 __all__ = ["ServingEngine", "init_serving"]
 
+# tokens at a sequence's end that ``score_logprobs`` scores one at a time
+# for a model with recurrent layers: a second or so of one-row steps
+_SCORE_STEP_TAIL = 64
+
 
 def _percentile(samples: List[float], q: float) -> float:
     xs = sorted(samples)
@@ -85,9 +89,27 @@ class ServingEngine:
         self.clock = clock
         self._lock = threading.RLock()
         self.alloc = paged_kv.BlockAllocator(self.config.pool_blocks())
+        # a model with recurrent ("kda") layers keeps, beside its pages, a
+        # matrix state and a convolution tail a sequence in pools of
+        # ``state_slots`` slots: decode row r owns slot r from admission to
+        # release, and the last slot is scratch (rows that hold nothing,
+        # and the sequence ``score_logprobs`` is scoring). A slot is never
+        # cleared by the host: the chunk that starts at position 0 starts
+        # it from zeros, on admission and on re-admission after a
+        # preemption (recompute) alike. What cannot follow such state yet
+        # is switched off or refused here and in ``_no_state_snapshot``
+        from ..models.transformer import layers_of_kind
+
+        self._recurrent_layers = len(layers_of_kind(cfg, "kda"))
+        self.state_slots = (self.config.max_seqs + 1
+                            if self._recurrent_layers else 0)
+        # the prefix cache shares PAGES between sequences; a recurrent
+        # layer's state at the shared prefix's end is in no page, so the
+        # cache is off for such a model (off, not refused: it is a default)
         self.prefix = (paged_kv.PrefixCache(self.alloc,
                                             self.config.block_size)
-                       if self.config.prefix_cache else None)
+                       if self.config.prefix_cache
+                       and not self._recurrent_layers else None)
         self.sched = Scheduler(self.config, allocator=self.alloc,
                                clock=clock, prefix_cache=self.prefix)
         # fleet identity on traces / serving-goodput labels (the router
@@ -102,11 +124,14 @@ class ServingEngine:
         with mesh_mod.ambient(engine.mesh):
             self._arena = paged_kv.init_paged_cache(
                 cfg, self.config.pool_blocks() + 1, self.config.block_size,
-                self._dtype)
+                self._dtype, state_slots=self.state_slots)
         # an MoE model's two programs return their routing counts behind
-        # the tokens (_moe_counts); 0 = a dense model, whose programs and
-        # spans know nothing of it
+        # the tokens (_program_counts); 0 = a dense model, whose programs and
+        # spans know nothing of it. ``total`` counts the ROUTER's outputs a
+        # layer, ``held`` the experts of a layer's stack (fewer where this
+        # chip holds its share of them: ``moe_experts_held``)
         self._moe_experts_total = cfg.moe_num_experts * cfg.num_layers
+        self._moe_experts_held = cfg.experts_held * cfg.num_layers
         moe = self._moe_experts_total > 0
         self._prefill = paged_kv.build_prefill_program(cfg, moe_counts=moe)
         self._decode = paged_kv.build_decode_program(cfg, moe_counts=moe)
@@ -152,6 +177,8 @@ class ServingEngine:
                                      draft_engine=draft_engine)
         self._verify = None
         if self._drafter is not None:
+            self._no_state_snapshot("speculative decoding (rolling a "
+                                    "rejected draft back)")
             self._verify = paged_kv.build_verify_program(
                 cfg, self.config.speculative.num_draft_tokens + 1)
             # one release point covers finish/cancel/preempt: the drafter
@@ -211,6 +238,19 @@ class ServingEngine:
             f"{paged_kv.paged_cache_memory_bytes(cfg, self.config.pool_blocks() + 1, self.config.block_size, self._dtype) / 2 ** 20:.0f}"
             " MiB")
 
+    def _no_state_snapshot(self, what: str) -> None:
+        """THE place that refuses, by name, what a model with recurrent
+        layers cannot do yet: whatever shares, copies or rolls back a
+        sequence's pages would have to snapshot its recurrent state too,
+        and nothing takes such a snapshot (ROADMAP B-m5)."""
+        if self._recurrent_layers:
+            raise NotImplementedError(
+                f"{what} is not supported for a model with recurrent "
+                "(linear-attention) layers: it needs a snapshot of a "
+                "sequence's recurrent state (the matrix state and "
+                "convolution tail of serving/paged_kv.py's state pools), "
+                "which nothing takes yet; pages alone do not hold it")
+
     # -- client API --------------------------------------------------------
     @property
     def threaded(self) -> bool:
@@ -245,6 +285,9 @@ class ServingEngine:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if n < 1:
             raise ValueError(f"submit(n={n}): need n >= 1")
+        if n > 1:
+            self._no_state_snapshot("submit(n > 1) (forking at the end of "
+                                    "the prompt)")
         obs = get_session()
         with obs.span("serving/submit", n_prompt=int(prompt.size)) as span:
             lock_wait = obs.span("serving/submit/lock_wait").begin()
@@ -537,6 +580,8 @@ class ServingEngine:
         rebuilds the recompute source from prompt[:n_prompt] + generated).
         Raises ``QueueFull`` when this engine cannot take the request —
         the caller still owns ``blocks`` and must free them."""
+        self._no_state_snapshot("kv_import (adopting a sequence prefilled "
+                                "on another engine)")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         with self._lock:
             if (self.sched.in_flight() + self._pending_fork_count() + 1
@@ -630,7 +675,16 @@ class ServingEngine:
         preempting) and freed before returning. Passing a resharded
         frozen-reference tree as ``params`` reuses the one compiled score
         program — the RLHF reference-logprob pass costs zero extra
-        compiles."""
+        compiles.
+
+        A model with recurrent layers scores a sequence's last
+        ``_SCORE_STEP_TAIL`` tokens ONE at a time (the same program traced
+        at a width of one): the model's one-token forms, which its decode
+        program runs (``kda_decode_step`` on the state pools in place, the
+        paged decode kernel), carry on from the state and the pages that
+        the chunks left. So a comparison of these log-probabilities with a
+        reference covers the chunk form, the step form and the hand-over
+        of one state between them."""
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         T = int(tokens.size)
         if T < 2:
@@ -655,15 +709,22 @@ class ServingEngine:
                     params = self.engine.params
                 out = np.zeros((T - 1,), np.float32)
                 obs = get_session()
+                # (start, tokens, the program's width). A chunk starts
+                # the sequence (only a chunk at 0 starts a state from
+                # zeros); the last position has no target, so no step
+                body = (max(T - 1 - _SCORE_STEP_TAIL, 1)
+                        if self._recurrent_layers else T)
+                pieces = ([(at, min(C, body - at), C)
+                           for at in range(0, body, C)]
+                          + [(at, 1, 1) for at in range(body, T - 1)])
                 with mesh_mod.ambient(self.engine.mesh):
-                    for start in range(0, T, C):
-                        n_valid = min(C, T - start)
-                        chunk = np.zeros((1, C), np.int32)
+                    for start, n_valid, width in pieces:
+                        chunk = np.zeros((1, width), np.int32)
                         chunk[0, :n_valid] = tokens[start:start + n_valid]
                         # the target for position p is tokens[p + 1]; the
                         # final sequence position has none
                         nt = min(n_valid, T - 1 - start)
-                        tgt = np.zeros((1, C), np.int32)
+                        tgt = np.zeros((1, width), np.int32)
                         if nt > 0:
                             tgt[0, :nt] = tokens[start + 1:start + 1 + nt]
                         with obs.span("serving/score_chunk",
@@ -714,6 +775,7 @@ class ServingEngine:
                                 for r in self.sched.running.values()),
                             blocks_total=self.alloc.capacity,
                             preemptions=self.sched.preemption_count,
+                            **self._state_counts(),
                             **hbm_counts())
                 finally:
                     if acct is not None:
@@ -808,6 +870,18 @@ class ServingEngine:
                 handle._wake()
         return len(expired)
 
+    def _state_counts(self) -> Dict[str, int]:
+        """A model with recurrent layers: the slots of the state pools that
+        hold a LIVE state (a running request's row once its first chunk has
+        run; until then the slot still holds what the row's last owner left,
+        which that chunk starts over) and the slots rows can own (scratch is
+        not one). Nothing for any other model."""
+        if not self._recurrent_layers:
+            return {}
+        return {"state_slots_in_use": sum(
+                    1 for r in self.sched.running.values() if r.length > 0),
+                "state_slots_total": self.config.max_seqs}
+
     def _table_for(self, reqs: List[Request]) -> np.ndarray:
         """(len(reqs), MAXB) block table; unfilled entries → scratch 0."""
         bt = np.zeros((len(reqs), self.blocks_per_seq), np.int32)
@@ -874,13 +948,20 @@ class ServingEngine:
             tok = np.asarray(tok)
         return tok, t0, self.clock()
 
-    def _moe_counts(self, span, fetched: np.ndarray, n: int) -> np.ndarray:
+    def _program_counts(self, span, fetched: np.ndarray, n: int,
+                    real_rows: int) -> np.ndarray:
         """The ``n`` sampled tokens of what an MoE model's program returned
         (``paged_kv._with_moe_counts``); the routing counts behind them go
         onto ``span``: over the real rows of this iteration and summed over
-        the layers, the (token, expert) assignments, the experts that had a
-        row (of ``moe_experts_total`` = experts x layers) and the rows of
-        each layer's largest expert."""
+        the layers, the (token, expert) assignments that reached an expert
+        held here, the held experts that had a row (of ``moe_experts_held``
+        = experts of a layer's stack x layers; ``moe_experts_total`` = the
+        router's outputs x layers, the same number unless this chip holds a
+        share) and the rows of each layer's largest expert. A model with
+        recurrent layers also says how many (row, layer) states the program
+        advanced: ``real_rows`` x its recurrent layers."""
+        if span.recording and self._recurrent_layers:
+            span.annotate(recurrent_rows=real_rows * self._recurrent_layers)
         if not self._moe_experts_total:
             return fetched
         if span.recording:
@@ -888,6 +969,7 @@ class ServingEngine:
             span.annotate(moe_assignments=assigned,
                           moe_experts_touched=touched,
                           moe_experts_total=self._moe_experts_total,
+                          moe_experts_held=self._moe_experts_held,
                           moe_max_expert_rows=largest)
         return fetched[:n]
 
@@ -919,8 +1001,10 @@ class ServingEngine:
                         obs, "serving/prefill_chunk", self._prefill, table,
                         chunk, np.asarray(start, np.int32),
                         np.asarray(n_valid, np.int32),
-                        temps, topks, topps, seeds, self._base_rng)
-            tok = self._moe_counts(span, tok, 1)
+                        temps, topks, topps, seeds, self._base_rng,
+                        *([np.asarray([req.row], np.int32)]
+                          if self._recurrent_layers else []))
+            tok = self._program_counts(span, tok, 1, real_rows=1)
             if self._serve_acct is not None:
                 self._serve_acct.note_phase("prefill", t1 - t0)
             if rt is not None and req.trace is not None:
@@ -1001,6 +1085,7 @@ class ServingEngine:
         decoding. Returns the new handles."""
         if n < 1:
             raise ValueError(f"fork(n={n}): need n >= 1")
+        self._no_state_snapshot("fork()")
         if seeds is not None and len(seeds) < n:
             raise ValueError(f"fork(n={n}): seeds has {len(seeds)} "
                              "entries — need one per sibling")
@@ -1122,7 +1207,8 @@ class ServingEngine:
                     nxt, t0, t1 = self._run_program(
                         obs, "serving/decode", self._decode, *operands,
                         self._base_rng)
-            nxt = self._moe_counts(span, nxt, self.config.max_seqs)
+            nxt = self._program_counts(span, nxt, self.config.max_seqs,
+                                   real_rows=len(ready))
             if acct is not None:
                 acct.note_phase("decode", t1 - t0)
             if rt is not None:
@@ -1594,14 +1680,17 @@ class ServingEngine:
                 jax.ShapeDtypeStruct((1,), i32),
                 jax.ShapeDtypeStruct((1,), jnp.float32),
                 jax.ShapeDtypeStruct((1,), i32),
-                jax.ShapeDtypeStruct((2,), jnp.uint32))
+                jax.ShapeDtypeStruct((2,), jnp.uint32),
+                *([jax.ShapeDtypeStruct((1,), i32)]
+                  if self._recurrent_layers else []))
 
     def _arena_sds(self):
         from ..inference.kv_cache import paged_cache_shape_struct
 
         return paged_cache_shape_struct(
             self.engine.model.config, self.config.pool_blocks() + 1,
-            self.config.block_size, self._dtype)
+            self.config.block_size, self._dtype,
+            state_slots=self.state_slots)
 
     def _register_audit_entries(self) -> List[str]:
         try:

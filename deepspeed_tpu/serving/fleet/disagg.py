@@ -118,6 +118,9 @@ class ArenaHandoff(KVHandoff):
         their replica identities — the handoff timeline spans both ends of
         the seam."""
         _check_geometry(_EngineView(src), _EngineView(dst))
+        # pages are all this transfer moves (ServingEngine._no_state_snapshot)
+        src._no_state_snapshot("kv_export (handing a prefilled sequence to "
+                               "another engine)")
         dst_ids = dst.alloc.alloc(len(blocks))
         if dst_ids is None:
             return None

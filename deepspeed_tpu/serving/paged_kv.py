@@ -392,6 +392,9 @@ def build_prefill_program(cfg, moe_counts: bool = False):
                                the scratch block; pad logits are never read)
       temperature/top_k/top_p/seeds (1,) — the request's sampling knobs
       base_key               — the engine's sampling key (constant)
+      state_slot (1,) int32  — a model with recurrent layers only: the
+                               request's slot in the state pools, which ride
+                               in ``cache`` beside the pages (its decode row)
 
     Returns (token (1,), last_logits (1, V) f32, cache): ``token`` samples
     the position-``n_valid-1`` logits at output-token index 0 — the
@@ -403,7 +406,8 @@ def build_prefill_program(cfg, moe_counts: bool = False):
     from ..models.transformer import forward as model_forward
 
     def prefill_chunk(params, cache, block_table, chunk, start, n_valid,
-                      temperature, top_k, top_p, seeds, base_key):
+                      temperature, top_k, top_p, seeds, base_key,
+                      state_slot=None):
         C = chunk.shape[1]
         offs = jnp.arange(C, dtype=jnp.int32)
         write_mask = (offs < n_valid)[None]
@@ -415,7 +419,7 @@ def build_prefill_program(cfg, moe_counts: bool = False):
         logits, cache, _, *counts = model_forward(
             params, chunk, cfg, cache=cache, positions=pos,
             block_table=block_table, paged_write_mask=write_mask,
-            moe_counts=moe_counts)
+            moe_counts=moe_counts, state_slots=state_slot)
         last = jnp.take_along_axis(
             logits, jnp.maximum(n_valid - 1, 0)[None, None, None],
             axis=1)[:, 0].astype(jnp.float32)
@@ -443,6 +447,9 @@ def build_decode_program(cfg, moe_counts: bool = False):
     out of the routing; with ``moe_counts`` (the serving engine's own
     program) ``next_token`` is (R + 3,): the tokens, then the step's routing
     counts over the rows that hold a request (``_with_moe_counts``).
+    A model with recurrent layers keeps row r's state in slot r of the
+    pools in ``cache``; a row that holds nothing is sent to the last slot,
+    scratch, and advances nothing.
     """
     from ..models.transformer import forward as model_forward
 
@@ -452,11 +459,18 @@ def build_decode_program(cfg, moe_counts: bool = False):
         # The mask also sends an empty row's write to the scratch block,
         # which is where its all-zero table sent it anyway. A dense model
         # routes nothing, and its program stays as it was.
-        live = (lengths > 0)[:, None] if cfg.moe_num_experts > 0 else None
+        recurrent = "state" in cache
+        live = ((lengths > 0)[:, None]
+                if cfg.moe_num_experts > 0 or recurrent else None)
+        slots = None
+        if recurrent:
+            slots = jnp.where(lengths > 0,
+                              jnp.arange(lengths.shape[0], dtype=jnp.int32),
+                              cache["state"].shape[1] - 1)
         logits, cache, _, *counts = model_forward(
             params, tokens[:, None], cfg, cache=cache,
             positions=lengths[:, None], block_table=block_table,
-            paged_write_mask=live, moe_counts=moe_counts)
+            paged_write_mask=live, moe_counts=moe_counts, state_slots=slots)
         nxt = sample_rows(logits[:, -1], base_key, temperature, top_k,
                           top_p, seeds, steps)
         if moe_counts:
@@ -564,10 +578,15 @@ def build_score_program(cfg):
         write_mask = (offs < n_valid)[None]
         # pad queries at position -1 — see prefill_chunk
         pos = jnp.where(write_mask, (start + offs)[None], -1)
+        # a scored sequence's recurrent state lives in the scratch slot:
+        # no decode row owns it, and its first chunk starts it from zeros
+        slots = (jnp.full((1,), cache["state"].shape[1] - 1, jnp.int32)
+                 if "state" in cache else None)
         logits, cache, _ = model_forward(params, chunk, cfg, cache=cache,
                                          positions=pos,
                                          block_table=block_table,
-                                         paged_write_mask=write_mask)
+                                         paged_write_mask=write_mask,
+                                         state_slots=slots)
         return gather_target_logprobs(logits, targets), cache
 
     return jax.jit(score_chunk, donate_argnums=(1,))
@@ -598,7 +617,7 @@ def build_kv_import_program():
     pad writes land in the scratch block, whose content is never read."""
 
     def kv_import(cache, buf_k, buf_v, ids):
-        return {"k": cache["k"].at[:, ids].set(buf_k),
+        return {**cache, "k": cache["k"].at[:, ids].set(buf_k),
                 "v": cache["v"].at[:, ids].set(buf_v)}
 
     return jax.jit(kv_import, donate_argnums=(0,))
@@ -613,7 +632,8 @@ def build_cow_program():
     while readers keep the original."""
 
     def cow_copy(cache, src, dst):
-        return {"k": cache["k"].at[:, dst].set(cache["k"][:, src]),
+        return {**cache,
+                "k": cache["k"].at[:, dst].set(cache["k"][:, src]),
                 "v": cache["v"].at[:, dst].set(cache["v"][:, src])}
 
     return jax.jit(cow_copy, donate_argnums=(0,))
