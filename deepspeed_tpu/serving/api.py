@@ -13,6 +13,17 @@ compiled exactly once per (shape) configuration: occupancy, request mix and
 sampling settings are all *data* (see ``docs/serving.md`` for the jit-cache
 discipline rationale).
 
+What a token does on the host is split in two. ``_apply`` is what the NEXT
+program's operands depend on (the request's tokens and length, the finish
+test, row and blocks free) and runs as soon as the tokens are on the host.
+``_flush`` is what only callers and measurement see (handles pushed and
+woken, latency samples, counters, the request tracer). ``step()`` flushes
+before it returns; the driver thread (``start()``) keeps an iteration's
+decode tokens and flushes them right after it has enqueued the NEXT program
+(``_run_program``), so the pushes and the callers they wake run while the
+device works. No program is ever in flight across an iteration boundary:
+only undelivered host tokens are.
+
 Telemetry flows through the PR-2 observability substrate: ``serving/*``
 metrics in the MetricsRegistry (ttft_ms, tpot_ms, queue_depth,
 kv_blocks_in_use, preemptions, ...), the spans of ``docs/serving.md``'s table
@@ -218,6 +229,17 @@ class ServingEngine:
         # (parent prefill completion)
         self._pending_forks: Dict[int, List[Request]] = {}
         self._tokens_out = 0
+        # tokens applied to their requests and not yet delivered to their
+        # handles, in order: (request, token, first, finished). Host data
+        # only, guarded by the engine lock
+        self._undelivered: List[tuple] = []
+        # True inside an iteration of the driver thread: what the next
+        # program's operands do not need waits for that program's enqueue
+        self._deferring = False
+        # the open iteration's span until its account (gauges, the span's
+        # counts) is drawn up: behind its first enqueue when deferring, else
+        # at its end
+        self._unaccounted = None
         self._started_s = clock()
         # fleet seam (serving/fleet): called with the request right after
         # its LAST prefill chunk completed and the first token was emitted,
@@ -354,6 +376,7 @@ class ServingEngine:
         #   siblings and parent-cascaded siblings included, so the
         #   requests_{submitted,completed,cancelled} ledger balances
         with self._lock:
+            self._flush()   # what is applied streams before the cancel ends it
             req = handle._req
             # a sibling cancelled before its fork point never reached the
             # scheduler — cancel it directly
@@ -557,7 +580,7 @@ class ServingEngine:
             self._rid += 1
             if generated:
                 # TTFT already happened on the dead engine — the unset-
-                # timestamp catch in _emit must not restamp it here
+                # timestamp catch in _apply must not restamp it here
                 req.first_token_s = req.arrival_s
             handle = RequestHandle(self, req)
             self._handles[req.rid] = handle
@@ -617,8 +640,11 @@ class ServingEngine:
         terminal for this engine (row/blocks freed, handle dropped)
         without touching the completion ledger."""
         with self._lock:
+            self._flush()
             self.sched.release_handoff(req)
-            self._handles.pop(req.rid, None)
+            handle = self._handles.pop(req.rid, None)
+            if handle is not None:
+                handle._wake()    # its stream continues on the other engine
             if req.trace is not None:
                 rt = get_session().reqtrace
                 if rt is not None:
@@ -638,6 +664,7 @@ class ServingEngine:
         cannot be continued coherently. Returns the number of prefix-cache
         entries dropped."""
         with self._lock:
+            self._flush()
             if self.sched.in_flight() or self._pending_fork_count():
                 raise RuntimeError(
                     "weight flip with requests in flight "
@@ -695,6 +722,7 @@ class ServingEngine:
                 f"serving.max_model_len={self.config.max_model_len}")
         C = self.config.prefill_chunk
         with self._lock:
+            self._flush()
             need = paged_kv.blocks_for_tokens(T, self.config.block_size)
             ids = self.sched._alloc_evicting_cache(need)
             if ids is None:
@@ -744,9 +772,18 @@ class ServingEngine:
     def step(self) -> bool:
         """One continuous-batching iteration; returns True when any request
         made progress (admission, a prefill chunk, a decode token, or a
-        deadline expiry reclaiming its resources). The ``serving/iteration``
-        span opens before the engine lock is taken, so the wait for callers
-        inside ``submit()`` is part of it (``.../lock_wait``)."""
+        deadline expiry reclaiming its resources). Every token the step
+        produced is delivered to its handle when it returns. The
+        ``serving/iteration`` span opens before the engine lock is taken, so
+        the wait for callers inside ``submit()`` is part of it
+        (``.../lock_wait``)."""
+        return self._iterate(defer=False)
+
+    def _iterate(self, defer: bool) -> bool:
+        """``step()``; with ``defer`` (the driver thread) the iteration's
+        decode tokens are applied and kept, and delivered behind the next
+        program this engine enqueues (``_run_program``), or by whoever
+        needs a settled engine first (``_flush``)."""
         obs = get_session()
         with obs.span("serving/iteration") as span:
             lock_wait = obs.span("serving/iteration/lock_wait").begin()
@@ -755,6 +792,8 @@ class ServingEngine:
                 acct = self._accountant()
                 if acct is not None:
                     acct.iteration_begin(self.clock())
+                self._deferring = defer
+                self._unaccounted = span
                 try:
                     # tpusync: disable=lock-order-inversion — the SE->FR
                     # edge (prefill-complete handoff, in _step_prefill) and
@@ -763,21 +802,14 @@ class ServingEngine:
                     # engines under a router are stepped only from
                     # FleetRouter.step, which already holds FR
                     progress = self._step_locked(obs)
+                    if not defer or self._unaccounted is not None:
+                        # nothing was enqueued to do it behind
+                        self._settle(obs)
                     it = self._iterations
                     self._iterations += 1
-                    if span.recording:
-                        span.annotate(
-                            it=it, queued=self.sched.queue_depth(),
-                            running=len(self.sched.running),
-                            blocks_in_use=self.alloc.blocks_in_use,
-                            blocks_running=sum(
-                                len(r.blocks)
-                                for r in self.sched.running.values()),
-                            blocks_total=self.alloc.capacity,
-                            preemptions=self.sched.preemption_count,
-                            **self._state_counts(),
-                            **hbm_counts())
                 finally:
+                    self._deferring = False
+                    self._unaccounted = None
                     if acct is not None:
                         acct.iteration_end(self.clock())
                         # gauge refresh at a cadence, always AFTER the
@@ -825,9 +857,31 @@ class ServingEngine:
                      if self._drafter is not None
                      and not self.spec_suspended
                      else self._step_decode())
+        return progress
+
+    def _settle(self, obs, deferred: bool = False) -> None:
+        """Deliver what is applied and undelivered, then draw up the open
+        iteration's account, once: the registry's gauges and the iteration
+        span's counts. ``deferred``: a program of this iteration is
+        enqueued, so all of it runs while the device works."""
+        self._flush(deferred)
+        span = self._unaccounted
+        if span is None:
+            return
+        self._unaccounted = None
         with obs.span("serving/publish"):
             self._publish_iteration()
-        return progress
+        if span.recording:
+            span.annotate(
+                it=self._iterations, queued=self.sched.queue_depth(),
+                running=len(self.sched.running),
+                blocks_in_use=self.alloc.blocks_in_use,
+                blocks_running=sum(
+                    len(r.blocks) for r in self.sched.running.values()),
+                blocks_total=self.alloc.capacity,
+                preemptions=self.sched.preemption_count,
+                **self._state_counts(),
+                **hbm_counts())
 
     def _expire_deadlines(self) -> int:
         """Deadline enforcement at decode time: a request whose absolute
@@ -842,6 +896,10 @@ class ServingEngine:
         if not expired:
             return 0
         from .scheduler import DEADLINE_EXCEEDED
+
+        # a token applied last iteration reaches its handle before the
+        # expiry ends the stream
+        self._flush()
 
         for req in list(expired):
             for sib in self._pending_forks.pop(req.rid, []):
@@ -936,14 +994,19 @@ class ServingEngine:
         sampled tokens, its last the arena) and bring the tokens to the host:
         ``<name>/dispatch`` is the call, which returns at enqueue, and
         ``<name>/fetch`` the wait for the tokens (device time + D2H: the
-        iteration's host sync). ONE pair of readings of the engine's clock
-        around both feeds the accountants and the request tracer: returns
-        (tokens, t0, t1). The spans stamp themselves, on the profiler's
-        clock, and only while they record."""
+        iteration's host sync). Between the two the device works and the
+        host has nothing to wait for: an iteration of the driver thread
+        delivers there what the last one applied and kept (``_settle``).
+        ONE pair of readings of the engine's clock around all of it feeds
+        the accountants and the request tracer: returns (tokens, t0, t1).
+        The spans stamp themselves, on the profiler's clock, and only while
+        they record."""
         t0 = self.clock()
         with obs.span(name + "/dispatch", category="phase"):
             tok, *_, self._arena = program(self.engine.params, self._arena,
                                            *args)
+        if self._deferring:
+            self._settle(obs, deferred=True)
         with obs.span(name + "/fetch", category="phase"):
             tok = np.asarray(tok)
         return tok, t0, self.clock()
@@ -1030,7 +1093,7 @@ class ServingEngine:
         req.state = DECODE
         # the COW fork point for submit(n=...): siblings share the
         # freshly prefilled blocks BEFORE the parent can finish (a
-        # max_new_tokens=1 parent releases its refs in _emit below;
+        # max_new_tokens=1 parent releases its refs in _apply below;
         # the siblings' increfs keep the blocks alive)
         self._submit_pending_forks(req)
         if req.resume:
@@ -1040,13 +1103,14 @@ class ServingEngine:
             # streamed — never re-emit
             req.resume = False
         else:
-            with obs.span("serving/emit", tokens=1) as span:
-                self._emit(req, token, first=True)
-                span.annotate(finished=int(req.done))
+            # a first token is never kept: one push, and TTFT is a latency
+            # callers feel
+            self._apply(req, token, first=True)
+            self._flush()
         if (self.on_prefill_complete is not None
                 and req.state == DECODE):
             # still DECODE: a max_new_tokens=1 request already finished
-            # in _emit above and has nothing left to hand off.
+            # in _apply above and has nothing left to hand off.
             # tpusync: disable=callback-under-lock — router-bound seam,
             # not user code; the handoff must see the request frozen at
             # prefill completion, so it runs under the engine lock
@@ -1090,6 +1154,7 @@ class ServingEngine:
             raise ValueError(f"fork(n={n}): seeds has {len(seeds)} "
                              "entries — need one per sibling")
         with self._lock:
+            self._flush()   # the parent's handle holds what siblings inherit
             req = handle._req
             if req.state != DECODE:
                 raise ValueError(
@@ -1216,13 +1281,12 @@ class ServingEngine:
                     if r.trace is not None:
                         rt.note_decode(r.trace, t0, t1, batch=len(ready),
                                        replica=self.trace_tag)
-            with obs.span("serving/emit", tokens=len(ready)) as emit:
-                for r in ready:
-                    r.length += 1
-                    self.sched.note_service(r, 1)
-                    self._emit(r, int(nxt[r.row]))
-                if emit.recording:
-                    emit.annotate(finished=sum(r.done for r in ready))
+            for r in ready:
+                r.length += 1
+                self.sched.note_service(r, 1)
+                self._apply(r, int(nxt[r.row]))
+            if not self._deferring:
+                self._flush()
             if acct is not None:
                 acct.note_phase("sample_host", self.clock() - t1)
         return True
@@ -1339,50 +1403,94 @@ class ServingEngine:
                     rt.note_decode(r.trace, t0, t1, kind="verify",
                                    batch=len(plan), replica=self.trace_tag)
         self._spec_dispatches += 1
-        emitted = self._spec_emitted
-        with obs.span("serving/emit") as emit:
-            for r, prop in plan:
-                x = sampled[r.row]
-                a = 0   # accepted drafts: x[j] (the sample after draft j)
-                #   must CONFIRM draft j — first mismatch emits x[a] as the
-                #   correction, full acceptance emits x[cap] as the bonus
-                while a < prop.size and int(x[a]) == int(prop[a]):
-                    a += 1
-                r.spec_proposed += int(prop.size)
-                r.spec_accepted += a
-                self._spec_proposed += int(prop.size)
-                self._spec_accepted += a
-                for t in x[:a + 1]:
-                    r.length += 1
-                    self.sched.note_service(r, 1)
-                    self._emit(r, int(t))
-                    self._spec_emitted += 1
-                    if r.done:
-                        break   # EOS/budget mid-verify: later samples are
-                        #   beyond the request's end — never emitted
-                if not r.done:
-                    # positional rollback: whole blocks past the accepted
-                    # length go back to the pool; the drafter rolls its arena
-                    # back the same way
-                    self.sched.truncate_blocks(r, r.length)
-                    self._drafter.commit(r)
-            if emit.recording:
-                emit.annotate(tokens=self._spec_emitted - emitted,
-                              finished=sum(r.done for r, _ in plan))
+        for r, prop in plan:
+            x = sampled[r.row]
+            a = 0   # accepted drafts: x[j] (the sample after draft j)
+            #   must CONFIRM draft j — first mismatch emits x[a] as the
+            #   correction, full acceptance emits x[cap] as the bonus
+            while a < prop.size and int(x[a]) == int(prop[a]):
+                a += 1
+            r.spec_proposed += int(prop.size)
+            r.spec_accepted += a
+            self._spec_proposed += int(prop.size)
+            self._spec_accepted += a
+            for t in x[:a + 1]:
+                r.length += 1
+                self.sched.note_service(r, 1)
+                self._apply(r, int(t))
+                self._spec_emitted += 1
+                if r.done:
+                    break   # EOS/budget mid-verify: later samples are
+                    #   beyond the request's end — never emitted
+            if not r.done:
+                # positional rollback: whole blocks past the accepted
+                # length go back to the pool; the drafter rolls its arena
+                # back the same way
+                self.sched.truncate_blocks(r, r.length)
+                self._drafter.commit(r)
+        self._flush()   # a verify step's tokens are never kept: its
+        #   drafter runs on the host before the next enqueue
         if acct is not None:
             acct.note_phase("sample_host", self.clock() - t1)
         return True
 
-    def _emit(self, req: Request, token: int, first: bool = False) -> None:
-        now = self.clock()
-        obs = get_session()
-        # ``first`` marks the prefill-completion emit; a submit(n=...)
+    def _apply(self, req: Request, token: int, first: bool = False) -> None:
+        """One sampled token, as far as the NEXT program's operands depend
+        on it: the request's tokens, the finish test, and for a finished
+        request its row and blocks free for the next admit. Whatever only
+        callers and measurement see waits in ``_undelivered`` for
+        ``_flush``."""
+        # ``first`` marks the prefill-completion token; a submit(n=...)
         # sibling skips prefill entirely (admitted straight to DECODE with
         # the parent's KV) and its first token arrives through the
         # decode/verify path — catch it by the unset timestamp so TTFT/
         # TPOT cover forked samples too
-        if first or req.first_token_s is None:
-            req.first_token_s = now
+        first = first or req.first_token_s is None
+        if first:
+            req.first_token_s = self.clock()
+        req.generated.append(token)
+        req.pending_token = token
+        finished = (len(req.generated) >= req.max_new_tokens
+                    or (req.eos_token_id is not None
+                        and token == req.eos_token_id))
+        if finished:
+            self.sched.finish(req)
+        self._undelivered.append((req, token, first, finished))
+
+    def _flush(self, deferred: bool = False) -> None:
+        """Deliver every applied token, in the order applied: handles pushed
+        (the last push of a request ends its stream), latency samples,
+        counters, the request tracer, the accountant. Under the engine lock,
+        on the thread that holds it. ``deferred``: called with a program of
+        this iteration enqueued (``_run_program``)."""
+        pending = self._undelivered
+        if not pending:
+            return
+        self._undelivered = []
+        obs = get_session()
+        with obs.span("serving/emit", tokens=len(pending),
+                      deferred=int(deferred)) as span:
+            for item in pending:
+                self._deliver(obs, *item)
+            if span.recording:
+                span.annotate(finished=sum(item[3] for item in pending))
+        self._tokens_out += len(pending)
+        if self._serve_acct is not None:
+            self._serve_acct.note_tokens(len(pending))
+        if obs.enabled:
+            obs.registry.counter(
+                "serving/tokens_out",
+                help="tokens delivered to request handles").inc(len(pending))
+            if deferred:
+                obs.registry.counter(
+                    "serving/tokens_delivered_in_shadow",
+                    help="of serving/tokens_out, those delivered with the "
+                         "next program already enqueued (the driver "
+                         "thread's form)").inc(len(pending))
+
+    def _deliver(self, obs, req: Request, token: int, first: bool,
+                 finished: bool) -> None:
+        if first:
             self._request_span(obs, "serving/request/first_token", req,
                                ttft_us=int(req.ttft_s * 1e6))
             if obs.enabled:
@@ -1393,50 +1501,40 @@ class ServingEngine:
                     help="entry to submit() → first streamed token, wall "
                          "ms").observe(
                         ttft_ms, tenant=req.tenant)
-        req.generated.append(token)
-        req.pending_token = token
-        self._tokens_out += 1
         if req.trace is not None:
             # live progress marker: a crash dump's in-flight tail must say
             # how far each stuck request got (finish() re-stamps the
             # authoritative count from len(generated))
             req.trace.tokens += 1
-        if self._serve_acct is not None:
-            self._serve_acct.note_tokens(1)
         handle = self._handles.get(req.rid)
         if handle is not None:
-            handle._push(token)
-        finished = (len(req.generated) >= req.max_new_tokens
-                    or (req.eos_token_id is not None
-                        and token == req.eos_token_id))
-        if finished:
-            self.sched.finish(req)
-            if self._drafter is not None and req.spec_proposed:
-                self._accept_samples.append(
-                    req.spec_accepted / req.spec_proposed)
-            if obs.enabled:
-                obs.registry.counter(
-                    "serving/requests_completed",
-                    help="requests that finished generation").inc(
-                        tenant=req.tenant)
-                tpot = req.tpot_s
-                if tpot is not None:
-                    self._tpot_samples.append(tpot * 1e3)
-                    obs.registry.histogram(
-                        "serving/tpot_ms",
-                        help="mean per-token wall ms after the first "
-                             "token").observe(tpot * 1e3, tenant=req.tenant)
-            if self._serve_acct is not None:
-                ttft, tpot = req.ttft_s, req.tpot_s
-                self._serve_acct.note_request(
-                    ttft_ms=ttft * 1e3 if ttft is not None else None,
-                    tpot_ms=tpot * 1e3 if tpot is not None else None)
-            self._trace_finish(req, "finished")
-            self._handles.pop(req.rid, None)   # the client holds its own
-            #   reference; keeping ours would leak one handle per request
-            #   over a server's lifetime
-            if handle is not None:
-                handle._wake()
+            handle._push(token, last=finished)
+        if not finished:
+            return
+        if self._drafter is not None and req.spec_proposed:
+            self._accept_samples.append(
+                req.spec_accepted / req.spec_proposed)
+        if obs.enabled:
+            obs.registry.counter(
+                "serving/requests_completed",
+                help="requests that finished generation").inc(
+                    tenant=req.tenant)
+            tpot = req.tpot_s
+            if tpot is not None:
+                self._tpot_samples.append(tpot * 1e3)
+                obs.registry.histogram(
+                    "serving/tpot_ms",
+                    help="mean per-token wall ms after the first "
+                         "token").observe(tpot * 1e3, tenant=req.tenant)
+        if self._serve_acct is not None:
+            ttft, tpot = req.ttft_s, req.tpot_s
+            self._serve_acct.note_request(
+                ttft_ms=ttft * 1e3 if ttft is not None else None,
+                tpot_ms=tpot * 1e3 if tpot is not None else None)
+        self._trace_finish(req, "finished")
+        self._handles.pop(req.rid, None)   # the client holds its own
+        #   reference; keeping ours would leak one handle per request
+        #   over a server's lifetime
 
     def _publish_iteration(self) -> None:
         obs = get_session()
@@ -1569,21 +1667,31 @@ class ServingEngine:
         self._thread.start()
 
     def _drive(self) -> None:
-        while not self._stop.is_set():
-            try:
-                # the poll takes the engine lock, behind callers in
-                # submit(): that wait, like the one when nothing is in
-                # flight, is the driver outside an iteration
-                with get_session().span("serving/idle"):
-                    busy = self.in_flight()
-                    if not busy:
-                        self._stop.wait(0.002)
-                if busy:
-                    self.step()
-            except Exception:
-                logger.exception("serving driver step failed")
-                get_session().crash_dump("serving-step-exception")
-                self._stop.wait(0.05)
+        try:
+            while not self._stop.is_set():
+                try:
+                    # the poll takes the engine lock, behind callers in
+                    # submit(): that wait, like the one when nothing is in
+                    # flight, is the driver outside an iteration
+                    with get_session().span("serving/idle"):
+                        busy = self.in_flight()
+                        if not busy:
+                            # the last iteration's tokens: no program will
+                            # be enqueued to deliver them behind
+                            self._flush_locked()
+                            self._stop.wait(0.002)
+                    if busy:
+                        self._iterate(defer=True)
+                except Exception:
+                    logger.exception("serving driver step failed")
+                    get_session().crash_dump("serving-step-exception")
+                    self._stop.wait(0.05)
+        finally:
+            self._flush_locked()
+
+    def _flush_locked(self) -> None:
+        with self._lock:
+            self._flush()
 
     def stop(self) -> None:
         self._stop.set()
@@ -1640,6 +1748,7 @@ class ServingEngine:
         accumulators, which would otherwise dominate draft_time_share and
         skew acceptance/emitted-per-dispatch."""
         with self._lock:
+            self._flush()   # into the window that ends here
             self._ttft_samples.clear()
             self._tpot_samples.clear()
             self._accept_samples.clear()

@@ -510,7 +510,7 @@ class FleetRouter:
             if fr.done:
                 return False
             self._drain_tokens(fr)
-            if fr.u_req.done:        # finished just before the cancel
+            if fr.u_handle.done:     # finished just before the cancel
                 self._settle(fr)
                 return False
             if fr.replica.alive:
@@ -1065,7 +1065,9 @@ class FleetRouter:
 
     def _settle(self, fr: _FleetRequest) -> None:
         """Terminal-state propagation for the CURRENT binding."""
-        if fr.done or not fr.u_req.done:
+        # the engine handle's end, not the scheduler's state: it turns with
+        # the last token delivered, which _drain_tokens has then seen
+        if fr.done or not fr.u_handle.done:
             return
         if fr.u_req.state == FINISHED:
             state = F_FINISHED

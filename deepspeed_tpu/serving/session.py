@@ -12,6 +12,13 @@ modes:
 Cancellation is cooperative: ``cancel()`` marks the request and the engine
 releases its row/blocks at the next iteration boundary (or immediately when
 called between steps).
+
+A stream ends with its last DELIVERED token, not with the scheduler's state:
+the engine finishes a request (row and blocks free) when the token reaches
+the host, and under its driver thread pushes that token only once the next
+program is enqueued (``docs/serving.md``, threading). The handle's own
+``_ended`` flag, set under its condition by the final push or by the wake of
+a cancel or an expiry, is what ``done``, ``stream()`` and ``result()`` read.
 """
 
 from __future__ import annotations
@@ -92,16 +99,23 @@ class RequestHandle:
         self._req = req
         self._cond = threading.Condition()
         self._tokens: List[int] = []
+        self._ended = False     # no token will follow (guarded by _cond)
 
     # -- engine-side (called from ServingEngine.step under its lock) -------
-    def _push(self, token: int) -> None:
+    def _push(self, token: int, last: bool = False) -> None:
+        """``last``: the request finished with this token, so the stream
+        ends behind it."""
         with self._cond:
             self._tokens.append(int(token))
+            if last:
+                self._ended = True
             self._cond.notify_all()
 
     def _wake(self) -> None:
-        """Terminal-state transition: wake any blocked consumers."""
+        """Terminal-state transition with no token of its own (cancelled,
+        expired, released): end the stream and wake any blocked consumers."""
         with self._cond:
+            self._ended = True
             self._cond.notify_all()
 
     # -- client-side -------------------------------------------------------
@@ -115,7 +129,9 @@ class RequestHandle:
 
     @property
     def done(self) -> bool:
-        return self._req.done
+        """Terminal AND every token delivered (``state`` is the scheduler's
+        view, which can turn an iteration earlier under the driver thread)."""
+        return self._ended
 
     @property
     def tokens(self) -> List[int]:
@@ -166,7 +182,7 @@ class RequestHandle:
         (the same starvation guard as ``ServingEngine.run``)."""
         eng = self._engine
         yield from drive_stream(
-            self._cond, self._tokens, lambda: self._req.done, eng.clock,
+            self._cond, self._tokens, lambda: self._ended, eng.clock,
             lambda: eng.threaded, eng.step,
             lambda: 2 * eng.config.max_queue + 4,
             f"request {self._req.rid}",

@@ -292,10 +292,26 @@ class TestServingFromTheInside:
                                 set()).add(s["name"])
         assert kids["serving/iteration"] >= {
             "serving/iteration/lock_wait", "serving/admit",
-            "serving/prefill_chunk", "serving/decode", "serving/publish"}
+            "serving/prefill_chunk", "serving/decode"}
+        # the driver thread delivers and publishes behind the iteration's
+        # first enqueue: inside that program's span, before its fetch
         assert kids["serving/decode"] == {
             "serving/decode/prepare", "serving/decode/dispatch",
-            "serving/decode/fetch", "serving/emit"}
+            "serving/decode/fetch", "serving/emit", "serving/publish"}
+        shadowed = 0
+        for s in spans:
+            parent = by_id.get(s.get("parent_id"))
+            if (s["name"] == "serving/emit" and s["attrs"]["deferred"]) or (
+                    s["name"] == "serving/publish"
+                    and parent["name"] != "serving/iteration"):
+                shadowed += 1
+                disp, fetch = (next(
+                    c for c in spans if c.get("parent_id") == parent["id"]
+                    and c["name"] == parent["name"] + part)
+                    for part in ("/dispatch", "/fetch"))
+                assert disp["end_s"] <= s["start_s"] <= s["end_s"] \
+                    <= fetch["start_s"]
+        assert shadowed >= 8
         assert kids["serving/prefill_chunk"] >= {
             "serving/prefill_chunk/prepare",
             "serving/prefill_chunk/dispatch", "serving/prefill_chunk/fetch"}

@@ -972,3 +972,337 @@ class TestDeadlineEnforcement:
             assert out.size == 4 and h.state == "finished"
         finally:
             srv.close()
+
+
+# ---------------------------------------------------------------------------
+# deferred delivery: the driver thread hands out a step's tokens behind the
+# next enqueue; step() before it returns. Same tokens, same order, either way
+# ---------------------------------------------------------------------------
+
+
+def drive_on_this_thread(srv, iterations=None):
+    """The driver thread's loop (``ServingEngine._drive``) on the calling
+    thread, so a test can stop between two iterations: with ``iterations``
+    that many and NO idle flush, else until nothing is in flight."""
+    n = 0
+    while srv.in_flight() and (iterations is None or n < iterations):
+        srv._iterate(defer=True)
+        n += 1
+    if iterations is None:
+        srv._flush_locked()
+    return n
+
+
+DELIVERY_CASES = {
+    # name: (engine config, [(prompt length, submit kwargs)])
+    "greedy": ({}, [(7, dict(max_new_tokens=9)), (23, dict(max_new_tokens=6)),
+                    (40, dict(max_new_tokens=8)), (3, dict(max_new_tokens=10)),
+                    (18, dict(max_new_tokens=7))]),
+    "sampled": ({}, [(9, dict(max_new_tokens=8, temperature=0.8, top_k=20,
+                              top_p=0.9, seed=5)),
+                     (30, dict(max_new_tokens=8, temperature=1.3, top_p=0.7,
+                               seed=6)),
+                     (12, dict(max_new_tokens=8, temperature=0.5, top_k=5,
+                               seed=7))]),
+    "eos": ({}, [(11, dict(max_new_tokens=12, eos_at=3)),
+                 (26, dict(max_new_tokens=12, eos_at=5)),
+                 (5, dict(max_new_tokens=12))]),
+    "one_token": ({}, [(8, dict(max_new_tokens=1)),
+                       (19, dict(max_new_tokens=1)),
+                       (33, dict(max_new_tokens=4))]),
+    "preempted": (dict(num_blocks=10, prefix_cache=False),
+                  [(n, dict(max_new_tokens=10))
+                   for n in (27, 44, 58, 20, 39, 51)]),
+}
+
+
+def delivery_requests(tiny_engine, case):
+    """The case's prompts and submit() arguments; ``eos_at`` becomes the
+    token the greedy stream holds at that index, so EOS hits mid-stream."""
+    cfg, specs = DELIVERY_CASES[case]
+    rng = np.random.RandomState(sum(map(ord, case)))
+    out = []
+    for n, kw in specs:
+        prompt = rng.randint(0, 250, (n,)).astype(np.int32)
+        kw = dict(kw)
+        at = kw.pop("eos_at", None)
+        if at is not None:
+            greedy = np.asarray(tiny_engine.generate(
+                prompt[None], max_new_tokens=kw["max_new_tokens"]))[0]
+            kw["eos_token_id"] = int(greedy[at])
+        out.append((prompt, kw))
+    return cfg, out
+
+
+class TestDeferredDelivery:
+    @pytest.mark.parametrize("case", sorted(DELIVERY_CASES))
+    def test_threaded_and_step_driven_streams_are_identical(self,
+                                                            tiny_engine,
+                                                            case):
+        cfg, reqs = delivery_requests(tiny_engine, case)
+        streams = {}
+        for mode in ("step", "thread", "driver_loop"):
+            srv = serving(tiny_engine, **cfg)
+            try:
+                # all queued before the first iteration: the schedule, and
+                # with it the preemptions, are the same in every mode
+                handles = [srv.submit(p, **kw) for p, kw in reqs]
+                if mode == "step":
+                    srv.run()
+                elif mode == "thread":
+                    srv.start()
+                else:
+                    drive_on_this_thread(srv)
+                streams[mode] = [list(h.result(timeout_s=120.0))
+                                 for h in handles]
+                assert all(h.done and h.state == "finished"
+                           for h in handles)
+                assert [len(h._req.generated) for h in handles] \
+                    == [len(s) for s in streams[mode]]
+                if case == "preempted":
+                    assert srv.sched.preemption_count > 0
+                if case == "eos":
+                    assert len(streams[mode][0]) < 12
+                srv.stop()
+                assert not srv.sched.running and not srv._handles
+                assert not srv._undelivered
+            finally:
+                srv.close()
+        assert streams["thread"] == streams["step"]
+        assert streams["driver_loop"] == streams["step"]
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline"])
+    def test_a_stream_cut_with_a_delivery_pending_keeps_its_tokens(
+            self, tiny_engine, how):
+        """Four iterations in the driver's form leave the fourth's token
+        applied and undelivered; the cancel, or the expiry at the next
+        admit, streams it before it ends the stream: what a step-driven
+        engine cut at the same iteration streams."""
+        from deepspeed_tpu.serving import DeadlineExceeded
+
+        got = {}
+        for mode in ("step", "driver_loop"):
+            clk = FakeClock()
+            srv = serving(tiny_engine, clock=clk, prefix_cache=False)
+            try:
+                h = srv.submit(np.arange(1, 30, dtype=np.int32),
+                               max_new_tokens=40, deadline_s=5.0)
+                other = srv.submit(np.arange(3, 20, dtype=np.int32),
+                                   max_new_tokens=40)
+                if mode == "step":
+                    for _ in range(4):
+                        srv.step()
+                    assert not srv._undelivered
+                else:
+                    assert drive_on_this_thread(srv, iterations=4) == 4
+                    assert {r.rid for r, *_ in srv._undelivered} \
+                        == {h.request_id, other.request_id}
+                    assert len(h.tokens) == len(h._req.generated) - 1
+                if how == "cancel":
+                    assert h.cancel()
+                    with pytest.raises(RequestCancelled):
+                        h.result()
+                else:
+                    clk.advance(10.0)
+                    srv.step() if mode == "step" else srv._iterate(defer=True)
+                    assert h.state == "deadline_exceeded"
+                    with pytest.raises(DeadlineExceeded):
+                        h.result()
+                assert h.done and h.tokens == h._req.generated
+                assert list(h.stream()) == h.tokens
+                got[mode] = h.tokens
+                other.cancel()
+                assert other.tokens == other._req.generated
+                assert srv.alloc.blocks_in_use == 0
+            finally:
+                srv.close()
+        assert got["driver_loop"] == got["step"] and len(got["step"]) >= 3
+
+    @staticmethod
+    def _spy(monkeypatch, srv):
+        """Every dispatch (entry to and return from ``_run_program``), push
+        and return of an iteration, in order."""
+        from deepspeed_tpu.serving.session import RequestHandle
+
+        events = []
+        run, push, iterate = (srv._run_program, RequestHandle._push,
+                              srv._iterate)
+
+        def spy_run(obs, name, *a, **kw):
+            events.append(("dispatch", name.split("/")[1]))
+            out = run(obs, name, *a, **kw)
+            events.append(("fetched", name.split("/")[1]))
+            return out
+
+        def spy_push(self, token, last=False):
+            events.append(("push", self.request_id, len(self._tokens)))
+            return push(self, token, last)
+
+        def spy_iterate(defer):
+            out = iterate(defer)
+            events.append(("iteration_end",))
+            return out
+
+        monkeypatch.setattr(srv, "_run_program", spy_run)
+        monkeypatch.setattr(srv, "_iterate", spy_iterate)
+        monkeypatch.setattr(RequestHandle, "_push", spy_push)
+        return events
+
+    def test_driver_thread_pushes_behind_the_next_dispatch(self, tiny_engine,
+                                                           monkeypatch):
+        srv = serving(tiny_engine)
+        events = self._spy(monkeypatch, srv)
+        srv.start()
+        try:
+            h = srv.submit(np.arange(7), max_new_tokens=4)
+            assert len(h.result(timeout_s=60.0)) == 4
+        finally:
+            srv.stop()
+        r = h.request_id
+        assert events == [
+            # the first token at once: before its iteration's decode dispatch
+            ("dispatch", "prefill_chunk"), ("fetched", "prefill_chunk"),
+            ("push", r, 0),
+            ("dispatch", "decode"), ("fetched", "decode"),
+            ("iteration_end",),
+            # step n's token behind step n+1's dispatch, before its fetch
+            ("dispatch", "decode"), ("push", r, 1), ("fetched", "decode"),
+            ("iteration_end",),
+            ("dispatch", "decode"), ("push", r, 2), ("fetched", "decode"),
+            ("iteration_end",),
+            # the request finished at apply; nothing is enqueued any more:
+            # the driver flushes before it idles
+            ("push", r, 3)]
+        srv.close()
+
+    def test_a_late_request_s_chunk_shadows_the_pending_pushes(
+            self, tiny_engine, monkeypatch):
+        srv = serving(tiny_engine)
+        events = self._spy(monkeypatch, srv)
+        try:
+            a = srv.submit(np.arange(7), max_new_tokens=8)
+            drive_on_this_thread(srv, iterations=2)
+            b = srv.submit(np.arange(9), max_new_tokens=8)
+            del events[:]
+            drive_on_this_thread(srv, iterations=1)
+            ra, rb = a.request_id, b.request_id
+            assert events == [
+                ("dispatch", "prefill_chunk"), ("push", ra, 2),
+                ("fetched", "prefill_chunk"), ("push", rb, 0),
+                ("dispatch", "decode"), ("fetched", "decode"),
+                ("iteration_end",)]
+            drive_on_this_thread(srv)
+            assert len(a.tokens) == len(b.tokens) == 8
+        finally:
+            srv.close()
+
+    def test_step_delivers_before_it_returns(self, tiny_engine, monkeypatch):
+        srv = serving(tiny_engine)
+        events = self._spy(monkeypatch, srv)
+        try:
+            h = srv.submit(np.arange(7), max_new_tokens=3)
+            while srv.in_flight():
+                srv.step()
+                assert len(h.tokens) == len(h._req.generated)
+                assert not srv._undelivered
+            r = h.request_id
+            assert events == [
+                ("dispatch", "prefill_chunk"), ("fetched", "prefill_chunk"),
+                ("push", r, 0),
+                ("dispatch", "decode"), ("fetched", "decode"), ("push", r, 1),
+                ("iteration_end",),
+                ("dispatch", "decode"), ("fetched", "decode"), ("push", r, 2),
+                ("iteration_end",)]
+            assert h.done
+        finally:
+            srv.close()
+
+    def test_a_stream_never_ends_a_token_short(self, tiny_engine,
+                                               monkeypatch):
+        """Between apply (the scheduler's state turns) and delivery the
+        handle is not done and its stream goes on waiting."""
+        import threading
+
+        srv = serving(tiny_engine)
+        n = 6
+        at_apply, at_done = [], []
+        apply_ = srv._apply
+
+        def spy_apply(req, token, first=False):
+            apply_(req, token, first)
+            if req.done:
+                at_apply.append((h.done, len(h.tokens), h.state))
+
+        monkeypatch.setattr(srv, "_apply", spy_apply)
+
+        def poll():
+            while not h.done:
+                pass
+            at_done.append(len(h.tokens))
+
+        srv.start()
+        try:
+            h = srv.submit(np.arange(7), max_new_tokens=n)
+            poller = threading.Thread(target=poll, daemon=True)
+            poller.start()
+            streamed = list(h.stream(timeout_s=60.0))
+            poller.join(timeout=60.0)
+        finally:
+            srv.stop()
+        assert at_apply == [(False, n - 1, "finished")]
+        assert at_done == [n] and len(streamed) == n
+        assert streamed == list(h.result()) == h._req.generated
+        srv.close()
+
+    @pytest.mark.parametrize("how", ["stop", "close", "idle"])
+    def test_whatever_is_pending_is_flushed(self, tiny_engine, how):
+        srv = serving(tiny_engine)
+        h = srv.submit(np.arange(7), max_new_tokens=3)
+        drive_on_this_thread(srv, iterations=3)
+        # the scheduler is through with the request; its last token waits
+        assert srv.in_flight() == 0 and h.state == "finished"
+        assert len(srv._undelivered) == 1 and not h.done
+        assert len(h.tokens) == 2
+        if how == "idle":
+            srv.start()
+            assert len(h.result(timeout_s=60.0)) == 3   # no stop() needed
+            srv.stop()
+        else:
+            import threading
+
+            srv._stop.set()         # the driver leaves before its first poll
+            srv._thread = threading.Thread(target=srv._drive, daemon=True)
+            srv._thread.start()
+            srv.stop() if how == "stop" else srv.close()
+        assert h.done and len(h.tokens) == 3 and not srv._undelivered
+        srv.close()
+
+    @pytest.mark.parametrize("mode", ["thread", "step"])
+    def test_the_deferred_count_and_the_shadow_counter(self, tiny_engine,
+                                                       obs_session, mode):
+        from deepspeed_tpu.observability import recorded_spans
+
+        reg = get_registry()     # the process's: read what this test adds
+        out_c = reg.counter("serving/tokens_out")
+        shadow_c = reg.counter("serving/tokens_delivered_in_shadow")
+        out0, shadow0 = out_c.value(), shadow_c.value()
+        srv = serving(tiny_engine)
+        if mode == "thread":
+            srv.start()
+        h = srv.submit(np.arange(7), max_new_tokens=5)
+        assert len(h.result(timeout_s=60.0)) == 5
+        srv.stop()
+        emits = [s["attrs"] for s in recorded_spans()
+                 if s["name"] == "serving/emit"]
+        assert sum(a["tokens"] for a in emits) == 5
+        assert sum(a["finished"] for a in emits) == 1
+        assert out_c.value() - out0 == 5
+        shadow = shadow_c.value() - shadow0
+        if mode == "thread":
+            # the first token at once, the last at the idle flush
+            assert [a["deferred"] for a in emits] == [0, 1, 1, 1, 0]
+            assert shadow == 3
+        else:
+            assert [a["deferred"] for a in emits] == [0] * 5
+            assert shadow == 0
+        srv.close()
