@@ -41,7 +41,7 @@ TINY_KEYS = {"preset", "dtype", "overrides", "reference_args"}
 SHARE_KEYS = {"chips", "divided", "how"}
 # the floors of the `model-configs` guide, section 4, for a chip's share
 MIN_EXPERTS_HELD = 8
-MAX_CHIPS_OVER_A_VOCABULARY = 8
+MAX_WAYS_OVER_A_VOCABULARY = 8
 MIN_LAYERS_OF_A_SHARE = 4
 
 
@@ -64,6 +64,20 @@ def names_a_count(key: str, value: Any) -> bool:
     """A size that chips sharing a layer may divide among them: how many
     experts, heads or rows of the vocabulary, never how wide."""
     return names_a_size(key, value) and not names_a_width(key)
+
+
+def share_ways(cfg: Dict[str, Any]) -> Dict[str, int]:
+    """Source key -> the number of ways the chips that share a layer divide
+    it; `{}` for a file that states no `share`. The one place that tells the
+    two forms of `share.divided` apart: a list divides every key it names
+    over all of `share.chips`, an object gives each key its own ways."""
+    share = cfg.get("share")
+    if not share:
+        return {}
+    divided = share["divided"]
+    if isinstance(divided, dict):
+        return dict(divided)
+    return {key: share["chips"] for key in divided}
 
 
 class SpecError(ValueError):
@@ -144,8 +158,9 @@ class Spec:
     def _validate_share(cfg: Dict[str, Any], reduced, bad) -> Dict[str, int]:
         """The file's optional `share` block: the cell is what ONE of `chips`
         chips that share each layer would hold. Returns, for each source key
-        it divides, the count held here (exactly `published / chips`); `{}`
-        for a file that states no deployment."""
+        it divides, the count held here (exactly `published / ways`, the
+        key's own ways: `share_ways`); `{}` for a file that states no
+        deployment."""
         if "share" not in cfg:
             return {}
         share, published = cfg["share"], cfg["published"]
@@ -156,15 +171,34 @@ class Spec:
         if isinstance(chips, bool) or not isinstance(chips, int) or chips < 2:
             raise bad(f"share.chips is {chips!r}: a share is of 2 chips or "
                       "more that divide each layer among them")
-        if (not isinstance(divided, list) or not divided
+        if (not isinstance(divided, (list, dict)) or not divided
                 or len(set(divided)) != len(divided)):
             raise bad("share.divided lists, once each, the keys of "
-                      "'published' that the chips divide")
+                      "'published' that the chips divide (or gives each its "
+                      "own number of ways)")
         if not isinstance(share["how"], str) or not share["how"].strip():
             raise bad("share.how says what is divided over the chips that "
                       "share a layer and what every chip holds whole")
+        ways = share_ways(cfg)
+        if isinstance(divided, dict):
+            for key, w in ways.items():
+                if isinstance(w, bool) or not isinstance(w, int) or w < 2:
+                    raise bad(f"share.divided.{key} is {w!r}: the ways the "
+                              f"chips divide {key}, a whole number of 2 or "
+                              "more (a count held whole is not listed)")
+                if chips % w:
+                    raise bad(f"share.divided.{key} is {w} ways, which do "
+                              f"not divide share.chips of {chips}: a count "
+                              "divided w ways over c chips lives in c / w "
+                              "copies")
+            key, most = max(ways.items(), key=lambda kw: kw[1])
+            if most != chips:
+                raise bad(f"share.chips is {chips} and nothing is divided "
+                          f"{chips} ways (the most: {key} {most} ways): "
+                          "chips counts the chips that share each layer, so "
+                          "this states the wrong deployment")
         held = {}
-        for key in divided:
+        for key, w in ways.items():
             if key not in published:
                 raise bad(f"share.divided: '{key}' is no key of 'published'")
             if names_a_width(key):
@@ -178,19 +212,21 @@ class Spec:
                 raise bad(f"share.divided names '{key}', which 'reduced' "
                           "does not list: the count held here is a change "
                           "from the source")
-            if published[key] % chips:
+            if published[key] % w:
                 raise bad(f"share: {chips} chips do not divide the source's "
-                          f"{key} of {published[key]} (remainder "
-                          f"{published[key] % chips})")
-            held[key] = published[key] // chips
+                          f"{key} of {published[key]} {w} ways (remainder "
+                          f"{published[key] % w})")
+            held[key] = published[key] // w
             if "experts" in key and held[key] < MIN_EXPERTS_HELD:
-                raise bad(f"share: {held[key]} of {published[key]} {key} "
-                          f"held; the floor is {MIN_EXPERTS_HELD} experts "
-                          "in each layer that has them")
-            if "vocab" in key and chips > MAX_CHIPS_OVER_A_VOCABULARY:
-                raise bad(f"share: {key} over {chips} chips; the floor is "
-                          "an eighth of the vocabulary (at most "
-                          f"{MAX_CHIPS_OVER_A_VOCABULARY} chips)")
+                raise bad(f"share: {key} {w} ways: {held[key]} of "
+                          f"{published[key]} {key} held; the floor is "
+                          f"{MIN_EXPERTS_HELD} experts in each layer that "
+                          "has them")
+            if "vocab" in key and w > MAX_WAYS_OVER_A_VOCABULARY:
+                raise bad(f"share: {key} over {w} chips; the floor is an "
+                          "eighth of the vocabulary (at most "
+                          f"{MAX_WAYS_OVER_A_VOCABULARY} ways, however many "
+                          "chips share the layer)")
         return held
 
     def _validate_config(self, entry: Dict[str, Any]) -> None:
@@ -218,6 +254,7 @@ class Spec:
         if set(cfg.get("reduced", {})) != set(entry["reduced"]):
             raise bad("'reduced' differs from BENCHMARK.json's")
         held = self._validate_share(cfg, entry["reduced"], bad)
+        ways = share_ways(cfg)
         for key in entry["reduced"]:
             if ((key in widths.values() or key in equal)
                     and not names_depth(key) and key not in held):
@@ -236,7 +273,8 @@ class Spec:
                 if overrides[key] not in (published[source], held[source]):
                     raise bad(f"model.overrides.{key} is {overrides[key]}: "
                               f"of the source's {source} a chip of "
-                              f"{cfg['share']['chips']} holds all "
+                              f"{cfg['share']['chips']}, which divide "
+                              f"{source} {ways[source]} ways, holds all "
                               f"{published[source]} or its share of "
                               f"{held[source]}, nothing else")
             elif (overrides[key] != published[source]
@@ -247,7 +285,8 @@ class Spec:
         for source, part in held.items():
             if part not in [overrides[k] for k, s in widths.items()
                             if s == source]:
-                raise bad(f"share.divided names '{source}', and no key that "
+                raise bad(f"share.divided names '{source}' "
+                          f"{ways[source]} ways, and no key that "
                           f"'widths' maps to it holds the share of {part}")
         if held:
             depth = [overrides[k] for k, s in widths.items()

@@ -8,7 +8,7 @@ import json
 import os
 import shutil
 
-from benchmarks.harness.spec import REPO_ROOT
+from benchmarks.harness.spec import REPO_ROOT, share_ways
 
 TINY_SERVING = {"num_blocks": 40, "block_size": 16, "max_seqs": 4,
                 "prefill_chunk": 32, "max_model_len": 128}
@@ -42,14 +42,14 @@ def tiny_config(cfg: dict) -> dict:
     the reference is called with the small model's arguments. Where the file
     states a `share`, the block stays and `published` holds the WHOLE count
     of each divided key: the smallest number the small model runs under it
-    (a router may keep the whole) times the chips."""
+    (a router may keep the whole) times that key's own ways."""
     cfg = copy.deepcopy(cfg)
     tiny = cfg["tiny"]
     cfg["model"] = {k: tiny[k] for k in ("preset", "dtype", "overrides")}
     for key, source in cfg["widths"].items():
         cfg["published"][source] = tiny["overrides"][key]
-    for source in cfg.get("share", {}).get("divided", ()):
-        cfg["published"][source] = cfg["share"]["chips"] * min(
+    for source, ways in share_ways(cfg).items():
+        cfg["published"][source] = ways * min(
             tiny["overrides"][k] for k, s in cfg["widths"].items()
             if s == source)
     for key, other in cfg.get("equal_widths", {}).items():

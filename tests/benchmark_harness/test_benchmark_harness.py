@@ -63,15 +63,15 @@ def test_cell_files_are_found_by_name(cell):
            if model[key] != published[source]}
     assert cut == set(entry["reduced"]) & set(widths.values())
     assert set(entry["reduced"]) == set(c.config["reduced"])
-    share = c.config.get("share", {"chips": 1, "divided": []})
+    ways = spec_mod.share_ways(c.config)
     for source in cut:
         if spec_mod.names_depth(source):
             continue
-        assert source in share["divided"]
+        assert source in ways
         assert spec_mod.names_a_count(source, published[source])
-        assert published[source] % share["chips"] == 0
+        assert published[source] % ways[source] == 0
         assert {model[k] for k, s in widths.items() if s == source} <= {
-            published[source], published[source] // share["chips"]}
+            published[source], published[source] // ways[source]}
     accounted = set(widths.values()) | set(c.config.get("equal_widths", {}))
     assert all(key in accounted for key, value in published.items()
                if spec_mod.names_a_size(key, value))
@@ -271,6 +271,25 @@ def _break_share(cfg, entry, how):
     if how == "share-with-another-key":
         share["layers_elsewhere"] = 4
         return "share has keys"
+    # the object form: the experts over all 8 chips, the vocabulary 4 ways
+    # (each quarter on two of the eight), sound before it is broken
+    share["divided"] = {"num_experts": 8, "vocab_size": 4}
+    overrides["vocab_size"] = 50304 // 4
+    if how == "share-ways-do-not-divide-the-chips":
+        share["divided"]["vocab_size"] = 3
+        return "share.divided.vocab_size is 3 ways, which do not divide " \
+               "share.chips of 8"
+    if how == "share-widest-ways-under-the-chips":
+        share["divided"]["num_experts"] = 4
+        return "nothing is divided 8 ways \\(the most: num_experts 4 ways\\)"
+    if how == "share-override-the-chips-part-not-the-keys":
+        overrides["vocab_size"] = 50304 // 8
+        return "a chip of 8, which divide vocab_size 4 ways, holds all " \
+               "50304 or its share of 12576, nothing else"
+    if how == "share-ways-held-by-no-key":
+        overrides["vocab_size"] = 50304
+        return "names 'vocab_size' 4 ways, and no key that 'widths' maps " \
+               "to it holds the share of 12576"
     raise KeyError(how)
 
 
@@ -279,7 +298,11 @@ BROKEN_SHARES = ["share-remainder", "share-vocabulary-over-16-chips",
                  "share-divides-no-count", "share-divided-not-reduced",
                  "share-override-neither-whole-nor-share",
                  "share-held-by-no-key", "share-three-layers",
-                 "share-of-one-chip", "share-with-another-key"]
+                 "share-of-one-chip", "share-with-another-key",
+                 "share-ways-do-not-divide-the-chips",
+                 "share-widest-ways-under-the-chips",
+                 "share-override-the-chips-part-not-the-keys",
+                 "share-ways-held-by-no-key"]
 BROKEN_DOCS = ["bad-name", "unknown-moves", "moves-not-reported",
                "width-reduced", "two-four-chip-cells", "no-setup",
                "loose-bound", "extra-key"]
@@ -420,22 +443,114 @@ A_SHARE = {
 }
 
 
+def _two_divisors():
+    """The same made-up file with counts that 8 chips cannot hold at four
+    layers: 384 experts over 32 chips (12 held, the router whole), 163,840
+    rows 8 ways (each slice on 4 of the 32), 5 of 61 layers. The small model
+    follows: a router of 8 x 32 outputs over 8 experts held."""
+    cfg = copy.deepcopy(A_SHARE)
+    cfg["published"].update(n_routed_experts=384, vocab_size=163840,
+                            num_hidden_layers=61)
+    cfg["reduced"] = {
+        "num_hidden_layers": "61 -> 5: the leading dense layer and four",
+        "n_routed_experts": "384 -> 12 held; the router keeps 384 outputs",
+        "vocab_size": "163840 -> 20480, an eighth"}
+    cfg["share"] = {
+        "chips": 32, "divided": {"n_routed_experts": 32, "vocab_size": 8},
+        "how": "32 chips share each layer: the 384 routed experts expert-"
+               "parallel 32 ways (12 here); the vocabulary 8 ways, each "
+               "slice on 4 of the 32; attention, router and shared expert "
+               "whole on every chip"}
+    cfg["model"]["overrides"].update(moe_num_experts=384, moe_experts_held=12,
+                                     vocab_size=20480, num_layers=5)
+    cfg["tiny"]["overrides"].update(moe_num_experts=256)
+    return cfg
+
+
+def _break_two_divisors(cfg, how):
+    """`_two_divisors`, as stated (returns None: it validates) or broken in
+    one of the ways `validate` refuses (returns what the refusal says)."""
+    share, overrides = cfg["share"], cfg["model"]["overrides"]
+    ways, published = share["divided"], cfg["published"]
+    if how == "as-stated":
+        return None
+    if how == "a-vocabulary-that-8-divide-and-32-do-not":
+        published["vocab_size"], overrides["vocab_size"] = 163848, 20481
+        return None
+    if how == "the-list-form-at-32-chips":      # why the object form exists
+        share["divided"] = sorted(ways)
+        overrides["vocab_size"] = 163840 // 32
+        return "vocab_size over 32 chips; the floor is an eighth"
+    if how == "ways-that-do-not-divide-the-chips":
+        ways["vocab_size"] = 12
+        return "share.divided.vocab_size is 12 ways, which do not divide " \
+               "share.chips of 32"
+    if how == "widest-ways-under-the-chips":
+        ways["n_routed_experts"], overrides["moe_experts_held"] = 16, 24
+        return "share.chips is 32 and nothing is divided 32 ways"
+    if how == "vocabulary-16-ways":
+        ways["vocab_size"], overrides["vocab_size"] = 16, 163840 // 16
+        return "vocab_size over 16 chips; the floor is an eighth of the " \
+               "vocabulary \\(at most 8 ways"
+    if how == "384-experts-64-ways":
+        share["chips"], ways["n_routed_experts"] = 64, 64
+        overrides["moe_experts_held"] = 6
+        return "n_routed_experts 64 ways: 6 of 384 n_routed_experts held; " \
+               "the floor is 8 experts"
+    if how == "remainder-against-the-keys-own-ways":
+        published["vocab_size"] = 163844        # 32 x 12 divides the experts
+        return "32 chips do not divide the source's vocab_size of 163844 " \
+               "8 ways \\(remainder 4\\)"
+    if how in ("ways-of-one", "ways-a-bool", "ways-a-string"):
+        ways["vocab_size"] = {"ways-of-one": 1, "ways-a-bool": True,
+                              "ways-a-string": "8"}[how]
+        return f"share.divided.vocab_size is {ways['vocab_size']!r}: the " \
+               "ways the chips divide vocab_size, a whole number of 2 or more"
+    if how == "override-a-32nd-of-the-vocabulary":
+        overrides["vocab_size"] = 5120
+        return "model.overrides.vocab_size is 5120: of the source's " \
+               "vocab_size a chip of 32, which divide vocab_size 8 ways, " \
+               "holds all 163840 or its share of 20480, nothing else"
+    if how == "experts-per-token-8-ways":
+        ways["num_experts_per_tok"] = 8
+        return "'num_experts_per_tok' is a width"
+    raise KeyError(how)
+
+
+TWO_DIVISORS = [
+    "as-stated", "a-vocabulary-that-8-divide-and-32-do-not",
+    "the-list-form-at-32-chips", "ways-that-do-not-divide-the-chips",
+    "widest-ways-under-the-chips", "vocabulary-16-ways",
+    "384-experts-64-ways", "remainder-against-the-keys-own-ways",
+    "ways-of-one", "ways-a-bool", "ways-a-string",
+    "override-a-32nd-of-the-vocabulary", "experts-per-token-8-ways"]
+
+
 @pytest.mark.parametrize("how", [
     "as-stated", "as-stated-at-its-tiny-size", "41-experts-held",
-    "16-chips", "experts-per-token-divided", "no-share-block"])
+    "16-chips", "experts-per-token-divided", "no-share-block"]
+    + [f"two-divisors:{how}" for how in TWO_DIVISORS]
+    + ["two-divisors:as-stated-at-its-tiny-size"])
 def test_a_model_whose_experts_outnumber_a_chip_arrives_as_a_share(
         how, tmp_path):
     """320 routed experts, 8 a token, a vocabulary of 196,608 and 48 layers
     as published; as run 40 experts beside a router of 320, 24,576 rows and
     8 layers, one of 8 chips that share each layer. That validates; another
     number of experts, a vocabulary in sixteenths, a divided top-k or the
-    same cuts with no deployment stated do not."""
+    same cuts with no deployment stated do not. `two-divisors`: 384 experts
+    that 32 chips share and a vocabulary that 8 of them divide, in one file
+    (`_two_divisors`); each break is refused by the key and ITS ways."""
     root = bench_tiny.make_root(str(tmp_path))
-    cfg = copy.deepcopy(A_SHARE)
-    says = None
+    two, how = how.startswith("two-divisors:"), how.split(":")[-1]
+    cfg, says = _two_divisors() if two else copy.deepcopy(A_SHARE), None
     if how == "as-stated-at-its-tiny-size":
         cfg = bench_tiny.tiny_config(cfg)
-        assert cfg["published"]["n_routed_experts"] == 64
+        # what the small model holds, times each key's OWN ways
+        assert [cfg["published"][k] for k in (
+            "n_routed_experts", "vocab_size")] == [8 * (32 if two else 8),
+                                                   256 * 8]
+    elif two:
+        says = _break_two_divisors(cfg, how)
     elif how == "41-experts-held":
         cfg["model"]["overrides"]["moe_experts_held"] = 41
         says = "holds all 320 or its share of 40, nothing else"
@@ -459,6 +574,26 @@ def test_a_model_whose_experts_outnumber_a_chip_arrives_as_a_share(
     else:
         with pytest.raises(spec_mod.SpecError, match=says):
             spec.validate()
+
+
+@pytest.mark.parametrize("form", ["no-share", "a-list", "an-object"])
+def test_share_ways_tells_the_two_forms_apart(form):
+    """The one reader of `share.divided`: a list is every key over all of
+    `chips` (Solar's file, byte for byte what PR 33 committed), an object
+    gives each key its own ways, no block divides nothing."""
+    if form == "no-share":
+        cfg = SPEC.cell("opt-1.3b.serve-decode").config
+        assert "share" not in cfg and spec_mod.share_ways(cfg) == {}
+    elif form == "a-list":
+        cfg = SPEC.cell("solar-open2-250b-ep8-d4.serve-decode-r64").config
+        assert cfg["share"]["divided"] == ["n_routed_experts", "vocab_size"]
+        assert spec_mod.share_ways(cfg) == {"n_routed_experts": 8,
+                                            "vocab_size": 8}
+    else:
+        cfg = _two_divisors()
+        assert spec_mod.share_ways(cfg) == {"n_routed_experts": 32,
+                                            "vocab_size": 8}
+        assert spec_mod.share_ways(cfg) is not cfg["share"]["divided"]
 
 
 # -- the arithmetic --------------------------------------------------------
@@ -517,6 +652,92 @@ def test_percentile_and_spread_match_the_contracts_definitions():
     assert stats.spread(xs) == pytest.approx((q[2] - q[0]) / 3.5)
     assert stats.union_seconds([(0, 2), (1, 3), (5, 6)]) == 4
     assert stats.gaps([(0, 2), (1, 3), (5, 6)], 0, 7) == [[3, 5], [6, 7]]
+
+
+@pytest.mark.parametrize("case", ["one-far-run", "two-far-runs",
+                                  "no-far-run", "two-runs"])
+def test_trimmed_spread_on_known_inputs(case):
+    """What decides admission: the set's inter-quartile distance, or that
+    of the set without the run farthest from the median where that is
+    narrower, over the set's median. One far-off run in a set does no harm,
+    two do."""
+    xs = {"one-far-run": [1.0, 2.0, 3.0, 4.0, 5.0, 60.0],
+          "two-far-runs": [10.0, 10.1, 9.9, 10.0, 12.0, 12.2],
+          "no-far-run": [16.90, 16.95, 17.00, 17.05, 17.10, 17.15],
+          "two-runs": [3.0, 5.0]}[case]
+    got = stats.trimmed_spread(xs)
+    assert got <= stats.spread(xs)
+    if case == "one-far-run":       # [1..5]: quartiles 1.5 and 4.5
+        assert got == pytest.approx(3.0 / 3.5)
+        assert stats.spread(xs) == pytest.approx(17.0 / 3.5)
+    elif case == "two-far-runs":    # 12.2 goes, 12.0 stays in the quartile
+        assert got == pytest.approx((11.05 - 9.95) / 10.05)
+    elif case == "no-far-run":      # 17.15 goes: 0.15 for 0.175
+        assert got == pytest.approx(0.15 / 17.025)
+        assert stats.spread(xs) == pytest.approx(0.175 / 17.025)
+    else:                           # nothing to leave out of two
+        assert got == stats.spread(xs)
+
+
+@pytest.mark.parametrize("case", ["pr-35-as-the-ledger-has-it",
+                                  "on-the-line", "solar-at-pr-34"])
+def test_admission_is_the_mean_of_the_sets_against_half_the_bound(case):
+    """PR 35 was refused on these numbers (ledger): two sets spreading by
+    0.168873 and 0.14035 ms, a bound of 2% of 14.7885 ms, so 0.1546 against
+    0.1479: too noisy, by 4.5%."""
+    from benchmarks import measure
+
+    units, bound, median, verdict, over = {
+        "pr-35-as-the-ledger-has-it":
+            ([0.168873, 0.14035], 0.02, 14.7885, "too_noisy", 0.0455),
+        "on-the-line": ([0.125, 0.375], 0.03125, 16.0, "ok", 0.0),
+        # the check's note at PR 34: 0.196869 ms of a bound of 0.338064
+        "solar-at-pr-34":
+            ([0.196869, 0.196869], 0.02, 16.9032, "too_noisy", 0.1647),
+    }[case]
+    got = measure.admission(units, bound, median)
+    assert got["verdict"] == verdict
+    assert got["mean"] == pytest.approx(sum(units) / 2)
+    assert got["half_bound"] == pytest.approx(0.5 * bound * median)
+    assert got["over_by"] == pytest.approx(over, abs=1e-3)
+
+
+@pytest.mark.parametrize("verdict", ["ok", "too_noisy"])
+def test_measure_prints_the_admission_line(verdict, tmp_path, monkeypatch,
+                                           capsys):
+    """Two sets of six through `measure.main`, the runs canned: a line a
+    cell and bounded metric, the same fields in `summary.json`, and none
+    for `setup_s`, which the check judges by its medians."""
+    from benchmarks import measure
+
+    cell = "solar-open2-250b-ep8-d4.serve-decode-r64"
+    wide = 0.5 if verdict == "too_noisy" else 0.05
+    runs = iter([17.0 + wide * d for d in (-.5, -.3, -.1, .1, .3, 3.0)] * 2)
+
+    def one_run(workload, seed, seconds, trace, log):
+        value = next(runs)
+        return {"rc": 0, "wall_s": 1.0, "result": {
+            "correct": True, "metrics": {
+                "itl_p50_ms": {"value": value, "unit": "ms"},
+                "setup_s": {"value": 40.0 + value, "unit": "s"}}}}
+
+    monkeypatch.setattr(measure, "one_run", one_run)
+    assert measure.main(["--workload", cell, "--sets", "2", "--runs", "6",
+                         "--out", str(tmp_path)]) == 0
+    lines = [ln.split() for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("ADMISSION")]
+    # the far run (3.0) is left out of each set: quartiles -0.4 and 0.2
+    mean, half = 0.6 * wide, 0.5 * 0.02 * 17.0
+    assert lines == [["ADMISSION", cell, "itl_p50_ms", f"mean={mean:.6g}",
+                      f"half_bound={half:.6g}", verdict]]
+    doc = json.load(open(tmp_path / "summary.json"))[cell]
+    assert set(doc["admission"]) == {"itl_p50_ms"}
+    assert doc["admission"]["itl_p50_ms"]["verdict"] == verdict
+    for one in doc["sets"]:
+        got = one["itl_p50_ms"]
+        assert got["trimmed_unit"] == pytest.approx(mean)
+        assert got["trimmed"] == pytest.approx(mean / got["median"])
+        assert got["spread"] > got["trimmed"] and got["n"] == 6
 
 
 # -- seed-invariant traffic ------------------------------------------------
@@ -765,24 +986,26 @@ def _files(top):
             for f in fs}
 
 
-def _neox_arrives(cfg, why, tmp_path, monkeypatch, capsys):
-    """Adds the GPT-NeoX fixture (as `cfg` has it) to a tiny root as files
-    and entries, validates it as it is and cut to its own small model, and
-    serves its cell at tiny size on the CPU. Returns the result, the served
-    log-probabilities' distance from the reference, the run's output, the
-    root and its files as they were before."""
+def _arrives(name, cfg, like, why, tmp_path, monkeypatch, capsys,
+             reference=None):
+    """Adds a configuration (as `cfg` has it) to a tiny root as files and
+    entries, with its plain reference where the benchmark has none for its
+    family, validates it as it is and cut to its own small model, and serves
+    its cell (under the traffic and metrics of the cell `like`) at tiny size
+    on the CPU. Returns the result, the served log-probabilities' distance
+    from the reference, the run's output, the root and its files as they
+    were before."""
     root = bench_tiny.make_root(str(tmp_path))
     before = {rel: open(path, "rb").read()
               for rel, path in _files(root).items()}
-    shutil.copy(os.path.join(HERE, "fixtures", "references", "gptneox.py"),
-                os.path.join(root, "benchmarks", "references"))
-    cell = _add_config_and_cell(root, "gptneox-20b", cfg,
-                                "opt-1.3b.serve-decode", why)
-    # the file holds together as the fixture has it, and again cut to its
-    # own small model
+    if reference:
+        shutil.copy(os.path.join(HERE, "fixtures", "references", reference),
+                    os.path.join(root, "benchmarks", "references"))
+    cell = _add_config_and_cell(root, name, cfg, like, why)
+    # the file holds together as it is, and again cut to its own small model
     spec = spec_mod.Spec(root)
     for as_run in (cfg, bench_tiny.tiny_config(cfg)):
-        json.dump(as_run, open(spec.path("configs", "gptneox-20b.json"), "w"))
+        json.dump(as_run, open(spec.path("configs", f"{name}.json"), "w"))
         spec.validate()
     monkeypatch.setitem(device.TARGET, "platform", "cpu")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
@@ -798,7 +1021,16 @@ def _neox_arrives(cfg, why, tmp_path, monkeypatch, capsys):
     return result, diff, out, root, before
 
 
-def _only_files_were_added(root, before):
+def _neox_arrives(cfg, why, tmp_path, monkeypatch, capsys):
+    return _arrives("gptneox-20b", cfg, "opt-1.3b.serve-decode", why,
+                    tmp_path, monkeypatch, capsys, reference="gptneox.py")
+
+
+NEOX_FILES = {"benchmarks/configs/gptneox-20b.json",
+              "benchmarks/references/gptneox.py"}
+
+
+def _only_files_were_added(root, before, files=NEOX_FILES):
     """The harness that ran is the repo's own, and what was copied beside it
     is byte for byte what it was."""
     after = _files(root)
@@ -806,8 +1038,7 @@ def _only_files_were_added(root, before):
                for rel, data in before.items() if rel != "BENCHMARK.json")
     added = {rel for rel in set(after) - set(before)
              if not rel.startswith(".jax_cache")}
-    assert added == {"benchmarks/configs/gptneox-20b.json",
-                     "benchmarks/references/gptneox.py"}
+    assert added == files
     repo = _files(SPEC.bench_dir)
     for sub in ("references", "reducers", "layer_metrics"):
         for rel, path in _files(os.path.join(root, "benchmarks", sub)).items():
@@ -853,33 +1084,79 @@ def test_new_family_arrives_as_files(rope_base, tmp_path, monkeypatch,
     _only_files_were_added(root, before)
 
 
-def test_a_sliced_vocabulary_arrives_as_files(tmp_path, monkeypatch, capsys):
+def _solar_over_16(cfg):
+    """Solar's own file as one of SIXTEEN chips that share each layer would
+    run it: 20 of the 320 experts here, and the vocabulary still in eighths
+    (each slice on two of the sixteen), which only the object form can say.
+    The small model: a router of 8 x 16 outputs over 8 experts held."""
+    cfg["share"] = {
+        "chips": 16, "divided": {"n_routed_experts": 16, "vocab_size": 8},
+        "how": "16 chips share each layer: its 320 routed experts 16 ways "
+               "(20 here), the vocabulary 8 ways, each slice on 2 of the "
+               "16; everything else whole on every chip"}
+    cfg["n_routed_experts"] = cfg["model"]["overrides"][
+        "moe_experts_held"] = 20
+    cfg["reduced"]["n_routed_experts"] = "320 -> 20 held, the router whole"
+    cfg["tiny"]["overrides"].update(moe_num_experts=128, moe_experts_held=8)
+    return cfg
+
+
+@pytest.mark.parametrize("what", ["gptneox-vocabulary-over-8",
+                                  "solar-experts-16-ways-vocabulary-8"])
+def test_a_sliced_vocabulary_arrives_as_files(what, tmp_path, monkeypatch,
+                                              capsys):
     """The same family as one of 8 chips that divide the vocabulary among
     them (the guide's third cut, where it needs nothing of the program): the
     file states the deployment under `share`, runs 50432 / 8 rows as a
     smaller vocabulary, and lists the key under `reduced`. The traffic draws
     its ids from the slice, the served tokens are checked against it and the
     reference, which reads the slice from the parameters' shapes, agrees:
-    files and entries only, as for any family."""
-    cfg = _neox_fixture()
-    cfg["model"]["overrides"]["vocab_size"] = 50432 // 8
-    cfg["reduced"]["vocab_size"] = "50432 -> 6304: an eighth"
-    cfg["share"] = {"chips": 8, "divided": ["vocab_size"],
-                    "how": "8 chips divide the embedding's and the head's "
-                           "rows; every layer whole on each"}
-    cfg["tiny"]["overrides"]["num_layers"] = 4      # a share's floor
-    result, diff, out, root, before = _neox_arrives(
-        cfg, "an eighth of the vocabulary, every layer whole: attention "
-        "sees more than its share of the batch", tmp_path, monkeypatch,
-        capsys)
+    files and entries only, as for any family.
+
+    The second case is a share with TWO divisors rehearsed end to end: the
+    Solar program's small model with its experts divided 16 ways (8 of 128
+    held, the router whole) and its vocabulary 8 ways, one configuration
+    file and entries."""
+    if what.startswith("solar"):
+        like = "solar-open2-250b-ep8-d4.serve-decode-r64"
+        name, reference = "solar-open2-250b-ep16-d4", None
+        cfg = _solar_over_16(copy.deepcopy(SPEC.cell(like).config))
+        why = ("one of 16 chips that share each layer, the vocabulary in "
+               "eighths: attention and the recurrent layers see 16 times "
+               "their share of the batch")
+        ways, held = {"n_routed_experts": 16, "vocab_size": 8}, 8
+        files = {f"benchmarks/configs/{name}.json"}
+    else:
+        like, name, reference = ("opt-1.3b.serve-decode", "gptneox-20b",
+                                 "gptneox.py")
+        cfg = _neox_fixture()
+        cfg["model"]["overrides"]["vocab_size"] = 50432 // 8
+        cfg["reduced"]["vocab_size"] = "50432 -> 6304: an eighth"
+        cfg["share"] = {"chips": 8, "divided": ["vocab_size"],
+                        "how": "8 chips divide the embedding's and the "
+                               "head's rows; every layer whole on each"}
+        cfg["tiny"]["overrides"]["num_layers"] = 4      # a share's floor
+        why = ("an eighth of the vocabulary, every layer whole: attention "
+               "sees more than its share of the batch")
+        ways, held, files = {"vocab_size": 8}, None, NEOX_FILES
+    result, diff, out, root, before = _arrives(
+        name, cfg, like, why, tmp_path, monkeypatch, capsys,
+        reference=reference)
     assert result["correct"], out
     assert diff <= 1e-4
-    spec = spec_mod.Spec(root)
-    as_run = spec.cell("gptneox-20b.serve-decode").config
+    traffic_name = next(w["traffic"] for w in DOC["workloads"]
+                        if w["name"] == like)
+    as_run = spec_mod.Spec(root).cell(f"{name}.{traffic_name}").config
+    assert spec_mod.share_ways(as_run) == ways
+    # `published` holds the WHOLE counts: what is run times each key's ways
     assert as_run["model"]["overrides"]["vocab_size"] == 256
     assert as_run["published"]["vocab_size"] == 256 * 8
-    assert as_run["share"]["chips"] == 8
-    _only_files_were_added(root, before)
+    if held:
+        over = as_run["model"]["overrides"]
+        assert over["moe_experts_held"] == held
+        assert over["moe_num_experts"] == as_run["published"][
+            "n_routed_experts"] == held * ways["n_routed_experts"]
+    _only_files_were_added(root, before, files)
 
 
 # -- no chip, no result ----------------------------------------------------
