@@ -31,8 +31,8 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from . import stats, traffic as traffic_mod
-from .program import (build_model, program_seed, reference_args,
-                      reference_module)
+from .program import (build_model, place_held_experts, program_seed,
+                      reference_args, reference_module)
 
 
 # some tens of decode iterations, and as a rule a prefill chunk or two
@@ -215,6 +215,12 @@ def run(cell, seed: int, seconds: float, tracer, devs, counter,
         model=model, serving_config=serving_config(cell, devs)(cfg),
         config=InferenceConfig(dtype=dtype, seed=program_seed(seed)))
     setup.mark("engine")
+    # every serving program is called with `engine.params` as it then reads
+    placed = place_held_experts(cell, serving.engine.params, seed,
+                                cfg.vocab_size)
+    if placed is not None:
+        serving.engine.params = placed
+        setup.mark("placement")
     warm_s = float(t["warm_loop_s"])
     load = Load(serving, t, seed, cfg.vocab_size,
                 horizon_s=warm_s + seconds + 600.0)

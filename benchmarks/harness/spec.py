@@ -14,6 +14,7 @@ of the contract that a run on the CPU can check.
 
 from __future__ import annotations
 
+import ast
 import dataclasses
 import json
 import os
@@ -39,6 +40,11 @@ COUNT_WORDS = ("heads", "n_head", "vocab_size", "experts")
 DEPTH_WORDS = ("layer",)
 TINY_KEYS = {"preset", "dtype", "overrides", "reference_args"}
 SHARE_KEYS = {"chips", "divided", "how"}
+# optional in a share that divides an expert count, `"placement":
+# "balanced"`: the held experts PLACED in balance before the first request
+# (`program.place_held_experts`); what a family's reference then supplies
+PLACEMENT = "balanced"
+PLACES = "place_held_experts"
 # the floors of the `model-configs` guide, section 4, for a chip's share
 MIN_EXPERTS_HELD = 8
 MAX_WAYS_OVER_A_VOCABULARY = 8
@@ -82,6 +88,17 @@ def share_ways(cfg: Dict[str, Any]) -> Dict[str, int]:
 
 class SpecError(ValueError):
     """BENCHMARK.json or a file it names breaks the contract."""
+
+
+def expert_ways(cfg: Dict[str, Any]) -> int:
+    """The ways the file's `share` divides its experts (the one divided key
+    that counts experts): what a placement of the held experts reads."""
+    ways = [w for key, w in share_ways(cfg).items() if "experts" in key]
+    if len(ways) != 1:
+        raise SpecError(f"share.divided names {len(ways)} counts of "
+                        "experts; a placement of the held experts needs "
+                        "one")
+    return ways[0]
 
 
 def _load(path: str) -> Any:
@@ -164,9 +181,11 @@ class Spec:
         if "share" not in cfg:
             return {}
         share, published = cfg["share"], cfg["published"]
-        if not isinstance(share, dict) or set(share) != SHARE_KEYS:
+        if (not isinstance(share, dict)
+                or set(share) - {"placement"} != SHARE_KEYS):
             has = sorted(share) if isinstance(share, dict) else share
-            raise bad(f"share has keys {has!r}, not {sorted(SHARE_KEYS)}")
+            raise bad(f"share has keys {has!r}, not {sorted(SHARE_KEYS)} "
+                      "(and, where it divides experts, 'placement')")
         chips, divided = share["chips"], share["divided"]
         if isinstance(chips, bool) or not isinstance(chips, int) or chips < 2:
             raise bad(f"share.chips is {chips!r}: a share is of 2 chips or "
@@ -229,6 +248,27 @@ class Spec:
                           "chips share the layer)")
         return held
 
+    def _validate_placement(self, cfg: Dict[str, Any], bad) -> None:
+        """`share.placement`: the held experts placed in balance on the
+        harness's calibration batch from the seed. Only a share that divides
+        ONE count of experts can state it, only as `"balanced"`, and only
+        where the family's reference has the function that places."""
+        try:
+            expert_ways(cfg)
+        except SpecError as e:
+            raise bad(f"share.placement: {e}") from None
+        placement = cfg["share"]["placement"]
+        if placement != PLACEMENT:
+            raise bad(f"share.placement is {placement!r}: the one placement "
+                      f"is {PLACEMENT!r} (without the key the held experts "
+                      "are the router's first)")
+        with open(self.path("references", f"{cfg['reference']}.py")) as f:
+            defined = {node.name for node in ast.parse(f.read()).body
+                       if isinstance(node, ast.FunctionDef)}
+        if PLACES not in defined:
+            raise bad(f"share.placement, and references/{cfg['reference']}"
+                      f".py has no {PLACES}")
+
     def _validate_config(self, entry: Dict[str, Any]) -> None:
         """What a configuration's file says of its family holds together:
         no size is written that the source does not have, and the program
@@ -254,6 +294,8 @@ class Spec:
         if set(cfg.get("reduced", {})) != set(entry["reduced"]):
             raise bad("'reduced' differs from BENCHMARK.json's")
         held = self._validate_share(cfg, entry["reduced"], bad)
+        if "placement" in cfg.get("share", {}):
+            self._validate_placement(cfg, bad)
         ways = share_ways(cfg)
         for key in entry["reduced"]:
             if ((key in widths.values() or key in equal)

@@ -82,6 +82,16 @@ def prompt_ids(seed: int, index: int, length: int, vocab: int) -> np.ndarray:
     return rng.randint(0, vocab, length).astype(np.int32)
 
 
+def calibration_ids(seed: int, sequences: int, length: int, vocab: int
+                    ) -> np.ndarray:
+    """The batch a balanced placement of held experts is counted on
+    (`program.place_held_experts`): `sequences` rows of `length` ids, drawn
+    from the seed apart from every request's ids and from the order the
+    requests are sent in."""
+    rng = np.random.RandomState(seed32(seed, salt=-2))
+    return rng.randint(0, vocab, (sequences, length)).astype(np.int32)
+
+
 def train_batches(seed: int, n_batches: int, rows: int, seq: int,
                   vocab: int) -> List[np.ndarray]:
     """`n_batches` host arrays of shape (1, rows, seq): the small set the

@@ -35,6 +35,16 @@ outputs, they are the router's FIRST ones (one chip of a deployment whose
 chips divide each layer's experts): choice and weights are over all outputs
 as published, the sum runs over the chosen experts that are held, and what
 the absent ones would add is left out. The share is read from the shapes.
+**A share placed** (`place_held_experts`; the configuration's
+`share.placement`): WHICH of the router's outputs come first is a labelling
+of one draw of exchangeable experts, and the harness relabels them so that
+a chip carries its part of the load. The policy is the harness's, handed in
+as `order` (`harness/program.py` `balanced_order`); this file supplies the
+walk: layer by layer from the first, on a calibration batch, count the
+assignments to each of the router's outputs, ask `order` where each output
+shall stand, and reorder them so; this layer's result, with the outputs so
+reordered and the absent experts' part left out, is the next layer's input.
+Only the columns of `router` and the entries of `router_bias` move.
 
 Straightforward `jax.numpy` in float32: no kernels, no cache, no dispatch
 (every held expert is computed for every token, one expert's float32 copy
@@ -157,13 +167,28 @@ def _experts(layer, stacks, h, top_k, norm_topk_prob, scale, shared,
     return out, chosen
 
 
+def _kind(i, gqa_interval):
+    """Layer `i` is a softmax layer at the head of every period."""
+    return "attn" if i % (gqa_interval + 1) == 0 else "kda"
+
+
+def _assignments(chosen, E):
+    """(..., k) chosen outputs -> (E,) how many times each was chosen."""
+    return jnp.zeros((E,), jnp.int32).at[chosen.reshape(-1)].add(1)
+
+
 def _forward(params, input_ids, *, num_heads, head_dim, num_experts_per_tok,
              rms_norm_eps, norm_topk_prob, routed_scaling_factor,
              gqa_interval, use_gqa_gate, kda_allow_neg_eigval, use_rope,
              beta_scale=None, state_dtype=jnp.float32, decay=True, conv=True,
-             shared=True, renorm_over_held=False, mantissa_bits=None):
+             shared=True, renorm_over_held=False, mantissa_bits=None,
+             order=None):
     """(B, S) int ids -> ((B, S, V) float32 logits, (L, B, S, k) the experts
-    each layer's router chose, of all its outputs).
+    each layer's router chose, of all its outputs, (L, E) each layer's
+    outputs in the order `order` gave them: as they were without it).
+    `order(load, held)`: `(E,)` this layer's assignments to each output ->
+    `(E,)` the outputs in the order that they shall stand in, the first
+    `held` this chip's.
 
     `mantissa_bits` (a control: the model in the precision below the one it
     is served in): every matrix and every layer's normed input rounded to
@@ -183,9 +208,9 @@ def _forward(params, input_ids, *, num_heads, head_dim, num_experts_per_tok,
     stacks = params["layers"]
     depth = sum(t["ln1"]["scale"].shape[0] for t in stacks.values())
     count = {"attn": 0, "kda": 0}
-    routed = []
+    routed, orders = [], []
     for i in range(depth):
-        kind = "attn" if i % (gqa_interval + 1) == 0 else "kda"
+        kind = _kind(i, gqa_interval)
         mine = jax.tree.map(lambda a: a[count[kind]], stacks[kind])
         count[kind] += 1
         experts = mine.pop("mlp")
@@ -198,6 +223,17 @@ def _forward(params, input_ids, *, num_heads, head_dim, num_experts_per_tok,
             x = x + _linear_layer(layer["kda"], h, rms_norm_eps, beta_scale,
                                   state_dtype, decay, conv)
         h = low(_rms_norm(x, layer["ln2"]["scale"], rms_norm_eps))
+        E = layer["router"].shape[-1]
+        stands = jnp.arange(E)
+        if order is not None:
+            _, chosen = jax.lax.top_k(
+                jax.nn.sigmoid(h @ layer["router"]) + layer["router_bias"],
+                num_experts_per_tok)
+            stands = order(_assignments(chosen, E),
+                           experts["w_up"].shape[0])
+            layer = dict(layer, router=layer["router"][:, stands],
+                         router_bias=layer["router_bias"][stands])
+        orders.append(stands)
         out, chosen = _experts(layer, experts, h, num_experts_per_tok,
                                norm_topk_prob, routed_scaling_factor, shared,
                                renorm_over_held, low)
@@ -206,7 +242,7 @@ def _forward(params, input_ids, *, num_heads, head_dim, num_experts_per_tok,
     x = _rms_norm(x, params["final_norm"]["scale"].astype(jnp.float32),
                   rms_norm_eps)
     return (low(x) @ low(params["lm_head"].astype(jnp.float32)),
-            jnp.stack(routed))
+            jnp.stack(routed), jnp.stack(orders))
 
 
 def logits(params, input_ids, **reference_args):
@@ -218,6 +254,30 @@ def router_choices(params, input_ids, **reference_args):
     """(B, S) -> (L, B, S, k): the experts every layer's router chose, for
     counting how often a lower precision chooses another set."""
     return _forward(params, input_ids, **reference_args)[1]
+
+
+def place_held_experts(params, input_ids, order, **reference_args):
+    """(B, S) calibration ids and the harness's policy `order(load, held)`
+    (`_forward`) -> the leaves of `params` that the placement reorders, as a
+    tree of `params`' own shape holding those leaves alone (each layer's
+    `router` columns and `router_bias` entries, in the program's dtypes),
+    and `(L, E)` the calibration batch's assignments to each output in its
+    NEW place (the first `held` of a row are this chip's)."""
+    _, routed, orders = _forward(params, input_ids, order=order,
+                                 **reference_args)
+    L, E = orders.shape
+    load = jax.vmap(lambda chosen: _assignments(chosen, E))(routed)
+    moved = {}
+    for kind, stack in params["layers"].items():
+        mine = orders[jnp.array([
+            i for i in range(L)
+            if _kind(i, reference_args["gqa_interval"]) == kind])]
+        moved[kind] = {
+            "router": jnp.take_along_axis(stack["router"], mine[:, None],
+                                          axis=2),
+            "router_bias": jnp.take_along_axis(stack["router_bias"], mine,
+                                               axis=1)}
+    return {"layers": moved}, load
 
 
 def next_token_logprobs(params, input_ids, **reference_args):

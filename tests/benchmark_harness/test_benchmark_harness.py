@@ -271,6 +271,25 @@ def _break_share(cfg, entry, how):
     if how == "share-with-another-key":
         share["layers_elsewhere"] = 4
         return "share has keys"
+    if how.startswith("placement-"):
+        # OLMoE's reference has no `place_held_experts`: each case is refused
+        # at the first rule it breaks (the experts' ways, the word, the
+        # reference's function, in that order)
+        share["placement"] = "balanced"
+        if how == "placement-where-no-experts-are-divided":
+            _undivide(cfg, entry, "num_experts")
+            return "share.placement: share.divided names 0 counts of experts"
+        if how == "placement-not-balanced":
+            share["placement"] = "popular"
+            return "share.placement is 'popular': the one placement is " \
+                   "'balanced'"
+        if how == "placement-an-object":
+            share["placement"] = {"experts": "balanced",
+                                  "calibration_tokens": 4096}
+            return "the one placement is 'balanced'"
+        if how == "placement-the-reference-cannot-make":
+            return "share.placement, and references/olmoe.py has no " \
+                   "place_held_experts"
     # the object form: the experts over all 8 chips, the vocabulary 4 ways
     # (each quarter on two of the eight), sound before it is broken
     share["divided"] = {"num_experts": 8, "vocab_size": 4}
@@ -302,7 +321,10 @@ BROKEN_SHARES = ["share-remainder", "share-vocabulary-over-16-chips",
                  "share-ways-do-not-divide-the-chips",
                  "share-widest-ways-under-the-chips",
                  "share-override-the-chips-part-not-the-keys",
-                 "share-ways-held-by-no-key"]
+                 "share-ways-held-by-no-key",
+                 "placement-where-no-experts-are-divided",
+                 "placement-not-balanced", "placement-an-object",
+                 "placement-the-reference-cannot-make"]
 BROKEN_DOCS = ["bad-name", "unknown-moves", "moves-not-reported",
                "width-reduced", "two-four-chip-cells", "no-setup",
                "loose-bound", "extra-key"]
@@ -711,7 +733,9 @@ def test_measure_prints_the_admission_line(verdict, tmp_path, monkeypatch,
     from benchmarks import measure
 
     cell = "solar-open2-250b-ep8-d4.serve-decode-r64"
-    wide = 0.5 if verdict == "too_noisy" else 0.05
+    bound = next(m["bound"] for m in DOC["end_to_end"]
+                 if m["name"] == "itl_p50_ms")      # whatever the file has
+    wide = bound * 17.0 * (1.5 if verdict == "too_noisy" else 0.15)
     runs = iter([17.0 + wide * d for d in (-.5, -.3, -.1, .1, .3, 3.0)] * 2)
 
     def one_run(workload, seed, seconds, trace, log):
@@ -727,7 +751,7 @@ def test_measure_prints_the_admission_line(verdict, tmp_path, monkeypatch,
     lines = [ln.split() for ln in capsys.readouterr().out.splitlines()
              if ln.startswith("ADMISSION")]
     # the far run (3.0) is left out of each set: quartiles -0.4 and 0.2
-    mean, half = 0.6 * wide, 0.5 * 0.02 * 17.0
+    mean, half = 0.6 * wide, 0.5 * bound * 17.0
     assert lines == [["ADMISSION", cell, "itl_p50_ms", f"mean={mean:.6g}",
                       f"half_bound={half:.6g}", verdict]]
     doc = json.load(open(tmp_path / "summary.json"))[cell]
