@@ -956,6 +956,13 @@ class ServingEngine:
                 np.asarray([r.sampling.top_p for r in reqs], np.float32),
                 np.asarray([r.seed for r in reqs], np.int32))
 
+    @staticmethod
+    def _sampled_rows(reqs) -> int:
+        """Rows of a step with ``temperature > 0``: at 0 the program's
+        sampler took the argmax and sorted nothing
+        (``paged_kv.sample_rows``)."""
+        return sum(r.sampling.temperature > 0 for r in reqs)
+
     def _make_writable(self, req: Request, start: int, end: int,
                        optional: bool = False) -> bool:
         """Copy-on-write: every block covering write positions
@@ -1046,7 +1053,8 @@ class ServingEngine:
         start = req.prefill_pos
         n_valid = min(C, int(src.size) - start)
         with obs.span("serving/prefill_chunk", rid=req.rid,
-                      chunk_start=int(start)) as span:
+                      chunk_start=int(start),
+                      sampled_rows=self._sampled_rows([req])) as span:
             with obs.span("serving/prefill_chunk/prepare", category="phase"):
                 if not self.sched.ensure_blocks(req, start + n_valid):
                     return False    # pool dry, nothing evictable — wait
@@ -1259,7 +1267,8 @@ class ServingEngine:
             with obs.span("serving/decode/prepare", category="phase"):
                 ready = self._ready_decode_rows(dec)
                 operands = self._decode_operands(ready) if ready else ()
-            span.annotate(rows=len(ready))
+            span.annotate(rows=len(ready),
+                          sampled_rows=self._sampled_rows(ready))
             if not ready:
                 return False
             rt = obs.reqtrace
@@ -1382,7 +1391,8 @@ class ServingEngine:
             steps[row] = len(r.generated)   # first output-token index of
             #   this dispatch — position j samples index steps+j, the
             #   exact key the non-speculative path uses
-        span.annotate(rows=len(plan), tokens=int(n_valid.sum()))
+        span.annotate(rows=len(plan), tokens=int(n_valid.sum()),
+                      sampled_rows=self._sampled_rows(r for r, _ in plan))
         rt = obs.reqtrace
         acct = self._serve_acct
         first_trace = (next((r.trace for r, _ in plan

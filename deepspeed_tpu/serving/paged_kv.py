@@ -26,7 +26,11 @@ pool of fixed-size **blocks** (vLLM's PagedAttention, Kwon et al. SOSP '23):
 * ``sample_rows`` — per-row greedy/temperature/top-k/top-p sampling with
   *array-valued* knobs, so requests with different sampling settings share
   one decode program. The greedy path is bit-identical to
-  ``inference/engine._sample`` at ``temperature=0``.
+  ``inference/engine._sample`` at ``temperature=0``. A ``lax.cond`` on the
+  rows' temperatures decides on the device what runs: a step whose rows
+  are all greedy takes the argmax and sorts nothing, a step with a sampled
+  row sorts the vocabulary once (``vmap`` over it would defeat the
+  ``cond`` and run both branches).
 
 The model-side write/read lives in ``models/transformer._layer_forward``
 (paged branch): the layout is left-aligned — token at position ``p`` sits in
@@ -334,37 +338,59 @@ def sample_rows(logits: jax.Array, base_key: jax.Array,
                 seeds: jax.Array, steps: jax.Array) -> jax.Array:
     """Per-row sampling with array-valued knobs: ``logits`` (R, V);
     ``temperature``/``top_p`` (R,) float32; ``top_k`` (R,) int32 (0 = off).
-    Rows with ``temperature <= 0`` take the greedy branch — the same
+    Rows with ``temperature <= 0`` take the greedy token — the same
     fp32 argmax as ``inference/engine._sample``, so serving greedy output
     is bit-identical to offline ``generate()``.
+
+    The work follows what the rows ask, decided on the device under ONE
+    ``lax.cond`` on ``any(temperature > 0)``: a batch with no sampled row
+    (empty rows carry temperature 0) runs the argmax and nothing else; a
+    batch with a sampled row also runs the divide, ONE sort of the
+    vocabulary, the softmax, the cumulative sum and the categorical draw,
+    and its greedy rows still keep the argmax. The predicate is a
+    replicated scalar under a sharded vocabulary. Under ``vmap`` a ``cond``
+    lowers to a ``select`` that computes both branches: nobody maps this
+    function, and whoever does pays for the sort in every batch again.
 
     Each row draws from ``fold_in(fold_in(base_key, seeds[r]), steps[r])``
     — ``seeds`` the request's sampling seed, ``steps`` its output-token
     index — so a request's stream depends only on (engine seed, request
-    seed, token index), NOT on how the scheduler batched it: reproducible
-    across runs and bit-stable across preemption/recompute."""
-    logits = logits.astype(jnp.float32)
+    seed, token index), NOT on how the scheduler batched it or on which
+    branch its neighbours sent the batch down: reproducible across runs and
+    bit-stable across preemption/recompute."""
     V = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    # top-k: keep scores >= the k-th largest (per row, traced k)
-    desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(
-        desc, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=1)
-    scaled = jnp.where((top_k[:, None] > 0) & (scaled < kth),
-                       -jnp.inf, scaled)
-    # top-p over the (possibly top-k-filtered) scores; top-1 always survives
-    desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs < top_p[:, None]).at[:, 0].set(True)
-    cutoff = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1, keepdims=True)
-    scaled = jnp.where(scaled >= cutoff, scaled, -jnp.inf)
-    keys = jax.vmap(
-        lambda s, t: jax.random.fold_in(jax.random.fold_in(base_key, s), t)
-    )(seeds, steps)
-    sampled = jax.vmap(jax.random.categorical)(keys, scaled).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+    greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+
+    def sample():
+        # the float32 copy of the logits is the branch's own: made before
+        # the cond it would be written out in every greedy step too
+        scaled = (logits.astype(jnp.float32)
+                  / jnp.maximum(temperature, 1e-6)[:, None])
+        # top-k: keep scores >= the k-th largest (per row, traced k). The
+        # filter is monotone, so applied to the sorted row it gives the
+        # sorted row of the filtered scores: one sort serves top-k and top-p
+        desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+        kth = jnp.take_along_axis(
+            desc, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=1)
+        on = top_k[:, None] > 0
+        scaled = jnp.where(on & (scaled < kth), -jnp.inf, scaled)
+        desc = jnp.where(on & (desc < kth), -jnp.inf, desc)
+        # top-p over the (possibly top-k-filtered) scores; top-1 always
+        # survives
+        probs = jax.nn.softmax(desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (cum - probs < top_p[:, None]).at[:, 0].set(True)
+        cutoff = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1,
+                         keepdims=True)
+        scaled = jnp.where(scaled >= cutoff, scaled, -jnp.inf)
+        keys = jax.vmap(
+            lambda s, t: jax.random.fold_in(jax.random.fold_in(base_key, s),
+                                            t))(seeds, steps)
+        sampled = jax.vmap(jax.random.categorical)(keys, scaled)
+        return jnp.where(temperature <= 0.0, greedy,
+                         sampled.astype(jnp.int32))
+
+    return jax.lax.cond(jnp.any(temperature > 0.0), sample, lambda: greedy)
 
 
 # ---------------------------------------------------------------------------
