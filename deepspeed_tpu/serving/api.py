@@ -58,6 +58,30 @@ __all__ = ["ServingEngine", "init_serving"]
 # for a model with recurrent layers: a second or so of one-row steps
 _SCORE_STEP_TAIL = 64
 
+# a program whose jitted call, or whose wait for its tokens, lasted this
+# long held the whole engine: the longest iteration the benchmark's cells
+# know is 25 ms, and the freezes seen on the chip lasted 2-15 s
+# (``ServingEngine.holds``)
+HOLD_SECONDS = 0.5
+
+
+def _host_operands(call_args) -> Dict[str, int]:
+    """The counts ``<name>/dispatch`` carries: the arguments of the jitted
+    call, at any depth, that are host values (numpy arrays and scalars,
+    Python numbers), each of which the call transfers to the device before
+    it can enqueue, and their bytes (a Python number at numpy's width)."""
+    import jax
+
+    n = nbytes = 0
+    for leaf in jax.tree_util.tree_leaves(call_args):
+        if isinstance(leaf, (np.ndarray, np.generic)):
+            n += 1
+            nbytes += leaf.nbytes
+        elif isinstance(leaf, (bool, int, float, complex)):
+            n += 1
+            nbytes += np.asarray(leaf).nbytes
+    return {"host_operands": n, "host_operand_bytes": nbytes}
+
 
 def _percentile(samples: List[float], q: float) -> float:
     xs = sorted(samples)
@@ -240,6 +264,10 @@ class ServingEngine:
         # counts) is drawn up: behind its first enqueue when deferring, else
         # at its end
         self._unaccounted = None
+        # programs that held the engine (``HOLD_SECONDS``), cumulative, and
+        # each program's compiles so far: a call that compiled is no hold
+        self.holds = 0
+        self._compiles: Dict[str, int] = {}
         self._started_s = clock()
         # fleet seam (serving/fleet): called with the request right after
         # its LAST prefill chunk completed and the first token was emitted,
@@ -871,17 +899,18 @@ class ServingEngine:
         self._unaccounted = None
         with obs.span("serving/publish"):
             self._publish_iteration()
-        if span.recording:
-            span.annotate(
-                it=self._iterations, queued=self.sched.queue_depth(),
-                running=len(self.sched.running),
-                blocks_in_use=self.alloc.blocks_in_use,
-                blocks_running=sum(
-                    len(r.blocks) for r in self.sched.running.values()),
-                blocks_total=self.alloc.capacity,
-                preemptions=self.sched.preemption_count,
-                **self._state_counts(),
-                **hbm_counts())
+            if span.recording:
+                span.annotate(
+                    it=self._iterations, queued=self.sched.queue_depth(),
+                    running=len(self.sched.running),
+                    blocks_in_use=self.alloc.blocks_in_use,
+                    blocks_running=sum(
+                        len(r.blocks) for r in self.sched.running.values()),
+                    blocks_total=self.alloc.capacity,
+                    preemptions=self.sched.preemption_count,
+                    holds=self.holds,
+                    **self._state_counts(),
+                    **hbm_counts())
 
     def _expire_deadlines(self) -> int:
         """Deadline enforcement at decode time: a request whose absolute
@@ -996,27 +1025,70 @@ class ServingEngine:
             self._cow_copies += 1
         return True
 
-    def _run_program(self, obs, name: str, program, *args):
+    def _run_program(self, obs, name: str, program, *args, trace=None):
         """Dispatch one jitted program over the arena (its first output the
         sampled tokens, its last the arena) and bring the tokens to the host:
-        ``<name>/dispatch`` is the call, which returns at enqueue, and
-        ``<name>/fetch`` the wait for the tokens (device time + D2H: the
-        iteration's host sync). Between the two the device works and the
-        host has nothing to wait for: an iteration of the driver thread
-        delivers there what the last one applied and kept (``_settle``).
-        ONE pair of readings of the engine's clock around all of it feeds
-        the accountants and the request tracer: returns (tokens, t0, t1).
+        ``<name>/dispatch`` is the call, which returns at enqueue, with the
+        mesh and the request tracer's compile attribution (``trace``: whose
+        dispatch this is) entered and left around it, so that nothing lies
+        unnamed between ``<name>/prepare`` and the call; ``<name>/fetch``
+        is the wait for the tokens (device time + D2H: the iteration's host
+        sync). Between the two the device works and the host has nothing to
+        wait for: an iteration of the driver thread delivers there what the
+        last one applied and kept (``_settle``). THREE readings of the
+        engine's clock: the first and the last feed the accountants and the
+        request tracer (returns (tokens, t0, t1)), and with the one after
+        the call they tell a program that held the engine
+        (``_note_hold``), whether or not anything records; a call that
+        compiled (the jitted ``program``'s call cache grew) is excused.
         The spans stamp themselves, on the profiler's clock, and only while
         they record."""
         t0 = self.clock()
-        with obs.span(name + "/dispatch", category="phase"):
-            tok, *_, self._arena = program(self.engine.params, self._arena,
-                                           *args)
+        with obs.span(name + "/dispatch", category="phase") as span:
+            if span.recording:
+                span.annotate(**_host_operands(
+                    (self.engine.params, self._arena, args)))
+            with self._trace_dispatch(obs.reqtrace, trace):
+                with mesh_mod.ambient(self.engine.mesh):
+                    tok, *_, self._arena = program(
+                        self.engine.params, self._arena, *args)
+        t_call = self.clock()
         if self._deferring:
             self._settle(obs, deferred=True)
         with obs.span(name + "/fetch", category="phase"):
             tok = np.asarray(tok)
-        return tok, t0, self.clock()
+        t1 = self.clock()
+        call_s, fetch_s = t_call - t0, t1 - t_call
+        compiles = program._cache_size()
+        if compiles != self._compiles.get(name):
+            # the call traced and compiled (or read the compile cache): a
+            # program's first, and the chunk program's second, whose arena
+            # is no longer the fresh one. Set-up, not a hold
+            self._compiles[name] = compiles
+            call_s = 0.0
+        if call_s >= HOLD_SECONDS or fetch_s >= HOLD_SECONDS:
+            self._note_hold(obs, name, call_s, fetch_s)
+        return tok, t0, t1
+
+    def _note_hold(self, obs, name: str, call_s: float,
+                   fetch_s: float) -> None:
+        """A program held the engine: its jitted call, or the wait from the
+        call's return to its tokens on the host (with what the driver thread
+        delivers meanwhile), lasted ``HOLD_SECONDS`` or more. Counted on the
+        engine and logged, whether or not anything records; the next
+        ``serving/iteration`` span to settle carries the count."""
+        self.holds += 1
+        held = " and ".join(f"{phase} {secs:.3f} s" for phase, secs in
+                            (("call", call_s), ("fetch", fetch_s))
+                            if secs >= HOLD_SECONDS)
+        logger.warning("serving hold: %s held the engine at iteration %d: "
+                       "%s (%d so far)", name, self._iterations, held,
+                       self.holds)
+        if obs.enabled:
+            obs.registry.counter(
+                "serving/holds",
+                help=f"programs whose call or fetch lasted {HOLD_SECONDS} s "
+                     "or more").inc(program=name)
 
     def _program_counts(self, span, fetched: np.ndarray, n: int,
                     real_rows: int) -> np.ndarray:
@@ -1065,35 +1137,35 @@ class ServingEngine:
                 chunk[0, :n_valid] = src[start:start + n_valid]
                 temps, topks, topps, seeds = self._sampling_arrays([req])
                 table = self._table_for([req])
-            rt = obs.reqtrace
-            with self._trace_dispatch(rt, req.trace):
-                with mesh_mod.ambient(self.engine.mesh):
-                    tok, t0, t1 = self._run_program(
-                        obs, "serving/prefill_chunk", self._prefill, table,
-                        chunk, np.asarray(start, np.int32),
-                        np.asarray(n_valid, np.int32),
-                        temps, topks, topps, seeds, self._base_rng,
-                        *([np.asarray([req.row], np.int32)]
-                          if self._recurrent_layers else []))
-            tok = self._program_counts(span, tok, 1, real_rows=1)
-            if self._serve_acct is not None:
-                self._serve_acct.note_phase("prefill", t1 - t0)
-            if rt is not None and req.trace is not None:
-                rt.interval(req.trace, "prefill", t0, t1,
-                            kind="prefill_chunk", tokens=int(n_valid),
-                            chunk_start=int(start), replica=self.trace_tag)
-            span.annotate(tokens=int(n_valid))   # the chunk ran: a span
-            #   without the count is a chunk the pool could not place
-            self.prefill_chunks_run += 1
-            self.prefill_tokens_run += int(n_valid)
-            req.prefill_pos += n_valid
-            req.length = req.prefill_pos
-            # newly completed full prompt blocks become shareable prefix
-            # cache
-            self.sched.note_prefill_progress(req, start, req.prefill_pos)
-            self.sched.note_service(req, n_valid)
-            if req.prefill_pos == int(src.size):
-                self._finish_prefill(obs, req, int(tok[0]))
+            tok, t0, t1 = self._run_program(
+                obs, "serving/prefill_chunk", self._prefill, table,
+                chunk, np.asarray(start, np.int32),
+                np.asarray(n_valid, np.int32),
+                temps, topks, topps, seeds, self._base_rng,
+                *([np.asarray([req.row], np.int32)]
+                  if self._recurrent_layers else []), trace=req.trace)
+            with obs.span("serving/prefill_chunk/apply", category="phase"):
+                tok = self._program_counts(span, tok, 1, real_rows=1)
+                if self._serve_acct is not None:
+                    self._serve_acct.note_phase("prefill", t1 - t0)
+                rt = obs.reqtrace
+                if rt is not None and req.trace is not None:
+                    rt.interval(req.trace, "prefill", t0, t1,
+                                kind="prefill_chunk", tokens=int(n_valid),
+                                chunk_start=int(start),
+                                replica=self.trace_tag)
+                span.annotate(tokens=int(n_valid))   # the chunk ran: a span
+                #   without the count is a chunk the pool could not place
+                self.prefill_chunks_run += 1
+                self.prefill_tokens_run += int(n_valid)
+                req.prefill_pos += n_valid
+                req.length = req.prefill_pos
+                # newly completed full prompt blocks become shareable prefix
+                # cache
+                self.sched.note_prefill_progress(req, start, req.prefill_pos)
+                self.sched.note_service(req, n_valid)
+                if req.prefill_pos == int(src.size):
+                    self._finish_prefill(obs, req, int(tok[0]))
         return True
 
     def _finish_prefill(self, obs, req: Request, token: int) -> None:
@@ -1267,8 +1339,8 @@ class ServingEngine:
             with obs.span("serving/decode/prepare", category="phase"):
                 ready = self._ready_decode_rows(dec)
                 operands = self._decode_operands(ready) if ready else ()
-            span.annotate(rows=len(ready),
-                          sampled_rows=self._sampled_rows(ready))
+                span.annotate(rows=len(ready),
+                              sampled_rows=self._sampled_rows(ready))
             if not ready:
                 return False
             rt = obs.reqtrace
@@ -1276,28 +1348,28 @@ class ServingEngine:
             first_trace = (next((r.trace for r in ready
                                  if r.trace is not None), None)
                            if rt is not None else None)
-            with self._trace_dispatch(rt, first_trace):
-                with mesh_mod.ambient(self.engine.mesh):
-                    nxt, t0, t1 = self._run_program(
-                        obs, "serving/decode", self._decode, *operands,
-                        self._base_rng)
-            nxt = self._program_counts(span, nxt, self.config.max_seqs,
-                                   real_rows=len(ready))
-            if acct is not None:
-                acct.note_phase("decode", t1 - t0)
-            if rt is not None:
+            nxt, t0, t1 = self._run_program(
+                obs, "serving/decode", self._decode, *operands,
+                self._base_rng, trace=first_trace)
+            with obs.span("serving/decode/apply", category="phase"):
+                nxt = self._program_counts(span, nxt, self.config.max_seqs,
+                                           real_rows=len(ready))
+                if acct is not None:
+                    acct.note_phase("decode", t1 - t0)
+                if rt is not None:
+                    for r in ready:
+                        if r.trace is not None:
+                            rt.note_decode(r.trace, t0, t1,
+                                           batch=len(ready),
+                                           replica=self.trace_tag)
                 for r in ready:
-                    if r.trace is not None:
-                        rt.note_decode(r.trace, t0, t1, batch=len(ready),
-                                       replica=self.trace_tag)
-            for r in ready:
-                r.length += 1
-                self.sched.note_service(r, 1)
-                self._apply(r, int(nxt[r.row]))
-            if not self._deferring:
-                self._flush()
-            if acct is not None:
-                acct.note_phase("sample_host", self.clock() - t1)
+                    r.length += 1
+                    self.sched.note_service(r, 1)
+                    self._apply(r, int(nxt[r.row]))
+                if not self._deferring:
+                    self._flush()
+                if acct is not None:
+                    acct.note_phase("sample_host", self.clock() - t1)
         return True
 
     def _step_verify(self) -> bool:
@@ -1394,16 +1466,22 @@ class ServingEngine:
         span.annotate(rows=len(plan), tokens=int(n_valid.sum()),
                       sampled_rows=self._sampled_rows(r for r, _ in plan))
         rt = obs.reqtrace
-        acct = self._serve_acct
         first_trace = (next((r.trace for r, _ in plan
                              if r.trace is not None), None)
                        if rt is not None else None)
-        with self._trace_dispatch(rt, first_trace):
-            with mesh_mod.ambient(self.engine.mesh):
-                sampled, t0, t1 = self._run_program(
-                    obs, "serving/verify", self._verify, bt, lengths,
-                    tokens, n_valid, temps, topks, topps, seeds, steps,
-                    self._base_rng)
+        sampled, t0, t1 = self._run_program(
+            obs, "serving/verify", self._verify, bt, lengths, tokens,
+            n_valid, temps, topks, topps, seeds, steps, self._base_rng,
+            trace=first_trace)
+        with obs.span("serving/verify/apply", category="phase"):
+            self._accept_verified(rt, plan, sampled, t0, t1)
+        return True
+
+    def _accept_verified(self, rt, plan, sampled, t0, t1) -> None:
+        """A verify step's tokens on the host: acceptance row by row, the
+        accepted tokens applied, the rejected drafts' blocks rolled back,
+        and the delivery (``serving/verify/apply``)."""
+        acct = self._serve_acct
         self._spec_verify_s += t1 - t0
         if acct is not None:
             acct.note_phase("verify", t1 - t0)
@@ -1442,7 +1520,6 @@ class ServingEngine:
         #   drafter runs on the host before the next enqueue
         if acct is not None:
             acct.note_phase("sample_host", self.clock() - t1)
-        return True
 
     def _apply(self, req: Request, token: int, first: bool = False) -> None:
         """One sampled token, as far as the NEXT program's operands depend

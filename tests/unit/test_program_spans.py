@@ -2,8 +2,10 @@
 its call sites in the serving and training engines: a span lies in the
 ``jax.profiler`` capture with its counts, and in the in-memory record with an
 id and its parent's id; nothing records without a session or a capture; the
-serving iteration is covered from the inside; the program's TTFT counts from
-entry to ``submit()``; one clock reading serves each dispatch boundary."""
+serving iteration is covered from the inside, in named leaves from one
+enqueue to the next; the program's TTFT counts from entry to ``submit()``;
+one clock reading serves each dispatch boundary, and a program that held the
+engine is counted with nothing recording."""
 
 import glob
 import os
@@ -22,7 +24,6 @@ from deepspeed_tpu.observability import (configure_observability,
                                          recorded_spans, reset_session)
 from deepspeed_tpu.observability.memory import hbm_counts
 from deepspeed_tpu.observability.spans import NOOP_SPAN, Span, SpanTracer
-from deepspeed_tpu.parallel import mesh as mesh_mod
 from deepspeed_tpu.serving import ServingEngine
 
 
@@ -297,7 +298,8 @@ class TestServingFromTheInside:
         # first enqueue: inside that program's span, before its fetch
         assert kids["serving/decode"] == {
             "serving/decode/prepare", "serving/decode/dispatch",
-            "serving/decode/fetch", "serving/emit", "serving/publish"}
+            "serving/decode/fetch", "serving/decode/apply", "serving/emit",
+            "serving/publish"}
         shadowed = 0
         for s in spans:
             parent = by_id.get(s.get("parent_id"))
@@ -314,8 +316,54 @@ class TestServingFromTheInside:
         assert shadowed >= 8
         assert kids["serving/prefill_chunk"] >= {
             "serving/prefill_chunk/prepare",
-            "serving/prefill_chunk/dispatch", "serving/prefill_chunk/fetch"}
+            "serving/prefill_chunk/dispatch", "serving/prefill_chunk/fetch",
+            "serving/prefill_chunk/apply"}
+        # a prompt's first token is never kept: its delivery runs where it
+        # is applied
+        assert kids["serving/prefill_chunk/apply"] == {"serving/emit"}
         assert kids["serving/submit"] == {"serving/submit/lock_wait"}
+
+    @pytest.mark.parametrize("program", ["serving/decode",
+                                         "serving/prefill_chunk"])
+    def test_the_driver_thread_is_named_from_enqueue_to_enqueue(
+            self, served, program):
+        """Under a program's span the driver thread's time lies in leaves,
+        in this order: ``prepare``, ``dispatch``, (what is delivered and
+        published in the device's shadow,) ``fetch``, ``apply``. The
+        ``dispatch`` span says how many of the call's operands were host
+        arrays."""
+        spans, _, _ = served
+        ran = [s for s in spans if s["name"] == program
+               and s["attrs"].get("rows", s["attrs"].get("tokens"))]
+        assert len(ran) >= 4
+        for parent in ran:
+            kids = sorted((c for c in spans
+                           if c.get("parent_id") == parent["id"]),
+                          key=lambda c: c["start_s"])
+            names = [c["name"].replace(program, "...") for c in kids]
+            shadow = [n for n in names if n.startswith("serving/")]
+            assert names == [".../prepare", ".../dispatch", *shadow,
+                             ".../fetch", ".../apply"]
+            assert set(shadow) <= {"serving/emit", "serving/publish"}
+            for a, b in zip(kids, kids[1:]):
+                assert a["end_s"] <= b["start_s"]
+            assert all(c["cat"] == "phase" for c in kids
+                       if c["name"].startswith(program))
+            disp = kids[1]["attrs"]
+            # every operand but the key is a numpy array today (ROADMAP A8)
+            assert disp["host_operands"] >= 8
+            assert disp["host_operand_bytes"] >= 4 * disp["host_operands"]
+
+    def test_host_operands_reach_the_capture_as_stats(self, tiny_engine,
+                                                      tmp_path):
+        srv = serving(tiny_engine)
+        srv.submit(np.arange(1, 20), max_new_tokens=2)
+        with Capture(tmp_path) as cap:
+            srv.run()
+        stats = [st for evs in cap.events("serving/decode/dispatch").values()
+                 for _, _, _, st in evs]
+        assert stats and all(int(st["host_operands"]) >= 8 for st in stats)
+        srv.close()
 
     def test_iteration_and_decode_carry_their_counts(self, served):
         spans, _, _ = served
@@ -435,12 +483,14 @@ class FakeClock:
 
 
 @pytest.mark.parametrize("telemetry", ["off", "goodput_and_reqtrace"])
-def test_one_pair_of_engine_clock_readings_per_dispatch(tiny_engine, tmp_path,
-                                                        telemetry):
-    """Dispatch begins, the tokens are on the host: one pair of readings of
-    the engine's clock a program, whoever consumes them (ServeGoodput,
-    ReqTrace). The spans never take the engine's clock: they stay on
-    ``perf_counter``, the clock of their parents and of the capture."""
+def test_three_engine_clock_readings_per_dispatch(tiny_engine, tmp_path,
+                                                  telemetry):
+    """Dispatch begins, the call has returned, the tokens are on the host:
+    the first and the last reading of the engine's clock are the one pair
+    that every consumer shares (ServeGoodput, ReqTrace), and with the one
+    between them a program that held the engine is told, whatever records.
+    The spans never take the engine's clock: they stay on ``perf_counter``,
+    the clock of their parents and of the capture."""
     if telemetry != "off":
         configure_observability(ObservabilityConfig(
             enabled=True, output_dir=str(tmp_path / "obs"),
@@ -453,12 +503,11 @@ def test_one_pair_of_engine_clock_readings_per_dispatch(tiny_engine, tmp_path,
     args = srv._decode_operands([])
     before = clock.reads
     p0 = time.perf_counter()
-    with mesh_mod.ambient(srv.engine.mesh):
-        with obs.span("serving/decode") as parent:
-            tok, t0, t1 = srv._run_program(obs, "serving/decode",
-                                           srv._decode, *args, srv._base_rng)
+    with obs.span("serving/decode") as parent:
+        tok, t0, t1 = srv._run_program(obs, "serving/decode",
+                                       srv._decode, *args, srv._base_rng)
     p1 = time.perf_counter()
-    assert clock.reads - before == 2 and t1 - t0 == 1.0
+    assert clock.reads - before == 3 and t1 - t0 == 2.0
     assert tok.shape == (4,)
     if telemetry == "off":
         assert parent is NOOP_SPAN and recorded_spans() == []
@@ -469,6 +518,133 @@ def test_one_pair_of_engine_clock_readings_per_dispatch(tiny_engine, tmp_path,
         assert disp["parent_id"] == fetch["parent_id"] == dec["id"]
         assert p0 <= dec["start_s"] <= disp["start_s"] <= disp["end_s"] \
             <= fetch["start_s"] <= fetch["end_s"] <= dec["end_s"] <= p1
+    srv.close()
+
+
+def test_nothing_recording_builds_nothing_at_the_new_sites(tiny_engine,
+                                                           monkeypatch):
+    """No session, no capture: ``.../apply`` and ``.../dispatch`` are the
+    shared no-op, and the operands are never counted."""
+    from deepspeed_tpu.serving import api
+
+    def counted(call_args):
+        raise AssertionError("host operands counted with nothing recording")
+
+    monkeypatch.setattr(api, "_host_operands", counted)
+    srv = serving(tiny_engine)
+    obs = get_session()
+    handed, span = [], obs.span
+
+    def spy(name, *a, **kw):
+        got = span(name, *a, **kw)
+        handed.append((name, got))
+        return got
+
+    monkeypatch.setattr(obs, "span", spy)
+    h = srv.submit(np.arange(1, 20), max_new_tokens=3)
+    srv.run()
+    assert len(h.tokens) == 3 and recorded_spans() == []
+    assert {"serving/prefill_chunk/apply", "serving/decode/apply",
+            "serving/decode/dispatch"} <= {name for name, _ in handed}
+    assert all(got is NOOP_SPAN for _, got in handed)
+    srv.close()
+
+
+class ScriptedClock:
+    """An engine clock that advances by what the test says at each
+    reading (0 once the script is read out)."""
+
+    def __init__(self):
+        self.now, self.script = 100.0, []
+
+    def __call__(self):
+        self.now += self.script.pop(0) if self.script else 0.0
+        return self.now
+
+
+@pytest.fixture
+def package_log(caplog):
+    """The package's logger does not propagate: caplog's handler on it."""
+    from deepspeed_tpu.utils.logging import logger
+
+    logger.addHandler(caplog.handler)
+    yield caplog
+    logger.removeHandler(caplog.handler)
+
+
+def _warned(log):
+    return [r.getMessage() for r in log.records if r.levelname == "WARNING"]
+
+
+def _run_with(srv, clock, script):
+    """One decode program with the engine's three clock readings moved on
+    by `script`: before the call, after it, tokens on the host."""
+    clock.script = list(script)
+    srv._run_program(get_session(), "serving/decode", srv._decode,
+                     *srv._decode_operands([]), srv._base_rng)
+
+
+@pytest.mark.parametrize("script,held", [
+    ((0.0, 0.7, 0.1), "call 0.700 s"),
+    ((0.0, 0.1, 2.5), "fetch 2.500 s"),
+    ((0.0, 0.6, 0.5), "call 0.600 s and fetch 0.500 s"),
+    ((0.0, 0.49, 0.49), None),
+], ids=["call", "fetch", "both", "neither"])
+def test_a_program_that_held_the_engine_is_counted(tiny_engine, package_log,
+                                                   script, held):
+    """Half a second or more in the jitted call, or from its return to the
+    tokens on the host: counted on the engine and logged once, with no
+    session and no capture."""
+    clock = ScriptedClock()
+    srv = serving(tiny_engine, clock=clock)
+    # a call that compiles is set-up, however long: the program's first, and
+    # its second, whose arena is no longer the fresh one
+    _run_with(srv, clock, (0.0, 30.0, 0.01))
+    _run_with(srv, clock, (0.0, 30.0, 0.01))
+    assert srv._decode._cache_size() == 2
+    assert srv.holds == 0 and not _warned(package_log)
+    srv._iterations = 41
+    _run_with(srv, clock, script)
+    assert recorded_spans() == []
+    warned = _warned(package_log)
+    if held is None:
+        assert srv.holds == 0 and not warned
+    else:
+        assert srv.holds == 1 and len(warned) == 1
+        assert "serving/decode" in warned[0] and held in warned[0]
+        assert "iteration 41" in warned[0]
+    srv.close()
+
+
+def test_a_call_that_compiled_and_waits_for_its_tokens_is_a_hold(
+        tiny_engine, package_log):
+    """Only the CALL is excused where it compiled."""
+    clock = ScriptedClock()
+    srv = serving(tiny_engine, clock=clock)
+    _run_with(srv, clock, (0.0, 30.0, 0.8))
+    (warned,) = _warned(package_log)
+    assert srv.holds == 1
+    assert "fetch 0.800 s" in warned and "call" not in warned
+    srv.close()
+
+
+def test_the_iteration_span_carries_the_holds(tiny_engine, tmp_path):
+    configure_observability(ObservabilityConfig(
+        enabled=True, output_dir=str(tmp_path / "obs"),
+        recompile_watchdog=False, flight_recorder=False,
+        hang_watchdog=False))
+    clock = ScriptedClock()
+    srv = serving(tiny_engine, clock=clock)
+    srv.submit(np.arange(1, 20), max_new_tokens=3)
+    srv.run()
+    _run_with(srv, clock, (0.0, 0.1, 0.9))
+    srv.submit(np.arange(1, 20), max_new_tokens=2)
+    srv.run()
+    its = [s["attrs"]["holds"] for s in recorded_spans()
+           if s["name"] == "serving/iteration"]
+    assert its[0] == 0 and its[-1] == 1 and its == sorted(its)
+    assert get_registry().counter("serving/holds").value(
+        program="serving/decode") == 1
     srv.close()
 
 
