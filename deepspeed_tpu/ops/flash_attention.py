@@ -5,11 +5,26 @@ training softmax/attention path in ``csrc/transformer/softmax_kernels.cu`` +
 ``ds_transformer_cuda.cpp`` and the inference ``softmax_context`` op
 (csrc/transformer/inference/csrc/softmax.cu). Online-softmax tiling (Flash
 Attention 2 schedule): the KV loop is the innermost sequential grid dimension,
-with running max/denominator kept in VMEM scratch; causal blocks above the
-diagonal are skipped entirely.
+with running max/denominator kept in VMEM scratch.
+
+What one tile step executes follows from what the call can see (see "which
+part of a tile runs" below). Causal tiles above the diagonal are skipped; a
+tile wholly below it builds no mask at all; a square tile on the diagonal
+masks with a compile-time constant, and the backward kernels walk it in
+strips so that only the part under the diagonal is multiplied. The
+key-length mask exists only where keys were padded, the key-padding mask only
+where the caller gave one.
 
 Layouts: q (B, N, S, D); k, v (B, N, T, D) — callers with GQA expand KV heads
-before the call (wrapper does it). All matmuls accumulate in fp32 on the MXU.
+before the call (wrapper does it). Operands enter the MXU in the dtype they
+are stored in (bf16 stays bf16; probabilities and score gradients are rounded
+to it for their products); every product is summed in fp32, and scores,
+running max and sum, ``lse``, ``delta`` and the accumulators are fp32. A
+product whose result is only D wide fills D of the MXU's 128 columns, so
+the backward kernels compute dq, dk and dv TRANSPOSED, (D, rows): the large
+matrix (p, ds) becomes the MXU's weights as it lies and the D-wide one is
+streamed, half the passes at D = 64 and no transpose of p or ds; each
+accumulator is transposed once, when its block is written.
 
 The backward pass is two Pallas kernels (dq, and dkv) following the standard
 FA2 recomputation scheme with the forward's logsumexp as residual.
@@ -18,6 +33,7 @@ FA2 recomputation scheme with the forward's logsumexp as residual.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -28,15 +44,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+STRIP = 256   # a diagonal tile is walked in strips of this many rows/columns
 
 
 def _block_sizes(s: int, t: int) -> Tuple[int, int]:
-    """Pick (bq, bk) power-of-two blocks. Measured on v5e at B32/N12/S1024/D64:
-    (128,128) 17.8ms fwd vs (1024,1024) 8.0ms — large tiles keep the MXU busy
-    and amortise grid overhead; the fp32 score tile is capped at 4MB VMEM so
-    long sequences fall back to (1024,1024) tiling with causal block-skip.
-    Blocks are always >=128 (inputs are padded up), keeping the TPU sublane
-    rule (multiples of 8) satisfied for any raw sequence length."""
+    """Pick (bq, bk) power-of-two blocks, a function of (S, T) alone: the
+    largest tile up to 1024 a side whose fp32 score tile stays within 4MB of
+    VMEM. Blocks are always >=128 (inputs are padded up), keeping the TPU
+    sublane rule (multiples of 8) satisfied for any raw sequence length."""
 
     def pick(n: int, cap: int = 1024) -> int:
         b = 128
@@ -54,68 +69,209 @@ def _block_sizes(s: int, t: int) -> Tuple[int, int]:
 
 
 # ---------------------------------------------------------------------------
+# which part of a tile runs, and what it masks
+# ---------------------------------------------------------------------------
+#
+# A (bq, bk) tile of the causal grid is skipped (wholly above the diagonal),
+# visible (wholly at or below it) or crossed by it. A crossed tile whose
+# corners the diagonal joins (bq == bk) is a DIAGONAL tile: its mask is a
+# compile-time constant (the tile's offsets cancel), so the compiler keeps
+# a select only in the vregs the diagonal crosses and drops the vector work
+# of those above it. The backward kernels, which the MXU bounds, also WALK
+# a diagonal tile in strips of STRIP rows, each multiplying only the
+# columns at or left of its rows; the forward, which vector work bounds,
+# runs it whole (on the chip the walk cost it more in small products than
+# it saved: PERF.md section 6, PR 42). Any other crossed tile runs whole
+# under the causal mask. A visible tile builds no causal mask. The
+# key-length mask (col < kv_len) exists only where the keys were padded
+# (``ragged``) and then only in the last column of tiles and in crossed
+# ones; the key-padding mask, where the caller gave one, is on every tile.
+
+
+def _tile_kind(i, j, bq: int, bk: int):
+    """(runs, visible) of causal tile (i, j); ints or traced scalars."""
+    return j * bk <= i * bq + (bq - 1), (j + 1) * bk - 1 <= i * bq
+
+
+def _strips(kind: str, bq: int, bk: int, walk: bool):
+    """The rectangles (rows, cols, causal) of one tile that run: the whole
+    tile, or with ``walk`` the strips of a diagonal one."""
+    if not walk or kind != "diagonal" or bq <= STRIP:
+        return [(slice(0, bq), slice(0, bk), kind != "visible")]
+    return [(slice(a, a + STRIP), slice(0, a + STRIP), True)
+            for a in range(0, bq, STRIP)]
+
+
+def _tile_plan(s: int, t: int, causal: bool = True, walk: bool = True):
+    """What a kernel executes for (S, T), as a function of the shapes alone:
+    the rectangles (r0, r1, c0, c1, masked) of the padded score matrix that
+    are multiplied (``walk``: the backward kernels; without it the forward).
+    Tests hold it to the causal triangle."""
+    bq, bk = _block_sizes(s, t)
+    sp, tp = -(-s // bq) * bq, -(-t // bk) * bk
+    ragged = t < tp
+    rects = []
+    for i in range(sp // bq):
+        for j in range(tp // bk):
+            runs, visible = _tile_kind(i, j, bq, bk) if causal else (True, True)
+            if not runs:
+                continue
+            kind = ("visible" if visible else
+                    "diagonal" if bq == bk else "crossed")
+            edge = ragged and (j == tp // bk - 1 or not visible)
+            for rows, cols, diag in _strips(kind, bq, bk, walk):
+                rects.append((i * bq + rows.start, i * bq + rows.stop,
+                              j * bk + cols.start, j * bk + cols.stop,
+                              diag or edge))
+    return rects
+
+
+def _by_kind(i, j, last_col, *, causal: bool, bq: int, bk: int, ragged: bool,
+             body):
+    """Run ``body(kind, len_mask)`` for grid tile (i, j) under the ``pl.when``
+    that its kind asks for; ``last_col``: j is the last column of tiles."""
+
+    def when(pred, kind, len_mask):
+        if pred is None:
+            body(kind, len_mask)
+        else:
+            pl.when(pred)(lambda: body(kind, len_mask))
+
+    def both(a, b):
+        return b if a is None else a & b
+
+    def visible(pred):
+        if ragged:
+            when(both(pred, last_col), "visible", True)
+            when(both(pred, jnp.logical_not(last_col)), "visible", False)
+        else:
+            when(pred, "visible", False)
+
+    if not causal:
+        return visible(None)
+    runs, vis = _tile_kind(i, j, bq, bk)
+    visible(vis)
+    when(runs & jnp.logical_not(vis),
+         "diagonal" if bq == bk else "crossed", ragged)
+
+
+def _fold_scale(dtype, scale: float) -> bool:
+    """Whether ``scale`` goes onto q before the product (exact: float32
+    operands, or a power of two) or onto the float32 scores after it."""
+    return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
+
+
+def _scaled(q, scale: float):
+    """(q, what is left to put on the scores): ``scale`` goes onto q, in q's
+    own dtype, where that is exact."""
+    if not _fold_scale(q.dtype, scale):
+        return q, scale
+    return (q.astype(jnp.float32) * scale).astype(q.dtype), 1.0
+
+
+def _part_scores(q, scale_s: float, rows, cols, diag: bool, *, kind: str,
+                 len_mask: bool, i, j, k_ref, kvm_ref, slope, bq: int,
+                 bk: int, kv_len: int):
+    """Float32 scores of the (rows, cols) part of tile (i, j), rows ``q``
+    against the keys ``k_ref[cols]``, biased and masked as this part needs
+    and no further: ``diag`` the causal corner, ``len_mask`` the padded
+    keys, ``kvm_ref`` the key-padding block or None, ``slope`` ALiBi's."""
+    s = jax.lax.dot_general(q, k_ref[0, 0, cols, :], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if scale_s != 1.0:
+        s = s * scale_s
+    keep = None
+    if slope is not None or len_mask:
+        col = (jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
+               + (cols.start + j * bk))
+        if slope is not None:
+            # key-position-linear bias (query term is softmax-shift-invariant)
+            s = s + slope * col.astype(jnp.float32)
+        if len_mask:
+            keep = col < kv_len
+    if kvm_ref is not None:
+        kvm = kvm_ref[0, :, cols] != 0                        # (1, c)
+        keep = kvm if keep is None else keep & kvm
+    if diag:
+        # on a diagonal tile i * bq == j * bk: the tile's offsets cancel
+        row0, col0 = ((rows.start, cols.start) if kind == "diagonal" else
+                      (rows.start + i * bq, cols.start + j * bk))
+        tri = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + row0
+               >= jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col0)
+        keep = tri if keep is None else keep & tri
+    return s if keep is None else jnp.where(keep, s, NEG_INF)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, slopes_ref, o_ref, lse_ref,
-                acc, m_scr, l_scr, *, scale: float, causal: bool,
-                bq: int, bk: int, kv_len: int, has_mask: bool,
+                acc, m_scr, *l_scr, scale: float, causal: bool,
+                bq: int, bk: int, kv_len: int, ragged: bool, has_mask: bool,
                 has_alibi: bool):
     i = pl.program_id(2)   # q block
     j = pl.program_id(3)   # kv block
     nj = pl.num_programs(3)
+    d = o_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        for l in l_scr:
+            l[:] = jnp.zeros_like(l)
 
-    # causal: skip blocks strictly above the diagonal
-    run = True
-    if causal:
-        run = j * bk <= i * bq + (bq - 1)
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (bk, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (bq, bk)
-        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-        if has_alibi:
-            # key-position-linear bias (query term is softmax-shift-invariant)
-            s = s + slopes_ref[0, 0, 0] * col.astype(jnp.float32)
-        mask = col < kv_len
-        if causal:
-            row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * bq
-            mask = mask & (col <= row)
-        if has_mask:
-            mask = mask & (kvm_ref[0, 0] != 0)[None, :]      # key-padding (bk,)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[:, :1]                                # (bq, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)            # (bq, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                               # (bq, bk)
-        correction = jnp.exp(m_prev - m_new)                 # (bq, 1)
-        l_new = correction * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+    def body(kind, len_mask):
+        # the whole tile in one piece (see "which part of a tile runs")
+        q, scale_s = _scaled(q_ref[0, 0], scale)              # (bq, D)
+        s = _part_scores(
+            q, scale_s, slice(0, bq), slice(0, bk), kind != "visible",
+            kind=kind, len_mask=len_mask, i=i, j=j, k_ref=k_ref,
+            kvm_ref=kvm_ref if has_mask else None,
+            slope=slopes_ref[0, 0, 0] if has_alibi else None,
+            bq=bq, bk=bk, kv_len=kv_len)                      # (bq, bk)
+        m_prev = m_scr[:, :1]                                 # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)                                # (bq, bk)
+        correction = jnp.exp(m_prev - m_new)                  # (bq, 1)
+        v = v_ref[0, 0]
+        for l in l_scr:
+            l[:] = jnp.broadcast_to(
+                correction * l[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+                l.shape)
+        if not l_scr:
+            # The head leaves half of the accumulator's lanes empty (``_fwd``
+            # then gives no ``l_scr``): a block of ones beside v has the MXU
+            # sum p's rows into them, so the running sum rides in acc[:, d:]
+            # and is rescaled with it.
+            v = jnp.concatenate([v, jnp.ones_like(v)], axis=1)
         acc[:] = acc[:] * correction + jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    _by_kind(i, j, j == nj - 1, causal=causal, bq=bq, bk=bk, ragged=ragged,
+             body=body)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[0][:, :1] if l_scr else acc[:, d:d + 1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc[:, :d] / safe_l).astype(o_ref.dtype)
         lse = m_scr[:, :1] + jnp.log(safe_l)
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref[0, 0].shape)
 
 
+# ``_fwd`` and ``_bwd`` are jitted so that a kernel's body, which the masks'
+# and the walk's several bodies made slower to trace, is traced once for its
+# shapes: without it a program that is traced twice (the engine's train step
+# is, and the forward once more for its residuals) pays for each again.
+_STATIC = ("causal", "scale", "kv_len", "has_mask", "has_alibi", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _fwd(q: jax.Array, k: jax.Array, v: jax.Array, kvm: jax.Array,
          slopes: jax.Array, *,
          causal: bool, scale: float, kv_len: int, has_mask: bool,
@@ -126,8 +282,8 @@ def _fwd(q: jax.Array, k: jax.Array, v: jax.Array, kvm: jax.Array,
     grid = (B, N, S // bq, T // bk)
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, kv_len=kv_len, has_mask=has_mask,
-                               has_alibi=has_alibi)
+                               bq=bq, bk=bk, kv_len=kv_len, ragged=kv_len < T,
+                               has_mask=has_mask, has_alibi=has_alibi)
     out_shape = [
         jax.ShapeDtypeStruct((B, N, S, D), q.dtype),
         jax.ShapeDtypeStruct((B, N, S, LANES), jnp.float32),  # lse (lane-padded)
@@ -147,11 +303,11 @@ def _fwd(q: jax.Array, k: jax.Array, v: jax.Array, kvm: jax.Array,
             pl.BlockSpec((1, 1, bq, LANES), lambda b, n, i, j: (b, n, i, 0)),
         ],
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-        ],
+        # acc, running max, and the running sum unless it rides in acc
+        scratch_shapes=(
+            [pltpu.VMEM((bq, LANES), jnp.float32)] * 2 if 2 * D == LANES else
+            [pltpu.VMEM((bq, D), jnp.float32)]
+            + [pltpu.VMEM((bq, LANES), jnp.float32)] * 2),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         name="flash_attention_fwd",
@@ -165,10 +321,20 @@ def _fwd(q: jax.Array, k: jax.Array, v: jax.Array, kvm: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def _p_and_ds(s, do, rows, cols, v_ref, lse_ref, delta_ref):
+    """Recomputed probabilities and score gradients of one part of a tile
+    from its scores, float32: p = exp(s - lse), ds = p * (do v^T - delta)."""
+    p = jnp.exp(s - lse_ref[0, 0, rows, :1])                  # (r, c)
+    dp = jax.lax.dot_general(do, v_ref[0, 0, cols, :],
+                             (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta_ref[0, 0, rows, :1])
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kvm_ref,
                    slopes_ref, dq_ref, acc, *, scale: float, causal: bool,
-                   bq: int, bk: int, kv_len: int, has_mask: bool,
-                   has_alibi: bool):
+                   bq: int, bk: int, kv_len: int, ragged: bool,
+                   has_mask: bool, has_alibi: bool):
     i = pl.program_id(2)
     j = pl.program_id(3)
     nj = pl.num_programs(3)
@@ -177,46 +343,38 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kvm_ref,
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
-    run = True
-    if causal:
-        run = j * bk <= i * bq + (bq - 1)
+    def body(kind, len_mask):
+        scores = functools.partial(
+            _part_scores, kind=kind, len_mask=len_mask, i=i, j=j, k_ref=k_ref,
+            kvm_ref=kvm_ref if has_mask else None,
+            slope=slopes_ref[0, 0, 0] if has_alibi else None,
+            bq=bq, bk=bk, kv_len=kv_len)
+        for rows, cols, diag in _strips(kind, bq, bk, walk=True):
+            q, scale_s = _scaled(q_ref[0, 0, rows, :], scale)
+            _, ds = _p_and_ds(scores(q, scale_s, rows, cols, diag),
+                              do_ref[0, 0, rows, :], rows, cols, v_ref,
+                              lse_ref, delta_ref)
+            k = k_ref[0, 0, cols, :]
+            # dq^T = k^T ds^T, (D, r): see the module docstring
+            acc[:, rows] += jax.lax.dot_general(
+                k, ds.astype(k.dtype), (((0,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-        if has_alibi:
-            s = s + slopes_ref[0, 0, 0] * col.astype(jnp.float32)
-        mask = col < kv_len
-        if causal:
-            row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * bq
-            mask = mask & (col <= row)
-        if has_mask:
-            mask = mask & (kvm_ref[0, 0] != 0)[None, :]
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, :1])                 # (bq, bk)
-        do = do_ref[0, 0].astype(jnp.float32)                 # (bq, D)
-        dp = jax.lax.dot_general(do, v_ref[0, 0].astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, :1])                # (bq, bk)
-        acc[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+    _by_kind(i, j, j == nj - 1, causal=causal, bq=bq, bk=bk, ragged=ragged,
+             body=body)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        dq_ref[0, 0] = (acc[:] * scale).astype(dq_ref.dtype)
+        dq_ref[0, 0] = (acc[:].T * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kvm_ref,
                     slopes_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                     scale: float, causal: bool, bq: int, bk: int, kv_len: int,
-                    has_mask: bool, has_alibi: bool):
+                    ragged: bool, has_mask: bool, has_alibi: bool):
     j = pl.program_id(2)   # kv block (outer)
     i = pl.program_id(3)   # q block (inner, sequential)
+    nj = pl.num_programs(2)
     ni = pl.num_programs(3)
 
     @pl.when(i == 0)
@@ -224,43 +382,38 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kvm_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = True
-    if causal:
-        run = j * bk <= i * bq + (bq - 1)
+    def body(kind, len_mask):
+        scores = functools.partial(
+            _part_scores, kind=kind, len_mask=len_mask, i=i, j=j, k_ref=k_ref,
+            kvm_ref=kvm_ref if has_mask else None,
+            slope=slopes_ref[0, 0, 0] if has_alibi else None,
+            bq=bq, bk=bk, kv_len=kv_len)
+        for rows, cols, diag in _strips(kind, bq, bk, walk=True):
+            q, scale_s = _scaled(q_ref[0, 0, rows, :], scale)  # (r, D)
+            do = do_ref[0, 0, rows, :]
+            p, ds = _p_and_ds(scores(q, scale_s, rows, cols, diag), do,
+                              rows, cols, v_ref, lse_ref, delta_ref)
+            # dv^T = do^T p and dk^T = q^T ds, (D, c): p and ds as they lie
+            dv_acc[:, cols] += jax.lax.dot_general(
+                do, p.astype(do.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_acc[:, cols] += jax.lax.dot_general(
+                q, ds.astype(q.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale           # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)                   # (bk, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
-        if has_alibi:
-            s = s + slopes_ref[0, 0, 0] * col.astype(jnp.float32)
-        mask = col < kv_len
-        if causal:
-            row = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + i * bq
-            mask = mask & (col <= row)
-        if has_mask:
-            mask = mask & (kvm_ref[0, 0] != 0)[None, :]
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, :1])                 # (bq, bk)
-        do = do_ref[0, 0].astype(jnp.float32)
-        dv_acc[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[0, 0].astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, :1])
-        dk_acc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+    _by_kind(i, j, j == nj - 1, causal=causal, bq=bq, bk=bk, ragged=ragged,
+             body=body)
 
     @pl.when(i == ni - 1)
     def _finalize():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+        dk = dk_acc[:].T
+        if not _fold_scale(q_ref.dtype, scale):
+            dk = dk * scale   # q went in unscaled: its scale goes on here
+        dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].T.astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd(causal: bool, scale: float, kv_len: int, has_mask: bool,
          has_alibi: bool, interpret: bool, residuals, grads):
     q, k, v, kvm, slopes, o, lse = residuals
@@ -283,16 +436,16 @@ def _bwd(causal: bool, scale: float, kv_len: int, has_mask: bool,
         pl.BlockSpec((1, 1, bk), lambda b, n, x, y: (b, 0, y)),            # kv mask
         pl.BlockSpec((1, 1, LANES), lambda b, n, x, y: (n, 0, 0)),         # slopes
     ]
+    static = dict(scale=scale, causal=causal, bq=bq, bk=bk, kv_len=kv_len,
+                  ragged=kv_len < T, has_mask=has_mask, has_alibi=has_alibi)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, kv_len=kv_len, has_mask=has_mask,
-                          has_alibi=has_alibi),
+        functools.partial(_bwd_dq_kernel, **static),
         grid=(B, N, S // bq, T // bk),
         in_specs=common_specs,
         out_specs=[pl.BlockSpec((1, 1, bq, D), lambda b, n, x, y: (b, n, x, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, N, S, D), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((D, bq), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         name="flash_attention_bwd_dq",
@@ -311,9 +464,7 @@ def _bwd(causal: bool, scale: float, kv_len: int, has_mask: bool,
         pl.BlockSpec((1, 1, LANES), lambda b, n, y, x: (n, 0, 0)),
     ]
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, kv_len=kv_len, has_mask=has_mask,
-                          has_alibi=has_alibi),
+        functools.partial(_bwd_dkv_kernel, **static),
         grid=(B, N, T // bk, S // bq),
         in_specs=swapped_specs,
         out_specs=[
@@ -322,8 +473,8 @@ def _bwd(causal: bool, scale: float, kv_len: int, has_mask: bool,
         ],
         out_shape=[jax.ShapeDtypeStruct((B, N, T, D), k.dtype),
                    jax.ShapeDtypeStruct((B, N, T, D), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((D, bk), jnp.float32),
+                        pltpu.VMEM((D, bk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         name="flash_attention_bwd_dkv",

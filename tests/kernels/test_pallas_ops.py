@@ -15,6 +15,7 @@ from deepspeed_tpu.ops import (decode_attention, dequantize_symmetric,
                                reference_decode_attention,
                                reference_layer_norm,
                                reference_quantize_symmetric)
+from deepspeed_tpu.ops.flash_attention import STRIP, _block_sizes, _tile_plan
 
 INTERPRET = True  # CPU mesh — run kernels through the pallas interpreter
 
@@ -162,6 +163,171 @@ class TestFlashAttention:
         out = flash_attention(q, k, v, mask=full, causal=True, interpret=INTERPRET)
         ref = dot_product_attention(q, k, v, full, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+    # -- what one tile executes (PR 42): only the part of a causal tile under
+    # the diagonal runs, only tiles that need a mask build one, and operands
+    # enter the MXU in their own dtype -----------------------------------
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("what", ["forward", "gradients"])
+    @pytest.mark.parametrize("d", [64, 32])
+    def test_bf16_operands(self, causal, what, d):
+        """bf16 q/k/v are multiplied as stored and summed in float32: held to
+        the float32 jnp reference on the same (rounded) values at bf16
+        tolerances, at a size whose diagonal tile is walked in strips. At
+        head size 64 the scale is a power of two and goes onto q; at 32 it
+        is not, and goes onto the float32 scores."""
+        q, k, v = _qkv(b=1, s=1024, d=d, dtype=jnp.bfloat16)
+        qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=causal,
+                                   interpret=INTERPRET).astype(jnp.float32)
+
+        def ref(q, k, v):
+            return dot_product_attention(q, k, v, None, causal=causal)
+
+        if what == "forward":
+            got, want = [flash(q, k, v)], [ref(qf, kf, vf)]
+            assert flash_attention(q, k, v, causal=causal,
+                                   interpret=INTERPRET).dtype == jnp.bfloat16
+        else:
+            got = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), (0, 1, 2))(q, k, v)
+            want = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), (0, 1, 2))(qf, kf, vf)
+            assert all(g.dtype == jnp.bfloat16 for g in got)
+        for a, b in zip(got, want):
+            a, b = np.asarray(a, np.float32), np.asarray(b)
+            assert np.isfinite(a).all()
+            assert np.abs(a - b).max() <= 3e-2 * max(1.0, np.abs(b).max())
+
+    @staticmethod
+    def _three_kinds(variant):
+        """A causal call whose grid holds a skipped, a wholly visible and a
+        diagonal tile at once (three tiles a side of ``_block_sizes``'
+        choice, B = N = 1), and the jnp reference for it."""
+        s = t = 3 * _block_sizes(4096, 4096)[0]
+        kw, mask, n = {}, None, 1
+        if variant == "unaligned":       # kv_len < T padded: the last column
+            s = t = s - 72               # of tiles masks, the others do not
+        elif variant == "key_padding":
+            mask = jnp.ones((1, t), jnp.int32).at[0, t - 300:].set(0) \
+                .at[0, 5:40].set(0)
+        elif variant == "alibi":
+            n = 2
+            kw["alibi"] = alibi_slopes(n)
+        elif variant == "t_ne_s":        # fewer, unaligned keys than queries
+            t = t - s // 3 - 48
+        q, k, v = _qkv(b=1, s=s, t=t, n=n, d=32)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, mask=mask, causal=True,
+                                   interpret=INTERPRET, **kw)
+
+        def ref(q, k, v):
+            if s == t:
+                return dot_product_attention(q, k, v, mask, causal=True, **kw)
+            # the kernel's causal rule is col <= row from the top left
+            tri = (jnp.arange(t)[None, :] <= jnp.arange(s)[:, None])[None]
+            return dot_product_attention(q, k, v, tri, causal=False)
+
+        return (q, k, v), flash, ref
+
+    VARIANTS = ["aligned", "unaligned", "key_padding", "alibi", "t_ne_s"]
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_three_kinds_of_tile_forward(self, variant):
+        qkv, flash, ref = self._three_kinds(variant)
+        np.testing.assert_allclose(np.asarray(flash(*qkv)),
+                                   np.asarray(ref(*qkv)),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_three_kinds_of_tile_gradients(self, variant):
+        qkv, flash, ref = self._three_kinds(variant)
+        g1 = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), (0, 1, 2))(*qkv)
+        g2 = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), (0, 1, 2))(*qkv)
+        for a, b, name in zip(g1, g2, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-4, rtol=1e-3,
+                                       err_msg=f"d{name} mismatch")
+
+    @pytest.mark.parametrize("walk", [True, False])
+    @pytest.mark.parametrize("s,t", [(2048, 2048), (1024, 1024), (3072, 3072),
+                                     (4096, 4096), (3000, 3000), (100, 100),
+                                     (3072, 2000), (2048, 3072), (128, 256)])
+    def test_tile_plan_covers_the_causal_triangle(self, s, t, walk):
+        """The tile plan is a pure function of (S, T): every (row, col) with
+        col <= row is multiplied exactly once and nothing that needs a mask
+        runs without one. The backward kernels walk a diagonal tile
+        (``walk``): at 2048 their executed area is at most 1.25 of the
+        causal half (it was 1.5); the forward runs it whole."""
+        bq, bk = _block_sizes(s, t)
+        rects = _tile_plan(s, t, causal=True, walk=walk)
+        sp, tp = -(-s // bq) * bq, -(-t // bk) * bk
+        seen = np.zeros((sp, tp), np.int8)
+        for r0, r1, c0, c1, masked in rects:
+            assert 0 <= r0 < r1 <= sp and 0 <= c0 < c1 <= tp
+            seen[r0:r1, c0:c1] += 1
+            if not masked:   # wholly at or below the diagonal, no padded key
+                assert c1 - 1 <= r0 and c1 <= t
+        assert seen.max() == 1
+        need = np.tril(np.ones((s, t), bool))
+        assert (seen[:s, :t][need] == 1).all()
+        if (s, t) == (2048, 2048):
+            area = sum((r1 - r0) * (c1 - c0) for r0, r1, c0, c1, _ in rects)
+            assert area / (s * t / 2) == (1.125 if walk else 1.5)
+            assert sum(m for *_, m in rects) < len(rects)
+        # not causal: every tile runs whole, and only padded keys mask
+        full = _tile_plan(s, t, causal=False, walk=walk)
+        assert len(full) == (sp // bq) * (tp // bk)
+        assert all(m == (t < tp and c1 == tp) for *_, c1, m in full)
+
+    def test_nan_above_the_diagonal_of_a_crossed_tile(self):
+        """NaN planted in the k columns of a diagonal tile's last strip must
+        not reach the rows above that strip: the forward masks those scores
+        before anything reads them, and the dq kernel's walk never multiplies
+        those columns (whole, its ds * k would be 0 * NaN). The rows at or
+        below see those keys by right, and through them every dk / dv row:
+        for dk and dv the skipped tile below is the case that can be held;
+        and NaN in v reaches every row of the tile through p * v, where p is
+        an exact zero, as in the jnp reference."""
+        s = _block_sizes(4096, 4096)[0]
+        assert s > STRIP
+        clean = s - STRIP
+        q, k, v = _qkv(b=1, s=s, n=1)
+        kn = k.at[:, clean:].set(jnp.nan)
+
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, causal=True, interpret=INTERPRET)
+            return jnp.sum(o[:, :clean] ** 2)
+
+        o = flash_attention(q, kn, v, causal=True, interpret=INTERPRET)
+        ref = flash_attention(q, k, v, causal=True, interpret=INTERPRET)
+        np.testing.assert_allclose(np.asarray(o[:, :clean]),
+                                   np.asarray(ref[:, :clean]), rtol=1e-6)
+        assert np.isnan(np.asarray(o[:, clean:])).all()
+        np.testing.assert_allclose(
+            np.asarray(jax.grad(loss)(q, kn, v)[:, :clean]),
+            np.asarray(jax.grad(loss)(q, k, v)[:, :clean]), rtol=1e-6)
+
+    def test_nan_in_a_skipped_tile_reaches_nothing(self):
+        """More keys than queries: the key tiles wholly above the diagonal
+        are never read, so NaN there reaches none of o, dq, dk, dv."""
+        s = _block_sizes(4096, 4096)[0]
+        q, k, v = _qkv(b=1, s=s, t=2 * s, n=1)
+        k, v = (x.at[:, s:].set(jnp.nan) for x in (k, v))
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=True,
+                                           interpret=INTERPRET) ** 2)
+
+        out = flash_attention(q, k, v, causal=True, interpret=INTERPRET)
+        grads = jax.grad(loss, (0, 1, 2))(q, k, v)
+        for x in (out, *grads):
+            assert np.isfinite(np.asarray(x)).all()
+        assert not np.asarray(grads[1][:, s:]).any()
+        assert not np.asarray(grads[2][:, s:]).any()
 
 
 class TestDecodeAttention:

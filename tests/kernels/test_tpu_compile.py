@@ -52,7 +52,7 @@ def v5e():
     cc.reset_cache()
 
 
-def _flash(b, t, h, d, grad):
+def _flash(b, t, h, d, grad, kv_heads=None):
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True)
 
@@ -60,7 +60,8 @@ def _flash(b, t, h, d, grad):
         return fwd(q, k, v).astype(F32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    return fn, [((b, t, h, d), BF16)] * 3
+    return fn, ([((b, t, h, d), BF16)]
+                + [((b, t, kv_heads or h, d), BF16)] * 2)
 
 
 def _layer_norm(rows, t, e, grad):
@@ -147,6 +148,12 @@ CASES = {
     "flash-bwd-gpt2-125m": lambda: _flash(32, 1024, 12, 64, grad=True),
     "flash-fwd-opt-1.3b": lambda: _flash(4, 2048, 32, 64, grad=False),
     "flash-bwd-opt-1.3b": lambda: _flash(4, 2048, 32, 64, grad=True),
+    # shapes no cell trains: the strip walk's static slices and the tile rule
+    # at head size 128 (olmoe-1b-7b), under GQA (8 query heads over 2) and
+    # at a sequence of four tiles a side
+    "flash-bwd-olmoe-1b-7b": lambda: _flash(2, 2048, 16, 128, grad=True),
+    "flash-bwd-gqa": lambda: _flash(2, 2048, 8, 128, grad=True, kv_heads=2),
+    "flash-bwd-seq-4096": lambda: _flash(2, 4096, 32, 64, grad=True),
     "layernorm-fwd-gpt2-125m": lambda: _layer_norm(32, 1024, 768, False),
     "layernorm-bwd-gpt2-125m": lambda: _layer_norm(32, 1024, 768, True),
     "layernorm-fwd-opt-1.3b": lambda: _layer_norm(8, 1024, 2048, False),
