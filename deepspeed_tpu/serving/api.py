@@ -168,7 +168,8 @@ class ServingEngine:
         self._moe_experts_total = cfg.moe_num_experts * cfg.num_layers
         self._moe_experts_held = cfg.experts_held * cfg.num_layers
         moe = self._moe_experts_total > 0
-        self._prefill = paged_kv.build_prefill_program(cfg, moe_counts=moe)
+        self._prefill = paged_kv.build_prefill_program(
+            cfg, self.config.prefill_chunk, moe_counts=moe)
         self._decode = paged_kv.build_decode_program(cfg, moe_counts=moe)
         self._cow = paged_kv.build_cow_program()
         # teacher-forced scoring over the same arena (the RLHF second
@@ -1027,7 +1028,11 @@ class ServingEngine:
 
     def _run_program(self, obs, name: str, program, *args, trace=None):
         """Dispatch one jitted program over the arena (its first output the
-        sampled tokens, its last the arena) and bring the tokens to the host:
+        sampled tokens, its last the arena) and bring the tokens to the host.
+        ``args`` is what the program takes behind the params and the arena:
+        ONE numpy array, its step's operands packed (``paged_kv.pack_*``),
+        which is the one transfer the call makes, and the sampling key, on
+        the device already.
         ``<name>/dispatch`` is the call, which returns at enqueue, with the
         mesh and the request tracer's compile attribution (``trace``: whose
         dispatch this is) entered and left around it, so that nothing lies
@@ -1135,15 +1140,14 @@ class ServingEngine:
                     #   can't give
                 chunk = np.zeros((1, C), np.int32)
                 chunk[0, :n_valid] = src[start:start + n_valid]
-                temps, topks, topps, seeds = self._sampling_arrays([req])
-                table = self._table_for([req])
+                packed = paged_kv.pack_chunk(
+                    self._table_for([req]), chunk, start, n_valid,
+                    *self._sampling_arrays([req]),
+                    state_slot=([req.row] if self._recurrent_layers
+                                else None))
             tok, t0, t1 = self._run_program(
-                obs, "serving/prefill_chunk", self._prefill, table,
-                chunk, np.asarray(start, np.int32),
-                np.asarray(n_valid, np.int32),
-                temps, topks, topps, seeds, self._base_rng,
-                *([np.asarray([req.row], np.int32)]
-                  if self._recurrent_layers else []), trace=req.trace)
+                obs, "serving/prefill_chunk", self._prefill, packed,
+                self._base_rng, trace=req.trace)
             with obs.span("serving/prefill_chunk/apply", category="phase"):
                 tok = self._program_counts(span, tok, 1, real_rows=1)
                 if self._serve_acct is not None:
@@ -1305,7 +1309,10 @@ class ServingEngine:
         return [r for r in ready if r.state == DECODE]
 
     def _decode_operands(self, ready: List[Request]):
-        """The decode program's host arrays, one row per decode row."""
+        """The decode program's operands, one row per decode row, packed
+        into the ONE host array it takes (``paged_kv.pack_decode_rows``): a
+        new array each step, since the dispatched call may still read the
+        last one."""
         R = self.config.max_seqs
         bt = np.zeros((R, self.blocks_per_seq), np.int32)
         lengths = np.zeros((R,), np.int32)
@@ -1327,7 +1334,8 @@ class ServingEngine:
             steps[row] = len(r.generated)   # output-token index: the
             #   sampling stream is (engine seed, request seed, index) —
             #   schedule-independent and preemption-stable
-        return bt, lengths, tokens, temps, topks, topps, seeds, steps
+        return paged_kv.pack_decode_rows(bt, lengths, tokens, temps, topks,
+                                         topps, seeds, steps)
 
     def _step_decode(self) -> bool:
         dec = self.sched.decode_requests()
@@ -1338,7 +1346,7 @@ class ServingEngine:
                       max_rows=self.config.max_seqs) as span:
             with obs.span("serving/decode/prepare", category="phase"):
                 ready = self._ready_decode_rows(dec)
-                operands = self._decode_operands(ready) if ready else ()
+                packed = self._decode_operands(ready) if ready else None
                 span.annotate(rows=len(ready),
                               sampled_rows=self._sampled_rows(ready))
             if not ready:
@@ -1349,7 +1357,7 @@ class ServingEngine:
                                  if r.trace is not None), None)
                            if rt is not None else None)
             nxt, t0, t1 = self._run_program(
-                obs, "serving/decode", self._decode, *operands,
+                obs, "serving/decode", self._decode, packed,
                 self._base_rng, trace=first_trace)
             with obs.span("serving/decode/apply", category="phase"):
                 nxt = self._program_counts(span, nxt, self.config.max_seqs,
@@ -1470,9 +1478,10 @@ class ServingEngine:
                              if r.trace is not None), None)
                        if rt is not None else None)
         sampled, t0, t1 = self._run_program(
-            obs, "serving/verify", self._verify, bt, lengths, tokens,
-            n_valid, temps, topks, topps, seeds, steps, self._base_rng,
-            trace=first_trace)
+            obs, "serving/verify", self._verify,
+            paged_kv.pack_verify_rows(bt, lengths, tokens, n_valid, temps,
+                                      topks, topps, seeds, steps),
+            self._base_rng, trace=first_trace)
         with obs.span("serving/verify/apply", category="phase"):
             self._accept_verified(rt, plan, sampled, t0, t1)
         return True
@@ -1863,22 +1872,14 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        cfg = self.engine.model.config
         C, MAXB = self.config.prefill_chunk, self.blocks_per_seq
-        i32 = jnp.int32
         return (self.engine._params_sds(),
                 self._arena_sds(),
-                jax.ShapeDtypeStruct((1, MAXB), i32),
-                jax.ShapeDtypeStruct((1, C), i32),
-                jax.ShapeDtypeStruct((), i32),
-                jax.ShapeDtypeStruct((), i32),
-                jax.ShapeDtypeStruct((1,), jnp.float32),
-                jax.ShapeDtypeStruct((1,), i32),
-                jax.ShapeDtypeStruct((1,), jnp.float32),
-                jax.ShapeDtypeStruct((1,), i32),
-                jax.ShapeDtypeStruct((2,), jnp.uint32),
-                *([jax.ShapeDtypeStruct((1,), i32)]
-                  if self._recurrent_layers else []))
+                jax.ShapeDtypeStruct(
+                    paged_kv.chunk_shape(MAXB, C,
+                                         bool(self._recurrent_layers)),
+                    jnp.int32),
+                jax.ShapeDtypeStruct((2,), jnp.uint32))
 
     def _arena_sds(self):
         from ..inference.kv_cache import paged_cache_shape_struct
@@ -1920,16 +1921,9 @@ class ServingEngine:
                 eng = wself()
                 if eng is None:
                     raise StaleEntryError("serving/decode: engine gone")
-                i32 = jnp.int32
                 args = (eng.engine._params_sds(), eng._arena_sds(),
-                        jax.ShapeDtypeStruct((R, MAXB), i32),
-                        jax.ShapeDtypeStruct((R,), i32),
-                        jax.ShapeDtypeStruct((R,), i32),
-                        jax.ShapeDtypeStruct((R,), jnp.float32),
-                        jax.ShapeDtypeStruct((R,), i32),
-                        jax.ShapeDtypeStruct((R,), jnp.float32),
-                        jax.ShapeDtypeStruct((R,), i32),
-                        jax.ShapeDtypeStruct((R,), i32),
+                        jax.ShapeDtypeStruct(
+                            paged_kv.decode_rows_shape(R, MAXB), jnp.int32),
                         jax.ShapeDtypeStruct((2,), jnp.uint32))
                 return eng._decode, args, {}
 
@@ -2016,22 +2010,15 @@ class ServingEngine:
 
         R, MAXB = self.config.max_seqs, self.blocks_per_seq
         S = self.config.speculative.num_draft_tokens + 1
-        i32, f32 = jnp.int32, jnp.float32
+        i32 = jnp.int32
 
         def build_verify():
             eng = wself()
             if eng is None:
                 raise StaleEntryError("serving/verify: engine gone")
             args = (eng.engine._params_sds(), eng._arena_sds(),
-                    jax.ShapeDtypeStruct((R, MAXB), i32),
-                    jax.ShapeDtypeStruct((R,), i32),
-                    jax.ShapeDtypeStruct((R, S), i32),
-                    jax.ShapeDtypeStruct((R,), i32),
-                    jax.ShapeDtypeStruct((R,), f32),
-                    jax.ShapeDtypeStruct((R,), i32),
-                    jax.ShapeDtypeStruct((R,), f32),
-                    jax.ShapeDtypeStruct((R,), i32),
-                    jax.ShapeDtypeStruct((R,), i32),
+                    jax.ShapeDtypeStruct(
+                        paged_kv.verify_rows_shape(R, MAXB, S), i32),
                     jax.ShapeDtypeStruct((2,), jnp.uint32))
             return eng._verify, args, {}
 
@@ -2071,14 +2058,8 @@ class ServingEngine:
             if eng is None:
                 raise StaleEntryError("serving/draft_decode: engine gone")
             args = (eng._drafter.engine._params_sds(), draft_arena_sds(eng),
-                    jax.ShapeDtypeStruct((R, MAXB), i32),
-                    jax.ShapeDtypeStruct((R,), i32),
-                    jax.ShapeDtypeStruct((R,), i32),
-                    jax.ShapeDtypeStruct((R,), f32),
-                    jax.ShapeDtypeStruct((R,), i32),
-                    jax.ShapeDtypeStruct((R,), f32),
-                    jax.ShapeDtypeStruct((R,), i32),
-                    jax.ShapeDtypeStruct((R,), i32),
+                    jax.ShapeDtypeStruct(
+                        paged_kv.decode_rows_shape(R, MAXB), i32),
                     jax.ShapeDtypeStruct((2,), jnp.uint32))
             return eng._drafter._decode, args, {}
 
@@ -2087,14 +2068,8 @@ class ServingEngine:
             if eng is None:
                 raise StaleEntryError("serving/draft_prefill: engine gone")
             args = (eng._drafter.engine._params_sds(), draft_arena_sds(eng),
-                    jax.ShapeDtypeStruct((1, MAXB), i32),
-                    jax.ShapeDtypeStruct((1, C), i32),
-                    jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((), i32),
-                    jax.ShapeDtypeStruct((1,), f32),
-                    jax.ShapeDtypeStruct((1,), i32),
-                    jax.ShapeDtypeStruct((1,), f32),
-                    jax.ShapeDtypeStruct((1,), i32),
+                    jax.ShapeDtypeStruct(
+                        paged_kv.chunk_shape(MAXB, C, False), i32),
                     jax.ShapeDtypeStruct((2,), jnp.uint32))
             return eng._drafter._prefill, args, {}
 

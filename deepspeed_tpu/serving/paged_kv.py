@@ -58,7 +58,10 @@ __all__ = ["BlockAllocator", "BlockAllocatorError", "PrefixCache",
            "build_decode_program", "build_verify_program",
            "build_score_program", "build_cow_program",
            "build_kv_export_program", "build_kv_import_program",
-           "sample_rows", "extend_block_list", "truncate_block_list"]
+           "sample_rows", "extend_block_list", "truncate_block_list",
+           "pack_decode_rows", "unpack_decode_rows", "pack_verify_rows",
+           "unpack_verify_rows", "pack_chunk", "unpack_chunk",
+           "decode_rows_shape", "verify_rows_shape", "chunk_shape"]
 
 
 class BlockAllocatorError(RuntimeError):
@@ -394,6 +397,156 @@ def sample_rows(logits: jax.Array, base_key: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# packed operands: one int32 host array a dispatch
+# ---------------------------------------------------------------------------
+
+# Each layout names a program's operands in the order of its arguments, with
+# the int32 columns each takes. The float32 ones cross as their bit patterns
+# (``ndarray.view(int32)`` on the host, ``bitcast_convert_type`` in the
+# program), so no value is rounded on the way
+_F32_COLUMNS = frozenset({"temperature", "top_p"})
+_SAMPLING_COLUMNS = (("temperature", 1), ("top_k", 1), ("top_p", 1),
+                     ("seeds", 1))
+
+
+def _decode_columns(max_blocks: int):
+    """The decode program's ``(R, MAXB + 7)`` rows."""
+    return (("block_table", max_blocks), ("lengths", 1), ("tokens", 1),
+            *_SAMPLING_COLUMNS, ("steps", 1))
+
+
+def _verify_columns(max_blocks: int, num_tokens: int):
+    """The verify program's ``(R, MAXB + S + 7)`` rows: the decode rows
+    with ``S`` token columns for the one, and ``n_valid`` behind them."""
+    return (("block_table", max_blocks), ("lengths", 1),
+            ("tokens", num_tokens), ("n_valid", 1), *_SAMPLING_COLUMNS,
+            ("steps", 1))
+
+
+def _chunk_columns(max_blocks: int, chunk: int, state_slot: bool):
+    """The prefill-chunk program's flat ``(MAXB + C + 6,)`` vector, one
+    entry longer for a model with recurrent layers (``state_slot``)."""
+    return (("block_table", max_blocks), ("chunk", chunk), ("start", 1),
+            ("n_valid", 1), *_SAMPLING_COLUMNS,
+            *([("state_slot", 1)] if state_slot else []))
+
+
+def _width(columns) -> int:
+    return sum(width for _, width in columns)
+
+
+def decode_rows_shape(rows: int, max_blocks: int):
+    """The shape of ``pack_decode_rows``' array."""
+    return rows, _width(_decode_columns(max_blocks))
+
+
+def verify_rows_shape(rows: int, max_blocks: int, num_tokens: int):
+    """The shape of ``pack_verify_rows``' array."""
+    return rows, _width(_verify_columns(max_blocks, num_tokens))
+
+
+def chunk_shape(max_blocks: int, chunk_tokens: int, state_slot: bool):
+    """The shape of ``pack_chunk``'s vector."""
+    return (_width(_chunk_columns(max_blocks, chunk_tokens, state_slot)),)
+
+
+def _pack(columns, rows: int, operands) -> np.ndarray:
+    """Host side of a layout: a NEW ``(rows, sum of columns)`` int32 array
+    holding ``operands``, which come in the columns' order."""
+    out = np.empty((rows, _width(columns)), np.int32)
+    at = 0
+    for (name, width), value in zip(columns, operands, strict=True):
+        value = np.asarray(value, np.float32 if name in _F32_COLUMNS
+                           else np.int32)
+        out[:, at:at + width] = value.view(np.int32).reshape(rows, width)
+        at += width
+    return out
+
+
+def _unpack(columns, packed: jax.Array):
+    """Program side of a layout: ``packed`` (rows, sum of columns) taken
+    apart into one ``(rows, columns)`` array an operand, in the columns'
+    order, the float32 operands as float32 again."""
+    if (packed.shape[-1] != _width(columns)
+            or min(width for _, width in columns) < 1):
+        raise ValueError(f"packed operands of shape {packed.shape} do not "
+                         f"hold the columns {columns}")
+    out, at = [], 0
+    for name, width in columns:
+        value = packed[:, at:at + width]
+        if name in _F32_COLUMNS:
+            value = jax.lax.bitcast_convert_type(value, jnp.float32)
+        out.append(value)
+        at += width
+    return out
+
+
+def pack_decode_rows(block_table, lengths, tokens, temperature, top_k,
+                     top_p, seeds, steps) -> np.ndarray:
+    """The decode program's operands, ``block_table`` (R, MAXB) and the
+    rest (R,), as its one ``(R, MAXB + 7)`` int32 host array."""
+    rows, max_blocks = np.shape(block_table)
+    return _pack(_decode_columns(max_blocks), rows,
+                 (block_table, lengths, tokens, temperature, top_k, top_p,
+                  seeds, steps))
+
+
+def unpack_decode_rows(packed: jax.Array):
+    """``pack_decode_rows``' inverse, in the program: its arguments, in
+    its order, with their shapes and dtypes."""
+    max_blocks = packed.shape[1] - _width(_decode_columns(0))
+    table, *rest = _unpack(_decode_columns(max_blocks), packed)
+    return (table, *(column[:, 0] for column in rest))
+
+
+def pack_verify_rows(block_table, lengths, tokens, n_valid, temperature,
+                     top_k, top_p, seeds, steps) -> np.ndarray:
+    """The verify program's operands, ``block_table`` (R, MAXB), ``tokens``
+    (R, S) and the rest (R,), as its one ``(R, MAXB + S + 7)`` int32 host
+    array."""
+    rows, max_blocks = np.shape(block_table)
+    return _pack(_verify_columns(max_blocks, np.shape(tokens)[1]), rows,
+                 (block_table, lengths, tokens, n_valid, temperature, top_k,
+                  top_p, seeds, steps))
+
+
+def unpack_verify_rows(packed: jax.Array, num_tokens: int):
+    """``pack_verify_rows``' inverse, in the program."""
+    max_blocks = packed.shape[1] - _width(_verify_columns(0, num_tokens))
+    table, lengths, tokens, *rest = _unpack(
+        _verify_columns(max_blocks, num_tokens), packed)
+    return (table, lengths[:, 0], tokens,
+            *(column[:, 0] for column in rest))
+
+
+def pack_chunk(block_table, chunk, start, n_valid, temperature, top_k,
+               top_p, seeds, state_slot=None) -> np.ndarray:
+    """The prefill-chunk program's operands (``block_table`` (1, MAXB),
+    ``chunk`` (1, C), ``start`` / ``n_valid`` (), the four sampling values
+    (1,) and, for a model with recurrent layers, ``state_slot`` (1,)) as
+    its one flat int32 host array."""
+    operands = [block_table, chunk, start, n_valid, temperature, top_k,
+                top_p, seeds]
+    if state_slot is not None:
+        operands.append(state_slot)
+    return _pack(_chunk_columns(np.shape(block_table)[1], np.shape(chunk)[1],
+                                state_slot is not None), 1, operands)[0]
+
+
+def unpack_chunk(packed: jax.Array, chunk_tokens: int, state_slot: bool):
+    """``pack_chunk``'s inverse, in the program: its arguments in its
+    order, ``state_slot`` None where the layout holds none."""
+    max_blocks = packed.shape[0] - _width(
+        _chunk_columns(0, chunk_tokens, state_slot))
+    table, chunk, start, n_valid, *rest = _unpack(
+        _chunk_columns(max_blocks, chunk_tokens, state_slot), packed[None])
+    rest = [column[0] for column in rest]
+    if not state_slot:
+        rest.append(None)
+    return (table, chunk, start[0, 0], n_valid[0, 0], *rest)
+
+
+# ---------------------------------------------------------------------------
 # the two serving programs
 # ---------------------------------------------------------------------------
 
@@ -406,18 +559,28 @@ def _with_moe_counts(tokens: jax.Array, counts: jax.Array) -> jax.Array:
     return jnp.concatenate([tokens.astype(jnp.int32), counts])
 
 
-def build_prefill_program(cfg, moe_counts: bool = False):
-    """Jitted prefill-chunk program over the paged arena.
+def build_prefill_program(cfg, chunk_tokens: int, moe_counts: bool = False):
+    """Jitted prefill-chunk program over the paged arena, for chunks of
+    ``chunk_tokens`` tokens (C): the one static split of its flat operand
+    vector, as ``num_tokens`` is of ``build_verify_program``'s rows.
 
-    Args (all shapes static per (C, max_blocks) pair):
+    Args (all shapes static per max_blocks):
       params, cache          — model params / paged arena (arena DONATED)
+      packed (MAXB + C + 6,) int32 — ``pack_chunk`` of the operands below,
+                               one entry longer where ``cache`` holds
+                               recurrent state; taken apart in the program
+                               (``unpack_chunk``)
+      base_key               — the engine's sampling key (constant)
+
+    What ``packed`` holds, in its order:
       block_table (1, MAXB)  — the request's physical block ids
       chunk (1, C) int32     — prompt tokens, zero-padded past ``n_valid``
       start () int32         — absolute position of chunk[0]
       n_valid () int32       — real tokens in this chunk (pad writes land in
                                the scratch block; pad logits are never read)
       temperature/top_k/top_p/seeds (1,) — the request's sampling knobs
-      base_key               — the engine's sampling key (constant)
+                               (temperature and top_p as float32 bit
+                               patterns)
       state_slot (1,) int32  — a model with recurrent layers only: the
                                request's slot in the state pools, which ride
                                in ``cache`` beside the pages (its decode row)
@@ -429,11 +592,24 @@ def build_prefill_program(cfg, moe_counts: bool = False):
     programs) ``token`` is (4,): the token, then the chunk's routing counts
     over its ``n_valid`` real tokens (``_with_moe_counts``).
     """
+    step = _chunk_step(cfg, moe_counts)
+
+    def prefill_chunk(params, cache, packed, base_key):
+        return step(params, cache,
+                    *unpack_chunk(packed, chunk_tokens, "state" in cache),
+                    base_key)
+
+    return jax.jit(prefill_chunk, donate_argnums=(1,))
+
+
+def _chunk_step(cfg, moe_counts: bool = False):
+    """The prefill-chunk program behind its unpacking: the operands that
+    ``build_prefill_program`` names, each an argument (``state_slot`` None
+    for a model with no recurrent layers)."""
     from ..models.transformer import forward as model_forward
 
-    def prefill_chunk(params, cache, block_table, chunk, start, n_valid,
-                      temperature, top_k, top_p, seeds, base_key,
-                      state_slot=None):
+    def chunk_step(params, cache, block_table, chunk, start, n_valid,
+                   temperature, top_k, top_p, seeds, state_slot, base_key):
         C = chunk.shape[1]
         offs = jnp.arange(C, dtype=jnp.int32)
         write_mask = (offs < n_valid)[None]
@@ -455,7 +631,7 @@ def build_prefill_program(cfg, moe_counts: bool = False):
             tok = _with_moe_counts(tok, counts[0])
         return tok, last, cache
 
-    return jax.jit(prefill_chunk, donate_argnums=(1,))
+    return chunk_step
 
 
 def build_decode_program(cfg, moe_counts: bool = False):
@@ -464,11 +640,13 @@ def build_decode_program(cfg, moe_counts: bool = False):
     writes land in the scratch block and their sampled tokens are ignored by
     the host — so occupancy changes never respecialize the program.
 
-    Args: params, cache (DONATED), block_table (R, MAXB), lengths (R,) int32
-    (tokens already in cache per row — the incoming token's position),
-    tokens (R,) int32, temperature/top_k/top_p/seeds (R,), steps (R,) int32
-    (each row's output-token index, for the schedule-independent sampling
-    stream), base_key.
+    Args: params, cache (DONATED), packed (R, MAXB + 7) int32, base_key.
+    ``packed`` is ``pack_decode_rows`` of the step's operands, taken apart
+    in the program (``unpack_decode_rows``); a row of it holds, in this
+    order: block_table (MAXB columns), lengths (tokens already in cache —
+    the incoming token's position), tokens, temperature / top_k / top_p /
+    seeds (temperature and top_p as float32 bit patterns), steps (the row's
+    output-token index, for the schedule-independent sampling stream).
     Returns (next_token (R,), cache). An MoE model keeps rows of length 0
     out of the routing; with ``moe_counts`` (the serving engine's own
     program) ``next_token`` is (R + 3,): the tokens, then the step's routing
@@ -477,10 +655,21 @@ def build_decode_program(cfg, moe_counts: bool = False):
     pools in ``cache``; a row that holds nothing is sent to the last slot,
     scratch, and advances nothing.
     """
+    step = _decode_step(cfg, moe_counts)
+
+    def decode(params, cache, packed, base_key):
+        return step(params, cache, *unpack_decode_rows(packed), base_key)
+
+    return jax.jit(decode, donate_argnums=(1,))
+
+
+def _decode_step(cfg, moe_counts: bool = False):
+    """The decode program behind its unpacking: the operands that
+    ``build_decode_program`` names, each an argument."""
     from ..models.transformer import forward as model_forward
 
-    def decode(params, cache, block_table, lengths, tokens,
-               temperature, top_k, top_p, seeds, steps, base_key):
+    def decode_step(params, cache, block_table, lengths, tokens,
+                    temperature, top_k, top_p, seeds, steps, base_key):
         # a row that holds a request has at least its prompt in the cache.
         # The mask also sends an empty row's write to the scratch block,
         # which is where its all-zero table sent it anyway. A dense model
@@ -503,7 +692,7 @@ def build_decode_program(cfg, moe_counts: bool = False):
             nxt = _with_moe_counts(nxt, counts[0])
         return nxt, cache
 
-    return jax.jit(decode, donate_argnums=(1,))
+    return decode_step
 
 
 def build_verify_program(cfg, num_tokens: int):
@@ -529,18 +718,36 @@ def build_verify_program(cfg, num_tokens: int):
     path at any temperature, greedy included (see
     ``serving/speculative.py`` for the acceptance math).
 
-    Args: params, cache (DONATED), block_table (R, MAXB), lengths (R,)
-    int32, tokens (R, S) int32, n_valid (R,) int32,
-    temperature/top_k/top_p/seeds (R,), steps (R,) int32 (each row's FIRST
-    output-token index this iteration), base_key.
+    Args: params, cache (DONATED), packed (R, MAXB + S + 7) int32,
+    base_key. ``packed`` is ``pack_verify_rows`` of the step's operands,
+    taken apart in the program (``unpack_verify_rows``); a row of it holds,
+    in this order: block_table (MAXB columns), lengths, tokens (S columns),
+    n_valid, temperature / top_k / top_p / seeds (temperature and top_p as
+    float32 bit patterns), steps (the row's FIRST output-token index this
+    iteration).
     Returns (sampled (R, S) int32, cache): ``sampled[r, j]`` is the target
     sample after token j — the host emits ``sampled[r, 0..a]`` where ``a``
     is the accepted-draft count.
     """
+    if num_tokens < 2:
+        raise ValueError(f"build_verify_program(num_tokens={num_tokens}): "
+                         "need the pending token plus >= 1 draft slot")
+    step = _verify_step(cfg)
+
+    def verify(params, cache, packed, base_key):
+        return step(params, cache, *unpack_verify_rows(packed, num_tokens),
+                    base_key)
+
+    return jax.jit(verify, donate_argnums=(1,))
+
+
+def _verify_step(cfg):
+    """The verify program behind its unpacking: the operands that
+    ``build_verify_program`` names, each an argument."""
     from ..models.transformer import forward as model_forward
 
-    def verify(params, cache, block_table, lengths, tokens, n_valid,
-               temperature, top_k, top_p, seeds, steps, base_key):
+    def verify_step(params, cache, block_table, lengths, tokens, n_valid,
+                    temperature, top_k, top_p, seeds, steps, base_key):
         R, S = tokens.shape
         offs = jnp.arange(S, dtype=jnp.int32)
         write_mask = offs[None] < n_valid[:, None]
@@ -561,10 +768,7 @@ def build_verify_program(cfg, num_tokens: int):
                               (steps[:, None] + offs[None]).reshape(-1))
         return sampled.reshape(R, S), cache
 
-    if num_tokens < 2:
-        raise ValueError(f"build_verify_program(num_tokens={num_tokens}): "
-                         "need the pending token plus >= 1 draft slot")
-    return jax.jit(verify, donate_argnums=(1,))
+    return verify_step
 
 
 def build_score_program(cfg):

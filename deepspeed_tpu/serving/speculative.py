@@ -196,7 +196,7 @@ class DraftModelDrafter(Drafter):
                 cfg, config.pool_blocks() + 1, config.block_size,
                 self._dtype)
         self._decode = paged_kv.build_decode_program(cfg)
-        self._prefill = paged_kv.build_prefill_program(cfg)
+        self._prefill = paged_kv.build_prefill_program(cfg, self.draft_chunk)
         self._state: Dict[int, _DraftState] = {}
         self._key = jax.random.PRNGKey(0)   # greedy drafts never draw
 
@@ -241,10 +241,9 @@ class DraftModelDrafter(Drafter):
             with self._mesh_mod.ambient(self.engine.mesh):
                 with obs.span("serving/draft_prefill", tokens=int(n_valid)):
                     tok, _last, self._arena = self._prefill(
-                        self.engine.params, self._arena, bt, chunk,
-                        np.asarray(st.length, np.int32),
-                        np.asarray(n_valid, np.int32),
-                        z1, zi, o1, zi, self._key)
+                        self.engine.params, self._arena,
+                        paged_kv.pack_chunk(bt, chunk, st.length, n_valid,
+                                            z1, zi, o1, zi), self._key)
                     np.asarray(tok)     # fence
             self.dispatches += 1
             st.length += n_valid
@@ -305,8 +304,10 @@ class DraftModelDrafter(Drafter):
             with self._mesh_mod.ambient(self.engine.mesh):
                 with obs.span("serving/draft_decode", batch=len(fed)):
                     nxt, self._arena = self._decode(
-                        self.engine.params, self._arena, bt, lengths,
-                        tokens, zR, ziR, oR, ziR, ziR, self._key)
+                        self.engine.params, self._arena,
+                        paged_kv.pack_decode_rows(bt, lengths, tokens, zR,
+                                                  ziR, oR, ziR, ziR),
+                        self._key)
                     nxt = np.asarray(nxt)
             self.dispatches += 1
             for i, st, row, emits in fed:

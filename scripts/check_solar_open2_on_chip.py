@@ -79,6 +79,7 @@ def main(argv=None):
     from deepspeed_tpu.models.transformer import (forward,
                                                   gather_target_logprobs)
     from deepspeed_tpu.parallel import mesh as mesh_mod
+    from deepspeed_tpu.serving import paged_kv
 
     from benchmarks.harness import program
     from benchmarks.harness.serve_cell import serving_config
@@ -142,12 +143,12 @@ def main(argv=None):
                 n = min(C, n_prompt - start)
                 chunk = np.zeros((1, C), np.int32)
                 chunk[0, :n] = full[start:start + n]
+                zero = np.zeros((1,), np.int32)
                 _, _, serving._arena = serving._prefill(
-                    params, serving._arena, table[:1], chunk,
-                    np.asarray(start, np.int32), np.asarray(n, np.int32),
-                    0 * one, np.zeros((1,), np.int32), one,
-                    np.zeros((1,), np.int32), serving._base_rng,
-                    np.zeros((1,), np.int32))
+                    params, serving._arena,
+                    paged_kv.pack_chunk(table[:1], chunk, start, n, 0 * one,
+                                        zero, one, zero, state_slot=zero),
+                    serving._base_rng)
             logp = []
             for p in range(n_prompt, len(full) - 1):
                 # new arrays a step: a dispatched call may still read them

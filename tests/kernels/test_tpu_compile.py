@@ -234,24 +234,19 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
     r = rows
     if kind == "decode":
         return paged_kv.build_decode_program(cfg, **program_options).lower(
-            params, arena, arg((r, MAXB), I32), arg((r,), I32),
-            arg((r,), I32), arg((r,), F32), arg((r,), I32), arg((r,), F32),
-            arg((r,), I32), arg((r,), I32), key)
+            params, arena, arg(paged_kv.decode_rows_shape(r, MAXB), I32), key)
     if kind == "verify":
         return paged_kv.build_verify_program(cfg, SPEC_TOKENS).lower(
-            params, arena, arg((r, MAXB), I32), arg((r,), I32),
-            arg((r, SPEC_TOKENS), I32), arg((r,), I32), arg((r,), F32),
-            arg((r,), I32), arg((r,), F32), arg((r,), I32), arg((r,), I32),
-            key)
+            params, arena,
+            arg(paged_kv.verify_rows_shape(r, MAXB, SPEC_TOKENS), I32), key)
     if kind == "score":
         return paged_kv.build_score_program(cfg).lower(
             params, arena, arg((1, MAXB), I32), arg((1, chunk), I32),
             arg((1, chunk), I32), arg((), I32), arg((), I32))
-    return paged_kv.build_prefill_program(cfg, **program_options).lower(
-        params, arena, arg((1, MAXB), I32), arg((1, CHUNK), I32),
-        arg((), I32), arg((), I32), arg((1,), F32), arg((1,), I32),
-        arg((1,), F32), arg((1,), I32), key,
-        *([arg((1,), I32)] if recurrent else []))
+    return paged_kv.build_prefill_program(
+        cfg, CHUNK, **program_options).lower(
+            params, arena,
+            arg(paged_kv.chunk_shape(MAXB, CHUNK, recurrent), I32), key)
 
 
 def _fusion_roots(text):

@@ -331,8 +331,14 @@ class TestServingFromTheInside:
         in this order: ``prepare``, ``dispatch``, (what is delivered and
         published in the device's shadow,) ``fetch``, ``apply``. The
         ``dispatch`` span says how many of the call's operands were host
-        arrays."""
+        arrays: one, the step's packed operands."""
+        from deepspeed_tpu.serving import paged_kv
+
         spans, _, _ = served
+        # ``serving()``'s 4 rows, 128 / 16 blocks a sequence, chunks of 16
+        packed_shape = {
+            "serving/decode": paged_kv.decode_rows_shape(4, 8),
+            "serving/prefill_chunk": paged_kv.chunk_shape(8, 16, False)}
         ran = [s for s in spans if s["name"] == program
                and s["attrs"].get("rows", s["attrs"].get("tokens"))]
         assert len(ran) >= 4
@@ -349,10 +355,11 @@ class TestServingFromTheInside:
                 assert a["end_s"] <= b["start_s"]
             assert all(c["cat"] == "phase" for c in kids
                        if c["name"].startswith(program))
-            disp = kids[1]["attrs"]
-            # every operand but the key is a numpy array today (ROADMAP A8)
-            assert disp["host_operands"] >= 8
-            assert disp["host_operand_bytes"] >= 4 * disp["host_operands"]
+            # the step's operands packed into one numpy array; the key is
+            # on the device already
+            assert kids[1]["attrs"]["host_operands"] == 1
+            assert kids[1]["attrs"]["host_operand_bytes"] == 4 * int(
+                np.prod(packed_shape[program]))
 
     def test_host_operands_reach_the_capture_as_stats(self, tiny_engine,
                                                       tmp_path):
@@ -362,7 +369,9 @@ class TestServingFromTheInside:
             srv.run()
         stats = [st for evs in cap.events("serving/decode/dispatch").values()
                  for _, _, _, st in evs]
-        assert stats and all(int(st["host_operands"]) >= 8 for st in stats)
+        assert stats and all(
+            (int(st["host_operands"]), int(st["host_operand_bytes"]))
+            == (1, srv._decode_operands([]).nbytes) for st in stats)
         srv.close()
 
     def test_iteration_and_decode_carry_their_counts(self, served):
@@ -500,12 +509,12 @@ def test_three_engine_clock_readings_per_dispatch(tiny_engine, tmp_path,
     clock = FakeClock()
     srv = serving(tiny_engine, clock=clock)
     obs = get_session()
-    args = srv._decode_operands([])
+    packed = srv._decode_operands([])
     before = clock.reads
     p0 = time.perf_counter()
     with obs.span("serving/decode") as parent:
         tok, t0, t1 = srv._run_program(obs, "serving/decode",
-                                       srv._decode, *args, srv._base_rng)
+                                       srv._decode, packed, srv._base_rng)
     p1 = time.perf_counter()
     assert clock.reads - before == 3 and t1 - t0 == 2.0
     assert tok.shape == (4,)
@@ -581,7 +590,7 @@ def _run_with(srv, clock, script):
     by `script`: before the call, after it, tokens on the host."""
     clock.script = list(script)
     srv._run_program(get_session(), "serving/decode", srv._decode,
-                     *srv._decode_operands([]), srv._base_rng)
+                     srv._decode_operands([]), srv._base_rng)
 
 
 @pytest.mark.parametrize("script,held", [

@@ -256,16 +256,15 @@ class TestRejectionStatistics:
         blocks = alloc.alloc(2)
         bt1 = np.zeros((1, MAXB), np.int32)
         bt1[0, :2] = blocks
-        prefill = paged_kv.build_prefill_program(cfg)
+        prefill = paged_kv.build_prefill_program(cfg, 16)
         chunk = np.zeros((1, 16), np.int32)
         chunk[0, :n] = prompt
         key = jax.random.PRNGKey(0)
         z1, zi, o1 = (np.zeros((1,), np.float32), np.zeros((1,), np.int32),
                       np.ones((1,), np.float32))
-        tok, _, arena = prefill(eng.params, arena, bt1, chunk,
-                                np.asarray(0, np.int32),
-                                np.asarray(n, np.int32),
-                                z1, zi, o1, zi, key)
+        tok, _, arena = prefill(
+            eng.params, arena,
+            paged_kv.pack_chunk(bt1, chunk, 0, n, z1, zi, o1, zi), key)
         pending = int(np.asarray(tok)[0])
 
         # target distribution after the pending token: plain (cache-free)
@@ -294,8 +293,10 @@ class TestRejectionStatistics:
             # base-key reuse is the verify contract: randomness comes from
             # fold_in(seeds, token_index), and seeds change per iteration
             sampled, arena = verify(  # tpulint: disable=key-reuse
-                eng.params, arena, btR, lengths, tokens, n_valid, temps,
-                topks, topps, seeds, steps, key)
+                eng.params, arena,
+                paged_kv.pack_verify_rows(btR, lengths, tokens, n_valid,
+                                          temps, topks, topps, seeds, steps),
+                key)
             for t in np.asarray(sampled)[:, 0]:
                 counts[int(t)] = counts.get(int(t), 0) + 1
                 draws += 1
