@@ -41,12 +41,15 @@ arena (``models/transformer.forward``, paged branch).
 
 **Two kinds of state.** Pages are what a softmax layer keeps: ``L`` above
 counts the layers whose mixer is "attn" (``TransformerConfig.layer_pattern``),
-not the model's depth. A recurrent ("kda") layer keeps no pages but, for
-each sequence, a matrix state and a convolution tail of fixed size; they
-live in two pools beside the pages, in the same dict:
+not the model's depth. A layer whose mixer is recurrent
+(``models/transformer.recurrent_layers``: the delta rule "kda", the
+state-space "mamba2") keeps no pages but, for each sequence, a float32
+state and a convolution tail of fixed size; they live in two pools beside
+the pages, in the same dict (``_state_shapes``):
 
-    {"state": (KDA_LAYERS, SLOTS, HEADS, D, D) float32,
-     "tail":  (KDA_LAYERS, SLOTS, TAPS - 1, 3 * HEADS * D)}
+    {"state": (RECURRENT_LAYERS, SLOTS, HEADS, D, D) float32,      "kda"
+              (RECURRENT_LAYERS, SLOTS, GROUPS, N, K P) float32,  "mamba2"
+     "tail":  (RECURRENT_LAYERS, SLOTS, TAPS - 1, the convolution's width)}
 
 A slot belongs to a decode row (``serving/api.py``); the last slot is
 scratch. The programs address a row's slot where it lies, as they address a
@@ -128,9 +131,9 @@ def assert_block_divisible(max_seq_len: int, block_size: int) -> int:
 
 def _paged_layers(cfg) -> int:
     """Layers that keep pages: those whose mixer is softmax attention."""
-    from ..models.transformer import layers_of_kind
+    from ..models.transformer import layers_with_mixer
 
-    return len(layers_of_kind(cfg, "attn"))
+    return len(layers_with_mixer(cfg, "attn"))
 
 
 def _paged_shape(cfg, num_blocks: int, block_size: int):
@@ -140,21 +143,30 @@ def _paged_shape(cfg, num_blocks: int, block_size: int):
 
 def _state_shapes(cfg, state_slots: int, dtype) -> Dict[str, Any]:
     """The second kind of per-sequence state, beside pages: for each
-    recurrent ("kda") layer and slot a float32 matrix state a head and the
-    last ``taps - 1`` rows of the convolution's input (q, k and v side by
-    side, in the model's dtype). ``{}`` for a model with no such layer."""
-    from ..models.transformer import KDA_CONV_TAPS, layers_of_kind
+    recurrent layer and slot a float32 state a head (a delta-rule layer's
+    matrix; a state-space layer's, in ``ops/mamba2.pack_states``' layout)
+    and the last ``taps - 1`` rows of the convolution's input (in the
+    model's dtype). ``{}`` for a model with no such layer."""
+    from ..models.transformer import KDA_CONV_TAPS, recurrent_layers
 
-    n = len(layers_of_kind(cfg, "kda"))
+    mixer, layers = recurrent_layers(cfg)
+    n = len(layers)
     if not n:
         return {}
     if state_slots < 1:
         raise ValueError("a model with recurrent layers needs state_slots: "
                          "a slot a decode row and one scratch")
-    H, d = cfg.kda_num_heads, cfg.kda_head_dim
-    return {"state": ((n, state_slots, H, d, d), jnp.float32),
-            "tail": ((n, state_slots, KDA_CONV_TAPS - 1, 3 * H * d),
-                     dtype)}
+    if mixer == "mamba2":
+        H, P, N = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                   cfg.mamba_state_size)
+        G = cfg.mamba_n_groups
+        state = (G, N, H // G * P)
+        taps, width = cfg.mamba_conv_taps, H * P + 2 * G * N
+    else:
+        H, d = cfg.kda_num_heads, cfg.kda_head_dim
+        state, taps, width = (H, d, d), KDA_CONV_TAPS, 3 * H * d
+    return {"state": ((n, state_slots) + state, jnp.float32),
+            "tail": ((n, state_slots, taps - 1, width), dtype)}
 
 
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype,

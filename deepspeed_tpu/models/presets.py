@@ -5,7 +5,10 @@ since then OLMoE (``olmoe-1b-7b``, ``tiny-olmoe``) and Solar Open 2
 (``solar-open2-250b``, ``tiny-solar-open2``: layers of two kinds, three
 gated delta-rule linear-attention layers to one gated NoPE GQA layer, a
 shared expert beside sigmoid-routed ones; served through ``init_serving``,
-whole or as one chip's share of its experts via ``moe_experts_held``)."""
+whole or as one chip's share of its experts via ``moe_experts_held``) and
+Nemotron 3 Super (``nemotron-3-super-120b-a12b``, ``tiny-nemotron-3-super``:
+layers that are ONE function each, a Mamba-2 state-space mixer, a GQA
+attention mixer or an FFN of experts in a latent; served the same way)."""
 
 from __future__ import annotations
 
@@ -15,6 +18,21 @@ import jax.numpy as jnp
 
 from .core import Model
 from .transformer import TransformerConfig, build_model
+
+
+def nemotron_h_pattern(hybrid_override_pattern: str) -> tuple:
+    """The published ``hybrid_override_pattern`` of the ``nemotron_h``
+    family, a character a layer, as a ``layer_pattern``: ``M`` a Mamba-2
+    mixer, ``*`` an attention mixer, ``E`` an FFN of experts. ``-`` (a dense
+    FFN beside expert ones: FFN kinds that differ by layer) has no form yet."""
+    kinds = {"M": "mamba2_mixer", "*": "attn_mixer", "E": "ffn"}
+    if set(hybrid_override_pattern) - set(kinds):
+        raise NotImplementedError(
+            f"hybrid_override_pattern {hybrid_override_pattern!r}: only "
+            f"{sorted(kinds)} have a layer kind (a dense '-' layer beside "
+            "expert layers needs FFN kinds that differ by layer)")
+    return tuple(kinds[c] for c in hybrid_override_pattern)
+
 
 # family defaults: (norm, position, activation, tie)
 _FAMILIES: Dict[str, Dict[str, Any]] = {
@@ -75,6 +93,19 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
                         moe_score_func="sigmoid", moe_router_bias=True,
                         moe_norm_topk_prob=True,
                         moe_shared_experts=1),
+    # NVIDIA Nemotron 3 Super (nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16
+    # config.json, model_type "nemotron_h"): every layer is x + f(norm(x))
+    # with ONE f, named by a character of hybrid_override_pattern (the size
+    # preset's layer_pattern): a Mamba-2 state-space mixer, GQA attention
+    # with no positional term at all, or LatentMoE: sigmoid scores with a
+    # choice-only bias, the chosen weights renormalised and times
+    # routed_scaling_factor, relu-squared experts that are not gated and run
+    # in a latent, one shared expert on the full width; no biases
+    "nemotron-h": dict(norm="rmsnorm", position="none", activation="relu2",
+                       tie_embeddings=False, norm_eps=1e-5,
+                       moe_score_func="sigmoid", moe_router_bias=True,
+                       moe_norm_topk_prob=True, moe_shared_experts=1,
+                       mamba_conv_taps=4),
 }
 
 # size presets: hidden, layers, heads, kv_heads, vocab, max_seq
@@ -172,6 +203,32 @@ _SIZES: Dict[str, Dict[str, Any]] = {
                              kda_num_heads=4, kda_head_dim=16,
                              kda_gate_rank=16, moe_num_experts=16,
                              moe_top_k=3, vocab_size=256, max_seq_len=128),
+    # nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16 config.json (120.7B, 12B
+    # active): 88 layers, 40 Mamba-2, 40 LatentMoE, 8 attention; num_layers
+    # counts the SOURCE's layers, and fewer run a prefix of the pattern. The
+    # multi-token-prediction module (num_nextn_predict_layers 1) is a
+    # drafter beside the model and is not built
+    "nemotron-3-super-120b-a12b": dict(
+        family="nemotron-h", hidden_size=4096, num_layers=88,
+        layer_pattern=nemotron_h_pattern(
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+            "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+        num_heads=32, num_kv_heads=2, head_size=128,
+        mamba_num_heads=128, mamba_head_dim=64, mamba_n_groups=8,
+        mamba_state_size=128, ffn_hidden_size=2688, moe_latent_size=1024,
+        moe_shared_ffn_hidden_size=5376, moe_num_experts=512, moe_top_k=22,
+        moe_routed_scale=5.0, vocab_size=131072, max_seq_len=262144),
+    # the same first 11 layers (5 mixers of each recurrent kind's state, one
+    # attention layer, 5 expert layers); 4 heads a group, so pairs of heads
+    # lie inside one; 3 experts a token
+    "tiny-nemotron-3-super": dict(
+        family="nemotron-h", hidden_size=64, num_layers=11,
+        layer_pattern=nemotron_h_pattern("MEMEMEM*EME"),
+        num_heads=4, num_kv_heads=2, head_size=32,
+        mamba_num_heads=8, mamba_head_dim=16, mamba_n_groups=2,
+        mamba_state_size=16, ffn_hidden_size=48, moe_latent_size=32,
+        moe_shared_ffn_hidden_size=96, moe_num_experts=16, moe_top_k=3,
+        moe_routed_scale=5.0, vocab_size=256, max_seq_len=128),
     # GShard/Switch-style 8-expert GPT (BASELINE tracked config #4)
     "moe-tiny": dict(family="gpt2", hidden_size=64, num_layers=2, num_heads=4,
                      vocab_size=256, max_seq_len=128, moe_num_experts=8),

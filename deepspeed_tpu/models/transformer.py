@@ -31,7 +31,17 @@ from .core import (EMBED, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ, VOCAB,
                    cast_floating)
 
 
-MIXERS = ("attn", "kda")
+# the mixers that keep, a sequence, a state of fixed size and a convolution
+# tail in place of pages: THE list that kv_cache and the serving layer ask
+RECURRENT_MIXERS = ("kda", "mamba2")
+# a layer's kind -> (its mixer or None, whether the model's FFN follows it).
+# "attn" and "kda" are a whole block, norm, mixer, add, norm, FFN, add; the
+# others are ONE function under one norm and one add, for a family whose
+# layers are a mixer alone or an FFN alone
+LAYER_KINDS = {"attn": ("attn", True), "kda": ("kda", True),
+               "attn_mixer": ("attn", False),
+               "mamba2_mixer": ("mamba2", False),
+               "ffn": (None, True)}
 # taps of the "kda" mixer's depthwise convolution over time (the published
 # short_conv_kernel_size of the one family that has the mixer)
 KDA_CONV_TAPS = 4
@@ -52,7 +62,8 @@ class TransformerConfig:
     position: str = "learned"               # learned | rope | alibi | none
     #   (none: no positional term anywhere; causality alone orders tokens)
     embed_norm: bool = False                # LayerNorm after embedding (BLOOM)
-    activation: str = "gelu"                # gelu | relu | swiglu
+    activation: str = "gelu"                # gelu | relu | relu2 | swiglu
+    #   (relu2: relu squared, not gated)
     tie_embeddings: bool = True
     causal: bool = True                     # False: bidirectional encoder
     parallel_residual: bool = False         # x + attn(ln1(x)) + mlp(ln2(x))
@@ -125,8 +136,16 @@ class TransformerConfig:
     #   FIRST moe_experts_held, and an assignment to an absent expert is
     #   dropped before the expert matmuls (parallel/moe._grouped_experts):
     #   the layer computes its own experts' part of the result. 0 = all
-    moe_shared_experts: int = 0       # a dense SwiGLU of width
-    #   moe_shared_experts * ffn_hidden_size added to every token, unweighted
+    moe_shared_experts: int = 0       # a dense FFN of the model's
+    #   activation and of width moe_shared_experts * ffn_hidden_size (or
+    #   moe_shared_ffn_hidden_size) added to every token, unweighted
+    moe_shared_ffn_hidden_size: Optional[int] = None
+    moe_latent_size: int = 0          # >0: the routed experts run in a
+    #   latent of this width: one projection hidden -> latent before them
+    #   and one back after their weighted sum (experts latent x ffn); the
+    #   router and the shared expert read the full width
+    moe_routed_scale: float = 1.0     # the chosen weights, after their
+    #   renormalisation, times this (the published routed_scaling_factor)
     moe_score_func: str = "softmax"   # softmax | sigmoid: the router's scores
     #   over all experts, in float32
     moe_router_bias: bool = False     # a per-expert bias added to the scores
@@ -134,17 +153,27 @@ class TransformerConfig:
     dense_ffn_hidden_size: Optional[int] = None   # the width of a family's
     #   leading dense layers (its first_k_dense_replace). No preset has such
     #   layers yet, so no layer reads it; a configuration states it
-    # layers of more than one kind. ``layer_pattern`` names the MIXER of each
-    # layer of one period, cycled over the depth (num_layers a multiple of
-    # it); () = every layer "attn". "attn": softmax attention as configured
-    # above; "kda": the gated delta rule with per-channel decay
-    # (ops/kda.py), kda_num_heads heads of kda_head_dim for keys and values
-    # alike, a depthwise causal convolution of KDA_CONV_TAPS taps and SiLU on
-    # q, k and v, decay and output gate through low-rank pairs of
-    # kda_gate_rank, beta = 2 * sigmoid (negative eigenvalues allowed). Each
-    # kind keeps its own stacked parameter tree and its own cache entry
-    # (pages / a matrix state and a convolution tail)
+    # layers of more than one kind. ``layer_pattern`` names the KIND
+    # (``LAYER_KINDS``) of each layer of one period, cycled over the depth
+    # (num_layers a multiple of it); a pattern LONGER than the depth is a
+    # family's whole published order, of which the first num_layers are run
+    # as one period; () = every layer "attn". "attn": softmax attention as
+    # configured above, then the FFN; "kda": the gated delta rule with
+    # per-channel decay (ops/kda.py), kda_num_heads heads of kda_head_dim
+    # for keys and values alike, a depthwise causal convolution of
+    # KDA_CONV_TAPS taps and SiLU on q, k and v, decay and output gate
+    # through low-rank pairs of kda_gate_rank, beta = 2 * sigmoid (negative
+    # eigenvalues allowed), then the FFN; "attn_mixer", "mamba2_mixer" and
+    # "ffn": one function alone (the Mamba-2 state-space mixer:
+    # ops/mamba2.py, ``_mamba2_mixer``). Each kind keeps its own stacked
+    # parameter tree and each mixer its own cache entry (pages / a state
+    # and a convolution tail)
     layer_pattern: tuple = ()
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    mamba_state_size: int = 0
+    mamba_conv_taps: int = 4
     head_size: Optional[int] = None         # None => hidden_size / num_heads
     attn_gate: bool = False                 # y = (attn * sigmoid(x W_g)) W_o
     kda_num_heads: int = 0
@@ -165,10 +194,17 @@ class TransformerConfig:
                 self.ffn_hidden_size = 4 * self.hidden_size
         assert self.head_size or self.hidden_size % self.num_heads == 0
         assert self.num_heads % self.num_kv_heads == 0
-        self.layer_pattern = tuple(self.layer_pattern)
+        self.layer_pattern = tuple(self.layer_pattern)[:self.num_layers]
         if self.layer_pattern:
-            assert set(self.layer_pattern) <= set(MIXERS), self.layer_pattern
+            assert set(self.layer_pattern) <= set(LAYER_KINDS), \
+                self.layer_pattern
             assert self.num_layers % len(self.layer_pattern) == 0
+            # a mixer's cache entry and an FFN's expert stack are indexed by
+            # the layer's place among its KIND: one kind a mixer
+            mixers = [LAYER_KINDS[k][0] for k in set(self.layer_pattern)]
+            assert len(mixers) == len(set(mixers)), self.layer_pattern
+            assert len(set(mixers) & set(RECURRENT_MIXERS)) <= 1, \
+                "the state pools hold one kind of recurrent state"
         if self.moe_experts_held:
             assert 0 < self.moe_experts_held <= self.moe_num_experts
 
@@ -181,7 +217,14 @@ class TransformerConfig:
         """The router is one that only the dropless path of
         ``parallel/moe.moe_mlp`` computes (no capacity plan, no aux loss)."""
         return bool(self.moe_experts_held or self.moe_router_bias
-                    or self.moe_score_func != "softmax")
+                    or self.moe_score_func != "softmax"
+                    or self.moe_latent_size or self.moe_routed_scale != 1.0)
+
+    @property
+    def shared_ffn_hidden_size(self) -> int:
+        """The width of the shared expert's FFN."""
+        return (self.moe_shared_ffn_hidden_size
+                or self.moe_shared_experts * self.ffn_hidden_size)
 
     @property
     def experts_held(self) -> int:
@@ -243,7 +286,7 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
 
 
 def layer_kinds(cfg: TransformerConfig) -> Tuple[str, ...]:
-    """The mixer kind of each layer of one period of the stack."""
+    """The kind (``LAYER_KINDS``) of each layer of one period of the stack."""
     return cfg.layer_pattern or ("attn",)
 
 
@@ -252,6 +295,33 @@ def layers_of_kind(cfg: TransformerConfig, kind: str) -> Tuple[int, ...]:
     pattern = layer_kinds(cfg)
     return tuple(i for i in range(cfg.num_layers)
                  if pattern[i % len(pattern)] == kind)
+
+
+def layers_with_mixer(cfg: TransformerConfig, mixer: str) -> Tuple[int, ...]:
+    """The layers (global indices, ascending) whose mixer is ``mixer``,
+    whether or not an FFN follows it in the layer."""
+    return tuple(i for kind in set(layer_kinds(cfg))
+                 if LAYER_KINDS[kind][0] == mixer
+                 for i in layers_of_kind(cfg, kind))
+
+
+def recurrent_layers(cfg: TransformerConfig
+                     ) -> Tuple[Optional[str], Tuple[int, ...]]:
+    """(the model's recurrent mixer, the layers that have it): THE answer to
+    "does this layer keep a state and a convolution tail a sequence, not
+    pages". ``(None, ())`` for a model of softmax layers alone."""
+    for mixer in RECURRENT_MIXERS:
+        layers = layers_with_mixer(cfg, mixer)
+        if layers:
+            return mixer, layers
+    return None, ()
+
+
+def ffn_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
+    """The layers that have an FFN (every one, but for a family whose
+    layers are one function each)."""
+    return tuple(i for kind in set(layer_kinds(cfg)) if LAYER_KINDS[kind][1]
+                 for i in layers_of_kind(cfg, kind))
 
 
 def layer_stacks(layers: Dict[str, Any], cfg: TransformerConfig
@@ -290,11 +360,13 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
             k = jax.random.fold_in(jax.random.fold_in(base_key, tag), li)
             return jax.random.uniform(k, shape, jnp.float32, lo, hi)
 
-        layer: Dict[str, Any] = {
-            "ln1": {"scale": jnp.ones((H,), cfg.dtype)},
-            "ln2": {"scale": jnp.ones((H,), cfg.dtype)},
-        }
-        if kind == "attn":
+        mixer, has_ffn = LAYER_KINDS[kind]
+        layer: Dict[str, Any] = {}
+        if mixer is not None:
+            layer["ln1"] = {"scale": jnp.ones((H,), cfg.dtype)}
+        if has_ffn:
+            layer["ln2"] = {"scale": jnp.ones((H,), cfg.dtype)}
+        if mixer == "attn":
             layer["attn"] = {
                 "wq": normal(0, (H, N * D)),
                 "wk": normal(1, (H, K * D)),
@@ -306,7 +378,27 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                 layer["attn"]["k_norm"] = jnp.ones((K * D,), cfg.dtype)
             if cfg.attn_gate:
                 layer["attn"]["wg"] = normal(11, (H, N * D))
-        else:
+        elif mixer == "mamba2":
+            MH, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+            inner = MH * P
+            conv = inner + 2 * cfg.mamba_n_groups * cfg.mamba_state_size
+            layer["mamba2"] = {
+                # [z | x B C | dt], as the published in_proj lays them
+                "w_in": normal(40, (H, inner + conv + MH)),
+                # taps of the depthwise convolution over time, oldest first
+                "conv_w": normal(41, (cfg.mamba_conv_taps, conv), 0.5),
+                "conv_b": normal(42, (conv,)),
+                # the published init: a step dt of 0.001 to 0.1 a head (its
+                # inverse softplus, floored at 1e-4), a rate of 1 to 16, D 1
+                "dt_bias": (lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(
+                    jnp.maximum(jnp.exp(uniform(
+                        43, (MH,), jnp.log(1e-3), jnp.log(0.1))), 1e-4)),
+                "A_log": jnp.log(uniform(44, (MH,), 1.0, 16.0)),
+                "D": jnp.ones((MH,), jnp.float32),
+                "norm": jnp.ones((inner,), cfg.dtype),
+                "w_out": normal(45, (inner, H), resid_std),
+            }
+        elif mixer == "kda":
             KH, KD, r = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_gate_rank
             W = KH * KD
             layer["kda"] = {
@@ -327,7 +419,9 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                     jnp.exp(uniform(33, (W,), jnp.log(1e-3), jnp.log(0.1)))),
                 "o_norm": jnp.ones((KD,), cfg.dtype),
             }
-        if E > 0:
+        if not has_ffn:
+            pass            # a mixer alone: no FFN of any kind below
+        elif E > 0:
             layer["router"] = normal(4, (H, E))
             if cfg.moe_router_bias:
                 # nonzero, or the choice-only bias would go untested; small,
@@ -337,11 +431,17 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                 layer["router_bias"] = normal(12, (E,), 0.01).astype(
                     jnp.float32)
             if cfg.moe_shared_experts:
-                Fs = cfg.moe_shared_experts * F
+                Fs = cfg.shared_ffn_hidden_size
                 layer["shared"] = {
-                    "w_gate": normal(13, (H, Fs)),
                     "w_up": normal(14, (H, Fs)),
                     "w_down": normal(15, (Fs, H), resid_std),
+                }
+                if cfg.activation == "swiglu":
+                    layer["shared"]["w_gate"] = normal(13, (H, Fs))
+            if cfg.moe_latent_size:
+                layer["latent"] = {
+                    "w_in": normal(16, (H, cfg.moe_latent_size)),
+                    "w_out": normal(17, (cfg.moe_latent_size, H), resid_std),
                 }
             if cfg.moe_use_residual:
                 layer["res_mlp"] = {
@@ -352,17 +452,16 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                 }
                 layer["res_coef"] = {"w": normal(7, (H, 2)),
                                      "b": jnp.zeros((2,), cfg.dtype)}
+            He = cfg.moe_latent_size or H       # the experts' own width
+            layer["mlp"] = {
+                "w_up": normal(9, (held, He, F)),
+                # in a latent it is the projection out of it that writes
+                # to the residual stream, not an expert's own
+                "w_down": normal(10, (held, F, He),
+                                 std if cfg.moe_latent_size else resid_std),
+            }
             if cfg.activation == "swiglu":
-                layer["mlp"] = {
-                    "w_gate": normal(8, (held, H, F)),
-                    "w_up": normal(9, (held, H, F)),
-                    "w_down": normal(10, (held, F, H), resid_std),
-                }
-            else:
-                layer["mlp"] = {
-                    "w_up": normal(9, (held, H, F)),
-                    "w_down": normal(10, (held, F, H), resid_std),
-                }
+                layer["mlp"]["w_gate"] = normal(8, (held, He, F))
         elif cfg.activation == "swiglu":
             layer["mlp"] = {
                 "w_gate": normal(8, (H, F)),
@@ -377,9 +476,10 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                 "b_down": jnp.zeros((H,), cfg.dtype),
             }
         if cfg.norm == "layernorm":
-            layer["ln1"]["bias"] = jnp.zeros((H,), cfg.dtype)
-            layer["ln2"]["bias"] = jnp.zeros((H,), cfg.dtype)
-            if kind == "attn":
+            for ln in ("ln1", "ln2"):
+                if ln in layer:
+                    layer[ln]["bias"] = jnp.zeros((H,), cfg.dtype)
+            if mixer == "attn":
                 layer["attn"]["bq"] = jnp.zeros((N * D,), cfg.dtype)
                 layer["attn"]["bk"] = jnp.zeros((K * D,), cfg.dtype)
                 layer["attn"]["bv"] = jnp.zeros((K * D,), cfg.dtype)
@@ -417,16 +517,18 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
            "wg1": (LAYERS, EMBED, None), "wg2": (LAYERS, None, HEADS),
            "wb": (LAYERS, EMBED, None), "A_log": (LAYERS, None),
            "dt_bias": (LAYERS, HEADS), "o_norm": (LAYERS, None)}
+    mamba2 = {"w_in": (LAYERS, EMBED, HEADS), "w_out": (LAYERS, HEADS, EMBED),
+              "conv_w": (LAYERS, None, HEADS), "conv_b": (LAYERS, HEADS),
+              "norm": (LAYERS, HEADS),
+              **{a: (LAYERS, None) for a in ("dt_bias", "A_log", "D")}}
     from .core import EXPERT
 
     if cfg.moe_num_experts > 0:
+        wide = None if cfg.moe_latent_size else EMBED   # the experts' width
+        mlp = {"w_up": (LAYERS, EXPERT, wide, MLP),
+               "w_down": (LAYERS, EXPERT, MLP, wide)}
         if cfg.activation == "swiglu":
-            mlp = {"w_gate": (LAYERS, EXPERT, EMBED, MLP),
-                   "w_up": (LAYERS, EXPERT, EMBED, MLP),
-                   "w_down": (LAYERS, EXPERT, MLP, EMBED)}
-        else:
-            mlp = {"w_up": (LAYERS, EXPERT, EMBED, MLP),
-                   "w_down": (LAYERS, EXPERT, MLP, EMBED)}
+            mlp["w_gate"] = (LAYERS, EXPERT, wide, MLP)
     elif cfg.activation == "swiglu":
         mlp = {"w_gate": (LAYERS, EMBED, MLP), "w_up": (LAYERS, EMBED, MLP),
                "w_down": (LAYERS, MLP, EMBED)}
@@ -436,15 +538,19 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     ln = {"scale": (LAYERS, EMBED)}
     if cfg.norm == "layernorm":
         ln = {"scale": (LAYERS, EMBED), "bias": (LAYERS, EMBED)}
-    layer_axes = {"ln1": dict(ln), "ln2": dict(ln), "mlp": mlp}
+    layer_axes = ffn_axes = {"ln2": dict(ln), "mlp": mlp}
     if cfg.moe_num_experts > 0:
         layer_axes["router"] = (LAYERS, EMBED, None)
         if cfg.moe_router_bias:
             layer_axes["router_bias"] = (LAYERS, None)
         if cfg.moe_shared_experts:
-            layer_axes["shared"] = {"w_gate": (LAYERS, EMBED, MLP),
-                                    "w_up": (LAYERS, EMBED, MLP),
+            layer_axes["shared"] = {"w_up": (LAYERS, EMBED, MLP),
                                     "w_down": (LAYERS, MLP, EMBED)}
+            if cfg.activation == "swiglu":
+                layer_axes["shared"]["w_gate"] = (LAYERS, EMBED, MLP)
+        if cfg.moe_latent_size:
+            layer_axes["latent"] = {"w_in": (LAYERS, EMBED, None),
+                                    "w_out": (LAYERS, None, EMBED)}
         if cfg.moe_use_residual:
             layer_axes["res_mlp"] = {
                 "w_up": (LAYERS, EMBED, MLP), "b_up": (LAYERS, MLP),
@@ -452,8 +558,13 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
             layer_axes["res_coef"] = {"w": (LAYERS, EMBED, None),
                                       "b": (LAYERS, None)}
     kinds = sorted(set(layer_kinds(cfg)))
-    mixers = {"attn": attn, "kda": kda}
-    by_kind = {kind: {**layer_axes, kind: mixers[kind]} for kind in kinds}
+    mixers = {"attn": attn, "kda": kda, "mamba2": mamba2}
+    by_kind = {}
+    for kind in kinds:
+        mixer, has_ffn = LAYER_KINDS[kind]
+        by_kind[kind] = {**(ffn_axes if has_ffn else {}),
+                         **({} if mixer is None
+                            else {"ln1": dict(ln), mixer: mixers[mixer]})}
     axes: Dict[str, Any] = {
         "embed": {"tokens": (VOCAB, EMBED)},
         "layers": by_kind if len(kinds) > 1 else by_kind[kinds[0]],
@@ -814,6 +925,19 @@ def _swiglu(cfg: "TransformerConfig", h: jax.Array,
                     cfg.dtype, a8=cfg.a8_decode)
 
 
+def _plain_ffn(cfg: "TransformerConfig", h: jax.Array,
+               w: Dict[str, Any]) -> jax.Array:
+    """``down(act(up(h)))`` with no gate and no bias: the shared expert of a
+    family whose experts are not gated (``relu2``: relu squared; anything
+    else: the experts' tanh GELU, ``parallel/moe.expert_activation``)."""
+    from ..parallel.moe import expert_activation
+
+    up = _qeinsum("bsh,hf->bsf", h, w["w_up"], cfg.dtype, a8=cfg.a8_decode)
+    inner = expert_activation(cfg.activation, up.astype(jnp.float32))
+    return _qeinsum("bsf,fh->bsh", inner.astype(cfg.dtype), w["w_down"],
+                    cfg.dtype, a8=cfg.a8_decode)
+
+
 def _dropout(x: jax.Array, cfg: "TransformerConfig", salt: int) -> jax.Array:
     """Inverted dropout on a residual-path tensor; active only when the
     engine enabled it (training). Key derives from the tensor's content —
@@ -994,6 +1118,34 @@ def pld_gate(cfg: TransformerConfig, h: jax.Array, h_new: jax.Array,
     return h_mixed, aux * gate / keep_p
 
 
+def _single_chip_kernels() -> bool:
+    """A recurrent mixer's one-token Pallas kernel runs where kernels are
+    active and no mesh of several devices makes XLA partition the layer."""
+    from ..ops import registry
+    from ..parallel.mesh import ambient_mesh
+
+    mesh = ambient_mesh()
+    return registry.kernels_active() and (mesh is None or mesh.size == 1)
+
+
+def _with_conv_history(x: jax.Array, cache: Optional[Dict[str, jax.Array]],
+                       kind_layer, state_slots, positions: jax.Array,
+                       taps: int) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """A recurrent mixer's convolution input ``x`` (B, S, W) behind the
+    ``taps - 1`` rows that came before it -> ``(ext (B, taps - 1 + S, W),
+    fresh)``: zeros with no cache and for a row whose first position is 0
+    (``fresh`` (B,), None with no cache), else the row's slot of the pool
+    ``"tail"``."""
+    if cache is None:
+        tail = jnp.zeros((x.shape[0], taps - 1, x.shape[-1]), x.dtype)
+        fresh = None
+    else:
+        fresh = positions[:, 0] == 0
+        tail = jnp.where(fresh[:, None, None], 0,
+                         cache["tail"][kind_layer, state_slots])
+    return jnp.concatenate([tail.astype(x.dtype), x], axis=1), fresh
+
+
 def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                cache: Optional[Dict[str, jax.Array]],
                kind_layer: Optional[jax.Array],
@@ -1022,8 +1174,6 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     holds nothing) write nothing - beta 0, decay 1, and the tail is taken
     from the last REAL rows."""
     from ..ops import kda as kda_ops
-    from ..ops import registry
-    from ..parallel.mesh import ambient_mesh
 
     f32 = jnp.float32
     B, S, _ = h.shape
@@ -1032,14 +1182,8 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     qkv = jnp.concatenate(
         [jnp.einsum("bsh,hd->bsd", h, p[w]) for w in ("wq", "wk", "wv")],
         axis=-1)                                            # (B, S, 3W)
-    if cache is None:
-        tail = jnp.zeros((B, taps - 1, 3 * W), qkv.dtype)
-        fresh = None
-    else:
-        fresh = positions[:, 0] == 0
-        tail = jnp.where(fresh[:, None, None], 0,
-                         cache["tail"][kind_layer, state_slots])
-    ext = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
+    ext, fresh = _with_conv_history(qkv, cache, kind_layer, state_slots,
+                                    positions, taps)
     conv = jnp.concatenate([p["conv_q"], p["conv_k"], p["conv_v"]],
                            axis=-1).astype(f32)             # (taps, 3W)
     mixed = sum(conv[j] * ext[:, j:j + S].astype(f32) for j in range(taps))
@@ -1070,10 +1214,7 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                                  jnp.zeros((B, KH, KD, KD), f32))
     else:
         if S == 1:
-            mesh = ambient_mesh()
-            step = (kda_ops.kda_decode_step
-                    if registry.kernels_active()
-                    and (mesh is None or mesh.size == 1)
+            step = (kda_ops.kda_decode_step if _single_chip_kernels()
                     else kda_ops.reference_kda_decode_step)
             o, states = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
                              cache["state"], kind_layer, state_slots)
@@ -1084,20 +1225,102 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
             o, end = kda_ops.kda_chunk(q, k, v, g, beta, start)
             states = cache["state"].at[kind_layer, state_slots].set(
                 end.astype(cache["state"].dtype))
-        # the last taps - 1 rows that exist, of the history and this call
-        n_real = (jnp.full((B,), S, jnp.int32) if real is None
-                  else real.sum(axis=1, dtype=jnp.int32))
-        new_tail = jax.vmap(lambda rows, n: lax.dynamic_slice_in_dim(
-            rows, n, taps - 1, axis=0))(ext, n_real)
         new_cache = {**cache, "state": states,
                      "tail": cache["tail"].at[kind_layer, state_slots].set(
-                         new_tail.astype(cache["tail"].dtype))}
+                         _last_real_rows(ext, real, taps - 1).astype(
+                             cache["tail"].dtype))}
 
     o = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.norm_eps) \
         * p["o_norm"].astype(f32)
     y = (o.reshape(B, S, W)
          * jax.nn.sigmoid(low_rank("wg1", "wg2"))).astype(h.dtype)
     return jnp.einsum("bsd,dh->bsh", y, p["wo"]), new_cache
+
+
+def _mamba2_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+                  cache: Optional[Dict[str, jax.Array]],
+                  kind_layer: Optional[jax.Array],
+                  state_slots: Optional[jax.Array], positions: jax.Array,
+                  real: Optional[jax.Array]
+                  ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """The "mamba2" mixer of ``_layer_forward``: the Mamba-2 state-space
+    recurrence (``ops/mamba2.py``) over the normed input ``h`` (B, S, H) ->
+    (its contribution to the residual, new cache).
+
+    ``[z | xBC | dt] = h W_in``; on ``xBC`` a depthwise causal convolution
+    over time with a bias, then SiLU, and the split into ``x`` (heads x head
+    dim) and the groups' ``B`` and ``C``; ``dt = softplus(dt + dt_bias)`` and
+    ``A = -exp(A_log)`` a head; the recurrence on a float32 state, plus
+    ``D x``; ``y = RMSNorm_grouped(y * silu(z)) W_out``, the norm over each
+    group's channels.
+
+    The cache, ``kind_layer``, ``state_slots``, a row's start at position 0
+    and ``real`` are ``_kda_mixer``'s, with ``"state"`` in the layout of
+    ``ops/mamba2.pack_states`` and a tail of ``xBC``'s width; a token that
+    does not exist has ``dt`` 0 and writes nothing."""
+    from ..ops import mamba2 as ssm
+
+    f32 = jnp.float32
+    B, S, _ = h.shape
+    MH, P, G, N, taps = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                         cfg.mamba_n_groups, cfg.mamba_state_size,
+                         cfg.mamba_conv_taps)
+    inner = MH * P
+    z, xbc, dt = jnp.split(jnp.einsum("bsh,hd->bsd", h, p["w_in"]),
+                           [inner, 2 * inner + 2 * G * N], axis=-1)
+    ext, fresh = _with_conv_history(xbc, cache, kind_layer, state_slots,
+                                    positions, taps)
+    conv = p["conv_w"].astype(f32)
+    mixed = jax.nn.silu(p["conv_b"].astype(f32) + sum(
+        conv[j] * ext[:, j:j + S].astype(f32) for j in range(taps)))
+    x, Bm, Cm = jnp.split(mixed, [inner, inner + G * N], axis=-1)
+    x = x.reshape(B, S, MH, P)
+    Bm, Cm = Bm.reshape(B, S, G, N), Cm.reshape(B, S, G, N)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+    if real is not None:
+        dt = jnp.where(real[..., None], dt, 0.0)
+    A = -jnp.exp(p["A_log"].astype(f32))
+
+    new_cache = None
+    if cache is None:
+        y, _ = ssm.mamba2_chunk(x, dt, A, Bm, Cm,
+                                jnp.zeros((B, G, N, MH // G * P), f32),
+                                packed=True)
+    else:
+        if S == 1:
+            step = (ssm.mamba2_decode_step if _single_chip_kernels()
+                    else ssm.reference_mamba2_decode_step)
+            y, states = step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                             cache["state"], kind_layer, state_slots)
+            y = y[:, None]
+        else:
+            start = jnp.where(fresh[:, None, None, None], 0.0,
+                              cache["state"][kind_layer, state_slots])
+            y, end = ssm.mamba2_chunk(x, dt, A, Bm, Cm, start, packed=True)
+            states = cache["state"].at[kind_layer, state_slots].set(
+                end.astype(cache["state"].dtype))
+        new_cache = {**cache, "state": states,
+                     "tail": cache["tail"].at[kind_layer, state_slots].set(
+                         _last_real_rows(ext, real, taps - 1).astype(
+                             cache["tail"].dtype))}
+
+    y = (y + p["D"].astype(f32)[:, None] * x).reshape(B, S, inner)
+    y = (y * jax.nn.silu(z.astype(f32))).reshape(B, S, G, inner // G)
+    y = y * lax.rsqrt((y * y).mean(-1, keepdims=True) + cfg.norm_eps)
+    y = (y.reshape(B, S, inner) * p["norm"].astype(f32)).astype(h.dtype)
+    return jnp.einsum("bsd,dh->bsh", y, p["w_out"]), new_cache
+
+
+def _last_real_rows(ext: jax.Array, real: Optional[jax.Array],
+                    n: int) -> jax.Array:
+    """A convolution's new tail: of ``ext`` (B, n + S, W), the old tail and
+    then this call's rows, the last ``n`` rows that exist (``real`` (B, S)
+    marks a prefix of each row's tokens; None: all of them)."""
+    B = ext.shape[0]
+    n_real = (jnp.full((B,), ext.shape[1] - n, jnp.int32) if real is None
+              else real.sum(axis=1, dtype=jnp.int32))
+    return jax.vmap(lambda rows, k: lax.dynamic_slice_in_dim(
+        rows, k, n, axis=0))(ext, n_real)
 
 
 def _softmax_mixer(cfg: TransformerConfig, h: jax.Array,
@@ -1335,6 +1558,66 @@ def _softmax_mixer(cfg: TransformerConfig, h: jax.Array,
     return attn_out, new_cache
 
 
+def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
+                mixer: str, has_ffn: bool, mask, positions, cache,
+                static_prefill, key_positions, window, block_table,
+                paged_write_mask, paged_layer, state_slots):
+    """The first half of ``_layer_forward`` for a layer that has a mixer:
+    norm, mixer and its add -> ``(x, the FFN's normed input or None where no
+    FFN follows, the mixer's output, new cache)``. ``x`` comes back with the
+    mixer's output added but under ``parallel_residual``, which adds it
+    beside the FFN's."""
+    post_ln = cfg.norm_position == "post"
+    if post_ln:
+        h = x      # post-LN (BERT family): raw input feeds attention; the
+        #            norm is applied after each residual add below
+    else:
+        h = _norm(x, layer["ln1"]["scale"], layer["ln1"].get("bias"),
+                  cfg.norm, cfg.norm_eps)
+    if cfg.act_quant_bits and cache is None:
+        # activation QAT (reference QuantAct): quantize the attention input
+        from ..compression.compress import fake_quant_activation
+
+        h = fake_quant_activation(h, cfg.act_quant_bits)
+    if mixer == "kda":
+        attn_out, new_cache = _kda_mixer(
+            cfg, h, layer["kda"], cache, paged_layer, state_slots,
+            positions, paged_write_mask)
+    elif mixer == "mamba2":
+        attn_out, new_cache = _mamba2_mixer(
+            cfg, h, layer["mamba2"], cache, paged_layer, state_slots,
+            positions, paged_write_mask)
+    else:
+        attn_out, new_cache = _softmax_mixer(
+            cfg, h, layer, mask, positions, cache, static_prefill,
+            key_positions, window, block_table, paged_write_mask,
+            paged_layer)
+    if cache is None:
+        attn_out = _dropout(attn_out, cfg, salt=31)
+    if cache is None:
+        from ..parallel.sequence import constrain, hidden_spec, sequence_parallel_enabled
+
+        if sequence_parallel_enabled():
+            attn_out = constrain(attn_out, hidden_spec())
+    if not has_ffn:
+        return x + attn_out, None, attn_out, new_cache
+    if cfg.parallel_residual:
+        # GPT-J/NeoX: x + attn(ln1(x)) + mlp(ln2(x)) — one residual add,
+        # the MLP reads the ORIGINAL x through its own norm
+        h = _norm(x, layer["ln2"]["scale"], layer["ln2"].get("bias"),
+                  cfg.norm, cfg.norm_eps)
+    elif post_ln:
+        # BERT family: norm AFTER the residual add; the normed sum feeds MLP
+        x = _norm(x + attn_out, layer["ln1"]["scale"],
+                  layer["ln1"].get("bias"), cfg.norm, cfg.norm_eps)
+        h = x
+    else:
+        x = x + attn_out
+        h = _norm(x, layer["ln2"]["scale"], layer["ln2"].get("bias"),
+                  cfg.norm, cfg.norm_eps)
+    return x, h, attn_out, new_cache
+
+
 def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                    mask: Optional[jax.Array],
                    positions: jax.Array,
@@ -1389,48 +1672,27 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
 
     Returns ``(x, new_cache, aux)``; with ``moe_counts`` (an MoE model) a
     fourth value, this layer's routing counts (``parallel/moe.moe_mlp``)."""
+    mixer, has_ffn = LAYER_KINDS[kind]
     post_ln = cfg.norm_position == "post"
-    if post_ln:
-        h = x      # post-LN (BERT family): raw input feeds attention; the
-        #            norm is applied after each residual add below
+    if not (mixer and has_ffn) and (post_ln or cfg.parallel_residual):
+        raise NotImplementedError(
+            "a layer that is a mixer alone or an FFN alone is x + f(norm(x)): "
+            "it has no post-norm or parallel-residual form")
+    new_cache = cache
+    if mixer is not None:
+        x, h, attn_out, new_cache = _mixer_half(
+            cfg, x, layer, mixer, has_ffn, mask, positions, cache,
+            static_prefill, key_positions, window, block_table,
+            paged_write_mask, paged_layer, state_slots)
     else:
-        h = _norm(x, layer["ln1"]["scale"], layer["ln1"].get("bias"),
-                  cfg.norm, cfg.norm_eps)
-    if cfg.act_quant_bits and cache is None:
-        # activation QAT (reference QuantAct): quantize the attention input
-        from ..compression.compress import fake_quant_activation
-
-        h = fake_quant_activation(h, cfg.act_quant_bits)
-    if kind == "kda":
-        attn_out, new_cache = _kda_mixer(
-            cfg, h, layer["kda"], cache, paged_layer, state_slots,
-            positions, paged_write_mask)
-    else:
-        attn_out, new_cache = _softmax_mixer(
-            cfg, h, layer, mask, positions, cache, static_prefill,
-            key_positions, window, block_table, paged_write_mask,
-            paged_layer)
-    if cache is None:
-        attn_out = _dropout(attn_out, cfg, salt=31)
-    if cache is None:
-        from ..parallel.sequence import constrain, hidden_spec, sequence_parallel_enabled
-
-        if sequence_parallel_enabled():
-            attn_out = constrain(attn_out, hidden_spec())
-    if cfg.parallel_residual:
-        # GPT-J/NeoX: x + attn(ln1(x)) + mlp(ln2(x)) — one residual add,
-        # the MLP reads the ORIGINAL x through its own norm
+        attn_out = None
         h = _norm(x, layer["ln2"]["scale"], layer["ln2"].get("bias"),
                   cfg.norm, cfg.norm_eps)
-    elif post_ln:
-        # BERT family: norm AFTER the residual add; the normed sum feeds MLP
-        x = _norm(x + attn_out, layer["ln1"]["scale"],
-                  layer["ln1"].get("bias"), cfg.norm, cfg.norm_eps)
-        h = x
-    else:
-        x = x + attn_out
-        h = _norm(x, layer["ln2"]["scale"], layer["ln2"].get("bias"),
-                  cfg.norm, cfg.norm_eps)
+    if not has_ffn:
+        aux = jnp.float32(0.0)
+        if moe_counts:
+            return x, new_cache, aux, jnp.zeros((3,), jnp.int32)
+        return x, new_cache, aux
     if cfg.act_quant_bits and cache is None:
         from ..compression.compress import fake_quant_activation
 
@@ -1457,11 +1719,13 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             norm_topk_prob=cfg.moe_norm_topk_prob, infer=infer,
             row_mask=paged_write_mask, with_counts=moe_counts,
             score_func=cfg.moe_score_func,
-            choice_bias=layer.get("router_bias"))
+            choice_bias=layer.get("router_bias"),
+            latent=layer.get("latent"), routed_scale=cfg.moe_routed_scale)
         if "shared" in layer:
-            # the shared expert: one dense SwiGLU beside the routed ones,
-            # added to every token unweighted
-            mlp_out = mlp_out + _swiglu(cfg, h, layer["shared"])
+            # the shared expert: one dense FFN beside the routed ones, on
+            # the full width, added to every token unweighted
+            mlp_out = mlp_out + (_swiglu if "w_gate" in layer["shared"]
+                                 else _plain_ffn)(cfg, h, layer["shared"])
         if cfg.moe_use_residual:
             # PR-MoE (reference moe/layer.py:120): dense MLP in parallel,
             # mixed by a learned softmax coefficient over (moe, dense)
@@ -1482,6 +1746,8 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         inner = _qeinsum("bsh,hf->bsf", h, layer["mlp"]["w_up"], cfg.dtype, a8=cfg.a8_decode) + layer["mlp"]["b_up"]
         if cfg.activation == "relu":
             inner = jax.nn.relu(inner)
+        elif cfg.activation == "relu2":
+            inner = jnp.square(jax.nn.relu(inner))
         elif cfg.activation == "quick_gelu":
             # CLIP's x*sigmoid(1.702x) (HF QuickGELUActivation)
             inner = inner * jax.nn.sigmoid(1.702 * inner)
@@ -1628,7 +1894,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             "cache (inference/engine.py) holds no recurrent state, and "
             "progressive layer drop, random-LTD and per-layer windows index "
             "a stack of one kind")
-    if cache is not None and "kda" in per and state_slots is None:
+    if (cache is not None and recurrent_layers(cfg)[1]
+            and state_slots is None):
         raise ValueError("a model with recurrent layers needs state_slots "
                          "beside its cache: each row's slot in the pools")
     stacks = layer_stacks(params["layers"], cfg)
@@ -1637,7 +1904,7 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     # _layer_forward's ``expert_banks``)
     banks = None
     if cache is not None and cfg.moe_num_experts > 0:
-        banks = {kind: tree["mlp"] for kind, tree in stacks.items()}
+        banks = {kind: tree.get("mlp") for kind, tree in stacks.items()}
         stacks = {kind: {k: v for k, v in tree.items() if k != "mlp"}
                   for kind, tree in stacks.items()}
     periods = {kind: tree if per[kind] == 1 else jax.tree.map(

@@ -12,6 +12,8 @@ from .moe_grouped_matmul import (moe_grouped_matmul,
                                  reference_grouped_matmul)
 from .kda import (kda_chunk, kda_decode_step, kda_recurrence,
                   reference_kda_decode_step)
+from .mamba2 import (mamba2_chunk, mamba2_decode_step, mamba2_recurrence,
+                     reference_mamba2_decode_step)
 from .fused_adam import fused_adam_flat, reference_adam_flat
 from .fused_lamb import fused_lamb_flat, reference_lamb_flat
 from .normalization import fused_layer_norm, reference_layer_norm
@@ -67,6 +69,10 @@ register_op("kda_decode_step", kda_decode_step,
             reference=reference_kda_decode_step,
             description="one token of the gated delta rule a decode row, "
                         "the state pool updated in place")
+register_op("mamba2_decode_step", mamba2_decode_step,
+            reference=reference_mamba2_decode_step,
+            description="one token of the Mamba-2 state-space recurrence a "
+                        "decode row, the state pool updated in place")
 register_op("int4_a8_matmul", int4_a8_matmul,
             reference=reference_int4_a8_matmul,
             description="W4A8 GEMM (s8 unpack + s8xs8 MXU)")
@@ -103,6 +109,8 @@ __all__ = [
     "moe_grouped_matmul", "reference_grouped_matmul",
     "kda_chunk", "kda_decode_step", "kda_recurrence",
     "reference_kda_decode_step",
+    "mamba2_chunk", "mamba2_decode_step", "mamba2_recurrence",
+    "reference_mamba2_decode_step",
     "flash_attention", "make_attention_impl", "fused_adam_flat",
     "reference_adam_flat", "fused_lamb_flat", "reference_lamb_flat",
     "fused_layer_norm", "reference_layer_norm",

@@ -192,6 +192,14 @@ def _ep_active(num_experts: int) -> bool:
     return ep > 1 and num_experts % ep == 0
 
 
+def expert_activation(activation: str, up: jax.Array) -> jax.Array:
+    """An expert's activation where it is not gated: ``relu2`` is relu
+    squared, anything else the tanh GELU these experts always had."""
+    if activation == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.gelu(up, approximate=True)
+
+
 def _expert_ffn(dispatched: jax.Array, experts: Dict[str, jax.Array],
                 activation: str, E: int) -> jax.Array:
     """(E, C, H) → (E, C, H) batched expert MLPs, EP-constrained."""
@@ -203,9 +211,9 @@ def _expert_ffn(dispatched: jax.Array, experts: Dict[str, jax.Array],
         u = jnp.einsum("ech,ehf->ecf", dispatched, experts["w_up"])
         inner = jax.nn.silu(g) * u
     else:
-        inner = jax.nn.gelu(
-            jnp.einsum("ech,ehf->ecf", dispatched, experts["w_up"]),
-            approximate=True)
+        inner = expert_activation(
+            activation, jnp.einsum("ech,ehf->ecf", dispatched,
+                                   experts["w_up"]))
     expert_out = jnp.einsum("ecf,efh->ech", inner, experts["w_down"])
     if _ep_active(E):
         expert_out = constrain(expert_out, P(EXPERT_AXIS, None, None))
@@ -282,7 +290,8 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
                      experts: Dict[str, jax.Array], activation: str, k: int,
                      normalize: bool, real: Optional[jax.Array],
                      layer: Optional[jax.Array] = None,
-                     choice: Optional[jax.Array] = None
+                     choice: Optional[jax.Array] = None,
+                     routed_scale: float = 1.0
                      ) -> Tuple[jax.Array, jax.Array]:
     """Dropless expert compute over the ASSIGNED rows only: the ``T x k``
     assignments are laid out sorted by expert, each expert's group padded to
@@ -297,7 +306,9 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     ``choice`` (T, E): what the k experts are chosen by where that is not
     ``gates`` (a choice-only bias). ``gates`` may be wider than the stacks
     hold experts: an assignment to an expert past the held ones is dropped
-    here like a row that is not ``real`` (``moe_mlp``: a chip's share). Returns ``(out (T, H), counts)`` with ``counts`` int32
+    here like a row that is not ``real`` (``moe_mlp``: a chip's share).
+    ``routed_scale`` multiplies the chosen weights after their
+    renormalisation. Returns ``(out (T, H), counts)`` with ``counts`` int32
     ``[assignments, experts with a row, rows of the largest expert]``."""
     from ..ops.moe_grouped_matmul import (group_layout, moe_grouped_matmul,
                                           reference_grouped_matmul,
@@ -309,6 +320,8 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     E = experts["w_up"].shape[-3]       # held here; the router may be wider
     idx, weight = route_topk(gates, gates if choice is None else choice, k,
                              normalize)                           # (T, k)
+    if routed_scale != 1.0:
+        weight = weight * routed_scale
     flat = idx.reshape(-1)
     chosen = flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :]
     if E < gates.shape[-1]:
@@ -341,7 +354,7 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
         gate = mm(xs, experts["w_gate"], tile_expert, used, layer).astype(f32)
         inner = jax.nn.silu(gate) * up
     else:
-        inner = jax.nn.gelu(up, approximate=True)
+        inner = expert_activation(activation, up)
     y = mm(inner.astype(xt.dtype), experts["w_down"], tile_expert, used,
            layer)
 
@@ -368,7 +381,9 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
             with_counts: bool = False,
             expert_layer: Optional[jax.Array] = None,
             score_func: str = "softmax",
-            choice_bias: Optional[jax.Array] = None):
+            choice_bias: Optional[jax.Array] = None,
+            latent: Optional[Dict[str, jax.Array]] = None,
+            routed_scale: float = 1.0):
     """MoE FFN for one layer. x (B, S, H); router_w (H, E); experts:
     w_up/w_down (+w_gate for swiglu) with leading expert dim E - or, with
     ``expert_layer`` (int32 scalar), the model's whole stacks with a layer
@@ -386,7 +401,13 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
     too); ``choice_bias`` (E,) is added to the scores for the CHOICE of the
     k experts and never to their weights. Both exist at inference with the
     experts whole on the chip (the dropless path below); the capacity plans
-    refuse them.
+    refuse them, and these two with them: ``latent`` ``{"w_in": (H, Z),
+    "w_out": (Z, H)}``, experts that run in a latent of width ``Z`` (their
+    matrices ``Z x F`` and ``F x Z``): the router reads ``x``, the experts
+    ``x W_in``, and the weighted sum of what the chosen and held experts
+    give goes back through ``W_out``, so an absent expert's part is left
+    out BEFORE that projection; ``routed_scale``, a factor on the chosen
+    weights after their renormalisation.
 
     **A chip's share of the experts.** Where the stacks hold FEWER experts
     than the router has outputs, they are the router's first ones, held here
@@ -441,15 +462,21 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
         scores = (jax.nn.softmax(logits, axis=-1) if score_func == "softmax"
                   else jax.nn.sigmoid(logits))
         out, counts = _grouped_experts(
-            xt, scores, experts, activation, top_k, norm_topk_prob,
+            xt if latent is None else xt @ latent["w_in"], scores, experts,
+            activation, top_k, norm_topk_prob,
             None if row_mask is None else row_mask.reshape(T), expert_layer,
             choice=(None if choice_bias is None
-                    else scores + choice_bias.astype(jnp.float32)))
+                    else scores + choice_bias.astype(jnp.float32)),
+            routed_scale=routed_scale)
+        if latent is not None:
+            out = out @ latent["w_out"]
         out, aux = out.reshape(B, S, H), jnp.float32(0.0)
         return (out, aux, counts) if with_counts else (out, aux)
-    if held != E or score_func != "softmax" or choice_bias is not None:
+    if (held != E or score_func != "softmax" or choice_bias is not None
+            or latent is not None or routed_scale != 1.0):
         raise NotImplementedError(
-            "a share of the experts, sigmoid scores and a choice-only bias "
+            "a share of the experts, sigmoid scores, a choice-only bias, "
+            "experts in a latent and a scale on the routed sum "
             "exist on the dropless inference path only "
             "(parallel/moe._grouped_experts): the capacity plans of "
             "training, and of experts sharded over chips, have none of them")

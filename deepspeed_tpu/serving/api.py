@@ -124,8 +124,9 @@ class ServingEngine:
         self.clock = clock
         self._lock = threading.RLock()
         self.alloc = paged_kv.BlockAllocator(self.config.pool_blocks())
-        # a model with recurrent ("kda") layers keeps, beside its pages, a
-        # matrix state and a convolution tail a sequence in pools of
+        # a model with recurrent layers (``transformer.recurrent_layers``:
+        # delta-rule or state-space mixers) keeps, beside its pages, a
+        # float32 state and a convolution tail a sequence in pools of
         # ``state_slots`` slots: decode row r owns slot r from admission to
         # release, and the last slot is scratch (rows that hold nothing,
         # and the sequence ``score_logprobs`` is scoring). A slot is never
@@ -133,9 +134,13 @@ class ServingEngine:
         # it from zeros, on admission and on re-admission after a
         # preemption (recompute) alike. What cannot follow such state yet
         # is switched off or refused here and in ``_no_state_snapshot``
-        from ..models.transformer import layers_of_kind
+        from ..models.transformer import ffn_layers, recurrent_layers
 
-        self._recurrent_layers = len(layers_of_kind(cfg, "kda"))
+        mixer, layers = recurrent_layers(cfg)
+        self._recurrent_layers = len(layers)
+        # the span count of the (row, layer) states a step advanced
+        self._recurrent_rows = {"mamba2": "ssm_rows"}.get(mixer,
+                                                          "recurrent_rows")
         self.state_slots = (self.config.max_seqs + 1
                             if self._recurrent_layers else 0)
         # the prefix cache shares PAGES between sequences; a recurrent
@@ -165,8 +170,8 @@ class ServingEngine:
         # spans know nothing of it. ``total`` counts the ROUTER's outputs a
         # layer, ``held`` the experts of a layer's stack (fewer where this
         # chip holds its share of them: ``moe_experts_held``)
-        self._moe_experts_total = cfg.moe_num_experts * cfg.num_layers
-        self._moe_experts_held = cfg.experts_held * cfg.num_layers
+        self._moe_experts_total = cfg.moe_num_experts * len(ffn_layers(cfg))
+        self._moe_experts_held = cfg.experts_held * len(ffn_layers(cfg))
         moe = self._moe_experts_total > 0
         self._prefill = paged_kv.build_prefill_program(
             cfg, self.config.prefill_chunk, moe_counts=moe)
@@ -297,8 +302,8 @@ class ServingEngine:
         if self._recurrent_layers:
             raise NotImplementedError(
                 f"{what} is not supported for a model with recurrent "
-                "(linear-attention) layers: it needs a snapshot of a "
-                "sequence's recurrent state (the matrix state and "
+                "(linear-attention or state-space) layers: it needs a "
+                "snapshot of a sequence's recurrent state (the state and "
                 "convolution tail of serving/paged_kv.py's state pools), "
                 "which nothing takes yet; pages alone do not hold it")
 
@@ -736,7 +741,8 @@ class ServingEngine:
         A model with recurrent layers scores a sequence's last
         ``_SCORE_STEP_TAIL`` tokens ONE at a time (the same program traced
         at a width of one): the model's one-token forms, which its decode
-        program runs (``kda_decode_step`` on the state pools in place, the
+        program runs (``kda_decode_step`` or ``mamba2_decode_step`` on the state
+        pools in place, the
         paged decode kernel), carry on from the state and the pages that
         the chunks left. So a comparison of these log-probabilities with a
         reference covers the chunk form, the step form and the hand-over
@@ -1106,9 +1112,11 @@ class ServingEngine:
         router's outputs x layers, the same number unless this chip holds a
         share) and the rows of each layer's largest expert. A model with
         recurrent layers also says how many (row, layer) states the program
-        advanced: ``real_rows`` x its recurrent layers."""
+        advanced: ``real_rows`` x its recurrent layers, as ``recurrent_rows``
+        (delta-rule layers) or ``ssm_rows`` (state-space layers)."""
         if span.recording and self._recurrent_layers:
-            span.annotate(recurrent_rows=real_rows * self._recurrent_layers)
+            span.annotate(**{self._recurrent_rows:
+                             real_rows * self._recurrent_layers})
         if not self._moe_experts_total:
             return fetched
         if span.recording:

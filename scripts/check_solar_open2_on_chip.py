@@ -48,10 +48,6 @@ import sys
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
-CELL = "serve-decode-r64"
-UNTOLD = ("state-in-bfloat16",)
-
-
 def controls(jnp):
     return {"all-in-float8": {"mantissa_bits": 3},
             "state-in-bfloat16": {"state_dtype": jnp.bfloat16},
@@ -62,9 +58,19 @@ def controls(jnp):
             "renormalised-over-held": {"renorm_over_held": True}}
 
 
-def main(argv=None):
+# what of this check is a family's own (scripts/check_nemotron_h_on_chip.py
+# brings another): the configuration and its cell's traffic, the reference's
+# controls, those of them that no limit on logits can tell, the output file
+FAMILY = {"config": "solar-open2-250b-ep8-d4", "traffic": "serve-decode-r64",
+          "controls": controls, "untold": ("state-in-bfloat16",),
+          "out": "solar_open2_check.json"}
+
+
+def main(argv=None, family=FAMILY):
+    controls, UNTOLD, CELL = (family["controls"], family["untold"],
+                              family["traffic"])
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--config", default="solar-open2-250b-ep8-d4")
+    ap.add_argument("--config", default=family["config"])
     ap.add_argument("--sequences", type=int, default=3)
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--tiny", action="store_true")
@@ -219,8 +225,8 @@ def main(argv=None):
     out["ok"] = bool(worst["reference"] < limit and set(
         out["controls_under_the_limit"]) <= set(UNTOLD))
     os.makedirs(os.path.join(REPO_ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(REPO_ROOT, "chiprun_out",
-                           "solar_open2_check.json"), "w") as f:
+    with open(os.path.join(REPO_ROOT, "chiprun_out", family["out"]),
+              "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
     return 0

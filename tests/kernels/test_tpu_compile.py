@@ -18,7 +18,7 @@ import pytest
 
 from deepspeed_tpu.ops import (decode_attention, flash_attention,
                                fused_layer_norm, kda_decode_step,
-                               moe_grouped_matmul,
+                               mamba2_decode_step, moe_grouped_matmul,
                                paged_decode_attention,
                                paged_prefill_attention)
 from deepspeed_tpu.ops.moe_grouped_matmul import max_tiles, tile_rows
@@ -106,6 +106,15 @@ def _kda_decode(rows, heads, d, layers=3):
              ((rows,), I32)])
 
 
+def _mamba2_decode(rows, heads, p, groups, n, layers=5):
+    # the pool of a slot a row and one scratch, the layer a traced scalar
+    return (mamba2_decode_step,
+            [((rows, heads, p), F32), ((rows, heads), F32), ((heads,), F32),
+             ((rows, groups, n), F32), ((rows, groups, n), F32),
+             ((layers, rows + 1, groups, n, heads // groups * p), F32),
+             ((), I32), ((rows,), I32)])
+
+
 def _grouped_matmul(tokens, top_k, experts, k, n):
     """The expert matmul of `tokens` x `top_k` assignments laid out in
     tiles, as parallel/moe.py calls it."""
@@ -124,7 +133,18 @@ def _grouped_matmul(tokens, top_k, experts, k, n):
 # solar-open2-250b as one of 8 chips: 64 decode rows; 64 heads over 8 KV heads
 # of 128; 64 linear-attention heads of 128 x 128 state; a row's 8
 # assignments of which an eighth reach the 40 held experts of width 1280
+# nemotron-3-super as one of 8 chips: 64 decode rows; 128 state-space heads
+# of 64 in 8 groups of state 128; 32 heads over 2 KV heads of 128; a row's 22
+# assignments of which an eighth reach the 64 held experts, 1024 x 2688
 CASES = {
+    "mamba2-decode-nemotron-3-super":
+        lambda: _mamba2_decode(64, 128, 64, 8, 128),
+    "paged-decode-nemotron-3-super":
+        lambda: _paged_decode(64, 32, 128, 16, 128, kv_heads=2),
+    "moe-up-decode-nemotron-3-super":
+        lambda: _grouped_matmul(64, 22, 64, 1024, 2688),
+    "moe-down-decode-nemotron-3-super":
+        lambda: _grouped_matmul(64, 22, 64, 2688, 1024),
     "kda-decode-solar-open2": lambda: _kda_decode(64, 64, 128),
     "paged-decode-solar-open2":
         lambda: _paged_decode(64, 64, 128, 16, 128, kv_heads=8),
@@ -222,7 +242,7 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
 
     params = on_chip(jax.eval_shape(
         lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
-    recurrent = bool(T.layers_of_kind(cfg, "kda"))
+    recurrent = bool(T.recurrent_layers(cfg)[1])
     arena = on_chip(paged_cache_shape_struct(
         cfg, num_blocks, BLOCK, BF16,
         state_slots=rows + 1 if recurrent else 0))
@@ -414,6 +434,51 @@ def test_solar_serving_program_updates_the_states_where_they_lie(
     pool_bytes = 3 * (SOLAR_ROWS + 1) * 64 * 128 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
     m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool_bytes        # donated, in place
+
+
+# nemotron-3-super as the benchmark serves it: the first 11 source layers (5
+# Mamba-2, 5 expert layers, 1 attention), 64 of 512 experts held, an eighth
+# of the vocabulary, 64 rows, 8,192 blocks
+NEMOTRON = {"num_layers": 11, "moe_experts_held": 64, "vocab_size": 16384}
+NEMOTRON_STATES = f"f32[5,{SOLAR_ROWS + 1},8,128,1024]"
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_nemotron_serving_program_updates_the_states_where_they_lie(
+        v5e, monkeypatch, kind):
+    """Nemotron 3 Super's serving programs for the chip at the cell's
+    shapes: a decode step is five calls of `mamba2_decode_step` (one a
+    Mamba-2 layer) whose pool operand is the whole pool and comes back
+    aliased; the chunk program reads and writes a row's state in the pool's
+    own layout, so that no program holds a second copy of the pool (1.36 GB;
+    a transpose between the pool and the chunked form's arithmetic is folded
+    into the pool's layout and copies it in and out) or of a layer's experts,
+    and the attention layer reads its ONE layer of pages through the paged
+    kernel."""
+    compiled = _serving_program(
+        kind, v5e, monkeypatch, preset="nemotron-3-super-120b-a12b",
+        overrides=NEMOTRON, rows=SOLAR_ROWS, num_blocks=SOLAR_BLOCKS,
+        moe_counts=True).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "custom-call" in ln
+             and "tpu_custom_call" in ln]
+    steps = kind == "decode"
+    assert sum("mamba2_decode_step" in ln for ln in calls) \
+        == (5 if steps else 0)
+    assert sum("moe_grouped_matmul" in ln for ln in calls) == 5 * 2
+    paged = "paged_decode_attention" if steps \
+        else "paged_prefill_attention"
+    assert sum(paged in ln for ln in calls) == 1
+    for ln in calls:
+        if "mamba2_decode_step" in ln:
+            assert NEMOTRON_STATES in ln.split("custom-call(", 1)[0]
+    # a layer's experts are read where they lie: no (64, 1024, 2688) copy
+    assert "bf16[64,1024,2688]" not in text
+    assert "bf16[64,2688,1024]" not in text
+    pool_bytes = 5 * (SOLAR_ROWS + 1) * 8 * 128 * 1024 * 4
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < pool_bytes // 4
     assert m.alias_size_in_bytes >= pool_bytes        # donated, in place
 
 

@@ -406,8 +406,21 @@ def test_pages_are_for_the_softmax_layers_alone():
         init_paged_cache(cfg, 4, 16, jnp.float32)
 
 
-def test_what_cannot_follow_recurrent_state_is_refused_by_name(tiny):
-    model, params, _ = tiny
+@pytest.fixture(scope="module", params=["delta-rule", "delta-rule-share",
+                                        "state-space"])
+def recurrent(request):
+    """A model of each kind of layer that keeps a state: the delta rule
+    (whole and as a share) and the Mamba-2 state-space mixer."""
+    if request.param == "state-space":
+        model = create_model("tiny-nemotron-3-super")
+    else:
+        model = create_model("tiny-solar-open2",
+                             **(SHARE if "share" in request.param else {}))
+    return model, model.init(jax.random.PRNGKey(SEED))
+
+
+def test_what_cannot_follow_recurrent_state_is_refused_by_name(recurrent):
+    model, params = recurrent
     served = _serving(model, params)
     prompt = np.arange(20, dtype=np.int32)
     with pytest.raises(NotImplementedError, match="snapshot"):
