@@ -39,12 +39,17 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
 STRIP = 256   # a diagonal tile is walked in strips of this many rows/columns
+# checkpoint_name tags of the forward kernel's two results, (o, lse): a
+# jax.checkpoint policy that saves these names keeps them for the backward
+# kernels, which otherwise get them back by running the forward kernel again
+SAVED_RESIDUALS = ("flash_attention_o", "flash_attention_lse")
 
 
 def _block_sizes(s: int, t: int) -> Tuple[int, int]:
@@ -503,6 +508,8 @@ def _flash_core_fwd(q, k, v, kvm, slopes, causal, scale, kv_len, has_mask,
     o, lse = _fwd(q, k, v, kvm, slopes, causal=causal, scale=scale,
                   kv_len=kv_len, has_mask=has_mask, has_alibi=has_alibi,
                   interpret=interpret)
+    # lse compact, (B, N, S): the backward lane-broadcasts it again
+    o, lse = map(checkpoint_name, (o, lse), SAVED_RESIDUALS)
     return o, (q, k, v, kvm, slopes, o, lse)
 
 

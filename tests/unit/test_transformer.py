@@ -234,12 +234,89 @@ def test_init_layer_block_matches_init_slice(kw):
 def test_remat_policy_knobs():
     """remat_policy surface incl. the cpu_checkpointing analog
     ('offload-dots' — saved dots live in pinned host memory; functional
-    equivalence validated on real TPU, docs/offload_design.md)."""
+    equivalence validated on real TPU, docs/offload_design.md). "dots" also
+    keeps what the flash forward kernel tags with SAVED_RESIDUALS, and no
+    other value that is not a dot."""
+    from jax.ad_checkpoint import checkpoint_name
+
     from deepspeed_tpu.models.transformer import (TransformerConfig,
                                                   resolve_remat_policy)
+    from deepspeed_tpu.ops.flash_attention import SAVED_RESIDUALS
 
     assert resolve_remat_policy(TransformerConfig(remat_policy="full")) is None
     assert resolve_remat_policy(
-        TransformerConfig(remat_policy="dots")) is not None
-    assert resolve_remat_policy(
         TransformerConfig(remat_policy="offload-dots")) is not None
+    dots = resolve_remat_policy(TransformerConfig(remat_policy="dots"))
+
+    def saved(fn, *args):
+        eqn = jax.make_jaxpr(fn)(*args).eqns[-1]
+        return bool(dots(eqn.primitive, *[v.aval for v in eqn.invars],
+                         **eqn.params))
+
+    x = jnp.ones((4, 4))
+    for name in SAVED_RESIDUALS:
+        assert saved(lambda x: checkpoint_name(x, name), x)
+    assert saved(jnp.dot, x, x)
+    assert not saved(lambda x: checkpoint_name(x, "some_other_name"), x)
+    assert not saved(jnp.exp, x)
+
+
+def _count_primitive(jaxpr, name):
+    return sum((eqn.primitive.name == name)
+               + sum(_count_primitive(sub, name)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("remat_policy", ["dots", "full"])
+def test_remat_dots_keeps_the_flash_forward_results(monkeypatch, devices,
+                                                    remat_policy):
+    """Under "dots" the backward of a layer takes the flash forward kernel's
+    o and lse from the forward pass: 3 Pallas calls a layer body (forward,
+    dq, dkv), bit for bit the gradients of the bare dots policy, which runs
+    the forward kernel again for them as "full" still does (4). On one
+    device and through ``_per_shard`` on four (the four-chip cell's path)."""
+    import importlib
+
+    import deepspeed_tpu.models.transformer as T
+    from deepspeed_tpu.config.config import ParallelConfig
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+    from jax.sharding import NamedSharding
+
+    fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        lambda *a, **k: flash_attention(*a, **{**k, "interpret": True}))
+    cfg = TransformerConfig(**{
+        **create_model("tiny").config.__dict__, "remat": True,
+        "remat_policy": remat_policy, "attention_impl": T._flash_attention})
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = _batch(cfg, b=4, s=128)
+    mesh = mesh_mod.build_mesh(ParallelConfig(data_parallel_size=devices),
+                               devices=jax.devices()[:devices])
+
+    def grads():
+        with mesh_mod.ambient(mesh):
+            sharded = jax.device_put(
+                batch, NamedSharding(mesh, P(mesh_mod.DATA_SHARD)))
+            grad = jax.grad(model.loss_fn)
+            return (jax.make_jaxpr(grad)(params, sharded).jaxpr,
+                    jax.jit(grad)(params, sharded))
+
+    jaxpr, got = grads()
+    # the layers are one scan, so a layer's body is in the program once
+    assert _count_primitive(jaxpr, "pallas_call") == (
+        3 if remat_policy == "dots" else 4)
+    assert (_count_primitive(jaxpr, "shard_map") > 0) == (devices > 1)
+    if remat_policy != "dots":
+        return
+    monkeypatch.setattr(
+        T, "resolve_remat_policy",
+        lambda cfg: jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    bare_jaxpr, want = grads()
+    assert _count_primitive(bare_jaxpr, "pallas_call") == 4
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), got, want)
