@@ -1343,6 +1343,54 @@ def _last_real_rows(ext: jax.Array, real: Optional[jax.Array],
         rows, k, n, axis=0))(ext, n_real)
 
 
+def _run_pages(block_table: jax.Array, block: int, S: int,
+               start: jax.Array, n_valid: jax.Array
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The pages that a run of positions ``[start, start + n_valid)`` a row
+    can touch, of at most ``S`` positions: ``start`` and ``n_valid`` are ()
+    or (B,), ``start`` any position (not only a page's first). Returns
+    ``(blk (B, P), new (B, P * block), off (B,))``: the
+    P = (S + block - 2) // block + 1 physical blocks from ``start``'s page
+    on, a page that holds no position of the run (or lies past the table)
+    sent to the scratch block 0; which positions of those pages the run
+    writes; and where in its first page it begins."""
+    B, max_blocks = block_table.shape
+    start = jnp.broadcast_to(start, (B,))[:, None]
+    end = start + jnp.broadcast_to(n_valid, (B,))[:, None]
+    P = (S + block - 2) // block + 1
+    first = start // block
+    page = first + jnp.arange(P, dtype=jnp.int32)                  # (B, P)
+    held = (page * block < end) & (end > start) & (page < max_blocks)
+    blk = jnp.where(held, jnp.take_along_axis(
+        block_table, jnp.minimum(page, max_blocks - 1), axis=1), 0)
+    at = first * block + jnp.arange(P * block, dtype=jnp.int32)    # (B, P*BS)
+    return blk, (at >= start) & (at < end), start[:, 0] % block
+
+
+def _write_pages(arena: jax.Array, layer: jax.Array, rows: jax.Array,
+                 blk: jax.Array, new: jax.Array,
+                 off: jax.Array) -> jax.Array:
+    """``rows`` (B, S, W), a run of positions, written into ``arena``
+    (L, NUM_BLOCKS, BLOCK, W) as the whole pages that ``_run_pages`` names
+    (``blk``, ``new``, ``off``): the pages are gathered, the run's rows laid
+    over them from row ``off`` on, every position outside ``new`` keeps what
+    its page held, and ONE scatter of (BLOCK, W) windows puts them back
+    where they lie (the arena is the layer scan's carry: no pool is
+    copied). The bytes of every block but scratch are those of the row
+    scatter."""
+    B, S, W = rows.shape
+    P, block = blk.shape[1], arena.shape[2]
+    old = arena[layer, blk].reshape(B, P * block, W)
+    # row s of the run at row off + s of its pages, by a pad and a slice: on
+    # the chip a tenth faster than a dynamic_update_slice into `old`, and
+    # 17 single-page updates take half as long again (PERF.md, PR 49)
+    padded = jnp.pad(rows, ((0, 0), (block, P * block - S), (0, 0)))
+    laid = jnp.stack([lax.dynamic_slice_in_dim(padded[b], block - off[b],
+                                               P * block) for b in range(B)])
+    pages = jnp.where(new[..., None], laid, old)
+    return arena.at[layer, blk].set(pages.reshape(B, P, block, W))
+
+
 def _softmax_mixer(cfg: TransformerConfig, h: jax.Array,
                    layer: Dict[str, Any], mask: Optional[jax.Array],
                    positions: jax.Array,
@@ -1352,7 +1400,8 @@ def _softmax_mixer(cfg: TransformerConfig, h: jax.Array,
                    window: Optional[jax.Array],
                    block_table: Optional[jax.Array],
                    paged_write_mask: Optional[jax.Array],
-                   paged_layer: Optional[jax.Array]
+                   paged_layer: Optional[jax.Array],
+                   paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
                    ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """The "attn" mixer of ``_layer_forward``: softmax attention over the
     normed input ``h`` -> (its contribution to the residual, new cache)."""
@@ -1447,23 +1496,37 @@ def _softmax_mixer(cfg: TransformerConfig, h: jax.Array,
         # compiled program covers any arena occupancy (the jit-cache analog
         # of vLLM's PagedAttention block tables).
         BSz = cache["k"].shape[2]
-        T_view = block_table.shape[1] * BSz
         pos = positions if positions.ndim == 2 else jnp.broadcast_to(
             positions[None], (B, S))
-        wpos = jnp.minimum(pos, T_view - 1)   # clamp pad writes in-range
-        blk = jnp.take_along_axis(block_table, wpos // BSz, axis=1)  # (B,S)
-        off = wpos % BSz
-        if paged_write_mask is not None:
-            # chunk padding / inactive decode rows write to scratch block 0
-            blk = jnp.where(paged_write_mask, blk, 0)
-            off = jnp.where(paged_write_mask, off, 0)
-        # ONE scatter into the 4-D arena, which the layer scan carries: it
-        # updates the carry in place, only the written rows move. An arena
-        # row is one token's K*D lanes (ops/paged_decode_attention.py)
-        ck = cache["k"].at[paged_layer, blk, off].set(
-            k.reshape(B, S, K * D).astype(cache["k"].dtype))
-        cv = cache["v"].at[paged_layer, blk, off].set(
-            v.reshape(B, S, K * D).astype(cache["v"].dtype))
+        k_rows = k.reshape(B, S, K * D).astype(cache["k"].dtype)
+        v_rows = v.reshape(B, S, K * D).astype(cache["v"].dtype)
+        if paged_run is not None and S >= BSz:
+            # a RUN of a page or more (a prompt or scoring chunk): whole
+            # pages, not rows. The arena's tiling on the chip packs two
+            # consecutive token rows into every 32-bit word and makes a
+            # 16-row page 16 whole tiles, so a row update is a half-word
+            # write into tiles the next row touches again: 256 of them cost
+            # a chunk program a quarter of its time (PERF.md, PR 49)
+            pages = _run_pages(block_table, BSz, S, *paged_run)
+            ck = _write_pages(cache["k"], paged_layer, k_rows, *pages)
+            cv = _write_pages(cache["v"], paged_layer, v_rows, *pages)
+        else:
+            # one token a row (decode) or fewer than a page (verify): rows
+            T_view = block_table.shape[1] * BSz
+            wpos = jnp.minimum(pos, T_view - 1)   # clamp pad writes in-range
+            blk = jnp.take_along_axis(block_table, wpos // BSz, axis=1)
+            off = wpos % BSz
+            if paged_write_mask is not None:
+                # chunk padding / inactive decode rows write to scratch
+                # block 0
+                blk = jnp.where(paged_write_mask, blk, 0)
+                off = jnp.where(paged_write_mask, off, 0)
+            # ONE scatter into the 4-D arena, which the layer scan carries:
+            # it updates the carry in place, only the written rows move. An
+            # arena row is one token's K*D lanes
+            # (ops/paged_decode_attention.py)
+            ck = cache["k"].at[paged_layer, blk, off].set(k_rows)
+            cv = cache["v"].at[paged_layer, blk, off].set(v_rows)
         new_cache = {**cache, "k": ck, "v": cv}
         from ..ops.paged_decode_attention import paged_attention
 
@@ -1581,7 +1644,7 @@ def _softmax_mixer(cfg: TransformerConfig, h: jax.Array,
 def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                 mixer: str, has_ffn: bool, mask, positions, cache,
                 static_prefill, key_positions, window, block_table,
-                paged_write_mask, paged_layer, state_slots):
+                paged_write_mask, paged_layer, state_slots, paged_run=None):
     """The first half of ``_layer_forward`` for a layer that has a mixer:
     norm, mixer and its add -> ``(x, the FFN's normed input or None where no
     FFN follows, the mixer's output, new cache)``. ``x`` comes back with the
@@ -1611,7 +1674,7 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         attn_out, new_cache = _softmax_mixer(
             cfg, h, layer, mask, positions, cache, static_prefill,
             key_positions, window, block_table, paged_write_mask,
-            paged_layer)
+            paged_layer, paged_run)
     if cache is None:
         attn_out = _dropout(attn_out, cfg, salt=31)
     if cache is None:
@@ -1652,7 +1715,8 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                    expert_banks: Optional[Dict[str, jax.Array]] = None,
                    layer_index: Optional[jax.Array] = None,
                    kind: str = "attn",
-                   state_slots: Optional[jax.Array] = None
+                   state_slots: Optional[jax.Array] = None,
+                   paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
                    ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """One decoder block: ``x + mixer(norm(x))``, then the FFN. ``kind``
     names the mixer (``TransformerConfig.layer_pattern``); what follows is
@@ -1676,7 +1740,8 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     pool-sized copy in and another out (ops/paged_decode_attention.py).
     ``positions`` must then be the (B, S) absolute write
     positions; ``paged_write_mask`` (B, S) routes masked-off tokens (prompt
-    chunk padding) to the scratch block 0 instead of the row's blocks.
+    chunk padding) to the scratch block 0 instead of the row's blocks;
+    ``paged_run`` says that the two are one run a row (``forward``).
     The read is ``ops.paged_decode_attention.paged_attention``, which picks
     kernel or reference by platform; S > 1 queries of a row sit at
     ``positions[b, 0] + arange(S)`` (the chunk, verify and score programs).
@@ -1703,7 +1768,7 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         x, h, attn_out, new_cache = _mixer_half(
             cfg, x, layer, mixer, has_ffn, mask, positions, cache,
             static_prefill, key_positions, window, block_table,
-            paged_write_mask, paged_layer, state_slots)
+            paged_write_mask, paged_layer, state_slots, paged_run)
     else:
         attn_out = None
         h = _norm(x, layer["ln2"]["scale"], layer["ln2"].get("bias"),
@@ -1801,7 +1866,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             block_table: Optional[jax.Array] = None,
             paged_write_mask: Optional[jax.Array] = None,
             moe_counts: bool = False,
-            state_slots: Optional[jax.Array] = None
+            state_slots: Optional[jax.Array] = None,
+            paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
             ) -> Tuple[jax.Array, Optional[Dict[str, Any]], jax.Array]:
     """Token ids (B,S) → (logits (B,S,V), new_cache, moe_aux_loss). With
     ``cache``, runs in decode mode (cache is a per-layer stacked pytree; see
@@ -1814,6 +1880,12 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     ``{"k","v": (L, NUM_BLOCKS, BLOCK, K*D)}`` (serving layer); ``positions``
     is then REQUIRED — per-row absolute write positions — and
     ``paged_write_mask`` (B, S) routes padding writes to the scratch block.
+    ``paged_run`` ``(start, n_valid)``, each () or (B,): the caller's word
+    that row b's ``positions`` are the run ``start + arange(S)`` and its
+    mask ``arange(S) < n_valid`` (a prompt or scoring chunk). A run of a
+    page or more is then written a whole page at a time, to the same bytes
+    (``_write_pages``); without it, and for fewer tokens than a page, the
+    write is a row a token.
     The paged read has no window, custom-scale or custom-impl operand: a
     model with ``attention_layers``, ``attention_scale`` or
     ``attention_impl`` is refused. ``moe_counts`` (paged mode, an MoE
@@ -2031,10 +2103,11 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     elif block_table is not None:
         # PAGED: the layer scan's CARRY is the arena itself, and the body
         # hands it down whole with the layer index. _layer_forward scatters
-        # the new rows into it at (idx, blk, off) — in place on the carry —
-        # and the paged kernels address arena[idx, page] where it lies. The
-        # body must never take a layer's pool out (dynamic_index_in_dim) and
-        # put it back: a Pallas call's operand is a buffer of its own, so
+        # the new rows into it at (idx, blk, off), or a run's whole pages at
+        # (idx, blk) — in place on the carry — and the paged kernels address
+        # arena[idx, page] where it lies. The body must never take a layer's
+        # pool out (dynamic_index_in_dim) and put it back: a Pallas call's
+        # operand is a buffer of its own, so
         # XLA then copies the pool out and in around every kernel and
         # scatter — four 185 MiB copies a layer at OPT-1.3B's serving size,
         # 54 ms of a 73 ms decode iteration on the v5e (PERF.md, PR 26).
@@ -2055,7 +2128,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                     paged_write_mask=paged_write_mask, paged_layer=kidx,
                     moe_counts=moe_counts,
                     expert_banks=None if banks is None else banks[kind],
-                    layer_index=kidx, kind=kind, state_slots=state_slots)
+                    layer_index=kidx, kind=kind, state_slots=state_slots,
+                    paged_run=paged_run)
                 return (h, aux_sum + aux, arena,
                         *(a + c for a, c in zip(counts_sum, counts)))
 

@@ -8,6 +8,14 @@ pool of fixed-size **blocks** (vLLM's PagedAttention, Kwon et al. SOSP '23):
 * ``BlockAllocator`` — host-side free list over the pool. Block 0 is a
   reserved scratch block (inactive decode rows and prompt-chunk padding
   write there); allocatable ids are 1..num_blocks.
+* Who writes what: the decode and verify programs write a ROW a token; the
+  prefill-chunk and score programs write a run of positions and hand it
+  down as ``paged_run=(start, n_valid)``, and the model writes a run of a
+  page or more as WHOLE PAGES (the pages gathered, the run laid over them,
+  one scatter back; pages with none of its positions sent to scratch). The
+  bytes are the same. On the chip two token rows share each 32-bit word of
+  the arena and a page is 16 whole tiles, so 256 row updates cost a chunk
+  program a quarter of its time (``models/transformer._write_pages``).
 * ``build_prefill_program`` / ``build_decode_program`` — the two jitted
   serving programs. Both are **shape-static**: the block table
   ``(rows, max_blocks)`` and per-row lengths are data, not shapes, so one
@@ -32,7 +40,7 @@ pool of fixed-size **blocks** (vLLM's PagedAttention, Kwon et al. SOSP '23):
   row sorts the vocabulary once (``vmap`` over it would defeat the
   ``cond`` and run both branches).
 
-The model-side write/read lives in ``models/transformer._layer_forward``
+The model-side write/read lives in ``models/transformer._softmax_mixer``
 (paged branch): the layout is left-aligned — token at position ``p`` sits in
 block ``table[p // BLOCK]`` offset ``p % BLOCK`` — so a key's gathered
 column IS its position and causality over true positions is the entire
@@ -621,7 +629,8 @@ def _chunk_step(cfg, moe_counts: bool = False):
         logits, cache, _, *counts = model_forward(
             params, chunk, cfg, cache=cache, positions=pos,
             block_table=block_table, paged_write_mask=write_mask,
-            moe_counts=moe_counts, state_slots=state_slot)
+            moe_counts=moe_counts, state_slots=state_slot,
+            paged_run=(start, n_valid))
         last = jnp.take_along_axis(
             logits, jnp.maximum(n_valid - 1, 0)[None, None, None],
             axis=1)[:, 0].astype(jnp.float32)
@@ -816,7 +825,8 @@ def build_score_program(cfg):
                                          positions=pos,
                                          block_table=block_table,
                                          paged_write_mask=write_mask,
-                                         state_slots=slots)
+                                         state_slots=slots,
+                                         paged_run=(start, n_valid))
         return gather_target_logprobs(logits, targets), cache
 
     return jax.jit(score_chunk, donate_argnums=(1,))

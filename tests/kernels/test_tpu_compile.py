@@ -364,6 +364,48 @@ def test_serving_program_never_copies_a_pool(v5e, monkeypatch, kind):
     assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES
 
 
+# what one scatter into the arena places, by program: a token's row for each
+# of the step's rows (decode), for each row's draft slots (verify), and for a
+# run of CHUNK positions the whole pages it can touch (PR 49: a row scatter
+# there has CHUNK index rows, each a half-word write on the chip's tiling)
+ARENA_UPDATES = {
+    "decode": f"bf16[{ROWS},2048]",
+    "verify": f"bf16[{ROWS},{SPEC_TOKENS},2048]",
+    "prefill": f"bf16[{CHUNK // BLOCK + 1},{BLOCK},2048]",
+    "score": f"bf16[{CHUNK // BLOCK + 1},{BLOCK},2048]",
+}
+
+
+@pytest.mark.parametrize("kind", sorted(ARENA_UPDATES))
+def test_a_run_is_written_in_pages_and_a_token_in_a_row(v5e, monkeypatch,
+                                                        kind):
+    """The two writes of a layer (k, v) are one scatter each into the arena
+    where it lies; a chunk's is of whole pages, never of CHUNK rows, and the
+    decode and verify programs' are the row scatters they were."""
+    compiled = _serving_program(kind, v5e, monkeypatch).compile()
+    arena = f"bf16[{LAYERS},{NUM_BLOCKS},{BLOCK},2048]"
+    shape_of, updates = {}, []
+    for line in compiled.as_text().splitlines():
+        m = _RESULT.match(line)
+        if m is None:
+            continue
+        name, result, op = m.groups()
+        shape_of[name] = result.split("{")[0]
+        if op == "scatter" and result.startswith(arena):
+            # (the arena, the index rows, the updates), each defined above
+            updates.append(shape_of[re.findall(
+                r"%([\w.\-]+)", line.split(" scatter(", 1)[1])[2]])
+    assert updates == [ARENA_UPDATES[kind]] * 2
+    memory = compiled.memory_analysis()
+    # both sides of the arena are the program's own operand, written in place
+    assert memory.alias_size_in_bytes >= 2 * LAYERS * POOL_BYTES
+    if kind in ("prefill", "score"):
+        # the pages gathered and laid over are 17 x 64 KB a side (parent of
+        # PR 49: 902,144 and 451,584 bytes of temporaries; 1,031,168 and
+        # 612,864 with them)
+        assert memory.temp_size_in_bytes < 2 << 20
+
+
 @pytest.mark.parametrize("kind,rows", [("decode", ROWS), ("prefill", CHUNK)])
 def test_olmoe_serving_program_computes_assigned_rows_only(v5e, monkeypatch,
                                                            kind, rows):

@@ -25,7 +25,7 @@ from deepspeed_tpu.config.base import ConfigError
 from deepspeed_tpu.config.config import ObservabilityConfig, ServingConfig
 from deepspeed_tpu.inference import init_inference
 from deepspeed_tpu.observability import (configure_observability, get_registry,
-                                         reset_session)
+                                         get_session, reset_session)
 from deepspeed_tpu.serving import (BlockAllocator, BlockAllocatorError,
                                    QueueFull, Request, RequestCancelled,
                                    Scheduler, ServingEngine)
@@ -369,6 +369,40 @@ class TestServingEngine:
                                                max_new_tokens=6))[0]
         assert got[0] == want[0]
         np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("first_turn", [5, 11, 16])
+    def test_a_chunk_that_starts_inside_a_page(self, tiny_engine, first_turn):
+        """``prefill_pos`` is any length once a context has grown by
+        something other than whole chunks (a session's earlier turn; a
+        prefix hit that recomputes its last token). The chunks behind it
+        begin inside a page and are written as whole pages, over what the
+        earlier turn left in their first page: the tokens are those of the
+        unpaged whole-prompt path."""
+        from deepspeed_tpu.serving import paged_kv
+
+        srv = serving(tiny_engine, prefill_chunk=16)
+        prompt = np.random.RandomState(3).randint(0, 250, (53,))
+        h = srv.submit(prompt, max_new_tokens=6)
+        req = srv.sched.admit()[0]
+        # the earlier turn, sent the way _step_prefill sends a chunk
+        assert srv.sched.ensure_blocks(req, first_turn)
+        chunk = np.zeros((1, 16), np.int32)
+        chunk[0, :first_turn] = prompt[:first_turn]
+        srv._run_program(
+            get_session(), "serving/prefill_chunk", srv._prefill,
+            paged_kv.pack_chunk(srv._table_for([req]), chunk, 0, first_turn,
+                                *srv._sampling_arrays([req])),
+            srv._base_rng)
+        req.prefill_pos = req.length = first_turn
+        starts = []
+        while req.state == PREFILL:
+            starts.append(req.prefill_pos)
+            assert srv._step_prefill()
+        assert starts == list(range(first_turn, 53, 16))
+        srv.run()
+        want = np.asarray(tiny_engine.generate(prompt[None],
+                                               max_new_tokens=6))[0]
+        np.testing.assert_array_equal(h.result(), want)
 
     def test_prompt_shorter_than_chunk(self, tiny_engine):
         srv = serving(tiny_engine, prefill_chunk=32)
