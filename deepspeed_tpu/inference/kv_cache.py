@@ -93,18 +93,6 @@ def cache_memory_bytes(cfg, batch_size: int, max_seq_len: int,
             * cfg.num_kv_heads * cfg.head_dim * itemsize)
 
 
-def cache_shape_struct(cfg, batch_size: int, max_seq_len: int,
-                       dtype) -> Dict[str, Any]:
-    """eval_shape-compatible structure (for AOT sharding planning)."""
-    L, K, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    shape = (L, batch_size, max_seq_len, K, D)
-    return {
-        "k": jax.ShapeDtypeStruct(shape, dtype),
-        "v": jax.ShapeDtypeStruct(shape, dtype),
-        "index": jax.ShapeDtypeStruct((cfg.num_layers,), jnp.int32),
-    }
-
-
 # ---------------------------------------------------------------------------
 # paged arena (serving layer)
 # ---------------------------------------------------------------------------
@@ -147,7 +135,7 @@ def _state_shapes(cfg, state_slots: int, dtype) -> Dict[str, Any]:
     matrix; a state-space layer's, in ``ops/mamba2.pack_states``' layout)
     and the last ``taps - 1`` rows of the convolution's input (in the
     model's dtype). ``{}`` for a model with no such layer."""
-    from ..models.transformer import KDA_CONV_TAPS, recurrent_layers
+    from ..models.transformer import MIXERS, recurrent_layers
 
     mixer, layers = recurrent_layers(cfg)
     n = len(layers)
@@ -156,15 +144,7 @@ def _state_shapes(cfg, state_slots: int, dtype) -> Dict[str, Any]:
     if state_slots < 1:
         raise ValueError("a model with recurrent layers needs state_slots: "
                          "a slot a decode row and one scratch")
-    if mixer == "mamba2":
-        H, P, N = (cfg.mamba_num_heads, cfg.mamba_head_dim,
-                   cfg.mamba_state_size)
-        G = cfg.mamba_n_groups
-        state = (G, N, H // G * P)
-        taps, width = cfg.mamba_conv_taps, H * P + 2 * G * N
-    else:
-        H, d = cfg.kda_num_heads, cfg.kda_head_dim
-        state, taps, width = (H, d, d), KDA_CONV_TAPS, 3 * H * d
+    state, taps, width = MIXERS[mixer].state(cfg)
     return {"state": ((n, state_slots) + state, jnp.float32),
             "tail": ((n, state_slots, taps - 1, width), dtype)}
 

@@ -27,14 +27,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .core import (EMBED, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ, VOCAB,
-                   cast_floating)
+from .core import (EMBED, EXPERT, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ,
+                   VOCAB)
 
 
-# the mixers that keep, a sequence, a state of fixed size and a convolution
-# tail in place of pages: THE list that kv_cache and the serving layer ask
-RECURRENT_MIXERS = ("kda", "mamba2")
-# a layer's kind -> (its mixer or None, whether the model's FFN follows it).
+# a layer's kind -> (its mixer, a key of ``MIXERS``, or None; whether the
+# model's FFN follows it).
 # "attn" and "kda" are a whole block, norm, mixer, add, norm, FFN, add; the
 # others are ONE function under one norm and one add, for a family whose
 # layers are a mixer alone or an FFN alone
@@ -204,7 +202,7 @@ class TransformerConfig:
             # the layer's place among its KIND: one kind a mixer
             mixers = [LAYER_KINDS[k][0] for k in set(self.layer_pattern)]
             assert len(mixers) == len(set(mixers)), self.layer_pattern
-            assert len(set(mixers) & set(RECURRENT_MIXERS)) <= 1, \
+            assert sum(1 for m in mixers if m and MIXERS[m].state) <= 1, \
                 "the state pools hold one kind of recurrent state"
         if self.moe_experts_held:
             assert 0 < self.moe_experts_held <= self.moe_num_experts
@@ -248,8 +246,7 @@ def eval_config(cfg: TransformerConfig) -> TransformerConfig:
 
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
     H = cfg.hidden_size
-    N, K, D, V = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                  cfg.vocab_size)
+    V = cfg.vocab_size
     # fixed key slots (branch-independent): 0 embed, 1 pos, 2 layers base,
     # 3 lm_head — the layers base key feeds init_layer_params, which draws
     # per (leaf, layer) via fold_in so any layer RANGE can be initialised
@@ -311,8 +308,8 @@ def recurrent_layers(cfg: TransformerConfig
     """(the model's recurrent mixer, the layers that have it): THE answer to
     "does this layer keep a state and a convolution tail a sequence, not
     pages". ``(None, ())`` for a model of softmax layers alone."""
-    for mixer in RECURRENT_MIXERS:
-        layers = layers_with_mixer(cfg, mixer)
+    for mixer, record in MIXERS.items():
+        layers = record.state and layers_with_mixer(cfg, mixer)
         if layers:
             return mixer, layers
     return None, ()
@@ -335,6 +332,11 @@ def layer_stacks(layers: Dict[str, Any], cfg: TransformerConfig
     return layers if len(kinds) > 1 else {next(iter(kinds)): layers}
 
 
+def _resid_std(cfg: TransformerConfig) -> float:
+    """GPT-2-style scaled init on residual-writing projections."""
+    return 0.02 / (2 * cfg.num_layers) ** 0.5
+
+
 def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                       lo: Any, blen: int) -> Dict[str, Any]:
     """Layer-stack params for layers [lo, lo+blen): leaves shaped
@@ -342,12 +344,8 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
     layer_idx)`` — so ANY range reproduces exactly the same values the full
     init produces (ZeRO-3 param offload inits one block at a time). A stack
     of several kinds (``layer_stacks``) is initialised whole."""
-    H, L = cfg.hidden_size, cfg.num_layers
-    N, K, D, F = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                  cfg.ffn_hidden_size)
-    std = 0.02
-    # GPT-2-style scaled init on residual-writing projections
-    resid_std = std / (2 * L) ** 0.5
+    H, L, F = cfg.hidden_size, cfg.num_layers, cfg.ffn_hidden_size
+    std, resid_std = 0.02, _resid_std(cfg)
     E = cfg.moe_num_experts
     held = cfg.experts_held
 
@@ -365,61 +363,10 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
         layer: Dict[str, Any] = {}
         if mixer is not None:
             layer["ln1"] = {"scale": jnp.ones((H,), cfg.dtype)}
+            layer[MIXERS[mixer].name] = MIXERS[mixer].init(cfg, normal,
+                                                           uniform)
         if has_ffn:
             layer["ln2"] = {"scale": jnp.ones((H,), cfg.dtype)}
-        if mixer == "attn":
-            layer["attn"] = {
-                "wq": normal(0, (H, N * D)),
-                "wk": normal(1, (H, K * D)),
-                "wv": normal(2, (H, K * D)),
-                "wo": normal(3, (N * D, H), resid_std),
-            }
-            if cfg.qk_norm:
-                layer["attn"]["q_norm"] = jnp.ones((N * D,), cfg.dtype)
-                layer["attn"]["k_norm"] = jnp.ones((K * D,), cfg.dtype)
-            if cfg.attn_gate:
-                layer["attn"]["wg"] = normal(11, (H, N * D))
-        elif mixer == "mamba2":
-            MH, P = cfg.mamba_num_heads, cfg.mamba_head_dim
-            inner = MH * P
-            conv = inner + 2 * cfg.mamba_n_groups * cfg.mamba_state_size
-            layer["mamba2"] = {
-                # [z | x B C | dt], as the published in_proj lays them
-                "w_in": normal(40, (H, inner + conv + MH)),
-                # taps of the depthwise convolution over time, oldest first
-                "conv_w": normal(41, (cfg.mamba_conv_taps, conv), 0.5),
-                "conv_b": normal(42, (conv,)),
-                # the published init: a step dt of 0.001 to 0.1 a head (its
-                # inverse softplus, floored at 1e-4), a rate of 1 to 16, D 1
-                "dt_bias": (lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(
-                    jnp.maximum(jnp.exp(uniform(
-                        43, (MH,), jnp.log(1e-3), jnp.log(0.1))), 1e-4)),
-                "A_log": jnp.log(uniform(44, (MH,), 1.0, 16.0)),
-                "D": jnp.ones((MH,), jnp.float32),
-                "norm": jnp.ones((inner,), cfg.dtype),
-                "w_out": normal(45, (inner, H), resid_std),
-            }
-        elif mixer == "kda":
-            KH, KD, r = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_gate_rank
-            W = KH * KD
-            layer["kda"] = {
-                "wq": normal(20, (H, W)), "wk": normal(21, (H, W)),
-                "wv": normal(22, (H, W)), "wo": normal(23, (W, H), resid_std),
-                # taps of the depthwise convolution over time, oldest first
-                "conv_q": normal(24, (KDA_CONV_TAPS, W), 0.5),
-                "conv_k": normal(25, (KDA_CONV_TAPS, W), 0.5),
-                "conv_v": normal(26, (KDA_CONV_TAPS, W), 0.5),
-                "wf1": normal(27, (H, r)), "wf2": normal(28, (r, W)),
-                "wg1": normal(29, (H, r)), "wg2": normal(30, (r, W)),
-                "wb": normal(31, (H, KH)),
-                # decay g = -exp(A_log) * softplus(. + dt_bias): a rate of
-                # 1 to 16 a head times a step of 0.001 to 0.1 a channel
-                # (softplus^-1 of it), so a token keeps 20% to 99.9%
-                "A_log": jnp.log(uniform(32, (KH,), 1.0, 16.0)),
-                "dt_bias": (lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(
-                    jnp.exp(uniform(33, (W,), jnp.log(1e-3), jnp.log(0.1)))),
-                "o_norm": jnp.ones((KD,), cfg.dtype),
-            }
         if not has_ffn:
             pass            # a mixer alone: no FFN of any kind below
         elif E > 0:
@@ -480,11 +427,6 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
             for ln in ("ln1", "ln2"):
                 if ln in layer:
                     layer[ln]["bias"] = jnp.zeros((H,), cfg.dtype)
-            if mixer == "attn":
-                layer["attn"]["bq"] = jnp.zeros((N * D,), cfg.dtype)
-                layer["attn"]["bk"] = jnp.zeros((K * D,), cfg.dtype)
-                layer["attn"]["bv"] = jnp.zeros((K * D,), cfg.dtype)
-                layer["attn"]["bo"] = jnp.zeros((H,), cfg.dtype)
         return layer
 
     kinds = sorted(set(layer_kinds(cfg)))
@@ -501,29 +443,6 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
 
 def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Logical-axis tree mirroring init_params — drives TP/ZeRO sharding."""
-    attn = {"wq": (LAYERS, EMBED, HEADS), "wk": (LAYERS, EMBED, KV_HEADS),
-            "wv": (LAYERS, EMBED, KV_HEADS), "wo": (LAYERS, HEADS, EMBED)}
-    if cfg.norm == "layernorm":
-        attn.update({"bq": (LAYERS, HEADS), "bk": (LAYERS, KV_HEADS),
-                     "bv": (LAYERS, KV_HEADS), "bo": (LAYERS, EMBED)})
-    if cfg.qk_norm:
-        attn.update({"q_norm": (LAYERS, HEADS), "k_norm": (LAYERS, KV_HEADS)})
-    if cfg.attn_gate:
-        attn["wg"] = (LAYERS, EMBED, HEADS)
-    kda = {**{w: (LAYERS, EMBED, HEADS) for w in ("wq", "wk", "wv")},
-           "wo": (LAYERS, HEADS, EMBED),
-           **{c: (LAYERS, None, HEADS) for c in ("conv_q", "conv_k",
-                                                   "conv_v")},
-           "wf1": (LAYERS, EMBED, None), "wf2": (LAYERS, None, HEADS),
-           "wg1": (LAYERS, EMBED, None), "wg2": (LAYERS, None, HEADS),
-           "wb": (LAYERS, EMBED, None), "A_log": (LAYERS, None),
-           "dt_bias": (LAYERS, HEADS), "o_norm": (LAYERS, None)}
-    mamba2 = {"w_in": (LAYERS, EMBED, HEADS), "w_out": (LAYERS, HEADS, EMBED),
-              "conv_w": (LAYERS, None, HEADS), "conv_b": (LAYERS, HEADS),
-              "norm": (LAYERS, HEADS),
-              **{a: (LAYERS, None) for a in ("dt_bias", "A_log", "D")}}
-    from .core import EXPERT
-
     if cfg.moe_num_experts > 0:
         wide = None if cfg.moe_latent_size else EMBED   # the experts' width
         mlp = {"w_up": (LAYERS, EXPERT, wide, MLP),
@@ -559,13 +478,13 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
             layer_axes["res_coef"] = {"w": (LAYERS, EMBED, None),
                                       "b": (LAYERS, None)}
     kinds = sorted(set(layer_kinds(cfg)))
-    mixers = {"attn": attn, "kda": kda, "mamba2": mamba2}
     by_kind = {}
     for kind in kinds:
         mixer, has_ffn = LAYER_KINDS[kind]
         by_kind[kind] = {**(ffn_axes if has_ffn else {}),
                          **({} if mixer is None
-                            else {"ln1": dict(ln), mixer: mixers[mixer]})}
+                            else {"ln1": dict(ln), MIXERS[mixer].name:
+                                  MIXERS[mixer].axes(cfg)})}
     axes: Dict[str, Any] = {
         "embed": {"tokens": (VOCAB, EMBED)},
         "layers": by_kind if len(kinds) > 1 else by_kind[kinds[0]],
@@ -590,9 +509,6 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 # building blocks
 # ---------------------------------------------------------------------------
-
-
-import functools as _functools
 
 
 def _tp_world() -> int:
@@ -874,17 +790,6 @@ def quantize_model_weights(params: Dict[str, Any], bits: int = 8,
     return params
 
 
-def _dense(w: Any, dtype: Any) -> jax.Array:
-    """Materialise a (possibly weight-only-quantized) weight as dense."""
-    if isinstance(w, dict) and "q8" in w:
-        return (w["q8"].astype(jnp.float32) * w["s"]).astype(dtype)
-    if isinstance(w, dict) and "q4" in w:
-        from ..ops.quant_matmul import unpack_int4
-
-        return unpack_int4(w["q4"], w["s"], dtype)
-    return w
-
-
 def _qeinsum(spec: str, x: jax.Array, w: Any, dtype: Any,
              a8: bool = False) -> jax.Array:
     """Weight-site einsum with on-the-fly int8 dequant.
@@ -1102,6 +1007,56 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class Step:
+    """What one call of ``forward`` hands each layer beside the activations
+    and the layer's params: THE list of a step's operands. ``forward``
+    builds it once from its arguments, which its docstring explains, and its
+    scan bodies close over it (it is never a scan operand, so it is no
+    pytree) and set what differs a layer (``cache``, ``layer_index``,
+    ``window``) with ``dataclasses.replace``; a function on the way down
+    names the fields it reads and no other.
+
+    ``cache`` is None (training), this layer's slice of the dense cache,
+    k/v of shape (B, T_max, K, D) and scalar ``index``
+    (``_attend_dense_cache``), or, with ``block_table``, the WHOLE arena
+    ``{"k","v": (L, NUM_BLOCKS, BLOCK, K*D)}`` of the serving layer, which
+    comes back as the new cache: ``layer_index`` (int32 scalar, the layer's
+    place among the layers of its KIND) says which layer's pool this block
+    writes and reads inside it. A pool is never sliced out of the arena: a
+    custom call's operand is a buffer of its own, so a slice is a pool-sized
+    copy in and another out (ops/paged_decode_attention.py). A recurrent
+    mixer keeps its state pools in the same dict (``_kda_mixer``), a slot a
+    row (``state_slots``). ``write_mask`` (B, S), ``forward``'s
+    ``paged_write_mask``, routes masked-off tokens (prompt chunk padding) to
+    the scratch block 0 instead of the row's blocks, keeps them out of an
+    MoE layer's routing and is what a recurrent mixer calls ``real``. S > 1
+    queries of a row sit at ``positions[b, 0] + arange(S)`` (the chunk,
+    verify and score programs).
+
+    ``expert_banks`` (inference, an MoE model): by kind of layer the model's
+    WHOLE expert stacks ``(L, E, ...)`` in place of ``layer["mlp"]``, with
+    ``layer_index`` saying which layer this is - ``forward`` keeps them out
+    of the layer scan's slicing, because the grouped-matmul kernel can read
+    a touched expert where it lies in the stack but not out of a slice that
+    XLA would first have to copy (``ops/moe_grouped_matmul.py``)."""
+    mask: Optional[jax.Array] = None        # (B, T) key padding or (B, S, T)
+    positions: Optional[jax.Array] = None   # (S,) shared or (B, S) a row
+    cache: Optional[Dict[str, jax.Array]] = None
+    block_table: Optional[jax.Array] = None     # (B, MAX_BLOCKS)
+    write_mask: Optional[jax.Array] = None
+    layer_index: Optional[jax.Array] = None
+    state_slots: Optional[jax.Array] = None     # (B,)
+    paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
+    static_prefill: bool = False    # the dense cache is written from 0 on
+    key_positions: Optional[jax.Array] = None   # (B, T), ragged alibi decode
+    window: Optional[jax.Array] = None      # this layer's sliding-window
+    #   width (traced scalar, <=0 = global): attention_layers models
+    #   (GPT-Neo) only, which take the windowed jnp attention path throughout
+    moe_counts: bool = False
+    expert_banks: Optional[Dict[str, Any]] = None
+
+
 def window_table(cfg: TransformerConfig) -> jax.Array:
     """(L,) int32 per-layer sliding-window widths from the cycled
     ``attention_layers`` pattern (0 = global/unlimited). ONE builder shared
@@ -1148,29 +1103,25 @@ def _single_chip_kernels() -> bool:
     return registry.kernels_active() and (mesh is None or mesh.size == 1)
 
 
-def _with_conv_history(x: jax.Array, cache: Optional[Dict[str, jax.Array]],
-                       kind_layer, state_slots, positions: jax.Array,
-                       taps: int) -> Tuple[jax.Array, Optional[jax.Array]]:
+def _with_conv_history(x: jax.Array, step: Step, taps: int
+                       ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """A recurrent mixer's convolution input ``x`` (B, S, W) behind the
     ``taps - 1`` rows that came before it -> ``(ext (B, taps - 1 + S, W),
     fresh)``: zeros with no cache and for a row whose first position is 0
     (``fresh`` (B,), None with no cache), else the row's slot of the pool
     ``"tail"``."""
-    if cache is None:
+    if step.cache is None:
         tail = jnp.zeros((x.shape[0], taps - 1, x.shape[-1]), x.dtype)
         fresh = None
     else:
-        fresh = positions[:, 0] == 0
-        tail = jnp.where(fresh[:, None, None], 0,
-                         cache["tail"][kind_layer, state_slots])
+        fresh = step.positions[:, 0] == 0
+        tail = jnp.where(fresh[:, None, None], 0, step.cache["tail"][
+            step.layer_index, step.state_slots])
     return jnp.concatenate([tail.astype(x.dtype), x], axis=1), fresh
 
 
 def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
-               cache: Optional[Dict[str, jax.Array]],
-               kind_layer: Optional[jax.Array],
-               state_slots: Optional[jax.Array], positions: jax.Array,
-               real: Optional[jax.Array]
+               step: Step
                ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """The "kda" mixer of ``_layer_forward``: the gated delta rule with
     per-channel decay (``ops/kda.py`` has the recurrence) over the normed
@@ -1185,25 +1136,26 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     With no cache a sequence starts from a zero state and zero convolution
     history. A cache is the serving layer's ``{"state": (layers of this
     kind, slots, heads, d, d) float32, "tail": (..., slots, taps - 1, 3 *
-    heads * d)}`` beside the pages; ``kind_layer`` says which of this kind's
-    layers this is and ``state_slots`` (B,) which slot each row owns. A row
-    whose first position is 0 starts from zeros whatever its slot held (a
-    sequence is reset as data, on admission and on re-admission after a
-    preemption alike). ``real`` (B, S) marks the tokens that exist, a prefix
-    of each row: the others (a ragged chunk's padding, a decode row that
-    holds nothing) write nothing - beta 0, decay 1, and the tail is taken
-    from the last REAL rows."""
+    heads * d)}`` beside the pages; ``step.layer_index`` says which of this
+    kind's layers this is and ``step.state_slots`` (B,) which slot each row
+    owns. A row whose first position is 0 starts from zeros whatever its
+    slot held (a sequence is reset as data, on admission and on re-admission
+    after a preemption alike). ``real``, the step's ``write_mask`` (B, S),
+    marks the tokens that exist, a prefix of each row: the others (a ragged
+    chunk's padding, a decode row that holds nothing) write nothing - beta
+    0, decay 1, and the tail is taken from the last REAL rows."""
     from ..ops import kda as kda_ops
 
     f32 = jnp.float32
+    cache, real = step.cache, step.write_mask
+    at = (step.layer_index, step.state_slots)   # this layer's, each row's
     B, S, _ = h.shape
     KH, KD, taps = cfg.kda_num_heads, cfg.kda_head_dim, KDA_CONV_TAPS
     W = KH * KD
     qkv = jnp.concatenate(
         [jnp.einsum("bsh,hd->bsd", h, p[w]) for w in ("wq", "wk", "wv")],
         axis=-1)                                            # (B, S, 3W)
-    ext, fresh = _with_conv_history(qkv, cache, kind_layer, state_slots,
-                                    positions, taps)
+    ext, fresh = _with_conv_history(qkv, step, taps)
     conv = jnp.concatenate([p["conv_q"], p["conv_k"], p["conv_v"]],
                            axis=-1).astype(f32)             # (taps, 3W)
     mixed = sum(conv[j] * ext[:, j:j + S].astype(f32) for j in range(taps))
@@ -1234,19 +1186,19 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                                  jnp.zeros((B, KH, KD, KD), f32))
     else:
         if S == 1:
-            step = (kda_ops.kda_decode_step if _single_chip_kernels()
-                    else kda_ops.reference_kda_decode_step)
-            o, states = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                             cache["state"], kind_layer, state_slots)
+            advance = (kda_ops.kda_decode_step if _single_chip_kernels()
+                       else kda_ops.reference_kda_decode_step)
+            o, states = advance(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0], cache["state"], *at)
             o = o[:, None]
         else:
             start = jnp.where(fresh[:, None, None, None], 0.0,
-                              cache["state"][kind_layer, state_slots])
+                              cache["state"][at])
             o, end = kda_ops.kda_chunk(q, k, v, g, beta, start)
-            states = cache["state"].at[kind_layer, state_slots].set(
+            states = cache["state"].at[at].set(
                 end.astype(cache["state"].dtype))
         new_cache = {**cache, "state": states,
-                     "tail": cache["tail"].at[kind_layer, state_slots].set(
+                     "tail": cache["tail"].at[at].set(
                          _last_real_rows(ext, real, taps - 1).astype(
                              cache["tail"].dtype))}
 
@@ -1258,10 +1210,7 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
 
 
 def _mamba2_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
-                  cache: Optional[Dict[str, jax.Array]],
-                  kind_layer: Optional[jax.Array],
-                  state_slots: Optional[jax.Array], positions: jax.Array,
-                  real: Optional[jax.Array]
+                  step: Step
                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """The "mamba2" mixer of ``_layer_forward``: the Mamba-2 state-space
     recurrence (``ops/mamba2.py``) over the normed input ``h`` (B, S, H) ->
@@ -1274,13 +1223,15 @@ def _mamba2_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     ``D x``; ``y = RMSNorm_grouped(y * silu(z)) W_out``, the norm over each
     group's channels.
 
-    The cache, ``kind_layer``, ``state_slots``, a row's start at position 0
-    and ``real`` are ``_kda_mixer``'s, with ``"state"`` in the layout of
+    The cache, the layer's index, the rows' slots, a row's start at position
+    0 and ``real`` are ``_kda_mixer``'s, with ``"state"`` in the layout of
     ``ops/mamba2.pack_states`` and a tail of ``xBC``'s width; a token that
     does not exist has ``dt`` 0 and writes nothing."""
     from ..ops import mamba2 as ssm
 
     f32 = jnp.float32
+    cache, real = step.cache, step.write_mask
+    at = (step.layer_index, step.state_slots)   # this layer's, each row's
     B, S, _ = h.shape
     MH, P, G, N, taps = (cfg.mamba_num_heads, cfg.mamba_head_dim,
                          cfg.mamba_n_groups, cfg.mamba_state_size,
@@ -1288,8 +1239,7 @@ def _mamba2_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     inner = MH * P
     z, xbc, dt = jnp.split(jnp.einsum("bsh,hd->bsd", h, p["w_in"]),
                            [inner, 2 * inner + 2 * G * N], axis=-1)
-    ext, fresh = _with_conv_history(xbc, cache, kind_layer, state_slots,
-                                    positions, taps)
+    ext, fresh = _with_conv_history(xbc, step, taps)
     conv = p["conv_w"].astype(f32)
     mixed = jax.nn.silu(p["conv_b"].astype(f32) + sum(
         conv[j] * ext[:, j:j + S].astype(f32) for j in range(taps)))
@@ -1308,19 +1258,19 @@ def _mamba2_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                                 packed=True)
     else:
         if S == 1:
-            step = (ssm.mamba2_decode_step if _single_chip_kernels()
-                    else ssm.reference_mamba2_decode_step)
-            y, states = step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
-                             cache["state"], kind_layer, state_slots)
+            advance = (ssm.mamba2_decode_step if _single_chip_kernels()
+                       else ssm.reference_mamba2_decode_step)
+            y, states = advance(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                                cache["state"], *at)
             y = y[:, None]
         else:
             start = jnp.where(fresh[:, None, None, None], 0.0,
-                              cache["state"][kind_layer, state_slots])
+                              cache["state"][at])
             y, end = ssm.mamba2_chunk(x, dt, A, Bm, Cm, start, packed=True)
-            states = cache["state"].at[kind_layer, state_slots].set(
+            states = cache["state"].at[at].set(
                 end.astype(cache["state"].dtype))
         new_cache = {**cache, "state": states,
-                     "tail": cache["tail"].at[kind_layer, state_slots].set(
+                     "tail": cache["tail"].at[at].set(
                          _last_real_rows(ext, real, taps - 1).astype(
                              cache["tail"].dtype))}
 
@@ -1391,260 +1341,409 @@ def _write_pages(arena: jax.Array, layer: jax.Array, rows: jax.Array,
     return arena.at[layer, blk].set(pages.reshape(B, P, block, W))
 
 
-def _softmax_mixer(cfg: TransformerConfig, h: jax.Array,
-                   layer: Dict[str, Any], mask: Optional[jax.Array],
-                   positions: jax.Array,
-                   cache: Optional[Dict[str, jax.Array]],
-                   static_prefill: bool,
-                   key_positions: Optional[jax.Array],
-                   window: Optional[jax.Array],
-                   block_table: Optional[jax.Array],
-                   paged_write_mask: Optional[jax.Array],
-                   paged_layer: Optional[jax.Array],
-                   paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
-                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """The "attn" mixer of ``_layer_forward``: softmax attention over the
-    normed input ``h`` -> (its contribution to the residual, new cache)."""
-    B, S, H = h.shape
+def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any]
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The "attn" mixer's projections of ``h`` (B, S, H) as heads: q
+    (B, S, N, D), k and v (B, S, K, D), biased and normed, not yet roped."""
+    B, S, _ = h.shape
     N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _qeinsum("bsh,hd->bsd", h, layer["attn"]["wq"], cfg.dtype, a8=cfg.a8_decode)
-    k = _qeinsum("bsh,hd->bsd", h, layer["attn"]["wk"], cfg.dtype, a8=cfg.a8_decode)
-    v = _qeinsum("bsh,hd->bsd", h, layer["attn"]["wv"], cfg.dtype, a8=cfg.a8_decode)
-    if "bq" in layer["attn"]:
-        q = q + layer["attn"]["bq"]
-        k = k + layer["attn"]["bk"]
-        v = v + layer["attn"]["bv"]
+    q = _qeinsum("bsh,hd->bsd", h, p["wq"], cfg.dtype, a8=cfg.a8_decode)
+    k = _qeinsum("bsh,hd->bsd", h, p["wk"], cfg.dtype, a8=cfg.a8_decode)
+    v = _qeinsum("bsh,hd->bsd", h, p["wv"], cfg.dtype, a8=cfg.a8_decode)
+    if "bq" in p:
+        q = q + p["bq"]
+        k = k + p["bk"]
+        v = v + p["bv"]
     if cfg.qk_norm:
         # over all heads at once (the published OlmoeAttention: q_norm and
         # k_norm are hidden-wide), before the heads are split and roped
-        q = _norm(q, layer["attn"]["q_norm"], None, "rmsnorm", cfg.norm_eps)
-        k = _norm(k, layer["attn"]["k_norm"], None, "rmsnorm", cfg.norm_eps)
-    q = q.reshape(B, S, N, D)
-    k = k.reshape(B, S, K, D)
-    v = v.reshape(B, S, K, D)
+        q = _norm(q, p["q_norm"], None, "rmsnorm", cfg.norm_eps)
+        k = _norm(k, p["k_norm"], None, "rmsnorm", cfg.norm_eps)
+    return (q.reshape(B, S, N, D), k.reshape(B, S, K, D),
+            v.reshape(B, S, K, D))
 
-    # SP reshard around attention. Ulysses: sequence gathered, heads
-    # scattered over ('seq','model') — XLA lowers the constraint to the
-    # head-scatter all-to-all. Ring: tokens STAY seq-sharded; KV chunks
-    # rotate inside ring_attention instead. Training path only (no cache).
-    from ..parallel.ring import ring_attention_enabled
 
-    use_ring = (cache is None and ring_attention_enabled()
-                and cfg.attention_impl is None)
-    if cache is None and not use_ring:
-        from ..parallel.sequence import attn_out_spec, heads_spec, constrain
+def _rope_qk(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
+             positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """q and k with the rotary embedding of ``positions`` (a model of
+    another position kind: as they came)."""
+    if cfg.position != "rope":
+        return q, k
+    D = cfg.head_dim
+    rd = cfg.rotary_dim or D
+    cos, sin = rope_table(positions, rd, cfg.rope_theta)
+    if rd < D:
+        # partial rotary (GPT-J/NeoX): rope on the first rd dims only.
+        # (GPT-J's interleaved convention is handled at import time by
+        # permuting the rotary columns of wq/wk into rotate-half order.)
+        q = jnp.concatenate(
+            [apply_rope(q[..., :rd], cos, sin), q[..., rd:]], axis=-1)
+        k = jnp.concatenate(
+            [apply_rope(k[..., :rd], cos, sin), k[..., rd:]], axis=-1)
+        return q, k
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
-        qspec = heads_spec(N)
-        kspec = heads_spec(K)
-        if qspec is not None and kspec is not None:
-            # two-step reshard: first pin the natural post-reshape layout
-            # (tokens over 'seq', heads over 'model') so the head-scatter
-            # all-to-all is a 4D→4D transition — without this, the BACKWARD
-            # of the (B,S,N·D)→(B,S,N,D) reshape sees a heads-over-4-way
-            # cotangent and XLA falls into involuntary full remat
-            nat_q, nat_k = attn_out_spec(N), attn_out_spec(K)
-            if nat_q is not None and nat_k is not None:
-                q = constrain(q, nat_q)
-                k = constrain(k, nat_k)
-                v = constrain(v, nat_k)
-            q = constrain(q, qspec)
-            k = constrain(k, kspec)
-            v = constrain(v, kspec)
 
-    if cfg.position == "rope":
-        rd = cfg.rotary_dim or D
-        cos, sin = rope_table(positions, rd, cfg.rope_theta)
-        if rd < D:
-            # partial rotary (GPT-J/NeoX): rope on the first rd dims only.
-            # (GPT-J's interleaved convention is handled at import time by
-            # permuting the rotary columns of wq/wk into rotate-half order.)
-            q = jnp.concatenate(
-                [apply_rope(q[..., :rd], cos, sin), q[..., rd:]], axis=-1)
-            k = jnp.concatenate(
-                [apply_rope(k[..., :rd], cos, sin), k[..., rd:]], axis=-1)
-        else:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-
-    attn_fn = cfg.attention_impl or default_attention_impl()
-    if window is not None or cfg.attention_scale is not None:
-        if cfg.attention_impl is not None:
-            raise NotImplementedError(
-                "custom attention_impl + sliding-window/custom-scale "
-                "attention (GPT-Neo family) is not supported — silently "
-                "replacing the custom impl with the windowed jnp path "
-                "would change the model")
-        # windowed / custom-scale attention routes through the jnp path
-        # (the flash kernel has neither operand); window is applied at the
-        # call sites below — the decode fallback needs TRUE positions, not
-        # the end-aligned convention inside dot_product_attention
-        attn_fn = _functools.partial(dot_product_attention,
-                                     scale=cfg.attention_scale)
-    alibi = alibi_slopes(N) if cfg.position == "alibi" else None
+def _attention_fn(cfg: TransformerConfig, window: Optional[jax.Array]
+                  ) -> Tuple[Callable, Optional[jax.Array]]:
+    """(the attention a read without pages calls: the custom
+    ``attention_impl``, the platform's default, or the windowed jnp path;
+    the per-head alibi slopes of a model that has them)."""
+    alibi = alibi_slopes(cfg.num_heads) if cfg.position == "alibi" else None
     if alibi is not None and cfg.attention_impl is not None:
         _require_impl_kwarg(cfg.attention_impl, "alibi",
                             "position='alibi' models (BLOOM) — silently "
                             "dropping the alibi bias would change the model")
-    new_cache = None
-    if cache is not None and block_table is not None:
-        # PAGED serving path (deepspeed_tpu/serving/paged_kv.py): token at
-        # absolute position p lands in physical block block_table[b, p//BS]
-        # at offset p%BS — a scatter write. The layout is left-aligned
-        # (column == true position), so causality over true positions is
-        # the whole validity story and keys' alibi column bias is exact by
-        # construction. The read walks the table and is shape-static: one
-        # compiled program covers any arena occupancy (the jit-cache analog
-        # of vLLM's PagedAttention block tables).
-        BSz = cache["k"].shape[2]
-        pos = positions if positions.ndim == 2 else jnp.broadcast_to(
-            positions[None], (B, S))
-        k_rows = k.reshape(B, S, K * D).astype(cache["k"].dtype)
-        v_rows = v.reshape(B, S, K * D).astype(cache["v"].dtype)
-        if paged_run is not None and S >= BSz:
-            # a RUN of a page or more (a prompt or scoring chunk): whole
-            # pages, not rows. The arena's tiling on the chip packs two
-            # consecutive token rows into every 32-bit word and makes a
-            # 16-row page 16 whole tiles, so a row update is a half-word
-            # write into tiles the next row touches again: 256 of them cost
-            # a chunk program a quarter of its time (PERF.md, PR 49)
-            pages = _run_pages(block_table, BSz, S, *paged_run)
-            ck = _write_pages(cache["k"], paged_layer, k_rows, *pages)
-            cv = _write_pages(cache["v"], paged_layer, v_rows, *pages)
+    if window is None and cfg.attention_scale is None:
+        return cfg.attention_impl or default_attention_impl(), alibi
+    if cfg.attention_impl is not None:
+        raise NotImplementedError(
+            "custom attention_impl + sliding-window/custom-scale "
+            "attention (GPT-Neo family) is not supported — silently "
+            "replacing the custom impl with the windowed jnp path "
+            "would change the model")
+    # windowed / custom-scale attention routes through the jnp path
+    # (the flash kernel has neither operand); window is applied at the
+    # call sites — the decode fallback needs TRUE positions, not
+    # the end-aligned convention inside dot_product_attention
+    return partial(dot_product_attention, scale=cfg.attention_scale), alibi
+
+
+def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
+                  v: jax.Array, step: Step
+                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The "attn" mixer's write and read over the serving layer's pages."""
+    # PAGED serving path (deepspeed_tpu/serving/paged_kv.py): token at
+    # absolute position p lands in physical block block_table[b, p//BS]
+    # at offset p%BS — a scatter write. The layout is left-aligned
+    # (column == true position), so causality over true positions is
+    # the whole validity story and keys' alibi column bias is exact by
+    # construction. The read walks the table and is shape-static: one
+    # compiled program covers any arena occupancy (the jit-cache analog
+    # of vLLM's PagedAttention block tables).
+    from ..ops.paged_decode_attention import paged_attention
+
+    cache, block_table, layer = step.cache, step.block_table, step.layer_index
+    B, S, K, D = k.shape
+    q, k = _rope_qk(cfg, q, k, step.positions)
+    _, alibi = _attention_fn(cfg, None)
+    BSz = cache["k"].shape[2]
+    pos = step.positions            # (B, S): ``forward`` takes no other
+    k_rows = k.reshape(B, S, K * D).astype(cache["k"].dtype)
+    v_rows = v.reshape(B, S, K * D).astype(cache["v"].dtype)
+    if step.paged_run is not None and S >= BSz:
+        # a RUN of a page or more (a prompt or scoring chunk): whole
+        # pages, not rows. The arena's tiling on the chip packs two
+        # consecutive token rows into every 32-bit word and makes a
+        # 16-row page 16 whole tiles, so a row update is a half-word
+        # write into tiles the next row touches again: 256 of them cost
+        # a chunk program a quarter of its time (PERF.md, PR 49)
+        pages = _run_pages(block_table, BSz, S, *step.paged_run)
+        ck = _write_pages(cache["k"], layer, k_rows, *pages)
+        cv = _write_pages(cache["v"], layer, v_rows, *pages)
+    else:
+        # one token a row (decode) or fewer than a page (verify): rows
+        T_view = block_table.shape[1] * BSz
+        wpos = jnp.minimum(pos, T_view - 1)   # clamp pad writes in-range
+        blk = jnp.take_along_axis(block_table, wpos // BSz, axis=1)
+        off = wpos % BSz
+        if step.write_mask is not None:
+            # chunk padding / inactive decode rows write to scratch
+            # block 0
+            blk = jnp.where(step.write_mask, blk, 0)
+            off = jnp.where(step.write_mask, off, 0)
+        # ONE scatter into the 4-D arena, which the layer scan carries:
+        # it updates the carry in place, only the written rows move. An
+        # arena row is one token's K*D lanes
+        # (ops/paged_decode_attention.py)
+        ck = cache["k"].at[layer, blk, off].set(k_rows)
+        cv = cache["v"].at[layer, blk, off].set(v_rows)
+    attn = paged_attention(q, ck, cv, layer, block_table, pos, alibi=alibi)
+    return attn, {**cache, "k": ck, "v": cv}
+
+
+def _attend_dense_cache(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
+                        v: jax.Array, step: Step
+                        ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The "attn" mixer's write and read over the dense cache of
+    ``inference/engine.py``: k and v (B, T_max, K, D) and a scalar
+    ``index``, this layer's slice."""
+    from ..ops import registry
+
+    cache, mask, window = step.cache, step.mask, step.window
+    B, S = q.shape[:2]
+    q, k = _rope_qk(cfg, q, k, step.positions)
+    attn_fn, alibi = _attention_fn(cfg, window)
+    idx = cache["index"]
+    ck = lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
+    cv = lax.dynamic_update_slice(cache["v"], v, (0, idx, 0, 0))
+    new_cache = {"k": ck, "v": cv, "index": idx + S}
+    T = ck.shape[1]
+    kernels = (cfg.attention_impl is None and registry.kernels_active()
+               and window is None and cfg.attention_scale is None)
+    akw = {} if alibi is None else {"alibi": alibi}
+    if S == 1 and kernels:
+        # single-token decode → Pallas decode kernel (GQA-native, reads
+        # the arena without head expansion; alibi in-kernel)
+        from ..ops.decode_attention import decode_attention
+
+        causal_valid = (jnp.arange(T)[None, :] <= idx).astype(jnp.int32)
+        if mask is not None:
+            # AND with causal so unwritten arena slots are never live,
+            # matching the jnp fallback's causal_mask * mask semantics
+            valid = mask * causal_valid
         else:
-            # one token a row (decode) or fewer than a page (verify): rows
-            T_view = block_table.shape[1] * BSz
-            wpos = jnp.minimum(pos, T_view - 1)   # clamp pad writes in-range
-            blk = jnp.take_along_axis(block_table, wpos // BSz, axis=1)
-            off = wpos % BSz
-            if paged_write_mask is not None:
-                # chunk padding / inactive decode rows write to scratch
-                # block 0
-                blk = jnp.where(paged_write_mask, blk, 0)
-                off = jnp.where(paged_write_mask, off, 0)
-            # ONE scatter into the 4-D arena, which the layer scan carries:
-            # it updates the carry in place, only the written rows move. An
-            # arena row is one token's K*D lanes
-            # (ops/paged_decode_attention.py)
-            ck = cache["k"].at[paged_layer, blk, off].set(k_rows)
-            cv = cache["v"].at[paged_layer, blk, off].set(v_rows)
-        new_cache = {**cache, "k": ck, "v": cv}
-        from ..ops.paged_decode_attention import paged_attention
-
-        attn = paged_attention(q, ck, cv, paged_layer, block_table, pos,
-                               alibi=alibi)
-    elif cache is not None:
-        from ..ops import registry
-
-        idx = cache["index"]
-        ck = lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
-        cv = lax.dynamic_update_slice(cache["v"], v, (0, idx, 0, 0))
-        new_cache = {"k": ck, "v": cv, "index": idx + S}
-        T = ck.shape[1]
-        if (S == 1 and cfg.attention_impl is None
-                and registry.kernels_active() and window is None
-                and cfg.attention_scale is None):
-            # single-token decode → Pallas decode kernel (GQA-native, reads
-            # the arena without head expansion; alibi in-kernel)
-            from ..ops.decode_attention import decode_attention
-
-            causal_valid = (jnp.arange(T)[None, :] <= idx).astype(jnp.int32)
-            if mask is not None:
-                # AND with causal so unwritten arena slots are never live,
-                # matching the jnp fallback's causal_mask * mask semantics
-                valid = mask * causal_valid
-            else:
-                valid = jnp.broadcast_to(causal_valid, (B, T))
-            attn = decode_attention(q[:, 0], ck, cv, valid, alibi=alibi,
-                                    key_positions=key_positions)[:, None]
-        elif (static_prefill and S > 1 and cfg.attention_impl is None
-              and registry.kernels_active() and T % 128 == 0 and window is None
-              and cfg.attention_scale is None):
-            # prefill from position 0: queries sit at absolute rows 0..S-1, so
-            # the flash kernel's 0-based causal col<=row over the arena is
-            # exact and the (B, T_max) validity mask covers padding +
-            # unwritten slots — keeps the TTFT path on the flash kernel
-            # instead of a (B,S,T) mask fallback. Kernel-only: the jnp path's
-            # causal convention is end-aligned (q at T-S), so it must not
-            # take this branch.
-            valid = (mask if mask is not None else
-                     jnp.broadcast_to(
-                         (jnp.arange(T)[None, :] < S).astype(jnp.int32), (B, T)))
-            if alibi is None:
-                attn = attn_fn(q, ck, cv, valid, causal=True)
-            else:
-                attn = attn_fn(q, ck, cv, valid, causal=True, alibi=alibi)
+            valid = jnp.broadcast_to(causal_valid, (B, T))
+        return decode_attention(q[:, 0], ck, cv, valid, alibi=alibi,
+                                key_positions=step.key_positions
+                                )[:, None], new_cache
+    if step.static_prefill and S > 1 and kernels and T % 128 == 0:
+        # prefill from position 0: queries sit at absolute rows 0..S-1, so
+        # the flash kernel's 0-based causal col<=row over the arena is
+        # exact and the (B, T_max) validity mask covers padding +
+        # unwritten slots — keeps the TTFT path on the flash kernel
+        # instead of a (B,S,T) mask fallback. Kernel-only: the jnp path's
+        # causal convention is end-aligned (q at T-S), so it must not
+        # take this branch.
+        valid = (mask if mask is not None else
+                 jnp.broadcast_to(
+                     (jnp.arange(T)[None, :] < S).astype(jnp.int32), (B, T)))
+        return attn_fn(q, ck, cv, valid, causal=True, **akw), new_cache
+    # causal over absolute positions: query s sits at idx+s, keys valid <= that
+    q_pos = idx + jnp.arange(S)
+    k_pos = jnp.arange(T)
+    causal_mask = (k_pos[None, :] <= q_pos[:, None])            # (S,T)
+    if window is not None:
+        # sliding window over TRUE positions (decode: q at idx+s)
+        causal_mask = causal_mask & (
+            (window <= 0)
+            | (q_pos[:, None] - k_pos[None, :] < window))
+    causal_mask = causal_mask.astype(jnp.int32)
+    full = jnp.broadcast_to(causal_mask[None], (B, S, T))
+    if mask is not None:  # (B, T_prompt) padding mask padded to T by caller
+        full = full * mask[:, None, :]
+    if alibi is not None and step.key_positions is not None:
+        akw["key_positions"] = step.key_positions
+        if cfg.attention_impl is None:
+            attn_fn = dot_product_attention
         else:
-            k, v = ck, cv
-            # causal over absolute positions: query s sits at idx+s, keys valid <= that
-            q_pos = idx + jnp.arange(S)
-            k_pos = jnp.arange(T)
-            causal_mask = (k_pos[None, :] <= q_pos[:, None])            # (S,T)
-            if window is not None:
-                # sliding window over TRUE positions (decode: q at idx+s)
-                causal_mask = causal_mask & (
-                    (window <= 0)
-                    | (q_pos[:, None] - k_pos[None, :] < window))
-            causal_mask = causal_mask.astype(jnp.int32)
-            full = jnp.broadcast_to(causal_mask[None], (B, S, T))
-            if mask is not None:  # (B, T_prompt) padding mask padded to T by caller
-                full = full * mask[:, None, :]
-            if alibi is None:
-                attn = attn_fn(q, k, v, full, causal=False)
-            elif key_positions is not None:
-                if cfg.attention_impl is not None:
-                    _require_impl_kwarg(
-                        cfg.attention_impl, "key_positions",
-                        "ragged alibi decode — silently swapping in the "
-                        "reference attention would change the model's "
-                        "performance profile")
-                    attn = attn_fn(q, k, v, full, causal=False, alibi=alibi,
-                                   key_positions=key_positions)
-                else:
-                    attn = dot_product_attention(
-                        q, k, v, full, causal=False, alibi=alibi,
-                        key_positions=key_positions)
-            else:
-                attn = attn_fn(q, k, v, full, causal=False, alibi=alibi)
-    elif use_ring:
-        from ..parallel.ring import ring_attention
+            _require_impl_kwarg(
+                cfg.attention_impl, "key_positions",
+                "ragged alibi decode — silently swapping in the "
+                "reference attention would change the model's "
+                "performance profile")
+    return attn_fn(q, ck, cv, full, causal=False, **akw), new_cache
 
+
+def _attend_train(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
+                  v: jax.Array, step: Step) -> Tuple[jax.Array, None]:
+    """The "attn" mixer's read with no cache: the sequence against itself,
+    under sequence parallelism between its two reshards."""
+    # SP reshard around attention. Ulysses: sequence gathered, heads
+    # scattered over ('seq','model') — XLA lowers the constraint to the
+    # head-scatter all-to-all. Ring: tokens STAY seq-sharded; KV chunks
+    # rotate inside ring_attention instead. Training path only (no cache).
+    from ..parallel.ring import ring_attention, ring_attention_enabled
+    from ..parallel.sequence import attn_out_spec, heads_spec, constrain
+
+    N, K = q.shape[2], k.shape[2]
+    use_ring = ring_attention_enabled() and cfg.attention_impl is None
+    qspec, kspec = heads_spec(N), heads_spec(K)
+    if not use_ring and qspec is not None and kspec is not None:
+        # two-step reshard: first pin the natural post-reshape layout
+        # (tokens over 'seq', heads over 'model') so the head-scatter
+        # all-to-all is a 4D→4D transition — without this, the BACKWARD
+        # of the (B,S,N·D)→(B,S,N,D) reshape sees a heads-over-4-way
+        # cotangent and XLA falls into involuntary full remat
+        nat_q, nat_k = attn_out_spec(N), attn_out_spec(K)
+        if nat_q is not None and nat_k is not None:
+            q = constrain(q, nat_q)
+            k = constrain(k, nat_k)
+            v = constrain(v, nat_k)
+        q = constrain(q, qspec)
+        k = constrain(k, kspec)
+        v = constrain(v, kspec)
+    q, k = _rope_qk(cfg, q, k, step.positions)
+    attn_fn, alibi = _attention_fn(cfg, step.window)
+    if use_ring:
         if alibi is not None:
             raise NotImplementedError(
                 "ring attention + alibi is not supported yet — use "
                 "sequence_parallel_impl='ulysses' for BLOOM-family models")
-        attn = ring_attention(q, k, v, mask=mask, causal=True)
-    else:
-        wkw = {} if window is None else {"window": window}
-        if alibi is None:
-            attn = attn_fn(q, k, v, mask, causal=cfg.causal, **wkw)
-        else:
-            attn = attn_fn(q, k, v, mask, causal=cfg.causal, alibi=alibi,
-                           **wkw)
+        return ring_attention(q, k, v, mask=step.mask, causal=True), None
+    kw = {} if step.window is None else {"window": step.window}
+    if alibi is not None:
+        kw["alibi"] = alibi
+    attn = attn_fn(q, k, v, step.mask, causal=cfg.causal, **kw)
+    out_spec = attn_out_spec(N)
+    if out_spec is not None:
+        # Ulysses inverse all-to-all on the 4D tensor (see attn_out_spec)
+        attn = constrain(attn, out_spec)
+    return attn, None
 
-    if cache is None and not use_ring:
-        from ..parallel.sequence import attn_out_spec, constrain
 
-        out_spec = attn_out_spec(N)
-        if out_spec is not None:
-            # Ulysses inverse all-to-all on the 4D tensor (see attn_out_spec)
-            attn = constrain(attn, out_spec)
-    attn = attn.reshape(B, S, N * D)
-    if "wg" in layer["attn"]:
+def _softmax_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+                   step: Step
+                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """The "attn" mixer of ``_layer_forward``: softmax attention over the
+    normed input ``h`` -> (its contribution to the residual, new cache).
+    The projections, one of three reads by what the step keeps (nothing,
+    the dense cache, pages), the output gate and projection."""
+    B, S, _ = h.shape
+    attend = (_attend_train if step.cache is None else
+              _attend_dense_cache if step.block_table is None else
+              _attend_paged)
+    attn, new_cache = attend(cfg, *_qkv_heads(cfg, h, p), step)
+    attn = attn.reshape(B, S, cfg.num_heads * cfg.head_dim)
+    if "wg" in p:
         # the output gate: elementwise and full-rank, from the layer's input
-        gate = _qeinsum("bsh,hd->bsd", h, layer["attn"]["wg"], cfg.dtype,
+        gate = _qeinsum("bsh,hd->bsd", h, p["wg"], cfg.dtype,
                         a8=cfg.a8_decode)
         attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
             attn.dtype)
-    attn_out = _qeinsum("bsd,dh->bsh", attn, layer["attn"]["wo"], cfg.dtype, a8=cfg.a8_decode)
-    if "bo" in layer["attn"]:
-        attn_out = attn_out + layer["attn"]["bo"]
+    attn_out = _qeinsum("bsd,dh->bsh", attn, p["wo"], cfg.dtype,
+                        a8=cfg.a8_decode)
+    if "bo" in p:
+        attn_out = attn_out + p["bo"]
     return attn_out, new_cache
 
 
+def _attn_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
+    H = cfg.hidden_size
+    N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    p = {
+        "wq": normal(0, (H, N * D)),
+        "wk": normal(1, (H, K * D)),
+        "wv": normal(2, (H, K * D)),
+        "wo": normal(3, (N * D, H), _resid_std(cfg)),
+    }
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((N * D,), cfg.dtype)
+        p["k_norm"] = jnp.ones((K * D,), cfg.dtype)
+    if cfg.attn_gate:
+        p["wg"] = normal(11, (H, N * D))
+    if cfg.norm == "layernorm":
+        p["bq"] = jnp.zeros((N * D,), cfg.dtype)
+        p["bk"] = jnp.zeros((K * D,), cfg.dtype)
+        p["bv"] = jnp.zeros((K * D,), cfg.dtype)
+        p["bo"] = jnp.zeros((H,), cfg.dtype)
+    return p
+
+
+def _attn_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    attn = {"wq": (LAYERS, EMBED, HEADS), "wk": (LAYERS, EMBED, KV_HEADS),
+            "wv": (LAYERS, EMBED, KV_HEADS), "wo": (LAYERS, HEADS, EMBED)}
+    if cfg.norm == "layernorm":
+        attn.update({"bq": (LAYERS, HEADS), "bk": (LAYERS, KV_HEADS),
+                     "bv": (LAYERS, KV_HEADS), "bo": (LAYERS, EMBED)})
+    if cfg.qk_norm:
+        attn.update({"q_norm": (LAYERS, HEADS), "k_norm": (LAYERS, KV_HEADS)})
+    if cfg.attn_gate:
+        attn["wg"] = (LAYERS, EMBED, HEADS)
+    return attn
+
+
+def _kda_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
+    H = cfg.hidden_size
+    KH, KD, r = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+    W = KH * KD
+    return {
+        "wq": normal(20, (H, W)), "wk": normal(21, (H, W)),
+        "wv": normal(22, (H, W)), "wo": normal(23, (W, H), _resid_std(cfg)),
+        # taps of the depthwise convolution over time, oldest first
+        "conv_q": normal(24, (KDA_CONV_TAPS, W), 0.5),
+        "conv_k": normal(25, (KDA_CONV_TAPS, W), 0.5),
+        "conv_v": normal(26, (KDA_CONV_TAPS, W), 0.5),
+        "wf1": normal(27, (H, r)), "wf2": normal(28, (r, W)),
+        "wg1": normal(29, (H, r)), "wg2": normal(30, (r, W)),
+        "wb": normal(31, (H, KH)),
+        # decay g = -exp(A_log) * softplus(. + dt_bias): a rate of
+        # 1 to 16 a head times a step of 0.001 to 0.1 a channel
+        # (softplus^-1 of it), so a token keeps 20% to 99.9%
+        "A_log": jnp.log(uniform(32, (KH,), 1.0, 16.0)),
+        "dt_bias": (lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(
+            jnp.exp(uniform(33, (W,), jnp.log(1e-3), jnp.log(0.1)))),
+        "o_norm": jnp.ones((KD,), cfg.dtype),
+    }
+
+
+def _kda_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    return {**{w: (LAYERS, EMBED, HEADS) for w in ("wq", "wk", "wv")},
+            "wo": (LAYERS, HEADS, EMBED),
+            **{c: (LAYERS, None, HEADS) for c in ("conv_q", "conv_k",
+                                                    "conv_v")},
+            "wf1": (LAYERS, EMBED, None), "wf2": (LAYERS, None, HEADS),
+            "wg1": (LAYERS, EMBED, None), "wg2": (LAYERS, None, HEADS),
+            "wb": (LAYERS, EMBED, None), "A_log": (LAYERS, None),
+            "dt_bias": (LAYERS, HEADS), "o_norm": (LAYERS, None)}
+
+
+def _kda_state(cfg: TransformerConfig):
+    H, d = cfg.kda_num_heads, cfg.kda_head_dim
+    return (H, d, d), KDA_CONV_TAPS, 3 * H * d
+
+
+def _mamba2_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
+    H = cfg.hidden_size
+    MH, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+    inner = MH * P
+    conv = inner + 2 * cfg.mamba_n_groups * cfg.mamba_state_size
+    return {
+        # [z | x B C | dt], as the published in_proj lays them
+        "w_in": normal(40, (H, inner + conv + MH)),
+        # taps of the depthwise convolution over time, oldest first
+        "conv_w": normal(41, (cfg.mamba_conv_taps, conv), 0.5),
+        "conv_b": normal(42, (conv,)),
+        # the published init: a step dt of 0.001 to 0.1 a head (its
+        # inverse softplus, floored at 1e-4), a rate of 1 to 16, D 1
+        "dt_bias": (lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(
+            jnp.maximum(jnp.exp(uniform(
+                43, (MH,), jnp.log(1e-3), jnp.log(0.1))), 1e-4)),
+        "A_log": jnp.log(uniform(44, (MH,), 1.0, 16.0)),
+        "D": jnp.ones((MH,), jnp.float32),
+        "norm": jnp.ones((inner,), cfg.dtype),
+        "w_out": normal(45, (inner, H), _resid_std(cfg)),
+    }
+
+
+def _mamba2_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    return {"w_in": (LAYERS, EMBED, HEADS), "w_out": (LAYERS, HEADS, EMBED),
+            "conv_w": (LAYERS, None, HEADS), "conv_b": (LAYERS, HEADS),
+            "norm": (LAYERS, HEADS),
+            **{a: (LAYERS, None) for a in ("dt_bias", "A_log", "D")}}
+
+
+def _mamba2_state(cfg: TransformerConfig):
+    H, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                  cfg.mamba_n_groups, cfg.mamba_state_size)
+    return (G, N, H // G * P), cfg.mamba_conv_taps, H * P + 2 * G * N
+
+
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    """What a mixer IS: every function that must tell one mixer from
+    another asks its record in ``MIXERS`` (docs/models.md)."""
+    name: str           # the key of its parameter subtree in a layer
+    init: Callable      # (cfg, normal, uniform) -> that subtree, ONE layer's
+    axes: Callable      # (cfg) -> the subtree's logical axes
+    apply: Callable     # (cfg, h, params, step) -> (out, new cache)
+    state: Optional[Callable] = None    # None: the mixer keeps pages; else
+    #   (cfg) -> (a slot's state shape, the convolution's taps, its width)
+    rows_count: Optional[str] = None    # the span count of states advanced
+
+
+# keyed by the mixer's name in ``LAYER_KINDS``; nothing reads it at import
+MIXERS: Dict[str, Mixer] = {
+    "attn": Mixer("attn", _attn_init, _attn_axes, _softmax_mixer),
+    "kda": Mixer("kda", _kda_init, _kda_axes, _kda_mixer, _kda_state,
+                 "recurrent_rows"),
+    "mamba2": Mixer("mamba2", _mamba2_init, _mamba2_axes, _mamba2_mixer,
+                    _mamba2_state, "ssm_rows"),
+}
+
+
 def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
-                mixer: str, has_ffn: bool, mask, positions, cache,
-                static_prefill, key_positions, window, block_table,
-                paged_write_mask, paged_layer, state_slots, paged_run=None):
+                mixer: str, has_ffn: bool, step: Step):
     """The first half of ``_layer_forward`` for a layer that has a mixer:
     norm, mixer and its add -> ``(x, the FFN's normed input or None where no
     FFN follows, the mixer's output, new cache)``. ``x`` comes back with the
@@ -1657,29 +1756,17 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     else:
         h = _norm(x, layer["ln1"]["scale"], layer["ln1"].get("bias"),
                   cfg.norm, cfg.norm_eps)
-    if cfg.act_quant_bits and cache is None:
+    if cfg.act_quant_bits and step.cache is None:
         # activation QAT (reference QuantAct): quantize the attention input
         from ..compression.compress import fake_quant_activation
 
         h = fake_quant_activation(h, cfg.act_quant_bits)
-    if mixer == "kda":
-        attn_out, new_cache = _kda_mixer(
-            cfg, h, layer["kda"], cache, paged_layer, state_slots,
-            positions, paged_write_mask)
-    elif mixer == "mamba2":
-        attn_out, new_cache = _mamba2_mixer(
-            cfg, h, layer["mamba2"], cache, paged_layer, state_slots,
-            positions, paged_write_mask)
-    else:
-        attn_out, new_cache = _softmax_mixer(
-            cfg, h, layer, mask, positions, cache, static_prefill,
-            key_positions, window, block_table, paged_write_mask,
-            paged_layer, paged_run)
-    if cache is None:
-        attn_out = _dropout(attn_out, cfg, salt=31)
-    if cache is None:
+    attn_out, new_cache = MIXERS[mixer].apply(
+        cfg, h, layer[MIXERS[mixer].name], step)
+    if step.cache is None:
         from ..parallel.sequence import constrain, hidden_spec, sequence_parallel_enabled
 
+        attn_out = _dropout(attn_out, cfg, salt=31)
         if sequence_parallel_enabled():
             attn_out = constrain(attn_out, hidden_spec())
     if not has_ffn:
@@ -1702,61 +1789,16 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
 
 
 def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
-                   mask: Optional[jax.Array],
-                   positions: jax.Array,
-                   cache: Optional[Dict[str, jax.Array]] = None,
-                   static_prefill: bool = False,
-                   key_positions: Optional[jax.Array] = None,
-                   window: Optional[jax.Array] = None,
-                   block_table: Optional[jax.Array] = None,
-                   paged_write_mask: Optional[jax.Array] = None,
-                   paged_layer: Optional[jax.Array] = None,
-                   moe_counts: bool = False,
-                   expert_banks: Optional[Dict[str, jax.Array]] = None,
-                   layer_index: Optional[jax.Array] = None,
-                   kind: str = "attn",
-                   state_slots: Optional[jax.Array] = None,
-                   paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
-                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+                   step: Step, kind: str = "attn"):
     """One decoder block: ``x + mixer(norm(x))``, then the FFN. ``kind``
-    names the mixer (``TransformerConfig.layer_pattern``); what follows is
-    the "attn" mixer's cache, and ``_kda_mixer`` says what a "kda" layer
-    keeps instead (``paged_layer`` then counts the layers of ITS kind, and
-    ``state_slots`` (B,) names each row's slot in the state pools).
-    ``layer`` holds this layer's (unstacked) params.
-    ``cache`` (decode): dict with k/v of shape (B, T_max, K, D) and scalar
-    ``index`` — returns the updated cache. ``window``: this layer's
-    sliding-window width (traced scalar, <=0 = global) — present only for
-    attention_layers models (GPT-Neo), which take the windowed jnp
-    attention path throughout.
+    (``LAYER_KINDS``) names the mixer and says whether the FFN follows;
+    ``layer`` holds this layer's (unstacked) params and ``step`` the
+    call's other operands.
 
-    ``block_table`` switches the cache to PAGED mode (serving layer):
-    ``cache`` is then the WHOLE arena ``{"k","v": (L, NUM_BLOCKS, BLOCK,
-    K*D)}``, ``paged_layer`` (int32 scalar, the layer scan's index) says
-    which layer's pool this block writes and reads inside it, and the arena
-    comes back as the new cache; ``block_table`` (B, MAX_BLOCKS) maps each
-    row's logical blocks to physical ids. A pool is never sliced out of the
-    arena: a custom call's operand is a buffer of its own, so a slice is a
-    pool-sized copy in and another out (ops/paged_decode_attention.py).
-    ``positions`` must then be the (B, S) absolute write
-    positions; ``paged_write_mask`` (B, S) routes masked-off tokens (prompt
-    chunk padding) to the scratch block 0 instead of the row's blocks;
-    ``paged_run`` says that the two are one run a row (``forward``).
-    The read is ``ops.paged_decode_attention.paged_attention``, which picks
-    kernel or reference by platform; S > 1 queries of a row sit at
-    ``positions[b, 0] + arange(S)`` (the chunk, verify and score programs).
-    It has no window, custom-scale or custom-impl operand: ``forward``
-    refuses such a model.
-
-    ``expert_banks`` (inference, an MoE model): the model's WHOLE expert
-    stacks ``(L, E, ...)`` in place of ``layer["mlp"]``, with ``layer_index``
-    saying which layer this is - ``forward`` keeps them out of the layer
-    scan's slicing, because the grouped-matmul kernel can read a touched
-    expert where it lies in the stack but not out of a slice that XLA would
-    first have to copy (``ops/moe_grouped_matmul.py``).
-
-    Returns ``(x, new_cache, aux)``; with ``moe_counts`` (an MoE model) a
-    fourth value, this layer's routing counts (``parallel/moe.moe_mlp``)."""
+    Returns ``(x, new_cache, aux)``; with ``step.moe_counts`` (an MoE model)
+    a fourth value, this layer's routing counts (``parallel/moe.moe_mlp``;
+    zeros for a layer that has no FFN)."""
+    cache = step.cache
     mixer, has_ffn = LAYER_KINDS[kind]
     post_ln = cfg.norm_position == "post"
     if not (mixer and has_ffn) and (post_ln or cfg.parallel_residual):
@@ -1765,24 +1807,20 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             "it has no post-norm or parallel-residual form")
     new_cache = cache
     if mixer is not None:
-        x, h, attn_out, new_cache = _mixer_half(
-            cfg, x, layer, mixer, has_ffn, mask, positions, cache,
-            static_prefill, key_positions, window, block_table,
-            paged_write_mask, paged_layer, state_slots, paged_run)
+        x, h, attn_out, new_cache = _mixer_half(cfg, x, layer, mixer,
+                                                has_ffn, step)
     else:
         attn_out = None
         h = _norm(x, layer["ln2"]["scale"], layer["ln2"].get("bias"),
                   cfg.norm, cfg.norm_eps)
     if not has_ffn:
-        aux = jnp.float32(0.0)
-        if moe_counts:
-            return x, new_cache, aux, jnp.zeros((3,), jnp.int32)
-        return x, new_cache, aux
+        return (x, new_cache, jnp.float32(0.0), *(
+            [jnp.zeros((3,), jnp.int32)] if step.moe_counts else []))
     if cfg.act_quant_bits and cache is None:
         from ..compression.compress import fake_quant_activation
 
         h = fake_quant_activation(h, cfg.act_quant_bits)   # MLP input
-    aux = jnp.float32(0.0)
+    aux, counts = jnp.float32(0.0), []
     if cfg.moe_num_experts > 0:
         from ..parallel.moe import moe_mlp
 
@@ -1792,17 +1830,18 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         infer = cache is not None or cfg.moe_dropless_only
         rts_rng = (_activation_derived_key(h, 0)
                    if (cfg.moe_use_rts and not infer) else None)
+        banks = (None if step.expert_banks is None
+                 else step.expert_banks[kind])
         mlp_out, aux, *counts = moe_mlp(
-            h, layer["router"],
-            layer["mlp"] if expert_banks is None else expert_banks,
+            h, layer["router"], layer["mlp"] if banks is None else banks,
             cfg.activation,
-            expert_layer=None if expert_banks is None else layer_index,
+            expert_layer=None if banks is None else step.layer_index,
             top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
             min_capacity=cfg.moe_min_capacity,
             drop_tokens=cfg.moe_drop_tokens, use_rts=cfg.moe_use_rts,
             rng=rts_rng, dispatch_impl=cfg.moe_dispatch,
             norm_topk_prob=cfg.moe_norm_topk_prob, infer=infer,
-            row_mask=paged_write_mask, with_counts=moe_counts,
+            row_mask=step.write_mask, with_counts=step.moe_counts,
             score_func=cfg.moe_score_func,
             choice_bias=layer.get("router_bias"),
             latent=layer.get("latent"), routed_scale=cfg.moe_routed_scale)
@@ -1849,9 +1888,7 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                   layer["ln2"].get("bias"), cfg.norm, cfg.norm_eps)
     else:
         x = x + mlp_out
-    if moe_counts:
-        return x, new_cache, aux, counts[0]
-    return x, new_cache, aux
+    return (x, new_cache, aux, *counts)
 
 
 def forward(params: Dict[str, Any], input_ids: jax.Array,
@@ -1882,10 +1919,9 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     ``paged_write_mask`` (B, S) routes padding writes to the scratch block.
     ``paged_run`` ``(start, n_valid)``, each () or (B,): the caller's word
     that row b's ``positions`` are the run ``start + arange(S)`` and its
-    mask ``arange(S) < n_valid`` (a prompt or scoring chunk). A run of a
+    mask ``arange(S) < n_valid`` (a prompt or scoring chunk): a run of a
     page or more is then written a whole page at a time, to the same bytes
-    (``_write_pages``); without it, and for fewer tokens than a page, the
-    write is a row a token.
+    (``_attend_paged``).
     The paged read has no window, custom-scale or custom-impl operand: a
     model with ``attention_layers``, ``attention_scale`` or
     ``attention_impl`` is refused. ``moe_counts`` (paged mode, an MoE
@@ -1945,21 +1981,17 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         # window, 'global' layers 0 (= unlimited); the pattern cycles over
         # layers like HF's attention_types expansion
         win_table = window_table(cfg)
-        from ..parallel.ring import ring_attention_enabled
-
-        if cache is None and ring_attention_enabled():
-            raise NotImplementedError(
-                "attention_layers (sliding-window) models + ring attention "
-                "are not supported — use sequence_parallel_impl='ulysses'")
-    if cfg.attention_scale is not None and cache is None:
+    if cache is None and (use_win or cfg.attention_scale is not None):
         from ..parallel.ring import ring_attention_enabled
 
         if ring_attention_enabled():
-            # ring_attention hardcodes 1/sqrt(head_dim); a custom scale
-            # (GPT-Neo uses 1.0) would be silently dropped
+            # ring_attention has no window operand and hardcodes
+            # 1/sqrt(head_dim); a custom scale (GPT-Neo uses 1.0) would be
+            # silently dropped
             raise NotImplementedError(
-                "custom attention_scale models + ring attention are not "
-                "supported — use sequence_parallel_impl='ulysses'")
+                "attention_layers (sliding-window) and custom "
+                "attention_scale models + ring attention are not supported "
+                "— use sequence_parallel_impl='ulysses'")
     if use_ltd:
         # default mirrors the engine (engine.py random-LTD init): all but the
         # first and last layer; degenerate depths keep at least one layer
@@ -1993,7 +2025,7 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     stacks = layer_stacks(params["layers"], cfg)
     # at inference an MoE model's expert stacks stay whole, outside the
     # layer scan's slicing, and go down with the layer's index (see
-    # _layer_forward's ``expert_banks``)
+    # ``Step.expert_banks``)
     banks = None
     if cache is not None and cfg.moe_num_experts > 0:
         banks = {kind: tree.get("mlp") for kind, tree in stacks.items()}
@@ -2004,6 +2036,11 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         for kind, tree in stacks.items()}
     layers = periods if several else periods[pattern[0]]
     with_idx = use_pld or use_win or banks is not None or several
+    step = Step(mask=attention_mask, positions=positions, cache=cache,
+                block_table=block_table, write_mask=paged_write_mask,
+                state_slots=state_slots, paged_run=paged_run,
+                static_prefill=static_prefill, key_positions=key_positions,
+                moe_counts=moe_counts, expert_banks=banks)
 
     def run_period(layers, pidx, one_layer, h, *acc):
         """``one_layer(h, kind, layer, layer index among its kind, *acc)
@@ -2021,15 +2058,10 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
 
     def block(carry, layer_and_cache):
         h, aux_acc = carry
-        ltd_flag = None
-        if use_ltd:
-            (layer, layer_cache), idx, ltd_flag = layer_and_cache
-        elif with_idx:
-            (layer, layer_cache), idx = layer_and_cache
-        else:
-            layer, layer_cache = layer_and_cache
-            idx = None
-        window = (win_table[idx.astype(jnp.int32)] if use_win else None)
+        layer, layer_cache, idx, ltd_flag = layer_and_cache
+        at = dataclasses.replace(
+            step, cache=layer_cache,
+            window=win_table[idx.astype(jnp.int32)] if use_win else None)
         if use_ltd:
             # gather a random sorted token subset, run the layer on it,
             # scatter back — dropped tokens keep their input activations
@@ -2048,22 +2080,20 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                 part = gather_tokens(hh, kept)
                 msk = (None if attention_mask is None
                        else jnp.take(attention_mask, kept, axis=1))
-                out, _, aux = _layer_forward(cfg, part, layer, msk,
-                                             jnp.take(positions, kept), None,
-                                             window=window)
+                out, _, aux = _layer_forward(
+                    cfg, part, layer, dataclasses.replace(
+                        at, mask=msk, positions=jnp.take(positions, kept)))
                 return scatter_tokens(hh, out, kept), aux
 
             def full_branch(hh):
-                out, _, aux = _layer_forward(cfg, hh, layer, attention_mask,
-                                             positions, None, window=window)
+                out, _, aux = _layer_forward(cfg, hh, layer, at)
                 return out, aux
 
             h_new, aux = lax.cond(ltd_flag > 0, ltd_branch, full_branch, h)
             new_cache = None
         elif several:
             def one_layer(h, kind, layer, kidx, aux_sum):
-                h, _, aux = _layer_forward(cfg, h, layer, attention_mask,
-                                           positions, None, kind=kind)
+                h, _, aux = _layer_forward(cfg, h, layer, at, kind=kind)
                 return h, aux_sum + aux
 
             h_new, aux = run_period(layer, None, one_layer, h,
@@ -2071,13 +2101,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             new_cache = None
         else:
             h_new, new_cache, aux = _layer_forward(
-                cfg, h, layer, attention_mask, positions, layer_cache,
-                static_prefill=static_prefill, key_positions=key_positions,
-                window=window,
-                expert_banks=None if banks is None else banks[pattern[0]],
-                layer_index=(None if banks is None
-                             else idx.astype(jnp.int32)),
-                kind=pattern[0])
+                cfg, h, layer, at if banks is None else dataclasses.replace(
+                    at, layer_index=idx.astype(jnp.int32)), kind=pattern[0])
         if use_pld:
             h_new, aux = pld_gate(cfg, h, h_new, aux, idx, pld_theta)
         return (h_new, aux_acc + aux), new_cache
@@ -2087,20 +2112,18 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         block_fn = jax.checkpoint(block, prevent_cse=False,
                                   policy=resolve_remat_policy(cfg))
 
-    if cache is None:
-        # one scan; xs packing varies with the active stochastic features
-        # (block unpacks in the same order; None rides the pytree untouched)
-        if use_ltd:
-            xs = ((layers, None), jnp.arange(L, dtype=jnp.float32),
-                  ltd_flags)
-        elif with_idx:
-            xs = ((layers, None), jnp.arange(L // P, dtype=jnp.float32))
-        else:
-            xs = (layers, None)
-        (x, aux_total), _ = lax.scan(block_fn, (x, jnp.float32(0.0)), xs,
-                                     unroll=cfg.scan_unroll)
-        new_cache = None
-    elif block_table is not None:
+    if block_table is None:
+        # one scan, with or without the dense cache; what an inactive
+        # stochastic feature would read is None (None rides the pytree
+        # untouched)
+        xs = (layers, cache,
+              (jnp.arange(L // P, dtype=jnp.float32)
+               if with_idx or use_ltd else None),
+              ltd_flags if use_ltd else None)
+        (x, aux_total), new_cache = lax.scan(
+            block_fn, (x, jnp.float32(0.0)), xs,
+            unroll=cfg.scan_unroll if cache is None else 1)
+    else:
         # PAGED: the layer scan's CARRY is the arena itself, and the body
         # hands it down whole with the layer index. _layer_forward scatters
         # the new rows into it at (idx, blk, off), or a run's whole pages at
@@ -2123,13 +2146,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
 
             def one_layer(h, kind, layer, kidx, aux_sum, arena, *counts_sum):
                 h, arena, aux, *counts = _layer_forward(
-                    cfg, h, layer, attention_mask, positions, arena,
-                    block_table=block_table,
-                    paged_write_mask=paged_write_mask, paged_layer=kidx,
-                    moe_counts=moe_counts,
-                    expert_banks=None if banks is None else banks[kind],
-                    layer_index=kidx, kind=kind, state_slots=state_slots,
-                    paged_run=paged_run)
+                    cfg, h, layer, dataclasses.replace(
+                        step, cache=arena, layer_index=kidx), kind=kind)
                 return (h, aux_sum + aux, arena,
                         *(a + c for a, c in zip(counts_sum, counts)))
 
@@ -2141,11 +2159,6 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             (x, jnp.float32(0.0), dict(cache),
              *([jnp.zeros((3,), jnp.int32)] if moe_counts else [])),
             (layers, jnp.arange(L // P, dtype=jnp.int32)))
-    else:
-        xs = ((layers, cache) if not with_idx else
-              ((layers, cache), jnp.arange(L, dtype=jnp.float32)))
-        (x, aux_total), new_cache = lax.scan(block_fn, (x, jnp.float32(0.0)),
-                                             xs)
 
     logits = head_logits(params, x, cfg)
     if moe_counts:

@@ -187,7 +187,7 @@ def _stage_helpers(cfg):
     """Shared per-stage building blocks for BOTH the eval fill-drain loss and
     the 1F1B grad executor — one definition so train grads and eval losses
     can never structurally diverge (embed_norm incident of round 2)."""
-    from ..models.transformer import (_layer_forward, _norm,
+    from ..models.transformer import (Step, _layer_forward, _norm,
                                       cross_entropy_loss,
                                       resolve_remat_policy)
 
@@ -210,7 +210,7 @@ def _stage_helpers(cfg):
 
     def stage_apply(stage_layers, x, mask, positions):
         def block(h, layer):
-            h, _, aux = _layer_forward(cfg, h, layer, mask, positions, None)
+            h, _, aux = _layer_forward(cfg, h, layer, Step(mask, positions))
             return h, aux
 
         block_fn = (jax.checkpoint(block, prevent_cse=False,

@@ -257,8 +257,8 @@ def measured_model_profile(model, batch_size: int, seq_len: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from ..models.transformer import (_layer_forward, _norm, eval_config,
-                                      head_logits, window_table)
+    from ..models.transformer import (Step, _layer_forward, _norm,
+                                      eval_config, head_logits, window_table)
 
     cfg = eval_config(model.config)
     # per-layer sliding windows (GPT-Neo attention_layers): each timed layer
@@ -289,8 +289,8 @@ def measured_model_profile(model, batch_size: int, seq_len: int,
 
     # --- one compiled layer program, timed per layer's weights ---
     def layer_fn(layer, h, window):
-        return _layer_forward(cfg, h, layer, None, positions,
-                              window=window)[0]
+        return _layer_forward(
+            cfg, h, layer, Step(positions=positions, window=window))[0]
 
     layer_jit = jax.jit(layer_fn)
 

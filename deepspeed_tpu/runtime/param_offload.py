@@ -479,8 +479,8 @@ class ParamOffloadExecutor:
 
     # -- compiled segments (shared across blocks) --------------------------
     def _build_step_fns(self, model) -> None:
-        from ..models.transformer import (_dropout, _layer_forward, _norm,
-                                          _qeinsum, cross_entropy_loss,
+        from ..models.transformer import (Step, _dropout, _layer_forward,
+                                          _norm, _qeinsum, cross_entropy_loss,
                                           eval_config, resolve_remat_policy)
 
         cfg = self.cfg
@@ -535,8 +535,8 @@ class ParamOffloadExecutor:
                     idx = (lo + i).astype(jnp.float32)
                     window = (win_table[(lo + i).astype(jnp.int32)]
                               if win_table is not None else None)
-                    h2, _, a = _layer_forward(c, h, layer, mask, positions,
-                                              None, window=window)
+                    h2, _, a = _layer_forward(c, h, layer, Step(
+                        mask=mask, positions=positions, window=window))
                     if c.pld_enabled and theta is not None:
                         h2, a = pld_gate(c, h, h2, a, idx, theta)
                     return (h2, aux + a), None

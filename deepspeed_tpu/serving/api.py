@@ -134,13 +134,13 @@ class ServingEngine:
         # it from zeros, on admission and on re-admission after a
         # preemption (recompute) alike. What cannot follow such state yet
         # is switched off or refused here and in ``_no_state_snapshot``
-        from ..models.transformer import ffn_layers, recurrent_layers
+        from ..models.transformer import (MIXERS, ffn_layers,
+                                          recurrent_layers)
 
         mixer, layers = recurrent_layers(cfg)
         self._recurrent_layers = len(layers)
         # the span count of the (row, layer) states a step advanced
-        self._recurrent_rows = {"mamba2": "ssm_rows"}.get(mixer,
-                                                          "recurrent_rows")
+        self._recurrent_rows = mixer and MIXERS[mixer].rows_count
         self.state_slots = (self.config.max_seqs + 1
                             if self._recurrent_layers else 0)
         # the prefix cache shares PAGES between sequences; a recurrent
