@@ -1354,6 +1354,15 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any]
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
+    if S == 1:
+        # a decode step: q's product (and bias) is whole as ROWS before
+        # anything splits it into heads, as k's and v's are (they go back to
+        # rows for the page write). Left to fold the reshape into the
+        # product, the chip's compiler makes the product heads-major and
+        # pays with the WEIGHT: sliced out of the layer stack and transposed
+        # every step, two passes over it before the product's one (PERF.md,
+        # PR 53). The heads are then a relayout of B rows, not of H x N*D
+        q = lax.optimization_barrier(q)
     if cfg.qk_norm:
         # over all heads at once (the published OlmoeAttention: q_norm and
         # k_norm are hidden-wide), before the heads are split and roped

@@ -525,6 +525,96 @@ def test_nemotron_serving_program_updates_the_states_where_they_lie(
 
 
 # ---------------------------------------------------------------------------
+# a decode step's attention projections read their weights where they lie
+# ---------------------------------------------------------------------------
+
+# preset: (how `_serving_program` builds its decode program; hidden, N*D and
+# K*D; the layers in the attention weights' stack; the weights a layer: q,
+# k, v, o and Solar's output gate)
+DECODE_PROJECTIONS = {
+    "opt-1.3b": ({}, (2048, 2048, 2048), LAYERS, 4),
+    "olmoe-1b-7b": ({"moe_counts": True}, (2048, 2048, 2048), LAYERS, 4),
+    "solar-open2-250b": (
+        dict(overrides=SOLAR, rows=SOLAR_ROWS, num_blocks=SOLAR_BLOCKS,
+             moe_counts=True), (4096, 8192, 1024), 1, 5),
+    "nemotron-3-super-120b-a12b": (
+        dict(overrides=NEMOTRON, rows=SOLAR_ROWS, num_blocks=SOLAR_BLOCKS,
+             moe_counts=True), (4096, 4096, 256), 1, 4),
+}
+
+
+def _projection_weights(text, widths, layers):
+    """(the instructions outside a fused computation that MAKE a whole
+    attention projection weight: a copy, a transpose, or a fusion with no
+    product in it, such as a slice out of the layer stack; the fusions
+    outside one that hold a product and take an attention weight's stack
+    itself as an operand, prefetched or not)."""
+    hidden, heads, kv = widths
+    pairs = {(hidden, heads), (heads, hidden), (hidden, kv), (kv, hidden)}
+    whole = {f"bf16[{lead}{a},{b}]" for a, b in pairs for lead in ("", "1,")}
+    stacks = {f"bf16[{layers},{a},{b}]" for a, b in pairs}
+    sources = {(kind, stack) for stack in stacks
+               for kind in ("parameter", "get-tuple-element")}
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    body, made_by, outside, current = {}, {}, [], None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = head.group(1)
+            continue
+        body.setdefault(current, []).append(line)
+        m = _RESULT.match(line)
+        if m is None:
+            continue
+        name, result, op = m.groups()
+        operands = re.findall(r"%([\w.\-]+)",
+                              line.split(f" {op}(", 1)[1].split(")")[0])
+        made_by[name] = (op, result.split("{")[0], operands)
+        if current not in fused:
+            outside.append((name, line))
+
+    def source(name):
+        """What an operand is, behind the bitcasts and the asynchronous
+        prefetch that leave its bytes as they are."""
+        op, result, operands = made_by[name]
+        while op in ("bitcast", "copy-done", "copy-start"):
+            op, result, operands = made_by[operands[0]]
+        return op, result
+
+    makers, readers = [], []
+    for name, line in outside:
+        op, result, operands = made_by[name]
+        product = op == "fusion" and any(
+            " convolution(" in ln for ln in
+            body[re.search(r"calls=%([\w.\-]+)", line).group(1)])
+        if result in whole and not product and op in ("copy", "transpose",
+                                                      "fusion"):
+            makers.append(line.strip()[:160])
+        if product and any(source(o) in sources for o in operands):
+            readers.append(name)
+    return makers, readers
+
+
+@pytest.mark.parametrize("preset", sorted(DECODE_PROJECTIONS))
+def test_a_decode_step_reads_its_attention_weights_where_they_lie(
+        v5e, monkeypatch, preset):
+    """No CPU test can see a weight copied: the numbers are the same. With
+    the reshape to heads next to q's product the chip's compiler made the
+    product heads-major and re-laid the WEIGHT for it every step (opt-1.3b:
+    a slice of `[1,2048,2048]` out of the stack and a transposing copy a
+    layer; Solar and Nemotron: a copy of the whole `[8192,4096]` /
+    `[4096,4096]` at the program's entry; PR 53). Every attention
+    projection of a decode step, q's as k's and v's, is ONE fusion that
+    holds the product and reads the layer stack itself."""
+    options, widths, layers, weights = DECODE_PROJECTIONS[preset]
+    text = _serving_program("decode", v5e, monkeypatch, preset=preset,
+                            **options).compile().as_text()
+    makers, readers = _projection_weights(text, widths, layers)
+    assert not makers, "\n".join(makers)
+    assert len(readers) == weights, readers
+
+
+# ---------------------------------------------------------------------------
 # the platform probe those programs are steered by: one name, one owner
 # ---------------------------------------------------------------------------
 
