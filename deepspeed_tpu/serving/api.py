@@ -21,8 +21,18 @@ woken, latency samples, counters, the request tracer). ``step()`` flushes
 before it returns; the driver thread (``start()``) keeps an iteration's
 decode tokens and flushes them right after it has enqueued the NEXT program
 (``_run_program``), so the pushes and the callers they wake run while the
-device works. No program is ever in flight across an iteration boundary:
-only undelivered host tokens are.
+device works.
+
+The driver thread also enqueues a decode step AHEAD: with the step before
+it still running and that step's tokens not yet fetched, whenever nobody
+waits for the device (``_may_run_ahead``, ``_rows_ahead``: five rules about
+the engine's state, no setting). The step takes its tokens on the device,
+from the array its predecessor returned; fetch, apply, delivery, prepare
+and enqueue of the host's round then run in its shadow. So ONE decode step
+may be in flight across an iteration boundary of the driver thread
+(``_flight``); whatever needs a settled engine brings it home first
+(``_bring_home``). Under ``step()`` no program is ever in flight across an
+iteration boundary.
 
 Telemetry flows through the PR-2 observability substrate: ``serving/*``
 metrics in the MetricsRegistry (ttft_ms, tpot_ms, queue_depth,
@@ -36,6 +46,7 @@ watchdog its attribution site), and tpuaudit entries of the same names.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -81,6 +92,22 @@ def _host_operands(call_args) -> Dict[str, int]:
             n += 1
             nbytes += np.asarray(leaf).nbytes
     return {"host_operands": n, "host_operand_bytes": nbytes}
+
+
+@dataclasses.dataclass(eq=False)
+class _Enqueued:
+    """A program behind its enqueue and before its fetch: the engine's clock
+    before the call and behind it, and the call's seconds as far as they
+    count towards a hold. A decode step also keeps its rows, each request
+    with the row it held, and, where it was enqueued AHEAD, when its
+    predecessor's tokens came to the host: from there its interval counts."""
+    name: str
+    tok: Any
+    t0: float
+    t_call: float
+    call_s: float
+    rows: List[tuple] = ()
+    since: Optional[float] = None
 
 
 def _percentile(samples: List[float], q: float) -> float:
@@ -134,6 +161,9 @@ class ServingEngine:
         # it from zeros, on admission and on re-admission after a
         # preemption (recompute) alike. What cannot follow such state yet
         # is switched off or refused here and in ``_no_state_snapshot``
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
         from ..models.transformer import (MIXERS, ffn_layers,
                                           recurrent_layers)
 
@@ -176,6 +206,14 @@ class ServingEngine:
         self._prefill = paged_kv.build_prefill_program(
             cfg, self.config.prefill_chunk, moe_counts=moe)
         self._decode = paged_kv.build_decode_program(cfg, moe_counts=moe)
+        # what the last decode program returned, on the device still (its
+        # first ``max_seqs`` entries the tokens): the next one's last
+        # operand, from which a step enqueued AHEAD takes its tokens. Placed
+        # as the program places its result, so that the first call's
+        # signature is every call's
+        self._last_tokens = jax.device_put(
+            np.zeros((self.config.max_seqs + (3 if moe else 0),), np.int32),
+            NamedSharding(engine.mesh, PartitionSpec()))
         self._cow = paged_kv.build_cow_program()
         # teacher-forced scoring over the same arena (the RLHF second
         # serving pass — docs/rlhf.md); jit is lazy, so an engine that
@@ -237,8 +275,6 @@ class ServingEngine:
         self._forks = 0
         self._published_spec = (0, 0, 0, 0)   # proposed/accepted/disp/disabled
         self._published_forks = 0
-        import jax
-
         self._base_rng = jax.random.PRNGKey(self.config.seed)
         self._rid = 0
         self._iterations = 0
@@ -266,6 +302,12 @@ class ServingEngine:
         # True inside an iteration of the driver thread: what the next
         # program's operands do not need waits for that program's enqueue
         self._deferring = False
+        # the decode step that is enqueued and not fetched, where the
+        # driver thread left one at an iteration's end (``_step_decode``),
+        # and the scheduler's count of rows given back when
+        # ``_may_run_ahead`` last asked
+        self._flight: Optional[_Enqueued] = None
+        self._rows_released_seen = 0
         # the open iteration's span until its account (gauges, the span's
         # counts) is drawn up: behind its first enqueue when deferring, else
         # at its end
@@ -410,7 +452,9 @@ class ServingEngine:
         #   siblings and parent-cascaded siblings included, so the
         #   requests_{submitted,completed,cancelled} ledger balances
         with self._lock:
-            self._flush()   # what is applied streams before the cancel ends it
+            # what is enqueued and what is applied streams before the cancel
+            # ends it
+            self._bring_home()
             req = handle._req
             # a sibling cancelled before its fork point never reached the
             # scheduler — cancel it directly
@@ -674,7 +718,7 @@ class ServingEngine:
         terminal for this engine (row/blocks freed, handle dropped)
         without touching the completion ledger."""
         with self._lock:
-            self._flush()
+            self._bring_home()
             self.sched.release_handoff(req)
             handle = self._handles.pop(req.rid, None)
             if handle is not None:
@@ -698,7 +742,7 @@ class ServingEngine:
         cannot be continued coherently. Returns the number of prefix-cache
         entries dropped."""
         with self._lock:
-            self._flush()
+            self._bring_home()
             if self.sched.in_flight() or self._pending_fork_count():
                 raise RuntimeError(
                     "weight flip with requests in flight "
@@ -757,7 +801,7 @@ class ServingEngine:
                 f"serving.max_model_len={self.config.max_model_len}")
         C = self.config.prefill_chunk
         with self._lock:
-            self._flush()
+            self._bring_home()
             need = paged_kv.blocks_for_tokens(T, self.config.block_size)
             ids = self.sched._alloc_evicting_cache(need)
             if ids is None:
@@ -818,7 +862,9 @@ class ServingEngine:
         """``step()``; with ``defer`` (the driver thread) the iteration's
         decode tokens are applied and kept, and delivered behind the next
         program this engine enqueues (``_run_program``), or by whoever
-        needs a settled engine first (``_flush``)."""
+        needs a settled engine first (``_bring_home``); and a decode step
+        may stay in flight at the iteration's end, for the next iteration
+        to enqueue its successor ahead of its fetch (``_step_decode``)."""
         obs = get_session()
         with obs.span("serving/iteration") as span:
             lock_wait = obs.span("serving/iteration/lock_wait").begin()
@@ -873,7 +919,26 @@ class ServingEngine:
 
     def _step_locked(self, obs) -> bool:
         """The iteration's work, under the engine lock: each stretch of it
-        lies in one span of ``docs/serving.md``'s table."""
+        lies in one span of ``docs/serving.md``'s table. With a decode step
+        in flight (the driver thread left it there: ``_step_decode``) the
+        iteration is that step's: the next one enqueued AHEAD of its fetch
+        where the rules allow it, else the fetch alone, and today's
+        iteration, admission first, is the next. ``step()`` brings a step
+        it finds in flight home and goes on."""
+        flight = self._flight
+        if flight is not None:
+            if not self._deferring:
+                self._bring_home()
+            elif rows := self._rows_ahead(flight):
+                return self._step_decode(rows)
+            else:
+                self._land(obs, flight)
+                # its tokens wait, as those of every step that was fetched
+                # with nothing behind it do, for the next enqueue (the
+                # chunk's, if a caller is at the door): nothing is
+                # delivered into the gap
+                self._account(obs)
+                return True
         with obs.span("serving/admit") as span:
             # before admit: an already-expired queued request must
             # never take a decode row first
@@ -900,6 +965,10 @@ class ServingEngine:
         span's counts. ``deferred``: a program of this iteration is
         enqueued, so all of it runs while the device works."""
         self._flush(deferred)
+        self._account(obs)
+
+    def _account(self, obs) -> None:
+        """The open iteration's account, once."""
         span = self._unaccounted
         if span is None:
             return
@@ -1034,26 +1103,34 @@ class ServingEngine:
 
     def _run_program(self, obs, name: str, program, *args, trace=None):
         """Dispatch one jitted program over the arena (its first output the
-        sampled tokens, its last the arena) and bring the tokens to the host.
+        sampled tokens, its last the arena) and bring the tokens to the host:
+        ``_enqueue``, then ``_fetch``. Between the two the device works and
+        the host has nothing to wait for: an iteration of the driver thread
+        delivers there what the last one applied and kept (``_settle``).
+        THREE readings of the engine's clock: the first and the last feed
+        the accountants and the request tracer (returns (tokens, t0, t1)),
+        and with the one after the call they tell a program that held the
+        engine (``_note_hold``), whether or not anything records. The spans
+        stamp themselves, on the profiler's clock, and only while they
+        record."""
+        sent = self._enqueue(obs, name, program, *args, trace=trace)
+        if self._deferring:
+            self._settle(obs, deferred=True)
+        tok, t1 = self._fetch(obs, sent)
+        return tok, sent.t0, t1
+
+    def _enqueue(self, obs, name: str, program, *args,
+                 trace=None) -> "_Enqueued":
+        """``<name>/dispatch``: the jitted call, which returns at enqueue,
+        with the mesh and the request tracer's compile attribution
+        (``trace``: whose dispatch this is) entered and left around it, so
+        that nothing lies unnamed between ``<name>/prepare`` and the call.
         ``args`` is what the program takes behind the params and the arena:
         ONE numpy array, its step's operands packed (``paged_kv.pack_*``),
-        which is the one transfer the call makes, and the sampling key, on
-        the device already.
-        ``<name>/dispatch`` is the call, which returns at enqueue, with the
-        mesh and the request tracer's compile attribution (``trace``: whose
-        dispatch this is) entered and left around it, so that nothing lies
-        unnamed between ``<name>/prepare`` and the call; ``<name>/fetch``
-        is the wait for the tokens (device time + D2H: the iteration's host
-        sync). Between the two the device works and the host has nothing to
-        wait for: an iteration of the driver thread delivers there what the
-        last one applied and kept (``_settle``). THREE readings of the
-        engine's clock: the first and the last feed the accountants and the
-        request tracer (returns (tokens, t0, t1)), and with the one after
-        the call they tell a program that held the engine
-        (``_note_hold``), whether or not anything records; a call that
-        compiled (the jitted ``program``'s call cache grew) is excused.
-        The spans stamp themselves, on the profiler's clock, and only while
-        they record."""
+        which is the one transfer the call makes, and what is on the device
+        already (the sampling key; the decode program's last tokens). A call
+        that compiled (the jitted ``program``'s call cache grew) is set-up,
+        not a hold, however long."""
         t0 = self.clock()
         with obs.span(name + "/dispatch", category="phase") as span:
             if span.recording:
@@ -1064,22 +1141,27 @@ class ServingEngine:
                     tok, *_, self._arena = program(
                         self.engine.params, self._arena, *args)
         t_call = self.clock()
-        if self._deferring:
-            self._settle(obs, deferred=True)
-        with obs.span(name + "/fetch", category="phase"):
-            tok = np.asarray(tok)
-        t1 = self.clock()
-        call_s, fetch_s = t_call - t0, t1 - t_call
+        call_s = t_call - t0
         compiles = program._cache_size()
         if compiles != self._compiles.get(name):
             # the call traced and compiled (or read the compile cache): a
             # program's first, and the chunk program's second, whose arena
-            # is no longer the fresh one. Set-up, not a hold
+            # is no longer the fresh one
             self._compiles[name] = compiles
             call_s = 0.0
-        if call_s >= HOLD_SECONDS or fetch_s >= HOLD_SECONDS:
-            self._note_hold(obs, name, call_s, fetch_s)
-        return tok, t0, t1
+        return _Enqueued(name, tok, t0, t_call, call_s)
+
+    def _fetch(self, obs, sent: "_Enqueued"):
+        """``<name>/fetch``: the wait for an enqueued program's tokens
+        (device time + D2H: the host's sync). Returns (tokens, the engine's
+        clock behind them)."""
+        with obs.span(sent.name + "/fetch", category="phase"):
+            tok = np.asarray(sent.tok)
+        t1 = self.clock()
+        fetch_s = t1 - sent.t_call
+        if sent.call_s >= HOLD_SECONDS or fetch_s >= HOLD_SECONDS:
+            self._note_hold(obs, sent.name, sent.call_s, fetch_s)
+        return tok, t1
 
     def _note_hold(self, obs, name: str, call_s: float,
                    fetch_s: float) -> None:
@@ -1246,7 +1328,8 @@ class ServingEngine:
             raise ValueError(f"fork(n={n}): seeds has {len(seeds)} "
                              "entries — need one per sibling")
         with self._lock:
-            self._flush()   # the parent's handle holds what siblings inherit
+            self._bring_home()   # the parent's handle holds what siblings
+            #   inherit, and its state is theirs
             req = handle._req
             if req.state != DECODE:
                 raise ValueError(
@@ -1316,12 +1399,16 @@ class ServingEngine:
         # a later row's COW may have preempted an earlier accepted row
         return [r for r in ready if r.state == DECODE]
 
-    def _decode_operands(self, ready: List[Request]):
+    def _decode_operands(self, ready: List[Request], ahead: bool = False):
         """The decode program's operands, one row per decode row, packed
         into the ONE host array it takes (``paged_kv.pack_decode_rows``): a
         new array each step, since the dispatched call may still read the
-        last one."""
+        last one. ``ahead``: reckoned from the state the step in flight
+        will leave, one token on in length and in the sampling stream, and
+        the token itself -1: the program takes it from what that step
+        returned, on the device."""
         R = self.config.max_seqs
+        on = int(ahead)
         bt = np.zeros((R, self.blocks_per_seq), np.int32)
         lengths = np.zeros((R,), np.int32)
         tokens = np.zeros((R,), np.int32)
@@ -1333,60 +1420,157 @@ class ServingEngine:
         for r in ready:
             row = r.row
             bt[row, :len(r.blocks)] = r.blocks
-            lengths[row] = r.length
-            tokens[row] = r.pending_token
+            lengths[row] = r.length + on
+            tokens[row] = -1 if ahead else r.pending_token
             temps[row] = r.sampling.temperature
             topks[row] = r.sampling.top_k
             topps[row] = r.sampling.top_p
             seeds[row] = r.seed
-            steps[row] = len(r.generated)   # output-token index: the
+            steps[row] = len(r.generated) + on   # output-token index: the
             #   sampling stream is (engine seed, request seed, index) —
             #   schedule-independent and preemption-stable
         return paged_kv.pack_decode_rows(bt, lengths, tokens, temps, topks,
                                          topps, seeds, steps)
 
-    def _step_decode(self) -> bool:
-        dec = self.sched.decode_requests()
+    def _may_run_ahead(self, flight: "_Enqueued") -> bool:
+        """Whether the decode step behind ``flight``, which is enqueued and
+        not fetched, may be enqueued before that fetch, as far as the
+        engine's state says (its rows and pages: ``_rows_ahead``). Nothing
+        waits for the device: the queue is empty, every running request is
+        a row of ``flight`` (none in prefill, none that found no page), no
+        sibling waits for its fork, no drafter proposes, no deadline has
+        passed. And no row has come free since this was last asked: a freed
+        row is about to be taken, and the chunk that takes it must find the
+        device as free as it would without a step ahead."""
+        freed = self.sched.rows_released != self._rows_released_seen
+        self._rows_released_seen = self.sched.rows_released
+        return not (freed or self.sched.queued or self._pending_forks
+                    or len(self.sched.running) != len(flight.rows)
+                    or (self._drafter is not None
+                        and not self.spec_suspended)
+                    or self.sched.deadline_due(self.clock))
+
+    def _rows_ahead(self, flight: "_Enqueued") -> Optional[List[Request]]:
+        """The rows of the step to enqueue AHEAD of ``flight``'s fetch, or
+        None where it must wait for that fetch: ``flight``'s rows less
+        those that end there by their ``max_new_tokens``, each with the
+        page its next position needs taken from the free list (no eviction,
+        no preemption and no copy-on-write on behalf of a step ahead)."""
+        if not self._may_run_ahead(flight):
+            return None
+        rows = [r for r, _ in flight.rows
+                if len(r.generated) + 1 < r.max_new_tokens]
+        bs = self.config.block_size
+        need = sum(max(paged_kv.blocks_for_tokens(r.length + 2, bs)
+                       - len(r.blocks), 0) for r in rows)
+        if need > self.alloc.blocks_free or any(
+                self.sched.cow_block_indices(r, r.length + 1, r.length + 2)
+                for r in rows):
+            return None
+        for r in rows:
+            self.sched.try_extend_blocks(r, r.length + 2)
+        return rows
+
+    def _step_decode(self, ahead: Optional[List[Request]] = None) -> bool:
+        """One decode step enqueued, and one brought to the host. ``ahead``
+        (the driver thread, ``_rows_ahead``): the rows of a step that is
+        enqueued with its predecessor in flight; the predecessor is then
+        fetched and applied in this step's shadow, and its tokens are
+        delivered at once. Else the step is enqueued behind the state the
+        host holds, and fetched here too, unless the driver thread may run
+        the next one ahead of it (``_may_run_ahead``): then it stays in
+        flight for the next iteration."""
+        dec = ahead or self.sched.decode_requests()
         if not dec:
             return False
         obs = get_session()
         with obs.span("serving/decode",
                       max_rows=self.config.max_seqs) as span:
             with obs.span("serving/decode/prepare", category="phase"):
-                ready = self._ready_decode_rows(dec)
-                packed = self._decode_operands(ready) if ready else None
-                span.annotate(rows=len(ready),
+                ready = ahead or self._ready_decode_rows(dec)
+                packed = (self._decode_operands(ready, bool(ahead))
+                          if ready else None)
+                span.annotate(rows=len(ready), ahead=int(bool(ahead)),
                               sampled_rows=self._sampled_rows(ready))
             if not ready:
                 return False
-            rt = obs.reqtrace
-            acct = self._serve_acct
             first_trace = (next((r.trace for r in ready
                                  if r.trace is not None), None)
-                           if rt is not None else None)
-            nxt, t0, t1 = self._run_program(
-                obs, "serving/decode", self._decode, packed,
-                self._base_rng, trace=first_trace)
-            with obs.span("serving/decode/apply", category="phase"):
-                nxt = self._program_counts(span, nxt, self.config.max_seqs,
-                                           real_rows=len(ready))
-                if acct is not None:
-                    acct.note_phase("decode", t1 - t0)
-                if rt is not None:
-                    for r in ready:
-                        if r.trace is not None:
-                            rt.note_decode(r.trace, t0, t1,
-                                           batch=len(ready),
-                                           replica=self.trace_tag)
-                for r in ready:
-                    r.length += 1
-                    self.sched.note_service(r, 1)
-                    self._apply(r, int(nxt[r.row]))
-                if not self._deferring:
-                    self._flush()
-                if acct is not None:
-                    acct.note_phase("sample_host", self.clock() - t1)
+                           if obs.reqtrace is not None else None)
+            before = self._flight
+            sent = self._flight = self._enqueue(
+                obs, "serving/decode", self._decode, packed, self._base_rng,
+                self._last_tokens, trace=first_trace)
+            sent.rows = [(r, r.row) for r in ready]
+            self._last_tokens = sent.tok
+            if self._deferring:
+                self._settle(obs, deferred=True)
+            if before is not None:
+                if obs.enabled:
+                    obs.registry.counter(
+                        "serving/steps_enqueued_ahead",
+                        help="decode steps enqueued with their "
+                             "predecessor's tokens not yet on the host "
+                             "(the driver thread's form)").inc()
+                self._land(obs, before, span)
+                self._flush(deferred=True)   # at once: the device is busy
+            elif not (self._deferring and self._may_run_ahead(sent)):
+                self._land(obs, sent, span)
         return True
+
+    def _land(self, obs, sent: "_Enqueued", span=None) -> None:
+        """A decode step's tokens fetched and applied
+        (``serving/decode/fetch`` and ``.../apply``, under ``span``: the
+        ``serving/decode`` span that is open, else one of its own). A row
+        whose request ended behind the step's enqueue (by its
+        ``eos_token_id`` at the step before, with this one ahead) is
+        dropped: its token goes nowhere, and what it wrote lies in pages
+        and a state slot that whoever takes them next writes over, in a
+        program enqueued behind this one. An ahead step's interval, for the
+        accountant and the request tracer, runs from its predecessor's
+        fetch to its own: no second is counted twice."""
+        if span is None:
+            with obs.span("serving/decode",
+                          max_rows=self.config.max_seqs) as span:
+                return self._land(obs, sent, span)
+        if self._flight is sent:
+            self._flight = None
+        nxt, t1 = self._fetch(obs, sent)
+        if self._flight is not None:
+            self._flight.since = t1
+        t0 = sent.t0 if sent.since is None else sent.since
+        rt = obs.reqtrace
+        acct = self._serve_acct
+        with obs.span("serving/decode/apply", category="phase"):
+            live = [(r, row) for r, row in sent.rows
+                    if r.state == DECODE and r.row == row]
+            nxt = self._program_counts(span, nxt, self.config.max_seqs,
+                                       real_rows=len(sent.rows))
+            if len(live) < len(sent.rows):
+                span.annotate(dropped_rows=len(sent.rows) - len(live))
+            if acct is not None:
+                acct.note_phase("decode", t1 - t0)
+            if rt is not None:
+                for r, _ in live:
+                    if r.trace is not None:
+                        rt.note_decode(r.trace, t0, t1, batch=len(live),
+                                       replica=self.trace_tag)
+            for r, row in live:
+                r.length += 1
+                self.sched.note_service(r, 1)
+                self._apply(r, int(nxt[row]))
+            if not self._deferring:
+                self._flush()
+            if acct is not None:
+                acct.note_phase("sample_host", self.clock() - t1)
+
+    def _bring_home(self) -> None:
+        """A settled engine, for whoever needs one (under the engine lock):
+        the decode step in flight, if the driver thread left one, is
+        fetched and applied, and every applied token is delivered."""
+        if self._flight is not None:
+            self._land(get_session(), self._flight)
+        self._flush()
 
     def _step_verify(self) -> bool:
         """The speculative iteration: one R×(K+1) verify dispatch replaces
@@ -1780,8 +1964,9 @@ class ServingEngine:
                     with get_session().span("serving/idle"):
                         busy = self.in_flight()
                         if not busy:
-                            # the last iteration's tokens: no program will
-                            # be enqueued to deliver them behind
+                            # the last iteration's tokens, and a step
+                            # whose every row ended under it: no program
+                            # will be enqueued to deliver them behind
                             self._flush_locked()
                             self._stop.wait(0.002)
                     if busy:
@@ -1795,7 +1980,7 @@ class ServingEngine:
 
     def _flush_locked(self) -> None:
         with self._lock:
-            self._flush()
+            self._bring_home()
 
     def stop(self) -> None:
         self._stop.set()
@@ -1803,6 +1988,10 @@ class ServingEngine:
         if t is not None:
             t.join(timeout=5.0)
             self._thread = None
+        if t is None or not t.is_alive():
+            # a step that a driver's loop on some other thread left in
+            # flight (the thread's own loop brings its own home)
+            self._flush_locked()
 
     def close(self) -> None:
         if self._closed:
@@ -1852,7 +2041,7 @@ class ServingEngine:
         accumulators, which would otherwise dominate draft_time_share and
         skew acceptance/emitted-per-dispatch."""
         with self._lock:
-            self._flush()   # into the window that ends here
+            self._bring_home()   # into the window that ends here
             self._ttft_samples.clear()
             self._tpot_samples.clear()
             self._accept_samples.clear()
@@ -1932,7 +2121,9 @@ class ServingEngine:
                 args = (eng.engine._params_sds(), eng._arena_sds(),
                         jax.ShapeDtypeStruct(
                             paged_kv.decode_rows_shape(R, MAXB), jnp.int32),
-                        jax.ShapeDtypeStruct((2,), jnp.uint32))
+                        jax.ShapeDtypeStruct((2,), jnp.uint32),
+                        jax.ShapeDtypeStruct(eng._last_tokens.shape,
+                                             jnp.int32))
                 return eng._decode, args, {}
 
             register_entry_point(

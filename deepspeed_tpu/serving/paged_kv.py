@@ -649,13 +649,19 @@ def build_decode_program(cfg, moe_counts: bool = False):
     writes land in the scratch block and their sampled tokens are ignored by
     the host — so occupancy changes never respecialize the program.
 
-    Args: params, cache (DONATED), packed (R, MAXB + 7) int32, base_key.
+    Args: params, cache (DONATED), packed (R, MAXB + 7) int32, base_key,
+    last (optional).
     ``packed`` is ``pack_decode_rows`` of the step's operands, taken apart
     in the program (``unpack_decode_rows``); a row of it holds, in this
     order: block_table (MAXB columns), lengths (tokens already in cache —
     the incoming token's position), tokens, temperature / top_k / top_p /
     seeds (temperature and top_p as float32 bit patterns), steps (the row's
     output-token index, for the schedule-independent sampling stream).
+    ``last`` is what the last call of this program returned as
+    ``next_token``, on the device still: a row whose ``tokens`` entry is
+    negative takes its token from there, so that a step can be enqueued
+    before its predecessor's tokens have reached the host (the serving
+    engine's step ahead). Without it every token comes through ``packed``.
     Returns (next_token (R,), cache). An MoE model keeps rows of length 0
     out of the routing; with ``moe_counts`` (the serving engine's own
     program) ``next_token`` is (R + 3,): the tokens, then the step's routing
@@ -666,8 +672,9 @@ def build_decode_program(cfg, moe_counts: bool = False):
     """
     step = _decode_step(cfg, moe_counts)
 
-    def decode(params, cache, packed, base_key):
-        return step(params, cache, *unpack_decode_rows(packed), base_key)
+    def decode(params, cache, packed, base_key, last=None):
+        return step(params, cache, *unpack_decode_rows(packed), base_key,
+                    last)
 
     return jax.jit(decode, donate_argnums=(1,))
 
@@ -678,7 +685,10 @@ def _decode_step(cfg, moe_counts: bool = False):
     from ..models.transformer import forward as model_forward
 
     def decode_step(params, cache, block_table, lengths, tokens,
-                    temperature, top_k, top_p, seeds, steps, base_key):
+                    temperature, top_k, top_p, seeds, steps, base_key,
+                    last=None):
+        if last is not None:
+            tokens = jnp.where(tokens < 0, last[:tokens.shape[0]], tokens)
         # a row that holds a request has at least its prompt in the cache.
         # The mask also sends an empty row's write to the scratch block,
         # which is where its all-zero table sent it anyway. A dense model

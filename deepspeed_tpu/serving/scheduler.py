@@ -177,6 +177,9 @@ class Scheduler:
         # bounded trace of admission order (tests + debugging)
         self.admitted_log = collections.deque(maxlen=4096)
         self.preemption_count = 0
+        # decode rows given back, by whatever ended or evicted a request
+        # (the serving engine's step ahead waits out the chunk that takes one)
+        self.rows_released = 0
         self.finished_count = 0
         self.cancelled_count = 0
         self.deadline_exceeded_count = 0
@@ -261,6 +264,7 @@ class Scheduler:
             del self.running[req.row]
             self._free_rows.append(req.row)
             req.row = None
+            self.rows_released += 1
         if req.blocks:
             self.alloc.free(req.blocks)
             req.blocks = []
@@ -295,8 +299,7 @@ class Scheduler:
         deadline — the common workload never pays for the scan."""
         if self._deadline_reqs == 0:
             return []
-        expired = [r for r in list(self.queued) + list(self.running.values())
-                   if r.deadline_s is not None and now > r.deadline_s]
+        expired = self._past_deadline(now)
         for req in expired:
             if req.state == QUEUED:
                 self.queued.remove(req)
@@ -306,6 +309,15 @@ class Scheduler:
             req.finish_s = now
             self.deadline_exceeded_count += 1
         return expired
+
+    def _past_deadline(self, now: float) -> List[Request]:
+        return [r for r in [*self.queued, *self.running.values()]
+                if r.deadline_s is not None and now > r.deadline_s]
+
+    def deadline_due(self, clock) -> bool:
+        """Whether ``expire_deadlines(clock())`` would end a request. The
+        clock is read only where an in-flight request carries a deadline."""
+        return self._deadline_reqs > 0 and bool(self._past_deadline(clock()))
 
     def release_handoff(self, req: Request) -> None:
         """Terminal release for a request whose KV was handed to ANOTHER

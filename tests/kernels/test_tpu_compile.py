@@ -253,8 +253,12 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
     key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     r = rows
     if kind == "decode":
+        # as the serving engine calls it: its last result, on the device,
+        # behind the key (an MoE model's counts lie behind the tokens)
+        last = arg((r + 3 * bool(program_options.get("moe_counts")),), I32)
         return paged_kv.build_decode_program(cfg, **program_options).lower(
-            params, arena, arg(paged_kv.decode_rows_shape(r, MAXB), I32), key)
+            params, arena, arg(paged_kv.decode_rows_shape(r, MAXB), I32), key,
+            last)
     if kind == "verify":
         return paged_kv.build_verify_program(cfg, SPEC_TOKENS).lower(
             params, arena,

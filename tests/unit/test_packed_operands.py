@@ -146,7 +146,8 @@ def tiny_engine():
 class Loose:
     """A program of the packed calling convention that runs the unpacked
     body: its ``operands`` is the tuple of loose host arrays that the
-    patched pack function handed through."""
+    patched pack function handed through. What the engine passes behind
+    the key (the decode program's last tokens) goes to the body as it is."""
 
     calls = 0
 
@@ -154,9 +155,10 @@ class Loose:
         self.program = jax.jit(step, donate_argnums=(1,))
         self.tail = tail
 
-    def __call__(self, params, cache, operands, key):
+    def __call__(self, params, cache, operands, key, *on_device):
         Loose.calls += 1
-        return self.program(params, cache, *operands, *self.tail, key)
+        return self.program(params, cache, *operands, *self.tail, key,
+                            *on_device)
 
     def _cache_size(self):
         return self.program._cache_size()

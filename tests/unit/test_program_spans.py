@@ -307,12 +307,19 @@ class TestServingFromTheInside:
                     s["name"] == "serving/publish"
                     and parent["name"] != "serving/iteration"):
                 shadowed += 1
-                disp, fetch = (next(
-                    c for c in spans if c.get("parent_id") == parent["id"]
-                    and c["name"] == parent["name"] + part)
-                    for part in ("/dispatch", "/fetch"))
-                assert disp["end_s"] <= s["start_s"] <= s["end_s"] \
-                    <= fetch["start_s"]
+                disp, fetch, applied = (next(
+                    (c for c in spans if c.get("parent_id") == parent["id"]
+                     and c["name"] == parent["name"] + part), None)
+                    for part in ("/dispatch", "/fetch", "/apply"))
+                assert disp["end_s"] <= s["start_s"]
+                if parent["attrs"].get("ahead") \
+                        and s["name"] == "serving/emit" \
+                        and s["start_s"] >= fetch["start_s"]:
+                    # a step ahead is running: its predecessor's tokens
+                    # are delivered as soon as they are applied
+                    assert applied["end_s"] <= s["start_s"]
+                elif fetch is not None:     # else the step stays in flight
+                    assert s["end_s"] <= fetch["start_s"]
         assert shadowed >= 8
         assert kids["serving/prefill_chunk"] >= {
             "serving/prefill_chunk/prepare",
@@ -347,19 +354,62 @@ class TestServingFromTheInside:
                            if c.get("parent_id") == parent["id"]),
                           key=lambda c: c["start_s"])
             names = [c["name"].replace(program, "...") for c in kids]
-            shadow = [n for n in names if n.startswith("serving/")]
-            assert names == [".../prepare", ".../dispatch", *shadow,
-                             ".../fetch", ".../apply"]
+            shadow = [n for n in names[2:] if n.startswith("serving/")]
+            # a decode step that stays in flight at its iteration's end has
+            # no fetch of its own; one enqueued AHEAD holds its
+            # predecessor's, and delivers that step's tokens at once
+            landed = names.count(".../fetch")
+            ahead = parent["attrs"].get("ahead", 0)
+            assert landed or program == "serving/decode"
+            assert names == [".../prepare", ".../dispatch",
+                             *shadow[:len(shadow) - ahead],
+                             *[".../fetch", ".../apply"][:2 * landed],
+                             *shadow[len(shadow) - ahead:]]
+            assert landed >= ahead
             assert set(shadow) <= {"serving/emit", "serving/publish"}
             for a, b in zip(kids, kids[1:]):
                 assert a["end_s"] <= b["start_s"]
             assert all(c["cat"] == "phase" for c in kids
                        if c["name"].startswith(program))
-            # the step's operands packed into one numpy array; the key is
-            # on the device already
+            # the step's operands packed into one numpy array; the key, and
+            # the decode program's last tokens, are on the device already
             assert kids[1]["attrs"]["host_operands"] == 1
             assert kids[1]["attrs"]["host_operand_bytes"] == 4 * int(
                 np.prod(packed_shape[program]))
+
+    def test_a_step_ahead_is_dispatched_before_its_predecessor_is_fetched(
+            self, served):
+        """The decode program's calls and fetches, each in the order of its
+        begin on the driver thread: the k-th fetch is of the k-th call. A
+        span with ``ahead`` holds call k+1 and THEN fetch k; every other
+        span with a fetch holds its own call's, or no call at all."""
+        spans, _, _ = served
+        steps = sorted((s for s in spans if s["name"] == "serving/decode"),
+                       key=lambda s: s["start_s"])
+
+        def leaves(part):
+            return sorted((s for s in spans
+                           if s["name"] == f"serving/decode/{part}"),
+                          key=lambda s: s["start_s"])
+
+        calls, fetches = leaves("dispatch"), leaves("fetch")
+        assert len(calls) == len(fetches) >= 8
+        ahead = 0
+        for step in steps:
+            mine = [calls.index(c) for c in calls
+                    if c["parent_id"] == step["id"]]
+            got = [fetches.index(f) for f in fetches
+                   if f["parent_id"] == step["id"]]
+            assert len(mine) <= 1 and len(got) <= 1
+            if step["attrs"].get("ahead"):
+                ahead += 1
+                assert got == [mine[0] - 1]
+                assert calls[mine[0]]["end_s"] <= fetches[got[0]]["start_s"]
+            elif mine and got:
+                assert got == mine
+        # the six requests overlap, so rows end and chunks come all the
+        # time; still some steps found nobody waiting
+        assert 0 < ahead < len(calls)
 
     def test_host_operands_reach_the_capture_as_stats(self, tiny_engine,
                                                       tmp_path):
@@ -386,7 +436,12 @@ class TestServingFromTheInside:
         # no prefix cache here: every block handed out is in a running row
         assert all(a["blocks_running"] == a["blocks_in_use"] for a in its)
         dec = [s["attrs"] for s in spans if s["name"] == "serving/decode"]
-        assert all(a["max_rows"] == 4 and 1 <= a["rows"] <= 4 for a in dec)
+        # a span with no ``rows`` holds the fetch of a step that nothing
+        # could go ahead of, and is no step of its own
+        assert all(a["max_rows"] == 4 for a in dec)
+        assert all(1 <= a["rows"] <= 4 and a["ahead"] in (0, 1)
+                   for a in dec if "rows" in a)
+        assert sum("rows" in a for a in dec) > len(dec) / 2
         admits = [s["attrs"] for s in spans if s["name"] == "serving/admit"]
         assert sum(a["admitted"] for a in admits) == 6
 

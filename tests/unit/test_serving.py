@@ -16,6 +16,8 @@ Coverage map (the ISSUE-6 checklist):
     under the sum of per-request T_max rows (paging actually shares HBM).
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -1014,6 +1016,15 @@ class TestDeadlineEnforcement:
 # ---------------------------------------------------------------------------
 
 
+def never_ahead(srv):
+    """Hold the driver thread's form to the iteration that fetches what it
+    enqueued (what the engine does whenever somebody waits for the device:
+    ``TestStepAhead`` has the states that say so): the order of enqueue,
+    delivery and fetch around ONE program is what these tests fix."""
+    srv._may_run_ahead = lambda flight: False
+    return srv
+
+
 def drive_on_this_thread(srv, iterations=None):
     """The driver thread's loop (``ServingEngine._drive``) on the calling
     thread, so a test can stop between two iterations: with ``iterations``
@@ -1117,7 +1128,8 @@ class TestDeferredDelivery:
         got = {}
         for mode in ("step", "driver_loop"):
             clk = FakeClock()
-            srv = serving(tiny_engine, clock=clk, prefix_cache=False)
+            srv = never_ahead(serving(tiny_engine, clock=clk,
+                                      prefix_cache=False))
             try:
                 h = srv.submit(np.arange(1, 30, dtype=np.int32),
                                max_new_tokens=40, deadline_s=5.0)
@@ -1154,18 +1166,21 @@ class TestDeferredDelivery:
 
     @staticmethod
     def _spy(monkeypatch, srv):
-        """Every dispatch (entry to and return from ``_run_program``), push
-        and return of an iteration, in order."""
+        """Every dispatch (entry to ``_enqueue``), fetch (return from
+        ``_fetch``), push and return of an iteration, in order."""
         from deepspeed_tpu.serving.session import RequestHandle
 
         events = []
-        run, push, iterate = (srv._run_program, RequestHandle._push,
-                              srv._iterate)
+        enqueue, fetch, push, iterate = (srv._enqueue, srv._fetch,
+                                         RequestHandle._push, srv._iterate)
 
-        def spy_run(obs, name, *a, **kw):
+        def spy_enqueue(obs, name, *a, **kw):
             events.append(("dispatch", name.split("/")[1]))
-            out = run(obs, name, *a, **kw)
-            events.append(("fetched", name.split("/")[1]))
+            return enqueue(obs, name, *a, **kw)
+
+        def spy_fetch(obs, sent):
+            out = fetch(obs, sent)
+            events.append(("fetched", sent.name.split("/")[1]))
             return out
 
         def spy_push(self, token, last=False):
@@ -1177,14 +1192,15 @@ class TestDeferredDelivery:
             events.append(("iteration_end",))
             return out
 
-        monkeypatch.setattr(srv, "_run_program", spy_run)
+        monkeypatch.setattr(srv, "_enqueue", spy_enqueue)
+        monkeypatch.setattr(srv, "_fetch", spy_fetch)
         monkeypatch.setattr(srv, "_iterate", spy_iterate)
         monkeypatch.setattr(RequestHandle, "_push", spy_push)
         return events
 
     def test_driver_thread_pushes_behind_the_next_dispatch(self, tiny_engine,
                                                            monkeypatch):
-        srv = serving(tiny_engine)
+        srv = never_ahead(serving(tiny_engine))
         events = self._spy(monkeypatch, srv)
         srv.start()
         try:
@@ -1211,7 +1227,7 @@ class TestDeferredDelivery:
 
     def test_a_late_request_s_chunk_shadows_the_pending_pushes(
             self, tiny_engine, monkeypatch):
-        srv = serving(tiny_engine)
+        srv = never_ahead(serving(tiny_engine))
         events = self._spy(monkeypatch, srv)
         try:
             a = srv.submit(np.arange(7), max_new_tokens=8)
@@ -1290,7 +1306,7 @@ class TestDeferredDelivery:
 
     @pytest.mark.parametrize("how", ["stop", "close", "idle"])
     def test_whatever_is_pending_is_flushed(self, tiny_engine, how):
-        srv = serving(tiny_engine)
+        srv = never_ahead(serving(tiny_engine))
         h = srv.submit(np.arange(7), max_new_tokens=3)
         drive_on_this_thread(srv, iterations=3)
         # the scheduler is through with the request; its last token waits
@@ -1339,4 +1355,364 @@ class TestDeferredDelivery:
         else:
             assert [a["deferred"] for a in emits] == [0] * 5
             assert shadow == 0
+        srv.close()
+
+
+# ---------------------------------------------------------------------------
+# a step ahead: the driver thread enqueues decode step n+1 before step n's
+# tokens are on the host, whenever nobody waits for the device. Same tokens
+# as a step-driven engine, whatever ends a request under a step in flight
+# ---------------------------------------------------------------------------
+
+
+def watch_steps(srv):
+    """Every decode step's operands, in the order of enqueue: (ahead, the
+    rids of its rows, its packed tokens column, requests queued). And no
+    step in flight ever sees a preemption or a cache eviction."""
+    log = []
+    operands, preempt = srv._decode_operands, srv.sched._preempt_one
+
+    def spy_operands(ready, ahead=False):
+        packed = operands(ready, ahead)
+        log.append((bool(ahead), [r.rid for r in ready],
+                    packed[:, srv.blocks_per_seq + 1].copy(),
+                    len(srv.sched.queued)))
+        return packed
+
+    def spy_preempt(exclude):
+        assert srv._flight is None, "preempted under a step in flight"
+        return preempt(exclude)
+
+    srv._decode_operands = spy_operands
+    srv.sched._preempt_one = spy_preempt
+    return log
+
+
+def steps_with(log, handle, ahead=None):
+    return sum(handle.request_id in rids for a, rids, *_ in log
+               if ahead is None or a == ahead)
+
+
+def stream_with_a_new_token(tiny_engine):
+    """(prompt, sampling kwargs, the stream of 12 tokens, the index of its
+    first token from the third decode step on that no earlier one equals):
+    an ``eos_token_id`` that ends the request there and nowhere before."""
+    prompt = np.arange(3, 14, dtype=np.int32)
+    sampled = dict(temperature=1.2, seed=5)
+    whole = serving(tiny_engine, prefix_cache=False)
+    stream = list(whole.submit(prompt, max_new_tokens=12, **sampled).result())
+    whole.close()
+    at = next(i for i in range(3, 12) if stream[i] not in stream[:i])
+    return prompt, sampled, stream, at
+
+
+@pytest.fixture(scope="module")
+def tiny_moe_engine():
+    return init_inference("tiny-olmoe", dtype=jnp.float32,
+                          max_out_tokens=128)
+
+
+@pytest.fixture(scope="module")
+def tiny_recurrent_engine():
+    return init_inference("tiny-nemotron-3-super", dtype=jnp.float32,
+                          max_out_tokens=128)
+
+
+AHEAD_CASES = {
+    # name: (engine fixture, engine config, [(prompt length, submit kwargs)],
+    #        whether some step must have gone ahead)
+    "greedy": ("tiny_engine", {}, DELIVERY_CASES["greedy"][1], True),
+    "sampled": ("tiny_engine", {}, DELIVERY_CASES["sampled"][1], True),
+    "eos": ("tiny_engine", {}, DELIVERY_CASES["eos"][1], True),
+    "budget": ("tiny_engine", {},
+               [(9, dict(max_new_tokens=3)), (14, dict(max_new_tokens=11)),
+                (21, dict(max_new_tokens=2)), (6, dict(max_new_tokens=7))],
+               True),
+    "one_token": ("tiny_engine", {}, DELIVERY_CASES["one_token"][1], True),
+    # every page taken: a row that needs its next one finds the free list
+    # empty, and the step that evicts for it is never one ahead
+    "small_pool": ("tiny_engine", dict(num_blocks=10, prefix_cache=False),
+                   DELIVERY_CASES["preempted"][1], None),
+    # more requests than rows: while one waits, no step goes ahead
+    "queued": ("tiny_engine", dict(max_seqs=2),
+               DELIVERY_CASES["greedy"][1], True),
+    "recurrent": ("tiny_recurrent_engine", dict(prefix_cache=False),
+                  [(13, dict(max_new_tokens=7)), (37, dict(max_new_tokens=5)),
+                   (8, dict(max_new_tokens=9, temperature=0.9, seed=4))],
+                  True),
+    "moe": ("tiny_moe_engine", {},
+            [(13, dict(max_new_tokens=7)), (37, dict(max_new_tokens=5)),
+             (8, dict(max_new_tokens=9, temperature=0.9, seed=4))], True),
+}
+
+
+class TestStepAhead:
+    @pytest.mark.parametrize("case", sorted(AHEAD_CASES))
+    def test_streams_are_those_of_a_step_driven_engine(self, request, case):
+        fixture, cfg, specs, must_go_ahead = AHEAD_CASES[case]
+        engine = request.getfixturevalue(fixture)
+        if case in DELIVERY_CASES:
+            _, reqs = delivery_requests(engine, case)
+        else:
+            rng = np.random.RandomState(sum(map(ord, case)))
+            reqs = [(rng.randint(0, 250, (n,)).astype(np.int32), kw)
+                    for n, kw in specs]
+        streams, ahead = {}, {}
+        for mode in ("step", "thread", "driver_loop"):
+            srv = serving(engine, **cfg)
+            log = watch_steps(srv)
+            try:
+                handles = [srv.submit(p, **kw) for p, kw in reqs]
+                if mode == "step":
+                    srv.run()
+                elif mode == "thread":
+                    srv.start()
+                else:
+                    drive_on_this_thread(srv)
+                streams[mode] = [list(h.result(timeout_s=120.0))
+                                 for h in handles]
+                srv.stop()
+                assert all(h.done and h.state == "finished"
+                           and h.tokens == h._req.generated for h in handles)
+                assert srv._flight is None and not srv._undelivered
+                assert not srv.sched.running and not srv._handles
+                # every page came back, once (a double free raises)
+                assert srv.alloc.blocks_in_use == (
+                    srv.prefix.cached_blocks if srv.prefix else 0)
+                ahead[mode] = sum(a for a, *_ in log)
+                for a, rids, tokens, queued in log:
+                    rows = [h._req for h in handles if h.request_id in rids]
+                    assert len(rows) == len(rids)
+                    if a:
+                        # nobody waited, and every token stays on the device
+                        assert queued == 0
+                        assert sorted(tokens)[:len(rids)] == [-1] * len(rids)
+                        assert (tokens <= 0).all()
+                    else:
+                        assert (tokens >= 0).all()
+                if mode != "step":
+                    for h, (_, kw) in zip(handles, reqs):
+                        # a row sits in one step a decoded token; under the
+                        # driver an end by eos_token_id adds the one step
+                        # that was ahead of it, whose token is dropped
+                        decoded = len(h.tokens) - 1
+                        by_eos = len(h.tokens) < kw["max_new_tokens"]
+                        assert decoded <= steps_with(log, h) \
+                            <= decoded + by_eos
+            finally:
+                srv.close()
+        assert streams["thread"] == streams["step"]
+        assert streams["driver_loop"] == streams["step"]
+        assert ahead["step"] == 0
+        if must_go_ahead:
+            assert ahead["thread"] > 0 and ahead["driver_loop"] > 0
+
+    def test_an_end_by_eos_drops_the_token_of_the_step_ahead(self,
+                                                             tiny_engine):
+        prompt, sampled, greedy, at = stream_with_a_new_token(tiny_engine)
+        srv = serving(tiny_engine, prefix_cache=False)
+        log = watch_steps(srv)
+        applied = []
+        apply_ = srv._apply
+        srv._apply = lambda req, token, first=False: (
+            applied.append(token), apply_(req, token, first))[1]
+        try:
+            h = srv.submit(prompt, max_new_tokens=12,
+                           eos_token_id=int(greedy[at]), **sampled)
+            other = srv.submit(np.arange(40, 60, dtype=np.int32),
+                               max_new_tokens=12)
+            for _ in range(12):
+                if not h._req.done:
+                    srv._iterate(defer=True)
+            assert h._req.done
+            # the step ahead of the one that brought the eos holds the row
+            flight = srv._flight
+            assert flight is not None
+            assert h._req in [r for r, _ in flight.rows]
+            assert h._req.row is None and not h._req.blocks
+            n_applied = len(applied)
+            drive_on_this_thread(srv)
+            assert h.tokens == greedy[:at + 1]
+            assert steps_with(log, h) == at + 1     # `at` decoded + 1 dropped
+            # the dropped token was never applied: the rest are `other`'s
+            assert len(other.tokens) == 12
+            assert len(applied) == at + 1 + 12 and n_applied < len(applied)
+            assert srv.alloc.blocks_in_use == 0
+        finally:
+            srv.close()
+
+    def test_a_row_that_ends_by_its_budget_is_left_out_of_the_step_ahead(
+            self, tiny_engine):
+        srv = serving(tiny_engine)
+        log = watch_steps(srv)
+        try:
+            short = srv.submit(np.arange(9), max_new_tokens=4)
+            long = srv.submit(np.arange(5, 19), max_new_tokens=10)
+            drive_on_this_thread(srv)
+            assert len(short.tokens) == 4 and len(long.tokens) == 10
+            # one step a decoded token, none dropped
+            assert steps_with(log, short) == 3 and steps_with(log, long) == 9
+            assert steps_with(log, short, ahead=True) > 0
+            assert steps_with(log, long, ahead=True) > steps_with(
+                log, short, ahead=True)
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline"])
+    def test_a_stream_cut_with_a_step_in_flight(self, tiny_engine, how):
+        """The cancel brings the step home and streams its token before it
+        ends the stream; the deadline is seen at the next iteration, which
+        fetches first. Either way the stream is the uncut one's beginning,
+        and every page comes back."""
+        from deepspeed_tpu.serving import DeadlineExceeded
+
+        prompt = np.arange(1, 30, dtype=np.int32)
+        whole = serving(tiny_engine, prefix_cache=False)
+        want = list(whole.submit(prompt, max_new_tokens=40).result())
+        whole.close()
+        clk = FakeClock()
+        srv = serving(tiny_engine, clock=clk, prefix_cache=False)
+        try:
+            h = srv.submit(prompt, max_new_tokens=40, deadline_s=5.0)
+            other = srv.submit(np.arange(3, 20, dtype=np.int32),
+                               max_new_tokens=40)
+            for _ in range(8):
+                srv._iterate(defer=True)
+                if srv._flight is not None \
+                        and srv._flight.since is not None:
+                    break
+            in_flight = srv._flight             # a step that went ahead
+            assert [r for r, _ in in_flight.rows] == [h._req, other._req]
+            seen = len(h.tokens)
+            assert seen == len(h._req.generated)    # delivered at once
+            if how == "cancel":
+                assert h.cancel()
+                assert srv._flight is None
+                assert len(h.tokens) == seen + 1    # the step's token came
+                with pytest.raises(RequestCancelled):
+                    h.result()
+            else:
+                clk.advance(10.0)
+                srv._iterate(defer=True)        # not ahead: fetch alone
+                assert srv._flight is None and h.state == "decode"
+                assert len(h._req.generated) == seen + 1
+                srv._iterate(defer=True)        # today's: the expiry
+                assert h.state == "deadline_exceeded"
+                with pytest.raises(DeadlineExceeded):
+                    h.result()
+            assert h.done and h.tokens == h._req.generated
+            assert h.tokens == want[:len(h.tokens)] and len(h.tokens) >= 3
+            other.cancel()
+            assert other.tokens == other._req.generated
+            assert srv.alloc.blocks_in_use == 0
+        finally:
+            srv.close()
+
+    def test_an_admission_finds_the_enqueues_in_today_s_order(
+            self, tiny_engine, monkeypatch):
+        """With a step in flight and a request queued, the iteration
+        fetches and enqueues nothing; the next is today's: the chunk on an
+        idle device, the kept tokens delivered behind its enqueue, the
+        decode step behind its fetch. The step behind THAT goes ahead."""
+        srv = serving(tiny_engine)
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        try:
+            a = srv.submit(np.arange(7), max_new_tokens=12)
+            drive_on_this_thread(srv, iterations=3)
+            assert srv._flight is not None and len(a.tokens) == 3
+            b = srv.submit(np.arange(9), max_new_tokens=8)
+            del events[:]
+            drive_on_this_thread(srv, iterations=4)
+            ra, rb = a.request_id, b.request_id
+            assert events == [
+                ("fetched", "decode"), ("iteration_end",),
+                ("dispatch", "prefill_chunk"), ("push", ra, 3),
+                ("fetched", "prefill_chunk"),
+                ("push", rb, 0), ("dispatch", "decode"), ("iteration_end",),
+                ("dispatch", "decode"), ("fetched", "decode"),
+                ("push", ra, 4), ("push", rb, 1), ("iteration_end",),
+                ("dispatch", "decode"), ("fetched", "decode"),
+                ("push", ra, 5), ("push", rb, 2), ("iteration_end",)]
+            drive_on_this_thread(srv)
+            assert len(a.tokens) == 12 and len(b.tokens) == 8
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("how", ["stop", "close", "idle", "step",
+                                     "score_logprobs"])
+    def test_whoever_needs_a_settled_engine_brings_the_step_home(
+            self, tiny_engine, how):
+        srv = serving(tiny_engine, prefix_cache=False)
+        h = srv.submit(np.arange(7), max_new_tokens=6)
+        drive_on_this_thread(srv, iterations=3)
+        # the prompt's chunk and a step, then two steps ahead: the second
+        # of them is enqueued, the first's token delivered
+        assert srv._flight is not None and len(h.tokens) == 3
+        if how == "idle":
+            # a request that ends by its eos_token_id under a step ahead
+            # leaves that step in flight with nothing else to do
+            prompt, sampled, stream, at = stream_with_a_new_token(
+                tiny_engine)
+            srv.cancel(h)
+            e = srv.submit(prompt, max_new_tokens=12,
+                           eos_token_id=stream[at], **sampled)
+            for _ in range(12):
+                if not e._req.done:
+                    srv._iterate(defer=True)
+            assert srv._flight is not None and srv.in_flight() == 0
+            srv.start()
+            assert e.result(timeout_s=60.0).tolist() == stream[:at + 1]
+            for _ in range(500):
+                if srv._flight is None:
+                    break
+                time.sleep(0.01)
+            assert srv._flight is None      # and no stop() was needed
+            srv.stop()
+        elif how == "step":
+            srv.step()                  # the fetch, and a whole iteration
+            assert srv._flight is None and len(h.tokens) == 5
+        elif how == "score_logprobs":
+            srv.score_logprobs(np.arange(20))
+            assert srv._flight is None and len(h.tokens) == 4
+        else:
+            srv.stop() if how == "stop" else srv.close()
+            assert srv._flight is None and len(h.tokens) == 4
+        srv.run()
+        assert h.done and not srv._undelivered
+        assert len(h.tokens) == (4 if how == "idle" else 6)
+        assert srv._flight is None and srv.alloc.blocks_in_use == 0
+        srv.close()
+
+    @pytest.mark.parametrize("model", ["dense", "moe"])
+    def test_the_ahead_count_the_counter_and_the_one_transfer(
+            self, request, obs_session, model):
+        from deepspeed_tpu.observability import recorded_spans
+
+        engine = request.getfixturevalue(
+            "tiny_engine" if model == "dense" else "tiny_moe_engine")
+        counter = get_registry().counter("serving/steps_enqueued_ahead")
+        before = counter.value()
+        srv = serving(engine)
+        srv.start()
+        h = srv.submit(np.arange(7), max_new_tokens=6)
+        assert len(h.result(timeout_s=60.0)) == 6
+        srv.stop()
+        spans = recorded_spans()
+        steps = [s["attrs"] for s in spans if s["name"] == "serving/decode"
+                 and s["attrs"].get("rows")]
+        # five decoded tokens: the first step behind the prompt's chunk,
+        # four ahead, each of one row
+        assert [a["ahead"] for a in steps] == [0, 1, 1, 1, 1]
+        assert counter.value() - before == 4
+        dispatches = [s["attrs"] for s in spans
+                      if s["name"] == "serving/decode/dispatch"]
+        assert len(dispatches) == 5
+        assert all(a["host_operands"] == 1 for a in dispatches)
+        if model == "moe":
+            # the counts behind the tokens, read where each step is fetched
+            routed = [s["attrs"] for s in spans
+                      if s["name"] == "serving/decode"
+                      and "moe_assignments" in s["attrs"]]
+            assert len(routed) == 5
+            assert all(a["moe_assignments"] > 0 for a in routed)
         srv.close()
