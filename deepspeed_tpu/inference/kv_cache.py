@@ -118,10 +118,26 @@ def assert_block_divisible(max_seq_len: int, block_size: int) -> int:
 
 
 def _paged_layers(cfg) -> int:
-    """Layers that keep pages: those whose mixer is softmax attention."""
-    from ..models.transformer import layers_with_mixer
+    """Layers that keep pages of their own: those whose mixer is softmax
+    attention over all of a sequence (a window layer keeps a ring a row
+    beside the state slots, a cross layer reads another layer's pages)."""
+    from ..models.transformer import paged_layers
 
-    return len(layers_with_mixer(cfg, "attn"))
+    return len(paged_layers(cfg))
+
+
+def ring_blocks(cfg, chunk_tokens: int, block_size: int) -> int:
+    """Pages of a window layer's ring a row (0: the model has no such
+    layer): what ``attention_window`` keys and the ``chunk_tokens`` queries
+    of the widest program can see together, whole pages. A page is written
+    over only by a position a ring's length ahead, so every key in sight of
+    a query is resident when it reads, whatever the row's length: the bound
+    on a window layer's bytes a row."""
+    from ..models.transformer import ring_layers
+
+    if not ring_layers(cfg):
+        return 0
+    return -(-(cfg.attention_window + chunk_tokens) // block_size)
 
 
 def _paged_shape(cfg, num_blocks: int, block_size: int):
@@ -129,13 +145,19 @@ def _paged_shape(cfg, num_blocks: int, block_size: int):
             cfg.num_kv_heads * cfg.head_dim)
 
 
-def _state_shapes(cfg, state_slots: int, dtype) -> Dict[str, Any]:
+def _state_shapes(cfg, state_slots: int, dtype,
+                  ring: tuple = (0, 0)) -> Dict[str, Any]:
     """The second kind of per-sequence state, beside pages: for each
     recurrent layer and slot a float32 state a head (a delta-rule layer's
-    matrix; a state-space layer's, in ``ops/mamba2.pack_states``' layout)
-    and the last ``taps - 1`` rows of the convolution's input (in the
-    model's dtype). ``{}`` for a model with no such layer."""
-    from ..models.transformer import MIXERS, recurrent_layers
+    matrix; a state-space layer's, in ``ops/mamba2.pack_states``' or
+    ``ops/mamba1``'s layout) and the last ``taps - 1`` rows of the
+    convolution's input (in the model's dtype); and for each window layer
+    and slot a ring of ``ring`` = (pages, tokens a page) for its keys and
+    one for its values, behind one scratch page (``"wk"``, ``"wv"``: arenas
+    of pages as ``"k"`` and ``"v"`` are, which the paged kernels read
+    through the table ``models/transformer._ring_table`` makes). ``{}`` for
+    a model with no such layer."""
+    from ..models.transformer import MIXERS, recurrent_layers, ring_layers
 
     mixer, layers = recurrent_layers(cfg)
     n = len(layers)
@@ -145,24 +167,37 @@ def _state_shapes(cfg, state_slots: int, dtype) -> Dict[str, Any]:
         raise ValueError("a model with recurrent layers needs state_slots: "
                          "a slot a decode row and one scratch")
     state, taps, width = MIXERS[mixer].state(cfg)
-    return {"state": ((n, state_slots) + state, jnp.float32),
-            "tail": ((n, state_slots, taps - 1, width), dtype)}
+    shapes = {"state": ((n, state_slots) + state, jnp.float32),
+              "tail": ((n, state_slots, taps - 1, width), dtype)}
+    windows = len(ring_layers(cfg))
+    if windows:
+        pages, block_size = ring
+        if pages < 1:
+            raise ValueError("a model with window layers needs the pages of "
+                             "a row's ring (kv_cache.ring_blocks)")
+        pool = (windows, 1 + state_slots * pages, block_size,
+                cfg.num_kv_heads * cfg.head_dim)
+        shapes.update(wk=(pool, dtype), wv=(pool, dtype))
+    return shapes
 
 
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype,
-                     state_slots: int = 0) -> Dict[str, jax.Array]:
+                     state_slots: int = 0, ring_blocks: int = 0
+                     ) -> Dict[str, jax.Array]:
     """Allocate the paged arena: ``num_blocks`` INCLUDES the reserved
     scratch block 0 (allocatable blocks are 1..num_blocks-1). Pages exist
     for the softmax layers only; a model with recurrent layers gets, in the
     same dict, the pools ``"state"`` and ``"tail"`` of ``state_slots`` slots
-    (``_state_shapes``), zeroed."""
+    and, for its window layers, the rings ``"wk"`` and ``"wv"`` of
+    ``ring_blocks`` pages a slot (``_state_shapes``), zeroed."""
     if num_blocks < 2:
         raise ValueError(f"num_blocks={num_blocks}: need the scratch block "
                          "plus at least one allocatable block")
     shape = _paged_shape(cfg, num_blocks, block_size)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             **{name: jnp.zeros(sh, dt) for name, (sh, dt)
-               in _state_shapes(cfg, state_slots, dtype).items()}}
+               in _state_shapes(cfg, state_slots, dtype,
+                                (ring_blocks, block_size)).items()}}
 
 
 def paged_cache_memory_bytes(cfg, num_blocks: int, block_size: int,
@@ -174,16 +209,20 @@ def paged_cache_memory_bytes(cfg, num_blocks: int, block_size: int,
             * cfg.num_kv_heads * cfg.head_dim * itemsize)
 
 
-def state_pool_memory_bytes(cfg, state_slots: int, dtype) -> int:
-    """The state pools' footprint; 0 for a model with no recurrent layer."""
+def state_pool_memory_bytes(cfg, state_slots: int, dtype,
+                            ring: tuple = (0, 0)) -> int:
+    """The state pools' footprint, a window layer's rings with them; 0 for
+    a model with no recurrent layer."""
     return sum(int(np.prod(sh)) * jnp.dtype(dt).itemsize for sh, dt
-               in _state_shapes(cfg, state_slots, dtype).values())
+               in _state_shapes(cfg, state_slots, dtype, ring).values())
 
 
 def paged_cache_shape_struct(cfg, num_blocks: int, block_size: int,
-                             dtype, state_slots: int = 0) -> Dict[str, Any]:
+                             dtype, state_slots: int = 0,
+                             ring_blocks: int = 0) -> Dict[str, Any]:
     shape = _paged_shape(cfg, num_blocks, block_size)
     return {"k": jax.ShapeDtypeStruct(shape, dtype),
             "v": jax.ShapeDtypeStruct(shape, dtype),
             **{name: jax.ShapeDtypeStruct(sh, dt) for name, (sh, dt)
-               in _state_shapes(cfg, state_slots, dtype).items()}}
+               in _state_shapes(cfg, state_slots, dtype,
+                                (ring_blocks, block_size)).items()}}

@@ -8,7 +8,12 @@ shared expert beside sigmoid-routed ones; served through ``init_serving``,
 whole or as one chip's share of its experts via ``moe_experts_held``) and
 Nemotron 3 Super (``nemotron-3-super-120b-a12b``, ``tiny-nemotron-3-super``:
 layers that are ONE function each, a Mamba-2 state-space mixer, a GQA
-attention mixer or an FFN of experts in a latent; served the same way)."""
+attention mixer or an FFN of experts in a latent; served the same way) and
+Phi-4-mini-flash-reasoning (``phi-4-mini-flash-reasoning``,
+``tiny-phi4flash``: a self-decoder of Mamba-1 and window layers, one full
+layer, and a cross-decoder of gated memory units and layers that read the
+full layer's pages, all with differential attention; served the same way,
+and only so)."""
 
 from __future__ import annotations
 
@@ -18,6 +23,20 @@ import jax.numpy as jnp
 
 from .core import Model
 from .transformer import TransformerConfig, build_model
+
+
+def phi4flash_runs(num_layers: int) -> tuple:
+    """The published order of the ``phi4flash`` family (``mb_per_layer`` 2)
+    as ``layer_runs``: Mamba-1 on the even layers of the self-decoder and a
+    window layer on its odd ones, the full layer at ``L/2 + 1``, then gated
+    memory units on the even layers and cross layers on the odd ones. 32
+    layers: 8 x (mamba1, swa), (mamba1, full), 7 x (gmu, cross)."""
+    if num_layers % 4 or num_layers < 8:
+        raise ValueError(f"phi4flash: {num_layers} layers; the published "
+                         "rule wants a depth divisible by 4, of 8 or more")
+    half = num_layers // 2
+    return ((("mamba1", "swa"), half // 2), (("mamba1", "full"), 1),
+            (("gmu", "cross"), half // 2 - 1))
 
 
 def nemotron_h_pattern(hybrid_override_pattern: str) -> tuple:
@@ -106,6 +125,13 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
                        moe_score_func="sigmoid", moe_router_bias=True,
                        moe_norm_topk_prob=True, moe_shared_experts=1,
                        mamba_conv_taps=4),
+    # microsoft/Phi-4-mini-flash-reasoning (SambaY, arXiv:2507.06607): no
+    # positional term anywhere; the Mamba-1 sizes are the family's
+    # convention (state 16, 4 taps, expand 2, step rank hidden / 16)
+    "phi4flash": dict(norm="layernorm", position="none", activation="swiglu",
+                      tie_embeddings=True, norm_eps=1e-5, diff_attn=True,
+                      mamba_state_size=16, mamba_conv_taps=4,
+                      mamba_expand=2),
 }
 
 # size presets: hidden, layers, heads, kv_heads, vocab, max_seq
@@ -229,6 +255,19 @@ _SIZES: Dict[str, Dict[str, Any]] = {
         mamba_state_size=16, ffn_hidden_size=48, moe_latent_size=32,
         moe_shared_ffn_hidden_size=96, moe_num_experts=16, moe_top_k=3,
         moe_routed_scale=5.0, vocab_size=256, max_seq_len=128),
+    # microsoft/Phi-4-mini-flash-reasoning config.json (3.85 B): 40 query
+    # and 20 key-value heads of 64, window 512; the order of its layers is
+    # ``phi4flash_runs`` of the depth; max_seq_len bounds nothing
+    "phi-4-mini-flash-reasoning": dict(
+        family="phi4flash", hidden_size=2560, num_layers=32, num_heads=40,
+        num_kv_heads=20, ffn_hidden_size=10240, attention_window=512,
+        vocab_size=200064, max_seq_len=262144),
+    # 8 layers: two (mamba1, swa) periods, (mamba1, full), (gmu, cross); a
+    # window shorter than a chunk can be, heads in two groups of pairs
+    "tiny-phi4flash": dict(
+        family="phi4flash", hidden_size=64, num_layers=8, num_heads=8,
+        num_kv_heads=4, ffn_hidden_size=96, attention_window=8,
+        vocab_size=256, max_seq_len=128),
     # GShard/Switch-style 8-expert GPT (BASELINE tracked config #4)
     "moe-tiny": dict(family="gpt2", hidden_size=64, num_layers=2, num_heads=4,
                      vocab_size=256, max_seq_len=128, moe_num_experts=8),
@@ -249,6 +288,8 @@ def transformer_config(preset: str, dtype=jnp.float32, **overrides) -> Transform
     kwargs = dict(_FAMILIES[family])
     kwargs.update(spec)
     kwargs.update(overrides)
+    if family == "phi4flash":
+        kwargs.setdefault("layer_runs", phi4flash_runs(kwargs["num_layers"]))
     return TransformerConfig(dtype=dtype, **kwargs)
 
 

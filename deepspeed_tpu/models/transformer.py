@@ -35,11 +35,17 @@ from .core import (EMBED, EXPERT, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ,
 # model's FFN follows it).
 # "attn" and "kda" are a whole block, norm, mixer, add, norm, FFN, add; the
 # others are ONE function under one norm and one add, for a family whose
-# layers are a mixer alone or an FFN alone
+# layers are a mixer alone or an FFN alone. "swa", "full" and "cross" are the
+# softmax mixer in three forms (``AttnForm``: a window's ring of pages, pages
+# of its own, another layer's pages), "mamba1" the selective scan and "gmu" a
+# gate on the value the last "mamba1" layer handed on
 LAYER_KINDS = {"attn": ("attn", True), "kda": ("kda", True),
                "attn_mixer": ("attn", False),
                "mamba2_mixer": ("mamba2", False),
-               "ffn": (None, True)}
+               "ffn": (None, True),
+               "mamba1": ("mamba1", True), "swa": ("swa", True),
+               "full": ("full", True), "cross": ("cross", True),
+               "gmu": ("gmu", True)}
 # taps of the "kda" mixer's depthwise convolution over time (the published
 # short_conv_kernel_size of the one family that has the mixer)
 KDA_CONV_TAPS = 4
@@ -68,10 +74,13 @@ class TransformerConfig:
     # GPT-Neo family: per-layer attention pattern ('global'|'local', cycled
     # over layers) with a sliding window for local layers; non-empty routes
     # attention through the windowed jnp path (the flash kernel has no
-    # window operand). attention_scale: None => 1/sqrt(head_dim); GPT-Neo
-    # uses unscaled scores (1.0).
+    # window operand; training and the dense cache only). attention_scale:
+    # None => 1/sqrt(head_dim); GPT-Neo uses unscaled scores (1.0).
     attention_layers: tuple = ()
-    attention_window: int = 256
+    attention_window: int = 256             # keys a query of a window layer
+    #   sees, its own included: the 'local' layers of ``attention_layers``
+    #   and the "swa" layers of ``layer_runs``, which the serving layer runs
+    #   through the paged kernels over a ring of pages a row
     attention_scale: Optional[float] = None
     #   (GPT-J/GPT-NeoX; GPT-J shares one LN — its import aliases ln2=ln1)
     rotary_dim: Optional[int] = None        # partial rotary: rope on the
@@ -168,6 +177,19 @@ class TransformerConfig:
     # parameter tree and each mixer its own cache entry (pages / a state
     # and a convolution tail)
     layer_pattern: tuple = ()
+    # a stack that is no one period: RUNS of equal periods, ((kinds of a
+    # period, ...), periods) each, in order; ``layer_pattern`` is then the
+    # whole order they spell and ``forward`` scans each run (paged mode
+    # only). The kinds that read what ANOTHER layer made ("cross" the pages
+    # of the "full" layer before it, "gmu" the value the last "mamba1" layer
+    # handed on) and a window's ring ("swa") exist only in such a stack
+    layer_runs: tuple = ()
+    diff_attn: bool = False           # differential attention
+    #   (arXiv:2410.05258) in the "swa", "full" and "cross" layers: adjacent
+    #   heads pair, two softmax maps a pair over values twice as wide,
+    #   subtracted (``_diff_pairs``)
+    mamba_expand: int = 2             # "mamba1": inner width / hidden (its
+    #   step's projection has rank ceil(hidden / 16), the family's rule)
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
     mamba_n_groups: int = 1
@@ -193,6 +215,13 @@ class TransformerConfig:
                 self.ffn_hidden_size = 4 * self.hidden_size
         assert self.head_size or self.hidden_size % self.num_heads == 0
         assert self.num_heads % self.num_kv_heads == 0
+        self.layer_runs = tuple((tuple(kinds), int(n))
+                                for kinds, n in self.layer_runs)
+        if self.layer_runs:
+            self.layer_pattern = tuple(
+                kind for kinds, n in self.layer_runs for kind in kinds * n)
+            assert len(self.layer_pattern) == self.num_layers, \
+                (self.layer_runs, self.num_layers)
         self.layer_pattern = tuple(self.layer_pattern)[:self.num_layers]
         if self.layer_pattern:
             assert set(self.layer_pattern) <= set(LAYER_KINDS), \
@@ -204,6 +233,15 @@ class TransformerConfig:
             assert len(mixers) == len(set(mixers)), self.layer_pattern
             assert sum(1 for m in mixers if m and MIXERS[m].state) <= 1, \
                 "the state pools hold one kind of recurrent state"
+            records = [MIXERS[m] for m in mixers if m]
+            if any(r.keeps != "pages" and not r.state for r in records):
+                # what addresses a row's ring by its state slot, or reads
+                # what another layer made, is built by ``_run_layers`` alone
+                assert self.layer_runs, \
+                    f"{self.layer_pattern}: kinds of a stack of layer_runs"
+                assert any(r.state for r in records) or not any(
+                    r.keeps == "ring" for r in records), \
+                    "a window's ring is addressed by a row's state slot"
         if self.moe_experts_held:
             assert 0 < self.moe_experts_held <= self.moe_num_experts
 
@@ -315,6 +353,48 @@ def recurrent_layers(cfg: TransformerConfig
     return None, ()
 
 
+def _layers_keeping(cfg: TransformerConfig, what: str) -> Tuple[int, ...]:
+    return tuple(sorted(
+        i for mixer, record in MIXERS.items() if record.keeps == what
+        for i in layers_with_mixer(cfg, mixer)))
+
+
+def paged_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
+    """The layers that keep pages of their own in the serving arena's
+    ``"k"`` and ``"v"`` (a window layer keeps a ring a row, a cross layer
+    reads another's pages)."""
+    return _layers_keeping(cfg, "pages")
+
+
+def ring_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
+    """The window layers, which keep ``attention_window`` keys a row in a
+    ring of pages (the pools ``"wk"`` and ``"wv"``)."""
+    return _layers_keeping(cfg, "ring")
+
+
+def pool_readers(cfg: TransformerConfig) -> Tuple[int, ...]:
+    """The layers of a stack of runs that read the arena's ``"k"`` and
+    ``"v"``: the full layers, which also write them, and the cross layers
+    (``()`` for any other stack: the count is the span's, not a cache's)."""
+    if not cfg.layer_runs:
+        return ()
+    return tuple(sorted(i for kind in ("full", "cross")
+                        for i in layers_of_kind(cfg, kind)))
+
+
+def tail_runs(cfg: TransformerConfig) -> int:
+    """How many of the stack's LAST runs keep nothing of a token (no pages,
+    ring or state: they read what earlier layers made): a prompt chunk runs
+    them for its last real token alone (``forward``'s ``last_token``)."""
+    n = 0
+    for kinds, _ in reversed(cfg.layer_runs):
+        records = [MIXERS[LAYER_KINDS[k][0]] for k in kinds]
+        if any(r.keeps or r.state for r in records):
+            break
+        n += 1
+    return n
+
+
 def ffn_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
     """The layers that have an FFN (every one, but for a family whose
     layers are one function each)."""
@@ -365,6 +445,11 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
             layer["ln1"] = {"scale": jnp.ones((H,), cfg.dtype)}
             layer[MIXERS[mixer].name] = MIXERS[mixer].init(cfg, normal,
                                                            uniform)
+            if "lam_q1" in layer[MIXERS[mixer].name]:
+                # the published lambda_init of the layer's place in the
+                # WHOLE stack: a constant a layer, not learned
+                layer[MIXERS[mixer].name]["lam_init"] = (
+                    0.8 - 0.6 * jnp.exp(-0.3 * li.astype(jnp.float32)))
         if has_ffn:
             layer["ln2"] = {"scale": jnp.ones((H,), cfg.dtype)}
         if not has_ffn:
@@ -1052,9 +1137,15 @@ class Step:
     key_positions: Optional[jax.Array] = None   # (B, T), ragged alibi decode
     window: Optional[jax.Array] = None      # this layer's sliding-window
     #   width (traced scalar, <=0 = global): attention_layers models
-    #   (GPT-Neo) only, which take the windowed jnp attention path throughout
+    #   (GPT-Neo), which take the windowed jnp attention path in training
+    #   and over the dense cache. A "swa" layer's window is static
+    #   (``cfg.attention_window``) and rides the paged kernels
     moe_counts: bool = False
     expert_banks: Optional[Dict[str, Any]] = None
+    memory: Optional[jax.Array] = None      # (B, S, inner): what the last
+    #   "mamba1" layer handed on, for the "gmu" layers of the same step
+    shared_layer: Optional[int] = None      # a "cross" layer: the place
+    #   among the layers that keep pages of the one whose pool it reads
 
 
 def window_table(cfg: TransformerConfig) -> jax.Array:
@@ -1418,10 +1509,36 @@ def _attention_fn(cfg: TransformerConfig, window: Optional[jax.Array]
     return partial(dot_product_attention, scale=cfg.attention_scale), alibi
 
 
+@dataclasses.dataclass(frozen=True)
+class AttnForm:
+    """What tells the softmax mixer's forms apart: what a layer projects,
+    which pool it writes and which it reads (docs/models.md)."""
+    window: bool = False    # keys and values go to a RING of pages a row
+    #   (the pools "wk" and "wv", addressed by the row's state slot) and a
+    #   query sees ``cfg.attention_window`` of them
+    cross: bool = False     # q and the output projection alone: the keys
+    #   and values are those of the layer ``step.shared_layer`` of "k", "v"
+
+
+def _ring_table(cache: Dict[str, jax.Array], slots: jax.Array,
+                max_blocks: int) -> jax.Array:
+    """The block table of a window layer, (B, max_blocks), made in the
+    program: the ring of the row's state slot, a run of pages of the pools
+    ``"wk"`` and ``"wv"`` behind their scratch page 0, repeated. Position p
+    then lies where the paged write and read look for it, in table entry
+    ``p // BLOCK`` at offset ``p % BLOCK``; a page is written over when the
+    ring comes round, which is after every query that could see it
+    (``inference/kv_cache.ring_blocks``)."""
+    ring = (cache["wk"].shape[1] - 1) // cache["tail"].shape[1]
+    return (1 + slots[:, None] * ring
+            + jnp.arange(max_blocks, dtype=jnp.int32)[None] % ring)
+
+
 def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
-                  v: jax.Array, step: Step
+                  v: jax.Array, step: Step, form: Optional[AttnForm] = None
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """The "attn" mixer's write and read over the serving layer's pages."""
+    """The "attn" mixer's write and read over the serving layer's pages;
+    with a ``form``, over a window's ring (no ``k``: the read alone)."""
     # PAGED serving path (deepspeed_tpu/serving/paged_kv.py): token at
     # absolute position p lands in physical block block_table[b, p//BS]
     # at offset p%BS — a scatter write. The layout is left-aligned
@@ -1433,13 +1550,28 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     from ..ops.paged_decode_attention import paged_attention
 
     cache, block_table, layer = step.cache, step.block_table, step.layer_index
+    pos = step.positions            # (B, S): ``forward`` takes no other
+    kn, vn, read = "k", "v", {}
+    if form is not None:
+        # the heads as ``_diff_pairs`` folds them: twice as wide, 1/sqrt of
+        # the model's own head size
+        read = {"scale": cfg.head_dim ** -0.5,
+                "name": "shared_kv_decode_attention"}
+        if form.window:
+            kn, vn = "wk", "wv"
+            block_table = _ring_table(cache, step.state_slots,
+                                      block_table.shape[1])
+            read.update(window=cfg.attention_window,
+                        name="window_decode_attention")
+        if form.cross:
+            return paged_attention(q, cache[kn], cache[vn], step.shared_layer,
+                                   block_table, pos, **read), cache
     B, S, K, D = k.shape
     q, k = _rope_qk(cfg, q, k, step.positions)
     _, alibi = _attention_fn(cfg, None)
-    BSz = cache["k"].shape[2]
-    pos = step.positions            # (B, S): ``forward`` takes no other
-    k_rows = k.reshape(B, S, K * D).astype(cache["k"].dtype)
-    v_rows = v.reshape(B, S, K * D).astype(cache["v"].dtype)
+    BSz = cache[kn].shape[2]
+    k_rows = k.reshape(B, S, K * D).astype(cache[kn].dtype)
+    v_rows = v.reshape(B, S, K * D).astype(cache[vn].dtype)
     if step.paged_run is not None and S >= BSz:
         # a RUN of a page or more (a prompt or scoring chunk): whole
         # pages, not rows. The arena's tiling on the chip packs two
@@ -1448,8 +1580,8 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
         # write into tiles the next row touches again: 256 of them cost
         # a chunk program a quarter of its time (PERF.md, PR 49)
         pages = _run_pages(block_table, BSz, S, *step.paged_run)
-        ck = _write_pages(cache["k"], layer, k_rows, *pages)
-        cv = _write_pages(cache["v"], layer, v_rows, *pages)
+        ck = _write_pages(cache[kn], layer, k_rows, *pages)
+        cv = _write_pages(cache[vn], layer, v_rows, *pages)
     else:
         # one token a row (decode) or fewer than a page (verify): rows
         T_view = block_table.shape[1] * BSz
@@ -1465,10 +1597,11 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
         # it updates the carry in place, only the written rows move. An
         # arena row is one token's K*D lanes
         # (ops/paged_decode_attention.py)
-        ck = cache["k"].at[layer, blk, off].set(k_rows)
-        cv = cache["v"].at[layer, blk, off].set(v_rows)
-    attn = paged_attention(q, ck, cv, layer, block_table, pos, alibi=alibi)
-    return attn, {**cache, "k": ck, "v": cv}
+        ck = cache[kn].at[layer, blk, off].set(k_rows)
+        cv = cache[vn].at[layer, blk, off].set(v_rows)
+    attn = paged_attention(q, ck, cv, layer, block_table, pos, alibi=alibi,
+                           **read)
+    return attn, {**cache, kn: ck, vn: cv}
 
 
 def _attend_dense_cache(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
@@ -1591,18 +1724,90 @@ def _attend_train(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     return attn, None
 
 
+def _diff_pairs(cfg: TransformerConfig, q: jax.Array,
+                k: Optional[jax.Array], v: Optional[jax.Array]):
+    """Differential attention's pairs as heads the paged kernels know: the
+    key heads ``2g`` and ``2g + 1`` side by side are ONE key head of twice
+    the size, and so are the value heads (the group's 2 D wide value); a
+    query head is laid in the half of its own key, ``[q, 0]`` for the first
+    of a pair and ``[0, q]`` for the second, so that its product with the
+    wide key is its product with its own (the zeros add exactly nothing)
+    and the group's four query heads share the wide head as GQA heads do.
+    The arena's rows are the bytes they were: K * D lanes a token."""
+    B, S, N, D = q.shape
+    zeros = jnp.zeros_like(q)
+    first = (jnp.arange(N) % 2 == 0)[:, None]
+    q = jnp.where(first, jnp.concatenate([q, zeros], axis=-1),
+                  jnp.concatenate([zeros, q], axis=-1))
+    if k is None:
+        return q, None, None
+    wide = k.shape[:2] + (k.shape[2] // 2, 2 * D)
+    return q, k.reshape(wide), v.reshape(wide)
+
+
+def _diff_combine(cfg: TransformerConfig, attn: jax.Array,
+                  p: Dict[str, Any]) -> jax.Array:
+    """``attn`` (B, S, N, 2 D), the two maps of each pair over the group's
+    wide value -> (B, S, N * D): ``RMSNorm(a1 - lam a2; g) * (1 - lam0)``,
+    ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0``, in float32."""
+    f32 = jnp.float32
+    B, S, N, W = attn.shape
+    lam0 = p["lam_init"].astype(f32)
+    lam = (jnp.exp(jnp.sum(p["lam_q1"].astype(f32) * p["lam_k1"].astype(f32)))
+           - jnp.exp(jnp.sum(p["lam_q2"].astype(f32)
+                             * p["lam_k2"].astype(f32))) + lam0)
+    pairs = attn.astype(f32).reshape(B, S, N // 2, 2, W)
+    o = pairs[:, :, :, 0] - lam * pairs[:, :, :, 1]
+    o = o * lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.norm_eps)
+    o = o * p["subln"].astype(f32) * (1.0 - lam0)
+    return o.reshape(B, S, N // 2 * W).astype(attn.dtype)
+
+
 def _softmax_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                    step: Step
                    ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """The "attn" mixer of ``_layer_forward``: softmax attention over the
+    """The "attn" mixer of ``_layer_forward`` (``_softmax`` in no form)."""
+    return _softmax(cfg, h, p, step, None)
+
+
+def _softmax(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+             step: Step, form: Optional[AttnForm]
+             ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """The softmax mixer of ``_layer_forward``: softmax attention over the
     normed input ``h`` -> (its contribution to the residual, new cache).
     The projections, one of three reads by what the step keeps (nothing,
-    the dense cache, pages), the output gate and projection."""
+    the dense cache, pages), the output gate and projection. With a
+    ``form`` (the kinds "swa", "full", "cross"): over the serving layer's
+    pages alone, a window's ring or another layer's pool, and as
+    differential attention where the model has it."""
     B, S, _ = h.shape
-    attend = (_attend_train if step.cache is None else
-              _attend_dense_cache if step.block_table is None else
-              _attend_paged)
-    attn, new_cache = attend(cfg, *_qkv_heads(cfg, h, p), step)
+    if form is not None:
+        if step.cache is None or step.block_table is None:
+            raise NotImplementedError(
+                "a window, full or cross layer of a stack of layer_runs runs "
+                "over the serving layer's paged cache: a cross layer reads "
+                "the full layer's pages and a window layer its row's ring, "
+                "which training and the dense cache do not keep")
+        if form.cross:
+            q = _qeinsum("bsh,hd->bsd", h, p["wq"], cfg.dtype,
+                         a8=cfg.a8_decode)
+            if "bq" in p:
+                q = q + p["bq"]
+            if S == 1:
+                q = lax.optimization_barrier(q)     # see ``_qkv_heads``
+            heads = (q.reshape(B, S, cfg.num_heads, cfg.head_dim), None, None)
+        else:
+            heads = _qkv_heads(cfg, h, p)
+        if cfg.diff_attn:
+            heads = _diff_pairs(cfg, *heads)
+        attn, new_cache = _attend_paged(cfg, *heads, step, form)
+        if cfg.diff_attn:
+            attn = _diff_combine(cfg, attn, p)
+    else:
+        attend = (_attend_train if step.cache is None else
+                  _attend_dense_cache if step.block_table is None else
+                  _attend_paged)
+        attn, new_cache = attend(cfg, *_qkv_heads(cfg, h, p), step)
     attn = attn.reshape(B, S, cfg.num_heads * cfg.head_dim)
     if "wg" in p:
         # the output gate: elementwise and full-rank, from the layer's input
@@ -1728,6 +1933,179 @@ def _mamba2_state(cfg: TransformerConfig):
     return (G, N, H // G * P), cfg.mamba_conv_taps, H * P + 2 * G * N
 
 
+# what the softmax mixer's subtree holds for its keys and values, which a
+# cross layer lacks: it reads another layer's
+_CROSS_LACKS = ("wk", "wv", "bk", "bv", "k_norm")
+
+
+def _form_init(cfg: TransformerConfig, normal, uniform,
+               form: AttnForm) -> Dict[str, Any]:
+    """The softmax mixer's subtree in one of its forms: the projections'
+    biases drawn (std 0.02); a cross layer has a query and an output
+    projection alone; differential attention adds four
+    vectors of a head's size (normal, std 0.1, as published) and the scale
+    of the pairs' norm."""
+    p = _attn_init(cfg, normal, uniform)
+    for tag, name in enumerate(("bq", "bk", "bv", "bo")):
+        if name in p:       # drawn: a zero bias would leave a term untested
+            p[name] = normal(54 + tag, p[name].shape)
+    if form.cross:
+        p = {k: v for k, v in p.items() if k not in _CROSS_LACKS}
+    if cfg.diff_attn:
+        D = cfg.head_dim
+        for tag, name in enumerate(("lam_q1", "lam_k1", "lam_q2", "lam_k2")):
+            p[name] = normal(50 + tag, (D,), 0.1).astype(jnp.float32)
+        p["subln"] = jnp.ones((2 * D,), cfg.dtype)
+    return p
+
+
+def _form_axes(cfg: TransformerConfig, form: AttnForm) -> Dict[str, Any]:
+    attn = _attn_axes(cfg)
+    if form.cross:
+        attn = {k: v for k, v in attn.items() if k not in _CROSS_LACKS}
+    if cfg.diff_attn:
+        attn.update({name: (LAYERS, None) for name in (
+            "lam_q1", "lam_k1", "lam_q2", "lam_k2", "subln")},
+            lam_init=(LAYERS,))
+    return attn
+
+
+def _mamba1_sizes(cfg: TransformerConfig) -> Tuple[int, int, int]:
+    """(inner width, state a channel, rank of the step's projection)."""
+    return (cfg.mamba_expand * cfg.hidden_size, cfg.mamba_state_size,
+            -(-cfg.hidden_size // 16))
+
+
+def _mamba1_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
+    H = cfg.hidden_size
+    I, N, R = _mamba1_sizes(cfg)
+    return {
+        "w_in": normal(60, (H, 2 * I)),         # [x | z]
+        # taps of the depthwise convolution over time, oldest first
+        "conv_w": normal(61, (cfg.mamba_conv_taps, I), 0.5),
+        "conv_b": normal(62, (I,)),
+        "w_x": normal(63, (I, R + 2 * N)),      # [step | B | C]
+        # the published init of the step's projection: uniform in
+        # +-rank^-0.5, and a step of 0.001 to 0.1 a channel (its inverse
+        # softplus, floored at 1e-4)
+        "w_dt": uniform(64, (R, I), -R ** -0.5, R ** -0.5).astype(cfg.dtype),
+        "dt_bias": (lambda dt: dt + jnp.log(-jnp.expm1(-dt)))(
+            jnp.maximum(jnp.exp(uniform(
+                65, (I,), jnp.log(1e-3), jnp.log(0.1))), 1e-4)),
+        # a rate of 1 to 16 a (state, channel), transposed as the state
+        # pool lies (``ops/mamba1.py``); the skip 1
+        "A_log": jnp.log(uniform(66, (N, I), 1.0, 16.0)),
+        "D": jnp.ones((I,), jnp.float32),
+        "w_out": normal(67, (I, H), _resid_std(cfg)),
+    }
+
+
+def _mamba1_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    return {"w_in": (LAYERS, EMBED, HEADS), "w_out": (LAYERS, HEADS, EMBED),
+            "conv_w": (LAYERS, None, HEADS), "conv_b": (LAYERS, HEADS),
+            "w_x": (LAYERS, HEADS, None), "w_dt": (LAYERS, None, HEADS),
+            "dt_bias": (LAYERS, HEADS), "A_log": (LAYERS, None, HEADS),
+            "D": (LAYERS, HEADS)}
+
+
+def _mamba1_state(cfg: TransformerConfig):
+    I, N, _ = _mamba1_sizes(cfg)
+    return (N, I), cfg.mamba_conv_taps, I
+
+
+def _mamba1_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+                  step: Step):
+    """The "mamba1" mixer of ``_layer_forward``: the selective scan
+    (``ops/mamba1.py``) over the normed input ``h`` (B, S, H) -> (its
+    contribution to the residual, new cache, the value it hands on).
+
+    ``[x | z] = h W_in``; on ``x`` a depthwise causal convolution over time
+    with a bias, then SiLU; ``[r | B | C] = x W_x``; ``dt = softplus(r W_dt +
+    dt_bias)`` a channel and ``A = -exp(A_log)`` a (state, channel); the
+    recurrence on a float32 state, plus ``D x``: that is ``m``, handed on
+    to the "gmu" layers of the same step BEFORE the gate; ``out = (m *
+    silu(z)) W_out``.
+
+    The cache, the layer's index, the rows' slots, a row's start at position
+    0 and ``real`` are ``_kda_mixer``'s, with ``"state"`` (layers of this
+    kind, slots, state, inner) and a tail of ``x``'s width; a token that
+    does not exist has ``dt`` 0 and writes nothing."""
+    from ..ops import mamba1 as ssm
+
+    f32 = jnp.float32
+    cache, real = step.cache, step.write_mask
+    at = (step.layer_index, step.state_slots)   # this layer's, each row's
+    B, S, _ = h.shape
+    I, N, R = _mamba1_sizes(cfg)
+    taps = cfg.mamba_conv_taps
+    x, z = jnp.split(jnp.einsum("bsh,hd->bsd", h, p["w_in"]), 2, axis=-1)
+    ext, fresh = _with_conv_history(x, step, taps)
+    conv = p["conv_w"].astype(f32)
+    x = jax.nn.silu(p["conv_b"].astype(f32) + sum(
+        conv[j] * ext[:, j:j + S].astype(f32) for j in range(taps))
+    ).astype(h.dtype)
+    r, Bm, Cm = jnp.split(jnp.einsum("bsd,dr->bsr", x, p["w_x"]),
+                          [R, R + N], axis=-1)
+    dt = jax.nn.softplus(jnp.einsum("bsr,rd->bsd", r, p["w_dt"]).astype(f32)
+                         + p["dt_bias"].astype(f32))
+    if real is not None:
+        dt = jnp.where(real[..., None], dt, 0.0)
+    A = -jnp.exp(p["A_log"].astype(f32))
+
+    new_cache = None
+    if cache is None:
+        y, _ = ssm.mamba1_recurrence(x, dt, A, Bm, Cm,
+                                     jnp.zeros((B, N, I), f32))
+    else:
+        kernels = _single_chip_kernels()
+        if S == 1:
+            advance = (ssm.mamba1_decode_step if kernels
+                       else ssm.reference_mamba1_decode_step)
+            y, states = advance(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                                cache["state"], *at)
+            y = y[:, None]
+        else:
+            start = jnp.where(fresh[:, None, None], 0.0, cache["state"][at])
+            scan = ssm.mamba1_chunk_scan if kernels else ssm.mamba1_recurrence
+            y, end = scan(x, dt, A, Bm, Cm, start)
+            states = cache["state"].at[at].set(
+                end.astype(cache["state"].dtype))
+        new_cache = {**cache, "state": states,
+                     "tail": cache["tail"].at[at].set(
+                         _last_real_rows(ext, real, taps - 1).astype(
+                             cache["tail"].dtype))}
+
+    y = y + p["D"].astype(f32) * x.astype(f32)
+    out = (y * jax.nn.silu(z.astype(f32))).astype(h.dtype)
+    return (jnp.einsum("bsd,dh->bsh", out, p["w_out"]), new_cache,
+            y.astype(h.dtype))
+
+
+def _gmu_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
+    H, I = cfg.hidden_size, _mamba1_sizes(cfg)[0]
+    return {"w_in": normal(70, (H, I)),
+            "w_out": normal(71, (I, H), _resid_std(cfg))}
+
+
+def _gmu_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    return {"w_in": (LAYERS, EMBED, HEADS), "w_out": (LAYERS, HEADS, EMBED)}
+
+
+def _gmu_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+               step: Step) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
+    """The "gmu" mixer of ``_layer_forward``, a gated memory unit: ``out =
+    (silu(h W_1) * m) W_2`` with ``m`` (``step.memory``) what the last
+    "mamba1" layer handed on for the same token. It keeps nothing."""
+    f32 = jnp.float32
+    if step.memory is None:
+        raise NotImplementedError(
+            "a gated memory unit reads the value a mamba1 layer of the same "
+            "step handed on: it runs in a stack of layer_runs")
+    gate = jnp.einsum("bsh,hd->bsd", h, p["w_in"]).astype(f32)
+    y = (jax.nn.silu(gate) * step.memory.astype(f32)).astype(h.dtype)
+    return jnp.einsum("bsd,dh->bsh", y, p["w_out"]), step.cache
+
+
 @dataclasses.dataclass(frozen=True)
 class Mixer:
     """What a mixer IS: every function that must tell one mixer from
@@ -1735,19 +2113,41 @@ class Mixer:
     name: str           # the key of its parameter subtree in a layer
     init: Callable      # (cfg, normal, uniform) -> that subtree, ONE layer's
     axes: Callable      # (cfg) -> the subtree's logical axes
-    apply: Callable     # (cfg, h, params, step) -> (out, new cache)
-    state: Optional[Callable] = None    # None: the mixer keeps pages; else
-    #   (cfg) -> (a slot's state shape, the convolution's taps, its width)
+    apply: Callable     # (cfg, h, params, step) -> (out, new cache), and a
+    #   third value where the mixer ``hands_on``
+    state: Optional[Callable] = None    # None: the mixer keeps no state;
+    #   else (cfg) -> (a slot's state shape, the convolution's taps, width)
     rows_count: Optional[str] = None    # the span count of states advanced
+    keeps: Optional[str] = None     # "pages": a pool of its own in the
+    #   arena's "k" and "v"; "ring": a window of pages a row in "wk" and
+    #   "wv"; None: no keys of its own (a state, or what another layer made)
+    hands_on: bool = False          # its third result rides the step's
+    #   carry as ``Step.memory``
+
+
+def _form(form: AttnForm, keeps: Optional[str]) -> Mixer:
+    """The softmax mixer's record in one of its forms."""
+    def apply(cfg, h, p, step):
+        return _softmax(cfg, h, p, step, form)
+
+    return Mixer("attn", partial(_form_init, form=form),
+                 partial(_form_axes, form=form), apply, keeps=keeps)
 
 
 # keyed by the mixer's name in ``LAYER_KINDS``; nothing reads it at import
 MIXERS: Dict[str, Mixer] = {
-    "attn": Mixer("attn", _attn_init, _attn_axes, _softmax_mixer),
+    "attn": Mixer("attn", _attn_init, _attn_axes, _softmax_mixer,
+                  keeps="pages"),
     "kda": Mixer("kda", _kda_init, _kda_axes, _kda_mixer, _kda_state,
                  "recurrent_rows"),
     "mamba2": Mixer("mamba2", _mamba2_init, _mamba2_axes, _mamba2_mixer,
                     _mamba2_state, "ssm_rows"),
+    "mamba1": Mixer("mamba1", _mamba1_init, _mamba1_axes, _mamba1_mixer,
+                    _mamba1_state, "ssm_rows", hands_on=True),
+    "swa": _form(AttnForm(window=True), "ring"),
+    "full": _form(AttnForm(), "pages"),
+    "cross": _form(AttnForm(cross=True), None),
+    "gmu": Mixer("gmu", _gmu_init, _gmu_axes, _gmu_mixer),
 }
 
 
@@ -1757,7 +2157,8 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     norm, mixer and its add -> ``(x, the FFN's normed input or None where no
     FFN follows, the mixer's output, new cache)``. ``x`` comes back with the
     mixer's output added but under ``parallel_residual``, which adds it
-    beside the FFN's."""
+    beside the FFN's. A mixer that ``hands_on`` sets ``step.memory`` for
+    the layers below: a fifth value, the memory as it now stands."""
     post_ln = cfg.norm_position == "post"
     if post_ln:
         h = x      # post-LN (BERT family): raw input feeds attention; the
@@ -1770,8 +2171,10 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         from ..compression.compress import fake_quant_activation
 
         h = fake_quant_activation(h, cfg.act_quant_bits)
-    attn_out, new_cache = MIXERS[mixer].apply(
+    attn_out, new_cache, *handed = MIXERS[mixer].apply(
         cfg, h, layer[MIXERS[mixer].name], step)
+    memory = [] if step.memory is None else [handed[0] if handed
+                                             else step.memory]
     if step.cache is None:
         from ..parallel.sequence import constrain, hidden_spec, sequence_parallel_enabled
 
@@ -1779,7 +2182,7 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         if sequence_parallel_enabled():
             attn_out = constrain(attn_out, hidden_spec())
     if not has_ffn:
-        return x + attn_out, None, attn_out, new_cache
+        return (x + attn_out, None, attn_out, new_cache, *memory)
     if cfg.parallel_residual:
         # GPT-J/NeoX: x + attn(ln1(x)) + mlp(ln2(x)) — one residual add,
         # the MLP reads the ORIGINAL x through its own norm
@@ -1794,7 +2197,7 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         x = x + attn_out
         h = _norm(x, layer["ln2"]["scale"], layer["ln2"].get("bias"),
                   cfg.norm, cfg.norm_eps)
-    return x, h, attn_out, new_cache
+    return (x, h, attn_out, new_cache, *memory)
 
 
 def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
@@ -1806,7 +2209,9 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
 
     Returns ``(x, new_cache, aux)``; with ``step.moe_counts`` (an MoE model)
     a fourth value, this layer's routing counts (``parallel/moe.moe_mlp``;
-    zeros for a layer that has no FFN)."""
+    zeros for a layer that has no FFN); with ``step.memory`` (a stack with
+    gated memory units) a last one, the memory as it stands below this
+    layer."""
     cache = step.cache
     mixer, has_ffn = LAYER_KINDS[kind]
     post_ln = cfg.norm_position == "post"
@@ -1815,16 +2220,18 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             "a layer that is a mixer alone or an FFN alone is x + f(norm(x)): "
             "it has no post-norm or parallel-residual form")
     new_cache = cache
+    memory = [] if step.memory is None else [step.memory]
     if mixer is not None:
-        x, h, attn_out, new_cache = _mixer_half(cfg, x, layer, mixer,
-                                                has_ffn, step)
+        x, h, attn_out, new_cache, *memory = _mixer_half(
+            cfg, x, layer, mixer, has_ffn, step)
     else:
         attn_out = None
         h = _norm(x, layer["ln2"]["scale"], layer["ln2"].get("bias"),
                   cfg.norm, cfg.norm_eps)
     if not has_ffn:
         return (x, new_cache, jnp.float32(0.0), *(
-            [jnp.zeros((3,), jnp.int32)] if step.moe_counts else []))
+            [jnp.zeros((3,), jnp.int32)] if step.moe_counts else []),
+            *memory)
     if cfg.act_quant_bits and cache is None:
         from ..compression.compress import fake_quant_activation
 
@@ -1897,7 +2304,7 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                   layer["ln2"].get("bias"), cfg.norm, cfg.norm_eps)
     else:
         x = x + mlp_out
-    return (x, new_cache, aux, *counts)
+    return (x, new_cache, aux, *counts, *memory)
 
 
 def forward(params: Dict[str, Any], input_ids: jax.Array,
@@ -1913,7 +2320,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             paged_write_mask: Optional[jax.Array] = None,
             moe_counts: bool = False,
             state_slots: Optional[jax.Array] = None,
-            paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
+            paged_run: Optional[Tuple[jax.Array, jax.Array]] = None,
+            last_token: Optional[jax.Array] = None
             ) -> Tuple[jax.Array, Optional[Dict[str, Any]], jax.Array]:
     """Token ids (B,S) → (logits (B,S,V), new_cache, moe_aux_loss). With
     ``cache``, runs in decode mode (cache is a per-layer stacked pytree; see
@@ -1946,7 +2354,16 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     the "attn" layers alone, ``(layers of that kind, ...)``, and for "kda"
     layers the pools ``"state"`` and ``"tail"`` (``_kda_mixer``), with
     ``state_slots`` (B,) naming each row's slot; the dense cache of
-    ``inference/engine.py`` has no such entry and refuses such a model."""
+    ``inference/engine.py`` has no such entry and refuses such a model.
+
+    **A stack of runs** (``cfg.layer_runs``; paged mode only): each run of
+    equal periods is one scan (``_run_layers``), and the value a "mamba1"
+    layer hands on rides the carry to the "gmu" layers. ``last_token`` (B,)
+    int32, a prompt chunk's word: the stack's last runs that keep nothing of
+    a token (``tail_runs``: the cross-decoder) run for token ``last_token[b]``
+    of each row alone, and the logits are (B, 1, V), that token's; where
+    every entry is below 0 (a chunk that is not its prompt's last) neither
+    they nor the head run, and the logits are zeros."""
     B, S = input_ids.shape
     if block_table is not None:
         for operand in ("attention_layers", "attention_scale",
@@ -2009,6 +2426,23 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                       else tuple(range(L - 1, L)))
         ltd_flags = jnp.array([1.0 if i in ltd_layers else 0.0
                                for i in range(L)], jnp.float32)
+
+    if cfg.layer_runs:
+        if block_table is None or use_pld or use_ltd or use_win \
+                or moe_counts:
+            raise NotImplementedError(
+                "a stack of layer_runs runs over the serving layer's paged "
+                "cache alone: its window layers keep a ring of pages a row "
+                "and its cross layers read another layer's pages, which "
+                "training and the dense cache (inference/engine.py) do not "
+                "keep; progressive layer drop, random-LTD, per-layer "
+                "windows and expert counts index a stack of one period")
+        logits, new_cache = _run_layers(
+            cfg, params, x, Step(
+                mask=attention_mask, positions=positions, cache=dict(cache),
+                block_table=block_table, write_mask=paged_write_mask,
+                state_slots=state_slots, paged_run=paged_run), last_token)
+        return logits, new_cache, jnp.float32(0.0)
 
     # a period of the layer pattern is one step of the scan: each kind's
     # stacked tree goes in sliced by the period (several layers of a kind in
@@ -2173,6 +2607,95 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     if moe_counts:
         return logits, new_cache, aux_total, moe_totals[0]
     return logits, new_cache, aux_total
+
+
+def _run_layers(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array,
+                step: Step, last_token: Optional[jax.Array]
+                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The layers of a stack of runs (``cfg.layer_runs``) over the paged
+    cache, and the head -> ``(logits, new cache)``. Each run of equal
+    periods is ONE ``lax.scan`` over its periods (a run of one period is run
+    as it stands), so the program holds a period of each run, not the depth.
+    The carry is the activations, the arena and the state pools, as in
+    ``forward``'s paged scan, and the value the last "mamba1" layer handed
+    on. A kind's stacked tree stays whole outside the scans and a layer is
+    taken out of it by its place among its kind, where the run stands plus
+    where the period stands in it: a slice of the stack as a scan operand
+    would be a copy of the run's weights a step.
+
+    ``last_token`` (B,): before the stack's ``tail_runs`` the carry is
+    narrowed to that token of each row (activations, memory, positions and
+    mask), and the rest of the stack and the head run at a width of one,
+    under ONE ``lax.cond``: where no row names a token (all below 0) they do
+    not run, and the logits are zeros."""
+    stacks = layer_stacks(params["layers"], cfg)
+    B, S, _ = x.shape
+    has_memory = any(MIXERS[LAYER_KINDS[k][0]].hands_on
+                     for k in layer_kinds(cfg))
+    memory = (jnp.zeros((B, S, _mamba1_sizes(cfg)[0]), x.dtype)
+              if has_memory else None)
+    runs = cfg.layer_runs
+    above = [dict.fromkeys(stacks, 0)]      # layers of each kind above a run
+    for kinds, periods in runs:
+        above.append({kind: n + kinds.count(kind) * periods
+                      for kind, n in above[-1].items()})
+
+    def run(r, carry, step):
+        kinds, periods = runs[r]
+        per = {kind: kinds.count(kind) for kind in kinds}
+        base = above[r]
+        pages_above = sum(n for kind, n in base.items()
+                          if MIXERS[LAYER_KINDS[kind][0]].keeps == "pages")
+
+        def period(carry, pidx):
+            h, arena, memory = carry
+            done = dict.fromkeys(per, 0)
+            for kind in kinds:
+                kidx = base[kind] + pidx * per[kind] + done[kind]
+                done[kind] += 1
+                layer = jax.tree.map(lambda a: a[kidx], stacks[kind])
+                h, arena, _, *memory = _layer_forward(
+                    cfg, h, layer, dataclasses.replace(
+                        step, cache=arena, layer_index=kidx,
+                        memory=memory, shared_layer=pages_above - 1),
+                    kind=kind)
+                memory = memory[0] if memory else None
+            return (h, arena, memory), None
+
+        if periods == 1:
+            return period(carry, 0)[0]
+        return lax.scan(period, carry,
+                        jnp.arange(periods, dtype=jnp.int32))[0]
+
+    tail_from = len(runs) - (tail_runs(cfg) if last_token is not None else 0)
+    carry = (x, step.cache, memory)
+    for r in range(tail_from):
+        carry = run(r, carry, step)
+    h, arena, memory = carry
+    if tail_from == len(runs):
+        return head_logits(params, h, cfg), arena
+
+    def last(a):
+        return jnp.take_along_axis(
+            a, jnp.maximum(last_token, 0).reshape(
+                (B, 1) + (1,) * (a.ndim - 2)), axis=1)
+
+    narrow = dataclasses.replace(
+        step, positions=last(step.positions), paged_run=None,
+        write_mask=(None if step.write_mask is None
+                    else last(step.write_mask)))
+
+    def tail(h, memory):        # these runs keep nothing: the arena is read
+        carry = (h, arena, memory)
+        for r in range(tail_from, len(runs)):
+            carry = run(r, carry, narrow)
+        return head_logits(params, carry[0], cfg)
+
+    operands = (last(h), None if memory is None else last(memory))
+    logits = jax.eval_shape(tail, *operands)
+    return lax.cond(jnp.any(last_token >= 0), tail,
+                    lambda h, memory: jnp.zeros(logits.shape, logits.dtype),
+                    *operands), arena
 
 
 def head_logits(params: Dict[str, Any], x: jax.Array,

@@ -12,6 +12,8 @@ from .moe_grouped_matmul import (moe_grouped_matmul,
                                  reference_grouped_matmul)
 from .kda import (kda_chunk, kda_decode_step, kda_recurrence,
                   reference_kda_decode_step)
+from .mamba1 import (mamba1_chunk_scan, mamba1_decode_step,
+                     mamba1_recurrence, reference_mamba1_decode_step)
 from .mamba2 import (mamba2_chunk, mamba2_decode_step, mamba2_recurrence,
                      reference_mamba2_decode_step)
 from .fused_adam import fused_adam_flat, reference_adam_flat
@@ -73,6 +75,14 @@ register_op("mamba2_decode_step", mamba2_decode_step,
             reference=reference_mamba2_decode_step,
             description="one token of the Mamba-2 state-space recurrence a "
                         "decode row, the state pool updated in place")
+register_op("mamba1_decode_step", mamba1_decode_step,
+            reference=reference_mamba1_decode_step,
+            description="one token of the Mamba-1 selective scan a decode "
+                        "row, the state pool updated in place")
+register_op("mamba1_chunk_scan", mamba1_chunk_scan,
+            reference=mamba1_recurrence,
+            description="a chunk of tokens of the Mamba-1 selective scan, "
+                        "a slab of channels a grid step")
 register_op("int4_a8_matmul", int4_a8_matmul,
             reference=reference_int4_a8_matmul,
             description="W4A8 GEMM (s8 unpack + s8xs8 MXU)")
@@ -111,6 +121,8 @@ __all__ = [
     "reference_kda_decode_step",
     "mamba2_chunk", "mamba2_decode_step", "mamba2_recurrence",
     "reference_mamba2_decode_step",
+    "mamba1_chunk_scan", "mamba1_decode_step", "mamba1_recurrence",
+    "reference_mamba1_decode_step",
     "flash_attention", "make_attention_impl", "fused_adam_flat",
     "reference_adam_flat", "fused_lamb_flat", "reference_lamb_flat",
     "fused_layer_norm", "reference_layer_norm",

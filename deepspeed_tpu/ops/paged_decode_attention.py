@@ -242,7 +242,7 @@ def _tile_arrives(each_copy, vbuf, row, rows, t, n_tiles, base, length):
 def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
                    o_ref, kbuf, vbuf, sems, lane, diag, qbd, acc, m_scr,
                    l_scr, slot_ref, *, scale: float, n_heads: int,
-                   kv_heads: int, has_alibi: bool):
+                   kv_heads: int, has_alibi: bool, lo_ref=None):
     r = pl.program_id(0)
     R = pl.num_programs(0)
     _, P, BS, W = kbuf.shape
@@ -294,7 +294,11 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
             if has_alibi:
                 # left-aligned layout: the tile's column IS the key position
                 s = s + alibi_ref[0][:, None] * col.astype(jnp.float32)
-            s = jnp.where(col < length, s, NEG_INF)
+            live = col < length
+            if lo_ref is not None:
+                # a window: the keys below its first are out of sight
+                live = live & (col >= lo_ref[r])
+            s = jnp.where(live, s, NEG_INF)
             m_prev = m_scr[:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -319,13 +323,20 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
                            block_table: jax.Array, lengths: jax.Array,
                            alibi: Optional[jax.Array] = None,
                            scale: Optional[float] = None,
-                           interpret: bool = False) -> jax.Array:
+                           interpret: bool = False,
+                           lo: Optional[jax.Array] = None,
+                           name: str = "paged_decode_attention"
+                           ) -> jax.Array:
     """q (R, N, D) — one new token per row; k/v_arena (L, NUM_BLOCKS, BLOCK,
     K*D) — the whole shared arena; layer — int32 scalar (may be traced),
     the layer whose pool is read; block_table (R, MAXB) int32 physical page
     ids (unfilled entries 0 = scratch); lengths (R,) int32 — valid keys per
     row INCLUDING the just-written token (0 ⇒ inactive row, output zeros).
-    Returns (R, N, D). Reads only each row's resident pages of that layer."""
+    Returns (R, N, D). Reads only each row's resident pages of that layer.
+    ``lo`` (R,) int32, a window's form: row r sees the keys ``lo[r] <= key <
+    lengths[r]`` of the pages its table names, which the caller starts at
+    the window's first page (``paged_attention``); ``name`` is the kernel's
+    in a trace."""
     R, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
     BS, W = k_arena.shape[2:]
@@ -337,8 +348,9 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
                  else jnp.zeros((1, N), jnp.float32))
     # the products take q and the keys in the wider of their two dtypes
     pd = jnp.promote_types(q.dtype, k_arena.dtype)
+    windowed = lo is not None
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + windowed,
         grid=(R,),
         in_specs=[
             pl.BlockSpec((1, N, D), lambda r, *_: (r, 0, 0)),
@@ -363,6 +375,13 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
     )
     kernel = functools.partial(_decode_kernel, scale=scale, n_heads=N,
                                kv_heads=K, has_alibi=has_alibi)
+    scalars = (block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+               _layer_operand(layer))
+    if windowed:
+        def kernel(bt_ref, len_ref, layer_ref, lo_ref, *refs, _body=kernel):
+            _body(bt_ref, len_ref, layer_ref, *refs, lo_ref=lo_ref)
+
+        scalars += (lo.astype(jnp.int32),)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -370,10 +389,9 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
         # rows in order: a row starts the copies of the next one's first tile
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name="paged_decode_attention",
+        name=name,
         interpret=interpret,
-    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      _layer_operand(layer), q, k_arena, v_arena, alibi_arr)
+    )(*scalars, q, k_arena, v_arena, alibi_arr)
 
 
 # ---------------------------------------------------------------------------
@@ -395,7 +413,8 @@ def _heads_per_group(kv_heads: int, head_dim: int) -> int:
 def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
                     v_hbm, alibi_ref, o_ref, kbuf, vbuf, sems, acc, m_scr,
                     l_scr, slot_ref, *, scale: float, n_heads: int,
-                    kv_heads: int, has_alibi: bool):
+                    kv_heads: int, has_alibi: bool,
+                    window: Optional[int] = None):
     b = pl.program_id(0)
     B = pl.num_programs(0)
     _, P, BS, W = kbuf.shape
@@ -411,6 +430,9 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
     n_tiles = pl.cdiv(length, TK)
     # tiles wholly at or below ``start`` are visible to every query
     n_open = jnp.minimum((start + 1) // TK, n_tiles)
+    if window is not None:
+        # under a window no tile is open to every query: each masks its own
+        n_open = 0
     each_copy = _page_copies(bt_ref, len_ref, layer_ref[0], k_hbm, v_hbm,
                              kbuf, vbuf, sems)
 
@@ -461,6 +483,8 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
                         start + jax.lax.broadcasted_iota(jnp.int32, (C, 1),
                                                          0), length - 1)
                     keep = col <= qpos                          # (C, TK)
+                    if window is not None:
+                        keep = keep & (col > qpos - window)
                 k = kbuf[slot, :, :, lanes(g, HP * D)].reshape(TK, HP * D)
                 v = vbuf[slot, :, :, lanes(g, HP * D)].reshape(TK, HP * D)
                 q = q_ref[0, :, lanes(g, G * HP * D)]
@@ -519,7 +543,8 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
                             lengths: Optional[jax.Array] = None,
                             alibi: Optional[jax.Array] = None,
                             scale: Optional[float] = None,
-                            interpret: bool = False) -> jax.Array:
+                            interpret: bool = False,
+                            window: Optional[int] = None) -> jax.Array:
     """Chunked-prefill attention through the block table: q (B, C, N, D) —
     C contiguous queries per row at absolute positions ``start[b] + s``
     (the serving ``prefill_chunk`` contract; the chunk's own keys must
@@ -535,7 +560,9 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
     heads (``_heads_per_group``) at a time. The products take q, k and v as
     they are stored and sum in float32; ``p`` is rounded to the values'
     dtype for the value product. Queries past a row's real tokens come out
-    finite and mean nothing."""
+    finite and mean nothing. ``window`` (static): a query sees its own key
+    and the ``window - 1`` before it, of the pages the table names, which
+    the caller starts at the window's first page (``paged_attention``)."""
     B, C, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
     BS, W = k_arena.shape[2:]
@@ -582,7 +609,9 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
         ],
     )
     kernel = functools.partial(_prefill_kernel, scale=scale, n_heads=N,
-                               kv_heads=K, has_alibi=has_alibi)
+                               kv_heads=K, has_alibi=has_alibi,
+                               **({} if window is None
+                                  else {"window": int(window)}))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -608,14 +637,18 @@ def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
                               v_arena: jax.Array, layer,
                               block_table: jax.Array, positions: jax.Array,
                               alibi: Optional[jax.Array] = None,
-                              scale: Optional[float] = None) -> jax.Array:
+                              scale: Optional[float] = None,
+                              window: Optional[int] = None) -> jax.Array:
     """GQA-native jnp paged attention — parity oracle for both kernels and
     the CPU serving fallback. q (B, S, N, D); positions (B, S) absolute
     query positions (decode: the row's length-1; negative ⇒ row inactive,
     output zeros); arenas (L, NUM_BLOCKS, BLOCK, K*D) and the ``layer`` to
     read, gathered as ``arena[layer, block_table]`` — no pool-sized
     intermediate; mask is causality over true positions (left-aligned
-    layout: gathered column == position)."""
+    layout: gathered column == position), and under a ``window`` the
+    query's own key and the ``window - 1`` before it (a table that is a ring
+    of pages repeats them past the ring's length: every column in sight
+    holds the position it stands for)."""
     B, S, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
     BS = k_arena.shape[2]
@@ -640,6 +673,8 @@ def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
         al = alibi.astype(jnp.float32).reshape(K, G)
         s = s + al[None, :, :, None, None] * col.astype(jnp.float32)
     keep = col[None, None, :] <= positions[:, :, None]          # (B, S, T)
+    if window is not None:
+        keep = keep & (col[None, None, :] > positions[:, :, None] - window)
     s = jnp.where(keep[:, None, None, :, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     o = jnp.einsum("bkgst,btkd->bskgd", p, vv)
@@ -658,7 +693,10 @@ def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
 
 def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
                     layer, block_table: jax.Array, positions: jax.Array,
-                    alibi: Optional[jax.Array] = None) -> jax.Array:
+                    alibi: Optional[jax.Array] = None,
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None,
+                    name: Optional[str] = None) -> jax.Array:
     """The model's paged read, after its scatter: q (B, S, N, D) at absolute
     ``positions`` (B, S) against ``arena[layer]`` through ``block_table``;
     returns (B, S, N, D). Where the Pallas kernels run (``ops/registry``'s
@@ -667,15 +705,44 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
     the largest position + 1 as its length: the serving programs' contract
     that S > 1 queries sit at ``start + 0..S-1`` (slots past a row's real
     tokens ride position -1 and are never read; a row of them all holds
-    nothing). Anywhere else: ``reference_paged_attention``."""
+    nothing). Anywhere else: ``reference_paged_attention``.
+
+    ``window`` (static): a query sees its own key and the ``window - 1``
+    before it. The kernels' walks then START at the window's first page:
+    they are handed the table from that page on, as many pages as a window
+    and S queries can span, and positions counted from that page's first
+    key, so that the pages below it cost no copy and no step, whatever the
+    row's length; the keys of that first page that lie below the window are
+    masked (``lo``, ``window``). ``name``: the decode walk's in a trace."""
     if not registry.kernels_active():
         return reference_paged_attention(q, k_arena, v_arena, layer,
-                                         block_table, positions, alibi=alibi)
-    if q.shape[1] == 1:
-        return paged_decode_attention(q[:, 0], k_arena, v_arena, layer,
-                                      block_table, positions[:, 0] + 1,
-                                      alibi=alibi)[:, None]
+                                         block_table, positions, alibi=alibi,
+                                         scale=scale, window=window)
+    named = {} if name is None else {"name": name}
+    if window is None:
+        if q.shape[1] == 1:
+            return paged_decode_attention(q[:, 0], k_arena, v_arena, layer,
+                                          block_table, positions[:, 0] + 1,
+                                          alibi=alibi, scale=scale,
+                                          **named)[:, None]
+        return paged_prefill_attention(q, k_arena, v_arena, layer,
+                                       block_table, positions[:, 0],
+                                       jnp.max(positions, axis=1) + 1,
+                                       alibi=alibi, scale=scale)
+    S, BS = q.shape[1], k_arena.shape[2]
+    start = positions[:, 0]
+    below = jnp.maximum(start - (window - 1), 0)        # the window's first
+    first = below // BS
+    pages = (window + S - 2) // BS + 2
+    block_table = jnp.take_along_axis(
+        block_table, jnp.minimum(
+            first[:, None] + jnp.arange(pages, dtype=jnp.int32),
+            block_table.shape[1] - 1), axis=1)
+    ends = jnp.maximum(jnp.max(positions, axis=1) + 1 - first * BS, 0)
+    if S == 1:
+        return paged_decode_attention(
+            q[:, 0], k_arena, v_arena, layer, block_table, ends, alibi=alibi,
+            scale=scale, lo=below - first * BS, **named)[:, None]
     return paged_prefill_attention(q, k_arena, v_arena, layer, block_table,
-                                   positions[:, 0],
-                                   jnp.max(positions, axis=1) + 1,
-                                   alibi=alibi)
+                                   start - first * BS, ends, alibi=alibi,
+                                   scale=scale, window=window)

@@ -164,11 +164,25 @@ class ServingEngine:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
-        from ..models.transformer import (MIXERS, ffn_layers,
-                                          recurrent_layers)
+        from ..inference.kv_cache import ring_blocks
+        from ..models.transformer import (MIXERS, ffn_layers, pool_readers,
+                                          recurrent_layers, ring_layers,
+                                          tail_runs)
 
         mixer, layers = recurrent_layers(cfg)
         self._recurrent_layers = len(layers)
+        # window layers keep their keys in a ring of pages a row, beside the
+        # row's state slot and bounded whatever the row's length; the layers
+        # that read the pages (their own, or the full layer's) are the rest
+        # of the softmax layers
+        self._window_layers = len(ring_layers(cfg))
+        self._ring_blocks = ring_blocks(cfg, self.config.prefill_chunk,
+                                        self.config.block_size)
+        self._page_readers = len(pool_readers(cfg))
+        # the stack's last runs keep nothing of a token: the chunk program
+        # runs them, and the head, for a prompt's LAST chunk alone and is
+        # told which chunk that is (``paged_kv.pack_chunk``'s ``last``)
+        self._chunk_says_last = tail_runs(cfg) > 0
         # the span count of the (row, layer) states a step advanced
         self._recurrent_rows = mixer and MIXERS[mixer].rows_count
         self.state_slots = (self.config.max_seqs + 1
@@ -194,7 +208,8 @@ class ServingEngine:
         with mesh_mod.ambient(engine.mesh):
             self._arena = paged_kv.init_paged_cache(
                 cfg, self.config.pool_blocks() + 1, self.config.block_size,
-                self._dtype, state_slots=self.state_slots)
+                self._dtype, state_slots=self.state_slots,
+                ring_blocks=self._ring_blocks)
         # an MoE model's two programs return their routing counts behind
         # the tokens (_program_counts); 0 = a dense model, whose programs and
         # spans know nothing of it. ``total`` counts the ROUTER's outputs a
@@ -785,9 +800,9 @@ class ServingEngine:
         A model with recurrent layers scores a sequence's last
         ``_SCORE_STEP_TAIL`` tokens ONE at a time (the same program traced
         at a width of one): the model's one-token forms, which its decode
-        program runs (``kda_decode_step`` or ``mamba2_decode_step`` on the state
-        pools in place, the
-        paged decode kernel), carry on from the state and the pages that
+        program runs (``kda_decode_step``, ``mamba2_decode_step`` or
+        ``mamba1_decode_step`` on the state pools in place, the paged decode
+        kernel), carry on from the state and the pages that
         the chunks left. So a comparison of these log-probabilities with a
         reference covers the chunk form, the step form and the hand-over
         of one state between them."""
@@ -1041,9 +1056,20 @@ class ServingEngine:
         not one). Nothing for any other model."""
         if not self._recurrent_layers:
             return {}
-        return {"state_slots_in_use": sum(
-                    1 for r in self.sched.running.values() if r.length > 0),
-                "state_slots_total": self.config.max_seqs}
+        live = [r.length for r in self.sched.running.values()
+                if r.length > 0]
+        counts = {"state_slots_in_use": len(live),
+                  "state_slots_total": self.config.max_seqs}
+        if self._window_layers:
+            # the keys a window layer holds for the live rows (a ring's
+            # worth at most), over a window's worth for each of them
+            ring = self._ring_blocks * self.config.block_size
+            window = self.engine.model.config.attention_window
+            counts.update(
+                window_resident_tokens=self._window_layers * sum(
+                    min(n, ring) for n in live),
+                window_tokens_bound=self._window_layers * window * len(live))
+        return counts
 
     def _table_for(self, reqs: List[Request]) -> np.ndarray:
         """(len(reqs), MAXB) block table; unfilled entries → scratch 0."""
@@ -1199,6 +1225,12 @@ class ServingEngine:
         if span.recording and self._recurrent_layers:
             span.annotate(**{self._recurrent_rows:
                              real_rows * self._recurrent_layers})
+            if self._window_layers:
+                # (row, layer) reads of a ring, and of the pool the full
+                # and cross layers share
+                span.annotate(
+                    window_rows=real_rows * self._window_layers,
+                    shared_kv_reads=real_rows * self._page_readers)
         if not self._moe_experts_total:
             return fetched
         if span.recording:
@@ -1234,7 +1266,9 @@ class ServingEngine:
                     self._table_for([req]), chunk, start, n_valid,
                     *self._sampling_arrays([req]),
                     state_slot=([req.row] if self._recurrent_layers
-                                else None))
+                                else None),
+                    **({"last": [start + n_valid == int(src.size)]}
+                       if self._chunk_says_last else {}))
             tok, t0, t1 = self._run_program(
                 obs, "serving/prefill_chunk", self._prefill, packed,
                 self._base_rng, trace=req.trace)
@@ -2074,7 +2108,8 @@ class ServingEngine:
                 self._arena_sds(),
                 jax.ShapeDtypeStruct(
                     paged_kv.chunk_shape(MAXB, C,
-                                         bool(self._recurrent_layers)),
+                                         bool(self._recurrent_layers),
+                                         self._chunk_says_last),
                     jnp.int32),
                 jax.ShapeDtypeStruct((2,), jnp.uint32))
 
@@ -2084,7 +2119,7 @@ class ServingEngine:
         return paged_cache_shape_struct(
             self.engine.model.config, self.config.pool_blocks() + 1,
             self.config.block_size, self._dtype,
-            state_slots=self.state_slots)
+            state_slots=self.state_slots, ring_blocks=self._ring_blocks)
 
     def _register_audit_entries(self) -> List[str]:
         try:

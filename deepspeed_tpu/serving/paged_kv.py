@@ -431,12 +431,16 @@ def _verify_columns(max_blocks: int, num_tokens: int):
             ("steps", 1))
 
 
-def _chunk_columns(max_blocks: int, chunk: int, state_slot: bool):
+def _chunk_columns(max_blocks: int, chunk: int, state_slot: bool,
+                   last: bool = False):
     """The prefill-chunk program's flat ``(MAXB + C + 6,)`` vector, one
-    entry longer for a model with recurrent layers (``state_slot``)."""
+    entry longer for a model with recurrent layers (``state_slot``) and one
+    for a model whose stack ends in runs that only a prompt's LAST chunk
+    needs (``last``: ``models/transformer.tail_runs``)."""
     return (("block_table", max_blocks), ("chunk", chunk), ("start", 1),
             ("n_valid", 1), *_SAMPLING_COLUMNS,
-            *([("state_slot", 1)] if state_slot else []))
+            *([("state_slot", 1)] if state_slot else []),
+            *([("last", 1)] if last else []))
 
 
 def _width(columns) -> int:
@@ -453,9 +457,11 @@ def verify_rows_shape(rows: int, max_blocks: int, num_tokens: int):
     return rows, _width(_verify_columns(max_blocks, num_tokens))
 
 
-def chunk_shape(max_blocks: int, chunk_tokens: int, state_slot: bool):
+def chunk_shape(max_blocks: int, chunk_tokens: int, state_slot: bool,
+                last: bool = False):
     """The shape of ``pack_chunk``'s vector."""
-    return (_width(_chunk_columns(max_blocks, chunk_tokens, state_slot)),)
+    return (_width(_chunk_columns(max_blocks, chunk_tokens, state_slot,
+                                  last)),)
 
 
 def _pack(columns, rows: int, operands) -> np.ndarray:
@@ -528,29 +534,33 @@ def unpack_verify_rows(packed: jax.Array, num_tokens: int):
 
 
 def pack_chunk(block_table, chunk, start, n_valid, temperature, top_k,
-               top_p, seeds, state_slot=None) -> np.ndarray:
+               top_p, seeds, state_slot=None, last=None) -> np.ndarray:
     """The prefill-chunk program's operands (``block_table`` (1, MAXB),
     ``chunk`` (1, C), ``start`` / ``n_valid`` (), the four sampling values
-    (1,) and, for a model with recurrent layers, ``state_slot`` (1,)) as
-    its one flat int32 host array."""
+    (1,), for a model with recurrent layers ``state_slot`` (1,) and, for
+    one whose stack ends in ``tail_runs``, ``last`` (1,): 1 where the chunk
+    is its prompt's last) as its one flat int32 host array."""
     operands = [block_table, chunk, start, n_valid, temperature, top_k,
                 top_p, seeds]
-    if state_slot is not None:
-        operands.append(state_slot)
+    operands += [extra for extra in (state_slot, last) if extra is not None]
     return _pack(_chunk_columns(np.shape(block_table)[1], np.shape(chunk)[1],
-                                state_slot is not None), 1, operands)[0]
+                                state_slot is not None, last is not None),
+                 1, operands)[0]
 
 
-def unpack_chunk(packed: jax.Array, chunk_tokens: int, state_slot: bool):
+def unpack_chunk(packed: jax.Array, chunk_tokens: int, state_slot: bool,
+                 last: bool = False):
     """``pack_chunk``'s inverse, in the program: its arguments in its
-    order, ``state_slot`` None where the layout holds none."""
+    order, ``state_slot`` None where the layout holds none, and ``last``
+    (1,) behind it only where the layout holds one."""
     max_blocks = packed.shape[0] - _width(
-        _chunk_columns(0, chunk_tokens, state_slot))
+        _chunk_columns(0, chunk_tokens, state_slot, last))
     table, chunk, start, n_valid, *rest = _unpack(
-        _chunk_columns(max_blocks, chunk_tokens, state_slot), packed[None])
+        _chunk_columns(max_blocks, chunk_tokens, state_slot, last),
+        packed[None])
     rest = [column[0] for column in rest]
     if not state_slot:
-        rest.append(None)
+        rest.insert(len(_SAMPLING_COLUMNS), None)
     return (table, chunk, start[0, 0], n_valid[0, 0], *rest)
 
 
@@ -592,6 +602,11 @@ def build_prefill_program(cfg, chunk_tokens: int, moe_counts: bool = False):
       state_slot (1,) int32  — a model with recurrent layers only: the
                                request's slot in the state pools, which ride
                                in ``cache`` beside the pages (its decode row)
+      last (1,) int32        — a model whose stack ends in ``tail_runs``
+                               only: 1 where the chunk is its prompt's last.
+                               Any other chunk runs the stack up to those
+                               runs and neither them nor the head: its token
+                               is 0 and its logits zeros
 
     Returns (token (1,), last_logits (1, V) f32, cache): ``token`` samples
     the position-``n_valid-1`` logits at output-token index 0 — the
@@ -600,12 +615,16 @@ def build_prefill_program(cfg, chunk_tokens: int, moe_counts: bool = False):
     programs) ``token`` is (4,): the token, then the chunk's routing counts
     over its ``n_valid`` real tokens (``_with_moe_counts``).
     """
+    from ..models.transformer import tail_runs
+
     step = _chunk_step(cfg, moe_counts)
+    has_last = tail_runs(cfg) > 0
 
     def prefill_chunk(params, cache, packed, base_key):
-        return step(params, cache,
-                    *unpack_chunk(packed, chunk_tokens, "state" in cache),
-                    base_key)
+        operands = list(unpack_chunk(packed, chunk_tokens, "state" in cache,
+                                     has_last))
+        last = operands.pop() if has_last else None
+        return step(params, cache, *operands, base_key, last)
 
     return jax.jit(prefill_chunk, donate_argnums=(1,))
 
@@ -615,9 +634,17 @@ def _chunk_step(cfg, moe_counts: bool = False):
     ``build_prefill_program`` names, each an argument (``state_slot`` None
     for a model with no recurrent layers)."""
     from ..models.transformer import forward as model_forward
+    from ..models.transformer import tail_runs
+
+    # a stack whose last runs keep nothing of a token (a cross-decoder)
+    # runs them for the chunk's last real token alone, the one whose logits
+    # are read, and only where the chunk is its prompt's last (``last``; a
+    # caller that hands none gets them for every chunk)
+    last_only = tail_runs(cfg) > 0
 
     def chunk_step(params, cache, block_table, chunk, start, n_valid,
-                   temperature, top_k, top_p, seeds, state_slot, base_key):
+                   temperature, top_k, top_p, seeds, state_slot, base_key,
+                   last=None):
         C = chunk.shape[1]
         offs = jnp.arange(C, dtype=jnp.int32)
         write_mask = (offs < n_valid)[None]
@@ -626,19 +653,29 @@ def _chunk_step(cfg, moe_counts: bool = False):
         # path's residency window onto scratch/recycled pages, whose
         # nonfinite residue must never touch live rows
         pos = jnp.where(write_mask, (start + offs)[None], -1)
+        tail = {}
+        if last_only:
+            # the token whose logits are read, or -1: none of this chunk's
+            wanted = jnp.maximum(n_valid - 1, 0)
+            if last is not None:
+                wanted = jnp.where(last[0] > 0, wanted, -1)
+            tail["last_token"] = wanted[None]
         logits, cache, _, *counts = model_forward(
             params, chunk, cfg, cache=cache, positions=pos,
             block_table=block_table, paged_write_mask=write_mask,
             moe_counts=moe_counts, state_slots=state_slot,
-            paged_run=(start, n_valid))
-        last = jnp.take_along_axis(
-            logits, jnp.maximum(n_valid - 1, 0)[None, None, None],
-            axis=1)[:, 0].astype(jnp.float32)
-        tok = sample_rows(last, base_key, temperature, top_k, top_p,
+            paged_run=(start, n_valid), **tail)
+        if last_only:
+            read = logits[:, 0].astype(jnp.float32)
+        else:
+            read = jnp.take_along_axis(
+                logits, jnp.maximum(n_valid - 1, 0)[None, None, None],
+                axis=1)[:, 0].astype(jnp.float32)
+        tok = sample_rows(read, base_key, temperature, top_k, top_p,
                           seeds, jnp.zeros((1,), jnp.int32))
         if moe_counts:
             tok = _with_moe_counts(tok, counts[0])
-        return tok, last, cache
+        return tok, read, cache
 
     return chunk_step
 

@@ -99,7 +99,9 @@ def main(argv=None, family=FAMILY):
         cell.config = bench_tiny.tiny_config(cell.config)
         lo, hi, new_lo, new_hi, pad_to = 10, 70, 6, 20, 96
     else:
-        lo, hi, new_lo, new_hi, pad_to = 32, 512, 128, 384, 1024
+        # a family may ask for longer sequences (a window several deep)
+        lo, hi, new_lo, new_hi, pad_to = family.get(
+            "lengths", (32, 512, 128, 384, 1024))
     limit = float(cell.traffic["reference"]["logprob_atol"])
     dtype = getattr(jnp, cell.config["model"]["dtype"])
     model = program.build_model(cell)
@@ -152,8 +154,13 @@ def main(argv=None, family=FAMILY):
                 zero = np.zeros((1,), np.int32)
                 _, _, serving._arena = serving._prefill(
                     params, serving._arena,
-                    paged_kv.pack_chunk(table[:1], chunk, start, n, 0 * one,
-                                        zero, one, zero, state_slot=zero),
+                    paged_kv.pack_chunk(
+                        table[:1], chunk, start, n, 0 * one, zero, one, zero,
+                        state_slot=zero,
+                        # a stack with ``tail_runs``: the chunk says whether
+                        # it is its prompt's last
+                        **({"last": [start + n == n_prompt]}
+                           if serving._chunk_says_last else {})),
                     serving._base_rng)
             logp = []
             for p in range(n_prompt, len(full) - 1):
@@ -175,6 +182,12 @@ def main(argv=None, family=FAMILY):
 
     def ref_pass(**changed):
         kw = dict(ref_args, **changed)
+
+        if hasattr(reference, "next_token_stats"):
+            # a reference whose vocabulary is too wide for a sequence's
+            # logits at once reads the three statistics in blocks itself
+            return jax.jit(lambda p, ids: tuple(
+                a[0] for a in reference.next_token_stats(p, ids, **kw)))
 
         @jax.jit
         def run(p, ids):

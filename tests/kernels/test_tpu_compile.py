@@ -21,6 +21,8 @@ from deepspeed_tpu.ops import (decode_attention, flash_attention,
                                mamba2_decode_step, moe_grouped_matmul,
                                paged_decode_attention,
                                paged_prefill_attention)
+from deepspeed_tpu.ops import mamba1_chunk_scan, mamba1_decode_step
+from deepspeed_tpu.ops.paged_decode_attention import paged_attention
 from deepspeed_tpu.ops.moe_grouped_matmul import max_tiles, tile_rows
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -115,6 +117,39 @@ def _mamba2_decode(rows, heads, p, groups, n, layers=5):
              ((), I32), ((rows,), I32)])
 
 
+def _mamba1_decode(rows, inner, n, layers=9):
+    # the pool of a slot a row and one scratch, the layer a traced scalar
+    return (mamba1_decode_step,
+            [((rows, inner), F32), ((rows, inner), F32), ((n, inner), F32),
+             ((rows, n), F32), ((rows, n), F32),
+             ((layers, rows + 1, n, inner), F32), ((), I32), ((rows,), I32)])
+
+
+def _mamba1_chunk(chunk, inner, n):
+    return (mamba1_chunk_scan,
+            [((1, chunk, inner), F32), ((1, chunk, inner), F32),
+             ((n, inner), F32), ((1, chunk, n), F32), ((1, chunk, n), F32),
+             ((1, n, inner), F32)])
+
+
+def _paged_window(queries, rows, heads, d, block, maxb, kv_heads, window,
+                  monkeypatch):
+    """``paged_attention`` under a window on its kernel branch: the decode
+    walk (one query a row) or the prefill kernel, over a ring's pool."""
+    from deepspeed_tpu.ops import registry
+
+    monkeypatch.setattr(registry, "kernels_active", lambda: True)
+    arena = ((8, 3121, block, kv_heads * d), BF16)
+
+    def fn(q, k, v, layer, table, positions):
+        return paged_attention(q, k, v, layer, table, positions,
+                               scale=0.125, window=window,
+                               name="window_decode_attention")
+
+    return fn, [((rows, queries, heads, d), BF16), arena, arena, ((), I32),
+                ((rows, maxb), I32), ((rows, queries), I32)]
+
+
 def _grouped_matmul(tokens, top_k, experts, k, n):
     """The expert matmul of `tokens` x `top_k` assignments laid out in
     tiles, as parallel/moe.py calls it."""
@@ -136,7 +171,17 @@ def _grouped_matmul(tokens, top_k, experts, k, n):
 # nemotron-3-super as one of 8 chips: 64 decode rows; 128 state-space heads
 # of 64 in 8 groups of state 128; 32 heads over 2 KV heads of 128; a row's 22
 # assignments of which an eighth reach the 64 held experts, 1024 x 2688
+# phi-4-mini-flash-reasoning whole: 64 decode rows of up to 2,560 tokens; a
+# Mamba-1 state of 16 x 5,120 a (row, layer); differential attention's pairs
+# folded into 40 query heads over 10 key-value heads of 128
 CASES = {
+    "mamba1-decode-phi-4-mini-flash":
+        lambda: _mamba1_decode(64, 5120, 16),
+    "mamba1-chunk-phi-4-mini-flash": lambda: _mamba1_chunk(256, 5120, 16),
+    "paged-decode-phi-4-mini-flash":
+        lambda: _paged_decode(64, 40, 128, 16, 160, kv_heads=10),
+    "paged-prefill-phi-4-mini-flash":
+        lambda: _paged_prefill(256, 40, 128, 16, 160, kv_heads=10),
     "mamba2-decode-nemotron-3-super":
         lambda: _mamba2_decode(64, 128, 64, 8, 128),
     "paged-decode-nemotron-3-super":
@@ -204,6 +249,21 @@ def test_kernel_compiles_for_v5e(v5e, case):
         assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("queries,rows", [(1, 64), (256, 1)],
+                         ids=["decode", "prefill"])
+def test_window_walk_compiles_for_v5e(v5e, monkeypatch, queries, rows):
+    """The paged kernels' window form at phi-4-mini-flash-reasoning's
+    shapes: a window of 512 keys over a ring of 48 pages a row, the table
+    cut to the pages a window and its queries can span."""
+    fn, shapes = _paged_window(queries, rows, 40, 128, 16, 160, 10, 512,
+                               monkeypatch)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=v5e) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("window_decode_attention" if queries == 1
+            else "paged_prefill_attention") in text
+
+
 # ---------------------------------------------------------------------------
 # the serving programs at the benchmark's size: a layer's pool is addressed
 # inside the arena, never copied out of it
@@ -240,12 +300,16 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
             tree)
 
+    from deepspeed_tpu.inference.kv_cache import ring_blocks
+
     params = on_chip(jax.eval_shape(
         lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
     recurrent = bool(T.recurrent_layers(cfg)[1])
+    maxb = program_options.pop("maxb", MAXB)
     arena = on_chip(paged_cache_shape_struct(
         cfg, num_blocks, BLOCK, BF16,
-        state_slots=rows + 1 if recurrent else 0))
+        state_slots=rows + 1 if recurrent else 0,
+        ring_blocks=ring_blocks(cfg, CHUNK, BLOCK)))
 
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
@@ -257,20 +321,21 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
         # behind the key (an MoE model's counts lie behind the tokens)
         last = arg((r + 3 * bool(program_options.get("moe_counts")),), I32)
         return paged_kv.build_decode_program(cfg, **program_options).lower(
-            params, arena, arg(paged_kv.decode_rows_shape(r, MAXB), I32), key,
+            params, arena, arg(paged_kv.decode_rows_shape(r, maxb), I32), key,
             last)
     if kind == "verify":
         return paged_kv.build_verify_program(cfg, SPEC_TOKENS).lower(
             params, arena,
-            arg(paged_kv.verify_rows_shape(r, MAXB, SPEC_TOKENS), I32), key)
+            arg(paged_kv.verify_rows_shape(r, maxb, SPEC_TOKENS), I32), key)
     if kind == "score":
         return paged_kv.build_score_program(cfg).lower(
-            params, arena, arg((1, MAXB), I32), arg((1, chunk), I32),
+            params, arena, arg((1, maxb), I32), arg((1, chunk), I32),
             arg((1, chunk), I32), arg((), I32), arg((), I32))
     return paged_kv.build_prefill_program(
         cfg, CHUNK, **program_options).lower(
             params, arena,
-            arg(paged_kv.chunk_shape(MAXB, CHUNK, recurrent), I32), key)
+            arg(paged_kv.chunk_shape(maxb, CHUNK, recurrent,
+                                     T.tail_runs(cfg) > 0), I32), key)
 
 
 def _fusion_roots(text):
@@ -545,6 +610,57 @@ DECODE_PROJECTIONS = {
         dict(overrides=NEMOTRON, rows=SOLAR_ROWS, num_blocks=SOLAR_BLOCKS,
              moe_counts=True), (4096, 4096, 256), 1, 4),
 }
+
+
+# phi-4-mini-flash-reasoning as the benchmark serves it: all 32 layers, 64 rows
+# of up to 2,560 tokens; pages for the ONE full layer, a ring of 48 pages a
+# row and window layer, a Mamba-1 state a row and layer
+PHI_ROWS, PHI_BLOCKS, PHI_MAXB = 64, 10241, 160
+PHI_STATES = f"f32[9,{PHI_ROWS + 1},16,5120]"
+PHI_RINGS = f"bf16[8,{1 + (PHI_ROWS + 1) * 48},{BLOCK},1280]"
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_phi4flash_serving_program_scans_its_runs_and_copies_no_pool(
+        v5e, monkeypatch, kind):
+    """Phi-4-mini-flash-reasoning's serving programs for the chip at the
+    cell's shapes. The stack's three runs are scanned, so a program holds ONE
+    call of each kernel a run, not one a layer: a decode step has two calls
+    of `mamba1_decode_step` (the 8 periods' and layer 16's), one windowed
+    walk and two walks of the shared pool (layer 17's and the cross
+    layers'); the chunk program runs the cross-decoder for its last token
+    alone, a decode walk. The state pool and the rings come back aliased,
+    and no program holds a second copy of a pool, of the rings (2 GB) or of
+    a run's weights."""
+    compiled = _serving_program(
+        kind, v5e, monkeypatch, preset="phi-4-mini-flash-reasoning",
+        overrides={"num_layers": 32}, rows=PHI_ROWS, num_blocks=PHI_BLOCKS,
+        maxb=PHI_MAXB).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "custom-call" in ln
+             and "tpu_custom_call" in ln]
+
+    def count(kernel):
+        return sum(re.search(rf"%?{kernel}(\.\d+)? = ", ln) is not None
+                   for ln in calls)
+
+    steps = kind == "decode"
+    assert count("mamba1_decode_step") == (2 if steps else 0)
+    assert count("mamba1_chunk_scan") == (0 if steps else 2)
+    assert count("window_decode_attention") == (1 if steps else 0)
+    assert count("shared_kv_decode_attention") \
+        == {"decode": 2, "prefill": 1}[kind]
+    assert count("paged_prefill_attention") \
+        == {"decode": 0, "prefill": 2}[kind]
+    assert PHI_STATES in text and PHI_RINGS in text
+    # nothing pool-sized is a temporary: the full pool is 0.42 GB a side,
+    # the rings 1.0 GB a side, the states 0.21 GB, a run's FFN weights 1.3 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+    for shape in (PHI_STATES, PHI_RINGS,
+                  f"bf16[1,{PHI_BLOCKS},{BLOCK},1280]"):
+        copied = [ln for ln in text.splitlines()
+                  if re.search(rf"= {re.escape(shape)}\S* copy\(", ln)]
+        assert not copied, copied[:2]
 
 
 def _projection_weights(text, widths, layers):

@@ -256,7 +256,7 @@ def test_only_the_model_names_a_mixer_by_string():
     owners = {package / "models" / "transformer.py",
               package / "models" / "presets.py"}
     recurrent = {m for m, record in T.MIXERS.items() if record.state}
-    assert recurrent == {"kda", "mamba2"}
+    assert recurrent == {"kda", "mamba2", "mamba1"}
     for path in sorted(set(package.rglob("*.py")) - owners):
         for node in ast.walk(ast.parse(path.read_text())):
             if isinstance(node, ast.Constant) and node.value in recurrent:
@@ -266,6 +266,29 @@ def test_only_the_model_names_a_mixer_by_string():
     # and inside the owner: the table, LAYER_KINDS, nothing that branches
     source = (package / "models" / "transformer.py").read_text()
     assert "if mixer ==" not in source and "elif mixer ==" not in source
+
+
+@pytest.mark.parametrize("mixer,keeps,state,hands_on", [
+    ("attn", "pages", False, False), ("full", "pages", False, False),
+    ("swa", "ring", False, False), ("cross", None, False, False),
+    ("gmu", None, False, False), ("mamba1", None, True, True),
+    ("mamba2", None, True, False), ("kda", None, True, False)])
+def test_a_record_says_what_its_mixer_keeps(mixer, keeps, state, hands_on):
+    """What a layer keeps for a sequence is its record's word: pages of its
+    own, a ring a row, a recurrent state, or nothing (it reads what another
+    layer made); the three forms of the softmax mixer share one subtree name
+    and one function."""
+    record = T.MIXERS[mixer]
+    assert (record.keeps, bool(record.state), record.hands_on) \
+        == (keeps, state, hands_on)
+    if mixer in ("swa", "full", "cross"):
+        assert record.name == "attn"
+    cfg = transformer_config("tiny-phi4flash")
+    assert T.paged_layers(cfg) == (5,) and T.ring_layers(cfg) == (1, 3)
+    assert T.pool_readers(cfg) == (5, 7) and T.tail_runs(cfg) == 1
+    plain = transformer_config("tiny-opt")
+    assert T.paged_layers(plain) == (0, 1) and T.ring_layers(plain) == ()
+    assert T.pool_readers(plain) == () and T.tail_runs(plain) == 0
 
 
 def test_a_layer_takes_its_operands_as_one_record():

@@ -41,7 +41,7 @@ def _rows(rng):
         steps=np.asarray([0, 1, 511, 1023, 3, 4], np.int32))
 
 
-def _chunk(rng, state_slot):
+def _chunk(rng, state_slot, last=False):
     table = rng.integers(1, I32.max, (1, MAXB)).astype(np.int32)
     got = dict(
         block_table=table,
@@ -53,6 +53,8 @@ def _chunk(rng, state_slot):
         seeds=np.asarray([-7], np.int32))
     if state_slot:
         got["state_slot"] = np.asarray([63], np.int32)
+    if last:
+        got["last"] = np.asarray([1], np.int32)
     return got
 
 
@@ -74,6 +76,15 @@ LAYOUTS = {
         lambda rng: _chunk(rng, True), paged_kv.pack_chunk,
         lambda p: paged_kv.unpack_chunk(p, C, True),
         paged_kv.chunk_shape(MAXB, C, True), (MAXB + C + 7,)),
+    "chunk_that_says_last": (
+        lambda rng: _chunk(rng, True, True), paged_kv.pack_chunk,
+        lambda p: paged_kv.unpack_chunk(p, C, True, True),
+        paged_kv.chunk_shape(MAXB, C, True, True), (MAXB + C + 8,)),
+    "chunk_that_says_last_without_a_state_slot": (
+        lambda rng: _chunk(rng, False, True), paged_kv.pack_chunk,
+        lambda p: (lambda got: got[:-2] + got[-1:])(
+            paged_kv.unpack_chunk(p, C, False, True)),
+        paged_kv.chunk_shape(MAXB, C, False, True), (MAXB + C + 7,)),
     "verify": (_verify, paged_kv.pack_verify_rows,
                lambda p: paged_kv.unpack_verify_rows(p, S),
                paged_kv.verify_rows_shape(R, MAXB, S), (R, MAXB + S + 7)),
@@ -118,6 +129,10 @@ def test_a_new_array_each_call(layout):
 def test_chunk_without_a_state_slot_says_none():
     packed = paged_kv.pack_chunk(**_chunk(np.random.default_rng(2), False))
     assert paged_kv.unpack_chunk(jnp.asarray(packed), C, False)[-1] is None
+    packed = paged_kv.pack_chunk(**_chunk(np.random.default_rng(2), False,
+                                          True))
+    got = paged_kv.unpack_chunk(jnp.asarray(packed), C, False, True)
+    assert got[-2] is None and int(got[-1][0]) == 1
 
 
 @pytest.mark.parametrize("unpack,shape", [
