@@ -34,6 +34,14 @@ may be in flight across an iteration boundary of the driver thread
 (``_bring_home``). Under ``step()`` no program is ever in flight across an
 iteration boundary.
 
+And INSIDE an iteration the driver thread enqueues the decode step BEHIND
+THE CHUNK, with the chunk still running, whenever the chunk is not its
+prompt's last (``_step_prefill``, ``_decode_can_follow``: five rules again,
+no setting): such a chunk's token is read by nobody and its request is no
+decode row, so the chunk's fetch and apply lie in the decode program's
+shadow and no host round lies between the two programs (``_chunk``, never
+set across an iteration's end).
+
 Telemetry flows through the PR-2 observability substrate: ``serving/*``
 metrics in the MetricsRegistry (ttft_ms, tpot_ms, queue_depth,
 kv_blocks_in_use, preemptions, ...), the spans of ``docs/serving.md``'s table
@@ -59,8 +67,8 @@ from ..observability.memory import hbm_counts
 from ..parallel import mesh as mesh_mod
 from ..utils.logging import log_dist, logger
 from . import paged_kv
-from .scheduler import (CANCELLED, DECODE, Request, SamplingParams,
-                        Scheduler)
+from .scheduler import (CANCELLED, DECODE, PREFILL, Request,
+                        SamplingParams, Scheduler)
 from .session import RequestHandle
 
 __all__ = ["ServingEngine", "init_serving"]
@@ -99,8 +107,10 @@ class _Enqueued:
     """A program behind its enqueue and before its fetch: the engine's clock
     before the call and behind it, and the call's seconds as far as they
     count towards a hold. A decode step also keeps its rows, each request
-    with the row it held, and, where it was enqueued AHEAD, when its
-    predecessor's tokens came to the host: from there its interval counts."""
+    with the row it held, and, where it was enqueued AHEAD or BEHIND A CHUNK
+    still in flight, when that program's tokens came to the host: from there
+    its interval counts. A chunk keeps its request so, and where in the
+    prompt it starts and how many tokens it holds."""
     name: str
     tok: Any
     t0: float
@@ -108,6 +118,8 @@ class _Enqueued:
     call_s: float
     rows: List[tuple] = ()
     since: Optional[float] = None
+    start: int = 0
+    tokens: int = 0
 
 
 def _percentile(samples: List[float], q: float) -> float:
@@ -323,6 +335,11 @@ class ServingEngine:
         # ``_may_run_ahead`` last asked
         self._flight: Optional[_Enqueued] = None
         self._rows_released_seen = 0
+        # the chunk that is enqueued and not fetched, inside an iteration of
+        # the driver thread: ``_step_decode`` enqueues its step behind it
+        # and lands it in that step's shadow (``_step_prefill``). Never
+        # across an iteration's end
+        self._chunk: Optional[_Enqueued] = None
         # the open iteration's span until its account (gauges, the span's
         # counts) is drawn up: behind its first enqueue when deferring, else
         # at its end
@@ -906,6 +923,9 @@ class ServingEngine:
                 finally:
                     self._deferring = False
                     self._unaccounted = None
+                    # set only where the iteration raised: the chunk is run
+                    # again, as one whose fetch raised always was
+                    self._chunk = None
                     if acct is not None:
                         acct.iteration_end(self.clock())
                         # gauge refresh at a cadence, always AFTER the
@@ -963,15 +983,22 @@ class ServingEngine:
                 self._trace_admitted(obs, admitted)
             span.annotate(admitted=len(admitted), expired=expired)
         progress = bool(expired or admitted)
-        for _ in range(max(int(self.prefill_chunks_per_iter), 1)):
-            ran_chunk = self._step_prefill()
+        drafting = self._drafter is not None and not self.spec_suspended
+        chunks = max(int(self.prefill_chunks_per_iter), 1)
+        for i in range(chunks):
+            # the iteration's last chunk may stay in flight for the decode
+            # step to be enqueued behind it: the driver thread's form only,
+            # and never under a verify step, whose drafter runs first
+            ran_chunk = self._step_prefill(
+                may_wait=(self._deferring and not drafting
+                          and i == chunks - 1))
             progress |= ran_chunk
             if not ran_chunk:
                 break
-        progress |= (self._step_verify()
-                     if self._drafter is not None
-                     and not self.spec_suspended
-                     else self._step_decode())
+        progress |= self._step_verify() if drafting else self._step_decode()
+        if self._chunk is not None:
+            # no decode step was enqueued behind it after all
+            self._land_chunk(obs, self._chunk)
         return progress
 
     def _settle(self, obs, deferred: bool = False) -> None:
@@ -1242,7 +1269,20 @@ class ServingEngine:
                           moe_max_expert_rows=largest)
         return fetched[:n]
 
-    def _step_prefill(self) -> bool:
+    def _step_prefill(self, may_wait: bool = False) -> bool:
+        """One chunk of the oldest prompt in prefill, enqueued, and fetched
+        and applied too, unless it ``may_wait`` (``_step_locked``: the driver
+        thread's iteration, at its last chunk, with no drafter), is not its
+        prompt's last, and this iteration's decode rows can grow without a
+        preemption (``_decode_can_follow``). Then it stays in flight
+        (``_chunk``): nothing the decode step's operands are reckoned from
+        waits for it (its token is read by nobody, its request is no decode
+        row), so ``_step_decode`` enqueues that step BEHIND it, the device
+        goes from one program to the other with no host round between, and
+        the chunk is fetched and applied in the decode program's shadow. A
+        prompt's last chunk brings a first token and a new decode row: it is
+        always fetched, applied and delivered before the decode step is
+        prepared."""
         req = self.sched.next_prefill()
         if req is None:
             return False
@@ -1251,6 +1291,7 @@ class ServingEngine:
         src = req.prompt
         start = req.prefill_pos
         n_valid = min(C, int(src.size) - start)
+        last = start + n_valid == int(src.size)
         with obs.span("serving/prefill_chunk", rid=req.rid,
                       chunk_start=int(start),
                       sampled_rows=self._sampled_rows([req])) as span:
@@ -1267,34 +1308,81 @@ class ServingEngine:
                     *self._sampling_arrays([req]),
                     state_slot=([req.row] if self._recurrent_layers
                                 else None),
-                    **({"last": [start + n_valid == int(src.size)]}
-                       if self._chunk_says_last else {}))
-            tok, t0, t1 = self._run_program(
-                obs, "serving/prefill_chunk", self._prefill, packed,
-                self._base_rng, trace=req.trace)
-            with obs.span("serving/prefill_chunk/apply", category="phase"):
-                tok = self._program_counts(span, tok, 1, real_rows=1)
-                if self._serve_acct is not None:
-                    self._serve_acct.note_phase("prefill", t1 - t0)
-                rt = obs.reqtrace
-                if rt is not None and req.trace is not None:
-                    rt.interval(req.trace, "prefill", t0, t1,
-                                kind="prefill_chunk", tokens=int(n_valid),
-                                chunk_start=int(start),
-                                replica=self.trace_tag)
-                span.annotate(tokens=int(n_valid))   # the chunk ran: a span
-                #   without the count is a chunk the pool could not place
-                self.prefill_chunks_run += 1
-                self.prefill_tokens_run += int(n_valid)
-                req.prefill_pos += n_valid
-                req.length = req.prefill_pos
-                # newly completed full prompt blocks become shareable prefix
-                # cache
-                self.sched.note_prefill_progress(req, start, req.prefill_pos)
-                self.sched.note_service(req, n_valid)
-                if req.prefill_pos == int(src.size):
-                    self._finish_prefill(obs, req, int(tok[0]))
+                    **({"last": [last]} if self._chunk_says_last else {}))
+            sent = self._enqueue(obs, "serving/prefill_chunk", self._prefill,
+                                 packed, self._base_rng, trace=req.trace)
+            sent.rows = [(req, req.row)]
+            sent.start, sent.tokens = int(start), int(n_valid)
+            if may_wait and not last and self._decode_can_follow():
+                self._chunk = sent
+                return True
+            if self._deferring:
+                self._settle(obs, deferred=True)
+            self._land_chunk(obs, sent, span)
         return True
+
+    def _decode_can_follow(self) -> bool:
+        """Whether this iteration's decode step may be enqueued behind a
+        chunk that is still in flight, as far as its rows and their pages
+        say: there are rows, the page each one's next token needs comes from
+        the free list or from an unpinned prefix-cache entry (which frees
+        only blocks no request holds, and whoever takes one writes it in a
+        program enqueued behind the chunk), and none of them writes into a
+        shared block. So nobody is preempted and no block copied with the
+        chunk's progress not yet applied; where either would be, the chunk
+        is fetched and applied first. Asked before anything is taken."""
+        dec = self.sched.decode_requests()
+        return bool(dec) and self.sched.grows_without_preemption(dec)
+
+    def _land_chunk(self, obs, sent: "_Enqueued", span=None) -> None:
+        """A chunk's token fetched and its progress applied
+        (``serving/prefill_chunk/fetch`` and ``.../apply``, under ``span``:
+        the chunk's span where it is still open, else one of its own, for a
+        chunk that waited for the decode step's enqueue; ``tokens`` and the
+        program's counts go onto the span that holds the fetch). A request
+        that was cancelled, expired or preempted behind the chunk's enqueue
+        is dropped as ``_land`` drops a row (``dropped_rows``): what the
+        chunk wrote lies in pages and a state slot that their next owner
+        writes over in a later program, and its progress is applied to
+        nobody. A decode step enqueued behind the chunk counts its interval
+        from here (``_Enqueued.since``): no second is counted twice."""
+        (req, row), = sent.rows
+        if span is None:
+            with obs.span("serving/prefill_chunk", rid=req.rid,
+                          chunk_start=sent.start,
+                          sampled_rows=self._sampled_rows([req])) as span:
+                return self._land_chunk(obs, sent, span)
+        if self._chunk is sent:
+            self._chunk = None
+        tok, t1 = self._fetch(obs, sent)
+        if self._flight is not None:
+            self._flight.since = t1
+        n_valid = sent.tokens
+        with obs.span("serving/prefill_chunk/apply", category="phase"):
+            tok = self._program_counts(span, tok, 1, real_rows=1)
+            if self._serve_acct is not None:
+                self._serve_acct.note_phase("prefill", t1 - sent.t0)
+            span.annotate(tokens=n_valid)   # the chunk ran: a span
+            #   without the count is a chunk the pool could not place, or
+            #   the prepare and dispatch of one that waited for its fetch
+            self.prefill_chunks_run += 1
+            self.prefill_tokens_run += n_valid
+            if req.state != PREFILL or req.row != row:
+                span.annotate(dropped_rows=1)
+                return
+            rt = obs.reqtrace
+            if rt is not None and req.trace is not None:
+                rt.interval(req.trace, "prefill", sent.t0, t1,
+                            kind="prefill_chunk", tokens=n_valid,
+                            chunk_start=sent.start, replica=self.trace_tag)
+            req.prefill_pos += n_valid
+            req.length = req.prefill_pos
+            # newly completed full prompt blocks become shareable prefix
+            # cache
+            self.sched.note_prefill_progress(req, sent.start, req.prefill_pos)
+            self.sched.note_service(req, n_valid)
+            if req.prefill_pos == int(req.prompt.size):
+                self._finish_prefill(obs, req, int(tok[0]))
 
     def _finish_prefill(self, obs, req: Request, token: int) -> None:
         """The prompt's last chunk ran: the request decodes from here."""
@@ -1513,11 +1601,15 @@ class ServingEngine:
         delivered at once. Else the step is enqueued behind the state the
         host holds, and fetched here too, unless the driver thread may run
         the next one ahead of it (``_may_run_ahead``): then it stays in
-        flight for the next iteration."""
+        flight for the next iteration. With the iteration's chunk still in
+        flight (``_chunk``: ``_step_prefill`` left it there) the step is
+        enqueued BEHIND THE CHUNK, and then the chunk is landed and the step
+        behind it, each in a span of its own."""
         dec = ahead or self.sched.decode_requests()
         if not dec:
             return False
         obs = get_session()
+        chunk = self._chunk
         with obs.span("serving/decode",
                       max_rows=self.config.max_seqs) as span:
             with obs.span("serving/decode/prepare", category="phase"):
@@ -1525,6 +1617,8 @@ class ServingEngine:
                 packed = (self._decode_operands(ready, bool(ahead))
                           if ready else None)
                 span.annotate(rows=len(ready), ahead=int(bool(ahead)),
+                              behind_chunk=int(bool(ready)
+                                               and chunk is not None),
                               sampled_rows=self._sampled_rows(ready))
             if not ready:
                 return False
@@ -1539,7 +1633,14 @@ class ServingEngine:
             self._last_tokens = sent.tok
             if self._deferring:
                 self._settle(obs, deferred=True)
-            if before is not None:
+            if chunk is not None:
+                if obs.enabled:
+                    obs.registry.counter(
+                        "serving/decodes_enqueued_behind_chunk",
+                        help="decode steps enqueued with their iteration's "
+                             "chunk not yet fetched (the driver thread's "
+                             "form)").inc()
+            elif before is not None:
                 if obs.enabled:
                     obs.registry.counter(
                         "serving/steps_enqueued_ahead",
@@ -1550,6 +1651,11 @@ class ServingEngine:
                 self._flush(deferred=True)   # at once: the device is busy
             elif not (self._deferring and self._may_run_ahead(sent)):
                 self._land(obs, sent, span)
+        if chunk is not None:
+            # neither program's span inside the other's. A request is still
+            # in prefill, so nothing stays in flight at the iteration's end
+            self._land_chunk(obs, chunk)
+            self._land(obs, sent)
         return True
 
     def _land(self, obs, sent: "_Enqueued", span=None) -> None:
@@ -1560,9 +1666,10 @@ class ServingEngine:
         ``eos_token_id`` at the step before, with this one ahead) is
         dropped: its token goes nowhere, and what it wrote lies in pages
         and a state slot that whoever takes them next writes over, in a
-        program enqueued behind this one. An ahead step's interval, for the
-        accountant and the request tracer, runs from its predecessor's
-        fetch to its own: no second is counted twice."""
+        program enqueued behind this one. The interval of a step enqueued
+        ahead, or behind a chunk in flight, runs for the accountant and the
+        request tracer from that program's fetch to its own: no second is
+        counted twice."""
         if span is None:
             with obs.span("serving/decode",
                           max_rows=self.config.max_seqs) as span:

@@ -321,6 +321,16 @@ class PrefixCache:
         self.invalidations += n
         return n
 
+    def can_evict(self, need: int) -> bool:
+        """Whether ``evict(need)`` would free ``need`` blocks: that many
+        entries are unpinned. Looks LRU-first, as ``evict`` does, and stops
+        at the ``need``-th."""
+        for bid in self._entries.values():
+            if need <= 0:
+                break
+            need -= self.alloc.refcount(bid) == 1
+        return need <= 0
+
     def evict(self, need: int) -> int:
         """Drop up to ``need`` UNPINNED entries (blocks only the cache
         holds), LRU-first, returning their blocks to the free list.

@@ -453,6 +453,23 @@ class Scheduler:
         return extend_block_list(self.alloc, req.blocks, upto_tokens,
                                  self.config.block_size)
 
+    def grows_without_preemption(self, reqs: List[Request]) -> bool:
+        """Whether every request of ``reqs`` can take the page its NEXT
+        token needs (``ensure_blocks(r, r.length + 1)``) and write there with
+        nobody preempted and no block copied: the pages come from the free
+        list or from unpinned prefix-cache entries, and no written page is
+        shared. Asks only; takes nothing."""
+        bs = self.config.block_size
+        need = 0
+        for r in reqs:
+            need += max(blocks_for_tokens(r.length + 1, bs) - len(r.blocks),
+                        0)
+            if self.cow_block_indices(r, r.length, r.length + 1):
+                return False
+        short = need - self.alloc.blocks_free
+        return short <= 0 or (self.prefix is not None
+                              and self.prefix.can_evict(short))
+
     def truncate_blocks(self, req: Request, upto_tokens: int) -> int:
         """Positional rollback: free blocks past the ones covering
         positions [0, upto_tokens) — rejected speculative KV beyond the

@@ -338,7 +338,10 @@ class TestServingFromTheInside:
         in this order: ``prepare``, ``dispatch``, (what is delivered and
         published in the device's shadow,) ``fetch``, ``apply``. The
         ``dispatch`` span says how many of the call's operands were host
-        arrays: one, the step's packed operands."""
+        arrays: one, the step's packed operands. A chunk that waited for the
+        decode step's enqueue has its leaves in two spans: ``prepare`` and
+        ``dispatch`` in the first, ``fetch`` and ``apply`` in the one that
+        carries its ``tokens``."""
         from deepspeed_tpu.serving import paged_kv
 
         spans, _, _ = served
@@ -354,6 +357,18 @@ class TestServingFromTheInside:
                            if c.get("parent_id") == parent["id"]),
                           key=lambda c: c["start_s"])
             names = [c["name"].replace(program, "...") for c in kids]
+            if names == [".../fetch", ".../apply"]:
+                assert program == "serving/prefill_chunk"
+                early = max((s for s in spans if s["name"] == program
+                             and s["end_s"] <= parent["start_s"]),
+                            key=lambda s: s["end_s"])
+                assert "tokens" not in early["attrs"]
+                assert [early["attrs"][k] for k in ("rid", "chunk_start")] \
+                    == [parent["attrs"][k] for k in ("rid", "chunk_start")]
+                kids = sorted((c for c in spans
+                               if c.get("parent_id") == early["id"]),
+                              key=lambda c: c["start_s"]) + kids
+                names = [c["name"].replace(program, "...") for c in kids]
             shadow = [n for n in names[2:] if n.startswith("serving/")]
             # a decode step that stays in flight at its iteration's end has
             # no fetch of its own; one enqueued AHEAD holds its
@@ -411,6 +426,73 @@ class TestServingFromTheInside:
         # time; still some steps found nobody waiting
         assert 0 < ahead < len(calls)
 
+    def test_a_decode_step_is_dispatched_before_a_chunk_that_is_not_the_last_is_fetched(
+            self, tiny_engine, tmp_path):
+        """A prompt of three chunks beside two rows that decode, on the
+        driver thread: in the iterations of chunks one and two the decode
+        step's call has returned before the chunk's fetch begins
+        (``behind_chunk`` 1, and the chunk's fetch and apply lie in a span of
+        their own that carries its ``tokens``); the third chunk brings a
+        first token, and its fetch and apply come before the step is
+        prepared. Neither program's span lies inside the other's."""
+        reset_session()
+        srv = serving(tiny_engine)
+        srv.submit(np.arange(1, 40), max_new_tokens=3)
+        srv.run()                                   # both programs compiled
+        srv.start()
+        with Capture(tmp_path):
+            rows = [srv.submit(np.arange(1, 8 + i), max_new_tokens=90)
+                    for i in range(2)]
+            deadline = time.monotonic() + 60
+            while not all(h.tokens for h in rows):
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+            late = srv.submit(np.arange(50, 90), max_new_tokens=2)
+            assert len(late.result(timeout_s=120)) == 2
+            srv.stop()
+        spans = recorded_spans()
+        srv.close()
+        by_id = {s["id"]: s for s in spans}
+
+        def leaf(parent, part):
+            return next((c for c in spans if c.get("parent_id") == parent["id"]
+                         and c["name"] == f"{parent['name']}/{part}"), None)
+
+        behind = []
+        for start in (0, 16, 32):
+            chunk = next(s for s in spans
+                         if s["name"] == "serving/prefill_chunk"
+                         and s["attrs"]["rid"] == late.request_id
+                         and s["attrs"]["chunk_start"] == start
+                         and "tokens" in s["attrs"])
+            iteration = by_id[chunk["parent_id"]]
+            assert iteration["name"] == "serving/iteration"
+            step = next(s for s in spans if s["name"] == "serving/decode"
+                        and s.get("parent_id") == iteration["id"]
+                        and s["attrs"].get("rows"))
+            assert step["attrs"]["rows"] == 2 + (start == 32)
+            call, fetch = leaf(step, "dispatch"), leaf(chunk, "fetch")
+            behind.append(step["attrs"]["behind_chunk"])
+            if start < 32:
+                assert call["end_s"] <= fetch["start_s"]
+                assert leaf(chunk, "prepare") is None
+                assert leaf(step, "fetch") is None
+                assert step["end_s"] <= chunk["start_s"]
+                landed = next(s for s in spans if s["name"] == "serving/decode"
+                              and s.get("parent_id") == iteration["id"]
+                              and "rows" not in s["attrs"])
+                assert chunk["end_s"] <= landed["start_s"]
+                assert leaf(chunk, "apply")["end_s"] \
+                    <= leaf(landed, "fetch")["start_s"]
+            else:
+                assert leaf(chunk, "apply")["end_s"] \
+                    <= leaf(step, "prepare")["start_s"]
+                assert leaf(chunk, "prepare") is not None
+        assert behind == [1, 1, 0]
+        assert sum(s["attrs"].get("tokens", 0) for s in spans
+                   if s["name"] == "serving/prefill_chunk"
+                   and s["attrs"]["rid"] == late.request_id) == 40
+
     def test_host_operands_reach_the_capture_as_stats(self, tiny_engine,
                                                       tmp_path):
         srv = serving(tiny_engine)
@@ -449,8 +531,10 @@ class TestServingFromTheInside:
         spans, rise, _ = served
         chunks = [s["attrs"] for s in spans
                   if s["name"] == "serving/prefill_chunk"]
-        assert sum(a["tokens"] for a in chunks) == rise > 0
-        assert all(a["tokens"] <= 16 and a["chunk_start"] % 16 == 0
+        # a chunk that waited for the decode step's enqueue lies in two
+        # spans, and the one with its fetch carries the count
+        assert sum(a.get("tokens", 0) for a in chunks) == rise > 0
+        assert all(a.get("tokens", 0) <= 16 and a["chunk_start"] % 16 == 0
                    for a in chunks)
 
     def test_a_requests_life_shares_one_rid(self, served):

@@ -1716,3 +1716,493 @@ class TestStepAhead:
             assert len(routed) == 5
             assert all(a["moe_assignments"] > 0 for a in routed)
         srv.close()
+
+
+# ---------------------------------------------------------------------------
+# a decode step behind its chunk: under the driver thread an iteration whose
+# chunk is not its prompt's last enqueues its decode step with the chunk
+# still in flight, and fetches and applies the chunk in that step's shadow.
+# Same programs, same operands, same order on the device, same tokens
+# ---------------------------------------------------------------------------
+
+
+def watch_chunks(srv):
+    """Every decode step's rids and whether it was enqueued behind a chunk
+    in flight, in the order of enqueue. And with a chunk in flight nobody
+    is preempted and no block copied."""
+    log = []
+    operands, preempt, cow = (srv._decode_operands, srv.sched._preempt_one,
+                              srv._make_writable)
+
+    def spy_operands(ready, ahead=False):
+        log.append((srv._chunk is not None, [r.rid for r in ready]))
+        return operands(ready, ahead)
+
+    def spy_preempt(exclude):
+        assert srv._chunk is None, "preempted under a chunk in flight"
+        return preempt(exclude)
+
+    def spy_cow(req, start, end, optional=False):
+        copies = srv._cow_copies
+        out = cow(req, start, end, optional)
+        assert srv._chunk is None or srv._cow_copies == copies, \
+            "a block copied under a chunk in flight"
+        return out
+
+    srv._decode_operands = spy_operands
+    srv.sched._preempt_one = spy_preempt
+    srv._make_writable = spy_cow
+    return log
+
+
+@pytest.fixture(scope="module")
+def tiny_kda_engine():
+    return init_inference("tiny-solar-open2", dtype=jnp.float32,
+                          max_out_tokens=128)
+
+
+@pytest.fixture(scope="module")
+def tiny_mamba1_engine():
+    return init_inference("tiny-phi4flash", dtype=jnp.float32,
+                          max_out_tokens=128)
+
+
+def decoding_pair(srv, n=(7, 9), max_new_tokens=40):
+    """Two requests of one chunk each, driven until both decode."""
+    rows = [srv.submit(np.arange(3 + i, 3 + i + k, dtype=np.int32),
+                       max_new_tokens=max_new_tokens)
+            for i, k in enumerate(n)]
+    drive_on_this_thread(srv, iterations=len(n) + 1)
+    assert all(h.state == "decode" for h in rows)
+    return rows
+
+
+# prompts of 2-4 chunks of 16 beside short ones, more requests than rows
+BEHIND_PROMPTS = [(40, dict(max_new_tokens=9)), (7, dict(max_new_tokens=12)),
+                  (55, dict(max_new_tokens=6)),
+                  (23, dict(max_new_tokens=8, temperature=0.9, seed=4)),
+                  (37, dict(max_new_tokens=7)), (64, dict(max_new_tokens=5))]
+
+BEHIND_CASES = {
+    # name: (engine fixture, engine config[, prompts])
+    "dense": ("tiny_engine", {}),
+    "dense_no_cache": ("tiny_engine", dict(prefix_cache=False)),
+    "moe": ("tiny_moe_engine", {}),
+    "kda": ("tiny_kda_engine", dict(prefix_cache=False)),
+    "mamba2": ("tiny_recurrent_engine", dict(prefix_cache=False)),
+    "mamba1": ("tiny_mamba1_engine", dict(prefix_cache=False)),
+    # rule 4: a pool so small that decode rows must preempt to grow. Where
+    # they would, the chunk is fetched and applied first, as today
+    "small_pool": ("tiny_engine", dict(num_blocks=10, prefix_cache=False),
+                   DELIVERY_CASES["preempted"][1]),
+}
+
+
+class TestDecodeBehindChunk:
+    @pytest.mark.parametrize("case", sorted(BEHIND_CASES))
+    def test_streams_are_those_of_a_step_driven_engine(self, request, case):
+        fixture, cfg, *prompts = BEHIND_CASES[case]
+        engine = request.getfixturevalue(fixture)
+        rng = np.random.RandomState(sum(map(ord, case)))
+        reqs = [(rng.randint(0, 250, (n,)).astype(np.int32), kw)
+                for n, kw in (prompts[0] if prompts else BEHIND_PROMPTS)]
+        streams, behind = {}, {}
+        for mode in ("step", "thread", "driver_loop"):
+            srv = serving(engine, **cfg)
+            log = watch_chunks(srv)
+            try:
+                handles = [srv.submit(p, **kw) for p, kw in reqs]
+                if mode == "step":
+                    srv.run()
+                elif mode == "thread":
+                    srv.start()
+                else:
+                    drive_on_this_thread(srv)
+                streams[mode] = [list(h.result(timeout_s=120.0))
+                                 for h in handles]
+                srv.stop()
+                assert all(h.done and h.state == "finished"
+                           and h.tokens == h._req.generated for h in handles)
+                assert srv._chunk is None and srv._flight is None
+                assert not srv.sched.running and not srv._undelivered
+                assert srv.alloc.blocks_in_use == (
+                    srv.prefix.cached_blocks if srv.prefix else 0)
+                behind[mode] = sum(b for b, _ in log)
+                if case == "small_pool":
+                    assert srv.sched.preemption_count > 0
+            finally:
+                srv.close()
+        assert streams["thread"] == streams["step"]
+        assert streams["driver_loop"] == streams["step"]
+        assert behind["step"] == 0
+        assert behind["thread"] > 0 and behind["driver_loop"] > 0
+
+    def test_the_step_is_dispatched_before_a_chunk_that_is_not_the_last_is_fetched(
+            self, tiny_engine, monkeypatch):
+        """A prompt of three chunks beside two rows that decode: the decode
+        step's enqueue comes before the chunk's fetch for chunks one and two
+        (and the kept tokens are delivered with both programs enqueued), and
+        behind the third's, whose token is a first token."""
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False))
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        log = watch_chunks(srv)
+        try:
+            a, b = decoding_pair(srv)
+            c = srv.submit(np.arange(50, 90, dtype=np.int32),
+                           max_new_tokens=4)
+            na, nb = len(a.tokens), len(b.tokens)
+            del events[:], log[:]
+            drive_on_this_thread(srv, iterations=3)
+            ra, rb, rc = a.request_id, b.request_id, c.request_id
+            want = []
+            for it in range(2):
+                want += [("dispatch", "prefill_chunk"), ("dispatch", "decode"),
+                         ("push", ra, na + it), ("push", rb, nb + it),
+                         ("fetched", "prefill_chunk"), ("fetched", "decode"),
+                         ("iteration_end",)]
+            want += [("dispatch", "prefill_chunk"),
+                     ("push", ra, na + 2), ("push", rb, nb + 2),
+                     ("fetched", "prefill_chunk"), ("push", rc, 0),
+                     ("dispatch", "decode"), ("fetched", "decode"),
+                     ("iteration_end",)]
+            assert events == want
+            assert [b for b, _ in log] == [True, True, False]
+            assert [rids for _, rids in log] == [[ra, rb], [ra, rb],
+                                                 [ra, rb, rc]]
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4
+        finally:
+            srv.close()
+
+    def test_step_keeps_today_s_order(self, tiny_engine, monkeypatch):
+        """Rule 1: ``step()`` fetches and applies every chunk before it
+        prepares the decode step, and leaves a settled engine."""
+        srv = serving(tiny_engine, prefix_cache=False)
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        log = watch_chunks(srv)
+        try:
+            a = srv.submit(np.arange(3, 10, dtype=np.int32),
+                           max_new_tokens=40)
+            srv.step()
+            c = srv.submit(np.arange(50, 90, dtype=np.int32),
+                           max_new_tokens=4)
+            del events[:], log[:]
+            for _ in range(3):
+                srv.step()
+                assert srv._chunk is None and srv._flight is None
+                assert not srv._undelivered
+            order = [e for e in events if e[0] != "push"]
+            assert order == [("dispatch", "prefill_chunk"),
+                             ("fetched", "prefill_chunk"),
+                             ("dispatch", "decode"), ("fetched", "decode"),
+                             ("iteration_end",)] * 3
+            assert [b for b, _ in log] == [False] * 3
+            assert len(c.tokens) == 2 and len(a.tokens) == 5
+        finally:
+            srv.close()
+
+    def test_a_drafter_keeps_today_s_order(self, tiny_engine, monkeypatch):
+        """Rule 3: a verify step's drafter runs on the host before its
+        enqueue; every chunk is fetched first. With the drafter suspended
+        the plain decode step goes behind the chunk like any other."""
+        srv = never_ahead(serving(
+            tiny_engine, prefix_cache=False,
+            speculative={"mode": "ngram", "num_draft_tokens": 2}))
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        try:
+            a = srv.submit(np.arange(3, 10, dtype=np.int32),
+                           max_new_tokens=40)
+            drive_on_this_thread(srv, iterations=2)
+            srv.submit(np.arange(50, 122, dtype=np.int32), max_new_tokens=4)
+            del events[:]
+            drive_on_this_thread(srv, iterations=2)
+            order = [e for e in events if e[0] != "push"]
+            assert order == [("dispatch", "prefill_chunk"),
+                             ("fetched", "prefill_chunk"),
+                             ("dispatch", "verify"), ("fetched", "verify"),
+                             ("iteration_end",)] * 2
+            srv.spec_suspended = True
+            del events[:]
+            drive_on_this_thread(srv, iterations=2)
+            order = [e for e in events if e[0] != "push"]
+            assert order == [("dispatch", "prefill_chunk"),
+                             ("dispatch", "decode"),
+                             ("fetched", "prefill_chunk"),
+                             ("fetched", "decode"), ("iteration_end",)] * 2
+            drive_on_this_thread(srv)
+            assert a.done
+        finally:
+            srv.close()
+
+    def test_only_the_iteration_s_last_chunk_waits(self, tiny_engine,
+                                                   monkeypatch):
+        """Rule 3: with two chunks an iteration (the live tuner's knob) the
+        first is fetched as today; the second waits for the decode step."""
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False))
+        srv.prefill_chunks_per_iter = 2
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        try:
+            decoding_pair(srv)
+            srv.submit(np.arange(50, 122, dtype=np.int32), max_new_tokens=4)
+            del events[:]
+            drive_on_this_thread(srv, iterations=2)
+            order = [e for e in events if e[0] != "push"]
+            assert order == [("dispatch", "prefill_chunk"),
+                             ("fetched", "prefill_chunk"),
+                             ("dispatch", "prefill_chunk"),
+                             ("dispatch", "decode"),
+                             ("fetched", "prefill_chunk"),
+                             ("fetched", "decode"), ("iteration_end",)] * 2
+        finally:
+            srv.close()
+
+    def test_a_row_that_would_preempt_keeps_today_s_order(self, tiny_engine,
+                                                          monkeypatch):
+        """Rule 4: the pool's last free page goes to the chunk; the decode
+        row that needs one has to preempt, so the chunk is fetched and
+        applied before the step is prepared, and only then is its request
+        evicted. With room again the step goes behind the chunk."""
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False,
+                                  num_blocks=8))
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        try:
+            # 15 prompt tokens: the row needs its second page one step on
+            a = srv.submit(np.arange(3, 18, dtype=np.int32),
+                           max_new_tokens=40)
+            drive_on_this_thread(srv, iterations=1)
+            c = srv.submit(np.arange(50, 146, dtype=np.int32),
+                           max_new_tokens=4)      # six pages, chunk by chunk
+            spare = srv.alloc.alloc(srv.alloc.blocks_free - 1)
+            del events[:]
+            applied = []
+            progress = srv.sched.note_prefill_progress
+            srv.sched.note_prefill_progress = lambda req, old, new: (
+                applied.append((req.rid, new, srv.sched.preemption_count)),
+                progress(req, old, new))[1]
+            srv._iterate(defer=True)        # admits c: the last free page
+            order = [e for e in events if e[0] != "push"]
+            assert order == [("dispatch", "prefill_chunk"),
+                             ("fetched", "prefill_chunk"),
+                             ("dispatch", "decode"), ("fetched", "decode"),
+                             ("iteration_end",)]
+            # the chunk's progress was applied, THEN a's page cost c its row
+            assert applied == [(c.request_id, 16, 0)]
+            assert srv.sched.preemption_count == 1 and c.state == "queued"
+            assert a.state == "decode" and len(a._req.blocks) == 2
+            srv.alloc.free(spare)
+            del events[:]
+            srv._iterate(defer=True)        # room again: behind the chunk
+            assert [e for e in events if e[0] != "push"][:3] == [
+                ("dispatch", "prefill_chunk"), ("dispatch", "decode"),
+                ("fetched", "prefill_chunk")]
+            assert srv.sched.preemption_count == 1
+        finally:
+            srv.close()
+
+    def test_unpinned_cache_entries_are_room_and_shared_pages_are_not(self):
+        from deepspeed_tpu.serving.paged_kv import PrefixCache
+
+        clock = FakeClock()
+        alloc = BlockAllocator(6)
+        cache = PrefixCache(alloc, 16)
+        sched = Scheduler(ServingConfig(block_size=16, num_blocks=6,
+                                        max_seqs=4, max_model_len=64,
+                                        prefill_chunk=16),
+                          allocator=alloc, clock=clock, prefix_cache=cache)
+        row = mk_req(0, n=16, max_new=8)
+        row.blocks, row.length = alloc.alloc(1), 16     # needs a 2nd page
+        held = alloc.alloc(5)
+        assert alloc.blocks_free == 0
+        assert not sched.grows_without_preemption([row])
+        # two entries only the cache holds: evictable, so room
+        for i, bid in enumerate(held[:2]):
+            cache.insert_key(bytes([i]), bid)
+        alloc.free(held[:2])
+        assert cache.can_evict(2) and not cache.can_evict(3)
+        assert sched.grows_without_preemption([row])
+        in_use = alloc.blocks_in_use
+        assert sched.grows_without_preemption([row, row])
+        assert not sched.grows_without_preemption([row] * 3)
+        assert alloc.blocks_in_use == in_use and cache.cached_blocks == 2
+        # an entry a request holds too is pinned
+        alloc.incref(held[:1])
+        assert not cache.can_evict(2)
+        alloc.free(held[:1])
+        # the page the next token is written to is shared: a copy first
+        row.length = 15
+        assert sched.grows_without_preemption([row])
+        alloc.incref(row.blocks)
+        assert not sched.grows_without_preemption([row])
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline", "preempt"])
+    def test_a_request_that_ends_under_its_chunk_gets_no_progress(
+            self, tiny_engine, how):
+        """Rule 5: between the chunk's enqueue and its apply the request is
+        cancelled, expires, or loses its row. Its progress is applied to
+        nobody, its pages come back once, and the rows that decode stream
+        what they stream without it."""
+        want = {}
+        for cut in (False, True):
+            clk = FakeClock()
+            srv = never_ahead(serving(tiny_engine, clock=clk,
+                                      prefix_cache=False))
+            try:
+                a, b = decoding_pair(srv, max_new_tokens=12)
+                c = srv.submit(np.arange(50, 90, dtype=np.int32),
+                               max_new_tokens=4, deadline_s=5.0)
+                settle, cuts = srv._settle, []
+
+                def spy_settle(obs, deferred=False):
+                    settle(obs, deferred)
+                    if cut and srv._chunk is not None and not cuts:
+                        # both programs are enqueued, the chunk not fetched
+                        cuts.append(c._req.row)
+                        if how == "cancel":
+                            assert srv.sched.cancel(c._req)
+                        elif how == "deadline":
+                            clk.advance(10.0)
+                            assert srv._expire_deadlines() == 1
+                        else:
+                            srv.sched.preempt(c._req)
+
+                srv._settle = spy_settle
+                chunks, tokens = srv.prefill_chunks_run, srv.prefill_tokens_run
+                srv._iterate(defer=True)
+                assert srv._chunk is None and srv._flight is None
+                # the chunk ran on the device, whoever it was for
+                assert srv.prefill_chunks_run == chunks + 1
+                assert srv.prefill_tokens_run == tokens + 16
+                if cut:
+                    assert len(cuts) == 1
+                    assert c._req.prefill_pos == 0 and c._req.length == 0
+                    assert c._req.row is None and not c._req.blocks
+                    if how == "preempt":
+                        assert c.state == "queued"
+                        drive_on_this_thread(srv)
+                        assert len(c.tokens) == 4
+                    else:
+                        assert c._req.done
+                        srv.cancel(c)       # wakes the handle
+                else:
+                    assert c._req.prefill_pos == 16
+                drive_on_this_thread(srv)
+                want[cut] = (a.tokens, b.tokens)
+                assert len(a.tokens) == len(b.tokens) == 12
+                assert srv.alloc.blocks_in_use == 0
+            finally:
+                srv.close()
+        assert want[True] == want[False]
+
+    @pytest.mark.parametrize("model", ["dense", "moe", "mamba2"])
+    def test_the_behind_chunk_count_and_the_counter(self, request,
+                                                    obs_session, model):
+        from deepspeed_tpu.observability import recorded_spans
+
+        engine = request.getfixturevalue(
+            {"dense": "tiny_engine", "moe": "tiny_moe_engine",
+             "mamba2": "tiny_recurrent_engine"}[model])
+        counter = get_registry().counter(
+            "serving/decodes_enqueued_behind_chunk")
+        before = counter.value()
+        srv = never_ahead(serving(engine, prefix_cache=False))
+        log = watch_chunks(srv)
+        a, b = decoding_pair(srv)
+        c = srv.submit(np.arange(50, 90, dtype=np.int32), max_new_tokens=4)
+        del log[:]
+        mark = len(recorded_spans())
+        drive_on_this_thread(srv, iterations=3)
+        spans = recorded_spans()[mark:]
+        steps = [s["attrs"] for s in spans if s["name"] == "serving/decode"
+                 and s["attrs"].get("rows")]
+        assert [a["behind_chunk"] for a in steps] == [1, 1, 0]
+        assert [a["ahead"] for a in steps] == [0, 0, 0]
+        assert [a["rows"] for a in steps] == [2, 2, 3]
+        assert counter.value() - before == 2 == sum(b for b, _ in log)
+        # neither program's span inside the other's, and a chunk's counts
+        # on the span that holds its fetch, once
+        by_id = {s["id"]: s for s in spans}
+        top = [s for s in spans
+               if s["name"] in ("serving/decode", "serving/prefill_chunk")]
+        assert all(by_id[s["parent_id"]]["name"] == "serving/iteration"
+                   for s in top)
+        kids = {s["id"]: [k["name"].rsplit("/", 1)[1] for k in spans
+                          if k.get("parent_id") == s["id"]
+                          and k["cat"] == "phase"] for s in top}
+        shapes = [(s["name"].split("/")[1], kids[s["id"]]) for s in top]
+        half = [("prefill_chunk", ["prepare", "dispatch"]),
+                ("decode", ["prepare", "dispatch"]),
+                ("prefill_chunk", ["fetch", "apply"]),
+                ("decode", ["fetch", "apply"])]
+        assert shapes == half * 2 + [
+            ("prefill_chunk", ["prepare", "dispatch", "fetch", "apply"]),
+            ("decode", ["prepare", "dispatch", "fetch", "apply"])]
+        chunks = [s["attrs"] for s in top
+                  if s["name"] == "serving/prefill_chunk"]
+        assert [a.get("tokens") for a in chunks] == [None, 16, None, 16, 8]
+        assert {a["rid"] for a in chunks} == {c.request_id}
+        assert [a["chunk_start"] for a in chunks] == [0, 0, 16, 16, 32]
+        counted = "moe_assignments" if model == "moe" else (
+            "ssm_rows" if model == "mamba2" else None)
+        if counted:
+            assert [counted in a for a in chunks] == [False, True, False,
+                                                      True, True]
+            fetched = [s["attrs"] for s in top
+                       if s["name"] == "serving/decode"
+                       and "fetch" in kids[s["id"]]]
+            assert len(fetched) == 3 and all(a[counted] > 0 for a in fetched)
+        # the deferred delivery lies with both programs enqueued
+        emits = [s for s in spans if s["name"] == "serving/emit"
+                 and s["attrs"]["deferred"]]
+        assert [by_id[s["parent_id"]]["name"] for s in emits] == [
+            "serving/decode", "serving/decode", "serving/prefill_chunk"]
+        drive_on_this_thread(srv)
+        assert len(c.tokens) == 4
+        srv.close()
+
+    @pytest.mark.parametrize("telemetry", ["accountant", "reqtrace"])
+    def test_no_second_is_counted_twice(self, tiny_engine, tmp_path,
+                                        telemetry):
+        """The chunk's interval runs from its enqueue to its fetch, the
+        step's behind it from the chunk's fetch to its own."""
+        configure_observability(ObservabilityConfig(
+            enabled=True, output_dir=str(tmp_path / "obs"),
+            recompile_watchdog=False, flight_recorder=False,
+            hang_watchdog=False, request_tracing=True,
+            trace_sample_rate=1.0, serve_goodput=True))
+        try:
+            ticks = iter(range(10_000))
+            srv = never_ahead(serving(tiny_engine, prefix_cache=False,
+                                      clock=lambda: float(next(ticks))))
+            a, b = decoding_pair(srv)
+            srv.submit(np.arange(50, 90, dtype=np.int32), max_new_tokens=4)
+            noted, intervals = [], []
+            acct = srv._accountant()
+            note, rt = acct.note_phase, get_session().reqtrace
+            interval, note_decode = rt.interval, rt.note_decode
+            acct.note_phase = lambda phase, s: (noted.append((phase, s)),
+                                                note(phase, s))[1]
+            rt.interval = lambda tr, phase, t0, t1, **kw: (
+                intervals.append((phase, t0, t1)),
+                interval(tr, phase, t0, t1, **kw))[1]
+            rt.note_decode = lambda tr, t0, t1, **kw: (
+                intervals.append(("decode", t0, t1)),
+                note_decode(tr, t0, t1, **kw))[1]
+            t_begin = float(next(ticks))
+            srv._iterate(defer=True)
+            t_end = float(next(ticks))
+            if telemetry == "accountant":
+                phases = dict((p, s) for p, s in noted
+                              if p in ("prefill", "decode"))
+                assert set(phases) == {"prefill", "decode"}
+                assert phases["prefill"] > 0 and phases["decode"] > 0
+                assert phases["prefill"] + phases["decode"] \
+                    <= t_end - t_begin
+            else:
+                (p, p0, p1), = [i for i in intervals if i[0] == "prefill"]
+                steps = {(t0, t1) for ph, t0, t1 in intervals
+                         if ph == "decode"}
+                (d0, d1), = steps           # one step, two rows
+                assert t_begin < p0 < p1 == d0 < d1 < t_end
+            srv.close()
+        finally:
+            reset_session()
