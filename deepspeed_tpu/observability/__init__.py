@@ -68,11 +68,10 @@ from .profiler import (DeepProfiler, install_sigusr2, parse_trace_dir,
 from .recompile import RecompileWatchdog, get_watchdog
 from .recompile import install as install_watchdog
 from .recompile import uninstall as uninstall_watchdog
-from .reqtrace import ReqTrace, RequestTracer
+from .reqtrace import ReqTrace, RequestTracer, write_chrome_trace
 from .servegoodput import ServeGoodput
 from .servegoodput import note_compile_current as _sg_note_compile
-from .spans import (NOOP_SPAN, Span, SpanTracer, noop_tracer,
-                    write_chrome_trace)
+from .spans import NOOP_SPAN, Span, SpanTracer, noop_tracer
 from .timeseries import TimeSeriesStore
 
 __all__ = [
@@ -115,6 +114,11 @@ class Observability:
                                  all_ranks=config.all_ranks,
                                  max_spans=config.max_spans,
                                  process_index=process_index)
+        # the one span call, ``obs.span(name, **counts)``: records while
+        # this session is enabled or a profiler capture is open, else hands
+        # back the shared ``NOOP_SPAN``. The tracer's own method, bound
+        # here: a delegate would repack the counts at every boundary
+        self.span = self.tracer.span
         self.watchdog: Optional[RecompileWatchdog] = None
         if self.enabled and config.recompile_watchdog:
             self.watchdog = install_watchdog(
@@ -325,13 +329,6 @@ class Observability:
             self.goodput.publish()
 
     # -- thin delegates (the API integration sites use) -------------------
-    def span(self, name: str, category: str = "span", sync: bool = False,
-             **attrs: Any):
-        """The one span call, ``obs.span(name, **counts)``: records while
-        this session is enabled or a profiler capture is open, else hands
-        back the shared ``NOOP_SPAN``."""
-        return self.tracer.span(name, category, sync, **attrs)
-
     def heartbeat(self, name: str) -> None:
         """Non-span liveness signal (comm census, pipeline census) for the
         hang watchdog."""

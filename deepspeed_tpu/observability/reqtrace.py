@@ -31,9 +31,9 @@ or TTFT past ``trace_ttft_slo_ms``. Outliers always survive, whatever the
 sample rate — the tail is the point.
 
 Export: one JSONL record per retained trace (the ``report`` CLI's
-``== request traces ==`` input) plus Chrome trace-event rendering through
-the same :func:`~.spans.write_chrome_trace` exporter the span tracer uses
-(one row per trace, pid = replica of first service).
+``== request traces ==`` input) plus Chrome trace-event rendering
+(:func:`write_chrome_trace`: one row per trace, pid = replica of first
+service; spans reach a timeline through the profiler's own capture).
 
 Everything is gated off by default (``ObservabilityConfig.request_tracing``);
 the disabled path wires nothing — no fields on requests, no events, zero
@@ -49,13 +49,21 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from ..utils.logging import logger
-from .spans import write_chrome_trace
 
-__all__ = ["ReqTrace", "RequestTracer"]
+__all__ = ["ReqTrace", "RequestTracer", "write_chrome_trace"]
 
 # terminal states a trace can finish in (mirrors the scheduler's states plus
 # the router-level "shed")
 TERMINAL_STATES = ("finished", "cancelled", "deadline_exceeded", "shed")
+
+
+def write_chrome_trace(events: List[Dict[str, Any]], path: str) -> str:
+    """Write pre-built Chrome trace events as a loadable trace file."""
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    with open(path, "w") as fh:
+        json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, fh)
+    return path
+
 
 _ACTIVE = threading.local()   # .trace — the trace whose dispatch is open on
 #   this thread (compile attribution; see RequestTracer.active)

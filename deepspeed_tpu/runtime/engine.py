@@ -1039,7 +1039,8 @@ class TrainEngine:
                 help="training batch bytes transferred to device").inc(
                     sum(int(getattr(x, "nbytes", 0))
                         for x in jax.tree.leaves(batch)))
-        _batch_span = obs.span("train_batch", step=self.global_steps)
+        _batch_span = obs.span("train_batch", cpu=True,
+                               step=self.global_steps)
         if _batch_span.recording:
             _batch_span.annotate(**hbm_counts())
         _batch_span.begin()

@@ -25,22 +25,22 @@ device works.
 
 The driver thread also enqueues a decode step AHEAD: with the step before
 it still running and that step's tokens not yet fetched, whenever nobody
-waits for the device (``_may_run_ahead``, ``_rows_ahead``: five rules about
-the engine's state, no setting). The step takes its tokens on the device,
-from the array its predecessor returned; fetch, apply, delivery, prepare
-and enqueue of the host's round then run in its shadow. So ONE decode step
-may be in flight across an iteration boundary of the driver thread
-(``_flight``); whatever needs a settled engine brings it home first
-(``_bring_home``). Under ``step()`` no program is ever in flight across an
-iteration boundary.
+waits for the device (``_ahead_held_by``, ``_rows_ahead``: rules about the
+engine's state, each with a name, ``HELD_BY``; no setting). The step takes
+its tokens on the device, from the array its predecessor returned; fetch,
+apply, delivery, prepare and enqueue of the host's round then run in its
+shadow. So ONE decode step may be in flight across an iteration boundary of
+the driver thread (``_flight``); whatever needs a settled engine brings it
+home first (``_bring_home``). Under ``step()`` no program is ever in flight
+across an iteration boundary.
 
 And INSIDE an iteration the driver thread enqueues the decode step BEHIND
 THE CHUNK, with the chunk still running, whenever the chunk is not its
-prompt's last (``_step_prefill``, ``_decode_can_follow``: five rules again,
-no setting): such a chunk's token is read by nobody and its request is no
-decode row, so the chunk's fetch and apply lie in the decode program's
-shadow and no host round lies between the two programs (``_chunk``, never
-set across an iteration's end).
+prompt's last (``_step_prefill``, ``_chunk_first_by``: rules with names
+again, ``CHUNK_FIRST_BY``; no setting): such a chunk's token is read by
+nobody and its request is no decode row, so the chunk's fetch and apply lie
+in the decode program's shadow and no host round lies between the two
+programs (``_chunk``, never set across an iteration's end).
 
 Telemetry flows through the PR-2 observability substrate: ``serving/*``
 metrics in the MetricsRegistry (ttft_ms, tpot_ms, queue_depth,
@@ -84,11 +84,33 @@ _SCORE_STEP_TAIL = 64
 HOLD_SECONDS = 0.5
 
 
-def _host_operands(call_args) -> Dict[str, int]:
-    """The counts ``<name>/dispatch`` carries: the arguments of the jitted
-    call, at any depth, that are host values (numpy arrays and scalars,
-    Python numbers), each of which the call transfers to the device before
-    it can enqueue, and their bytes (a Python number at numpy's width)."""
+# why a decode step was fetched with its successor NOT enqueued ahead of that
+# fetch (``held_by`` on the ``serving/decode`` span that fetches it), in the
+# order the rules are asked: ``_ahead_held_by`` gives the first six,
+# ``_rows_ahead`` the next three, and ``step_mode`` is an iteration that is
+# not the driver thread's
+HELD_BY = ("row_freed", "queued", "fork", "prefill", "drafter", "deadline",
+           "ends", "pages", "cow", "step_mode")
+
+# why an iteration's chunk was fetched BEFORE its decode step was enqueued
+# (``chunk_first_by`` on the span of that step): ``_step_locked`` gives
+# ``step_mode``, ``drafter`` and ``more_chunks``, ``_chunk_first_by`` the rest
+CHUNK_FIRST_BY = ("last_chunk", "pages", "no_rows", "drafter", "more_chunks",
+                  "step_mode")
+
+# ``hbm_*`` go onto every this-many-th ``serving/iteration`` span: a peak
+# loses nothing by it, and the allocator's statistics of every local device
+# are not read inside each iteration's account (the goodput accountant
+# publishes at the same cadence)
+ACCOUNT_EVERY = 16
+
+
+def _host_operands(call_args) -> tuple:
+    """(number, bytes) of the arguments of a jitted call, at any depth, that
+    are host values (numpy arrays and scalars, Python numbers), each of which
+    the call transfers to the device before it can enqueue (a Python number
+    at numpy's width): what ``<name>/dispatch`` carries as ``host_operands``
+    and ``host_operand_bytes``."""
     import jax
 
     n = nbytes = 0
@@ -99,7 +121,7 @@ def _host_operands(call_args) -> Dict[str, int]:
         elif isinstance(leaf, (bool, int, float, complex)):
             n += 1
             nbytes += np.asarray(leaf).nbytes
-    return {"host_operands": n, "host_operand_bytes": nbytes}
+    return n, nbytes
 
 
 @dataclasses.dataclass(eq=False)
@@ -177,20 +199,17 @@ class ServingEngine:
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..inference.kv_cache import ring_blocks
-        from ..models.transformer import (MIXERS, ffn_layers, pool_readers,
+        from ..models.transformer import (MIXERS, ffn_layers,
                                           recurrent_layers, ring_layers,
                                           tail_runs)
 
         mixer, layers = recurrent_layers(cfg)
         self._recurrent_layers = len(layers)
         # window layers keep their keys in a ring of pages a row, beside the
-        # row's state slot and bounded whatever the row's length; the layers
-        # that read the pages (their own, or the full layer's) are the rest
-        # of the softmax layers
+        # row's state slot and bounded whatever the row's length
         self._window_layers = len(ring_layers(cfg))
         self._ring_blocks = ring_blocks(cfg, self.config.prefill_chunk,
                                         self.config.block_size)
-        self._page_readers = len(pool_readers(cfg))
         # the stack's last runs keep nothing of a token: the chunk program
         # runs them, and the head, for a prompt's LAST chunk alone and is
         # told which chunk that is (``paged_kv.pack_chunk``'s ``last``)
@@ -332,9 +351,18 @@ class ServingEngine:
         # the decode step that is enqueued and not fetched, where the
         # driver thread left one at an iteration's end (``_step_decode``),
         # and the scheduler's count of rows given back when
-        # ``_may_run_ahead`` last asked
+        # ``_ahead_held_by`` last asked
         self._flight: Optional[_Enqueued] = None
         self._rows_released_seen = 0
+        # why this iteration's chunk was fetched before its decode step was
+        # enqueued (a name of ``CHUNK_FIRST_BY``), from ``_step_prefill`` to
+        # the span of that step; None where no chunk ran, or the step went
+        # behind it
+        self._chunk_first: Optional[str] = None
+        # the host values among a program's params and arena, (number,
+        # bytes) by program name: counted at its first recorded dispatch
+        # and kept (``_operand_counts``)
+        self._fixed_operands: Dict[str, tuple] = {}
         # the chunk that is enqueued and not fetched, inside an iteration of
         # the driver thread: ``_step_decode`` enqueues its step behind it
         # and lands it in that step's shadow (``_step_prefill``). Never
@@ -898,8 +926,9 @@ class ServingEngine:
         may stay in flight at the iteration's end, for the next iteration
         to enqueue its successor ahead of its fetch (``_step_decode``)."""
         obs = get_session()
-        with obs.span("serving/iteration") as span:
-            lock_wait = obs.span("serving/iteration/lock_wait").begin()
+        with obs.span("serving/iteration", cpu=True) as span:
+            lock_wait = obs.span("serving/iteration/lock_wait",
+                                 cpu=True).begin()
             with self._lock:
                 lock_wait.end()
                 acct = self._accountant()
@@ -933,7 +962,7 @@ class ServingEngine:
                         # per-iteration publishing would put O(window)
                         # breach-deque scans on the decode loop's critical
                         # path. close() publishes the final snapshot.
-                        if acct.iterations % 16 == 1:
+                        if acct.iterations % ACCOUNT_EVERY == 1:
                             acct.publish()
             # the live tuner's decision tick runs OUTSIDE the engine lock:
             # the controller is foreign code with its own lock, and its knob
@@ -961,19 +990,19 @@ class ServingEngine:
         iteration, admission first, is the next. ``step()`` brings a step
         it finds in flight home and goes on."""
         flight = self._flight
-        if flight is not None:
-            if not self._deferring:
-                self._bring_home()
-            elif rows := self._rows_ahead(flight):
+        self._chunk_first = None
+        if flight is not None and not self._deferring:
+            self._bring_home("step_mode")
+        elif flight is not None:
+            rows, held_by = self._rows_ahead(flight)
+            if rows:
                 return self._step_decode(rows)
-            else:
-                self._land(obs, flight)
-                # its tokens wait, as those of every step that was fetched
-                # with nothing behind it do, for the next enqueue (the
-                # chunk's, if a caller is at the door): nothing is
-                # delivered into the gap
-                self._account(obs)
-                return True
+            self._land(obs, flight, held_by=held_by)
+            # its tokens wait, as those of every step that was fetched with
+            # nothing behind it do, for the next enqueue (the chunk's, if a
+            # caller is at the door): nothing is delivered into the gap
+            self._account(obs)
+            return True
         with obs.span("serving/admit") as span:
             # before admit: an already-expired queued request must
             # never take a decode row first
@@ -990,8 +1019,9 @@ class ServingEngine:
             # step to be enqueued behind it: the driver thread's form only,
             # and never under a verify step, whose drafter runs first
             ran_chunk = self._step_prefill(
-                may_wait=(self._deferring and not drafting
-                          and i == chunks - 1))
+                first_by=("step_mode" if not self._deferring
+                          else "drafter" if drafting
+                          else "more_chunks" if i < chunks - 1 else None))
             progress |= ran_chunk
             if not ran_chunk:
                 break
@@ -1027,8 +1057,10 @@ class ServingEngine:
                     blocks_total=self.alloc.capacity,
                     preemptions=self.sched.preemption_count,
                     holds=self.holds,
+                    **obs.tracer.gc_counts(),
                     **self._state_counts(),
-                    **hbm_counts())
+                    **(hbm_counts()
+                       if self._iterations % ACCOUNT_EVERY == 0 else {}))
 
     def _expire_deadlines(self) -> int:
         """Deadline enforcement at decode time: a request whose absolute
@@ -1183,12 +1215,13 @@ class ServingEngine:
         which is the one transfer the call makes, and what is on the device
         already (the sampling key; the decode program's last tokens). A call
         that compiled (the jitted ``program``'s call cache grew) is set-up,
-        not a hold, however long."""
+        not a hold, however long. The span's counts (``_operand_counts``) are
+        taken before it opens: counting them is none of the enqueue."""
+        span = obs.span(name + "/dispatch", category="phase")
+        if span.recording:
+            span.annotate(**self._operand_counts(name, args))
         t0 = self.clock()
-        with obs.span(name + "/dispatch", category="phase") as span:
-            if span.recording:
-                span.annotate(**_host_operands(
-                    (self.engine.params, self._arena, args)))
+        with span:
             with self._trace_dispatch(obs.reqtrace, trace):
                 with mesh_mod.ambient(self.engine.mesh):
                     tok, *_, self._arena = program(
@@ -1204,11 +1237,25 @@ class ServingEngine:
             call_s = 0.0
         return _Enqueued(name, tok, t0, t_call, call_s)
 
+    def _operand_counts(self, name: str, args) -> Dict[str, int]:
+        """``host_operands`` and ``host_operand_bytes`` of a dispatch of
+        program ``name``, read only while its span records. The parameters
+        and the arena are device arrays for the engine's life: their leaves
+        are walked once a program name and the count kept; ``args`` is
+        walked at every dispatch."""
+        fixed = self._fixed_operands.get(name)
+        if fixed is None:
+            fixed = self._fixed_operands[name] = _host_operands(
+                (self.engine.params, self._arena))
+        n, nbytes = _host_operands(args)
+        return {"host_operands": fixed[0] + n,
+                "host_operand_bytes": fixed[1] + nbytes}
+
     def _fetch(self, obs, sent: "_Enqueued"):
         """``<name>/fetch``: the wait for an enqueued program's tokens
         (device time + D2H: the host's sync). Returns (tokens, the engine's
         clock behind them)."""
-        with obs.span(sent.name + "/fetch", category="phase"):
+        with obs.span(sent.name + "/fetch", category="phase", cpu=True):
             tok = np.asarray(sent.tok)
         t1 = self.clock()
         fetch_s = t1 - sent.t_call
@@ -1252,12 +1299,6 @@ class ServingEngine:
         if span.recording and self._recurrent_layers:
             span.annotate(**{self._recurrent_rows:
                              real_rows * self._recurrent_layers})
-            if self._window_layers:
-                # (row, layer) reads of a ring, and of the pool the full
-                # and cross layers share
-                span.annotate(
-                    window_rows=real_rows * self._window_layers,
-                    shared_kv_reads=real_rows * self._page_readers)
         if not self._moe_experts_total:
             return fetched
         if span.recording:
@@ -1269,12 +1310,14 @@ class ServingEngine:
                           moe_max_expert_rows=largest)
         return fetched[:n]
 
-    def _step_prefill(self, may_wait: bool = False) -> bool:
+    def _step_prefill(self, first_by: Optional[str] = "step_mode") -> bool:
         """One chunk of the oldest prompt in prefill, enqueued, and fetched
-        and applied too, unless it ``may_wait`` (``_step_locked``: the driver
-        thread's iteration, at its last chunk, with no drafter), is not its
+        and applied too, unless nothing says it must be fetched first:
+        neither the iteration (``first_by``, from ``_step_locked``: None for
+        the driver thread's iteration, at its last chunk, with no drafter)
+        nor the chunk and the decode rows (``_chunk_first_by``: it is not its
         prompt's last, and this iteration's decode rows can grow without a
-        preemption (``_decode_can_follow``). Then it stays in flight
+        preemption). Then it stays in flight
         (``_chunk``): nothing the decode step's operands are reckoned from
         waits for it (its token is read by nobody, its request is no decode
         row), so ``_step_decode`` enqueues that step BEHIND it, the device
@@ -1282,7 +1325,8 @@ class ServingEngine:
         the chunk is fetched and applied in the decode program's shadow. A
         prompt's last chunk brings a first token and a new decode row: it is
         always fetched, applied and delivered before the decode step is
-        prepared."""
+        prepared. Which rule had the chunk fetched first is kept for the
+        span of the iteration's step (``_chunk_first``)."""
         req = self.sched.next_prefill()
         if req is None:
             return False
@@ -1292,7 +1336,7 @@ class ServingEngine:
         start = req.prefill_pos
         n_valid = min(C, int(src.size) - start)
         last = start + n_valid == int(src.size)
-        with obs.span("serving/prefill_chunk", rid=req.rid,
+        with obs.span("serving/prefill_chunk", cpu=True, rid=req.rid,
                       chunk_start=int(start),
                       sampled_rows=self._sampled_rows([req])) as span:
             with obs.span("serving/prefill_chunk/prepare", category="phase"):
@@ -1313,7 +1357,8 @@ class ServingEngine:
                                  packed, self._base_rng, trace=req.trace)
             sent.rows = [(req, req.row)]
             sent.start, sent.tokens = int(start), int(n_valid)
-            if may_wait and not last and self._decode_can_follow():
+            self._chunk_first = first_by or self._chunk_first_by(last)
+            if self._chunk_first is None:
                 self._chunk = sent
                 return True
             if self._deferring:
@@ -1321,18 +1366,27 @@ class ServingEngine:
             self._land_chunk(obs, sent, span)
         return True
 
-    def _decode_can_follow(self) -> bool:
-        """Whether this iteration's decode step may be enqueued behind a
-        chunk that is still in flight, as far as its rows and their pages
-        say: there are rows, the page each one's next token needs comes from
+    def _chunk_first_by(self, last: bool) -> Optional[str]:
+        """What keeps this iteration's decode step from being enqueued
+        behind its chunk while that is still in flight, as far as the chunk,
+        the rows and their pages say (a name of ``CHUNK_FIRST_BY``), or None
+        where nothing does. ``last_chunk``: the chunk is its prompt's last.
+        ``no_rows``: no request decodes. ``pages``: a row's next token needs
+        a page that only a preemption would free, or writes into a shared
+        block. Else the page each one's next token needs comes from
         the free list or from an unpinned prefix-cache entry (which frees
         only blocks no request holds, and whoever takes one writes it in a
         program enqueued behind the chunk), and none of them writes into a
         shared block. So nobody is preempted and no block copied with the
         chunk's progress not yet applied; where either would be, the chunk
         is fetched and applied first. Asked before anything is taken."""
+        if last:
+            return "last_chunk"
         dec = self.sched.decode_requests()
-        return bool(dec) and self.sched.grows_without_preemption(dec)
+        if not dec:
+            return "no_rows"
+        return (None if self.sched.grows_without_preemption(dec)
+                else "pages")
 
     def _land_chunk(self, obs, sent: "_Enqueued", span=None) -> None:
         """A chunk's token fetched and its progress applied
@@ -1348,7 +1402,7 @@ class ServingEngine:
         from here (``_Enqueued.since``): no second is counted twice."""
         (req, row), = sent.rows
         if span is None:
-            with obs.span("serving/prefill_chunk", rid=req.rid,
+            with obs.span("serving/prefill_chunk", cpu=True, rid=req.rid,
                           chunk_start=sent.start,
                           sampled_rows=self._sampled_rows([req])) as span:
                 return self._land_chunk(obs, sent, span)
@@ -1554,44 +1608,62 @@ class ServingEngine:
         return paged_kv.pack_decode_rows(bt, lengths, tokens, temps, topks,
                                          topps, seeds, steps)
 
-    def _may_run_ahead(self, flight: "_Enqueued") -> bool:
-        """Whether the decode step behind ``flight``, which is enqueued and
-        not fetched, may be enqueued before that fetch, as far as the
-        engine's state says (its rows and pages: ``_rows_ahead``). Nothing
-        waits for the device: the queue is empty, every running request is
-        a row of ``flight`` (none in prefill, none that found no page), no
-        sibling waits for its fork, no drafter proposes, no deadline has
-        passed. And no row has come free since this was last asked: a freed
-        row is about to be taken, and the chunk that takes it must find the
-        device as free as it would without a step ahead."""
+    def _ahead_held_by(self, flight: "_Enqueued") -> Optional[str]:
+        """What keeps the decode step behind ``flight``, which is enqueued
+        and not fetched, from being enqueued before that fetch, as far as
+        the engine's state says (its rows and pages: ``_rows_ahead``): the
+        first, in this order, of these names of ``HELD_BY``, or None where
+        nothing waits for the device. ``row_freed``: a row has come free
+        since this was last asked; a freed row is about to be taken, and the
+        chunk that takes it must find the device as free as it would without
+        a step ahead. ``queued``: the queue is not empty. ``fork``: a
+        sibling waits for its fork. ``prefill``: a running request is not a
+        row of ``flight`` (it is in prefill, or found no page). ``drafter``:
+        a drafter proposes. ``deadline``: a deadline has passed."""
         freed = self.sched.rows_released != self._rows_released_seen
         self._rows_released_seen = self.sched.rows_released
-        return not (freed or self.sched.queued or self._pending_forks
-                    or len(self.sched.running) != len(flight.rows)
-                    or (self._drafter is not None
-                        and not self.spec_suspended)
-                    or self.sched.deadline_due(self.clock))
+        if freed:
+            return "row_freed"
+        if self.sched.queued:
+            return "queued"
+        if self._pending_forks:
+            return "fork"
+        if len(self.sched.running) != len(flight.rows):
+            return "prefill"
+        if self._drafter is not None and not self.spec_suspended:
+            return "drafter"
+        if self.sched.deadline_due(self.clock):
+            return "deadline"
+        return None
 
-    def _rows_ahead(self, flight: "_Enqueued") -> Optional[List[Request]]:
-        """The rows of the step to enqueue AHEAD of ``flight``'s fetch, or
-        None where it must wait for that fetch: ``flight``'s rows less
-        those that end there by their ``max_new_tokens``, each with the
-        page its next position needs taken from the free list (no eviction,
-        no preemption and no copy-on-write on behalf of a step ahead)."""
-        if not self._may_run_ahead(flight):
-            return None
+    def _rows_ahead(self, flight: "_Enqueued") -> tuple:
+        """(rows, None): the rows of the step to enqueue AHEAD of
+        ``flight``'s fetch: ``flight``'s rows less those that end there by
+        their ``max_new_tokens``, each with the page its next position needs
+        taken from the free list (no eviction, no preemption and no
+        copy-on-write on behalf of a step ahead). Or (None, the name of
+        ``HELD_BY`` that says why it must wait for that fetch):
+        ``_ahead_held_by``'s, else ``ends`` (every row ends at ``flight``),
+        ``pages`` (the free list is short of what the rows need) or ``cow``
+        (a row would write into a shared block)."""
+        held_by = self._ahead_held_by(flight)
+        if held_by is not None:
+            return None, held_by
         rows = [r for r, _ in flight.rows
                 if len(r.generated) + 1 < r.max_new_tokens]
+        if not rows:
+            return None, "ends"
         bs = self.config.block_size
         need = sum(max(paged_kv.blocks_for_tokens(r.length + 2, bs)
                        - len(r.blocks), 0) for r in rows)
-        if need > self.alloc.blocks_free or any(
-                self.sched.cow_block_indices(r, r.length + 1, r.length + 2)
-                for r in rows):
-            return None
+        if need > self.alloc.blocks_free:
+            return None, "pages"
+        if any(self.sched.cow_block_indices(r, r.length + 1, r.length + 2)
+               for r in rows):
+            return None, "cow"
         for r in rows:
             self.sched.try_extend_blocks(r, r.length + 2)
-        return rows
+        return rows, None
 
     def _step_decode(self, ahead: Optional[List[Request]] = None) -> bool:
         """One decode step enqueued, and one brought to the host. ``ahead``
@@ -1600,17 +1672,23 @@ class ServingEngine:
         fetched and applied in this step's shadow, and its tokens are
         delivered at once. Else the step is enqueued behind the state the
         host holds, and fetched here too, unless the driver thread may run
-        the next one ahead of it (``_may_run_ahead``): then it stays in
-        flight for the next iteration. With the iteration's chunk still in
-        flight (``_chunk``: ``_step_prefill`` left it there) the step is
-        enqueued BEHIND THE CHUNK, and then the chunk is landed and the step
-        behind it, each in a span of its own."""
+        the next one ahead of it (``_ahead_held_by`` names nothing): then it
+        stays in flight for the next iteration. With the iteration's chunk
+        still in flight (``_chunk``: ``_step_prefill`` left it there) the
+        step is enqueued BEHIND THE CHUNK, and then the chunk is landed and
+        the step behind it, each in a span of its own. While the span
+        records it says of a step enqueued behind a program in flight
+        whether it came too ``late`` (that program's tokens were ready at
+        the enqueue: the device stood idle between the two), of a step
+        whose chunk was fetched first which rule had it so
+        (``chunk_first_by``), and of a step fetched with no successor
+        enqueued which rule held that one (``held_by``, in ``_land``)."""
         dec = ahead or self.sched.decode_requests()
         if not dec:
             return False
         obs = get_session()
         chunk = self._chunk
-        with obs.span("serving/decode",
+        with obs.span("serving/decode", cpu=True,
                       max_rows=self.config.max_seqs) as span:
             with obs.span("serving/decode/prepare", category="phase"):
                 ready = ahead or self._ready_decode_rows(dec)
@@ -1620,6 +1698,8 @@ class ServingEngine:
                               behind_chunk=int(bool(ready)
                                                and chunk is not None),
                               sampled_rows=self._sampled_rows(ready))
+                if ready and chunk is None and self._chunk_first:
+                    span.annotate(chunk_first_by=self._chunk_first)
             if not ready:
                 return False
             first_trace = (next((r.trace for r in ready
@@ -1631,6 +1711,18 @@ class ServingEngine:
                 self._last_tokens, trace=first_trace)
             sent.rows = [(r, r.row) for r in ready]
             self._last_tokens = sent.tok
+            behind = chunk or before
+            if behind is not None and (span.recording or obs.enabled):
+                # one non-blocking question to the array the engine holds
+                late = int(behind.tok.is_ready())
+                span.annotate(late=late)
+                if late and obs.enabled:
+                    obs.registry.counter(
+                        "serving/steps_enqueued_late",
+                        help="decode steps enqueued behind a program in "
+                             "flight (ahead, or behind their chunk) that "
+                             "had already ended: the host's round outlasted "
+                             "it").inc()
             if self._deferring:
                 self._settle(obs, deferred=True)
             if chunk is not None:
@@ -1649,19 +1741,25 @@ class ServingEngine:
                              "(the driver thread's form)").inc()
                 self._land(obs, before, span)
                 self._flush(deferred=True)   # at once: the device is busy
-            elif not (self._deferring and self._may_run_ahead(sent)):
-                self._land(obs, sent, span)
+            elif held_by := ("step_mode" if not self._deferring
+                             else self._ahead_held_by(sent)):
+                self._land(obs, sent, span, held_by=held_by)
         if chunk is not None:
             # neither program's span inside the other's. A request is still
-            # in prefill, so nothing stays in flight at the iteration's end
+            # in prefill (the chunk was not its prompt's last), so nothing
+            # stays in flight at the iteration's end: the rule is known
+            # without being asked
             self._land_chunk(obs, chunk)
-            self._land(obs, sent)
+            self._land(obs, sent, held_by="prefill")
         return True
 
-    def _land(self, obs, sent: "_Enqueued", span=None) -> None:
+    def _land(self, obs, sent: "_Enqueued", span=None,
+              held_by: Optional[str] = None) -> None:
         """A decode step's tokens fetched and applied
         (``serving/decode/fetch`` and ``.../apply``, under ``span``: the
-        ``serving/decode`` span that is open, else one of its own). A row
+        ``serving/decode`` span that is open, else one of its own).
+        ``held_by``: the step is fetched with its successor not enqueued,
+        and this name of ``HELD_BY`` is why; the span carries it. A row
         whose request ended behind the step's enqueue (by its
         ``eos_token_id`` at the step before, with this one ahead) is
         dropped: its token goes nowhere, and what it wrote lies in pages
@@ -1671,11 +1769,13 @@ class ServingEngine:
         request tracer from that program's fetch to its own: no second is
         counted twice."""
         if span is None:
-            with obs.span("serving/decode",
+            with obs.span("serving/decode", cpu=True,
                           max_rows=self.config.max_seqs) as span:
-                return self._land(obs, sent, span)
+                return self._land(obs, sent, span, held_by)
         if self._flight is sent:
             self._flight = None
+        if held_by is not None:
+            span.annotate(held_by=held_by)
         nxt, t1 = self._fetch(obs, sent)
         if self._flight is not None:
             self._flight.since = t1
@@ -1705,12 +1805,14 @@ class ServingEngine:
             if acct is not None:
                 acct.note_phase("sample_host", self.clock() - t1)
 
-    def _bring_home(self) -> None:
+    def _bring_home(self, held_by: Optional[str] = None) -> None:
         """A settled engine, for whoever needs one (under the engine lock):
         the decode step in flight, if the driver thread left one, is
-        fetched and applied, and every applied token is delivered."""
+        fetched and applied (``held_by``: as ``_land``; a caller that needs
+        the engine settled gives none), and every applied token is
+        delivered."""
         if self._flight is not None:
-            self._land(get_session(), self._flight)
+            self._land(get_session(), self._flight, held_by=held_by)
         self._flush()
 
     def _step_verify(self) -> bool:
@@ -1726,7 +1828,7 @@ class ServingEngine:
         if not dec:
             return False
         obs = get_session()
-        with obs.span("serving/verify",
+        with obs.span("serving/verify", cpu=True,
                       max_rows=self.config.max_seqs) as span:
             return self._verify_rows(obs, span, dec)
 
@@ -1806,6 +1908,8 @@ class ServingEngine:
             #   exact key the non-speculative path uses
         span.annotate(rows=len(plan), tokens=int(n_valid.sum()),
                       sampled_rows=self._sampled_rows(r for r, _ in plan))
+        if self._chunk_first:
+            span.annotate(chunk_first_by=self._chunk_first)
         rt = obs.reqtrace
         first_trace = (next((r.trace for r, _ in plan
                              if r.trace is not None), None)

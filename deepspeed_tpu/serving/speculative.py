@@ -302,7 +302,7 @@ class DraftModelDrafter(Drafter):
             if not fed:
                 break
             with self._mesh_mod.ambient(self.engine.mesh):
-                with obs.span("serving/draft_decode", batch=len(fed)):
+                with obs.span("serving/draft_decode"):
                     nxt, self._arena = self._decode(
                         self.engine.params, self._arena,
                         paged_kv.pack_decode_rows(bt, lengths, tokens, zR,

@@ -58,6 +58,20 @@ def _reset_global_mesh():
     mesh.reset_mesh()
 
 
+@pytest.fixture(autouse=True)
+def _collector_unwatched(request, monkeypatch):
+    """A collection can fall into any test, and while a tracer records it
+    closes as a ``runtime/gc`` span in whatever record that test counts. So
+    only the tests that are about it (a class with ``watches_collector``) let
+    a tracer hook ``gc.callbacks``; everywhere else the tracers watch
+    nothing, as in an untraced run."""
+    if not getattr(request.cls, "watches_collector", False):
+        from deepspeed_tpu.observability import spans
+
+        monkeypatch.setattr(spans, "_gc_watch", lambda tracer, on: None)
+    yield
+
+
 @pytest.fixture
 def devices8():
     devs = jax.devices()

@@ -93,16 +93,6 @@ class TestSpans:
             pass
         assert tr_all.snapshot()[0]["pid"] == 1
 
-    def test_decorator(self):
-        tr = SpanTracer(process_index=0)
-
-        @tr.trace("work")
-        def f(a):
-            return a + 1
-
-        assert f(1) == 2
-        assert tr.snapshot()[0]["name"] == "work"
-
     def test_non_lexical_begin_end(self):
         tr = SpanTracer(process_index=0)
         s = tr.span("profile").begin()

@@ -7,6 +7,7 @@ enqueue to the next; the program's TTFT counts from entry to ``submit()``;
 one clock reading serves each dispatch boundary, and a program that held the
 engine is counted with nothing recording."""
 
+import gc
 import glob
 import os
 import threading
@@ -72,6 +73,15 @@ class Capture:
         return out
 
 
+def counts(attrs, cpu=False):
+    """A span's counts less ``cpu_us``, which a span opened with ``cpu``
+    carries and no test can give a number for."""
+    assert ("cpu_us" in attrs) == cpu
+    assert not cpu or (isinstance(attrs["cpu_us"], int)
+                       and attrs["cpu_us"] >= 0)
+    return {k: v for k, v in attrs.items() if k != "cpu_us"}
+
+
 # ---------------------------------------------------------------------------
 # the span call
 
@@ -86,6 +96,8 @@ class TestSpanCall:
         reads = []
         monkeypatch.setattr(time, "perf_counter_ns",
                             lambda: reads.append(1) or 0)
+        monkeypatch.setattr(time, "thread_time_ns", lambda: pytest.fail(
+            "the thread's clock was read with nothing recording"))
         with obs.span("serving/x", rows=3) as s:
             s.annotate(rows=4)
         assert s is NOOP_SPAN and not s.recording and s.duration_s == 0.0
@@ -96,7 +108,8 @@ class TestSpanCall:
         obs = get_session()
 
         def work():
-            with obs.span("serving/outer", it=3, queued=2) as outer:
+            with obs.span("serving/outer", cpu=True, it=3,
+                          queued=2) as outer:
                 time.sleep(0.002)
                 with obs.span("serving/inner", rows=5):
                     time.sleep(0.001)
@@ -109,8 +122,8 @@ class TestSpanCall:
             assert not th.is_alive()
         (line,) = cap.events("serving/").values()      # one thread, one line
         by = {name: (a, b, stats) for name, a, b, stats in line}
-        assert by["serving/outer"][2] == {"it": 3, "queued": 2,
-                                          "blocks_in_use": 7}
+        assert counts(by["serving/outer"][2], cpu=True) == {
+            "it": 3, "queued": 2, "blocks_in_use": 7}
         assert by["serving/inner"][2] == {"rows": 5}
         assert by["serving/outer"][0] <= by["serving/inner"][0]
         assert by["serving/inner"][1] <= by["serving/outer"][1]
@@ -225,6 +238,181 @@ class TestSpanCall:
 
     def test_hbm_counts_are_empty_on_a_statless_backend(self):
         assert hbm_counts() == {}       # the CPU reports no memory stats
+
+    def test_snapshot_gives_the_record_it_gave_with_cpu_us_added(self):
+        """One golden record, key for key: the dictionary is built when the
+        record is read, not as the span closes. ``cpu_us`` where the span
+        asked for it and nowhere else."""
+        tr = SpanTracer(process_index=3, all_ranks=True)
+        with tr.span("serving/decode", rows=2):
+            with tr.span("serving/decode/fetch", category="phase",
+                         cpu=True) as child:
+                pass
+            with tr.span("serving/decode/apply", category="phase"):
+                pass
+        fetch, apply_, decode = tr.snapshot()
+        assert "attrs" not in apply_ and decode["attrs"] == {"rows": 2}
+        assert list(fetch) == ["type", "name", "cat", "id", "start_s",
+                               "end_s", "dur_us", "depth", "synced",
+                               "parent_id", "attrs", "pid", "tid", "thread"]
+        assert fetch == {
+            "type": "span", "name": "serving/decode/fetch", "cat": "phase",
+            "id": 2, "start_s": child.start_ns / 1e9,
+            "end_s": child.end_ns / 1e9,
+            "dur_us": (child.end_ns - child.start_ns) / 1e3, "depth": 1,
+            "synced": False, "parent_id": 1,
+            "attrs": {"cpu_us": fetch["attrs"]["cpu_us"]}, "pid": 3,
+            "tid": threading.get_ident() & 0xFFFF,
+            "thread": threading.current_thread().name}
+        assert "parent_id" not in decode and decode["depth"] == 0
+        assert tr.snapshot() == [fetch, apply_, decode]
+        tr.close()
+
+    def test_cpu_us_is_what_the_thread_ran(self):
+        """A small part of the duration for a span that sleeps; for one
+        that spins until its thread has run 30 ms, those 30 ms and at most
+        the duration (however many other threads the machine runs)."""
+        tr = SpanTracer(process_index=0)
+        with tr.span("sleeps", cpu=True):
+            time.sleep(0.05)
+        with tr.span("spins", cpu=True):
+            until = time.thread_time() + 0.03
+            while time.thread_time() < until:
+                pass
+        sleeps, spins = tr.snapshot()
+        assert sleeps["attrs"]["cpu_us"] < 0.2 * sleeps["dur_us"]
+        assert 29_000 <= spins["attrs"]["cpu_us"] <= spins["dur_us"]
+        tr.close()
+
+    def test_off_cpu_self_time_is_what_a_span_waited(self):
+        """(duration less the children's) less (``cpu_us`` less the
+        children's): a parent that sleeps 30 ms around a child that spins."""
+        tr = SpanTracer(process_index=0)
+        with tr.span("parent", cpu=True):
+            time.sleep(0.03)
+            with tr.span("child", cpu=True):
+                until = time.perf_counter() + 0.02
+                while time.perf_counter() < until:
+                    pass
+        child, parent = tr.snapshot()
+        waited = (parent["dur_us"] - child["dur_us"]) - (
+            parent["attrs"]["cpu_us"] - child["attrs"]["cpu_us"])
+        assert 25e3 < waited <= parent["dur_us"] - child["dur_us"]
+        tr.close()
+
+
+# ---------------------------------------------------------------------------
+# the collector's pauses
+
+
+class TestCollectorSpans:
+    watches_collector = True        # ``tests/conftest.py``: elsewhere no
+    #   tracer hooks the collector, so no test's record holds a stray span
+
+    @pytest.fixture(autouse=True)
+    def _nobody_watches_yet(self):
+        """Tracers that other tests of this class built enabled and never
+        closed would still watch: each test starts from a process that hooks
+        nothing."""
+        from deepspeed_tpu.observability import spans as spans_mod
+
+        for tracer in list(spans_mod._gc_tracers):
+            spans_mod._gc_watch(tracer, False)
+        assert spans_mod._gc_callback not in gc.callbacks
+
+    def test_a_forced_collection_is_one_span_of_its_threads_open_span(
+            self, tmp_path):
+        obs = get_session()
+        before = list(gc.callbacks)
+        seen = {}
+
+        def collect():
+            with obs.span("serving/submit") as mine:
+                seen["mine"] = mine.id
+                gc.collect()
+
+        with Capture(tmp_path) as cap:
+            with obs.span("serving/iteration") as other:
+                assert len(gc.callbacks) == len(before) + 1
+                th = threading.Thread(target=collect, name="caller")
+                th.start()
+                th.join(timeout=30)
+            full = [s for s in recorded_spans() if s["name"] == "runtime/gc"
+                    and s["attrs"]["generation"] == 2]
+        (span,) = full
+        assert span["parent_id"] == seen["mine"] != other.id
+        assert span["thread"] == "caller" and span["cat"] == "runtime"
+        assert set(span["attrs"]) == {"generation", "collected", "pause_us",
+                                      "cpu_us"}
+        assert span["attrs"]["pause_us"] == pytest.approx(span["dur_us"])
+        # an event of the capture too, on the thread it ran on
+        events = [e for line in cap.events("runtime/gc").values()
+                  for e in line if e[3].get("generation") == 2]
+        assert len(events) == 1 and events[0][3]["pause_us"] > 0
+        # the capture has closed: reading the record takes the hook out
+        assert len(recorded_spans()) >= 3 and gc.callbacks == before
+
+    def test_a_root_on_a_thread_with_no_open_span(self, tmp_path):
+        obs = get_session()
+        with Capture(tmp_path):
+            with obs.span("serving/iteration"):
+                pass
+            th = threading.Thread(target=gc.collect, name="bare")
+            th.start()
+            th.join(timeout=30)
+            (span,) = [s for s in recorded_spans()
+                       if s["name"] == "runtime/gc" and s["thread"] == "bare"]
+        assert "parent_id" not in span and span["depth"] == 0
+
+    def test_short_collections_are_counted_not_recorded(self, monkeypatch):
+        from deepspeed_tpu.observability import spans as spans_mod
+
+        tr = SpanTracer(process_index=0)
+        assert tr.gc_counts() == {"gc_collections": 0, "gc_pause_us": 0}
+        monkeypatch.setattr(spans_mod, "GC_RECORD_US", 60e6)
+        gc.collect(0)
+        gc.collect(1)
+        got = tr.gc_counts()
+        assert got["gc_collections"] == 2 and got["gc_pause_us"] >= 0
+        assert tr.gc_counts() == {"gc_collections": 0, "gc_pause_us": 0}
+        assert tr.snapshot() == []
+        gc.collect()                    # generation 2: whatever it lasted
+        assert [s["name"] for s in tr.snapshot()] == ["runtime/gc"]
+        tr.close()
+
+    def test_the_hook_is_there_exactly_while_something_records(
+            self, tmp_path):
+        before = list(gc.callbacks)
+        tr = SpanTracer(process_index=0)            # enabled: it records
+        assert len(gc.callbacks) == len(before) + 1
+        other = SpanTracer(process_index=0)
+        assert len(gc.callbacks) == len(before) + 1     # ONE hook a process
+        tr.close()
+        assert len(gc.callbacks) == len(before) + 1
+        other.close()
+        assert gc.callbacks == before
+        obs = get_session()
+        with Capture(tmp_path):
+            assert gc.callbacks == before       # no span() call has seen it
+            obs.span("serving/x").begin().end()
+            assert len(gc.callbacks) == len(before) + 1
+        obs.span("serving/y")                   # the edge, seen at a call
+        assert gc.callbacks == before
+
+    def test_nothing_recording_never_touches_the_callbacks(self,
+                                                           monkeypatch):
+        class Untouchable(list):
+            def append(self, item):
+                pytest.fail("gc.callbacks touched with nothing recording")
+            remove = append
+
+        monkeypatch.setattr(gc, "callbacks", Untouchable(gc.callbacks))
+        obs = get_session()
+        assert not obs.enabled
+        with obs.span("serving/x"):
+            gc.collect()
+        SpanTracer(enabled=False, process_index=0).span("y").begin().end()
+        assert recorded_spans() == []
 
 
 # ---------------------------------------------------------------------------
@@ -824,8 +1012,8 @@ def test_train_batch_spans_reach_the_capture_with_the_step(tmp_path):
             if s.get("parent_id") == steps[0]["id"]}
     assert kids == {"train_batch/h2d", "train_batch/dispatch"}
     (line,) = cap.events("train_batch").values()
-    assert [e[3] for e in line if e[0] == "train_batch"] == [{"step": 1},
-                                                              {"step": 2}]
+    assert [counts(e[3], cpu=True) for e in line
+            if e[0] == "train_batch"] == [{"step": 1}, {"step": 2}]
 
 
 def test_generate_ttft_needs_no_telemetry(tiny_engine):

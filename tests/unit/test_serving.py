@@ -1021,7 +1021,7 @@ def never_ahead(srv):
     enqueued (what the engine does whenever somebody waits for the device:
     ``TestStepAhead`` has the states that say so): the order of enqueue,
     delivery and fetch around ONE program is what these tests fix."""
-    srv._may_run_ahead = lambda flight: False
+    srv._ahead_held_by = lambda flight: "queued"
     return srv
 
 
@@ -1715,6 +1715,361 @@ class TestStepAhead:
                       and "moe_assignments" in s["attrs"]]
             assert len(routed) == 5
             assert all(a["moe_assignments"] > 0 for a in routed)
+        srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the host's causes: a step enqueued behind a program in flight says whether
+# it came too late, a step that was not says which rule held it, and with
+# nothing recording none of it is asked. The decisions are the parent's
+# ---------------------------------------------------------------------------
+
+# the enqueues (c: a chunk's, d: a decode step's) and fetches (C, D) of
+# ``AHEAD_CASES`` under the driver's loop, as the commit before the rules had
+# names made them (PR 57: written down from a checkout of PR 56's tree)
+PARENT_ORDER = {
+    "budget": "cCdDcCdDcdCDcCdDcCdDddDdDdDdDdDD",
+    "eos": "cCcCcCcCdDddDdDdDdDdDdDdDdDdDD",
+    "greedy": "cCdDcdCDcCdDcdCDcdCDcCdDcCdDcdCDcCdDddDdDdDDddDD",
+    "moe": "cCdDcdCDcdCDcCdDcCddDdDDdDddDdDdDD",
+    "one_token": "cCcCcCcCcCcCdDddDD",
+    "queued": "cCdDcdCDcCdDdDdDdDdDcdCDcCcCdDcCdDdDdDdDdDdDcdCDcCdDddDDddDdDD",
+    "recurrent": "cCdDcdCDcdCDcCdDcCddDdDDdDddDdDdDD",
+    "sampled": "cCdDcdCDcCdDcCddDdDdDdDDddDD",
+    "small_pool": "cCcCdDcdCDcdCDcCdDcdCDcdCDcdCDcCdDcdCDcdCDcdCDcCdDcdCDcCdD"
+                  "cdCDcdCDcCdDcCdDdDdDcdCDcdCDcdCDcCdDddDDddDdDdDdDdDD",
+}
+
+
+ORDER_LETTER = {("dispatch", "prefill_chunk"): "c",
+                ("dispatch", "decode"): "d",
+                ("fetched", "prefill_chunk"): "C",
+                ("fetched", "decode"): "D"}
+
+
+def decode_spans(mark=0):
+    from deepspeed_tpu.observability import recorded_spans
+
+    return [s["attrs"] for s in recorded_spans()[mark:]
+            if s["name"] in ("serving/decode", "serving/verify")]
+
+
+def _grab_free_pages(srv, leave=0):
+    return srv.alloc.alloc(srv.alloc.blocks_free - leave)
+
+
+# name -> (engine config, what brings about the state its rule's docstring
+# describes, from two rows that decode with a step in flight ahead; returns
+# what to undo afterwards, or None). The iteration behind it fetches the
+# step in flight with ``held_by`` the name
+def _held_row_freed(srv, rows):
+    srv.sched.rows_released += 1        # as a request's end counts it
+
+
+def _held_queued(srv, rows):
+    srv.submit(np.arange(9, dtype=np.int32), max_new_tokens=2)
+
+
+def _held_fork(srv, rows):
+    srv._pending_forks[10 ** 6] = [rows[0]._req]    # a sibling waits
+    return lambda: srv._pending_forks.pop(10 ** 6)
+
+
+def _held_prefill(srv, rows):
+    # admitted, not yet a row of any step: what a request in prefill is
+    srv.submit(np.arange(40, dtype=np.int32), max_new_tokens=2)
+    srv.sched.admit()
+
+
+def _held_drafter(srv, rows):
+    srv.spec_suspended = False          # the fleet's ladder steps back up
+    return lambda: setattr(srv, "spec_suspended", True)
+
+
+def _held_deadline(srv, rows):
+    srv.clock.advance(100.0)            # past the first row's deadline
+
+
+def _held_ends(srv, rows):
+    for h in rows:                      # every row ends at the step in flight
+        h._req.max_new_tokens = len(h._req.generated) + 1
+
+
+def _held_pages(srv, rows):
+    # until the position behind the step in flight needs a new page
+    while rows[0]._req.length + 2 <= 16 * len(rows[0]._req.blocks):
+        srv._iterate(defer=True)
+    assert srv._flight is not None and srv._flight.since is not None
+    ids = _grab_free_pages(srv)
+    return lambda: srv.alloc.free(ids)
+
+
+def _held_cow(srv, rows):
+    shared = [rows[0]._req.blocks[-1]]  # another holder of its write block
+    srv.alloc.incref(shared)
+    return lambda: srv.alloc.free(shared)
+
+
+HELD_BY_CASES = {
+    "row_freed": ({}, _held_row_freed),
+    "queued": ({}, _held_queued),
+    "fork": ({}, _held_fork),
+    "prefill": ({}, _held_prefill),
+    "drafter": (dict(speculative={"mode": "ngram", "num_draft_tokens": 2}),
+                _held_drafter),
+    "deadline": ({}, _held_deadline),
+    "ends": ({}, _held_ends),
+    "pages": (dict(prefix_cache=False), _held_pages),
+    "cow": ({}, _held_cow),
+}
+
+
+class TestHostCauses:
+    @pytest.mark.parametrize("recording", [False, True],
+                             ids=["untraced", "recorded"])
+    @pytest.mark.parametrize("case", sorted(AHEAD_CASES))
+    def test_the_order_of_enqueues_and_fetches_is_the_parents(
+            self, request, tmp_path, monkeypatch, case, recording):
+        """Name for name, with nothing recording and with every span
+        recorded: a rule that gives its name decides as the bare one did."""
+        fixture, cfg, specs, _ = AHEAD_CASES[case]
+        engine = request.getfixturevalue(fixture)
+        if case in DELIVERY_CASES:
+            _, reqs = delivery_requests(engine, case)
+        else:
+            rng = np.random.RandomState(sum(map(ord, case)))
+            reqs = [(rng.randint(0, 250, (n,)).astype(np.int32), kw)
+                    for n, kw in specs]
+        reset_session()
+        if recording:
+            configure_observability(ObservabilityConfig(
+                enabled=True, output_dir=str(tmp_path / "obs"),
+                flight_recorder=False))
+        try:
+            srv = serving(engine, **cfg)
+            events = TestDeferredDelivery._spy(monkeypatch, srv)
+            handles = [srv.submit(p, **kw) for p, kw in reqs]
+            drive_on_this_thread(srv)
+            assert all(h.done for h in handles)
+            srv.close()
+        finally:
+            reset_session()
+        assert "".join(ORDER_LETTER[e] for e in events
+                       if e in ORDER_LETTER) == PARENT_ORDER[case]
+
+    @pytest.mark.parametrize("name", sorted(HELD_BY_CASES))
+    def test_held_by_names_the_rule_that_held_the_step(self, tiny_engine,
+                                                       obs_session, name):
+        from deepspeed_tpu.serving.api import HELD_BY
+
+        cfg, arrange = HELD_BY_CASES[name]
+        clock = FakeClock()
+        srv = serving(tiny_engine, clock=clock, **cfg)
+        srv.spec_suspended = True       # a drafter, where there is one,
+        #   proposes only once its case says so
+        try:
+            rows = [srv.submit(np.arange(3, 18, dtype=np.int32),
+                               max_new_tokens=40, deadline_s=50.0),
+                    srv.submit(np.arange(4, 19, dtype=np.int32),
+                               max_new_tokens=40)]
+            drive_on_this_thread(srv, iterations=5)
+            assert srv._flight is not None and srv._flight.since is not None
+            assert all(h.state == "decode" for h in rows)
+            undo = arrange(srv, rows)
+            mark = len(decode_spans())
+            srv._iterate(defer=True)
+            (fetched,) = decode_spans()[mark:]
+            assert fetched["held_by"] == name and name in HELD_BY
+            # the span holds the fetch alone: it dispatched nothing
+            assert "rows" not in fetched and "late" not in fetched
+            assert srv._flight is None
+            if undo:
+                undo()
+        finally:
+            for h in rows:
+                h.cancel()
+            srv.close()
+
+    def test_held_by_step_mode_and_the_chunks_own_prefill(self, tiny_engine,
+                                                          obs_session):
+        """``step()`` fetches what it enqueued: ``step_mode``. And a step
+        enqueued behind its chunk is fetched in its own iteration, a request
+        being in prefill: ``prefill``, without the rules being asked."""
+        srv = serving(tiny_engine, prefix_cache=False)
+        a = srv.submit(np.arange(7, dtype=np.int32), max_new_tokens=40)
+        srv.step()
+        srv.step()
+        steps = decode_spans()
+        assert [s.get("held_by") for s in steps] == ["step_mode"] * 2
+        assert [s["chunk_first_by"] for s in steps[:1]] == ["step_mode"]
+        assert "chunk_first_by" not in steps[1]     # no chunk ran
+        asked = []
+        held = srv._ahead_held_by
+        srv._ahead_held_by = lambda flight: asked.append(1) or held(flight)
+        srv.submit(np.arange(40, dtype=np.int32), max_new_tokens=2)
+        mark = len(steps)
+        srv._iterate(defer=True)
+        behind, fetched = decode_spans()[mark:]
+        assert behind["behind_chunk"] == 1 and "held_by" not in behind
+        assert fetched["held_by"] == "prefill" and not asked
+        a.cancel()
+        srv.close()
+
+    @pytest.mark.parametrize("name", ["last_chunk", "pages", "no_rows",
+                                      "drafter", "more_chunks", "step_mode"])
+    def test_chunk_first_by_names_the_rule_that_had_the_chunk_fetched_first(
+            self, tiny_engine, obs_session, name):
+        from deepspeed_tpu.serving.api import CHUNK_FIRST_BY
+
+        spec = (dict(speculative={"mode": "ngram", "num_draft_tokens": 2})
+                if name == "drafter" else {})
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False, **spec))
+        srv.spec_suspended = True
+        try:
+            if name != "no_rows":
+                # one row that decodes, its next token in need of a page
+                a = srv.submit(np.arange(3, 18, dtype=np.int32),
+                               max_new_tokens=40)
+                drive_on_this_thread(srv, iterations=1)
+                assert a.state == "decode" and a._req.length == 16
+            prompt = np.arange(40 if name in ("pages", "no_rows", "drafter")
+                               else 9, dtype=np.int32)
+            srv.submit(prompt, max_new_tokens=4)
+            ids = []
+            if name == "pages":
+                ids = _grab_free_pages(srv, leave=1)    # the chunk's own
+            elif name == "more_chunks":
+                srv.prefill_chunks_per_iter = 2
+            elif name == "drafter":
+                srv.spec_suspended = False
+            mark = len(decode_spans())
+            if name == "step_mode":
+                srv.step()
+            else:
+                srv._iterate(defer=True)
+            srv.alloc.free(ids)
+            assert srv._chunk_first == name and name in CHUNK_FIRST_BY
+            steps = decode_spans()[mark:]
+            if name == "no_rows":
+                assert not steps        # no step: the name is the engine's
+            else:
+                dispatched = [s for s in steps if s.get("rows")]
+                assert [s["chunk_first_by"] for s in dispatched] == [name]
+                assert not any(s.get("behind_chunk") for s in steps)
+        finally:
+            srv.close()
+
+    def test_a_step_behind_its_chunk_or_ahead_carries_neither(
+            self, tiny_engine, obs_session):
+        srv = serving(tiny_engine, prefix_cache=False)
+        a, b = decoding_pair(srv)
+        srv.submit(np.arange(50, 90, dtype=np.int32), max_new_tokens=2)
+        drive_on_this_thread(srv)
+        went = [s for s in decode_spans()
+                if s.get("ahead") or s.get("behind_chunk")]
+        assert sum(s["ahead"] for s in went) > 3
+        assert sum(s["behind_chunk"] for s in went) == 2
+        assert not any("chunk_first_by" in s for s in went)
+        # a span that enqueued ahead fetches the step BEFORE: nothing held
+        # that one's successor
+        assert not any("held_by" in s for s in went)
+        srv.close()
+
+    @pytest.mark.parametrize("told", [False, True], ids=["in_time", "late"])
+    def test_late_says_whether_the_program_in_flight_had_ended(
+            self, tiny_engine, obs_session, monkeypatch, told):
+        """``is_ready()`` of the tokens the engine holds, told what to say:
+        ``late`` on the spans with ``ahead`` or ``behind_chunk`` and on no
+        other, and the counter beside ``serving/steps_enqueued_ahead``."""
+        srv = serving(tiny_engine, prefix_cache=False)
+        asked = []
+        monkeypatch.setattr(type(srv._last_tokens), "is_ready",
+                            lambda self: asked.append(1) or told)
+        late = get_registry().counter("serving/steps_enqueued_late")
+        before = late.value()
+        a, b = decoding_pair(srv)
+        srv.submit(np.arange(50, 90, dtype=np.int32), max_new_tokens=2)
+        drive_on_this_thread(srv)
+        steps = decode_spans()
+        behind = [s for s in steps if s.get("ahead") or s.get("behind_chunk")]
+        assert len(behind) > 5 and len(asked) == len(behind)
+        assert all(s["late"] == int(told) for s in behind)
+        assert not any("late" in s for s in steps if s not in behind)
+        assert late.value() - before == (len(behind) if told else 0)
+        srv.close()
+
+    def test_nothing_recording_asks_nothing(self, tiny_engine, monkeypatch):
+        """No clock of the tracer's, no ``is_ready()``, no walk over the
+        operands and no allocator statistics: each fails the test if it
+        runs. The streams are those of the recorded run above."""
+        from deepspeed_tpu.serving import api
+
+        reset_session()
+        assert not get_session().enabled
+        srv = serving(tiny_engine, prefix_cache=False)
+        for mod, attr in ((time, "thread_time_ns"), (api, "_host_operands"),
+                          (api, "hbm_counts")):
+            monkeypatch.setattr(mod, attr, lambda *a, _n=attr: pytest.fail(
+                f"{_n} ran with nothing recording"))
+        monkeypatch.setattr(
+            type(srv._last_tokens), "is_ready",
+            lambda self: pytest.fail("is_ready() with nothing recording"))
+        a, b = decoding_pair(srv, max_new_tokens=12)
+        c = srv.submit(np.arange(50, 90, dtype=np.int32), max_new_tokens=2)
+        drive_on_this_thread(srv)
+        assert [len(h.tokens) for h in (a, b, c)] == [12, 12, 2]
+        srv.close()
+
+    def test_the_operands_are_counted_before_the_dispatch_opens(
+            self, tiny_engine, obs_session, monkeypatch):
+        """The parameters' and the arena's leaves once a program name, the
+        step's own at every dispatch, and none of it inside
+        ``<name>/dispatch``."""
+        from deepspeed_tpu.observability import recorded_spans
+        from deepspeed_tpu.serving import api
+
+        walked = []
+        count = api._host_operands
+
+        def spy(call_args):
+            tracer = get_session().tracer
+            walked.append((len(call_args), tracer.current_name(),
+                           [s.name for s in tracer._local.stack][-1:]))
+            return count(call_args)
+
+        monkeypatch.setattr(api, "_host_operands", spy)
+        srv = serving(tiny_engine, prefix_cache=False)
+        h = srv.submit(np.arange(7, dtype=np.int32), max_new_tokens=5)
+        drive_on_this_thread(srv)
+        assert len(h.tokens) == 5
+        dispatches = [s for s in recorded_spans()
+                      if s["name"].endswith("/dispatch")]
+        assert len(dispatches) == 5         # a chunk and four steps
+        assert all(s["attrs"]["host_operands"] == 1
+                   and s["attrs"]["host_operand_bytes"] > 0
+                   for s in dispatches)
+        # (params, arena) twice in all; (packed, key[, tokens]) a dispatch
+        assert sorted(n for n, *_ in walked) == [2, 2, 2, 3, 3, 3, 3]
+        assert not any(open_[-1:] == [name + "/dispatch"]
+                       for _, name, open_ in walked)
+        srv.close()
+
+    def test_the_allocators_statistics_every_sixteenth_iteration(
+            self, tiny_engine, obs_session, monkeypatch):
+        from deepspeed_tpu.observability import recorded_spans
+        from deepspeed_tpu.serving import api
+
+        monkeypatch.setattr(api, "hbm_counts", lambda: {
+            "hbm_bytes_in_use": 5, "hbm_peak_bytes": 7})
+        srv = serving(tiny_engine, prefix_cache=False)
+        h = srv.submit(np.arange(7, dtype=np.int32), max_new_tokens=40)
+        drive_on_this_thread(srv)
+        its = [s["attrs"] for s in recorded_spans()
+               if s["name"] == "serving/iteration"]
+        assert len(its) == 40
+        assert [a["it"] for a in its if "hbm_peak_bytes" in a] == [0, 16, 32]
+        assert all("gc_collections" in a and a["gc_pause_us"] >= 0
+                   for a in its)
         srv.close()
 
 
