@@ -31,8 +31,7 @@ its tokens on the device, from the array its predecessor returned; fetch,
 apply, delivery, prepare and enqueue of the host's round then run in its
 shadow. So ONE decode step may be in flight across an iteration boundary of
 the driver thread (``_flight``); whatever needs a settled engine brings it
-home first (``_bring_home``). Under ``step()`` no program is ever in flight
-across an iteration boundary.
+home first (``_bring_home``).
 
 And INSIDE an iteration the driver thread enqueues the decode step BEHIND
 THE CHUNK, with the chunk still running, whenever the chunk is not its
@@ -40,7 +39,19 @@ prompt's last (``_step_prefill``, ``_chunk_first_by``: rules with names
 again, ``CHUNK_FIRST_BY``; no setting): such a chunk's token is read by
 nobody and its request is no decode row, so the chunk's fetch and apply lie
 in the decode program's shadow and no host round lies between the two
-programs (``_chunk``, never set across an iteration's end).
+programs (``_chunk``).
+
+Such an iteration then enqueues its prompt's NEXT CHUNK behind that decode
+step, once the chunk is applied and with the step's tokens not yet fetched
+(``_chunk_ahead``; ``CHUNK_HELD_BY`` names what keeps it back): all the chunk
+needs is on the host by then, and fetch, apply and delivery of the step, the
+iteration's tail, the next admission and the next step's prepare and enqueue
+run in its shadow. So ONE chunk may be in flight across an iteration
+boundary of the driver thread too (``_chunk``), never beside a step
+(``_flight``): the next iteration finds it, asks ``_chunk_first_by`` as of
+any chunk and enqueues its step behind it, or lands it first.
+``_bring_home`` lands it for whoever needs a settled engine. Under ``step()``
+no program is ever in flight across an iteration boundary.
 
 Telemetry flows through the PR-2 observability substrate: ``serving/*``
 metrics in the MetricsRegistry (ttft_ms, tpot_ms, queue_depth,
@@ -98,6 +109,12 @@ HELD_BY = ("row_freed", "queued", "fork", "prefill", "drafter", "deadline",
 CHUNK_FIRST_BY = ("last_chunk", "pages", "no_rows", "drafter", "more_chunks",
                   "step_mode")
 
+# why the decode step behind a chunk was fetched with its prompt's next chunk
+# NOT enqueued ahead of that fetch (``chunk_held_by`` on the ``serving/decode``
+# span that fetches it), in the order the rules are asked: ``_step_decode``
+# gives ``dropped``, ``_chunk_held_by`` the rest
+CHUNK_HELD_BY = ("dropped", "more_chunks", "pages", "cow")
+
 # ``hbm_*`` go onto every this-many-th ``serving/iteration`` span: a peak
 # loses nothing by it, and the allocator's statistics of every local device
 # are not read inside each iteration's account (the goodput accountant
@@ -131,8 +148,12 @@ class _Enqueued:
     count towards a hold. A decode step also keeps its rows, each request
     with the row it held, and, where it was enqueued AHEAD or BEHIND A CHUNK
     still in flight, when that program's tokens came to the host: from there
-    its interval counts. A chunk keeps its request so, and where in the
-    prompt it starts and how many tokens it holds."""
+    its interval counts. A chunk keeps its request so, where in the prompt
+    it starts and how many tokens it holds, and, where it was enqueued AHEAD
+    (behind a decode step not yet fetched: ``since`` is that step's fetch),
+    whether the step had ended by then (``late``; None where nothing
+    recorded asked), and how often its request had been preempted at the
+    enqueue: one that lost its row since is not the request it ran for."""
     name: str
     tok: Any
     t0: float
@@ -142,6 +163,9 @@ class _Enqueued:
     since: Optional[float] = None
     start: int = 0
     tokens: int = 0
+    ahead: bool = False
+    late: Optional[int] = None
+    preempted: int = 0
 
 
 def _percentile(samples: List[float], q: float) -> float:
@@ -363,10 +387,10 @@ class ServingEngine:
         # bytes) by program name: counted at its first recorded dispatch
         # and kept (``_operand_counts``)
         self._fixed_operands: Dict[str, tuple] = {}
-        # the chunk that is enqueued and not fetched, inside an iteration of
-        # the driver thread: ``_step_decode`` enqueues its step behind it
-        # and lands it in that step's shadow (``_step_prefill``). Never
-        # across an iteration's end
+        # the chunk that is enqueued and not fetched, under the driver
+        # thread: ``_step_decode`` enqueues its step behind it and lands it
+        # in that step's shadow (``_step_prefill``), and may leave the
+        # prompt's next one here at the iteration's end (``_chunk_ahead``)
         self._chunk: Optional[_Enqueued] = None
         # the open iteration's span until its account (gauges, the span's
         # counts) is drawn up: behind its first enqueue when deferring, else
@@ -513,7 +537,12 @@ class ServingEngine:
         #   requests_{submitted,completed,cancelled} ledger balances
         with self._lock:
             # what is enqueued and what is applied streams before the cancel
-            # ends it
+            # ends it.
+            # tpusync: disable=lock-order-inversion — here and wherever an
+            # engine is brought home: a last chunk landed there hands its
+            # request on (the SE->FR edge of ``_iterate``), but an engine
+            # under a router never has a chunk in flight: ``FleetRouter``
+            # drives it by ``step()``, on the one thread that holds FR
             self._bring_home()
             req = handle._req
             # a sibling cancelled before its fork point never reached the
@@ -924,7 +953,8 @@ class ServingEngine:
         program this engine enqueues (``_run_program``), or by whoever
         needs a settled engine first (``_bring_home``); and a decode step
         may stay in flight at the iteration's end, for the next iteration
-        to enqueue its successor ahead of its fetch (``_step_decode``)."""
+        to enqueue its successor ahead of its fetch, or a chunk, for the
+        next iteration's step to go behind (``_step_decode``)."""
         obs = get_session()
         with obs.span("serving/iteration", cpu=True) as span:
             lock_wait = obs.span("serving/iteration/lock_wait",
@@ -949,12 +979,14 @@ class ServingEngine:
                         self._settle(obs)
                     it = self._iterations
                     self._iterations += 1
+                except BaseException:
+                    # a chunk in flight is run again, as one whose fetch
+                    # raised always was
+                    self._chunk = None
+                    raise
                 finally:
                     self._deferring = False
                     self._unaccounted = None
-                    # set only where the iteration raised: the chunk is run
-                    # again, as one whose fetch raised always was
-                    self._chunk = None
                     if acct is not None:
                         acct.iteration_end(self.clock())
                         # gauge refresh at a cadence, always AFTER the
@@ -987,12 +1019,15 @@ class ServingEngine:
         in flight (the driver thread left it there: ``_step_decode``) the
         iteration is that step's: the next one enqueued AHEAD of its fetch
         where the rules allow it, else the fetch alone, and today's
-        iteration, admission first, is the next. ``step()`` brings a step
-        it finds in flight home and goes on."""
+        iteration, admission first, is the next. With a CHUNK in flight
+        (``_chunk_ahead`` left it there) the iteration is today's and that
+        chunk is its chunk (``_step_prefill``). ``step()`` brings what it
+        finds in flight home and goes on."""
         flight = self._flight
         self._chunk_first = None
-        if flight is not None and not self._deferring:
-            self._bring_home("step_mode")
+        if not self._deferring:
+            if flight is not None or self._chunk is not None:
+                self._bring_home("step_mode")
         elif flight is not None:
             rows, held_by = self._rows_ahead(flight)
             if rows:
@@ -1025,10 +1060,11 @@ class ServingEngine:
             progress |= ran_chunk
             if not ran_chunk:
                 break
+        chunk = self._chunk
         progress |= self._step_verify() if drafting else self._step_decode()
-        if self._chunk is not None:
+        if chunk is not None and self._chunk is chunk:
             # no decode step was enqueued behind it after all
-            self._land_chunk(obs, self._chunk)
+            self._land_chunk(obs, chunk)
         return progress
 
     def _settle(self, obs, deferred: bool = False) -> None:
@@ -1326,38 +1362,30 @@ class ServingEngine:
         prompt's last chunk brings a first token and a new decode row: it is
         always fetched, applied and delivered before the decode step is
         prepared. Which rule had the chunk fetched first is kept for the
-        span of the iteration's step (``_chunk_first``)."""
+        span of the iteration's step (``_chunk_first``). A chunk that the
+        last iteration enqueued AHEAD (``_chunk_ahead``) is this iteration's
+        chunk, in flight already: the same rules are asked of it, before the
+        decode rows take anything, and where one names itself it is landed
+        here, in a span of its own."""
+        obs = get_session()
+        sent = self._chunk
+        if sent is not None:
+            self._chunk_first = first_by or self._chunk_first_by(sent)
+            if self._chunk_first is not None:
+                (req, _), = sent.rows
+                with self._chunk_span(obs, req, sent.start, True) as span:
+                    if self._deferring:
+                        self._settle(obs, deferred=True)
+                    self._land_chunk(obs, sent, span)
+            return True
         req = self.sched.next_prefill()
         if req is None:
             return False
-        obs = get_session()
-        C = self.config.prefill_chunk
-        src = req.prompt
-        start = req.prefill_pos
-        n_valid = min(C, int(src.size) - start)
-        last = start + n_valid == int(src.size)
-        with obs.span("serving/prefill_chunk", cpu=True, rid=req.rid,
-                      chunk_start=int(start),
-                      sampled_rows=self._sampled_rows([req])) as span:
-            with obs.span("serving/prefill_chunk/prepare", category="phase"):
-                if not self.sched.ensure_blocks(req, start + n_valid):
-                    return False    # pool dry, nothing evictable — wait
-                if not self._make_writable(req, start, start + n_valid):
-                    return False    # a shared block needs a copy the pool
-                    #   can't give
-                chunk = np.zeros((1, C), np.int32)
-                chunk[0, :n_valid] = src[start:start + n_valid]
-                packed = paged_kv.pack_chunk(
-                    self._table_for([req]), chunk, start, n_valid,
-                    *self._sampling_arrays([req]),
-                    state_slot=([req.row] if self._recurrent_layers
-                                else None),
-                    **({"last": [last]} if self._chunk_says_last else {}))
-            sent = self._enqueue(obs, "serving/prefill_chunk", self._prefill,
-                                 packed, self._base_rng, trace=req.trace)
-            sent.rows = [(req, req.row)]
-            sent.start, sent.tokens = int(start), int(n_valid)
-            self._chunk_first = first_by or self._chunk_first_by(last)
+        with self._chunk_span(obs, req, req.prefill_pos) as span:
+            sent = self._enqueue_chunk(obs, req)
+            if sent is None:
+                return False    # the pool could not place it — wait
+            self._chunk_first = first_by or self._chunk_first_by(sent)
             if self._chunk_first is None:
                 self._chunk = sent
                 return True
@@ -1366,11 +1394,118 @@ class ServingEngine:
             self._land_chunk(obs, sent, span)
         return True
 
-    def _chunk_first_by(self, last: bool) -> Optional[str]:
+    def _chunk_span(self, obs, req: Request, start: int, ahead: bool = False):
+        """A ``serving/prefill_chunk`` span with what each of a chunk's
+        spans carries: whose chunk, where in the prompt it starts, and
+        whether it was enqueued AHEAD (``_chunk_ahead``)."""
+        return obs.span("serving/prefill_chunk", cpu=True, rid=req.rid,
+                        chunk_start=int(start), ahead=int(ahead),
+                        sampled_rows=self._sampled_rows([req]))
+
+    def _enqueue_chunk(self, obs, req: Request) -> Optional["_Enqueued"]:
+        """``serving/prefill_chunk/prepare`` and ``.../dispatch`` of the
+        chunk of ``req``'s prompt that starts where its prefill stands, under
+        the chunk's span, which is open. None where the pool cannot place
+        it."""
+        C = self.config.prefill_chunk
+        src = req.prompt
+        start = req.prefill_pos
+        n_valid = min(C, int(src.size) - start)
+        with obs.span("serving/prefill_chunk/prepare", category="phase"):
+            if not self.sched.ensure_blocks(req, start + n_valid):
+                return None    # pool dry, nothing evictable
+            if not self._make_writable(req, start, start + n_valid):
+                return None    # a shared block needs a copy the pool
+                #   can't give
+            chunk = np.zeros((1, C), np.int32)
+            chunk[0, :n_valid] = src[start:start + n_valid]
+            last = start + n_valid == int(src.size)
+            packed = paged_kv.pack_chunk(
+                self._table_for([req]), chunk, start, n_valid,
+                *self._sampling_arrays([req]),
+                state_slot=([req.row] if self._recurrent_layers
+                            else None),
+                **({"last": [last]} if self._chunk_says_last else {}))
+        sent = self._enqueue(obs, "serving/prefill_chunk", self._prefill,
+                             packed, self._base_rng, trace=req.trace)
+        sent.rows = [(req, req.row)]
+        sent.start, sent.tokens = int(start), int(n_valid)
+        sent.preempted = req.preemptions
+        return sent
+
+    def _chunk_held_by(self, req: Request) -> Optional[str]:
+        """What keeps the NEXT chunk of ``req``'s prompt, whose last chunk
+        was just applied behind this iteration's decode step, from being
+        enqueued behind that step while it is in flight: the first, in this
+        order, of these names of ``CHUNK_HELD_BY``, or None. (``dropped``,
+        which ``_step_decode`` gives before it asks here: the request ended
+        or lost its row under its chunk, so the next chunk is another
+        prompt's or this one's first again, and a prompt's first chunk is
+        never enqueued ahead of anything.) ``more_chunks``: an iteration
+        runs more chunks than one (the live tuner's knob), so the next one's
+        first is fetched before its second is prepared. ``pages``: the pages
+        the chunk needs would cost a request its row: they come neither from
+        the free list nor from unpinned prefix-cache entries, which no
+        request holds and no program in flight reads (in a pool that has run
+        for a while every free page is such an entry). ``cow``: it would
+        write into a shared block. So nobody is preempted and no block
+        copied on behalf of a chunk ahead. Asked before anything is taken,
+        as ``_rows_ahead`` asks. That this is the driver thread's iteration
+        and that no drafter proposes need not be asked: the step went behind
+        its chunk (``_step_locked``). Nor whose chunk the scheduler would
+        run next: the oldest admission in prefill, which ``req`` was and is,
+        whoever was admitted since (``Scheduler.next_prefill``)."""
+        if max(int(self.prefill_chunks_per_iter), 1) > 1:
+            return "more_chunks"
+        start = req.prefill_pos
+        end = min(start + self.config.prefill_chunk, int(req.prompt.size))
+        need = paged_kv.blocks_for_tokens(end, self.config.block_size) \
+            - len(req.blocks)
+        if not self.sched.pages_without_preemption(need):
+            return "pages"
+        if self.sched.cow_block_indices(req, start, end):
+            return "cow"
+        return None
+
+    def _chunk_ahead(self, obs, req: Request,
+                     step: "_Enqueued") -> Optional[str]:
+        """The next chunk of ``req``'s prompt, whose last chunk was just
+        applied, prepared and enqueued BEHIND ``step``, the decode step that
+        went behind that chunk and is not fetched yet: its span holds
+        ``.../prepare`` and ``.../dispatch`` and says ``ahead`` 1, and whether
+        the step had ended by the enqueue (``late``: one non-blocking
+        question). It stays in flight (``_chunk``) for the next iteration,
+        across the step's fetch, the delivery, the iteration's tail and the
+        next admission; the device goes from the step to it with no host
+        round between. Returns None, or the name of ``CHUNK_HELD_BY`` that
+        kept it back: then the next iteration runs as it always did."""
+        held_by = self._chunk_held_by(req)
+        if held_by is not None:
+            return held_by
+        with self._chunk_span(obs, req, req.prefill_pos, True) as span:
+            sent = self._enqueue_chunk(obs, req)
+            if sent is None:
+                return "pages"
+            sent.ahead = True
+            if span.recording or obs.enabled:
+                sent.late = int(step.tok.is_ready())
+                span.annotate(late=sent.late)
+            if obs.enabled:
+                obs.registry.counter(
+                    "serving/chunks_enqueued_ahead",
+                    help="prefill chunks enqueued behind their prompt's "
+                         "last chunk and its decode step, with that step's "
+                         "tokens not yet on the host (the driver thread's "
+                         "form)").inc()
+            self._chunk = sent
+        return None
+
+    def _chunk_first_by(self, sent: "_Enqueued") -> Optional[str]:
         """What keeps this iteration's decode step from being enqueued
-        behind its chunk while that is still in flight, as far as the chunk,
-        the rows and their pages say (a name of ``CHUNK_FIRST_BY``), or None
-        where nothing does. ``last_chunk``: the chunk is its prompt's last.
+        behind its chunk ``sent`` while that is still in flight, as far as
+        the chunk, the rows and their pages say (a name of
+        ``CHUNK_FIRST_BY``), or None where nothing does. ``last_chunk``: the
+        chunk is its prompt's last.
         ``no_rows``: no request decodes. ``pages``: a row's next token needs
         a page that only a preemption would free, or writes into a shared
         block. Else the page each one's next token needs comes from
@@ -1380,7 +1515,8 @@ class ServingEngine:
         shared block. So nobody is preempted and no block copied with the
         chunk's progress not yet applied; where either would be, the chunk
         is fetched and applied first. Asked before anything is taken."""
-        if last:
+        (req, _), = sent.rows
+        if sent.start + sent.tokens == int(req.prompt.size):
             return "last_chunk"
         dec = self.sched.decode_requests()
         if not dec:
@@ -1388,7 +1524,7 @@ class ServingEngine:
         return (None if self.sched.grows_without_preemption(dec)
                 else "pages")
 
-    def _land_chunk(self, obs, sent: "_Enqueued", span=None) -> None:
+    def _land_chunk(self, obs, sent: "_Enqueued", span=None) -> bool:
         """A chunk's token fetched and its progress applied
         (``serving/prefill_chunk/fetch`` and ``.../apply``, under ``span``:
         the chunk's span where it is still open, else one of its own, for a
@@ -1399,34 +1535,40 @@ class ServingEngine:
         chunk wrote lies in pages and a state slot that their next owner
         writes over in a later program, and its progress is applied to
         nobody. A decode step enqueued behind the chunk counts its interval
-        from here (``_Enqueued.since``): no second is counted twice."""
+        from here, and a chunk that was enqueued AHEAD, behind a decode step
+        in flight, its own from that step's fetch (``_Enqueued.since``): no
+        second is counted twice. The span of such a chunk says so (``ahead``,
+        and ``late`` as its enqueue read it). Returns whether the progress
+        was applied."""
         (req, row), = sent.rows
         if span is None:
-            with obs.span("serving/prefill_chunk", cpu=True, rid=req.rid,
-                          chunk_start=sent.start,
-                          sampled_rows=self._sampled_rows([req])) as span:
+            with self._chunk_span(obs, req, sent.start, sent.ahead) as span:
                 return self._land_chunk(obs, sent, span)
         if self._chunk is sent:
             self._chunk = None
         tok, t1 = self._fetch(obs, sent)
         if self._flight is not None:
             self._flight.since = t1
+        t0 = sent.t0 if sent.since is None else sent.since
         n_valid = sent.tokens
         with obs.span("serving/prefill_chunk/apply", category="phase"):
             tok = self._program_counts(span, tok, 1, real_rows=1)
             if self._serve_acct is not None:
-                self._serve_acct.note_phase("prefill", t1 - sent.t0)
+                self._serve_acct.note_phase("prefill", t1 - t0)
             span.annotate(tokens=n_valid)   # the chunk ran: a span
             #   without the count is a chunk the pool could not place, or
             #   the prepare and dispatch of one that waited for its fetch
+            if sent.late is not None:
+                span.annotate(late=sent.late)
             self.prefill_chunks_run += 1
             self.prefill_tokens_run += n_valid
-            if req.state != PREFILL or req.row != row:
+            if (req.state != PREFILL or req.row != row
+                    or req.preemptions != sent.preempted):
                 span.annotate(dropped_rows=1)
-                return
+                return False
             rt = obs.reqtrace
             if rt is not None and req.trace is not None:
-                rt.interval(req.trace, "prefill", sent.t0, t1,
+                rt.interval(req.trace, "prefill", t0, t1,
                             kind="prefill_chunk", tokens=n_valid,
                             chunk_start=sent.start, replica=self.trace_tag)
             req.prefill_pos += n_valid
@@ -1437,6 +1579,7 @@ class ServingEngine:
             self.sched.note_service(req, n_valid)
             if req.prefill_pos == int(req.prompt.size):
                 self._finish_prefill(obs, req, int(tok[0]))
+        return True
 
     def _finish_prefill(self, obs, req: Request, token: int) -> None:
         """The prompt's last chunk ran: the request decodes from here."""
@@ -1675,14 +1818,19 @@ class ServingEngine:
         the next one ahead of it (``_ahead_held_by`` names nothing): then it
         stays in flight for the next iteration. With the iteration's chunk
         still in flight (``_chunk``: ``_step_prefill`` left it there) the
-        step is enqueued BEHIND THE CHUNK, and then the chunk is landed and
-        the step behind it, each in a span of its own. While the span
+        step is enqueued BEHIND THE CHUNK, and then the chunk is landed, the
+        prompt's next chunk enqueued behind the step where nothing keeps it
+        back (``_chunk_ahead``), and the step landed, each in a span of its
+        own; with a chunk ahead the step's tokens are delivered at once, the
+        device being busy. While the span
         records it says of a step enqueued behind a program in flight
         whether it came too ``late`` (that program's tokens were ready at
         the enqueue: the device stood idle between the two), of a step
         whose chunk was fetched first which rule had it so
-        (``chunk_first_by``), and of a step fetched with no successor
-        enqueued which rule held that one (``held_by``, in ``_land``)."""
+        (``chunk_first_by``), of a step fetched with no successor
+        enqueued which rule held that one (``held_by``, in ``_land``), and
+        of a step behind its chunk that was fetched with no chunk enqueued
+        ahead which rule kept that one back (``chunk_held_by``)."""
         dec = ahead or self.sched.decode_requests()
         if not dec:
             return False
@@ -1746,11 +1894,20 @@ class ServingEngine:
                 self._land(obs, sent, span, held_by=held_by)
         if chunk is not None:
             # neither program's span inside the other's. A request is still
-            # in prefill (the chunk was not its prompt's last), so nothing
+            # in prefill (the chunk was not its prompt's last), so no STEP
             # stays in flight at the iteration's end: the rule is known
-            # without being asked
-            self._land_chunk(obs, chunk)
-            self._land(obs, sent, held_by="prefill")
+            # without being asked. That request's next chunk may
+            (req, _), = chunk.rows
+            kept_by = (self._chunk_ahead(obs, req, sent)
+                       if self._land_chunk(obs, chunk) else "dropped")
+            with obs.span("serving/decode", cpu=True,
+                          max_rows=self.config.max_seqs) as span:
+                if kept_by is not None:
+                    span.annotate(chunk_held_by=kept_by)
+                self._land(obs, sent, span, held_by="prefill")
+                if self._chunk is not None:
+                    self._flush(deferred=True)   # at once: the device is
+                    #   busy
         return True
 
     def _land(self, obs, sent: "_Enqueued", span=None,
@@ -1766,7 +1923,8 @@ class ServingEngine:
         and a state slot that whoever takes them next writes over, in a
         program enqueued behind this one. The interval of a step enqueued
         ahead, or behind a chunk in flight, runs for the accountant and the
-        request tracer from that program's fetch to its own: no second is
+        request tracer from that program's fetch to its own, and that of
+        whatever is enqueued behind this step from here: no second is
         counted twice."""
         if span is None:
             with obs.span("serving/decode", cpu=True,
@@ -1777,8 +1935,9 @@ class ServingEngine:
         if held_by is not None:
             span.annotate(held_by=held_by)
         nxt, t1 = self._fetch(obs, sent)
-        if self._flight is not None:
-            self._flight.since = t1
+        for behind in (self._flight, self._chunk):
+            if behind is not None:
+                behind.since = t1
         t0 = sent.t0 if sent.since is None else sent.since
         rt = obs.reqtrace
         acct = self._serve_acct
@@ -1809,10 +1968,12 @@ class ServingEngine:
         """A settled engine, for whoever needs one (under the engine lock):
         the decode step in flight, if the driver thread left one, is
         fetched and applied (``held_by``: as ``_land``; a caller that needs
-        the engine settled gives none), and every applied token is
-        delivered."""
+        the engine settled gives none), or the chunk it left in flight
+        (``_chunk_ahead``), and every applied token is delivered."""
         if self._flight is not None:
             self._land(get_session(), self._flight, held_by=held_by)
+        if self._chunk is not None:
+            self._land_chunk(get_session(), self._chunk)
         self._flush()
 
     def _step_verify(self) -> bool:
@@ -2210,8 +2371,9 @@ class ServingEngine:
                         busy = self.in_flight()
                         if not busy:
                             # the last iteration's tokens, and a step
-                            # whose every row ended under it: no program
-                            # will be enqueued to deliver them behind
+                            # whose every row ended under it, or a chunk
+                            # whose request did: no program will be
+                            # enqueued to deliver them behind
                             self._flush_locked()
                             self._stop.wait(0.002)
                     if busy:
@@ -2234,8 +2396,8 @@ class ServingEngine:
             t.join(timeout=5.0)
             self._thread = None
         if t is None or not t.is_alive():
-            # a step that a driver's loop on some other thread left in
-            # flight (the thread's own loop brings its own home)
+            # a step or a chunk that a driver's loop on some other thread
+            # left in flight (the thread's own loop brings its own home)
             self._flush_locked()
 
     def close(self) -> None:

@@ -466,6 +466,12 @@ class Scheduler:
                         0)
             if self.cow_block_indices(r, r.length, r.length + 1):
                 return False
+        return self.pages_without_preemption(need)
+
+    def pages_without_preemption(self, need: int) -> bool:
+        """Whether ``need`` pages come from the free list or from unpinned
+        prefix-cache entries (which no request holds, so no program in
+        flight reads them), with nobody preempted. Asks only."""
         short = need - self.alloc.blocks_free
         return short <= 0 or (self.prefix is not None
                               and self.prefix.can_evict(short))

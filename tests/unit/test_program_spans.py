@@ -499,6 +499,21 @@ class TestServingFromTheInside:
                     (c for c in spans if c.get("parent_id") == parent["id"]
                      and c["name"] == parent["name"] + part), None)
                     for part in ("/dispatch", "/fetch", "/apply"))
+                if disp is None:
+                    # the span that lands a step behind its chunk, with the
+                    # prompt's next chunk enqueued ahead of that fetch: the
+                    # device is busy, the tokens are delivered at once
+                    # (and the late half of a LAST chunk that went ahead,
+                    # fetched before its iteration's step is prepared: the
+                    # delivery and the account lie before that fetch)
+                    if parent["name"] == "serving/decode":
+                        assert parent["attrs"]["held_by"] == "prefill"
+                        assert "chunk_held_by" not in parent["attrs"]
+                        assert applied["end_s"] <= s["start_s"]
+                    else:
+                        assert parent["attrs"]["ahead"] == 1
+                        assert s["end_s"] <= fetch["start_s"]
+                    continue
                 assert disp["end_s"] <= s["start_s"]
                 if parent["attrs"].get("ahead") \
                         and s["name"] == "serving/emit" \
@@ -545,7 +560,10 @@ class TestServingFromTheInside:
                            if c.get("parent_id") == parent["id"]),
                           key=lambda c: c["start_s"])
             names = [c["name"].replace(program, "...") for c in kids]
-            if names == [".../fetch", ".../apply"]:
+            if ".../prepare" not in names:
+                # the late half: of a chunk whose step went behind it, or of
+                # one that the last iteration enqueued ahead (a last chunk's
+                # holds what is delivered before its fetch too)
                 assert program == "serving/prefill_chunk"
                 early = max((s for s in spans if s["name"] == program
                              and s["end_s"] <= parent["start_s"]),
@@ -562,7 +580,8 @@ class TestServingFromTheInside:
             # no fetch of its own; one enqueued AHEAD holds its
             # predecessor's, and delivers that step's tokens at once
             landed = names.count(".../fetch")
-            ahead = parent["attrs"].get("ahead", 0)
+            ahead = (parent["attrs"].get("ahead", 0)
+                     if program == "serving/decode" else 0)
             assert landed or program == "serving/decode"
             assert names == [".../prepare", ".../dispatch",
                              *shadow[:len(shadow) - ahead],
@@ -622,7 +641,12 @@ class TestServingFromTheInside:
         (``behind_chunk`` 1, and the chunk's fetch and apply lie in a span of
         their own that carries its ``tokens``); the third chunk brings a
         first token, and its fetch and apply come before the step is
-        prepared. Neither program's span lies inside the other's."""
+        prepared. Neither program's span lies inside the other's. Chunks two
+        and three are enqueued AHEAD (PR 58): ``.../prepare`` and
+        ``.../dispatch`` in a span of the iteration before, between the fetch
+        of that iteration's chunk and the fetch of its step, ``.../fetch`` and
+        ``.../apply`` in a span of their own in the next; ``ahead`` and
+        ``late`` agree on the two."""
         reset_session()
         srv = serving(tiny_engine)
         srv.submit(np.arange(1, 40), max_new_tokens=3)
@@ -675,8 +699,43 @@ class TestServingFromTheInside:
             else:
                 assert leaf(chunk, "apply")["end_s"] \
                     <= leaf(step, "prepare")["start_s"]
-                assert leaf(chunk, "prepare") is not None
+                assert step["attrs"]["chunk_first_by"] == "last_chunk"
+            assert chunk["attrs"]["ahead"] == (start > 0)
+            early = [s for s in spans if s["name"] == "serving/prefill_chunk"
+                     and s["attrs"]["rid"] == late.request_id
+                     and s["attrs"]["chunk_start"] == start
+                     and "tokens" not in s["attrs"]]
+            assert len(early) == 1 and leaf(early[0], "fetch") is None
+            assert leaf(early[0], "dispatch")["end_s"] <= fetch["start_s"]
+            assert early[0]["attrs"]["ahead"] == (start > 0)
+            if start == 0:
+                assert early[0]["parent_id"] == iteration["id"]
+                assert "late" not in chunk["attrs"]
+                continue
+            # enqueued in the iteration before, behind that iteration's
+            # chunk's fetch and ahead of its step's
+            before = by_id[early[0]["parent_id"]]
+            assert before["name"] == "serving/iteration"
+            assert before["end_s"] <= iteration["start_s"]
+            tops = sorted((s for s in spans if s.get("parent_id")
+                           == before["id"] and s["name"] in (
+                               "serving/prefill_chunk", "serving/decode")),
+                          key=lambda s: s["start_s"])
+            assert tops[-2] is early[0]
+            assert tops[-3]["attrs"]["chunk_start"] == start - 16
+            assert leaf(tops[-3], "fetch") is not None
+            assert tops[-3]["end_s"] <= early[0]["start_s"]
+            assert early[0]["end_s"] <= tops[-1]["start_s"]
+            assert tops[-1]["attrs"]["held_by"] == "prefill"
+            assert "chunk_held_by" not in tops[-1]["attrs"]
+            assert leaf(early[0], "dispatch")["end_s"] \
+                <= leaf(tops[-1], "fetch")["start_s"]
+            assert chunk["attrs"]["late"] == early[0]["attrs"]["late"]
         assert behind == [1, 1, 0]
+        went = [s["attrs"] for s in spans if s["name"] == "serving/prefill_chunk"
+                and s["attrs"]["ahead"] and "tokens" in s["attrs"]]
+        assert len(went) == 2       # (the registry's counter, which counts
+        #   under an enabled session: ``test_serving.py::TestChunkAhead``)
         assert sum(s["attrs"].get("tokens", 0) for s in spans
                    if s["name"] == "serving/prefill_chunk"
                    and s["attrs"]["rid"] == late.request_id) == 40

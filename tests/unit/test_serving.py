@@ -1016,12 +1016,16 @@ class TestDeadlineEnforcement:
 # ---------------------------------------------------------------------------
 
 
-def never_ahead(srv):
+def never_ahead(srv, chunks=True):
     """Hold the driver thread's form to the iteration that fetches what it
     enqueued (what the engine does whenever somebody waits for the device:
-    ``TestStepAhead`` has the states that say so): the order of enqueue,
-    delivery and fetch around ONE program is what these tests fix."""
+    ``TestStepAhead`` has the states that say so, ``TestChunkAhead`` those
+    of a chunk, which ``chunks=False`` leaves to the engine): the order of
+    enqueue, delivery and fetch around ONE program is what these tests
+    fix."""
     srv._ahead_held_by = lambda flight: "queued"
+    if chunks:
+        srv._chunk_held_by = lambda req: "pages"
     return srv
 
 
@@ -1741,6 +1745,11 @@ PARENT_ORDER = {
 }
 
 
+# the chunks of each case that go ahead of their decode step's fetch: the
+# chunks but the first of every prompt whose step went behind the chunk before
+CHUNKS_AHEAD = {"budget": 1, "eos": 0, "greedy": 4, "moe": 2, "one_token": 0,
+                "queued": 3, "recurrent": 2, "sampled": 1, "small_pool": 13}
+
 ORDER_LETTER = {("dispatch", "prefill_chunk"): "c",
                 ("dispatch", "decode"): "d",
                 ("fetched", "prefill_chunk"): "C",
@@ -1854,8 +1863,13 @@ class TestHostCauses:
             srv.close()
         finally:
             reset_session()
-        assert "".join(ORDER_LETTER[e] for e in events
-                       if e in ORDER_LETTER) == PARENT_ORDER[case]
+        order = "".join(ORDER_LETTER[e] for e in events if e in ORDER_LETTER)
+        # since PR 58 a continuing prompt's next chunk is enqueued between
+        # its chunk's fetch and the fetch of the step behind that chunk:
+        # put behind that fetch again, every enqueue and fetch is where the
+        # parent had it
+        assert order.count("CcD") == CHUNKS_AHEAD[case]
+        assert order.replace("CcD", "CDc") == PARENT_ORDER[case]
 
     @pytest.mark.parametrize("name", sorted(HELD_BY_CASES))
     def test_held_by_names_the_rule_that_held_the_step(self, tiny_engine,
@@ -1980,7 +1994,11 @@ class TestHostCauses:
             self, tiny_engine, obs_session, monkeypatch, told):
         """``is_ready()`` of the tokens the engine holds, told what to say:
         ``late`` on the spans with ``ahead`` or ``behind_chunk`` and on no
-        other, and the counter beside ``serving/steps_enqueued_ahead``."""
+        other, and the counter beside ``serving/steps_enqueued_ahead``. A
+        chunk enqueued ahead asks once too, and says so on both its spans:
+        ``TestChunkAhead``."""
+        from deepspeed_tpu.observability import recorded_spans
+
         srv = serving(tiny_engine, prefix_cache=False)
         asked = []
         monkeypatch.setattr(type(srv._last_tokens), "is_ready",
@@ -1992,8 +2010,12 @@ class TestHostCauses:
         drive_on_this_thread(srv)
         steps = decode_spans()
         behind = [s for s in steps if s.get("ahead") or s.get("behind_chunk")]
-        assert len(behind) > 5 and len(asked) == len(behind)
-        assert all(s["late"] == int(told) for s in behind)
+        chunks = [s["attrs"] for s in recorded_spans()
+                  if s["name"] == "serving/prefill_chunk"
+                  and s["attrs"]["ahead"] and "tokens" in s["attrs"]]
+        assert len(chunks) == 2
+        assert len(behind) > 5 and len(asked) == len(behind) + len(chunks)
+        assert all(s["late"] == int(told) for s in behind + chunks)
         assert not any("late" in s for s in steps if s not in behind)
         assert late.value() - before == (len(behind) if told else 0)
         srv.close()
@@ -2561,3 +2583,616 @@ class TestDecodeBehindChunk:
             srv.close()
         finally:
             reset_session()
+
+
+# ---------------------------------------------------------------------------
+# a chunk ahead of its decode step's fetch: under the driver thread an
+# iteration whose step went behind its chunk enqueues the prompt's NEXT chunk
+# behind that step, once the chunk is applied and before the step is fetched.
+# The chunk is in flight across the iteration's end; the next iteration's
+# step goes behind it, or it is landed first. Same programs, same operands,
+# same order on the device, same tokens
+# ---------------------------------------------------------------------------
+
+
+def watch_chunks_ahead(srv):
+    """What ``_chunk_ahead`` answered, call by call: None where the chunk
+    went ahead, else the rule's name."""
+    log, ahead = [], srv._chunk_ahead
+
+    def spy(obs, req, step):
+        log.append(ahead(obs, req, step))
+        if log[-1] is None:
+            assert srv._chunk is not None and srv._chunk.ahead
+            assert srv._flight is step
+        return log[-1]
+
+    srv._chunk_ahead = spy
+    return log
+
+
+def long_prompt_beside_rows(srv, n=40, max_new_tokens=4, **kw):
+    """Two rows that decode, then a prompt of ``n`` tokens beside them."""
+    a, b = decoding_pair(srv)
+    return a, b, srv.submit(np.arange(50, 50 + n, dtype=np.int32),
+                            max_new_tokens=max_new_tokens, **kw)
+
+
+def chunk_spans(mark=0):
+    from deepspeed_tpu.observability import recorded_spans
+
+    return [s["attrs"] for s in recorded_spans()[mark:]
+            if s["name"] == "serving/prefill_chunk"]
+
+
+# prompts of 3-7 chunks of 16 beside short ones that decode meanwhile
+AHEAD_PROMPTS = [(9, dict(max_new_tokens=14)),
+                 (100, dict(max_new_tokens=6)),
+                 (52, dict(max_new_tokens=7, temperature=0.9, top_k=20,
+                           seed=4)),
+                 (7, dict(max_new_tokens=12, temperature=1.2, seed=9)),
+                 (112, dict(max_new_tokens=5)), (70, dict(max_new_tokens=8))]
+
+CHUNK_AHEAD_CASES = {
+    # name: (engine fixture, engine config)
+    "dense": ("tiny_engine", {}),
+    "dense_no_cache": ("tiny_engine", dict(prefix_cache=False)),
+    "moe": ("tiny_moe_engine", {}),
+    # a state slot a row, which the chunk ahead reads behind the step
+    "kda": ("tiny_kda_engine", dict(prefix_cache=False)),
+    "mamba2": ("tiny_recurrent_engine", dict(prefix_cache=False)),
+    # a slot, a window's ring, one shared pool and a ``last`` flag
+    "mamba1": ("tiny_mamba1_engine", dict(prefix_cache=False)),
+    # a pool so small that chunks and rows preempt to grow: no chunk goes
+    # ahead where its pages are not on the free list
+    "small_pool": ("tiny_engine", dict(num_blocks=11, prefix_cache=False)),
+    "two_rows": ("tiny_engine", dict(max_seqs=2)),
+}
+
+
+class TestChunkAhead:
+    @pytest.mark.parametrize("case", sorted(CHUNK_AHEAD_CASES))
+    def test_streams_are_those_of_a_step_driven_engine(self, request, case):
+        fixture, cfg = CHUNK_AHEAD_CASES[case]
+        engine = request.getfixturevalue(fixture)
+        rng = np.random.RandomState(sum(map(ord, case)))
+        reqs = [(rng.randint(0, 250, (n,)).astype(np.int32), kw)
+                for n, kw in AHEAD_PROMPTS]
+        streams, went = {}, {}
+        for mode in ("step", "thread", "driver_loop"):
+            srv = serving(engine, **cfg)
+            watch_chunks(srv)
+            log = watch_chunks_ahead(srv)
+            try:
+                handles = [srv.submit(p, **kw) for p, kw in reqs]
+                if mode == "step":
+                    srv.run()
+                elif mode == "thread":
+                    srv.start()
+                else:
+                    drive_on_this_thread(srv)
+                streams[mode] = [list(h.result(timeout_s=120.0))
+                                 for h in handles]
+                srv.stop()
+                assert all(h.done and h.state == "finished"
+                           and h.tokens == h._req.generated for h in handles)
+                assert srv._chunk is None and srv._flight is None
+                assert not srv.sched.running and not srv._undelivered
+                assert srv.alloc.blocks_in_use == (
+                    srv.prefix.cached_blocks if srv.prefix else 0)
+                went[mode] = log
+                if case == "small_pool":
+                    assert srv.sched.preemption_count > 0
+            finally:
+                srv.close()
+        assert streams["thread"] == streams["step"]
+        assert streams["driver_loop"] == streams["step"]
+        assert went["step"] == []
+        for mode in ("thread", "driver_loop"):
+            assert went[mode].count(None) >= 3, went[mode]
+        if case == "small_pool":
+            assert "pages" in went["driver_loop"]
+
+    def test_the_next_chunk_is_dispatched_before_the_step_is_fetched(
+            self, tiny_engine, monkeypatch):
+        """A prompt of three chunks beside two rows that decode. Chunk two
+        is enqueued between chunk one's fetch and the fetch of the step
+        behind it, and that step's tokens are delivered at once; the next
+        iteration starts at its decode step. Chunk three, the prompt's
+        last, goes ahead too and is fetched, and its first token delivered,
+        before its iteration's step is prepared."""
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False),
+                          chunks=False)
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        log = watch_chunks(srv)
+        went = watch_chunks_ahead(srv)
+        try:
+            a, b, c = long_prompt_beside_rows(srv)
+            na, nb = len(a.tokens), len(b.tokens)
+            del events[:], log[:]
+            drive_on_this_thread(srv, iterations=3)
+            ra, rb, rc = a.request_id, b.request_id, c.request_id
+            assert events == [
+                ("dispatch", "prefill_chunk"), ("dispatch", "decode"),
+                ("push", ra, na), ("push", rb, nb),
+                ("fetched", "prefill_chunk"), ("dispatch", "prefill_chunk"),
+                ("fetched", "decode"),
+                ("push", ra, na + 1), ("push", rb, nb + 1),
+                ("iteration_end",),
+                ("dispatch", "decode"),
+                ("fetched", "prefill_chunk"), ("dispatch", "prefill_chunk"),
+                ("fetched", "decode"),
+                ("push", ra, na + 2), ("push", rb, nb + 2),
+                ("iteration_end",),
+                ("fetched", "prefill_chunk"), ("push", rc, 0),
+                ("dispatch", "decode"), ("fetched", "decode"),
+                ("iteration_end",)]
+            assert went == [None, None]
+            assert [b for b, _ in log] == [True, True, False]
+            assert [rids for _, rids in log] == [[ra, rb], [ra, rb],
+                                                 [ra, rb, rc]]
+            assert srv._chunk_first == "last_chunk"
+            assert srv._chunk is None and srv._flight is None
+            assert srv.prefill_chunks_run == 2 + 3
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("how", ["step", "drafter", "two_chunks"])
+    def test_today_s_order_is_kept(self, tiny_engine, monkeypatch, how):
+        """Under ``step()``, with a drafter proposing, or with two chunks an
+        iteration (the live tuner's knob) no chunk goes ahead; ``step()``
+        and the drafter never come to ask."""
+        cfg = (dict(speculative={"mode": "ngram", "num_draft_tokens": 2})
+               if how == "drafter" else {})
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False, **cfg),
+                          chunks=False)
+        went = watch_chunks_ahead(srv)
+        if how == "two_chunks":
+            srv.prefill_chunks_per_iter = 2
+        try:
+            a = srv.submit(np.arange(3, 10, dtype=np.int32),
+                           max_new_tokens=40)
+            srv.step()
+            c = srv.submit(np.arange(50, 122, dtype=np.int32),
+                           max_new_tokens=4)
+            for _ in range(2):
+                srv.step() if how == "step" else srv._iterate(defer=True)
+                assert srv._chunk is None and srv._flight is None
+            assert went == (["more_chunks"] * 2 if how == "two_chunks"
+                            else [])
+            assert c._req.prefill_pos == (64 if how == "two_chunks" else 32)
+            srv.prefill_chunks_per_iter = 1
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4 and a.done
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("how", ["step", "more_chunks", "drafter"])
+    def test_a_rule_that_comes_to_hold_lands_the_chunk_first(
+            self, tiny_engine, monkeypatch, how):
+        """With a chunk ahead in flight the next iteration asks today's
+        rules: ``step()``, a second chunk an iteration or a drafter that
+        proposes again each have the chunk fetched and applied before
+        anything else is enqueued."""
+        cfg = (dict(speculative={"mode": "ngram", "num_draft_tokens": 2})
+               if how == "drafter" else {})
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False, **cfg),
+                          chunks=False)
+        srv.spec_suspended = True
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        watch_chunks(srv)
+        try:
+            a, b, c = long_prompt_beside_rows(srv, n=72)
+            srv._iterate(defer=True)
+            assert srv._chunk is not None and srv._chunk.ahead
+            assert c._req.prefill_pos == 16
+            if how == "more_chunks":
+                srv.prefill_chunks_per_iter = 2
+            srv.spec_suspended = how != "drafter"
+            del events[:]
+            srv.step() if how == "step" else srv._iterate(defer=True)
+            order = [e for e in events if e[0] != "push"]
+            chunk = [("dispatch", "prefill_chunk"),
+                     ("fetched", "prefill_chunk")]
+            assert order == [("fetched", "prefill_chunk")] + {
+                # brought home, then an iteration of its own
+                "step": chunk + [("dispatch", "decode"),
+                                 ("fetched", "decode")],
+                # the iteration's first chunk; its second waits for the step
+                "more_chunks": [chunk[0], ("dispatch", "decode"), chunk[1],
+                                ("fetched", "decode")],
+                "drafter": [("dispatch", "verify"), ("fetched", "verify")],
+            }[how] + [("iteration_end",)]
+            assert c._req.prefill_pos == (32 if how == "drafter" else 48)
+            assert srv._chunk is None and srv._flight is None
+            srv.prefill_chunks_per_iter = 1
+            srv.spec_suspended = True
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4 and srv.alloc.blocks_in_use == 0
+        finally:
+            srv.close()
+
+    def test_a_row_that_would_preempt_has_the_chunk_ahead_landed_first(
+            self, tiny_engine, monkeypatch):
+        """The chunk ahead took its page; the decode row that needs one an
+        iteration on has to preempt, so the chunk in flight is fetched and
+        applied before the step is prepared, and only then is its request
+        evicted: nobody loses a row with a chunk's progress not applied."""
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False,
+                                  num_blocks=8), chunks=False)
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        watch_chunks(srv)
+        try:
+            # 14 prompt tokens: the row needs its second page two steps on
+            a = srv.submit(np.arange(3, 17, dtype=np.int32),
+                           max_new_tokens=40)
+            drive_on_this_thread(srv, iterations=1)
+            c = srv.submit(np.arange(50, 146, dtype=np.int32),
+                           max_new_tokens=4)      # six pages, chunk by chunk
+            srv._iterate(defer=True)
+            assert srv._chunk is not None and srv._chunk.ahead
+            assert len(c._req.blocks) == 2 and a._req.length == 16
+            spare = _grab_free_pages(srv)
+            applied = []
+            progress = srv.sched.note_prefill_progress
+            srv.sched.note_prefill_progress = lambda req, old, new: (
+                applied.append((req.rid, new, srv.sched.preemption_count)),
+                progress(req, old, new))[1]
+            del events[:]
+            srv._iterate(defer=True)
+            order = [e for e in events if e[0] != "push"]
+            assert order == [("fetched", "prefill_chunk"),
+                             ("dispatch", "decode"), ("fetched", "decode"),
+                             ("iteration_end",)]
+            assert srv._chunk_first == "pages"
+            assert applied == [(c.request_id, 32, 0)]
+            assert srv.sched.preemption_count == 1 and c.state == "queued"
+            assert a.state == "decode" and len(a._req.blocks) == 2
+            srv.alloc.free(spare)
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4 and srv.alloc.blocks_in_use == 0
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline", "preempt",
+                                     "stop"])
+    def test_a_request_that_ends_under_its_chunk_ahead(self, tiny_engine,
+                                                       obs_session, how):
+        """Between two iterations, with the prompt's second chunk in flight
+        behind the first iteration's step: the request is cancelled, it
+        expires, it loses its row, or the engine is stopped. The engine is
+        settled by whoever needs it so, the request's pages and row come
+        back once, nothing is delivered to it, the chunk's progress goes to
+        nobody it is not for, and the rows that decode stream what they
+        stream without any of it."""
+        from deepspeed_tpu.observability import recorded_spans
+
+        want = {}
+        for cut in (False, True):
+            clk = FakeClock()
+            srv = never_ahead(serving(tiny_engine, clock=clk,
+                                      prefix_cache=False), chunks=False)
+            try:
+                a, b, c = long_prompt_beside_rows(srv, deadline_s=5.0)
+                srv._iterate(defer=True)
+                sent = srv._chunk
+                assert sent.ahead and sent.rows == [(c._req, c._req.row)]
+                assert (sent.start, c._req.prefill_pos) == (16, 16)
+                free, chunks = srv.alloc.blocks_free, srv.prefill_chunks_run
+                mark = len(recorded_spans())
+                if cut and how == "cancel":
+                    assert c.cancel()
+                    assert srv._chunk is None and not srv._undelivered
+                    # brought home for the cancel: applied, then given back
+                    assert c._req.prefill_pos == 32
+                elif cut and how == "deadline":
+                    clk.advance(10.0)
+                    srv._iterate(defer=True)
+                    assert c.state == "deadline_exceeded"
+                    assert c._req.prefill_pos == 16
+                elif cut and how == "preempt":
+                    srv.sched.preempt(c._req)
+                    srv._iterate(defer=True)    # admits it again, at 0
+                    assert c.state == "prefill" and c._req.preemptions == 1
+                    assert c._req.prefill_pos == 0
+                elif cut:
+                    srv.stop()
+                    assert srv._chunk is None and c._req.prefill_pos == 32
+                if cut and how in ("deadline", "preempt"):
+                    # the chunk ran for nobody, the step went behind it, and
+                    # nothing went ahead of that step's fetch
+                    landed, = [s for s in chunk_spans(mark) if "tokens" in s]
+                    assert landed["dropped_rows"] == 1 and landed["ahead"]
+                    steps = [s for s in decode_spans() if "held_by" in s]
+                    assert steps[-1]["chunk_held_by"] == "dropped"
+                    assert srv._chunk is None
+                if cut:
+                    assert srv.prefill_chunks_run == chunks + 1
+                if cut and how in ("cancel", "deadline"):
+                    assert c._req.row is None and not c._req.blocks
+                    assert srv.alloc.blocks_free == free + 2
+                    assert c.tokens == [] and c._req.done
+                    srv.cancel(c)       # wakes the handle; frees nothing
+                    assert srv.alloc.blocks_free == free + 2
+                drive_on_this_thread(srv)
+                if not (cut and how in ("cancel", "deadline")):
+                    assert len(c.tokens) == 4
+                    want.setdefault("c", c.tokens)
+                    assert c.tokens == want["c"]
+                want[cut] = (a.tokens, b.tokens)
+                assert len(a.tokens) == len(b.tokens) == 40
+                assert srv._chunk is None and srv._flight is None
+                assert srv.alloc.blocks_in_use == 0
+            finally:
+                srv.close()
+        assert want[True] == want[False]
+
+    def test_an_iteration_that_raises_runs_its_chunk_ahead_again(
+            self, tiny_engine, monkeypatch):
+        """The fetch of the step raises with the prompt's next chunk
+        enqueued ahead of it: the iteration ends, the chunk in flight is
+        forgotten, not applied, and the next iteration runs it again."""
+        streams = {}
+        for fails in (False, True):
+            srv = never_ahead(serving(tiny_engine, prefix_cache=False),
+                              chunks=False)
+            try:
+                a, b, c = long_prompt_beside_rows(srv)
+                fetch, raised = srv._fetch, []
+
+                def failing_fetch(obs, sent):
+                    if (fails and not raised and sent.name == "serving/decode"
+                            and srv._chunk is not None):
+                        raised.append(srv._chunk.start)
+                        raise RuntimeError("the device is gone")
+                    return fetch(obs, sent)
+
+                monkeypatch.setattr(srv, "_fetch", failing_fetch)
+                if fails:
+                    with pytest.raises(RuntimeError, match="device is gone"):
+                        srv._iterate(defer=True)
+                    assert raised == [16] and srv._chunk is None
+                    assert c._req.prefill_pos == 16
+                    srv._flight = None      # what the driver's loop drops
+                else:
+                    srv._iterate(defer=True)
+                    assert srv._chunk.start == 16
+                drive_on_this_thread(srv)
+                streams[fails] = c.tokens
+                assert len(c.tokens) == 4 and srv.alloc.blocks_in_use == 0
+            finally:
+                srv.close()
+        assert streams[True] == streams[False]
+
+    def test_prefix_sharing_of_a_prompt_whose_chunks_went_ahead(
+            self, tiny_engine):
+        """The pages of a prompt whose chunks went ahead are offered to the
+        prefix cache as each chunk is applied; a second request with the
+        same prefix maps them and skips those chunks, under the driver
+        thread as under ``step()``."""
+        prompt = np.arange(20, 100, dtype=np.int32)        # five chunks
+        got = {}
+        for mode in ("step", "driver_loop"):
+            srv = never_ahead(serving(tiny_engine), chunks=False)
+            went = watch_chunks_ahead(srv)
+            watch_chunks(srv)
+            try:
+                decoding_pair(srv)
+                first = srv.submit(prompt, max_new_tokens=5)
+                srv.run() if mode == "step" else drive_on_this_thread(srv)
+                chunks = srv.prefill_chunks_run
+                assert went == ([] if mode == "step" else [None] * 4)
+                decoding_pair(srv)
+                second = srv.submit(np.concatenate([prompt[:70], [7, 8, 9]]),
+                                    max_new_tokens=5, temperature=0.7,
+                                    seed=3)
+                srv.run() if mode == "step" else drive_on_this_thread(srv)
+                # four full pages shared: one chunk of 9 tokens is left
+                assert srv.sched.prefix_hit_tokens == 64
+                got[mode] = (first.tokens, second.tokens,
+                             srv.prefill_chunks_run - chunks,
+                             srv.prefix.cached_blocks)
+                assert srv._chunk is None
+            finally:
+                srv.close()
+        assert got["driver_loop"] == got["step"]
+
+    @pytest.mark.parametrize("model", ["dense", "moe", "mamba2"])
+    def test_the_ahead_count_the_late_count_and_the_counter(
+            self, request, obs_session, monkeypatch, model):
+        """``ahead`` is 1 on both spans of chunks two and three and 0 on the
+        prompt's first; ``late`` says what ``is_ready()`` of the step's
+        tokens said at the enqueue, on both; the registry counts the two;
+        each chunk's ``tokens`` and program counts lie once, on the span
+        that holds its fetch; neither program's span inside the other's."""
+        from deepspeed_tpu.observability import recorded_spans
+
+        engine = request.getfixturevalue(
+            {"dense": "tiny_engine", "moe": "tiny_moe_engine",
+             "mamba2": "tiny_recurrent_engine"}[model])
+        counter = get_registry().counter("serving/chunks_enqueued_ahead")
+        before = counter.value()
+        srv = never_ahead(serving(engine, prefix_cache=False), chunks=False)
+        told = iter([0, 1, 0, 1, 0, 1, 0])
+        a, b, c = long_prompt_beside_rows(srv)
+        monkeypatch.setattr(type(srv._last_tokens), "is_ready",
+                            lambda self: next(told))
+        mark = len(recorded_spans())
+        drive_on_this_thread(srv, iterations=3)
+        spans = recorded_spans()[mark:]
+        by_id = {s["id"]: s for s in spans}
+        top = [s for s in spans
+               if s["name"] in ("serving/decode", "serving/prefill_chunk")]
+        its = sorted({s["parent_id"] for s in top})
+        assert all(by_id[i]["name"] == "serving/iteration" for i in its)
+        kids = {s["id"]: [k["name"].rsplit("/", 1)[1] for k in spans
+                          if k.get("parent_id") == s["id"]
+                          and k["cat"] == "phase"] for s in top}
+        shapes = [[(s["name"].split("/")[1], kids[s["id"]]) for s in top
+                   if s["parent_id"] == i] for i in its]
+        early, late_half = ["prepare", "dispatch"], ["fetch", "apply"]
+        assert shapes == [
+            [("prefill_chunk", early), ("decode", early),
+             ("prefill_chunk", late_half), ("prefill_chunk", early),
+             ("decode", late_half)],
+            [("decode", early), ("prefill_chunk", late_half),
+             ("prefill_chunk", early), ("decode", late_half)],
+            [("prefill_chunk", late_half), ("decode", early + late_half)]]
+        chunks = [s["attrs"] for s in top
+                  if s["name"] == "serving/prefill_chunk"]
+        assert [a["chunk_start"] for a in chunks] == [0, 0, 16, 16, 32, 32]
+        assert [a["ahead"] for a in chunks] == [0, 0, 1, 1, 1, 1]
+        assert [a.get("tokens") for a in chunks] == [None, 16, None, 16,
+                                                     None, 8]
+        # asked: the step behind chunk one (0), chunk two behind that step
+        # (1), the step behind chunk two (0), chunk three (1)
+        assert [a.get("late") for a in chunks] == [None, None, 1, 1, 1, 1]
+        assert {a["rid"] for a in chunks} == {c.request_id}
+        assert counter.value() - before == 2
+        counted = "moe_assignments" if model == "moe" else (
+            "ssm_rows" if model == "mamba2" else None)
+        if counted:
+            assert [counted in a for a in chunks] == [False, True] * 3
+        steps = [s["attrs"] for s in top if s["name"] == "serving/decode"]
+        assert [a.get("behind_chunk") for a in steps] == [1, None, 1, None, 0]
+        assert [a.get("held_by") for a in steps] == [
+            None, "prefill", None, "prefill", "queued"]
+        assert not any("chunk_held_by" in a for a in steps)
+        assert steps[-1]["chunk_first_by"] == "last_chunk"
+        # what the step behind a chunk ahead brought is delivered at once,
+        # in the span that fetched it
+        emits = [s for s in spans if s["name"] == "serving/emit"]
+        assert [(by_id[s["parent_id"]]["name"], s["attrs"]["deferred"])
+                for s in emits] == [
+            ("serving/decode", 1), ("serving/decode", 1),
+            ("serving/decode", 1), ("serving/prefill_chunk/apply", 0)]
+        drive_on_this_thread(srv)
+        assert len(c.tokens) == 4
+        srv.close()
+
+    @pytest.mark.parametrize("name", ["pages", "cow"])
+    def test_chunk_held_by_names_the_rule_that_kept_the_chunk_back(
+            self, tiny_engine, obs_session, name):
+        """The free list is short of the chunk's page, or the chunk would
+        write into a page it shares: nothing is taken, evicted or copied on
+        behalf of a chunk ahead, the span that fetches the step says which
+        rule it was, and the next iteration runs the chunk as it always
+        did."""
+        from deepspeed_tpu.serving.api import CHUNK_HELD_BY
+
+        assert name in CHUNK_HELD_BY
+        srv = never_ahead(serving(tiny_engine, prefix_cache=False),
+                          chunks=False)
+        went = watch_chunks_ahead(srv)
+        try:
+            a, b, c = long_prompt_beside_rows(srv, n=72)
+            srv._iterate(defer=True)
+            assert went == [None] and len(c._req.blocks) == 2
+            if name == "pages":
+                spare = _grab_free_pages(srv)
+                undo = lambda: srv.alloc.free(spare)
+            else:
+                # the page the NEXT chunk writes is there, and shared
+                assert srv.sched.ensure_blocks(c._req, 48)
+                shared = [c._req.blocks[2]]
+                srv.alloc.incref(shared)
+                undo = lambda: srv.alloc.free(shared)
+            copies, in_use = srv._cow_copies, srv.alloc.blocks_in_use
+            srv._iterate(defer=True)
+            assert went == [None, name] and srv._chunk is None
+            assert (srv._cow_copies, srv.alloc.blocks_in_use) == (copies,
+                                                                  in_use)
+            step = [s for s in decode_spans() if "held_by" in s][-1]
+            assert step["chunk_held_by"] == name
+            assert step["held_by"] == "prefill"
+            assert c._req.prefill_pos == 32
+            if name == "pages":
+                undo()
+            srv._iterate(defer=True)
+            assert c._req.prefill_pos == 48 and went[2] is None
+            assert srv._cow_copies == copies + (name == "cow")
+            if name == "cow":
+                undo()
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4 and srv.alloc.blocks_in_use == 0
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("telemetry", ["accountant", "reqtrace"])
+    def test_no_second_is_counted_twice(self, tiny_engine, tmp_path,
+                                        telemetry):
+        """The chunk ahead's interval runs from the fetch of the step it was
+        enqueued behind to its own fetch, the next step's from there."""
+        configure_observability(ObservabilityConfig(
+            enabled=True, output_dir=str(tmp_path / "obs"),
+            recompile_watchdog=False, flight_recorder=False,
+            hang_watchdog=False, request_tracing=True,
+            trace_sample_rate=1.0, serve_goodput=True))
+        try:
+            ticks = iter(range(10_000))
+            srv = never_ahead(serving(tiny_engine, prefix_cache=False,
+                                      clock=lambda: float(next(ticks))),
+                              chunks=False)
+            a, b, c = long_prompt_beside_rows(srv, n=72)
+            noted, intervals = [], []
+            acct = srv._accountant()
+            note, rt = acct.note_phase, get_session().reqtrace
+            interval, note_decode = rt.interval, rt.note_decode
+            acct.note_phase = lambda phase, s: (noted.append((phase, s)),
+                                                note(phase, s))[1]
+            rt.interval = lambda tr, phase, t0, t1, **kw: (
+                intervals.append((phase, t0, t1)),
+                interval(tr, phase, t0, t1, **kw))[1]
+            rt.note_decode = lambda tr, t0, t1, **kw: (
+                intervals.append(("decode", t0, t1)),
+                note_decode(tr, t0, t1, **kw))[1]
+            t_begin = float(next(ticks))
+            srv._iterate(defer=True)
+            srv._iterate(defer=True)
+            t_end = float(next(ticks))
+            assert srv._chunk is not None and srv._chunk.start == 32
+            if telemetry == "accountant":
+                device = [s for p, s in noted if p in ("prefill", "decode")]
+                assert len(device) == 4 and all(s > 0 for s in device)
+                assert sum(device) <= t_end - t_begin
+            else:
+                chunks = [(t0, t1) for ph, t0, t1 in intervals
+                          if ph == "prefill"]
+                steps = sorted({(t0, t1) for ph, t0, t1 in intervals
+                                if ph == "decode"})
+                (p0, p1), (q0, q1) = chunks
+                (d0, d1), (e0, e1) = steps
+                assert t_begin < p0 < p1 == d0 < d1 == q0 < q1 == e0 < e1 \
+                    < t_end
+            srv.close()
+        finally:
+            reset_session()
+
+    def test_unpinned_cache_entries_are_room_for_a_chunk_ahead(
+            self, tiny_engine):
+        """A pool that has run for a while has no free page: what requests
+        gave back lives on as prefix-cache entries. A chunk ahead takes its
+        page from an entry no request holds, as the chunk of the next
+        iteration would have, and nobody is preempted."""
+        srv = never_ahead(serving(tiny_engine), chunks=False)
+        went = watch_chunks_ahead(srv)
+        watch_chunks(srv)
+        try:
+            srv.submit(np.arange(100, 180, dtype=np.int32), max_new_tokens=2)
+            srv.run()
+            assert srv.prefix.cached_blocks == 5
+            a, b, c = long_prompt_beside_rows(srv, n=72)
+            spare = _grab_free_pages(srv)
+            cached = srv.prefix.cached_blocks
+            assert srv.alloc.blocks_free == 0 and srv.prefix.can_evict(5)
+            for _ in range(4):
+                srv._iterate(defer=True)
+            assert went == [None] * 4 and srv.sched.preemption_count == 0
+            assert srv.prefix.cached_blocks < cached + 4
+            srv.alloc.free(spare)
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4
+        finally:
+            srv.close()
