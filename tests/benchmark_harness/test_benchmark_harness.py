@@ -20,6 +20,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import bench_tiny  # noqa: E402
+import live_document  # noqa: E402
 from benchmarks import run as bench_run  # noqa: E402
 from benchmarks.harness import (device, layers, program,  # noqa: E402
                                 spec as spec_mod, stats, traffic,
@@ -127,23 +128,27 @@ def test_moves_is_reported_wherever_the_metric_is(metric):
 
 
 def _break(doc, how):
-    doc = copy.deepcopy(doc)
+    """`doc` broken in one way. What is broken is found by NAME: a later PR
+    appends to every list of the document."""
+    doc, named = copy.deepcopy(doc), live_document.named
     if how == "bad-name":
-        doc["workloads"][0]["name"] = "has space"
+        named(doc["workloads"], "opt-1.3b-d8.train-x1")["name"] = "has space"
     elif how == "unknown-moves":
-        doc["per_layer"][0]["moves"] = "nothing"
+        named(doc["per_layer"], "train_step_dev_ms")["moves"] = "nothing"
     elif how == "moves-not-reported":
-        doc["per_layer"][0]["moves"] = "ttft_p25_ms"
+        # a training cell's metric said to move what only serve-decode reports
+        named(doc["per_layer"], "train_step_dev_ms")["moves"] = "ttft_p25_ms"
     elif how == "width-reduced":
-        doc["configs"][0]["reduced"] = ["hidden_size"]
-    elif how == "two-four-chip-cells":
-        for w in doc["workloads"][:2]:
+        named(doc["configs"], "opt-1.3b")["reduced"] = ["hidden_size"]
+    elif how == "too-many-four-chip-cells":
+        # a quarter of the cells may take four chips, however many there are
+        for w in doc["workloads"]:
             w["chips"] = 4
     elif how == "no-setup":
         doc["end_to_end"] = [m for m in doc["end_to_end"]
                              if m["name"] != "setup_s"]
     elif how == "loose-bound":
-        doc["end_to_end"][0]["bound"] = 0.5
+        named(doc["end_to_end"], "train_tok_s")["bound"] = 0.5
     elif how == "extra-key":
         doc["notes"] = "x"
     return doc
@@ -326,7 +331,7 @@ BROKEN_SHARES = ["share-remainder", "share-vocabulary-over-16-chips",
                  "placement-not-balanced", "placement-an-object",
                  "placement-the-reference-cannot-make"]
 BROKEN_DOCS = ["bad-name", "unknown-moves", "moves-not-reported",
-               "width-reduced", "two-four-chip-cells", "no-setup",
+               "width-reduced", "too-many-four-chip-cells", "no-setup",
                "loose-bound", "extra-key"]
 BROKEN_CONFIGS = ["config-without-tiny", "widths-source-unknown",
                   "size-neither-mapped-nor-equal",
@@ -376,7 +381,7 @@ def test_validate_refuses(how, tmp_path):
         root, doc, path, entry = _shared_root(tmp_path)
     else:
         root, doc = bench_tiny.make_root(str(tmp_path)), copy.deepcopy(DOC)
-        entry = doc["configs"][0]
+        entry = live_document.named(doc["configs"], "opt-1.3b")
         path = os.path.join(root, entry["file"])
     spec_mod.Spec(root).validate()      # sound before it is broken
     if how in BROKEN_DOCS:
@@ -894,8 +899,8 @@ def test_breakdown_names_operations_and_gaps(small_trace):
 @pytest.mark.parametrize("metric", [m["name"] for m in DOC["per_layer"]
                                     if m["source"] == "device_trace"])
 def test_reader_with_nothing_to_read_returns_nothing(metric):
-    ctx = layers.Context(cell=SPEC.cell(CELLS[0]), chips=1, peaks={},
-                         counters={}, model_config=None, trace=None)
+    ctx = layers.Context(cell=SPEC.cell("opt-1.3b-d8.train-x1"), chips=1,
+                         peaks={}, counters={}, model_config=None, trace=None)
     r = SPEC.reader(metric)
     assert layers.reducer(r["reducer"]).reduce(ctx, **r.get("args", {})) \
         is None
@@ -983,7 +988,8 @@ def test_open_loop_cell_arrives_as_data_only(steered, tiny_root, capsys):
     json.dump(t, open(os.path.join(root, "benchmarks", "traffic",
                                    "serve-chat-burst.json"), "w"))
     doc = copy.deepcopy(DOC)
-    doc["workloads"].append(dict(doc["workloads"][1],
+    doc["workloads"].append(dict(live_document.named(doc["workloads"],
+                                        "opt-1.3b.serve-decode"),
                                  name="opt-1.3b.serve-chat-burst",
                                  traffic="serve-chat-burst"))
     for m in doc["end_to_end"] + doc["per_layer"]:

@@ -13,6 +13,7 @@ import os
 
 import pytest
 
+import live_document
 from benchmarks.harness import layers, spec as spec_mod, trace as trace_mod
 from benchmarks.reducers import gap_phase_ms, program_gap_pct
 
@@ -45,8 +46,7 @@ def test_every_gap_metric_of_the_benchmark_has_a_known_number():
                 if SPEC.reader(m["name"])["reducer"] == "gap_phase_ms"}
     assert declared == set(METRICS)
     by_name = {m["name"]: m for m in SPEC.doc["per_layer"]}
-    serving = [c["name"] for c in SPEC.doc["workloads"]
-               if "serve" in c["traffic"]]
+    serving = live_document.serving_cells(SPEC)     # by the traffic's kind
     for name in METRICS:
         m = by_name[name]
         assert (m["layer"], m["unit"], m["better"], m["source"],

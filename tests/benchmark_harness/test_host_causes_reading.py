@@ -1,33 +1,32 @@
 """The readers of the host's causes (PR 57): ten `layer_metrics/` files over
 the spans the serving engine records while a capture is open, and the three
 reducers they bring (`span_wait_ms`, `span_max`, `span_share`; the rest read
-through the accepted `span_count`). Each has a known number on
-`fixtures/host_causes_spans.json`, whose iterations are laid out from designed
-numbers (its `about` says which), reads nothing on the records of a commit
-before the counts were added, and the accepted readers of the same spans keep
-their meaning on the fixture.
+through the accepted `span_count`). Each reads nothing on the records of a
+commit before the counts were added, and the accepted readers of the same
+spans keep their meaning on the fixture, whose iterations are laid out from
+designed numbers (its `about` says which).
 
-Like the nine of PRs 54-56 these are FILES, not yet entries of
-`BENCHMARK.json` (ROADMAP B0 xiii: an entry behind the last breaks
-`test_nemotron_h_cell.py`'s `per_layer[-3:]`, a file no PR but a `benchmark`
-PR may edit), so the fixture lies beside `fixtures/spans/`, not in it, where
-every fixture's metric must be declared; the PR that declares them moves it
-there. The last test appends the entries the files give to a copy of the
-document and validates it: they are ready for that PR."""
+Since PR 59 the ten are entries of `BENCHMARK.json`, at the end of
+`per_layer`, and the fixture lies in `fixtures/spans/host_causes.json`, where
+`test_program_span_metrics.py` finds it by its place and makes the cases that
+stood here: each reader's known number, nothing recorded, no span record.
+What is asked of the LIVE document is asked by name (`live_document.py`): the
+serving cells are its cells whose traffic is not of kind `train`, whatever
+their number."""
 
 import json
 import os
 
 import pytest
 
+import live_document
 from benchmarks.harness import layers, spec as spec_mod
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SPEC = spec_mod.Spec()
-FIXTURE = json.load(open(os.path.join(HERE, "fixtures",
-                                      "host_causes_spans.json")))
-SERVING = next(m for m in SPEC.doc["end_to_end"]
-               if m["name"] == "itl_p50_ms")["workloads"]
+FIXTURE = json.load(open(os.path.join(HERE, "fixtures", "spans",
+                                      "host_causes.json")))
+SERVING = live_document.serving_cells(SPEC)
 
 # name -> (unit, reducer, args): what the issue's table names
 NEW = {
@@ -128,13 +127,6 @@ def test_the_fixture_holds_what_a_known_number_needs():
 
 
 @pytest.mark.parametrize("name", sorted(NEW))
-def test_known_number_on_the_recorded_spans(name, program, capfd):
-    program(FIXTURE["spans"])
-    assert _read(name) == pytest.approx(FIXTURE["expect"][name], rel=1e-9)
-    assert " samples" in capfd.readouterr().err
-
-
-@pytest.mark.parametrize("name", sorted(NEW))
 def test_a_program_whose_spans_carry_no_such_count_leaves_it_out(
         name, program):
     """The parent's records: `rows`, `ahead`, `behind_chunk`, and neither
@@ -143,18 +135,6 @@ def test_a_program_whose_spans_carry_no_such_count_leaves_it_out(
              if s["name"] != "runtime/gc"]
     program(spans)
     assert _read(name) is None
-
-
-@pytest.mark.parametrize("name", sorted(NEW))
-@pytest.mark.parametrize("spans", [[], None], ids=["nothing", "no_record"])
-def test_nothing_recorded_leaves_the_metric_out(name, spans, program,
-                                                monkeypatch):
-    if spans is None:
-        from deepspeed_tpu import observability
-        monkeypatch.delattr(observability, "recorded_spans")
-    else:
-        program(spans)
-    assert _read(name, (400.0, 401.0)) is None
 
 
 def _wait(spans, **args):
@@ -252,33 +232,19 @@ def test_the_accepted_readers_keep_their_meaning_on_these_spans(metric,
                                           rel=1e-6)
 
 
-def _entry(name):
-    r = SPEC.reader(name)
-    return dict(name=name, workloads=list(SERVING),
-                **{k: r[k] for k in ("unit", "better", "source", "layer",
-                                     "moves")})
-
-
 @pytest.mark.parametrize("name", sorted(NEW))
-def test_a_new_metrics_entry_has_the_form_of_the_declared(name):
-    m = _entry(name)
-    assert m["source"] in spec_mod.SOURCES
-    assert m["layer"] in {e["layer"] for e in SPEC.doc["per_layer"]}
-    assert len(SERVING) == 6 and all(
-        SPEC.cell(c).traffic["kind"] != "train" for c in SERVING)
-    assert name not in {e["name"] for e in SPEC.doc["per_layer"]}
+def test_the_metric_is_declared_and_equal_to_its_file(name):
+    """Each for serving cells only, and only for those whose every traced
+    run on the chip reported it (`PERF.md` section 3)."""
+    m = live_document.is_what_its_file_gives(SPEC, name)
+    assert m["layer"] == "serving engine" and m["moves"] == "itl_p50_ms"
 
 
-def test_the_new_metrics_are_ready_to_be_declared_at_the_end():
-    """Appended to `per_layer` as the entries their files give (what a
-    `benchmark` PR does, once `test_nemotron_h_cell.py` finds its three by
-    name), the document validates, the six serving cells report them and no
-    training cell does."""
-    later = spec_mod.Spec()
-    later.doc["per_layer"] += [_entry(n) for n in sorted(NEW)]
-    later.validate()
-    for cell in SERVING:
-        assert set(NEW) <= {m["name"] for m in later.cell(cell).per_layer}
-    for cell in ("opt-1.3b-d8.train-x1", "opt-1.3b.train-zero3-x4"):
-        assert not set(NEW) & {m["name"]
-                               for m in later.cell(cell).per_layer}
+def test_no_training_cell_reports_the_ten():
+    """A training program records none of these spans."""
+    assert set(FIXTURE["expect"]) == set(NEW)
+    training = [w["name"] for w in SPEC.doc["workloads"]
+                if w["name"] not in SERVING]
+    assert SERVING and training
+    for cell in training:
+        assert not set(NEW) & {m["name"] for m in SPEC.cell(cell).per_layer}
