@@ -25,6 +25,9 @@ tables, Kwon et al. SOSP '23):
     {"k": (L, NUM_BLOCKS, BLOCK, KV_HEADS * HEAD_DIM),
      "v": (L, NUM_BLOCKS, BLOCK, KV_HEADS * HEAD_DIM)}
 
+(``L`` pools: a layer's, or in a looped stack a (pass, layer)'s,
+``paged_pools``; a block is then a run of tokens in every pass's pools.)
+
 A token's KV heads share one lane-dense row: the TPU stores and blocks
 arrays in (8, 128) tiles of the last two dims, so a trailing
 ``(KV_HEADS, 64)`` would be padded to twice its size and could not be read
@@ -140,8 +143,15 @@ def ring_blocks(cfg, chunk_tokens: int, block_size: int) -> int:
     return -(-(cfg.attention_window + chunk_tokens) // block_size)
 
 
+def paged_pools(cfg) -> int:
+    """Pools of pages in the arena's ``"k"`` and in its ``"v"``: one a
+    layer that keeps pages and, in a looped stack (``loop_passes``), one a
+    (pass, layer), pass-major: a query of a pass sees that pass's keys."""
+    return cfg.loop_passes * _paged_layers(cfg)
+
+
 def _paged_shape(cfg, num_blocks: int, block_size: int):
-    return (_paged_layers(cfg), num_blocks, block_size,
+    return (paged_pools(cfg), num_blocks, block_size,
             cfg.num_kv_heads * cfg.head_dim)
 
 
@@ -205,7 +215,7 @@ def paged_cache_memory_bytes(cfg, num_blocks: int, block_size: int,
     """The pages' footprint (what an arena of ``num_blocks`` costs; the
     state pools are sized by rows, not blocks: ``state_pool_memory_bytes``)."""
     itemsize = jnp.dtype(dtype).itemsize
-    return (2 * _paged_layers(cfg) * num_blocks * block_size
+    return (2 * paged_pools(cfg) * num_blocks * block_size
             * cfg.num_kv_heads * cfg.head_dim * itemsize)
 
 

@@ -13,7 +13,10 @@ Phi-4-mini-flash-reasoning (``phi-4-mini-flash-reasoning``,
 ``tiny-phi4flash``: a self-decoder of Mamba-1 and window layers, one full
 layer, and a cross-decoder of gated memory units and layers that read the
 full layer's pages, all with differential attention; served the same way,
-and only so)."""
+and only so) and Ouro (``ouro-2.6b``, ``tiny-ouro``: a looped stack, the
+same layers run ``loop_passes`` times with a norm behind each half of a
+layer, the final norm closing every pass and an exit gate reading it; served
+the same way, one pool of pages a (pass, layer))."""
 
 from __future__ import annotations
 
@@ -132,6 +135,13 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
                       tie_embeddings=True, norm_eps=1e-5, diff_attn=True,
                       mamba_state_size=16, mamba_conv_taps=4,
                       mamba_expand=2),
+    # ByteDance/Ouro-2.6B (LoopLM, arXiv:2510.25741; model_type "ouro"): a
+    # Llama block with a second RMSNorm BEHIND each half (sandwich), the
+    # whole stack run total_ut_steps times with the final norm closing every
+    # pass, an exit gate a pass; no biases but the gate's
+    "ouro": dict(norm="rmsnorm", norm_position="sandwich", position="rope",
+                 activation="swiglu", tie_embeddings=False, norm_eps=1e-6,
+                 rope_theta=1e6, loop_passes=4, loop_exit_threshold=1.0),
 }
 
 # size presets: hidden, layers, heads, kv_heads, vocab, max_seq
@@ -268,6 +278,17 @@ _SIZES: Dict[str, Dict[str, Any]] = {
         family="phi4flash", hidden_size=64, num_layers=8, num_heads=8,
         num_kv_heads=4, ffn_hidden_size=96, attention_window=8,
         vocab_size=256, max_seq_len=128),
+    # ByteDance/Ouro-2.6B config.json (2.67 B, run four times over)
+    "ouro-2.6b": dict(family="ouro", hidden_size=2048, num_layers=48,
+                      num_heads=16, num_kv_heads=16, head_size=128,
+                      ffn_hidden_size=5632, vocab_size=49152,
+                      max_seq_len=65536),
+    # three passes: neither one nor two, so a stack run once, or a pass that
+    # reads the pool before or behind its own, cannot pass the tests
+    "tiny-ouro": dict(family="ouro", hidden_size=64, num_layers=4,
+                      num_heads=4, num_kv_heads=4, head_size=16,
+                      ffn_hidden_size=96, vocab_size=256, max_seq_len=128,
+                      loop_passes=3),
     # GShard/Switch-style 8-expert GPT (BASELINE tracked config #4)
     "moe-tiny": dict(family="gpt2", hidden_size=64, num_layers=2, num_heads=4,
                      vocab_size=256, max_seq_len=128, moe_num_experts=8),

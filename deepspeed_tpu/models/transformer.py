@@ -61,8 +61,10 @@ class TransformerConfig:
     ffn_hidden_size: Optional[int] = None   # None => 4*hidden (gelu) / llama rule (swiglu)
     max_seq_len: int = 1024
     norm: str = "layernorm"                 # layernorm | rmsnorm
-    norm_position: str = "pre"              # pre | post (post: BERT-family
-    #   encoders — LN applied AFTER each residual add, no final norm)
+    norm_position: str = "pre"              # pre | post | sandwich (post:
+    #   BERT-family encoders — LN applied AFTER each residual add, no final
+    #   norm; sandwich: pre, and a norm of its own BEHIND each half, on what
+    #   the mixer and the FFN add to the residual: x + R2(mixer(R1(x))))
     position: str = "learned"               # learned | rope | alibi | none
     #   (none: no positional term anywhere; causality alone orders tokens)
     embed_norm: bool = False                # LayerNorm after embedding (BLOOM)
@@ -200,6 +202,16 @@ class TransformerConfig:
     kda_num_heads: int = 0
     kda_head_dim: int = 0
     kda_gate_rank: int = 0
+    # a looped stack (arXiv:2510.25741): the SAME layers run ``loop_passes``
+    # times, the final norm closing every pass and feeding the next; a query
+    # of pass t sees the keys of pass t alone, so the serving arena keeps one
+    # pool a (pass, layer) (``Step.pool_index``). An exit gate reads every
+    # pass's output, ``lam_t = sigmoid(h_t w + b)``, and the head reads the
+    # first pass whose cumulated exit probability reaches
+    # ``loop_exit_threshold`` (the last at 1 and over: nothing is selected).
+    # 1 pass is every other model
+    loop_passes: int = 1
+    loop_exit_threshold: float = 1.0
     a8_decode: bool = False           # W8A8: decode-shaped int8 weight sites
     #   quantize the activation row too and ride the MXU's s8xs8 path
     #   (set by InferenceEngine from InferenceConfig.quantize_activations;
@@ -244,6 +256,18 @@ class TransformerConfig:
                     "a window's ring is addressed by a row's state slot"
         if self.moe_experts_held:
             assert 0 < self.moe_experts_held <= self.moe_num_experts
+        assert self.norm_position in ("pre", "post", "sandwich"), \
+            self.norm_position
+        if self.norm_position == "sandwich":
+            assert not self.parallel_residual, \
+                "a norm behind each half has no parallel-residual form"
+        assert self.loop_passes >= 1
+        if self.loop_passes > 1:
+            # a pass ends in the final norm; pools are a (pass, layer) of
+            # softmax layers, and a recurrent state a pass has no pool yet
+            assert self.final_norm and set(layer_kinds(self)) == {"attn"} \
+                and not self.moe_num_experts, \
+                "a looped stack is dense softmax layers under a final norm"
 
     @property
     def head_dim(self) -> int:
@@ -267,6 +291,18 @@ class TransformerConfig:
     def experts_held(self) -> int:
         """Experts in a layer's stack (the router is moe_num_experts wide)."""
         return self.moe_experts_held or self.moe_num_experts
+
+
+def require_one_pass(cfg: TransformerConfig, what: str) -> None:
+    """THE refusal of whatever runs the stack of layers itself, a layer or a
+    stage at a time, and so once: a looped stack (``loop_passes`` > 1) run
+    once is another model."""
+    if cfg.loop_passes > 1:
+        raise NotImplementedError(
+            f"{what} runs the layers once over, and this is a looped stack "
+            f"(loop_passes={cfg.loop_passes}: the same layers run "
+            "that many times, the final norm closing every pass); only "
+            "models/transformer.forward makes the passes")
 
 
 def eval_config(cfg: TransformerConfig) -> TransformerConfig:
@@ -318,6 +354,11 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict[str, Any]:
         params["lm_head"] = normal(ks[3], (H, V))
         if cfg.lm_head_bias:
             params["lm_head_b"] = jnp.zeros((V,), cfg.dtype)
+    if cfg.loop_passes > 1:
+        # the exit gate, one output; a bias that is not zero, or leaving it
+        # out would go untested
+        params["exit_gate"] = {"w": normal(ks[5], (H,)),
+                               "b": normal(ks[6], (), 0.5)}
     return params
 
 
@@ -508,8 +549,12 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                 "w_down": normal(10, (F, H), resid_std),
                 "b_down": jnp.zeros((H,), cfg.dtype),
             }
-        if cfg.norm == "layernorm":
+        if cfg.norm_position == "sandwich":
             for ln in ("ln1", "ln2"):
+                if ln in layer:
+                    layer[ln + "_post"] = {"scale": jnp.ones((H,), cfg.dtype)}
+        if cfg.norm == "layernorm":
+            for ln in ("ln1", "ln2", "ln1_post", "ln2_post"):
                 if ln in layer:
                     layer[ln]["bias"] = jnp.zeros((H,), cfg.dtype)
         return layer
@@ -543,7 +588,9 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     ln = {"scale": (LAYERS, EMBED)}
     if cfg.norm == "layernorm":
         ln = {"scale": (LAYERS, EMBED), "bias": (LAYERS, EMBED)}
-    layer_axes = ffn_axes = {"ln2": dict(ln), "mlp": mlp}
+    sandwich = cfg.norm_position == "sandwich"
+    layer_axes = ffn_axes = {"ln2": dict(ln), "mlp": mlp,
+                             **({"ln2_post": dict(ln)} if sandwich else {})}
     if cfg.moe_num_experts > 0:
         layer_axes["router"] = (LAYERS, EMBED, None)
         if cfg.moe_router_bias:
@@ -569,7 +616,9 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         by_kind[kind] = {**(ffn_axes if has_ffn else {}),
                          **({} if mixer is None
                             else {"ln1": dict(ln), MIXERS[mixer].name:
-                                  MIXERS[mixer].axes(cfg)})}
+                                  MIXERS[mixer].axes(cfg)}),
+                         **({"ln1_post": dict(ln)}
+                            if sandwich and mixer is not None else {})}
     axes: Dict[str, Any] = {
         "embed": {"tokens": (VOCAB, EMBED)},
         "layers": by_kind if len(kinds) > 1 else by_kind[kinds[0]],
@@ -588,6 +637,8 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
         axes["lm_head"] = (EMBED, VOCAB)
         if cfg.lm_head_bias:
             axes["lm_head_b"] = (VOCAB,)
+    if cfg.loop_passes > 1:
+        axes["exit_gate"] = {"w": (EMBED,), "b": ()}
     return axes
 
 
@@ -1131,6 +1182,9 @@ class Step:
     block_table: Optional[jax.Array] = None     # (B, MAX_BLOCKS)
     write_mask: Optional[jax.Array] = None
     layer_index: Optional[jax.Array] = None
+    pool_index: Optional[jax.Array] = None      # a looped stack: the pool of
+    #   "k" and "v" this layer writes and reads in this pass, pass x layers
+    #   + ``layer_index``; None: the layer's own index is its pool's
     state_slots: Optional[jax.Array] = None     # (B,)
     paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
     static_prefill: bool = False    # the dense cache is written from 0 on
@@ -1432,10 +1486,12 @@ def _write_pages(arena: jax.Array, layer: jax.Array, rows: jax.Array,
     return arena.at[layer, blk].set(pages.reshape(B, P, block, W))
 
 
-def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any]
+def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+               cached: bool = False
                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The "attn" mixer's projections of ``h`` (B, S, H) as heads: q
-    (B, S, N, D), k and v (B, S, K, D), biased and normed, not yet roped."""
+    (B, S, N, D), k and v (B, S, K, D), biased and normed, not yet roped.
+    ``cached``: the step keeps a cache (an inference program)."""
     B, S, _ = h.shape
     N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = _qeinsum("bsh,hd->bsd", h, p["wq"], cfg.dtype, a8=cfg.a8_decode)
@@ -1445,7 +1501,10 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any]
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    if S == 1:
+    # rope with no norm over the rows before it: nothing stands between k's
+    # product and the heads its rope wants, in a chunk as in a step
+    bare_rope = cached and cfg.position == "rope" and not cfg.qk_norm
+    if S == 1 or bare_rope:
         # a decode step: q's product (and bias) is whole as ROWS before
         # anything splits it into heads, as k's and v's are (they go back to
         # rows for the page write). Left to fold the reshape into the
@@ -1454,6 +1513,12 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any]
         # every step, two passes over it before the product's one (PERF.md,
         # PR 53). The heads are then a relayout of B rows, not of H x N*D
         q = lax.optimization_barrier(q)
+    if bare_rope:
+        # k as well (a norm over the rows parts the two, and a model with no
+        # rope writes k to the pages as the rows it is): the compiler
+        # re-laid the WHOLE stack of wk at a step's entry, and of wq too at
+        # a chunk's, 0.4 GB copied each at 48 layers of 2,048 (PERF.md, PR 60)
+        k = lax.optimization_barrier(k)
     if cfg.qk_norm:
         # over all heads at once (the published OlmoeAttention: q_norm and
         # k_norm are hidden-wide), before the heads are split and roped
@@ -1549,7 +1614,8 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     # of vLLM's PagedAttention block tables).
     from ..ops.paged_decode_attention import paged_attention
 
-    cache, block_table, layer = step.cache, step.block_table, step.layer_index
+    cache, block_table = step.cache, step.block_table
+    layer = step.layer_index if step.pool_index is None else step.pool_index
     pos = step.positions            # (B, S): ``forward`` takes no other
     kn, vn, read = "k", "v", {}
     if form is not None:
@@ -1797,7 +1863,7 @@ def _softmax(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                 q = lax.optimization_barrier(q)     # see ``_qkv_heads``
             heads = (q.reshape(B, S, cfg.num_heads, cfg.head_dim), None, None)
         else:
-            heads = _qkv_heads(cfg, h, p)
+            heads = _qkv_heads(cfg, h, p, cached=True)
         if cfg.diff_attn:
             heads = _diff_pairs(cfg, *heads)
         attn, new_cache = _attend_paged(cfg, *heads, step, form)
@@ -1807,7 +1873,8 @@ def _softmax(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
         attend = (_attend_train if step.cache is None else
                   _attend_dense_cache if step.block_table is None else
                   _attend_paged)
-        attn, new_cache = attend(cfg, *_qkv_heads(cfg, h, p), step)
+        attn, new_cache = attend(
+            cfg, *_qkv_heads(cfg, h, p, cached=step.cache is not None), step)
     attn = attn.reshape(B, S, cfg.num_heads * cfg.head_dim)
     if "wg" in p:
         # the output gate: elementwise and full-rank, from the layer's input
@@ -2175,6 +2242,10 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         cfg, h, layer[MIXERS[mixer].name], step)
     memory = [] if step.memory is None else [handed[0] if handed
                                              else step.memory]
+    if cfg.norm_position == "sandwich":
+        attn_out = _norm(attn_out, layer["ln1_post"]["scale"],
+                         layer["ln1_post"].get("bias"), cfg.norm,
+                         cfg.norm_eps)
     if step.cache is None:
         from ..parallel.sequence import constrain, hidden_spec, sequence_parallel_enabled
 
@@ -2295,6 +2366,9 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
             inner = jax.nn.gelu(inner,
                                 approximate=cfg.activation != "gelu-exact")
         mlp_out = _qeinsum("bsf,fh->bsh", inner, layer["mlp"]["w_down"], cfg.dtype, a8=cfg.a8_decode) + layer["mlp"]["b_down"]
+    if cfg.norm_position == "sandwich":
+        mlp_out = _norm(mlp_out, layer["ln2_post"]["scale"],
+                        layer["ln2_post"].get("bias"), cfg.norm, cfg.norm_eps)
     if cache is None:
         mlp_out = _dropout(mlp_out, cfg, salt=37)
     if cfg.parallel_residual:
@@ -2427,6 +2501,15 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         ltd_flags = jnp.array([1.0 if i in ltd_layers else 0.0
                                for i in range(L)], jnp.float32)
 
+    looped = cfg.loop_passes > 1
+    if looped and (use_pld or use_ltd or use_win
+                   or (cache is not None and block_table is None)):
+        raise NotImplementedError(
+            "a looped stack (loop_passes > 1) runs without a cache or over "
+            "the serving layer's paged cache, which keeps one pool a (pass, "
+            "layer): the dense cache (inference/engine.py) keeps one a "
+            "layer, and progressive layer drop, random-LTD and per-layer "
+            "windows index a stack that runs once")
     if cfg.layer_runs:
         if block_table is None or use_pld or use_ltd or use_win \
                 or moe_counts:
@@ -2563,9 +2646,20 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
               (jnp.arange(L // P, dtype=jnp.float32)
                if with_idx or use_ltd else None),
               ltd_flags if use_ltd else None)
-        (x, aux_total), new_cache = lax.scan(
-            block_fn, (x, jnp.float32(0.0)), xs,
-            unroll=cfg.scan_unroll if cache is None else 1)
+
+        def scan_layers(x):
+            return lax.scan(block_fn, (x, jnp.float32(0.0)), xs,
+                            unroll=cfg.scan_unroll if cache is None else 1)
+
+        if looped:
+            # no cache (the dense one was refused above): a pass keeps
+            # nothing, and the sequence attends to itself in each
+            x, new_cache = _run_passes(
+                cfg, params, x, None,
+                lambda h, _, t: (scan_layers(h)[0][0], None))
+            aux_total = jnp.float32(0.0)    # a looped stack has no experts
+        else:
+            (x, aux_total), new_cache = scan_layers(x)
     else:
         # PAGED: the layer scan's CARRY is the arena itself, and the body
         # hands it down whole with the layer index. _layer_forward scatters
@@ -2583,30 +2677,96 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         # slot) where it lies. window/PLD/LTD are training- or
         # dense-cache-only features (a sliding-window model was refused
         # above).
-        def paged_block(carry, layers_and_idx):
-            h, aux_acc, arena, *counts_acc = carry
-            layers, pidx = layers_and_idx
+        def scan_paged(x, arena, pools_above=None):
+            """The stack once over the arena; ``pools_above``: a looped
+            stack's pools of the passes before this one."""
+            def paged_block(carry, layers_and_idx):
+                h, aux_acc, arena, *counts_acc = carry
+                layers, pidx = layers_and_idx
 
-            def one_layer(h, kind, layer, kidx, aux_sum, arena, *counts_sum):
-                h, arena, aux, *counts = _layer_forward(
-                    cfg, h, layer, dataclasses.replace(
-                        step, cache=arena, layer_index=kidx), kind=kind)
-                return (h, aux_sum + aux, arena,
-                        *(a + c for a, c in zip(counts_sum, counts)))
+                def one_layer(h, kind, layer, kidx, aux_sum, arena,
+                              *counts_sum):
+                    h, arena, aux, *counts = _layer_forward(
+                        cfg, h, layer, dataclasses.replace(
+                            step, cache=arena, layer_index=kidx,
+                            pool_index=(None if pools_above is None
+                                        else pools_above + kidx)),
+                        kind=kind)
+                    return (h, aux_sum + aux, arena,
+                            *(a + c for a, c in zip(counts_sum, counts)))
 
-            return run_period(layers, pidx, one_layer, h, aux_acc, arena,
-                              *counts_acc), None
+                return run_period(layers, pidx, one_layer, h, aux_acc, arena,
+                                  *counts_acc), None
 
-        (x, aux_total, new_cache, *moe_totals), _ = lax.scan(
-            paged_block,
-            (x, jnp.float32(0.0), dict(cache),
-             *([jnp.zeros((3,), jnp.int32)] if moe_counts else [])),
-            (layers, jnp.arange(L // P, dtype=jnp.int32)))
+            return lax.scan(
+                paged_block,
+                (x, jnp.float32(0.0), arena,
+                 *([jnp.zeros((3,), jnp.int32)] if moe_counts else [])),
+                (layers, jnp.arange(L // P, dtype=jnp.int32)))[0]
 
-    logits = head_logits(params, x, cfg)
+        if looped:
+            # passes x layers, two nested scans with the ARENA in the carry
+            # of both: pass t writes and reads the pools [t L, (t + 1) L) of
+            # its L layers that keep pages, and the weights are the inner
+            # scan's operand, whatever the pass
+            def stack(h, arena, t):
+                h, _, arena = scan_paged(h, arena, t * len(paged_layers(cfg)))
+                return h, arena
+
+            x, new_cache = _run_passes(cfg, params, x, dict(cache), stack)
+            aux_total = jnp.float32(0.0)    # a looped stack has no experts
+        else:
+            x, aux_total, new_cache, *moe_totals = scan_paged(x, dict(cache))
+
+    logits = head_logits(params, x, cfg, normed=looped)
     if moe_counts:
         return logits, new_cache, aux_total, moe_totals[0]
     return logits, new_cache, aux_total
+
+
+def _run_passes(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array,
+                arena: Optional[Dict[str, jax.Array]], stack: Callable
+                ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """A looped stack (``cfg.loop_passes`` > 1): ``stack(h, arena, t) ->
+    (h, arena)``, the layers once over, run ``loop_passes`` times as ONE
+    ``lax.scan`` whose carry is the activations and the arena; the final norm
+    closes every pass, and what it gives is the next pass's input. Returns
+    ``(the head's input, arena)``: the last pass's output at an
+    exit threshold of 1 and over, where nothing is selected and the gate is
+    not computed; under 1, of each token the output of the first pass t
+    whose cumulated exit probability ``sum_{j<=t} p_j`` reaches the
+    threshold (the last where none does), ``p_t = lam_t prod_{j<t}(1 -
+    lam_j)``, ``lam_t = sigmoid(h_t w + b)`` in float32, and the last pass
+    takes what is left. Every pass runs whatever the gate says: a token that
+    has left still owes the later passes its keys."""
+    T, threshold = cfg.loop_passes, cfg.loop_exit_threshold
+    gated = threshold < 1.0
+    f32 = jnp.float32
+
+    def one_pass(carry, t):
+        h, arena, *gate = carry
+        h, arena = stack(h, arena, t)
+        h = _final_norm(params, h, cfg)
+        if gated:
+            chosen, cum, left, done = gate
+            g = params["exit_gate"]
+            lam = jax.nn.sigmoid(
+                jnp.einsum("bsh,h->bs", h.astype(f32), g["w"].astype(f32),
+                           precision=lax.Precision.HIGHEST)
+                + g["b"].astype(f32))
+            last = t == T - 1
+            cum = cum + jnp.where(last, left, lam * left)
+            take = ~done & ((cum >= threshold) | last)
+            gate = [jnp.where(take[..., None], h, chosen), cum,
+                    left * (1.0 - lam), done | take]
+        return (h, arena, *gate), None
+
+    B, S, _ = x.shape
+    gate = ([jnp.zeros_like(x), jnp.zeros((B, S), f32), jnp.ones((B, S), f32),
+             jnp.zeros((B, S), bool)] if gated else [])
+    (h, arena, *gate), _ = lax.scan(
+        one_pass, (x, arena, *gate), jnp.arange(T, dtype=jnp.int32))
+    return (gate[0] if gated else h), arena
 
 
 def _run_layers(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array,
@@ -2698,14 +2858,20 @@ def _run_layers(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array,
                     *operands), arena
 
 
-def head_logits(params: Dict[str, Any], x: jax.Array,
+def _final_norm(params: Dict[str, Any], x: jax.Array,
                 cfg: TransformerConfig) -> jax.Array:
+    return _norm(x, params["final_norm"]["scale"],
+                 params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
+
+
+def head_logits(params: Dict[str, Any], x: jax.Array,
+                cfg: TransformerConfig, normed: bool = False) -> jax.Array:
     """Final norm + output projection — THE one head implementation (the
     pipeline and param-offload executors call it too; a config knob added
-    here must not be re-implemented there)."""
-    if cfg.final_norm:
-        x = _norm(x, params["final_norm"]["scale"],
-                  params["final_norm"].get("bias"), cfg.norm, cfg.norm_eps)
+    here must not be re-implemented there). ``normed``: ``x`` has the final
+    norm behind it (a looped stack's every pass ends in it)."""
+    if cfg.final_norm and not normed:
+        x = _final_norm(params, x, cfg)
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsh,vh->bsv", x, params["embed"]["tokens"])
     else:

@@ -188,8 +188,10 @@ def _stage_helpers(cfg):
     the 1F1B grad executor — one definition so train grads and eval losses
     can never structurally diverge (embed_norm incident of round 2)."""
     from ..models.transformer import (Step, _layer_forward, _norm,
-                                      cross_entropy_loss,
+                                      cross_entropy_loss, require_one_pass,
                                       resolve_remat_policy)
+
+    require_one_pass(cfg, "pipeline parallelism")
 
     aux_coef = (cfg.moe_aux_loss_coef / max(cfg.num_layers, 1)
                 if cfg.moe_num_experts > 0 else 0.0)

@@ -258,9 +258,11 @@ def measured_model_profile(model, batch_size: int, seq_len: int,
     import numpy as np
 
     from ..models.transformer import (Step, _layer_forward, _norm,
-                                      eval_config, head_logits, window_table)
+                                      eval_config, head_logits,
+                                      require_one_pass, window_table)
 
     cfg = eval_config(model.config)
+    require_one_pass(cfg, "the per-layer profile")
     # per-layer sliding windows (GPT-Neo attention_layers): each timed layer
     # must see ITS window, exactly as forward()'s scan passes it — else
     # 'local' layers would be profiled as all-global attention

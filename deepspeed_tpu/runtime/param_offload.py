@@ -481,9 +481,11 @@ class ParamOffloadExecutor:
     def _build_step_fns(self, model) -> None:
         from ..models.transformer import (Step, _dropout, _layer_forward,
                                           _norm, _qeinsum, cross_entropy_loss,
-                                          eval_config, resolve_remat_policy)
+                                          eval_config, require_one_pass,
+                                          resolve_remat_policy)
 
         cfg = self.cfg
+        require_one_pass(cfg, "parameter offload (a block of layers a step)")
 
         def make_fns(c):
             def embed_fwd(resident, ids):

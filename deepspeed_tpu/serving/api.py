@@ -240,6 +240,14 @@ class ServingEngine:
         self._chunk_says_last = tail_runs(cfg) > 0
         # the span count of the (row, layer) states a step advanced
         self._recurrent_rows = mixer and MIXERS[mixer].rows_count
+        # a looped stack (``loop_passes`` > 1): what the spans of its two
+        # programs say of a run, the passes it made over the layers and the
+        # pools of pages they wrote and read, one a (pass, layer); a block
+        # of the allocator and of the prefix cache is its tokens in every
+        # pool. {} for a stack that runs once: its spans stay as they were
+        self._loop_counts = ({"loop_passes": cfg.loop_passes,
+                              "pools": paged_kv.paged_pools(cfg)}
+                             if cfg.loop_passes > 1 else {})
         self.state_slots = (self.config.max_seqs + 1
                             if self._recurrent_layers else 0)
         # the prefix cache shares PAGES between sequences; a recurrent
@@ -1264,6 +1272,12 @@ class ServingEngine:
                         self.engine.params, self._arena, *args)
         t_call = self.clock()
         call_s = t_call - t0
+        if self._loop_counts and obs.enabled:
+            obs.registry.counter(
+                "serving/loop_passes_run",
+                help="passes over the layers that the enqueued decode and "
+                     "prefill-chunk programs of a looped stack made").inc(
+                self._loop_counts["loop_passes"])
         compiles = program._cache_size()
         if compiles != self._compiles.get(name):
             # the call traced and compiled (or read the compile cache): a
@@ -1400,7 +1414,8 @@ class ServingEngine:
         whether it was enqueued AHEAD (``_chunk_ahead``)."""
         return obs.span("serving/prefill_chunk", cpu=True, rid=req.rid,
                         chunk_start=int(start), ahead=int(ahead),
-                        sampled_rows=self._sampled_rows([req]))
+                        sampled_rows=self._sampled_rows([req]),
+                        **self._loop_counts)
 
     def _enqueue_chunk(self, obs, req: Request) -> Optional["_Enqueued"]:
         """``serving/prefill_chunk/prepare`` and ``.../dispatch`` of the
@@ -1845,7 +1860,8 @@ class ServingEngine:
                 span.annotate(rows=len(ready), ahead=int(bool(ahead)),
                               behind_chunk=int(bool(ready)
                                                and chunk is not None),
-                              sampled_rows=self._sampled_rows(ready))
+                              sampled_rows=self._sampled_rows(ready),
+                              **self._loop_counts)
                 if ready and chunk is None and self._chunk_first:
                     span.annotate(chunk_first_by=self._chunk_first)
             if not ready:

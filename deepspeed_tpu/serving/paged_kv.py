@@ -58,11 +58,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..inference.kv_cache import (assert_block_divisible, blocks_for_tokens,
-                                  init_paged_cache, paged_cache_memory_bytes)
+                                  init_paged_cache, paged_cache_memory_bytes,
+                                  paged_pools)
 
 __all__ = ["BlockAllocator", "BlockAllocatorError", "PrefixCache",
            "blocks_for_tokens", "assert_block_divisible", "init_paged_cache",
-           "paged_cache_memory_bytes", "build_prefill_program",
+           "paged_cache_memory_bytes", "paged_pools",
+           "build_prefill_program",
            "build_decode_program", "build_verify_program",
            "build_score_program", "build_cow_program",
            "build_kv_export_program", "build_kv_import_program",

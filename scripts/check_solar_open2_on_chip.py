@@ -130,8 +130,10 @@ def main(argv=None, family=FAMILY):
 
     def one_token(params, cache, table, lengths, tokens, targets):
         live = (lengths > 0)[:, None]
-        slots = jnp.where(lengths > 0, jnp.arange(R, dtype=jnp.int32),
-                          cache["state"].shape[1] - 1)
+        # a model with no recurrent layer keeps no state pools
+        slots = (jnp.where(lengths > 0, jnp.arange(R, dtype=jnp.int32),
+                           cache["state"].shape[1] - 1)
+                 if "state" in cache else None)
         logits, cache, _ = forward(
             params, tokens[:, None], cfg, cache=cache,
             positions=lengths[:, None], block_table=table,
@@ -156,7 +158,7 @@ def main(argv=None, family=FAMILY):
                     params, serving._arena,
                     paged_kv.pack_chunk(
                         table[:1], chunk, start, n, 0 * one, zero, one, zero,
-                        state_slot=zero,
+                        state_slot=zero if serving.state_slots else None,
                         # a stack with ``tail_runs``: the chunk says whether
                         # it is its prompt's last
                         **({"last": [start + n == n_prompt]}

@@ -332,9 +332,9 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
             params, arena, arg((1, maxb), I32), arg((1, chunk), I32),
             arg((1, chunk), I32), arg((), I32), arg((), I32))
     return paged_kv.build_prefill_program(
-        cfg, CHUNK, **program_options).lower(
+        cfg, chunk, **program_options).lower(
             params, arena,
-            arg(paged_kv.chunk_shape(maxb, CHUNK, recurrent,
+            arg(paged_kv.chunk_shape(maxb, chunk, recurrent,
                                      T.tail_runs(cfg) > 0), I32), key)
 
 
@@ -661,6 +661,52 @@ def test_phi4flash_serving_program_scans_its_runs_and_copies_no_pool(
         copied = [ln for ln in text.splitlines()
                   if re.search(rf"= {re.escape(shape)}\S* copy\(", ln)]
         assert not copied, copied[:2]
+
+
+# ouro-2.6b as its cell serves it, whole: 4 passes x 48 layers, 16 rows of
+# 320 tokens and the scratch block; the arena 4.04 GB a side, a pool 21 MB
+OURO_ROWS, OURO_BLOCKS, OURO_MAXB, OURO_CHUNK = 16, 321, 20, 128
+OURO_ARENA = f"bf16[192,{OURO_BLOCKS},{BLOCK},2048]"
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_a_looped_stack_carries_its_arena_through_both_loops(
+        v5e, monkeypatch, kind):
+    """Ouro-2.6B's serving programs for the chip at the cell's shapes: the
+    passes and the layers are two nested loops, so a program holds ONE walk
+    and one body of a layer, not 192; the arena rides the carry of both and
+    is scattered into where it lies (a copy of it, 4 GB a side, would not
+    fit beside the weights: that is how it would show on the chip); the
+    weights are the inner loop's operand, and no stack of 48 is re-laid at
+    the program's entry (the compiler did that to wk, and to wq in the
+    chunk, until k's product was parted from its rope: 0.4 GB a copy)."""
+    compiled = _serving_program(
+        kind, v5e, monkeypatch, preset="ouro-2.6b",
+        overrides={"num_layers": 48}, rows=OURO_ROWS,
+        num_blocks=OURO_BLOCKS, maxb=OURO_MAXB, chunk=OURO_CHUNK).compile()
+    text = compiled.as_text()
+    roots = _fusion_roots(text)
+    kernel = ("paged_decode_attention" if kind == "decode"
+              else "paged_prefill_attention")
+    calls, offenders, loops = 0, [], 0
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m is None:
+            continue
+        _, result, op = m.groups()
+        loops += op == "while" and OURO_ARENA in result
+        if op == "custom-call" and kernel in line:
+            calls += 1
+            operands = line.split("operand_layout_constraints=", 1)[1]
+            assert operands.count(OURO_ARENA) == 2, line[:300]
+        if OURO_ARENA in result and not _writes_in_place(line, op, roots):
+            offenders.append(line.strip()[:200])
+        if op == "copy" and re.match(r"bf16\[48,", result):
+            offenders.append(line.strip()[:200])
+    assert not offenders, "\n".join(offenders)
+    assert calls == 1 and loops == 2, (calls, loops)
+    # a layer's FFN weights are 69 MB, a pool 21 MB, a stack of wk 0.4 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
 
 
 def _projection_weights(text, widths, layers):

@@ -23,9 +23,9 @@ MIXERS = {"biased": ("tiny-opt", {}),
           "qk-norm-rope-gqa": ("tiny-olmoe", {"num_kv_heads": 2})}
 
 
-def _reshape_first(cfg, h, p):
+def _reshape_first(cfg, h, p, cached=False):
     """``_qkv_heads`` as it stood before PR 53: nothing between q's product
-    and its heads."""
+    and its heads (``cached``, PR 60: taken and not read)."""
     B, S, _ = h.shape
     N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q, k, v = (T._qeinsum("bsh,hd->bsd", h, p[w], cfg.dtype,
@@ -82,7 +82,7 @@ def test_a_decode_step_is_the_reshape_first_step_bit_for_bit(
     logits, arena = step()
     seen = []
     monkeypatch.setattr(T, "_qkv_heads",
-                        lambda *a: seen.append(1) or _reshape_first(*a))
+                        lambda *a, **kw: seen.append(1) or _reshape_first(*a, **kw))
     want_logits, want_arena = step()
     assert seen, "the mixer no longer reads its projections by that name"
     assert np.isfinite(np.asarray(logits, np.float32)).all()
