@@ -1175,7 +1175,19 @@ class Step:
     ``layer_index`` saying which layer this is - ``forward`` keeps them out
     of the layer scan's slicing, because the grouped-matmul kernel can read
     a touched expert where it lies in the stack but not out of a slice that
-    XLA would first have to copy (``ops/moe_grouped_matmul.py``)."""
+    XLA would first have to copy (``ops/moe_grouped_matmul.py``).
+
+    **A mixed step** (``serving/paged_kv.build_mixed_program``): the
+    activations are ONE flat run ``(1, R + C, H)``, R decode rows' tokens
+    and then a prompt chunk's C, so that every per-token product of a layer
+    reads its weight once for both. ``divide`` is R, where the run divides;
+    the step's own ``positions`` (R, 1), ``block_table`` (R, MAXB) and
+    ``write_mask`` are the ROWS' as the decode program gives them, and
+    ``chunk`` holds the second part's: a ``Step`` with the ``positions``
+    (1, C), ``block_table`` (1, MAXB), ``write_mask`` (1, C) and
+    ``paged_run`` of the chunk program. Only the mixer's write and read over
+    the pages split there (``_attend_paged``); nothing else on the way down
+    reads either field."""
     mask: Optional[jax.Array] = None        # (B, T) key padding or (B, S, T)
     positions: Optional[jax.Array] = None   # (S,) shared or (B, S) a row
     cache: Optional[Dict[str, jax.Array]] = None
@@ -1200,6 +1212,9 @@ class Step:
     #   "mamba1" layer handed on, for the "gmu" layers of the same step
     shared_layer: Optional[int] = None      # a "cross" layer: the place
     #   among the layers that keep pages of the one whose pool it reads
+    divide: Optional[int] = None    # a mixed step: the flat run's first
+    #   ``divide`` tokens are decode rows, a token a row; the rest a chunk
+    chunk: Optional["Step"] = None  # a mixed step: the chunk's operands
 
 
 def window_table(cfg: TransformerConfig) -> jax.Array:
@@ -1487,11 +1502,12 @@ def _write_pages(arena: jax.Array, layer: jax.Array, rows: jax.Array,
 
 
 def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
-               cached: bool = False
+               cached: bool = False, divided: bool = False
                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The "attn" mixer's projections of ``h`` (B, S, H) as heads: q
     (B, S, N, D), k and v (B, S, K, D), biased and normed, not yet roped.
-    ``cached``: the step keeps a cache (an inference program)."""
+    ``cached``: the step keeps a cache (an inference program); ``divided``:
+    the run divides behind the heads (a mixed step)."""
     B, S, _ = h.shape
     N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = _qeinsum("bsh,hd->bsd", h, p["wq"], cfg.dtype, a8=cfg.a8_decode)
@@ -1504,7 +1520,7 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     # rope with no norm over the rows before it: nothing stands between k's
     # product and the heads its rope wants, in a chunk as in a step
     bare_rope = cached and cfg.position == "rope" and not cfg.qk_norm
-    if S == 1 or bare_rope:
+    if S == 1 or bare_rope or divided:
         # a decode step: q's product (and bias) is whole as ROWS before
         # anything splits it into heads, as k's and v's are (they go back to
         # rows for the page write). Left to fold the reshape into the
@@ -1513,12 +1529,18 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
         # every step, two passes over it before the product's one (PERF.md,
         # PR 53). The heads are then a relayout of B rows, not of H x N*D
         q = lax.optimization_barrier(q)
-    if bare_rope:
+    if bare_rope or divided:
         # k as well (a norm over the rows parts the two, and a model with no
         # rope writes k to the pages as the rows it is): the compiler
         # re-laid the WHOLE stack of wk at a step's entry, and of wq too at
         # a chunk's, 0.4 GB copied each at 48 layers of 2,048 (PERF.md, PR 60)
         k = lax.optimization_barrier(k)
+    if divided:
+        # and v, in a mixed step: the run's two parts are sliced out of the
+        # heads, and with the slices folded into the three products the
+        # compiler sliced each weight out of its stack and transposed it, a
+        # layer (8 MB each at a width of 2,048; PERF.md, PR 61)
+        v = lax.optimization_barrier(v)
     if cfg.qk_norm:
         # over all heads at once (the published OlmoeAttention: q_norm and
         # k_norm are hidden-wide), before the heads are split and roped
@@ -1614,6 +1636,8 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     # of vLLM's PagedAttention block tables).
     from ..ops.paged_decode_attention import paged_attention
 
+    if step.divide is not None:
+        return _attend_mixed(cfg, q, k, v, step)
     cache, block_table = step.cache, step.block_table
     layer = step.layer_index if step.pool_index is None else step.pool_index
     pos = step.positions            # (B, S): ``forward`` takes no other
@@ -1668,6 +1692,31 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     attn = paged_attention(q, ck, cv, layer, block_table, pos, alibi=alibi,
                            **read)
     return attn, {**cache, kn: ck, vn: cv}
+
+
+def _attend_mixed(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
+                  v: jax.Array, step: Step
+                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """``_attend_paged`` of a mixed step: ``q``, ``k`` and ``v`` are the
+    heads of the flat run (1, R + C, ...). Its last C tokens go down as the
+    chunk program's (``(1, C)``: whole pages written, the prefill read) and
+    its first R = ``step.divide`` as the decode program's (``(R, 1)``: a row
+    scattered a token, the decode read through each row's own table and
+    length), in the order the two programs ran in, each with the ``Step``
+    fields it has there; their outputs are laid back into the flat run. A
+    chunk's request is no decode row, so neither part reads a page the other
+    writes but scratch, which nobody reads."""
+    R = step.divide
+    rows = dataclasses.replace(step, divide=None, chunk=None)
+    part = step.chunk
+    chunk = dataclasses.replace(
+        rows, positions=part.positions, block_table=part.block_table,
+        write_mask=part.write_mask, paged_run=part.paged_run)
+    out_c, cache = _attend_paged(cfg, q[:, R:], k[:, R:], v[:, R:], chunk)
+    out_r, cache = _attend_paged(
+        cfg, *(jnp.swapaxes(a[:, :R], 0, 1) for a in (q, k, v)),
+        dataclasses.replace(rows, cache=cache))
+    return jnp.concatenate([jnp.swapaxes(out_r, 0, 1), out_c], axis=1), cache
 
 
 def _attend_dense_cache(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
@@ -1874,7 +1923,8 @@ def _softmax(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                   _attend_dense_cache if step.block_table is None else
                   _attend_paged)
         attn, new_cache = attend(
-            cfg, *_qkv_heads(cfg, h, p, cached=step.cache is not None), step)
+            cfg, *_qkv_heads(cfg, h, p, cached=step.cache is not None,
+                             divided=step.divide is not None), step)
     attn = attn.reshape(B, S, cfg.num_heads * cfg.head_dim)
     if "wg" in p:
         # the output gate: elementwise and full-rank, from the layer's input
@@ -2395,7 +2445,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             moe_counts: bool = False,
             state_slots: Optional[jax.Array] = None,
             paged_run: Optional[Tuple[jax.Array, jax.Array]] = None,
-            last_token: Optional[jax.Array] = None
+            last_token: Optional[jax.Array] = None,
+            mixed_chunk: Optional[Dict[str, Any]] = None
             ) -> Tuple[jax.Array, Optional[Dict[str, Any]], jax.Array]:
     """Token ids (B,S) → (logits (B,S,V), new_cache, moe_aux_loss). With
     ``cache``, runs in decode mode (cache is a per-layer stacked pytree; see
@@ -2437,8 +2488,30 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     a token (``tail_runs``: the cross-decoder) run for token ``last_token[b]``
     of each row alone, and the logits are (B, 1, V), that token's; where
     every entry is below 0 (a chunk that is not its prompt's last) neither
-    they nor the head run, and the logits are zeros."""
+    they nor the head run, and the logits are zeros.
+
+    **A mixed step** (``mixed_chunk``; paged mode, a one-pass stack of
+    "attn" layers with a dense FFN: ``serving/paged_kv.mixes``):
+    ``input_ids`` is ONE flat run (1, R + C), R decode rows' tokens and then
+    a prompt chunk's C. ``positions`` (R, 1), ``block_table`` (R, MAXB) and
+    ``paged_write_mask`` are the rows', as the decode program hands them;
+    ``mixed_chunk`` holds the chunk's ``positions`` (1, C), ``block_table``
+    (1, MAXB), ``write_mask`` (1, C) and ``paged_run``, as the chunk program
+    hands them. Every per-token product runs once over the R + C tokens; the
+    mixer's page write and read alone split (``Step.divide``). The logits
+    are the ROWS', (R, 1, V): nobody reads a chunk's that is not its
+    prompt's last, and no other chunk rides a mixed step."""
     B, S = input_ids.shape
+    divide = None
+    if mixed_chunk is not None:
+        divide = S - mixed_chunk["positions"].shape[1]
+        if (block_table is None or cfg.layer_runs or cfg.loop_passes > 1
+                or cfg.moe_num_experts > 0 or B != 1 or divide < 1
+                or positions is None or positions.shape != (divide, 1)):
+            raise ValueError(
+                "a mixed step is one flat run (1, R + C) over the paged "
+                "cache of a one-pass stack with dense FFNs, with the rows' "
+                "(R, 1) positions")
     if block_table is not None:
         for operand in ("attention_layers", "attention_scale",
                         "attention_impl"):
@@ -2451,7 +2524,9 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     if positions is None:
         positions = jnp.arange(S) + start_pos
     if cfg.position == "learned":
-        x = x + params["pos"][positions].astype(cfg.dtype)
+        at = positions if divide is None else jnp.concatenate(
+            [positions.reshape(1, divide), mixed_chunk["positions"]], axis=1)
+        x = x + params["pos"][at].astype(cfg.dtype)
     if cfg.type_vocab_size > 0:
         # BERT segment embeddings; absent ids mean segment 0 (HF default)
         tti = (jnp.zeros((B, S), jnp.int32) if token_type_ids is None
@@ -2566,7 +2641,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                 block_table=block_table, write_mask=paged_write_mask,
                 state_slots=state_slots, paged_run=paged_run,
                 static_prefill=static_prefill, key_positions=key_positions,
-                moe_counts=moe_counts, expert_banks=banks)
+                moe_counts=moe_counts, expert_banks=banks, divide=divide,
+                chunk=None if divide is None else Step(**mixed_chunk))
 
     def run_period(layers, pidx, one_layer, h, *acc):
         """``one_layer(h, kind, layer, layer index among its kind, *acc)
@@ -2718,6 +2794,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         else:
             x, aux_total, new_cache, *moe_totals = scan_paged(x, dict(cache))
 
+    if divide is not None:
+        x = jnp.swapaxes(x[:, :divide], 0, 1)   # the rows' tokens alone
     logits = head_logits(params, x, cfg, normed=looped)
     if moe_counts:
         return logits, new_cache, aux_total, moe_totals[0]

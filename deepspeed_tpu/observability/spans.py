@@ -387,7 +387,11 @@ class SpanTracer:
         """A closed span into the record, on the thread that is being
         timed: no dictionary is built (``snapshot`` builds them) and no lock
         taken, unless a JSONL file is open or the bound is reached."""
-        if self._gc_pending:
+        if self._gc_pending and span.name != "runtime/gc":
+            # (a collector's span is recorded by the loop that takes them:
+            # taking from inside it recursed once a pending span, and a
+            # thousand pending ones, which a process that builds many
+            # engines between two spans collects, ended the run)
             self._take_gc_spans()
         if self._fh is None and len(self._spans) < self.max_spans:
             # tpusync: disable=unguarded-shared-write — a list's append is

@@ -8,10 +8,11 @@ streaming session API (``session.py``).
 One *iteration* (``step()``) is: admit queued requests onto free decode
 rows → run at most one prefill chunk → run one decode step over every
 decoding row → host-materialize the sampled tokens (the iteration's one
-sync), stream them to handles, grow/free blocks. Both device programs are
-compiled exactly once per (shape) configuration: occupancy, request mix and
-sampling settings are all *data* (see ``docs/serving.md`` for the jit-cache
-discipline rationale).
+sync), stream them to handles, grow/free blocks. The device programs (the
+chunk program, the decode program and, for a configuration whose layers
+mix, the mixed step: below) are compiled exactly once per (shape)
+configuration: occupancy, request mix and sampling settings are all *data*
+(see ``docs/serving.md`` for the jit-cache discipline rationale).
 
 What a token does on the host is split in two. ``_apply`` is what the NEXT
 program's operands depend on (the request's tokens and length, the finish
@@ -52,6 +53,26 @@ boundary of the driver thread too (``_chunk``), never beside a step
 any chunk and enqueues its step behind it, or lands it first.
 ``_bring_home`` lands it for whoever needs a settled engine. Under ``step()``
 no program is ever in flight across an iteration boundary.
+
+Those are two of the THREE forms an iteration's enqueues take. The third is
+the MIXED STEP, for a configuration whose layers mix (``paged_kv.mixes``: a
+one-pass stack of plain attention layers with dense FFNs; decided by the
+layers' kinds, no setting): where the two forms above would run a chunk that
+is not its prompt's last and then the decode step behind it, the driver
+thread asks the same rules BEFORE anything is enqueued and sends ONE program
+that runs the chunk and the rows through one pass over the layers, so that
+each weight is read once an iteration (``_step_prefill`` keeps the chunk
+back, ``_mix``; ``_step_decode`` sends it with the rows; one ``_Enqueued``
+carries both and one fetch lands both). It stays in flight as a step does
+(``_flight``), and its successor, the rows beside the prompt's next chunk,
+goes AHEAD of its fetch under the union of ``_rows_ahead``'s and
+``_chunk_held_by``'s rules (less ``row_freed``, ``queued`` and ``fork``,
+which never held a chunk ahead: the admission runs in the step's shadow, as
+it does in a chunk's); a prompt's LAST chunk brings a first token, so
+it always keeps the chunk program of its own (behind the mixed step in
+flight: ``_chunk_ahead``). Whenever a name of ``CHUNK_FIRST_BY`` is given,
+and for every configuration that does not mix, the iteration is the two
+programs above.
 
 Telemetry flows through the PR-2 observability substrate: ``serving/*``
 metrics in the MetricsRegistry (ttft_ms, tpot_ms, queue_depth,
@@ -153,7 +174,9 @@ class _Enqueued:
     (behind a decode step not yet fetched: ``since`` is that step's fetch),
     whether the step had ended by then (``late``; None where nothing
     recorded asked), and how often its request had been preempted at the
-    enqueue: one that lost its row since is not the request it ran for."""
+    enqueue: one that lost its row since is not the request it ran for. A
+    MIXED step is a decode step that also keeps its chunk (``chunk``: such
+    a record with no tokens of its own; the step's one fetch lands both)."""
     name: str
     tok: Any
     t0: float
@@ -166,6 +189,7 @@ class _Enqueued:
     ahead: bool = False
     late: Optional[int] = None
     preempted: int = 0
+    chunk: Optional["_Enqueued"] = None
 
 
 def _percentile(samples: List[float], q: float) -> float:
@@ -268,11 +292,21 @@ class ServingEngine:
         self._serve_acct = None
         self.sched.on_preempt = self._trace_preempt
         self._dtype = engine.config.dtype
+        # made committed, as every program hands it back (replicated over
+        # the engine's mesh), and made in place by a jitted function: a fresh
+        # ``jnp.zeros`` is uncommitted, so the program that ran first
+        # compiled a second time at its second call, for the arena its first
+        # had returned (ROADMAP A13 (1): about a second of every cell's
+        # set-up, which pays for the mixed step's load), and a
+        # ``device_put`` of it may copy what fills most of the chip
         with mesh_mod.ambient(engine.mesh):
-            self._arena = paged_kv.init_paged_cache(
-                cfg, self.config.pool_blocks() + 1, self.config.block_size,
-                self._dtype, state_slots=self.state_slots,
-                ring_blocks=self._ring_blocks)
+            self._arena = jax.jit(
+                lambda: paged_kv.init_paged_cache(
+                    cfg, self.config.pool_blocks() + 1,
+                    self.config.block_size, self._dtype,
+                    state_slots=self.state_slots,
+                    ring_blocks=self._ring_blocks),
+                out_shardings=NamedSharding(engine.mesh, PartitionSpec()))()
         # an MoE model's two programs return their routing counts behind
         # the tokens (_program_counts); 0 = a dense model, whose programs and
         # spans know nothing of it. ``total`` counts the ROUTER's outputs a
@@ -284,6 +318,13 @@ class ServingEngine:
         self._prefill = paged_kv.build_prefill_program(
             cfg, self.config.prefill_chunk, moe_counts=moe)
         self._decode = paged_kv.build_decode_program(cfg, moe_counts=moe)
+        # the third program, for a configuration whose layers mix
+        # (``paged_kv.mixes``: decided by their kinds, no setting): a chunk
+        # that is not its prompt's last and the iteration's decode rows in
+        # ONE pass over the layers (``_step_decode``). None: the engine is
+        # the two programs' to the letter
+        self._mixed = (paged_kv.build_mixed_program(
+            cfg, self.config.prefill_chunk) if paged_kv.mixes(cfg) else None)
         # what the last decode program returned, on the device still (its
         # first ``max_seqs`` entries the tokens): the next one's last
         # operand, from which a step enqueued AHEAD takes its tokens. Placed
@@ -400,14 +441,25 @@ class ServingEngine:
         # in that step's shadow (``_step_prefill``), and may leave the
         # prompt's next one here at the iteration's end (``_chunk_ahead``)
         self._chunk: Optional[_Enqueued] = None
+        # the chunk that ``_step_prefill`` prepared and did NOT enqueue,
+        # (request, packed operands, start, tokens): ``_step_decode`` sends
+        # it with its rows as one mixed step. Never set across an
+        # iteration's end
+        self._mix: Optional[tuple] = None
+        # the chunk of the mixed step just fetched, with the step's
+        # interval, until the span that holds the fetch has closed
+        # (``_land``, ``_apply_landed``)
+        self._landed: Optional[tuple] = None
         # the open iteration's span until its account (gauges, the span's
         # counts) is drawn up: behind its first enqueue when deferring, else
         # at its end
         self._unaccounted = None
         # programs that held the engine (``HOLD_SECONDS``), cumulative, and
-        # each program's compiles so far: a call that compiled is no hold
+        # each program's compiles so far (by the jitted function: the mixed
+        # step shares the decode step's span names): a call that compiled is
+        # no hold
         self.holds = 0
-        self._compiles: Dict[str, int] = {}
+        self._compiles: Dict[Any, int] = {}
         self._started_s = clock()
         # fleet seam (serving/fleet): called with the request right after
         # its LAST prefill chunk completed and the first token was emitted,
@@ -989,8 +1041,10 @@ class ServingEngine:
                     self._iterations += 1
                 except BaseException:
                     # a chunk in flight is run again, as one whose fetch
-                    # raised always was
+                    # raised always was (a mixed step's chunk too, whose
+                    # progress nobody applies behind a fetch that raised)
                     self._chunk = None
+                    self._landed = None
                     raise
                 finally:
                     self._deferring = False
@@ -1029,14 +1083,18 @@ class ServingEngine:
         where the rules allow it, else the fetch alone, and today's
         iteration, admission first, is the next. With a CHUNK in flight
         (``_chunk_ahead`` left it there) the iteration is today's and that
-        chunk is its chunk (``_step_prefill``). ``step()`` brings what it
-        finds in flight home and goes on."""
+        chunk is its chunk (``_step_prefill``). A MIXED step in flight is a
+        chunk in flight in this: the admission runs first, in its shadow,
+        as it does in a chunk's, and then its successor goes ahead of its
+        fetch, or it is landed and the iteration is a settled engine's.
+        ``step()`` brings what it finds in flight home and goes on."""
         flight = self._flight
         self._chunk_first = None
+        self._mix = None
         if not self._deferring:
             if flight is not None or self._chunk is not None:
                 self._bring_home("step_mode")
-        elif flight is not None:
+        elif flight is not None and flight.chunk is None:
             rows, held_by = self._rows_ahead(flight)
             if rows:
                 return self._step_decode(rows)
@@ -1055,6 +1113,12 @@ class ServingEngine:
                 self._trace_admitted(obs, admitted)
             span.annotate(admitted=len(admitted), expired=expired)
         progress = bool(expired or admitted)
+        if self._flight is not None:
+            rows, held_by = self._rows_ahead(flight)
+            if rows:
+                return self._step_decode(rows)
+            self._land(obs, flight, held_by=held_by)
+            progress = True
         drafting = self._drafter is not None and not self.spec_suspended
         chunks = max(int(self.prefill_chunks_per_iter), 1)
         for i in range(chunks):
@@ -1279,11 +1343,10 @@ class ServingEngine:
                      "prefill-chunk programs of a looped stack made").inc(
                 self._loop_counts["loop_passes"])
         compiles = program._cache_size()
-        if compiles != self._compiles.get(name):
+        if compiles != self._compiles.get(program):
             # the call traced and compiled (or read the compile cache): a
-            # program's first, and the chunk program's second, whose arena
-            # is no longer the fresh one
-            self._compiles[name] = compiles
+            # program's first
+            self._compiles[program] = compiles
             call_s = 0.0
         return _Enqueued(name, tok, t0, t_call, call_s)
 
@@ -1380,13 +1443,20 @@ class ServingEngine:
         last iteration enqueued AHEAD (``_chunk_ahead``) is this iteration's
         chunk, in flight already: the same rules are asked of it, before the
         decode rows take anything, and where one names itself it is landed
-        here, in a span of its own."""
+        here, in a span of its own.
+
+        A configuration whose layers MIX (``_mixed``) asks the same rules
+        with the chunk prepared and NOT yet enqueued, and where none names
+        itself the chunk is kept (``_mix``) for ``_step_decode`` to send
+        with its rows as ONE program; whenever a rule is named the
+        iteration is the two programs' above."""
         obs = get_session()
         sent = self._chunk
         if sent is not None:
-            self._chunk_first = first_by or self._chunk_first_by(sent)
+            (req, _), = sent.rows
+            self._chunk_first = first_by or self._chunk_first_by(
+                req, sent.start, sent.tokens)
             if self._chunk_first is not None:
-                (req, _), = sent.rows
                 with self._chunk_span(obs, req, sent.start, True) as span:
                     if self._deferring:
                         self._settle(obs, deferred=True)
@@ -1396,10 +1466,15 @@ class ServingEngine:
         if req is None:
             return False
         with self._chunk_span(obs, req, req.prefill_pos) as span:
-            sent = self._enqueue_chunk(obs, req)
-            if sent is None:
+            prepared = self._prepare_chunk(obs, req, req.prefill_pos)
+            if prepared is None:
                 return False    # the pool could not place it — wait
-            self._chunk_first = first_by or self._chunk_first_by(sent)
+            self._chunk_first = first_by or self._chunk_first_by(
+                req, *prepared[1:])
+            if self._chunk_first is None and self._mixed is not None:
+                self._mix = (req, *prepared)
+                return True
+            sent = self._enqueue_chunk(obs, req, *prepared)
             if self._chunk_first is None:
                 self._chunk = sent
                 return True
@@ -1417,14 +1492,15 @@ class ServingEngine:
                         sampled_rows=self._sampled_rows([req]),
                         **self._loop_counts)
 
-    def _enqueue_chunk(self, obs, req: Request) -> Optional["_Enqueued"]:
-        """``serving/prefill_chunk/prepare`` and ``.../dispatch`` of the
-        chunk of ``req``'s prompt that starts where its prefill stands, under
-        the chunk's span, which is open. None where the pool cannot place
-        it."""
+    def _prepare_chunk(self, obs, req: Request,
+                       start: int) -> Optional[tuple]:
+        """``serving/prefill_chunk/prepare`` of the chunk of ``req``'s prompt
+        that starts at ``start`` (where its prefill stands, or will once the
+        mixed step in flight is applied), under the chunk's span, which is
+        open: its pages taken and made its own, its operands packed. Returns
+        (packed, start, tokens), or None where the pool cannot place it."""
         C = self.config.prefill_chunk
         src = req.prompt
-        start = req.prefill_pos
         n_valid = min(C, int(src.size) - start)
         with obs.span("serving/prefill_chunk/prepare", category="phase"):
             if not self.sched.ensure_blocks(req, start + n_valid):
@@ -1441,14 +1517,36 @@ class ServingEngine:
                 state_slot=([req.row] if self._recurrent_layers
                             else None),
                 **({"last": [last]} if self._chunk_says_last else {}))
+        return packed, int(start), int(n_valid)
+
+    def _enqueue_chunk(self, obs, req: Request, packed, start: int,
+                       tokens: int) -> "_Enqueued":
+        """``serving/prefill_chunk/dispatch`` of a chunk that
+        ``_prepare_chunk`` prepared: the chunk program of its own."""
         sent = self._enqueue(obs, "serving/prefill_chunk", self._prefill,
                              packed, self._base_rng, trace=req.trace)
+        return self._chunk_of(sent, req, start, tokens)
+
+    @staticmethod
+    def _chunk_dropped(sent: "_Enqueued") -> bool:
+        """Whether the chunk's request was cancelled, expired or preempted
+        behind its enqueue: its progress is then applied to nobody."""
+        (req, row), = sent.rows
+        return (req.state != PREFILL or req.row != row
+                or req.preemptions != sent.preempted)
+
+    @staticmethod
+    def _chunk_of(sent: "_Enqueued", req: Request, start: int,
+                  tokens: int) -> "_Enqueued":
+        """``sent`` as the record of ``req``'s chunk at ``start``."""
         sent.rows = [(req, req.row)]
-        sent.start, sent.tokens = int(start), int(n_valid)
+        sent.start, sent.tokens = start, tokens
         sent.preempted = req.preemptions
         return sent
 
-    def _chunk_held_by(self, req: Request) -> Optional[str]:
+    def _chunk_held_by(self, req: Request,
+                       start: Optional[int] = None,
+                       rows_need: int = 0) -> Optional[str]:
         """What keeps the NEXT chunk of ``req``'s prompt, whose last chunk
         was just applied behind this iteration's decode step, from being
         enqueued behind that step while it is in flight: the first, in this
@@ -1469,21 +1567,27 @@ class ServingEngine:
         and that no drafter proposes need not be asked: the step went behind
         its chunk (``_step_locked``). Nor whose chunk the scheduler would
         run next: the oldest admission in prefill, which ``req`` was and is,
-        whoever was admitted since (``Scheduler.next_prefill``)."""
+        whoever was admitted since (``Scheduler.next_prefill``).
+
+        ``start``: where the chunk begins, if not where the request's
+        prefill stands: behind a MIXED step in flight, whose chunk's progress
+        is not applied yet (``_rows_ahead``); ``rows_need`` is then the pages
+        that step's successor takes for its rows, from the same pool."""
         if max(int(self.prefill_chunks_per_iter), 1) > 1:
             return "more_chunks"
-        start = req.prefill_pos
+        if start is None:
+            start = req.prefill_pos
         end = min(start + self.config.prefill_chunk, int(req.prompt.size))
-        need = paged_kv.blocks_for_tokens(end, self.config.block_size) \
-            - len(req.blocks)
-        if not self.sched.pages_without_preemption(need):
+        need = max(paged_kv.blocks_for_tokens(end, self.config.block_size)
+                   - len(req.blocks), 0)
+        if not self.sched.pages_without_preemption(need + rows_need):
             return "pages"
         if self.sched.cow_block_indices(req, start, end):
             return "cow"
         return None
 
-    def _chunk_ahead(self, obs, req: Request,
-                     step: "_Enqueued") -> Optional[str]:
+    def _chunk_ahead(self, obs, req: Request, step: "_Enqueued",
+                     start: Optional[int] = None) -> Optional[str]:
         """The next chunk of ``req``'s prompt, whose last chunk was just
         applied, prepared and enqueued BEHIND ``step``, the decode step that
         went behind that chunk and is not fetched yet: its span holds
@@ -1493,14 +1597,19 @@ class ServingEngine:
         across the step's fetch, the delivery, the iteration's tail and the
         next admission; the device goes from the step to it with no host
         round between. Returns None, or the name of ``CHUNK_HELD_BY`` that
-        kept it back: then the next iteration runs as it always did."""
-        held_by = self._chunk_held_by(req)
+        kept it back: then the next iteration runs as it always did.
+        ``start``: as ``_chunk_held_by``'s; ``step`` is then a mixed step
+        and the chunk its prompt's LAST, which keeps a program of its own."""
+        if start is None:
+            start = req.prefill_pos
+        held_by = self._chunk_held_by(req, start)
         if held_by is not None:
             return held_by
-        with self._chunk_span(obs, req, req.prefill_pos, True) as span:
-            sent = self._enqueue_chunk(obs, req)
-            if sent is None:
+        with self._chunk_span(obs, req, start, True) as span:
+            prepared = self._prepare_chunk(obs, req, start)
+            if prepared is None:
                 return "pages"
+            sent = self._enqueue_chunk(obs, req, *prepared)
             sent.ahead = True
             if span.recording or obs.enabled:
                 sent.late = int(step.tok.is_ready())
@@ -1515,9 +1624,12 @@ class ServingEngine:
             self._chunk = sent
         return None
 
-    def _chunk_first_by(self, sent: "_Enqueued") -> Optional[str]:
+    def _chunk_first_by(self, req: Request, start: int,
+                        tokens: int) -> Optional[str]:
         """What keeps this iteration's decode step from being enqueued
-        behind its chunk ``sent`` while that is still in flight, as far as
+        behind its chunk (``tokens`` of ``req``'s prompt from ``start`` on)
+        while that is still in flight, or from being sent WITH it as one
+        mixed step, as far as
         the chunk, the rows and their pages say (a name of
         ``CHUNK_FIRST_BY``), or None where nothing does. ``last_chunk``: the
         chunk is its prompt's last.
@@ -1530,8 +1642,7 @@ class ServingEngine:
         shared block. So nobody is preempted and no block copied with the
         chunk's progress not yet applied; where either would be, the chunk
         is fetched and applied first. Asked before anything is taken."""
-        (req, _), = sent.rows
-        if sent.start + sent.tokens == int(req.prompt.size):
+        if start + tokens == int(req.prompt.size):
             return "last_chunk"
         dec = self.sched.decode_requests()
         if not dec:
@@ -1565,11 +1676,22 @@ class ServingEngine:
         if self._flight is not None:
             self._flight.since = t1
         t0 = sent.t0 if sent.since is None else sent.since
+        return self._apply_chunk(obs, sent, span, tok, t0, t1)
+
+    def _apply_chunk(self, obs, sent: "_Enqueued", span, tok, t0: float,
+                     t1: float) -> bool:
+        """``serving/prefill_chunk/apply`` under ``span``: the progress of a
+        chunk whose program ran from ``t0`` to ``t1``, as ``_land_chunk``
+        says. ``tok`` is what the chunk program returned, or None for the
+        chunk of a MIXED step, which returns nothing for it (it is never its
+        prompt's last) and whose seconds the step's own account holds."""
+        (req, _), = sent.rows
         n_valid = sent.tokens
         with obs.span("serving/prefill_chunk/apply", category="phase"):
-            tok = self._program_counts(span, tok, 1, real_rows=1)
-            if self._serve_acct is not None:
-                self._serve_acct.note_phase("prefill", t1 - t0)
+            if tok is not None:
+                tok = self._program_counts(span, tok, 1, real_rows=1)
+                if self._serve_acct is not None:
+                    self._serve_acct.note_phase("prefill", t1 - t0)
             span.annotate(tokens=n_valid)   # the chunk ran: a span
             #   without the count is a chunk the pool could not place, or
             #   the prepare and dispatch of one that waited for its fetch
@@ -1577,8 +1699,7 @@ class ServingEngine:
                 span.annotate(late=sent.late)
             self.prefill_chunks_run += 1
             self.prefill_tokens_run += n_valid
-            if (req.state != PREFILL or req.row != row
-                    or req.preemptions != sent.preempted):
+            if self._chunk_dropped(sent):
                 span.annotate(dropped_rows=1)
                 return False
             rt = obs.reqtrace
@@ -1592,7 +1713,7 @@ class ServingEngine:
             # cache
             self.sched.note_prefill_progress(req, sent.start, req.prefill_pos)
             self.sched.note_service(req, n_valid)
-            if req.prefill_pos == int(req.prompt.size):
+            if tok is not None and req.prefill_pos == int(req.prompt.size):
                 self._finish_prefill(obs, req, int(tok[0]))
         return True
 
@@ -1777,16 +1898,31 @@ class ServingEngine:
         a step ahead. ``queued``: the queue is not empty. ``fork``: a
         sibling waits for its fork. ``prefill``: a running request is not a
         row of ``flight`` (it is in prefill, or found no page). ``drafter``:
-        a drafter proposes. ``deadline``: a deadline has passed."""
-        freed = self.sched.rows_released != self._rows_released_seen
-        self._rows_released_seen = self.sched.rows_released
-        if freed:
-            return "row_freed"
-        if self.sched.queued:
-            return "queued"
-        if self._pending_forks:
-            return "fork"
-        if len(self.sched.running) != len(flight.rows):
+        a drafter proposes. ``deadline``: a deadline has passed.
+
+        A MIXED step is asked neither ``row_freed``, ``queued`` nor
+        ``fork``: its successor carries the same prompt's next chunk, and
+        whoever takes a freed row, waits in the queue or waits for a fork
+        at that prompt's end gets no chunk of its own before that prompt's
+        last, step ahead or not (the oldest admission in prefill goes
+        first); the admission itself has run by the time this is asked
+        (``_step_locked``), as it does with a chunk in flight, which these
+        three never held either (``_chunk_held_by``). And ``prefill`` asks
+        it of the requests that DECODE: the ones in prefill are its chunk's
+        and those that wait behind it."""
+        if flight.chunk is None:
+            freed = self.sched.rows_released != self._rows_released_seen
+            self._rows_released_seen = self.sched.rows_released
+            if freed:
+                return "row_freed"
+            if self.sched.queued:
+                return "queued"
+            if self._pending_forks:
+                return "fork"
+            others = len(self.sched.running)
+        else:
+            others = len(self.sched.decode_requests())
+        if others != len(flight.rows):
             return "prefill"
         if self._drafter is not None and not self.spec_suspended:
             return "drafter"
@@ -1803,24 +1939,51 @@ class ServingEngine:
         ``HELD_BY`` that says why it must wait for that fetch):
         ``_ahead_held_by``'s, else ``ends`` (every row ends at ``flight``),
         ``pages`` (the free list is short of what the rows need) or ``cow``
-        (a row would write into a shared block)."""
+        (a row would write into a shared block). The successor of a MIXED
+        step carries its prompt's next chunk, so that chunk's rules are
+        asked with the rows' (the union of the two): ``dropped`` (the
+        chunk's request ended or lost its row under the step) and
+        ``_chunk_held_by``'s names, for the chunk that begins where the
+        step's ends; and ``pages`` is then the chunk's rule for the rows'
+        pages too: they come from the free list or from UNPINNED
+        prefix-cache entries, which no request holds and no program in
+        flight reads (a pool that has run for a while has no free page: a
+        decode step behind its chunk took its rows' pages so,
+        ``_chunk_first_by``, and its successor of one program does). All of
+        it is asked before anything is taken."""
         held_by = self._ahead_held_by(flight)
         if held_by is not None:
             return None, held_by
-        rows = [r for r, _ in flight.rows
-                if len(r.generated) + 1 < r.max_new_tokens]
+        # (a row whose request expired at the admission that ran in a mixed
+        # step's shadow is its request's no more)
+        rows = [r for r, row in flight.rows
+                if r.state == DECODE and r.row == row
+                and len(r.generated) + 1 < r.max_new_tokens]
         if not rows:
             return None, "ends"
         bs = self.config.block_size
         need = sum(max(paged_kv.blocks_for_tokens(r.length + 2, bs)
                        - len(r.blocks), 0) for r in rows)
-        if need > self.alloc.blocks_free:
+        mixed = flight.chunk is not None
+        if need > self.alloc.blocks_free and not (
+                mixed and self.sched.pages_without_preemption(need)):
             return None, "pages"
         if any(self.sched.cow_block_indices(r, r.length + 1, r.length + 2)
                for r in rows):
             return None, "cow"
+        if mixed:
+            part = flight.chunk
+            (req, _), = part.rows
+            held_by = ("dropped" if self._chunk_dropped(part)
+                       else self._chunk_held_by(
+                           req, part.start + part.tokens, rows_need=need))
+            if held_by is not None:
+                return None, held_by
         for r in rows:
-            self.sched.try_extend_blocks(r, r.length + 2)
+            # (``ensure_blocks`` evicts unpinned entries where the free list
+            # is short, and was shown above to need nobody's row)
+            (self.sched.ensure_blocks if mixed
+             else self.sched.try_extend_blocks)(r, r.length + 2)
         return rows, None
 
     def _step_decode(self, ahead: Optional[List[Request]] = None) -> bool:
@@ -1845,34 +2008,131 @@ class ServingEngine:
         (``chunk_first_by``), of a step fetched with no successor
         enqueued which rule held that one (``held_by``, in ``_land``), and
         of a step behind its chunk that was fetched with no chunk enqueued
-        ahead which rule kept that one back (``chunk_held_by``)."""
-        dec = ahead or self.sched.decode_requests()
-        if not dec:
-            return False
+        ahead which rule kept that one back (``chunk_held_by``).
+
+        **The mixed step** (a configuration whose layers mix). Where
+        ``_step_prefill`` kept the iteration's chunk back (``_mix``: no rule
+        of ``CHUNK_FIRST_BY`` named itself), the rows and the chunk go as
+        ONE program (``_mixed``), one record that carries both, and its span
+        says ``mixed`` 1 and ``chunk_tokens``; it stays in flight under the
+        rules of any step. Its successor (``ahead``, with ``_flight`` such a
+        step: ``_rows_ahead`` asked the chunk's rules too) takes the
+        prompt's next chunk, from where the step in flight will leave the
+        prompt: a mixed step again where that chunk is not the last, and
+        where it is, the chunk program of its own enqueued behind the step
+        (``_chunk_ahead``) and no step ahead, so that the last chunk finds
+        the next iteration as it always did."""
         obs = get_session()
+        mix, self._mix = self._mix, None
+        before = self._flight
+        if ahead and before.chunk is not None:
+            part = before.chunk
+            (req, _), = part.rows
+            start = part.start + part.tokens
+            if start + self.config.prefill_chunk >= int(req.prompt.size):
+                # the LAST chunk brings a first token, which must not wait
+                # for the rows' walks and head: the chunk program of its
+                # own, behind the step, and no successor ahead. The next
+                # iteration finds it in flight and lands it first
+                self._land_behind(
+                    obs, before, self._chunk_ahead(obs, req, before, start))
+                return True
+            with self._chunk_span(obs, req, start, True):
+                prepared = self._prepare_chunk(obs, req, start)
+            # None: the pool could not place it after all, and the rows go
+            # ahead as the decode step they are
+            mix = prepared and (req, *prepared)
+        dec = ahead or self.sched.decode_requests()
         chunk = self._chunk
+        ready = []
+        if dec:
+            ready = self._enqueue_step(obs, dec, ahead, chunk, mix)
+        if mix is not None and not ready:
+            # no row could take its page after all: the chunk alone
+            req, *prepared = mix
+            with self._chunk_span(obs, req, prepared[1]) as span:
+                sent = self._enqueue_chunk(obs, req, *prepared)
+                if self._deferring:
+                    self._settle(obs, deferred=True)
+                self._land_chunk(obs, sent, span)
+            return True
+        if not ready:
+            return False
+        if chunk is not None:
+            # neither program's span inside the other's. A request is still
+            # in prefill (the chunk was not its prompt's last), so no STEP
+            # stays in flight at the iteration's end: the rule is known
+            # without being asked. That request's next chunk may
+            sent = self._flight
+            (req, _), = chunk.rows
+            kept_by = (self._chunk_ahead(obs, req, sent)
+                       if self._land_chunk(obs, chunk) else "dropped")
+            self._land_behind(obs, sent, kept_by)
+        return True
+
+    def _land_behind(self, obs, sent: "_Enqueued",
+                     kept_by: Optional[str]) -> None:
+        """The step ``sent`` landed in a ``serving/decode`` span of its own
+        with a request still in prefill (``held_by`` ``prefill``), behind
+        the attempt to enqueue that request's next chunk behind it
+        (``kept_by``: the name of ``CHUNK_HELD_BY`` that kept the chunk
+        back, None where it went); with a chunk in flight the step's tokens
+        are delivered at once, the device being busy."""
+        with obs.span("serving/decode", cpu=True,
+                      max_rows=self.config.max_seqs) as span:
+            if kept_by is not None:
+                span.annotate(chunk_held_by=kept_by)
+            self._land(obs, sent, span, held_by="prefill")
+            if self._chunk is not None:
+                self._flush(deferred=True)
+        self._apply_landed(obs)
+
+    def _enqueue_step(self, obs, dec: List[Request],
+                      ahead: Optional[List[Request]],
+                      chunk: Optional["_Enqueued"],
+                      mix: Optional[tuple]) -> List[Request]:
+        """The ``serving/decode`` span of ``_step_decode`` that prepares and
+        enqueues the step (decode rows alone, or with ``mix``, the prepared
+        chunk, as one mixed step) and lands what the rules say is landed
+        inside it. Returns the rows the step took; none: nothing was
+        enqueued."""
         with obs.span("serving/decode", cpu=True,
                       max_rows=self.config.max_seqs) as span:
             with obs.span("serving/decode/prepare", category="phase"):
                 ready = ahead or self._ready_decode_rows(dec)
                 packed = (self._decode_operands(ready, bool(ahead))
                           if ready else None)
+                mixed = bool(ready) and mix is not None
+                req, chunk_packed, start, tokens = mix if mixed else (
+                    None, None, 0, 0)
                 span.annotate(rows=len(ready), ahead=int(bool(ahead)),
                               behind_chunk=int(bool(ready)
                                                and chunk is not None),
+                              mixed=int(mixed), chunk_tokens=tokens,
                               sampled_rows=self._sampled_rows(ready),
                               **self._loop_counts)
-                if ready and chunk is None and self._chunk_first:
+                if ready and chunk is None and not mixed \
+                        and self._chunk_first:
                     span.annotate(chunk_first_by=self._chunk_first)
             if not ready:
-                return False
+                return ready
             first_trace = (next((r.trace for r in ready
                                  if r.trace is not None), None)
                            if obs.reqtrace is not None else None)
             before = self._flight
-            sent = self._flight = self._enqueue(
-                obs, "serving/decode", self._decode, packed, self._base_rng,
-                self._last_tokens, trace=first_trace)
+            if mixed:
+                sent = self._enqueue(
+                    obs, "serving/decode", self._mixed, packed, chunk_packed,
+                    self._base_rng, self._last_tokens, trace=first_trace)
+                sent.chunk = self._chunk_of(
+                    _Enqueued("serving/prefill_chunk", None, sent.t0,
+                              sent.t_call, 0.0, ahead=bool(ahead)),
+                    req, start, tokens)
+            else:
+                sent = self._enqueue(
+                    obs, "serving/decode", self._decode, packed,
+                    self._base_rng, self._last_tokens, trace=first_trace)
+            self._flight = sent
             sent.rows = [(r, r.row) for r in ready]
             self._last_tokens = sent.tok
             behind = chunk or before
@@ -1880,6 +2140,8 @@ class ServingEngine:
                 # one non-blocking question to the array the engine holds
                 late = int(behind.tok.is_ready())
                 span.annotate(late=late)
+                if mixed:
+                    sent.chunk.late = late
                 if late and obs.enabled:
                     obs.registry.counter(
                         "serving/steps_enqueued_late",
@@ -1887,6 +2149,12 @@ class ServingEngine:
                              "flight (ahead, or behind their chunk) that "
                              "had already ended: the host's round outlasted "
                              "it").inc()
+            if mixed and obs.enabled:
+                obs.registry.counter(
+                    "serving/mixed_steps",
+                    help="decode steps that carried a prompt chunk (not its "
+                         "prompt's last) as ONE program with their rows (a "
+                         "configuration whose layers mix)").inc()
             if self._deferring:
                 self._settle(obs, deferred=True)
             if chunk is not None:
@@ -1908,23 +2176,8 @@ class ServingEngine:
             elif held_by := ("step_mode" if not self._deferring
                              else self._ahead_held_by(sent)):
                 self._land(obs, sent, span, held_by=held_by)
-        if chunk is not None:
-            # neither program's span inside the other's. A request is still
-            # in prefill (the chunk was not its prompt's last), so no STEP
-            # stays in flight at the iteration's end: the rule is known
-            # without being asked. That request's next chunk may
-            (req, _), = chunk.rows
-            kept_by = (self._chunk_ahead(obs, req, sent)
-                       if self._land_chunk(obs, chunk) else "dropped")
-            with obs.span("serving/decode", cpu=True,
-                          max_rows=self.config.max_seqs) as span:
-                if kept_by is not None:
-                    span.annotate(chunk_held_by=kept_by)
-                self._land(obs, sent, span, held_by="prefill")
-                if self._chunk is not None:
-                    self._flush(deferred=True)   # at once: the device is
-                    #   busy
-        return True
+        self._apply_landed(obs)
+        return ready
 
     def _land(self, obs, sent: "_Enqueued", span=None,
               held_by: Optional[str] = None) -> None:
@@ -1941,11 +2194,16 @@ class ServingEngine:
         ahead, or behind a chunk in flight, runs for the accountant and the
         request tracer from that program's fetch to its own, and that of
         whatever is enqueued behind this step from here: no second is
-        counted twice."""
+        counted twice. A MIXED step's fetch lands its chunk too, over the
+        step's own interval: its progress is applied as soon as the span
+        that holds the fetch has closed (``_apply_landed``: neither
+        program's span lies inside the other's), which whoever handed
+        ``span`` sees to."""
         if span is None:
             with obs.span("serving/decode", cpu=True,
                           max_rows=self.config.max_seqs) as span:
-                return self._land(obs, sent, span, held_by)
+                self._land(obs, sent, span, held_by)
+            return self._apply_landed(obs)
         if self._flight is sent:
             self._flight = None
         if held_by is not None:
@@ -1957,6 +2215,8 @@ class ServingEngine:
         t0 = sent.t0 if sent.since is None else sent.since
         rt = obs.reqtrace
         acct = self._serve_acct
+        if sent.chunk is not None:
+            self._landed = (sent.chunk, t0, t1)
         with obs.span("serving/decode/apply", category="phase"):
             live = [(r, row) for r, row in sent.rows
                     if r.state == DECODE and r.row == row]
@@ -1979,6 +2239,19 @@ class ServingEngine:
                 self._flush()
             if acct is not None:
                 acct.note_phase("sample_host", self.clock() - t1)
+
+    def _apply_landed(self, obs) -> None:
+        """The progress of the chunk that the mixed step just fetched
+        carried (``_land`` left it: ``_landed``), applied in a
+        ``serving/prefill_chunk`` span of its own, behind the
+        ``serving/decode`` span that holds the fetch, which says ``tokens``,
+        ``ahead`` and ``late`` as every chunk's span does."""
+        if self._landed is None:
+            return
+        (part, t0, t1), self._landed = self._landed, None
+        (req, _), = part.rows
+        with self._chunk_span(obs, req, part.start, part.ahead) as span:
+            self._apply_chunk(obs, part, span, None, t0, t1)
 
     def _bring_home(self, held_by: Optional[str] = None) -> None:
         """A settled engine, for whoever needs one (under the engine lock):
@@ -2570,6 +2843,27 @@ class ServingEngine:
                       # one decode iteration emits one token per row
                       "tokens_per_step": R, "shard": shard,
                       "program": "decode"})
+
+            if self._mixed is not None:
+                def build_mixed():
+                    eng = wself()
+                    if eng is None:
+                        raise StaleEntryError("serving/mixed_step: "
+                                              "engine gone")
+                    params, arena, rows, key, last = build_decode()[1]
+                    return (eng._mixed, (params, arena, rows,
+                                         eng._audit_args_prefill()[2], key,
+                                         last), {})
+
+                register_entry_point(
+                    "serving/mixed_step", build=build_mixed,
+                    donate_argnums=(1,), expected_collectives=expected,
+                    mesh=self.engine.mesh,
+                    tags={"engine": "ServingEngine", "rows": R, "chunk": C,
+                          "max_blocks": MAXB,
+                          # a token a row and the chunk's C prompt tokens
+                          "tokens_per_step": R + C, "shard": shard,
+                          "program": "mixed_step"})
 
             def build_cow():
                 eng = wself()

@@ -16,8 +16,13 @@ pool of fixed-size **blocks** (vLLM's PagedAttention, Kwon et al. SOSP '23):
   bytes are the same. On the chip two token rows share each 32-bit word of
   the arena and a page is 16 whole tiles, so 256 row updates cost a chunk
   program a quarter of its time (``models/transformer._write_pages``).
-* ``build_prefill_program`` / ``build_decode_program`` — the two jitted
-  serving programs. Both are **shape-static**: the block table
+* ``build_prefill_program`` / ``build_decode_program`` /
+  ``build_mixed_program`` — the three jitted serving programs; the third,
+  a prompt chunk that is not its prompt's last and the decode rows in ONE
+  pass over the layers, only for a configuration whose layers mix
+  (``mixes``: a one-pass stack of plain attention layers with dense FFNs;
+  recurrent, ring, cross, expert and looped kinds keep the two). All are
+  **shape-static**: the block table
   ``(rows, max_blocks)`` and per-row lengths are data, not shapes, so one
   compiled decode program serves every occupancy the scheduler produces
   (the jit-cache analog of the reference's CUDA-graph discipline). The
@@ -65,7 +70,8 @@ __all__ = ["BlockAllocator", "BlockAllocatorError", "PrefixCache",
            "blocks_for_tokens", "assert_block_divisible", "init_paged_cache",
            "paged_cache_memory_bytes", "paged_pools",
            "build_prefill_program",
-           "build_decode_program", "build_verify_program",
+           "build_decode_program", "build_mixed_program", "mixes",
+           "build_verify_program",
            "build_score_program", "build_cow_program",
            "build_kv_export_program", "build_kv_import_program",
            "sample_rows", "extend_block_list", "truncate_block_list",
@@ -577,7 +583,7 @@ def unpack_chunk(packed: jax.Array, chunk_tokens: int, state_slot: bool,
 
 
 # ---------------------------------------------------------------------------
-# the two serving programs
+# the serving programs: chunk, decode and (where the layers mix) both as one
 # ---------------------------------------------------------------------------
 
 
@@ -587,6 +593,18 @@ def _with_moe_counts(tokens: jax.Array, counts: jax.Array) -> jax.Array:
     with a row, rows of the largest expert]``, the three summed over the
     layers (``models/transformer.forward(moe_counts=True)``)."""
     return jnp.concatenate([tokens.astype(jnp.int32), counts])
+
+
+def _run_operands(tokens: int, start: jax.Array, n_valid: jax.Array):
+    """``(write_mask (1, C), positions (1, C))`` of a run of ``tokens`` = C
+    positions from ``start`` on whose first ``n_valid`` are real (a prompt
+    or scoring chunk). Pad queries ride position -1 (the inactive
+    convention): a pad position past the written range would otherwise widen
+    the read path's residency window onto scratch/recycled pages, whose
+    nonfinite residue must never touch live rows."""
+    offs = jnp.arange(tokens, dtype=jnp.int32)
+    write_mask = (offs < n_valid)[None]
+    return write_mask, jnp.where(write_mask, (start + offs)[None], -1)
 
 
 def build_prefill_program(cfg, chunk_tokens: int, moe_counts: bool = False):
@@ -657,14 +675,7 @@ def _chunk_step(cfg, moe_counts: bool = False):
     def chunk_step(params, cache, block_table, chunk, start, n_valid,
                    temperature, top_k, top_p, seeds, state_slot, base_key,
                    last=None):
-        C = chunk.shape[1]
-        offs = jnp.arange(C, dtype=jnp.int32)
-        write_mask = (offs < n_valid)[None]
-        # pad queries ride position -1 (the inactive convention): a pad
-        # position past the written range would otherwise widen the read
-        # path's residency window onto scratch/recycled pages, whose
-        # nonfinite residue must never touch live rows
-        pos = jnp.where(write_mask, (start + offs)[None], -1)
+        write_mask, pos = _run_operands(chunk.shape[1], start, n_valid)
         tail = {}
         if last_only:
             # the token whose logits are read, or -1: none of this chunk's
@@ -763,6 +774,73 @@ def _decode_step(cfg, moe_counts: bool = False):
     return decode_step
 
 
+def mixes(cfg) -> bool:
+    """THE predicate of the mixed step: whether a configuration's non-last
+    prompt chunk and the iteration's decode rows can run as one program
+    (``build_mixed_program``). Decided by what its layers are, never by a
+    name: a stack that runs once (no ``loop_passes``, no ``layer_runs``)
+    whose every mixer is the plain ``"attn"`` of ``MIXERS`` (it keeps pages
+    of its own and hands nothing between a chunk kernel and a step kernel)
+    with a dense FFN behind it. A recurrent mixer's state, a window's ring
+    and a cross layer's borrowed pool pass from the chunk program's kernel
+    to the step program's, an expert FFN's routing mask and counts are a
+    token's, and a looped stack's only traffic is one-chunk prompts: those
+    keep the two programs."""
+    from ..models.transformer import LAYER_KINDS, layer_kinds
+
+    return (cfg.loop_passes == 1 and not cfg.layer_runs
+            and cfg.moe_num_experts == 0
+            and all(LAYER_KINDS[kind] == ("attn", True)
+                    for kind in layer_kinds(cfg)))
+
+
+def build_mixed_program(cfg, chunk_tokens: int):
+    """Jitted MIXED step over the paged arena, for a configuration that
+    ``mixes``: a prompt chunk that is NOT its prompt's last and the
+    iteration's decode rows through ONE pass over the layers. The R row
+    tokens and the C chunk tokens are embedded as one flat run of R + C, so
+    every per-token product of a layer (norms, projections, FFN) is one
+    product over R + C rows and each weight is read once an iteration, not
+    once a program; only the mixer's write and read over the pages split
+    (``models/transformer._attend_mixed``): the chunk's as the chunk
+    program's, the rows' as the decode program's. The head runs over the R
+    row tokens alone: a chunk that is not the last has no token anybody
+    reads (a last chunk's is its request's first token, which must not wait
+    for the rows' walks: it keeps the chunk program).
+
+    Args: params, cache (DONATED), rows (R, MAXB + 7) int32
+    (``pack_decode_rows``), chunk (MAXB + C + 6,) int32 (``pack_chunk``; its
+    sampling values ride along unread), base_key, last (optional: as the
+    decode program's). Returns (next_token (R,), cache), the decode
+    program's own result: a row draws from its own stream (``seeds``,
+    ``steps``), so the tokens are those of the chunk program followed by the
+    decode program, and so are the arena's bytes."""
+    from ..models.transformer import forward as model_forward
+
+    if not mixes(cfg):
+        raise ValueError("this configuration's layers do not mix "
+                         "(paged_kv.mixes)")
+
+    def mixed_step(params, cache, rows, chunk, base_key, last=None):
+        (row_table, lengths, tokens, temperature, top_k, top_p, seeds,
+         steps) = unpack_decode_rows(rows)
+        table, ids, start, n_valid, *_ = unpack_chunk(chunk, chunk_tokens,
+                                                      False)
+        if last is not None:
+            tokens = jnp.where(tokens < 0, last[:tokens.shape[0]], tokens)
+        write_mask, pos = _run_operands(chunk_tokens, start, n_valid)
+        logits, cache, _ = model_forward(
+            params, jnp.concatenate([tokens[None], ids], axis=1), cfg,
+            cache=cache, positions=lengths[:, None], block_table=row_table,
+            mixed_chunk=dict(positions=pos, block_table=table,
+                             write_mask=write_mask,
+                             paged_run=(start, n_valid)))
+        return sample_rows(logits[:, -1], base_key, temperature, top_k,
+                           top_p, seeds, steps), cache
+
+    return jax.jit(mixed_step, donate_argnums=(1,))
+
+
 def build_verify_program(cfg, num_tokens: int):
     """Jitted speculative-decoding verify step: the R×1 decode program
     generalized to R×S (S = ``num_tokens`` = K+1 draft slots + the pending
@@ -820,7 +898,7 @@ def _verify_step(cfg):
         offs = jnp.arange(S, dtype=jnp.int32)
         write_mask = offs[None] < n_valid[:, None]
         # invalid slots (beyond the row's proposal count, and every slot
-        # of an inactive row) ride position -1 — see prefill_chunk: pad
+        # of an inactive row) ride position -1 — see ``_run_operands``: pad
         # positions past the written range would widen the residency
         # window onto scratch/recycled pages
         pos = jnp.where(write_mask, lengths[:, None] + offs[None], -1)
@@ -871,11 +949,7 @@ def build_score_program(cfg):
 
     def score_chunk(params, cache, block_table, chunk, targets, start,
                     n_valid):
-        C = chunk.shape[1]
-        offs = jnp.arange(C, dtype=jnp.int32)
-        write_mask = (offs < n_valid)[None]
-        # pad queries at position -1 — see prefill_chunk
-        pos = jnp.where(write_mask, (start + offs)[None], -1)
+        write_mask, pos = _run_operands(chunk.shape[1], start, n_valid)
         # a scored sequence's recurrent state lives in the scratch slot:
         # no decode row owns it, and its first chunk starts it from zeros
         slots = (jnp.full((1,), cache["state"].shape[1] - 1, jnp.int32)

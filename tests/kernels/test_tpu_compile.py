@@ -281,7 +281,7 @@ _RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
 def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
                      overrides=None, rows=ROWS, num_blocks=NUM_BLOCKS,
                      chunk=CHUNK, **program_options):
-    """One of ``paged_kv``'s four programs (decode, prefill, verify, score)
+    """One of ``paged_kv``'s programs (decode, prefill, mixed, verify, score)
     lowered for the described chip on its kernel path
     (``jax.default_backend()`` is the CPU here, so the platform probe is
     steered)."""
@@ -323,6 +323,11 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
         return paged_kv.build_decode_program(cfg, **program_options).lower(
             params, arena, arg(paged_kv.decode_rows_shape(r, maxb), I32), key,
             last)
+    if kind == "mixed":
+        return paged_kv.build_mixed_program(cfg, chunk).lower(
+            params, arena, arg(paged_kv.decode_rows_shape(r, maxb), I32),
+            arg(paged_kv.chunk_shape(maxb, chunk, False), I32), key,
+            arg((r,), I32))
     if kind == "verify":
         return paged_kv.build_verify_program(cfg, SPEC_TOKENS).lower(
             params, arena,
@@ -778,6 +783,59 @@ def test_a_decode_step_reads_its_attention_weights_where_they_lie(
     makers, readers = _projection_weights(text, widths, layers)
     assert not makers, "\n".join(makers)
     assert len(readers) == weights, readers
+
+
+def test_the_mixed_step_reads_each_weight_once_and_copies_none(v5e,
+                                                               monkeypatch):
+    """`jit_mixed_step` at opt-1.3b's widths (3 layers, 16 rows beside a
+    chunk of 256): ONE pass over the layers, so each attention projection is
+    one product over the 272 tokens that reads the layer stack where it
+    lies (with the run sliced into its two parts next to the products, the
+    compiler sliced wq, wk and wv out of the stack and transposed them, a
+    layer: PR 61), and the FFN's two products and the tied head appear once;
+    both paged kernels are there, a call each, on the whole arena, which
+    the two writes of each part (pages for the chunk, rows for the rows)
+    update in place; and the temporaries are the decode program's walk
+    tiles, not a pool."""
+    compiled = _serving_program("mixed", v5e, monkeypatch).compile()
+    text = compiled.as_text()
+    makers, readers = _projection_weights(text, (2048, 2048, 2048), LAYERS)
+    assert not makers, "\n".join(makers)
+    assert len(readers) == 4, readers
+    pool = f"bf16[{NUM_BLOCKS},{BLOCK},2048]"
+    arena = f"bf16[{LAYERS},{NUM_BLOCKS},{BLOCK},2048]"
+    roots = _fusion_roots(text)
+    calls, offenders, updates, shape_of = [], [], [], {}
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m is None:
+            continue
+        name, result, op = m.groups()
+        shape_of[name] = result.split("{")[0]
+        if op == "custom-call" and "tpu_custom_call" in line:
+            calls.append(line)
+            if "paged_" in line:
+                operands = line.split("operand_layout_constraints=", 1)[1]
+                assert operands.count(arena) == 2 and pool not in operands
+        if op == "scatter" and result.startswith(arena):
+            updates.append(shape_of[re.findall(
+                r"%([\w.\-]+)", line.split(" scatter(", 1)[1])[2]])
+        if (pool in result or arena in result) \
+                and not _writes_in_place(line, op, roots):
+            offenders.append(line.strip()[:200])
+    assert not offenders, "\n".join(offenders)
+    assert sum("paged_prefill_attention" in ln for ln in calls) == 1
+    assert sum("paged_decode_attention" in ln for ln in calls) == 1
+    # the chunk's k and v as whole pages, then the rows' as a row a token
+    assert updates == [ARENA_UPDATES["prefill"]] * 2 \
+        + [ARENA_UPDATES["decode"]] * 2
+    # the FFN's weights are read by one product each, over all 272 tokens
+    assert "bf16[272,8192]" in text
+    assert "bf16[256,8192]" not in text and "bf16[16,8192]" not in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * LAYERS * POOL_BYTES
+    assert memory.temp_size_in_bytes <= PARENT_DECODE_TEMP_BYTES[
+        "olmoe-1b-7b"]
 
 
 # ---------------------------------------------------------------------------

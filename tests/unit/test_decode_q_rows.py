@@ -23,9 +23,10 @@ MIXERS = {"biased": ("tiny-opt", {}),
           "qk-norm-rope-gqa": ("tiny-olmoe", {"num_kv_heads": 2})}
 
 
-def _reshape_first(cfg, h, p, cached=False):
+def _reshape_first(cfg, h, p, cached=False, divided=False):
     """``_qkv_heads`` as it stood before PR 53: nothing between q's product
-    and its heads (``cached``, PR 60: taken and not read)."""
+    and its heads (``cached``, PR 60, and ``divided``, PR 61: taken and not
+    read)."""
     B, S, _ = h.shape
     N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q, k, v = (T._qeinsum("bsh,hd->bsd", h, p[w], cfg.dtype,

@@ -52,12 +52,20 @@ def tiny_engine():
     return init_inference("tiny", dtype=jnp.float32, max_out_tokens=128)
 
 
-def serving(tiny_engine, clock=None, **cfg):
+def serving(tiny_engine, clock=None, mixed=False, **cfg):
+    """A serving engine over ``tiny_engine``. ``mixed=False``: held to the
+    chunk and decode programs, as an engine is whose layers do not mix
+    (``paged_kv.mixes``; most of the tests below fix the order of those two
+    programs' enqueues and fetches); ``mixed=True``: as it was built, with
+    the mixed step where its configuration mixes (``TestMixedStep``)."""
     defaults = dict(block_size=16, num_blocks=32, max_seqs=4,
                     max_model_len=128, prefill_chunk=16, max_queue=64)
     defaults.update(cfg)
-    return ServingEngine(tiny_engine, ServingConfig(**defaults),
+    srv = ServingEngine(tiny_engine, ServingConfig(**defaults),
                         **({"clock": clock} if clock else {}))
+    if not mixed:
+        srv._mixed = None
+    return srv
 
 
 # ---------------------------------------------------------------------------
@@ -1025,7 +1033,7 @@ def never_ahead(srv, chunks=True):
     fix."""
     srv._ahead_held_by = lambda flight: "queued"
     if chunks:
-        srv._chunk_held_by = lambda req: "pages"
+        srv._chunk_held_by = lambda req, *at: "pages"
     return srv
 
 
@@ -3196,3 +3204,457 @@ class TestChunkAhead:
             assert len(c.tokens) == 4
         finally:
             srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the mixed step: a configuration whose layers mix (``paged_kv.mixes``) sends
+# a chunk that is NOT its prompt's last and the iteration's decode rows as
+# ONE program, and the next one ahead of its fetch. Same tokens as the two
+# programs; whenever a rule of CHUNK_FIRST_BY names itself, the two programs
+# ---------------------------------------------------------------------------
+
+
+def watch_mixed(srv):
+    """Every mixed step's (rids of its rows, rid of its chunk, start,
+    tokens, prompt size), in the order of enqueue; and no last chunk ever
+    rides one."""
+    log, program = [], srv._mixed
+
+    def spy(params, arena, rows, chunk, key, last):
+        req, _, start, tokens = srv._mix_sent
+        assert start + tokens < int(req.prompt.size), "a last chunk mixed"
+        assert req.state == PREFILL
+        log.append((start, tokens))
+        return program(params, arena, rows, chunk, key, last)
+
+    spy._cache_size = program._cache_size
+    enqueue = srv._enqueue_step
+
+    def spy_step(obs, dec, ahead, chunk, mix):
+        srv._mix_sent = mix
+        return enqueue(obs, dec, ahead, chunk, mix)
+
+    srv._mixed = spy
+    srv._enqueue_step = spy_step
+    return log
+
+
+# prompts of 1-5 chunks of 16 beside short ones, more requests than rows
+MIXED_PROMPTS = [(9, dict(max_new_tokens=14)),
+                 (70, dict(max_new_tokens=6)),
+                 (52, dict(max_new_tokens=7, temperature=0.9, top_k=20,
+                           seed=4)),
+                 (7, dict(max_new_tokens=12, temperature=1.2, seed=9)),
+                 (80, dict(max_new_tokens=5)), (33, dict(max_new_tokens=8)),
+                 (16, dict(max_new_tokens=3)), (64, dict(max_new_tokens=9))]
+
+MIXED_CASES = {
+    "dense": {},
+    "dense_no_cache": dict(prefix_cache=False),
+    # chunks and rows preempt to grow: a step mixes only where its pages
+    # are on the free list
+    "small_pool": dict(num_blocks=11, prefix_cache=False),
+    "two_rows": dict(max_seqs=2),
+}
+
+
+def _mixed_events(srv, handles, how, clock):
+    """Something that ends or moves a request under the traffic: called
+    between two iterations of the driver's loop."""
+    if how == "cancel":
+        srv.cancel(handles[1])          # in prefill, maybe under a step
+        srv.cancel(handles[0])          # a row that decodes
+    elif how == "deadline":
+        clock.advance(100.0)            # handles[4] has one
+    elif how == "preempt":
+        victim = next((r for r in srv.sched.running.values()
+                       if r.state == PREFILL), None)
+        if victim is not None:
+            srv._bring_home()
+            srv.sched.preempt(victim)
+    elif how == "fork":
+        h = next((h for h in handles if h.state == "decode"), None)
+        if h is not None:
+            return srv.fork(h, 2)
+    return []
+
+
+class TestMixedStep:
+    @pytest.mark.parametrize("mode", ["thread", "driver_loop"])
+    @pytest.mark.parametrize("case", sorted(MIXED_CASES))
+    def test_streams_are_those_of_the_two_programs(self, tiny_engine, case,
+                                                   mode):
+        rng = np.random.RandomState(sum(map(ord, case)))
+        reqs = [(rng.randint(0, 250, (n,)).astype(np.int32), kw)
+                for n, kw in MIXED_PROMPTS]
+        streams, steps = {}, {}
+        for mixed in (False, True):
+            srv = serving(tiny_engine, mixed=mixed, **MIXED_CASES[case])
+            log = watch_mixed(srv) if mixed else []
+            try:
+                handles = [srv.submit(p, **kw) for p, kw in reqs]
+                if mode == "thread":
+                    srv.start()
+                else:
+                    drive_on_this_thread(srv)
+                streams[mixed] = [list(h.result(timeout_s=120.0))
+                                  for h in handles]
+                srv.stop()
+                assert all(h.done and h.state == "finished"
+                           and h.tokens == h._req.generated for h in handles)
+                assert srv._chunk is None and srv._flight is None
+                assert srv._mix is None
+                assert not srv.sched.running and not srv._undelivered
+                assert srv.alloc.blocks_in_use == (
+                    srv.prefix.cached_blocks if srv.prefix else 0)
+                steps[mixed] = log
+                if case == "small_pool":
+                    assert srv.sched.preemption_count > 0
+            finally:
+                srv.close()
+        assert streams[True] == streams[False]
+        assert len(steps[True]) >= 3, steps[True]
+
+    @pytest.mark.parametrize("how", ["cancel", "deadline", "preempt", "fork"])
+    def test_a_request_that_ends_or_moves_under_the_traffic(self, tiny_engine,
+                                                            how):
+        """A cancellation, a deadline, a preemption and a copy-on-write fork
+        beside a prompt in prefill, at the same iteration of the driver's
+        loop with and without the mixed step: every request, the forked
+        ones too, ends with the tokens of the two programs."""
+        rng = np.random.RandomState(7)
+        reqs = [(rng.randint(0, 250, (n,)).astype(np.int32), kw)
+                for n, kw in MIXED_PROMPTS[:6]]
+        reqs[4][1].update(deadline_s=50.0) if how == "deadline" else None
+        streams = {}
+        for mixed in (False, True):
+            clock = FakeClock()
+            srv = serving(tiny_engine, clock=clock, mixed=mixed)
+            log = watch_mixed(srv) if mixed else []
+            try:
+                handles = [srv.submit(p, **dict(kw)) for p, kw in reqs]
+                drive_on_this_thread(srv, iterations=5)
+                handles += _mixed_events(srv, handles, how, clock)
+                drive_on_this_thread(srv)
+                streams[mixed] = [(h.state, list(h.tokens)) for h in handles]
+                assert srv._chunk is None and srv._flight is None
+                assert not srv.sched.running and not srv._undelivered
+                assert not mixed or len(log) >= 2
+            finally:
+                srv.close()
+        assert len(streams[True]) == len(streams[False])
+        for (state, tokens), (want_state, want) in zip(streams[True],
+                                                       streams[False]):
+            assert state == want_state
+            if state == "finished":
+                assert tokens == want
+            else:
+                # cut at the same iteration, which is not the same token:
+                # a mixed step's progress lands a fetch later
+                short, long = sorted((tokens, want), key=len)
+                assert long[:len(short)] == short
+        if how in ("cancel", "deadline"):
+            assert any(state != "finished" for state, _ in streams[True])
+
+    def test_the_next_mixed_step_is_dispatched_before_the_one_in_flight_is_fetched(
+            self, tiny_engine, monkeypatch, obs_session):
+        """A prompt of four chunks beside two rows that decode: chunks one
+        to three ride mixed steps, the second and third enqueued AHEAD of
+        their predecessor's fetch (no host round between them), and the
+        fourth, the last, is a chunk program of its own behind the third
+        step, landed at the head of the next iteration with its first
+        token."""
+        srv = serving(tiny_engine, mixed=True, prefix_cache=False)
+        events = TestDeferredDelivery._spy(monkeypatch, srv)
+        log = watch_mixed(srv)
+        try:
+            a, b = decoding_pair(srv)
+            c = srv.submit(np.arange(50, 110, dtype=np.int32),
+                           max_new_tokens=4)
+            ra, rb, rc = a.request_id, b.request_id, c.request_id
+            del events[:]
+            mark, chunks = len(decode_spans()), len(chunk_spans())
+            drive_on_this_thread(srv, iterations=6)
+            programs = [e for e in events if e[0] != "push"]
+            assert programs == [
+                # the rows' step in flight is landed for the admission
+                ("fetched", "decode"), ("iteration_end",),
+                # from a settled engine: admission, then the first mixed step
+                ("dispatch", "decode"), ("iteration_end",),
+                # each successor ahead of its predecessor's fetch
+                ("dispatch", "decode"), ("fetched", "decode"),
+                ("iteration_end",),
+                ("dispatch", "decode"), ("fetched", "decode"),
+                ("iteration_end",),
+                # the last chunk: its own program behind the third step
+                ("dispatch", "prefill_chunk"), ("fetched", "decode"),
+                ("iteration_end",),
+                ("fetched", "prefill_chunk"), ("dispatch", "decode"),
+                ("iteration_end",)]
+            assert log == [(0, 16), (16, 16), (32, 16)]
+            assert c.state == "decode" and len(c.tokens) == 1
+            steps = [s for s in decode_spans()[mark:] if s.get("rows")]
+            assert [(s["mixed"], s["chunk_tokens"], s["ahead"])
+                    for s in steps] == [(1, 16, 0), (1, 16, 1), (1, 16, 1),
+                                        (0, 0, 0)]
+            assert not any(s["behind_chunk"] for s in steps)
+            ran = [s for s in chunk_spans()[chunks:] if "tokens" in s]
+            assert [(s["chunk_start"], s["tokens"], s["ahead"])
+                    for s in ran] == [(0, 16, 0), (16, 16, 1), (32, 16, 1),
+                                      (48, 12, 1)]
+            assert get_registry().counter("serving/mixed_steps").value() == 3
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("name", ["last_chunk", "pages", "no_rows",
+                                      "drafter", "more_chunks", "step_mode"])
+    def test_a_rule_of_chunk_first_by_keeps_the_two_programs(
+            self, tiny_engine, obs_session, name):
+        """``TestHostCauses``' states, in an engine that mixes: the
+        iteration is the chunk program, fetched first, and the decode
+        program, as each name says."""
+        from deepspeed_tpu.serving.api import CHUNK_FIRST_BY
+
+        spec = (dict(speculative={"mode": "ngram", "num_draft_tokens": 2})
+                if name == "drafter" else {})
+        srv = never_ahead(serving(tiny_engine, mixed=True,
+                                  prefix_cache=False, **spec))
+        srv.spec_suspended = True
+        log = watch_mixed(srv)
+        try:
+            if name != "no_rows":
+                a = srv.submit(np.arange(3, 18, dtype=np.int32),
+                               max_new_tokens=40)
+                drive_on_this_thread(srv, iterations=1)
+                assert a.state == "decode" and a._req.length == 16
+            prompt = np.arange(40 if name in ("pages", "no_rows", "drafter")
+                               else 9, dtype=np.int32)
+            srv.submit(prompt, max_new_tokens=4)
+            ids = []
+            if name == "pages":
+                ids = _grab_free_pages(srv, leave=1)    # the chunk's own
+            elif name == "more_chunks":
+                srv.prefill_chunks_per_iter = 2
+            elif name == "drafter":
+                srv.spec_suspended = False
+            mark = len(decode_spans())
+            if name == "step_mode":
+                srv.step()
+            else:
+                srv._iterate(defer=True)
+            srv.alloc.free(ids)
+            assert srv._chunk_first == name and name in CHUNK_FIRST_BY
+            assert log == [] and srv._mix is None
+            assert not any(s.get("mixed") for s in decode_spans()[mark:])
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("name", ["dropped", "more_chunks", "pages",
+                                      "cow", "ends"])
+    def test_a_rule_that_holds_lands_the_mixed_step_with_nothing_ahead(
+            self, tiny_engine, obs_session, name):
+        """With a mixed step in flight, the names of ``CHUNK_HELD_BY`` for
+        its prompt's next chunk and those of ``HELD_BY`` for its rows: the
+        step is fetched with that name and nothing enqueued ahead of the
+        fetch, and the iteration goes on as a settled engine's."""
+        from deepspeed_tpu.serving.api import CHUNK_HELD_BY, HELD_BY
+
+        clock = FakeClock()
+        srv = serving(tiny_engine, clock=clock, mixed=True)
+        log = watch_mixed(srv)
+        try:
+            a, b, c = long_prompt_beside_rows(
+                srv, n=70, max_new_tokens=3,
+                **({"deadline_s": 50.0} if name == "dropped" else {}))
+            # the rows' step in flight is landed (``queued``), then the
+            # prompt's first chunk rides a mixed step from a settled engine
+            drive_on_this_thread(srv, iterations=2)
+            flight = srv._flight
+            assert flight is not None and flight.chunk is not None
+            assert log == [(0, 16)] and c._req.prefill_pos == 0
+            undo = None
+            if name == "dropped":
+                # the request expires at the admission that runs in the
+                # step's shadow: its chunk's progress is applied to nobody
+                clock.advance(100.0)
+            elif name == "more_chunks":
+                srv.prefill_chunks_per_iter = 2
+            elif name == "pages":
+                undo = _grab_free_pages(srv)
+            elif name == "cow":
+                # the chunk ahead would write into a block another holds
+                srv.sched.ensure_blocks(c._req, 32)
+                undo = [c._req.blocks[1]]
+                srv.alloc.incref(undo)
+            elif name == "ends":
+                for h in (a, b):
+                    h._req.max_new_tokens = len(h._req.generated) + 1
+            mark = len(decode_spans())
+            srv._iterate(defer=True)
+            assert srv._flight is not flight
+            spans = decode_spans()[mark:]
+            landed = [s for s in spans if "held_by" in s]
+            assert landed[0]["held_by"] == name
+            assert name in CHUNK_HELD_BY + HELD_BY
+            assert not any(s.get("ahead") for s in spans)
+            if name == "dropped":
+                assert c.state == "deadline_exceeded" and c._req.prefill_pos == 0
+            else:
+                assert c._req.prefill_pos >= 16    # the progress, applied
+            if undo:
+                srv.alloc.free(undo)
+            srv.prefill_chunks_per_iter = 1
+            drive_on_this_thread(srv)
+            assert all(h.done for h in (a, b, c))
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("what", ["queued", "row_freed", "fork"])
+    def test_an_admission_runs_in_the_mixed_step_s_shadow(self, tiny_engine,
+                                                          obs_session, what):
+        """What holds a decode step's successor for the sake of a request at
+        the door (``queued``, ``row_freed``, ``fork``) does not hold a mixed
+        step's: the successor carries the same prompt's next chunk, and
+        nobody's first chunk comes before that prompt's last. The admission
+        itself runs first, with the step in flight, as it does with a chunk
+        in flight."""
+        srv = serving(tiny_engine, mixed=True)
+        log = watch_mixed(srv)
+        try:
+            a, b, c = long_prompt_beside_rows(srv, n=70, max_new_tokens=3)
+            drive_on_this_thread(srv, iterations=2)
+            flight = srv._flight
+            assert flight is not None and flight.chunk is not None
+            late = None
+            if what == "queued":
+                late = srv.submit(np.arange(9, dtype=np.int32),
+                                  max_new_tokens=2)
+            elif what == "row_freed":
+                srv.sched.rows_released += 1    # as a request's end counts it
+            else:
+                srv._pending_forks[10 ** 6] = [a._req]
+            mark = len(decode_spans())
+            srv._iterate(defer=True)
+            srv._pending_forks.pop(10 ** 6, None)
+            step, = [s for s in decode_spans()[mark:] if s.get("rows")]
+            assert (step["ahead"], step["mixed"]) == (1, 1)
+            assert log == [(0, 16), (16, 16)]
+            assert srv._flight is not flight and srv._flight.chunk is not None
+            assert late is None or late.state == "prefill"    # admitted
+            drive_on_this_thread(srv)
+            assert all(h.done for h in (a, b, c))
+            assert late is None or late.done
+        finally:
+            srv.close()
+
+    def test_unpinned_cache_entries_are_room_for_a_mixed_step_ahead(
+            self, tiny_engine):
+        """A pool that has run for a while has no free page: the rows and
+        the chunk of a mixed step ahead take theirs from prefix-cache
+        entries no request holds, as the two programs of the next iteration
+        would have, and nobody is preempted."""
+        srv = serving(tiny_engine, mixed=True)
+        log = watch_mixed(srv)
+        try:
+            srv.submit(np.arange(100, 180, dtype=np.int32), max_new_tokens=2)
+            srv.run()
+            assert srv.prefix.cached_blocks == 5
+            # rows of 13 and 15 tokens: each needs a second page soon
+            a, b = decoding_pair(srv, n=(13, 15))
+            c = srv.submit(np.arange(50, 122, dtype=np.int32),
+                           max_new_tokens=4)
+            drive_on_this_thread(srv, iterations=2)
+            assert srv._flight is not None and srv._flight.chunk is not None
+            spare = _grab_free_pages(srv)
+            cached = srv.prefix.cached_blocks
+            assert srv.alloc.blocks_free == 0 and srv.prefix.can_evict(5)
+            ahead = []
+            for _ in range(3):
+                before = srv._flight
+                srv._iterate(defer=True)
+                ahead.append(srv._flight is not None
+                             and srv._flight.since is not None
+                             and srv._flight is not before)
+            assert ahead == [True] * 3 and srv.sched.preemption_count == 0
+            assert log == [(0, 16), (16, 16), (32, 16), (48, 16)]
+            assert srv.prefix.cached_blocks < cached + 4
+            assert len(a._req.blocks) == 2 and len(b._req.blocks) == 2
+            srv.alloc.free(spare)
+            drive_on_this_thread(srv)
+            assert len(c.tokens) == 4
+        finally:
+            srv.close()
+
+    def test_whoever_needs_a_settled_engine_brings_the_mixed_step_home(
+            self, tiny_engine):
+        srv = serving(tiny_engine, mixed=True)
+        try:
+            a, b, c = long_prompt_beside_rows(srv, n=70)
+            drive_on_this_thread(srv, iterations=2)
+            flight = srv._flight
+            assert flight is not None and flight.chunk is not None
+            na, pos = len(a.tokens), c._req.prefill_pos
+            srv._bring_home()
+            assert srv._flight is None and srv._chunk is None
+            assert not srv._undelivered
+            assert c._req.prefill_pos == pos + 16 and len(a.tokens) > na
+            # step() finds nothing in flight across its end either
+            drive_on_this_thread(srv, iterations=1)
+            assert srv._flight is not None
+            srv.step()
+            assert srv._flight is None and srv._chunk is None
+            srv.run()
+            assert all(h.done for h in (a, b, c))
+        finally:
+            srv.close()
+
+    @pytest.mark.parametrize("fixture", ["tiny_moe_engine", "tiny_kda_engine",
+                                         "tiny_recurrent_engine",
+                                         "tiny_mamba1_engine",
+                                         "tiny_ouro_engine"])
+    def test_a_configuration_that_does_not_mix_builds_no_third_program(
+            self, request, fixture):
+        """Experts, a recurrent, ring or cross kind, a looped stack: the
+        engine holds the two programs, registers the parent's entries with
+        the auditor and runs a long prompt beside rows as it did."""
+        from tools.tpuaudit.registry import clear_registry, get_entry_points
+
+        engine = request.getfixturevalue(fixture)
+        clear_registry()
+        srv = serving(engine, mixed=True, prefix_cache=False)
+        try:
+            assert srv._mixed is None
+            names = {e.name for e in get_entry_points()
+                     if e.name.startswith("serving/")}
+            assert "serving/mixed_step" not in names
+            assert {"serving/decode", "serving/prefill_chunk"} <= names
+            a, b, c = long_prompt_beside_rows(srv, n=40)
+            drive_on_this_thread(srv)
+            assert all(h.done for h in (a, b, c)) and srv._mix is None
+        finally:
+            srv.close()
+
+    def test_an_engine_that_mixes_registers_its_third_program(self,
+                                                              tiny_engine):
+        from tools.tpuaudit.core import run_audit
+        from tools.tpuaudit.registry import get_entry_points
+
+        srv = serving(tiny_engine, mixed=True)
+        try:
+            entry, = get_entry_points(["serving/mixed_step"])
+            assert entry.donate_argnums == (1,)     # the arena
+            program, args, _ = entry.build()
+            assert program is srv._mixed
+            assert args[2].shape == (4, 8 + 7) and args[3].shape == (8 + 16
+                                                                     + 6,)
+            findings = run_audit([entry], publish_metrics=False)
+            assert findings == [], [f"{f.entry}:{f.check}" for f in findings]
+        finally:
+            srv.close()
+
+
+@pytest.fixture(scope="module")
+def tiny_ouro_engine():
+    return init_inference("tiny-ouro", dtype=jnp.float32, max_out_tokens=128)
