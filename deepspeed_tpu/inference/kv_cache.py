@@ -33,6 +33,19 @@ arrays in (8, 128) tiles of the last two dims, so a trailing
 ``(KV_HEADS, 64)`` would be padded to twice its size and could not be read
 a head at a time (``ops/paged_decode_attention.py``).
 
+**A latent arena.** A model whose mixer keeps ONE latent a token in place of
+the keys and values of every head (``Mixer.keeps`` "latent": latent
+attention, MLA) has no ``"k"`` and no ``"v"`` but one arena of pages
+
+    {"latent": (POOLS, NUM_BLOCKS, BLOCK, LANES)}
+
+a pool a sublayer that has the mixer (``models/transformer.latent_pools``),
+a token's latent with the one roped key all heads share behind it
+(``latent_width``: 512 + 64 = 576 values for the published family, 4.5
+lane tiles) and zeros up to whole lane tiles (``latent_page_width``: 640,
+what the chip lays 576 out in anyway). Blocks, tables, scratch and the
+allocator are the pages'.
+
 Block 0 is a reserved scratch block: writes of inactive decode rows and
 prompt-chunk padding land there, so the jit program needs no write-masking
 branch. A host-side free list (``serving/paged_kv.BlockAllocator``) owns
@@ -155,6 +168,24 @@ def _paged_shape(cfg, num_blocks: int, block_size: int):
             cfg.num_kv_heads * cfg.head_dim)
 
 
+# the names an arena of pages can have in the cache's dict: what a block of
+# the allocator is a run of tokens in (copy-on-write copies a block in each)
+PAGE_ARENAS = ("k", "v", "latent")
+
+
+def _page_shapes(cfg, num_blocks: int, block_size: int) -> Dict[str, tuple]:
+    """The arenas of pages by name: ``"k"`` and ``"v"``, or for a model
+    whose mixer keeps a latent a token ``"latent"`` alone."""
+    from ..models.transformer import latent_page_width, latent_pools
+
+    pools = latent_pools(cfg)
+    if pools:
+        return {"latent": (pools, num_blocks, block_size,
+                           latent_page_width(cfg))}
+    shape = _paged_shape(cfg, num_blocks, block_size)
+    return {"k": shape, "v": shape}
+
+
 def _state_shapes(cfg, state_slots: int, dtype,
                   ring: tuple = (0, 0)) -> Dict[str, Any]:
     """The second kind of per-sequence state, beside pages: for each
@@ -203,8 +234,8 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype,
     if num_blocks < 2:
         raise ValueError(f"num_blocks={num_blocks}: need the scratch block "
                          "plus at least one allocatable block")
-    shape = _paged_shape(cfg, num_blocks, block_size)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+    return {**{name: jnp.zeros(shape, dtype) for name, shape
+               in _page_shapes(cfg, num_blocks, block_size).items()},
             **{name: jnp.zeros(sh, dt) for name, (sh, dt)
                in _state_shapes(cfg, state_slots, dtype,
                                 (ring_blocks, block_size)).items()}}
@@ -215,8 +246,8 @@ def paged_cache_memory_bytes(cfg, num_blocks: int, block_size: int,
     """The pages' footprint (what an arena of ``num_blocks`` costs; the
     state pools are sized by rows, not blocks: ``state_pool_memory_bytes``)."""
     itemsize = jnp.dtype(dtype).itemsize
-    return (2 * paged_pools(cfg) * num_blocks * block_size
-            * cfg.num_kv_heads * cfg.head_dim * itemsize)
+    return sum(int(np.prod(shape)) for shape in _page_shapes(
+        cfg, num_blocks, block_size).values()) * itemsize
 
 
 def state_pool_memory_bytes(cfg, state_slots: int, dtype,
@@ -230,9 +261,8 @@ def state_pool_memory_bytes(cfg, state_slots: int, dtype,
 def paged_cache_shape_struct(cfg, num_blocks: int, block_size: int,
                              dtype, state_slots: int = 0,
                              ring_blocks: int = 0) -> Dict[str, Any]:
-    shape = _paged_shape(cfg, num_blocks, block_size)
-    return {"k": jax.ShapeDtypeStruct(shape, dtype),
-            "v": jax.ShapeDtypeStruct(shape, dtype),
+    return {**{name: jax.ShapeDtypeStruct(shape, dtype) for name, shape
+               in _page_shapes(cfg, num_blocks, block_size).items()},
             **{name: jax.ShapeDtypeStruct(sh, dt) for name, (sh, dt)
                in _state_shapes(cfg, state_slots, dtype,
                                 (ring_blocks, block_size)).items()}}

@@ -16,7 +16,12 @@ full layer's pages, all with differential attention; served the same way,
 and only so) and Ouro (``ouro-2.6b``, ``tiny-ouro``: a looped stack, the
 same layers run ``loop_passes`` times with a norm behind each half of a
 layer, the final norm closing every pass and an exit gate reading it; served
-the same way, one pool of pages a (pass, layer))."""
+the same way, one pool of pages a (pass, layer)) and LongCat-Flash
+(``longcat-flash-chat``, ``tiny-longcat-flash``: every layer a DOUBLE layer,
+two latent-attention (MLA) sublayers each with a dense FFN and one
+shortcut-connected FFN of routed and zero-computation experts across both;
+served the same way, one pool of latents a sublayer and no keys or
+values)."""
 
 from __future__ import annotations
 
@@ -123,6 +128,21 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
     # choice-only bias, the chosen weights renormalised and times
     # routed_scaling_factor, relu-squared experts that are not gated and run
     # in a latent, one shared expert on the full width; no biases
+    # LongCat-Flash (meituan-longcat/LongCat-Flash-Chat config.json,
+    # model_type "longcat_flash"; arXiv:2509.01322): every layer is the
+    # "shortcut" double layer (``transformer.SUBLAYERS``): two latent-attention
+    # sublayers (rope on the last rotary_dim values of a query head and on
+    # one key a token, theta 1e7, neighbours paired), each with a dense
+    # SwiGLU FFN, and ONE FFN of experts read at the first sublayer and joined
+    # behind the second: softmax scores over routed AND zero-computation
+    # (identity) experts, a choice-only bias, the chosen weights times
+    # routed_scaling_factor and NOT renormalised; no biases
+    "longcat-flash": dict(norm="rmsnorm", position="rope",
+                          activation="swiglu", tie_embeddings=False,
+                          norm_eps=1e-5, rope_theta=1e7,
+                          layer_pattern=("shortcut",),
+                          moe_score_func="softmax", moe_router_bias=True,
+                          moe_norm_topk_prob=False, moe_routed_scale=6.0),
     "nemotron-h": dict(norm="rmsnorm", position="none", activation="relu2",
                        tie_embeddings=False, norm_eps=1e-5,
                        moe_score_func="sigmoid", moe_router_bias=True,
@@ -289,6 +309,24 @@ _SIZES: Dict[str, Dict[str, Any]] = {
                       num_heads=4, num_kv_heads=4, head_size=16,
                       ffn_hidden_size=96, vocab_size=256, max_seq_len=128,
                       loop_passes=3),
+    # meituan-longcat/LongCat-Flash-Chat config.json (560 B, 18.6-31.3 B
+    # active): 28 double layers; ffn_hidden_size is an EXPERT's width, the
+    # dense FFNs' is dense_ffn_hidden_size (the source's ffn_hidden_size)
+    "longcat-flash-chat": dict(
+        family="longcat-flash", hidden_size=6144, num_layers=28,
+        num_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, rotary_dim=64, v_head_dim=128,
+        dense_ffn_hidden_size=12288, ffn_hidden_size=2048,
+        moe_num_experts=512, moe_zero_experts=256, moe_top_k=12,
+        vocab_size=131072, max_seq_len=131072),
+    # 5 experts a token of 16 routed and 8 zero-computation ones; values
+    # narrower than keys, as in the real one
+    "tiny-longcat-flash": dict(
+        family="longcat-flash", hidden_size=64, num_layers=2, num_heads=4,
+        q_lora_rank=32, kv_lora_rank=24, qk_nope_head_dim=16, rotary_dim=8,
+        v_head_dim=12, dense_ffn_hidden_size=128, ffn_hidden_size=32,
+        moe_num_experts=16, moe_zero_experts=8, moe_top_k=5,
+        vocab_size=256, max_seq_len=128),
     # GShard/Switch-style 8-expert GPT (BASELINE tracked config #4)
     "moe-tiny": dict(family="gpt2", hidden_size=64, num_layers=2, num_heads=4,
                      vocab_size=256, max_seq_len=128, moe_num_experts=8),

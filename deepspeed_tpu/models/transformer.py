@@ -38,14 +38,24 @@ from .core import (EMBED, EXPERT, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ,
 # layers are a mixer alone or an FFN alone. "swa", "full" and "cross" are the
 # softmax mixer in three forms (``AttnForm``: a window's ring of pages, pages
 # of its own, another layer's pages), "mamba1" the selective scan and "gmu" a
-# gate on the value the last "mamba1" layer handed on
+# gate on the value the last "mamba1" layer handed on. "shortcut" is a DOUBLE
+# layer (``SUBLAYERS``, ``_sublayers_forward``): the "mla" mixer and a dense
+# FFN twice over, and the model's expert FFN once across both
 LAYER_KINDS = {"attn": ("attn", True), "kda": ("kda", True),
                "attn_mixer": ("attn", False),
                "mamba2_mixer": ("mamba2", False),
                "ffn": (None, True),
                "mamba1": ("mamba1", True), "swa": ("swa", True),
                "full": ("full", True), "cross": ("cross", True),
-               "gmu": ("gmu", True)}
+               "gmu": ("gmu", True), "shortcut": ("mla", True)}
+# a kind whose layer is SUBLAYERS[kind] sublayers in sequence, norm, mixer,
+# add, norm, dense FFN (``dense_ffn_hidden_size`` wide), add, each with its
+# own weights (a leading axis of that length on the leaves ``ln1``, the
+# mixer's, ``ln2`` and ``dense``) and its own pool in the arena, ``2 * layer
+# + sublayer``; the layer's expert FFN reads the FIRST sublayer's normed FFN
+# input and what it gives joins the stream behind the LAST sublayer's FFN
+# (a shortcut-connected MoE, arXiv:2509.01322). Every other kind: one
+SUBLAYERS = {"shortcut": 2}
 # taps of the "kda" mixer's depthwise convolution over time (the published
 # short_conv_kernel_size of the one family that has the mixer)
 KDA_CONV_TAPS = 4
@@ -160,9 +170,15 @@ class TransformerConfig:
     #   over all experts, in float32
     moe_router_bias: bool = False     # a per-expert bias added to the scores
     #   for the CHOICE of experts only, never to their weights
+    moe_zero_experts: int = 0         # router outputs BEHIND the
+    #   moe_num_experts routed ones that have no matrices: a token that
+    #   chooses one gets its weight times the token itself (zero-computation
+    #   experts of type identity). The router is moe_num_experts +
+    #   moe_zero_experts wide and chooses over all of them
     dense_ffn_hidden_size: Optional[int] = None   # the width of a family's
-    #   leading dense layers (its first_k_dense_replace). No preset has such
-    #   layers yet, so no layer reads it; a configuration states it
+    #   dense FFNs where its ``ffn_hidden_size`` is an expert's: the two of a
+    #   "shortcut" layer read it; a family's leading dense layers (its
+    #   first_k_dense_replace) would, and no preset has such layers yet
     # layers of more than one kind. ``layer_pattern`` names the KIND
     # (``LAYER_KINDS``) of each layer of one period, cycled over the depth
     # (num_layers a multiple of it); a pattern LONGER than the depth is a
@@ -212,6 +228,18 @@ class TransformerConfig:
     # 1 pass is every other model
     loop_passes: int = 1
     loop_exit_threshold: float = 1.0
+    # latent attention (MLA, arXiv:2405.04434; the "mla" mixer,
+    # ``_latent_mixer``): queries through a pair of rank ``q_lora_rank``,
+    # heads of ``qk_nope_head_dim`` + ``rotary_dim`` values of which the LAST
+    # ``rotary_dim`` (the published qk_rope_head_dim) are roped; keys and
+    # values expanded from ONE latent of ``kv_lora_rank`` a token, beside one
+    # roped key of ``rotary_dim`` shared by all heads; values
+    # ``v_head_dim`` wide. The arena keeps the latent and the roped key, no
+    # key or value of any head (``Mixer.keeps`` "latent")
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    v_head_dim: int = 0
     a8_decode: bool = False           # W8A8: decode-shaped int8 weight sites
     #   quantize the activation row too and ride the MXU's s8xs8 path
     #   (set by InferenceEngine from InferenceConfig.quantize_activations;
@@ -246,7 +274,8 @@ class TransformerConfig:
             assert sum(1 for m in mixers if m and MIXERS[m].state) <= 1, \
                 "the state pools hold one kind of recurrent state"
             records = [MIXERS[m] for m in mixers if m]
-            if any(r.keeps != "pages" and not r.state for r in records):
+            if any(r.keeps not in ("pages", "latent") and not r.state
+                   for r in records):
                 # what addresses a row's ring by its state slot, or reads
                 # what another layer made, is built by ``_run_layers`` alone
                 assert self.layer_runs, \
@@ -261,6 +290,16 @@ class TransformerConfig:
         if self.norm_position == "sandwich":
             assert not self.parallel_residual, \
                 "a norm behind each half has no parallel-residual form"
+        if set(self.layer_pattern) & set(SUBLAYERS):
+            assert (self.norm, self.norm_position, self.activation) == (
+                "rmsnorm", "pre", "swiglu") and not self.parallel_residual \
+                and self.moe_num_experts and self.dense_ffn_hidden_size, \
+                "a layer of sublayers is pre-RMSNorm SwiGLU halves under " \
+                "an expert FFN, its dense FFNs dense_ffn_hidden_size wide"
+        if self.moe_zero_experts:
+            assert self.moe_num_experts and not self.moe_latent_size, \
+                "zero-computation experts stand behind routed ones of the " \
+                "model's own width"
         assert self.loop_passes >= 1
         if self.loop_passes > 1:
             # a pass ends in the final norm; pools are a (pass, layer) of
@@ -279,7 +318,8 @@ class TransformerConfig:
         ``parallel/moe.moe_mlp`` computes (no capacity plan, no aux loss)."""
         return bool(self.moe_experts_held or self.moe_router_bias
                     or self.moe_score_func != "softmax"
-                    or self.moe_latent_size or self.moe_routed_scale != 1.0)
+                    or self.moe_latent_size or self.moe_routed_scale != 1.0
+                    or self.moe_zero_experts)
 
     @property
     def shared_ffn_hidden_size(self) -> int:
@@ -407,6 +447,46 @@ def paged_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
     return _layers_keeping(cfg, "pages")
 
 
+def sublayer_leaves(kind: str) -> Tuple[str, ...]:
+    """The leaves of a layer of ``SUBLAYERS`` that its sublayers own (a
+    leading axis of sublayers behind the layers'); every other leaf is the
+    layer's one."""
+    return ("ln1", MIXERS[LAYER_KINDS[kind][0]].name, "ln2", "dense")
+
+
+def latent_pools(cfg: TransformerConfig) -> int:
+    """Pools of the serving arena's ``"latent"``: one a SUBLAYER of each
+    layer whose mixer keeps a latent a token in place of keys and values
+    (0: the model has no such layer, and its arena is ``"k"`` and ``"v"``)."""
+    return sum(SUBLAYERS.get(kind, 1) * len(layers_of_kind(cfg, kind))
+               for kind in set(layer_kinds(cfg))
+               if LAYER_KINDS[kind][0]
+               and MIXERS[LAYER_KINDS[kind][0]].keeps == "latent")
+
+
+def latent_width(cfg: TransformerConfig) -> int:
+    """Values a token keeps in a pool of ``"latent"``: the latent and,
+    behind it, the one roped key all heads share."""
+    return cfg.kv_lora_rank + (cfg.rotary_dim or 0)
+
+
+def latent_page_width(cfg: TransformerConfig) -> int:
+    """Lanes a token takes in a pool of ``"latent"``: ``latent_width`` in
+    whole lane tiles of 128, the rest zeros. The chip lays 576 values out
+    in 640 lanes whatever the array says, and its DMA copies whole tiles
+    alone (Mosaic: "slice shape along dimension 3 must be aligned to tiling
+    (128), but is 576"), so the pad costs the arena nothing it did not
+    cost already and lets the paged walk copy a page."""
+    return -(-latent_width(cfg) // 128) * 128
+
+
+def moe_count_width(cfg: TransformerConfig) -> int:
+    """Entries of an MoE layer's routing counts (``parallel/moe.moe_mlp``):
+    three, and a fourth BEHIND them, the assignments to zero-computation
+    experts, only where the model has such experts."""
+    return 3 + (cfg.moe_zero_experts > 0)
+
+
 def ring_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
     """The window layers, which keep ``attention_window`` keys a row in a
     ring of pages (the pools ``"wk"`` and ``"wv"``)."""
@@ -471,8 +551,10 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
     held = cfg.experts_held
 
     def one_layer(li, kind="attn"):
-        def normal(tag, shape, s=std):
+        def normal(tag, shape, s=std, sub=None):
             k = jax.random.fold_in(jax.random.fold_in(base_key, tag), li)
+            if sub is not None:     # a sublayer's own draw
+                k = jax.random.fold_in(k, sub)
             return (jax.random.normal(k, shape, jnp.float32) * s
                     ).astype(cfg.dtype)
 
@@ -482,7 +564,25 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
 
         mixer, has_ffn = LAYER_KINDS[kind]
         layer: Dict[str, Any] = {}
-        if mixer is not None:
+        if kind in SUBLAYERS:
+            # each sublayer's norms, mixer and dense FFN, stacked on a
+            # leading axis; the expert FFN below is the layer's one
+            Fd = cfg.dense_ffn_hidden_size
+
+            def sublayer(i):
+                draw = partial(normal, sub=i)
+                return {"ln1": {"scale": jnp.ones((H,), cfg.dtype)},
+                        MIXERS[mixer].name: MIXERS[mixer].init(cfg, draw,
+                                                               uniform),
+                        "ln2": {"scale": jnp.ones((H,), cfg.dtype)},
+                        "dense": {"w_gate": draw(80, (H, Fd)),
+                                  "w_up": draw(81, (H, Fd)),
+                                  "w_down": draw(82, (Fd, H), resid_std)}}
+
+            layer = jax.tree.map(
+                lambda *a: jnp.stack(a),
+                *(sublayer(i) for i in range(SUBLAYERS[kind])))
+        elif mixer is not None:
             layer["ln1"] = {"scale": jnp.ones((H,), cfg.dtype)}
             layer[MIXERS[mixer].name] = MIXERS[mixer].init(cfg, normal,
                                                            uniform)
@@ -491,19 +591,27 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                 # WHOLE stack: a constant a layer, not learned
                 layer[MIXERS[mixer].name]["lam_init"] = (
                     0.8 - 0.6 * jnp.exp(-0.3 * li.astype(jnp.float32)))
-        if has_ffn:
+        if has_ffn and kind not in SUBLAYERS:
             layer["ln2"] = {"scale": jnp.ones((H,), cfg.dtype)}
         if not has_ffn:
             pass            # a mixer alone: no FFN of any kind below
         elif E > 0:
-            layer["router"] = normal(4, (H, E))
+            # the router's outputs: the routed experts, then the
+            # zero-computation ones, which have no matrices below
+            layer["router"] = normal(4, (H, E + cfg.moe_zero_experts))
             if cfg.moe_router_bias:
                 # nonzero, or the choice-only bias would go untested; small,
                 # as a trained one is: sigmoid scores of the most probable
                 # experts lie within 0.005 of each other, and a bias of 0.1
                 # would choose the same experts for every token
-                layer["router_bias"] = normal(12, (E,), 0.01).astype(
-                    jnp.float32)
+                # would choose the same experts for every token. Softmax
+                # scores sum to one: beside them, their mean, 1 / outputs
+                # (at 768 outputs the 12th largest score is 0.012, and a
+                # bias of 0.01 gave 1 output in 64 to every token)
+                outputs = E + cfg.moe_zero_experts
+                layer["router_bias"] = normal(
+                    12, (outputs,), 0.01 if cfg.moe_score_func == "sigmoid"
+                    else 1.0 / outputs).astype(jnp.float32)
             if cfg.moe_shared_experts:
                 Fs = cfg.shared_ffn_hidden_size
                 layer["shared"] = {
@@ -619,6 +727,15 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                                   MIXERS[mixer].axes(cfg)}),
                          **({"ln1_post": dict(ln)}
                             if sandwich and mixer is not None else {})}
+        if kind in SUBLAYERS:
+            # the sublayers' leaves carry their axis behind the layers'
+            by_kind[kind]["dense"] = {"w_gate": (LAYERS, EMBED, MLP),
+                                      "w_up": (LAYERS, EMBED, MLP),
+                                      "w_down": (LAYERS, MLP, EMBED)}
+            by_kind[kind].update(jax.tree.map(
+                lambda a: (a[0], None) + a[1:],
+                {name: by_kind[kind][name] for name in sublayer_leaves(kind)},
+                is_leaf=lambda a: isinstance(a, tuple)))
     axes: Dict[str, Any] = {
         "embed": {"tokens": (VOCAB, EMBED)},
         "layers": by_kind if len(kinds) > 1 else by_kind[kinds[0]],
@@ -1212,6 +1329,13 @@ class Step:
     #   "mamba1" layer handed on, for the "gmu" layers of the same step
     shared_layer: Optional[int] = None      # a "cross" layer: the place
     #   among the layers that keep pages of the one whose pool it reads
+    sublayer_stacks: Optional[Dict[str, Any]] = None    # inference, a kind
+    #   of ``SUBLAYERS``: by kind the WHOLE stacks ``(L, sublayers, ...)`` of
+    #   the leaves its sublayers own, kept out of the layer scan's slicing
+    #   like ``expert_banks``: sliced a layer, the compiler copies a layer's
+    #   two sublayers out of the stack before each reads its half (1.3 GB a
+    #   layer at the published widths, PERF.md PR 63); taken at (layer,
+    #   sublayer) each product reads its matrix where it lies
     divide: Optional[int] = None    # a mixed step: the flat run's first
     #   ``divide`` tokens are decode rows, a token a row; the rest a chunk
     chunk: Optional["Step"] = None  # a mixed step: the chunk's operands
@@ -1662,6 +1786,20 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     BSz = cache[kn].shape[2]
     k_rows = k.reshape(B, S, K * D).astype(cache[kn].dtype)
     v_rows = v.reshape(B, S, K * D).astype(cache[vn].dtype)
+    write = _page_writer(step, block_table, BSz, S)
+    ck = write(cache[kn], layer, k_rows)
+    cv = write(cache[vn], layer, v_rows)
+    attn = paged_attention(q, ck, cv, layer, block_table, pos, alibi=alibi,
+                           **read)
+    return attn, {**cache, kn: ck, vn: cv}
+
+
+def _page_writer(step: Step, block_table: jax.Array, BSz: int,
+                 S: int) -> Callable:
+    """``write(arena, pool, rows) -> arena``: the step's S tokens a row,
+    ``rows`` (B, S, W), written into pool ``pool`` of an arena of pages at
+    the step's ``positions`` through ``block_table``. Where they go is
+    reckoned once, here, for every arena the step writes."""
     if step.paged_run is not None and S >= BSz:
         # a RUN of a page or more (a prompt or scoring chunk): whole
         # pages, not rows. The arena's tiling on the chip packs two
@@ -1670,28 +1808,23 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
         # write into tiles the next row touches again: 256 of them cost
         # a chunk program a quarter of its time (PERF.md, PR 49)
         pages = _run_pages(block_table, BSz, S, *step.paged_run)
-        ck = _write_pages(cache[kn], layer, k_rows, *pages)
-        cv = _write_pages(cache[vn], layer, v_rows, *pages)
-    else:
-        # one token a row (decode) or fewer than a page (verify): rows
-        T_view = block_table.shape[1] * BSz
-        wpos = jnp.minimum(pos, T_view - 1)   # clamp pad writes in-range
-        blk = jnp.take_along_axis(block_table, wpos // BSz, axis=1)
-        off = wpos % BSz
-        if step.write_mask is not None:
-            # chunk padding / inactive decode rows write to scratch
-            # block 0
-            blk = jnp.where(step.write_mask, blk, 0)
-            off = jnp.where(step.write_mask, off, 0)
-        # ONE scatter into the 4-D arena, which the layer scan carries:
-        # it updates the carry in place, only the written rows move. An
-        # arena row is one token's K*D lanes
-        # (ops/paged_decode_attention.py)
-        ck = cache[kn].at[layer, blk, off].set(k_rows)
-        cv = cache[vn].at[layer, blk, off].set(v_rows)
-    attn = paged_attention(q, ck, cv, layer, block_table, pos, alibi=alibi,
-                           **read)
-    return attn, {**cache, kn: ck, vn: cv}
+        return lambda arena, pool, rows: _write_pages(arena, pool, rows,
+                                                      *pages)
+    # one token a row (decode) or fewer than a page (verify): rows
+    T_view = block_table.shape[1] * BSz
+    wpos = jnp.minimum(step.positions, T_view - 1)  # pad writes in-range
+    blk = jnp.take_along_axis(block_table, wpos // BSz, axis=1)
+    off = wpos % BSz
+    if step.write_mask is not None:
+        # chunk padding / inactive decode rows write to scratch
+        # block 0
+        blk = jnp.where(step.write_mask, blk, 0)
+        off = jnp.where(step.write_mask, off, 0)
+    # ONE scatter into the 4-D arena, which the layer scan carries:
+    # it updates the carry in place, only the written rows move. An
+    # arena row is one token's K*D lanes
+    # (ops/paged_decode_attention.py)
+    return lambda arena, pool, rows: arena.at[pool, blk, off].set(rows)
 
 
 def _attend_mixed(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
@@ -2223,6 +2356,122 @@ def _gmu_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     return jnp.einsum("bsd,dh->bsh", y, p["w_out"]), step.cache
 
 
+# epsilon of the two norms inside the "mla" mixer: their class's default
+# in the published code, whatever the model's ``norm_eps``
+LATENT_NORM_EPS = 1e-6
+
+
+def _latent_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
+    H, N = cfg.hidden_size, cfg.num_heads
+    Rq, R = cfg.q_lora_rank, cfg.kv_lora_rank
+    Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.rotary_dim, cfg.v_head_dim
+    return {
+        "wq_a": normal(90, (H, Rq)), "q_norm": jnp.ones((Rq,), cfg.dtype),
+        "wq_b": normal(91, (Rq, N * (Dn + Dr))),
+        "wkv_a": normal(92, (H, R + Dr)), "kv_norm": jnp.ones((R,), cfg.dtype),
+        # the published kv_b_proj in two, each a head at a time as the
+        # absorbed read multiplies by it: a head's keys (Dn, R), q side, and
+        # its values (R, Dv), output side
+        "wk_b": normal(93, (N, Dn, R)), "wv_b": normal(94, (N, R, Dv)),
+        "wo": normal(95, (N * Dv, H), _resid_std(cfg)),
+    }
+
+
+def _latent_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    return {"wq_a": (LAYERS, EMBED, None), "q_norm": (LAYERS, None),
+            "wq_b": (LAYERS, None, HEADS), "wkv_a": (LAYERS, EMBED, None),
+            "kv_norm": (LAYERS, None), "wk_b": (LAYERS, HEADS, None, None),
+            "wv_b": (LAYERS, HEADS, None, None), "wo": (LAYERS, HEADS, EMBED)}
+
+
+def _rope_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """The rotary embedding over the pairs of NEIGHBOURS (2j, 2j + 1) of
+    ``x`` (B, S, n, D), as the latent family publishes it; the result lies
+    with every pair's first values in front of the second ones, for queries
+    and keys alike, so their products are the published ones."""
+    return apply_rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
+                      cos, sin)
+
+
+def _latent_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+                  step: Step
+                  ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """The "mla" mixer of a layer: multi-head latent attention over the
+    normed input ``h`` (B, S, H) -> (its contribution to the residual, new
+    cache).
+
+    ``cq = RMSNorm(h W_qa)``; ``q = (cq W_qb) * sqrt(H / q_lora_rank)`` a
+    head, its first ``qk_nope_head_dim`` values as they are and its last
+    ``rotary_dim`` roped. ``[ckv | kr] = h W_kva``; ``c = RMSNorm(ckv) *
+    sqrt(H / kv_lora_rank)``; ``k_rope = rope(kr)``, ONE a token for all
+    heads. A head's keys are ``[c W_kb_h | k_rope]`` and its values ``c
+    W_vb_h``; scores times ``(qk_nope_head_dim + rotary_dim) ** -0.5``,
+    causal softmax, ``out = concat_h(p v_h) W_o``. Both inner norms take
+    ``LATENT_NORM_EPS``; no bias anywhere.
+
+    What a token KEEPS is ``[c | k_rope]``, ``latent_width`` values (and
+    zeros up to ``latent_page_width`` lanes) in pool
+    ``step.pool_index`` of the arena's ``"latent"``; the read over the
+    pages is ``ops/paged_decode_attention.latent_paged_attention``'s, which
+    never makes a cached token's keys or values for one query a row. With
+    no cache the sequence attends to itself through the expanded keys and
+    values; the dense cache (``inference/engine.py``) has no latent entry
+    and is refused."""
+    from ..ops.paged_decode_attention import latent_paged_attention
+
+    f32 = jnp.float32
+    B, S, H = h.shape
+    N, Rq, R = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+    Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.rotary_dim, cfg.v_head_dim
+    scale = (Dn + Dr) ** -0.5
+
+    def rms(x, g, by=1.0):      # in float32, the scale with the norm's
+        x32 = x.astype(f32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * lax.rsqrt(var + LATENT_NORM_EPS) * g.astype(f32)
+                * by).astype(x.dtype)
+
+    cq = rms(jnp.einsum("bsh,hr->bsr", h, p["wq_a"]), p["q_norm"])
+    q = jnp.einsum("bsr,rd->bsd", cq, p["wq_b"])
+    kv = jnp.einsum("bsh,hr->bsr", h, p["wkv_a"])
+    if step.cache is not None:
+        q, kv = lax.optimization_barrier((q, kv))   # see ``_qkv_heads``
+    q = (q.reshape(B, S, N, Dn + Dr).astype(f32) * (H / Rq) ** 0.5
+         ).astype(h.dtype)
+    c = rms(kv[..., :R], p["kv_norm"], (H / R) ** 0.5)
+    cos, sin = rope_table(step.positions, Dr, cfg.rope_theta)
+    q_nope, q_rope = q[..., :Dn], _rope_pairs(q[..., Dn:], cos, sin)
+    k_rope = _rope_pairs(kv[..., None, R:], cos, sin)[:, :, 0]     # (B, S, Dr)
+
+    if step.cache is None:
+        k_nope = jnp.einsum("btr,ndr->btnd", c, p["wk_b"])
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, :, None], (B, S, N, Dr))], axis=-1)
+        v = jnp.einsum("btr,nrv->btnv", c, p["wv_b"])
+        attn = dot_product_attention(
+            jnp.concatenate([q_nope, q_rope], axis=-1), k, v, step.mask,
+            causal=cfg.causal, scale=scale)
+        new_cache = None
+    elif step.block_table is None:
+        raise NotImplementedError(
+            "a latent-attention layer runs without a cache or over the "
+            "serving layer's paged arena, which keeps a pool of latents: "
+            "the dense cache (inference/engine.py) keeps keys and values "
+            "of every head and has no latent entry")
+    else:
+        arena = step.cache["latent"]
+        pad = jnp.zeros((B, S, arena.shape[-1] - R - Dr), c.dtype)
+        rows = jnp.concatenate([c, k_rope, pad], axis=-1).astype(arena.dtype)
+        arena = _page_writer(step, step.block_table, arena.shape[2], S)(
+            arena, step.pool_index, rows)
+        attn = latent_paged_attention(
+            q_nope, q_rope, p["wk_b"], p["wv_b"], arena, step.pool_index,
+            step.block_table, step.positions, scale)
+        new_cache = {**step.cache, "latent": arena}
+    out = jnp.einsum("bsd,dh->bsh", attn.reshape(B, S, N * Dv), p["wo"])
+    return out, new_cache
+
+
 @dataclasses.dataclass(frozen=True)
 class Mixer:
     """What a mixer IS: every function that must tell one mixer from
@@ -2237,7 +2486,9 @@ class Mixer:
     rows_count: Optional[str] = None    # the span count of states advanced
     keeps: Optional[str] = None     # "pages": a pool of its own in the
     #   arena's "k" and "v"; "ring": a window of pages a row in "wk" and
-    #   "wv"; None: no keys of its own (a state, or what another layer made)
+    #   "wv"; "latent": a pool of its own in the arena's "latent", which
+    #   then has no "k" and no "v"; None: no keys of its own (a state, or
+    #   what another layer made)
     hands_on: bool = False          # its third result rides the step's
     #   carry as ``Step.memory``
 
@@ -2265,6 +2516,8 @@ MIXERS: Dict[str, Mixer] = {
     "full": _form(AttnForm(), "pages"),
     "cross": _form(AttnForm(cross=True), None),
     "gmu": Mixer("gmu", _gmu_init, _gmu_axes, _gmu_mixer),
+    "mla": Mixer("mla", _latent_init, _latent_axes, _latent_mixer,
+                 keeps="latent"),
 }
 
 
@@ -2321,6 +2574,58 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     return (x, h, attn_out, new_cache, *memory)
 
 
+def _expert_ffn(cfg: TransformerConfig, h: jax.Array, layer: Dict[str, Any],
+                step: Step, kind: str):
+    """A layer's FFN of experts over the normed input ``h`` -> ``(out, aux,
+    *counts)``: the routed experts (``parallel/moe.moe_mlp``; ``counts`` with
+    ``step.moe_counts``), the shared expert and the PR-MoE residual where
+    the model has them."""
+    from ..parallel.moe import moe_mlp
+
+    # cache mode == inference: moe_mlp then routes exactly (no capacity
+    # drops, no RTS) and keeps padding rows out of the routing
+    # (a router the capacity plans cannot express routes so always)
+    infer = step.cache is not None or cfg.moe_dropless_only
+    rts_rng = (_activation_derived_key(h, 0)
+               if (cfg.moe_use_rts and not infer) else None)
+    banks = (None if step.expert_banks is None
+             else step.expert_banks[kind])
+    mlp_out, aux, *counts = moe_mlp(
+        h, layer["router"], layer["mlp"] if banks is None else banks,
+        cfg.activation,
+        expert_layer=None if banks is None else step.layer_index,
+        top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
+        min_capacity=cfg.moe_min_capacity,
+        drop_tokens=cfg.moe_drop_tokens, use_rts=cfg.moe_use_rts,
+        rng=rts_rng, dispatch_impl=cfg.moe_dispatch,
+        norm_topk_prob=cfg.moe_norm_topk_prob, infer=infer,
+        row_mask=step.write_mask, with_counts=step.moe_counts,
+        score_func=cfg.moe_score_func,
+        choice_bias=layer.get("router_bias"),
+        latent=layer.get("latent"), routed_scale=cfg.moe_routed_scale,
+        zero_experts=cfg.moe_zero_experts)
+    if "shared" in layer:
+        # the shared expert: one dense FFN beside the routed ones, on
+        # the full width, added to every token unweighted
+        mlp_out = mlp_out + (_swiglu if "w_gate" in layer["shared"]
+                             else _plain_ffn)(cfg, h, layer["shared"])
+    if cfg.moe_use_residual:
+        # PR-MoE (reference moe/layer.py:120): dense MLP in parallel,
+        # mixed by a learned softmax coefficient over (moe, dense)
+        inner = jnp.einsum("bsh,hf->bsf", h, layer["res_mlp"]["w_up"]) \
+            + layer["res_mlp"]["b_up"]
+        inner = jax.nn.gelu(inner, approximate=True)
+        res_out = jnp.einsum("bsf,fh->bsh", inner,
+                             layer["res_mlp"]["w_down"]) \
+            + layer["res_mlp"]["b_down"]
+        coef = jax.nn.softmax(
+            (jnp.einsum("bsh,hc->bsc", h, layer["res_coef"]["w"])
+             + layer["res_coef"]["b"]).astype(jnp.float32), axis=-1
+        ).astype(h.dtype)
+        mlp_out = mlp_out * coef[..., 0:1] + res_out * coef[..., 1:2]
+    return (mlp_out, aux, *counts)
+
+
 def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                    step: Step, kind: str = "attn"):
     """One decoder block: ``x + mixer(norm(x))``, then the FFN. ``kind``
@@ -2333,6 +2638,8 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     zeros for a layer that has no FFN); with ``step.memory`` (a stack with
     gated memory units) a last one, the memory as it stands below this
     layer."""
+    if kind in SUBLAYERS:
+        return _sublayers_forward(cfg, x, layer, step, kind)
     cache = step.cache
     mixer, has_ffn = LAYER_KINDS[kind]
     post_ln = cfg.norm_position == "post"
@@ -2351,56 +2658,15 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                   cfg.norm, cfg.norm_eps)
     if not has_ffn:
         return (x, new_cache, jnp.float32(0.0), *(
-            [jnp.zeros((3,), jnp.int32)] if step.moe_counts else []),
-            *memory)
+            [jnp.zeros((moe_count_width(cfg),), jnp.int32)]
+            if step.moe_counts else []), *memory)
     if cfg.act_quant_bits and cache is None:
         from ..compression.compress import fake_quant_activation
 
         h = fake_quant_activation(h, cfg.act_quant_bits)   # MLP input
     aux, counts = jnp.float32(0.0), []
     if cfg.moe_num_experts > 0:
-        from ..parallel.moe import moe_mlp
-
-        # cache mode == inference: moe_mlp then routes exactly (no capacity
-        # drops, no RTS) and keeps padding rows out of the routing
-        # (a router the capacity plans cannot express routes so always)
-        infer = cache is not None or cfg.moe_dropless_only
-        rts_rng = (_activation_derived_key(h, 0)
-                   if (cfg.moe_use_rts and not infer) else None)
-        banks = (None if step.expert_banks is None
-                 else step.expert_banks[kind])
-        mlp_out, aux, *counts = moe_mlp(
-            h, layer["router"], layer["mlp"] if banks is None else banks,
-            cfg.activation,
-            expert_layer=None if banks is None else step.layer_index,
-            top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
-            min_capacity=cfg.moe_min_capacity,
-            drop_tokens=cfg.moe_drop_tokens, use_rts=cfg.moe_use_rts,
-            rng=rts_rng, dispatch_impl=cfg.moe_dispatch,
-            norm_topk_prob=cfg.moe_norm_topk_prob, infer=infer,
-            row_mask=step.write_mask, with_counts=step.moe_counts,
-            score_func=cfg.moe_score_func,
-            choice_bias=layer.get("router_bias"),
-            latent=layer.get("latent"), routed_scale=cfg.moe_routed_scale)
-        if "shared" in layer:
-            # the shared expert: one dense FFN beside the routed ones, on
-            # the full width, added to every token unweighted
-            mlp_out = mlp_out + (_swiglu if "w_gate" in layer["shared"]
-                                 else _plain_ffn)(cfg, h, layer["shared"])
-        if cfg.moe_use_residual:
-            # PR-MoE (reference moe/layer.py:120): dense MLP in parallel,
-            # mixed by a learned softmax coefficient over (moe, dense)
-            inner = jnp.einsum("bsh,hf->bsf", h, layer["res_mlp"]["w_up"]) \
-                + layer["res_mlp"]["b_up"]
-            inner = jax.nn.gelu(inner, approximate=True)
-            res_out = jnp.einsum("bsf,fh->bsh", inner,
-                                 layer["res_mlp"]["w_down"]) \
-                + layer["res_mlp"]["b_down"]
-            coef = jax.nn.softmax(
-                (jnp.einsum("bsh,hc->bsc", h, layer["res_coef"]["w"])
-                 + layer["res_coef"]["b"]).astype(jnp.float32), axis=-1
-            ).astype(h.dtype)
-            mlp_out = mlp_out * coef[..., 0:1] + res_out * coef[..., 1:2]
+        mlp_out, aux, *counts = _expert_ffn(cfg, h, layer, step, kind)
     elif cfg.activation == "swiglu":
         mlp_out = _swiglu(cfg, h, layer["mlp"])
     else:
@@ -2429,6 +2695,39 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     else:
         x = x + mlp_out
     return (x, new_cache, aux, *counts, *memory)
+
+
+def _sublayers_forward(cfg: TransformerConfig, x: jax.Array,
+                       layer: Dict[str, Any], step: Step, kind: str):
+    """A layer of ``SUBLAYERS[kind]`` sublayers (the published double layer
+    of a shortcut-connected MoE) -> ``_layer_forward``'s results:
+
+        for i in sublayers:
+            a = x + mixer_i(norm(x; ln1_i));  h = norm(a; ln2_i)
+            if i == 0: s = experts(h)             # read here ...
+            x = a + swiglu_i(h)                   # dense_ffn_hidden_size wide
+        x = x + s                                 # ... joined here
+
+    The leaves ``ln1``, the mixer's, ``ln2`` and ``dense`` carry the
+    sublayers on their leading axis; sublayer i writes and reads pool
+    ``SUBLAYERS[kind] * step.layer_index + i`` (``Step.pool_index``). The
+    expert FFN (router, bias, bank) is the layer's one."""
+    mixer, n = LAYER_KINDS[kind][0], SUBLAYERS[kind]
+    if step.sublayer_stacks is None:
+        halves, at_layer = {name: layer[name]
+                            for name in sublayer_leaves(kind)}, ()
+    else:   # the whole stacks, this layer's taken where it lies
+        halves, at_layer = step.sublayer_stacks[kind], (step.layer_index,)
+    cache = step.cache
+    for i in range(n):
+        sub = jax.tree.map(lambda a: a[at_layer + (i,)], halves)
+        at = dataclasses.replace(step, cache=cache, pool_index=(
+            None if step.layer_index is None else n * step.layer_index + i))
+        x, h, _, cache = _mixer_half(cfg, x, sub, mixer, True, at)
+        if i == 0:
+            shortcut, aux, *counts = _expert_ffn(cfg, h, layer, step, kind)
+        x = x + _swiglu(cfg, h, sub["dense"])
+    return (x + shortcut, cache, aux, *counts)
 
 
 def forward(params: Dict[str, Any], input_ids: jax.Array,
@@ -2632,6 +2931,15 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         banks = {kind: tree.get("mlp") for kind, tree in stacks.items()}
         stacks = {kind: {k: v for k, v in tree.items() if k != "mlp"}
                   for kind, tree in stacks.items()}
+    # and so do the leaves that the sublayers of a double layer own
+    # (``Step.sublayer_stacks``)
+    halves = None
+    if cache is not None and set(stacks) & set(SUBLAYERS):
+        halves = {kind: {k: tree[k] for k in sublayer_leaves(kind)}
+                  for kind, tree in stacks.items() if kind in SUBLAYERS}
+        stacks = {kind: {k: v for k, v in tree.items()
+                         if k not in halves.get(kind, ())}
+                  for kind, tree in stacks.items()}
     periods = {kind: tree if per[kind] == 1 else jax.tree.map(
         lambda a, n=per[kind]: a.reshape((L // P, n) + a.shape[1:]), tree)
         for kind, tree in stacks.items()}
@@ -2641,7 +2949,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                 block_table=block_table, write_mask=paged_write_mask,
                 state_slots=state_slots, paged_run=paged_run,
                 static_prefill=static_prefill, key_positions=key_positions,
-                moe_counts=moe_counts, expert_banks=banks, divide=divide,
+                moe_counts=moe_counts, expert_banks=banks,
+                sublayer_stacks=halves, divide=divide,
                 chunk=None if divide is None else Step(**mixed_chunk))
 
     def run_period(layers, pidx, one_layer, h, *acc):
@@ -2777,7 +3086,8 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             return lax.scan(
                 paged_block,
                 (x, jnp.float32(0.0), arena,
-                 *([jnp.zeros((3,), jnp.int32)] if moe_counts else [])),
+                 *([jnp.zeros((moe_count_width(cfg),), jnp.int32)]
+                   if moe_counts else [])),
                 (layers, jnp.arange(L // P, dtype=jnp.int32)))[0]
 
         if looped:

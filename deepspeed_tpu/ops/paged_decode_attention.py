@@ -746,3 +746,98 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
     return paged_prefill_attention(q, k_arena, v_arena, layer, block_table,
                                    start - first * BS, ends, alibi=alibi,
                                    scale=scale, window=window)
+
+
+# ---------------------------------------------------------------------------
+# a latent pool: one latent and one roped key a token, no head's keys or values
+# ---------------------------------------------------------------------------
+
+# the absorbed walk's name in a trace, whatever implements it
+LATENT_DECODE = "latent_decode_attention"
+# heads an expanded read attends at a time: their scores, (heads, C, T) in
+# float32, are what bounds a chunk's memory
+_EXPANDED_HEADS = 8
+
+
+def latent_absorbed_attention(q_nope: jax.Array, q_rope: jax.Array,
+                              wk_b: jax.Array, wv_b: jax.Array,
+                              arena: jax.Array, layer,
+                              block_table: jax.Array, positions: jax.Array,
+                              scale: float) -> jax.Array:
+    """The read of a latent pool with the expansion ABSORBED into the query
+    and the output: ``qt_h = q_nope_h W_kb_h`` (a latent wide), score ``qt_h
+    . c + q_rope_h . k_rope``, ``o_h = (sum p c) W_vb_h``. That is
+    multi-query attention with ONE key-value head as wide as the page, whose
+    values are the page's first ``R`` lanes: ``paged_attention`` takes the
+    pool as keys AND as values (the decode walk under the name
+    ``LATENT_DECODE``, which so copies a page twice) and the rope lanes of
+    its result are dropped. No key or value of any head is ever made."""
+    R = wk_b.shape[-1]
+    unused = arena.shape[-1] - R - q_rope.shape[-1]     # a page's pad lanes
+    q = jnp.concatenate(
+        [jnp.einsum("bsnd,ndr->bsnr", q_nope, wk_b), q_rope,
+         jnp.zeros(q_rope.shape[:-1] + (unused,), q_rope.dtype)], axis=-1)
+    mixed = paged_attention(q, arena, arena, layer, block_table, positions,
+                            scale=scale, name=LATENT_DECODE)[..., :R]
+    return jnp.einsum("bsnr,nrv->bsnv", mixed, wv_b)
+
+
+def latent_expanded_attention(q_nope: jax.Array, q_rope: jax.Array,
+                              wk_b: jax.Array, wv_b: jax.Array,
+                              arena: jax.Array, layer,
+                              block_table: jax.Array, positions: jax.Array,
+                              scale: float) -> jax.Array:
+    """The read of a latent pool with every resident token's keys and
+    values EXPANDED: the row's pages gathered through its table, ``k_h = [c
+    W_kb_h | k_rope]`` and ``v_h = c W_vb_h`` made for ``_EXPANDED_HEADS``
+    heads at a time, and plain causal attention over true positions (a pad
+    query, position -1, gives zeros). The work follows the table's length,
+    not the tokens in it: a chunk's read, where the absorbed form would
+    carry the chunk's queries a latent wide."""
+    B, S, N, _ = q_nope.shape
+    R, Dv = wk_b.shape[-1], wv_b.shape[-1]
+    T = block_table.shape[1] * arena.shape[2]
+    rows = arena[layer, block_table].reshape(B, T, arena.shape[-1])
+    col = jnp.arange(T, dtype=jnp.int32)
+    # nothing of a page past the row's last position is read: 0 x NaN is NaN
+    rows = jnp.where((col[None] <= jnp.max(positions, axis=1)[:, None])
+                     [..., None], rows, 0)
+    c, k_rope = rows[..., :R], rows[..., R:R + q_rope.shape[-1]]
+    keep = (col[None, None] <= positions[:, :, None])[:, None]  # (B,1,S,T)
+    G = min(N, _EXPANDED_HEADS)
+    while N % G:
+        G -= 1
+
+    def heads(g):
+        def mine(a, axis):
+            return jax.lax.dynamic_slice_in_dim(a, g * G, G, axis)
+
+        k_nope = jnp.einsum("btr,ndr->btnd", c, mine(wk_b, 0))
+        v = jnp.einsum("btr,nrv->btnv", c, mine(wv_b, 0))
+        s = (jnp.einsum("bsnd,btnd->bnst", mine(q_nope, 2), k_nope)
+             + jnp.einsum("bsnd,btd->bnst", mine(q_rope, 2), k_rope)
+             ).astype(jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(keep, s, NEG_INF), axis=-1)
+        return jnp.einsum("bnst,btnv->bsnv", p.astype(v.dtype), v)
+
+    out = jax.lax.map(heads, jnp.arange(N // G, dtype=jnp.int32))
+    out = jnp.moveaxis(out, 0, 2).reshape(B, S, N, Dv)
+    return jnp.where((positions < 0)[:, :, None, None], 0, out)
+
+
+def latent_paged_attention(q_nope: jax.Array, q_rope: jax.Array,
+                           wk_b: jax.Array, wv_b: jax.Array,
+                           arena: jax.Array, layer, block_table: jax.Array,
+                           positions: jax.Array, scale: float) -> jax.Array:
+    """A latent-attention layer's paged read, after its write: ``q_nope``
+    (B, S, N, Dn) and the roped ``q_rope`` (B, S, N, Dr) at absolute
+    ``positions`` (B, S) against pool ``layer`` of ``arena`` (POOLS,
+    NUM_BLOCKS, BLOCK, LANES), a token's latent (R), the roped key all heads
+    share (Dr) and zeros up to whole lane tiles, through ``block_table``; ``wk_b`` (N, Dn, R) and ``wv_b`` (N, R,
+    Dv) expand a latent into a head's keys and values. Returns (B, S, N,
+    Dv). THE place the form of that read is chosen: one query a row reads
+    absorbed, a chunk of queries expanded; both compute the same numbers."""
+    read = (latent_absorbed_attention if q_nope.shape[1] == 1
+            else latent_expanded_attention)
+    return read(q_nope, q_rope, wk_b, wv_b, arena, layer, block_table,
+                positions, scale)

@@ -291,7 +291,7 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
                      normalize: bool, real: Optional[jax.Array],
                      layer: Optional[jax.Array] = None,
                      choice: Optional[jax.Array] = None,
-                     routed_scale: float = 1.0
+                     routed_scale: float = 1.0, zero_experts: int = 0
                      ) -> Tuple[jax.Array, jax.Array]:
     """Dropless expert compute over the ASSIGNED rows only: the ``T x k``
     assignments are laid out sorted by expert, each expert's group padded to
@@ -308,8 +308,14 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     hold experts: an assignment to an expert past the held ones is dropped
     here like a row that is not ``real`` (``moe_mlp``: a chip's share).
     ``routed_scale`` multiplies the chosen weights after their
-    renormalisation. Returns ``(out (T, H), counts)`` with ``counts`` int32
-    ``[assignments, experts with a row, rows of the largest expert]``."""
+    renormalisation. The LAST ``zero_experts`` of the router's outputs are
+    zero-computation experts of type identity: no matrices, no row and no
+    tile; a token that chose one gets its weight (scaled like the others)
+    times the token itself, computed here in full whatever share of the
+    routed experts is held. Returns ``(out (T, H), counts)`` with ``counts``
+    int32 ``[assignments, experts with a row, rows of the largest expert]``
+    over the HELD experts and, only with ``zero_experts``, a fourth behind
+    them: the assignments to zero-computation experts."""
     from ..ops.moe_grouped_matmul import (group_layout, moe_grouped_matmul,
                                           reference_grouped_matmul,
                                           tile_rows)
@@ -361,8 +367,19 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     # rows past the used tiles were never written: select, do not multiply
     picked = jnp.where(routed[:, None], y[jnp.minimum(slot, rows - 1)], 0)
     out = (picked.astype(f32).reshape(T, k, H)
-           * weight[..., None]).sum(axis=1).astype(xt.dtype)
-    return out, _routing_counts(sizes)
+           * weight[..., None]).sum(axis=1)
+    if zero_experts:
+        is_zero = idx >= gates.shape[-1] - zero_experts           # (T, k)
+        if real is not None:
+            is_zero = is_zero & real[:, None]
+        out = out + (jnp.where(is_zero, weight, 0.0).sum(axis=1)[:, None]
+                     * xt.astype(f32))
+    out = out.astype(xt.dtype)
+    counts = _routing_counts(sizes)
+    if zero_experts:
+        counts = jnp.concatenate(
+            [counts, is_zero.sum(dtype=jnp.int32)[None]])
+    return out, counts
 
 
 def _routing_counts(sizes: jax.Array) -> jax.Array:
@@ -383,7 +400,7 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
             score_func: str = "softmax",
             choice_bias: Optional[jax.Array] = None,
             latent: Optional[Dict[str, jax.Array]] = None,
-            routed_scale: float = 1.0):
+            routed_scale: float = 1.0, zero_experts: int = 0):
     """MoE FFN for one layer. x (B, S, H); router_w (H, E); experts:
     w_up/w_down (+w_gate for swiglu) with leading expert dim E - or, with
     ``expert_layer`` (int32 scalar), the model's whole stacks with a layer
@@ -407,7 +424,9 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
     ``x W_in``, and the weighted sum of what the chosen and held experts
     give goes back through ``W_out``, so an absent expert's part is left
     out BEFORE that projection; ``routed_scale``, a factor on the chosen
-    weights after their renormalisation.
+    weights after their renormalisation; ``zero_experts``, the router's
+    LAST outputs that are zero-computation experts (identity: weight times
+    the token, ``_grouped_experts``), counted beside the three counts.
 
     **A chip's share of the experts.** Where the stacks hold FEWER experts
     than the router has outputs, they are the router's first ones, held here
@@ -467,16 +486,17 @@ def moe_mlp(x: jax.Array, router_w: jax.Array, experts: Dict[str, jax.Array],
             None if row_mask is None else row_mask.reshape(T), expert_layer,
             choice=(None if choice_bias is None
                     else scores + choice_bias.astype(jnp.float32)),
-            routed_scale=routed_scale)
+            routed_scale=routed_scale, zero_experts=zero_experts)
         if latent is not None:
             out = out @ latent["w_out"]
         out, aux = out.reshape(B, S, H), jnp.float32(0.0)
         return (out, aux, counts) if with_counts else (out, aux)
     if (held != E or score_func != "softmax" or choice_bias is not None
-            or latent is not None or routed_scale != 1.0):
+            or latent is not None or routed_scale != 1.0 or zero_experts):
         raise NotImplementedError(
             "a share of the experts, sigmoid scores, a choice-only bias, "
-            "experts in a latent and a scale on the routed sum "
+            "experts in a latent, a scale on the routed sum and "
+            "zero-computation experts "
             "exist on the dropless inference path only "
             "(parallel/moe._grouped_experts): the capacity plans of "
             "training, and of experts sharded over chips, have none of them")

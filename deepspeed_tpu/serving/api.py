@@ -247,12 +247,18 @@ class ServingEngine:
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..inference.kv_cache import ring_blocks
-        from ..models.transformer import (MIXERS, ffn_layers,
-                                          recurrent_layers, ring_layers,
-                                          tail_runs)
+        from ..models.transformer import (MIXERS, ffn_layers, latent_pools,
+                                          moe_count_width, recurrent_layers,
+                                          ring_layers, tail_runs)
 
         mixer, layers = recurrent_layers(cfg)
         self._recurrent_layers = len(layers)
+        # a model whose mixer keeps a latent a token (``transformer.
+        # latent_pools``) has one arena, ``"latent"``, and no ``"k"`` or
+        # ``"v"``: blocks, the prefix cache, copy-on-write and preemption
+        # are the pages'; what cannot read or move such a pool yet is
+        # refused in ``_no_latent_read``
+        self._latent_pools = latent_pools(cfg)
         # window layers keep their keys in a ring of pages a row, beside the
         # row's state slot and bounded whatever the row's length
         self._window_layers = len(ring_layers(cfg))
@@ -314,6 +320,7 @@ class ServingEngine:
         # chip holds its share of them: ``moe_experts_held``)
         self._moe_experts_total = cfg.moe_num_experts * len(ffn_layers(cfg))
         self._moe_experts_held = cfg.experts_held * len(ffn_layers(cfg))
+        self._moe_choices = cfg.moe_top_k * len(ffn_layers(cfg))   # a token
         moe = self._moe_experts_total > 0
         self._prefill = paged_kv.build_prefill_program(
             cfg, self.config.prefill_chunk, moe_counts=moe)
@@ -331,7 +338,8 @@ class ServingEngine:
         # as the program places its result, so that the first call's
         # signature is every call's
         self._last_tokens = jax.device_put(
-            np.zeros((self.config.max_seqs + (3 if moe else 0),), np.int32),
+            np.zeros((self.config.max_seqs
+                      + (moe_count_width(cfg) if moe else 0),), np.int32),
             NamedSharding(engine.mesh, PartitionSpec()))
         self._cow = paged_kv.build_cow_program()
         # teacher-forced scoring over the same arena (the RLHF second
@@ -377,6 +385,8 @@ class ServingEngine:
         if self._drafter is not None:
             self._no_state_snapshot("speculative decoding (rolling a "
                                     "rejected draft back)")
+            self._no_latent_read("speculative decoding (a verify step: "
+                                 "several queries a row, of many rows)")
             self._verify = paged_kv.build_verify_program(
                 cfg, self.config.speculative.num_draft_tokens + 1)
             # one release point covers finish/cancel/preempt: the drafter
@@ -492,6 +502,20 @@ class ServingEngine:
                 "snapshot of a sequence's recurrent state (the state and "
                 "convolution tail of serving/paged_kv.py's state pools), "
                 "which nothing takes yet; pages alone do not hold it")
+
+    def _no_latent_read(self, what: str) -> None:
+        """THE place that refuses, by name, what a model with a latent arena
+        cannot do yet: its paged read is absorbed at one query a row and
+        expanded for ONE row's chunk (``ops/paged_decode_attention.
+        latent_paged_attention``), and the programs that move pages between
+        engines move ``"k"`` and ``"v"`` (ROADMAP B-m3)."""
+        if self._latent_pools:
+            raise NotImplementedError(
+                f"{what} is not supported for a model with latent attention "
+                "(one pool of latents a sublayer in the arena's 'latent', no "
+                "'k' and no 'v'): the paged read of such a pool is absorbed "
+                "at one query a row and expanded for one row's chunk, and "
+                "the hand-off programs move keys and values")
 
     # -- client API --------------------------------------------------------
     @property
@@ -832,6 +856,8 @@ class ServingEngine:
         the caller still owns ``blocks`` and must free them."""
         self._no_state_snapshot("kv_import (adopting a sequence prefilled "
                                 "on another engine)")
+        self._no_latent_read("kv_import (adopting a sequence prefilled on "
+                             "another engine)")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         with self._lock:
             if (self.sched.in_flight() + self._pending_fork_count() + 1
@@ -1397,15 +1423,20 @@ class ServingEngine:
                      "or more").inc(program=name)
 
     def _program_counts(self, span, fetched: np.ndarray, n: int,
-                    real_rows: int) -> np.ndarray:
+                        real_rows: int, tokens: int) -> np.ndarray:
         """The ``n`` sampled tokens of what an MoE model's program returned
         (``paged_kv._with_moe_counts``); the routing counts behind them go
         onto ``span``: over the real rows of this iteration and summed over
         the layers, the (token, expert) assignments that reached an expert
         held here, the held experts that had a row (of ``moe_experts_held``
         = experts of a layer's stack x layers; ``moe_experts_total`` = the
-        router's outputs x layers, the same number unless this chip holds a
-        share) and the rows of each layer's largest expert. A model with
+        router's ROUTED outputs x layers, the same number unless this chip
+        holds a share) and the rows of each layer's largest expert; and for
+        a model with zero-computation experts ``moe_zero_assignments``, the
+        assignments that chose one (they reach no held expert and are in
+        none of the other counts), beside ``moe_choices``, all the
+        assignments the routers made: the program's real ``tokens`` x expert
+        layers x experts a token. A model with
         recurrent layers also says how many (row, layer) states the program
         advanced: ``real_rows`` x its recurrent layers, as ``recurrent_rows``
         (delta-rule layers) or ``ssm_rows`` (state-space layers)."""
@@ -1415,12 +1446,15 @@ class ServingEngine:
         if not self._moe_experts_total:
             return fetched
         if span.recording:
-            assigned, touched, largest = (int(c) for c in fetched[n:n + 3])
+            assigned, touched, largest, *zero = (int(c) for c in fetched[n:])
             span.annotate(moe_assignments=assigned,
                           moe_experts_touched=touched,
                           moe_experts_total=self._moe_experts_total,
                           moe_experts_held=self._moe_experts_held,
                           moe_max_expert_rows=largest)
+            if zero:    # a model with zero-computation experts alone
+                span.annotate(moe_zero_assignments=zero[0],
+                              moe_choices=tokens * self._moe_choices)
         return fetched[:n]
 
     def _step_prefill(self, first_by: Optional[str] = "step_mode") -> bool:
@@ -1689,7 +1723,8 @@ class ServingEngine:
         n_valid = sent.tokens
         with obs.span("serving/prefill_chunk/apply", category="phase"):
             if tok is not None:
-                tok = self._program_counts(span, tok, 1, real_rows=1)
+                tok = self._program_counts(span, tok, 1, real_rows=1,
+                                           tokens=n_valid)
                 if self._serve_acct is not None:
                     self._serve_acct.note_phase("prefill", t1 - t0)
             span.annotate(tokens=n_valid)   # the chunk ran: a span
@@ -1934,11 +1969,16 @@ class ServingEngine:
         """(rows, None): the rows of the step to enqueue AHEAD of
         ``flight``'s fetch: ``flight``'s rows less those that end there by
         their ``max_new_tokens``, each with the page its next position needs
-        taken from the free list (no eviction, no preemption and no
-        copy-on-write on behalf of a step ahead). Or (None, the name of
+        taken from the free list or from an UNPINNED prefix-cache entry,
+        which no request holds and no program in flight reads (no
+        preemption and no copy-on-write on behalf of a step ahead; until PR
+        63 the free list alone, which a pool that has run for a while does
+        not have: released blocks live on as cache entries, and every step
+        of the latent cell's second generation of requests waited for its
+        predecessor's fetch, 3 ms of host round in 19). Or (None, the name of
         ``HELD_BY`` that says why it must wait for that fetch):
         ``_ahead_held_by``'s, else ``ends`` (every row ends at ``flight``),
-        ``pages`` (the free list is short of what the rows need) or ``cow``
+        ``pages`` (neither gives what the rows need) or ``cow``
         (a row would write into a shared block). The successor of a MIXED
         step carries its prompt's next chunk, so that chunk's rules are
         asked with the rows' (the union of the two): ``dropped`` (the
@@ -1965,8 +2005,7 @@ class ServingEngine:
         need = sum(max(paged_kv.blocks_for_tokens(r.length + 2, bs)
                        - len(r.blocks), 0) for r in rows)
         mixed = flight.chunk is not None
-        if need > self.alloc.blocks_free and not (
-                mixed and self.sched.pages_without_preemption(need)):
+        if not self.sched.pages_without_preemption(need):
             return None, "pages"
         if any(self.sched.cow_block_indices(r, r.length + 1, r.length + 2)
                for r in rows):
@@ -1982,8 +2021,7 @@ class ServingEngine:
         for r in rows:
             # (``ensure_blocks`` evicts unpinned entries where the free list
             # is short, and was shown above to need nobody's row)
-            (self.sched.ensure_blocks if mixed
-             else self.sched.try_extend_blocks)(r, r.length + 2)
+            self.sched.ensure_blocks(r, r.length + 2)
         return rows, None
 
     def _step_decode(self, ahead: Optional[List[Request]] = None) -> bool:
@@ -2221,7 +2259,8 @@ class ServingEngine:
             live = [(r, row) for r, row in sent.rows
                     if r.state == DECODE and r.row == row]
             nxt = self._program_counts(span, nxt, self.config.max_seqs,
-                                       real_rows=len(sent.rows))
+                                       real_rows=len(sent.rows),
+                                       tokens=len(sent.rows))
             if len(live) < len(sent.rows):
                 span.annotate(dropped_rows=len(sent.rows) - len(live))
             if acct is not None:

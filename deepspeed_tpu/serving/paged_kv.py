@@ -62,9 +62,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..inference.kv_cache import (assert_block_divisible, blocks_for_tokens,
-                                  init_paged_cache, paged_cache_memory_bytes,
-                                  paged_pools)
+from ..inference.kv_cache import (PAGE_ARENAS, assert_block_divisible,
+                                  blocks_for_tokens, init_paged_cache,
+                                  paged_cache_memory_bytes, paged_pools)
 
 __all__ = ["BlockAllocator", "BlockAllocatorError", "PrefixCache",
            "blocks_for_tokens", "assert_block_divisible", "init_paged_cache",
@@ -1005,8 +1005,7 @@ def build_cow_program():
     while readers keep the original."""
 
     def cow_copy(cache, src, dst):
-        return {**cache,
-                "k": cache["k"].at[:, dst].set(cache["k"][:, src]),
-                "v": cache["v"].at[:, dst].set(cache["v"][:, src])}
+        return {**cache, **{name: cache[name].at[:, dst].set(
+            cache[name][:, src]) for name in PAGE_ARENAS if name in cache}}
 
     return jax.jit(cow_copy, donate_argnums=(0,))

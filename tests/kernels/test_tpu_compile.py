@@ -174,7 +174,17 @@ def _grouped_matmul(tokens, top_k, experts, k, n):
 # phi-4-mini-flash-reasoning whole: 64 decode rows of up to 2,560 tokens; a
 # Mamba-1 state of 16 x 5,120 a (row, layer); differential attention's pairs
 # folded into 40 query heads over 10 key-value heads of 128
+# longcat-flash-chat as one of 32 chips: 32 decode rows of up to 5,120 tokens;
+# the absorbed latent read is the decode walk with ONE key-value head as wide
+# as a page, 576 values in 640 lanes (at 576 Mosaic refuses the page's copy:
+# "slice shape along dimension 3 must be aligned to tiling (128)")
 CASES = {
+    "latent-decode-longcat-flash":
+        lambda: _paged_decode(32, 64, 640, 16, 320, kv_heads=1),
+    "moe-up-decode-longcat-flash":
+        lambda: _grouped_matmul(32, 12, 16, 6144, 2048),
+    "moe-down-decode-longcat-flash":
+        lambda: _grouped_matmul(32, 12, 16, 2048, 6144),
     "mamba1-decode-phi-4-mini-flash":
         lambda: _mamba1_decode(64, 5120, 16),
     "mamba1-chunk-phi-4-mini-flash": lambda: _mamba1_chunk(256, 5120, 16),
@@ -319,7 +329,8 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
     if kind == "decode":
         # as the serving engine calls it: its last result, on the device,
         # behind the key (an MoE model's counts lie behind the tokens)
-        last = arg((r + 3 * bool(program_options.get("moe_counts")),), I32)
+        last = arg((r + T.moe_count_width(cfg)
+                    * bool(program_options.get("moe_counts")),), I32)
         return paged_kv.build_decode_program(cfg, **program_options).lower(
             params, arena, arg(paged_kv.decode_rows_shape(r, maxb), I32), key,
             last)
@@ -712,6 +723,58 @@ def test_a_looped_stack_carries_its_arena_through_both_loops(
     assert calls == 1 and loops == 2, (calls, loops)
     # a layer's FFN weights are 69 MB, a pool 21 MB, a stack of wk 0.4 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+
+
+LONGCAT_ROWS, LONGCAT_BLOCKS, LONGCAT_MAXB, LONGCAT_CHUNK = 32, 10241, 320, \
+    1024
+LONGCAT_ARENA = f"bf16[8,{LONGCAT_BLOCKS},{BLOCK},640]"
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_a_double_layer_reads_its_sublayers_where_they_lie(
+        v5e, monkeypatch, kind):
+    """LongCat-Flash-Chat's serving programs for the chip at the cell's
+    shapes (4 double layers, 16 of 512 routed experts held, 32 rows over an
+    arena of 10,241 blocks of 640-lane latent pages): the latent arena rides
+    the layer loop's carry and is scattered into where it lies; the decode
+    program's body holds TWO walks, one a sublayer, each handed the arena as
+    keys and as values; no sublayer's weights are copied out of their stack
+    (sliced a layer by the scan, the compiler copied a layer's two sublayers
+    out before each read its half: 1.3 GB a layer, 308 MB of temporaries);
+    the chunk program reads expanded and calls no walk."""
+    compiled = _serving_program(
+        kind, v5e, monkeypatch, preset="longcat-flash-chat",
+        overrides={"num_layers": 4, "moe_experts_held": 16,
+                   "vocab_size": 16384}, rows=LONGCAT_ROWS,
+        num_blocks=LONGCAT_BLOCKS, maxb=LONGCAT_MAXB, chunk=LONGCAT_CHUNK,
+        moe_counts=True).compile()
+    text = compiled.as_text()
+    roots = _fusion_roots(text)
+    calls, offenders = 0, []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m is None:
+            continue
+        _, result, op = m.groups()
+        if op == "custom-call" and "latent_decode_attention" in line:
+            calls += 1
+            operands = line.split("operand_layout_constraints=", 1)[1]
+            assert operands.count(LONGCAT_ARENA) == 2, line[:300]
+        if LONGCAT_ARENA in result and not _writes_in_place(line, op, roots):
+            offenders.append(line.strip()[:200])
+        # a layer's or a sublayer's dense FFN, attention or expert matrices
+        # (a chunk's 1,024 x 12 assigned rows of 6,144 are an activation)
+        if op in ("copy", "transpose") and "/gather" not in line and re.match(
+                r"bf16\[(4,)?(2,)?(6144,12288|12288,6144|8192,6144|"
+                r"1536,12288|16,6144,2048|16,2048,6144)\]", result):
+            offenders.append(line.strip()[:200])
+    assert not offenders, "\n".join(offenders)
+    assert calls == (2 if kind == "decode" else 0)
+    assert "moe_grouped_matmul" in text
+    # a sublayer's dense FFN matrix is 151 MB; the chunk's scores, 8 heads
+    # at a time in float32, and its experts' rows are 0.4 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        16e6 if kind == "decode" else 0.5e9)
 
 
 def _projection_weights(text, widths, layers):

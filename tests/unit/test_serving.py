@@ -3549,6 +3549,41 @@ class TestMixedStep:
         finally:
             srv.close()
 
+    def test_unpinned_cache_entries_are_room_for_a_step_ahead(
+            self, tiny_engine):
+        """A pool that has run for a while has no free page: released
+        blocks live on as prefix-cache entries. The rows of a plain step
+        ahead take their next pages from entries no request holds, as the
+        step would have after its predecessor's fetch, and nobody is
+        preempted; with nothing to evict either, the rule is `pages`."""
+        srv = serving(tiny_engine)
+        try:
+            srv.submit(np.arange(100, 180, dtype=np.int32), max_new_tokens=2)
+            srv.run()
+            assert srv.prefix.cached_blocks == 5
+            # rows of 7 and 9 tokens: each needs a second page in some steps
+            a, b = decoding_pair(srv)
+            drive_on_this_thread(srv, iterations=1)
+            assert srv._flight is not None and srv._flight.chunk is None
+            assert len(a._req.blocks) == 1 and len(b._req.blocks) == 1
+            spare = _grab_free_pages(srv)
+            cached = srv.prefix.cached_blocks
+            assert srv.alloc.blocks_free == 0 and srv.prefix.can_evict(2)
+            ahead = []
+            for _ in range(10):
+                before = srv._flight
+                srv._iterate(defer=True)
+                ahead.append(srv._flight is not None
+                             and srv._flight is not before)
+            assert ahead == [True] * 10 and srv.sched.preemption_count == 0
+            assert len(a._req.blocks) == 2 and len(b._req.blocks) == 2
+            assert srv.prefix.cached_blocks == cached - 2
+            srv.alloc.free(spare)
+            drive_on_this_thread(srv)
+            assert a.done and b.done
+        finally:
+            srv.close()
+
     def test_unpinned_cache_entries_are_room_for_a_mixed_step_ahead(
             self, tiny_engine):
         """A pool that has run for a while has no free page: the rows and
