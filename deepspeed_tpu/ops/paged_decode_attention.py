@@ -27,6 +27,19 @@ calls and the one place kernel or reference is chosen):
   bf16 terms stacked into the one product, ``_dot_f32``). GQA-native (KV
   heads never expanded), alibi in-kernel; a tile's tail past the row's
   length is masked by true position, in the scores and in v.
+* ``latent_decode_attention`` — the decode walk over ONE pool that is keys
+  and values both (the absorbed read of a latent pool: one key-value head
+  as wide as a page, its values the page's first lanes). A page is copied
+  ONCE, into one tile that both products read; with one key-value head q is
+  its own block-diagonal form, so there is no product before or after the
+  walk; the value product and the accumulator run over the value lanes
+  alone. What set its pace was not the products (they run at the MXU's own
+  pace) but the descriptors, a serial scalar chain a page: so a tile holds
+  twice the keys (``_pages_per_tile`` with one side), a tile known whole
+  takes its starts unrolled and ONE wait (``_page_copies``,
+  ``whole_tiles``), and the tile's body is built once a buffer, so that
+  every address in it is static. The arithmetic is the two-pool walk's,
+  term for term.
 * ``paged_prefill_attention`` — the same walk under a chunk of queries: C
   queries a row at absolute positions ``start..start+C-1``, of which the
   row's ``length`` says how many are real (``start + n_valid`` keys). The
@@ -72,6 +85,10 @@ of (8, 128) or the whole array dims, and stores arrays in such tiles: a
 64 is padded to twice its size. A ``(BLOCK, K*D)`` page is lane-dense as
 stored, and the kernels slice heads out of it by static lane offsets.
 
+The three kernels share the walk's copies and nothing else
+(``_page_copies``, ``_first_tile``, ``_tile_arrives``, over a tuple of
+sides: keys and values, or the one pool).
+
 ``reference_paged_attention`` is the pure-jnp oracle and CPU fallback:
 GQA-native over the view gathered straight from the arena
 (``arena[layer, block_table]``; no head expansion, no (B,S,T) mask
@@ -104,10 +121,11 @@ _TILE_KEYS = 256
 _CHUNK_TILE_KEYS = 512
 
 
-def _check_page_fits(block_size: int, width: int, dtype) -> None:
-    """k + v ``(block_size, width)`` pages, double-buffered, as VMEM holds
-    them."""
-    per_page = 4 * tiled_vmem_bytes(block_size, width, dtype)
+def _check_page_fits(block_size: int, width: int, dtype,
+                     sides: int = 2) -> None:
+    """``sides`` (k + v, or one pool that is both) ``(block_size, width)``
+    pages, double-buffered, as VMEM holds them."""
+    per_page = 2 * sides * tiled_vmem_bytes(block_size, width, dtype)
     if per_page > _VMEM_PAGE_BUDGET:
         raise ValueError(
             f"paged attention KV pages do not fit VMEM: block_size "
@@ -138,15 +156,17 @@ def _layer_operand(layer) -> jax.Array:
 
 
 def _pages_per_tile(block_size: int, width: int, dtype,
-                    max_keys: int = _TILE_KEYS) -> int:
+                    max_keys: int = _TILE_KEYS, sides: int = 2) -> int:
     """Pages one tile of a walk holds — derived, not set: the largest power
-    of two whose k + v tiles, two buffers each, fit the VMEM budget as VMEM
-    lays them out, capped at ``max_keys`` keys a tile (at least one page:
-    ``_check_page_fits`` guards that one)."""
+    of two whose tiles, ``sides`` of them (k + v) with two buffers each, fit
+    the VMEM budget as VMEM lays them out, capped at ``max_keys`` keys a
+    tile for k + v (at least one page: ``_check_page_fits`` guards that
+    one). A walk with ONE side spends the second's room on keys: twice as
+    many a tile."""
     pages = 1
-    while (2 * pages * block_size <= max_keys
-           and 4 * tiled_vmem_bytes(2 * pages * block_size, width, dtype)
-           <= _VMEM_PAGE_BUDGET):
+    while (2 * pages * block_size <= max_keys * 2 // sides
+           and 2 * sides * tiled_vmem_bytes(2 * pages * block_size, width,
+                                            dtype) <= _VMEM_PAGE_BUDGET):
         pages *= 2
     return pages
 
@@ -170,13 +190,21 @@ def _dot_f32(a, b, b_dim: int):
     return sum(out[i * M:(i + 1) * M] for i in range(terms))
 
 
-def _page_copies(bt_ref, len_ref, layer, k_hbm, v_hbm, kbuf, vbuf, sems):
-    """The walk's copies, for both kernels: ``each_copy(row, tile, slot)``
+def _page_copies(bt_ref, len_ref, layer, sides, sems, whole_tiles=False):
+    """The walk's copies, for every kernel: ``each_copy(row, tile, slot)``
     starts (or, with ``wait``, waits for) the copy of every RESIDENT page of
     ``row``'s tile ``tile``, ``arena[layer, table[row, page]]``, into slot p
     of buffer ``slot`` — nothing for a table slot past the row's last
-    resident page."""
-    P, BS = kbuf.shape[1:3]
+    resident page. ``sides``: the ``(arena, buffer)`` pairs a page is copied
+    for (keys and values, or one pool that is both).
+
+    ``whole_tiles``: a tile whose every page is resident (all but a row's
+    last) takes its starts UNROLLED, so that the scalar work of one page
+    (the table read, the address, the bounds checks) is packed beside the
+    next one's and not a loop's serial chain, and ONE wait for the tile's
+    bytes (a copy's wait takes its byte count off the semaphore, so a
+    descriptor as large as the tile waits for all of its pages)."""
+    P, BS = sides[0][1].shape[1:3]
 
     def each_copy(row, tile, slot, wait=False):
         first = tile * P
@@ -184,14 +212,30 @@ def _page_copies(bt_ref, len_ref, layer, k_hbm, v_hbm, kbuf, vbuf, sems):
 
         def page(p, carry):
             blk = bt_ref[row, first + p]
-            for side, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+            for side, (hbm, buf) in enumerate(sides):
                 copy = pltpu.make_async_copy(hbm.at[layer, blk],
                                              buf.at[slot, p],
                                              sems.at[side, slot])
                 copy.wait() if wait else copy.start()
             return carry
 
-        jax.lax.fori_loop(0, resident, page, 0)
+        if not whole_tiles:
+            jax.lax.fori_loop(0, resident, page, 0)
+            return
+
+        @pl.when(resident == P)
+        def _whole_tile():
+            if not wait:
+                for p in range(P):
+                    page(p, 0)
+                return
+            for side, (_, buf) in enumerate(sides):
+                pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                      sems.at[side, slot]).wait()
+
+        @pl.when(resident < P)
+        def _last_tile():
+            jax.lax.fori_loop(0, resident, page, 0)
 
     return each_copy
 
@@ -209,14 +253,16 @@ def _first_tile(each_copy, len_ref, slot_ref, row):
     return base
 
 
-def _tile_arrives(each_copy, vbuf, row, rows, t, n_tiles, base, length):
-    """The buffer that holds ``row``'s tile ``t``, copied. The next tile's
+def _tile_arrives(each_copy, vbuf, row, rows, t, n_tiles, slot, length,
+                  lanes=None):
+    """``row``'s tile ``t`` arrives in buffer ``slot``. The next tile's
     copies go out first: this row's, or after its last the first of the row
     below. A tile's tail past the row's length holds what was there before
     (another row's pages, or whatever the buffer started with): the kernels
-    mask its scores, and its v is zeroed here: 0 * NaN is NaN."""
+    mask its scores, and its v is zeroed here: 0 * NaN is NaN (``lanes``:
+    how many of a page's first lanes are its values; None: all of them)."""
     _, P, BS, W = vbuf.shape
-    slot = (base + t) % 2
+    W = W if lanes is None else lanes
 
     @pl.when(t + 1 < n_tiles)
     def _next_tile():
@@ -233,10 +279,25 @@ def _tile_arrives(each_copy, vbuf, row, rows, t, n_tiles, base, length):
         pos = (t * P * BS
                + jax.lax.broadcasted_iota(jnp.int32, (P, BS, W), 0) * BS
                + jax.lax.broadcasted_iota(jnp.int32, (P, BS, W), 1))
-        vbuf[slot] = jnp.where(pos < length, vbuf[slot].astype(jnp.float32),
-                               0.0).astype(vbuf.dtype)
+        vbuf[slot, :, :, :W] = jnp.where(
+            pos < length, vbuf[slot, :, :, :W].astype(jnp.float32),
+            0.0).astype(vbuf.dtype)
 
-    return slot
+
+def _softmax_step(s, m_scr, l_scr):
+    """One tile of a decode walk's online softmax: the masked float32 scores
+    ``s`` (N, TK) against the running max and sum (kept across a row's tiles,
+    a head a row, broadcast over the lanes). Returns ``p`` (N, TK) and the
+    factor that brings what was accumulated so far to the new max."""
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[:] = jnp.broadcast_to(
+        corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+        l_scr.shape)
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    return p, corr
 
 
 def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
@@ -253,8 +314,8 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
     length = len_ref[r]
     n_tiles = pl.cdiv(length, TK)
 
-    each_copy = _page_copies(bt_ref, len_ref, layer, k_hbm, v_hbm, kbuf, vbuf,
-                             sems)
+    each_copy = _page_copies(bt_ref, len_ref, layer,
+                             ((k_hbm, kbuf), (v_hbm, vbuf)), sems)
 
     @pl.when(r == 0)
     def _first_row():
@@ -284,8 +345,8 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
         qbd[:] = (_dot_f32(q_ref[0], lane[:], 0) * diag[:]).astype(qbd.dtype)
 
         def tile(t, carry):
-            slot = _tile_arrives(each_copy, vbuf, r, R, t, n_tiles, base,
-                                 length)
+            slot = (base + t) % 2
+            _tile_arrives(each_copy, vbuf, r, R, t, n_tiles, slot, length)
             k = kbuf[slot].reshape(TK, W).astype(qbd.dtype)
             s = jax.lax.dot_general(
                 qbd[:], k, (((1,), (1,)), ((), ())),
@@ -298,15 +359,7 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
             if lo_ref is not None:
                 # a window: the keys below its first are out of sight
                 live = live & (col >= lo_ref[r])
-            s = jnp.where(live, s, NEG_INF)
-            m_prev = m_scr[:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[:] = jnp.broadcast_to(
-                corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-                l_scr.shape)
-            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            p, corr = _softmax_step(jnp.where(live, s, NEG_INF), m_scr, l_scr)
             # p goes into the value product as float32, not rounded to v's
             acc[:] = acc[:] * corr + _dot_f32(
                 p, vbuf[slot].reshape(TK, W), 0)               # (N, K*D)
@@ -395,6 +448,131 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# decode over ONE pool: one key-value head as wide as a page, whose values
+# are the page's first lanes (the absorbed read of a latent pool)
+# ---------------------------------------------------------------------------
+
+# the walk's name in a trace, whatever implements it
+LATENT_DECODE = "latent_decode_attention"
+
+
+def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, hbm, o_ref, buf,
+                          sems, acc, m_scr, l_scr, slot_ref, *, scale: float):
+    r = pl.program_id(0)
+    R = pl.num_programs(0)
+    _, P, BS, W = buf.shape
+    TK = P * BS
+    V = acc.shape[1]
+    # the score takes q and the keys in the wider of their two dtypes
+    pd = jnp.promote_types(q_ref.dtype, buf.dtype)
+    length = len_ref[r]
+    n_tiles = pl.cdiv(length, TK)
+    # keys and values are ONE tile of pages: one copy a page
+    each_copy = _page_copies(bt_ref, len_ref, layer_ref[0], ((hbm, buf),),
+                             sems, whole_tiles=True)
+
+    @pl.when(r == 0)
+    def _first_row():
+        slot_ref[0] = 0
+
+    @pl.when(n_tiles == 0)
+    def _empty_row():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(n_tiles > 0)
+    def _row():
+        base = _first_tile(each_copy, len_ref, slot_ref, r)
+        acc[:] = jnp.zeros_like(acc)
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+        def tile(t, slot):
+            _tile_arrives(each_copy, buf, r, R, t, n_tiles, slot, length,
+                          lanes=V)
+            # with one key-value head q IS its block-diagonal form and the
+            # accumulator its own output: no product before or after the walk
+            s = jax.lax.dot_general(
+                q_ref[0].astype(pd), buf[slot].reshape(TK, W).astype(pd),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # (N, TK)
+            col = t * TK + jax.lax.broadcasted_iota(jnp.int32, (1, TK), 1)
+            p, corr = _softmax_step(jnp.where(col < length, s, NEG_INF),
+                                    m_scr, l_scr)
+            # p as float32, as the two-pool walk weighs its values
+            acc[:] = acc[:] * corr + _dot_f32(
+                p, buf[slot, :, :, :V].reshape(TK, V), 0)      # (N, V)
+
+        def in_its_buffer(t, carry):
+            # the body is built once a buffer, so that every address of a
+            # tile (a page's place in VMEM among them) is known when the
+            # kernel is built: a page's start is 16 bundles, not 22
+            for slot in (0, 1):
+                pl.when((base + t) % 2 == slot)(
+                    functools.partial(tile, t, slot))
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, in_its_buffer, 0)
+        slot_ref[0] = (base + n_tiles) % 2
+        o_ref[0] = (acc[:] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q: jax.Array, arena: jax.Array, layer,
+                            block_table: jax.Array, lengths: jax.Array,
+                            values: int, scale: Optional[float] = None,
+                            interpret: bool = False) -> jax.Array:
+    """The decode walk over ONE pool: q (R, N, W) — one new token per row, a
+    head's query as wide as a page; arena (POOLS, NUM_BLOCKS, BLOCK, W) — a
+    token's ONE key-value head is its row of a page, and its values that
+    row's first ``values`` lanes (whole lane tiles); ``layer``,
+    ``block_table`` and ``lengths`` as in ``paged_decode_attention``.
+    Returns (R, N, values): multi-query attention whose keys and values are
+    the same bytes. A page is copied ONCE into one tile that both products
+    read (twice the keys a tile for it); the arithmetic is the two-pool
+    walk's, term for term: the pool as stored, float32 scores, softmax state
+    and accumulator, ``p`` as float32 in three bfloat16 terms."""
+    R, N, W = q.shape
+    BS = arena.shape[2]
+    if arena.ndim != 4 or arena.shape[3] != W or values % LANES \
+            or not 0 < values <= W:
+        raise ValueError(
+            f"a one-pool walk takes q (R, N, W) against (POOLS, NUM_BLOCKS, "
+            f"BLOCK, W) with the values a page's first whole lane tiles, got "
+            f"q {q.shape}, arena {arena.shape}, values {values}")
+    _check_page_fits(BS, W, arena.dtype, sides=1)
+    pages = _pages_per_tile(BS, W, arena.dtype, sides=1)
+    scale = scale if scale is not None else W ** -0.5
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(R,),
+        in_specs=[
+            pl.BlockSpec((1, N, W), lambda r, *_: (r, 0, 0)),
+            # the pool stays where it lies: the kernel copies pages itself
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, N, values), lambda r, *_: (r, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, BS, W), arena.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),          # (the one side, buffer)
+            pltpu.VMEM((N, values), jnp.float32),
+            pltpu.VMEM((N, LANES), jnp.float32),
+            pltpu.VMEM((N, LANES), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, N, values), q.dtype),
+        # rows in order: a row starts the copies of the next one's first tile
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name=LATENT_DECODE,
+        interpret=interpret,
+    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      _layer_operand(layer), q, arena)
+
+
+# ---------------------------------------------------------------------------
 # chunked prefill: C queries per row at positions start..start+C-1
 # ---------------------------------------------------------------------------
 
@@ -433,8 +611,8 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
     if window is not None:
         # under a window no tile is open to every query: each masks its own
         n_open = 0
-    each_copy = _page_copies(bt_ref, len_ref, layer_ref[0], k_hbm, v_hbm,
-                             kbuf, vbuf, sems)
+    each_copy = _page_copies(bt_ref, len_ref, layer_ref[0],
+                             ((k_hbm, kbuf), (v_hbm, vbuf)), sems)
 
     def lanes(g, width):
         """Group ``g``'s slab of ``width`` lanes: where there is more than
@@ -469,8 +647,8 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
         l_scr[:] = jnp.zeros_like(l_scr)
 
         def tile(t, carry, *, masked: bool):
-            slot = _tile_arrives(each_copy, vbuf, b, B, t, n_tiles, base,
-                                 length)
+            slot = (base + t) % 2
+            _tile_arrives(each_copy, vbuf, b, B, t, n_tiles, slot, length)
 
             def group(g):
                 # left-aligned layout: a tile's column IS the key position
@@ -691,12 +869,14 @@ def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
-                    layer, block_table: jax.Array, positions: jax.Array,
+def paged_attention(q: jax.Array, k_arena: jax.Array,
+                    v_arena: Optional[jax.Array], layer,
+                    block_table: jax.Array, positions: jax.Array,
                     alibi: Optional[jax.Array] = None,
                     scale: Optional[float] = None,
                     window: Optional[int] = None,
-                    name: Optional[str] = None) -> jax.Array:
+                    name: Optional[str] = None,
+                    values: Optional[int] = None) -> jax.Array:
     """The model's paged read, after its scatter: q (B, S, N, D) at absolute
     ``positions`` (B, S) against ``arena[layer]`` through ``block_table``;
     returns (B, S, N, D). Where the Pallas kernels run (``ops/registry``'s
@@ -713,7 +893,28 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
     and S queries can span, and positions counted from that page's first
     key, so that the pages below it cost no copy and no step, whatever the
     row's length; the keys of that first page that lie below the window are
-    masked (``lo``, ``window``). ``name``: the decode walk's in a trace."""
+    masked (``lo``, ``window``). ``name``: the decode walk's in a trace.
+
+    ``v_arena`` None: ``k_arena`` is ONE pool that is keys and values both,
+    one key-value head as wide as a page whose values are the page's first
+    ``values`` lanes, read with neither alibi nor a window and returned (B,
+    S, N, ``values``). Its kernel is ``latent_decode_attention``, one query
+    a row (a chunk of queries reads a latent pool expanded:
+    ``latent_paged_attention``)."""
+    if v_arena is None:
+        if alibi is not None or window is not None:
+            raise ValueError("a one-pool read takes neither alibi nor a "
+                             "window")
+        if not registry.kernels_active():
+            return reference_paged_attention(
+                q, k_arena, k_arena, layer, block_table, positions,
+                scale=scale)[..., :values]
+        if q.shape[1] != 1:
+            raise ValueError("the one-pool kernel reads one query a row, "
+                             f"got {q.shape[1]}")
+        return latent_decode_attention(
+            q[:, 0], k_arena, layer, block_table, positions[:, 0] + 1,
+            values, scale)[:, None]
     if not registry.kernels_active():
         return reference_paged_attention(q, k_arena, v_arena, layer,
                                          block_table, positions, alibi=alibi,
@@ -752,8 +953,6 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
 # a latent pool: one latent and one roped key a token, no head's keys or values
 # ---------------------------------------------------------------------------
 
-# the absorbed walk's name in a trace, whatever implements it
-LATENT_DECODE = "latent_decode_attention"
 # heads an expanded read attends at a time: their scores, (heads, C, T) in
 # float32, are what bounds a chunk's memory
 _EXPANDED_HEADS = 8
@@ -769,16 +968,18 @@ def latent_absorbed_attention(q_nope: jax.Array, q_rope: jax.Array,
     . c + q_rope_h . k_rope``, ``o_h = (sum p c) W_vb_h``. That is
     multi-query attention with ONE key-value head as wide as the page, whose
     values are the page's first ``R`` lanes: ``paged_attention`` takes the
-    pool as keys AND as values (the decode walk under the name
-    ``LATENT_DECODE``, which so copies a page twice) and the rope lanes of
-    its result are dropped. No key or value of any head is ever made."""
+    ONE pool (``latent_decode_attention``, the walk under the name
+    ``LATENT_DECODE``: a page copied once, the latents mixed and nothing
+    else written). q spans the page's lanes for the score: the absorbed
+    query, the roped one, zeros over the pad. No key or value of any head
+    is ever made."""
     R = wk_b.shape[-1]
     unused = arena.shape[-1] - R - q_rope.shape[-1]     # a page's pad lanes
     q = jnp.concatenate(
         [jnp.einsum("bsnd,ndr->bsnr", q_nope, wk_b), q_rope,
          jnp.zeros(q_rope.shape[:-1] + (unused,), q_rope.dtype)], axis=-1)
-    mixed = paged_attention(q, arena, arena, layer, block_table, positions,
-                            scale=scale, name=LATENT_DECODE)[..., :R]
+    mixed = paged_attention(q, arena, None, layer, block_table, positions,
+                            scale=scale, values=R)
     return jnp.einsum("bsnr,nrv->bsnv", mixed, wv_b)
 
 

@@ -286,6 +286,100 @@ class TestPagedDecodeKernel:
                                      interpret=INTERPRET)
         assert bool(jnp.all(out[1] == 0))
 
+
+# the one-pool walk's edges: a page of 256 lanes whose first 128 are its
+# values, 8 heads over the ONE key-value head; a tile holds 512 keys (twice
+# the two-pool walk's), a table of 80 pages is two and a half of them
+LATENT_W, LATENT_V, LATENT_HEADS, LATENT_MAXB = 256, 128, 8, 80
+LATENT_TILE = 2 * TILE
+# rows of different lengths in one call: one that ends inside a page (and
+# inside a tile), an empty one, a few tokens, one that ends on a tile's edge,
+# the whole table, exactly a page, and a row whose first tile the empty rows
+# above it did not start
+LATENT_ROWS = (LATENT_TILE + 5, 0, 9, 2 * LATENT_TILE, LATENT_MAXB * BS, 0,
+               0, BS, LATENT_TILE)
+LATENT_WALKS = {
+    # (pool, dtype, NaN in what no row reads, tolerance)
+    "rows-of-every-length": (0, jnp.float32, False, 2e-5),
+    "a-pool-that-is-not-the-first": (2, jnp.float32, False, 2e-5),
+    "nan-past-a-rows-length": (1, jnp.float32, True, 2e-5),
+    "bfloat16": (1, jnp.bfloat16, False, 2e-2),
+    "bfloat16-nan-past-a-rows-length": (0, jnp.bfloat16, True, 2e-2),
+    # not the one-pool walk: one key-value head in TWO arenas, the GQA way
+    "two-arenas-one-kv-head": (1, jnp.float32, True, 2e-5),
+}
+
+
+class TestLatentDecodeKernel:
+    @pytest.mark.parametrize("case", sorted(LATENT_WALKS))
+    def test_one_pool_walk_is_the_two_pool_walk(self, case):
+        """``latent_decode_attention`` on ONE pool against the parent's form
+        of the same read (the pool handed to ``paged_decode_attention`` as
+        keys AND as values at one key-value head, the value lanes cut out of
+        the result) to float32 round-off, and against the reference; and a
+        one-key-value-head caller with two arenas still reads what the
+        reference reads."""
+        pool, dtype, poison, tol = LATENT_WALKS[case]
+        f32 = jnp.float32
+        assert paged_module._pages_per_tile(BS, LATENT_W, dtype, sides=1) \
+            * BS == LATENT_TILE
+        nb = 1 + sum(-(-n // BS) for n in LATENT_ROWS)
+        arena, other = (
+            a.astype(dtype)
+            for a in _arena(nb=nb, k=1, d=LATENT_W, seed=7, dtype=f32))
+        bt, lengths = _walk_tables(LATENT_ROWS, nb, maxb=LATENT_MAXB, seed=8)
+        q = jax.random.normal(jax.random.PRNGKey(17),
+                              (len(LATENT_ROWS), LATENT_HEADS, LATENT_W),
+                              f32).astype(dtype)
+        scale = 0.11
+        dirty = (functools.partial(_poison_what_no_row_reads, bt=bt,
+                                   lengths=lengths) if poison
+                 else lambda a: a)
+        empty = np.asarray(lengths) == 0
+        if case == "two-arenas-one-kv-head":
+            out = paged_decode_attention(q, dirty(arena), dirty(other), pool,
+                                         bt, lengths, scale=scale,
+                                         interpret=INTERPRET)
+            ref = _pool_reference(q[:, None], arena, other, pool, bt,
+                                  lengths[:, None] - 1, scale=scale)[:, 0]
+        else:
+            out = paged_module.latent_decode_attention(
+                q, dirty(arena), pool, bt, lengths, LATENT_V, scale,
+                interpret=INTERPRET)
+            assert out.shape == q.shape[:2] + (LATENT_V,)
+            assert out.dtype == dtype
+            parent = paged_decode_attention(
+                q, dirty(arena), dirty(arena), pool, bt, lengths,
+                scale=scale, interpret=INTERPRET)[..., :LATENT_V]
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32), np.asarray(parent, np.float32),
+                atol=2e-6 if dtype == f32 else 2e-2, rtol=0)
+            ref = _pool_reference(
+                q.astype(f32)[:, None], arena.astype(f32), arena.astype(f32),
+                pool, bt, lengths[:, None] - 1,
+                scale=scale)[:, 0, :, :LATENT_V]
+        assert bool(jnp.all(jnp.isfinite(out)))
+        assert np.abs(np.asarray(out, np.float32)[~empty]).max() > 0.1
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), atol=tol, rtol=tol)
+        assert not np.asarray(out, np.float32)[empty].any()
+
+    def test_one_pool_walk_copies_a_page_once(self, monkeypatch):
+        """What the walk is for, without a chip: ONE copy is started for
+        each resident page (a whole tile's unrolled, a row's last tile's in
+        a loop), none for a table slot past a row's length."""
+        started = _count_started_copies(monkeypatch)
+        nb = 1 + sum(-(-n // BS) for n in LATENT_ROWS)
+        arena, _ = _arena(nb=nb, k=1, d=LATENT_W, seed=9)
+        bt, lengths = _walk_tables(LATENT_ROWS, nb, maxb=LATENT_MAXB)
+        q = jax.random.normal(jax.random.PRNGKey(18),
+                              (len(LATENT_ROWS), LATENT_HEADS, LATENT_W))
+        out = paged_module.latent_decode_attention(
+            q, arena, 1, bt, lengths, LATENT_V, 0.1, interpret=INTERPRET)
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+        assert len(started) == sum(-(-n // BS) for n in LATENT_ROWS)
+
     @pytest.mark.parametrize("layer", LAYERS)
     def test_reference_matches_dense_gather_path(self, layer):
         """The jnp paged reference (CPU serving fallback) computes the

@@ -22,7 +22,8 @@ from deepspeed_tpu.ops import (decode_attention, flash_attention,
                                paged_decode_attention,
                                paged_prefill_attention)
 from deepspeed_tpu.ops import mamba1_chunk_scan, mamba1_decode_step
-from deepspeed_tpu.ops.paged_decode_attention import paged_attention
+from deepspeed_tpu.ops.paged_decode_attention import (
+    latent_decode_attention, paged_attention)
 from deepspeed_tpu.ops.moe_grouped_matmul import max_tiles, tile_rows
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -83,6 +84,17 @@ def _paged_decode(rows, heads, d, block, maxb, kv_heads=None):
     return (paged_decode_attention,
             [((rows, heads, d), BF16), arena, arena, ((), I32),
              ((rows, maxb), I32), ((rows,), I32)])
+
+
+def _latent_decode(rows, heads, width, values, block, maxb):
+    """The one-pool walk: q as wide as a page, the values its first lanes."""
+    def walk(q, arena, layer, table, lengths):
+        return latent_decode_attention(q, arena, layer, table, lengths,
+                                       values, 192 ** -0.5)
+
+    return (walk, [((rows, heads, width), BF16),
+                   ((3, 1024, block, width), BF16), ((), I32),
+                   ((rows, maxb), I32), ((rows,), I32)])
 
 
 def _paged_prefill(chunk, heads, d, block, maxb, rows=1, kv_heads=None):
@@ -175,12 +187,13 @@ def _grouped_matmul(tokens, top_k, experts, k, n):
 # Mamba-1 state of 16 x 5,120 a (row, layer); differential attention's pairs
 # folded into 40 query heads over 10 key-value heads of 128
 # longcat-flash-chat as one of 32 chips: 32 decode rows of up to 5,120 tokens;
-# the absorbed latent read is the decode walk with ONE key-value head as wide
+# the absorbed latent read is the one-pool walk: ONE key-value head as wide
 # as a page, 576 values in 640 lanes (at 576 Mosaic refuses the page's copy:
-# "slice shape along dimension 3 must be aligned to tiling (128)")
+# "slice shape along dimension 3 must be aligned to tiling (128)"), of which
+# the first 512 are the values
 CASES = {
     "latent-decode-longcat-flash":
-        lambda: _paged_decode(32, 64, 640, 16, 320, kv_heads=1),
+        lambda: _latent_decode(32, 64, 640, 512, 16, 320),
     "moe-up-decode-longcat-flash":
         lambda: _grouped_matmul(32, 12, 16, 6144, 2048),
     "moe-down-decode-longcat-flash":
@@ -737,8 +750,9 @@ def test_a_double_layer_reads_its_sublayers_where_they_lie(
     shapes (4 double layers, 16 of 512 routed experts held, 32 rows over an
     arena of 10,241 blocks of 640-lane latent pages): the latent arena rides
     the layer loop's carry and is scattered into where it lies; the decode
-    program's body holds TWO walks, one a sublayer, each handed the arena as
-    keys and as values; no sublayer's weights are copied out of their stack
+    program's body holds TWO walks, one a sublayer, each handed the arena
+    ONCE (keys and values are the same pages, copied once); no sublayer's
+    weights are copied out of their stack
     (sliced a layer by the scan, the compiler copied a layer's two sublayers
     out before each read its half: 1.3 GB a layer, 308 MB of temporaries);
     the chunk program reads expanded and calls no walk."""
@@ -759,7 +773,7 @@ def test_a_double_layer_reads_its_sublayers_where_they_lie(
         if op == "custom-call" and "latent_decode_attention" in line:
             calls += 1
             operands = line.split("operand_layout_constraints=", 1)[1]
-            assert operands.count(LONGCAT_ARENA) == 2, line[:300]
+            assert operands.count(LONGCAT_ARENA) == 1, line[:300]
         if LONGCAT_ARENA in result and not _writes_in_place(line, op, roots):
             offenders.append(line.strip()[:200])
         # a layer's or a sublayer's dense FFN, attention or expert matrices
