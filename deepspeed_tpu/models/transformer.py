@@ -31,8 +31,8 @@ from .core import (EMBED, EXPERT, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ,
                    VOCAB)
 
 
-# a layer's kind -> (its mixer, a key of ``MIXERS``, or None; whether the
-# model's FFN follows it).
+# a layer's kind -> (its mixer, a key of ``MIXERS``, or None; whether an FFN
+# follows it: WHICH one, a key of ``FFNS``, is ``ffn_of``'s word).
 # "attn" and "kda" are a whole block, norm, mixer, add, norm, FFN, add; the
 # others are ONE function under one norm and one add, for a family whose
 # layers are a mixer alone or an FFN alone. "swa", "full" and "cross" are the
@@ -447,11 +447,12 @@ def paged_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
     return _layers_keeping(cfg, "pages")
 
 
-def sublayer_leaves(kind: str) -> Tuple[str, ...]:
+def sublayer_leaves(cfg: TransformerConfig, kind: str) -> Tuple[str, ...]:
     """The leaves of a layer of ``SUBLAYERS`` that its sublayers own (a
     leading axis of sublayers behind the layers'); every other leaf is the
     layer's one."""
-    return ("ln1", MIXERS[LAYER_KINDS[kind][0]].name, "ln2", "dense")
+    return ("ln1", MIXERS[LAYER_KINDS[kind][0]].name, "ln2",
+            *FFNS[ffn_of(cfg, kind, sublayer=True)].axes(cfg))
 
 
 def latent_pools(cfg: TransformerConfig) -> int:
@@ -482,9 +483,8 @@ def latent_page_width(cfg: TransformerConfig) -> int:
 
 def moe_count_width(cfg: TransformerConfig) -> int:
     """Entries of an MoE layer's routing counts (``parallel/moe.moe_mlp``):
-    three, and a fourth BEHIND them, the assignments to zero-computation
-    experts, only where the model has such experts."""
-    return 3 + (cfg.moe_zero_experts > 0)
+    what the experts' record says (``Ffn.count_width``)."""
+    return FFNS["experts"].count_width(cfg)
 
 
 def ring_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
@@ -516,10 +516,27 @@ def tail_runs(cfg: TransformerConfig) -> int:
     return n
 
 
+def ffn_of(cfg: TransformerConfig, kind: str,
+           sublayer: bool = False) -> Optional[str]:
+    """THE answer to "which FFN has a layer of this kind": a key of ``FFNS``,
+    or None where ``LAYER_KINDS`` says no FFN follows the mixer. Today the
+    whole configuration decides it, the same for every kind that has one;
+    an FFN that differs by layer is a kind in ``LAYER_KINDS`` and a line
+    here. ``sublayer``: the FFN EACH sublayer of a kind of ``SUBLAYERS`` has
+    beside the layer's one (None for any other kind)."""
+    if sublayer:
+        return "dense" if kind in SUBLAYERS else None
+    if not LAYER_KINDS[kind][1]:
+        return None
+    if cfg.moe_num_experts > 0:
+        return "experts"
+    return "swiglu" if cfg.activation == "swiglu" else "biased"
+
+
 def ffn_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
     """The layers that have an FFN (every one, but for a family whose
     layers are one function each)."""
-    return tuple(i for kind in set(layer_kinds(cfg)) if LAYER_KINDS[kind][1]
+    return tuple(i for kind in set(layer_kinds(cfg)) if ffn_of(cfg, kind)
                  for i in layers_of_kind(cfg, kind))
 
 
@@ -545,13 +562,10 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
     layer_idx)`` — so ANY range reproduces exactly the same values the full
     init produces (ZeRO-3 param offload inits one block at a time). A stack
     of several kinds (``layer_stacks``) is initialised whole."""
-    H, L, F = cfg.hidden_size, cfg.num_layers, cfg.ffn_hidden_size
-    std, resid_std = 0.02, _resid_std(cfg)
-    E = cfg.moe_num_experts
-    held = cfg.experts_held
+    H, L = cfg.hidden_size, cfg.num_layers
 
     def one_layer(li, kind="attn"):
-        def normal(tag, shape, s=std, sub=None):
+        def normal(tag, shape, s=0.02, sub=None):
             k = jax.random.fold_in(jax.random.fold_in(base_key, tag), li)
             if sub is not None:     # a sublayer's own draw
                 k = jax.random.fold_in(k, sub)
@@ -562,12 +576,12 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
             k = jax.random.fold_in(jax.random.fold_in(base_key, tag), li)
             return jax.random.uniform(k, shape, jnp.float32, lo, hi)
 
-        mixer, has_ffn = LAYER_KINDS[kind]
+        mixer, ffn = LAYER_KINDS[kind][0], ffn_of(cfg, kind)
         layer: Dict[str, Any] = {}
         if kind in SUBLAYERS:
             # each sublayer's norms, mixer and dense FFN, stacked on a
-            # leading axis; the expert FFN below is the layer's one
-            Fd = cfg.dense_ffn_hidden_size
+            # leading axis; the layer's own FFN below is its one
+            dense = FFNS[ffn_of(cfg, kind, sublayer=True)]
 
             def sublayer(i):
                 draw = partial(normal, sub=i)
@@ -575,9 +589,7 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                         MIXERS[mixer].name: MIXERS[mixer].init(cfg, draw,
                                                                uniform),
                         "ln2": {"scale": jnp.ones((H,), cfg.dtype)},
-                        "dense": {"w_gate": draw(80, (H, Fd)),
-                                  "w_up": draw(81, (H, Fd)),
-                                  "w_down": draw(82, (Fd, H), resid_std)}}
+                        **dense.init(cfg, draw)}
 
             layer = jax.tree.map(
                 lambda *a: jnp.stack(a),
@@ -591,72 +603,10 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
                 # WHOLE stack: a constant a layer, not learned
                 layer[MIXERS[mixer].name]["lam_init"] = (
                     0.8 - 0.6 * jnp.exp(-0.3 * li.astype(jnp.float32)))
-        if has_ffn and kind not in SUBLAYERS:
-            layer["ln2"] = {"scale": jnp.ones((H,), cfg.dtype)}
-        if not has_ffn:
-            pass            # a mixer alone: no FFN of any kind below
-        elif E > 0:
-            # the router's outputs: the routed experts, then the
-            # zero-computation ones, which have no matrices below
-            layer["router"] = normal(4, (H, E + cfg.moe_zero_experts))
-            if cfg.moe_router_bias:
-                # nonzero, or the choice-only bias would go untested; small,
-                # as a trained one is: sigmoid scores of the most probable
-                # experts lie within 0.005 of each other, and a bias of 0.1
-                # would choose the same experts for every token
-                # would choose the same experts for every token. Softmax
-                # scores sum to one: beside them, their mean, 1 / outputs
-                # (at 768 outputs the 12th largest score is 0.012, and a
-                # bias of 0.01 gave 1 output in 64 to every token)
-                outputs = E + cfg.moe_zero_experts
-                layer["router_bias"] = normal(
-                    12, (outputs,), 0.01 if cfg.moe_score_func == "sigmoid"
-                    else 1.0 / outputs).astype(jnp.float32)
-            if cfg.moe_shared_experts:
-                Fs = cfg.shared_ffn_hidden_size
-                layer["shared"] = {
-                    "w_up": normal(14, (H, Fs)),
-                    "w_down": normal(15, (Fs, H), resid_std),
-                }
-                if cfg.activation == "swiglu":
-                    layer["shared"]["w_gate"] = normal(13, (H, Fs))
-            if cfg.moe_latent_size:
-                layer["latent"] = {
-                    "w_in": normal(16, (H, cfg.moe_latent_size)),
-                    "w_out": normal(17, (cfg.moe_latent_size, H), resid_std),
-                }
-            if cfg.moe_use_residual:
-                layer["res_mlp"] = {
-                    "w_up": normal(5, (H, F)),
-                    "b_up": jnp.zeros((F,), cfg.dtype),
-                    "w_down": normal(6, (F, H), resid_std),
-                    "b_down": jnp.zeros((H,), cfg.dtype),
-                }
-                layer["res_coef"] = {"w": normal(7, (H, 2)),
-                                     "b": jnp.zeros((2,), cfg.dtype)}
-            He = cfg.moe_latent_size or H       # the experts' own width
-            layer["mlp"] = {
-                "w_up": normal(9, (held, He, F)),
-                # in a latent it is the projection out of it that writes
-                # to the residual stream, not an expert's own
-                "w_down": normal(10, (held, F, He),
-                                 std if cfg.moe_latent_size else resid_std),
-            }
-            if cfg.activation == "swiglu":
-                layer["mlp"]["w_gate"] = normal(8, (held, He, F))
-        elif cfg.activation == "swiglu":
-            layer["mlp"] = {
-                "w_gate": normal(8, (H, F)),
-                "w_up": normal(9, (H, F)),
-                "w_down": normal(10, (F, H), resid_std),
-            }
-        else:
-            layer["mlp"] = {
-                "w_up": normal(9, (H, F)),
-                "b_up": jnp.zeros((F,), cfg.dtype),
-                "w_down": normal(10, (F, H), resid_std),
-                "b_down": jnp.zeros((H,), cfg.dtype),
-            }
+        if ffn is not None:     # else a mixer alone: no FFN of any kind
+            if kind not in SUBLAYERS:
+                layer["ln2"] = {"scale": jnp.ones((H,), cfg.dtype)}
+            layer.update(FFNS[ffn].init(cfg, normal))
         if cfg.norm_position == "sandwich":
             for ln in ("ln1", "ln2"):
                 if ln in layer:
@@ -681,47 +631,18 @@ def init_layer_params(base_key: jax.Array, cfg: TransformerConfig,
 
 def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Logical-axis tree mirroring init_params — drives TP/ZeRO sharding."""
-    if cfg.moe_num_experts > 0:
-        wide = None if cfg.moe_latent_size else EMBED   # the experts' width
-        mlp = {"w_up": (LAYERS, EXPERT, wide, MLP),
-               "w_down": (LAYERS, EXPERT, MLP, wide)}
-        if cfg.activation == "swiglu":
-            mlp["w_gate"] = (LAYERS, EXPERT, wide, MLP)
-    elif cfg.activation == "swiglu":
-        mlp = {"w_gate": (LAYERS, EMBED, MLP), "w_up": (LAYERS, EMBED, MLP),
-               "w_down": (LAYERS, MLP, EMBED)}
-    else:
-        mlp = {"w_up": (LAYERS, EMBED, MLP), "b_up": (LAYERS, MLP),
-               "w_down": (LAYERS, MLP, EMBED), "b_down": (LAYERS, EMBED)}
     ln = {"scale": (LAYERS, EMBED)}
     if cfg.norm == "layernorm":
         ln = {"scale": (LAYERS, EMBED), "bias": (LAYERS, EMBED)}
     sandwich = cfg.norm_position == "sandwich"
-    layer_axes = ffn_axes = {"ln2": dict(ln), "mlp": mlp,
-                             **({"ln2_post": dict(ln)} if sandwich else {})}
-    if cfg.moe_num_experts > 0:
-        layer_axes["router"] = (LAYERS, EMBED, None)
-        if cfg.moe_router_bias:
-            layer_axes["router_bias"] = (LAYERS, None)
-        if cfg.moe_shared_experts:
-            layer_axes["shared"] = {"w_up": (LAYERS, EMBED, MLP),
-                                    "w_down": (LAYERS, MLP, EMBED)}
-            if cfg.activation == "swiglu":
-                layer_axes["shared"]["w_gate"] = (LAYERS, EMBED, MLP)
-        if cfg.moe_latent_size:
-            layer_axes["latent"] = {"w_in": (LAYERS, EMBED, None),
-                                    "w_out": (LAYERS, None, EMBED)}
-        if cfg.moe_use_residual:
-            layer_axes["res_mlp"] = {
-                "w_up": (LAYERS, EMBED, MLP), "b_up": (LAYERS, MLP),
-                "w_down": (LAYERS, MLP, EMBED), "b_down": (LAYERS, EMBED)}
-            layer_axes["res_coef"] = {"w": (LAYERS, EMBED, None),
-                                      "b": (LAYERS, None)}
     kinds = sorted(set(layer_kinds(cfg)))
     by_kind = {}
     for kind in kinds:
-        mixer, has_ffn = LAYER_KINDS[kind]
-        by_kind[kind] = {**(ffn_axes if has_ffn else {}),
+        mixer, ffn = LAYER_KINDS[kind][0], ffn_of(cfg, kind)
+        by_kind[kind] = {**({} if ffn is None
+                            else {"ln2": dict(ln), **FFNS[ffn].axes(cfg)}),
+                         **({"ln2_post": dict(ln)}
+                            if sandwich and ffn is not None else {}),
                          **({} if mixer is None
                             else {"ln1": dict(ln), MIXERS[mixer].name:
                                   MIXERS[mixer].axes(cfg)}),
@@ -729,12 +650,12 @@ def param_axes(cfg: TransformerConfig) -> Dict[str, Any]:
                             if sandwich and mixer is not None else {})}
         if kind in SUBLAYERS:
             # the sublayers' leaves carry their axis behind the layers'
-            by_kind[kind]["dense"] = {"w_gate": (LAYERS, EMBED, MLP),
-                                      "w_up": (LAYERS, EMBED, MLP),
-                                      "w_down": (LAYERS, MLP, EMBED)}
+            by_kind[kind].update(
+                FFNS[ffn_of(cfg, kind, sublayer=True)].axes(cfg))
             by_kind[kind].update(jax.tree.map(
                 lambda a: (a[0], None) + a[1:],
-                {name: by_kind[kind][name] for name in sublayer_leaves(kind)},
+                {name: by_kind[kind][name]
+                 for name in sublayer_leaves(cfg, kind)},
                 is_leaf=lambda a: isinstance(a, tuple)))
     axes: Dict[str, Any] = {
         "embed": {"tokens": (VOCAB, EMBED)},
@@ -1031,8 +952,9 @@ def quantize_model_weights(params: Dict[str, Any], bits: int = 8,
     for name in ("wq", "wk", "wv", "wo"):
         attn[name] = quant(attn[name], sh_of("layers", "attn", name))
     layers["attn"] = attn
-    if "router" not in layers:           # dense MLP only (skip MoE banks)
-        mlp = dict(layers["mlp"])
+    mlp = dict(layers["mlp"])
+    if mlp["w_up"].ndim == 3:   # a matrix a layer, which ``_qeinsum`` reads:
+        # a dense MLP only (an expert bank, a matrix an expert, stays dense)
         for name in ("w_up", "w_gate", "w_down"):
             if name in mlp:
                 mlp[name] = quant(mlp[name], sh_of("layers", "mlp", name))
@@ -2574,8 +2496,147 @@ def _mixer_half(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     return (x, h, attn_out, new_cache, *memory)
 
 
-def _expert_ffn(cfg: TransformerConfig, h: jax.Array, layer: Dict[str, Any],
+@dataclasses.dataclass(frozen=True)
+class Ffn:
+    """What an FFN IS, as ``Mixer`` is what a mixer is: every function that
+    must tell one FFN from another asks its record in ``FFNS``, and
+    ``ffn_of`` alone says which one a kind's layer has (docs/models.md)."""
+    init: Callable      # (cfg, normal) -> ONE layer's FFN leaves, each under
+    #   its top-level name in the layer
+    axes: Callable      # (cfg) -> the same names' logical axes
+    apply: Callable     # (cfg, h, layer, step, kind) -> (out, aux, *counts):
+    #   the FFN over the normed input ``h`` (``layer``: the layer's whole
+    #   tree); ``counts`` with ``step.moe_counts``, from an FFN that routes
+    count_width: Callable = lambda cfg: 0   # (cfg) -> entries of ``counts``
+    whole: Optional[str] = None     # the leaf whose WHOLE stack stays out of
+    #   the layer scan's slicing at inference (``Step.expert_banks``)
+
+
+_MATRIX_AXES = {"w_gate": (LAYERS, EMBED, MLP), "w_up": (LAYERS, EMBED, MLP),
+                "b_up": (LAYERS, MLP), "w_down": (LAYERS, MLP, EMBED),
+                "b_down": (LAYERS, EMBED)}
+
+
+def _biased_init(cfg: TransformerConfig, normal, name: str = "mlp",
+                 tags: Tuple[int, int] = (9, 10)) -> Dict[str, Any]:
+    H, F = cfg.hidden_size, cfg.ffn_hidden_size
+    return {name: {"w_up": normal(tags[0], (H, F)),
+                   "b_up": jnp.zeros((F,), cfg.dtype),
+                   "w_down": normal(tags[1], (F, H), _resid_std(cfg)),
+                   "b_down": jnp.zeros((H,), cfg.dtype)}}
+
+
+def _biased_axes(cfg: TransformerConfig, name: str = "mlp") -> Dict[str, Any]:
+    return {name: {k: _MATRIX_AXES[k]
+                   for k in ("w_up", "b_up", "w_down", "b_down")}}
+
+
+def _biased_ffn(cfg: TransformerConfig, h: jax.Array, layer: Dict[str, Any],
                 step: Step, kind: str):
+    """``down(act(up(h) + b_up)) + b_down``: the two-matrix FFN."""
+    w = layer["mlp"]
+    inner = _qeinsum("bsh,hf->bsf", h, w["w_up"], cfg.dtype, a8=cfg.a8_decode) + w["b_up"]
+    if cfg.activation == "relu":
+        inner = jax.nn.relu(inner)
+    elif cfg.activation == "relu2":
+        inner = jnp.square(jax.nn.relu(inner))
+    elif cfg.activation == "quick_gelu":
+        # CLIP's x*sigmoid(1.702x) (HF QuickGELUActivation)
+        inner = inner * jax.nn.sigmoid(1.702 * inner)
+    else:
+        inner = jax.nn.gelu(inner,
+                            approximate=cfg.activation != "gelu-exact")
+    return (_qeinsum("bsf,fh->bsh", inner, w["w_down"], cfg.dtype, a8=cfg.a8_decode) + w["b_down"],
+            jnp.float32(0.0))
+
+
+def _swiglu_ffn(name: str, width: Callable, tags: Tuple[int, int, int]
+                ) -> Ffn:
+    """The SwiGLU FFN's record in one of its forms: its leaves under
+    ``name``, ``width(cfg)`` wide, drawn under ``tags`` (gate, up, down)."""
+    def init(cfg, normal):
+        H, F = cfg.hidden_size, width(cfg)
+        return {name: {"w_gate": normal(tags[0], (H, F)),
+                       "w_up": normal(tags[1], (H, F)),
+                       "w_down": normal(tags[2], (F, H), _resid_std(cfg))}}
+
+    def axes(cfg):
+        return {name: {k: _MATRIX_AXES[k]
+                       for k in ("w_gate", "w_up", "w_down")}}
+
+    def apply(cfg, h, layer, step, kind):
+        return _swiglu(cfg, h, layer[name]), jnp.float32(0.0)
+
+    return Ffn(init, axes, apply)
+
+
+def _experts_init(cfg: TransformerConfig, normal) -> Dict[str, Any]:
+    H, F, E = cfg.hidden_size, cfg.ffn_hidden_size, cfg.moe_num_experts
+    held, resid_std = cfg.experts_held, _resid_std(cfg)
+    gated = cfg.activation == "swiglu"
+    # the router's outputs: the routed experts, then the zero-computation
+    # ones, which have no matrices below
+    layer = {"router": normal(4, (H, E + cfg.moe_zero_experts))}
+    if cfg.moe_router_bias:
+        # nonzero, or the choice-only bias would go untested; small, as a
+        # trained one is: sigmoid scores of the most probable experts lie
+        # within 0.005 of each other, and a bias of 0.1 would choose the same
+        # experts for every token. Softmax scores sum to one: beside them,
+        # their mean, 1 / outputs (at 768 outputs the 12th largest score is
+        # 0.012, and a bias of 0.01 gave 1 output in 64 to every token)
+        outputs = E + cfg.moe_zero_experts
+        layer["router_bias"] = normal(
+            12, (outputs,), 0.01 if cfg.moe_score_func == "sigmoid"
+            else 1.0 / outputs).astype(jnp.float32)
+    if cfg.moe_shared_experts:
+        Fs = cfg.shared_ffn_hidden_size
+        layer["shared"] = {"w_up": normal(14, (H, Fs)),
+                           "w_down": normal(15, (Fs, H), resid_std)}
+        if gated:
+            layer["shared"]["w_gate"] = normal(13, (H, Fs))
+    if cfg.moe_latent_size:
+        layer["latent"] = {
+            "w_in": normal(16, (H, cfg.moe_latent_size)),
+            "w_out": normal(17, (cfg.moe_latent_size, H), resid_std)}
+    if cfg.moe_use_residual:
+        layer.update(_biased_init(cfg, normal, "res_mlp", (5, 6)))
+        layer["res_coef"] = {"w": normal(7, (H, 2)),
+                             "b": jnp.zeros((2,), cfg.dtype)}
+    He = cfg.moe_latent_size or H       # the experts' own width
+    layer["mlp"] = {
+        "w_up": normal(9, (held, He, F)),
+        # in a latent it is the projection out of it that writes to the
+        # residual stream, not an expert's own
+        "w_down": normal(10, (held, F, He),
+                         0.02 if cfg.moe_latent_size else resid_std)}
+    if gated:
+        layer["mlp"]["w_gate"] = normal(8, (held, He, F))
+    return layer
+
+
+def _experts_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    gate = ("w_gate",) if cfg.activation == "swiglu" else ()
+    wide = None if cfg.moe_latent_size else EMBED   # the experts' width
+    axes = {"router": (LAYERS, EMBED, None),
+            "mlp": {"w_up": (LAYERS, EXPERT, wide, MLP),
+                    "w_down": (LAYERS, EXPERT, MLP, wide),
+                    **{k: (LAYERS, EXPERT, wide, MLP) for k in gate}}}
+    if cfg.moe_router_bias:
+        axes["router_bias"] = (LAYERS, None)
+    if cfg.moe_shared_experts:
+        axes["shared"] = {k: _MATRIX_AXES[k]
+                          for k in ("w_up", "w_down") + gate}
+    if cfg.moe_latent_size:
+        axes["latent"] = {"w_in": (LAYERS, EMBED, None),
+                          "w_out": (LAYERS, None, EMBED)}
+    if cfg.moe_use_residual:
+        axes.update(_biased_axes(cfg, "res_mlp"))
+        axes["res_coef"] = {"w": (LAYERS, EMBED, None), "b": (LAYERS, None)}
+    return axes
+
+
+def _experts_ffn(cfg: TransformerConfig, h: jax.Array, layer: Dict[str, Any],
+                 step: Step, kind: str):
     """A layer's FFN of experts over the normed input ``h`` -> ``(out, aux,
     *counts)``: the routed experts (``parallel/moe.moe_mlp``; ``counts`` with
     ``step.moe_counts``), the shared expert and the PR-MoE residual where
@@ -2588,8 +2649,7 @@ def _expert_ffn(cfg: TransformerConfig, h: jax.Array, layer: Dict[str, Any],
     infer = step.cache is not None or cfg.moe_dropless_only
     rts_rng = (_activation_derived_key(h, 0)
                if (cfg.moe_use_rts and not infer) else None)
-    banks = (None if step.expert_banks is None
-             else step.expert_banks[kind])
+    banks = (step.expert_banks or {}).get(kind)
     mlp_out, aux, *counts = moe_mlp(
         h, layer["router"], layer["mlp"] if banks is None else banks,
         cfg.activation,
@@ -2626,6 +2686,22 @@ def _expert_ffn(cfg: TransformerConfig, h: jax.Array, layer: Dict[str, Any],
     return (mlp_out, aux, *counts)
 
 
+# keyed by what ``ffn_of`` answers; nothing reads it at import. "dense" is
+# the SwiGLU FFN in its second form: a sublayer's own (``SUBLAYERS``),
+# ``dense_ffn_hidden_size`` wide, with its own leaf and draws
+FFNS: Dict[str, Ffn] = {
+    "biased": Ffn(_biased_init, _biased_axes, _biased_ffn),
+    "swiglu": _swiglu_ffn("mlp", lambda cfg: cfg.ffn_hidden_size, (8, 9, 10)),
+    # three counts, and a fourth BEHIND them, the assignments to
+    # zero-computation experts, only where the model has such experts
+    "experts": Ffn(_experts_init, _experts_axes, _experts_ffn,
+                   count_width=lambda cfg: 3 + (cfg.moe_zero_experts > 0),
+                   whole="mlp"),
+    "dense": _swiglu_ffn("dense", lambda cfg: cfg.dense_ffn_hidden_size,
+                         (80, 81, 82)),
+}
+
+
 def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                    step: Step, kind: str = "attn"):
     """One decoder block: ``x + mixer(norm(x))``, then the FFN. ``kind``
@@ -2641,7 +2717,8 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
     if kind in SUBLAYERS:
         return _sublayers_forward(cfg, x, layer, step, kind)
     cache = step.cache
-    mixer, has_ffn = LAYER_KINDS[kind]
+    mixer, ffn = LAYER_KINDS[kind][0], ffn_of(cfg, kind)
+    has_ffn = ffn is not None
     post_ln = cfg.norm_position == "post"
     if not (mixer and has_ffn) and (post_ln or cfg.parallel_residual):
         raise NotImplementedError(
@@ -2664,24 +2741,7 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
         from ..compression.compress import fake_quant_activation
 
         h = fake_quant_activation(h, cfg.act_quant_bits)   # MLP input
-    aux, counts = jnp.float32(0.0), []
-    if cfg.moe_num_experts > 0:
-        mlp_out, aux, *counts = _expert_ffn(cfg, h, layer, step, kind)
-    elif cfg.activation == "swiglu":
-        mlp_out = _swiglu(cfg, h, layer["mlp"])
-    else:
-        inner = _qeinsum("bsh,hf->bsf", h, layer["mlp"]["w_up"], cfg.dtype, a8=cfg.a8_decode) + layer["mlp"]["b_up"]
-        if cfg.activation == "relu":
-            inner = jax.nn.relu(inner)
-        elif cfg.activation == "relu2":
-            inner = jnp.square(jax.nn.relu(inner))
-        elif cfg.activation == "quick_gelu":
-            # CLIP's x*sigmoid(1.702x) (HF QuickGELUActivation)
-            inner = inner * jax.nn.sigmoid(1.702 * inner)
-        else:
-            inner = jax.nn.gelu(inner,
-                                approximate=cfg.activation != "gelu-exact")
-        mlp_out = _qeinsum("bsf,fh->bsh", inner, layer["mlp"]["w_down"], cfg.dtype, a8=cfg.a8_decode) + layer["mlp"]["b_down"]
+    mlp_out, aux, *counts = FFNS[ffn].apply(cfg, h, layer, step, kind)
     if cfg.norm_position == "sandwich":
         mlp_out = _norm(mlp_out, layer["ln2_post"]["scale"],
                         layer["ln2_post"].get("bias"), cfg.norm, cfg.norm_eps)
@@ -2704,8 +2764,8 @@ def _sublayers_forward(cfg: TransformerConfig, x: jax.Array,
 
         for i in sublayers:
             a = x + mixer_i(norm(x; ln1_i));  h = norm(a; ln2_i)
-            if i == 0: s = experts(h)             # read here ...
-            x = a + swiglu_i(h)                   # dense_ffn_hidden_size wide
+            if i == 0: s = ffn(h)                 # the layer's: read here ...
+            x = a + dense_i(h)                    # the sublayer's own
         x = x + s                                 # ... joined here
 
     The leaves ``ln1``, the mixer's, ``ln2`` and ``dense`` carry the
@@ -2713,9 +2773,11 @@ def _sublayers_forward(cfg: TransformerConfig, x: jax.Array,
     ``SUBLAYERS[kind] * step.layer_index + i`` (``Step.pool_index``). The
     expert FFN (router, bias, bank) is the layer's one."""
     mixer, n = LAYER_KINDS[kind][0], SUBLAYERS[kind]
+    ffn = FFNS[ffn_of(cfg, kind)]
+    dense = FFNS[ffn_of(cfg, kind, sublayer=True)]
     if step.sublayer_stacks is None:
         halves, at_layer = {name: layer[name]
-                            for name in sublayer_leaves(kind)}, ()
+                            for name in sublayer_leaves(cfg, kind)}, ()
     else:   # the whole stacks, this layer's taken where it lies
         halves, at_layer = step.sublayer_stacks[kind], (step.layer_index,)
     cache = step.cache
@@ -2725,8 +2787,8 @@ def _sublayers_forward(cfg: TransformerConfig, x: jax.Array,
             None if step.layer_index is None else n * step.layer_index + i))
         x, h, _, cache = _mixer_half(cfg, x, sub, mixer, True, at)
         if i == 0:
-            shortcut, aux, *counts = _expert_ffn(cfg, h, layer, step, kind)
-        x = x + _swiglu(cfg, h, sub["dense"])
+            shortcut, aux, *counts = ffn.apply(cfg, h, layer, step, kind)
+        x = x + dense.apply(cfg, h, sub, at, kind)[0]
     return (x + shortcut, cache, aux, *counts)
 
 
@@ -2801,11 +2863,14 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     are the ROWS', (R, 1, V): nobody reads a chunk's that is not its
     prompt's last, and no other chunk rides a mixed step."""
     B, S = input_ids.shape
+    ffns = {kind: FFNS[ffn_of(cfg, kind)] for kind in set(layer_kinds(cfg))
+            if ffn_of(cfg, kind)}
+    routes = any(record.count_width(cfg) for record in ffns.values())
     divide = None
     if mixed_chunk is not None:
         divide = S - mixed_chunk["positions"].shape[1]
         if (block_table is None or cfg.layer_runs or cfg.loop_passes > 1
-                or cfg.moe_num_experts > 0 or B != 1 or divide < 1
+                or routes or B != 1 or divide < 1
                 or positions is None or positions.shape != (divide, 1)):
             raise ValueError(
                 "a mixed step is one flat run (1, R + C) over the paged "
@@ -2841,7 +2906,7 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                                     or positions.ndim != 2):
         raise ValueError("paged mode (block_table) requires cache= and "
                          "explicit (B, S) positions")
-    if moe_counts and (block_table is None or cfg.moe_num_experts <= 0):
+    if moe_counts and (block_table is None or not routes):
         raise ValueError("moe_counts needs an MoE model in paged mode")
     static_prefill = (cache is not None and block_table is None
                       and isinstance(start_pos, int) and start_pos == 0)
@@ -2927,15 +2992,18 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     # layer scan's slicing, and go down with the layer's index (see
     # ``Step.expert_banks``)
     banks = None
-    if cache is not None and cfg.moe_num_experts > 0:
-        banks = {kind: tree.get("mlp") for kind, tree in stacks.items()}
-        stacks = {kind: {k: v for k, v in tree.items() if k != "mlp"}
+    whole = {kind: record.whole for kind, record in ffns.items()
+             if record.whole}
+    if cache is not None and whole:
+        banks = {kind: stacks[kind][leaf] for kind, leaf in whole.items()}
+        stacks = {kind: {k: v for k, v in tree.items()
+                         if k != whole.get(kind)}
                   for kind, tree in stacks.items()}
     # and so do the leaves that the sublayers of a double layer own
     # (``Step.sublayer_stacks``)
     halves = None
     if cache is not None and set(stacks) & set(SUBLAYERS):
-        halves = {kind: {k: tree[k] for k in sublayer_leaves(kind)}
+        halves = {kind: {k: tree[k] for k in sublayer_leaves(cfg, kind)}
                   for kind, tree in stacks.items() if kind in SUBLAYERS}
         stacks = {kind: {k: v for k, v in tree.items()
                          if k not in halves.get(kind, ())}

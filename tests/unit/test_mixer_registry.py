@@ -7,6 +7,12 @@ leaf by leaf); a FOURTH mixer that only this file knows goes through init,
 axes, the cache's shapes and ``forward`` with and without pages; the state
 pools of the two recurrent families have the shapes they had; and no module
 but the model's own names a mixer by its string.
+
+The same for an FFN since PR 65: ONE record in ``FFNS`` and ONE function,
+``ffn_of``, that says which a kind's layer has. Digests of the dense SwiGLU,
+the sublayers' dense FFN, zero experts and the PR-MoE residual from the
+parent of PR 65; a FOURTH FFN that only this file knows, in every second
+layer; and no module but the model's own names an expert leaf.
 """
 
 import ast
@@ -22,14 +28,16 @@ import pytest
 
 from deepspeed_tpu.inference import kv_cache
 from deepspeed_tpu.models import transformer as T
-from deepspeed_tpu.models.core import EMBED, LAYERS
+from deepspeed_tpu.models.core import EMBED, LAYERS, MLP
 from deepspeed_tpu.models.presets import transformer_config
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
 
 # sha256 (first ten hex digits) of every leaf of
-# ``init_params(PRNGKey(0), transformer_config(preset))`` at c6e4150, the
-# parent of PR 50, on the CPU
+# ``init_params(PRNGKey(0), transformer_config(preset))`` on the CPU: the
+# first four at c6e4150, the parent of PR 50; the others (a key
+# ``preset+flag`` is the preset with that flag set) at 32d3238, the parent of
+# PR 65, before the FFNs moved behind ``FFNS``
 PARENT_DIGESTS = {
     "tiny-opt": """
         embed/tokens=6865c2299c final_norm/bias=5341e6b264
@@ -94,6 +102,99 @@ PARENT_DIGESTS = {
         layers/mamba2_mixer/mamba2/norm=71f3f0e945
         layers/mamba2_mixer/mamba2/w_in=e7edfa5033
         layers/mamba2_mixer/mamba2/w_out=fd5410a44f lm_head=1d87c77b8f""",
+    "tiny-llama": """
+        embed/tokens=6865c2299c final_norm/scale=2f20cd03c9
+        layers/attn/wk=4479327429 layers/attn/wo=881bdeb2da
+        layers/attn/wq=6f783c6a6d layers/attn/wv=2e1e8a9c63
+        layers/ln1/scale=02722f124d layers/ln2/scale=02722f124d
+        layers/mlp/w_down=90cb5f6a23 layers/mlp/w_gate=b49b20f6d0
+        layers/mlp/w_up=2485f9c27d lm_head=1d87c77b8f""",
+    "tiny-phi4flash": """
+        embed/tokens=6865c2299c final_norm/bias=5341e6b264
+        final_norm/scale=2f20cd03c9 layers/cross/attn/bo=350070fd25
+        layers/cross/attn/bq=b0189c0f1b layers/cross/attn/lam_init=aa58269973
+        layers/cross/attn/lam_k1=b019daabc0
+        layers/cross/attn/lam_k2=9afe701f5a
+        layers/cross/attn/lam_q1=5b55b82927
+        layers/cross/attn/lam_q2=2a3e6d0c0e layers/cross/attn/subln=9628e545ed
+        layers/cross/attn/wo=aa9b5c272c layers/cross/attn/wq=e61db7b240
+        layers/cross/ln1/bias=5341e6b264 layers/cross/ln1/scale=2f20cd03c9
+        layers/cross/ln2/bias=5341e6b264 layers/cross/ln2/scale=2f20cd03c9
+        layers/cross/mlp/w_down=48ce97928d layers/cross/mlp/w_gate=27fa6e527e
+        layers/cross/mlp/w_up=4493520d4e layers/full/attn/bk=07c5eda096
+        layers/full/attn/bo=b459c3c99c layers/full/attn/bq=34f6ed6b04
+        layers/full/attn/bv=44568004f2 layers/full/attn/lam_init=b8d416d50a
+        layers/full/attn/lam_k1=870fdf4847 layers/full/attn/lam_k2=d525effc8e
+        layers/full/attn/lam_q1=b8f082cd3f layers/full/attn/lam_q2=a0185be967
+        layers/full/attn/subln=9628e545ed layers/full/attn/wk=136ece3564
+        layers/full/attn/wo=48110d70aa layers/full/attn/wq=3943bd36bf
+        layers/full/attn/wv=368ee1efc8 layers/full/ln1/bias=5341e6b264
+        layers/full/ln1/scale=2f20cd03c9 layers/full/ln2/bias=5341e6b264
+        layers/full/ln2/scale=2f20cd03c9 layers/full/mlp/w_down=e320a65c51
+        layers/full/mlp/w_gate=37f287be12 layers/full/mlp/w_up=d8e98f1e9b
+        layers/gmu/gmu/w_in=97950dd0c3 layers/gmu/gmu/w_out=662fac0a51
+        layers/gmu/ln1/bias=5341e6b264 layers/gmu/ln1/scale=2f20cd03c9
+        layers/gmu/ln2/bias=5341e6b264 layers/gmu/ln2/scale=2f20cd03c9
+        layers/gmu/mlp/w_down=ec10b90a95 layers/gmu/mlp/w_gate=e6223e3d6c
+        layers/gmu/mlp/w_up=2a5ab9b3c7 layers/mamba1/ln1/bias=ef115a0e0c
+        layers/mamba1/ln1/scale=5ce183e97a layers/mamba1/ln2/bias=ef115a0e0c
+        layers/mamba1/ln2/scale=5ce183e97a
+        layers/mamba1/mamba1/A_log=f81f956b93
+        layers/mamba1/mamba1/D=702cc37cfd
+        layers/mamba1/mamba1/conv_b=3a67a4ee28
+        layers/mamba1/mamba1/conv_w=8246f8f5a1
+        layers/mamba1/mamba1/dt_bias=4b03128f8a
+        layers/mamba1/mamba1/w_dt=1310a13194
+        layers/mamba1/mamba1/w_in=682920e888
+        layers/mamba1/mamba1/w_out=6af74b8054
+        layers/mamba1/mamba1/w_x=44b52ca7f0
+        layers/mamba1/mlp/w_down=55a6350b3e
+        layers/mamba1/mlp/w_gate=2163605c29 layers/mamba1/mlp/w_up=5e0d5ce259
+        layers/swa/attn/bk=9efbc90ca6 layers/swa/attn/bo=8770a13397
+        layers/swa/attn/bq=f269259a66 layers/swa/attn/bv=143854c0af
+        layers/swa/attn/lam_init=170dc9ae5c layers/swa/attn/lam_k1=f751cbb02d
+        layers/swa/attn/lam_k2=af66f2f7d9 layers/swa/attn/lam_q1=4b519d803f
+        layers/swa/attn/lam_q2=c4c54da73e layers/swa/attn/subln=b638277a86
+        layers/swa/attn/wk=b93925633b layers/swa/attn/wo=0144562891
+        layers/swa/attn/wq=faf492c1bb layers/swa/attn/wv=af9e1c4b7a
+        layers/swa/ln1/bias=076a27c79e layers/swa/ln1/scale=02722f124d
+        layers/swa/ln2/bias=076a27c79e layers/swa/ln2/scale=02722f124d
+        layers/swa/mlp/w_down=77c9f60f56 layers/swa/mlp/w_gate=e41abf2ac7
+        layers/swa/mlp/w_up=86df150b30""",
+    "tiny-ouro": """
+        embed/tokens=6865c2299c exit_gate/b=f04da81a20 exit_gate/w=c11455c38e
+        final_norm/scale=2f20cd03c9 layers/attn/wk=12281c7974
+        layers/attn/wo=50b5ec92b4 layers/attn/wq=630b34146f
+        layers/attn/wv=2be654f8e7 layers/ln1/scale=893a106828
+        layers/ln1_post/scale=893a106828 layers/ln2/scale=893a106828
+        layers/ln2_post/scale=893a106828 layers/mlp/w_down=b851ad6f75
+        layers/mlp/w_gate=12bfdbf19d layers/mlp/w_up=e3b19870ca
+        lm_head=1d87c77b8f""",
+    "tiny-longcat-flash": """
+        embed/tokens=6865c2299c final_norm/scale=2f20cd03c9
+        layers/dense/w_down=02f5e08d4f layers/dense/w_gate=8d94006bfc
+        layers/dense/w_up=76d96c7d57 layers/ln1/scale=893a106828
+        layers/ln2/scale=893a106828 layers/mla/kv_norm=23a90de394
+        layers/mla/q_norm=02722f124d layers/mla/wk_b=6041523cc1
+        layers/mla/wkv_a=71ee45d9b9 layers/mla/wo=980422941f
+        layers/mla/wq_a=9a865c0904 layers/mla/wq_b=22023940c3
+        layers/mla/wv_b=dac42ceef6 layers/mlp/w_down=659fc22140
+        layers/mlp/w_gate=f553d62dd4 layers/mlp/w_up=00a682abf9
+        layers/router=b7b1df4893 layers/router_bias=f77a741377
+        lm_head=1d87c77b8f""",
+    "moe-tiny+moe_use_residual": """
+        embed/tokens=6865c2299c final_norm/bias=5341e6b264
+        final_norm/scale=2f20cd03c9 layers/attn/bk=076a27c79e
+        layers/attn/bo=076a27c79e layers/attn/bq=076a27c79e
+        layers/attn/bv=076a27c79e layers/attn/wk=6340c995c5
+        layers/attn/wo=881bdeb2da layers/attn/wq=6f783c6a6d
+        layers/attn/wv=911a95ba58 layers/ln1/bias=076a27c79e
+        layers/ln1/scale=02722f124d layers/ln2/bias=076a27c79e
+        layers/ln2/scale=02722f124d layers/mlp/w_down=862373f451
+        layers/mlp/w_up=479370d1b3 layers/res_coef/b=374708fff7
+        layers/res_coef/w=25e67b1d30 layers/res_mlp/b_down=076a27c79e
+        layers/res_mlp/b_up=e5a00aa999 layers/res_mlp/w_down=b769db4c25
+        layers/res_mlp/w_up=497da58b47 layers/router=6a593738bf pos=53e217c7b8""",
 }
 
 
@@ -101,11 +202,14 @@ PARENT_DIGESTS = {
 def test_seeded_parameters_are_the_parents_bits(preset):
     """The harness's reference compares on seeded weights: a mixer's
     ``fold_in`` tags, shapes and dtypes are its own for good."""
-    params = T.init_params(jax.random.PRNGKey(0), transformer_config(preset))
+    preset, *flags = preset.split("+")
+    params = T.init_params(jax.random.PRNGKey(0), transformer_config(
+        preset, **dict.fromkeys(flags, True)))
     got = {"/".join(k.key for k in path):
            hashlib.sha256(np.asarray(leaf).tobytes()).hexdigest()[:10]
            for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]}
-    want = dict(item.split("=") for item in PARENT_DIGESTS[preset].split())
+    want = dict(item.split("=") for item in PARENT_DIGESTS[
+        "+".join([preset, *flags])].split())
     assert sorted(got) == sorted(want)
     assert {k: v for k, v in got.items() if v != want[k]} == {}
 
@@ -229,6 +333,119 @@ def test_a_fourth_mixer_runs_forward_with_and_without_pages(mean_model):
     assert float(jnp.abs(cache["state"][:, 0] - 7.0).max()) == 0.0
 
 
+# ---------------------------------------------------------------------------
+# a fourth FFN, registered here alone: a product of two projections
+# ---------------------------------------------------------------------------
+
+
+def _bilinear_ffn(cfg, h, layer, step, kind):
+    w = layer["bilinear"]
+    pair = jnp.einsum("bsh,hf->bsf", h, w["a"]) \
+        * jnp.einsum("bsh,hf->bsf", h, w["b"])
+    return jnp.einsum("bsf,fh->bsh", pair, w["c"]) + w["bias"], \
+        jnp.float32(0.0)
+
+
+BILINEAR = T.Ffn(
+    init=lambda cfg, normal: {"bilinear": {
+        "a": normal(91, (cfg.hidden_size, 48), 0.5),
+        "b": normal(92, (cfg.hidden_size, 48), 0.5),
+        "c": normal(93, (48, cfg.hidden_size)),
+        "bias": jnp.zeros((cfg.hidden_size,), cfg.dtype)}},
+    axes=lambda cfg: {"bilinear": {
+        "a": (LAYERS, EMBED, MLP), "b": (LAYERS, EMBED, MLP),
+        "c": (LAYERS, MLP, EMBED), "bias": (LAYERS, EMBED)}},
+    apply=_bilinear_ffn)
+
+
+@pytest.fixture
+def bilinear_model(mean_model, monkeypatch):
+    """``mean_model`` whose "mean" layers have the bilinear FFN and whose
+    "attn" layers keep tiny-llama's SwiGLU: an FFN that differs by layer.
+    Nothing of the package is edited: the FFN is one table entry, and which
+    layers have it is one line of ``ffn_of``."""
+    cfg, _ = mean_model
+    ffn_of = T.ffn_of
+    monkeypatch.setitem(T.FFNS, "bilinear", BILINEAR)
+    monkeypatch.setattr(
+        T, "ffn_of", lambda cfg, kind, sublayer=False: "bilinear"
+        if kind == "mean" and not sublayer else ffn_of(cfg, kind, sublayer))
+    return cfg, T.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def test_a_fourth_ffn_has_params_and_axes(bilinear_model, mean_model):
+    cfg, params = bilinear_model
+    H = cfg.hidden_size
+    assert sorted(params["layers"]["mean"]) == ["bilinear", "ln1", "ln2",
+                                                "mean"]
+    assert sorted(params["layers"]["attn"]) == ["attn", "ln1", "ln2", "mlp"]
+    assert params["layers"]["mean"]["bilinear"]["a"].shape == (2, H, 48)
+    # the layers of the other kind, and this kind's mixer, drew what they drew
+    for path, leaf in jax.tree_util.tree_flatten_with_path(mean_model[1])[0]:
+        if "mlp" not in [k.key for k in path]:
+            mine = params
+            for k in path:
+                mine = mine[k.key]
+            assert np.array_equal(np.asarray(mine), np.asarray(leaf)), path
+    axes = T.param_axes(cfg)
+    assert axes["layers"]["mean"]["bilinear"]["c"] == (LAYERS, MLP, EMBED)
+    assert "mlp" in axes["layers"]["attn"] \
+        and "mlp" not in axes["layers"]["mean"]
+    is_axes = lambda x: isinstance(x, tuple)       # noqa: E731
+    assert (jax.tree.structure(axes, is_leaf=is_axes)
+            == jax.tree.structure(params))
+    for a, leaf in zip(jax.tree.leaves(axes, is_leaf=is_axes),
+                       jax.tree.leaves(params)):
+        assert len(a) == leaf.ndim
+    assert sorted(T.ffn_layers(cfg)) == [0, 1, 2, 3]
+    assert T.moe_count_width(cfg) == 3
+
+
+def test_a_fourth_ffn_runs_forward_with_and_without_pages(bilinear_model):
+    """As the fourth mixer's: the whole sequence with no cache against a
+    ragged chunk of 5 (padded to 8) and then a token a step through the
+    pages and the state pools; and training differentiates through it."""
+    cfg, params = bilinear_model
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 9), 0,
+                             cfg.vocab_size)
+    want, _, aux = T.forward(params, ids, cfg)
+    assert np.isfinite(np.asarray(want)).all() and float(aux) == 0.0
+    # the bilinear FFN is in the program: without its weights the logits move
+    zeroed = jax.tree.map(lambda a: a, params)
+    zeroed["layers"]["mean"]["bilinear"]["c"] *= 0
+    assert np.abs(np.asarray(T.forward(zeroed, ids, cfg)[0] - want)).max() \
+        > 1e-3
+    grads = jax.grad(T.build_model(cfg).loss_fn)(params, {"input_ids": ids})
+    assert float(jnp.abs(grads["layers"]["mean"]["bilinear"]["a"]).max()) > 0
+
+    cache = kv_cache.init_paged_cache(cfg, 9, 4, jnp.float32, state_slots=3)
+    table = jnp.asarray([[1, 2, 3, 0]], jnp.int32)
+    slots = jnp.asarray([1], jnp.int32)
+
+    def run(cache, tokens, pos, mask, **how):
+        logits, cache, _ = T.forward(
+            params, tokens, cfg, cache=cache, positions=pos,
+            block_table=table, paged_write_mask=mask, state_slots=slots,
+            **how)
+        return np.asarray(logits), cache
+
+    chunk = jnp.zeros((1, 8), jnp.int32).at[0, :5].set(ids[0, :5])
+    got, cache = run(cache, chunk, jnp.arange(8)[None],
+                     jnp.arange(8)[None] < 5,
+                     paged_run=(jnp.int32(0), jnp.int32(5)))
+    out = [got[0, :5]]
+    for p in range(5, 9):
+        got, cache = run(cache, ids[:, p:p + 1], jnp.asarray([[p]]),
+                         jnp.ones((1, 1), bool))
+        out.append(got[0])
+    np.testing.assert_allclose(np.concatenate(out), np.asarray(want)[0],
+                               atol=2e-5)
+    # it routes nothing: routing counts are refused, as for any dense model
+    with pytest.raises(ValueError, match="moe_counts"):
+        run(cache, ids[:, :1], jnp.asarray([[0]]), jnp.ones((1, 1), bool),
+            moe_counts=True)
+
+
 @pytest.mark.parametrize("preset,shapes", [
     ("tiny-solar-open2", {"state": (3, 5, 4, 16, 16), "tail": (3, 5, 3, 192)}),
     ("tiny-nemotron-3-super", {"state": (5, 5, 2, 16, 64),
@@ -266,6 +483,79 @@ def test_only_the_model_names_a_mixer_by_string():
     # and inside the owner: the table, LAYER_KINDS, nothing that branches
     source = (package / "models" / "transformer.py").read_text()
     assert "if mixer ==" not in source and "elif mixer ==" not in source
+
+
+EXPERT_LEAVES = {"router", "router_bias", "res_mlp", "res_coef"}
+
+
+def test_only_the_model_names_an_expert_leaf():
+    """What an FFN is lives in its record: outside ``models/transformer.py``
+    and ``models/presets.py`` no code of the package holds the literal of a
+    leaf that only the experts' FFN has. A docstring may."""
+    package = REPO / "deepspeed_tpu"
+    owners = {package / "models" / "transformer.py",
+              package / "models" / "presets.py"}
+    for path in sorted(set(package.rglob("*.py")) - owners):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Constant) and node.value in EXPERT_LEAVES:
+                raise AssertionError(
+                    f"{path.relative_to(REPO)}:{node.lineno}: "
+                    f"{node.value!r}")
+
+
+@pytest.mark.parametrize("function", [
+    "init_layer_params", "param_axes", "_layer_forward",
+    "_sublayers_forward", "forward", "moe_count_width",
+    "quantize_model_weights"])
+def test_a_function_of_the_stack_asks_which_ffn(function):
+    """The functions that walk a layer ask ``ffn_of`` and the record: none
+    reads ``cfg.moe_num_experts``, tests the activation for SwiGLU or holds
+    an expert leaf's name (its docstring may)."""
+    tree = ast.parse(inspect.getsource(getattr(T, function)))
+    doc = ast.get_docstring(tree.body[0])
+    for node in ast.walk(tree):
+        assert not (isinstance(node, ast.Attribute)
+                    and node.attr == "moe_num_experts"), node.lineno
+        if isinstance(node, ast.Constant) and node.value != doc:
+            assert node.value not in EXPERT_LEAVES | {"swiglu"}, node.lineno
+
+
+def test_one_function_says_which_ffn_a_layer_has():
+    """Of the model's functions ``ffn_of`` alone compares
+    ``cfg.moe_num_experts`` to choose an FFN; the loss adds the experts'
+    auxiliary term under the same test, and nothing else."""
+    tree = ast.parse(inspect.getsource(T))
+    assert {fn.name for fn in ast.walk(tree)
+            if isinstance(fn, ast.FunctionDef) and any(
+                isinstance(n, ast.Compare)
+                and isinstance(n.left, ast.Attribute)
+                and n.left.attr == "moe_num_experts" for n in ast.walk(fn))
+            } == {"ffn_of", "build_model", "make_loss", "loss_fn"}
+
+
+def test_a_record_says_what_its_ffn_counts_and_keeps_whole():
+    """The experts' routing counts and the bank that stays out of the layer
+    scan are its record's word; ``ffn_of`` answers from the configuration as
+    the three chains did."""
+    assert sorted(T.FFNS) == ["biased", "dense", "experts", "swiglu"]
+    assert {name: record.whole for name, record in T.FFNS.items()} == {
+        "biased": None, "dense": None, "experts": "mlp", "swiglu": None}
+    longcat = transformer_config("tiny-longcat-flash")
+    assert T.FFNS["experts"].count_width(longcat) == 4 \
+        == T.moe_count_width(longcat)
+    assert T.moe_count_width(transformer_config("tiny-olmoe")) == 3
+    assert T.FFNS["swiglu"].count_width(longcat) == 0
+    assert (T.ffn_of(longcat, "shortcut"),
+            T.ffn_of(longcat, "shortcut", sublayer=True)) == ("experts",
+                                                              "dense")
+    nemotron = transformer_config("tiny-nemotron-3-super")
+    assert [T.ffn_of(nemotron, k) for k in ("mamba2_mixer", "attn_mixer",
+                                            "ffn")] == [None, None, "experts"]
+    for preset, ffn in [("tiny-opt", "biased"), ("tiny-llama", "swiglu"),
+                        ("tiny-phi4flash", "swiglu"), ("moe-tiny", "experts")]:
+        cfg = transformer_config(preset)
+        assert {T.ffn_of(cfg, k) for k in T.layer_kinds(cfg)} == {ffn}
+        assert T.ffn_of(cfg, "attn", sublayer=True) is None
 
 
 @pytest.mark.parametrize("mixer,keeps,state,hands_on", [
