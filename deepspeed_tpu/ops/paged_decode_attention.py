@@ -20,7 +20,13 @@ calls and the one place kernel or reference is chosen):
   table slot past a row's last resident page: the work follows the tokens
   in the cache, not ``max_model_len``. P is derived (``_pages_per_tile``:
   what k + v, two buffers each, fit the VMEM budget, up to 256 keys a
-  tile). All heads of a tile are scored in ONE product against q laid out
+  tile). Where a page is small enough that its descriptors and not its
+  bytes set the walk's pace (``_unrolls_whole_tiles``: under 64 KiB a
+  side), a tile whose every page is resident takes its starts unrolled and
+  ONE wait a side (``_page_copies``, ``whole_tiles``); a row's last tile,
+  and every tile of wider pages, keeps a loop of starts and a loop of
+  waits. All heads of a tile are
+  scored in ONE product against q laid out
   block-diagonally over the ``K*D`` lanes, and weigh the values in one
   more: bf16 keys and values as stored, scores, softmax state and
   accumulator in float32, ``p`` into the value product as float32 (three
@@ -36,10 +42,12 @@ calls and the one place kernel or reference is chosen):
   alone. What set its pace was not the products (they run at the MXU's own
   pace) but the descriptors, a serial scalar chain a page: so a tile holds
   twice the keys (``_pages_per_tile`` with one side), a tile known whole
-  takes its starts unrolled and ONE wait (``_page_copies``,
-  ``whole_tiles``), and the tile's body is built once a buffer, so that
-  every address in it is static. The arithmetic is the two-pool walk's,
-  term for term.
+  takes its starts unrolled and ONE wait as the two-pool walk's does, and
+  the tile's body is built once a buffer, so that every address in it is
+  static (the two-pool walk takes its buffer as a traced value: built once
+  a buffer it read 2-4% faster alone, and cost every program that holds it
+  a second more of lowering, PERF.md section 6, PR 66). The arithmetic is
+  the two-pool walk's, term for term.
 * ``paged_prefill_attention`` — the same walk under a chunk of queries: C
   queries a row at absolute positions ``start..start+C-1``, of which the
   row's ``length`` says how many are real (``start + n_valid`` keys). The
@@ -87,7 +95,12 @@ stored, and the kernels slice heads out of it by static lane offsets.
 
 The three kernels share the walk's copies and nothing else
 (``_page_copies``, ``_first_tile``, ``_tile_arrives``, over a tuple of
-sides: keys and values, or the one pool).
+sides: keys and values, or the one pool). The latent walk asks for
+``whole_tiles``, the two-pool walk where ``_unrolls_whole_tiles`` says its
+pages gain by it; the kernel under a chunk of queries does not, its copies
+being noise beside its products, and keeps a loop of starts and a loop of
+waits for every tile. ``walk_page_counts`` tells the serving engine how
+many of a step's pages the form takes, by the same two rules.
 
 ``reference_paged_attention`` is the pure-jnp oracle and CPU fallback:
 GQA-native over the view gathered straight from the arena
@@ -98,10 +111,11 @@ materialization).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -171,6 +185,44 @@ def _pages_per_tile(block_size: int, width: int, dtype,
     return pages
 
 
+# the two-pool walk takes a whole tile's starts unrolled where a page, one
+# side of it, is smaller than this. Measured, the walk alone and forced
+# either way (PERF.md section 6, PR 66): at 64 KiB a page (2,048 lanes of
+# bf16, 16 keys) the form reads nothing at any rows tried, 16 or 64 of them,
+# 40 to 2,000 tokens long (a page's bytes take longer to arrive than its
+# descriptors to issue), and costs a call of 16 short rows 0.5-0.9 us; at
+# 40, 32 and 8 KiB it reads -4, -5 and -9% at 64 rows of some hundred to
+# 2,000 tokens, and nothing at 16 short ones
+_WHOLE_TILE_PAGE_BYTES = 64 * 1024
+
+
+def _unrolls_whole_tiles(block_size: int, width: int, dtype) -> bool:
+    """Whether the two-pool decode walk over pages ``(block_size, width)``
+    asks ``_page_copies`` for ``whole_tiles``: by the page's bytes, which
+    say whether its descriptors or its bytes set the walk's pace."""
+    return (block_size * width * jnp.dtype(dtype).itemsize
+            < _WHOLE_TILE_PAGE_BYTES)
+
+
+def walk_page_counts(lengths, arena, latent: bool = False) -> Dict[str, int]:
+    """What a decode step's walks over ``arena`` (..., BLOCK, lanes) meet,
+    from its rows' ``lengths`` (host integers): ``walk_pages``, the resident
+    pages summed over the rows, and ``walk_pages_whole``, those of them
+    whose copies take the whole-tile form of ``_page_copies``: the pages of
+    tiles whose every page is resident (all but a row's last tile, and that
+    one where its pages fill it), where the walk asks for the form at all
+    (``latent``, the one-pool walk: always; the two-pool walk by
+    ``_unrolls_whole_tiles``, so 0 over wide pages)."""
+    block, width = arena.shape[-2:]
+    pages = (np.asarray(lengths) + (block - 1)) // block
+    whole = 0
+    if latent or _unrolls_whole_tiles(block, width, arena.dtype):
+        tile = _pages_per_tile(block, width, arena.dtype,
+                               sides=1 if latent else 2)
+        whole = int((pages // tile).sum()) * tile
+    return {"walk_pages": int(pages.sum()), "walk_pages_whole": whole}
+
+
 def _dot_f32(a, b, b_dim: int):
     """``a`` (M, C) times ``b``, contracted over ``b``'s dim ``b_dim``,
     summed in float32 with every bit of ``a``, whatever ``b``'s dtype: ``a``
@@ -198,12 +250,22 @@ def _page_copies(bt_ref, len_ref, layer, sides, sems, whole_tiles=False):
     resident page. ``sides``: the ``(arena, buffer)`` pairs a page is copied
     for (keys and values, or one pool that is both).
 
-    ``whole_tiles``: a tile whose every page is resident (all but a row's
-    last) takes its starts UNROLLED, so that the scalar work of one page
-    (the table read, the address, the bounds checks) is packed beside the
-    next one's and not a loop's serial chain, and ONE wait for the tile's
-    bytes (a copy's wait takes its byte count off the semaphore, so a
-    descriptor as large as the tile waits for all of its pages)."""
+    ``whole_tiles`` (the latent walk, and the two-pool walk over pages
+    small enough to gain by it, ``_unrolls_whole_tiles``; not the kernel
+    under a chunk of queries, whose copies are noise beside its products):
+    a tile whose every page is resident (all but a row's last) takes its
+    starts UNROLLED, so that the scalar work of one page (the table read,
+    the address, the bounds checks) is packed beside the next one's and not
+    a loop's serial chain, and ONE wait for the tile's bytes (a copy's wait
+    takes its byte count off the semaphore, so a descriptor as large as the
+    tile waits for all of its pages). The unrolled starts are ONE traced
+    body (``fori_loop(..., unroll=True)``): a kernel is traced and lowered
+    anew for every program that holds it, before the compile cache is
+    asked, and P bodies traced apart cost every set-up a second a kernel. A
+    row's LAST tile keeps its loops: each form that took it out of them
+    (unrolled under predicates, by the bits of its count, down a tree of
+    halves: PERF.md section 6, PR 66) read nothing on the chip and tripled
+    the kernel's text."""
     P, BS = sides[0][1].shape[1:3]
 
     def each_copy(row, tile, slot, wait=False):
@@ -226,8 +288,7 @@ def _page_copies(bt_ref, len_ref, layer, sides, sems, whole_tiles=False):
         @pl.when(resident == P)
         def _whole_tile():
             if not wait:
-                for p in range(P):
-                    page(p, 0)
+                jax.lax.fori_loop(0, P, page, 0, unroll=True)
                 return
             for side, (_, buf) in enumerate(sides):
                 pltpu.make_async_copy(buf.at[slot], buf.at[slot],
@@ -314,8 +375,9 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
     length = len_ref[r]
     n_tiles = pl.cdiv(length, TK)
 
-    each_copy = _page_copies(bt_ref, len_ref, layer,
-                             ((k_hbm, kbuf), (v_hbm, vbuf)), sems)
+    each_copy = _page_copies(
+        bt_ref, len_ref, layer, ((k_hbm, kbuf), (v_hbm, vbuf)), sems,
+        whole_tiles=_unrolls_whole_tiles(BS, W, kbuf.dtype))
 
     @pl.when(r == 0)
     def _first_row():

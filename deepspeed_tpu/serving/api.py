@@ -2149,6 +2149,14 @@ class ServingEngine:
                               mixed=int(mixed), chunk_tokens=tokens,
                               sampled_rows=self._sampled_rows(ready),
                               **self._loop_counts)
+                if ready and span.recording:
+                    # the kernels' module counts the pages its walks meet
+                    # and those that take the whole-tile form
+                    from ..ops.paged_decode_attention import walk_page_counts
+                    latent = bool(self._latent_pools)
+                    span.annotate(**walk_page_counts(
+                        paged_kv.packed_decode_lengths(packed),
+                        self._arena["latent" if latent else "k"], latent))
                 if ready and chunk is None and not mixed \
                         and self._chunk_first:
                     span.annotate(chunk_first_by=self._chunk_first)

@@ -523,6 +523,12 @@ def pack_decode_rows(block_table, lengths, tokens, temperature, top_k,
                   seeds, steps))
 
 
+def packed_decode_lengths(packed: np.ndarray) -> np.ndarray:
+    """The ``lengths`` column of ``pack_decode_rows``' host array, (R,): 0
+    for a row no request holds."""
+    return packed[:, packed.shape[1] - _width(_decode_columns(0))]
+
+
 def unpack_decode_rows(packed: jax.Array):
     """``pack_decode_rows``' inverse, in the program: its arguments, in
     its order, with their shapes and dtypes."""

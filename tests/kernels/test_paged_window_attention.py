@@ -19,6 +19,7 @@ import pytest
 
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.ops import registry
+from test_paged_copy_paths import COPY_PATHS, _copy_path_rows
 
 paged = importlib.import_module("deepspeed_tpu.ops.paged_decode_attention")
 
@@ -209,3 +210,46 @@ def test_the_pairs_fold_into_heads_of_twice_the_size(path, request):
     np.testing.assert_array_equal(np.asarray(kf).reshape(T_, -1),
                                   k.reshape(T_, -1))
     assert vf.shape == (1, T_, Kh // 2, 2 * D)
+
+
+# the windowed call at the lengths that take each path of the walk's copies
+# (test_paged_copy_paths.py's, in the places it puts them): pages of 16 keys,
+# a tile of 16 pages; two tiles and a key are Phi's 33 pages
+PAGE, TILE_PAGES = 16, 16
+
+
+@pytest.mark.parametrize("path", sorted(COPY_PATHS))
+def test_the_windowed_walk_takes_every_path_of_the_copies(path):
+    """``paged_decode_attention`` as ``paged_attention`` calls it under a
+    window: a table that starts at the window's first page, lengths counted
+    from that page's first key and ``lo``, the keys of that page below the
+    window; a row of the length first, under an empty row, under a full one
+    and above one. Each row against the reference under a window of its
+    own, ``length - lo`` keys."""
+    rows = _copy_path_rows(COPY_PATHS[path], 33 * PAGE)
+    assert paged._pages_per_tile(PAGE, K * D, F32) == TILE_PAGES
+    nb = 1 + sum(-(-n // PAGE) for n in rows)
+    rng = np.random.default_rng(31)
+    pool_k, pool_v = (jnp.asarray(rng.standard_normal(
+        (2, nb, PAGE, K * D)).astype(np.float32)) for _ in range(2))
+    pages = rng.permutation(np.arange(1, nb))
+    table, at = np.zeros((len(rows), 33), np.int32), 0
+    for r, n in enumerate(rows):
+        held = -(-n // PAGE)
+        table[r, :held] = pages[at:at + held]
+        at += held
+    lo = np.asarray([min(5 + r, max(n - 1, 0)) for r, n in enumerate(rows)],
+                    np.int32)
+    q = jnp.asarray(rng.standard_normal((len(rows), N, D)).astype(np.float32))
+    got = paged.paged_decode_attention(
+        q, pool_k, pool_v, 1, jnp.asarray(table),
+        jnp.asarray(rows, jnp.int32), lo=jnp.asarray(lo), interpret=True)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    for r, n in enumerate(rows):
+        if n == 0:
+            assert not np.asarray(got[r]).any()
+            continue
+        want = paged.reference_paged_attention(
+            q[r][None, None], pool_k, pool_v, 1, jnp.asarray(table[r][None]),
+            jnp.asarray([[n - 1]], jnp.int32), window=n - int(lo[r]))[0, 0]
+        assert np.abs(np.asarray(got[r]) - np.asarray(want)).max() < 2e-5, r

@@ -288,6 +288,132 @@ def test_window_walk_compiles_for_v5e(v5e, monkeypatch, queries, rows):
 
 
 # ---------------------------------------------------------------------------
+# the form of a walk's copies, read off the kernel's own text: what sets a
+# decode walk's pace is its descriptors, and a loop around a page's start
+# doubles them (PERF.md section 6, PRs 64 and 66)
+# ---------------------------------------------------------------------------
+
+
+def _dma_census(fn, args):
+    """The kernel ``fn`` lowers to, as Mosaic has it: for each DMA start and
+    each DMA wait, the loops around it (innermost first, by identity) and
+    the pages its first operand spans."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    text = jax.jit(fn).lower(*args).as_text()
+    bodies = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+    assert len(bodies) == 1, len(bodies)
+    context = mlir.make_ir_context()
+    context.allow_unregistered_dialects = True
+    starts, waits, met = [], [], []
+
+    def visit(op, loops):
+        name = op.operation.name
+        if name.endswith(("scf.for", "scf.while")):
+            met.append(name)
+            loops = (len(met),) + loops
+        if name.endswith("tpu.enqueue_dma"):
+            starts.append(loops)
+        if name.endswith("tpu.wait_dma2"):
+            shape = ir.MemRefType(op.operation.operands[1].type).shape
+            waits.append((loops, shape[0] if len(shape) == 3 else 1))
+        for region in op.operation.regions:
+            for block in region:
+                for inner in block:
+                    visit(inner, loops)
+
+    with context:
+        module = ir.Module.parse(base64.b64decode(bodies[0]))
+        for op in module.body:
+            visit(op, ())
+    return starts, waits
+
+
+def _a_page_a_turn(census, sides):
+    """Of a census of copies, each the loops around it: (the innermost loops
+    that hold ONE page's copies, ``sides`` of them; the copies in no such
+    loop)."""
+    inner = {}
+    for loops in census:
+        inner[loops[:1]] = inner.get(loops[:1], 0) + 1
+    paged = [loop for loop, n in inner.items() if loop and n == sides]
+    return paged, [loops for loops in census if loops[:1] not in paged]
+
+
+# (the walk, the pages a tile of it holds, the sides a page is copied for,
+# the sites that start a tile's copies: a row's own first tile, the next
+# tile and the next row's first, the last two once a buffer where a tile's
+# body is built once a buffer; the sites that wait for one)
+DECODE_WALKS = {
+    # pages of 2,048 lanes are 64 KiB a side: their bytes set the walk's
+    # pace and not their descriptors (``_unrolls_whole_tiles``), so the
+    # loops alone, no unrolled start and no wait larger than a page
+    # (``pages`` 0)
+    "opt-1.3b": (lambda mp: _paged_decode(16, 32, 64, 16, 128), 0, 2, 3, 1),
+    "ouro-2.6b": (lambda mp: _paged_decode(16, 16, 128, 16, 20), 0, 2, 3, 1),
+    "solar-open2-gqa": (
+        lambda mp: _paged_decode(64, 64, 128, 16, 128, kv_heads=8),
+        16, 2, 3, 1),
+    "nemotron-3-super": (
+        lambda mp: _paged_decode(64, 32, 128, 16, 128, kv_heads=2),
+        16, 2, 3, 1),
+    "phi-4-mini-flash": (
+        lambda mp: _paged_decode(64, 40, 128, 16, 160, kv_heads=10),
+        16, 2, 3, 1),
+    "phi-4-mini-flash-window": (
+        lambda mp: _paged_window(1, 64, 40, 128, 16, 160, 10, 512, mp),
+        16, 2, 3, 1),
+    "longcat-flash-latent": (
+        lambda mp: _latent_decode(32, 64, 640, 512, 16, 320), 32, 1, 5, 2),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(DECODE_WALKS))
+def test_a_decode_walk_starts_a_whole_tiles_pages_side_by_side(
+        v5e, monkeypatch, walk):
+    """No loop around a WHOLE tile's starts and ONE wait a side for it: at
+    every site that starts a tile's copies the kernel holds the tile's P
+    starts a side unrolled (beside the loop over a row's tiles, where the
+    site lies in it, they stand in no loop) and one loop of a page a turn
+    for a row's last tile; at every site that waits, one wait a side as
+    large as the tile and one loop of a page a turn. A two-pool walk over
+    pages of 64 KiB a side keeps the loops alone."""
+    build, pages, sides, start_sites, wait_sites = DECODE_WALKS[walk]
+    fn, shapes = build(monkeypatch)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=v5e) for s, dt in shapes]
+    starts, waits = _dma_census(fn, args)
+
+    loops_of_starts, unrolled = _a_page_a_turn(starts, sides)
+    assert len(loops_of_starts) == start_sites
+    assert len(unrolled) == start_sites * sides * pages
+    assert max((len(loops) for loops in unrolled), default=0) <= 1
+    assert sorted(n for _, n in waits) == (
+        [1] * (wait_sites * sides) + [pages] * (wait_sites * sides * (pages > 0)))
+    loops_of_waits, _ = _a_page_a_turn([l for l, n in waits if n == 1],
+                                       sides)
+    assert len(loops_of_waits) == wait_sites
+    assert not {l[:1] for l, n in waits if n == pages} & set(loops_of_waits)
+
+
+def test_the_chunk_kernel_keeps_its_loops_of_starts(v5e):
+    """``paged_prefill_attention`` shares the walk's helpers and not the
+    decode walks' forms: its copies are noise beside its products, so it
+    keeps a loop of starts and a loop of waits, a page (k and v) a turn, and
+    its text stays short."""
+    fn, shapes = _paged_prefill(256, 32, 64, 16, 128)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=v5e) for s, dt in shapes]
+    starts, waits = _dma_census(fn, args)
+    assert len(starts) == 10 and len(waits) == 4
+    for census in (starts, [loops for loops, _ in waits]):
+        paged, unrolled = _a_page_a_turn(census, 2)
+        assert len(paged) == len(census) // 2 and not unrolled
+    assert {n for _, n in waits} == {1}
+
+
+# ---------------------------------------------------------------------------
 # the serving programs at the benchmark's size: a layer's pool is addressed
 # inside the arena, never copied out of it
 # ---------------------------------------------------------------------------

@@ -778,6 +778,17 @@ class TestServingFromTheInside:
         admits = [s["attrs"] for s in spans if s["name"] == "serving/admit"]
         assert sum(a["admitted"] for a in admits) == 6
 
+    def test_a_decode_step_counts_the_pages_its_walks_meet(self, served):
+        """``walk_pages``: the resident pages of the step's rows; of them
+        ``walk_pages_whole`` lie in tiles whose every page is resident: none
+        here, where a row holds 8 pages at most and a tile 16."""
+        spans, _, _ = served
+        dec = [s["attrs"] for s in spans if s["name"] == "serving/decode"
+               and s["attrs"].get("rows")]
+        assert dec and all(a["rows"] <= a["walk_pages"] <= 8 * a["rows"]
+                           and a["walk_pages_whole"] == 0 for a in dec)
+        assert any(a["walk_pages"] > a["rows"] for a in dec)
+
     def test_prefill_chunk_tokens_add_up_to_the_engines_count(self, served):
         spans, rise, _ = served
         chunks = [s["attrs"] for s in spans
@@ -813,6 +824,29 @@ class TestServingFromTheInside:
         chunk_rids = {s["attrs"]["rid"] for s in spans
                       if s["name"] == "serving/prefill_chunk"}
         assert chunk_rids == set(life)
+
+
+@pytest.mark.parametrize("lengths, pages, whole", [
+    ((140,), 9, 0), ((280,), 18, 16), ((1300,), 82, 80),
+    ((140, 280, 1300, 0), 109, 96)])
+def test_the_walks_page_counts_match_a_hand_count(lengths, pages, whole):
+    """Rows of 140, 280 and 1,300 tokens over pages of 16 keys and tiles of
+    256: 9 pages and no whole tile; 18 pages, one tile of 16 whole; 82
+    pages, five tiles whole; a row that holds nothing adds nothing. Over
+    pages of 64 KiB a side the two-pool walk keeps its loops, so none of
+    its pages takes the whole-tile form; the latent walk's tile is 512 keys
+    of one pool."""
+    from deepspeed_tpu.ops.paged_decode_attention import walk_page_counts
+
+    narrow = jax.ShapeDtypeStruct((3, 64, 16, 1280), jnp.bfloat16)
+    assert walk_page_counts(np.asarray(lengths), narrow) == {
+        "walk_pages": pages, "walk_pages_whole": whole}
+    wide = jax.ShapeDtypeStruct((3, 64, 16, 2048), jnp.bfloat16)
+    assert walk_page_counts(np.asarray(lengths), wide) == {
+        "walk_pages": pages, "walk_pages_whole": 0}
+    latent = jax.ShapeDtypeStruct((8, 64, 16, 640), jnp.bfloat16)
+    assert walk_page_counts(np.asarray(lengths), latent, latent=True) == {
+        "walk_pages": pages, "walk_pages_whole": 64 * (max(lengths) > 1024)}
 
 
 class TestTtftFromEntryToSubmit:
