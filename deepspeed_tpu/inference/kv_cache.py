@@ -191,9 +191,11 @@ def _state_shapes(cfg, state_slots: int, dtype,
     """The second kind of per-sequence state, beside pages: for each
     recurrent layer and slot a float32 state a head (a delta-rule layer's
     matrix; a state-space layer's, in ``ops/mamba2.pack_states``' or
-    ``ops/mamba1``'s layout) and the last ``taps - 1`` rows of the
-    convolution's input (in the model's dtype); and for each window layer
-    and slot a ring of ``ring`` = (pages, tokens a page) for its keys and
+    ``ops/mamba1``'s layout; none for a gated short convolution, whose
+    ``Mixer.state`` names no state shape) and the last ``taps - 1`` rows of
+    the convolution's input (in the model's dtype), which every such mixer
+    keeps: ``"tail"`` is what says the cache holds slots; and for each
+    window layer and slot a ring of ``ring`` = (pages, tokens a page) for its keys and
     one for its values, behind one scratch page (``"wk"``, ``"wv"``: arenas
     of pages as ``"k"`` and ``"v"`` are, which the paged kernels read
     through the table ``models/transformer._ring_table`` makes). ``{}`` for
@@ -208,8 +210,9 @@ def _state_shapes(cfg, state_slots: int, dtype,
         raise ValueError("a model with recurrent layers needs state_slots: "
                          "a slot a decode row and one scratch")
     state, taps, width = MIXERS[mixer].state(cfg)
-    shapes = {"state": ((n, state_slots) + state, jnp.float32),
-              "tail": ((n, state_slots, taps - 1, width), dtype)}
+    shapes = {"tail": ((n, state_slots, taps - 1, width), dtype)}
+    if state is not None:   # a short convolution keeps its tail and no state
+        shapes = {"state": ((n, state_slots) + state, jnp.float32), **shapes}
     windows = len(ring_layers(cfg))
     if windows:
         pages, block_size = ring

@@ -21,7 +21,10 @@ the same way, one pool of pages a (pass, layer)) and LongCat-Flash
 two latent-attention (MLA) sublayers each with a dense FFN and one
 shortcut-connected FFN of routed and zero-computation experts across both;
 served the same way, one pool of latents a sublayer and no keys or
-values)."""
+values) and LFM2 (``lfm2-8b-a1b``, ``tiny-lfm2``: gated short-convolution
+layers whose only state is a tail of two rows, grouped-query layers with a
+norm a head, two leading DENSE layers under the same mixers as the expert
+layers; served the same way, a stack of ``layer_runs``)."""
 
 from __future__ import annotations
 
@@ -45,6 +48,42 @@ def phi4flash_runs(num_layers: int) -> tuple:
     half = num_layers // 2
     return ((("mamba1", "swa"), half // 2), (("mamba1", "full"), 1),
             (("gmu", "cross"), half // 2 - 1))
+
+
+def lfm2_runs(layer_types, num_dense_layers: int) -> tuple:
+    """The published ``layer_types`` of the ``lfm2_moe`` family (``"conv"`` a
+    gated short convolution, ``"full_attention"`` grouped-query attention)
+    and its ``num_dense_layers`` (so many leading layers have a dense FFN,
+    every other one experts) as ``layer_runs``: each layer's kind, the equal
+    neighbours merged into runs of one-layer periods and what repeats
+    folded into periods. The first 12 of the published 24: ``(conv_dense)
+    x 2, (attn, conv, conv, conv) x 2, (attn, conv) x 1``."""
+    mixers = {"conv": "conv", "full_attention": "attn"}
+    if set(layer_types) - set(mixers):
+        raise NotImplementedError(
+            f"layer_types {sorted(set(layer_types) - set(mixers))}: the "
+            f"lfm2 family has {sorted(mixers)}")
+    kinds = [mixers[t] + ("_dense" if i < num_dense_layers else "")
+             for i, t in enumerate(layer_types)]
+    runs, alone, i = [], [], 0
+    while i < len(kinds):
+        # the longest stretch from here on that is whole repeats of a period
+        # (the shortest period wins a tie: a run of equal layers is ones)
+        best = ((kinds[i],), 1)
+        for width in range(1, (len(kinds) - i) // 2 + 1):
+            period = tuple(kinds[i:i + width])
+            n = 1
+            while tuple(kinds[i + n * width:i + (n + 1) * width]) == period:
+                n += 1
+            if n > 1 and n * width > best[1] * len(best[0]):
+                best = (period, n)
+        if best[1] == 1:        # repeats nothing: one period with what
+            alone.append(kinds[i])      # follows, up to the next repeat
+        else:
+            runs += [(tuple(alone), 1)] * bool(alone) + [best]
+            alone = []
+        i += best[1] * len(best[0])
+    return tuple(runs + [(tuple(alone), 1)] * bool(alone))
 
 
 def nemotron_h_pattern(hybrid_override_pattern: str) -> tuple:
@@ -143,6 +182,21 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
                           layer_pattern=("shortcut",),
                           moe_score_func="softmax", moe_router_bias=True,
                           moe_norm_topk_prob=False, moe_routed_scale=6.0),
+    # LiquidAI/LFM2-8B-A1B config.json (model_type "lfm2_moe"): every layer
+    # x + mixer(rms(x)) then x + ffn(rms(x)); the mixer a gated short
+    # convolution (conv_L_cache taps, no bias: ``transformer.
+    # _shortconv_mixer``) or grouped-query attention with an RMSNorm over
+    # each head's values of q and k before rope (theta 1e6); the first
+    # num_dense_layers layers a dense SwiGLU FFN, the others SwiGLU experts
+    # by sigmoid scores with a choice-only bias, renormalised, times
+    # routed_scaling_factor 1, no shared expert; the head tied; no biases.
+    # The order of its layers is ``lfm2_runs`` of the size preset's
+    # ``layer_types`` (a prefix of it at fewer layers) and num_dense_layers
+    "lfm2": dict(norm="rmsnorm", position="rope", activation="swiglu",
+                 tie_embeddings=True, norm_eps=1e-5, rope_theta=1e6,
+                 qk_norm="head", moe_score_func="sigmoid",
+                 moe_router_bias=True, moe_norm_topk_prob=True,
+                 moe_routed_scale=1.0),
     "nemotron-h": dict(norm="rmsnorm", position="none", activation="relu2",
                        tie_embeddings=False, norm_eps=1e-5,
                        moe_score_func="sigmoid", moe_router_bias=True,
@@ -327,6 +381,33 @@ _SIZES: Dict[str, Dict[str, Any]] = {
         v_head_dim=12, dense_ffn_hidden_size=128, ffn_hidden_size=32,
         moe_num_experts=16, moe_zero_experts=8, moe_top_k=5,
         vocab_size=256, max_seq_len=128),
+    # LiquidAI/LFM2-8B-A1B config.json (8.34 B, 1.5 B active): 18 short
+    # convolutions and 6 attention layers (32 query heads over 8 key-value
+    # heads of 64); ffn_hidden_size is an EXPERT's width (the source's
+    # moe_intermediate_size), the two leading dense FFNs'
+    # dense_ffn_hidden_size (its intermediate_size)
+    "lfm2-8b-a1b": dict(
+        family="lfm2", hidden_size=2048, num_layers=24,
+        layer_types=("conv", "conv", "full_attention", "conv", "conv", "conv",
+                     "full_attention", "conv", "conv", "conv",
+                     "full_attention", "conv", "conv", "conv",
+                     "full_attention", "conv", "conv", "conv",
+                     "full_attention", "conv", "conv", "full_attention",
+                     "conv", "conv"),
+        num_dense_layers=2, num_heads=32, num_kv_heads=8,
+        dense_ffn_hidden_size=7168, ffn_hidden_size=1792, shortconv_taps=3,
+        moe_num_experts=32, moe_top_k=4, vocab_size=65536,
+        max_seq_len=128000),
+    # 7 layers in three runs: (conv_dense) x 2, (attn, conv, conv) x 1 and
+    # (attn, conv) x 1, so a convolution's pool index is not its place
+    # among its kind; 3 experts a token of 8
+    "tiny-lfm2": dict(
+        family="lfm2", hidden_size=64, num_layers=7,
+        layer_types=("conv", "conv", "full_attention", "conv", "conv",
+                     "full_attention", "conv"),
+        num_dense_layers=2, num_heads=4, num_kv_heads=2,
+        dense_ffn_hidden_size=128, ffn_hidden_size=32, shortconv_taps=3,
+        moe_num_experts=8, moe_top_k=3, vocab_size=256, max_seq_len=128),
     # GShard/Switch-style 8-expert GPT (BASELINE tracked config #4)
     "moe-tiny": dict(family="gpt2", hidden_size=64, num_layers=2, num_heads=4,
                      vocab_size=256, max_seq_len=128, moe_num_experts=8),
@@ -349,6 +430,10 @@ def transformer_config(preset: str, dtype=jnp.float32, **overrides) -> Transform
     kwargs.update(overrides)
     if family == "phi4flash":
         kwargs.setdefault("layer_runs", phi4flash_runs(kwargs["num_layers"]))
+    if family == "lfm2":
+        types, dense = kwargs.pop("layer_types"), kwargs.pop("num_dense_layers")
+        kwargs.setdefault("layer_runs", lfm2_runs(
+            tuple(types)[:kwargs["num_layers"]], dense))
     return TransformerConfig(dtype=dtype, **kwargs)
 
 

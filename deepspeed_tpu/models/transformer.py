@@ -40,14 +40,24 @@ from .core import (EMBED, EXPERT, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ,
 # of its own, another layer's pages), "mamba1" the selective scan and "gmu" a
 # gate on the value the last "mamba1" layer handed on. "shortcut" is a DOUBLE
 # layer (``SUBLAYERS``, ``_sublayers_forward``): the "mla" mixer and a dense
-# FFN twice over, and the model's expert FFN once across both
+# FFN twice over, and the model's expert FFN once across both. "conv" is the
+# gated short convolution (``_shortconv_mixer``) under the model's FFN.
+# Where the second entry is a key of ``FFNS`` the kind's layers have THAT
+# FFN whatever the model's other layers have: "conv_dense" and "attn_dense"
+# are a family's leading dense layers (its num_dense_layers /
+# first_k_dense_replace), ``dense_ffn_hidden_size`` wide, under the mixer
+# that its expert layers have too. Kinds that share a mixer share its cache
+# pools: ``layer_places`` says which pool is a layer's
 LAYER_KINDS = {"attn": ("attn", True), "kda": ("kda", True),
                "attn_mixer": ("attn", False),
                "mamba2_mixer": ("mamba2", False),
                "ffn": (None, True),
                "mamba1": ("mamba1", True), "swa": ("swa", True),
                "full": ("full", True), "cross": ("cross", True),
-               "gmu": ("gmu", True), "shortcut": ("mla", True)}
+               "gmu": ("gmu", True), "shortcut": ("mla", True),
+               "conv": ("shortconv", True),
+               "conv_dense": ("shortconv", "dense"),
+               "attn_dense": ("attn", "dense")}
 # a kind whose layer is SUBLAYERS[kind] sublayers in sequence, norm, mixer,
 # add, norm, dense FFN (``dense_ffn_hidden_size`` wide), add, each with its
 # own weights (a leading axis of that length on the leaves ``ln1``, the
@@ -102,9 +112,11 @@ class TransformerConfig:
     #   layer (post-LN encoders norm inside the block)
     lm_head_bias: bool = False              # untied head carries a bias (GPT-J)
     norm_eps: float = 1e-5
-    qk_norm: bool = False                   # RMSNorm over the WHOLE q and the
-    #   whole k projection (N*D / K*D wide), before the split into heads and
-    #   before rope (OLMoE, OLMo-2): part of the architecture, not a knob
+    qk_norm: Any = False                    # True: RMSNorm over the WHOLE q
+    #   and the whole k projection (N*D / K*D wide), before the split into
+    #   heads and before rope (OLMoE, OLMo-2); "head": over each HEAD's D
+    #   values, one weight of D shared by the heads, before rope (LFM2):
+    #   part of the architecture, not a knob
     rope_theta: float = 10000.0
     dropout: float = 0.0              # embed/attn-out/mlp-out dropout rate.
     #   Applied only when dropout_enabled (the TrainEngine sets it; eval and
@@ -177,8 +189,8 @@ class TransformerConfig:
     #   moe_zero_experts wide and chooses over all of them
     dense_ffn_hidden_size: Optional[int] = None   # the width of a family's
     #   dense FFNs where its ``ffn_hidden_size`` is an expert's: the two of a
-    #   "shortcut" layer read it; a family's leading dense layers (its
-    #   first_k_dense_replace) would, and no preset has such layers yet
+    #   "shortcut" layer read it, and a family's leading dense layers (the
+    #   kinds "conv_dense" and "attn_dense")
     # layers of more than one kind. ``layer_pattern`` names the KIND
     # (``LAYER_KINDS``) of each layer of one period, cycled over the depth
     # (num_layers a multiple of it); a pattern LONGER than the depth is a
@@ -213,6 +225,9 @@ class TransformerConfig:
     mamba_n_groups: int = 1
     mamba_state_size: int = 0
     mamba_conv_taps: int = 4
+    shortconv_taps: int = 3           # the "shortconv" mixer's depthwise
+    #   convolution over time (the published conv_L_cache): a sequence keeps
+    #   the last ``taps - 1`` rows of its input and no other state
     head_size: Optional[int] = None         # None => hidden_size / num_heads
     attn_gate: bool = False                 # y = (attn * sigmoid(x W_g)) W_o
     kda_num_heads: int = 0
@@ -267,10 +282,10 @@ class TransformerConfig:
             assert set(self.layer_pattern) <= set(LAYER_KINDS), \
                 self.layer_pattern
             assert self.num_layers % len(self.layer_pattern) == 0
-            # a mixer's cache entry and an FFN's expert stack are indexed by
-            # the layer's place among its KIND: one kind a mixer
-            mixers = [LAYER_KINDS[k][0] for k in set(self.layer_pattern)]
-            assert len(mixers) == len(set(mixers)), self.layer_pattern
+            # a layer's weights and its expert bank lie at its place among
+            # its KIND, its mixer's cache pools at its place among the
+            # layers of that MIXER, whatever their kinds (``layer_places``)
+            mixers = {LAYER_KINDS[k][0] for k in self.layer_pattern}
             assert sum(1 for m in mixers if m and MIXERS[m].state) <= 1, \
                 "the state pools hold one kind of recurrent state"
             records = [MIXERS[m] for m in mixers if m]
@@ -296,6 +311,10 @@ class TransformerConfig:
                 and self.moe_num_experts and self.dense_ffn_hidden_size, \
                 "a layer of sublayers is pre-RMSNorm SwiGLU halves under " \
                 "an expert FFN, its dense FFNs dense_ffn_hidden_size wide"
+        if any(LAYER_KINDS[k][1] == "dense" for k in self.layer_pattern):
+            assert self.dense_ffn_hidden_size, \
+                "a dense layer's FFN is dense_ffn_hidden_size wide"
+        assert self.qk_norm in (False, True, "head"), self.qk_norm
         if self.moe_zero_experts:
             assert self.moe_num_experts and not self.moe_latent_size, \
                 "zero-computation experts stand behind routed ones of the " \
@@ -417,9 +436,53 @@ def layers_of_kind(cfg: TransformerConfig, kind: str) -> Tuple[int, ...]:
 def layers_with_mixer(cfg: TransformerConfig, mixer: str) -> Tuple[int, ...]:
     """The layers (global indices, ascending) whose mixer is ``mixer``,
     whether or not an FFN follows it in the layer."""
-    return tuple(i for kind in set(layer_kinds(cfg))
-                 if LAYER_KINDS[kind][0] == mixer
-                 for i in layers_of_kind(cfg, kind))
+    return tuple(sorted(i for kind in set(layer_kinds(cfg))
+                        if LAYER_KINDS[kind][0] == mixer
+                        for i in layers_of_kind(cfg, kind)))
+
+
+def layer_places(cfg: TransformerConfig) -> Tuple[Dict[str, Any], ...]:
+    """THE answer to "which stack entry, which pool" for each layer of the
+    stack, in order: ``kind`` (``LAYER_KINDS``); ``layer``, its place among
+    the layers of its KIND, where its weights lie in the kind's stacked tree
+    and its experts in the kind's bank (``Step.layer_index``); ``pool``, its
+    place among the layers of its MIXER whatever their kinds, where the
+    mixer's pages, tail and state lie in the cache (``Step.pool_index``;
+    None for a layer with no mixer). The two differ only where kinds share a
+    mixer (a leading dense layer and the expert layers under one mixer);
+    ``forward`` and ``_run_layers`` reckon both a period at a time with
+    ``_period_places``, which counts as this does."""
+    pattern = layer_kinds(cfg)
+    runs = cfg.layer_runs or ((pattern, cfg.num_layers // len(pattern)),)
+    above: Dict[str, int] = {}          # layers of each kind so far
+    pools_above: Dict[Any, int] = {}    # and of each mixer
+    places = []
+    for kinds, periods in runs:
+        mixers = [LAYER_KINDS[kind][0] for kind in kinds]
+        for p in range(periods):
+            for kind, mixer, (n, j, of_mixer, jm) in zip(
+                    kinds, mixers, _period_places(kinds)):
+                places.append({
+                    "kind": kind, "layer": above.get(kind, 0) + p * n + j,
+                    "pool": (pools_above.get(mixer, 0) + p * of_mixer + jm
+                             if mixer else None)})
+        for kind in set(kinds):
+            above[kind] = above.get(kind, 0) + kinds.count(kind) * periods
+        for mixer in set(mixers):
+            pools_above[mixer] = (pools_above.get(mixer, 0)
+                                  + mixers.count(mixer) * periods)
+    return tuple(places)
+
+
+def _period_places(kinds: Tuple[str, ...]) -> Tuple[Tuple[int, ...], ...]:
+    """For each layer of one period of ``kinds``: ``(layers of its kind in
+    the period, its place among them, layers of its MIXER in the period,
+    its place among those)``; a layer's index in period p is then ``layers
+    above the run + p * count + place``, by kind and by mixer alike."""
+    mixers = [LAYER_KINDS[kind][0] for kind in kinds]
+    return tuple((kinds.count(kind), kinds[:j].count(kind),
+                  mixers.count(mixers[j]), mixers[:j].count(mixers[j]))
+                 for j, kind in enumerate(kinds))
 
 
 def recurrent_layers(cfg: TransformerConfig
@@ -519,15 +582,16 @@ def tail_runs(cfg: TransformerConfig) -> int:
 def ffn_of(cfg: TransformerConfig, kind: str,
            sublayer: bool = False) -> Optional[str]:
     """THE answer to "which FFN has a layer of this kind": a key of ``FFNS``,
-    or None where ``LAYER_KINDS`` says no FFN follows the mixer. Today the
-    whole configuration decides it, the same for every kind that has one;
-    an FFN that differs by layer is a kind in ``LAYER_KINDS`` and a line
-    here. ``sublayer``: the FFN EACH sublayer of a kind of ``SUBLAYERS`` has
+    or None where ``LAYER_KINDS`` says no FFN follows the mixer. The kind
+    names it (a leading dense layer's ``"dense"``) or leaves it to the whole
+    configuration, which answers the same for every such kind. ``sublayer``: the FFN EACH sublayer of a kind of ``SUBLAYERS`` has
     beside the layer's one (None for any other kind)."""
     if sublayer:
         return "dense" if kind in SUBLAYERS else None
     if not LAYER_KINDS[kind][1]:
         return None
+    if LAYER_KINDS[kind][1] is not True:
+        return LAYER_KINDS[kind][1]     # the kind's own, whatever the model's
     if cfg.moe_num_experts > 0:
         return "experts"
     return "swiglu" if cfg.activation == "swiglu" else "biased"
@@ -538,6 +602,15 @@ def ffn_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
     layers are one function each)."""
     return tuple(i for kind in set(layer_kinds(cfg)) if ffn_of(cfg, kind)
                  for i in layers_of_kind(cfg, kind))
+
+
+def expert_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
+    """The layers whose FFN routes (``Ffn.count_width``): every layer that
+    has an FFN, but for a family's leading dense layers."""
+    return tuple(sorted(
+        i for kind in set(layer_kinds(cfg))
+        if ffn_of(cfg, kind) and FFNS[ffn_of(cfg, kind)].count_width(cfg)
+        for i in layers_of_kind(cfg, kind)))
 
 
 def layer_stacks(layers: Dict[str, Any], cfg: TransformerConfig
@@ -1233,9 +1306,12 @@ class Step:
     block_table: Optional[jax.Array] = None     # (B, MAX_BLOCKS)
     write_mask: Optional[jax.Array] = None
     layer_index: Optional[jax.Array] = None
-    pool_index: Optional[jax.Array] = None      # a looped stack: the pool of
-    #   "k" and "v" this layer writes and reads in this pass, pass x layers
-    #   + ``layer_index``; None: the layer's own index is its pool's
+    pool_index: Optional[jax.Array] = None      # the pool of the cache that
+    #   this layer's mixer writes and reads, where it is not ``layer_index``
+    #   (``_pool_of``): a looped stack's pool of "k" and "v" in this pass,
+    #   pass x layers + ``layer_index``; a sublayer's; and the layer's place
+    #   among the layers of its MIXER where kinds share one
+    #   (``layer_places``). None: the layer's own index is its pool's
     state_slots: Optional[jax.Array] = None     # (B,)
     paged_run: Optional[Tuple[jax.Array, jax.Array]] = None
     static_prefill: bool = False    # the dense cache is written from 0 on
@@ -1309,6 +1385,13 @@ def _single_chip_kernels() -> bool:
     return registry.kernels_active() and (mesh is None or mesh.size == 1)
 
 
+def _pool_of(step: Step) -> Optional[jax.Array]:
+    """THE answer to "which pool of the cache is this layer's": its mixer's
+    pages, tail and state lie at ``step.pool_index`` where the step names
+    one, else at the layer's own index."""
+    return step.layer_index if step.pool_index is None else step.pool_index
+
+
 def _with_conv_history(x: jax.Array, step: Step, taps: int
                        ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """A recurrent mixer's convolution input ``x`` (B, S, W) behind the
@@ -1322,7 +1405,7 @@ def _with_conv_history(x: jax.Array, step: Step, taps: int
     else:
         fresh = step.positions[:, 0] == 0
         tail = jnp.where(fresh[:, None, None], 0, step.cache["tail"][
-            step.layer_index, step.state_slots])
+            _pool_of(step), step.state_slots])
     return jnp.concatenate([tail.astype(x.dtype), x], axis=1), fresh
 
 
@@ -1354,7 +1437,7 @@ def _kda_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
 
     f32 = jnp.float32
     cache, real = step.cache, step.write_mask
-    at = (step.layer_index, step.state_slots)   # this layer's, each row's
+    at = (_pool_of(step), step.state_slots)     # this layer's, each row's
     B, S, _ = h.shape
     KH, KD, taps = cfg.kda_num_heads, cfg.kda_head_dim, KDA_CONV_TAPS
     W = KH * KD
@@ -1437,7 +1520,7 @@ def _mamba2_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
 
     f32 = jnp.float32
     cache, real = step.cache, step.write_mask
-    at = (step.layer_index, step.state_slots)   # this layer's, each row's
+    at = (_pool_of(step), step.state_slots)     # this layer's, each row's
     B, S, _ = h.shape
     MH, P, G, N, taps = (cfg.mamba_num_heads, cfg.mamba_head_dim,
                          cfg.mamba_n_groups, cfg.mamba_state_size,
@@ -1565,7 +1648,9 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
         v = v + p["bv"]
     # rope with no norm over the rows before it: nothing stands between k's
     # product and the heads its rope wants, in a chunk as in a step
-    bare_rope = cached and cfg.position == "rope" and not cfg.qk_norm
+    # (a norm a head stands behind the split into heads, as rope does)
+    bare_rope = (cached and cfg.position == "rope"
+                 and cfg.qk_norm in (False, "head"))
     if S == 1 or bare_rope or divided:
         # a decode step: q's product (and bias) is whole as ROWS before
         # anything splits it into heads, as k's and v's are (they go back to
@@ -1587,6 +1672,18 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
         # compiler sliced each weight out of its stack and transposed it, a
         # layer (8 MB each at a width of 2,048; PERF.md, PR 61)
         v = lax.optimization_barrier(v)
+    if cfg.qk_norm == "head":
+        # over each head's D values, one weight of D for all heads (the
+        # published Lfm2MoeAttention's q_layernorm and k_layernorm), before
+        # rope; in float32 and plain: D is half a lane tile
+        def head_norm(a, w):
+            a = a.reshape(B, S, -1, D).astype(jnp.float32)
+            return (a * lax.rsqrt((a * a).mean(-1, keepdims=True)
+                                  + cfg.norm_eps)
+                    * w.astype(jnp.float32)).astype(cfg.dtype)
+
+        return (head_norm(q, p["q_norm"]), head_norm(k, p["k_norm"]),
+                v.reshape(B, S, K, D))
     if cfg.qk_norm:
         # over all heads at once (the published OlmoeAttention: q_norm and
         # k_norm are hidden-wide), before the heads are split and roped
@@ -1685,7 +1782,7 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     if step.divide is not None:
         return _attend_mixed(cfg, q, k, v, step)
     cache, block_table = step.cache, step.block_table
-    layer = step.layer_index if step.pool_index is None else step.pool_index
+    layer = _pool_of(step)
     pos = step.positions            # (B, S): ``forward`` takes no other
     kn, vn, read = "k", "v", {}
     if form is not None:
@@ -2003,7 +2100,10 @@ def _attn_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
         "wv": normal(2, (H, K * D)),
         "wo": normal(3, (N * D, H), _resid_std(cfg)),
     }
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
+        p["q_norm"] = jnp.ones((D,), cfg.dtype)
+        p["k_norm"] = jnp.ones((D,), cfg.dtype)
+    elif cfg.qk_norm:
         p["q_norm"] = jnp.ones((N * D,), cfg.dtype)
         p["k_norm"] = jnp.ones((K * D,), cfg.dtype)
     if cfg.attn_gate:
@@ -2022,7 +2122,9 @@ def _attn_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.norm == "layernorm":
         attn.update({"bq": (LAYERS, HEADS), "bk": (LAYERS, KV_HEADS),
                      "bv": (LAYERS, KV_HEADS), "bo": (LAYERS, EMBED)})
-    if cfg.qk_norm:
+    if cfg.qk_norm == "head":
+        attn.update({"q_norm": (LAYERS, None), "k_norm": (LAYERS, None)})
+    elif cfg.qk_norm:
         attn.update({"q_norm": (LAYERS, HEADS), "k_norm": (LAYERS, KV_HEADS)})
     if cfg.attn_gate:
         attn["wg"] = (LAYERS, EMBED, HEADS)
@@ -2206,7 +2308,7 @@ def _mamba1_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
 
     f32 = jnp.float32
     cache, real = step.cache, step.write_mask
-    at = (step.layer_index, step.state_slots)   # this layer's, each row's
+    at = (_pool_of(step), step.state_slots)     # this layer's, each row's
     B, S, _ = h.shape
     I, N, R = _mamba1_sizes(cfg)
     taps = cfg.mamba_conv_taps
@@ -2251,6 +2353,62 @@ def _mamba1_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     out = (y * jax.nn.silu(z.astype(f32))).astype(h.dtype)
     return (jnp.einsum("bsd,dh->bsh", out, p["w_out"]), new_cache,
             y.astype(h.dtype))
+
+
+def _shortconv_init(cfg: TransformerConfig, normal, uniform
+                    ) -> Dict[str, Any]:
+    H = cfg.hidden_size
+    return {
+        "w_in": normal(100, (H, 3 * H)),        # [B | C | u]
+        # taps of the depthwise convolution over time, oldest first
+        "conv_w": normal(101, (cfg.shortconv_taps, H), 0.5),
+        "w_out": normal(102, (H, H), _resid_std(cfg)),
+    }
+
+
+def _shortconv_axes(cfg: TransformerConfig) -> Dict[str, Any]:
+    return {"w_in": (LAYERS, EMBED, HEADS), "conv_w": (LAYERS, None, HEADS),
+            "w_out": (LAYERS, HEADS, EMBED)}
+
+
+def _shortconv_state(cfg: TransformerConfig):
+    # no state: the convolution's tail is all a sequence keeps
+    return None, cfg.shortconv_taps, cfg.hidden_size
+
+
+def _shortconv_mixer(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
+                     step: Step
+                     ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """The "shortconv" mixer of ``_layer_forward``, a gated short
+    convolution over the normed input ``h`` (B, S, H) -> (its contribution
+    to the residual, new cache).
+
+    ``[B | C | u] = h W_in``; ``z = B * u``; on ``z`` a depthwise causal
+    convolution over time (``shortconv_taps`` taps, no bias, no activation):
+    ``y``; ``out = (C * y) W_out``. Between the two products it is ``taps``
+    multiply-adds a value, which XLA fuses: no kernel.
+
+    The cache, the layer's pool, the rows' slots, a row's start at position
+    0 and ``real`` are ``_kda_mixer``'s, but there is NO ``"state"``: the
+    pool ``"tail"`` (layers of this mixer, slots, taps - 1, H) holds a
+    sequence's last ``taps - 1`` rows of ``z``, and that is all it keeps. A
+    token that does not exist leaves the tail as it was
+    (``_last_real_rows``)."""
+    f32 = jnp.float32
+    S = h.shape[1]
+    taps = cfg.shortconv_taps
+    b, c, u = jnp.split(jnp.einsum("bsh,hd->bsd", h, p["w_in"]), 3, axis=-1)
+    ext, _ = _with_conv_history(b * u, step, taps)
+    conv = p["conv_w"].astype(f32)
+    y = sum(conv[j] * ext[:, j:j + S].astype(f32) for j in range(taps))
+    new_cache = None
+    if step.cache is not None:
+        tails = step.cache["tail"]
+        new_cache = {**step.cache, "tail": tails.at[
+            _pool_of(step), step.state_slots].set(_last_real_rows(
+                ext, step.write_mask, taps - 1).astype(tails.dtype))}
+    out = (c.astype(f32) * y).astype(h.dtype)
+    return jnp.einsum("bsd,dh->bsh", out, p["w_out"]), new_cache
 
 
 def _gmu_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
@@ -2403,8 +2561,9 @@ class Mixer:
     axes: Callable      # (cfg) -> the subtree's logical axes
     apply: Callable     # (cfg, h, params, step) -> (out, new cache), and a
     #   third value where the mixer ``hands_on``
-    state: Optional[Callable] = None    # None: the mixer keeps no state;
-    #   else (cfg) -> (a slot's state shape, the convolution's taps, width)
+    state: Optional[Callable] = None    # None: the mixer keeps nothing a
+    #   sequence beside pages; else (cfg) -> (a slot's state shape, or None
+    #   where the tail is all it keeps; the convolution's taps; its width)
     rows_count: Optional[str] = None    # the span count of states advanced
     keeps: Optional[str] = None     # "pages": a pool of its own in the
     #   arena's "k" and "v"; "ring": a window of pages a row in "wk" and
@@ -2438,6 +2597,8 @@ MIXERS: Dict[str, Mixer] = {
     "full": _form(AttnForm(), "pages"),
     "cross": _form(AttnForm(cross=True), None),
     "gmu": Mixer("gmu", _gmu_init, _gmu_axes, _gmu_mixer),
+    "shortconv": Mixer("shortconv", _shortconv_init, _shortconv_axes,
+                       _shortconv_mixer, _shortconv_state, "conv_rows"),
     "mla": Mixer("mla", _latent_init, _latent_axes, _latent_mixer,
                  keeps="latent"),
 }
@@ -2702,6 +2863,20 @@ FFNS: Dict[str, Ffn] = {
 }
 
 
+def _banks_apart(cfg: TransformerConfig, stacks: Dict[str, Any]
+                 ) -> Tuple[Dict[str, Any], Optional[Dict[str, Any]]]:
+    """``(stacks without their banks, banks by kind or None)``: at inference
+    an MoE model's expert stacks stay whole, outside the layer scan's
+    slicing, and go down with the layer's index (``Step.expert_banks``)."""
+    whole = {kind: FFNS[ffn_of(cfg, kind)].whole for kind in stacks
+             if ffn_of(cfg, kind) and FFNS[ffn_of(cfg, kind)].whole}
+    if not whole:
+        return stacks, None
+    return ({kind: {k: v for k, v in tree.items() if k != whole.get(kind)}
+             for kind, tree in stacks.items()},
+            {kind: stacks[kind][leaf] for kind, leaf in whole.items()})
+
+
 def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
                    step: Step, kind: str = "attn"):
     """One decoder block: ``x + mixer(norm(x))``, then the FFN. ``kind``
@@ -2742,6 +2917,10 @@ def _layer_forward(cfg: TransformerConfig, x: jax.Array, layer: Dict[str, Any],
 
         h = fake_quant_activation(h, cfg.act_quant_bits)   # MLP input
     mlp_out, aux, *counts = FFNS[ffn].apply(cfg, h, layer, step, kind)
+    if step.moe_counts and not counts:
+        # a dense layer of a model whose other layers route: it assigns
+        # nothing, and says so in the place the counts have
+        counts = [jnp.zeros((moe_count_width(cfg),), jnp.int32)]
     if cfg.norm_position == "sandwich":
         mlp_out = _norm(mlp_out, layer["ln2_post"]["scale"],
                         layer["ln2_post"].get("bias"), cfg.norm, cfg.norm_eps)
@@ -2863,9 +3042,7 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
     are the ROWS', (R, 1, V): nobody reads a chunk's that is not its
     prompt's last, and no other chunk rides a mixed step."""
     B, S = input_ids.shape
-    ffns = {kind: FFNS[ffn_of(cfg, kind)] for kind in set(layer_kinds(cfg))
-            if ffn_of(cfg, kind)}
-    routes = any(record.count_width(cfg) for record in ffns.values())
+    routes = bool(expert_layers(cfg))       # some layer's FFN routes
     divide = None
     if mixed_chunk is not None:
         divide = S - mixed_chunk["positions"].shape[1]
@@ -2950,21 +3127,21 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             "layer, and progressive layer drop, random-LTD and per-layer "
             "windows index a stack that runs once")
     if cfg.layer_runs:
-        if block_table is None or use_pld or use_ltd or use_win \
-                or moe_counts:
+        if block_table is None or use_pld or use_ltd or use_win:
             raise NotImplementedError(
                 "a stack of layer_runs runs over the serving layer's paged "
                 "cache alone: its window layers keep a ring of pages a row "
                 "and its cross layers read another layer's pages, which "
                 "training and the dense cache (inference/engine.py) do not "
-                "keep; progressive layer drop, random-LTD, per-layer "
-                "windows and expert counts index a stack of one period")
-        logits, new_cache = _run_layers(
+                "keep; progressive layer drop, random-LTD and per-layer "
+                "windows index a stack of one period")
+        logits, new_cache, *moe_totals = _run_layers(
             cfg, params, x, Step(
                 mask=attention_mask, positions=positions, cache=dict(cache),
                 block_table=block_table, write_mask=paged_write_mask,
-                state_slots=state_slots, paged_run=paged_run), last_token)
-        return logits, new_cache, jnp.float32(0.0)
+                state_slots=state_slots, paged_run=paged_run,
+                moe_counts=moe_counts), last_token)
+        return (logits, new_cache, jnp.float32(0.0), *moe_totals)
 
     # a period of the layer pattern is one step of the scan: each kind's
     # stacked tree goes in sliced by the period (several layers of a kind in
@@ -2988,17 +3165,9 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
         raise ValueError("a model with recurrent layers needs state_slots "
                          "beside its cache: each row's slot in the pools")
     stacks = layer_stacks(params["layers"], cfg)
-    # at inference an MoE model's expert stacks stay whole, outside the
-    # layer scan's slicing, and go down with the layer's index (see
-    # ``Step.expert_banks``)
     banks = None
-    whole = {kind: record.whole for kind, record in ffns.items()
-             if record.whole}
-    if cache is not None and whole:
-        banks = {kind: stacks[kind][leaf] for kind, leaf in whole.items()}
-        stacks = {kind: {k: v for k, v in tree.items()
-                         if k != whole.get(kind)}
-                  for kind, tree in stacks.items()}
+    if cache is not None:
+        stacks, banks = _banks_apart(cfg, stacks)
     # and so do the leaves that the sublayers of a double layer own
     # (``Step.sublayer_stacks``)
     halves = None
@@ -3022,17 +3191,19 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                 chunk=None if divide is None else Step(**mixed_chunk))
 
     def run_period(layers, pidx, one_layer, h, *acc):
-        """``one_layer(h, kind, layer, layer index among its kind, *acc)
-        -> (h, *acc)`` for each layer of period ``pidx``, in order."""
-        seen = dict.fromkeys(per, 0)
-        for kind in pattern:
-            j, n = seen[kind], per[kind]
-            seen[kind] += 1
+        """``one_layer(h, kind, layer, layer index among its kind, its
+        mixer's pool where that is another index, *acc) -> (h, *acc)`` for
+        each layer of period ``pidx``, in order."""
+        for kind, (n, j, of_mixer, jm) in zip(pattern,
+                                              _period_places(pattern)):
             layer = layers[kind] if several else layers
             if n > 1:
                 layer = jax.tree.map(lambda a: a[j], layer)
             kidx = pidx if n == 1 or pidx is None else pidx * n + j
-            h, *acc = one_layer(h, kind, layer, kidx, *acc)
+            # kinds that share a mixer share its pools (``layer_places``)
+            pool = (None if pidx is None or (of_mixer, jm) == (n, j)
+                    else pidx * of_mixer + jm)
+            h, *acc = one_layer(h, kind, layer, kidx, pool, *acc)
         return (h, *acc)
 
     def block(carry, layer_and_cache):
@@ -3071,7 +3242,7 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
             h_new, aux = lax.cond(ltd_flag > 0, ltd_branch, full_branch, h)
             new_cache = None
         elif several:
-            def one_layer(h, kind, layer, kidx, aux_sum):
+            def one_layer(h, kind, layer, kidx, pool, aux_sum):
                 h, _, aux = _layer_forward(cfg, h, layer, at, kind=kind)
                 return h, aux_sum + aux
 
@@ -3137,12 +3308,12 @@ def forward(params: Dict[str, Any], input_ids: jax.Array,
                 h, aux_acc, arena, *counts_acc = carry
                 layers, pidx = layers_and_idx
 
-                def one_layer(h, kind, layer, kidx, aux_sum, arena,
+                def one_layer(h, kind, layer, kidx, pool, aux_sum, arena,
                               *counts_sum):
                     h, arena, aux, *counts = _layer_forward(
                         cfg, h, layer, dataclasses.replace(
                             step, cache=arena, layer_index=kidx,
-                            pool_index=(None if pools_above is None
+                            pool_index=(pool if pools_above is None
                                         else pools_above + kidx)),
                         kind=kind)
                     return (h, aux_sum + aux, arena,
@@ -3226,57 +3397,73 @@ def _run_passes(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array,
 
 
 def _run_layers(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array,
-                step: Step, last_token: Optional[jax.Array]
-                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                step: Step, last_token: Optional[jax.Array]):
     """The layers of a stack of runs (``cfg.layer_runs``) over the paged
-    cache, and the head -> ``(logits, new cache)``. Each run of equal
-    periods is ONE ``lax.scan`` over its periods (a run of one period is run
-    as it stands), so the program holds a period of each run, not the depth.
-    The carry is the activations, the arena and the state pools, as in
-    ``forward``'s paged scan, and the value the last "mamba1" layer handed
-    on. A kind's stacked tree stays whole outside the scans and a layer is
-    taken out of it by its place among its kind, where the run stands plus
-    where the period stands in it: a slice of the stack as a scan operand
-    would be a copy of the run's weights a step.
+    cache, and the head -> ``(logits, new cache)`` and, with
+    ``step.moe_counts``, the routing counts summed over the layers. Each run
+    of equal periods is ONE ``lax.scan`` over its periods (a run of one
+    period is run as it stands), so the program holds a period of each run,
+    not the depth. The carry is the activations, the arena and the state
+    pools, as in ``forward``'s paged scan, the value the last "mamba1" layer
+    handed on and the routing counts. A kind's stacked tree stays whole
+    outside the scans, its expert bank apart from it (``_banks_apart``), and
+    a layer is taken out of it by its place among its kind, where the run
+    stands plus where the period stands in it: a slice of the stack as a
+    scan operand would be a copy of the run's weights a step. Its mixer's
+    pool is its place among the layers of that MIXER, counted the same way
+    (``_period_places``, ``layer_places``).
 
     ``last_token`` (B,): before the stack's ``tail_runs`` the carry is
     narrowed to that token of each row (activations, memory, positions and
     mask), and the rest of the stack and the head run at a width of one,
     under ONE ``lax.cond``: where no row names a token (all below 0) they do
     not run, and the logits are zeros."""
-    stacks = layer_stacks(params["layers"], cfg)
+    stacks, banks = _banks_apart(cfg, layer_stacks(params["layers"], cfg))
+    step = dataclasses.replace(step, expert_banks=banks)
     B, S, _ = x.shape
     has_memory = any(MIXERS[LAYER_KINDS[k][0]].hands_on
                      for k in layer_kinds(cfg))
     memory = (jnp.zeros((B, S, _mamba1_sizes(cfg)[0]), x.dtype)
               if has_memory else None)
+    counts = (jnp.zeros((moe_count_width(cfg),), jnp.int32)
+              if step.moe_counts else None)
     runs = cfg.layer_runs
-    above = [dict.fromkeys(stacks, 0)]      # layers of each kind above a run
+    mixer_of = {kind: LAYER_KINDS[kind][0] for kind in stacks}
+    # layers of each kind, and of each mixer, above a run
+    above = [dict.fromkeys(stacks, 0)]
+    pools_above = [dict.fromkeys(mixer_of.values(), 0)]
     for kinds, periods in runs:
+        mixers = [mixer_of[kind] for kind in kinds]
         above.append({kind: n + kinds.count(kind) * periods
                       for kind, n in above[-1].items()})
+        pools_above.append({mixer: n + mixers.count(mixer) * periods
+                            for mixer, n in pools_above[-1].items()})
 
     def run(r, carry, step):
         kinds, periods = runs[r]
-        per = {kind: kinds.count(kind) for kind in kinds}
-        base = above[r]
-        pages_above = sum(n for kind, n in base.items()
-                          if MIXERS[LAYER_KINDS[kind][0]].keeps == "pages")
+        base, pools = above[r], pools_above[r]
+        pages_above = sum(n for mixer, n in pools.items()
+                          if mixer and MIXERS[mixer].keeps == "pages")
 
         def period(carry, pidx):
-            h, arena, memory = carry
-            done = dict.fromkeys(per, 0)
-            for kind in kinds:
-                kidx = base[kind] + pidx * per[kind] + done[kind]
-                done[kind] += 1
+            h, arena, memory, counts = carry
+            for kind, (n, j, of_mixer, jm) in zip(kinds,
+                                                  _period_places(kinds)):
+                kidx = base[kind] + pidx * n + j
+                mixer = mixer_of[kind]
+                pool = (None if (pools[mixer], of_mixer, jm)
+                        == (base[kind], n, j)
+                        else pools[mixer] + pidx * of_mixer + jm)
                 layer = jax.tree.map(lambda a: a[kidx], stacks[kind])
-                h, arena, _, *memory = _layer_forward(
+                h, arena, _, *rest = _layer_forward(
                     cfg, h, layer, dataclasses.replace(
-                        step, cache=arena, layer_index=kidx,
+                        step, cache=arena, layer_index=kidx, pool_index=pool,
                         memory=memory, shared_layer=pages_above - 1),
                     kind=kind)
-                memory = memory[0] if memory else None
-            return (h, arena, memory), None
+                if counts is not None:
+                    counts = counts + rest.pop(0)
+                memory = rest[0] if rest else None
+            return (h, arena, memory, counts), None
 
         if periods == 1:
             return period(carry, 0)[0]
@@ -3284,12 +3471,13 @@ def _run_layers(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array,
                         jnp.arange(periods, dtype=jnp.int32))[0]
 
     tail_from = len(runs) - (tail_runs(cfg) if last_token is not None else 0)
-    carry = (x, step.cache, memory)
+    carry = (x, step.cache, memory, counts)
     for r in range(tail_from):
         carry = run(r, carry, step)
-    h, arena, memory = carry
+    h, arena, memory, counts = carry
+    totals = [] if counts is None else [counts]
     if tail_from == len(runs):
-        return head_logits(params, h, cfg), arena
+        return (head_logits(params, h, cfg), arena, *totals)
 
     def last(a):
         return jnp.take_along_axis(
@@ -3298,20 +3486,21 @@ def _run_layers(cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array,
 
     narrow = dataclasses.replace(
         step, positions=last(step.positions), paged_run=None,
+        moe_counts=False,
         write_mask=(None if step.write_mask is None
                     else last(step.write_mask)))
 
     def tail(h, memory):        # these runs keep nothing: the arena is read
-        carry = (h, arena, memory)
+        carry = (h, arena, memory, None)
         for r in range(tail_from, len(runs)):
             carry = run(r, carry, narrow)
         return head_logits(params, carry[0], cfg)
 
     operands = (last(h), None if memory is None else last(memory))
     logits = jax.eval_shape(tail, *operands)
-    return lax.cond(jnp.any(last_token >= 0), tail,
-                    lambda h, memory: jnp.zeros(logits.shape, logits.dtype),
-                    *operands), arena
+    return (lax.cond(jnp.any(last_token >= 0), tail,
+                     lambda h, memory: jnp.zeros(logits.shape, logits.dtype),
+                     *operands), arena, *totals)
 
 
 def _final_norm(params: Dict[str, Any], x: jax.Array,

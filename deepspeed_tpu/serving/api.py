@@ -247,9 +247,10 @@ class ServingEngine:
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..inference.kv_cache import ring_blocks
-        from ..models.transformer import (MIXERS, ffn_layers, latent_pools,
-                                          moe_count_width, recurrent_layers,
-                                          ring_layers, tail_runs)
+        from ..models.transformer import (MIXERS, expert_layers,
+                                          latent_pools, moe_count_width,
+                                          recurrent_layers, ring_layers,
+                                          tail_runs)
 
         mixer, layers = recurrent_layers(cfg)
         self._recurrent_layers = len(layers)
@@ -318,9 +319,10 @@ class ServingEngine:
         # spans know nothing of it. ``total`` counts the ROUTER's outputs a
         # layer, ``held`` the experts of a layer's stack (fewer where this
         # chip holds its share of them: ``moe_experts_held``)
-        self._moe_experts_total = cfg.moe_num_experts * len(ffn_layers(cfg))
-        self._moe_experts_held = cfg.experts_held * len(ffn_layers(cfg))
-        self._moe_choices = cfg.moe_top_k * len(ffn_layers(cfg))   # a token
+        routed = len(expert_layers(cfg))    # not a leading dense layer
+        self._moe_experts_total = cfg.moe_num_experts * routed
+        self._moe_experts_held = cfg.experts_held * routed
+        self._moe_choices = cfg.moe_top_k * routed          # a token
         moe = self._moe_experts_total > 0
         self._prefill = paged_kv.build_prefill_program(
             cfg, self.config.prefill_chunk, moe_counts=moe)

@@ -657,7 +657,7 @@ def build_prefill_program(cfg, chunk_tokens: int, moe_counts: bool = False):
     has_last = tail_runs(cfg) > 0
 
     def prefill_chunk(params, cache, packed, base_key):
-        operands = list(unpack_chunk(packed, chunk_tokens, "state" in cache,
+        operands = list(unpack_chunk(packed, chunk_tokens, "tail" in cache,
                                      has_last))
         last = operands.pop() if has_last else None
         return step(params, cache, *operands, base_key, last)
@@ -759,14 +759,14 @@ def _decode_step(cfg, moe_counts: bool = False):
         # The mask also sends an empty row's write to the scratch block,
         # which is where its all-zero table sent it anyway. A dense model
         # routes nothing, and its program stays as it was.
-        recurrent = "state" in cache
+        recurrent = "tail" in cache     # every per-sequence state has one
         live = ((lengths > 0)[:, None]
                 if cfg.moe_num_experts > 0 or recurrent else None)
         slots = None
         if recurrent:
             slots = jnp.where(lengths > 0,
                               jnp.arange(lengths.shape[0], dtype=jnp.int32),
-                              cache["state"].shape[1] - 1)
+                              cache["tail"].shape[1] - 1)
         logits, cache, _, *counts = model_forward(
             params, tokens[:, None], cfg, cache=cache,
             positions=lengths[:, None], block_table=block_table,
@@ -958,8 +958,8 @@ def build_score_program(cfg):
         write_mask, pos = _run_operands(chunk.shape[1], start, n_valid)
         # a scored sequence's recurrent state lives in the scratch slot:
         # no decode row owns it, and its first chunk starts it from zeros
-        slots = (jnp.full((1,), cache["state"].shape[1] - 1, jnp.int32)
-                 if "state" in cache else None)
+        slots = (jnp.full((1,), cache["tail"].shape[1] - 1, jnp.int32)
+                 if "tail" in cache else None)
         logits, cache, _ = model_forward(params, chunk, cfg, cache=cache,
                                          positions=pos,
                                          block_table=block_table,

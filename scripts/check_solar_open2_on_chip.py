@@ -130,10 +130,11 @@ def main(argv=None, family=FAMILY):
 
     def one_token(params, cache, table, lengths, tokens, targets):
         live = (lengths > 0)[:, None]
-        # a model with no recurrent layer keeps no state pools
+        # a model with no recurrent layer keeps no state pools (every
+        # per-sequence state has a "tail"; a short convolution's is all)
         slots = (jnp.where(lengths > 0, jnp.arange(R, dtype=jnp.int32),
-                           cache["state"].shape[1] - 1)
-                 if "state" in cache else None)
+                           cache["tail"].shape[1] - 1)
+                 if "tail" in cache else None)
         logits, cache, _ = forward(
             params, tokens[:, None], cfg, cache=cache,
             positions=lengths[:, None], block_table=table,

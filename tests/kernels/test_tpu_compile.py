@@ -191,7 +191,24 @@ def _grouped_matmul(tokens, top_k, experts, k, n):
 # as a page, 576 values in 640 lanes (at 576 Mosaic refuses the page's copy:
 # "slice shape along dimension 3 must be aligned to tiling (128)"), of which
 # the first 512 are the values
+# lfm2-8b-a1b's first stage: 16 decode rows of up to 8,320 tokens (a table
+# of 520 pages) and chunks of 1,024; 32 heads over 8 key-value heads of 64
+# (pages 512 values wide); 4 of 32 experts of 2,048 x 1,792 (1,792 = 14 x
+# 128, no multiple of 512) a token: 128 rows an expert in a chunk, 16 rows'
+# 64 assignments in a step
 CASES = {
+    "paged-decode-lfm2-8b-a1b":
+        lambda: _paged_decode(16, 32, 64, 16, 520, kv_heads=8),
+    "paged-prefill-lfm2-8b-a1b":
+        lambda: _paged_prefill(1024, 32, 64, 16, 520, kv_heads=8),
+    "moe-up-prefill-lfm2-8b-a1b":
+        lambda: _grouped_matmul(1024, 4, 32, 2048, 1792),
+    "moe-down-prefill-lfm2-8b-a1b":
+        lambda: _grouped_matmul(1024, 4, 32, 1792, 2048),
+    "moe-up-decode-lfm2-8b-a1b":
+        lambda: _grouped_matmul(16, 4, 32, 2048, 1792),
+    "moe-down-decode-lfm2-8b-a1b":
+        lambda: _grouped_matmul(16, 4, 32, 1792, 2048),
     "latent-decode-longcat-flash":
         lambda: _latent_decode(32, 64, 640, 512, 16, 320),
     "moe-up-decode-longcat-flash":
@@ -913,6 +930,53 @@ def test_a_double_layer_reads_its_sublayers_where_they_lie(
     assert "moe_grouped_matmul" in text
     # a sublayer's dense FFN matrix is 151 MB; the chunk's scores, 8 heads
     # at a time in float32, and its experts' rows are 0.4 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        16e6 if kind == "decode" else 0.5e9)
+
+
+LFM2_ROWS, LFM2_BLOCKS, LFM2_MAXB, LFM2_CHUNK = 16, 8321, 520, 1024
+LFM2_ARENA = f"bf16[3,{LFM2_BLOCKS},{BLOCK},512]"
+LFM2_TAILS = f"bf16[9,{LFM2_ROWS + 1},2,2048]"
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_a_tail_only_stack_reads_banks_and_pools_where_they_lie(
+        v5e, monkeypatch, kind):
+    """LFM2-8B-A1B's serving programs for the chip at its cell's shapes (the
+    first 12 layers in three runs, 16 rows over an arena of 8,321 blocks,
+    chunks of 1,024): the pages of the 3 attention layers and the tails of
+    the 9 convolution layers ride the runs' carries, the pages written
+    where they lie (the tails are 1.25 MB: a slot's two rows are updated in
+    them); no layer's experts are copied out of their kind's bank (352 MB
+    a layer) and no leading layer's dense FFN out of its stack; both paged
+    kernels and the grouped matmul are in the program, and there is no
+    ``"state"`` pool at all."""
+    compiled = _serving_program(
+        kind, v5e, monkeypatch, preset="lfm2-8b-a1b",
+        overrides={"num_layers": 12}, rows=LFM2_ROWS, num_blocks=LFM2_BLOCKS,
+        maxb=LFM2_MAXB, chunk=LFM2_CHUNK, moe_counts=True).compile()
+    text = compiled.as_text()
+    roots = _fusion_roots(text)
+    offenders = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m is None:
+            continue
+        _, result, op = m.groups()
+        if LFM2_ARENA in result and not _writes_in_place(line, op, roots):
+            offenders.append(line.strip()[:200])
+        # a layer's experts or a kind's bank, a dense FFN's matrices (a
+        # chunk's 4,096 assigned rows are an activation)
+        if op in ("copy", "transpose") and "/gather" not in line and re.match(
+                r"bf16\[([237],)?(32,)?(2048,1792|1792,2048|2048,7168|"
+                r"7168,2048|2048,6144)\]", result):
+            offenders.append(line.strip()[:200])
+    assert not offenders, "\n".join(offenders)
+    assert LFM2_ARENA in text and LFM2_TAILS in text
+    assert "moe_grouped_matmul" in text
+    assert ("paged_decode_attention" if kind == "decode"
+            else "paged_prefill_attention") in text
+    # a chunk's experts' rows, 4,096 x 1,792 twice over, and its scores
     assert compiled.memory_analysis().temp_size_in_bytes < (
         16e6 if kind == "decode" else 0.5e9)
 

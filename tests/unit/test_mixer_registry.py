@@ -473,7 +473,7 @@ def test_only_the_model_names_a_mixer_by_string():
     owners = {package / "models" / "transformer.py",
               package / "models" / "presets.py"}
     recurrent = {m for m, record in T.MIXERS.items() if record.state}
-    assert recurrent == {"kda", "mamba2", "mamba1"}
+    assert recurrent == {"kda", "mamba2", "mamba1", "shortconv"}
     for path in sorted(set(package.rglob("*.py")) - owners):
         for node in ast.walk(ast.parse(path.read_text())):
             if isinstance(node, ast.Constant) and node.value in recurrent:
