@@ -22,6 +22,38 @@ their matmul, so the time follows the assignments, not the bound.
 ``tile_expert`` and ``used`` ride as scalar-prefetch operands: the index
 maps resolve them before a block's DMA is issued.
 
+**The weight block is an expert's WHOLE matrix wherever that fits**
+(``_weight_block_cols``): ``tn = N``, the grid ``(1, TILES)``, one
+contiguous copy a touched expert. A few rows against a matrix is a copy
+with a product hidden behind it, and what the copy costs is its geometry: a
+block of some of the columns is a strided copy, a run a 16-row band of the
+matrix, and on a v5e runs under 32 KiB moved at 63-84% of the memory's
+nominal rate where a whole matrix moves at 89-90% (``PERF.md`` section 6,
+PR 68; ``scripts/time_moe_grouped_matmul_on_chip.py`` repeats it). A row
+tile also comes once, not once a column step. The rule reads the operands'
+shapes and dtype and nothing else. The weight buffers of a call, its
+matrices x the pipeline's 2 buffers x one block, get ``_WEIGHT_VMEM_BYTES``
+(64 MiB) of a core's 128 MiB of VMEM: a matrix of up to 32 MiB is one block
+of a one-matrix call and a matrix of up to 16 MiB (16,777,216 bytes) one
+block of a gated call; a matrix a byte larger comes as the widest column
+blocks that are a multiple of the lanes, divide ``N`` and fit the same
+share. The call raises its ``vmem_limit_bytes`` to those buffers and
+``_REST_VMEM_BYTES`` (16 MiB, the default of a kernel) for the row tiles and
+the float32 products beside them (``_vmem_bytes``). The contraction is one
+``dot`` over all of ``K`` under either block, so a row's sums keep their
+order.
+
+**A gated expert's gate and up are ONE call** (``gate=``): the row tile comes
+once, the two matrices' blocks side by side, and the tile written is
+``silu(rows @ gate) * (rows @ up)``, each product rounded to the stored
+dtype before the float32 gating, which is what two calls and a fusion
+between them gave. A SwiGLU layer is two calls (gate with up; down), any
+other expert two one-matrix calls with its activation between them.
+**Every call is named ``moe_grouped_matmul``**: the benchmark's readers find
+the kernel's device time by that name and reckon its work from the spans'
+counts of assignments and touched experts, which no form of the call
+changes.
+
 The weights may be a model's whole stack ``(L, E, K, N)`` with the ``layer``
 to use (a traced int32 scalar: the layer scan's index), a third
 scalar-prefetch operand that the weight block's index map puts in front of
@@ -38,7 +70,7 @@ kernel is tested against.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,9 +79,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 MAX_TILE_ROWS = 128            # one MXU pass on a v5e
-# one weight block, double-buffered by the pipeline: 2 x 2 MiB of the 16 MiB
-# a kernel may use by default, large enough to stream at HBM speed
-_WEIGHT_BLOCK_BYTES = 2 * 2 ** 20
+# what a call's weight buffers may hold of a v5e core's 128 MiB of VMEM: the
+# matrices of the call (one, or a gated expert's two) x the pipeline's 2
+# buffers x one block. A block is an expert's whole (K, N) matrix where that
+# fits (16 MiB a block of a gated call, 32 MiB of a one-matrix call), else
+# the widest column block that does
+_WEIGHT_VMEM_BYTES = 64 * 2 ** 20
+# beside them: the row tiles in and out (two buffers each) and the float32
+# products of a tile, within what a kernel gets by default
+_REST_VMEM_BYTES = 16 * 2 ** 20
 
 
 def tile_rows(rows: int, groups: int, dtype) -> int:
@@ -89,27 +127,44 @@ def group_layout(sizes: jax.Array, rows: int, tm: int
             used.astype(jnp.int32).reshape(1))
 
 
-def _weight_block_cols(K: int, N: int, dtype) -> int:
-    """Columns of one ``(K, tn)`` weight block: the most lanes that divide
-    ``N`` within the block budget, or all of a narrow ``N``."""
-    if N % LANES:
+def _weight_block_cols(K: int, N: int, dtype, matrices: int = 1) -> int:
+    """Columns of one ``(K, tn)`` weight block of a call that streams
+    ``matrices`` stacks: all of ``N`` where a whole matrix fits the call's
+    share of the weight buffers (one contiguous copy an expert), else the
+    most lanes that divide ``N`` within it (a narrow ``N`` is never cut)."""
+    budget = (_WEIGHT_VMEM_BYTES // (2 * matrices)
+              // (K * jnp.dtype(dtype).itemsize))
+    if N % LANES or N <= budget:
         return N
-    budget = _WEIGHT_BLOCK_BYTES // (K * jnp.dtype(dtype).itemsize)
     tn = LANES
-    for cand in range(LANES, N + 1, LANES):
+    for cand in range(LANES, N, LANES):
         if N % cand == 0 and cand <= budget:
             tn = cand
     return tn
 
 
-def _gmm_kernel(tile_expert_ref, used_ref, layer_ref, x_ref, w_ref, o_ref):
+def _vmem_bytes(K: int, tn: int, dtype, matrices: int) -> int:
+    """What a call asks of VMEM: its weight buffers and the rest."""
+    return (2 * matrices * K * tn * jnp.dtype(dtype).itemsize
+            + _REST_VMEM_BYTES)
+
+
+def _gmm_kernel(tile_expert_ref, used_ref, layer_ref, x_ref, *refs):
     del tile_expert_ref, layer_ref         # read by the index maps
+    *w_refs, o_ref = refs                  # (up,) or (gate, up)
 
     @pl.when(pl.program_id(1) < used_ref[0])
     def _tile():
-        o_ref[...] = jnp.dot(
-            x_ref[...], w_ref[...],
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        x = x_ref[...]
+        # each product rounded to the stored dtype, as a call of its own
+        # would hand it on
+        outs = [jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32
+                        ).astype(o_ref.dtype) for w_ref in w_refs]
+        if len(outs) == 2:
+            gate, up = (o.astype(jnp.float32) for o in outs)
+            o_ref[...] = (jax.nn.silu(gate) * up).astype(o_ref.dtype)
+        else:
+            o_ref[...] = outs[0]
 
 
 def _stack(rhs: jax.Array, layer) -> Tuple[jax.Array, jax.Array]:
@@ -123,33 +178,41 @@ def _stack(rhs: jax.Array, layer) -> Tuple[jax.Array, jax.Array]:
 
 def moe_grouped_matmul(lhs: jax.Array, rhs: jax.Array,
                        tile_expert: jax.Array, used: jax.Array,
-                       layer=None, interpret: bool = False) -> jax.Array:
+                       layer=None, interpret: bool = False,
+                       gate: Optional[jax.Array] = None) -> jax.Array:
     """lhs (TILES * tm, K) rows sorted by expert and padded per expert to
     the tile (``group_layout``); rhs (E, K, N), or (L, E, K, N) with the
     int32 scalar ``layer`` (may be traced); tile_expert (TILES,) int32;
     used (1,) int32. Returns (TILES * tm, N) in lhs's dtype. Rows of tiles
-    past ``used`` are not written: mask them where they are read."""
+    past ``used`` are not written: mask them where they are read.
+
+    With ``gate``, a second stack of ``rhs``'s shape, the call is a gated
+    expert's first half in one pass over the rows:
+    ``silu(lhs @ gate) * (lhs @ rhs)``, each product rounded to lhs's dtype
+    before the float32 gating, as two calls and a fusion between them give
+    it."""
     rows, K = lhs.shape
     rhs, layer = _stack(rhs, layer)
+    stacks = [rhs] if gate is None else [_stack(gate, layer)[0], rhs]
     N = rhs.shape[-1]
     tiles = tile_expert.shape[0]
     tm = rows // tiles
-    tn = _weight_block_cols(K, N, rhs.dtype)
+    tn = _weight_block_cols(K, N, rhs.dtype, len(stacks))
 
     def tile(i, used_ref):
         return jnp.minimum(i, jnp.maximum(used_ref[0] - 1, 0))
 
+    # layer and expert are squeezed: the kernel sees a (K, tn) block
+    weights = pl.BlockSpec((None, None, K, tn),
+                           lambda n, i, te, used, layer:
+                           (layer[0], te[i], 0, n))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(N // tn, tiles),
         in_specs=[
             pl.BlockSpec((tm, K),
                          lambda n, i, te, used, layer: (tile(i, used), 0)),
-            # layer and expert are squeezed: the kernel sees a (K, tn) block
-            pl.BlockSpec((None, None, K, tn),
-                         lambda n, i, te, used, layer:
-                         (layer[0], te[i], 0, n)),
-        ],
+        ] + [weights] * len(stacks),
         out_specs=pl.BlockSpec(
             (tm, tn), lambda n, i, te, used, layer: (tile(i, used), n)),
     )
@@ -158,17 +221,25 @@ def moe_grouped_matmul(lhs: jax.Array, rhs: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, N), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_bytes(K, tn, rhs.dtype, len(stacks))),
         name="moe_grouped_matmul",
         interpret=interpret,
-    )(tile_expert, used, layer, lhs, rhs)
+    )(tile_expert, used, layer, lhs, *stacks)
 
 
 def reference_grouped_matmul(lhs: jax.Array, rhs: jax.Array,
                              tile_expert: jax.Array, used: jax.Array,
-                             layer=None) -> jax.Array:
+                             layer=None,
+                             gate: Optional[jax.Array] = None) -> jax.Array:
     """The same product in plain ``jnp``: each tile against its expert's
-    matrix, gathered; tiles past ``used`` come back zero."""
+    matrix, gathered; tiles past ``used`` come back zero. With ``gate``,
+    the gated pass of ``moe_grouped_matmul``."""
+    if gate is not None:
+        g, up = (reference_grouped_matmul(lhs, w, tile_expert, used, layer
+                                          ).astype(jnp.float32)
+                 for w in (gate, rhs))
+        return (jax.nn.silu(g) * up).astype(lhs.dtype)
     rows, K = lhs.shape
     rhs, layer = _stack(rhs, layer)
     tiles = tile_expert.shape[0]

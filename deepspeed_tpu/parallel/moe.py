@@ -355,14 +355,13 @@ def _grouped_experts(xt: jax.Array, gates: jax.Array,
     else:       # CPU; or XLA partitions the layer over the mesh itself
         mm = reference_grouped_matmul
     f32 = jnp.float32
-    up = mm(xs, experts["w_up"], tile_expert, used, layer).astype(f32)
-    if activation == "swiglu":
-        gate = mm(xs, experts["w_gate"], tile_expert, used, layer).astype(f32)
-        inner = jax.nn.silu(gate) * up
+    if activation == "swiglu":      # gate and up in one pass over the rows
+        inner = mm(xs, experts["w_up"], tile_expert, used, layer,
+                   gate=experts["w_gate"])
     else:
-        inner = expert_activation(activation, up)
-    y = mm(inner.astype(xt.dtype), experts["w_down"], tile_expert, used,
-           layer)
+        up = mm(xs, experts["w_up"], tile_expert, used, layer).astype(f32)
+        inner = expert_activation(activation, up).astype(xt.dtype)
+    y = mm(inner, experts["w_down"], tile_expert, used, layer)
 
     # rows past the used tiles were never written: select, do not multiply
     picked = jnp.where(routed[:, None], y[jnp.minimum(slot, rows - 1)], 0)

@@ -162,14 +162,24 @@ def _paged_window(queries, rows, heads, d, block, maxb, kv_heads, window,
                 ((rows, maxb), I32), ((rows, queries), I32)]
 
 
-def _grouped_matmul(tokens, top_k, experts, k, n):
+def _grouped_matmul(tokens, top_k, experts, k, n, gated=False):
     """The expert matmul of `tokens` x `top_k` assignments laid out in
-    tiles, as parallel/moe.py calls it."""
+    tiles, as parallel/moe.py calls it: one matrix an expert out of a
+    model's `(L, E, K, N)` stack at a traced layer, or (`gated`) a gated
+    expert's gate and up in one call."""
     tm = tile_rows(tokens * top_k, experts, BF16)
     tiles = max_tiles(tokens * top_k, experts, tm)
-    return (moe_grouped_matmul,
-            [((tiles * tm, k), BF16), ((experts, k, n), BF16),
-             ((tiles,), I32), ((1,), I32)])
+    stack = ((GMM_LAYERS, experts, k, n), BF16)
+
+    def fn(lhs, tile_expert, used, layer, up, *gate):
+        return moe_grouped_matmul(lhs, up, tile_expert, used, layer,
+                                  **({"gate": gate[0]} if gated else {}))
+
+    return (fn, [((tiles * tm, k), BF16), ((tiles,), I32), ((1,), I32),
+                 ((), I32)] + [stack] * (2 if gated else 1))
+
+
+GMM_LAYERS = 3
 
 
 # gpt2-125m: 12 heads x 64, hidden 768, seq 1024, micro-batch 32.
@@ -203,16 +213,22 @@ CASES = {
         lambda: _paged_prefill(1024, 32, 64, 16, 520, kv_heads=8),
     "moe-up-prefill-lfm2-8b-a1b":
         lambda: _grouped_matmul(1024, 4, 32, 2048, 1792),
+    "moe-gated-prefill-lfm2-8b-a1b":
+        lambda: _grouped_matmul(1024, 4, 32, 2048, 1792, gated=True),
     "moe-down-prefill-lfm2-8b-a1b":
         lambda: _grouped_matmul(1024, 4, 32, 1792, 2048),
     "moe-up-decode-lfm2-8b-a1b":
         lambda: _grouped_matmul(16, 4, 32, 2048, 1792),
+    "moe-gated-decode-lfm2-8b-a1b":
+        lambda: _grouped_matmul(16, 4, 32, 2048, 1792, gated=True),
     "moe-down-decode-lfm2-8b-a1b":
         lambda: _grouped_matmul(16, 4, 32, 1792, 2048),
     "latent-decode-longcat-flash":
         lambda: _latent_decode(32, 64, 640, 512, 16, 320),
     "moe-up-decode-longcat-flash":
         lambda: _grouped_matmul(32, 12, 16, 6144, 2048),
+    "moe-gated-decode-longcat-flash":
+        lambda: _grouped_matmul(32, 12, 16, 6144, 2048, gated=True),
     "moe-down-decode-longcat-flash":
         lambda: _grouped_matmul(32, 12, 16, 2048, 6144),
     "mamba1-decode-phi-4-mini-flash":
@@ -237,16 +253,22 @@ CASES = {
         lambda: _paged_prefill(256, 64, 128, 16, 128, kv_heads=8),
     "moe-up-decode-solar-open2":
         lambda: _grouped_matmul(64, 8, 40, 4096, 1280),
+    "moe-gated-decode-solar-open2":
+        lambda: _grouped_matmul(64, 8, 40, 4096, 1280, gated=True),
     "moe-down-decode-solar-open2":
         lambda: _grouped_matmul(64, 8, 40, 1280, 4096),
     "paged-decode-olmoe-1b-7b": lambda: _paged_decode(16, 16, 128, 16, 128),
     "paged-prefill-olmoe-1b-7b": lambda: _paged_prefill(256, 16, 128, 16, 128),
     "moe-up-decode-olmoe-1b-7b":
         lambda: _grouped_matmul(16, 8, 64, 2048, 1024),
+    "moe-gated-decode-olmoe-1b-7b":
+        lambda: _grouped_matmul(16, 8, 64, 2048, 1024, gated=True),
     "moe-down-decode-olmoe-1b-7b":
         lambda: _grouped_matmul(16, 8, 64, 1024, 2048),
     "moe-up-prefill-olmoe-1b-7b":
         lambda: _grouped_matmul(256, 8, 64, 2048, 1024),
+    "moe-gated-prefill-olmoe-1b-7b":
+        lambda: _grouped_matmul(256, 8, 64, 2048, 1024, gated=True),
     "moe-down-prefill-olmoe-1b-7b":
         lambda: _grouped_matmul(256, 8, 64, 1024, 2048),
     "flash-fwd-gpt2-125m": lambda: _flash(32, 1024, 12, 64, grad=False),
@@ -311,10 +333,9 @@ def test_window_walk_compiles_for_v5e(v5e, monkeypatch, queries, rows):
 # ---------------------------------------------------------------------------
 
 
-def _dma_census(fn, args):
-    """The kernel ``fn`` lowers to, as Mosaic has it: for each DMA start and
-    each DMA wait, the loops around it (innermost first, by identity) and
-    the pages its first operand spans."""
+def _kernel_module(fn, args):
+    """(the ONE Mosaic kernel ``fn`` lowers to, parsed; the context it lives
+    in)."""
     import base64
 
     from jax._src.interpreters import mlir
@@ -325,6 +346,16 @@ def _dma_census(fn, args):
     assert len(bodies) == 1, len(bodies)
     context = mlir.make_ir_context()
     context.allow_unregistered_dialects = True
+    with context:
+        return ir.Module.parse(base64.b64decode(bodies[0])), context
+
+
+def _dma_census(fn, args):
+    """The kernel ``fn`` lowers to, as Mosaic has it: for each DMA start and
+    each DMA wait, the loops around it (innermost first, by identity) and
+    the pages its first operand spans."""
+    from jax._src.lib.mlir import ir
+
     starts, waits, met = [], [], []
 
     def visit(op, loops):
@@ -342,11 +373,24 @@ def _dma_census(fn, args):
                 for inner in block:
                     visit(inner, loops)
 
+    module, context = _kernel_module(fn, args)
     with context:
-        module = ir.Module.parse(base64.b64decode(bodies[0]))
         for op in module.body:
             visit(op, ())
     return starts, waits
+
+
+def _block_census(fn, args):
+    """Of a kernel whose copies are the pipeline's (its `BlockSpec`s): (the
+    grid, the block of each pipelined operand and result, in order), as the
+    kernel's own text declares them."""
+    module, context = _kernel_module(fn, args)
+    with context:
+        text = module.operation.get_asm(enable_debug_info=False)
+    grid = re.search(r"iteration_bounds = array<i64: ([0-9, ]+)>", text)
+    blocks = re.findall(r"window_bounds = array<i64: ([0-9, ]+)>", text)
+    as_ints = lambda found: tuple(int(n) for n in found.split(","))
+    return as_ints(grid.group(1)), [as_ints(b) for b in blocks]
 
 
 def _a_page_a_turn(census, sides):
@@ -431,6 +475,49 @@ def test_the_chunk_kernel_keeps_its_loops_of_starts(v5e):
 
 
 # ---------------------------------------------------------------------------
+# the expert matmul's weight block, read off the kernel's own text: a touched
+# expert's matrix is ONE block, one contiguous copy, wherever the call's
+# weight buffers fit their share of VMEM (PERF.md section 6, PR 68)
+# ---------------------------------------------------------------------------
+
+# case: the columns of its weight block (all of N: the whole matrix)
+GROUPED_BLOCKS = {
+    case: None for case in CASES if case.startswith("moe-")}
+# 6,144 x 2,048 is 24 MiB a matrix: two of them twice over do not fit, so
+# gate and up come as column blocks of 1,024 (12 MiB, runs of 32 KiB)
+GROUPED_BLOCKS["moe-gated-decode-longcat-flash"] = 1024
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_BLOCKS))
+def test_a_touched_experts_matrix_is_one_block(v5e, case):
+    """Every call of the grouped matmul at every cell's shape: the pipeline
+    makes the copies (no hand-made DMA), the weight block is the whole
+    `(K, N)` matrix of ONE expert of ONE layer, so the grid is `(1, TILES)`
+    and a touched expert's matrix moves once a call (the block index
+    changes only with the tile's expert); a gated call takes the row tile
+    ONCE beside two such blocks and writes one tile; the stack is the
+    call's operand where it lies, no layer's bank is sliced out of it."""
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=v5e) for s, dt in shapes]
+    assert _dma_census(fn, args) == ([], [])
+    (rows, K), (tiles,) = shapes[0][0], shapes[1][0]
+    _, E, _, N = shapes[4][0]
+    tm, matrices = rows // tiles, len(shapes) - 4
+    assert matrices == (2 if "gated" in case else 1)
+    tn = GROUPED_BLOCKS[case] or N
+    grid, blocks = _block_census(fn, args)
+    assert grid == (N // tn, tiles)
+    assert blocks == ([(tm, K)] + [(1, 1, K, tn)] * matrices + [(tm, tn)])
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "moe_grouped_matmul" in text
+    assert f"bf16[{E},{K},{N}]" not in text
+    assert f"bf16[{GMM_LAYERS},{E},{K},{N}]" in text
+    # the stack is read in place: nothing as large as a layer's bank is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * K * N
+
+
+# ---------------------------------------------------------------------------
 # the serving programs at the benchmark's size: a layer's pool is addressed
 # inside the arena, never copied out of it
 # ---------------------------------------------------------------------------
@@ -508,6 +595,12 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
             params, arena,
             arg(paged_kv.chunk_shape(maxb, chunk, recurrent,
                                      T.tail_runs(cfg) > 0), I32), key)
+
+
+def _custom_calls(text, kernel):
+    """How many Mosaic calls of ``kernel`` a compiled program's text holds."""
+    return sum(kernel in ln for ln in text.splitlines()
+               if "custom-call" in ln and "tpu_custom_call" in ln)
 
 
 def _fusion_roots(text):
@@ -651,7 +744,7 @@ def test_a_run_is_written_in_pages_and_a_token_in_a_row(v5e, monkeypatch,
 def test_olmoe_serving_program_computes_assigned_rows_only(v5e, monkeypatch,
                                                            kind, rows):
     """OLMoE-1B-7B's two serving programs (3 layers) for the chip: the
-    expert compute is the kernel `moe_grouped_matmul` (gate, up, down), no
+    expert compute is the kernel `moe_grouped_matmul` (gate with up, down), no
     tensor has the (experts, rows, hidden) shape of the capacity path's
     dispatch with C = T, the paged kernel is there at head size 128 and no
     pool-sized temporary is."""
@@ -660,7 +753,7 @@ def test_olmoe_serving_program_computes_assigned_rows_only(v5e, monkeypatch,
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "custom-call" in ln
              and "tpu_custom_call" in ln]
-    assert sum("moe_grouped_matmul" in ln for ln in calls) == 3
+    assert sum("moe_grouped_matmul" in ln for ln in calls) == 2
     assert sum(f"paged_{kind}_attention" in ln for ln in calls) == 1
     for width in (2048, 1024):
         assert f"[64,{rows},{width}]" not in text
@@ -700,7 +793,7 @@ def test_solar_serving_program_updates_the_states_where_they_lie(
              and "tpu_custom_call" in ln]
     assert sum("kda_decode_step" in ln for ln in calls) \
         == (3 if steps else 0)
-    assert sum("moe_grouped_matmul" in ln for ln in calls) == 3 * 4
+    assert sum("moe_grouped_matmul" in ln for ln in calls) == 2 * 4
     paged = "paged_decode_attention" if steps \
         else "paged_prefill_attention"
     assert sum(paged in ln for ln in calls) == 1
@@ -756,6 +849,17 @@ def test_nemotron_serving_program_updates_the_states_where_they_lie(
     for ln in calls:
         if "mamba2_decode_step" in ln:
             assert NEMOTRON_STATES in ln.split("custom-call(", 1)[0]
+    if steps:
+        # which of the ten expert matmuls' results the compiler keeps in
+        # fast memory (`S(1)` on the result's layout; ROADMAP A19 (1)),
+        # as read at PR 68: all five down calls' (3,456 x 1,024) and one
+        # up call's (3,456 x 2,688) under whole-matrix blocks, where the
+        # column blocks' calls had the five down results alone
+        fast = [ln.split("custom-call(", 1)[0] for ln in calls
+                if "moe_grouped_matmul" in ln
+                and "S(1)" in ln.split("custom-call(", 1)[0]]
+        assert sum("bf16[3456,1024]" in result for result in fast) == 5
+        assert len(fast) >= 5
     # a layer's experts are read where they lie: no (64, 1024, 2688) copy
     assert "bf16[64,1024,2688]" not in text
     assert "bf16[64,2688,1024]" not in text
@@ -884,6 +988,8 @@ def test_a_looped_stack_carries_its_arena_through_both_loops(
 LONGCAT_ROWS, LONGCAT_BLOCKS, LONGCAT_MAXB, LONGCAT_CHUNK = 32, 10241, 320, \
     1024
 LONGCAT_ARENA = f"bf16[8,{LONGCAT_BLOCKS},{BLOCK},640]"
+# the ONE double-layer body: gate with up, down
+LONGCAT_GMM = 2
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
@@ -927,7 +1033,7 @@ def test_a_double_layer_reads_its_sublayers_where_they_lie(
             offenders.append(line.strip()[:200])
     assert not offenders, "\n".join(offenders)
     assert calls == (2 if kind == "decode" else 0)
-    assert "moe_grouped_matmul" in text
+    assert _custom_calls(text, "moe_grouped_matmul") == LONGCAT_GMM
     # a sublayer's dense FFN matrix is 151 MB; the chunk's scores, 8 heads
     # at a time in float32, and its experts' rows are 0.4 GB
     assert compiled.memory_analysis().temp_size_in_bytes < (
@@ -935,6 +1041,8 @@ def test_a_double_layer_reads_its_sublayers_where_they_lie(
 
 
 LFM2_ROWS, LFM2_BLOCKS, LFM2_MAXB, LFM2_CHUNK = 16, 8321, 520, 1024
+# the six layer bodies with experts: gate with up, down
+LFM2_GMM = 2 * 6
 LFM2_ARENA = f"bf16[3,{LFM2_BLOCKS},{BLOCK},512]"
 LFM2_TAILS = f"bf16[9,{LFM2_ROWS + 1},2,2048]"
 
@@ -973,7 +1081,7 @@ def test_a_tail_only_stack_reads_banks_and_pools_where_they_lie(
             offenders.append(line.strip()[:200])
     assert not offenders, "\n".join(offenders)
     assert LFM2_ARENA in text and LFM2_TAILS in text
-    assert "moe_grouped_matmul" in text
+    assert _custom_calls(text, "moe_grouped_matmul") == LFM2_GMM
     assert ("paged_decode_attention" if kind == "decode"
             else "paged_prefill_attention") in text
     # a chunk's experts' rows, 4,096 x 1,792 twice over, and its scores
