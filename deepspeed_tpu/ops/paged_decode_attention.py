@@ -53,20 +53,34 @@ calls and the one place kernel or reference is chosen):
   row's ``length`` says how many are real (``start + n_valid`` keys). The
   grid runs over the rows; a row's step copies ITS resident pages up to its
   last real token, a tile at a time into the same two buffers, the next
-  tile in flight (``_pages_per_tile`` again: 512 keys a tile at the served
-  page, where the budget binds), and flash-accumulates every head against
-  each tile, a group of heads at a time (``_heads_per_group``: a slab of
-  whole 128-lane tiles of q, the page and the accumulator, which a loop
-  can address). No copy and no step for a pad slot's page or a table slot
-  past the real length; a row of length 0 writes zeros. The products take
-  q, k and v as they are stored (bf16 x bf16 as served), summed in
-  float32; scores, running max, sum and accumulator stay float32 and
-  ``scale`` multiplies the scores; ``p`` is ROUNDED to the values' dtype
-  for the value product, as the reference below and the training flash
-  kernels round it (with a chunk of rows in the product the three-term
-  float32 ``p`` of the decode walk would triple it). Only tiles that
-  overlap ``[start, start + C)`` pay for the causal mask. So a later chunk
-  never materializes the gathered view either.
+  tile in flight (``_chunk_tile_pages``: 1,024 keys a tile, under a budget
+  of the kernel's own), and flash-accumulates every head against each
+  tile. No copy and no step for a pad slot's page or a table slot past the
+  real length; a row of length 0 writes zeros. What ONE tile step executes
+  (PERF.md section 6, PR 69): a group of heads (``_heads_per_group``: the
+  KV heads of one 128-lane slab of the page, and their query heads) is
+  whole slabs. q is laid out block-diagonally over the slab once a row (a
+  head's rows hold its D lanes where its KV head's lie in the page, zeros
+  in the others'; ``scale`` folded in where that is exact), the group's
+  heads stacked along the rows, so ONE product against the slab as it is
+  stored scores them all and no head is ever sliced out of q, k, v or the
+  accumulator; running max (and sum) are lane-replicated, a row a query.
+  The chunk's queries go in query blocks and a tile's keys in sub-blocks
+  (``_chunk_blocks``), and a (query block, tile) VISIT computes the tile's
+  first sub-blocks up to the block's last query and no further
+  (``_tile_extent``): nothing above the chunk's diagonal, past the row's
+  keys, below every query's window or for pad queries alone; a visit every
+  query sees whole takes no mask, any other builds ONE mask for all its
+  groups. The products take q, k and v as they are stored (bf16 x bf16 as
+  served), summed in float32; scores, running max, sum and accumulator
+  stay float32; ``p`` is ROUNDED to the values' dtype for the value
+  product, as the reference below and the training flash kernels round it
+  (with a chunk of rows in the product the three-term float32 ``p`` of the
+  decode walk would triple it). Where a slab holds more heads than one,
+  the value product of a head carries ONES in the lanes of the slab's
+  other heads, so the MXU sums ``p``'s rows there and the running sum
+  rides the accumulator (``_sums_in_slab``; the flash forward's form). So
+  a later chunk never materializes the gathered view either.
 
 Layout contract (shared with ``models/transformer._layer_forward``): the
 arena is LEFT-ALIGNED — the token at absolute position ``p`` sits in block
@@ -100,7 +114,9 @@ sides: keys and values, or the one pool). The latent walk asks for
 pages gain by it; the kernel under a chunk of queries does not, its copies
 being noise beside its products, and keeps a loop of starts and a loop of
 waits for every tile. ``walk_page_counts`` tells the serving engine how
-many of a step's pages the form takes, by the same two rules.
+many of a step's pages the form takes, by the same two rules, and
+``prefill_block_counts`` how many of the sub-blocks a chunk's tiles span
+its tile steps leave out, by the kernel's own.
 
 ``reference_paged_attention`` is the pure-jnp oracle and CPU fallback:
 GQA-native over the view gathered straight from the arena
@@ -125,14 +141,18 @@ LANES = 128
 # with the dense decode kernel's tile sizing
 from .decode_attention import VMEM_KV_BUDGET as _VMEM_PAGE_BUDGET
 from .decode_attention import tiled_vmem_bytes
+from .flash_attention import _fold_scale, _scaled
 from . import registry
 
 # keys a tile of a walk holds at most (and at least 128, where the budget
 # allows): see ``_pages_per_tile``. A chunk of queries has the rows to feed
-# a wider tile; at the served page (2,048 lanes of bf16) the budget, not the
-# cap, sets its 512
+# a wider tile, and a budget of its own (``_chunk_tile_pages``)
 _TILE_KEYS = 256
-_CHUNK_TILE_KEYS = 512
+_CHUNK_TILE_KEYS = 1024
+# the chunk kernel's k + v tiles, two buffers each: it asks for its VMEM
+# itself (``vmem_limit_bytes``), so its tiles are not held to the decode
+# walks' budget
+_CHUNK_PAGE_BUDGET = 16 << 20
 
 
 def _check_page_fits(block_size: int, width: int, dtype,
@@ -170,7 +190,8 @@ def _layer_operand(layer) -> jax.Array:
 
 
 def _pages_per_tile(block_size: int, width: int, dtype,
-                    max_keys: int = _TILE_KEYS, sides: int = 2) -> int:
+                    max_keys: int = _TILE_KEYS, sides: int = 2,
+                    budget: int = _VMEM_PAGE_BUDGET) -> int:
     """Pages one tile of a walk holds — derived, not set: the largest power
     of two whose tiles, ``sides`` of them (k + v) with two buffers each, fit
     the VMEM budget as VMEM lays them out, capped at ``max_keys`` keys a
@@ -180,7 +201,7 @@ def _pages_per_tile(block_size: int, width: int, dtype,
     pages = 1
     while (2 * pages * block_size <= max_keys * 2 // sides
            and 2 * sides * tiled_vmem_bytes(2 * pages * block_size, width,
-                                            dtype) <= _VMEM_PAGE_BUDGET):
+                                            dtype) <= budget):
         pages *= 2
     return pages
 
@@ -650,29 +671,142 @@ def _heads_per_group(kv_heads: int, head_dim: int) -> int:
     return kv_heads
 
 
+# what one visit of the prefill kernel's tile step takes (``_chunk_blocks``):
+# the keys of a tile in sub-blocks of at most this many, and the stacked rows
+# of a group's heads (a query block's, times the heads) at most that many
+_BLOCK_KEYS = 512
+_BLOCK_ROWS = 2048
+# sublanes a packed 16-bit tile holds: a head's rows in the kernel's scratch
+# start on such a tile whatever the chunk
+_ROW_TILE = 16
+
+
+def _chunk_tile_pages(block_size: int, width: int, dtype) -> int:
+    """Pages a tile of the walk under a chunk of queries holds
+    (``_pages_per_tile`` at the chunk kernel's cap and budget: 1,024 keys at
+    every served page)."""
+    return _pages_per_tile(block_size, width, dtype, _CHUNK_TILE_KEYS,
+                           budget=_CHUNK_PAGE_BUDGET)
+
+
+def _chunk_blocks(chunk: int, heads: int, pages: int, block_size: int):
+    """``(QB, PB)``: the queries of a query block and the PAGES of a key
+    sub-block of the prefill kernel's tile step — derived, not set. A group
+    of ``heads`` query heads stacks a query block's rows a head, and the
+    float32 scores of those rows against a tile are what the step holds:
+    the chunk is halved until they are ``_BLOCK_ROWS`` rows at most (whole
+    row tiles; a small chunk is one block). A tile's ``pages`` (a power of
+    two) part into the largest runs of whole pages that hold at most
+    ``_BLOCK_KEYS`` keys, one page at least."""
+    qb = chunk
+    while heads * qb > _BLOCK_ROWS and qb % (2 * _ROW_TILE) == 0:
+        qb //= 2
+    pb = 1
+    while 2 * pb <= pages and 2 * pb * block_size <= _BLOCK_KEYS:
+        pb *= 2
+    return qb, pb
+
+
+def _chunk_geometry(chunk: int, n_heads: int, kv_heads: int, block_size: int,
+                    width: int, dtype):
+    """``(pages, HP, QB, PB)`` of a call of the prefill kernel, from its
+    static shapes: the pages of a tile, the KV heads of a group, the queries
+    of a query block and the pages of a key sub-block."""
+    pages = _chunk_tile_pages(block_size, width, dtype)
+    HP = _heads_per_group(kv_heads, width // kv_heads)
+    QB, PB = _chunk_blocks(chunk, n_heads // kv_heads * HP, pages,
+                           block_size)
+    return pages, HP, QB, PB
+
+
+def _sums_in_slab(heads_per_group: int) -> bool:
+    """Whether the running sum of a head's ``p`` rides the accumulator: a
+    slab of more heads than one leaves each the others' lanes, and ones
+    there have the value product sum ``p``'s rows; a slab that is one head's
+    keeps a lane-replicated sum of its own."""
+    return heads_per_group > 1
+
+
+def _tile_extent(start, length, q0, k0, QB: int, KB: int, nk: int,
+                 window: Optional[int], xp):
+    """``(n, whole)``: what the query block ``start + q0 ...`` (QB queries)
+    computes of the tile whose first key is ``k0``, in a row of ``length``
+    keys: its first ``n`` key sub-blocks (KB keys each, ``nk`` a tile) —
+    those that reach up to the block's last query and no further, 0 where
+    the tile lies above the diagonal, past the row's keys, below every
+    query's window, or all of the block's queries are pad — and ``whole``
+    where every query sees every key of them, so that no mask is needed. A
+    pad query stands at the row's last real position. The kernel asks it of
+    traced scalars and ``prefill_block_counts`` of host integers (``xp``:
+    jnp or numpy)."""
+    q_lo = xp.minimum(start + q0, length - 1)
+    q_hi = xp.minimum(start + q0 + QB - 1, length - 1)
+    n = xp.clip((q_hi - k0) // KB + 1, 0, nk)
+    n = xp.where(start + q0 < length, n, 0)
+    last = k0 + n * KB - 1
+    whole = last <= q_lo
+    if window is not None:
+        n = xp.where(last > q_lo - window, n, 0)
+        whole = whole & (k0 > q_hi - window)
+    return n, whole
+
+
+def prefill_block_counts(start, lengths, chunk: int, n_heads: int,
+                         head_dim: int, arena,
+                         window: Optional[int] = None) -> Dict[str, int]:
+    """What the prefill kernel's tile steps meet under a chunk of ``chunk``
+    queries a row, ``n_heads`` heads of ``head_dim``, from the rows'
+    ``start`` and ``lengths`` (host integers, as ``paged_prefill_attention``
+    takes them) and the ``arena`` (..., BLOCK, lanes) it walks:
+    ``prefill_blocks``, the (query block, key sub-block) pairs that the
+    rows' tiles span, and ``prefill_blocks_skipped``, those of them the
+    kernel does not compute (``_tile_extent``: past a row's keys, above the
+    diagonal, below the window, all pad). By the kernel's own rules:
+    ``_pages_per_tile``, ``_heads_per_group``, ``_chunk_blocks``."""
+    block, width = arena.shape[-2:]
+    pages, _, QB, PB = _chunk_geometry(chunk, n_heads, width // head_dim,
+                                       block, width, arena.dtype)
+    TK, KB, nk = pages * block, PB * block, pages // PB
+    start, lengths = (np.asarray(a, np.int64).reshape(-1, 1)
+                      for a in (start, lengths))
+    tiles = -(-lengths // TK)                                   # (rows, 1)
+    computed = 0
+    for q0 in range(0, chunk, QB):
+        k0 = np.arange(int(tiles.max(initial=0)))[None] * TK    # (1, tiles)
+        n, _ = _tile_extent(start, lengths, q0, k0, QB, KB, nk, window, np)
+        computed += int(np.where(k0 < tiles * TK, n, 0).sum())
+    blocks = int(tiles.sum()) * nk * (chunk // QB)
+    return {"prefill_blocks": blocks,
+            "prefill_blocks_skipped": blocks - computed}
+
+
 def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
-                    v_hbm, alibi_ref, o_ref, kbuf, vbuf, sems, acc, m_scr,
-                    l_scr, slot_ref, *, scale: float, n_heads: int,
-                    kv_heads: int, has_alibi: bool,
+                    v_hbm, alibi_ref, o_ref, kbuf, vbuf, sems, qbd, acc,
+                    m_scr, *rest, scale: float, n_heads: int, kv_heads: int,
+                    has_alibi: bool, block_pages: int,
                     window: Optional[int] = None):
+    *l_scr, slot_ref = rest
     b = pl.program_id(0)
     B = pl.num_programs(0)
     _, P, BS, W = kbuf.shape
     TK = P * BS
+    PB = block_pages
+    KB, nk = PB * BS, P // PB
     C = q_ref.shape[1]
     D = W // kv_heads
     G = n_heads // kv_heads
-    n_groups = m_scr.shape[0]
-    HP = kv_heads // n_groups           # KV heads a group; G * HP queries'
-    pd = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+    # a group's scratch: (query blocks, its heads x QBP rows, the slab)
+    n_groups, nq, rows, WG = qbd.shape
+    HP = WG // D                        # KV heads a group, G * HP queries'
+    QBP = rows // (G * HP)              # a head's rows: QB, up to a row tile
+    QB = C // nq
+    # the sum of a head's p rides the lanes its slab leaves it, else l_scr
+    sums_in_acc = not l_scr
+    pd = qbd.dtype
+    scale_s = 1.0 if _fold_scale(pd, scale) else scale
     start = start_ref[b]
     length = len_ref[b]
     n_tiles = pl.cdiv(length, TK)
-    # tiles wholly at or below ``start`` are visible to every query
-    n_open = jnp.minimum((start + 1) // TK, n_tiles)
-    if window is not None:
-        # under a window no tile is open to every query: each masks its own
-        n_open = 0
     each_copy = _page_copies(bt_ref, len_ref, layer_ref[0],
                              ((k_hbm, kbuf), (v_hbm, vbuf)), sems)
 
@@ -680,22 +814,36 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
         """Group ``g``'s slab of ``width`` lanes: where there is more than
         one group a slab is whole 128-lane tiles, so a loop can address it."""
         first = g * width
-        return pl.ds(first if n_groups == 1 else pl.multiple_of(first, LANES),
-                     width)
+        return pl.ds(first if isinstance(first, int)
+                     else pl.multiple_of(first, LANES), width)
 
-    def each_group(body):
-        if n_groups == 1:
+    def each(n, body):
+        """``body(i)`` for i < n: a loop, unless there is one turn."""
+        if n == 1:
             return body(0)
 
-        def step(g, carry):
-            body(g)
+        def step(i, carry):
+            body(i)
             return carry
 
-        jax.lax.fori_loop(0, n_groups, step, 0)
+        jax.lax.fori_loop(0, n, step, 0)
+
+    def queries(qb):
+        return pl.ds(qb * QB if isinstance(qb, int)
+                     else pl.multiple_of(qb * QB, QB), QB)
+
+    def head_rows(j):
+        return slice(j * QBP, j * QBP + QB)
+
+    own = [jax.lax.broadcasted_iota(jnp.int32, (1, WG), 1) // D == h
+           for h in range(HP)]
 
     @pl.when(b == 0)
     def _first_row():
         slot_ref[0] = 0
+        if QBP != QB:
+            # the rows between a head's queries and its next row tile
+            qbd[:] = jnp.zeros_like(qbd)
 
     @pl.when(n_tiles == 0)
     def _empty_row():
@@ -706,75 +854,137 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
         base = _first_tile(each_copy, len_ref, slot_ref, b)
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        for l in l_scr:
+            l[:] = jnp.zeros_like(l)
 
-        def tile(t, carry, *, masked: bool):
+        def lay_out(g, qb):
+            # q block-diagonal over its group's slab: a head's rows hold its
+            # D lanes where its KV head's lie in the page and zeros in the
+            # others', so that ONE product against the slab as it is stored
+            # scores every head of the group (an added zero changes no
+            # sum); the heads stacked along the rows. ``scale`` rides on q
+            # where that is exact
+            x = q_ref[0, queries(qb), lanes(g, G * HP * D)]
+            for j in range(G * HP):
+                h = j // G
+                piece, _ = _scaled(x[:, j * D:(j + 1) * D].astype(pd), scale)
+                beside = [jnp.zeros((QB, w), pd)
+                          for w in (h * D, WG - (h + 1) * D)]
+                qbd[g, qb, head_rows(j), :] = jnp.concatenate(
+                    [part for part in (beside[0], piece, beside[1])
+                     if part.shape[1]], axis=1)
+
+        each(n_groups, lambda g: each(nq, functools.partial(lay_out, g)))
+
+        def group(g, *, slot, qb, k0, keys, keep):
+            # the tile's first ``keys`` keys (static: whole sub-blocks)
+            k = kbuf[slot, :keys // BS, :, lanes(g, WG)].reshape(keys, WG)
+            v = vbuf[slot, :keys // BS, :, lanes(g, WG)].reshape(keys, WG)
+            s = jax.lax.dot_general(
+                qbd[g, qb], k.astype(pd), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)             # (rows, keys)
+            if scale_s != 1.0:
+                s = s * scale_s
+            if has_alibi:
+                # left-aligned layout: a column IS the key position
+                col = (k0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, keys), 1)).astype(jnp.float32)
+                slope = jnp.concatenate(
+                    [jnp.full((QBP, 1), alibi_ref[0, g * G * HP + j])
+                     for j in range(G * HP)], axis=0)
+                s = s + slope * col
+            if keep is not None:
+                s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[g, qb]                               # lane-replicated
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            m_scr[g, qb] = m_new
+            p = jnp.exp(s - m_new[:, :1])
+            if sums_in_acc:
+                # ones in the lanes of the slab's other heads: the value
+                # product sums p's rows into them, and the running sum is
+                # rescaled with the accumulator it rides in. p is rounded to
+                # the values' dtype, as the reference and the training flash
+                # kernels round it
+                p = p.astype(v.dtype)
+                pv = jnp.concatenate([
+                    jax.lax.dot_general(
+                        p[h * G * QBP:(h + 1) * G * QBP],
+                        jnp.where(own[h], v, jnp.ones_like(v)),
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    for h in range(HP)], axis=0)
+            else:
+                l_scr[0][g, qb] = corr * l_scr[0][g, qb] + jnp.sum(
+                    p, axis=1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            acc[g, qb] = (acc[g, qb] * (corr if WG == LANES else corr[:, :1])
+                          + pv)
+
+        def visit(qb, *, t, slot, keys, masked):
+            k0 = t * TK
+            keep = None
+            if masked:
+                # ONE mask a visit, for every group: a query sees the keys
+                # at or below its position and none past the row's real
+                # tokens (a pad query stands at the last of them: finite,
+                # and never read)
+                col = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+                qpos = jnp.minimum(
+                    start + qb * QB + jax.lax.broadcasted_iota(
+                        jnp.int32, (rows, 1), 0) % QBP, length - 1)
+                keep = col <= qpos                              # (rows, keys)
+                if window is not None:
+                    keep = keep & (col > qpos - window)
+            each(n_groups, functools.partial(group, slot=slot, qb=qb, k0=k0,
+                                             keys=keys, keep=keep))
+
+        def tile(t, carry):
             slot = (base + t) % 2
             _tile_arrives(each_copy, vbuf, b, B, t, n_tiles, slot, length)
 
-            def group(g):
-                # left-aligned layout: a tile's column IS the key position
-                col = t * TK + jax.lax.broadcasted_iota(jnp.int32, (1, TK), 1)
-                if masked:
-                    # a query sees the keys at or below its position and
-                    # none past the row's real tokens (a pad query past
-                    # them sees them all: finite, and never read)
-                    qpos = jnp.minimum(
-                        start + jax.lax.broadcasted_iota(jnp.int32, (C, 1),
-                                                         0), length - 1)
-                    keep = col <= qpos                          # (C, TK)
-                    if window is not None:
-                        keep = keep & (col > qpos - window)
-                k = kbuf[slot, :, :, lanes(g, HP * D)].reshape(TK, HP * D)
-                v = vbuf[slot, :, :, lanes(g, HP * D)].reshape(TK, HP * D)
-                q = q_ref[0, :, lanes(g, G * HP * D)]
-                a = acc[:, lanes(g, G * HP * D)]
-                out = []
-                for j in range(G * HP):     # static: a head is a lane slice
-                    kv = slice(j // G * D, (j // G + 1) * D)
-                    s = jax.lax.dot_general(
-                        q[:, j * D:(j + 1) * D].astype(pd),
-                        k[:, kv].astype(pd), (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * scale
-                    if has_alibi:
-                        s = s + (alibi_ref[0, g * G * HP + j]
-                                 * col.astype(jnp.float32))
-                    if masked:
-                        s = jnp.where(keep, s, NEG_INF)
-                    m_prev = m_scr[g, :, j:j + 1]
-                    m_new = jnp.maximum(m_prev,
-                                        jnp.max(s, axis=1, keepdims=True))
-                    p = jnp.exp(s - m_new)
-                    corr = jnp.exp(m_prev - m_new)
-                    l_scr[g, :, j:j + 1] = (
-                        corr * l_scr[g, :, j:j + 1]
-                        + jnp.sum(p, axis=1, keepdims=True))
-                    m_scr[g, :, j:j + 1] = m_new
-                    # p is rounded to the values' dtype, as the reference
-                    # and the training flash kernels round it; its sum is not
-                    out.append(
-                        a[:, j * D:(j + 1) * D] * corr + jax.lax.dot_general(
-                            p.astype(v.dtype), v[:, kv],
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-                acc[:, lanes(g, G * HP * D)] = jnp.concatenate(out, axis=1)
+            def block(qb):
+                n, whole = _tile_extent(start, length, qb * QB, t * TK, QB,
+                                        KB, nk, window, jnp)
+                # a body a number of sub-blocks, and one more for a whole
+                # tile that needs no mask (fewer sub-blocks are the
+                # diagonal's, or a ragged row's end: masked, or as good as)
+                for sub in range(1, nk + 1):
+                    at = dict(t=t, slot=slot, keys=sub * KB)
+                    if sub < nk:
+                        pl.when(n == sub)(functools.partial(
+                            visit, qb, masked=True, **at))
+                        continue
+                    for masked in (False, True):
+                        pl.when((n == sub) & (whole != masked))(
+                            functools.partial(visit, qb, masked=masked, **at))
 
-            each_group(group)
+            each(nq, block)
             return carry
 
-        jax.lax.fori_loop(0, n_open, functools.partial(tile, masked=False),
-                          0)
-        jax.lax.fori_loop(n_open, n_tiles,
-                          functools.partial(tile, masked=True), 0)
+        jax.lax.fori_loop(0, n_tiles, tile, 0)
         slot_ref[0] = (base + n_tiles) % 2
 
-        def finish(g):
-            a = acc[:, lanes(g, G * HP * D)]
-            o_ref[0, :, lanes(g, G * HP * D)] = jnp.concatenate(
-                [a[:, j * D:(j + 1) * D] / l_scr[g, :, j:j + 1]
-                 for j in range(G * HP)], axis=1).astype(o_ref.dtype)
+        def finish(g, qb):
+            out = []
+            for j in range(G * HP):
+                h = j // G
+                a = acc[g, qb, head_rows(j), :]
+                if sums_in_acc:
+                    other = (h + 1) % HP * D
+                    l = a[:, other:other + 1]
+                else:
+                    l = l_scr[0][g, qb, head_rows(j), :1]
+                # a block of pad queries alone was never computed: zeros
+                out.append(a[:, h * D:(h + 1) * D]
+                           / jnp.where(l == 0.0, 1.0, l))
+            o_ref[0, queries(qb), lanes(g, G * HP * D)] = (
+                out[0] if len(out) == 1
+                else jnp.concatenate(out, axis=1)).astype(o_ref.dtype)
 
-        each_group(finish)
+        each(n_groups, lambda g: each(nq, functools.partial(finish, g)))
 
 
 def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
@@ -796,36 +1006,70 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
     (B, C, N, D). The decode walk under a chunk of queries: the grid runs
     over the rows, a row's step copies ITS ``ceil(length / BLOCK)`` resident
     pages from the arenas in HBM, a tile of pages at a time with the next in
-    flight, and flash-accumulates every head against each tile, a group of
-    heads (``_heads_per_group``) at a time. The products take q, k and v as
-    they are stored and sum in float32; ``p`` is rounded to the values'
-    dtype for the value product. Queries past a row's real tokens come out
-    finite and mean nothing. ``window`` (static): a query sees its own key
-    and the ``window - 1`` before it, of the pages the table names, which
-    the caller starts at the window's first page (``paged_attention``)."""
+    flight, and flash-accumulates every head against each tile.
+
+    How a tile step's blocks are derived (``_chunk_blocks``, from the call's
+    static shapes alone): a GROUP is the KV heads of one 128-lane slab of
+    the page with their query heads (``_heads_per_group``; the whole page
+    where it has no such slab); a QUERY BLOCK is the chunk, halved while
+    the group's heads times its queries, the rows one product stacks,
+    exceed ``_BLOCK_ROWS``; a tile's keys part into SUB-BLOCKS of the whole
+    pages that hold ``_BLOCK_KEYS`` keys at most. A (query block, tile)
+    visit computes the tile's first sub-blocks up to the block's last
+    query (``_tile_extent``): none of a tile above the chunk's diagonal,
+    past the row's keys, below every query's window, or for pad queries
+    alone; a visit every query sees whole takes no mask, any other builds
+    ONE mask for all its groups. Inside a visit a group's heads are whole
+    slabs: q lies block-diagonally over the slab (laid out once a row,
+    ``scale`` folded in where that is exact), so one product scores the
+    group's heads, stacked along its rows, against the slab as stored; no
+    head is sliced out of q, k, v or the accumulator. Running max (and
+    sum) are lane-replicated, a row a query. The products take q, k and v
+    as they are stored and sum in float32; ``p`` is rounded to the values'
+    dtype for the value product. Where a slab holds more heads than one
+    (``_sums_in_slab``), a head's value product carries ones in the lanes
+    of the slab's OTHER heads and so the sum of ``p`` (of the rounded
+    ``p``: the weights that were applied), rescaled with the accumulator it
+    rides in; a slab that is one head's keeps a float32 sum of its own.
+    Queries past a row's real tokens come out finite (zeros, where their
+    block holds no real query) and mean nothing. ``window`` (static): a
+    query sees its own key and the ``window - 1`` before it, of the pages
+    the table names, which the caller starts at the window's first page
+    (``paged_attention``)."""
     B, C, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
     BS, W = k_arena.shape[2:]
     _check_page_fits(BS, W, k_arena.dtype)
-    pages = _pages_per_tile(BS, W, k_arena.dtype, _CHUNK_TILE_KEYS)
-    HP = _heads_per_group(K, D)
+    pages, HP, QB, PB = _chunk_geometry(C, N, K, BS, W, k_arena.dtype)
+    G = N // K
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
     alibi_arr = (alibi.astype(jnp.float32).reshape(1, N) if has_alibi
                  else jnp.zeros((1, N), jnp.float32))
     if lengths is None:
         lengths = start + C
-    # running max and sum: a group's heads in columns 0..G*HP-1
-    stats = (K // HP, C, pl.cdiv(N // K * HP, LANES) * LANES)
+    # the products take q and the keys in the wider of their two dtypes
+    pd = jnp.promote_types(q.dtype, k_arena.dtype)
+    # a group's scratch: its G * HP query heads stacked along the rows, each
+    # on a row tile of its own, a query block at a time; as wide as the slab
+    rows = G * HP * pl.cdiv(QB, _ROW_TILE) * _ROW_TILE
+    per_group = (K // HP, C // QB, rows)
+    # the running sum needs lanes of its own where a slab is one head's
+    stats = [per_group + (LANES,)] * (1 if _sums_in_slab(HP) else 2)
     # what the kernel holds: the k + v tiles, q and the output as the
-    # pipeline double-buffers them, the accumulator and the statistics; and
-    # room for a few (C, tile) float32 blocks of a head's scores (a chunk of
-    # 256 passes the 16 MiB a kernel gets unasked; never ask for less)
+    # pipeline double-buffers them, q block-diagonal, the accumulator and
+    # the statistics; and what a visit makes: a group's slabs of a tile (k,
+    # v, and v under each head's ones) and its (rows, tile) blocks of scores
+    # (float32 scores, p, p rounded, a mask); 4 MiB more are the compiler's
+    # own (a tile's tail zeroed in float32, a page's copies)
     held = (4 * tiled_vmem_bytes(pages * BS, W, k_arena.dtype)
             + 4 * tiled_vmem_bytes(C, N * D, q.dtype)
-            + tiled_vmem_bytes(C, N * D, jnp.float32)
-            + 2 * stats[0] * tiled_vmem_bytes(*stats[1:], jnp.float32))
-    scores = tiled_vmem_bytes(C, pages * BS, jnp.float32)
+            + K // HP * (C // QB) * (
+                tiled_vmem_bytes(rows, HP * D, pd)
+                + tiled_vmem_bytes(rows, HP * D, jnp.float32)
+                + len(stats) * tiled_vmem_bytes(rows, LANES, jnp.float32)))
+    visit = ((2 + HP) * tiled_vmem_bytes(pages * BS, HP * D, pd)
+             + 4 * tiled_vmem_bytes(rows, pages * BS, jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
@@ -842,14 +1086,15 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
             pltpu.VMEM((2, pages, BS, W), k_arena.dtype),
             pltpu.VMEM((2, pages, BS, W), v_arena.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),          # (k | v, buffer)
-            pltpu.VMEM((C, N * D), jnp.float32),
-            pltpu.VMEM(stats, jnp.float32),
-            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM(per_group + (HP * D,), pd),    # block-diagonal q
+            pltpu.VMEM(per_group + (HP * D,), jnp.float32),
+            *(pltpu.VMEM(shape, jnp.float32) for shape in stats),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     kernel = functools.partial(_prefill_kernel, scale=scale, n_heads=N,
                                kv_heads=K, has_alibi=has_alibi,
+                               block_pages=PB,
                                **({} if window is None
                                   else {"window": int(window)}))
     out = pl.pallas_call(
@@ -859,7 +1104,7 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
         # rows in order: a row starts the copies of the next one's first tile
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=max(held + 8 * scores, 16 << 20)),
+            vmem_limit_bytes=held + visit + (4 << 20)),
         name="paged_prefill_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),

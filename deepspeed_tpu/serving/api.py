@@ -260,6 +260,8 @@ class ServingEngine:
         # are the pages'; what cannot read or move such a pool yet is
         # refused in ``_no_latent_read``
         self._latent_pools = latent_pools(cfg)
+        # what a chunk's span counts of the prefill kernel's tile steps
+        self._query_heads = (cfg.num_heads, cfg.head_dim)
         # window layers keep their keys in a ring of pages a row, beside the
         # row's state slot and bounded whatever the row's length
         self._window_layers = len(ring_layers(cfg))
@@ -1521,12 +1523,24 @@ class ServingEngine:
 
     def _chunk_span(self, obs, req: Request, start: int, ahead: bool = False):
         """A ``serving/prefill_chunk`` span with what each of a chunk's
-        spans carries: whose chunk, where in the prompt it starts, and
-        whether it was enqueued AHEAD (``_chunk_ahead``)."""
-        return obs.span("serving/prefill_chunk", cpu=True, rid=req.rid,
+        spans carries: whose chunk, where in the prompt it starts, whether
+        it was enqueued AHEAD (``_chunk_ahead``), and on a recorded span
+        ``prefill_blocks`` and ``prefill_blocks_skipped`` (``ops/
+        paged_decode_attention.prefill_block_counts``)."""
+        span = obs.span("serving/prefill_chunk", cpu=True, rid=req.rid,
                         chunk_start=int(start), ahead=int(ahead),
                         sampled_rows=self._sampled_rows([req]),
                         **self._loop_counts)
+        if span.recording and not self._latent_pools:
+            # the kernels' module counts the (query, key) sub-blocks the
+            # chunk's tiles span and those its tile steps leave out (a
+            # latent pool's chunk reads expanded, by no such kernel)
+            from ..ops.paged_decode_attention import prefill_block_counts
+            C = self.config.prefill_chunk
+            span.annotate(**prefill_block_counts(
+                [start], [min(start + C, int(req.prompt.size))], C,
+                *self._query_heads, self._arena["k"]))
+        return span
 
     def _prepare_chunk(self, obs, req: Request,
                        start: int) -> Optional[tuple]:

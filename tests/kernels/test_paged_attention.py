@@ -108,8 +108,8 @@ def _poison_what_no_row_reads(arena, bt, lengths):
 
 
 # the chunk walk's edges, a row as (start, real tokens): the arenas of these
-# tests give the prefill kernel a tile of 32 pages
-CHUNK_TILE = 32 * BS
+# tests give the prefill kernel a tile of 64 pages
+CHUNK_TILE = 64 * BS
 CHUNKS = {
     "start-0": [(0, 16)],
     "ends-on-a-tile-edge": [(CHUNK_TILE - 16, 16)],
@@ -131,12 +131,53 @@ CHUNKS = {
 CHUNK_SLOTS = {"verify": 5}     # every other case: 16 slots a row
 
 
-def _chunk_case(case, nb, n, d, maxb=3 * 32 + 8, seed=0, dtype=jnp.float32):
-    """(q, block_table, start, lengths, positions, real) of ``CHUNKS[case]``:
-    positions -1 on the slots past a row's real tokens, as the serving
-    programs send them; ``real`` marks the queries a caller reads."""
-    rows = CHUNKS[case]
-    C = CHUNK_SLOTS.get(case, 16)
+# the tile step's sub-blocks (PR 69): chunks large enough to have them. A
+# tile of these arenas is 1,024 keys in two key sub-blocks of 512; a case is
+# (slots a row, heads, KV heads, head size, rows as (start, real tokens),
+# alibi)
+TILE_STEPS = {
+    # the row's keys end inside its second tile's FIRST key sub-block
+    "ends-inside-a-first-sub-block": (256, 4, 4, 64, [(1024, 100)], False),
+    "ends-on-a-tile-edge": (256, 4, 4, 64, [(768, 256)], False),
+    "ends-one-past-a-sub-block": (256, 4, 4, 64, [(256, 256), (257, 256)],
+                                  False),
+    # fewer real tokens than slots: the pad queries' keys were never written
+    "ragged-last-chunk": (256, 4, 4, 64, [(1536, 77)], False),
+    # a row that holds nothing between two live rows: the row below starts
+    # its own first tile, in the buffer the slot's parity names
+    "empty-row-between-live-rows": (256, 4, 4, 64,
+                                    [(256, 256), (0, 0), (1024, 130)], False),
+    "heads-of-128": (256, 4, 4, 128, [(300, 256)], False),
+    "grouped-queries-of-128": (256, 8, 2, 128, [(1024, 256), (0, 9)], False),
+    "alibi-over-a-slab-of-two-heads": (256, 4, 2, 64,
+                                       [(300, 256), (0, 256)], True),
+    # 1,024 queries: 8 heads a group stack 8,192 rows, so two query blocks
+    # of 512; the first sees nothing of its tile's second sub-block
+    "chunk-of-1024-four-query-heads-a-kv-head": (
+        1024, 8, 2, 64, [(1024, 1024), (0, 700)], False),
+    "chunk-of-1024-a-head-a-kv-head": (1024, 4, 4, 64, [(512, 1024)], False),
+    "chunk-of-1024-heads-of-128": (1024, 2, 2, 128, [(0, 1024), (1024, 5)],
+                                   False),
+}
+
+
+def _tile_step_case(case, seed=0, dtype=jnp.float32):
+    """(q, k arena, v arena, table, start, lengths, positions, real, alibi)
+    of ``TILE_STEPS[case]``, by ``_chunk_case``'s rules."""
+    C, n, k, d, rows, alibi = TILE_STEPS[case]
+    nb = 1 + sum(-(-(s + v) // BS) for s, v in rows)
+    ka, va = _arena(nb=nb, k=k, d=d, seed=seed + 20, dtype=dtype)
+    out = _chunk_rows(rows, C, nb, n, d, maxb=max(
+        -(-(s + v) // BS) for s, v in rows), seed=seed, dtype=dtype)
+    return (out[0], ka, va) + out[1:] + (alibi_slopes(n) if alibi else None,)
+
+
+def _chunk_rows(rows, C, nb, n, d, maxb=3 * 64 + 8, seed=0,
+                dtype=jnp.float32):
+    """(q, block_table, start, lengths, positions, real) of rows given as
+    (start, real tokens), C slots each: positions -1 on the slots past a
+    row's real tokens, as the serving programs send them; ``real`` marks the
+    queries a caller reads."""
     bt, lengths = _walk_tables([s + v for s, v in rows], nb, maxb=maxb,
                                seed=seed)
     start = jnp.asarray(np.array([s for s, _ in rows], np.int32))
@@ -146,6 +187,12 @@ def _chunk_case(case, nb, n, d, maxb=3 * 32 + 8, seed=0, dtype=jnp.float32):
     q = jax.random.normal(jax.random.PRNGKey(16 + seed),
                           (len(rows), C, n, d), dtype)
     return q, bt, start, lengths, pos, real
+
+
+def _chunk_case(case, nb, n, d, **kwargs):
+    """``_chunk_rows`` of ``CHUNKS[case]``."""
+    return _chunk_rows(CHUNKS[case], CHUNK_SLOTS.get(case, 16), nb, n, d,
+                       **kwargs)
 
 
 def _dense_view(arena, layer, bt, d=32):
@@ -462,11 +509,10 @@ class TestPagedPrefillKernel:
         call; GQA 8 over 2 and head size 128. What lies past a row's real
         tokens is NaN, in k and in v, the scratch page too, and never
         reaches the output: the queries past them come out finite."""
-        assert paged_module._pages_per_tile(
-            BS, k * d, jnp.float32, paged_module._CHUNK_TILE_KEYS) * BS \
+        assert paged_module._chunk_tile_pages(BS, k * d, jnp.float32) * BS \
             == CHUNK_TILE
-        ka, va = _arena(nb=161, k=k, d=d, seed=7)
-        q, bt, start, lengths, pos, real = _chunk_case(case, 161, n, d)
+        ka, va = _arena(nb=321, k=k, d=d, seed=7)
+        q, bt, start, lengths, pos, real = _chunk_case(case, 321, n, d)
         ref = _pool_reference(q, ka, va, 1, bt, pos)
         out = paged_prefill_attention(
             q, _poison_what_no_row_reads(ka, bt, lengths),
@@ -480,9 +526,9 @@ class TestPagedPrefillKernel:
 
     @pytest.mark.parametrize("case", ["several-tiles", "mixed", "verify"])
     def test_walk_alibi_over_several_tiles(self, case):
-        ka, va = _arena(nb=161, k=2, seed=8)
+        ka, va = _arena(nb=321, k=2, seed=8)
         n = 4
-        q, bt, start, lengths, pos, real = _chunk_case(case, 161, n, 32,
+        q, bt, start, lengths, pos, real = _chunk_case(case, 321, n, 32,
                                                        seed=3)
         al = alibi_slopes(n)
         out = paged_prefill_attention(q, ka, va, 2, bt, start, lengths,
@@ -497,9 +543,9 @@ class TestPagedPrefillKernel:
         rounded to bf16 for the value product, everything summed in
         float32 — within the decode walk's 2e-2 of the reference computed in
         float32 from the same bf16 arena."""
-        ka, va = _arena(nb=161, k=2, seed=9, dtype=dtype)
+        ka, va = _arena(nb=321, k=2, seed=9, dtype=dtype)
         q, bt, start, lengths, pos, real = _chunk_case(
-            "mixed", 161, 4, 32, seed=5, dtype=dtype)
+            "mixed", 321, 4, 32, seed=5, dtype=dtype)
         out = paged_prefill_attention(q, ka, va, 0, bt, start, lengths,
                                       interpret=INTERPRET)
         assert out.dtype == dtype
@@ -510,7 +556,7 @@ class TestPagedPrefillKernel:
                                    np.asarray(ref)[real], atol=tol, rtol=tol)
 
     @pytest.mark.parametrize("case,maxb", [
-        ("small-table", 4), ("mixed", 128), ("mixed", 512), ("verify", 128),
+        ("small-table", 4), ("mixed", 160), ("mixed", 512), ("verify", 128),
         ("short-chunk", 128), ("several-tiles", 512)])
     def test_copies_follow_resident_pages_not_the_table(self, case, maxb,
                                                         monkeypatch):
@@ -519,8 +565,8 @@ class TestPagedPrefillKernel:
         row, and none for a table slot past it (nor for a pad slot's page),
         however wide the table is."""
         started = _count_started_copies(monkeypatch)
-        ka, va = _arena(nb=161, k=2, seed=6)
-        q, bt, start, lengths, _, _ = _chunk_case(case, 161, 4, 32,
+        ka, va = _arena(nb=321, k=2, seed=6)
+        q, bt, start, lengths, _, _ = _chunk_case(case, 321, 4, 32,
                                                   maxb=maxb)
         out = paged_prefill_attention(q, ka, va, 1, bt, start, lengths,
                                       interpret=INTERPRET)
@@ -528,6 +574,90 @@ class TestPagedPrefillKernel:
         jax.effects_barrier()
         resident = sum(-(-(s + v) // BS) for s, v in CHUNKS[case])
         assert len(started) == 2 * resident
+
+    @pytest.mark.parametrize("case", sorted(TILE_STEPS))
+    def test_tile_steps_compute_every_block_that_holds_something(self, case):
+        """Chunks of 256 and 1,024 queries, a head and four heads a KV head,
+        heads of 64 (two a slab) and of 128, under alibi: a row whose keys
+        end inside a tile's first key sub-block, on a tile's edge and one
+        past a sub-block's, a ragged last chunk, an empty row between live
+        ones. What lies past a row's real tokens is NaN in k and in v."""
+        q, ka, va, bt, start, lengths, pos, real, al = _tile_step_case(case)
+        ref = _pool_reference(q, ka, va, 1, bt, pos, alibi=al)
+        out = paged_prefill_attention(
+            q, _poison_what_no_row_reads(ka, bt, lengths),
+            _poison_what_no_row_reads(va, bt, lengths), 1, bt, start,
+            lengths, alibi=al, interpret=INTERPRET)
+        assert bool(jnp.all(jnp.isfinite(out)))
+        np.testing.assert_allclose(np.asarray(out)[real],
+                                   np.asarray(ref)[real],
+                                   atol=2e-5, rtol=2e-5)
+        assert not np.asarray(out)[np.asarray(lengths) == 0].any()
+
+    @pytest.mark.parametrize("case", [
+        "ends-inside-a-first-sub-block", "empty-row-between-live-rows",
+        "chunk-of-1024-four-query-heads-a-kv-head"])
+    def test_tile_steps_in_the_served_dtype(self, case, dtype=jnp.bfloat16,
+                                            tol=2e-2):
+        """bf16 as served, where a slab of two heads carries the sum of the
+        ROUNDED p in the lanes of the other head: within the walk's 2e-2 of
+        the reference computed in float32 from the same bf16 arena."""
+        q, ka, va, bt, start, lengths, pos, real, _ = _tile_step_case(
+            case, seed=1, dtype=dtype)
+        out = paged_prefill_attention(q, ka, va, 2, bt, start, lengths,
+                                      interpret=INTERPRET)
+        assert out.dtype == dtype
+        f32 = jnp.float32
+        ref = _pool_reference(q.astype(f32), ka.astype(f32), va.astype(f32),
+                              2, bt, pos)
+        np.testing.assert_allclose(np.asarray(out, np.float32)[real],
+                                   np.asarray(ref)[real], atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("window", [None, 512, 300])
+    @pytest.mark.parametrize("chunk,n,k,d", [(256, 32, 32, 64),
+                                             (1024, 32, 8, 64),
+                                             (256, 16, 16, 128),
+                                             (16, 4, 2, 32)])
+    def test_block_counts_match_a_count_over_the_mask(self, chunk, n, k, d,
+                                                      window):
+        """``prefill_block_counts`` against brute force: of the (query
+        block, key sub-block) pairs that a row's tiles span, the kernel
+        needs those in which some REAL query sees some key; it computes a
+        tile's sub-blocks up to the last it needs (under a window the first
+        of them may lie below every query's window: computed, and masked),
+        and none of a tile it needs nothing of."""
+        arena = jax.ShapeDtypeStruct((3, 64, BS, k * d), jnp.bfloat16)
+        pages, _, QB, PB = paged_module._chunk_geometry(
+            chunk, n, k, BS, k * d, arena.dtype)
+        TK, KB = pages * BS, PB * BS
+        rng = np.random.default_rng(chunk + (window or 0))
+        rows = [(0, chunk), (chunk, chunk), (3 * chunk, chunk), (TK, 1),
+                (TK - 1, min(chunk, 2)), (0, 0)] + [
+            (int(s), int(v)) for s, v in zip(
+                rng.integers(0, 4 * TK, 20), rng.integers(1, chunk + 1, 20))]
+        for s, v in rows:
+            length = s + v if v else 0
+            spanned = needed = computed = 0
+            key = np.arange(-(-length // TK) * TK)
+            for q0 in range(0, chunk, QB):
+                qpos = s + q0 + np.arange(QB)
+                qpos = qpos[qpos < length]
+                sees = (key[None] <= qpos[:, None]) & (key[None] < length)
+                if window is not None:
+                    sees &= key[None] > qpos[:, None] - window
+                need = sees.reshape(len(qpos), len(key) // KB, KB).any(
+                    axis=(0, 2))
+                spanned += len(need)
+                needed += int(need.sum())
+                for tile in need.reshape(-1, TK // KB):
+                    computed += int(np.flatnonzero(tile).max(initial=-1)) + 1
+            got = paged_module.prefill_block_counts(
+                [s], [length], chunk, n, d, arena, window=window)
+            assert got == {"prefill_blocks": spanned,
+                           "prefill_blocks_skipped": spanned - computed}, \
+                (s, v)
+            if window is None:
+                assert computed == needed, (s, v)
 
     def test_whole_chunk_is_real_where_no_length_is_given(self):
         ka, va = _arena(k=2)

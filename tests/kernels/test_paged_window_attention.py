@@ -253,3 +253,41 @@ def test_the_windowed_walk_takes_every_path_of_the_copies(path):
             q[r][None, None], pool_k, pool_v, 1, jnp.asarray(table[r][None]),
             jnp.asarray([[n - 1]], jnp.int32), window=n - int(lo[r]))[0, 0]
         assert np.abs(np.asarray(got[r]) - np.asarray(want)).max() < 2e-5, r
+
+
+# a chunk of 256 queries under a window of 512 over pages of 16 keys (Phi's
+# call, at few heads): (heads, KV heads, head size, start, real tokens)
+WINDOWED_CHUNKS = {
+    # every query's window reaches below the chunk's start; the first
+    # queries' lower edge lies inside the first page the walk is handed
+    "crosses-the-windows-lower-edge": (4, 4, 64, 700, 256),
+    "inside-the-window": (4, 4, 64, 100, 256),
+    "deep-in-a-long-row-ragged": (4, 2, 128, 1500, 100),
+    # the handed pages end one key past a tile (512 keys): 511 + 2 keys
+    "one-key-into-the-second-tile": (4, 4, 64, 511, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WINDOWED_CHUNKS))
+def test_a_large_chunk_under_a_window_of_512(case, kernels, monkeypatch):
+    """``paged_attention(window=512)`` through the prefill kernel's tile
+    steps (a tile of 512 keys in two sub-blocks; the table cut to the
+    window's first page) against the reference over the whole table."""
+    n, k, d, start, valid = WINDOWED_CHUNKS[case]
+    C, page, window = 256, 16, 512
+    nb = 1 + -(-(start + C) // page)
+    rng = np.random.default_rng(start)
+    pool_k, pool_v = (jnp.asarray(rng.standard_normal(
+        (2, nb, page, k * d)).astype(np.float32)) for _ in range(2))
+    table = jnp.asarray(rng.permutation(np.arange(1, nb))[None]
+                        .astype(np.int32))
+    q = jnp.asarray(rng.standard_normal((1, C, n, d)).astype(np.float32))
+    offs = np.arange(C)[None]
+    pos = jnp.asarray(np.where(offs < valid, start + offs, -1)
+                      .astype(np.int32))
+    got = paged.paged_attention(q, pool_k, pool_v, 1, table, pos,
+                                window=window)
+    want = paged.reference_paged_attention(q, pool_k, pool_v, 1, table, pos,
+                                           window=window)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert np.abs(np.asarray(got) - np.asarray(want))[0, :valid].max() < 2e-5

@@ -463,15 +463,39 @@ def test_the_chunk_kernel_keeps_its_loops_of_starts(v5e):
     """``paged_prefill_attention`` shares the walk's helpers and not the
     decode walks' forms: its copies are noise beside its products, so it
     keeps a loop of starts and a loop of waits, a page (k and v) a turn, and
-    its text stays short."""
+    its text stays short: ONE tile body since PR 69 (the sub-blocks of a
+    tile say which of them take a mask, not the tile), so three sites that
+    start a tile (a row's first, the next, the next row's first) and one
+    that waits, k and v each."""
     fn, shapes = _paged_prefill(256, 32, 64, 16, 128)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=v5e) for s, dt in shapes]
     starts, waits = _dma_census(fn, args)
-    assert len(starts) == 10 and len(waits) == 4
+    assert len(starts) == 6 and len(waits) == 2
     for census in (starts, [loops for loops, _ in waits]):
         paged, unrolled = _a_page_a_turn(census, 2)
         assert len(paged) == len(census) // 2 and not unrolled
     assert {n for _, n in waits} == {1}
+
+
+@pytest.mark.parametrize("case,most_mib", [
+    ("paged-prefill-opt-1.3b", 48), ("paged-prefill-lfm2-8b-a1b", 100),
+    ("paged-prefill-verify-opt-1.3b", 24)])
+def test_the_chunk_kernel_asks_for_the_vmem_it_holds(v5e, case, most_mib):
+    """What ``paged_prefill_attention`` asks for (``vmem_limit_bytes``, the
+    kernel's scoped memory in its text) at the two cells' shapes and the
+    verify step's: its tiles of 1,024 keys, q block-diagonal, the
+    accumulator and the lane-replicated statistics of every head, and a
+    visit's blocks of scores, under the chip's 128 MiB with room to spare;
+    and the compiler takes it (a kernel that holds more than it asked for
+    is refused here, as the verify step's was at 18.4 MiB)."""
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=v5e) for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    asked = [int(n) for n in re.findall(
+        r'scoped_memory_configs\\22: \[\{\\22memory_space\\22:1, '
+        r'\\22offset\\22: 0, \\22size\\22: (\d+)', lowered.as_text())]
+    assert len(asked) == 1 and 16 << 20 <= asked[0] <= most_mib << 20
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 # ---------------------------------------------------------------------------

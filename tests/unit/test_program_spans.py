@@ -789,6 +789,19 @@ class TestServingFromTheInside:
                            and a["walk_pages_whole"] == 0 for a in dec)
         assert any(a["walk_pages"] > a["rows"] for a in dec)
 
+    def test_a_chunk_counts_the_blocks_its_tile_steps_span(self, served):
+        """``prefill_blocks``: the (query block, key sub-block) pairs a
+        chunk's tiles span under the prefill kernel, on every span of a
+        chunk; ``prefill_blocks_skipped``: those its tile step leaves out.
+        A tiny prompt is one tile, which the arena of this engine parts
+        into sub-blocks of whole pages."""
+        spans, _, _ = served
+        chunks = [s["attrs"] for s in spans
+                  if s["name"] == "serving/prefill_chunk"]
+        assert chunks and all(
+            0 <= a["prefill_blocks_skipped"] < a["prefill_blocks"]
+            for a in chunks)
+
     def test_prefill_chunk_tokens_add_up_to_the_engines_count(self, served):
         spans, rise, _ = served
         chunks = [s["attrs"] for s in spans
@@ -847,6 +860,27 @@ def test_the_walks_page_counts_match_a_hand_count(lengths, pages, whole):
     latent = jax.ShapeDtypeStruct((8, 64, 16, 640), jnp.bfloat16)
     assert walk_page_counts(np.asarray(lengths), latent, latent=True) == {
         "walk_pages": pages, "walk_pages_whole": 64 * (max(lengths) > 1024)}
+
+
+@pytest.mark.parametrize("start, length, blocks, skipped", [
+    (0, 256, 2, 1), (256, 512, 2, 1), (512, 700, 2, 0), (1536, 1792, 4, 0),
+    (1024, 1100, 4, 1)])
+def test_the_chunks_block_counts_match_a_hand_count(start, length, blocks,
+                                                    skipped):
+    """opt-1.3b's shape: a chunk of 256 queries is one query block, a tile
+    of 1,024 keys two key sub-blocks of 512. Keys 0-255 and 0-511: the
+    tile's second half is above the diagonal; 188 real tokens at 512 reach
+    into the second half; a chunk at 1,536 spans two tiles and needs all of
+    both; 76 real tokens at 1,024 need the first half of their second tile
+    alone. Two rows add up, and a row that holds nothing adds nothing."""
+    from deepspeed_tpu.ops.paged_decode_attention import prefill_block_counts
+
+    arena = jax.ShapeDtypeStruct((24, 2956, 16, 2048), jnp.bfloat16)
+    assert prefill_block_counts([start], [length], 256, 32, 64, arena) == {
+        "prefill_blocks": blocks, "prefill_blocks_skipped": skipped}
+    assert prefill_block_counts([start, 0, 0], [length, 0, 256], 256, 32, 64,
+                                arena) == {
+        "prefill_blocks": blocks + 2, "prefill_blocks_skipped": skipped + 1}
 
 
 class TestTtftFromEntryToSubmit:
