@@ -135,8 +135,8 @@ def assert_block_divisible(max_seq_len: int, block_size: int) -> int:
 
 def _paged_layers(cfg) -> int:
     """Layers that keep pages of their own: those whose mixer is softmax
-    attention over all of a sequence (a window layer keeps a ring a row
-    beside the state slots, a cross layer reads another layer's pages)."""
+    attention over all of a sequence (a window layer keeps a ring for each
+    slot, a cross layer reads another layer's pages)."""
     from ..models.transformer import paged_layers
 
     return len(paged_layers(cfg))
@@ -163,9 +163,17 @@ def paged_pools(cfg) -> int:
     return cfg.loop_passes * _paged_layers(cfg)
 
 
-def _paged_shape(cfg, num_blocks: int, block_size: int):
-    return (paged_pools(cfg), num_blocks, block_size,
-            cfg.num_kv_heads * cfg.head_dim)
+def _paged_shapes(cfg, num_blocks: int, block_size: int,
+                  window: bool = False, pools: int = 0) -> tuple:
+    """The shapes of a pool of keys and of its values: ``"k"`` and ``"v"``
+    or, with ``window``, the rings ``"wk"`` and ``"wv"`` (``pools`` of
+    them). A token's key-value heads lie side by side in the lanes, the
+    form's own count of them, keys and values each as wide as they are
+    (``models/transformer.page_widths``)."""
+    from ..models.transformer import page_widths
+
+    return tuple((pools or paged_pools(cfg), num_blocks, block_size, lanes)
+                 for lanes in page_widths(cfg, window))
 
 
 # the names an arena of pages can have in the cache's dict: what a block of
@@ -182,46 +190,62 @@ def _page_shapes(cfg, num_blocks: int, block_size: int) -> Dict[str, tuple]:
     if pools:
         return {"latent": (pools, num_blocks, block_size,
                            latent_page_width(cfg))}
-    shape = _paged_shape(cfg, num_blocks, block_size)
-    return {"k": shape, "v": shape}
+    return dict(zip(("k", "v"), _paged_shapes(cfg, num_blocks, block_size)))
+
+
+def cache_slots(cache) -> int:
+    """Slots of the per-sequence pools in ``cache`` (0: it holds pages
+    alone): a recurrent mixer's ``"tail"`` has one a slot, and a cache
+    whose only per-sequence pools are window rings says it with
+    ``"slots"`` (``_state_shapes``). THE reader of either, for the programs
+    that send a row to its slot and for the ring's table."""
+    if "tail" in cache:
+        return cache["tail"].shape[1]
+    return cache["slots"].shape[0] if "slots" in cache else 0
 
 
 def _state_shapes(cfg, state_slots: int, dtype,
                   ring: tuple = (0, 0)) -> Dict[str, Any]:
-    """The second kind of per-sequence state, beside pages: for each
-    recurrent layer and slot a float32 state a head (a delta-rule layer's
-    matrix; a state-space layer's, in ``ops/mamba2.pack_states``' or
-    ``ops/mamba1``'s layout; none for a gated short convolution, whose
-    ``Mixer.state`` names no state shape) and the last ``taps - 1`` rows of
-    the convolution's input (in the model's dtype), which every such mixer
-    keeps: ``"tail"`` is what says the cache holds slots; and for each
-    window layer and slot a ring of ``ring`` = (pages, tokens a page) for its keys and
-    one for its values, behind one scratch page (``"wk"``, ``"wv"``: arenas
-    of pages as ``"k"`` and ``"v"`` are, which the paged kernels read
-    through the table ``models/transformer._ring_table`` makes). ``{}`` for
-    a model with no such layer."""
+    """The second kind of per-sequence state, beside pages, a SLOT a
+    sequence: for each recurrent layer and slot a float32 state a head (a
+    delta-rule layer's matrix; a state-space layer's, in
+    ``ops/mamba2.pack_states``' or ``ops/mamba1``'s layout; none for a gated
+    short convolution, whose ``Mixer.state`` names no state shape) and the
+    last ``taps - 1`` rows of the convolution's input (in the model's
+    dtype), which every such mixer keeps (``"tail"``); and for each window
+    layer and slot a ring of ``ring`` = (pages, tokens a page) for its keys
+    and one for its values, behind one scratch page (``"wk"``, ``"wv"``:
+    arenas of pages as ``"k"`` and ``"v"`` are, which the paged kernels read
+    through the table ``models/transformer._ring_table`` makes). A model may
+    have either or both; one with rings and no recurrent layer has no tail
+    to say how many slots there are, and keeps ``"slots"``, an int32 a slot
+    that nothing writes (``cache_slots`` reads the one or the other).
+    ``{}`` for a model with no such layer."""
     from ..models.transformer import MIXERS, recurrent_layers, ring_layers
 
     mixer, layers = recurrent_layers(cfg)
-    n = len(layers)
-    if not n:
+    n, windows = len(layers), len(ring_layers(cfg))
+    if not n and not windows:
         return {}
     if state_slots < 1:
-        raise ValueError("a model with recurrent layers needs state_slots: "
-                         "a slot a decode row and one scratch")
-    state, taps, width = MIXERS[mixer].state(cfg)
-    shapes = {"tail": ((n, state_slots, taps - 1, width), dtype)}
-    if state is not None:   # a short convolution keeps its tail and no state
-        shapes = {"state": ((n, state_slots) + state, jnp.float32), **shapes}
-    windows = len(ring_layers(cfg))
+        raise ValueError("a model with recurrent or window layers needs "
+                         "state_slots: a slot a decode row and one scratch")
+    if n:
+        state, taps, width = MIXERS[mixer].state(cfg)
+        shapes = {"tail": ((n, state_slots, taps - 1, width), dtype)}
+        if state is not None:   # a short convolution keeps a tail alone
+            shapes = {"state": ((n, state_slots) + state, jnp.float32),
+                      **shapes}
+    else:
+        shapes = {"slots": ((state_slots,), jnp.int32)}
     if windows:
         pages, block_size = ring
         if pages < 1:
             raise ValueError("a model with window layers needs the pages of "
                              "a row's ring (kv_cache.ring_blocks)")
-        pool = (windows, 1 + state_slots * pages, block_size,
-                cfg.num_kv_heads * cfg.head_dim)
-        shapes.update(wk=(pool, dtype), wv=(pool, dtype))
+        wk, wv = _paged_shapes(cfg, 1 + state_slots * pages, block_size,
+                               window=True, pools=windows)
+        shapes.update(wk=(wk, dtype), wv=(wv, dtype))
     return shapes
 
 
@@ -232,7 +256,7 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype,
     scratch block 0 (allocatable blocks are 1..num_blocks-1). Pages exist
     for the softmax layers only; a model with recurrent layers gets, in the
     same dict, the pools ``"state"`` and ``"tail"`` of ``state_slots`` slots
-    and, for its window layers, the rings ``"wk"`` and ``"wv"`` of
+    and one with window layers the rings ``"wk"`` and ``"wv"`` of
     ``ring_blocks`` pages a slot (``_state_shapes``), zeroed."""
     if num_blocks < 2:
         raise ValueError(f"num_blocks={num_blocks}: need the scratch block "
@@ -256,7 +280,7 @@ def paged_cache_memory_bytes(cfg, num_blocks: int, block_size: int,
 def state_pool_memory_bytes(cfg, state_slots: int, dtype,
                             ring: tuple = (0, 0)) -> int:
     """The state pools' footprint, a window layer's rings with them; 0 for
-    a model with no recurrent layer."""
+    a model with neither."""
     return sum(int(np.prod(sh)) * jnp.dtype(dt).itemsize for sh, dt
                in _state_shapes(cfg, state_slots, dtype, ring).values())
 

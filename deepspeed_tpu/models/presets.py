@@ -86,6 +86,39 @@ def lfm2_runs(layer_types, num_dense_layers: int) -> tuple:
     return tuple(runs + [(tuple(alone), 1)] * bool(alone))
 
 
+def mimo_runs(hybrid_layer_pattern, moe_layer_freq, num_layers: int) -> tuple:
+    """The published ``hybrid_layer_pattern`` (0 a full-attention layer, 1 a
+    window layer) and ``moe_layer_freq`` (0 a dense FFN, 1 experts) of the
+    ``mimo_v2_flash`` family as ``layer_runs``. A period ends with its full
+    layer, and equal neighbours merge into one run: all 48 layers are
+    ``(full_dense) x 1, (swa x 4, full) x 1, (swa x 5, full) x 7``. Fewer
+    layers are layer 0 and the pattern's LAST ``num_layers - 1``: the
+    leading dense layer once and whole periods of five window layers and a
+    full one, as every period behind the first is (7 layers: ``(full_dense)
+    x 1, (swa x 5, full) x 1``)."""
+    n = len(hybrid_layer_pattern)
+    layers = [0, *range(n - (num_layers - 1), n)][:min(num_layers, n)]
+    kinds = [("swa" if hybrid_layer_pattern[i] else "full")
+             + ("" if moe_layer_freq[i] else "_dense") for i in layers]
+    if "swa_dense" in kinds:
+        raise NotImplementedError("a window layer over a dense FFN has no "
+                                  "layer kind")
+    periods, period = [], []
+    for kind in kinds:
+        period.append(kind)
+        if kind.startswith("full"):
+            periods.append(tuple(period))
+            period = []
+    periods += [tuple(period)] * bool(period)
+    runs = []
+    for period in periods:
+        if runs and runs[-1][0] == period:
+            runs[-1] = (period, runs[-1][1] + 1)
+        else:
+            runs.append((period, 1))
+    return tuple(runs)
+
+
 def nemotron_h_pattern(hybrid_override_pattern: str) -> tuple:
     """The published ``hybrid_override_pattern`` of the ``nemotron_h``
     family, a character a layer, as a ``layer_pattern``: ``M`` a Mamba-2
@@ -197,6 +230,24 @@ _FAMILIES: Dict[str, Dict[str, Any]] = {
                  qk_norm="head", moe_score_func="sigmoid",
                  moe_router_bias=True, moe_norm_topk_prob=True,
                  moe_routed_scale=1.0),
+    # XiaomiMiMo/MiMo-V2-Flash config.json (model_type "mimo_v2_flash"):
+    # pre-RMSNorm halves, no bias; softmax attention in two forms, full
+    # layers (rope base rope_theta) and window layers of their own count of
+    # key-value heads, their own rope base (swa_rope_theta) and a learned
+    # sink a query head (add_swa_attention_sink_bias); keys wider than
+    # values, rope on the first partial_rotary_factor of a key's values,
+    # halves rotated, values times attention_value_scale; a leading dense
+    # SwiGLU layer, then SwiGLU experts by sigmoid scores with a choice-only
+    # bias (noaux_tc, one group), renormalised, no shared expert; the head
+    # untied. The order of its layers is ``mimo_runs`` of the size preset's
+    # ``hybrid_layer_pattern`` and ``moe_layer_freq``
+    "mimo-v2-flash": dict(norm="rmsnorm", position="rope",
+                          activation="swiglu", tie_embeddings=False,
+                          norm_eps=1e-5, rope_theta=5e6,
+                          window_rope_theta=1e4, window_sink=True,
+                          value_scale=0.707, moe_score_func="sigmoid",
+                          moe_router_bias=True, moe_norm_topk_prob=True,
+                          moe_routed_scale=1.0),
     "nemotron-h": dict(norm="rmsnorm", position="none", activation="relu2",
                        tie_embeddings=False, norm_eps=1e-5,
                        moe_score_func="sigmoid", moe_router_bias=True,
@@ -408,6 +459,33 @@ _SIZES: Dict[str, Dict[str, Any]] = {
         num_dense_layers=2, num_heads=4, num_kv_heads=2,
         dense_ffn_hidden_size=128, ffn_hidden_size=32, shortconv_taps=3,
         moe_num_experts=8, moe_top_k=3, vocab_size=256, max_seq_len=128),
+    # XiaomiMiMo/MiMo-V2-Flash config.json (309 B, 15 B active): 9 full
+    # layers of 4 key-value heads and 39 window layers (128 keys) of 8, 64
+    # query heads everywhere, keys 192 and values 128 wide, rope on the
+    # first int(192 x 0.334) = 64 values; ffn_hidden_size is an EXPERT's
+    # width (the source's moe_intermediate_size), the leading dense FFN's
+    # dense_ffn_hidden_size (its intermediate_size)
+    "mimo-v2-flash": dict(
+        family="mimo-v2-flash", hidden_size=4096, num_layers=48,
+        hybrid_layer_pattern=(0, 1, 1, 1, 1, 0) + (1, 1, 1, 1, 1, 0) * 7,
+        moe_layer_freq=(0,) + (1,) * 47,
+        num_heads=64, num_kv_heads=4, window_kv_heads=8, head_size=192,
+        v_head_dim=128, partial_rotary_factor=0.334, attention_window=128,
+        dense_ffn_hidden_size=16384, ffn_hidden_size=2048,
+        moe_num_experts=256, moe_top_k=8, vocab_size=152576,
+        max_seq_len=262144),
+    # 8 layers in three runs: (full_dense), (swa, swa, full) and (swa, swa,
+    # swa, full), so a full layer's pool is not its place among its kind; 2
+    # key-value heads in a full layer and 4 in a window layer, keys 24 and
+    # values 16 wide, rope on 8; 3 experts a token of 16
+    "tiny-mimo-v2-flash": dict(
+        family="mimo-v2-flash", hidden_size=64, num_layers=8,
+        hybrid_layer_pattern=(0, 1, 1, 0, 1, 1, 1, 0),
+        moe_layer_freq=(0,) + (1,) * 7,
+        num_heads=8, num_kv_heads=2, window_kv_heads=4, head_size=24,
+        v_head_dim=16, rotary_dim=8, attention_window=8,
+        dense_ffn_hidden_size=128, ffn_hidden_size=32, moe_num_experts=16,
+        moe_top_k=3, vocab_size=256, max_seq_len=128),
     # GShard/Switch-style 8-expert GPT (BASELINE tracked config #4)
     "moe-tiny": dict(family="gpt2", hidden_size=64, num_layers=2, num_heads=4,
                      vocab_size=256, max_seq_len=128, moe_num_experts=8),
@@ -434,6 +512,14 @@ def transformer_config(preset: str, dtype=jnp.float32, **overrides) -> Transform
         types, dense = kwargs.pop("layer_types"), kwargs.pop("num_dense_layers")
         kwargs.setdefault("layer_runs", lfm2_runs(
             tuple(types)[:kwargs["num_layers"]], dense))
+    if family == "mimo-v2-flash":
+        factor = kwargs.pop("partial_rotary_factor", None)
+        if factor is not None:
+            kwargs.setdefault("rotary_dim", int(kwargs["head_size"] * factor))
+        pattern = kwargs.pop("hybrid_layer_pattern")
+        freq = kwargs.pop("moe_layer_freq")
+        kwargs.setdefault("layer_runs", mimo_runs(
+            tuple(pattern), tuple(freq), kwargs["num_layers"]))
     return TransformerConfig(dtype=dtype, **kwargs)
 
 

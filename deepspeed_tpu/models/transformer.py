@@ -43,8 +43,8 @@ from .core import (EMBED, EXPERT, HEADS, KV_HEADS, LAYERS, MLP, Model, SEQ,
 # FFN twice over, and the model's expert FFN once across both. "conv" is the
 # gated short convolution (``_shortconv_mixer``) under the model's FFN.
 # Where the second entry is a key of ``FFNS`` the kind's layers have THAT
-# FFN whatever the model's other layers have: "conv_dense" and "attn_dense"
-# are a family's leading dense layers (its num_dense_layers /
+# FFN whatever the model's other layers have: "conv_dense", "attn_dense" and
+# "full_dense" are a family's leading dense layers (its num_dense_layers /
 # first_k_dense_replace), ``dense_ffn_hidden_size`` wide, under the mixer
 # that its expert layers have too. Kinds that share a mixer share its cache
 # pools: ``layer_places`` says which pool is a layer's
@@ -57,7 +57,8 @@ LAYER_KINDS = {"attn": ("attn", True), "kda": ("kda", True),
                "gmu": ("gmu", True), "shortcut": ("mla", True),
                "conv": ("shortconv", True),
                "conv_dense": ("shortconv", "dense"),
-               "attn_dense": ("attn", "dense")}
+               "attn_dense": ("attn", "dense"),
+               "full_dense": ("full", "dense")}
 # a kind whose layer is SUBLAYERS[kind] sublayers in sequence, norm, mixer,
 # add, norm, dense FFN (``dense_ffn_hidden_size`` wide), add, each with its
 # own weights (a leading axis of that length on the leaves ``ln1``, the
@@ -102,7 +103,18 @@ class TransformerConfig:
     attention_window: int = 256             # keys a query of a window layer
     #   sees, its own included: the 'local' layers of ``attention_layers``
     #   and the "swa" layers of ``layer_runs``, which the serving layer runs
-    #   through the paged kernels over a ring of pages a row
+    #   through the paged kernels over a ring of pages a slot, a slot a row
+    # what a family's WINDOW layers ("swa") have that its full layers do not
+    # (``attn_shape`` is the one reader): their own count of key-value
+    # heads (None: ``num_kv_heads``), their own rope base (None:
+    # ``rope_theta``) and a learned SINK a query head, a float that takes
+    # its share of the softmax's mass and adds no value (arXiv:2309.17453)
+    window_kv_heads: Optional[int] = None
+    window_rope_theta: Optional[float] = None
+    window_sink: bool = False
+    value_scale: float = 1.0                # v = value_scale * (h W_v), in
+    #   every softmax layer: the cached value is the scaled one (the
+    #   published attention_value_scale)
     attention_scale: Optional[float] = None
     #   (GPT-J/GPT-NeoX; GPT-J shares one LN — its import aliases ln2=ln1)
     rotary_dim: Optional[int] = None        # partial rotary: rope on the
@@ -254,7 +266,8 @@ class TransformerConfig:
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
-    v_head_dim: int = 0
+    v_head_dim: int = 0               # a head's VALUES, in the latent mixer
+    #   and in every softmax mixer alike (0: as wide as its keys, head_dim)
     a8_decode: bool = False           # W8A8: decode-shaped int8 weight sites
     #   quantize the activation row too and ride the MXU's s8xs8 path
     #   (set by InferenceEngine from InferenceConfig.quantize_activations;
@@ -270,6 +283,7 @@ class TransformerConfig:
                 self.ffn_hidden_size = 4 * self.hidden_size
         assert self.head_size or self.hidden_size % self.num_heads == 0
         assert self.num_heads % self.num_kv_heads == 0
+        assert self.num_heads % (self.window_kv_heads or 1) == 0
         self.layer_runs = tuple((tuple(kinds), int(n))
                                 for kinds, n in self.layer_runs)
         if self.layer_runs:
@@ -291,13 +305,10 @@ class TransformerConfig:
             records = [MIXERS[m] for m in mixers if m]
             if any(r.keeps not in ("pages", "latent") and not r.state
                    for r in records):
-                # what addresses a row's ring by its state slot, or reads
-                # what another layer made, is built by ``_run_layers`` alone
+                # what addresses a row's ring by its slot, or reads what
+                # another layer made, is built by ``_run_layers`` alone
                 assert self.layer_runs, \
                     f"{self.layer_pattern}: kinds of a stack of layer_runs"
-                assert any(r.state for r in records) or not any(
-                    r.keeps == "ring" for r in records), \
-                    "a window's ring is addressed by a row's state slot"
         if self.moe_experts_held:
             assert 0 < self.moe_experts_held <= self.moe_num_experts
         assert self.norm_position in ("pre", "post", "sandwich"), \
@@ -315,6 +326,11 @@ class TransformerConfig:
             assert self.dense_ffn_hidden_size, \
                 "a dense layer's FFN is dense_ffn_hidden_size wide"
         assert self.qk_norm in (False, True, "head"), self.qk_norm
+        if self.diff_attn:
+            assert self.v_head_dim in (0, self.head_dim) and not (
+                self.window_kv_heads or self.window_sink), \
+                "differential pairs fold keys and values of ONE width, the " \
+                "same heads in every form"
         if self.moe_zero_experts:
             assert self.moe_num_experts and not self.moe_latent_size, \
                 "zero-computation experts stand behind routed ones of the " \
@@ -1631,14 +1647,17 @@ def _write_pages(arena: jax.Array, layer: jax.Array, rows: jax.Array,
 
 
 def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
-               cached: bool = False, divided: bool = False
+               cached: bool = False, divided: bool = False,
+               form: Optional["AttnForm"] = None
                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The "attn" mixer's projections of ``h`` (B, S, H) as heads: q
-    (B, S, N, D), k and v (B, S, K, D), biased and normed, not yet roped.
-    ``cached``: the step keeps a cache (an inference program); ``divided``:
-    the run divides behind the heads (a mixed step)."""
+    """The softmax mixer's projections of ``h`` (B, S, H) as heads: q
+    (B, S, N, D), k (B, S, K, D) and v (B, S, K, Dv) by the ``form``'s
+    ``attn_shape``, biased, scaled and normed, not yet roped. ``cached``:
+    the step keeps a cache (an inference program); ``divided``: the run
+    divides behind the heads (a mixed step)."""
     B, S, _ = h.shape
-    N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    shape = attn_shape(cfg, form)
+    N, K, D, Dv = shape.heads, shape.kv_heads, shape.key_dim, shape.value_dim
     q = _qeinsum("bsh,hd->bsd", h, p["wq"], cfg.dtype, a8=cfg.a8_decode)
     k = _qeinsum("bsh,hd->bsd", h, p["wk"], cfg.dtype, a8=cfg.a8_decode)
     v = _qeinsum("bsh,hd->bsd", h, p["wv"], cfg.dtype, a8=cfg.a8_decode)
@@ -1646,6 +1665,8 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
+    if cfg.value_scale != 1.0:
+        v = v * jnp.asarray(cfg.value_scale, v.dtype)
     # rope with no norm over the rows before it: nothing stands between k's
     # product and the heads its rope wants, in a chunk as in a step
     # (a norm a head stands behind the split into heads, as rope does)
@@ -1683,25 +1704,26 @@ def _qkv_heads(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                     * w.astype(jnp.float32)).astype(cfg.dtype)
 
         return (head_norm(q, p["q_norm"]), head_norm(k, p["k_norm"]),
-                v.reshape(B, S, K, D))
+                v.reshape(B, S, K, Dv))
     if cfg.qk_norm:
         # over all heads at once (the published OlmoeAttention: q_norm and
         # k_norm are hidden-wide), before the heads are split and roped
         q = _norm(q, p["q_norm"], None, "rmsnorm", cfg.norm_eps)
         k = _norm(k, p["k_norm"], None, "rmsnorm", cfg.norm_eps)
     return (q.reshape(B, S, N, D), k.reshape(B, S, K, D),
-            v.reshape(B, S, K, D))
+            v.reshape(B, S, K, Dv))
 
 
 def _rope_qk(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
-             positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """q and k with the rotary embedding of ``positions`` (a model of
-    another position kind: as they came)."""
+             positions: jax.Array, form: Optional["AttnForm"] = None
+             ) -> Tuple[jax.Array, jax.Array]:
+    """q and k with the rotary embedding of ``positions``, at the ``form``'s
+    base (a model of another position kind: as they came)."""
     if cfg.position != "rope":
         return q, k
     D = cfg.head_dim
     rd = cfg.rotary_dim or D
-    cos, sin = rope_table(positions, rd, cfg.rope_theta)
+    cos, sin = rope_table(positions, rd, attn_shape(cfg, form).rope_theta)
     if rd < D:
         # partial rotary (GPT-J/NeoX): rope on the first rd dims only.
         # (GPT-J's interleaved convention is handled at import time by
@@ -1744,31 +1766,72 @@ class AttnForm:
     """What tells the softmax mixer's forms apart: what a layer projects,
     which pool it writes and which it reads (docs/models.md)."""
     window: bool = False    # keys and values go to a RING of pages a row
-    #   (the pools "wk" and "wv", addressed by the row's state slot) and a
-    #   query sees ``cfg.attention_window`` of them
+    #   (the pools "wk" and "wv", addressed by the row's slot) and a query
+    #   sees ``cfg.attention_window`` of them
     cross: bool = False     # q and the output projection alone: the keys
     #   and values are those of the layer ``step.shared_layer`` of "k", "v"
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnShape:
+    """The sizes of a softmax layer in one form, stated ONCE (``attn_shape``)
+    for the init, the projections, the rope, the pages and the benchmark's
+    cost functions alike."""
+    heads: int          # query heads
+    kv_heads: int       # key-value heads: ``heads // kv_heads`` queries each
+    key_dim: int        # a head's keys and queries
+    value_dim: int      # a head's values, and its share of the output
+    rope_theta: float
+    sink: bool          # a learned sink a query head in the softmax
+
+
+def attn_shape(cfg: TransformerConfig,
+               form: Optional[AttnForm] = None) -> AttnShape:
+    """THE answer to "how many heads, how wide, which rope base, a sink or
+    not" for a softmax layer of ``form`` (None: the plain "attn" mixer). A
+    window layer has what ``window_kv_heads``, ``window_rope_theta`` and
+    ``window_sink`` say; every other form the model's own."""
+    window = form is not None and form.window
+    return AttnShape(
+        heads=cfg.num_heads,
+        kv_heads=(window and cfg.window_kv_heads) or cfg.num_kv_heads,
+        key_dim=cfg.head_dim, value_dim=cfg.v_head_dim or cfg.head_dim,
+        rope_theta=(window and cfg.window_rope_theta) or cfg.rope_theta,
+        sink=bool(window and cfg.window_sink))
+
+
+def page_widths(cfg: TransformerConfig, window: bool = False
+                ) -> Tuple[int, int]:
+    """(lanes of a token's keys, of its values) in a pool of pages: the
+    arena's ``"k"`` and ``"v"``, or with ``window`` the rings' ``"wk"`` and
+    ``"wv"``; a token's key-value heads side by side."""
+    shape = attn_shape(cfg, AttnForm(window=window))
+    return shape.kv_heads * shape.key_dim, shape.kv_heads * shape.value_dim
 
 
 def _ring_table(cache: Dict[str, jax.Array], slots: jax.Array,
                 max_blocks: int) -> jax.Array:
     """The block table of a window layer, (B, max_blocks), made in the
-    program: the ring of the row's state slot, a run of pages of the pools
+    program: the ring of the row's slot, a run of pages of the pools
     ``"wk"`` and ``"wv"`` behind their scratch page 0, repeated. Position p
     then lies where the paged write and read look for it, in table entry
     ``p // BLOCK`` at offset ``p % BLOCK``; a page is written over when the
     ring comes round, which is after every query that could see it
     (``inference/kv_cache.ring_blocks``)."""
-    ring = (cache["wk"].shape[1] - 1) // cache["tail"].shape[1]
+    from ..inference.kv_cache import cache_slots
+
+    ring = (cache["wk"].shape[1] - 1) // cache_slots(cache)
     return (1 + slots[:, None] * ring
             + jnp.arange(max_blocks, dtype=jnp.int32)[None] % ring)
 
 
 def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
-                  v: jax.Array, step: Step, form: Optional[AttnForm] = None
+                  v: jax.Array, step: Step, form: Optional[AttnForm] = None,
+                  sink: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The "attn" mixer's write and read over the serving layer's pages;
-    with a ``form``, over a window's ring (no ``k``: the read alone)."""
+    with a ``form``, over a window's ring (no ``k``: the read alone).
+    ``sink`` (N,): the layer's learned sinks, where its form has them."""
     # PAGED serving path (deepspeed_tpu/serving/paged_kv.py): token at
     # absolute position p lands in physical block block_table[b, p//BS]
     # at offset p%BS — a scatter write. The layout is left-aligned
@@ -1788,8 +1851,13 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
     if form is not None:
         # the heads as ``_diff_pairs`` folds them: twice as wide, 1/sqrt of
         # the model's own head size
+        # a full layer's walk by whether later layers share its pool
         read = {"scale": cfg.head_dim ** -0.5,
-                "name": "shared_kv_decode_attention"}
+                "name": ("shared_kv_decode_attention"
+                         if "cross" in layer_kinds(cfg)
+                         else "full_kv_decode_attention")}
+        if sink is not None:
+            read["sink"] = sink
         if form.window:
             kn, vn = "wk", "wv"
             block_table = _ring_table(cache, step.state_slots,
@@ -1800,11 +1868,11 @@ def _attend_paged(cfg: TransformerConfig, q: jax.Array, k: jax.Array,
             return paged_attention(q, cache[kn], cache[vn], step.shared_layer,
                                    block_table, pos, **read), cache
     B, S, K, D = k.shape
-    q, k = _rope_qk(cfg, q, k, step.positions)
+    q, k = _rope_qk(cfg, q, k, step.positions, form)
     _, alibi = _attention_fn(cfg, None)
     BSz = cache[kn].shape[2]
     k_rows = k.reshape(B, S, K * D).astype(cache[kn].dtype)
-    v_rows = v.reshape(B, S, K * D).astype(cache[vn].dtype)
+    v_rows = v.reshape(B, S, -1).astype(cache[vn].dtype)
     write = _page_writer(step, block_table, BSz, S)
     ck = write(cache[kn], layer, k_rows)
     cv = write(cache[vn], layer, v_rows)
@@ -2064,10 +2132,11 @@ def _softmax(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
                 q = lax.optimization_barrier(q)     # see ``_qkv_heads``
             heads = (q.reshape(B, S, cfg.num_heads, cfg.head_dim), None, None)
         else:
-            heads = _qkv_heads(cfg, h, p, cached=True)
+            heads = _qkv_heads(cfg, h, p, cached=True, form=form)
         if cfg.diff_attn:
             heads = _diff_pairs(cfg, *heads)
-        attn, new_cache = _attend_paged(cfg, *heads, step, form)
+        attn, new_cache = _attend_paged(cfg, *heads, step, form,
+                                        p.get("sink"))
         if cfg.diff_attn:
             attn = _diff_combine(cfg, attn, p)
     else:
@@ -2077,7 +2146,7 @@ def _softmax(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
         attn, new_cache = attend(
             cfg, *_qkv_heads(cfg, h, p, cached=step.cache is not None,
                              divided=step.divide is not None), step)
-    attn = attn.reshape(B, S, cfg.num_heads * cfg.head_dim)
+    attn = attn.reshape(B, S, -1)       # (B, S, N * Dv)
     if "wg" in p:
         # the output gate: elementwise and full-rank, from the layer's input
         gate = _qeinsum("bsh,hd->bsd", h, p["wg"], cfg.dtype,
@@ -2091,14 +2160,16 @@ def _softmax(cfg: TransformerConfig, h: jax.Array, p: Dict[str, Any],
     return attn_out, new_cache
 
 
-def _attn_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
+def _attn_init(cfg: TransformerConfig, normal, uniform,
+               form: Optional[AttnForm] = None) -> Dict[str, Any]:
     H = cfg.hidden_size
-    N, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    shape = attn_shape(cfg, form)
+    N, K, D, Dv = shape.heads, shape.kv_heads, shape.key_dim, shape.value_dim
     p = {
         "wq": normal(0, (H, N * D)),
         "wk": normal(1, (H, K * D)),
-        "wv": normal(2, (H, K * D)),
-        "wo": normal(3, (N * D, H), _resid_std(cfg)),
+        "wv": normal(2, (H, K * Dv)),
+        "wo": normal(3, (N * Dv, H), _resid_std(cfg)),
     }
     if cfg.qk_norm == "head":
         p["q_norm"] = jnp.ones((D,), cfg.dtype)
@@ -2111,7 +2182,7 @@ def _attn_init(cfg: TransformerConfig, normal, uniform) -> Dict[str, Any]:
     if cfg.norm == "layernorm":
         p["bq"] = jnp.zeros((N * D,), cfg.dtype)
         p["bk"] = jnp.zeros((K * D,), cfg.dtype)
-        p["bv"] = jnp.zeros((K * D,), cfg.dtype)
+        p["bv"] = jnp.zeros((K * Dv,), cfg.dtype)
         p["bo"] = jnp.zeros((H,), cfg.dtype)
     return p
 
@@ -2212,14 +2283,27 @@ def _mamba2_state(cfg: TransformerConfig):
 _CROSS_LACKS = ("wk", "wv", "bk", "bv", "k_norm")
 
 
+# where a drawn sink stands. Random projections of std 0.02 give scores near
+# 0, so a window's keys weigh about their number together and a sink of s
+# takes e^s / (e^s + keys) of the mass: at 0 under 1% of a window of 128
+# keys, which a bfloat16 model's own rounding hides (leaving the sinks out
+# read 0.51-0.58 beside sound readings of 0.17-0.34, PERF.md section 6, PR
+# 70); at 3 +- 1 it takes 5-30%, as a trained sink takes a large share
+SINK_MEAN = 3.0
+
+
 def _form_init(cfg: TransformerConfig, normal, uniform,
                form: AttnForm) -> Dict[str, Any]:
     """The softmax mixer's subtree in one of its forms: the projections'
     biases drawn (std 0.02); a cross layer has a query and an output
     projection alone; differential attention adds four
     vectors of a head's size (normal, std 0.1, as published) and the scale
-    of the pairs' norm."""
-    p = _attn_init(cfg, normal, uniform)
+    of the pairs' norm; a form with a sink a float a query head, drawn with
+    a spread of 1 around ``SINK_MEAN``."""
+    p = _attn_init(cfg, normal, uniform, form)
+    if attn_shape(cfg, form).sink:
+        p["sink"] = SINK_MEAN + normal(58, (cfg.num_heads,), 1.0).astype(
+            jnp.float32)
     for tag, name in enumerate(("bq", "bk", "bv", "bo")):
         if name in p:       # drawn: a zero bias would leave a term untested
             p[name] = normal(54 + tag, p[name].shape)
@@ -2241,6 +2325,8 @@ def _form_axes(cfg: TransformerConfig, form: AttnForm) -> Dict[str, Any]:
         attn.update({name: (LAYERS, None) for name in (
             "lam_q1", "lam_k1", "lam_q2", "lam_k2", "subln")},
             lam_init=(LAYERS,))
+    if attn_shape(cfg, form).sink:
+        attn["sink"] = (LAYERS, None)
     return attn
 
 

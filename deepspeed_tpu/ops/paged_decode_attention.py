@@ -179,6 +179,16 @@ def _kv_heads(arena: jax.Array, n_heads: int, head_dim: int) -> int:
     return width // head_dim
 
 
+def _value_dim(v_arena: jax.Array, kv_heads: int) -> int:
+    """A head's values in ``v_arena`` (..., K * Dv): the values need not be
+    as wide as the keys, but they are the same heads."""
+    if v_arena.ndim != 4 or v_arena.shape[-1] % kv_heads:
+        raise ValueError(
+            f"the values' arena must be (L, NUM_BLOCKS, BLOCK, K*Dv) with "
+            f"the keys' K = {kv_heads}, got {v_arena.shape}")
+    return v_arena.shape[-1] // kv_heads
+
+
 def _layer_operand(layer) -> jax.Array:
     """``layer`` as the (1,) int32 scalar-prefetch operand."""
     return jnp.asarray(layer, jnp.int32).reshape(1)
@@ -384,14 +394,19 @@ def _softmax_step(s, m_scr, l_scr):
 
 def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
                    o_ref, kbuf, vbuf, sems, lane, diag, qbd, acc, m_scr,
-                   l_scr, slot_ref, *, scale: float, n_heads: int,
-                   kv_heads: int, has_alibi: bool, lo_ref=None):
+                   l_scr, slot_ref, *of_values, scale: float, n_heads: int,
+                   kv_heads: int, has_alibi: bool, lo_ref=None,
+                   sink_ref=None):
     r = pl.program_id(0)
     R = pl.num_programs(0)
     _, P, BS, W = kbuf.shape
     TK = P * BS
     N, D = q_ref.shape[1:]
     G = n_heads // kv_heads
+    # values as wide as the keys take their blocks out of the accumulator
+    # with the masks that laid q out; narrower ones have a pair of their own
+    lane_v, diag_v = of_values or (lane, diag)
+    Wv, Dv = vbuf.shape[3], o_ref.shape[2]
     layer = layer_ref[0]
     length = len_ref[r]
     n_tiles = pl.cdiv(length, TK)
@@ -414,6 +429,13 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
         diag[:] = (jax.lax.broadcasted_iota(jnp.int32, (N, W), 1) // D
                    == jax.lax.broadcasted_iota(jnp.int32, (N, W), 0) // G
                    ).astype(diag.dtype)
+        if of_values:
+            lane_v[:] = (jax.lax.broadcasted_iota(jnp.int32, (Dv, Wv), 1) % Dv
+                         == jax.lax.broadcasted_iota(jnp.int32, (Dv, Wv), 0)
+                         ).astype(lane_v.dtype)
+            diag_v[:] = (jax.lax.broadcasted_iota(jnp.int32, (N, Wv), 1) // Dv
+                         == jax.lax.broadcasted_iota(jnp.int32, (N, Wv), 0)
+                         // G).astype(diag_v.dtype)
 
     @pl.when(n_tiles == 0)
     def _empty_row():
@@ -423,8 +445,14 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
     def _row():
         base = _first_tile(each_copy, len_ref, slot_ref, r)
         acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sink_ref is None:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+        else:
+            # a head's sink is one more score that weighs no value: the
+            # running maximum starts at it and the running sum at its own 1
+            m_scr[:] = jnp.broadcast_to(sink_ref[0][:, None], m_scr.shape)
+            l_scr[:] = jnp.ones_like(l_scr)
         qbd[:] = (_dot_f32(q_ref[0], lane[:], 0) * diag[:]).astype(qbd.dtype)
 
         def tile(t, carry):
@@ -445,12 +473,12 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, alibi_ref,
             p, corr = _softmax_step(jnp.where(live, s, NEG_INF), m_scr, l_scr)
             # p goes into the value product as float32, not rounded to v's
             acc[:] = acc[:] * corr + _dot_f32(
-                p, vbuf[slot].reshape(TK, W), 0)               # (N, K*D)
+                p, vbuf[slot].reshape(TK, Wv), 0)              # (N, K*Dv)
             return carry
 
         jax.lax.fori_loop(0, n_tiles, tile, 0)
         slot_ref[0] = (base + n_tiles) % 2
-        out = _dot_f32(acc[:] * diag[:], lane[:], 1)           # (N, D)
+        out = _dot_f32(acc[:] * diag_v[:], lane_v[:], 1)       # (N, Dv)
         o_ref[0] = (out / l_scr[:, :1]).astype(o_ref.dtype)
 
 
@@ -461,10 +489,14 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
                            scale: Optional[float] = None,
                            interpret: bool = False,
                            lo: Optional[jax.Array] = None,
-                           name: str = "paged_decode_attention"
+                           name: str = "paged_decode_attention",
+                           sink: Optional[jax.Array] = None
                            ) -> jax.Array:
-    """q (R, N, D) — one new token per row; k/v_arena (L, NUM_BLOCKS, BLOCK,
-    K*D) — the whole shared arena; layer — int32 scalar (may be traced),
+    """q (R, N, D) — one new token per row; k_arena (L, NUM_BLOCKS, BLOCK,
+    K*D) and v_arena (L, NUM_BLOCKS, BLOCK, K*Dv) — the whole shared arena
+    (the values of a head need not be as wide as its keys: the walk copies
+    each side's pages as they are and returns (R, N, Dv)); layer — int32
+    scalar (may be traced),
     the layer whose pool is read; block_table (R, MAXB) int32 physical page
     ids (unfilled entries 0 = scratch); lengths (R,) int32 — valid keys per
     row INCLUDING the just-written token (0 ⇒ inactive row, output zeros).
@@ -472,10 +504,15 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
     ``lo`` (R,) int32, a window's form: row r sees the keys ``lo[r] <= key <
     lengths[r]`` of the pages its table names, which the caller starts at
     the window's first page (``paged_attention``); ``name`` is the kernel's
-    in a trace."""
+    in a trace. ``sink`` (N,) float32: a learned score a head that takes its
+    share of the softmax's mass and weighs no value, ``p_j = exp(s_j - m) /
+    (exp(sink - m) + sum_j' exp(s_j' - m))``: the running maximum starts at
+    it and the running sum at 1."""
     R, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
     BS, W = k_arena.shape[2:]
+    Dv = _value_dim(v_arena, K)
+    Wv = K * Dv
     _check_page_fits(BS, W, k_arena.dtype)
     pages = _pages_per_tile(BS, W, k_arena.dtype)
     scale = scale if scale is not None else D ** -0.5
@@ -485,6 +522,8 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
     # the products take q and the keys in the wider of their two dtypes
     pd = jnp.promote_types(q.dtype, k_arena.dtype)
     windowed = lo is not None
+    per_head = [alibi_arr] + ([] if sink is None else [
+        sink.astype(jnp.float32).reshape(1, N)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 + windowed,
         grid=(R,),
@@ -493,41 +532,53 @@ def paged_decode_attention(q: jax.Array, k_arena: jax.Array,
             # the arenas stay where they lie: the kernel copies pages itself
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, N), lambda r, *_: (0, 0)),
+            # alibi's slopes and the sinks, a float a head
+            *[pl.BlockSpec((1, N), lambda r, *_: (0, 0))] * len(per_head),
         ],
-        out_specs=pl.BlockSpec((1, N, D), lambda r, *_: (r, 0, 0)),
+        out_specs=pl.BlockSpec((1, N, Dv), lambda r, *_: (r, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, pages, BS, W), k_arena.dtype),
-            pltpu.VMEM((2, pages, BS, W), v_arena.dtype),
+            pltpu.VMEM((2, pages, BS, Wv), v_arena.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),          # (k | v, buffer)
             pltpu.VMEM((D, W), jnp.bfloat16),         # lane, 0/1: exact
             pltpu.VMEM((N, W), jnp.float32),          # diag
             pltpu.VMEM((N, W), pd),                   # block-diagonal q
-            pltpu.VMEM((N, W), jnp.float32),
+            pltpu.VMEM((N, Wv), jnp.float32),
             pltpu.VMEM((N, LANES), jnp.float32),
             pltpu.VMEM((N, LANES), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
+            # the values' own lane and diag, where they are not the keys'
+            *([pltpu.VMEM((Dv, Wv), jnp.bfloat16),
+               pltpu.VMEM((N, Wv), jnp.float32)] if Dv != D else []),
         ],
     )
-    kernel = functools.partial(_decode_kernel, scale=scale, n_heads=N,
-                               kv_heads=K, has_alibi=has_alibi)
+    body = functools.partial(_decode_kernel, scale=scale, n_heads=N,
+                             kv_heads=K, has_alibi=has_alibi)
     scalars = (block_table.astype(jnp.int32), lengths.astype(jnp.int32),
                _layer_operand(layer))
     if windowed:
-        def kernel(bt_ref, len_ref, layer_ref, lo_ref, *refs, _body=kernel):
-            _body(bt_ref, len_ref, layer_ref, *refs, lo_ref=lo_ref)
-
         scalars += (lo.astype(jnp.int32),)
+
+    def kernel(*refs):
+        # the optional operands, each where the call puts it: ``lo`` behind
+        # the scalars, the sinks behind alibi
+        refs, given = list(refs), {}
+        if sink is not None:
+            given["sink_ref"] = refs.pop(len(scalars) + 4)
+        if windowed:
+            given["lo_ref"] = refs.pop(3)
+        body(*refs, **given)
+
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, N, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, N, Dv), q.dtype),
         # rows in order: a row starts the copies of the next one's first tile
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name=name,
         interpret=interpret,
-    )(*scalars, q, k_arena, v_arena, alibi_arr)
+    )(*scalars, q, k_arena, v_arena, *per_head)
 
 
 # ---------------------------------------------------------------------------
@@ -660,13 +711,16 @@ def latent_decode_attention(q: jax.Array, arena: jax.Array, layer,
 # ---------------------------------------------------------------------------
 
 
-def _heads_per_group(kv_heads: int, head_dim: int) -> int:
+def _heads_per_group(kv_heads: int, head_dim: int,
+                     value_dim: Optional[int] = None) -> int:
     """KV heads the prefill kernel takes out of a tile at a time: the fewest
     whose lanes make a slab that a loop can address in the ``(BLOCK, K*D)``
-    page — a multiple of 128 (two heads at head_dim 64, one at 128), else
-    the whole page."""
+    page — a multiple of 128 (two heads at head_dim 64, one at 128; two at
+    keys of 192 beside values of 128, whose slabs are 384 and 256), in the
+    keys' page and in the values' alike, else the whole page."""
     for hp in range(1, kv_heads):
-        if kv_heads % hp == 0 and (hp * head_dim) % LANES == 0:
+        if kv_heads % hp == 0 and (hp * head_dim) % LANES == 0 \
+                and (hp * (value_dim or head_dim)) % LANES == 0:
             return hp
     return kv_heads
 
@@ -708,15 +762,78 @@ def _chunk_blocks(chunk: int, heads: int, pages: int, block_size: int):
 
 
 def _chunk_geometry(chunk: int, n_heads: int, kv_heads: int, block_size: int,
-                    width: int, dtype):
+                    width: int, dtype, value_dim: Optional[int] = None):
     """``(pages, HP, QB, PB)`` of a call of the prefill kernel, from its
-    static shapes: the pages of a tile, the KV heads of a group, the queries
-    of a query block and the pages of a key sub-block."""
+    static shapes (``width``: the lanes of a token's keys; ``value_dim``: a
+    head's values where they are not as wide as its keys): the pages of a
+    tile, the KV heads of a group, the queries of a query block and the
+    pages of a key sub-block."""
     pages = _chunk_tile_pages(block_size, width, dtype)
-    HP = _heads_per_group(kv_heads, width // kv_heads)
+    HP = _heads_per_group(kv_heads, width // kv_heads, value_dim)
     QB, PB = _chunk_blocks(chunk, n_heads // kv_heads * HP, pages,
                            block_size)
     return pages, HP, QB, PB
+
+
+# what a call of the prefill kernel may hold in VMEM and make in a visit
+# (``_chunk_vmem``): a chunk whose queries would pass it goes down as ROWS
+# (``_chunk_parts``). Every chunk served before keys were 192 wide under 64
+# heads stays one row (the widest, 1,024 queries of 32 heads of 64 over 8
+# key-value heads, asks 93 MiB)
+_CHUNK_VMEM_BUDGET = 96 << 20
+
+
+def _chunk_vmem(chunk: int, n_heads: int, kv_heads: int, head_dim: int,
+                value_dim: int, block_size: int, q_dtype, kv_dtype) -> int:
+    """Bytes a call of the prefill kernel asks of VMEM under ``chunk``
+    queries a row: what it HOLDS (the k and v tiles, two buffers each; q and
+    the output as the pipeline double-buffers them; q block-diagonal, the
+    accumulator and the statistics) and what a VISIT makes (a group's slabs
+    of a tile: k, v, and v under each head's ones; its (rows, tile) blocks
+    of scores: float32 scores, p, p rounded, a mask)."""
+    N, K, D, Dv, BS = n_heads, kv_heads, head_dim, value_dim, block_size
+    pages, HP, QB, _ = _chunk_geometry(chunk, N, K, BS, K * D, kv_dtype, Dv)
+    pd = jnp.promote_types(q_dtype, kv_dtype)
+    rows = N // K * HP * pl.cdiv(QB, _ROW_TILE) * _ROW_TILE
+    stats = 1 if _sums_in_slab(HP) else 2
+    held = (2 * tiled_vmem_bytes(pages * BS, K * D, kv_dtype)
+            + 2 * tiled_vmem_bytes(pages * BS, K * Dv, kv_dtype)
+            + 2 * tiled_vmem_bytes(chunk, N * D, q_dtype)
+            + 2 * tiled_vmem_bytes(chunk, N * Dv, q_dtype)
+            + K // HP * (chunk // QB) * (
+                tiled_vmem_bytes(rows, HP * D, pd)
+                + tiled_vmem_bytes(rows, HP * Dv, jnp.float32)
+                + stats * tiled_vmem_bytes(rows, LANES, jnp.float32)))
+    visit = (tiled_vmem_bytes(pages * BS, HP * D, pd)
+             + (1 + HP) * tiled_vmem_bytes(pages * BS, HP * Dv, pd)
+             + 4 * tiled_vmem_bytes(rows, pages * BS, jnp.float32))
+    return held + visit
+
+
+def _chunk_parts(chunk: int, *sizes) -> int:
+    """How many ROWS a row's chunk of queries goes down as (``sizes``:
+    ``_chunk_vmem``'s other arguments): the fewest equal parts, a power of
+    two of whole row tiles each, that the kernel holds within
+    ``_CHUNK_VMEM_BUDGET``. A part is a row of the kernel's grid with its
+    own start and length over the same table, so a later part's walk passes
+    the earlier parts' keys again: copies, which are noise beside a chunk's
+    products."""
+    parts = 1
+    while (_chunk_vmem(chunk // parts, *sizes) > _CHUNK_VMEM_BUDGET
+           and chunk % (2 * parts * _ROW_TILE) == 0):
+        parts *= 2
+    return parts
+
+
+def _part_rows(start, lengths, chunk: int, parts: int, xp):
+    """``(start, lengths)`` of the ``parts`` rows that each row's chunk of
+    ``chunk`` queries goes down as, row-major: a part starts where the one
+    before it ends, and holds the row's keys up to its own last real query
+    (0 where all its queries are pad)."""
+    at = start[:, None] + xp.arange(parts)[None] * (chunk // parts)
+    held = xp.where(at < lengths[:, None],
+                    xp.minimum(lengths[:, None], at + chunk // parts), 0)
+    return at.reshape(-1), held.reshape(-1)
 
 
 def _sums_in_slab(heads_per_group: int) -> bool:
@@ -753,7 +870,8 @@ def _tile_extent(start, length, q0, k0, QB: int, KB: int, nk: int,
 
 def prefill_block_counts(start, lengths, chunk: int, n_heads: int,
                          head_dim: int, arena,
-                         window: Optional[int] = None) -> Dict[str, int]:
+                         window: Optional[int] = None,
+                         value_dim: Optional[int] = None) -> Dict[str, int]:
     """What the prefill kernel's tile steps meet under a chunk of ``chunk``
     queries a row, ``n_heads`` heads of ``head_dim``, from the rows'
     ``start`` and ``lengths`` (host integers, as ``paged_prefill_attention``
@@ -762,13 +880,23 @@ def prefill_block_counts(start, lengths, chunk: int, n_heads: int,
     rows' tiles span, and ``prefill_blocks_skipped``, those of them the
     kernel does not compute (``_tile_extent``: past a row's keys, above the
     diagonal, below the window, all pad). By the kernel's own rules:
-    ``_pages_per_tile``, ``_heads_per_group``, ``_chunk_blocks``."""
+    ``_pages_per_tile``, ``_heads_per_group``, ``_chunk_blocks``, and
+    ``_chunk_parts`` where a chunk goes down as rows (``value_dim``: a
+    head's values where they are not as wide as its keys; q taken to be of
+    the arena's dtype)."""
     block, width = arena.shape[-2:]
-    pages, _, QB, PB = _chunk_geometry(chunk, n_heads, width // head_dim,
-                                       block, width, arena.dtype)
-    TK, KB, nk = pages * block, PB * block, pages // PB
-    start, lengths = (np.asarray(a, np.int64).reshape(-1, 1)
+    kv_heads, value_dim = width // head_dim, value_dim or head_dim
+    start, lengths = (np.asarray(a, np.int64).reshape(-1)
                       for a in (start, lengths))
+    parts = _chunk_parts(chunk, n_heads, kv_heads, head_dim, value_dim,
+                         block, arena.dtype, arena.dtype)
+    if parts > 1:
+        start, lengths = _part_rows(start, lengths, chunk, parts, np)
+        chunk //= parts
+    pages, _, QB, PB = _chunk_geometry(chunk, n_heads, kv_heads, block,
+                                       width, arena.dtype, value_dim)
+    TK, KB, nk = pages * block, PB * block, pages // PB
+    start, lengths = start.reshape(-1, 1), lengths.reshape(-1, 1)
     tiles = -(-lengths // TK)                                   # (rows, 1)
     computed = 0
     for q0 in range(0, chunk, QB):
@@ -784,7 +912,7 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
                     v_hbm, alibi_ref, o_ref, kbuf, vbuf, sems, qbd, acc,
                     m_scr, *rest, scale: float, n_heads: int, kv_heads: int,
                     has_alibi: bool, block_pages: int,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, sink_ref=None):
     *l_scr, slot_ref = rest
     b = pl.program_id(0)
     B = pl.num_programs(0)
@@ -794,10 +922,12 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
     KB, nk = PB * BS, P // PB
     C = q_ref.shape[1]
     D = W // kv_heads
+    Dv = vbuf.shape[3] // kv_heads      # a head's values: D, or narrower
     G = n_heads // kv_heads
     # a group's scratch: (query blocks, its heads x QBP rows, the slab)
     n_groups, nq, rows, WG = qbd.shape
     HP = WG // D                        # KV heads a group, G * HP queries'
+    WGv = HP * Dv                       # the group's slab of the values
     QBP = rows // (G * HP)              # a head's rows: QB, up to a row tile
     QB = C // nq
     # the sum of a head's p rides the lanes its slab leaves it, else l_scr
@@ -835,7 +965,7 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
     def head_rows(j):
         return slice(j * QBP, j * QBP + QB)
 
-    own = [jax.lax.broadcasted_iota(jnp.int32, (1, WG), 1) // D == h
+    own = [jax.lax.broadcasted_iota(jnp.int32, (1, WGv), 1) // Dv == h
            for h in range(HP)]
 
     @pl.when(b == 0)
@@ -856,6 +986,23 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         for l in l_scr:
             l[:] = jnp.zeros_like(l)
+
+        def sinks(g, qb):
+            # a head's sink is one more score that weighs no value: its
+            # running maximum starts at it and its running sum at 1, in the
+            # lanes of the accumulator or in a sum of its own
+            for j in range(G * HP):
+                m_scr[g, qb, head_rows(j), :] = jnp.full(
+                    (QB, LANES), sink_ref[0, g * G * HP + j])
+                if sums_in_acc:
+                    acc[g, qb, head_rows(j), :] = jnp.broadcast_to(
+                        jnp.where(own[j // G], 0.0, 1.0), (QB, WGv))
+                else:
+                    l_scr[0][g, qb, head_rows(j), :] = jnp.ones(
+                        (QB, LANES), jnp.float32)
+
+        if sink_ref is not None:
+            each(n_groups, lambda g: each(nq, functools.partial(sinks, g)))
 
         def lay_out(g, qb):
             # q block-diagonal over its group's slab: a head's rows hold its
@@ -879,7 +1026,7 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
         def group(g, *, slot, qb, k0, keys, keep):
             # the tile's first ``keys`` keys (static: whole sub-blocks)
             k = kbuf[slot, :keys // BS, :, lanes(g, WG)].reshape(keys, WG)
-            v = vbuf[slot, :keys // BS, :, lanes(g, WG)].reshape(keys, WG)
+            v = vbuf[slot, :keys // BS, :, lanes(g, WGv)].reshape(keys, WGv)
             s = jax.lax.dot_general(
                 qbd[g, qb], k.astype(pd), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)             # (rows, keys)
@@ -920,7 +1067,7 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
                 pv = jax.lax.dot_general(
                     p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-            acc[g, qb] = (acc[g, qb] * (corr if WG == LANES else corr[:, :1])
+            acc[g, qb] = (acc[g, qb] * (corr if WGv == LANES else corr[:, :1])
                           + pv)
 
         def visit(qb, *, t, slot, keys, masked):
@@ -973,14 +1120,14 @@ def _prefill_kernel(bt_ref, start_ref, len_ref, layer_ref, q_ref, k_hbm,
                 h = j // G
                 a = acc[g, qb, head_rows(j), :]
                 if sums_in_acc:
-                    other = (h + 1) % HP * D
+                    other = (h + 1) % HP * Dv
                     l = a[:, other:other + 1]
                 else:
                     l = l_scr[0][g, qb, head_rows(j), :1]
                 # a block of pad queries alone was never computed: zeros
-                out.append(a[:, h * D:(h + 1) * D]
+                out.append(a[:, h * Dv:(h + 1) * Dv]
                            / jnp.where(l == 0.0, 1.0, l))
-            o_ref[0, queries(qb), lanes(g, G * HP * D)] = (
+            o_ref[0, queries(qb), lanes(g, G * HP * Dv)] = (
                 out[0] if len(out) == 1
                 else jnp.concatenate(out, axis=1)).astype(o_ref.dtype)
 
@@ -994,7 +1141,8 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
                             alibi: Optional[jax.Array] = None,
                             scale: Optional[float] = None,
                             interpret: bool = False,
-                            window: Optional[int] = None) -> jax.Array:
+                            window: Optional[int] = None,
+                            sink: Optional[jax.Array] = None) -> jax.Array:
     """Chunked-prefill attention through the block table: q (B, C, N, D) —
     C contiguous queries per row at absolute positions ``start[b] + s``
     (the serving ``prefill_chunk`` contract; the chunk's own keys must
@@ -1035,19 +1183,35 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
     block holds no real query) and mean nothing. ``window`` (static): a
     query sees its own key and the ``window - 1`` before it, of the pages
     the table names, which the caller starts at the window's first page
-    (``paged_attention``)."""
+    (``paged_attention``). The values' arena may be narrower a head than the
+    keys' (L, NUM_BLOCKS, BLOCK, K*Dv): the result is (B, C, N, Dv), and a
+    group's slab is whole lane tiles in both. ``sink`` (N,) float32: a
+    learned score a head that takes its share of the softmax's mass and
+    weighs no value (``paged_decode_attention``). A chunk whose queries the
+    kernel cannot hold (64 heads of 192 under 1,024 queries: q alone is 25
+    MB) goes down as rows of fewer (``_chunk_parts``)."""
     B, C, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
     BS, W = k_arena.shape[2:]
+    Dv = _value_dim(v_arena, K)
     _check_page_fits(BS, W, k_arena.dtype)
-    pages, HP, QB, PB = _chunk_geometry(C, N, K, BS, W, k_arena.dtype)
+    if lengths is None:
+        lengths = start + C
+    sizes = (N, K, D, Dv, BS, q.dtype, k_arena.dtype)
+    parts = _chunk_parts(C, *sizes)
+    if parts > 1:
+        starts, held = _part_rows(start, lengths, C, parts, jnp)
+        return paged_prefill_attention(
+            q.reshape(B * parts, C // parts, N, D), k_arena, v_arena, layer,
+            jnp.repeat(block_table, parts, axis=0), starts, held,
+            alibi=alibi, scale=scale, interpret=interpret, window=window,
+            sink=sink).reshape(B, C, N, Dv)
+    pages, HP, QB, PB = _chunk_geometry(C, N, K, BS, W, k_arena.dtype, Dv)
     G = N // K
     scale = scale if scale is not None else D ** -0.5
     has_alibi = alibi is not None
     alibi_arr = (alibi.astype(jnp.float32).reshape(1, N) if has_alibi
                  else jnp.zeros((1, N), jnp.float32))
-    if lengths is None:
-        lengths = start + C
     # the products take q and the keys in the wider of their two dtypes
     pd = jnp.promote_types(q.dtype, k_arena.dtype)
     # a group's scratch: its G * HP query heads stacked along the rows, each
@@ -1056,20 +1220,8 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
     per_group = (K // HP, C // QB, rows)
     # the running sum needs lanes of its own where a slab is one head's
     stats = [per_group + (LANES,)] * (1 if _sums_in_slab(HP) else 2)
-    # what the kernel holds: the k + v tiles, q and the output as the
-    # pipeline double-buffers them, q block-diagonal, the accumulator and
-    # the statistics; and what a visit makes: a group's slabs of a tile (k,
-    # v, and v under each head's ones) and its (rows, tile) blocks of scores
-    # (float32 scores, p, p rounded, a mask); 4 MiB more are the compiler's
-    # own (a tile's tail zeroed in float32, a page's copies)
-    held = (4 * tiled_vmem_bytes(pages * BS, W, k_arena.dtype)
-            + 4 * tiled_vmem_bytes(C, N * D, q.dtype)
-            + K // HP * (C // QB) * (
-                tiled_vmem_bytes(rows, HP * D, pd)
-                + tiled_vmem_bytes(rows, HP * D, jnp.float32)
-                + len(stats) * tiled_vmem_bytes(rows, LANES, jnp.float32)))
-    visit = ((2 + HP) * tiled_vmem_bytes(pages * BS, HP * D, pd)
-             + 4 * tiled_vmem_bytes(rows, pages * BS, jnp.float32))
+    per_head = [alibi_arr] + ([] if sink is None else [
+        sink.astype(jnp.float32).reshape(1, N)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
@@ -1079,15 +1231,16 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
             pl.BlockSpec((1, C, N * D), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            # alibi's slopes and the sinks, a scalar a head
+            *[pl.BlockSpec(memory_space=pltpu.SMEM)] * len(per_head),
         ],
-        out_specs=pl.BlockSpec((1, C, N * D), lambda b, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, C, N * Dv), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, pages, BS, W), k_arena.dtype),
-            pltpu.VMEM((2, pages, BS, W), v_arena.dtype),
+            pltpu.VMEM((2, pages, BS, K * Dv), v_arena.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),          # (k | v, buffer)
             pltpu.VMEM(per_group + (HP * D,), pd),    # block-diagonal q
-            pltpu.VMEM(per_group + (HP * D,), jnp.float32),
+            pltpu.VMEM(per_group + (HP * Dv,), jnp.float32),
             *(pltpu.VMEM(shape, jnp.float32) for shape in stats),
             pltpu.SMEM((1,), jnp.int32),
         ],
@@ -1097,20 +1250,28 @@ def paged_prefill_attention(q: jax.Array, k_arena: jax.Array,
                                block_pages=PB,
                                **({} if window is None
                                   else {"window": int(window)}))
+    if sink is not None:
+        def kernel(*refs, _body=kernel):    # the sinks ride behind alibi
+            refs = list(refs)
+            _body(*refs, sink_ref=refs.pop(8))
+
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, N * D), q.dtype),
-        # rows in order: a row starts the copies of the next one's first tile
+        out_shape=jax.ShapeDtypeStruct((B, C, N * Dv), q.dtype),
+        # rows in order: a row starts the copies of the next one's first
+        # tile. What the kernel holds and a visit makes, and 4 MiB more that
+        # are the compiler's own (a tile's tail zeroed in float32, a page's
+        # copies)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=held + visit + (4 << 20)),
+            vmem_limit_bytes=_chunk_vmem(C, *sizes) + (4 << 20)),
         name="paged_prefill_attention",
         interpret=interpret,
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
       lengths.astype(jnp.int32), _layer_operand(layer),
-      q.reshape(B, C, N * D), k_arena, v_arena, alibi_arr)
-    return out.reshape(B, C, N, D)
+      q.reshape(B, C, N * D), k_arena, v_arena, *per_head)
+    return out.reshape(B, C, N, Dv)
 
 
 # ---------------------------------------------------------------------------
@@ -1123,7 +1284,8 @@ def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
                               block_table: jax.Array, positions: jax.Array,
                               alibi: Optional[jax.Array] = None,
                               scale: Optional[float] = None,
-                              window: Optional[int] = None) -> jax.Array:
+                              window: Optional[int] = None,
+                              sink: Optional[jax.Array] = None) -> jax.Array:
     """GQA-native jnp paged attention — parity oracle for both kernels and
     the CPU serving fallback. q (B, S, N, D); positions (B, S) absolute
     query positions (decode: the row's length-1; negative ⇒ row inactive,
@@ -1133,16 +1295,20 @@ def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
     layout: gathered column == position), and under a ``window`` the
     query's own key and the ``window - 1`` before it (a table that is a ring
     of pages repeats them past the ring's length: every column in sight
-    holds the position it stands for)."""
+    holds the position it stands for). The values may be narrower a head
+    than the keys (``v_arena`` (..., K*Dv); the result (B, S, N, Dv));
+    ``sink`` (N,): a score a head that joins the softmax's sum and weighs
+    no value."""
     B, S, N, D = q.shape
     K = _kv_heads(k_arena, N, D)
+    Dv = _value_dim(v_arena, K)
     BS = k_arena.shape[2]
     MAXB = block_table.shape[1]
     T = MAXB * BS
     G = N // K
     scale = scale if scale is not None else D ** -0.5
     kk = k_arena[layer, block_table].reshape(B, T, K, D)
-    vv = v_arena[layer, block_table].reshape(B, T, K, D)
+    vv = v_arena[layer, block_table].reshape(B, T, K, Dv)
     # zero v beyond each row's max resident position: masked columns get
     # softmax weight 0, but 0 × NaN = NaN — scratch/recycled pages may
     # carry nonfinite residue (e.g. KV written under briefly-poisoned
@@ -1161,14 +1327,21 @@ def reference_paged_attention(q: jax.Array, k_arena: jax.Array,
     if window is not None:
         keep = keep & (col[None, None, :] > positions[:, :, None] - window)
     s = jnp.where(keep[:, None, None, :, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    else:
+        # the sink as one more column, dropped behind the softmax
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, K, G, 1, 1), s.shape[:-1] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, column], axis=-1),
+                           axis=-1)[..., :-1].astype(q.dtype)
     o = jnp.einsum("bkgst,btkd->bskgd", p, vv)
     # rows whose position is negative have an all-masked score row; the
     # softmax then returns uniform weights — zero them explicitly so
     # inactive rows are exactly 0 like the kernel
     inactive = (positions < 0)[:, :, None, None]
-    o = jnp.where(inactive[:, :, None], 0.0, o.reshape(B, S, K, G, D))
-    return o.reshape(B, S, N, D)
+    o = jnp.where(inactive[:, :, None], 0.0, o.reshape(B, S, K, G, Dv))
+    return o.reshape(B, S, N, Dv)
 
 
 # ---------------------------------------------------------------------------
@@ -1183,7 +1356,8 @@ def paged_attention(q: jax.Array, k_arena: jax.Array,
                     scale: Optional[float] = None,
                     window: Optional[int] = None,
                     name: Optional[str] = None,
-                    values: Optional[int] = None) -> jax.Array:
+                    values: Optional[int] = None,
+                    sink: Optional[jax.Array] = None) -> jax.Array:
     """The model's paged read, after its scatter: q (B, S, N, D) at absolute
     ``positions`` (B, S) against ``arena[layer]`` through ``block_table``;
     returns (B, S, N, D). Where the Pallas kernels run (``ops/registry``'s
@@ -1201,6 +1375,7 @@ def paged_attention(q: jax.Array, k_arena: jax.Array,
     key, so that the pages below it cost no copy and no step, whatever the
     row's length; the keys of that first page that lie below the window are
     masked (``lo``, ``window``). ``name``: the decode walk's in a trace.
+    ``sink`` (N,): a learned score a head in the softmax's sum.
 
     ``v_arena`` None: ``k_arena`` is ONE pool that is keys and values both,
     one key-value head as wide as a page whose values are the page's first
@@ -1209,9 +1384,9 @@ def paged_attention(q: jax.Array, k_arena: jax.Array,
     a row (a chunk of queries reads a latent pool expanded:
     ``latent_paged_attention``)."""
     if v_arena is None:
-        if alibi is not None or window is not None:
+        if alibi is not None or window is not None or sink is not None:
             raise ValueError("a one-pool read takes neither alibi nor a "
-                             "window")
+                             "window nor sinks")
         if not registry.kernels_active():
             return reference_paged_attention(
                 q, k_arena, k_arena, layer, block_table, positions,
@@ -1222,11 +1397,12 @@ def paged_attention(q: jax.Array, k_arena: jax.Array,
         return latent_decode_attention(
             q[:, 0], k_arena, layer, block_table, positions[:, 0] + 1,
             values, scale)[:, None]
+    sunk = {} if sink is None else {"sink": sink}
     if not registry.kernels_active():
         return reference_paged_attention(q, k_arena, v_arena, layer,
                                          block_table, positions, alibi=alibi,
-                                         scale=scale, window=window)
-    named = {} if name is None else {"name": name}
+                                         scale=scale, window=window, **sunk)
+    named = {**sunk, **({} if name is None else {"name": name})}
     if window is None:
         if q.shape[1] == 1:
             return paged_decode_attention(q[:, 0], k_arena, v_arena, layer,
@@ -1236,7 +1412,7 @@ def paged_attention(q: jax.Array, k_arena: jax.Array,
         return paged_prefill_attention(q, k_arena, v_arena, layer,
                                        block_table, positions[:, 0],
                                        jnp.max(positions, axis=1) + 1,
-                                       alibi=alibi, scale=scale)
+                                       alibi=alibi, scale=scale, **sunk)
     S, BS = q.shape[1], k_arena.shape[2]
     start = positions[:, 0]
     below = jnp.maximum(start - (window - 1), 0)        # the window's first
@@ -1253,7 +1429,7 @@ def paged_attention(q: jax.Array, k_arena: jax.Array,
             scale=scale, lo=below - first * BS, **named)[:, None]
     return paged_prefill_attention(q, k_arena, v_arena, layer, block_table,
                                    start - first * BS, ends, alibi=alibi,
-                                   scale=scale, window=window)
+                                   scale=scale, window=window, **sunk)
 
 
 # ---------------------------------------------------------------------------

@@ -247,7 +247,7 @@ class ServingEngine:
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..inference.kv_cache import ring_blocks
-        from ..models.transformer import (MIXERS, expert_layers,
+        from ..models.transformer import (MIXERS, attn_shape, expert_layers,
                                           latent_pools, moe_count_width,
                                           recurrent_layers, ring_layers,
                                           tail_runs)
@@ -262,8 +262,11 @@ class ServingEngine:
         self._latent_pools = latent_pools(cfg)
         # what a chunk's span counts of the prefill kernel's tile steps
         self._query_heads = (cfg.num_heads, cfg.head_dim)
-        # window layers keep their keys in a ring of pages a row, beside the
-        # row's state slot and bounded whatever the row's length
+        self._value_dim = attn_shape(cfg).value_dim
+        # window layers keep their keys in a ring of pages a row, addressed
+        # by the row's slot and bounded whatever the row's length; a model
+        # may have rings, recurrent states, or both, and a row owns ONE slot
+        # for whichever it has
         self._window_layers = len(ring_layers(cfg))
         self._ring_blocks = ring_blocks(cfg, self.config.prefill_chunk,
                                         self.config.block_size)
@@ -282,14 +285,16 @@ class ServingEngine:
                               "pools": paged_kv.paged_pools(cfg)}
                              if cfg.loop_passes > 1 else {})
         self.state_slots = (self.config.max_seqs + 1
-                            if self._recurrent_layers else 0)
+                            if self._recurrent_layers or self._window_layers
+                            else 0)
         # the prefix cache shares PAGES between sequences; a recurrent
-        # layer's state at the shared prefix's end is in no page, so the
-        # cache is off for such a model (off, not refused: it is a default)
+        # layer's state at the shared prefix's end, and a window layer's
+        # ring, are in no page, so the cache is off for such a model (off,
+        # not refused: it is a default)
         self.prefix = (paged_kv.PrefixCache(self.alloc,
                                             self.config.block_size)
                        if self.config.prefix_cache
-                       and not self._recurrent_layers else None)
+                       and not self.state_slots else None)
         self.sched = Scheduler(self.config, allocator=self.alloc,
                                clock=clock, prefix_cache=self.prefix)
         # fleet identity on traces / serving-goodput labels (the router
@@ -316,6 +321,20 @@ class ServingEngine:
                     state_slots=self.state_slots,
                     ring_blocks=self._ring_blocks),
                 out_shardings=NamedSharding(engine.mesh, PartitionSpec()))()
+        # what the iteration's span says of the cache, read as the arena
+        # was built: the bytes a token keeps over all pools of the rings
+        # and of the pages, and how many pools each has
+        def token_bytes(names):
+            return sum(a.shape[0] * a.shape[-1] * a.dtype.itemsize
+                       for name, a in self._arena.items() if name in names)
+
+        self._ring_token_bytes = token_bytes(("wk", "wv"))
+        self._page_token_bytes = token_bytes(paged_kv.PAGE_ARENAS)
+        self._cache_layers = {
+            "window_layers": (self._arena["wk"].shape[0]
+                              if "wk" in self._arena else 0),
+            "full_layers": max(a.shape[0] for name, a in self._arena.items()
+                               if name in paged_kv.PAGE_ARENAS)}
         # an MoE model's two programs return their routing counts behind
         # the tokens (_program_counts); 0 = a dense model, whose programs and
         # spans know nothing of it. ``total`` counts the ROUTER's outputs a
@@ -495,17 +514,19 @@ class ServingEngine:
             " MiB")
 
     def _no_state_snapshot(self, what: str) -> None:
-        """THE place that refuses, by name, what a model with recurrent
-        layers cannot do yet: whatever shares, copies or rolls back a
-        sequence's pages would have to snapshot its recurrent state too,
-        and nothing takes such a snapshot (ROADMAP B-m5)."""
-        if self._recurrent_layers:
+        """THE place that refuses, by name, what a model whose rows own a
+        slot cannot do yet: whatever shares, copies or rolls back a
+        sequence's pages would have to snapshot what its slot holds too (a
+        recurrent state, a window's ring), and nothing takes such a snapshot
+        (ROADMAP B-m5)."""
+        if self.state_slots:
             raise NotImplementedError(
                 f"{what} is not supported for a model with recurrent "
-                "(linear-attention or state-space) layers: it needs a "
-                "snapshot of a sequence's recurrent state (the state and "
-                "convolution tail of serving/paged_kv.py's state pools), "
-                "which nothing takes yet; pages alone do not hold it")
+                "(linear-attention or state-space) layers or window layers: "
+                "it needs a snapshot of what a sequence's slot holds (the "
+                "state and convolution tail of serving/paged_kv.py's state "
+                "pools, a window layer's ring of pages), which nothing "
+                "takes yet; pages alone do not hold it")
 
     def _no_latent_read(self, what: str) -> None:
         """THE place that refuses, by name, what a model with a latent arena
@@ -961,8 +982,8 @@ class ServingEngine:
         program — the RLHF reference-logprob pass costs zero extra
         compiles.
 
-        A model with recurrent layers scores a sequence's last
-        ``_SCORE_STEP_TAIL`` tokens ONE at a time (the same program traced
+        A model whose rows own a slot (recurrent layers, window layers)
+        scores a sequence's last ``_SCORE_STEP_TAIL`` tokens ONE at a time (the same program traced
         at a width of one): the model's one-token forms, which its decode
         program runs (``kda_decode_step``, ``mamba2_decode_step`` or
         ``mamba1_decode_step`` on the state pools in place, the paged decode
@@ -999,7 +1020,7 @@ class ServingEngine:
                 # the sequence (only a chunk at 0 starts a state from
                 # zeros); the last position has no target, so no step
                 body = (max(T - 1 - _SCORE_STEP_TAIL, 1)
-                        if self._recurrent_layers else T)
+                        if self.state_slots else T)
                 pieces = ([(at, min(C, body - at), C)
                            for at in range(0, body, C)]
                           + [(at, 1, 1) for at in range(body, T - 1)])
@@ -1197,6 +1218,7 @@ class ServingEngine:
                     holds=self.holds,
                     **obs.tracer.gc_counts(),
                     **self._state_counts(),
+                    **self._cache_counts(),
                     **(hbm_counts()
                        if self._iterations % ACCOUNT_EVERY == 0 else {}))
 
@@ -1251,7 +1273,7 @@ class ServingEngine:
         run; until then the slot still holds what the row's last owner left,
         which that chunk starts over) and the slots rows can own (scratch is
         not one). Nothing for any other model."""
-        if not self._recurrent_layers:
+        if not self.state_slots:
             return {}
         live = [r.length for r in self.sched.running.values()
                 if r.length > 0]
@@ -1267,6 +1289,22 @@ class ServingEngine:
                     min(n, ring) for n in live),
                 window_tokens_bound=self._window_layers * window * len(live))
         return counts
+
+    def _cache_counts(self) -> Dict[str, int]:
+        """What the running requests keep in the cache: the bytes of their
+        tokens in the window layers' rings (a ring's worth a row at most;
+        0 for a model with no ring) and those plus the bytes of the blocks
+        they hold in the arenas of pages, beside the pools of each the
+        arena was built with."""
+        running = list(self.sched.running.values())
+        ring = self._ring_blocks * self.config.block_size
+        in_rings = self._ring_token_bytes * sum(
+            min(r.length, ring) for r in running)
+        in_pages = (self._page_token_bytes * self.config.block_size
+                    * sum(len(r.blocks) for r in running))
+        return {"ring_resident_bytes": in_rings,
+                "cache_resident_bytes": in_rings + in_pages,
+                **self._cache_layers}
 
     def _table_for(self, reqs: List[Request]) -> np.ndarray:
         """(len(reqs), MAXB) block table; unfilled entries → scratch 0."""
@@ -1539,7 +1577,8 @@ class ServingEngine:
             C = self.config.prefill_chunk
             span.annotate(**prefill_block_counts(
                 [start], [min(start + C, int(req.prompt.size))], C,
-                *self._query_heads, self._arena["k"]))
+                *self._query_heads, self._arena["k"],
+                value_dim=self._value_dim))
         return span
 
     def _prepare_chunk(self, obs, req: Request,
@@ -1564,8 +1603,7 @@ class ServingEngine:
             packed = paged_kv.pack_chunk(
                 self._table_for([req]), chunk, start, n_valid,
                 *self._sampling_arrays([req]),
-                state_slot=([req.row] if self._recurrent_layers
-                            else None),
+                state_slot=([req.row] if self.state_slots else None),
                 **({"last": [last]} if self._chunk_says_last else {}))
         return packed, int(start), int(n_valid)
 
@@ -2833,7 +2871,7 @@ class ServingEngine:
                 self._arena_sds(),
                 jax.ShapeDtypeStruct(
                     paged_kv.chunk_shape(MAXB, C,
-                                         bool(self._recurrent_layers),
+                                         bool(self.state_slots),
                                          self._chunk_says_last),
                     jnp.int32),
                 jax.ShapeDtypeStruct((2,), jnp.uint32))

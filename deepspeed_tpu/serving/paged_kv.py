@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..inference.kv_cache import (PAGE_ARENAS, assert_block_divisible,
+                                  cache_slots,
                                   blocks_for_tokens, init_paged_cache,
                                   paged_cache_memory_bytes, paged_pools)
 
@@ -452,7 +453,8 @@ def _verify_columns(max_blocks: int, num_tokens: int):
 def _chunk_columns(max_blocks: int, chunk: int, state_slot: bool,
                    last: bool = False):
     """The prefill-chunk program's flat ``(MAXB + C + 6,)`` vector, one
-    entry longer for a model with recurrent layers (``state_slot``) and one
+    entry longer for a model whose rows own a slot (``state_slot``: recurrent
+    layers, window layers) and one
     for a model whose stack ends in runs that only a prompt's LAST chunk
     needs (``last``: ``models/transformer.tail_runs``)."""
     return (("block_table", max_blocks), ("chunk", chunk), ("start", 1),
@@ -657,8 +659,8 @@ def build_prefill_program(cfg, chunk_tokens: int, moe_counts: bool = False):
     has_last = tail_runs(cfg) > 0
 
     def prefill_chunk(params, cache, packed, base_key):
-        operands = list(unpack_chunk(packed, chunk_tokens, "tail" in cache,
-                                     has_last))
+        operands = list(unpack_chunk(packed, chunk_tokens,
+                                     cache_slots(cache) > 0, has_last))
         last = operands.pop() if has_last else None
         return step(params, cache, *operands, base_key, last)
 
@@ -732,9 +734,10 @@ def build_decode_program(cfg, moe_counts: bool = False):
     out of the routing; with ``moe_counts`` (the serving engine's own
     program) ``next_token`` is (R + 3,): the tokens, then the step's routing
     counts over the rows that hold a request (``_with_moe_counts``).
-    A model with recurrent layers keeps row r's state in slot r of the
-    pools in ``cache``; a row that holds nothing is sent to the last slot,
-    scratch, and advances nothing.
+    A model with recurrent layers keeps row r's state, and one with window
+    layers row r's ring, in slot r of the pools in ``cache``
+    (``kv_cache.cache_slots``); a row that holds nothing is sent to the last
+    slot, scratch, and advances nothing.
     """
     step = _decode_step(cfg, moe_counts)
 
@@ -759,14 +762,14 @@ def _decode_step(cfg, moe_counts: bool = False):
         # The mask also sends an empty row's write to the scratch block,
         # which is where its all-zero table sent it anyway. A dense model
         # routes nothing, and its program stays as it was.
-        recurrent = "tail" in cache     # every per-sequence state has one
+        n_slots = cache_slots(cache)    # a state's or a ring's, a row
         live = ((lengths > 0)[:, None]
-                if cfg.moe_num_experts > 0 or recurrent else None)
+                if cfg.moe_num_experts > 0 or n_slots else None)
         slots = None
-        if recurrent:
+        if n_slots:
             slots = jnp.where(lengths > 0,
                               jnp.arange(lengths.shape[0], dtype=jnp.int32),
-                              cache["tail"].shape[1] - 1)
+                              n_slots - 1)
         logits, cache, _, *counts = model_forward(
             params, tokens[:, None], cfg, cache=cache,
             positions=lengths[:, None], block_table=block_table,
@@ -956,10 +959,11 @@ def build_score_program(cfg):
     def score_chunk(params, cache, block_table, chunk, targets, start,
                     n_valid):
         write_mask, pos = _run_operands(chunk.shape[1], start, n_valid)
-        # a scored sequence's recurrent state lives in the scratch slot:
-        # no decode row owns it, and its first chunk starts it from zeros
-        slots = (jnp.full((1,), cache["tail"].shape[1] - 1, jnp.int32)
-                 if "tail" in cache else None)
+        # a scored sequence's recurrent state, and its ring, live in the
+        # scratch slot: no decode row owns it, and its first chunk starts
+        # the state from zeros and the ring over
+        slots = (jnp.full((1,), cache_slots(cache) - 1, jnp.int32)
+                 if cache_slots(cache) else None)
         logits, cache, _ = model_forward(params, chunk, cfg, cache=cache,
                                          positions=pos,
                                          block_table=block_table,

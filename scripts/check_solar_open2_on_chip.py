@@ -82,6 +82,7 @@ def main(argv=None, family=FAMILY):
 
     import deepspeed_tpu
     from deepspeed_tpu.inference.engine import InferenceConfig
+    from deepspeed_tpu.inference.kv_cache import cache_slots
     from deepspeed_tpu.models.transformer import (forward,
                                                   gather_target_logprobs)
     from deepspeed_tpu.parallel import mesh as mesh_mod
@@ -130,11 +131,11 @@ def main(argv=None, family=FAMILY):
 
     def one_token(params, cache, table, lengths, tokens, targets):
         live = (lengths > 0)[:, None]
-        # a model with no recurrent layer keeps no state pools (every
-        # per-sequence state has a "tail"; a short convolution's is all)
+        # a model with neither a recurrent layer nor a window's ring keeps
+        # no slots (``kv_cache.cache_slots``: 0)
+        n_slots = cache_slots(cache)
         slots = (jnp.where(lengths > 0, jnp.arange(R, dtype=jnp.int32),
-                           cache["tail"].shape[1] - 1)
-                 if "tail" in cache else None)
+                           n_slots - 1) if n_slots else None)
         logits, cache, _ = forward(
             params, tokens[:, None], cfg, cache=cache,
             positions=lengths[:, None], block_table=table,

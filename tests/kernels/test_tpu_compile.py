@@ -326,6 +326,60 @@ def test_window_walk_compiles_for_v5e(v5e, monkeypatch, queries, rows):
             else "paged_prefill_attention") in text
 
 
+def _two_widths(queries, rows, kv_heads, window, monkeypatch):
+    """``paged_attention`` on its kernel branch at mimo-v2-flash's shapes:
+    64 query heads, keys 192 and values 128 wide; a full layer's 4 key-value
+    heads over pages, or a window layer's 8 over a ring of 72 pages a slot
+    with a learned sink a head."""
+    from deepspeed_tpu.ops import registry
+
+    monkeypatch.setattr(registry, "kernels_active", lambda: True)
+    blocks = 20481 if window is None else 1 + 33 * 72
+    pools = 2 if window is None else 5
+    name = ("full_kv_decode_attention" if window is None
+            else "window_decode_attention")
+
+    def fn(q, k, v, layer, table, positions, sink):
+        return paged_attention(q, k, v, layer, table, positions,
+                               scale=192 ** -0.5, window=window, name=name,
+                               **({} if window is None else {"sink": sink}))
+
+    return fn, [((rows, queries, 64, 192), BF16),
+                ((pools, blocks, 16, kv_heads * 192), BF16),
+                ((pools, blocks, 16, kv_heads * 128), BF16), ((), I32),
+                ((rows, 640), I32), ((rows, queries), I32),
+                ((64,), jnp.float32)]
+
+
+@pytest.mark.parametrize("queries,rows", [(1, 32), (1024, 1)],
+                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("kv_heads,window", [(4, None), (8, 128)],
+                         ids=["full", "window-sink"])
+def test_walks_of_two_widths_compile_for_v5e(v5e, monkeypatch, kv_heads,
+                                             window, queries, rows):
+    """Keys 192 and values 128 wide under 64 query heads: the decode walk
+    with the values' own lane and diag masks, and the prefill kernel over
+    slabs of two heads (384 and 256 lanes), its chunk of 1,024 queries gone
+    down as rows of 256 (16 heads a key-value head) or of 128 (8)."""
+    fn, shapes = _two_widths(queries, rows, kv_heads, window, monkeypatch)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=v5e) for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    if queries == 1:
+        assert ("window_decode_attention" if window
+                else "full_kv_decode_attention") in text
+        return
+    assert "paged_prefill_attention" in text
+    asked = [int(n) for n in re.findall(
+        r'scoped_memory_configs\\22: \[\{\\22memory_space\\22:1, '
+        r'\\22offset\\22: 0, \\22size\\22: (\d+)', lowered.as_text())]
+    assert len(asked) == 1 and 16 << 20 <= asked[0] <= 100 << 20
+    parts = 4 if window is None else 8
+    assert f"bf16[{parts},{1024 // parts},12288]" in lowered.as_text() \
+        or f"{parts}x{1024 // parts}x12288xbf16" in lowered.as_text()
+
+
 # ---------------------------------------------------------------------------
 # the form of a walk's copies, read off the kernel's own text: what sets a
 # decode walk's pace is its descriptors, and a loop around a page's start
@@ -581,12 +635,13 @@ def _serving_program(kind, v5e, monkeypatch, preset="opt-1.3b",
 
     params = on_chip(jax.eval_shape(
         lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
-    recurrent = bool(T.recurrent_layers(cfg)[1])
+    # a row owns a slot for a recurrent state, a window's ring, or both
+    recurrent = bool(T.recurrent_layers(cfg)[1] or T.ring_layers(cfg))
     maxb = program_options.pop("maxb", MAXB)
     arena = on_chip(paged_cache_shape_struct(
         cfg, num_blocks, BLOCK, BF16,
         state_slots=rows + 1 if recurrent else 0,
-        ring_blocks=ring_blocks(cfg, CHUNK, BLOCK)))
+        ring_blocks=ring_blocks(cfg, chunk, BLOCK)))
 
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
@@ -1111,6 +1166,65 @@ def test_a_tail_only_stack_reads_banks_and_pools_where_they_lie(
     # a chunk's experts' rows, 4,096 x 1,792 twice over, and its scores
     assert compiled.memory_analysis().temp_size_in_bytes < (
         16e6 if kind == "decode" else 0.5e9)
+
+
+# mimo-v2-flash as its cell serves it: layer 0 and one whole period (5 window
+# layers, a full one), 16 of 256 experts held, an eighth of the vocabulary;
+# 32 rows of up to 10,240 tokens: pages for the 2 full layers, a ring of 72
+# pages a slot for the 5 window layers, and no state at all
+MIMO = {"num_layers": 7, "moe_experts_held": 16, "vocab_size": 19072}
+MIMO_ROWS, MIMO_BLOCKS, MIMO_MAXB, MIMO_CHUNK = 32, 20481, 640, 1024
+MIMO_PAGES = (f"bf16[2,{MIMO_BLOCKS},{BLOCK},768]",
+              f"bf16[2,{MIMO_BLOCKS},{BLOCK},512]")
+MIMO_RINGS = (f"bf16[5,{1 + (MIMO_ROWS + 1) * 72},{BLOCK},1536]",
+              f"bf16[5,{1 + (MIMO_ROWS + 1) * 72},{BLOCK},1024]")
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_rings_without_a_state_ride_the_runs_carry_where_they_lie(
+        v5e, monkeypatch, kind):
+    """MiMo-V2-Flash's serving programs for the chip at its cell's shapes: a
+    window layer's ring is addressed by the row's slot with no recurrent
+    layer in the model (the arena holds `"slots"`, 33 int32, and no
+    `"tail"`); keys and values are pools of different widths in both forms;
+    a decode step walks the 5 rings under `window_decode_attention` and the
+    2 pools under `full_kv_decode_attention`, a chunk goes through the
+    prefill kernel 7 times; neither the pages (1.68 GB) nor the rings (0.97
+    GB) are copied, and no layer's experts leave their bank."""
+    compiled = _serving_program(
+        kind, v5e, monkeypatch, preset="mimo-v2-flash", overrides=MIMO,
+        rows=MIMO_ROWS, num_blocks=MIMO_BLOCKS, maxb=MIMO_MAXB,
+        chunk=MIMO_CHUNK, moe_counts=True).compile()
+    text = compiled.as_text()
+    roots = _fusion_roots(text)
+    offenders = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m is None:
+            continue
+        _, result, op = m.groups()
+        if any(pool in result for pool in MIMO_PAGES + MIMO_RINGS) \
+                and not _writes_in_place(line, op, roots):
+            offenders.append(line.strip()[:200])
+        # a layer's experts or a kind's bank, the dense FFN's matrices
+        if op in ("copy", "transpose") and "/gather" not in line and re.match(
+                r"bf16\[([15],)?(16,)?(4096,2048|2048,4096|4096,16384|"
+                r"16384,4096|4096,12288|8192,4096)\]", result):
+            offenders.append(line.strip()[:200])
+    assert not offenders, "\n".join(offenders)
+    for pool in MIMO_PAGES + MIMO_RINGS:
+        assert pool in text, pool
+    assert "s32[33]" in text
+    steps = kind == "decode"
+    assert _custom_calls(text, "window_decode_attention") == (5 if steps else 0)
+    assert _custom_calls(text, "full_kv_decode_attention") \
+        == (2 if steps else 0)
+    assert _custom_calls(text, "paged_prefill_attention") \
+        == (0 if steps else 7)
+    assert "shared_kv_decode_attention" not in text
+    assert _custom_calls(text, "moe_grouped_matmul") == 2 * 6
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        32e6 if steps else 1.0e9)
 
 
 def _projection_weights(text, widths, layers):
